@@ -27,6 +27,7 @@ from .runtime.config import DeepSpeedConfig, DeepSpeedConfigError  # noqa: F401
 from .runtime.engine import DeepSpeedEngine  # noqa: F401
 from .runtime.topology import MeshTopology, TopologyConfig  # noqa: F401
 from .comm.comm import init_distributed  # noqa: F401
+from .utils.compile_cache import enable_compile_cache  # noqa: F401
 
 
 def maybe_apply_tuned_config(config: Optional[Any]) -> Optional[Any]:
@@ -94,6 +95,7 @@ def initialize(args=None,
     # engine construction stays byte-identical to an autotuner-free build
     config = maybe_apply_tuned_config(config)
 
+    enable_compile_cache()
     init_distributed()
 
     # engine selection (reference deepspeed/__init__.py:156-193: hybrid_engine
@@ -151,6 +153,7 @@ def init_inference(model=None, config=None, model_path: Optional[str] = None, **
     (``inference/engine.py:254`` + ``module_inject/load_checkpoint.py``).
     """
     from .inference.engine import InferenceEngine
+    enable_compile_cache()
     if model_path is not None:
         if model is not None:
             raise ValueError("init_inference: pass either model or model_path, "

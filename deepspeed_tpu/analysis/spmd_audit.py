@@ -121,9 +121,11 @@ RESIDUAL_BYTES_DEFAULT = 1 << 14          # 16 KiB per-layer residual slice
 # source jaxpr collective primitive -> HLO collective kind(s) it may
 # legitimately lower to (reduce_scatter may legalize as all-reduce+slice).
 _SRC_PRIM_KINDS: Dict[str, Tuple[str, ...]] = {
-    "psum": ("all-reduce",), "psum2": ("all-reduce",),
+    # JAX 0.9.0 binds the *_invariant forms under shard_map(check_vma=True)
+    "psum": ("all-reduce",), "psum_invariant": ("all-reduce",),
     "pmin": ("all-reduce",), "pmax": ("all-reduce",),
-    "all_gather": ("all-gather",), "pgather": ("all-gather",),
+    "all_gather": ("all-gather",), "all_gather_invariant": ("all-gather",),
+    "pgather": ("all-gather",),
     "reduce_scatter": ("reduce-scatter", "all-reduce"),
     "ppermute": ("collective-permute",),
     "pshuffle": ("collective-permute",),

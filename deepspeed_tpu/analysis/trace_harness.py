@@ -115,8 +115,9 @@ _CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback",
 # pmin/pmax, 'axis_name' on the rest — reduce_scatter is psum_scatter's
 # primitive name).
 _COLLECTIVE_PRIMS = {
-    "psum", "pmin", "pmax", "ppermute", "pshuffle", "all_gather",
-    "all_to_all", "reduce_scatter", "axis_index", "pgather", "psum2",
+    "psum", "psum_invariant", "pmin", "pmax", "ppermute", "pshuffle",
+    "all_gather", "all_gather_invariant", "all_to_all", "reduce_scatter",
+    "axis_index", "pgather",
 }
 
 

@@ -17,15 +17,11 @@ RED_FAIL = "\033[91m[FAIL]\033[0m"
 def op_report() -> list:
     """Which op implementations are usable here (reference ds_report op table)."""
     import jax
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = jax.default_backend() == "tpu"
     rows = []
     rows.append(("fused_adam (pallas)", True, "interpret mode on cpu"))
     rows.append(("quantizer int8/int4", True, "XLA"))
-    try:
-        from jax.experimental.pallas.ops.tpu import flash_attention  # noqa: F401
-        rows.append(("flash_attention (pallas)", on_tpu, "tpu only; XLA fallback elsewhere"))
-    except ImportError:
-        rows.append(("flash_attention (pallas)", False, "pallas ops unavailable"))
+    rows.append(("flash_attention (pallas)", on_tpu, "tpu only; XLA fallback elsewhere"))
     try:
         from deepspeed_tpu.ops.aio import AsyncIOBuilder
         rows.append(("async_io (C++)", AsyncIOBuilder().is_compatible(), "NVMe offload tier"))

@@ -18,7 +18,6 @@ Page layout everywhere: ``[kv_heads, num_pages, page_size, head_dim]``.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -35,24 +34,13 @@ def _paged_kernel_opted_in() -> bool:
     return os.environ.get("DSTPU_PALLAS_PAGED", "0") == "1"
 
 
-@functools.lru_cache(None)
-def _paged_kernel_importable() -> bool:
-    try:
-        from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention  # noqa: F401
-        return True
-    except ImportError:  # pragma: no cover
-        return False
-
-
 def _pallas_paged_available() -> bool:
-    """Opt-IN via DSTPU_PALLAS_PAGED=1. Measured on the attached v5e
-    (round 2): decode is round-trip/bandwidth bound, the XLA gather path
-    is at least as fast, and the stock kernel fails Mosaic lowering for
-    head_dim-64 models inside the fused decode-burst scan (block spec
-    (..., 64) rejection) — an error a call-site try/except cannot catch
-    because it fires at compile time. XLA is therefore the default."""
-    return (_paged_kernel_opted_in() and jax.default_backend() == "tpu"
-            and _paged_kernel_importable())
+    """Opt-IN via DSTPU_PALLAS_PAGED=1: XLA gather is the default decode
+    path. The stock kernel's Mosaic lowering rejects head_dim-64 models
+    inside the fused decode-burst scan (block spec (..., 64)) — a
+    compile-time error a call-site try/except cannot catch. Not measured
+    on the current machine."""
+    return _paged_kernel_opted_in() and jax.default_backend() == "tpu"
 
 
 def _gather_pages(pages: jax.Array, block_tables: jax.Array,

@@ -265,7 +265,7 @@ def _wave_call(q_tiled, k_pages, v_pages, q_lens, kv_lens, page_indices, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((A, kvH, rows, D), q_tiled.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),

@@ -65,21 +65,19 @@ class DSModuleRegistry:
 
 
 def _pallas_paged_supported(ctx: Dict[str, Any]) -> bool:
-    """Opt-in (DSTPU_PALLAS_PAGED=1) + TPU backend + kernel importable —
+    """Opt-in (DSTPU_PALLAS_PAGED=1) + TPU backend —
     ONE policy shared with the kernel layer (paged_attention.py helpers)
     so the registry never selects an implementation the kernel dispatch
     would not take; the ctx may override the backend for planning."""
     import jax
 
-    from ..kernels.paged_attention import (_paged_kernel_importable,
-                                           _paged_kernel_opted_in)
+    from ..kernels.paged_attention import _paged_kernel_opted_in
     if not _paged_kernel_opted_in():
         return False
     if ctx.get("backend", jax.default_backend()) != "tpu":
         return False
-    if ctx.get("position") == "alibi":
-        return False  # stock kernel has no bias input (bloom → XLA path)
-    return _paged_kernel_importable()
+    # stock kernel has no bias input (bloom → XLA path)
+    return ctx.get("position") != "alibi"
 
 
 ATTENTION_DECODE_REGISTRY = DSModuleRegistry("attention_decode")

@@ -50,7 +50,8 @@ def top_k_gating_indices(logits: jax.Array, top_k: int, capacity_: int):
     (``ops/transformer/pallas_moe.py::_route_kernel``) replicates this
     function's fp32 operation sequence EXACTLY — same softmax, same
     lowest-index tie rule (``lax.top_k`` == masked re-argmax), same
-    cumsum position ranks, capacity clamps and weight normalization —
+    position ranks (the cumsum as a triangular product), capacity clamps
+    and weight normalization —
     so kernel- and XLA-path routing decisions are bit-identical. Any
     change here must be mirrored there;
     ``tests/unit/ops/test_pallas_moe.py::TestRoute`` pins the pair.

@@ -33,7 +33,6 @@ side was the extra pass."""
 
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
@@ -59,7 +58,7 @@ def _quant_rows_kernel(x_ref, q_out, s_out):
     scale = jnp.where(scale == 0, 1.0, scale)
     q = jnp.clip(jnp.round(x / scale), -128, 127).astype(jnp.int8)
     q_out[:] = q
-    s_out[:] = scale[:, 0]
+    s_out[:] = scale
 
 
 def quantize_rows_int8(groups: jax.Array, *, interpret=None
@@ -79,14 +78,14 @@ def quantize_rows_int8(groups: jax.Array, *, interpret=None
         x = jnp.pad(x, ((0, Gp - G), (0, 0)))
     spec = pl.BlockSpec((bm, gs), lambda i: (i, 0))
     q, s = pl.pallas_call(
-        functools.partial(_quant_rows_kernel),
+        _quant_rows_kernel,
         grid=(Gp // bm,),
         in_specs=[spec],
-        out_specs=[spec, pl.BlockSpec((bm,), lambda i: (i,))],
+        # scales leave as a [Gp, 1] column: Mosaic refuses a rank-1 block
+        # that is not a multiple of the 128-lane tiling
+        out_specs=[spec, pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((Gp, gs), jnp.int8),
-                   jax.ShapeDtypeStruct((Gp,), jnp.float32)],
+                   jax.ShapeDtypeStruct((Gp, 1), jnp.float32)],
         interpret=interpret,
     )(x)
-    if Gp != G:
-        q, s = q[:G], s[:G]
-    return q, s
+    return q[:G], s[:G, 0]

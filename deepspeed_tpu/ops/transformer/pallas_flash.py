@@ -238,7 +238,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info):
                 pltpu.VMEM((bq, D), jnp.float32),
             ]),
         out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=cfg.interpret,
@@ -389,7 +389,7 @@ def _bwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info,
             out_specs=pl.BlockSpec((1, 1, bq, D), q_row_idx),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BK, G, Sq, D), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=cfg.interpret,
@@ -437,7 +437,7 @@ def _bwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info,
                             pltpu.VMEM((bk, D), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((BK, Sk, D), k.dtype),
                    jax.ShapeDtypeStruct((BK, Sk, D), v.dtype)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=cfg.interpret,

@@ -10,11 +10,11 @@ op: the gathered ``[E*C, H]`` dispatch buffer, its wire-cast copy, the
 round-trip HBM between fusion boundaries. The kernel pair does the same
 math in three launches that each read their operands once:
 
-1. **route kernel** — top-k route select fused with the capacity-slot
-   scatter: softmax, top-k pick, per-expert position ranks, capacity
-   clamp, weight normalization and the inverse slot→token map
-   (``src``/``slot_w``) emerge from ONE launch over the logits instead
-   of the ~20-op XLA gating chain.
+1. **route kernel** — top-k route select: softmax, top-k pick,
+   per-expert position ranks, capacity clamp and weight normalization
+   in ONE launch over the (transposed, token-lane-dense) logits instead
+   of the ~20-op XLA gating chain; the inverse slot→token map
+   (``src``/``slot_w``) is an XLA scatter over the kernel's outputs.
 2. **dispatch gather+cast kernel** — the capacity-slot gather fused with
    the WIRE cast: a scalar-prefetched grid (one slot row per step, the
    paged-attention table-lookup idiom) reads each routed token row from
@@ -86,6 +86,8 @@ _FFN_BUDGET = 12 * 1024 * 1024
 #: capacity/ffn block caps (divisor-clamped to the actual extents).
 _CAP_BLOCK = 256
 _FFN_BLOCK = 512
+#: route kernel rank-scan lane block (one [tb, tb] triangular operand).
+_ROUTE_BLOCK = 512
 
 
 def moe_kernel_mode(env_var: str = "DSTPU_MOE_KERNEL") -> str:
@@ -114,7 +116,8 @@ def moe_kernel_supported(*, top_k: int, activation: str, dtype,
     shapes keep the XLA path (never an error): top-k beyond 2 (the
     in-kernel pick is a masked-argmax chain), exotic activations, fp16
     (the pad-row overflow case the XLA path masks), token counts whose
-    gating intermediates exceed the route kernel's VMEM budget, and
+    gating intermediates exceed the route kernel's VMEM budget or have
+    no lane-aligned block split for its rank scan, and
     hidden sizes whose FFN-grid working set (a [cap_block, H] payload
     block + three [H, ffn_block] weight blocks, double-buffered, plus
     the [cap_block, H] f32 accumulator) exceeds the FFN budget."""
@@ -126,6 +129,8 @@ def moe_kernel_supported(*, top_k: int, activation: str, dtype,
                                 jnp.dtype(jnp.bfloat16)):
         return False
     if tokens * num_experts * 4 > _ROUTE_BUDGET:
+        return False
+    if _route_block(tokens) is None:
         return False
     itemsize = jnp.dtype(dtype).itemsize
     ffn_step = hidden * (2 * (_CAP_BLOCK + 3 * _FFN_BLOCK) * itemsize
@@ -189,64 +194,77 @@ def _divisor_block(extent: int, cap: int) -> int:
 # 1. route kernel: top-k select + capacity-slot scatter in one launch
 # ---------------------------------------------------------------------------
 
-def _route_kernel(logits_ref, src_ref, slw_ref, slot_tk_ref, w_tk_ref,
-                  me_ref, ce_ref, *, top_k: int, cap: int):
-    """One launch over [T, E] logits. Replicates
-    ``top_k_gating_indices``'s fp32 operation sequence exactly (argmax ==
-    ``lax.top_k``'s lowest-index tie rule; the k=2 pick is a masked
-    re-argmax), then scatters the inverse slot→token map: ``src[slot]`` =
-    token index + 1 (0 = unfilled), ``slot_w[slot]`` = that choice's
-    normalized combine weight. Token-major combine metadata
-    (``slot_tk``/``w_tk``) feeds the split combine path."""
-    logits = logits_ref[...].astype(jnp.float32)        # [T, E]
-    T, E = logits.shape
+def _route_block(tokens: int) -> Optional[int]:
+    """Lane-block width of the route kernel's rank scan: the whole token
+    axis when it fits one block, else the widest 128-multiple divisor.
+    ``None`` = no lane-aligned split exists (the geometry keeps XLA)."""
+    if tokens <= _ROUTE_BLOCK:
+        return tokens
+    for tb in range(_ROUTE_BLOCK, 0, -128):
+        if tokens % tb == 0:
+            return tb
+    return None
+
+
+def _route_kernel(logits_ref, slot_ref, w_ref, me_ref, ce_ref, *,
+                  top_k: int, cap: int, tb: int):
+    """One launch over the TRANSPOSED logits [E, T] (experts on sublanes,
+    tokens lane-dense). Replicates ``top_k_gating_indices``'s fp32
+    operation sequence (lowest-index argmax == ``lax.top_k``'s tie rule;
+    the k=2 pick is a masked re-argmax). The per-expert position ranks —
+    a cumsum over tokens, which Mosaic does not lower — are a blocked
+    product with an upper-triangular 0/1 matrix on the MXU (exact: 0/1
+    operands, fp32 accumulation of counts < 2^24). Emits, per choice row
+    k: the capacity slot (``E*cap`` = dropped) and the normalized combine
+    weight; the slot->token inversion is an XLA scatter in ``moe_route``
+    (a per-token scalar store loop has no vector form)."""
+    logits = logits_ref[...].astype(jnp.float32)        # [E, T]
+    E, T = logits.shape
     S = E * cap
-    gates = jax.nn.softmax(logits, axis=-1)
+    unnorm = jnp.exp(logits - jnp.max(logits, axis=0, keepdims=True))
+    gates = unnorm / jnp.sum(unnorm, axis=0, keepdims=True)
 
-    src_ref[...] = jnp.zeros_like(src_ref)
-    slw_ref[...] = jnp.zeros_like(slw_ref)
-
-    counts = jnp.zeros((E,), jnp.int32)
-    gate_sum = jnp.zeros((T,), jnp.float32)
+    eidx = jax.lax.broadcasted_iota(jnp.int32, (E, T), 0)
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (tb, tb), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (tb, tb), 1)
+           ).astype(jnp.bfloat16)
+    counts = jnp.zeros((E, 1), jnp.float32)
+    gate_sum = jnp.zeros((1, T), jnp.float32)
     picked = gates
-    idxs, poss, keeps, gatews = [], [], [], []
+    slots, keeps, gatews = [], [], []
     for k in range(top_k):
-        idx_k = jnp.argmax(picked, axis=1).astype(jnp.int32)     # [T]
-        mask_k = jax.nn.one_hot(idx_k, E, dtype=jnp.int32)
+        top = jnp.max(picked, axis=0, keepdims=True)
+        idx_k = jnp.min(jnp.where(picked == top, eidx, E), axis=0,
+                        keepdims=True)                   # [1, T]
+        hit = eidx == idx_k
+        mask_k = hit.astype(jnp.float32)                 # [E, T] one-hot
         if k == 0:
-            me_ref[...] = jnp.mean(gates, axis=0)
-            ce_ref[...] = jnp.mean(mask_k.astype(jnp.float32), axis=0)
-        pos_in_expert = jnp.cumsum(mask_k, axis=0) - mask_k
-        pos_k = (jnp.sum(pos_in_expert * mask_k, axis=1)
-                 + jnp.sum(mask_k * counts[None, :], axis=1))
+            me_ref[...] = jnp.sum(gates, axis=1, keepdims=True) / T
+            ce_ref[...] = jnp.sum(mask_k, axis=1, keepdims=True) / T
+        # rank of each token among the tokens routed to the same expert
+        ranks, seen = [], counts
+        for b in range(T // tb):
+            m = mask_k[:, b * tb:(b + 1) * tb]
+            incl = jnp.dot(m.astype(jnp.bfloat16), tri,
+                           preferred_element_type=jnp.float32)
+            ranks.append(incl - m + seen)
+            seen = seen + incl[:, tb - 1:tb]
+        rank = ranks[0] if len(ranks) == 1 else jnp.concatenate(ranks, 1)
+        pos_k = jnp.sum(rank * mask_k, axis=0, keepdims=True
+                        ).astype(jnp.int32)              # [1, T]
         keep = pos_k < cap
-        gate_k = jnp.sum(gates * mask_k.astype(jnp.float32), axis=1) * keep
-        idxs.append(idx_k)
-        poss.append(jnp.minimum(pos_k, cap - 1).astype(jnp.int32))
+        gate_k = jnp.sum(gates * mask_k, axis=0, keepdims=True) * keep
+        slots.append(jnp.where(keep, idx_k * cap + pos_k, S))
         keeps.append(keep)
         gatews.append(gate_k)
-        counts = counts + jnp.sum(mask_k * keep[:, None].astype(jnp.int32),
-                                  axis=0)
+        counts = counts + jnp.sum(mask_k * keep, axis=1, keepdims=True)
         gate_sum = gate_sum + gate_k
-        picked = jnp.where(mask_k > 0, -jnp.inf, picked)
+        picked = jnp.where(hit, -jnp.inf, picked)
 
     denom = jnp.maximum(gate_sum, 1e-9)
     for k in range(top_k):
-        w_k = gatews[k] / denom                                   # [T]
-        slot_k = jnp.where(keeps[k], idxs[k] * cap + poss[k], S)
-        slot_tk_ref[:, k] = jnp.where(keeps[k], slot_k, 0).astype(jnp.int32)
-        w_tk_ref[:, k] = w_k * keeps[k]
-
-        def body(t, _):
-            slot = slot_k[t]
-
-            @pl.when(slot < S)
-            def _():
-                src_ref[slot] = t + 1
-                slw_ref[slot] = w_k[t]
-            return 0
-
-        jax.lax.fori_loop(0, T, body, 0)
+        slot_ref[k:k + 1, :] = slots[k].astype(jnp.int32)
+        w_ref[k:k + 1, :] = gatews[k] / denom * keeps[k]
 
 
 def moe_route(logits: jax.Array, *, top_k: int, capacity: int,
@@ -259,49 +277,73 @@ def moe_route(logits: jax.Array, *, top_k: int, capacity: int,
         interpret = moe_kernel_interpret()
     T, E = logits.shape
     S = E * capacity
-    full2 = pl.BlockSpec((T, E), lambda i: (0, 0))
-    vec = lambda n: pl.BlockSpec((n,), lambda i: (0,))
-    tk = pl.BlockSpec((T, top_k), lambda i: (0, 0))
-    return pl.pallas_call(
-        functools.partial(_route_kernel, top_k=top_k, cap=capacity),
+    tb = _route_block(T)
+    if tb is None:
+        raise ValueError(f"moe_route: {T} tokens have no lane-aligned "
+                         f"block split (moe_kernel_supported refuses it)")
+    full = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+    slot_kt, w_kt, me, ce = pl.pallas_call(
+        functools.partial(_route_kernel, top_k=top_k, cap=capacity, tb=tb),
         grid=(1,),
-        in_specs=[full2],
-        out_specs=[vec(S), vec(S), tk, tk, vec(E), vec(E)],
-        out_shape=[jax.ShapeDtypeStruct((S,), jnp.int32),
-                   jax.ShapeDtypeStruct((S,), jnp.float32),
-                   jax.ShapeDtypeStruct((T, top_k), jnp.int32),
-                   jax.ShapeDtypeStruct((T, top_k), jnp.float32),
-                   jax.ShapeDtypeStruct((E,), jnp.float32),
-                   jax.ShapeDtypeStruct((E,), jnp.float32)],
+        in_specs=[full(E, T)],
+        out_specs=[full(top_k, T), full(top_k, T), full(E, 1), full(E, 1)],
+        out_shape=[jax.ShapeDtypeStruct((top_k, T), jnp.int32),
+                   jax.ShapeDtypeStruct((top_k, T), jnp.float32),
+                   jax.ShapeDtypeStruct((E, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((E, 1), jnp.float32)],
         interpret=interpret,
-    )(logits)
+    )(logits.T)
+    slot, w_tk = slot_kt.T, w_kt.T                       # [T, K]
+    # inverse slot->token map: kept slots are unique, dropped choices
+    # land on the overflow row S and are sliced off
+    flat = slot.reshape(-1)
+    token1 = jnp.repeat(jnp.arange(T, dtype=jnp.int32), top_k) + 1
+    src = jnp.zeros((S + 1,), jnp.int32).at[flat].set(token1)[:S]
+    slot_w = jnp.zeros((S + 1,), jnp.float32).at[flat].set(
+        w_tk.reshape(-1))[:S]
+    return (src, slot_w, jnp.where(slot < S, slot, 0), w_tk,
+            me[:, 0], ce[:, 0])
 
 
 # ---------------------------------------------------------------------------
 # 2. dispatch gather + wire cast (payload emerges launch-ready)
 # ---------------------------------------------------------------------------
 
+# Row-granular blocks: Mosaic wants a block's last two dims tile-aligned
+# or equal to the array's, so every per-row operand below is viewed as
+# [rows, 1, H] and moves as a (1, 1, H) block.
+
 def _gather_kernel(src_ref, tok_ref, out_ref, *, mask_pad: bool):
     i = pl.program_id(0)
-    row = tok_ref[0, :].astype(jnp.float32)
+    row = tok_ref[0].astype(jnp.float32)                # [1, H]
     if mask_pad:
         row = jnp.where(src_ref[i] > 0, row, 0.0)
-    out_ref[0, :] = row.astype(out_ref.dtype)
+    out_ref[0] = row.astype(out_ref.dtype)
 
 
 def _gather_int8_kernel(src_ref, tok_ref, q_ref, s_ref, *, mask_pad: bool):
     i = pl.program_id(0)
-    row = tok_ref[0, :].astype(jnp.float32)
+    row = tok_ref[0].astype(jnp.float32)                # [1, H]
     if mask_pad:
         row = jnp.where(src_ref[i] > 0, row, 0.0)
     # quantize_rows_int8 / quantize_blockwise symmetric int8 math,
     # byte-for-byte (absmax/127, zero-scale -> 1, round-half-even, clip)
-    absmax = jnp.max(jnp.abs(row))
+    absmax = jnp.max(jnp.abs(row), axis=1, keepdims=True)
     scale = absmax / 127.0
     scale = jnp.where(scale == 0, 1.0, scale)
-    q_ref[0, :] = jnp.clip(jnp.round(row / scale), -128, 127
-                           ).astype(jnp.int8)
+    q_ref[0] = jnp.clip(jnp.round(row / scale), -128, 127).astype(jnp.int8)
     s_ref[0] = scale
+
+
+def _gather_grid_spec(S: int, H: int, out_specs):
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(S,),
+        in_specs=[pl.BlockSpec(
+            (1, 1, H), lambda i, src: (jnp.maximum(src[i] - 1, 0), 0, 0))],
+        out_specs=out_specs,
+    )
 
 
 def moe_dispatch_gather(tokens: jax.Array, src: jax.Array, *,
@@ -312,27 +354,19 @@ def moe_dispatch_gather(tokens: jax.Array, src: jax.Array, *,
     ``src``-lookup IS the index map) and stores it at wire width —
     payload ``[S, H]`` in ``wire_dtype`` (default: the compute dtype),
     byte-identical to ``tokens[max(src-1, 0)].astype(wire_dtype)``."""
-    from jax.experimental.pallas import tpu as pltpu
     if interpret is None:
         interpret = moe_kernel_interpret()
     S = src.shape[0]
     T, H = tokens.shape
     out_dtype = jnp.dtype(wire_dtype) if wire_dtype is not None \
         else tokens.dtype
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(S,),
-        in_specs=[pl.BlockSpec((1, H),
-                               lambda i, src: (jnp.maximum(src[i] - 1, 0),
-                                               0))],
-        out_specs=pl.BlockSpec((1, H), lambda i, src: (i, 0)),
-    )
     return pl.pallas_call(
         functools.partial(_gather_kernel, mask_pad=mask_pad),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H), out_dtype),
+        grid_spec=_gather_grid_spec(
+            S, H, pl.BlockSpec((1, 1, H), lambda i, src: (i, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((S, 1, H), out_dtype),
         interpret=interpret,
-    )(src.astype(jnp.int32), tokens)
+    )(src.astype(jnp.int32), tokens.reshape(T, 1, H)).reshape(S, H)
 
 
 def moe_dispatch_gather_int8(tokens: jax.Array, src: jax.Array, *,
@@ -342,27 +376,20 @@ def moe_dispatch_gather_int8(tokens: jax.Array, src: jax.Array, *,
     launch -> ``(q [S, H] int8, scale [S] f32)``, byte-identical to
     ``quantize_rows_int8(tokens[max(src-1, 0)])`` inside jitted programs
     (the ``pallas_quant`` contract, extended to dispatch traffic)."""
-    from jax.experimental.pallas import tpu as pltpu
     if interpret is None:
         interpret = moe_kernel_interpret()
     S = src.shape[0]
     T, H = tokens.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(S,),
-        in_specs=[pl.BlockSpec((1, H),
-                               lambda i, src: (jnp.maximum(src[i] - 1, 0),
-                                               0))],
-        out_specs=[pl.BlockSpec((1, H), lambda i, src: (i, 0)),
-                   pl.BlockSpec((1,), lambda i, src: (i,))],
-    )
-    return pl.pallas_call(
+    q, scale = pl.pallas_call(
         functools.partial(_gather_int8_kernel, mask_pad=mask_pad),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((S, H), jnp.int8),
-                   jax.ShapeDtypeStruct((S,), jnp.float32)],
+        grid_spec=_gather_grid_spec(
+            S, H, [pl.BlockSpec((1, 1, H), lambda i, src: (i, 0, 0)),
+                   pl.BlockSpec((1, 1, 1), lambda i, src: (i, 0, 0))]),
+        out_shape=[jax.ShapeDtypeStruct((S, 1, H), jnp.int8),
+                   jax.ShapeDtypeStruct((S, 1, 1), jnp.float32)],
         interpret=interpret,
-    )(src.astype(jnp.int32), tokens)
+    )(src.astype(jnp.int32), tokens.reshape(T, 1, H))
+    return q.reshape(S, H), scale.reshape(S)
 
 
 # ---------------------------------------------------------------------------
@@ -389,13 +416,14 @@ def _ffn_block(x, wg_ref, wu_ref, wo_ref, activation):
                                preferred_element_type=jnp.float32)
 
 
-def _ffn_combine_kernel(x_ref, wg_ref, wu_ref, wo_ref, src_ref, slw_ref,
+def _ffn_combine_kernel(src_ref, slw_ref, x_ref, wg_ref, wu_ref, wo_ref,
                         out_ref, y_acc, *, activation: str, cap: int,
                         cap_block: int):
     """Grid (E, C/Cb, F/Fb), f innermost. The last f step of each
     capacity block runs the fused combine epilogue: every filled slot
     row scatter-accumulates ``slot_w * y`` into its token's output row —
-    ``expert_out`` never exists in HBM."""
+    ``expert_out`` never exists in HBM. ``src``/``slot_w`` ride scalar
+    prefetch: the epilogue reads them one scalar at a time."""
     e, c, f = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nf = pl.num_programs(2)
 
@@ -423,9 +451,9 @@ def _ffn_combine_kernel(x_ref, wg_ref, wu_ref, wo_ref, src_ref, slw_ref,
 
             @pl.when(src_ref[slot] > 0)
             def _():
-                t = src_ref[slot] - 1
+                t = pl.ds(src_ref[slot] - 1, 1)
                 out_ref[t, :] = (out_ref[t, :]
-                                 + slw_ref[slot] * y_acc[r, :])
+                                 + slw_ref[slot] * y_acc[pl.ds(r, 1), :])
             return 0
 
         jax.lax.fori_loop(0, cap_block, body, 0)
@@ -452,21 +480,22 @@ def _ffn_kernel(x_ref, wg_ref, wu_ref, wo_ref, y_ref, y_acc, *,
         y_ref[0] = y_acc[...]
 
 
-def _combine_kernel(slots_ref, w_tk_ref, y_ref, out_ref):
+def _combine_kernel(slots_ref, w_ref, y_ref, out_ref):
     """Split combine: grid (T, K), k innermost — token t's output block
     is revisited K times, accumulating its picked rows online."""
     t, k = pl.program_id(0), pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
-        out_ref[0, :] = jnp.zeros_like(out_ref[0, :])
-    out_ref[0, :] = out_ref[0, :] + w_tk_ref[0, k] * y_ref[0, :]
+        out_ref[...] = jnp.zeros_like(out_ref)
+    out_ref[0] = out_ref[0] + w_ref[t * pl.num_programs(1) + k] * y_ref[0]
 
 
 def _ffn_specs(E, C, H, F, cap_block, ffn_block):
-    xspec = pl.BlockSpec((1, cap_block, H), lambda e, c, f: (e, c, 0))
-    wspec = pl.BlockSpec((1, H, ffn_block), lambda e, c, f: (e, 0, f))
-    wospec = pl.BlockSpec((1, ffn_block, H), lambda e, c, f: (e, f, 0))
+    """(payload, wi, wo) block specs; ``*_`` absorbs scalar-prefetch refs."""
+    xspec = pl.BlockSpec((1, cap_block, H), lambda e, c, f, *_: (e, c, 0))
+    wspec = pl.BlockSpec((1, H, ffn_block), lambda e, c, f, *_: (e, 0, f))
+    wospec = pl.BlockSpec((1, ffn_block, H), lambda e, c, f, *_: (e, f, 0))
     return xspec, wspec, wospec
 
 
@@ -490,20 +519,22 @@ def moe_ffn_combine(payload: jax.Array, wi_gate: jax.Array,
     xspec, wspec, wospec = _ffn_specs(E, C, H, F, cap_block, ffn_block)
     S = src.shape[0]
     assert S == E * C, (S, E, C)
-    vec_i = pl.BlockSpec((S,), lambda e, c, f: (0,))
-    out_spec = pl.BlockSpec((n_tokens, H), lambda e, c, f: (0, 0))
     from jax.experimental.pallas import tpu as pltpu
     wu = wi_up if gated else wi_gate
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(E, C // cap_block, F // ffn_block),
+        in_specs=[xspec, wspec, wspec, wospec],
+        out_specs=pl.BlockSpec((n_tokens, H), lambda e, c, f, *_: (0, 0)),
+        scratch_shapes=[pltpu.VMEM((cap_block, H), jnp.float32)],
+    )
     return pl.pallas_call(
         functools.partial(_ffn_combine_kernel, activation=activation,
                           cap=C, cap_block=cap_block),
-        grid=(E, C // cap_block, F // ffn_block),
-        in_specs=[xspec, wspec, wspec, wospec, vec_i, vec_i],
-        out_specs=out_spec,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tokens, H), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((cap_block, H), jnp.float32)],
         interpret=interpret,
-    )(payload, wi_gate, wu, wo, src.astype(jnp.int32), slot_w)
+    )(src.astype(jnp.int32), slot_w, payload, wi_gate, wu, wo)
 
 
 def moe_ffn(payload: jax.Array, wi_gate: jax.Array,
@@ -544,18 +575,19 @@ def moe_combine(y: jax.Array, slot_tk: jax.Array, w_tk: jax.Array, *,
     S, H = y.shape
     T, K = slot_tk.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(T, K),
-        in_specs=[pl.BlockSpec((1, K), lambda t, k, st: (t, 0)),
-                  pl.BlockSpec((1, H), lambda t, k, st: (st[t * K + k], 0))],
-        out_specs=pl.BlockSpec((1, H), lambda t, k, st: (t, 0)),
+        in_specs=[pl.BlockSpec((1, 1, H),
+                               lambda t, k, st, w: (st[t * K + k], 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, H), lambda t, k, st, w: (t, 0, 0)),
     )
     return pl.pallas_call(
         _combine_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, H), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((T, 1, H), jnp.float32),
         interpret=interpret,
-    )(slot_tk.astype(jnp.int32).reshape(-1), w_tk, y)
+    )(slot_tk.astype(jnp.int32).reshape(-1), w_tk.reshape(-1),
+      y.reshape(S, 1, H)).reshape(T, H)
 
 
 # ---------------------------------------------------------------------------

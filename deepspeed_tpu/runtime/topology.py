@@ -118,6 +118,9 @@ class MeshTopology:
     @staticmethod
     def _device_grid(devices: Sequence[jax.Device], shape: Tuple[int, ...]) -> np.ndarray:
         if len(devices) > 1 and devices[0].platform == "tpu":
+            # a TPU mesh follows the physical torus; a device set that
+            # mesh_utils cannot lay out is an error, never a silent
+            # enumeration-order mesh with ICI-hostile neighbours
             from jax.experimental import mesh_utils
             n_slices = len({getattr(d, "slice_index", 0) for d in devices})
             if n_slices > 1:
@@ -125,16 +128,10 @@ class MeshTopology:
                 # model/seq/expert stay inside each slice's ICI torus
                 dcn = MeshTopology._hybrid_dcn_shape(shape, n_slices)
                 if dcn is not None:
-                    try:
-                        ici = tuple(s // d for s, d in zip(shape, dcn))
-                        return mesh_utils.create_hybrid_device_mesh(
-                            ici, dcn, devices=devices)
-                    except Exception:
-                        pass  # fall through to the single-torus layout
-            try:
-                return mesh_utils.create_device_mesh(shape, devices=devices)
-            except Exception:
-                pass
+                    ici = tuple(s // d for s, d in zip(shape, dcn))
+                    return mesh_utils.create_hybrid_device_mesh(
+                        ici, dcn, devices=devices)
+            return mesh_utils.create_device_mesh(shape, devices=devices)
         return np.asarray(devices).reshape(shape)
 
     # -- mesh access ---------------------------------------------------------

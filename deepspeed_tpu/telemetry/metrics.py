@@ -30,38 +30,44 @@ import os
 from collections import deque
 from typing import Dict, List, Optional
 
-# Peak dense bf16/fp16 FLOPs per chip (marketing peaks; MFU is a ratio
-# against the roofline, so the convention just has to be stated). Keyed by
-# substrings of ``jax.devices()[0].device_kind`` lowercased.
-PEAK_FLOPS_BY_KIND = (
-    ("v6e", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 197e12),         # also matches "tpu v5 lite"
-    ("v5 lite", 197e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-    ("cpu", 1e12),           # nominal: keeps MFU finite on host-mesh runs
-)
+# Peak dense bf16 FLOP/s per chip, keyed by ``device_kind`` exactly as JAX
+# reports it — the ONE peaks table (bench.py and the tools read it too).
+# Source: Google Cloud TPU documentation, the system-architecture page of
+# each generation ("TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
+PEAK_FLOPS_BY_KIND = {
+    "TPU v2": 46e12,
+    "TPU v3": 123e12,
+    "TPU v4": 275e12,
+    "TPU v4 lite": 138e12,
+    "TPU v5": 459e12,        # v5p
+    "TPU v5p": 459e12,
+    "TPU v5 lite": 197e12,   # v5e
+    "TPU v5e": 197e12,
+    "TPU v6 lite": 918e12,   # v6e / Trillium
+    "TPU v6e": 918e12,
+}
+#: nominal, so MFU stays finite on the CPU test meshes; never a device metric
+CPU_NOMINAL_PEAK_FLOPS = 1e12
 
 
 def peak_flops_per_device(device_kind: Optional[str] = None) -> float:
     """Per-device peak from the table; ``DSTPU_PEAK_FLOPS`` (per-device,
-    in FLOPs) overrides for platforms the table mislabels."""
+    in FLOPs) overrides. A device that is neither a CPU nor in the table
+    is an error, not a default."""
     env = os.environ.get("DSTPU_PEAK_FLOPS")
     if env:
         return float(env)
     if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        except Exception:  # pragma: no cover - no backend
-            return 1e12
-    kind = (device_kind or "").lower()
-    for key, peak in PEAK_FLOPS_BY_KIND:
-        if key in kind:
-            return peak
-    return 1e12
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    if device_kind.lower() == "cpu":
+        return CPU_NOMINAL_PEAK_FLOPS
+    if device_kind not in PEAK_FLOPS_BY_KIND:
+        raise ValueError(
+            f"no peak FLOP/s known for device_kind {device_kind!r}: add it "
+            f"to telemetry.metrics.PEAK_FLOPS_BY_KIND with its source, or "
+            f"set DSTPU_PEAK_FLOPS")
+    return PEAK_FLOPS_BY_KIND[device_kind]
 
 
 def percentile(sorted_vals: List[float], p: float) -> float:

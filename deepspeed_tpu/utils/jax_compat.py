@@ -1,76 +1,26 @@
-"""JAX cross-version compatibility.
-
-``shard_map`` moved from ``jax.experimental.shard_map`` to top-level
-``jax.shard_map`` (and its replication-check kwarg was renamed
-``check_rep`` -> ``check_vma``) across JAX releases, and
-``jax.lax.axis_size`` only exists on newer releases. Every in-repo user
-imports these from here so a single site owns the version split.
-"""
+"""The few JAX spellings every in-repo user shares, as the installed JAX
+(0.9.0) has them: top-level ``jax.shard_map`` with ``check_vma``, and
+``jax.lax.axis_size``."""
 
 from __future__ import annotations
 
-try:  # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map
-    _TOP_LEVEL = True
-except ImportError:  # older jax: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _TOP_LEVEL = False
-
-
-def _detect_check_kw() -> str:
-    # The kwarg rename (check_rep -> check_vma) did not land in the same
-    # release as the top-level export, so ask the signature, not the import
-    # location.
-    import inspect
-
-    try:
-        params = inspect.signature(_shard_map).parameters
-    except (ValueError, TypeError):
-        return "check_vma" if _TOP_LEVEL else "check_rep"
-    if "check_vma" in params:
-        return "check_vma"
-    if "check_rep" in params:
-        return "check_rep"
-    return "check_vma" if _TOP_LEVEL else "check_rep"
-
-
-_CHECK_KW = _detect_check_kw()
+import jax
+from jax import shard_map  # noqa: F401  (re-exported)
 
 
 def axis_size(axis_name) -> int:
-    """Size of a bound mesh axis (product over a tuple of axes).
-
-    ``jax.lax.axis_size`` is missing on older JAX; ``psum(1, axis)`` is
-    evaluated statically at trace time on every version, so no collective
-    ever reaches the graph."""
-    import jax
-
-    native = getattr(jax.lax, "axis_size", None)
-    if native is not None:
-        if isinstance(axis_name, (tuple, list)):
-            size = 1
-            for a in axis_name:
-                size *= int(native(a))
-            return size
-        return int(native(axis_name))
-    return int(jax.lax.psum(1, tuple(axis_name)
-                            if isinstance(axis_name, list) else axis_name))
+    """Size of a bound mesh axis (product over a tuple of axes). Static at
+    trace time: no collective reaches the graph."""
+    if isinstance(axis_name, list):
+        axis_name = tuple(axis_name)
+    return int(jax.lax.axis_size(axis_name))
 
 
 def in_manual_axes() -> bool:
     """True while tracing inside a shard_map/pmap body (mesh axes bound as
     manual). Sharding constraints are illegal there — XLA already sees the
     per-device view."""
-    import jax
-
-    probe = getattr(jax.core, "nonempty_axis_env_DO_NOT_USE", None)
-    if probe is not None:
-        return bool(probe())
-    try:  # newer jax: the axis env hangs off the tracing context
-        from jax._src.core import get_axis_env
-        return bool(get_axis_env().axis_sizes)
-    except Exception:
-        return False
+    return bool(jax.core.nonempty_axis_env_DO_NOT_USE())
 
 
 def with_sharding_constraint(x, spec):
@@ -78,22 +28,9 @@ def with_sharding_constraint(x, spec):
     the constraint cannot apply: inside shard_map/pmap bodies (manual axes —
     the primitive binds at trace time but fails at lowering, so a call-site
     try/except cannot catch it) and outside any mesh context."""
-    import jax
-
     if in_manual_axes():
         return x
     try:
         return jax.lax.with_sharding_constraint(x, spec)
     except (ValueError, TypeError, RuntimeError):  # no mesh context
         return x
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, check_rep=None,
-              **kwargs):
-    """Version-stable ``shard_map``. Accepts either spelling of the
-    replication-check flag and forwards whichever the installed JAX takes."""
-    check = check_vma if check_vma is not None else check_rep
-    if check is not None:
-        kwargs[_CHECK_KW] = check
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)

@@ -1,0 +1,303 @@
+"""Chip rehearsals kept as tests (ISSUE 21; on-chip-measurement guide §2).
+
+1. Every Pallas kernel that is on a default path when ``default_backend()
+   == "tpu"`` is compiled by the chip's own compiler for a DESCRIBED
+   ``v5e:2x2`` (no chip attached), at the widths ``chip_smoke.py`` runs —
+   interpret mode accepts programs Mosaic refuses (rank-1 blocks off the
+   128-lane tiling, ``cumsum``, scalar stores into VMEM), and this is where
+   such a refusal shows without chip time. Skipped with the reason where the
+   topology cannot be described. A compile that passes is not a chip run.
+2. ``chip_smoke``'s phase functions are driven at tiny size on the CPU
+   mesh (the steering lives here, not in an option of the script).
+3. ``python chip_smoke.py`` without a TPU exits non-zero and prints no
+   result line.
+
+tests/conftest.py turns the persistent compile cache off for the session: a
+program compiled for a described device is written to the cache but cannot
+be read back without a chip.
+"""
+
+import functools
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+sys.path.insert(0, ROOT)
+import chip_smoke  # noqa: E402
+
+REAL = chip_smoke.Sizes()
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """``sds(shape, dtype)`` placing an abstract array on one described
+    v5e chip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / no compile-only client here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: "
+                    f"{type(e).__name__}: {str(e)[:200]}")
+    one = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one)
+
+
+def compile_for_chip(fn, *args):
+    """Raises what the chip's compiler would raise."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+
+
+class TestCompilesForV5e:
+
+    @pytest.mark.parametrize("shape", [
+        REAL.flash,                 # TinyLlama GQA heads (bench long-seq)
+        (1, 4096, 32, 32, 128),     # llama2-7b MHA heads
+    ])
+    def test_flash_fwd_bwd(self, chip, shape):
+        from deepspeed_tpu.ops.transformer.pallas_flash import \
+            flash_attention_kernel
+        B, S, H, kvH, D = shape
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention_kernel(
+                q, k, v, causal=True, interpret=False).astype(F32))
+
+        compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                         chip((B, S, H, D), BF16), chip((B, S, kvH, D), BF16),
+                         chip((B, S, kvH, D), BF16))
+
+    @pytest.mark.parametrize("mode,moments,n", [
+        ("adamw", "fp32", REAL.bucket_elems),
+        ("adamw", "bf16-sr", REAL.bucket_elems),
+        ("adam", "bf16-sr", REAL.bucket_elems),      # coupled weight decay
+        ("lamb", "bf16-sr", REAL.bucket_elems),      # trust-ratio epilogue
+        ("adamw", "bf16-sr", 50257 * 5),             # padded odd bucket
+    ])
+    def test_adam_bucket(self, chip, mode, moments, n):
+        from deepspeed_tpu.ops.adam.pallas_adam import adam_bucket_update
+        md, gd, pd = ((F32, F32, None) if moments == "fp32"
+                      else (BF16, BF16, BF16))
+
+        def step(g, p, m, v, t, lr, sm, sv):
+            return adam_bucket_update(
+                g, p, m, v, step=t, lr=lr, weight_decay=0.01, mode=mode,
+                seed_m=sm, seed_v=sv, m_dtype=md, v_dtype=md,
+                param_dtype=pd, interpret=False)
+
+        compile_for_chip(step, chip((n,), gd), chip((n,), F32),
+                         chip((n,), md), chip((n,), md), chip((), I32),
+                         chip((), F32), chip((), jnp.uint32),
+                         chip((), jnp.uint32))
+
+    @pytest.mark.parametrize("md", [F32, BF16])
+    def test_lion_bucket(self, chip, md):
+        from deepspeed_tpu.ops.lion.pallas_lion import lion_bucket_update
+        n = REAL.bucket_elems
+        pd = None if md == F32 else BF16
+
+        def step(g, p, m, lr, sm):
+            return lion_bucket_update(
+                g, p, m, lr=lr, weight_decay=0.01, seed_m=sm, m_dtype=md,
+                param_dtype=pd, interpret=False)
+
+        compile_for_chip(step, chip((n,), md), chip((n,), F32),
+                         chip((n,), md), chip((), F32),
+                         chip((), jnp.uint32))
+
+    @pytest.mark.parametrize("rows,group", [
+        REAL.quant_rows, (100, 256), (7, 128), (4096, 2048)])
+    def test_quantize_rows_int8(self, chip, rows, group):
+        # seed: "rank 1 block shapes ... multiple of the tiling size (128)"
+        from deepspeed_tpu.ops.quantizer.pallas_quant import \
+            quantize_rows_int8
+        compile_for_chip(
+            functools.partial(quantize_rows_int8, interpret=False),
+            chip((rows, group), F32))
+
+    @pytest.mark.parametrize("tokens,top_k", [
+        (REAL.moe[0], 2),   # 16 rank-scan lane blocks of 512
+        (REAL.moe[0], 1),
+        (640, 2),           # 128-wide blocks (no wider divisor)
+        (24, 2),            # one ragged block
+    ])
+    def test_moe_route(self, chip, tokens, top_k):
+        # seed: "Unimplemented primitive in Pallas TPU lowering: cumsum"
+        from deepspeed_tpu.moe.sharded_moe import capacity
+        from deepspeed_tpu.ops.transformer import pallas_moe
+        E = REAL.moe[3]
+        compile_for_chip(
+            functools.partial(pallas_moe.moe_route, top_k=top_k,
+                              capacity=capacity(tokens, E, 1.25, 4),
+                              interpret=False),
+            chip((tokens, E), F32))
+
+    @pytest.mark.parametrize("tokens,n_chunks", [
+        (REAL.moe[0], 1),   # bench dims: split FFN + token-major combine
+        (REAL.moe_small_tokens, 1),   # fused combine-scatter epilogue
+        (REAL.moe_small_tokens, 2),   # ... under the chunked scan carry
+        (24, 1),            # a serving wave: not a multiple of any tile
+    ])
+    def test_moe_forward(self, chip, tokens, n_chunks):
+        from deepspeed_tpu.moe.sharded_moe import capacity
+        from deepspeed_tpu.ops.transformer import pallas_moe
+        _, H, F, E = REAL.moe
+        assert pallas_moe.moe_kernel_supported(
+            top_k=2, activation="silu_gated", dtype=BF16, tokens=tokens,
+            num_experts=E, hidden=H)
+        fwd = pallas_moe.make_moe_forward(
+            top_k=2, capacity=capacity(tokens, E, 1.25, 4),
+            activation="silu_gated", mask_pad=False, n_chunks=n_chunks,
+            interpret=False)
+        params = {"gate": chip((H, E), BF16), "wo": chip((E, F, H), BF16),
+                  "wi_gate": chip((E, H, F), BF16),
+                  "wi_up": chip((E, H, F), BF16)}
+        compile_for_chip(fwd, params, chip((tokens, H), BF16))
+
+    @pytest.mark.parametrize("wire", ["bf16", "bf16-masked", "int8"])
+    def test_moe_dispatch_gather(self, chip, wire):
+        # seed: "last two dimensions of your block shape are divisible by
+        # 8 and 128 ... Block shape (1, 1024), array shape (8192, 1024)"
+        from deepspeed_tpu.ops.transformer import pallas_moe
+        T, H, _, _ = REAL.moe
+        fn = (functools.partial(pallas_moe.moe_dispatch_gather_int8,
+                                interpret=False) if wire == "int8" else
+              functools.partial(pallas_moe.moe_dispatch_gather,
+                                wire_dtype=BF16, interpret=False,
+                                mask_pad=wire == "bf16-masked"))
+        compile_for_chip(fn, chip((T, H), F32), chip((2 * T,), I32))
+
+    def test_moe_combine(self, chip):
+        # seed: block shape (1, 2) on (8192, 2) + a dynamic lane read
+        from deepspeed_tpu.ops.transformer import pallas_moe
+        T, H, _, E = REAL.moe
+        compile_for_chip(
+            functools.partial(pallas_moe.moe_combine, interpret=False),
+            chip((E * 1280, H), F32), chip((T, 2), I32), chip((T, 2), F32))
+
+    @pytest.mark.parametrize("heads", [
+        REAL.wave_heads, (20, 20, 64), (32, 8, 128), (32, 4, 64)])
+    def test_ragged_wave(self, chip, heads):
+        from deepspeed_tpu.inference.v2.kernels.ragged_paged_attention \
+            import ragged_paged_attention
+        H, kvH, D = heads
+        N, A, MP, P, ps = 512, 64, 64, 1024, REAL.wave_page
+        compile_for_chip(
+            functools.partial(ragged_paged_attention, block_q=8,
+                              use_pallas=True, interpret=False),
+            chip((N, H, D), BF16), chip((kvH, P, ps, D), BF16),
+            chip((kvH, P, ps, D), BF16), chip((A,), I32),
+            chip((A, MP), I32), chip((A + 1,), I32))
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke's phases, tiny, on the CPU mesh
+# ---------------------------------------------------------------------------
+
+TINY = chip_smoke.Sizes(
+    dtype="float32", preset="gpt2-tiny",
+    model_overrides=(("vocab_size", 256), ("max_seq_len", 64)),
+    micro=1, seq=32, train_steps=3,
+    flash=(1, 128, 4, 2, 16), bucket_elems=2048, quant_rows=(64, 128),
+    moe=(32, 16, 32, 4), moe_small_tokens=8, wave_heads=(4, 2, 16), wave_page=4,
+    wave_seqs=((1, 9), (1, 17), (11, 5), (6, 0)),
+    moe_steps=2, n_requests=3, prompt_range=(5, 20), max_new=4,
+    token_budget=64, stagger_s=0.001, multichip_steps=2)
+
+
+@pytest.fixture
+def stats():
+    return chip_smoke.CompileStats()
+
+
+class TestSmokePhasesOnCpu:
+
+    def test_device(self, eight_devices):
+        line = chip_smoke.phase_device(len(jax.devices()))
+        assert line["platform"] == "cpu" and line["accelerator"] == "cpu"
+        assert set(line["native_ops"].values()) <= {"built", "fallback"}
+        with pytest.raises(AssertionError, match="asked for 3"):
+            chip_smoke.phase_device(3)
+
+    def test_train(self, stats):
+        line = chip_smoke.phase_train(TINY, 0, stats)
+        assert line["losses"][-1] < line["losses"][0]
+        assert len(line["losses"]) == TINY.train_steps
+
+    def test_kernels(self, stats):
+        line = chip_smoke.phase_kernels(TINY, 0, stats)
+        assert [c["kernel"].split()[0] for c in line["checks"]] == [
+            "flash", "adam/lion", "quantize_rows_int8", "moe", "moe",
+            "ragged"]
+        assert all(c["interpret"] for c in line["checks"])  # CPU backend
+
+    def test_train_moe(self, stats):
+        from deepspeed_tpu.models import mixtral_model
+        model = mixtral_model("mixtral-tiny", dtype=BF16, remat=False,
+                              max_seq_len=64, vocab_size=512)
+        line = chip_smoke.phase_train_moe(TINY, 0, stats, model=model,
+                                          micro=1, seq=32)
+        assert line["moe_kernel_resolution"].startswith("xla")  # CPU mesh
+        assert len(line["losses"]) == TINY.moe_steps
+
+    def test_serve(self, stats):
+        line = chip_smoke.phase_serve(TINY, 0, stats)
+        assert line["new_tokens"] == [TINY.max_new] * TINY.n_requests
+        assert line["prefill_logits_rel_err"] <= line["tolerance"]
+
+    def test_multichip(self, eight_devices, stats):
+        line = chip_smoke.phase_multichip(TINY, 0, stats)
+        assert line["dp"] == len(jax.devices()) and line["overlap_active"]
+        assert line["losses"] == pytest.approx(line["one_device_losses"],
+                                               rel=0.05, abs=0.05)
+
+
+def test_smoke_moe_dims_are_the_bench_model():
+    # the kernels phase claims "bench dims": keep it true
+    import bench
+    c = bench._moe_bench_model().config
+    micro = bench._moe_bench_cfg()["train_micro_batch_size_per_gpu"]
+    assert REAL.moe == (micro * c.max_seq_len, c.hidden_size, c.ffn_size,
+                        c.moe.num_experts)
+    assert c.moe.top_k == 2
+
+
+def test_smoke_train_shape_is_gpt2_large_as_published():
+    c = chip_smoke.train_model(REAL).config
+    assert (c.num_layers, c.hidden_size, c.num_heads, c.vocab_size,
+            c.max_seq_len) == (36, 1280, 20, 50257, 1024)
+    assert c.remat and REAL.seq == c.max_seq_len
+
+
+def test_smoke_helpers():
+    import numpy as np
+    toks = chip_smoke.zipf_tokens(np.random.default_rng(0), 50, (4, 64))
+    assert toks.min() >= 0 and toks.max() < 50 and toks.dtype == np.int32
+    assert chip_smoke.max_err([1.0, 2.0], [1.0, 2.5], dict(
+        rtol=0, atol=1.0)) == 0.5
+    with pytest.raises(AssertionError):
+        chip_smoke.max_err([1.0], [2.0], dict(rtol=0, atol=0.5))
+    with pytest.raises(AssertionError, match="non-finite"):
+        chip_smoke.assert_finite([1.0, float("nan")], "x")
+
+
+def test_smoke_without_a_tpu_fails():
+    """No accelerator: non-zero exit before any phase, no result line."""
+    r = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=300,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode != 0
+    assert '"ok"' not in r.stdout and '"phase"' not in r.stdout
+    assert "needs a TPU" in r.stderr
+

@@ -73,6 +73,15 @@ class TestRoute:
         np.testing.assert_allclose(float(jnp.sum(me * ce) * E), float(aux),
                                    rtol=1e-6)
 
+    @pytest.mark.parametrize("tokens,block", [
+        (24, 24), (512, 512), (640, 128), (768, 384), (1024, 512),
+        (8192, 512), (1000, None)])
+    def test_rank_scan_block_split(self, tokens, block):
+        # the rank scan (cumsum as a triangular product) walks the token
+        # axis in lane blocks: the whole axis, else the widest
+        # 128-multiple divisor; none -> the geometry keeps XLA
+        assert pm._route_block(tokens) == block
+
     def test_route_dead_experts_and_overflow(self):
         # every token wants expert 0 at top-1: experts 1..3 are dead and
         # expert 0 overflows its capacity — clamps must match bitwise
@@ -306,6 +315,9 @@ class TestDispatchGates:
         assert not pm.moe_kernel_supported(**dict(ok, dtype=jnp.float16))
         assert not pm.moe_kernel_supported(
             **dict(ok, tokens=pm._ROUTE_BUDGET))
+        # the rank scan splits the token axis into 128-multiple lane
+        # blocks; a long axis with no such divisor keeps XLA
+        assert not pm.moe_kernel_supported(**dict(ok, tokens=1000))
         # FFN-grid working set scales with hidden: production-scale H
         # must keep XLA instead of hard-failing the Mosaic compile
         assert not pm.moe_kernel_supported(**dict(ok, hidden=7168))

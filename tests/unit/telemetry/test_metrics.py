@@ -75,9 +75,10 @@ def test_peak_flops_table_and_env_override(monkeypatch):
     monkeypatch.delenv("DSTPU_PEAK_FLOPS", raising=False)
     assert peak_flops_per_device("TPU v4") == 275e12
     assert peak_flops_per_device("TPU v5 lite") == 197e12
-    assert peak_flops_per_device("TPU v5p chip") == 459e12
+    assert peak_flops_per_device("TPU v5p") == 459e12
     assert peak_flops_per_device("cpu") == 1e12
-    assert peak_flops_per_device("mystery") == 1e12
+    with pytest.raises(ValueError, match="mystery"):
+        peak_flops_per_device("mystery")
     monkeypatch.setenv("DSTPU_PEAK_FLOPS", "123e12")
     assert peak_flops_per_device("TPU v4") == 123e12
 

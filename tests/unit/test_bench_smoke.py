@@ -3,20 +3,26 @@
 The committed BENCH_SUMMARY.json holds TPU measurements; a chipless host
 running the CPU smoke path used to clobber it with 3-step smoke numbers.
 ``_write_summary(..., smoke=True)`` must route to BENCH_SMOKE.json, and
-the CPU tail of ``_run_configs`` must pass the flag.
+the CPU tail of ``_run_configs`` must pass the flag. The CPU smoke runs
+only under ``--cpu-smoke``: without a chip ``python bench.py`` fails, and a
+failed child is a failure (the one-process-per-chip protocol).
 """
 
 import importlib.util
 import inspect
 import json
 import os
+import subprocess
+import sys
+
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def _load_bench():
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
     spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.join(root, "bench.py"))
+        "bench_under_test", os.path.join(ROOT, "bench.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)  # module top level is stdlib-only
     return mod
@@ -42,12 +48,23 @@ def test_tpu_summary_keeps_its_name(tmp_path, monkeypatch):
 
 
 def test_cpu_smoke_tail_passes_the_flag():
-    # wiring pin: the CPU in-process tail of _run_configs (the only
-    # caller that can run without a chip) must route by backend — a
-    # refactor that drops the flag regresses to the clobber
+    # wiring pin: the in-process tail of _run_configs is the CPU smoke
+    # (reached only through --cpu-smoke) and must write BENCH_SMOKE.json —
+    # a refactor that drops the flag regresses to the clobber
     bench = _load_bench()
     src = inspect.getsource(bench._run_configs)
-    assert "_write_summary(lines, smoke=not on_tpu)" in src
+    assert "_write_summary(lines, smoke=True)" in src
     # and the dispatcher's TPU write stays on the committed file
     src_tpu = inspect.getsource(bench._dispatch_tpu)
     assert "_write_summary(lines)" in src_tpu
+
+
+def test_one_child_without_a_chip_fails():
+    # the rest of the one-process-per-chip protocol is pinned in
+    # tests/unit/accelerator/test_device_selection.py
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "bench.py"), "--one", "0"],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode != 0
+    assert "metric" not in r.stdout

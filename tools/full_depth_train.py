@@ -48,16 +48,11 @@ def main():
     import jax.numpy as jnp
 
     import bench
-    from bench import PEAK_TFLOPS, REF_MFU_ZERO3, bench_train
+    from bench import REF_MFU_ZERO3, _child_setup, bench_train
     from deepspeed_tpu.models import llama_model
 
     import jax
-    kind = jax.devices()[0].device_kind
-    peak = PEAK_TFLOPS.get(kind)
-    on_tpu = jax.default_backend() not in ("cpu",)
-    if not on_tpu:
-        os.environ.setdefault("DSTPU_ACCELERATOR", "cpu")
-        peak = None
+    on_tpu, _, peak = _child_setup()
 
     model = llama_model(args.preset, dtype=jnp.bfloat16, remat=True,
                         max_seq_len=args.seq)

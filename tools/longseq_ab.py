@@ -59,7 +59,7 @@ def run_single(seq: int, path: str, offload: bool, micro: int = 1,
     import numpy as np
 
     import deepspeed_tpu
-    from bench import PEAK_TFLOPS, _flops_per_token
+    from bench import _flops_per_token, peak_tflops
     from deepspeed_tpu.models import llama_model
     from deepspeed_tpu.runtime import topology as topo_mod
 
@@ -112,8 +112,7 @@ def run_single(seq: int, path: str, offload: bool, micro: int = 1,
         sync(loss)
         sync(jax.tree.leaves(engine.state["params"])[0])
         best = min(best, time.perf_counter() - t0)
-    kind = jax.devices()[0].device_kind
-    peak = PEAK_TFLOPS.get(kind)
+    peak = peak_tflops(jax.devices()[0].device_kind)
     tok_s = micro * seq * steps / best
     ach = tok_s * _flops_per_token(model.config, seq) / 1e12
     print(json.dumps({

@@ -1,12 +1,11 @@
 #!/usr/bin/env python
 """A/B: full rematerialization (nothing_saveable) vs selective remat
-policies for the two bench lines whose no-remat backward crashes this
-environment's compile helper (bert-large seq128, gpt2-large 36L).
+policies for the two bench lines that train rematerialized (bert-large
+seq128, gpt2-large 36L).
 
 A selective policy saves matmul outputs and recomputes only the cheap
-elementwise chain in the backward — if the compile helper accepts it, the
-8/6 forced-recompute overhead mostly disappears without the no-remat
-memory footprint.
+elementwise chain in the backward: the 8/6 recompute overhead mostly
+disappears without the no-remat memory footprint.
 
 Two bert-large ZeRO-1 engines do NOT fit HBM together (measured:
 RESOURCE_EXHAUSTED at the second build), so interleaving is at PROCESS

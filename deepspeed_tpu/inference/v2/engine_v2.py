@@ -28,9 +28,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ... import comm as dist
 from ...models.transformer import TransformerLM
 from ...runtime.topology import (DATA_AXIS, MODEL_AXIS, MeshTopology,
                                  TopologyConfig)
+from ...telemetry import clock, get_telemetry
+from ...telemetry.trace import PHASE_SERVING
 from ...utils.compile_cache import enable_compile_cache
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
@@ -38,12 +41,14 @@ from .model import RaggedInferenceModel
 from .ragged.kv_cache import BlockedKVCache
 from .ragged.ragged_manager import DSStateManager
 from .ragged.ragged_wrapper import _next_bucket
+from .ragged.wave import (COUNTER_KEYS, WaveEntry, build_sharded_wave,
+                          burst_counters, wave_counters, wave_key)
+
 
 def _put_chunk_bytes() -> int:
     """Per-transfer byte cap for weight/KV uploads: leaves above it (llama2-7b's
     stacked down_proj is 2.9 GiB dense bf16) upload in slabs. Whether a
     directly attached chip needs the cap is not measured."""
-    import os
     return int(os.environ.get("DSTPU_PUT_CHUNK_BYTES", 1 << 30))
 
 
@@ -245,6 +250,13 @@ class InferenceEngineV2:
             self.kv_cache.place(kv_spec, num_shards=self.kv_shards)
 
         self._burst_fns: Dict[Tuple[int, int, int], Any] = {}
+        # what the dispatches counted (docs/OBSERVABILITY.md): running
+        # totals since the engine was built, the last put()/decode_burst()'s
+        # own, and each bucket key with the seconds its first call took.
+        # Plain integer adds, kept with telemetry on or off.
+        self.wave_totals: Dict[str, int] = dict.fromkeys(COUNTER_KEYS, 0)
+        self.last_counters: Dict[str, Any] = {}
+        self._seen_buckets: Dict[Tuple[str, Tuple[int, ...]], float] = {}
         log_dist(
             f"InferenceEngineV2: {num_blocks} KV blocks × {block_size} tokens "
             f"({self.kv_cache.mem_bytes() / 2**20:.0f} MiB"
@@ -614,6 +626,7 @@ class InferenceEngineV2:
         plan = self._plan_shards(batch_uids, [len(t) for t in batch_tokens])
         if plan is None:
             raise RuntimeError("batch does not fit KV/budget; call can_schedule first")
+        self.last_counters = {}
 
         work: List[Tuple[int, np.ndarray]] = []
         for uid, tokens in zip(batch_uids, batch_tokens):
@@ -654,23 +667,26 @@ class InferenceEngineV2:
         and prefill chunks; the host atom builder (ragged/wave.py)
         flattens it into ONE token stream + per-atom descriptors, sharded
         pools get one equally-bucketed sub-wave per data rank."""
-        from .ragged.wave import WaveEntry, build_sharded_wave
-
+        tele = get_telemetry()
+        uids = [uid for uid, _ in wave]
         sm = self.state_manager
         shards = max(self.kv_shards, 1)
-        per_shard: List[List[WaveEntry]] = [[] for _ in range(shards)]
-        for uid, chunk in wave:
-            seq = sm.get_sequence(uid)
-            r = seq.shard if shards > 1 else 0
-            local = [sm.allocator.local_id(b) for b in seq.blocks] \
-                if shards > 1 else list(seq.blocks)
-            per_shard[r].append(WaveEntry(uid, chunk, seq.seen_tokens, local))
-        desc = build_sharded_wave(per_shard,
-                                  block_q=self.config.ragged_block_q,
-                                  block_size=sm.block_size)
+        with tele.phase("wave.build", phase=PHASE_SERVING, req=uids):
+            per_shard: List[List[WaveEntry]] = [[] for _ in range(shards)]
+            for uid, chunk in wave:
+                seq = sm.get_sequence(uid)
+                r = seq.shard if shards > 1 else 0
+                local = [sm.allocator.local_id(b) for b in seq.blocks] \
+                    if shards > 1 else list(seq.blocks)
+                per_shard[r].append(
+                    WaveEntry(uid, chunk, seq.seen_tokens, local))
+            desc = build_sharded_wave(per_shard,
+                                      block_q=self.config.ragged_block_q,
+                                      block_size=sm.block_size)
+            key = wave_key(desc)
+            self._count("wave", key,
+                        wave_counters(desc, len(wave), sm.block_size))
         fn = self._wave_sharded_fn if shards > 1 else self._wave_fn
-        from ...telemetry import get_telemetry
-        from ... import comm as dist
         # The wave program moves ZERO collective bytes by contract (the
         # sharded pool keeps every gather/write rank-local; lint entry
         # `ragged-paged-attention` compiles and budgets exactly this).
@@ -681,22 +697,57 @@ class InferenceEngineV2:
         # into the wave shows up in both ledgers, not neither).
         dist.record_collective("wave_dispatch", 0, (DATA_AXIS,),
                                overlapped=True)
-        with get_telemetry().phase("wave_dispatch", phase="serving",
-                                   sequences=len(wave),
-                                   tokens=int(desc.n_tokens),
-                                   shards=shards):
-            with self.mesh:
-                logits, k_pages, v_pages = fn(
-                    self.params, self.kv_cache.k_pages, self.kv_cache.v_pages,
-                    jnp.asarray(desc.tokens), jnp.asarray(desc.positions),
-                    jnp.asarray(desc.write_idx), jnp.asarray(desc.cu_q_lens),
-                    jnp.asarray(desc.kv_lens), jnp.asarray(desc.page_indices),
-                    jnp.asarray(desc.last_rows))
+        logits, k_pages, v_pages = self._dispatch(
+            "wave", key, uids, lambda: fn(
+                self.params, self.kv_cache.k_pages, self.kv_cache.v_pages,
+                jnp.asarray(desc.tokens), jnp.asarray(desc.positions),
+                jnp.asarray(desc.write_idx), jnp.asarray(desc.cu_q_lens),
+                jnp.asarray(desc.kv_lens), jnp.asarray(desc.page_indices),
+                jnp.asarray(desc.last_rows)),
+            sequences=len(wave), tokens=int(desc.n_tokens), shards=shards)
         self.kv_cache.update(k_pages, v_pages)
         for uid, chunk in wave:
             sm.get_sequence(uid).post_forward(len(chunk))
-        logits = np.asarray(logits)
+        with tele.phase("wave.fetch", phase=PHASE_SERVING, req=uids):
+            logits = np.asarray(logits)     # waits for the device
         return np.stack([logits[desc.row_of_uid[uid]] for uid, _ in wave])
+
+    # -- what the dispatches counted ----------------------------------------
+    def _count(self, program: str, key: Tuple[int, ...],
+               counters: Dict[str, int]) -> None:
+        """Add one dispatch's counters to the running totals and to the
+        current put()/decode_burst()'s own."""
+        last = self.last_counters
+        for k, v in counters.items():
+            self.wave_totals[k] += v
+            last[k] = last.get(k, 0) + v
+        last.setdefault("buckets", []).append([program, *key])
+
+    def _dispatch(self, program: str, key: Tuple[int, ...],
+                  uids: Sequence[int], call, **args):
+        """Descriptor upload and the call, as one ``wave.dispatch`` span.
+        The first call of a bucket key traces and compiles (or loads the
+        compile cache): an instant ``compile:<program>`` says which key and
+        how long that call took."""
+        tele = get_telemetry()
+        first = (program, key) not in self._seen_buckets
+        t0 = clock.now()
+        with tele.phase("wave.dispatch", phase=PHASE_SERVING, req=uids,
+                        program=program, **args):
+            with self.mesh:
+                out = call()
+        if first:
+            secs = self._seen_buckets[(program, key)] = clock.now() - t0
+            tele.instant(f"compile:{program}", phase=PHASE_SERVING,
+                         key=list(key), seconds=round(secs, 4))
+        return out
+
+    def seen_buckets(self) -> Dict[Tuple[str, Tuple[int, ...]], float]:
+        """Every ``(program, bucket key)`` dispatched so far (wave ``(N, A,
+        MP, R)``, burst ``(B, mp, k)``, legacy ragged ``(Bd, mpd, Sp, T,
+        mpp)``) with the seconds its first call took: which step
+        recompiled, answered by the program."""
+        return dict(self._seen_buckets)
 
     def can_burst(self, batch_uids: Sequence[int], num_steps: int) -> bool:
         """Burst feasibility: the fused program runs len(uids) tokens PER
@@ -734,47 +785,53 @@ class InferenceEngineV2:
         """
         if not self.can_burst(batch_uids, num_steps):
             raise RuntimeError("burst does not fit KV budget; call can_burst")
+        tele = get_telemetry()
+        uids = list(batch_uids)
+        self.last_counters = {}
         sm = self.state_manager
-        seqs = []
-        for uid in batch_uids:
-            seq = sm.get_sequence(uid)
-            assert seq is not None and seq.seen_tokens > 0, \
-                f"decode_burst requires a prefilled sequence (uid {uid})"
-            sm.allocate_blocks(seq, num_steps)
-            seqs.append(seq)
+        with tele.phase("wave.build", phase=PHASE_SERVING, req=uids):
+            seqs = []
+            for uid in batch_uids:
+                seq = sm.get_sequence(uid)
+                assert seq is not None and seq.seen_tokens > 0, \
+                    f"decode_burst requires a prefilled sequence (uid {uid})"
+                sm.allocate_blocks(seq, num_steps)
+                seqs.append(seq)
 
-        B = _next_bucket(len(batch_uids), lo=16)
-        mp = self._bucket_blocks(batch_uids)
-        tokens = np.zeros((B,), np.int32)
-        positions = np.zeros((B,), np.int32)
-        tables = np.zeros((B, mp), np.int32)  # padded rows write null block 0
-        temps = np.zeros((B,), np.float32)
-        for i, (uid, seq) in enumerate(zip(batch_uids, seqs)):
-            tokens[i] = last_tokens[i]
-            positions[i] = seq.seen_tokens
-            bt = seq.blocks[:mp]
-            tables[i, :len(bt)] = bt
-            if temperatures is not None:
-                temps[i] = temperatures[i]
+            B = _next_bucket(len(batch_uids), lo=16)
+            mp = self._bucket_blocks(batch_uids)
+            tokens = np.zeros((B,), np.int32)
+            positions = np.zeros((B,), np.int32)
+            tables = np.zeros((B, mp), np.int32)  # padded rows: null block 0
+            temps = np.zeros((B,), np.float32)
+            for i, (uid, seq) in enumerate(zip(batch_uids, seqs)):
+                tokens[i] = last_tokens[i]
+                positions[i] = seq.seen_tokens
+                bt = seq.blocks[:mp]
+                tables[i, :len(bt)] = bt
+                if temperatures is not None:
+                    temps[i] = temperatures[i]
+            key = (B, mp, num_steps)
+            self._count("burst", key, burst_counters(
+                [seq.seen_tokens for seq in seqs], num_steps, B, mp,
+                sm.block_size))
 
-        key = (B, mp, num_steps)
         if key not in self._burst_fns:
             self._burst_fns[key] = jax.jit(
                 functools.partial(self._model.decode_burst, num_steps=num_steps),
                 donate_argnums=(1, 2))
-        from ...telemetry import get_telemetry
-        with get_telemetry().phase("decode_burst", phase="serving",
-                                   sequences=len(batch_uids), k=num_steps):
-            with self.mesh:
-                toks, k_pages, v_pages = self._burst_fns[key](
-                    self.params, self.kv_cache.k_pages, self.kv_cache.v_pages,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(tables), jax.random.PRNGKey(seed),
-                    jnp.asarray(temps))
+        toks, k_pages, v_pages = self._dispatch(
+            "burst", key, uids, lambda: self._burst_fns[key](
+                self.params, self.kv_cache.k_pages, self.kv_cache.v_pages,
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(tables), jax.random.PRNGKey(seed),
+                jnp.asarray(temps)),
+            sequences=len(batch_uids), k=num_steps)
         self.kv_cache.update(k_pages, v_pages)
         for seq in seqs:
             seq.post_forward(num_steps)
-        return np.asarray(toks)[:len(batch_uids)]
+        with tele.phase("wave.fetch", phase=PHASE_SERVING, req=uids):
+            return np.asarray(toks)[:len(batch_uids)]   # waits for the device
 
     def _bucket_blocks(self, uids) -> int:
         need = max((len(self.state_manager.get_sequence(u).blocks) for u in uids),
@@ -830,18 +887,18 @@ class InferenceEngineV2:
             bt = seq.blocks[:mpp]
             p_tables[i, :len(bt)] = bt
 
-        from ...telemetry import get_telemetry
-        with get_telemetry().phase("ragged_dispatch", phase="serving",
-                                   decode=len(decode), prefill=len(prefill),
-                                   prefill_tokens=int(p_valid.sum())):
-            with self.mesh:
-                logits, k_pages, v_pages = self._ragged_fn(
-                    self.params, self.kv_cache.k_pages, self.kv_cache.v_pages,
-                    jnp.asarray(d_tokens), jnp.asarray(d_positions),
-                    jnp.asarray(d_context), jnp.asarray(d_tables),
-                    jnp.asarray(p_tokens), jnp.asarray(p_positions),
-                    jnp.asarray(p_valid), jnp.asarray(p_history),
-                    jnp.asarray(p_tables))
+        # the legacy two-class program: spans and bucket keys, no counters
+        logits, k_pages, v_pages = self._dispatch(
+            "ragged", (Bd, mpd, Sp, T, mpp), [u for u, _ in wave],
+            lambda: self._ragged_fn(
+                self.params, self.kv_cache.k_pages, self.kv_cache.v_pages,
+                jnp.asarray(d_tokens), jnp.asarray(d_positions),
+                jnp.asarray(d_context), jnp.asarray(d_tables),
+                jnp.asarray(p_tokens), jnp.asarray(p_positions),
+                jnp.asarray(p_valid), jnp.asarray(p_history),
+                jnp.asarray(p_tables)),
+            decode=len(decode), prefill=len(prefill),
+            prefill_tokens=int(p_valid.sum()))
         self.kv_cache.update(k_pages, v_pages)
         for uid, chunk in wave:
             sm.get_sequence(uid).post_forward(len(chunk))

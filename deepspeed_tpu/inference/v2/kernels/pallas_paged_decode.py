@@ -144,6 +144,7 @@ def paged_gqa_decode(q: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(context_lens.astype(jnp.int32),
       block_tables.astype(jnp.int32).reshape(-1),
       (q * scale).astype(q.dtype), k_pages, v_pages)

@@ -268,6 +268,7 @@ def _wave_call(q_tiled, k_pages, v_pages, q_lens, kv_lens, page_indices, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
       page_indices.astype(jnp.int32).reshape(-1),
       (q_tiled * scale).astype(q_tiled.dtype), k_pages, v_pages)

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from ...models.transformer import ACTIVATIONS, TransformerLM
 from ...nn import layers as nn
+from ...utils.scope import scoped
 from .kernels.paged_attention import (chunk_prefill_attention, paged_decode_attention,
                                       ragged_chunk_attention)
 
@@ -96,6 +97,7 @@ class RaggedInferenceModel:
             self._moe_serve = None
 
     # -- shared pieces ------------------------------------------------------
+    @scoped("embed")
     def _embed(self, params: Params, tokens: jax.Array, positions: jax.Array) -> jax.Array:
         """tokens [N] -> [N, hidden] (reference ``_forward_embed``, ragged_embed)."""
         m = self.model
@@ -107,6 +109,7 @@ class RaggedInferenceModel:
             x = m._ln_emb(params["ln_emb"], x)
         return x.astype(self.config.dtype)
 
+    @scoped("head")
     def _unembed(self, params: Params, x: jax.Array) -> jax.Array:
         """x [N, hidden] -> logits [N, vocab] fp32 (reference
         ``_forward_unembed``, gather_for_logits)."""
@@ -131,6 +134,7 @@ class RaggedInferenceModel:
             k = m._rotate(k, positions)
         return q, k, v
 
+    @scoped("mlp")
     def _mlp(self, block: Params, h: jax.Array) -> jax.Array:
         """MLP over the PRE-NORMED input h."""
         c, m = self.config, self.model
@@ -171,19 +175,23 @@ class RaggedInferenceModel:
             x, k_pages, v_pages = carry
             block = jax.tree.map(lambda a: a[l], blocks)
             h1 = m._block_layers["ln_1"](block["ln_1"], x)
-            q, k, v = self._qkv(block, h1, positions)
-            k_l = self._write_kv(k_pages[l], k, write_idx)
-            v_l = self._write_kv(v_pages[l], v, write_idx)
-            k_pages = k_pages.at[l].set(k_l)
-            v_pages = v_pages.at[l].set(v_l)
+            with jax.named_scope("attn/qkv"):
+                q, k, v = self._qkv(block, h1, positions)
+            with jax.named_scope("kv_write"):
+                k_l = self._write_kv(k_pages[l], k, write_idx)
+                v_l = self._write_kv(v_pages[l], v, write_idx)
+                k_pages = k_pages.at[l].set(k_l)
+                v_pages = v_pages.at[l].set(v_l)
             win = (self._windows_arr[l] if self._windows_arr is not None
                    else None)
             # narrow KV store (fp8 cache): the attention kernels upcast
             # AFTER their per-sequence block gathers (paged_attention.py
             # _gather_pages), so the full pool is never widened
-            attn_out = attn_fn(q, k_l, v_l, win)
-            o = m._block_layers["o_proj"](
-                block["o_proj"], attn_out.reshape(x.shape[0], -1))
+            with jax.named_scope("attn/core"):
+                attn_out = attn_fn(q, k_l, v_l, win)
+            with jax.named_scope("attn/out"):
+                o = m._block_layers["o_proj"](
+                    block["o_proj"], attn_out.reshape(x.shape[0], -1))
             if c.parallel_block:
                 # falcon/phi: MLP reads the block INPUT through a shared
                 # (phi/falcon-7b) or per-branch (falcon-40b) norm
@@ -379,11 +387,12 @@ class RaggedInferenceModel:
             x, k_pages, v_pages = self._layer_loop(
                 params, k_pages, v_pages, x, attn, write_idx, positions)
             logits = self._unembed(params, x)              # [B, V]
-            rng, sub = jax.random.split(rng)
-            greedy = jnp.argmax(logits, axis=-1)
-            temp = jnp.maximum(temperatures, 1e-6)[:, None]
-            sampled = jax.random.categorical(sub, logits / temp, axis=-1)
-            nxt = jnp.where(temperatures <= 0.0, greedy, sampled).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                rng, sub = jax.random.split(rng)
+                greedy = jnp.argmax(logits, axis=-1)
+                temp = jnp.maximum(temperatures, 1e-6)[:, None]
+                sampled = jax.random.categorical(sub, logits / temp, axis=-1)
+                nxt = jnp.where(temperatures <= 0.0, greedy, sampled).astype(jnp.int32)
             return (nxt, positions + 1, k_pages, v_pages, rng), nxt
 
         carry = (tokens, positions, k_pages, v_pages, rng)

@@ -142,3 +142,61 @@ def build_sharded_wave(per_shard: Sequence[Sequence[WaveEntry]], *,
         last_rows=np.concatenate([w.last_rows for w in waves]),
         row_of_uid=row_of_uid,
         n_tokens=sum(w.n_tokens for w in waves))
+
+
+# ---------------------------------------------------------------------------
+# what a dispatch counted (engine_v2 keeps the running totals)
+# ---------------------------------------------------------------------------
+
+#: real against bucketed sizes, and the attention work of the real atoms:
+#: ``attn_q_kv`` is the sum over atoms of q_len x kv_len (the score and value
+#: FLOPs are 4 x heads x head_dim times it), ``attn_kv`` the sum of kv_len
+#: (the KV bytes read are 2 x kv_heads x head_dim x itemsize times it)
+COUNTER_KEYS = ("dispatches", "tokens", "tokens_bucket", "atoms",
+                "atoms_bucket", "pages", "pages_bucket", "rows",
+                "rows_bucket", "attn_q_kv", "attn_kv")
+
+
+def _counters(q_lens: np.ndarray, kv_lens: np.ndarray, block_size: int,
+              rows: int, bucket: Tuple[int, int, int, int]) -> Dict[str, int]:
+    """Counters of one dispatch from its REAL atoms (``q_lens > 0``) and its
+    bucket ``(tokens, atoms, pages, rows)``, all shards together."""
+    real = q_lens > 0
+    q = q_lens[real].astype(np.int64)
+    kv = kv_lens[real].astype(np.int64)
+    return {"dispatches": 1,
+            "tokens": int(q.sum()), "tokens_bucket": int(bucket[0]),
+            "atoms": int(real.sum()), "atoms_bucket": int(bucket[1]),
+            "pages": int((-(-kv // block_size)).sum()),
+            "pages_bucket": int(bucket[2]),
+            "rows": int(rows), "rows_bucket": int(bucket[3]),
+            "attn_q_kv": int((q * kv).sum()), "attn_kv": int(kv.sum())}
+
+
+def wave_key(desc: WaveDescriptors) -> Tuple[int, int, int, int]:
+    """The per-shard bucket ``(N, A, MP, R)`` the wave program compiled for."""
+    shards = len(desc.cu_q_lens) - len(desc.kv_lens)
+    return (len(desc.tokens) // shards, len(desc.kv_lens) // shards,
+            int(desc.page_indices.shape[1]), len(desc.last_rows) // shards)
+
+
+def wave_counters(desc: WaveDescriptors, rows: int,
+                  block_size: int) -> Dict[str, int]:
+    """Counters of one wave dispatch: ``rows`` real sequence-chunks; the
+    page bucket is every (atom, page) pair the kernel's grid walks."""
+    shards = len(desc.cu_q_lens) - len(desc.kv_lens)
+    q_lens = np.diff(desc.cu_q_lens.reshape(shards, -1), axis=1).reshape(-1)
+    return _counters(q_lens, desc.kv_lens, block_size, rows,
+                     (len(desc.tokens), len(desc.kv_lens),
+                      desc.page_indices.size, len(desc.last_rows)))
+
+
+def burst_counters(seen: Sequence[int], num_steps: int, bucket_rows: int,
+                   bucket_pages: int, block_size: int) -> Dict[str, int]:
+    """Counters of one fused decode burst: every (sequence, step) is an
+    atom of one query over ``seen + step + 1`` keys; padded rows run too."""
+    kv = (np.asarray(seen, np.int64)[:, None]
+          + np.arange(1, num_steps + 1, dtype=np.int64)[None, :]).reshape(-1)
+    slots = bucket_rows * num_steps
+    return _counters(np.ones_like(kv), kv, block_size, len(seen),
+                     (slots, slots, slots * bucket_pages, bucket_rows))

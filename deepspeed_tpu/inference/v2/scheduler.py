@@ -42,7 +42,9 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ...telemetry import clock, get_telemetry, maybe_enable_from_env
 from ...telemetry.metrics import LatencyHistogram
+from ...telemetry.trace import PHASE_SERVING
 
 
 @dataclasses.dataclass
@@ -85,7 +87,6 @@ class ContinuousBatchingScheduler:
         # serving telemetry (queue depth, occupancy, per-token latency
         # percentiles): the process-global recorder — a NULL object unless
         # an engine configured it or DSTPU_TELEMETRY=1
-        from deepspeed_tpu.telemetry import maybe_enable_from_env
         maybe_enable_from_env()
         self.token_budget = token_budget or engine.config.state_manager.max_ragged_batch_size
         # preemption stashes KV to host RAM (engine.offload_sequence) and
@@ -119,7 +120,6 @@ class ContinuousBatchingScheduler:
     # -- client API ---------------------------------------------------------
     def submit(self, prompt: Sequence[int], max_new_tokens: int = 64,
                temperature: float = 0.0, eos_token_id: Optional[int] = None) -> Request:
-        from deepspeed_tpu.telemetry import clock
         max_ctx = getattr(self.engine, "max_context", None)
         if max_ctx is not None and len(prompt) >= max_ctx:
             raise ValueError(f"prompt of {len(prompt)} tokens cannot fit the "
@@ -259,11 +259,40 @@ class ContinuousBatchingScheduler:
         one dispatch with on-device sampling (engine ``decode_burst``) —
         the serving loop's answer to per-dispatch round-trip latency.
         Prefill work pending disables bursting so TTFT never waits behind
-        a burst. Returns (tokens processed, burst depth k); (0, 0) = not
-        applicable."""
+        a burst. Returns (tokens processed, burst depth k, uids); tokens 0
+        = not applicable."""
         k_cfg = getattr(self.engine.config, "decode_burst", 1)
         if self._queue or not self._running or k_cfg <= 1:
-            return 0, 0
+            return 0, 0, []
+        tele = get_telemetry()
+        with tele.phase("sched.compose", phase=PHASE_SERVING) as span:
+            reqs, uids, k = self._compose_burst(k_cfg)
+            if tele.enabled:
+                span.req = uids
+        if k < 2:
+            # KV pressure (or nothing to fuse): let the single-token path
+            # run — it preempts one sequence at a time
+            return 0, 0, []
+        toks = self.engine.decode_burst(
+            uids, [r.generated[-1] for r in reqs], k,
+            temperatures=[r.temperature for r in reqs],
+            seed=int(self._rng.integers(1 << 31)))
+        with tele.phase("sched.sample", phase=PHASE_SERVING, req=uids):
+            for r, row in zip(reqs, toks):
+                for tok in row:
+                    r.generated.append(int(tok))
+                    if ((r.eos_token_id is not None and tok == r.eos_token_id)
+                            or len(r.generated) >= r.max_new_tokens):
+                        # overshoot tokens past EOS are discarded here; the
+                        # sequence's KV is flushed with the request
+                        self._finish(r)
+                        self._running.remove(r)
+                        break
+        return len(reqs) * k, k, uids
+
+    def _compose_burst(self, k_cfg: int):
+        """The burst's members and depth: (requests, uids, k); k < 2 means
+        no burst fits."""
         # pick the burst depth k maximizing fused tokens k * |{remaining>=k}|
         # and burst only that subset: a single nearly-done request must not
         # force everyone down to single-token steps (the tail would pay a
@@ -289,58 +318,90 @@ class ContinuousBatchingScheduler:
             if self.engine.can_burst(cand_uids, cand_k):
                 reqs, uids, k = cand_reqs, cand_uids, cand_k
                 break
-        if k < 2:
-            # KV pressure (or nothing to fuse): let the single-token path
-            # run — it preempts one sequence at a time
-            return 0, 0
-        toks = self.engine.decode_burst(
-            uids, [r.generated[-1] for r in reqs], k,
-            temperatures=[r.temperature for r in reqs],
-            seed=int(self._rng.integers(1 << 31)))
-        for r, row in zip(reqs, toks):
-            for tok in row:
-                r.generated.append(int(tok))
-                if ((r.eos_token_id is not None and tok == r.eos_token_id)
-                        or len(r.generated) >= r.max_new_tokens):
-                    # overshoot tokens past EOS are discarded here; the
-                    # sequence's KV is flushed with the request
-                    self._finish(r)
-                    self._running.remove(r)
-                    break
-        return len(reqs) * k, k
+        return reqs, uids, k
 
     def step(self, _retry: bool = True) -> int:
-        """Run one composed wave; returns tokens processed.
-        ``DSTPU_SCHED_LOG=1`` prints one line per wave (kind, per-request
-        token counts, wall ms) — the serving analog of the comms logger."""
-        import os
-        from deepspeed_tpu.telemetry import clock, get_telemetry
+        """Run one composed wave; returns tokens processed. One
+        ``sched.step`` span with the wave's uids; its children
+        (``sched.restore``, ``sched.compose``, the engine's ``wave.build``
+        / ``wave.dispatch`` / ``wave.fetch``, ``sched.sample``) say where
+        the host spent it (docs/OBSERVABILITY.md)."""
         tele = get_telemetry()
-        log = os.environ.get("DSTPU_SCHED_LOG") == "1"
-        if log:
-            import time as _t
-            _t0 = _t.perf_counter()
+        with tele.phase("sched.step", phase=PHASE_SERVING) as span:
+            return self._step(tele, span, _retry)
+
+    def _step(self, tele, step_span, _retry: bool) -> int:
         _w0 = clock.now()
         # restore offloaded sequences as KV pressure relents — they were
         # running before anything queued, so they outrank new prefills
-        self._restore_offloaded()
-        burst, burst_k = self._try_decode_burst()
+        with tele.phase("sched.restore", phase=PHASE_SERVING):
+            self._restore_offloaded()
+        burst, burst_k, burst_uids = self._try_decode_burst()
         if burst:
             dur = clock.now() - _w0
             # the admission policy reads this reservoir as "time per
             # decode token per sequence"; a burst wave carries k tokens
             # per sequence, so normalize or gen-pressure fires k x early
             self._exec_hist.record(dur / max(burst_k, 1))
-            if log:
-                print(f"[sched] burst tokens={burst} "
-                      f"running={len(self._running)} "
-                      f"ms={(_t.perf_counter() - _t0) * 1e3:.0f}", flush=True)
             if tele.enabled:
+                step_span.req = burst_uids
                 tele.record_wave(
                     "burst", tokens=burst, duration_s=dur,
                     queue_depth=len(self._queue), running=len(self._running),
-                    occupancy=burst / max(self.token_budget, 1))
+                    occupancy=burst / max(self.token_budget, 1),
+                    counters=self._engine_counters())
             return burst
+        with tele.phase("sched.compose", phase=PHASE_SERVING) as span:
+            (kind_plan, uids, tokens, decode_reqs, prefill_reqs,
+             admitted) = self._compose(_w0)
+            if tele.enabled:
+                span.req = step_span.req = uids
+        if not uids:
+            # a disaggregated single-class wave may compose empty (KV
+            # full / admission frozen on a prefill wave; every running
+            # sequence preempted on a decode wave): fall back to ONE
+            # mixed wave so the other class still drains rather than
+            # reporting a bogus deadlock to the driver
+            if kind_plan != "mixed" and (self._running or self._queue
+                                         or self._offloaded):
+                self._pf_credit = 0.0
+                return self._step_mixed_fallback(_retry)
+            # a preempt during decode budgeting may have just freed the
+            # blocks an offloaded sequence needs — drivers treat 0 as
+            # deadlock, so retry ONCE after a restore pass rather than
+            # abandoning restorable work (single retry: a genuinely wedged
+            # pool must still return 0)
+            if _retry and self._offloaded and self._restore_offloaded():
+                return self.step(_retry=False)
+            return 0
+
+        logits = self.engine.put(uids, tokens)
+        dur = clock.now() - _w0
+        self._exec_hist.record(dur)
+        if tele.enabled:
+            n_tokens = sum(len(t) for t in tokens)
+            kind = ("mixed" if decode_reqs and prefill_reqs
+                    else "decode" if decode_reqs else "prefill")
+            tele.record_wave(
+                kind, tokens=n_tokens, duration_s=dur,
+                queue_depth=len(self._queue), running=len(self._running),
+                occupancy=n_tokens / max(self.token_budget, 1),
+                admitted=len(admitted),
+                queue_wait_s=max((r.queue_wait_s for r in admitted),
+                                 default=0.0),
+                counters=self._engine_counters())
+        with tele.phase("sched.sample", phase=PHASE_SERVING, req=uids):
+            self._consume(tele, uids, tokens, logits, decode_reqs,
+                          prefill_reqs)
+        return sum(len(t) for t in tokens)
+
+    def _engine_counters(self) -> Optional[dict]:
+        """What the engine counted in the dispatches of this step."""
+        return getattr(self.engine, "last_counters", None)
+
+    def _compose(self, _w0: float):
+        """Admission and budgeting of one wave: (kind planned, uids,
+        token chunks, decode requests, prefill requests, newly admitted)."""
         kind_plan = self._wave_kind(_w0)
         uids: List[int] = []
         tokens: List[np.ndarray] = []
@@ -390,45 +451,11 @@ class ContinuousBatchingScheduler:
                 tokens.append(chunk)
                 prefill_reqs.append(req)
                 budget -= take
+        return kind_plan, uids, tokens, decode_reqs, prefill_reqs, admitted
 
-        if not uids:
-            # a disaggregated single-class wave may compose empty (KV
-            # full / admission frozen on a prefill wave; every running
-            # sequence preempted on a decode wave): fall back to ONE
-            # mixed wave so the other class still drains rather than
-            # reporting a bogus deadlock to the driver
-            if kind_plan != "mixed" and (self._running or self._queue
-                                         or self._offloaded):
-                self._pf_credit = 0.0
-                return self._step_mixed_fallback(_retry)
-            # a preempt during decode budgeting may have just freed the
-            # blocks an offloaded sequence needs — drivers treat 0 as
-            # deadlock, so retry ONCE after a restore pass rather than
-            # abandoning restorable work (single retry: a genuinely wedged
-            # pool must still return 0)
-            if _retry and self._offloaded and self._restore_offloaded():
-                return self.step(_retry=False)
-            return 0
-
-        logits = self.engine.put(uids, tokens)
-        dur = clock.now() - _w0
-        self._exec_hist.record(dur)
-        if tele.enabled:
-            n_tokens = sum(len(t) for t in tokens)
-            kind = ("mixed" if decode_reqs and prefill_reqs
-                    else "decode" if decode_reqs else "prefill")
-            tele.record_wave(
-                kind, tokens=n_tokens, duration_s=dur,
-                queue_depth=len(self._queue), running=len(self._running),
-                occupancy=n_tokens / max(self.token_budget, 1),
-                admitted=len(admitted),
-                queue_wait_s=max((r.queue_wait_s for r in admitted),
-                                 default=0.0))
-        if log:
-            print(f"[sched] wave[{kind_plan}] decode={len(decode_reqs)} "
-                  f"prefill={[len(tokens[uids.index(r.uid)]) for r in prefill_reqs]} "
-                  f"queue={len(self._queue)} "
-                  f"ms={(_t.perf_counter() - _t0) * 1e3:.0f}", flush=True)
+    def _consume(self, tele, uids, tokens, logits, decode_reqs,
+                 prefill_reqs) -> None:
+        """Sample from the wave's logits and move requests along."""
         by_uid: Dict[int, np.ndarray] = dict(zip(uids, logits))
 
         for req in decode_reqs:
@@ -456,8 +483,6 @@ class ContinuousBatchingScheduler:
                     self._finish(req)
                 else:
                     self._running.append(req)
-
-        return sum(len(t) for t in tokens)
 
     def _step_mixed_fallback(self, _retry: bool) -> int:
         """One forced-mixed step (disaggregated prefill wave composed

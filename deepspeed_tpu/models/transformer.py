@@ -32,6 +32,7 @@ from ..nn import layers as nn
 from ..ops.transformer.attention import flash_attention
 from ..runtime.topology import BATCH_AXES, DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 from ..utils.jax_compat import with_sharding_constraint
+from ..utils.scope import scoped
 from ..sequence.layer import ulysses_attention
 
 Params = Dict[str, Any]
@@ -53,6 +54,7 @@ def _c(x, spec):
     return with_sharding_constraint(x, spec)
 
 
+@scoped("loss")
 def masked_cross_entropy(logits: jax.Array, labels: jax.Array,
                          extra_mask: Optional[jax.Array] = None) -> jax.Array:
     """Mean cross-entropy over positions where ``labels >= 0`` (−100 = HF
@@ -359,12 +361,23 @@ class TransformerLM:
         (mistral sliding window / gpt-neo local layers)."""
         c = self.config
         B, S, _ = h.shape
-        q = self._block_layers["q_proj"](block["q_proj"], h).reshape(B, S, c.num_heads, c.head_dim)
-        k = self._block_layers["k_proj"](block["k_proj"], h).reshape(B, S, c.kv_heads, c.head_dim)
-        v = self._block_layers["v_proj"](block["v_proj"], h).reshape(B, S, c.kv_heads, c.head_dim)
-        if c.position == "rope":
-            q = self._rotate(q, positions)
-            k = self._rotate(k, positions)
+        with jax.named_scope("attn"):
+            with jax.named_scope("qkv"):
+                q = self._block_layers["q_proj"](block["q_proj"], h).reshape(B, S, c.num_heads, c.head_dim)
+                k = self._block_layers["k_proj"](block["k_proj"], h).reshape(B, S, c.kv_heads, c.head_dim)
+                v = self._block_layers["v_proj"](block["v_proj"], h).reshape(B, S, c.kv_heads, c.head_dim)
+                if c.position == "rope":
+                    q = self._rotate(q, positions)
+                    k = self._rotate(k, positions)
+            with jax.named_scope("core"):
+                out = self._attn_core(q, k, v, attn_mask, window)
+            with jax.named_scope("out"):
+                out = out.reshape(B, S, c.num_heads * c.head_dim)
+                return self._block_layers["o_proj"](block["o_proj"], out)
+
+    def _attn_core(self, q, k, v, attn_mask, window) -> jax.Array:
+        """Scores, softmax and values (XLA, flash, ring or Ulysses)."""
+        c = self.config
         seg = attn_mask.astype(jnp.int32) if attn_mask is not None else None
         kw = {}
         if c.attn_scale is not None:
@@ -376,18 +389,13 @@ class TransformerLM:
                 raise ValueError("ring attention does not support padding "
                                  "masks (attention_mask)")
             from ..sequence.ring_attention import ring_attention
-            out = ring_attention(q, k, v, causal=True, scale=c.attn_scale)
-        elif self._alibi_slopes is not None:
-            out = ulysses_attention(flash_attention, q, k, v, causal=c.causal,
-                                    segment_ids=seg,
-                                    alibi_slopes=jnp.asarray(self._alibi_slopes),
-                                    **kw)
-        else:
-            out = ulysses_attention(flash_attention, q, k, v, causal=c.causal,
-                                    segment_ids=seg, **kw)
-        out = out.reshape(B, S, c.num_heads * c.head_dim)
-        return self._block_layers["o_proj"](block["o_proj"], out)
+            return ring_attention(q, k, v, causal=True, scale=c.attn_scale)
+        if self._alibi_slopes is not None:
+            kw["alibi_slopes"] = jnp.asarray(self._alibi_slopes)
+        return ulysses_attention(flash_attention, q, k, v, causal=c.causal,
+                                 segment_ids=seg, **kw)
 
+    @scoped("mlp")
     def _mlp(self, block: Params, h: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """MLP over the PRE-NORMED input h."""
         c = self.config
@@ -403,6 +411,7 @@ class TransformerLM:
             out = self._block_layers["fc_out"](block["fc_out"], h2)
         return out, aux
 
+    @scoped("block")   # norms and residual adds are "block" and nothing finer
     def _block_fn(self, attn_mask, carry, block_and_keep):
         if len(block_and_keep) == 3:
             block, keep, window = block_and_keep
@@ -441,6 +450,7 @@ class TransformerLM:
             x = _c(x + keep * mlp_out, ACT_SPEC)
         return (x, positions, aux_acc + keep * aux), None
 
+    @scoped("embed")
     def embed(self, params: Params, input_ids: jax.Array,
               token_type_ids: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array]:
@@ -468,6 +478,7 @@ class TransformerLM:
             x = self._ln_emb(params["ln_emb"], x)
         return _c(x.astype(c.dtype), ACT_SPEC), positions
 
+    @scoped("head")
     def head(self, params: Params, x: jax.Array) -> jax.Array:
         """Back of the network: final norm (pre-LN), MLM transform, LM/MLM
         head. Input is the last block's output; returns fp32 logits. The

@@ -295,6 +295,7 @@ def adam_bucket_update(grads: jax.Array, master: jax.Array,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="adam_bucket",
     )(g2, p2, m2, v2, scal, seeds)
 
     outs = [o.reshape(-1)[:n] for o in outs]

@@ -102,6 +102,7 @@ def lion_bucket_update(grads: jax.Array, master: jax.Array,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="lion_bucket",
     )(g2, p2, m2, scal, seeds)
 
     outs = [o.reshape(-1)[:n] for o in outs]

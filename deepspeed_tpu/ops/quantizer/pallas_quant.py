@@ -87,5 +87,6 @@ def quantize_rows_int8(groups: jax.Array, *, interpret=None
         out_shape=[jax.ShapeDtypeStruct((Gp, gs), jnp.int8),
                    jax.ShapeDtypeStruct((Gp, 1), jnp.float32)],
         interpret=interpret,
+        name="quantize_rows_int8",
     )(x)
     return q[:G], s[:G, 0]

@@ -143,6 +143,7 @@ def woq_matmul(x: jax.Array, q: jax.Array, scale: jax.Array,
         ],
         out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
         interpret=interpret,
+        name="woq_matmul",
     )(x, q, scale)
     return out[:M]
 

@@ -242,6 +242,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=cfg.interpret,
+        name="flash_fwd",
     )(info, slopes, q, k, v, qseg_b, kseg_b)
     return o, lse[..., 0]
 
@@ -393,6 +394,7 @@ def _bwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=cfg.interpret,
+        name="flash_bwd_dq",
     )(info, slopes, q, k, v, qseg_b, kseg_b, do, lse_b, di_b)
 
     # ---- dk/dv: k-blocks outer, (group, q-block) accumulated in scratch --
@@ -441,6 +443,7 @@ def _bwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info,
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=cfg.interpret,
+        name="flash_bwd_dkv",
     )(info, slopes, q, k, v, qseg_b, kseg_b, do, lse_b, di_b)
     return dq, dk, dv
 

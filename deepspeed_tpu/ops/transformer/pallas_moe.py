@@ -292,6 +292,7 @@ def moe_route(logits: jax.Array, *, top_k: int, capacity: int,
                    jax.ShapeDtypeStruct((E, 1), jnp.float32),
                    jax.ShapeDtypeStruct((E, 1), jnp.float32)],
         interpret=interpret,
+        name="moe_route",
     )(logits.T)
     slot, w_tk = slot_kt.T, w_kt.T                       # [T, K]
     # inverse slot->token map: kept slots are unique, dropped choices
@@ -366,6 +367,7 @@ def moe_dispatch_gather(tokens: jax.Array, src: jax.Array, *,
             S, H, pl.BlockSpec((1, 1, H), lambda i, src: (i, 0, 0))),
         out_shape=jax.ShapeDtypeStruct((S, 1, H), out_dtype),
         interpret=interpret,
+        name="moe_dispatch_gather",
     )(src.astype(jnp.int32), tokens.reshape(T, 1, H)).reshape(S, H)
 
 
@@ -388,6 +390,7 @@ def moe_dispatch_gather_int8(tokens: jax.Array, src: jax.Array, *,
         out_shape=[jax.ShapeDtypeStruct((S, 1, H), jnp.int8),
                    jax.ShapeDtypeStruct((S, 1, 1), jnp.float32)],
         interpret=interpret,
+        name="moe_dispatch_gather_int8",
     )(src.astype(jnp.int32), tokens.reshape(T, 1, H))
     return q.reshape(S, H), scale.reshape(S)
 
@@ -534,6 +537,7 @@ def moe_ffn_combine(payload: jax.Array, wi_gate: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tokens, H), jnp.float32),
         interpret=interpret,
+        name="moe_ffn_combine",
     )(src.astype(jnp.int32), slot_w, payload, wi_gate, wu, wo)
 
 
@@ -561,6 +565,7 @@ def moe_ffn(payload: jax.Array, wi_gate: jax.Array,
         out_shape=jax.ShapeDtypeStruct((E, C, H), jnp.float32),
         scratch_shapes=[pltpu.VMEM((cap_block, H), jnp.float32)],
         interpret=interpret,
+        name="moe_ffn",
     )(payload, wi_gate, wu, wo)
 
 
@@ -586,6 +591,7 @@ def moe_combine(y: jax.Array, slot_tk: jax.Array, w_tk: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, 1, H), jnp.float32),
         interpret=interpret,
+        name="moe_combine",
     )(slot_tk.astype(jnp.int32).reshape(-1), w_tk.reshape(-1),
       y.reshape(S, 1, H)).reshape(T, H)
 

@@ -45,6 +45,7 @@ from ..resilience.fault_plan import (GUARDIAN_EXIT_CODE, STALL_EXIT_CODE,
                                      parse_elastic_env)
 from ..resilience.guardian import build_guardian, pack_anomaly_word
 from ..utils.logging import log_dist, logger
+from ..utils.scope import scoped
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER,
                            NoopTimer, SynchronizedWallClockTimer, ThroughputTimer)
 from .config import DeepSpeedConfig
@@ -1071,6 +1072,7 @@ class DeepSpeedEngine:
         return self._apply_from_grads(state, state["grad_acc"], lr,
                                       spike_thresh=spike_thresh)
 
+    @scoped("optimizer")
     def _apply_from_grads(self, state, grads, lr, spike_thresh=None,
                           loss=None):
         """The apply boundary with the gradient source explicit: the split
@@ -2038,6 +2040,14 @@ class DeepSpeedEngine:
         dispatch is accounted to the step timer."""
         topo_mod.set_topology(self.topology)
         self._build_fused_jit()
+        # the profiler's step marker: a trace can be cut by step, and the
+        # program's spans below lie inside it
+        with jax.profiler.StepTraceAnnotation("train_step",
+                                              step_num=self.global_steps):
+            return self._fused_step(batch)
+
+    def _fused_step(self, batch) -> jax.Array:
+        """The body of ``_train_batch_fused``, inside its step marker."""
         # prepare BEFORE the timer AND the telemetry step span: a rejected
         # batch must not leave the step timer running — or the watchdog
         # armed — into the next call (same rule as forward())
@@ -2072,7 +2082,9 @@ class DeepSpeedEngine:
                         self.state, batch, lr)
         self._cached_loss = loss
         self.micro_steps += 1
-        self._post_step(overflow, gnorm, anomaly=anomaly, loss=loss)
+        with self.telemetry.phase("post_step", phase="step",
+                                  step=self.global_steps):
+            self._post_step(overflow, gnorm, anomaly=anomaly, loss=loss)
         return loss
 
     # ------------------------------------------------------------------
@@ -2249,7 +2261,9 @@ class DeepSpeedEngine:
                     else:
                         self.state, overflow, gnorm = self._jit_apply_step(
                             self.state, lr)
-        self._post_step(overflow, gnorm, anomaly=anomaly)
+        with self.telemetry.phase("post_step", phase="step",
+                                  step=self.global_steps):
+            self._post_step(overflow, gnorm, anomaly=anomaly)
 
     def _post_step(self, overflow, gnorm, anomaly=None, loss=None) -> None:
         """Host-side bookkeeping after the optimizer update (shared by the

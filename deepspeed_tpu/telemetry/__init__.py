@@ -7,10 +7,11 @@ step percentiles, tokens/sec, MFU, goodput, overlap efficiency
 (metrics.py), memory watermarks + compiled-HLO analysis (memory.py), and
 a stall watchdog (watchdog.py), behind the facade in telemetry.py.
 
-Hard contract: **zero overhead when off** — the disabled path is
-:data:`NULL_TELEMETRY` (constant no-ops) and nothing is ever injected
-into traced code (no host callbacks, no syncs in span hooks); enforced by
-the ``telemetry-hot-path-sync`` lint rule and the ``telemetry-off-parity``
+Hard contract: **a TraceMe per span when off, nothing else** — the disabled
+path is :data:`NULL_TELEMETRY` (nothing recorded; a span is only a
+``jax.profiler.TraceAnnotation``) and nothing is ever injected into traced
+code (no host callbacks, no syncs in span hooks); enforced by the
+``telemetry-hot-path-sync`` lint rule and the ``telemetry-off-parity``
 Layer-B audit. See docs/OBSERVABILITY.md.
 """
 

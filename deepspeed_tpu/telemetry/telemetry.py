@@ -6,11 +6,14 @@ API, and fans derived metrics out to *sinks* — ``MonitorMaster``
 (TensorBoard/W&B/CSV) is one sink among several; a JSONL sink writes the
 same events for offline tooling (``tools/trace_view.py``).
 
-The zero-overhead-when-off contract lives here: a disabled engine holds
-:data:`NULL_TELEMETRY`, whose every hook is a constant no-op — no
-buffers, no locks, no threads, and (enforced by lint + the Layer-B
-``telemetry-off-parity`` audit) nothing injected into traced step code.
-Telemetry is HOST-side either way; enabling it must never change a jaxpr.
+The off contract lives here: a disabled engine holds
+:data:`NULL_TELEMETRY`, which records nothing — no buffers, no locks, no
+threads, and (enforced by lint + the Layer-B ``telemetry-off-parity``
+audit) nothing injected into traced step code. What it keeps is one
+``jax.profiler.TraceAnnotation`` per span (a flag test while no profiler
+session runs), so a profiler trace of a run with telemetry off still shows
+the program's spans beside the device's work. Telemetry is HOST-side either
+way; enabling it must never change a jaxpr.
 """
 
 from __future__ import annotations
@@ -18,6 +21,8 @@ from __future__ import annotations
 import os
 import threading
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..utils.logging import log_dist, logger
 from . import clock
@@ -100,8 +105,17 @@ class Telemetry:
 
     # -- spans -----------------------------------------------------------
     def phase(self, name: str, phase: Optional[str] = None,
-              step: Optional[int] = None, **args):
-        return self.trace.span(name, phase=phase or name, step=step, **args)
+              step: Optional[int] = None, req=None, **args):
+        """A recorder span that is also, while it is open as a context
+        manager, a ``TraceAnnotation`` of the same name in the profiler's
+        trace. ``name`` is a static string: what varies goes in ``args``
+        (or ``req``, the uids a serving span works for)."""
+        return self.trace.span(name, phase=phase or name, step=step, req=req,
+                               **args)
+
+    def instant(self, name: str, phase: Optional[str] = None, **args) -> None:
+        """A point event under the calling thread's open span."""
+        self.trace.instant(name, phase=phase or name, **args)
 
     # -- train-step lifecycle -------------------------------------------
     def step_begin(self, step: int) -> None:
@@ -197,17 +211,21 @@ class Telemetry:
     def record_wave(self, kind: str, tokens: int, duration_s: float,
                     queue_depth: int = 0, running: int = 0,
                     occupancy: float = 0.0, admitted: int = 0,
-                    queue_wait_s: float = 0.0) -> None:
+                    queue_wait_s: float = 0.0,
+                    counters: Optional[Dict[str, Any]] = None) -> None:
         """``duration_s`` is EXECUTE time only (compose + dispatch + fetch
         of this wave); ``queue_wait_s`` is the longest submit->schedule
         wait among the ``admitted`` requests this wave first scheduled —
-        kept separate so deep queues cannot masquerade as slow forwards."""
+        kept separate so deep queues cannot masquerade as slow forwards.
+        ``counters`` is what the engine counted in the wave's dispatches
+        (real against bucketed sizes, attention work, bucket keys)."""
         self.trace.instant(f"wave:{kind}", phase=PHASE_SERVING,
                            tokens=tokens, queue_depth=queue_depth,
                            running=running, occupancy=round(occupancy, 4),
                            dur_ms=round(duration_s * 1e3, 3),
                            admitted=admitted,
-                           queue_wait_ms=round(queue_wait_s * 1e3, 3))
+                           queue_wait_ms=round(queue_wait_s * 1e3, 3),
+                           **({"counters": counters} if counters else {}))
         self.metrics.wave_latency.record(duration_s)
         if tokens > 0:
             self.metrics.token_latency.record(duration_s / tokens)
@@ -327,15 +345,21 @@ class Telemetry:
 
 
 class NullTelemetry:
-    """The disabled path: every hook is a constant no-op. No state, no
-    threads, no syncs — and nothing for traced code to capture."""
+    """The disabled path: nothing is recorded, no state, no threads, no
+    syncs, nothing for traced code to capture. A span is still a
+    ``TraceAnnotation`` (a TraceMe: a flag test while no profiler session
+    runs), so a profiler trace shows the program's spans with telemetry
+    off; every other hook is a constant no-op."""
 
     enabled = False
     watchdog = None
     memory = None
 
-    def phase(self, name, phase=None, step=None, **args):
-        return NULL_SPAN
+    def phase(self, name, phase=None, step=None, req=None, **args):
+        return TraceAnnotation(name)
+
+    def instant(self, name, phase=None, **args):
+        pass
 
     def checkpoint_span(self, name="checkpoint", **args):
         return NULL_SPAN

@@ -14,6 +14,17 @@ Exports: Chrome-trace JSON (``chrome://tracing`` / Perfetto — spans as
 tracks) and JSONL (one record per line; ``tools/trace_view.py``
 summarizes it).
 
+One clock with the profiler: a span used as a context manager is also a
+``jax.profiler.TraceAnnotation`` of the same (static) name, so while a
+profiler session runs the program's spans lie in the profiler's own trace
+beside the device's operations, and an idle gap can be charged to what the
+host was doing. Arguments stay with the recorder: a TraceMe name with
+``#k=v#`` in it would be a thousand names.
+
+Every span has an ``id`` and the ``parent`` it was opened under (the top of
+the opening thread's stack), and serving spans carry ``req``, the uids of
+the requests in the wave; both exports keep all three.
+
 Thread safety: spans may begin/end on any thread (async checkpoint writes
 record their spans from the worker); the recorder keeps a per-thread span
 stack under one lock. The watchdog reads a *snapshot* of the live stacks
@@ -22,10 +33,13 @@ when it fires, so a stalled step dumps exactly which phase it is stuck in.
 
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from . import clock
 
@@ -56,16 +70,22 @@ class Span:
     """One open interval. Closed via the context-manager protocol or
     :meth:`TraceRecorder.end`."""
 
-    __slots__ = ("name", "phase", "t0", "t1", "step", "args", "_rec", "_tid")
+    __slots__ = ("name", "phase", "t0", "t1", "step", "args", "id", "parent",
+                 "req", "_rec", "_tid", "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, phase: str,
-                 step: Optional[int], args: Optional[Dict[str, Any]]):
+                 step: Optional[int], args: Optional[Dict[str, Any]],
+                 req: Optional[List[int]] = None):
         self._rec = rec
         self._tid = threading.get_ident()
+        self._ann = None
         self.name = name
         self.phase = phase
         self.step = step
         self.args = args
+        self.req = req
+        self.id = 0              # set by the recorder, under its lock
+        self.parent: Optional[int] = None
         self.t0 = clock.now()
         self.t1 = 0.0
 
@@ -74,9 +94,12 @@ class Span:
         return (self.t1 or clock.now()) - self.t0
 
     def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
         self._rec.end(self)
 
 
@@ -102,16 +125,27 @@ class TraceRecorder:
         self._events: deque = deque(maxlen=max(max_events, 1))
         self.dropped = 0
         self._epoch = clock.now()
-        # live span stacks by thread id — the watchdog's dump source
+        self._ids = itertools.count(1)
+        # live span stacks by thread id — the watchdog's dump source, and
+        # where a new span or instant finds its parent
         self._active: Dict[int, List[Span]] = {}
 
     # -- recording -------------------------------------------------------
     def span(self, name: str, phase: str = PHASE_OTHER,
-             step: Optional[int] = None, **args) -> Span:
-        s = Span(self, name, phase, step, args or None)
+             step: Optional[int] = None, req: Optional[List[int]] = None,
+             **args) -> Span:
+        s = Span(self, name, phase, step, args or None, req)
         with self._lock:
-            self._active.setdefault(s._tid, []).append(s)
+            stack = self._active.setdefault(s._tid, [])
+            s.id = next(self._ids)
+            s.parent = stack[-1].id if stack else None
+            stack.append(s)
         return s
+
+    def _parent_here(self) -> Optional[int]:
+        """The innermost open span of the calling thread (lock held)."""
+        stack = self._active.get(threading.get_ident())
+        return stack[-1].id if stack else None
 
     def end(self, span: Span) -> None:
         span.t1 = clock.now()
@@ -125,6 +159,8 @@ class TraceRecorder:
                 "kind": "span", "name": span.name, "phase": span.phase,
                 "ts": span.t0 - self._epoch, "dur": span.t1 - span.t0,
                 "step": span.step, "tid": span._tid,
+                "id": span.id, "parent": span.parent,
+                **({"req": list(span.req)} if span.req is not None else {}),
                 **({"args": span.args} if span.args else {}),
             })
 
@@ -140,6 +176,7 @@ class TraceRecorder:
                 "kind": "span", "name": name, "phase": phase,
                 "ts": max(0.0, t - self._epoch - dur), "dur": float(dur),
                 "step": step, "tid": threading.get_ident(),
+                "id": next(self._ids), "parent": self._parent_here(),
                 **({"args": args} if args else {}),
             })
 
@@ -148,6 +185,7 @@ class TraceRecorder:
         with self._lock:
             self._push({"kind": "instant", "name": name, "phase": phase,
                         "ts": clock.now() - self._epoch, "step": step,
+                        "parent": self._parent_here(),
                         **({"args": args} if args else {})})
 
     def comm(self, op: str, nbytes: int, axes, overlapped: Optional[bool],
@@ -211,7 +249,10 @@ class TraceRecorder:
                             "cat": rec["phase"], "dur": rec["dur"] * 1e6,
                             "tid": rec["tid"] % (1 << 31),
                             "args": {**rec.get("args", {}),
-                                     "step": rec.get("step")}})
+                                     "step": rec.get("step"),
+                                     "id": rec["id"], "parent": rec["parent"],
+                                     **({"req": rec["req"]}
+                                        if "req" in rec else {})}})
             elif rec["kind"] == "instant":
                 out.append({**base, "ph": "i", "s": "t", "tid": 0,
                             "name": rec["name"], "cat": rec["phase"],

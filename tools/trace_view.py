@@ -9,6 +9,10 @@ deepspeed_tpu/telemetry/trace.py for the schema) and prints:
 
 - top spans by total time (count, total/mean/p50/p95 ms) grouped by name,
 - per-phase time breakdown,
+- the span tree: spans grouped by their path of parents (``id`` /
+  ``parent``, since PR 24) with total and self time, and for serving the
+  number of distinct requests (``req``) under each path; a JSONL from before
+  those fields prints no tree,
 - comm overlap: overlapped/exposed traced bytes and the overlap fraction
   (the ``record_collective`` schedule-class split, docs/ZERO_OVERLAP.md),
 - the last flushed derived metrics (MFU, goodput, tokens/sec, step
@@ -55,15 +59,63 @@ def load(path):
     return records
 
 
+def span_tree(spans, top=15):
+    """Lines of the span tree: spans grouped by the names along their
+    chain of parents. Self time is a group's total less its children's."""
+    by_id = {s["id"]: s for s in spans if "id" in s}
+    if not by_id:
+        return []
+
+    def path(s):
+        names = [s["name"]]
+        while s.get("parent") in by_id:
+            s = by_id[s["parent"]]
+            names.append(s["name"])
+        return tuple(reversed(names))
+
+    total = defaultdict(float)
+    count = defaultdict(int)
+    reqs = defaultdict(set)
+    for s in by_id.values():
+        p = path(s)
+        total[p] += s["dur"]
+        count[p] += 1
+        reqs[p].update(s.get("req") or ())
+    child_total = defaultdict(float)
+    for p, t in total.items():
+        if len(p) > 1:
+            child_total[p[:-1]] += t
+    roots = sorted((p for p in total if len(p) == 1),
+                   key=lambda p: -total[p])[:top]
+    lines = [f"{'span tree':<36}{'count':>7}{'total ms':>12}{'self ms':>11}"
+             f"{'requests':>10}"]
+
+    def emit(p):
+        label = "  " * (len(p) - 1) + p[-1]
+        lines.append(f"{label:<36}{count[p]:>7}{total[p] * 1e3:>12.2f}"
+                     f"{(total[p] - child_total[p]) * 1e3:>11.2f}"
+                     f"{len(reqs[p]) or '':>10}")
+        for c in sorted((q for q in total if q[:-1] == p),
+                        key=lambda q: -total[q]):
+            emit(c)
+
+    for r in roots:
+        emit(r)
+    return lines + [""]
+
+
 def summarize(records, top=15, phase=None):
     spans = [r for r in records if r.get("kind") == "span"]
     if phase:
         spans = [s for s in spans if s.get("phase") == phase]
     by_name = defaultdict(list)
     by_phase = defaultdict(float)
+    phase_of = {s["id"]: s.get("phase") for s in spans if "id" in s}
     for s in spans:
         by_name[s["name"]].append(s["dur"])
-        by_phase[s.get("phase", "other")] += s["dur"]
+        # a child in its parent's phase is time the parent already counts
+        if phase_of.get(s.get("parent")) != s.get("phase", "other"):
+            by_phase[s.get("phase", "other")] += s["dur"]
 
     lines = []
     if by_name:
@@ -77,6 +129,7 @@ def summarize(records, top=15, phase=None):
                          f"{_pct(sd, 50) * 1e3:>10.2f}"
                          f"{_pct(sd, 95) * 1e3:>10.2f}")
         lines.append("")
+        lines += span_tree(spans, top)
         total = sum(by_phase.values())
         lines.append("phase breakdown:")
         for ph, t in sorted(by_phase.items(), key=lambda kv: -kv[1]):

@@ -2,10 +2,11 @@
 
 The training-attention slot of the reference's kernel stack
 (``csrc/transformer/softmax_kernels.cu`` + inference ``blocked_flash``). On
-TPU the long-sequence hot path is the in-repo Pallas flash-attention kernel
-pair (``pallas_flash.py`` — MXU-tiled, fp32 accumulation, blockwise fwd AND
-bwd); off-TPU (CPU test meshes) we fall back to a pure-XLA implementation
-with identical semantics so tests validate numerics everywhere.
+TPU the hot path from sequence 384 up is the in-repo Pallas flash-attention
+kernel pair (``pallas_flash.py`` — MXU-tiled, fp32 accumulation, blockwise
+fwd AND bwd, tiles chosen from the shape); shorter sequences, and every
+shape off-TPU (CPU test meshes), take a pure-XLA implementation with
+identical semantics so tests validate numerics everywhere.
 """
 
 from __future__ import annotations
@@ -58,10 +59,9 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
     grouped-query models never materialize a repeated KV.
 
     Layout: inputs transpose to [B, H, S, D] up front so both einsums are
-    plain batch matmuls over contiguous minor dims. Measured end-to-end on
-    the gpt2-125m train bench (v5e, interleaved A/B runs): +11% step
-    throughput over contracting directly in the model's [B, S, H, D]
-    layout, where XLA schedules the head-middle contraction worse.
+    plain batch matmuls over contiguous minor dims (XLA schedules the
+    head-middle contraction of the model's [B, S, H, D] layout worse; the
+    size of that effect is not measured on the current machine).
     """
     B, Sq, H, D = q.shape
     kvH = k.shape[2]
@@ -116,11 +116,11 @@ def _xla_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
     Identical math to :func:`_xla_attention`, but a ``lax.scan`` over
     query chunks bounds the materialized scores to [B, H, chunk, S_k]
     instead of [B, H, S, S] — the buffer that makes plain XLA a compile
-    OOM at seq >= 4096 full depth. Keeps XLA's fused-matmul attention
-    speed (measured +24% over the Pallas flash kernel at 2k, r4), paying
-    masked-out key flops instead of kernel inefficiency: measured 4k/8k
-    full-depth (tools/longseq_ab.py r5), chunked-XLA beats both the
-    stock flash kernel and splash at micro-batch 1.
+    OOM at seq >= 4096 full depth. The escape hatch (``DSTPU_ATTN=xla``),
+    not the fast path: on the v5e one layer forward + backward at 4096
+    took 8.9-18.5 ms here against 2.4-4.7 ms in the in-repo kernel (PR 25,
+    docs/KERNELS.md); earlier notes that XLA beat the kernel at 2k were
+    the 128 x 128 tiles' doing (5.8 ms against XLA's 3.3 at 1024).
     """
     B, Sq, H, D = q.shape
     # Auto-size the chunk so the per-chunk fp32 score transient
@@ -204,22 +204,50 @@ def attn_mode() -> str:
     return mode
 
 
-# At and above this query length the flash kernel is the DEFAULT: the
-# XLA path materializes [B, H, S, S] fp32 scores (2.1 GiB per unit batch
-# at 4k) next to a full-depth train state. The crossover is not measured
-# on the current machine.
-FLASH_DEFAULT_MIN_SEQ = 4096
+# At and above this query length the XLA route chunks its queries: the
+# one-shot path materializes [B, H, S, S] fp32 scores (2.1 GiB per unit
+# batch at 4k) next to a full-depth train state. A memory bound of the XLA
+# fallback, not a crossover: the kernel's own is `kernel_is_default`.
+XLA_CHUNK_MIN_SEQ = 4096
+
+# The crossover, from the chip (v5e, PR 25; docs/KERNELS.md "On the chip"
+# has the table): forward + backward device time of `_xla_attention`
+# against the in-repo kernel pair at S in {256, 384, 512, 1024, 2048,
+# 4096}, head dim 64 and 128, MHA and 32q/4kv, causal, and bert-style
+# padded 512 and 1024. From 384 up the kernel won every shape (1.3x at 384,
+# 1.7x-2.9x at 512, 3x-5x from 1024; at 4096, 2.9x-4.1x against the chunked
+# route). At 256 XLA won 20 heads x 64 by 2.3x (one S x S tile is 256 KB a
+# head: XLA's fusions are not HBM-bound there, and the kernel's one grid
+# step a head is mostly overhead) and 32q/4kv x 64 by 1 %, and lost
+# 16 heads x 128 by 3 %: head dim 128 doubles what XLA's score tensor costs
+# a FLOP.
+FLASH_MIN_SEQ = 384
+FLASH_MIN_SEQ_WIDE_HEAD = 256    # head dim >= 128
+
+
+def kernel_is_default(q_shape, k_shape, backend: str) -> bool:
+    """Whether ``flash_attention`` takes the in-repo blockwise kernel for
+    this call when nothing forces a route: a rule of shape and platform
+    alone. Off the TPU the XLA path stays (tier-1 dispatch and the
+    ``analysis/`` HLO artifacts are the CPU's)."""
+    if backend != "tpu":
+        return False
+    from . import pallas_flash as _pf
+    min_seq = FLASH_MIN_SEQ_WIDE_HEAD if q_shape[3] >= 128 else FLASH_MIN_SEQ
+    return (q_shape[1] >= min_seq
+            and _pf.supports(q_shape, k_shape, compiled=True))
 
 
 def _pallas_flash_available(seq_len: int = 0) -> bool:
-    """DSTPU_PALLAS_FLASH=1 forces the kernel ON, =0 forces it OFF; unset,
-    it auto-enables at seq >= FLASH_DEFAULT_MIN_SEQ. Below that, XLA stays
-    the hot path. The env read stays live so toggling mid-process works
-    (per-trace: jitted callers keep the path they traced with)."""
+    """The legacy stock-kernel knob: DSTPU_PALLAS_FLASH=1 forces the stock
+    JAX kernels ON, =0 forces them OFF; unset, they are reachable only at
+    seq >= XLA_CHUNK_MIN_SEQ with DSTPU_LONGSEQ_ATTN steering away from
+    the chunked route. The env read stays live so toggling mid-process
+    works (per-trace: jitted callers keep the path they traced with)."""
     flag = os.environ.get("DSTPU_PALLAS_FLASH", "")
     if flag == "0":
         return False
-    if flag != "1" and seq_len < FLASH_DEFAULT_MIN_SEQ:
+    if flag != "1" and seq_len < XLA_CHUNK_MIN_SEQ:
         return False
     return jax.default_backend() != "cpu"
 
@@ -278,42 +306,48 @@ def flash_attention(q: jax.Array,
                     window: Optional[jax.Array] = None) -> jax.Array:
     """Multi-head attention, [B, S, H, D] layout, GQA-aware.
 
-    Long sequences (>= FLASH_DEFAULT_MIN_SEQ on TPU) dispatch to the
-    IN-REPO Pallas flash kernel pair (pallas_flash.py: blockwise forward
-    and backward, GQA-native, full feature matrix — causal, sliding
-    window, segment ids, ALiBi, q_offset); ``DSTPU_ATTN=xla`` is the
-    escape hatch back to query-chunked XLA and ``DSTPU_ATTN=pallas``
-    forces the kernel at any length (interpret mode off-TPU). Short
-    sequences keep the one-shot XLA path (measured faster at <= 2k). The
-    legacy stock/splash-kernel knobs remain honored — see
-    docs/LONG_CONTEXT.md for the full decision table.
+    On a TPU, every shape where the chip showed it faster
+    (`kernel_is_default`) dispatches to the IN-REPO Pallas flash kernel
+    pair (pallas_flash.py: blockwise forward and one fused backward,
+    GQA-native, full feature matrix — causal, sliding window, segment
+    ids, ALiBi, q_offset — tiles chosen from the shape);
+    ``DSTPU_ATTN=xla`` is the escape hatch back to XLA (query-chunked at
+    >= XLA_CHUNK_MIN_SEQ) and ``DSTPU_ATTN=pallas`` forces the kernel at
+    any length (interpret mode off-TPU). Shapes under the crossover, and
+    every shape off the TPU, keep the one-shot XLA path. The legacy
+    stock/splash-kernel knobs remain honored — see docs/LONG_CONTEXT.md
+    for the full decision table.
     ``alibi_slopes`` [num_heads] adds the ALiBi positional bias (bloom);
     ``window`` (0 = global) is the causal sliding window.
     """
     head_dim = q.shape[-1]
     # Path selection (docs/LONG_CONTEXT.md). DSTPU_ATTN is the primary
-    # switch: '' (auto) routes long sequences to the IN-REPO Pallas flash
-    # kernel pair (ops/transformer/pallas_flash.py — blockwise fwd+bwd,
-    # full feature matrix: causal/GQA/window/segment-ids/ALiBi/q_offset);
-    # 'xla' is the escape hatch back to the round-5 chunked-XLA path;
-    # 'pallas' forces the in-repo kernel at ANY length (interpret mode on
-    # CPU test meshes). The legacy knobs (DSTPU_LONGSEQ_ATTN,
-    # DSTPU_PALLAS_FLASH) still steer the round-5 routes when set.
+    # switch: '' (auto) follows `kernel_is_default`; 'xla' is the escape
+    # hatch back to XLA; 'pallas' forces the in-repo kernel at ANY length
+    # (interpret mode on CPU test meshes). The legacy knobs
+    # (DSTPU_LONGSEQ_ATTN, DSTPU_PALLAS_FLASH) still steer the round-5
+    # routes when set.
     mode = attn_mode()
+    backend = jax.default_backend()
     if mode != "xla":
         from . import pallas_flash as _pf
-        on_cpu = jax.default_backend() == "cpu"
         force = mode == "pallas"
         # force mode runs the kernel wherever it CAN run (interpret mode
-        # relaxes the 128-wide k-tile requirement to plain divisibility)
-        kernel_ok = _pf.supports(q.shape, k.shape,
-                                 compiled=not (force and on_cpu))
-        auto = (mode == "" and q.shape[1] >= FLASH_DEFAULT_MIN_SEQ
-                and not on_cpu
-                and os.environ.get("DSTPU_LONGSEQ_ATTN") is None
-                and os.environ.get("DSTPU_PALLAS_FLASH", "") != "1")
-        if kernel_ok and (force or auto):
-            _log_path_once("pallas_flash_inrepo")
+        # relaxes the 128-lane tile requirement to plain divisibility)
+        compiled = not (force and backend == "cpu")
+        if force:
+            take = _pf.supports(q.shape, k.shape, compiled=compiled)
+        else:
+            take = (kernel_is_default(q.shape, k.shape, backend)
+                    and os.environ.get("DSTPU_LONGSEQ_ATTN") is None
+                    and os.environ.get("DSTPU_PALLAS_FLASH", "") != "1")
+        if take:
+            tiles = _pf.choose_tiles(q.shape[1], k.shape[1], head_dim,
+                                     q.dtype.itemsize, causal=causal,
+                                     compiled=compiled)
+            _log_path_once(
+                "pallas_flash_inrepo, tiles (block_q x block_k) forward "
+                "%dx%d backward %dx%d" % (tiles.fwd + tiles.bwd))
             return _pf.flash_attention_kernel(
                 q, k, v, causal=causal, scale=scale,
                 segment_ids=segment_ids, alibi_slopes=alibi_slopes,
@@ -326,7 +360,7 @@ def flash_attention(q: jax.Array,
                            f"q={q.shape} k={k.shape} unsupported)")
     # Long-seq XLA fallback (r5, tools/longseq_ab.py): query-chunked XLA —
     # the XLA attention path's speed with bounded score memory.
-    if (q.shape[1] >= FLASH_DEFAULT_MIN_SEQ
+    if (q.shape[1] >= XLA_CHUNK_MIN_SEQ
             and (mode == "xla"
                  or os.environ.get("DSTPU_PALLAS_FLASH", "") != "1")
             and (mode == "xla"

@@ -1,16 +1,25 @@
 """In-repo Pallas TPU flash attention — forward AND backward kernels.
 
-The training-attention slot's long-context fast path. The stock JAX kernels
-this repo previously imported cover only plain causal MHA: the GQA splash
-kernel has no bias/window/segment support, and the stock flash kernel
-repeats K/V up to the query head count. This kernel pair supports the full
-feature matrix the XLA reference path (`attention._xla_attention`) already
-has — causal (bottom-right aligned via ``q_offset``), GQA-NATIVE (K/V stay
-at kv_heads), sliding window (shared ``sliding_window_allowed`` semantics),
-segment ids, ALiBi — with fp32 accumulation and saved row-max/row-sum LSE
-residuals, bound with ``jax.custom_vjp`` so the backward is blockwise too
-(no O(S^2) score re-materialization: backward FLOPs are recomputed per
-tile, memory stays O(S) + the LSE).
+The training-attention slot's fast path on a TPU from sequence 384 up. The
+stock JAX kernels this repo previously imported cover only plain causal
+MHA: the GQA splash kernel has no bias/window/segment support, and the
+stock flash kernel repeats K/V up to the query head count. This kernel pair
+supports the full feature matrix the XLA reference path
+(`attention._xla_attention`) already has — causal (bottom-right aligned via
+``q_offset``), GQA-NATIVE (K/V stay at kv_heads), sliding window (shared
+``sliding_window_allowed`` semantics), segment ids, ALiBi — with fp32
+accumulation and a saved row LSE residual, bound with ``jax.custom_vjp`` so
+the backward is blockwise too (no O(S^2) score re-materialization: backward
+FLOPs are recomputed per tile, memory stays O(S) + the LSE).
+
+Two kernels: ``flash_fwd`` and ONE fused ``flash_bwd`` that recomputes each
+tile's probabilities once and takes dq, dk and dv from them (five matmuls,
+one exp a logit; a dq kernel beside a dk/dv kernel paid seven and two). The
+backward works on TRANSPOSED tiles S^T = K Q^T: per-query statistics (LSE,
+di) are then rows that broadcast over sublanes, so they travel as
+``[.., 1, Sq]`` rows and no ``[.., Sq, 128]`` lane-replicated copy of them
+is ever written to HBM, and dk/dv need no transposed-left matmul. Tiles
+come from the shape (:func:`choose_tiles`).
 
 ``q_offset`` and ``window`` ride scalar prefetch (SMEM), so they may be
 TRACED values — the same compiled kernel serves the main training call
@@ -26,15 +35,17 @@ CPU tier-1 tests validate numerics of the same program the chip runs.
 Layout conventions (GQA-folded, MXU-aligned tiles):
   q  [B, Sq, H, D]   -> [B*kvH, G, Sq, D]
   k,v[B, Sk, kvH, D] -> [B*kvH, Sk, D]
-LSE and the backward's di term are carried lane-broadcast ([..., 128]) in
-kernel-facing buffers — sublane->lane transposes are the expensive shape on
-TPU, lane replication is free.
+  lse, di            -> [B*kvH, G, 1, Sq]  (fp32 rows)
+Inside the forward the running max and sum are lane-replicated
+``[block_q, 128]`` scratch (lane replication is free; the one sublane->lane
+transpose happens once a q block, when the LSE row is stored).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -55,6 +66,18 @@ MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 HALF_MASK = MASK_VALUE * 0.5
 
 
+Tile = Tuple[int, int]  # (block_q, block_k)
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashTiles:
+    """The (block_q, block_k) of the forward and of the backward kernel,
+    and the scoped VMEM they ask the compiler for (None = its default)."""
+    fwd: Tile
+    bwd: Tile
+    vmem_limit_bytes: Optional[int] = None
+
+
 @dataclasses.dataclass(frozen=True)
 class FlashConfig:
     """Static kernel configuration (hashable: rides custom_vjp
@@ -65,8 +88,7 @@ class FlashConfig:
     use_alibi: bool
     use_window: bool
     kv_heads: int
-    block_q: int
-    block_k: int
+    tiles: FlashTiles
     interpret: bool
 
 
@@ -81,13 +103,13 @@ def _lanes(x: jax.Array, n: int) -> jax.Array:
     return jnp.concatenate([x] * (n // NUM_LANES), axis=1)
 
 
-def _should_run(cfg: FlashConfig, i, j, info_ref):
+def _should_run(cfg: FlashConfig, tile: Tile, i, j, info_ref):
     """Whether q-block i has ANY unmasked key in k-block j (block-level
     flop skip). info = [q_offset, window] (traced scalars in SMEM)."""
     if not cfg.causal:
         return True
     q_off = info_ref[0]
-    bq, bk = cfg.block_q, cfg.block_k
+    bq, bk = tile
     # last q row of the block sits at or after the block's first key
     run = (q_off + (i + 1) * bq - 1) >= (j * bk)
     if cfg.use_window:
@@ -97,34 +119,71 @@ def _should_run(cfg: FlashConfig, i, j, info_ref):
     return run
 
 
-def _tile_logits(cfg: FlashConfig, q, k, i, j, info_ref, slopes_ref,
-                 head_idx, qseg, kseg):
-    """Masked, scaled fp32 logits for one (block_q, block_k) tile — ONE
-    definition shared by the forward and both backward kernels so the
-    recomputed tiles cannot diverge from the forward's."""
-    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+def _fully_visible(cfg: FlashConfig, tile: Tile, i, j, info_ref):
+    """Whether EVERY key of k-block j is visible to every row of q-block i
+    under the causal (and window) mask: such a tile needs no positions,
+    compare or select. Only asked of causal configurations."""
+    q_off = info_ref[0]
+    bq, bk = tile
+    # first q row sits at or after the block's last key
+    full = (q_off + i * bq) >= (j * bk + bk - 1)
+    if cfg.use_window:
+        w = info_ref[1]
+        # last q row within window of the block's first key
+        full = full & ((w <= 0) | ((q_off + (i + 1) * bq - 1) - j * bk < w))
+    return full
+
+
+def _for_visible_tile(cfg: FlashConfig, tile: Tile, i, j, info_ref, body):
+    """Run ``body(positional)`` for a tile with any unmasked key; tiles
+    wholly below the diagonal run it without the positional mask."""
+    if not cfg.causal:
+        body(False)
+        return
+    run = _should_run(cfg, tile, i, j, info_ref)
+    full = _fully_visible(cfg, tile, i, j, info_ref)
+    pl.when(run & full)(lambda: body(False))
+    pl.when(run & jnp.logical_not(full))(lambda: body(True))
+
+
+def _tile_logits(cfg: FlashConfig, tile: Tile, q, k, i, j, info_ref,
+                 slopes_ref, head_idx, seg_col, seg_row, *,
+                 positional: bool, transposed: bool = False):
+    """Masked, scaled fp32 logits for one tile — ONE definition shared by
+    the forward and the backward kernel so the recomputed tiles cannot
+    diverge from the forward's. ``transposed`` gives S^T = K Q^T
+    ``[block_k, block_q]`` (the backward's orientation: every per-query
+    statistic is then a ROW, which broadcasts over sublanes for free).
+    ``seg_col`` is the lane-replicated ``[rows, 128]`` segment ids of the
+    tile's row axis, ``seg_row`` the ``[8, cols]`` ids of its column axis.
+    ``positional`` False leaves out the causal/window mask (the caller has
+    shown the tile to be wholly visible)."""
+    lhs, rhs = (k, q) if transposed else (q, k)
+    s = lax.dot_general(lhs, rhs, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
     if cfg.scale != 1.0:
         s = s * cfg.scale
-    bq, bk = s.shape
-    rows = lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + i * cfg.block_q
-    cols = lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * cfg.block_k
-    q_pos = rows + info_ref[0]
-    if cfg.use_alibi:
-        # bias = slope * (key_pos - query_pos), the row-shifted HF-BLOOM
-        # form the XLA path uses (softmax is shift-invariant per row)
-        slope = slopes_ref[head_idx]
-        s = s + slope * (cols - q_pos).astype(jnp.float32)
+    bq, bk = tile
+    q_axis = 1 if transposed else 0
     mask = None
     if cfg.use_seg:
-        # qseg [bq, 128] lane-replicated; kseg [8, bk] sublane-replicated
-        mask = _lanes(qseg, bk) == kseg[:1, :]
-    if cfg.causal:
-        cm = q_pos >= cols
-        if cfg.use_window:
-            w = info_ref[1]
-            cm = cm & ((w <= 0) | ((q_pos - cols) < w))
-        mask = cm if mask is None else mask & cm
+        mask = _lanes(seg_col, s.shape[1]) == seg_row[:1, :]
+    if cfg.use_alibi or (cfg.causal and positional):
+        q_pos = (lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+                 + (i * bq + info_ref[0]))
+        k_pos = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis) + j * bk
+        if cfg.use_alibi:
+            # bias = slope * (key_pos - query_pos), the row-shifted
+            # HF-BLOOM form the XLA path uses (softmax is shift-invariant
+            # per row)
+            slope = slopes_ref[head_idx]
+            s = s + slope * (k_pos - q_pos).astype(jnp.float32)
+        if cfg.causal and positional:
+            cm = q_pos >= k_pos
+            if cfg.use_window:
+                w = info_ref[1]
+                cm = cm & ((w <= 0) | ((q_pos - k_pos) < w))
+            mask = cm if mask is None else mask & cm
     if mask is not None:
         s = jnp.where(mask, s, MASK_VALUE)
     return s
@@ -134,6 +193,12 @@ def _head_index(cfg: FlashConfig, b, g, G):
     """Global query-head index for (folded batch*kv_head, group) — the
     ALiBi slope lookup."""
     return (b % cfg.kv_heads) * G + g
+
+
+def _compiler_params(cfg: FlashConfig, semantics):
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=cfg.tiles.vmem_limit_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -146,6 +211,7 @@ def _fwd_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
                 cfg: FlashConfig, G: int, nk: int, head_dim: int):
     b, g = pl.program_id(0), pl.program_id(1)
     i, j = pl.program_id(2), pl.program_id(3)
+    tile = cfg.tiles.fwd
 
     @pl.when(j == 0)
     def _init():
@@ -153,15 +219,15 @@ def _fwd_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when(_should_run(cfg, i, j, info))
-    def _compute():
+    def _compute(positional):
         q = q_ref[0, 0]          # [bq, D]
         k = k_ref[0]             # [bk, D]
         v = v_ref[0]
         qseg = qseg_ref[0] if cfg.use_seg else None
         kseg = kseg_ref[0] if cfg.use_seg else None
-        s = _tile_logits(cfg, q, k, i, j, info, slopes,
-                         _head_index(cfg, b, g, G), qseg, kseg)
+        s = _tile_logits(cfg, tile, q, k, i, j, info, slopes,
+                         _head_index(cfg, b, g, G), qseg, kseg,
+                         positional=positional)
         m_prev = m_scr[...]
         l_prev = l_scr[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -174,28 +240,34 @@ def _fwd_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
                         + lax.dot(p.astype(v.dtype), v,
                                   preferred_element_type=jnp.float32))
 
+    _for_visible_tile(cfg, tile, i, j, info, _compute)
+
     @pl.when(j == nk - 1)
     def _store():
         l = l_scr[...]
         m_safe = jnp.maximum(m_scr[...], HALF_MASK)
         inv = jnp.where(l == 0.0, 0.0, 1.0 / jnp.where(l == 0.0, 1.0, l))
         o_ref[0, 0] = (acc_scr[...] * _lanes(inv, head_dim)).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(
-            l == 0.0, MASK_VALUE,
-            m_safe + jnp.log(jnp.where(l == 0.0, 1.0, l)))
+        lse = jnp.where(l == 0.0, MASK_VALUE,
+                        m_safe + jnp.log(jnp.where(l == 0.0, 1.0, l)))
+        # the per-row statistic leaves as a ROW [1, bq]: one XLU transpose
+        # per q block here saves the [.., Sq, 128] lane-replicated copy in
+        # HBM that the backward would otherwise read back
+        lse_ref[0, 0] = lse.T[:1]
 
 
-def _fwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info):
+def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
+    """-> o [BK, G, Sq, D], lse [BK, G, 1, Sq] (fp32 rows)."""
     BK, G, Sq, D = q.shape
     Sk = k.shape[1]
-    bq, bk = cfg.block_q, cfg.block_k
+    tile = bq, bk = cfg.tiles.fwd
     nq, nk = Sq // bq, Sk // bk
     grid = (BK, G, nq, nk)
     kvH = cfg.kv_heads
 
     def kv_idx(b, g, i, j, info, slopes):
         if cfg.causal:
-            j = lax.select(_should_run(cfg, i, j, info), j, 0)
+            j = lax.select(_should_run(cfg, tile, i, j, info), j, 0)
         return (b, j, 0)
 
     in_specs = [
@@ -209,7 +281,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info):
 
         def kseg_idx(b, g, i, j, info, slopes):
             if cfg.causal:
-                j = lax.select(_should_run(cfg, i, j, info), j, 0)
+                j = lax.select(_should_run(cfg, tile, i, j, info), j, 0)
             return (b // kvH, 0, j)
         in_specs.append(pl.BlockSpec((1, NUM_SUBLANES, bk), kseg_idx))
     else:
@@ -217,15 +289,14 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info):
 
     out_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, g, i, j, *_: (b, g, i, 0)),
-        pl.BlockSpec((1, 1, bq, NUM_LANES),
-                     lambda b, g, i, j, *_: (b, g, i, 0)),
+        pl.BlockSpec((1, 1, 1, bq), lambda b, g, i, j, *_: (b, g, 0, i)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((BK, G, Sq, D), q.dtype),
-        jax.ShapeDtypeStruct((BK, G, Sq, NUM_LANES), jnp.float32),
+        jax.ShapeDtypeStruct((BK, G, 1, Sq), jnp.float32),
     ]
     kernel = functools.partial(_fwd_kernel, cfg=cfg, G=G, nk=nk, head_dim=D)
-    o, lse = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -238,13 +309,11 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info):
                 pltpu.VMEM((bq, D), jnp.float32),
             ]),
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=_compiler_params(
+            cfg, ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
         name="flash_fwd",
-    )(info, slopes, q, k, v, qseg_b, kseg_b)
-    return o, lse[..., 0]
+    )(info, slopes, q, k, v, qseg_c, kseg_r)
 
 
 # ---------------------------------------------------------------------------
@@ -252,84 +321,56 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info):
 # ---------------------------------------------------------------------------
 
 
-def _masked_p(cfg, s, lse_b):
-    """exp(s - lse) with the empty-row guard: rows whose LSE is the
-    MASK_VALUE sentinel (no unmasked key anywhere) contribute exactly 0."""
-    p = jnp.exp(s - lse_b)
-    return jnp.where(lse_b > HALF_MASK, p, 0.0)
-
-
-def _dq_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
-               do_ref, lse_ref, di_ref, dq_ref, dq_scr, *,
-               cfg: FlashConfig, G: int, nk: int):
-    b, g = pl.program_id(0), pl.program_id(1)
-    i, j = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
-
-    @pl.when(_should_run(cfg, i, j, info))
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0, 0]
-        qseg = qseg_ref[0] if cfg.use_seg else None
-        kseg = kseg_ref[0] if cfg.use_seg else None
-        s = _tile_logits(cfg, q, k, i, j, info, slopes,
-                         _head_index(cfg, b, g, G), qseg, kseg)
-        bk = s.shape[1]
-        p = _masked_p(cfg, s, _lanes(lse_ref[0, 0], bk))
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - _lanes(di_ref[0, 0], bk))
-        if cfg.scale != 1.0:
-            ds = ds * cfg.scale
-        dq_scr[...] += lax.dot(ds.astype(k.dtype), k,
-                               preferred_element_type=jnp.float32)
-
-    @pl.when(j == nk - 1)
-    def _store():
-        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
-                do_ref, lse_ref, di_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                cfg: FlashConfig, G: int, nq: int):
+def _bwd_kernel(info, slopes, q_ref, k_ref, v_ref, kseg_ref, qseg_ref,
+                do_ref, lse_ref, di_ref, dq_ref, dk_ref, dv_ref,
+                dk_scr, dv_scr, *, cfg: FlashConfig, G: int, nq: int):
+    """dq, dk and dv of one (k-block, q-block) tile from ONE recomputed
+    P^T = exp(K Q^T - lse): five matmuls, none with a transposed left
+    operand except dq's (one XLU transpose of dS^T). dk/dv accumulate in
+    scratch over the groups and q-blocks of their k-block; dq leaves per
+    k-block and the caller sums the k-blocks."""
     b = pl.program_id(0)
     j, g, i = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    tile = cfg.tiles.bwd
 
     @pl.when((g == 0) & (i == 0))
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    @pl.when(_should_run(cfg, i, j, info))
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0]
+    if cfg.causal:
+        @pl.when(jnp.logical_not(_should_run(cfg, tile, i, j, info)))
+        def _skipped():
+            dq_ref[0, 0, 0] = jnp.zeros(dq_ref.shape[3:], dq_ref.dtype)
+
+    def _compute(positional):
+        q = q_ref[0, 0]          # [bq, D]
+        k = k_ref[0]             # [bk, D]
         v = v_ref[0]
         do = do_ref[0, 0]
-        qseg = qseg_ref[0] if cfg.use_seg else None
         kseg = kseg_ref[0] if cfg.use_seg else None
-        s = _tile_logits(cfg, q, k, i, j, info, slopes,
-                         _head_index(cfg, b, g, G), qseg, kseg)
-        bk = s.shape[1]
-        p = _masked_p(cfg, s, _lanes(lse_ref[0, 0], bk))
-        # dv += P^T @ dO   (contract the q rows)
-        dv_scr[...] += lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - _lanes(di_ref[0, 0], bk))
+        qseg = qseg_ref[0] if cfg.use_seg else None
+        st = _tile_logits(cfg, tile, q, k, i, j, info, slopes,
+                          _head_index(cfg, b, g, G), kseg, qseg,
+                          positional=positional, transposed=True)
+        lse = lse_ref[0, 0]      # [1, bq]
+        # rows whose LSE is the MASK_VALUE sentinel (no unmasked key
+        # anywhere) contribute exactly 0
+        pt = jnp.where(lse > HALF_MASK, jnp.exp(st - lse), 0.0)
+        dv_scr[...] += lax.dot(pt.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dpt = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        dst = pt * (dpt - di_ref[0, 0])
         if cfg.scale != 1.0:
-            ds = ds * cfg.scale
-        # dk += dS^T @ q
-        dk_scr[...] += lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            dst = dst * cfg.scale
+        dk_scr[...] += lax.dot(dst.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)
+        dq_ref[0, 0, 0] = lax.dot(dst.T.astype(k.dtype), k,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(dq_ref.dtype)
+
+    _for_visible_tile(cfg, tile, i, j, info, _compute)
 
     @pl.when((g == G - 1) & (i == nq - 1))
     def _store():
@@ -337,114 +378,79 @@ def _dkv_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _bwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info,
+def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
               o, lse, do, dlse):
     BK, G, Sq, D = q.shape
     Sk = k.shape[1]
-    bq, bk = cfg.block_q, cfg.block_k
+    tile = bq, bk = cfg.tiles.bwd
     nq, nk = Sq // bq, Sk // bk
     kvH = cfg.kv_heads
 
     # di = rowsum(dO * O) (the softmax-jacobian diagonal term); a cotangent
     # on the LSE output folds in here: dL/ds = P*(dP - di) + dlse*P
     di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    di = di.reshape(BK, G, 1, Sq)
     if dlse is not None:
         di = di - dlse.astype(jnp.float32)
-    di_b = lax.broadcast_in_dim(di, (BK, G, Sq, NUM_LANES), (0, 1, 2))
-    lse_b = lax.broadcast_in_dim(lse, (BK, G, Sq, NUM_LANES), (0, 1, 2))
 
-    def kv_idx(b, g, i, j, info, slopes):
+    def q_blk(i, j, info):
+        # a skipped tile re-names the block already resident: no DMA
         if cfg.causal:
-            j = lax.select(_should_run(cfg, i, j, info), j, 0)
-        return (b, j, 0)
+            i = lax.select(_should_run(cfg, tile, i, j, info), i, nq - 1)
+        return i
 
-    def q_row_idx(b, g, i, j, *_):
-        return (b, g, i, 0)
+    def q_idx(b, j, g, i, info, slopes):
+        return (b, g, q_blk(i, j, info), 0)
+
+    def q_row_idx(b, j, g, i, info, slopes):
+        return (b, g, 0, q_blk(i, j, info))
+
+    def kv_idx(b, j, g, i, *_):
+        return (b, j, 0)
 
     seg_specs = [None, None]
     if cfg.use_seg:
-        def kseg_idx(b, g, i, j, info, slopes):
-            if cfg.causal:
-                j = lax.select(_should_run(cfg, i, j, info), j, 0)
-            return (b // kvH, 0, j)
         seg_specs = [
-            pl.BlockSpec((1, bq, NUM_LANES),
-                         lambda b, g, i, j, *_: (b // kvH, i, 0)),
-            pl.BlockSpec((1, NUM_SUBLANES, bk), kseg_idx),
+            pl.BlockSpec((1, bk, NUM_LANES),
+                         lambda b, j, g, i, *_: (b // kvH, j, 0)),
+            pl.BlockSpec(
+                (1, NUM_SUBLANES, bq),
+                lambda b, j, g, i, info, slopes: (
+                    b // kvH, 0, q_blk(i, j, info))),
         ]
-    # ---- dq: same grid walk as the forward -------------------------------
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, cfg=cfg, G=G, nk=nk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(BK, G, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, 1, bq, D), q_row_idx),
-                pl.BlockSpec((1, bk, D), kv_idx),
-                pl.BlockSpec((1, bk, D), kv_idx),
-                *seg_specs,
-                pl.BlockSpec((1, 1, bq, D), q_row_idx),
-                pl.BlockSpec((1, 1, bq, NUM_LANES), q_row_idx),
-                pl.BlockSpec((1, 1, bq, NUM_LANES), q_row_idx),
-            ],
-            out_specs=pl.BlockSpec((1, 1, bq, D), q_row_idx),
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((BK, G, Sq, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=cfg.interpret,
-        name="flash_bwd_dq",
-    )(info, slopes, q, k, v, qseg_b, kseg_b, do, lse_b, di_b)
-
-    # ---- dk/dv: k-blocks outer, (group, q-block) accumulated in scratch --
-    def kv_col_idx(b, j, g, i, *_):
-        return (b, j, 0)
-
-    def q_bwd_idx(b, j, g, i, info, slopes):
-        if cfg.causal:
-            i = lax.select(_should_run(cfg, i, j, info), i, nq - 1)
-        return (b, g, i, 0)
-
-    seg_specs2 = [None, None]
-    if cfg.use_seg:
-        def qseg_bwd_idx(b, j, g, i, info, slopes):
-            if cfg.causal:
-                i = lax.select(_should_run(cfg, i, j, info), i, nq - 1)
-            return (b // kvH, i, 0)
-        seg_specs2 = [
-            pl.BlockSpec((1, bq, NUM_LANES), qseg_bwd_idx),
-            pl.BlockSpec((1, NUM_SUBLANES, bk),
-                         lambda b, j, g, i, *_: (b // kvH, 0, j)),
-        ]
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, cfg=cfg, G=G, nq=nq),
+    # one k-block holds every key: its dq IS the answer, in q's dtype
+    dq_dtype = q.dtype if nk == 1 else jnp.float32
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, cfg=cfg, G=G, nq=nq),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(BK, nk, G, nq),
             in_specs=[
-                pl.BlockSpec((1, 1, bq, D), q_bwd_idx),
-                pl.BlockSpec((1, bk, D), kv_col_idx),
-                pl.BlockSpec((1, bk, D), kv_col_idx),
-                *seg_specs2,
-                pl.BlockSpec((1, 1, bq, D), q_bwd_idx),
-                pl.BlockSpec((1, 1, bq, NUM_LANES), q_bwd_idx),
-                pl.BlockSpec((1, 1, bq, NUM_LANES), q_bwd_idx),
+                pl.BlockSpec((1, 1, bq, D), q_idx),
+                pl.BlockSpec((1, bk, D), kv_idx),
+                pl.BlockSpec((1, bk, D), kv_idx),
+                *seg_specs,
+                pl.BlockSpec((1, 1, bq, D), q_idx),
+                pl.BlockSpec((1, 1, 1, bq), q_row_idx),
+                pl.BlockSpec((1, 1, 1, bq), q_row_idx),
             ],
             out_specs=[
-                pl.BlockSpec((1, bk, D), kv_col_idx),
-                pl.BlockSpec((1, bk, D), kv_col_idx),
+                pl.BlockSpec((1, 1, 1, bq, D),
+                             lambda b, j, g, i, *_: (j, b, g, i, 0)),
+                pl.BlockSpec((1, bk, D), kv_idx),
+                pl.BlockSpec((1, bk, D), kv_idx),
             ],
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                             pltpu.VMEM((bk, D), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((BK, Sk, D), k.dtype),
+        out_shape=[jax.ShapeDtypeStruct((nk, BK, G, Sq, D), dq_dtype),
+                   jax.ShapeDtypeStruct((BK, Sk, D), k.dtype),
                    jax.ShapeDtypeStruct((BK, Sk, D), v.dtype)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary",
-                                 "arbitrary")),
+        compiler_params=_compiler_params(
+            cfg, ("parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=cfg.interpret,
-        name="flash_bwd_dkv",
-    )(info, slopes, q, k, v, qseg_b, kseg_b, do, lse_b, di_b)
+        name="flash_bwd",
+    )(info, slopes, q, k, v, kseg_c, qseg_r, do, lse, di)
+    dq = dq[0] if nk == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
     return dq, dk, dv
 
 
@@ -454,22 +460,24 @@ def _bwd_call(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _flash(cfg: FlashConfig, q, k, v, qseg_b, kseg_b, slopes, info):
-    o, lse = _fwd_call(cfg, q, k, v, qseg_b, kseg_b, slopes, info)
-    return o, lse
+def _flash(cfg: FlashConfig, q, k, v, segs, slopes, info):
+    """``segs`` = (q ids as columns, k ids as rows, k ids as columns, q ids
+    as rows) or four Nones: the forward reads the first pair, the backward
+    (transposed tiles) the second."""
+    return _fwd_call(cfg, q, k, v, segs[0], segs[1], slopes, info)
 
 
-def _flash_fwd(cfg, q, k, v, qseg_b, kseg_b, slopes, info):
-    o, lse = _fwd_call(cfg, q, k, v, qseg_b, kseg_b, slopes, info)
-    return (o, lse), (q, k, v, qseg_b, kseg_b, slopes, info, o, lse)
+def _flash_fwd(cfg, q, k, v, segs, slopes, info):
+    o, lse = _fwd_call(cfg, q, k, v, segs[0], segs[1], slopes, info)
+    return (o, lse), (q, k, v, segs, slopes, info, o, lse)
 
 
 def _flash_bwd(cfg, res, cts):
-    q, k, v, qseg_b, kseg_b, slopes, info, o, lse = res
+    q, k, v, segs, slopes, info, o, lse = res
     do, dlse = cts  # a discarded LSE output arrives as a zero array
-    dq, dk, dv = _bwd_call(cfg, q, k, v, qseg_b, kseg_b, slopes, info,
+    dq, dk, dv = _bwd_call(cfg, q, k, v, segs[2], segs[3], slopes, info,
                            o, lse, do, dlse)
-    return dq, dk, dv, None, None, None, None
+    return dq, dk, dv, None, None, None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -484,22 +492,118 @@ def _auto_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def supports(q_shape, k_shape, block_q: int = 128, block_k: int = 128,
-             compiled: bool = True) -> bool:
+# The tile rule, from the chip (v5e, PR 25; docs/KERNELS.md "On the chip"
+# has the sweep). A grid step costs about 0.35 us whatever it computes, so
+# 128 x 128 tiles spend more time stepping than multiplying (5x slower at
+# 1024 x 64, and slower than XLA); past 512 the step count stops mattering
+# and what is left is how much of a causal tile is masked work. Forward,
+# causal: 512 x 512 (skips the tile above the diagonal, leaves the one below
+# it unmasked). Everything else (non-causal forward, every backward):
+# 1024 x 1024, where one k-block holds every key of a 1024 sequence and dq
+# needs no sum over k-blocks. A length the target does not divide takes the
+# largest 128-multiple under it that does.
+FWD_CAUSAL_TILE_TARGET: Tile = (512, 512)
+TILE_TARGET: Tile = (1024, 1024)
+# Scoped VMEM. The budget is the compiler's own default limit, so the
+# chosen tiles need no ``vmem_limit_bytes``; explicit larger tiles get the
+# limit their estimate asks for, up to the cap (v5e/v6e hold 128 MiB).
+VMEM_BUDGET = 16 * 1024 * 1024
+VMEM_CAP = 96 * 1024 * 1024
+
+
+def tile_vmem_bytes(tile: Tile, head_dim: int, itemsize: int, *,
+                    backward: bool) -> int:
+    """Upper estimate of the scoped VMEM one kernel needs: two fp32
+    ``[bq, bk]`` temporaries (Mosaic streams the softmax chain through
+    vregs and keeps about that much: 6.0-8.5 B per tile element with the
+    blocks, by bisecting ``vmem_limit_bytes`` on the v5e compiler at
+    512 x 512 to 1024 x 1024, head dim 64 and 128, masks and segment ids
+    in or out), the double-buffered operand blocks, and the fp32
+    accumulators."""
+    bq, bk = tile
+    d = max(head_dim, NUM_LANES)           # blocks pad to the lane width
+    rows = (3 * bq + 4 * bk) if backward else (2 * bq + 2 * bk)
+    acc = (2 * bk * d if backward else bq * (d + 2 * NUM_LANES)) * 4
+    return 2 * 4 * bq * bk + 2 * itemsize * d * rows + acc
+
+
+def _largest_tile(length: int, cap: int) -> Optional[int]:
+    """Largest 128-multiple <= cap that divides ``length``."""
+    for t in range(min(cap, length) // NUM_LANES * NUM_LANES, 0, -NUM_LANES):
+        if length % t == 0:
+            return t
+    return None
+
+
+def _fit(length: int, target: int, compiled: bool) -> Optional[int]:
+    """The tile of one length: the largest legal one under the target, or
+    the whole of a length under 128 (interpret mode only). A length whose
+    only divisor under the target is the step-bound 128 (640, 896) looks
+    up to ``TILE_TARGET`` instead, where the whole of it is a tile."""
+    t = _largest_tile(length, target)
+    if t == NUM_LANES:
+        t = _largest_tile(length, max(TILE_TARGET))
+    if t is None and length < NUM_LANES and not compiled:
+        t = length
+    return t
+
+
+def choose_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
+                 causal: bool = True, block_q: Optional[int] = None,
+                 block_k: Optional[int] = None,
+                 compiled: bool = True) -> Optional[FlashTiles]:
+    """Tiles of the forward and backward kernels for one call, from what
+    the call can observe: the two lengths, whether it is causal, and (for
+    the VMEM budget) the head dim and the operand size. Head dim 64 or
+    128, MHA or grouped heads and segment ids did not move the winner in
+    the sweep; a sliding window is a traced value the choice cannot see.
+    Explicit ``block_q``/``block_k`` win and apply to both kernels,
+    clamped to the lengths as the old 128-default was. None
+    where no legal tile exists: a length that no 128-multiple divides, or
+    (``compiled``, the TPU path) a tile off the 128-lane layout; interpret
+    mode accepts any length under 128 whole."""
+    def pick(target: Tile, backward: bool) -> Optional[Tile]:
+        bq = min(block_q, sq) if block_q else _fit(sq, target[0], compiled)
+        bk = min(block_k, sk) if block_k else _fit(sk, target[1], compiled)
+        if not bq or not bk or sq % bq or sk % bk:
+            return None
+        if compiled and (bq % NUM_LANES or bk % NUM_LANES):
+            return None
+        # step what was not asked for down to the next legal tile until
+        # the kernel fits the budget
+        while tile_vmem_bytes((bq, bk), head_dim, itemsize,
+                              backward=backward) > VMEM_BUDGET:
+            if not block_q and bq > NUM_LANES and (bq >= bk or block_k):
+                bq = _largest_tile(sq, bq - NUM_LANES)
+            elif not block_k and bk > NUM_LANES:
+                bk = _largest_tile(sk, bk - NUM_LANES)
+            else:
+                break
+        return bq, bk
+
+    fwd = pick(FWD_CAUSAL_TILE_TARGET if causal else TILE_TARGET, False)
+    bwd = pick(TILE_TARGET, True)
+    if fwd is None or bwd is None:
+        return None
+    need = max(tile_vmem_bytes(fwd, head_dim, itemsize, backward=False),
+               tile_vmem_bytes(bwd, head_dim, itemsize, backward=True))
+    return FlashTiles(fwd, bwd,
+                      None if need <= VMEM_BUDGET else min(need, VMEM_CAP))
+
+
+def supports(q_shape, k_shape, block_q: Optional[int] = None,
+             block_k: Optional[int] = None, compiled: bool = True) -> bool:
     """Shape gate. ``compiled=True`` (the TPU path) additionally requires
-    MXU-aligned k-tiles (128-multiple key length); ``compiled=False`` (the
-    interpret path driven on CPU test meshes) accepts anything the clamped
-    blocks divide evenly."""
+    tiles on the 128-lane layout; ``compiled=False`` (the interpret path
+    driven on CPU test meshes) accepts anything the tiles divide evenly."""
     B, Sq, H, D = q_shape
     Sk, kvH = k_shape[1], k_shape[2]
     if H % kvH:
         return False
     if D > NUM_LANES and D % NUM_LANES:
         return False
-    bq, bk = min(block_q, Sq), min(block_k, Sk)
-    if Sq % bq or Sk % bk:
-        return False
-    return bk % NUM_LANES == 0 or not compiled
+    return choose_tiles(Sq, Sk, D, block_q=block_q, block_k=block_k,
+                        compiled=compiled) is not None
 
 
 def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
@@ -509,33 +613,46 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
     if H % kvH:
         raise ValueError(f"query heads {H} not a multiple of kv heads {kvH}")
     G = H // kvH
-    bq, bk = min(block_q, Sq), min(block_k, Sk)
-    if Sq % bq or Sk % bk:
-        raise ValueError(f"seq lengths ({Sq}, {Sk}) not divisible by "
-                         f"blocks ({bq}, {bk})")
     if window is not None and not causal:
         raise ValueError("sliding window is causal-only")
-    scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
     interp = _auto_interpret() if interpret is None else interpret
-    cfg = FlashConfig(
-        causal=bool(causal), scale=scale,
-        use_seg=segment_ids is not None,
-        use_alibi=alibi_slopes is not None,
-        use_window=window is not None,
-        kv_heads=kvH, block_q=bq, block_k=bk, interpret=bool(interp))
+    tiles = choose_tiles(Sq, Sk, D, q.dtype.itemsize, causal=bool(causal),
+                         block_q=block_q, block_k=block_k,
+                         compiled=not interp)
+    if tiles is None:
+        raise ValueError(f"seq lengths ({Sq}, {Sk}) have no legal tiles "
+                         f"(block_q={block_q}, block_k={block_k})")
+    scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
 
     # GQA-folded layout
     q4 = q.transpose(0, 2, 1, 3).reshape(B * kvH, G, Sq, D)
     k3 = k.transpose(0, 2, 1, 3).reshape(B * kvH, Sk, D)
     v3 = v.transpose(0, 2, 1, 3).reshape(B * kvH, Sk, D)
+    if math.frexp(scale)[0] == 0.5:
+        # a power of two scales q EXACTLY in any float type, so the same
+        # logits come out of the matmul already scaled and the kernels skip
+        # one multiply per logit (XLA fuses this one into the transpose)
+        q4, scale = q4 * jnp.asarray(scale, q4.dtype), 1.0
+    cfg = FlashConfig(
+        causal=bool(causal), scale=scale,
+        use_seg=segment_ids is not None,
+        use_alibi=alibi_slopes is not None,
+        use_window=window is not None,
+        kv_heads=kvH, tiles=tiles, interpret=bool(interp))
 
-    qseg_b = kseg_b = None
+    segs = (None, None, None, None)
     if segment_ids is not None:
-        qseg = q_segment_ids if q_segment_ids is not None else segment_ids
-        qseg_b = lax.broadcast_in_dim(
-            qseg.astype(jnp.int32), (B, Sq, NUM_LANES), (0, 1))
-        kseg_b = lax.broadcast_in_dim(
-            segment_ids.astype(jnp.int32), (B, NUM_SUBLANES, Sk), (0, 2))
+        qseg = (q_segment_ids if q_segment_ids is not None
+                else segment_ids).astype(jnp.int32)
+        kseg = segment_ids.astype(jnp.int32)
+
+        def cols(ids, n):   # lane-replicated: the ids of a tile's row axis
+            return lax.broadcast_in_dim(ids, (B, n, NUM_LANES), (0, 1))
+
+        def rows(ids, n):   # sublane-replicated: the ids of its column axis
+            return lax.broadcast_in_dim(ids, (B, NUM_SUBLANES, n), (0, 2))
+        segs = (cols(qseg, Sq), rows(kseg, Sk), cols(kseg, Sk),
+                rows(qseg, Sq))
     if alibi_slopes is not None:
         # ALiBi slopes are a positional SCHEDULE (the fixed geometric
         # sequence of Press et al. — explicitly not learned), so the
@@ -554,7 +671,7 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         jnp.asarray(window if window is not None else 0,
                     jnp.int32).reshape(()),
     ])
-    return cfg, q4, k3, v3, qseg_b, kseg_b, slopes, info, (B, H, kvH, G)
+    return cfg, q4, k3, v3, segs, slopes, info, (B, H, kvH, G)
 
 
 def flash_attention_with_lse(
@@ -564,22 +681,23 @@ def flash_attention_with_lse(
         q_segment_ids: Optional[jax.Array] = None,
         alibi_slopes: Optional[jax.Array] = None,
         window: Optional[jax.Array] = None,
-        q_offset=None, block_q: int = 128, block_k: int = 128,
-        interpret: Optional[bool] = None
+        q_offset=None, block_q: Optional[int] = None,
+        block_k: Optional[int] = None, interpret: Optional[bool] = None
 ) -> Tuple[jax.Array, jax.Array]:
     """Flash attention returning ``(out [B, Sq, H, D], lse [B, H, Sq])``.
 
     ``lse`` is the per-row logsumexp of the masked scaled logits (fp32;
     rows with no unmasked key hold the finite ``MASK_VALUE`` sentinel) —
     the partial-softmax state ring attention accumulates across hops.
-    Differentiable in q/k/v including through ``lse``.
+    Differentiable in q/k/v including through ``lse``. Tiles come from
+    :func:`choose_tiles` unless ``block_q``/``block_k`` name them.
     """
     B, Sq, H, D = q.shape
-    cfg, q4, k3, v3, qseg_b, kseg_b, slopes, info, dims = _prepare(
+    cfg, q4, k3, v3, segs, slopes, info, dims = _prepare(
         q, k, v, causal, scale, segment_ids, q_segment_ids, alibi_slopes,
         window, q_offset, block_q, block_k, interpret)
     _, _, kvH, G = dims
-    o, lse = _flash(cfg, q4, k3, v3, qseg_b, kseg_b, slopes, info)
+    o, lse = _flash(cfg, q4, k3, v3, segs, slopes, info)
     out = o.reshape(B, kvH, G, Sq, D).reshape(B, H, Sq, D)
     out = out.transpose(0, 2, 1, 3)
     return out, lse.reshape(B, H, Sq)
@@ -592,7 +710,8 @@ def flash_attention_kernel(
         q_segment_ids: Optional[jax.Array] = None,
         alibi_slopes: Optional[jax.Array] = None,
         window: Optional[jax.Array] = None,
-        q_offset=None, block_q: int = 128, block_k: int = 128,
+        q_offset=None, block_q: Optional[int] = None,
+        block_k: Optional[int] = None,
         interpret: Optional[bool] = None) -> jax.Array:
     """Flash attention, ``[B, S, H, D]`` in and out — the drop-in training
     kernel `attention.flash_attention` dispatches to at long sequence."""

@@ -64,6 +64,7 @@ class TestCompilesForV5e:
     @pytest.mark.parametrize("shape", [
         REAL.flash,                 # TinyLlama GQA heads (bench long-seq)
         (1, 4096, 32, 32, 128),     # llama2-7b MHA heads
+        REAL.flash_train,           # gpt2-large micro 4: the benchmark cell
     ])
     def test_flash_fwd_bwd(self, chip, shape):
         from deepspeed_tpu.ops.transformer.pallas_flash import \
@@ -209,7 +210,8 @@ TINY = chip_smoke.Sizes(
     dtype="float32", preset="gpt2-tiny",
     model_overrides=(("vocab_size", 256), ("max_seq_len", 64)),
     micro=1, seq=32, train_steps=3,
-    flash=(1, 128, 4, 2, 16), bucket_elems=2048, quant_rows=(64, 128),
+    flash=(1, 128, 4, 2, 16), flash_train=(2, 256, 2, 2, 16),
+    bucket_elems=2048, quant_rows=(64, 128),
     moe=(32, 16, 32, 4), moe_small_tokens=8, wave_heads=(4, 2, 16), wave_page=4,
     wave_seqs=((1, 9), (1, 17), (11, 5), (6, 0)),
     moe_steps=2, n_requests=3, prompt_range=(5, 20), max_new=4,
@@ -238,7 +240,7 @@ class TestSmokePhasesOnCpu:
     def test_kernels(self, stats):
         line = chip_smoke.phase_kernels(TINY, 0, stats)
         assert [c["kernel"].split()[0] for c in line["checks"]] == [
-            "flash", "adam/lion", "quantize_rows_int8", "moe", "moe",
+            "flash", "flash", "adam/lion", "quantize_rows_int8", "moe", "moe",
             "ragged"]
         assert all(c["interpret"] for c in line["checks"])  # CPU backend
 

@@ -42,11 +42,14 @@ def _qkv(B=2, S=256, H=8, kvH=2, D=64, seed=0, dtype=jnp.float32):
 
 
 def _seg(B=2, S=256, seed=0):
-    return jnp.asarray(np.random.default_rng(seed).integers(0, 3, (B, S)),
-                       jnp.int32)
+    """Sorted ids (packed documents): long sequences keep whole tiles
+    inside one document and whole tiles across two."""
+    ids = np.random.default_rng(seed).integers(0, 3, (B, S))
+    return jnp.asarray(np.sort(ids, axis=1) if S > 256 else ids, jnp.int32)
 
 
-# the feature matrix: every feature alone plus the interacting pairs
+# the feature matrix: every feature alone plus the interacting pairs, at
+# one tile a sequence (S=256 -> the chooser's 256 x 256) ...
 CASES = {
     "causal": dict(causal=True),
     "noncausal": dict(causal=False),
@@ -56,85 +59,90 @@ CASES = {
     "alibi": dict(causal=True, alibi=True),
     "alibi_window": dict(causal=True, alibi=True, window=96),
     "window_segids": dict(causal=True, window=64, segids=True),
+    # ... and over 2+ blocks each way with block_q != block_k at head dim
+    # 64: skipped, wholly visible and diagonal tiles all occur, the
+    # backward sums dq over k-blocks (512-wide) or holds every key (1024)
+    "tiles_256x512": dict(causal=True, S=1024, tiles=(256, 512)),
+    "tiles_512x256": dict(causal=True, S=1024, tiles=(512, 256)),
+    "tiles_256x1024": dict(causal=True, S=1024, tiles=(256, 1024)),
+    "tiles_auto_2048": dict(causal=True, S=2048, B=1),
+    "tiles_q_offset": dict(causal=True, S=1024, tiles=(256, 512),
+                           q_offset=512),
+    "tiles_segids": dict(causal=False, segids=True, S=1024,
+                         tiles=(512, 256)),
+    "tiles_window": dict(causal=True, window=300, S=1024,
+                         tiles=(256, 512)),
+    "tiles_window_segids_alibi": dict(causal=True, window=300, segids=True,
+                                      alibi=True, S=1024, tiles=(256, 512)),
 }
 
 
-def _run_pair(case, kvH=2, dtype=jnp.float32, seed=0, S=256):
-    q, k, v = _qkv(S=S, kvH=kvH, seed=seed, dtype=dtype)
+def _run_pair(case, kvH=2, dtype=jnp.float32, seed=0):
+    """-> q, k, v, reference(q, k, v), kernel(q, k, v). A case with
+    ``q_offset`` attends the LAST rows of q (from that position on)
+    against all of k/v."""
+    S = case.get("S", 256)
+    q, k, v = _qkv(B=case.get("B", 2), S=S, kvH=kvH, seed=seed, dtype=dtype)
+    off = case.get("q_offset")
+    if off is not None:
+        q = q[:, off:]
     D = q.shape[-1]
     scale = 1.0 / (D ** 0.5)
-    seg = _seg(S=S, seed=seed) if case.get("segids") else None
+    seg = _seg(B=q.shape[0], S=S, seed=seed) if case.get("segids") else None
+    qseg = seg[:, off:] if (seg is not None and off is not None) else None
     sl = (jnp.asarray(alibi_slopes(q.shape[2])) if case.get("alibi")
           else None)
     w = (jnp.asarray(case["window"], jnp.int32) if case.get("window")
          else None)
-    ref = _xla_attention(q.astype(jnp.float32), k.astype(jnp.float32),
-                         v.astype(jnp.float32), case["causal"], scale, seg,
-                         alibi=sl, window=w)
+    bq, bk = case.get("tiles", (None, None))
+
+    def reference(q, k, v):
+        return _xla_attention(q.astype(jnp.float32), k.astype(jnp.float32),
+                              v.astype(jnp.float32), case["causal"], scale,
+                              seg, alibi=sl, window=w, q_offset=off,
+                              q_segment_ids=qseg)
 
     def kernel(q, k, v):
         return flash_attention_kernel(
             q, k, v, causal=case["causal"], scale=scale, segment_ids=seg,
-            alibi_slopes=sl, window=w, interpret=True)
+            q_segment_ids=qseg, alibi_slopes=sl, window=w, q_offset=off,
+            block_q=bq, block_k=bk, interpret=True)
 
-    return q, k, v, ref, kernel
+    return q, k, v, reference, kernel
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
 @pytest.mark.parametrize("kvH", [1, 2, 8])
 def test_forward_parity_fp32(eight_devices, name, kvH):
-    q, k, v, ref, kernel = _run_pair(CASES[name], kvH=kvH)
-    got = kernel(q, k, v)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), **FP32_TOL)
+    q, k, v, reference, kernel = _run_pair(CASES[name], kvH=kvH)
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(reference(q, k, v)), **FP32_TOL)
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_grad_parity_fp32(eight_devices, name):
-    q, k, v, _, kernel = _run_pair(CASES[name])
-    case = CASES[name]
-    scale = 1.0 / (q.shape[-1] ** 0.5)
-    seg = _seg() if case.get("segids") else None
-    sl = (jnp.asarray(alibi_slopes(q.shape[2])) if case.get("alibi")
-          else None)
-    w = (jnp.asarray(case["window"], jnp.int32) if case.get("window")
-         else None)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(jnp.square(_xla_attention(
-            q, k, v, case["causal"], scale, seg, alibi=sl, window=w)))
-
-    def loss_kernel(q, k, v):
-        return jnp.sum(jnp.square(kernel(q, k, v)))
-
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_ker = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
+    q, k, v, reference, kernel = _run_pair(CASES[name])
+    g_ref = jax.grad(lambda *a: jnp.sum(jnp.square(reference(*a))),
+                     argnums=(0, 1, 2))(q, k, v)
+    g_ker = jax.grad(lambda *a: jnp.sum(jnp.square(kernel(*a))),
+                     argnums=(0, 1, 2))(q, k, v)
     for a, b, nm in zip(g_ker, g_ref, ("dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    err_msg=f"{name}:{nm}", **GRAD_TOL)
 
 
 @pytest.mark.parametrize("name", ["causal", "window", "alibi",
-                                  "segids_causal"])
+                                  "segids_causal", "tiles_256x512"])
 def test_bf16_inputs_vs_fp32_reference(eight_devices, name):
     """bf16 training inputs against the fp32 reference: the fp32
     accumulation contract (errors stay at input-quantization scale)."""
-    case = CASES[name]
-    q, k, v, ref, kernel = _run_pair(case, dtype=jnp.bfloat16, seed=3)
+    q, k, v, reference, kernel = _run_pair(CASES[name], dtype=jnp.bfloat16,
+                                           seed=3)
     got = kernel(q, k, v).astype(jnp.float32)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), **BF16_TOL)
-
-    scale = 1.0 / (q.shape[-1] ** 0.5)
-    seg = _seg(seed=3) if case.get("segids") else None
-    sl = (jnp.asarray(alibi_slopes(q.shape[2])) if case.get("alibi")
-          else None)
-    w = (jnp.asarray(case["window"], jnp.int32) if case.get("window")
-         else None)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(jnp.square(_xla_attention(
-            q, k, v, case["causal"], scale, seg, alibi=sl, window=w)))
-
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(reference(q, k, v)), **BF16_TOL)
+    g_ref = jax.grad(lambda *a: jnp.sum(jnp.square(reference(*a))),
+                     argnums=(0, 1, 2))(
         q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32))
     g_ker = jax.grad(lambda q, k, v: jnp.sum(jnp.square(kernel(q, k, v))),
                      argnums=(0, 1, 2))(q, k, v)
@@ -321,3 +329,145 @@ def test_dispatch_env_gates(eight_devices, monkeypatch):
     monkeypatch.setenv("DSTPU_ATTN", "xla")
     ref = attn_mod.flash_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), **FP32_TOL)
+
+
+# ---------------------------------------------------------------------------
+# the tile rule and the route rule: pure Python, no kernel runs
+# ---------------------------------------------------------------------------
+
+TILE_SHAPES = [
+    # Sq, Sk, head_dim, itemsize
+    (1024, 1024, 64, 2),     # the benchmark cell
+    (1024, 1024, 128, 2),
+    (4096, 4096, 64, 2),
+    (4096, 4096, 128, 4),
+    (2048, 8192, 128, 2),    # a query chunk against a longer key
+    (512, 512, 64, 2),       # bert
+    (256, 256, 64, 2),
+    (384, 384, 64, 2),       # whole: no smaller 128-multiple but 128
+    (640, 640, 64, 2),
+    (1536, 1536, 96, 2),
+]
+
+
+@pytest.mark.parametrize("sq,causal,fwd,bwd", [
+    (1024, True, (512, 512), (1024, 1024)),     # what the chip sweep chose
+    (1024, False, (1024, 1024), (1024, 1024)),
+    (4096, True, (512, 512), (1024, 1024)),
+    (512, True, (512, 512), (512, 512)),
+    (640, True, (640, 640), (640, 640)),        # never the step-bound 128
+    (1536, True, (512, 512), (768, 768)),
+])
+def test_tile_rule_is_the_measured_one(sq, causal, fwd, bwd):
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    t = pf.choose_tiles(sq, sq, 64, causal=causal)
+    assert (t.fwd, t.bwd) == (fwd, bwd)
+
+
+@pytest.mark.parametrize("sq,sk,d,itemsize", TILE_SHAPES)
+def test_chosen_tiles_are_legal(sq, sk, d, itemsize):
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    tiles = pf.choose_tiles(sq, sk, d, itemsize)
+    for (bq, bk), backward in ((tiles.fwd, False), (tiles.bwd, True)):
+        assert sq % bq == 0 and sk % bk == 0
+        assert bq % 128 == 0 and bk % 128 == 0      # the 128-lane layout
+        assert pf.tile_vmem_bytes((bq, bk), d, itemsize,
+                                  backward=backward) <= pf.VMEM_BUDGET
+    # inside the budget the compiler's own limit stands
+    assert tiles.vmem_limit_bytes is None
+    assert pf.supports((1, sq, 8, d), (1, sk, 8, d))
+
+
+def test_explicit_tiles_win_and_oversize_ones_raise_the_limit():
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    t = pf.choose_tiles(1024, 1024, 64, block_q=128, block_k=256)
+    assert t.fwd == t.bwd == (128, 256) and t.vmem_limit_bytes is None
+    # one argument alone: the other comes from the rule
+    t = pf.choose_tiles(1024, 1024, 64, block_q=256)
+    assert t.fwd == (256, pf.FWD_CAUSAL_TILE_TARGET[1])
+    assert t.bwd == (256, pf.TILE_TARGET[1])
+    # clamped to the lengths, as the 128-default was
+    assert pf.choose_tiles(128, 256, 64, block_q=512,
+                           block_k=512).fwd == (128, 256)
+    big = pf.choose_tiles(4096, 4096, 128, block_q=2048, block_k=2048)
+    assert big.fwd == (2048, 2048)
+    assert pf.VMEM_BUDGET < big.vmem_limit_bytes <= pf.VMEM_CAP
+
+
+@pytest.mark.parametrize("sq,sk,kw,legal", [
+    (192, 192, {}, False),                   # no 128-multiple divides
+    (1000, 1000, {}, False),
+    (64, 64, {}, False),                     # compiled: off the lane layout
+    (64, 64, {"compiled": False}, True),     # interpret: whole
+    (1024, 1024, {"block_k": 384}, False),   # explicit tile does not divide
+    (64, 128, {}, False),                    # a short q against 128 keys
+    (64, 128, {"compiled": False}, True),
+])
+def test_lengths_without_a_legal_tile(sq, sk, kw, legal):
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    assert (pf.choose_tiles(sq, sk, 64, **kw) is not None) == legal
+    compiled = kw.get("compiled", True)
+    assert pf.supports((1, sq, 4, 64), (1, sk, 4, 64),
+                       block_k=kw.get("block_k"),
+                       compiled=compiled) == legal
+
+
+ROUTE_SHAPES = [
+    # q shape, k shape
+    ((4, 1024, 20, 64), (4, 1024, 20, 64)),     # the benchmark cell
+    ((1, 4096, 32, 64), (1, 4096, 4, 64)),      # GQA long
+    ((8, 512, 16, 64), (8, 512, 16, 64)),       # bert
+    ((16, 256, 20, 64), (16, 256, 20, 64)),     # XLA won it 2.3x
+    ((16, 256, 16, 128), (16, 256, 16, 128)),   # the kernel won it by 3 %
+    ((10, 384, 32, 64), (10, 384, 4, 64)),
+    ((2, 128, 8, 64), (2, 128, 2, 64)),
+    ((1, 1000, 8, 64), (1, 1000, 8, 64)),       # no legal tile
+    ((1, 2048, 8, 192), (1, 2048, 8, 192)),     # head dim off the lanes
+]
+
+
+@pytest.mark.parametrize("q_shape,k_shape", ROUTE_SHAPES)
+def test_route_rule_is_shape_and_platform_only(q_shape, k_shape, monkeypatch):
+    """Off the TPU nothing takes the kernel unasked; on it, exactly the
+    supported shapes at or over the measured crossover do; no environment
+    variable enters the rule."""
+    from deepspeed_tpu.ops.transformer import attention as attn_mod
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    for backend in ("cpu", "gpu", "METAL"):
+        assert not attn_mod.kernel_is_default(q_shape, k_shape, backend)
+    min_seq = (attn_mod.FLASH_MIN_SEQ_WIDE_HEAD if q_shape[3] >= 128
+               else attn_mod.FLASH_MIN_SEQ)
+    want = pf.supports(q_shape, k_shape) and q_shape[1] >= min_seq
+    assert attn_mod.kernel_is_default(q_shape, k_shape, "tpu") == want
+    for var in ("DSTPU_ATTN", "DSTPU_PALLAS_FLASH", "DSTPU_LONGSEQ_ATTN"):
+        monkeypatch.setenv(var, "xla" if var == "DSTPU_ATTN" else "1")
+    assert attn_mod.kernel_is_default(q_shape, k_shape, "tpu") == want
+
+
+def test_route_rule_takes_the_benchmark_cell_and_leaves_the_cpu(
+        eight_devices, monkeypatch):
+    """The cell's call (gpt2-large, micro 4 x 1024) is a kernel shape on the
+    TPU; on this CPU mesh the very same call still traces the XLA path."""
+    from deepspeed_tpu.ops.transformer import attention as attn_mod
+    monkeypatch.delenv("DSTPU_ATTN", raising=False)
+    shape = (4, 1024, 20, 64)
+    assert attn_mod.kernel_is_default(shape, shape, "tpu")
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    text = jax.jit(attn_mod.flash_attention).lower(q, q, q).as_text()
+    assert "pallas" not in text and "custom_call" not in text
+
+
+def test_log_names_the_path_and_the_tiles(eight_devices, monkeypatch):
+    """`_log_path_once` says which route a call took and, for the kernel,
+    the tiles of both kernels: a silent fallback or a silent 128-tile is
+    how this path lost its speed before."""
+    from deepspeed_tpu.ops.transformer import attention as attn_mod
+    said = []
+    monkeypatch.setattr(attn_mod, "_log_path_once", said.append)
+    q, k, v = _qkv(B=1, S=1024, H=2, kvH=2, seed=13)
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    attn_mod.flash_attention(q, k, v, causal=True)
+    monkeypatch.delenv("DSTPU_ATTN")
+    attn_mod.flash_attention(q, k, v, causal=True)       # CPU: XLA
+    assert said == ["pallas_flash_inrepo, tiles (block_q x block_k) "
+                    "forward 512x512 backward 1024x1024", "xla"]

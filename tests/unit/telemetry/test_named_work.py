@@ -128,7 +128,7 @@ def _pallas_call_sites():
 
 PALLAS_SITES = _pallas_call_sites()
 KERNEL_NAMES = {
-    "adam_bucket", "lion_bucket", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+    "adam_bucket", "lion_bucket", "flash_fwd", "flash_bwd",
     "ragged_paged_attention", "paged_decode", "woq_matmul",
     "quantize_rows_int8", "moe_route", "moe_dispatch_gather",
     "moe_dispatch_gather_int8", "moe_ffn_combine", "moe_ffn", "moe_combine"}
@@ -145,7 +145,7 @@ def test_every_pallas_call_has_a_name(site):
 
 def test_kernel_names_are_distinct_and_complete():
     names = [n.value for _, _, n in PALLAS_SITES]
-    assert len(names) == 15
+    assert len(names) == 14
     assert len(set(names)) == len(names)
     assert set(names) == KERNEL_NAMES
 

@@ -140,8 +140,10 @@ def main():
                    "--offload" in sys.argv, micro=micro, remat=remat)
         return
     from ab_common import run_interleaved
-    # "chunked" only routes at seq >= 4096 (FLASH_DEFAULT_MIN_SEQ); below
-    # that it would silently duplicate the plain-xla datapoint
+    # "chunked" only routes at seq >= 4096 (attention.XLA_CHUNK_MIN_SEQ,
+    # the XLA route's memory bound; the kernel's own default starts at
+    # FLASH_MIN_SEQ = 384); below that it would silently duplicate the
+    # plain-xla datapoint
     variants = [f"{s}/{p}" for s in SEQS for p in PATHS
                 if not (p == "chunked" and s < 4096)]
 

@@ -81,6 +81,14 @@ class RaggedInferenceModel:
         # program (forces the XLA path; the stock Pallas kernel has no bias)
         self._alibi = (jnp.asarray(model._alibi_slopes)
                        if model._alibi_slopes is not None else None)
+        if c.qk_norm or (c.moe is not None and c.moe.capacity_factor is None):
+            raise NotImplementedError(
+                "serving OLMoE is not supported yet: the ragged engine's "
+                "programs apply no QK-norm and route through the capacity "
+                "path (renormalised weights), so they would compute another "
+                f"model (qk_norm={c.qk_norm}, moe="
+                f"{None if c.moe is None else c.moe}); it trains through "
+                "deepspeed_tpu.initialize")
         # MoE serving routes DROPLESS: capacity_factor = num_experts makes
         # capacity == token count, so no token is ever dropped — the
         # training path's capacity cropping is a throughput/regularization

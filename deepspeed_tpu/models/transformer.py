@@ -76,11 +76,25 @@ def masked_cross_entropy(logits: jax.Array, labels: jax.Array,
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """What describes a sparse architecture's expert layer; nothing here
+    tunes it. Which path a layer takes follows from ``capacity_factor``:
+    a number is the capacity-bucketed GShard path (tokens over capacity
+    are dropped, kept weights renormalised, balance loss over the first
+    choice); None is the no-drop path (``moe/layer.py::
+    MoE.dropless_forward``), which the three fields after it describe."""
     num_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25   # None: no capacity, no drops
     min_capacity: int = 4
-    aux_loss_coef: float = 0.01
+    aux_loss_coef: float = 0.01          # load-balancing loss coefficient
+    normalize_weights: bool = True       # HF norm_topk_prob (OLMoE: False)
+    balance_loss: str = "gshard_top1"    # | 'topk_share' (OLMoE, Switch)
+    z_loss_coef: float = 0.0             # router z-loss (no-drop path only)
+
+    def __post_init__(self):
+        if self.capacity_factor is not None and self.z_loss_coef:
+            raise ValueError("the router z-loss is the no-drop path's "
+                             "(capacity_factor=None)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,6 +141,10 @@ class TransformerConfig:
     dtype: Any = jnp.float32         # compute dtype (params kept by engine policy)
     remat: bool = True
     remat_policy: str = "nothing_saveable"
+    # olmoe: RMSNorm with its own scale over the WHOLE projected q vector
+    # [heads*head_dim] and k vector [kv_heads*head_dim], before the head
+    # split and rope (HF OlmoeAttention q_norm / k_norm)
+    qk_norm: bool = False
     moe: Optional[MoEConfig] = None
     moe_layer_freq: int = 1          # every k-th layer is MoE when moe is set
 
@@ -147,6 +165,8 @@ class TransformerConfig:
         ffn = self.ffn_size
         kv = self.kv_heads * self.head_dim
         attn = h * (h + 2 * kv) + h * h
+        if self.qk_norm:
+            attn += h + kv
         if self.activation == "silu_gated":
             mlp = 3 * h * ffn
         else:
@@ -253,6 +273,9 @@ class TransformerLM:
             "v_proj": nn.Linear(c.hidden_size, kv_out, use_bias=attn_bias, shard="column"),
             "o_proj": nn.Linear(c.hidden_size, c.hidden_size, use_bias=attn_out_bias, shard="row"),
         }
+        if c.qk_norm:
+            self._block_layers["q_norm"] = nn.RMSNorm(c.hidden_size, eps=c.norm_eps)
+            self._block_layers["k_norm"] = nn.RMSNorm(kv_out, eps=c.norm_eps)
         if not c.parallel_block or c.parallel_norms:
             # parallel blocks (falcon-7b/phi) feed attention and MLP from the
             # SAME normed input — no second norm exists in the checkpoint;
@@ -268,6 +291,8 @@ class TransformerLM:
                 capacity_factor=c.moe.capacity_factor,
                 min_capacity=c.moe.min_capacity,
                 activation=c.activation,
+                normalize_weights=c.moe.normalize_weights,
+                balance_loss=c.moe.balance_loss,
             )
         elif c.activation == "silu_gated":
             self._block_layers.update({
@@ -363,8 +388,13 @@ class TransformerLM:
         B, S, _ = h.shape
         with jax.named_scope("attn"):
             with jax.named_scope("qkv"):
-                q = self._block_layers["q_proj"](block["q_proj"], h).reshape(B, S, c.num_heads, c.head_dim)
-                k = self._block_layers["k_proj"](block["k_proj"], h).reshape(B, S, c.kv_heads, c.head_dim)
+                q = self._block_layers["q_proj"](block["q_proj"], h)
+                k = self._block_layers["k_proj"](block["k_proj"], h)
+                if c.qk_norm:
+                    q = self._block_layers["q_norm"](block["q_norm"], q)
+                    k = self._block_layers["k_norm"](block["k_norm"], k)
+                q = q.reshape(B, S, c.num_heads, c.head_dim)
+                k = k.reshape(B, S, c.kv_heads, c.head_dim)
                 v = self._block_layers["v_proj"](block["v_proj"], h).reshape(B, S, c.kv_heads, c.head_dim)
                 if c.position == "rope":
                     q = self._rotate(q, positions)
@@ -395,12 +425,30 @@ class TransformerLM:
         return ulysses_attention(flash_attention, q, k, v, causal=c.causal,
                                  segment_ids=seg, **kw)
 
+    @property
+    def moe_path(self) -> Optional[str]:
+        """``"dropless"``, ``"capacity"`` or None (no experts): the path
+        the expert layers take, which follows from the configuration."""
+        if self.config.moe is None:
+            return None
+        return "dropless" if self._moe.dropless else "capacity"
+
+    def _aux_zero(self) -> jax.Array:
+        """The MoE auxiliary-loss accumulator's zero: a scalar (the GShard
+        balance loss), or the no-drop path's two router losses."""
+        return jnp.zeros((2,) if self.moe_path == "dropless" else (),
+                         dtype=jnp.float32)
+
     @scoped("mlp")
-    def _mlp(self, block: Params, h: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        """MLP over the PRE-NORMED input h."""
+    def _mlp(self, block: Params, h: jax.Array
+             ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+        """MLP over the PRE-NORMED input h -> (out, aux, the no-drop
+        path's rows per expert [experts] or None)."""
         c = self.config
-        aux = jnp.zeros((), dtype=jnp.float32)
-        if c.moe is not None:
+        aux, rows = self._aux_zero(), None
+        if self.moe_path == "dropless":
+            out, aux, rows = self._moe.dropless_forward(block["moe"], h)
+        elif c.moe is not None:
             out, aux = self._moe(block["moe"], h)
         elif c.activation == "silu_gated":
             gate = nn.silu(self._block_layers["gate_proj"](block["gate_proj"], h))
@@ -409,7 +457,7 @@ class TransformerLM:
         else:
             h2 = ACTIVATIONS[c.activation](self._block_layers["fc_in"](block["fc_in"], h))
             out = self._block_layers["fc_out"](block["fc_out"], h2)
-        return out, aux
+        return out, aux, rows
 
     @scoped("block")   # norms and residual adds are "block" and nothing finer
     def _block_fn(self, attn_mask, carry, block_and_keep):
@@ -429,10 +477,10 @@ class TransformerLM:
             # identity — gating inside would still double-normalize x.
             h = self._block_layers["ln_1"](
                 block["ln_1"], x + self._attn(block, x, positions, attn_mask))
-            mlp_out, aux = self._mlp(block, h)
+            mlp_out, aux, rows = self._mlp(block, h)
             y = self._block_layers["ln_2"](block["ln_2"], h + mlp_out)
             x = _c(keep * y + (1 - keep) * x, ACT_SPEC)
-            return (x, positions, aux_acc + keep * aux), None
+            return (x, positions, aux_acc + keep * aux), rows
         h1 = self._block_layers["ln_1"](block["ln_1"], x)
         if c.parallel_block:
             # falcon/phi residual form: both branches read the block INPUT —
@@ -441,14 +489,16 @@ class TransformerLM:
             attn_out = self._attn(block, h1, positions, attn_mask, window)
             hm = (self._block_layers["ln_2"](block["ln_2"], x)
                   if c.parallel_norms else h1)
-            mlp_out, aux = self._mlp(block, hm)
+            mlp_out, aux, rows = self._mlp(block, hm)
             x = _c(x + keep * (attn_out + mlp_out), ACT_SPEC)
         else:
             x = x + keep * self._attn(block, h1, positions, attn_mask, window)
             h2 = self._block_layers["ln_2"](block["ln_2"], x)
-            mlp_out, aux = self._mlp(block, h2)
+            mlp_out, aux, rows = self._mlp(block, h2)
             x = _c(x + keep * mlp_out, ACT_SPEC)
-        return (x, positions, aux_acc + keep * aux), None
+        # the scan stacks the no-drop path's rows per expert over the
+        # layers ([layers, experts]); every other model's ys stay None
+        return (x, positions, aux_acc + keep * aux), rows
 
     @scoped("embed")
     def embed(self, params: Params, input_ids: jax.Array,
@@ -508,7 +558,7 @@ class TransformerLM:
         param-streaming trainer's unit of compute (reference fetches one
         module's partitions at a time, partitioned_param_coordinator.py:280).
         Returns (x', moe_aux)."""
-        carry = (x, positions, jnp.zeros((), jnp.float32))
+        carry = (x, positions, self._aux_zero())
         keep = jnp.asarray(keep, self.config.dtype)
         packed = (block, keep) if window is None else (block, keep, window)
         (x2, _, aux), _ = self._block_fn(attn_mask, carry, packed)
@@ -605,7 +655,7 @@ class TransformerLM:
         take = lambda t, i: jax.tree.map(lambda a: a[i], t)
 
         def unit_call(bp, xx, kb, wb):
-            aux = jnp.zeros((), jnp.float32)
+            aux = self._aux_zero()
             for j in range(lps):
                 blk = jax.tree.map(lambda a: a[j], bp)
                 w = None if wb is None else wb[j]
@@ -649,7 +699,7 @@ class TransformerLM:
 
             with scope(n_steps):
                 (x_out, pf_last, aux_sum), acts = jax.lax.scan(
-                    fwd_body, (x, pf0, jnp.zeros((), jnp.float32)), xs)
+                    fwd_body, (x, pf0, self._aux_zero()), xs)
         else:
             def fwd_body(carry, xs_s):
                 xx, pf_a, pf_b, aux_acc = carry
@@ -659,8 +709,7 @@ class TransformerLM:
 
             with scope(n_steps):
                 (x_out, pf_last, _, aux_sum), acts = jax.lax.scan(
-                    fwd_body, (x, pf0, pf1, jnp.zeros((), jnp.float32)),
-                    xs)
+                    fwd_body, (x, pf0, pf1, self._aux_zero()), xs)
 
         # error-feedback carry plumbing: without scatter_err the scatter
         # call and the return arity are EXACTLY the pre-planner form
@@ -769,8 +818,13 @@ class TransformerLM:
               layer_mask: Optional[jax.Array] = None,
               token_type_ids: Optional[jax.Array] = None,
               attention_mask: Optional[jax.Array] = None,
-              return_hidden: bool = False) -> Tuple[jax.Array, jax.Array]:
+              return_hidden: bool = False,
+              return_stats: bool = False) -> Tuple[jax.Array, ...]:
         """Return (logits [B,S,V] in fp32, moe_aux_loss scalar).
+
+        ``return_stats`` appends the step's device-side statistics, a dict:
+        ``moe_expert_rows`` [layers, experts] int32 on the no-drop MoE path
+        (the assignments each expert received), else empty.
 
         ``layer_mask`` [num_layers] gates each block (PLD stochastic depth).
         ``token_type_ids`` [B,S] selects bert segment embeddings;
@@ -816,7 +870,8 @@ class TransformerLM:
         xs = (params["blocks"], keep)
         if self._windows is not None:
             xs = xs + (jnp.asarray(self._windows, jnp.int32),)
-        init = (x, positions, jnp.zeros((), jnp.float32))
+        init = (x, positions, self._aux_zero())
+        rows = None
         if alternating:
             # HALF-remat: scan over layer pairs, checkpointing only the
             # first of each pair — the backward recomputes every other
@@ -841,12 +896,14 @@ class TransformerLM:
                     (x, positions, aux),
                     jax.tree.map(lambda a: a[-1], xs))
         else:
-            (x, _, aux), _ = jax.lax.scan(block_fn, init, xs)
+            (x, _, aux), rows = jax.lax.scan(block_fn, init, xs)
+        stats = ({} if rows is None else {"moe_expert_rows": rows},) \
+            if return_stats else ()
         if return_hidden:
             if self._ln_f is not None:
                 x = self._ln_f(params["ln_f"], x)
-            return x, aux
-        return self.head(params, x), aux
+            return (x, aux) + stats
+        return (self.head(params, x), aux) + stats
 
     # The three loss ingredients are separate methods because the ZeRO
     # overlap schedule (engine._build_zeropp_micro_overlap) composes the
@@ -873,10 +930,17 @@ class TransformerLM:
                                     extra_mask=extra_mask)
 
     def combine_aux(self, loss: jax.Array, aux: jax.Array) -> jax.Array:
-        """Fold the accumulated MoE aux loss into the objective."""
-        if self.config.moe is not None:
-            loss = loss + self.config.moe.aux_loss_coef * aux / self.config.num_layers
-        return loss
+        """Fold the accumulated MoE aux loss into the objective: each
+        router loss under its own coefficient, averaged over the layers.
+        Reads ``self.config`` alone (``PipelineModule`` borrows it)."""
+        moe = self.config.moe
+        if moe is None:
+            return loss
+        if moe.capacity_factor is None:   # the no-drop path's two losses
+            aux = moe.aux_loss_coef * aux[0] + moe.z_loss_coef * aux[1]
+        else:
+            aux = moe.aux_loss_coef * aux
+        return loss + aux / self.config.num_layers
 
     def loss(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
         """Cross-entropy: next-token for causal LMs (labels derived by shift
@@ -891,3 +955,16 @@ class TransformerLM:
         loss = masked_cross_entropy(logits, labels,
                                     extra_mask=batch.get("loss_mask"))
         return self.combine_aux(loss, aux)
+
+    def loss_and_stats(self, params: Params, batch: Dict[str, jax.Array]
+                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """``loss`` with ``apply``'s device-side statistics of the step
+        (the engine keeps them on the device beside the loss)."""
+        labels = self.derive_labels(batch)
+        logits, aux, stats = self.apply(
+            params, batch["input_ids"], layer_mask=batch.get("layer_mask"),
+            token_type_ids=batch.get("token_type_ids"),
+            attention_mask=batch.get("attention_mask"), return_stats=True)
+        loss = masked_cross_entropy(logits, labels,
+                                    extra_mask=batch.get("loss_mask"))
+        return self.combine_aux(loss, aux), stats

@@ -17,8 +17,9 @@ inference/v2/kernels/cutlass_ops).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,8 @@ from jax.sharding import PartitionSpec as P
 from ..runtime import topology as topo_mod
 from ..runtime.topology import BATCH_AXES, DATA_AXIS, EXPERT_AXIS
 from ..utils.jax_compat import with_sharding_constraint
-from .sharded_moe import capacity as _capacity, top_k_gating_indices
+from .sharded_moe import (capacity as _capacity, softmax_topk_router,
+                          top_k_gating_indices)
 
 Params = Dict[str, Any]
 
@@ -79,22 +81,87 @@ def moe_reference_forward(params: Params, tokens: jax.Array, *,
     return jnp.sum(picked * w[:, :, None], axis=1), aux
 
 
+# -- the no-drop path's row movement ------------------------------------------
+# ``order`` sorts the ``tokens x top_k`` assignments by expert (stable, so
+# inside an expert the rows stand in token order) and ``inv`` is its inverse
+# permutation. Both directions of both movements are row GATHERS: the
+# transpose of a gather is a scatter-add, which the TPU serialises, so each
+# movement carries its own backward, the gather by the other permutation.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(tokens, order, inv, top_k):
+    """[T, h] -> [T x k, h]: row ``i`` is the token of sorted assignment
+    ``i``. Backward: each token's k rows, found by ``inv``, summed."""
+    return tokens.at[order // top_k].get(mode="promise_in_bounds")
+
+
+def _dispatch_rows_fwd(tokens, order, inv, top_k):
+    return _dispatch_rows(tokens, order, inv, top_k), inv
+
+
+def _dispatch_rows_bwd(top_k, inv, g):
+    picked = g.at[inv].get(mode="promise_in_bounds", unique_indices=True)
+    d = jnp.sum(picked.reshape(-1, top_k, g.shape[-1]).astype(jnp.float32), axis=1)
+    return d.astype(g.dtype), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _unsort_rows(rows, order, inv):
+    """[T x k, h] in expert order -> the same rows in assignment order
+    (token-major). Backward: sorted again, by ``order``."""
+    return rows.at[inv].get(mode="promise_in_bounds", unique_indices=True)
+
+
+def _unsort_rows_fwd(rows, order, inv):
+    return _unsort_rows(rows, order, inv), order
+
+
+def _unsort_rows_bwd(order, g):
+    return (g.at[order].get(mode="promise_in_bounds", unique_indices=True),
+            None, None)
+
+
+_unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
+
+
 @dataclasses.dataclass(frozen=True)
 class MoE:
     hidden_size: int
     intermediate_size: int
     num_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    #: None = no capacity and no drops: the sorted, grouped-matmul path
+    #: (``dropless_forward``); a number = the capacity-bucketed GShard path
+    capacity_factor: Optional[float] = 1.25
     min_capacity: int = 4
     activation: str = "silu_gated"  # 'silu_gated' | 'gelu'
     init_scale: float = 0.02
+    #: the no-drop router's description (the capacity gate renormalises
+    #: over the kept choices and balances over the first choice, always)
+    normalize_weights: bool = True
+    balance_loss: str = "gshard_top1"  # | 'topk_share' (sharded_moe.BALANCE_LOSSES)
     #: fused Pallas kernel dispatch (ISSUE 11): None = the
     #: ``DSTPU_MOE_KERNEL`` env gate (auto: Pallas on single-chip TPU,
     #: XLA elsewhere); 'xla'/'pallas' pin per-layer (lint entries,
     #: parity tests). The kernel serves the dead-EP composition only —
     #: a live expert/pipeline mesh keeps the GSPMD exchange path.
     kernel: Any = None
+
+    def __post_init__(self):
+        if not self.dropless and not (self.normalize_weights
+                                      and self.balance_loss == "gshard_top1"):
+            raise ValueError(
+                "the capacity path renormalises the kept weights and takes "
+                "its balance loss from the first choice; normalize_weights="
+                "False and other balance losses need capacity_factor=None "
+                "(the no-drop path)")
+
+    @property
+    def dropless(self) -> bool:
+        return self.capacity_factor is None
 
     def init(self, rng, dtype=jnp.float32) -> Params:
         e, h, f = self.num_experts, self.hidden_size, self.intermediate_size
@@ -141,8 +208,64 @@ class MoE:
                                          params["wi"].astype(dtype)))
         return jnp.einsum("ecf,efh->ech", mid, params["wo"].astype(dtype))
 
+    def dropless_forward(self, params: Params, x: jax.Array
+                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """The no-drop path. x: [batch, seq, hidden] -> (out, router losses
+        [2] = (load balancing, z-loss), rows [experts] int32: assignments
+        each expert received).
+
+        The ``tokens x top_k`` assignments are sorted by expert, their rows
+        gathered once, the expert FFN run as grouped matmuls over
+        ``rows[experts]`` (``jax.lax.ragged_dot``: its backward products
+        are a grouped matmul and one that contracts over the ragged rows),
+        and each token's k rows weighted and summed in float32. No
+        ``[experts, tokens, ..]`` tensor exists forward or backward, an
+        expert with no rows costs nothing and one with most of them is
+        exact. docs/KERNELS.md has the chip readings that chose this."""
+        topo = topo_mod.get_topology() if topo_mod.is_initialized() else None
+        if topo is not None and (topo.expert_parallel_size > 1
+                                 or topo.pipe_parallel_size > 1):
+            raise NotImplementedError(
+                "the no-drop MoE path (capacity_factor=None) runs with every "
+                "expert on each chip; expert and pipeline parallelism for it "
+                f"are not implemented (expert={topo.expert_parallel_size}, "
+                f"pipe={topo.pipe_parallel_size}). Use a capacity_factor, or "
+                "a mesh without those axes")
+        b, s, h = x.shape
+        n_tok, k, dt = b * s, self.top_k, x.dtype
+        tokens = x.reshape(n_tok, h)
+        with jax.named_scope("moe/route"):
+            # float32 logits at full precision: a bf16 logit moves tokens
+            # between experts whose probabilities are close
+            logits = jnp.dot(tokens.astype(jnp.float32),
+                             params["gate"].astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            eidx, weight, losses, rows = softmax_topk_router(
+                logits, k, normalize=self.normalize_weights,
+                balance_loss=self.balance_loss)
+            order = jnp.argsort(eidx.reshape(-1), stable=True).astype(jnp.int32)
+            inv = jnp.zeros_like(order).at[order].set(
+                jnp.arange(n_tok * k, dtype=jnp.int32), unique_indices=True)
+        with jax.named_scope("moe/dispatch"):
+            expert_in = _dispatch_rows(tokens, order, inv, k)
+        with jax.named_scope("moe/experts"):
+            grouped = lambda a, w: jax.lax.ragged_dot(a, w.astype(dt), rows)
+            if self.activation == "silu_gated":
+                mid = (jax.nn.silu(grouped(expert_in, params["wi_gate"]))
+                       * grouped(expert_in, params["wi_up"]))
+            else:
+                mid = jax.nn.gelu(grouped(expert_in, params["wi"]))
+            expert_out = grouped(mid, params["wo"])
+        with jax.named_scope("moe/combine"):
+            picked = _unsort_rows(expert_out, order, inv).reshape(n_tok, k, h)
+            out = jnp.sum(picked.astype(jnp.float32) * weight[:, :, None], axis=1)
+        return out.astype(dt).reshape(b, s, h), losses, rows
+
     def __call__(self, params: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        """x: [batch, seq, hidden] → (out, aux_loss)."""
+        """x: [batch, seq, hidden] → (out, aux_loss); the no-drop path's
+        aux is its two router losses, [2]."""
+        if self.dropless:
+            return self.dropless_forward(params, x)[:2]
         b, s, h = x.shape
         tokens = x.reshape(b * s, h)
         n_tok = b * s

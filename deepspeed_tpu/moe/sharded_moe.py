@@ -12,6 +12,12 @@ partitioner emits the all-to-all over ICI.
 
 Load-balancing aux loss follows the reference (GShard l_aux = E * Σ me·ce,
 sharded_moe.py:266-272).
+
+Beside the GShard gate stands the router of the dropless sparse models
+(``softmax_topk_router``: OLMoE, arXiv:2409.02060): no capacity, no drop,
+the top-k probabilities used as they are or renormalised, and the paper's
+two router losses. ``moe/layer.py`` takes it when a layer's
+``capacity_factor`` is None.
 """
 
 from __future__ import annotations
@@ -94,6 +100,51 @@ def top_k_gating_indices(logits: jax.Array, top_k: int, capacity_: int):
             jnp.stack(poss, axis=1).astype(jnp.int32),
             jnp.stack(keeps, axis=1),
             weight, aux_loss, me)
+
+
+BALANCE_LOSSES = ("gshard_top1", "topk_share")
+
+
+def softmax_topk_router(logits: jax.Array, top_k: int, *, normalize: bool,
+                        balance_loss: str = "topk_share"):
+    """The dropless router: a float32 softmax over ALL experts' logits,
+    ``lax.top_k`` (the lowest index wins a tie), and the k probabilities
+    as routing weights: as they are (``normalize`` False: OLMoE's
+    ``norm_topk_prob`` false) or divided by their sum (Mixtral). No
+    capacity: every one of the ``tokens x top_k`` assignments reaches its
+    expert.
+
+    logits: [tokens, experts]. Returns
+      expert_idx [tokens, k] int32
+      weight     [tokens, k] f32
+      losses     [2] f32: (load-balancing loss, router z-loss)
+      rows       [experts] int32: assignments each expert received
+
+    Load balancing, by ``balance_loss``: ``"topk_share"`` is
+    ``E x sum_e f_e x P_e`` with ``f_e`` the share of the ``tokens x k``
+    assignments that went to expert ``e`` and ``P_e`` its mean router
+    probability (OLMoE paper, section 3; Switch's loss over k choices);
+    ``"gshard_top1"`` is the capacity gate's (``f_e`` from the first
+    choice alone). The z-loss is ``mean(logsumexp(logits)^2)``.
+    """
+    if balance_loss not in BALANCE_LOSSES:
+        raise ValueError(f"balance_loss {balance_loss!r} is not one of "
+                         f"{BALANCE_LOSSES}")
+    tokens, num_experts = logits.shape
+    logits = logits.astype(jnp.float32)
+    gates = jax.nn.softmax(logits, axis=-1)
+    weight, expert_idx = jax.lax.top_k(gates, top_k)
+    if normalize:
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    rows = jnp.zeros((num_experts,), jnp.int32).at[expert_idx.reshape(-1)].add(1)
+    if balance_loss == "topk_share":
+        share = rows.astype(jnp.float32) / (tokens * top_k)
+    else:
+        share = jnp.mean(jax.nn.one_hot(expert_idx[:, 0], num_experts,
+                                        dtype=jnp.float32), axis=0)
+    balance = num_experts * jnp.sum(share * jnp.mean(gates, axis=0))
+    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    return (expert_idx.astype(jnp.int32), weight, jnp.stack([balance, z]), rows)
 
 
 def top_k_gating(logits: jax.Array, top_k: int, capacity_: int

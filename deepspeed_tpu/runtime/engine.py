@@ -523,6 +523,16 @@ class DeepSpeedEngine:
         self._jit_micro_step = None
         self._jit_apply_step = None
         self._jit_train_step = None
+        # MoE counters, plain values kept with telemetry off: the path the
+        # expert layers take ("dropless" / "capacity" / None, which follows
+        # from the model's configuration), the assignments (tokens x top_k x
+        # MoE layers) dispatched by the fused steps so far, and those steps.
+        # On the no-drop path the fused step also returns the per-expert row
+        # counts [layers, experts]; they stay on the device until
+        # ``moe_expert_rows()`` asks for them.
+        self.moe_totals = {"path": getattr(self.model, "moe_path", None),
+                           "rows_dispatched": 0, "steps": 0}
+        self._step_stats = None
         # overlap-planner state (set for real when the pipelined micro
         # builds; defaults keep non-overlap engines on the plain carry)
         self._ef_carry_active = False
@@ -1019,17 +1029,42 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------
     # jitted step functions
     # ------------------------------------------------------------------
-    def _micro_step_fn(self, state, batch):
-        """Scaled loss + grads, accumulated. Returns (state, loss)."""
+    @property
+    def _step_has_stats(self) -> bool:
+        """Whether the fused step returns the model's device-side
+        statistics as a last output (the no-drop MoE path's rows per
+        expert); every other model's program is as it was."""
+        return self.moe_totals["path"] == "dropless"
+
+    def moe_expert_rows(self):
+        """The last fused step's assignments per expert, ``[layers,
+        experts]`` int32, fetched now (the step itself never syncs on
+        them); None off the no-drop MoE path or before a step."""
+        if not self._step_stats:
+            return None
+        return np.asarray(self._step_stats["moe_expert_rows"])
+
+    def _loss_and_stats(self, params, batch):
+        """(loss, stats): the model's loss and, as a tuple of one, its
+        device-side step statistics where the fused step returns them
+        (``_step_has_stats``); else the empty tuple."""
+        if self._step_has_stats:
+            loss, stats = self.model.loss_and_stats(params, batch)
+            return loss, (stats,)
+        return self.model.loss(params, batch), ()
+
+    def _micro_step_fn(self, state, batch, with_stats: bool = False):
+        """Scaled loss + grads, accumulated. Returns (state, loss), and
+        the model's step statistics third when asked and there are any."""
         scale = state["loss_scale"]["cur_scale"]
         gas = self.gradient_accumulation_steps
 
         def scaled_loss(params):
-            loss = self.model.loss(params, batch)
-            return loss * (scale / gas), loss
+            loss, stats = self._loss_and_stats(params, batch)
+            return loss * (scale / gas), (loss, stats)
 
         grads_fn = jax.grad(scaled_loss, has_aux=True)
-        grads, loss = grads_fn(state["params"])
+        grads, (loss, stats) = grads_fn(state["params"])
         if jax.tree.leaves(state["grad_acc"]):
             new_acc = jax.tree.map(lambda a, g: a + g.astype(self.grad_dtype),
                                    state["grad_acc"], grads)
@@ -1039,7 +1074,7 @@ class DeepSpeedEngine:
             new_acc = jax.tree.map(lambda g: g.astype(self.grad_dtype), grads)
         state = dict(state)
         state["grad_acc"] = new_acc
-        return state, loss
+        return (state, loss) + (stats if with_stats else ())
 
     def _opt_kernel_choice(self) -> Optional[str]:
         """The engine's mesh-aware refinement of the ``DSTPU_OPT_KERNEL``
@@ -1180,26 +1215,29 @@ class DeepSpeedEngine:
         word returns as a 5th output. ONE body serves both modes —
         guardian-off and the armed program cannot drift apart."""
         guardian = spike_thresh is not None
+        # the model's step statistics, where it has any, are the LAST output
         if jax.tree.leaves(state["grad_acc"]):
             # a live buffer exists (split path was used on this engine):
             # keep accumulate-then-zero semantics
-            state, loss = self._micro_step_fn(state, batch)
+            state, loss, *stats = self._micro_step_fn(state, batch,
+                                                      with_stats=True)
             res = self._apply_from_grads(
                 state, state["grad_acc"], lr, spike_thresh=spike_thresh,
                 loss=loss if guardian else None)
-            return (res[0], loss) + res[1:]
+            return (res[0], loss) + res[1:] + tuple(stats)
         scale = state["loss_scale"]["cur_scale"]
 
         def scaled_loss(params):
-            loss = self.model.loss(params, batch)
-            return loss * scale, loss  # gas == 1: no /gas
+            loss, stats = self._loss_and_stats(params, batch)
+            return loss * scale, (loss, stats)  # gas == 1: no /gas
 
-        grads, loss = jax.grad(scaled_loss, has_aux=True)(state["params"])
+        grads, (loss, stats) = jax.grad(scaled_loss, has_aux=True)(
+            state["params"])
         grads = jax.tree.map(lambda g: g.astype(self.grad_dtype), grads)
         res = self._apply_from_grads(state, grads, lr,
                                      spike_thresh=spike_thresh,
                                      loss=loss if guardian else None)
-        return (res[0], loss) + res[1:]
+        return (res[0], loss) + res[1:] + stats
 
     def _train_step_fn_guardian(self, state, batch, lr, spike_thresh):
         """The guardian-armed fused step: ``_train_step_fn`` with the
@@ -1988,6 +2026,7 @@ class DeepSpeedEngine:
             self._cached_shardings = self._state_shardings()
         shardings = self._cached_shardings
         rep = NamedSharding(self.mesh, P())
+        stats = (rep,) if self._step_has_stats else ()
         if self._guardian is not None:
             # guardian-armed program: +1 replicated host-scalar input
             # (spike threshold) and the anomaly word as a 5th output
@@ -1995,14 +2034,14 @@ class DeepSpeedEngine:
                 self._train_step_fn_guardian,
                 donate_argnums=(0,),
                 in_shardings=(shardings, None, None, None),
-                out_shardings=(shardings, rep, rep, rep, rep),
+                out_shardings=(shardings, rep, rep, rep, rep) + stats,
             )
             return
         self._jit_train_step = jax.jit(
             self._train_step_fn,
             donate_argnums=(0,),
             in_shardings=(shardings, None, None),
-            out_shardings=(shardings, rep, rep, rep),
+            out_shardings=(shardings, rep, rep, rep) + stats,
         )
 
     def _prepare_batch(self, batch):
@@ -2072,20 +2111,32 @@ class DeepSpeedEngine:
                     thresh = jnp.asarray(self._guardian.spike_threshold(),
                                          jnp.float32)
                     probe_in = self._stage_replay_inputs(batch, lr, thresh)
-                    self.state, loss, overflow, gnorm, anomaly = \
+                    self.state, loss, overflow, gnorm, anomaly, *stats = \
                         self._jit_train_step(self.state, batch, lr, thresh)
                     if probe_in is not None:
                         anomaly = self._run_replay_probe(
                             probe_in, (loss, gnorm, anomaly))
                 else:
-                    self.state, loss, overflow, gnorm = self._jit_train_step(
-                        self.state, batch, lr)
+                    self.state, loss, overflow, gnorm, *stats = \
+                        self._jit_train_step(self.state, batch, lr)
+        self._count_moe(batch, stats)
         self._cached_loss = loss
         self.micro_steps += 1
         with self.telemetry.phase("post_step", phase="step",
                                   step=self.global_steps):
             self._post_step(overflow, gnorm, anomaly=anomaly, loss=loss)
         return loss
+
+    def _count_moe(self, batch, stats) -> None:
+        """The fused step's MoE counters: host arithmetic on shapes, and
+        the step's statistics kept as the device arrays they are."""
+        if self.moe_totals["path"] is None:
+            return
+        c = self.model.config
+        self.moe_totals["rows_dispatched"] += (
+            int(np.prod(batch["input_ids"].shape)) * c.moe.top_k * c.num_layers)
+        self.moe_totals["steps"] += 1
+        self._step_stats = stats[0] if stats else None
 
     # ------------------------------------------------------------------
     # public API (reference engine.py forward :1781 / backward :1922 / step :2120)
@@ -3450,7 +3501,7 @@ class DeepSpeedEngine:
         replay_state = jax.tree.map(
             lambda h, s: jax.device_put(h, s), host_state, shardings)
         _, r_loss, _, r_gnorm, r_word = self._jit_train_step(
-            replay_state, batch, lr, thresh)
+            replay_state, batch, lr, thresh)[:5]
         loss, gnorm, word = outputs
         mismatch = (
             np.asarray(r_loss).tobytes() != np.asarray(loss).tobytes()

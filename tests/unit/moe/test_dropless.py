@@ -1,0 +1,191 @@
+"""The no-drop expert path (``moe/layer.py::MoE.dropless_forward``: sorted
+assignments, grouped matmuls) against a dense all-experts float32
+computation written here from the equations: every expert on every token
+under the 0/1 mask of the tokens that chose it. Tolerance 1e-5 of an
+array's largest element (1e-5 absolute where that is under 1): float32
+rounding over a few hundred-term sums; a bf16 run of the same layer misses
+it by two orders of magnitude (the last test holds that)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.moe.layer import MoE
+from deepspeed_tpu.moe.sharded_moe import softmax_topk_router
+
+TOL = 1e-5
+H, F, B, S = 32, 16, 2, 24
+
+
+def close(got, want):
+    want = np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=TOL * max(1.0, float(np.abs(want).max())))
+
+
+def layer(num_experts, top_k, **kw):
+    kw.setdefault("balance_loss", "topk_share")
+    return MoE(hidden_size=H, intermediate_size=F, num_experts=num_experts,
+               top_k=top_k, capacity_factor=None, **kw)
+
+
+def skewed(moe, seed=0):
+    """Parameters and tokens with an uneven routing: every token carries a
+    shared direction, expert 0's router column points along it (more than
+    half of the tokens choose it: the most one expert can get is every
+    token once) and the last quarter of the experts point against it (no
+    token chooses them)."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    params = jax.tree.map(lambda a: a * 10.0, moe.init(k1))      # outputs of order 0.1-1
+    base = jax.random.normal(k2, (H,)) / np.sqrt(H)
+    x = base * 3.0 + jax.random.normal(k3, (B, S, H))
+    gate = params["gate"]
+    dead = moe.num_experts // 4
+    gate = gate.at[:, 0].set(base * 3.0).at[:, -dead:].set(-base[:, None] * 6.0)
+    return dict(params, gate=gate), x
+
+
+def dense(moe, params, x):
+    """All experts on all tokens under the mask; float32, the obvious way."""
+    t = x.reshape(-1, H).astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        probs = jax.nn.softmax(t @ params["gate"].astype(jnp.float32), axis=-1)
+        top, idx = jax.lax.top_k(probs, moe.top_k)
+        if moe.normalize_weights:
+            top = top / top.sum(-1, keepdims=True)
+        w = jnp.einsum("tk,tke->te", top, jax.nn.one_hot(idx, moe.num_experts))
+        mid = (jax.nn.silu(jnp.einsum("th,ehf->etf", t, params["wi_gate"]))
+               * jnp.einsum("th,ehf->etf", t, params["wi_up"]))
+        y = jnp.einsum("etf,efh->eth", mid, params["wo"])
+        return jnp.einsum("eth,te->th", y, w).reshape(x.shape)
+
+
+def probe(fn, moe, params, x):
+    """A scalar of the layer's output with a fixed random cotangent, so
+    that the gradient tests every element of it."""
+    ct = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    return jnp.sum(fn(moe, params, x) * ct)
+
+
+def run_layer(moe, params, x):
+    with jax.default_matmul_precision("highest"):
+        return moe(params, x)[0]
+
+
+CASES = [pytest.param(64, 8, id="8-of-64"), pytest.param(8, 3, id="3-of-8")]
+
+
+@pytest.mark.parametrize("normalize", [False, True], ids=["as-is", "renormalised"])
+@pytest.mark.parametrize("num_experts,top_k", CASES)
+def test_output_and_gradients_match_dense(num_experts, top_k, normalize):
+    moe = layer(num_experts, top_k, normalize_weights=normalize)
+    params, x = skewed(moe)
+    _, _, _, rows = route_of(moe, params, x)
+    assert rows.sum() == B * S * top_k                 # nothing dropped
+    assert rows[0] > B * S // 2 and (rows == 0).sum() >= num_experts // 4
+    close(run_layer(moe, params, x), dense(moe, params, x))
+    got = jax.grad(lambda p, v: probe(run_layer, moe, p, v), (0, 1))(params, x)
+    want = jax.grad(lambda p, v: probe(dense, moe, p, v), (0, 1))(params, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        close(g, w)
+
+
+def route_of(moe, params, x):
+    t = x.reshape(-1, H)
+    with jax.default_matmul_precision("highest"):
+        return softmax_topk_router(t @ params["gate"], moe.top_k,
+                                   normalize=moe.normalize_weights,
+                                   balance_loss=moe.balance_loss)
+
+
+@pytest.mark.parametrize("num_experts,top_k", CASES)
+def test_gradients_under_checkpoint(num_experts, top_k):
+    """Rematerialised (the benchmark's cell trains with full remat): the
+    custom backward of the two row movements replays the same numbers."""
+    moe = layer(num_experts, top_k, normalize_weights=False)
+    params, x = skewed(moe, seed=1)
+    plain = jax.grad(lambda p, v: probe(run_layer, moe, p, v), (0, 1))(params, x)
+    remat = jax.grad(jax.checkpoint(lambda p, v: probe(run_layer, moe, p, v)),
+                     (0, 1))(params, x)
+    want = jax.grad(lambda p, v: probe(dense, moe, p, v), (0, 1))(params, x)
+    for a, b, w in zip(*map(jax.tree.leaves, (plain, remat, want))):
+        np.testing.assert_array_equal(a, b)
+        close(b, w)
+
+
+@pytest.mark.parametrize("num_experts,top_k", CASES)
+def test_router_losses_are_the_papers(num_experts, top_k):
+    """E x sum_e f_e P_e over the T x k assignments, and mean logsumexp^2,
+    by hand from the logits; both carry a gradient to the router."""
+    moe = layer(num_experts, top_k, normalize_weights=False)
+    params, x = skewed(moe, seed=2)
+    _, losses, rows = moe.dropless_forward(params, x)
+    logits = np.asarray(x.reshape(-1, H), np.float64) @ np.asarray(params["gate"], np.float64)
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    chosen = np.argsort(-probs, axis=-1, kind="stable")[:, :top_k]
+    f = np.bincount(chosen.reshape(-1), minlength=num_experts) / chosen.size
+    np.testing.assert_array_equal(np.asarray(rows), f * chosen.size)
+    assert float(losses[0]) == pytest.approx(num_experts * (f * probs.mean(0)).sum(), rel=1e-5)
+    lse = np.log(np.exp(logits).sum(-1))
+    assert float(losses[1]) == pytest.approx((lse ** 2).mean(), rel=1e-5)
+    g = jax.grad(lambda p: moe.dropless_forward(p, x)[1].sum())(params)["gate"]
+    assert float(jnp.abs(g).max()) > 0
+
+
+def test_equals_the_capacity_path_when_capacity_cannot_bind():
+    """Capacity = tokens and renormalised weights: the two paths compute
+    the same layer, and with ``gshard_top1`` the same balance loss."""
+    kw = dict(hidden_size=H, intermediate_size=F, num_experts=8, top_k=2)
+    bucketed = MoE(capacity_factor=8.0, **kw)
+    dropless = MoE(capacity_factor=None, normalize_weights=True,
+                   balance_loss="gshard_top1", **kw)
+    params, x = skewed(bucketed, seed=3)
+    with jax.default_matmul_precision("highest"):
+        a, aux_a = bucketed(params, x)
+        b, aux_b = dropless(params, x)
+    close(a, b)
+    assert aux_b.shape == (2,) and float(aux_a) == pytest.approx(float(aux_b[0]), rel=1e-6)
+
+
+def test_capacity_path_takes_no_description_of_the_other():
+    with pytest.raises(ValueError, match="capacity_factor=None"):
+        MoE(hidden_size=H, intermediate_size=F, normalize_weights=False)
+    with pytest.raises(ValueError, match="capacity_factor=None"):
+        MoE(hidden_size=H, intermediate_size=F, balance_loss="topk_share")
+    with pytest.raises(ValueError, match="balance_loss"):
+        layer(8, 2, balance_loss="nope").dropless_forward(*skewed(layer(8, 2)))
+
+
+def test_a_live_expert_axis_is_refused(eight_devices):
+    from deepspeed_tpu.runtime import topology as topo_mod
+    from deepspeed_tpu.runtime.topology import TopologyConfig
+    moe = layer(8, 3)
+    params, x = skewed(moe)
+    topo_mod.reset()
+    try:
+        topo = topo_mod.initialize(TopologyConfig(expert=2, data=-1), force=True)
+        with topo.mesh, pytest.raises(NotImplementedError, match="expert"):
+            moe(params, x)
+    finally:
+        topo_mod.reset()
+
+
+def test_the_pallas_capacity_kernels_stay_out_of_the_way():
+    """OLMoE's shape resolves to no kernel, cleanly (top-k 8, hidden 2048)."""
+    from deepspeed_tpu.ops.transformer import pallas_moe as pm
+    shape = dict(top_k=8, activation="silu_gated", dtype=jnp.bfloat16,
+                 tokens=4096, num_experts=64, hidden=2048)
+    assert not pm.moe_kernel_supported(**shape)
+    assert pm.moe_kernel_resolution(**shape, kernel=None).startswith("xla")
+
+
+def test_bf16_fails_the_float32_tolerance():
+    """The tolerance tells float32 from bfloat16: the same layer on bf16
+    parameters and tokens is off by more than 10x the tolerance."""
+    moe = layer(8, 3, normalize_weights=False)
+    params, x = skewed(moe)
+    low = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+    got = moe(low, x.astype(jnp.bfloat16))[0].astype(jnp.float32)
+    assert float(jnp.abs(got - dense(moe, params, x)).max()) > 10 * TOL

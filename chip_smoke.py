@@ -10,7 +10,7 @@ heads, vocab 50257, seq 1024) with random weights from ``--seed``:
   kernels    every default-path Pallas kernel, compiled, against its
              reference (flash fwd+bwd, Adam/Lion buckets, int8 quantize
              rows, MoE forward, ragged wave attention)
-  train-moe  three steps of bench.py's mixtral-style MoE training line
+  train-moe  three steps of a mixtral-style MoE model (`moe_train_model`)
   serve      inference/v2 engine + ContinuousBatchingScheduler answering 8
              staggered requests; prefill logits against the training model
 
@@ -174,17 +174,17 @@ def train_model(sz: Sizes):
     import jax.numpy as jnp
 
     from deepspeed_tpu.models import gpt2_model
-    # full rematerialization: bench.py's gpt2-large line asks for the
-    # "attention_only" policy, which the chip's compiler refuses at this
-    # size (33.91G of 15.75G hbm: the policy saves every unnamed [B,H,S,S]
-    # intermediate, and six MLP-wide tensors per layer besides)
+    # full rematerialization: the chip's compiler refuses the
+    # "attention_only" policy at this size (33.91G of 15.75G hbm: the policy
+    # saves every unnamed [B,H,S,S] intermediate, and six MLP-wide tensors
+    # per layer besides)
     return gpt2_model(sz.preset, dtype=jnp.dtype(sz.dtype), remat=True,
                       **dict(sz.model_overrides))
 
 
 def train_config(sz: Sizes, micro: int, zero: Dict[str, Any]) -> Dict[str, Any]:
-    """bench.py's gpt2-large line: bf16, bf16 moments (SR store), clip 1.0
-    — 7.7 GB of state on one chip."""
+    """bf16, bf16 moments (SR store), clip 1.0 — 7.7 GB of state on one
+    chip."""
     cfg = {
         "train_micro_batch_size_per_gpu": micro,
         "optimizer": {"type": "adamw",
@@ -580,12 +580,37 @@ def phase_kernels(sz: Sizes, seed: int, stats: CompileStats) -> Dict[str, Any]:
                            sz.flash_train),
               _check_opt_buckets(sz, rng),
               _check_quant(sz, rng),
-              # bench dims (split FFN + token-major combine), then a small
-              # wave (the fused combine-scatter epilogue)
+              # `moe_train_model`'s dims (split FFN + token-major combine),
+              # then a small wave (the fused combine-scatter epilogue)
               _check_moe(sz, rng, sz.moe[0]),
               _check_moe(sz, rng, sz.moe_small_tokens),
               _check_wave(sz, rng)]
     return {"phase": "kernels", "checks": checks, **stats.take(), **memory()}
+
+
+def moe_train_model():
+    """The smoke's MoE model: mixtral-8x7b's shape (8 experts, top-2, GQA)
+    cut to 4 layers x 1024 so that its ZeRO-2 state fits one chip."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import mixtral_model
+
+    return mixtral_model("mixtral-8x7b", dtype=jnp.bfloat16, remat=False,
+                         num_layers=4, hidden_size=1024,
+                         intermediate_size=3584, num_heads=16,
+                         num_kv_heads=8, max_seq_len=1024)
+
+
+def moe_train_config() -> Dict[str, Any]:
+    return {
+        "train_micro_batch_size_per_gpu": 8,
+        "optimizer": {"type": "adamw",
+                      "params": {"lr": 1e-4, "weight_decay": 0.01}},
+        "zero_optimization": {"stage": 2},
+        "bf16": {"enabled": True},
+        "gradient_clipping": 1.0,
+        "data_types": {"grad_accum_dtype": "bf16"},
+    }
 
 
 def phase_train_moe(sz: Sizes, seed: int, stats: CompileStats,
@@ -594,12 +619,11 @@ def phase_train_moe(sz: Sizes, seed: int, stats: CompileStats,
     import jax.numpy as jnp
     import numpy as np
 
-    import bench
     import deepspeed_tpu
     from deepspeed_tpu.ops.transformer import pallas_moe
 
-    model = model or bench._moe_bench_model()
-    cfg = dict(bench._moe_bench_cfg(), train_micro_batch_size_per_gpu=micro)
+    model = model or moe_train_model()
+    cfg = dict(moe_train_config(), train_micro_batch_size_per_gpu=micro)
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, config=cfg,
                                                seed=seed)
     c = model.config

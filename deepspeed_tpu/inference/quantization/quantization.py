@@ -184,7 +184,7 @@ def quantized_matmul(x: jax.Array, qp: Dict[str, jax.Array]) -> jax.Array:
     NOTE (A/B protocol): the flag is read at TRACE time — a jitted caller
     that already compiled keeps the path it traced with, so flipping the
     env var mid-process has no effect on cached programs. A/B runs must
-    use fresh processes (tools/ab_common.py does) or jax.clear_caches()."""
+    use fresh processes or jax.clear_caches()."""
     q, scale = qp["q"], qp["scale"]
     stored_int8 = q.dtype == jnp.int8  # before unpack: the Pallas kernel
     # streams STORED bytes — feeding it unpacked int4 would materialize

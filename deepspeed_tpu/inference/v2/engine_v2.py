@@ -620,8 +620,7 @@ class InferenceEngineV2:
 
         Default dispatch is the unified ragged-WAVE program (one atom
         class, ragged_paged_attention); ``wave_dispatch="legacy"`` or
-        ``DSTPU_WAVE=legacy`` restores the previous two-class program
-        (the A/B denominator, tools/serving_ab.py).
+        ``DSTPU_WAVE=legacy`` restores the previous two-class program.
         """
         plan = self._plan_shards(batch_uids, [len(t) for t in batch_tokens])
         if plan is None:

@@ -23,16 +23,17 @@ Numerics: online softmax in fp32 (running max + denominator per group row),
 pages consumed in grid order — sequential accumulation over the last grid
 dimension, the TPU-guaranteed execution order.
 
-Measured on the attached v5e (tools/paged_decode_ab.py, interleaved
-best-of-4 windows, 2026-07-30): GQA g=8/D=64 lowers and runs — this
-kernel WINS at ctx 2k (3.78 vs 4.33 ms/step, 1.15x) and loses at 4k
-(0.65x) / 8k (0.52x): crossover ~3k. The XLA gather sits near the
-per-dispatch latency floor at every context while this kernel's program
-count grows with pages. MHA (g=1) q blocks violate Mosaic's 8-sublane
-minimum and raise at trace time — the call site falls back to XLA with a
-logged warning. XLA therefore remains the default on this environment;
-`DSTPU_PALLAS_PAGED=1` opts in (profitable for short-context GQA decode),
-and the recorded numbers are the decision's evidence (VERDICT r2 next #4).
+Measured on an earlier machine's v5e (tools/paged_decode_ab.py, interleaved
+best-of-4 windows, 2026-07-30; not measured on the current machine): GQA
+g=8/D=64 lowers and runs — this kernel WINS at ctx 2k (3.78 vs 4.33 ms/step,
+1.15x) and loses at 4k (0.65x) / 8k (0.52x): crossover ~3k. The XLA gather
+sits near the per-dispatch latency floor at every context while this
+kernel's program count grows with pages. MHA (g=1) q blocks violate Mosaic's
+8-sublane minimum and raise at trace time — the call site falls back to XLA
+with a logged warning. XLA therefore remains the default on this
+environment; `DSTPU_PALLAS_PAGED=1` opts in (profitable for short-context
+GQA decode), and the recorded numbers are the decision's evidence (VERDICT
+r2 next #4).
 """
 
 from __future__ import annotations

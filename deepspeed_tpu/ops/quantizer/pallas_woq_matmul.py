@@ -6,14 +6,14 @@ The TPU counterpart of the reference's dequant+GEMM inference kernels
 HBM→VMEM at ONE byte per element and are dequantized in-register inside
 the matmul — the bf16 weight tensor never exists in HBM.
 
-Why this kernel exists (measured, tools/woq_matmul_ab.py, v5e,
-2026-07-31): at decode shapes (M=8, llama2-7b MLP dims) XLA's einsum
-form of the same math runs 1.5x SLOWER than plain bf16-dense — the
-int8→bf16 convert + per-group partial products do not fuse into the
-dot's operand stream, so quantization saves HBM *capacity* but loses
-*latency*. Fusing the dequant into the matmul's VMEM pipeline makes the
-weight traffic half of dense, which is the whole point of WOQ serving
-on a bandwidth-bound decode.
+Why this kernel exists (tools/woq_matmul_ab.py on an earlier machine's
+v5e, 2026-07-31; not measured on the current machine): at decode shapes
+(M=8, llama2-7b MLP dims) XLA's einsum form of the same math runs 1.5x
+SLOWER than plain bf16-dense — the int8→bf16 convert + per-group partial
+products do not fuse into the dot's operand stream, so quantization
+saves HBM *capacity* but loses *latency*. Fusing the dequant into the
+matmul's VMEM pipeline makes the weight traffic half of dense, which is
+the whole point of WOQ serving on a bandwidth-bound decode.
 
 Measured outcome on the attached chip (chained-scan probe, interleaved,
 best-of-3): dense bf16 1.13 ms/step, XLA int8 1.58, THIS KERNEL 1.48

@@ -111,30 +111,26 @@ def _xla_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
                            alibi: Optional[jax.Array] = None,
                            window: Optional[jax.Array] = None,
                            chunk: int = 1024) -> jax.Array:
-    """Query-chunked XLA attention: the long-context path.
+    """Query-chunked XLA attention: the XLA side's memory bound at long
+    sequence.
 
-    Identical math to :func:`_xla_attention`, but a ``lax.scan`` over
-    query chunks bounds the materialized scores to [B, H, chunk, S_k]
-    instead of [B, H, S, S] — the buffer that makes plain XLA a compile
-    OOM at seq >= 4096 full depth. The escape hatch (``DSTPU_ATTN=xla``),
-    not the fast path: on the v5e one layer forward + backward at 4096
-    took 8.9-18.5 ms here against 2.4-4.7 ms in the in-repo kernel (PR 25,
-    docs/KERNELS.md); earlier notes that XLA beat the kernel at 2k were
-    the 128 x 128 tiles' doing (5.8 ms against XLA's 3.3 at 1024).
+    Identical math to :func:`_xla_attention`, but one query chunk at a time
+    bounds the materialized scores to [B, H, chunk, S_k] instead of
+    [B, H, S, S] — the buffer that makes plain XLA a compile OOM at
+    seq >= 4096 full depth. Not the fast path: on the v5e one layer
+    forward + backward at 4096 took 8.9-18.5 ms here against 2.4-4.7 ms in
+    the in-repo kernel (PR 25, docs/KERNELS.md). `choose_route` takes it
+    for a shape the kernel does not support, and under ``DSTPU_ATTN=xla``.
     """
     B, Sq, H, D = q.shape
-    # Auto-size the chunk so the per-chunk fp32 score transient
+    # Size the chunk so the per-chunk fp32 score transient
     # [B, H, chunk, S_k] stays under ~512 MB (a budget not measured on
-    # the current machine). DSTPU_CHUNK_Q overrides.
-    env_chunk = os.environ.get("DSTPU_CHUNK_Q")
-    if env_chunk:
-        chunk = int(env_chunk)
-    else:
-        budget = 512 * 1024 * 1024
-        per_row = H * k.shape[1] * 4  # fp32 logits bytes per (b, q-row)
-        cap = max(128, budget // max(B * per_row, 1))
-        while chunk > cap:
-            chunk //= 2
+    # the current machine).
+    budget = 512 * 1024 * 1024
+    per_row = H * k.shape[1] * 4  # fp32 logits bytes per (b, q-row)
+    cap = max(128, budget // max(B * per_row, 1))
+    while chunk > cap:
+        chunk //= 2
     if Sq % chunk:
         # keep the memory bound: shrink to the largest divisor of Sq
         # rather than silently re-materializing the full [B, H, S, S]
@@ -152,44 +148,23 @@ def _xla_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
     if segment_ids is not None:
         sq_c = (segment_ids.reshape(B, nc, chunk)
                 .transpose(1, 0, 2))  # [nc, B, chunk]
-    # bottom-right causal alignment, same contract as _xla_attention:
-    # q row 0 sits at absolute position k_len - Sq
-    offsets = (k.shape[1] - Sq) + jnp.arange(nc, dtype=jnp.int32) * chunk
-
-    unroll = os.environ.get("DSTPU_CHUNK_UNROLL", "1") == "1"
-    if unroll:
-        # UNROLLED chunk loop (default): the same program repeated nc
-        # times. Offsets are static, so each causal chunk STATICALLY
-        # slices K/V to its visible prefix — the flash-style flop skip
-        # (half the attention flops on average), no kernel needed.
-        base = k.shape[1] - Sq
-        outs = []
-        for i in range(nc):
-            off = base + i * chunk
-            end = off + chunk if causal else k.shape[1]
-            outs.append(_xla_attention(
-                qc[i], k[:, :end], v[:, :end], causal, scale,
-                segment_ids[:, :end] if segment_ids is not None else None,
-                alibi, window, q_offset=off,
-                q_segment_ids=(sq_c[i] if sq_c is not None else None)))
-        out = jnp.stack(outs)
-    else:
-        if segment_ids is not None:
-            def body(_, args):
-                qi, off, sqi = args
-                return None, _xla_attention(qi, k, v, causal, scale,
-                                            segment_ids, alibi, window,
-                                            q_offset=off, q_segment_ids=sqi)
-            xs = (qc, offsets, sq_c)
-        else:
-            def body(_, args):
-                qi, off = args
-                return None, _xla_attention(qi, k, v, causal, scale, None,
-                                            alibi, window, q_offset=off)
-            xs = (qc, offsets)
-        _, out = jax.lax.scan(body, None, xs)
+    # The same program repeated nc times. Offsets are static (bottom-right
+    # causal alignment, same contract as _xla_attention: q row 0 sits at
+    # absolute position k_len - Sq), so each causal chunk STATICALLY slices
+    # K/V to its visible prefix — the flash-style flop skip (half the
+    # attention flops on average), no kernel needed.
+    base = k.shape[1] - Sq
+    outs = []
+    for i in range(nc):
+        off = base + i * chunk
+        end = off + chunk if causal else k.shape[1]
+        outs.append(_xla_attention(
+            qc[i], k[:, :end], v[:, :end], causal, scale,
+            segment_ids[:, :end] if segment_ids is not None else None,
+            alibi, window, q_offset=off,
+            q_segment_ids=(sq_c[i] if sq_c is not None else None)))
     # [nc, B, chunk, H, D] -> [B, Sq, H, D]
-    return out.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, D)
+    return jnp.stack(outs).transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, D)
 
 
 def attn_mode() -> str:
@@ -238,62 +213,26 @@ def kernel_is_default(q_shape, k_shape, backend: str) -> bool:
             and _pf.supports(q_shape, k_shape, compiled=True))
 
 
-def _pallas_flash_available(seq_len: int = 0) -> bool:
-    """The legacy stock-kernel knob: DSTPU_PALLAS_FLASH=1 forces the stock
-    JAX kernels ON, =0 forces them OFF; unset, they are reachable only at
-    seq >= XLA_CHUNK_MIN_SEQ with DSTPU_LONGSEQ_ATTN steering away from
-    the chunked route. The env read stays live so toggling mid-process
-    works (per-trace: jitted callers keep the path they traced with)."""
-    flag = os.environ.get("DSTPU_PALLAS_FLASH", "")
-    if flag == "0":
-        return False
-    if flag != "1" and seq_len < XLA_CHUNK_MIN_SEQ:
-        return False
-    return jax.default_backend() != "cpu"
+def choose_route(q_shape, k_shape, backend: str, mode: str) -> str:
+    """The whole decision of `flash_attention`: ``"kernel"`` (the in-repo
+    blockwise pair), ``"xla"`` (one shot) or ``"xla_chunked"``. A pure
+    function of the two shapes, the platform and `attn_mode`'s value.
 
-
-@functools.lru_cache(maxsize=64)
-def _splash_kernel(s_q: int, s_k: int, groups: int, causal: bool,
-                   interpret: bool):
-    """GQA-native splash kernel for one (b, kv_head) slice: q [G, Sq, D],
-    k/v [Sk, D]. Cached per shape — mask construction is host work."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk)
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_mask as sm)
-    mask = (sm.CausalMask((s_q, s_k)) if causal
-            else sm.FullMask((s_q, s_k)))
-    mmask = sm.MultiHeadMask([mask] * groups)
-    kw = {}
-    if interpret:
-        bs = sk.BlockSizes(block_q=min(128, s_q), block_kv=min(128, s_k),
-                           block_kv_compute=min(128, s_k),
-                           block_q_dkv=min(128, s_q),
-                           block_kv_dkv=min(128, s_k),
-                           block_kv_dkv_compute=min(128, s_k),
-                           block_q_dq=min(128, s_q),
-                           block_kv_dq=min(128, s_k))
-        kw = {"block_sizes": bs, "interpret": True}
-    return sk.make_splash_mqa_single_device(mmask, **kw)
-
-
-def _splash_gqa(q, k, v, causal: bool, scale: float,
-                interpret: bool = False) -> jax.Array:
-    """GQA-NATIVE flash: K/V are loaded once per kv head (the reference's
-    blocked-flash consumes GQA natively, blocked_flash.py:64). The stock
-    pallas flash kernel needs matched head counts — broadcasting K/V up
-    8x (TinyLlama 32q/4kv) multiplied KV HBM traffic and memory in
-    exactly the long-seq regime where the kernel is the only path."""
-    B, S, H, D = q.shape
-    kvH = k.shape[2]
-    G = H // kvH
-    kernel = _splash_kernel(S, k.shape[1], G, causal, interpret)
-    # [B, S, H, D] -> q [B, kvH, G, S, D]; k/v [B, kvH, S, D]
-    qg = (q * scale).transpose(0, 2, 1, 3).reshape(B, kvH, G, S, D)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    out = jax.vmap(jax.vmap(kernel))(qg, kt, vt)   # [B, kvH, G, S, D]
-    return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    ``mode == "pallas"`` takes the kernel wherever it CAN run (interpret
+    mode off the TPU relaxes the 128-lane tile requirement to plain
+    divisibility); ``""`` takes it where the chip showed it faster
+    (`kernel_is_default`); every other call, a refused ``"pallas"`` among
+    them, is XLA's, chunked from `XLA_CHUNK_MIN_SEQ` up on a device.
+    """
+    if mode == "pallas":
+        from . import pallas_flash as _pf
+        if _pf.supports(q_shape, k_shape, compiled=backend != "cpu"):
+            return "kernel"
+    elif mode == "" and kernel_is_default(q_shape, k_shape, backend):
+        return "kernel"
+    if q_shape[1] >= XLA_CHUNK_MIN_SEQ and backend != "cpu":
+        return "xla_chunked"
+    return "xla"
 
 
 def flash_attention(q: jax.Array,
@@ -314,92 +253,36 @@ def flash_attention(q: jax.Array,
     ``DSTPU_ATTN=xla`` is the escape hatch back to XLA (query-chunked at
     >= XLA_CHUNK_MIN_SEQ) and ``DSTPU_ATTN=pallas`` forces the kernel at
     any length (interpret mode off-TPU). Shapes under the crossover, and
-    every shape off the TPU, keep the one-shot XLA path. The legacy
-    stock/splash-kernel knobs remain honored — see docs/LONG_CONTEXT.md
-    for the full decision table.
+    every shape off the TPU, keep the one-shot XLA path. `choose_route`
+    is the decision table (docs/LONG_CONTEXT.md).
     ``alibi_slopes`` [num_heads] adds the ALiBi positional bias (bloom);
     ``window`` (0 = global) is the causal sliding window.
     """
-    head_dim = q.shape[-1]
-    # Path selection (docs/LONG_CONTEXT.md). DSTPU_ATTN is the primary
-    # switch: '' (auto) follows `kernel_is_default`; 'xla' is the escape
-    # hatch back to XLA; 'pallas' forces the in-repo kernel at ANY length
-    # (interpret mode on CPU test meshes). The legacy knobs
-    # (DSTPU_LONGSEQ_ATTN, DSTPU_PALLAS_FLASH) still steer the round-5
-    # routes when set.
     mode = attn_mode()
     backend = jax.default_backend()
-    if mode != "xla":
+    route = choose_route(q.shape, k.shape, backend, mode)
+    if route == "kernel":
         from . import pallas_flash as _pf
-        force = mode == "pallas"
-        # force mode runs the kernel wherever it CAN run (interpret mode
-        # relaxes the 128-lane tile requirement to plain divisibility)
-        compiled = not (force and backend == "cpu")
-        if force:
-            take = _pf.supports(q.shape, k.shape, compiled=compiled)
-        else:
-            take = (kernel_is_default(q.shape, k.shape, backend)
-                    and os.environ.get("DSTPU_LONGSEQ_ATTN") is None
-                    and os.environ.get("DSTPU_PALLAS_FLASH", "") != "1")
-        if take:
-            tiles = _pf.choose_tiles(q.shape[1], k.shape[1], head_dim,
-                                     q.dtype.itemsize, causal=causal,
-                                     compiled=compiled)
-            _log_path_once(
-                "pallas_flash_inrepo, tiles (block_q x block_k) forward "
-                "%dx%d backward %dx%d" % (tiles.fwd + tiles.bwd))
-            return _pf.flash_attention_kernel(
-                q, k, v, causal=causal, scale=scale,
-                segment_ids=segment_ids, alibi_slopes=alibi_slopes,
-                window=window)
-        if force:
-            # an explicit DSTPU_ATTN=pallas that cannot be honored must
-            # not pass silently (round-1 review: perf regressions hide in
-            # silent fallbacks)
-            _log_path_once(f"xla (DSTPU_ATTN=pallas REFUSED: shapes "
-                           f"q={q.shape} k={k.shape} unsupported)")
-    # Long-seq XLA fallback (r5, tools/longseq_ab.py): query-chunked XLA —
-    # the XLA attention path's speed with bounded score memory.
-    if (q.shape[1] >= XLA_CHUNK_MIN_SEQ
-            and (mode == "xla"
-                 or os.environ.get("DSTPU_PALLAS_FLASH", "") != "1")
-            and (mode == "xla"
-                 or os.environ.get("DSTPU_LONGSEQ_ATTN", "chunked")
-                 == "chunked")
-            and jax.default_backend() != "cpu"):
-        _log_path_once("xla_chunked")
+        tiles = _pf.choose_tiles(q.shape[1], k.shape[1], q.shape[-1],
+                                 q.dtype.itemsize, causal=causal,
+                                 compiled=backend != "cpu")
+        _log_path_once(
+            "pallas_flash_inrepo, tiles (block_q x block_k) forward "
+            "%dx%d backward %dx%d" % (tiles.fwd + tiles.bwd))
+        return _pf.flash_attention_kernel(
+            q, k, v, causal=causal, scale=scale,
+            segment_ids=segment_ids, alibi_slopes=alibi_slopes,
+            window=window)
+    if mode == "pallas":
+        # an explicit DSTPU_ATTN=pallas that cannot be honored must
+        # not pass silently (round-1 review: perf regressions hide in
+        # silent fallbacks)
+        _log_path_once(f"xla (DSTPU_ATTN=pallas REFUSED: shapes "
+                       f"q={q.shape} k={k.shape} unsupported)")
+    _log_path_once(route)
+    if route == "xla_chunked":
         return _xla_attention_chunked(q, k, v, causal, scale, segment_ids,
                                       alibi_slopes, window)
-    # head_dim 64 (gpt2) is supported by the stock kernel — Mosaic pads the
-    # lane dim; requiring %128 hid the Pallas path from the benched model
-    if (mode != "xla" and _pallas_flash_available(q.shape[1])
-            and segment_ids is None
-            and alibi_slopes is None and window is None and head_dim % 64 == 0
-            and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0):
-        num_q_heads, num_kv_heads = q.shape[2], k.shape[2]
-        sm_scale = scale if scale is not None else 1.0 / (head_dim ** 0.5)
-        if (num_kv_heads != num_q_heads
-                # splash's CausalMask is top-left aligned; the XLA path's
-                # causal mask is bottom-right aligned (q_pos offset by
-                # k_len - Sq) — only identical lengths agree, and training
-                # always has Sq == Sk
-                and q.shape[1] == k.shape[1]
-                and os.environ.get("DSTPU_SPLASH", "1") != "0"):
-            assert num_q_heads % num_kv_heads == 0, (num_q_heads, num_kv_heads)
-            _log_path_once("splash_gqa")
-            return _splash_gqa(q, k, v, causal, sm_scale)
-        if num_kv_heads != num_q_heads:
-            # DSTPU_SPLASH=0 escape hatch: broadcast K/V for the stock kernel
-            k = jnp.repeat(k, num_q_heads // num_kv_heads, axis=2)
-            v = jnp.repeat(v, num_q_heads // num_kv_heads, axis=2)
-        from jax.experimental.pallas.ops.tpu import flash_attention as fa
-        _log_path_once("pallas_flash")
-        # pallas kernel uses [B, H, S, D]
-        out = fa.flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-            causal=causal, sm_scale=sm_scale)
-        return out.transpose(0, 2, 1, 3)
-    _log_path_once("xla")
     return _xla_attention(q, k, v, causal, scale, segment_ids, alibi_slopes,
                           window)
 

@@ -1,10 +1,7 @@
 """In-repo Pallas TPU flash attention — forward AND backward kernels.
 
 The training-attention slot's fast path on a TPU from sequence 384 up. The
-stock JAX kernels this repo previously imported cover only plain causal
-MHA: the GQA splash kernel has no bias/window/segment support, and the
-stock flash kernel repeats K/V up to the query head count. This kernel pair
-supports the full feature matrix the XLA reference path
+pair supports the full feature matrix the XLA reference path
 (`attention._xla_attention`) already has — causal (bottom-right aligned via
 ``q_offset``), GQA-NATIVE (K/V stay at kv_heads), sliding window (shared
 ``sliding_window_allowed`` semantics), segment ids, ALiBi — with fp32

@@ -1091,7 +1091,7 @@ class DeepSpeedEngine:
         TPU — the fused path's flat-bucket layout would make GSPMD
         reshard (fully rematerialize) the ZeRO-sharded optimizer state
         every step, the exact copy the kernel exists to avoid. The
-        single-chip meshes the dense MFU bench lines run on take the
+        single-chip meshes both benchmark cells run on take the
         kernel; the multi-chip enablement needs a shard_map'd local
         flat-partition layout (docs/KERNELS.md). Returning ``None``
         lets ``Optimizer.update`` resolve the env (TPU -> pallas,

@@ -84,8 +84,7 @@ class OffloadedOptimizerRunner:
             # 4 rotating buffers, not 2: with 2, the write-back of buffer i
             # must fence before its reuse at group i+2 — every other group
             # serializes behind a write and the read-ahead buys nothing
-            # (measured: pipelined 0.93x of serial with 2 buffers; see
-            # tools/offload_ab.py)
+            # (not measured on the current machine)
             self._buffers = [np.zeros(self._slots * max_elems, np.float32)
                              for _ in range(4)]
             for i, m in enumerate(self.master):

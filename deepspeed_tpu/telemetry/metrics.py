@@ -31,7 +31,7 @@ from collections import deque
 from typing import Dict, List, Optional
 
 # Peak dense bf16 FLOP/s per chip, keyed by ``device_kind`` exactly as JAX
-# reports it — the ONE peaks table (bench.py and the tools read it too).
+# reports it — the package's ONE peaks table.
 # Source: Google Cloud TPU documentation, the system-architecture page of
 # each generation ("TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
 PEAK_FLOPS_BY_KIND = {

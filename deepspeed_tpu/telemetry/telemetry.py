@@ -234,7 +234,7 @@ class Telemetry:
         """Per-request TTFT attribution at first token: total TTFT, the
         queue-wait component, and the execute remainder each land in
         their own reservoir (the serving SLA scoreboard the scheduler's
-        admission policy and the bench lines read)."""
+        admission policy reads)."""
         self.metrics.ttft_latency.record(ttft_s)
         self.metrics.queue_wait.record(queue_wait_s)
         self.metrics.ttft_execute.record(max(0.0, ttft_s - queue_wait_s))

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule for every entry point (``initialize``, the inference engines,
-``bench.py``, ``chip_smoke.py``): if ``JAX_COMPILATION_CACHE_DIR`` is set,
+``chip_smoke.py``): if ``JAX_COMPILATION_CACHE_DIR`` is set,
 JAX reads it itself and nothing is set in code; otherwise the cache goes to
 ``<checkout>/.jax_cache``. The path is part of the cache key, so it is fixed
 and derived from the package's own location — never a temp name, pid or

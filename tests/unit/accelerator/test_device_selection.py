@@ -1,16 +1,12 @@
 """Nothing hides the device (ISSUE 21 D/E/F).
 
 Backend -> accelerator is a strict map, the mesh of a TPU device set comes
-from ``mesh_utils`` or fails, the compile cache goes where the environment
-says or to one fixed path, and bench.py's one-process-per-chip protocol
-turns a failed child into a failure. The JAX spellings the package shares
+from ``mesh_utils`` or fails, and the compile cache goes where the
+environment says or to one fixed path. The JAX spellings the package shares
 (``utils/jax_compat.py``) are the installed JAX's.
 """
 
-import importlib.util
 import os
-import subprocess
-import sys
 import types
 
 import jax
@@ -210,97 +206,3 @@ def test_moe_route_refuses_an_unsplittable_token_axis():
     with pytest.raises(ValueError, match="1000 tokens"):
         pallas_moe.moe_route(jnp.zeros((1000, 4)), top_k=2, capacity=8,
                              interpret=True)
-
-
-# -- bench.py: one process per chip -------------------------------------------
-
-def _load_bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.join(ROOT, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)  # module top level is stdlib-only
-    return mod
-
-
-@pytest.fixture(scope="module")
-def bench():
-    return _load_bench()
-
-
-@pytest.mark.parametrize("script,match", [
-    ("import sys; sys.exit(3)", "rc=3"),
-    ("print('no metric here')", "rc=0"),
-    ("print('{\"metric\": \"bench error: x\", \"unit\": \"error\"}')", "rc=0"),
-    ("import time; time.sleep(30)", "timeout"),
-])
-def test_failed_child_is_a_failure(bench, script, match):
-    # a non-zero exit, a missing metric line, an error line, a timeout:
-    # never None, never a silently absent ratio
-    with pytest.raises(bench.ChildFailed, match=match):
-        bench._chip_child(["-c", script], 2)
-
-
-def test_child_metric_line_comes_back(bench):
-    line = bench._chip_child(
-        ["-c", "print('noise'); print('{\"metric\": \"m\", \"value\": 2.0}')"],
-        60, env_extra={"X": "1"})
-    assert line == {"metric": "m", "value": 2.0}
-
-
-def test_failed_arm_is_recorded_on_its_line(bench, monkeypatch):
-    def dead(*a, **k):
-        raise bench.ChildFailed("arm died")
-    monkeypatch.setattr(bench, "_chip_child", dead)
-    line = {"metric": "m", "value": 1.0}
-    bench._join_denominators(11, line)
-    assert "vs_overlap_off" not in line and line["arm_errors"]
-
-
-@pytest.mark.parametrize("marker,runs", [("pallas", True),
-                                         ("xla (multi-device auto-pin)",
-                                          False)])
-def test_arm_runs_only_when_the_kernel_ran(bench, monkeypatch, marker, runs):
-    # honesty marker: with the kernel pinned off both arms are one program
-    calls = []
-    monkeypatch.setattr(bench, "_chip_child",
-                        lambda argv, t: calls.append(argv) or {"value": 2.0})
-    line = {"metric": "m", "value": 3.0, "moe_kernel_resolved": marker}
-    bench._join_denominators(3, line)
-    assert bool(calls) == runs
-    assert line.get("vs_moe_kernel_off") == (1.5 if runs else None)
-
-
-def test_probe_failure_raises(bench, monkeypatch):
-    # seed answered "cpu" for a chip it could not open
-    monkeypatch.setattr(subprocess, "run", lambda *a, **k:
-                        types.SimpleNamespace(returncode=1, stdout="",
-                                              stderr="no chip"))
-    with pytest.raises(bench.ChildFailed, match="no chip"):
-        bench._probe_backend()
-
-
-def test_measuring_child_on_the_cpu_has_no_peak(bench, cache_config):
-    # (on_tpu, timed steps, peak): the CPU smoke never gets a made-up peak,
-    # and an unknown TPU kind raises instead of defaulting
-    assert bench._child_setup() == (False, 3, None)
-    assert bench.peak_tflops("TPU v5 lite") == 197.0
-    with pytest.raises(ValueError, match="TPU v9"):
-        bench.peak_tflops("TPU v9")
-
-
-def test_every_denominator_arm_has_a_child(bench):
-    flags = {arm[0] for arms in bench.DENOMINATOR_ARMS.values()
-             for arm in arms}
-    assert flags == set(bench._DENOMINATOR_CHILDREN)
-    assert all(i < bench.N_TPU_RUNS - bench.N_SERVING_RUNS
-               for i in bench.DENOMINATOR_ARMS)
-
-
-def test_bench_without_a_chip_fails():
-    # a measurement path that finds no chip fails; it does not carry on
-    # with the CPU smoke (that runs only under --cpu-smoke)
-    r = subprocess.run([sys.executable, os.path.join(ROOT, "bench.py")],
-                       capture_output=True, text=True, timeout=300,
-                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert r.returncode != 0
-    assert "--cpu-smoke" in r.stderr and "metric" not in r.stdout

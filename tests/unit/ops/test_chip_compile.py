@@ -62,7 +62,7 @@ def compile_for_chip(fn, *args):
 class TestCompilesForV5e:
 
     @pytest.mark.parametrize("shape", [
-        REAL.flash,                 # TinyLlama GQA heads (bench long-seq)
+        REAL.flash,                 # TinyLlama GQA heads at long sequence
         (1, 4096, 32, 32, 128),     # llama2-7b MHA heads
         REAL.flash_train,           # gpt2-large micro 4: the benchmark cell
     ])
@@ -156,7 +156,7 @@ class TestCompilesForV5e:
             chip((tokens, E), F32))
 
     @pytest.mark.parametrize("tokens,n_chunks", [
-        (REAL.moe[0], 1),   # bench dims: split FFN + token-major combine
+        (REAL.moe[0], 1),   # train-moe dims: split FFN + token-major combine
         (REAL.moe_small_tokens, 1),   # fused combine-scatter epilogue
         (REAL.moe_small_tokens, 2),   # ... under the chunked scan carry
         (24, 1),            # a serving wave: not a multiple of any tile
@@ -277,10 +277,9 @@ class TestSmokePhasesOnCpu:
 
 
 def test_smoke_moe_dims_are_the_bench_model():
-    # the kernels phase claims "bench dims": keep it true
-    import bench
-    c = bench._moe_bench_model().config
-    micro = bench._moe_bench_cfg()["train_micro_batch_size_per_gpu"]
+    # the kernels phase claims the train-moe model's dims: keep it true
+    c = chip_smoke.moe_train_model().config
+    micro = chip_smoke.moe_train_config()["train_micro_batch_size_per_gpu"]
     assert REAL.moe == (micro * c.max_seq_len, c.hidden_size, c.ffn_size,
                         c.moe.num_experts)
     assert c.moe.top_k == 2

@@ -439,9 +439,53 @@ def test_route_rule_is_shape_and_platform_only(q_shape, k_shape, monkeypatch):
                else attn_mod.FLASH_MIN_SEQ)
     want = pf.supports(q_shape, k_shape) and q_shape[1] >= min_seq
     assert attn_mod.kernel_is_default(q_shape, k_shape, "tpu") == want
-    for var in ("DSTPU_ATTN", "DSTPU_PALLAS_FLASH", "DSTPU_LONGSEQ_ATTN"):
-        monkeypatch.setenv(var, "xla" if var == "DSTPU_ATTN" else "1")
+    monkeypatch.setenv("DSTPU_ATTN", "xla")
     assert attn_mod.kernel_is_default(q_shape, k_shape, "tpu") == want
+
+
+@pytest.mark.parametrize("q_shape,kv_heads,backend,mode,route", [
+    ((4, 1024, 20, 64), 20, "tpu", "", "kernel"),     # the GPT-2 cell
+    ((1, 4096, 16, 128), 16, "tpu", "", "kernel"),    # the OLMoE cell
+    ((1, 2048, 32, 64), 4, "tpu", "", "kernel"),      # GQA 32q/4kv
+    ((4, 256, 20, 64), 20, "tpu", "", "xla"),
+    ((4, 256, 16, 128), 16, "tpu", "", "kernel"),
+    ((4, 384, 20, 64), 20, "tpu", "", "kernel"),
+    ((1, 2048, 8, 192), 8, "tpu", "", "xla"),         # head dim off the lanes
+    ((1, 4096, 8, 192), 8, "tpu", "", "xla_chunked"),
+    ((1, 4000, 16, 64), 16, "tpu", "", "xla"),        # no 128-multiple tile
+    ((1, 4104, 16, 64), 16, "tpu", "", "xla_chunked"),
+    ((1, 8192, 16, 128), 16, "tpu", "", "kernel"),
+    ((2, 128, 8, 64), 2, "tpu", "", "xla"),           # under the crossover
+    ((1, 8192, 16, 128), 16, "tpu", "xla", "xla_chunked"),
+    ((4, 1024, 20, 64), 20, "tpu", "xla", "xla"),
+    ((4, 1024, 20, 64), 20, "cpu", "", "xla"),
+    ((1, 4096, 16, 128), 16, "cpu", "", "xla"),       # never chunked on the CPU
+    ((1, 4096, 16, 128), 16, "cpu", "xla", "xla"),
+    ((2, 128, 8, 64), 2, "cpu", "pallas", "kernel"),  # interpret
+    ((2, 100, 8, 64), 2, "cpu", "pallas", "kernel"),  # one interpret tile
+    ((1, 8192, 16, 128), 16, "cpu", "pallas", "kernel"),
+    ((2, 128, 6, 64), 4, "cpu", "pallas", "xla"),     # heads do not divide
+    ((2, 128, 8, 64), 2, "tpu", "pallas", "kernel"),  # forced under the crossover
+    ((2, 100, 8, 64), 2, "tpu", "pallas", "xla"),     # no compiled tile
+    ((1, 4096, 8, 192), 8, "tpu", "pallas", "xla_chunked"),
+])
+def test_route_table(q_shape, kv_heads, backend, mode, route, monkeypatch):
+    """`choose_route` is the whole decision of `flash_attention`, a pure
+    function: the TPU's rows are checked here on the CPU, and an
+    environment that asks for another route moves none of them."""
+    from deepspeed_tpu.ops.transformer import attention as attn_mod
+    k_shape = q_shape[:2] + (kv_heads,) + q_shape[3:]
+    monkeypatch.setenv("DSTPU_ATTN", "xla" if route == "kernel" else "pallas")
+    assert attn_mod.choose_route(q_shape, k_shape, backend, mode) == route
+
+
+def test_attention_reads_one_environment_variable():
+    import inspect
+    import re
+
+    from deepspeed_tpu.ops.transformer import attention as attn_mod
+    names = set(re.findall(r"DSTPU_[A-Z0-9_]+", inspect.getsource(attn_mod)))
+    assert names == {"DSTPU_ATTN"}
 
 
 def test_route_rule_takes_the_benchmark_cell_and_leaves_the_cpu(

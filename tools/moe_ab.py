@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """A/B: capacity-dense batched einsum vs jax.lax.ragged_dot for the MoE
-expert FFN, at the bench MoE dims, on the attached chip (VERDICT r2 next
+expert FFN, at the smoke's MoE dims, on the attached chip (VERDICT r2 next
 #5 — record the grouped-matmul decision with numbers).
 
 Interleaved timed windows per the repo's noise protocol (A and B
@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# bench MoE dims (bench.py mixtral-style line): h=1024, f=3584, 8 experts
+# the smoke's MoE dims (chip_smoke.moe_train_model): h=1024, f=3584, 8 experts
 # top-2, tokens = micro(8) x seq(1024), capacity_factor 1.25
 E, H, F = 8, 1024, 3584
 TOKENS = 8 * 1024

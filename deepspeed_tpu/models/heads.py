@@ -77,14 +77,16 @@ class EncoderTaskModel:
     # -- forward -------------------------------------------------------------
     def apply(self, params: Params, input_ids: jax.Array,
               token_type_ids: Optional[jax.Array] = None,
-              attention_mask: Optional[jax.Array] = None) -> jax.Array:
+              attention_mask: Optional[jax.Array] = None,
+              remat_budget=None) -> jax.Array:
         """sequence_classification -> [B, num_labels];
         token_classification -> [B, S, num_labels];
         question_answering -> (start [B, S], end [B, S])."""
         hidden, _ = self.lm.apply(params, input_ids,
                                   token_type_ids=token_type_ids,
                                   attention_mask=attention_mask,
-                                  return_hidden=True)
+                                  return_hidden=True,
+                                  remat_budget=remat_budget)
         head = params["head"]
         if self.task == "sequence_classification":
             x = hidden[:, 0]                     # [CLS]
@@ -98,13 +100,16 @@ class EncoderTaskModel:
             return logits[..., 0], logits[..., 1]
         return logits
 
-    def loss(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
+    def loss(self, params: Params, batch: Dict[str, jax.Array],
+             remat_budget=None) -> jax.Array:
         """Cross-entropy per task; QA averages start+end position losses
         with HF's ignore convention (positions clamped to [0, S]; S =
-        ignored — truncated/impossible answer spans contribute no loss)."""
+        ignored — truncated/impossible answer spans contribute no loss).
+        ``remat_budget`` as in ``TransformerLM.apply``."""
         out = self.apply(params, batch["input_ids"],
                          token_type_ids=batch.get("token_type_ids"),
-                         attention_mask=batch.get("attention_mask"))
+                         attention_mask=batch.get("attention_mask"),
+                         remat_budget=remat_budget)
         if self.task == "question_answering":
             start, end = out
             S = start.shape[-1]

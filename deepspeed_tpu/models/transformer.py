@@ -12,7 +12,11 @@ TPU-first structure:
   dimension and the stack is executed with ``lax.scan`` — one trace/compile of
   the block regardless of depth, XLA-friendly.
 - **remat**: each block is wrapped in ``jax.checkpoint`` with a configurable
-  policy (counterpart of ``runtime/activation_checkpointing/checkpointing.py``).
+  policy (``runtime/activation_checkpointing/checkpointing.py`` builds it).
+  By default the backward keeps what a matmul or a kernel produced (the
+  values named with ``checkpoint_name`` below, in ``pallas_flash.py`` and in
+  ``moe/layer.py``), inside the byte budget the engine reads from the
+  device, and recomputes norms, rope, activations and residual adds.
 - **sharding**: params carry PartitionSpecs (TP over ``model``); activations
   are constrained to ``[data, seq, -]``; Ulysses resharding happens inside
   attention (see ``sequence/layer.py``).
@@ -26,10 +30,13 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..nn import layers as nn
 from ..ops.transformer.attention import flash_attention
+from ..runtime.activation_checkpointing.checkpointing import (
+    KEEP_PRODUCTS, Budget, checkpointed)
 from ..runtime.topology import BATCH_AXES, DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 from ..utils.jax_compat import with_sharding_constraint
 from ..utils.scope import scoped
@@ -140,7 +147,11 @@ class TransformerConfig:
     seq_parallel: str = "ulysses"    # 'ulysses' | 'ring' (long-context SP)
     dtype: Any = jnp.float32         # compute dtype (params kept by engine policy)
     remat: bool = True
-    remat_policy: str = "nothing_saveable"
+    # what the block's backward keeps: KEEP_PRODUCTS (matmul and kernel
+    # outputs inside the device's byte budget) | 'nothing_saveable'/'full'
+    # (recompute the whole block) | 'attention_only' | 'alternating' | any
+    # jax.checkpoint_policies name
+    remat_policy: str = KEEP_PRODUCTS
     # olmoe: RMSNorm with its own scale over the WHOLE projected q vector
     # [heads*head_dim] and k vector [kv_heads*head_dim], before the head
     # split and rope (HF OlmoeAttention q_norm / k_norm)
@@ -376,6 +387,11 @@ class TransformerLM:
         rot = nn.rotary_embedding(x[..., :rd], positions, c.rope_theta, c.rope_style)
         return jnp.concatenate([rot, x[..., rd:]], axis=-1)
 
+    def _project(self, block: Params, name: str, h: jax.Array) -> jax.Array:
+        """The block's linear layer ``name`` over ``h``, its result named
+        as one the backward may keep (``remat_policy``'s default)."""
+        return checkpoint_name(self._block_layers[name](block[name], h), name)
+
     def _attn(self, block: Params, h: jax.Array, positions: jax.Array,
               attn_mask: Optional[jax.Array] = None,
               window: Optional[jax.Array] = None) -> jax.Array:
@@ -388,14 +404,15 @@ class TransformerLM:
         B, S, _ = h.shape
         with jax.named_scope("attn"):
             with jax.named_scope("qkv"):
-                q = self._block_layers["q_proj"](block["q_proj"], h)
-                k = self._block_layers["k_proj"](block["k_proj"], h)
+                # saved as projected: QK-norm's backward needs its input
+                q = self._project(block, "q_proj", h)
+                k = self._project(block, "k_proj", h)
                 if c.qk_norm:
                     q = self._block_layers["q_norm"](block["q_norm"], q)
                     k = self._block_layers["k_norm"](block["k_norm"], k)
                 q = q.reshape(B, S, c.num_heads, c.head_dim)
                 k = k.reshape(B, S, c.kv_heads, c.head_dim)
-                v = self._block_layers["v_proj"](block["v_proj"], h).reshape(B, S, c.kv_heads, c.head_dim)
+                v = self._project(block, "v_proj", h).reshape(B, S, c.kv_heads, c.head_dim)
                 if c.position == "rope":
                     q = self._rotate(q, positions)
                     k = self._rotate(k, positions)
@@ -403,7 +420,7 @@ class TransformerLM:
                 out = self._attn_core(q, k, v, attn_mask, window)
             with jax.named_scope("out"):
                 out = out.reshape(B, S, c.num_heads * c.head_dim)
-                return self._block_layers["o_proj"](block["o_proj"], out)
+                return self._project(block, "o_proj", out)
 
     def _attn_core(self, q, k, v, attn_mask, window) -> jax.Array:
         """Scores, softmax and values (XLA, flash, ring or Ulysses)."""
@@ -451,11 +468,11 @@ class TransformerLM:
         elif c.moe is not None:
             out, aux = self._moe(block["moe"], h)
         elif c.activation == "silu_gated":
-            gate = nn.silu(self._block_layers["gate_proj"](block["gate_proj"], h))
-            up = self._block_layers["up_proj"](block["up_proj"], h)
+            gate = nn.silu(self._project(block, "gate_proj", h))
+            up = self._project(block, "up_proj", h)
             out = self._block_layers["down_proj"](block["down_proj"], gate * up)
         else:
-            h2 = ACTIVATIONS[c.activation](self._block_layers["fc_in"](block["fc_in"], h))
+            h2 = ACTIVATIONS[c.activation](self._project(block, "fc_in", h))
             out = self._block_layers["fc_out"](block["fc_out"], h2)
         return out, aux, rows
 
@@ -819,7 +836,8 @@ class TransformerLM:
               token_type_ids: Optional[jax.Array] = None,
               attention_mask: Optional[jax.Array] = None,
               return_hidden: bool = False,
-              return_stats: bool = False) -> Tuple[jax.Array, ...]:
+              return_stats: bool = False,
+              remat_budget: Optional[Budget] = None) -> Tuple[jax.Array, ...]:
         """Return (logits [B,S,V] in fp32, moe_aux_loss scalar).
 
         ``return_stats`` appends the step's device-side statistics, a dict:
@@ -832,37 +850,14 @@ class TransformerLM:
         ``return_hidden`` short-circuits before the LM/MLM head, returning
         the final hidden states [B,S,H] (post final-norm) — the hook task
         heads (models/heads.py) build on.
+        ``remat_budget``: what the device can give the blocks' saved values
+        under the default ``remat_policy`` (an engine's reading; ``None``
+        saves everything named) and where the decision is written.
         """
         c = self.config
         x, positions = self.embed(params, input_ids, token_type_ids)
 
         block_fn = functools.partial(self._block_fn, attention_mask)
-        alternating = c.remat and c.remat_policy == "alternating"
-        if c.remat and not alternating:
-            policy = None
-            if c.remat_policy == "attention_only":
-                # recompute ONLY the [B, H, S, S] attention buffers (named
-                # "attn_big" in ops/transformer/attention.py) — ~1% extra
-                # FLOPs instead of full remat's 33%, while removing exactly
-                # the buffers whose no-remat residuals blow compile memory
-                # at bert/gpt2 bench dims. NOTE: only the XLA attention
-                # path names those tensors. Under the in-repo Pallas flash
-                # kernel (ops/transformer/pallas_flash.py) no S^2 buffer
-                # exists to recompute: the kernel's custom-VJP residuals
-                # are O(S) — q/k/v, the output, and the row LSE — and this
-                # save-everything-else policy saves exactly those, so the
-                # backward re-runs only the blockwise tile recomputation
-                # already priced into the flash backward. The LSE residual
-                # REPLACES the attn_big checkpoint: same memory contract
-                # (no quadratic residual), enforced by the kernel instead
-                # of the remat namer.
-                policy = jax.checkpoint_policies \
-                    .save_anything_except_these_names("attn_big")
-            elif c.remat_policy and c.remat_policy not in ("full",
-                                                           "nothing_saveable"):
-                policy = getattr(jax.checkpoint_policies, c.remat_policy)
-            block_fn = jax.checkpoint(block_fn, policy=policy)
-
         if layer_mask is None:
             keep = jnp.ones((c.num_layers,), c.dtype)
         else:
@@ -872,7 +867,7 @@ class TransformerLM:
             xs = xs + (jnp.asarray(self._windows, jnp.int32),)
         init = (x, positions, self._aux_zero())
         rows = None
-        if alternating:
+        if c.remat and c.remat_policy == "alternating":
             # HALF-remat: scan over layer pairs, checkpointing only the
             # first of each pair — the backward recomputes every other
             # layer (half the recompute FLOPs of full remat) while the
@@ -895,6 +890,10 @@ class TransformerLM:
                 (x, _, aux), _ = ck_fn(
                     (x, positions, aux),
                     jax.tree.map(lambda a: a[-1], xs))
+        elif c.remat:
+            ck_fn = checkpointed(block_fn, c.remat_policy, c.num_layers,
+                                 remat_budget)
+            (x, _, aux), rows = jax.lax.scan(ck_fn, init, xs)
         else:
             (x, _, aux), rows = jax.lax.scan(block_fn, init, xs)
         stats = ({} if rows is None else {"moe_expert_rows": rows},) \
@@ -942,21 +941,24 @@ class TransformerLM:
             aux = moe.aux_loss_coef * aux
         return loss + aux / self.config.num_layers
 
-    def loss(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
+    def loss(self, params: Params, batch: Dict[str, jax.Array],
+             remat_budget: Optional[Budget] = None) -> jax.Array:
         """Cross-entropy: next-token for causal LMs (labels derived by shift
         when absent), masked-LM for encoders (labels required, -100 = ignore).
         batch: input_ids [B,S], optional labels/loss_mask/token_type_ids/
-        attention_mask."""
+        attention_mask. ``remat_budget`` as in :meth:`apply`."""
         labels = self.derive_labels(batch)
         logits, aux = self.apply(params, batch["input_ids"],
                                  layer_mask=batch.get("layer_mask"),
                                  token_type_ids=batch.get("token_type_ids"),
-                                 attention_mask=batch.get("attention_mask"))
+                                 attention_mask=batch.get("attention_mask"),
+                                 remat_budget=remat_budget)
         loss = masked_cross_entropy(logits, labels,
                                     extra_mask=batch.get("loss_mask"))
         return self.combine_aux(loss, aux)
 
-    def loss_and_stats(self, params: Params, batch: Dict[str, jax.Array]
+    def loss_and_stats(self, params: Params, batch: Dict[str, jax.Array],
+                       remat_budget: Optional[Budget] = None
                        ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """``loss`` with ``apply``'s device-side statistics of the step
         (the engine keeps them on the device beside the loss)."""
@@ -964,7 +966,8 @@ class TransformerLM:
         logits, aux, stats = self.apply(
             params, batch["input_ids"], layer_mask=batch.get("layer_mask"),
             token_type_ids=batch.get("token_type_ids"),
-            attention_mask=batch.get("attention_mask"), return_stats=True)
+            attention_mask=batch.get("attention_mask"), return_stats=True,
+            remat_budget=remat_budget)
         loss = masked_cross_entropy(logits, labels,
                                     extra_mask=batch.get("loss_mask"))
         return self.combine_aux(loss, aux), stats

@@ -23,6 +23,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..runtime import topology as topo_mod
@@ -240,6 +241,7 @@ class MoE:
             logits = jnp.dot(tokens.astype(jnp.float32),
                              params["gate"].astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
+            logits = checkpoint_name(logits, "moe_logits")
             eidx, weight, losses, rows = softmax_topk_router(
                 logits, k, normalize=self.normalize_weights,
                 balance_loss=self.balance_loss)
@@ -249,15 +251,22 @@ class MoE:
         with jax.named_scope("moe/dispatch"):
             expert_in = _dispatch_rows(tokens, order, inv, k)
         with jax.named_scope("moe/experts"):
-            grouped = lambda a, w: jax.lax.ragged_dot(a, w.astype(dt), rows)
+            grouped = lambda a, name: jax.lax.ragged_dot(
+                a, params[name].astype(dt), rows)
+            # named for the block's remat policy (what the backward may
+            # keep): the activation's gradient needs the first products
+            first = lambda name: checkpoint_name(grouped(expert_in, name), name)
             if self.activation == "silu_gated":
-                mid = (jax.nn.silu(grouped(expert_in, params["wi_gate"]))
-                       * grouped(expert_in, params["wi_up"]))
+                mid = jax.nn.silu(first("wi_gate")) * first("wi_up")
             else:
-                mid = jax.nn.gelu(grouped(expert_in, params["wi"]))
-            expert_out = grouped(mid, params["wo"])
+                mid = jax.nn.gelu(first("wi"))
+            expert_out = grouped(mid, "wo")
         with jax.named_scope("moe/combine"):
-            picked = _unsort_rows(expert_out, order, inv).reshape(n_tok, k, h)
+            # the combine's gradient by the routing weights needs the
+            # experts' rows as it reads them, in assignment order: named
+            # after the gather, so a backward that keeps them gathers once
+            picked = checkpoint_name(_unsort_rows(expert_out, order, inv),
+                                     "wo").reshape(n_tok, k, h)
             out = jnp.sum(picked.astype(jnp.float32) * weight[:, :, None], axis=1)
         return out.astype(dt).reshape(b, s, h), losses, rows
 

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.pallas import tpu as pltpu
 
 NUM_LANES = 128
@@ -466,6 +467,11 @@ def _flash(cfg: FlashConfig, q, k, v, segs, slopes, info):
 
 def _flash_fwd(cfg, q, k, v, segs, slopes, info):
     o, lse = _fwd_call(cfg, q, k, v, segs[0], segs[1], slopes, info)
+    # named HERE so that the residuals are the values a checkpoint policy
+    # saves: with both kept, a rematerialised block's backward launches no
+    # ``flash_fwd`` (models/transformer.py ``remat_policy``); either costs
+    # the whole kernel to make again
+    o, lse = checkpoint_name(o, "attn_o"), checkpoint_name(lse, "attn_lse")
     return (o, lse), (q, k, v, segs, slopes, info, o, lse)
 
 

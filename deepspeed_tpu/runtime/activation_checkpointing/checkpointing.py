@@ -20,14 +20,26 @@ shims. The extra modes map as:
 
 The config-driven entry (``configure``/``checkpoint``) keeps the reference's
 module-level API so ported training code works.
+
+**What ``remat=True`` keeps** (``KEEP_PRODUCTS``, the models' default
+``remat_policy``). A block's backward keeps the values that cost a matmul or
+a kernel to make and recomputes what is elementwise or a reduction over a
+row. The producers name them with ``jax.ad_checkpoint.checkpoint_name``
+(``models/transformer.py``, ``moe/layer.py``, ``pallas_flash.py``; they import
+nothing from here), :func:`checkpointed` reads the names and their bytes off
+the block's own differentiated jaxpr, :func:`choose_saved` takes the prefix
+of :data:`SAVE_ORDER` that fits a byte budget, and :func:`saved_budget` makes
+that budget of the room the engine reads from the device when the step is
+first traced (:class:`Budget`). Without a reading everything named is saved.
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable, Optional
+import dataclasses
+from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
 import jax
+from jax.extend.core import jaxpr_as_fun
 
 from ..topology import MODEL_AXIS
 
@@ -41,10 +53,23 @@ _CONFIG = {
     "policy": "full",
 }
 
+#: the models' default ``remat_policy``: keep what a matmul or a kernel
+#: produced (the values named in :data:`SAVE_ORDER`), inside the budget
+KEEP_PRODUCTS = "matmul_and_kernel_outputs"
+
 POLICIES = {
     # save nothing; recompute everything (classic gradient checkpointing)
     "full": None,
     "nothing_saveable": None,
+    # the pair scan of models/transformer.py checkpoints every other layer
+    # whole; where a block stands alone (a pipeline stage) that is "full"
+    "alternating": None,
+    # recompute ONLY the [B, H, S, S] buffers of the XLA attention routes
+    # (named "attn_big" in ops/transformer/attention.py) and save everything
+    # else. Under the flash kernel no such buffer exists: its custom-VJP
+    # residuals are O(S) (q, k, v, the output and the row LSE)
+    "attention_only": lambda: jax.checkpoint_policies
+    .save_anything_except_these_names("attn_big"),
     # save matmul outputs (skip recomputing the big GEMMs)
     "dots_saveable": "dots_saveable",
     "checkpoint_dots": "dots_saveable",
@@ -87,12 +112,148 @@ def is_configured() -> bool:
 
 
 def resolve_policy(name: Optional[str]):
+    """An explicit policy name -> what ``jax.checkpoint(policy=)`` takes:
+    a row of ``POLICIES`` or any ``jax.checkpoint_policies`` name."""
     if not name:
         name = _CONFIG["policy"]
     mapped = POLICIES.get(name, name)
     if mapped is None:
         return None
+    if callable(mapped):
+        return mapped()
     return getattr(jax.checkpoint_policies, mapped)
+
+
+# ---------------------------------------------------------------------------
+# what remat=True keeps: named values inside a byte budget
+# ---------------------------------------------------------------------------
+
+#: The names a block's backward may keep, in the order they are taken, in
+#: groups that are kept or recomputed together (the flash kernel runs again
+#: unless both its results are kept; XLA merges matmuls that share an input,
+#: so q and k kept without v spared nothing on the chip). The order is what a
+#: kept byte spared the backward as MEASURED on the v5e in both benchmark
+#: cells (PERF.md, PR 30; ms of a step for each GB kept): the kernel's output
+#: and row statistics (22), the no-drop experts' grouped products and the
+#: router's float32 logits (19), the dense MLP's first product and the
+#: attention output projection (4.0), the q, k, v projections (3.8: their
+#: layout copies in front of the kernel are recomputed either way). A value
+#: under any other name is never kept: an XLA attention route's ``[B,H,S,S]``
+#: scores (``attn_big``) are no candidate.
+SAVE_ORDER = (("attn_lse", "attn_o"), ("moe_logits",), ("wi_gate", "wi_up"),
+              ("wi",), ("wo",), ("fc_in",), ("gate_proj", "up_proj"),
+              ("o_proj",), ("q_proj", "k_proj", "v_proj"))
+
+#: What a saved byte costs the step's peak, measured on the chip by filling
+#: the device until a step fails (PERF.md, PR 30): 1.0 to 1.2 once something
+#: is kept (the layer scan keeps a ``[layers, ...]`` stack and copies a
+#: layer's slice out of it before the backward reads it).
+STACK_COST = 1.2
+
+#: The step's working set beside the gradients and the layers' inputs (the
+#: head's logits, one block's backward, and the jump the first kept value
+#: brings), in units of one layer's input: all scale with a step's tokens.
+#: The largest measured was 90 (gpt2-large at 8 x 1024 keeping the kernel's
+#: output), the others 44 to 60.
+WORKING_CARRIES = 128
+
+
+def choose_saved(candidates: Mapping[str, int],
+                 budget_bytes: Optional[int]) -> Tuple[str, ...]:
+    """The names to save. ``candidates``: name -> bytes the value takes over
+    all layers. Whole groups are taken in :data:`SAVE_ORDER` while the
+    running total fits ``budget_bytes``: a prefix, so a cheaper group never
+    displaces a dearer one. ``None`` is no budget and saves every candidate
+    the order lists; 0 saves none. Pure: bytes and one number in, names
+    out."""
+    saved, total = [], 0
+    for group in SAVE_ORDER:
+        names = [n for n in group if n in candidates]
+        total += sum(candidates[n] for n in names)
+        if budget_bytes is not None and total > budget_bytes:
+            break
+        saved += names
+    return tuple(saved)
+
+
+def saved_budget(room_bytes: Optional[int], layers: int,
+                 carry_bytes: int) -> Optional[int]:
+    """The bytes the saved values may take, of ``room_bytes``: what the
+    device can give the step's activations (:class:`Budget`). First comes
+    what the step needs besides: every layer's input (``carry_bytes``, the
+    scan's own residual) and a working set of :data:`WORKING_CARRIES` more;
+    what is left is divided by :data:`STACK_COST`. ``None`` (no reading)
+    stays ``None``: no budget. Pure."""
+    if room_bytes is None:
+        return None
+    left = room_bytes - (layers + WORKING_CARRIES) * carry_bytes
+    return max(0, int(left / STACK_COST))
+
+
+@dataclasses.dataclass
+class Budget:
+    """What an engine hands a model's differentiated call (``remat_budget=``):
+    ``room_bytes``, what the device can give the step's activations, counted
+    over the whole batch (free memory less the gradients, times the ways the
+    mesh splits an activation), or ``None`` where it cannot be read. Each
+    :func:`checkpointed` block under ``KEEP_PRODUCTS`` writes what it decided
+    into ``totals`` while it is traced."""
+    room_bytes: Optional[int]
+    totals: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+
+def named_bytes(jaxpr) -> Dict[str, int]:
+    """name -> bytes of every ``checkpoint_name``d value in ``jaxpr`` and
+    the jaxprs inside it (not a Pallas kernel's body)."""
+    found: Dict[str, int] = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            aval = eqn.outvars[0].aval
+            found[eqn.params["name"]] = aval.size * aval.dtype.itemsize
+        elif eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found.update(named_bytes(sub))
+    return found
+
+
+def _bytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def checkpointed(block_fn: Callable, policy: Optional[str], layers: int,
+                 budget: Optional[Budget] = None) -> Callable:
+    """``jax.checkpoint(block_fn)`` under the named policy, for a
+    ``lax.scan`` of ``block_fn(carry, layer)`` over ``layers`` blocks: the
+    one builder of the models' block policy (``TransformerLM.apply`` and
+    the pipeline stage both call it). Every explicit name is
+    :func:`resolve_policy`'s. ``KEEP_PRODUCTS`` saves the named values that
+    :func:`choose_saved` admits into :func:`saved_budget`'s bytes; the names
+    and their bytes are read off the block's differentiated jaxpr (a custom
+    VJP's residuals are named in its forward rule, which only
+    differentiation traces), so ``block_fn`` itself is traced once."""
+    if policy != KEEP_PRODUCTS:
+        return jax.checkpoint(block_fn, policy=resolve_policy(policy or "full"))
+
+    def kept(carry, layer):
+        closed, out = jax.make_jaxpr(block_fn, return_shape=True)(carry, layer)
+        block, args = jaxpr_as_fun(closed), jax.tree.leaves((carry, layer))
+        named = named_bytes(jax.make_jaxpr(
+            lambda *a: jax.vjp(block, *a)[0])(*args).jaxpr)
+        candidates = {n: layers * named[n] for group in SAVE_ORDER
+                      for n in group if n in named}
+        budget_bytes = saved_budget(
+            None if budget is None else budget.room_bytes, layers, _bytes(carry))
+        saved = choose_saved(candidates, budget_bytes)
+        if budget is not None:
+            budget.totals.update(
+                policy=KEEP_PRODUCTS, saved=saved,
+                saved_bytes=sum(candidates[n] for n in saved),
+                candidate_bytes=sum(candidates.values()),
+                budget_bytes=budget_bytes)
+        flat = jax.checkpoint(block, policy=jax.checkpoint_policies
+                              .save_only_these_names(*saved))(*args)
+        return jax.tree.unflatten(jax.tree.structure(out), flat)
+    return kept
 
 
 def checkpoint(function: Callable, *args, policy: Optional[str] = None, **kwargs) -> Any:

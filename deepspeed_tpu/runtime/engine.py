@@ -31,6 +31,8 @@ boundaries exactly like the reference
 from __future__ import annotations
 
 import dataclasses
+import functools
+import inspect
 import os
 import tempfile
 from typing import Any, Dict, Iterable, Optional, Tuple
@@ -48,13 +50,15 @@ from ..utils.logging import log_dist, logger
 from ..utils.scope import scoped
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER,
                            NoopTimer, SynchronizedWallClockTimer, ThroughputTimer)
+from .activation_checkpointing import checkpointing as remat
 from .config import DeepSpeedConfig
 from .fp16.loss_scaler import (dynamic_loss_scale_state, has_overflow, static_loss_scale_state,
                                update_scale)
 from .lr_schedules import build_lr_schedule
 from .optimizers import Optimizer, build_optimizer
 from . import topology as topo_mod
-from .topology import BATCH_AXES, DATA_AXIS, MeshTopology, TopologyConfig
+from .topology import (BATCH_AXES, DATA_AXIS, SEQ_AXIS, MeshTopology,
+                       TopologyConfig)
 from .zero.partition import ZeroPartitionPlan
 
 DATA_SPEC = P(BATCH_AXES)  # batches shard their leading dim over both dp axes
@@ -541,6 +545,15 @@ class DeepSpeedEngine:
         self.opt_kernel_totals = {"path": None, "launches_native": 0,
                                   "launches_flat": 0, "elements_native": 0,
                                   "elements_flat": 0}
+        # What ``remat=True`` kept, kept with telemetry off and written
+        # while a step is traced (checkpointing.KEEP_PRODUCTS): the
+        # policy, the names saved, their bytes over all layers, the bytes
+        # of every candidate, and the budget they were held to (None where
+        # the backend reports no memory). ``policy`` None: no block was
+        # differentiated under that policy (remat off, an explicit policy,
+        # the ZeRO-3 overlap schedule).
+        self.remat_totals = {"policy": None, "saved": (), "saved_bytes": 0,
+                             "candidate_bytes": 0, "budget_bytes": None}
         # overlap-planner state (set for real when the pipelined micro
         # builds; defaults keep non-overlap engines on the plain carry)
         self._ef_carry_active = False
@@ -1052,14 +1065,67 @@ class DeepSpeedEngine:
             return None
         return np.asarray(self._step_stats["moe_expert_rows"])
 
+    @functools.cached_property
+    def _remat_room_bytes(self) -> Optional[int]:
+        """What one device can give a step's activations, read ONCE, when a
+        step is first traced with the training state on the device: what the
+        mesh's fullest device reports free, less the gradients. Whatever
+        else is resident then (an evaluation batch, a harness's buffers)
+        counts as staying. None where the backend reports no memory (the
+        CPU): everything named is saved. Across processes every host must
+        decide alike, so until the reading is exchanged a multi-process run
+        gets 0: the block recomputed whole."""
+        stats = [d.memory_stats() for d in self.mesh.devices.flat
+                 if d.process_index == jax.process_index()]
+        if not stats or not all(s and "bytes_limit" in s for s in stats):
+            return None
+        if jax.process_count() > 1:
+            return 0
+        free = min(s["bytes_limit"] - s["bytes_in_use"] for s in stats)
+        width = jnp.dtype(self.grad_dtype).itemsize
+        grads = sum(p.size * max(p.dtype.itemsize, width)
+                    for p in jax.tree.leaves(self.state["params"]))
+        log_dist(f"remat room: {free / 1e9:.2f} GB free a device, "
+                 f"{grads / 1e9:.2f} GB of gradients", ranks=[0])
+        return max(0, free - grads)
+
+    def _remat_kw(self, local: bool = False) -> Dict[str, Any]:
+        """The ``remat_budget=`` argument for a differentiated call of the
+        model's loss, where it takes one (``checkpointing.Budget``: what its
+        blocks' backward may keep, and ``remat_totals`` to write the
+        decision into). A model's bytes are whole-batch, so the device's
+        room counts once for each way the mesh splits an activation;
+        ``local``: the call sits inside a ``shard_map`` and sees one
+        device's share."""
+        if "remat_budget" not in inspect.signature(self.model.loss).parameters:
+            return {}
+        room = self._remat_room_bytes
+        if room is not None and not local:
+            room *= int(np.prod([self.mesh.shape[a]
+                                 for a in BATCH_AXES + (SEQ_AXIS,)
+                                 if a in self.mesh.shape]))
+        return {"remat_budget": remat.Budget(room, self.remat_totals)}
+
     def _loss_and_stats(self, params, batch):
         """(loss, stats): the model's loss and, as a tuple of one, its
         device-side step statistics where the fused step returns them
         (``_step_has_stats``); else the empty tuple."""
+        traced = self.remat_totals["policy"] is not None
         if self._step_has_stats:
-            loss, stats = self.model.loss_and_stats(params, batch)
-            return loss, (stats,)
-        return self.model.loss(params, batch), ()
+            loss, stats = self.model.loss_and_stats(params, batch,
+                                                    **self._remat_kw())
+            out = loss, (stats,)
+        else:
+            out = self.model.loss(params, batch, **self._remat_kw()), ()
+        kept = self.remat_totals
+        if kept["policy"] is not None and not traced:
+            log_dist(f"remat keeps {', '.join(kept['saved']) or 'nothing'}: "
+                     f"{kept['saved_bytes'] / 1e6:.1f} of "
+                     f"{kept['candidate_bytes'] / 1e6:.1f} MB"
+                     + ("" if kept["budget_bytes"] is None else
+                        f" (budget {kept['budget_bytes'] / 1e6:.1f} MB)"),
+                     ranks=[0])
+        return out
 
     def _micro_step_fn(self, state, batch, with_stats: bool = False):
         """Scaled loss + grads, accumulated. Returns (state, loss), and
@@ -1282,7 +1348,7 @@ class DeepSpeedEngine:
 
         def local_micro(params, gacc, scale, batch):
             def scaled_loss(p):
-                loss = model.loss(p, batch)
+                loss = model.loss(p, batch, **self._remat_kw(local=True))
                 return loss * (scale / gas), loss
 
             grads, loss = jax.grad(scaled_loss, has_aux=True)(params)
@@ -1535,7 +1601,7 @@ class DeepSpeedEngine:
                                 is_leaf=lambda s: isinstance(s, P))
 
             def scaled_loss(p):
-                loss = model.loss(p, batch)
+                loss = model.loss(p, batch, **self._remat_kw(local=True))
                 return loss * (scale / gas), loss
 
             grads, loss = jax.grad(scaled_loss, has_aux=True)(full)

@@ -27,6 +27,7 @@ non-pipe dims) works unchanged — the counterpart of DeepSpeed selecting
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Tuple
 
 import jax
@@ -34,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...models.transformer import ACT_SPEC, TransformerConfig, TransformerLM, _c
+from ..activation_checkpointing.checkpointing import checkpointed
 from ..topology import PIPE_AXIS
 
 
@@ -78,30 +80,32 @@ class PipelineModule:
         return specs
 
     # -- pipelined forward ---------------------------------------------------
-    def _stage_fn(self, stage_blocks, x, positions):
+    def _stage_fn(self, stage_blocks, x, positions, passes: int = 1,
+                  remat_budget=None):
         """Run this stage's layer slice (a scan like the dense model)."""
         def block_fn(carry, block):
             # attn_mask=None: PP drives causal decoder stages (encoders with
             # padding masks aren't pipelined)
             return self._lm._block_fn(
                 None, carry, (block, jnp.asarray(1.0, self.config.dtype)))
-        if self.config.remat:
-            policy = None
-            if self.config.remat_policy == "alternating":
-                # the pair-scan half-remat lives in the dense model's layer
-                # scan (transformer.py apply); a pipeline stage's slice may
-                # be a single layer, so it degrades to full remat here
-                pass
-            elif self.config.remat_policy and self.config.remat_policy not in ("full", "nothing_saveable"):
-                policy = getattr(jax.checkpoint_policies, self.config.remat_policy)
-            block_fn = jax.checkpoint(block_fn, policy=policy)
-        (x, _, aux), _ = jax.lax.scan(
-            block_fn, (x, positions, jnp.zeros((), jnp.float32)), stage_blocks)
+        init = (x, positions, jnp.zeros((), jnp.float32))
+        if not self.config.remat:
+            (x, _, aux), _ = jax.lax.scan(block_fn, init, stage_blocks)
+            return x, aux
+        # the dense model's block policy from the same configuration (its
+        # "alternating" pair scan needs two layers; a stage's slice may be
+        # one, so it is full remat here). What a stage saves it saves once
+        # for every pass of the schedule through it
+        layers = jax.tree.leaves(stage_blocks)[0].shape[0]
+        ck_fn = checkpointed(block_fn, self.config.remat_policy,
+                             layers * passes, remat_budget)
+        (x, _, aux), _ = jax.lax.scan(ck_fn, init, stage_blocks)
         return x, aux
 
     def apply(self, params: Dict[str, Any], input_ids: jax.Array,
               layer_mask=None, token_type_ids=None,
-              attention_mask=None) -> Tuple[jax.Array, jax.Array]:
+              attention_mask=None, remat_budget=None
+              ) -> Tuple[jax.Array, jax.Array]:
         assert layer_mask is None, \
             "progressive layer drop is not supported under pipeline parallelism"
         assert token_type_ids is None and attention_mask is None, \
@@ -138,6 +142,8 @@ class PipelineModule:
         aux_total = jnp.zeros((), jnp.float32)
 
         stage_ids = jnp.arange(Pst)
+        stage_fn = functools.partial(self._stage_fn, passes=ticks,
+                                     remat_budget=remat_budget)
 
         def tick(carry, t):
             buf, out_mb, aux_total = carry
@@ -152,7 +158,7 @@ class PipelineModule:
             feed = jnp.where(feed_idx >= 0, feed, jnp.zeros_like(feed))
             inp = shifted.at[0].set(feed)
             # every stage computes in parallel (stage dim sharded over pipe)
-            out, aux = jax.vmap(self._stage_fn, in_axes=(0, 0, None))(
+            out, aux = jax.vmap(stage_fn, in_axes=(0, 0, None))(
                 params["blocks"], inp, positions)
             # last stage emits the scheduled microbatch during drain
             emit_idx = emit_plan[t]
@@ -184,5 +190,6 @@ class PipelineModule:
     derive_labels = TransformerLM.derive_labels
     combine_aux = TransformerLM.combine_aux
 
-    def loss(self, params: Dict[str, Any], batch: Dict[str, jax.Array]) -> jax.Array:
-        return TransformerLM.loss(self, params, batch)  # same loss math
+    def loss(self, params: Dict[str, Any], batch: Dict[str, jax.Array],
+             remat_budget=None) -> jax.Array:
+        return TransformerLM.loss(self, params, batch, remat_budget)  # same loss math

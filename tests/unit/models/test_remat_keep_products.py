@@ -1,0 +1,386 @@
+"""What ``remat=True`` keeps by default (``checkpointing.KEEP_PRODUCTS``):
+the block's backward saves what a matmul or a kernel produced, inside a
+byte budget, and recomputes norms, rope, activations and residual adds.
+
+A saved value is the value the recomputation would have produced, so
+gradients must equal those of an explicit ``"nothing_saveable"`` bit for
+bit; what changes is the program, which the jaxpr checks read."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import deepspeed_tpu
+from deepspeed_tpu.models import gpt2_model, llama_model, olmoe_model
+from deepspeed_tpu.runtime.activation_checkpointing import checkpointing
+from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
+    KEEP_PRODUCTS, SAVE_ORDER, STACK_COST, WORKING_CARRIES, Budget,
+    choose_saved, saved_budget)
+
+SEQ = 128   # the interpreted flash kernel's smallest tile
+
+
+def _model(family: str, policy: str, dtype=jnp.float32):
+    kw = dict(max_seq_len=SEQ, vocab_size=256, remat=True, remat_policy=policy,
+              dtype=dtype)
+    if family == "dense":        # learned positions, LayerNorm, GELU, biases
+        return gpt2_model("gpt2-tiny", **kw)
+    if family == "rope_gated":   # rope, RMSNorm, gated SiLU, GQA
+        return llama_model("llama2-tiny", **kw)
+    return olmoe_model("olmoe-tiny", **kw)   # no-drop MoE, QK-norm
+
+
+def _batch(rows: int = 2):
+    ids = np.random.default_rng(0).integers(0, 256, size=(rows, SEQ))
+    return {"input_ids": jnp.asarray(ids, jnp.int32)}
+
+
+def _grads(model, room=None, dtype=jnp.float32):
+    """((loss, gradients), the decision's totals) under an engine's
+    reading ``room`` of the device; None saves everything named, as a
+    model without an engine does."""
+    params = model.init(jax.random.PRNGKey(0), dtype)
+    budget = Budget(room)
+    fn = jax.jit(jax.value_and_grad(
+        lambda p: model.loss(p, _batch(), remat_budget=budget)))
+    return fn(params), budget.totals
+
+
+def _room_for(model, saved_bytes: int) -> int:
+    """The engine's reading under which ``saved_budget`` comes to
+    ``saved_bytes``: the inverse of that function at the model's shapes."""
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.float32))
+    carry = jax.eval_shape(lambda p: model.embed(p, _batch()["input_ids"], None)
+                           + (model._aux_zero(),), params)
+    layers = model.config.num_layers
+    return ((layers + WORKING_CARRIES) * checkpointing._bytes(carry)
+            + int(np.ceil(saved_bytes * STACK_COST)))
+
+
+def _assert_same_bits(a, b):
+    (loss_a, grads_a), (loss_b, grads_b) = a, b
+    assert float(loss_a) == float(loss_b)
+    for x, y in zip(jax.tree.leaves(grads_a), jax.tree.leaves(grads_b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+# -- (a) the gradients are the recomputing program's, bit for bit -----------
+
+@pytest.mark.parametrize("family,attn", [
+    ("dense", "xla"), ("dense", "pallas"),
+    ("rope_gated", "xla"), ("rope_gated", "pallas"),
+    ("moe_qk_norm", "xla"), ("moe_qk_norm", "pallas"),
+])
+def test_gradients_equal_nothing_saveable(monkeypatch, family, attn):
+    monkeypatch.setenv("DSTPU_ATTN", attn)   # pallas: the kernel, interpreted
+    kept, _ = _grads(_model(family, KEEP_PRODUCTS))
+    recomputed, _ = _grads(_model(family, "nothing_saveable"))
+    _assert_same_bits(kept, recomputed)
+
+
+@pytest.mark.parametrize("family", ["dense", "rope_gated", "moe_qk_norm"])
+def test_gradients_equal_under_a_partial_budget(monkeypatch, family):
+    """A budget that admits some names and not others changes what is
+    saved and not one bit of the gradients."""
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    model = _model(family, KEEP_PRODUCTS)
+    _, everything = _grads(model)
+    half = everything["candidate_bytes"] // 2
+    got, kept = _grads(model, _room_for(model, half))
+    assert 0 < len(kept["saved"]) < len(everything["saved"])
+    assert kept["saved"] == everything["saved"][:len(kept["saved"])]
+    assert 0 < kept["saved_bytes"] <= half == kept["budget_bytes"]
+    recomputed, _ = _grads(_model(family, "nothing_saveable"))
+    _assert_same_bits(got, recomputed)
+
+
+@pytest.mark.parametrize("family", ["dense", "rope_gated", "moe_qk_norm"])
+def test_no_room_is_the_recomputing_program(monkeypatch, family):
+    """A device with no room saves nothing: the program of an explicit
+    ``"nothing_saveable"``, jaxpr for jaxpr."""
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    _, kept = _grads(_model(family, KEEP_PRODUCTS), 0)
+    assert kept["saved"] == () and kept["saved_bytes"] == 0 == kept["budget_bytes"]
+    assert kept["candidate_bytes"] > 0
+    assert _recomputed(_model(family, KEEP_PRODUCTS), Budget(0)) \
+        == _recomputed(_model(family, "nothing_saveable"))
+
+
+@pytest.mark.parametrize("family", ["dense", "rope_gated", "moe_qk_norm"])
+def test_bfloat16_gradients_within_a_rounding(monkeypatch, family):
+    """In bfloat16 a kept value is rounded to bfloat16 where it is named,
+    where the recomputing program's fusions may carry float32 across the
+    same point (XLA's excess precision; on the chip the first step's
+    compared numbers move in the fourth digit, PERF.md PR 30). The
+    difference is a rounding, not another gradient: every leaf within 2 %
+    of its own norm, the loss within 2e-3."""
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    bf16 = jnp.bfloat16
+    (loss_a, grads_a), _ = _grads(_model(family, KEEP_PRODUCTS, bf16), dtype=bf16)
+    (loss_b, grads_b), _ = _grads(_model(family, "nothing_saveable", bf16), dtype=bf16)
+    assert abs(float(loss_a) - float(loss_b)) <= 2e-3
+    for x, y in zip(jax.tree.leaves(grads_a), jax.tree.leaves(grads_b)):
+        x, y = np.asarray(x, np.float32), np.asarray(y, np.float32)
+        assert np.linalg.norm(x - y) <= 0.02 * np.linalg.norm(y) + 1e-6
+
+
+# -- (b) the decision, two pure functions ------------------------------------
+
+MB = 1 << 20
+#: name -> bytes over all layers: a kernel's output and its row statistics,
+#: four matmul outputs, and an XLA route's [B, H, S, S] scores (named
+#: "attn_big", which SAVE_ORDER does not list)
+CANDIDATES = {
+    "q_proj": 10 * MB, "k_proj": 10 * MB, "v_proj": 10 * MB, "fc_in": 40 * MB,
+    "attn_o": 10 * MB, "attn_lse": MB // 4, "wo": 80 * MB, "attn_big": 2 * MB,
+}
+RANKED = ("attn_lse", "attn_o", "wo", "fc_in", "q_proj", "k_proj", "v_proj")
+KERNEL = 10 * MB + MB // 4
+
+
+@pytest.mark.parametrize("budget,saved", [
+    (None, RANKED),                            # no budget: every name listed
+    (0, ()),                                   # no room: the parent's program
+    (10 * MB, ()),                             # the kernel's two or neither
+    (KERNEL, RANKED[:2]),
+    # wo does not fit and fc_in, cheaper per byte, may not jump the queue
+    (KERNEL + 50 * MB, RANKED[:2]),
+    (KERNEL + 80 * MB, RANKED[:3]),
+    (KERNEL + 120 * MB - 1, RANKED[:3]),
+    (KERNEL + 120 * MB, RANKED[:4]),
+    # q and k without v spare nothing (one merged matmul): all three or none
+    (KERNEL + 140 * MB, RANKED[:4]),
+    (KERNEL + 150 * MB, RANKED),
+    (1 << 40, RANKED),                         # and never the scores
+])
+def test_choose_saved_table(budget, saved):
+    assert choose_saved(CANDIDATES, budget) == saved
+
+
+def test_choose_saved_takes_the_measured_order():
+    names = tuple(n for group in SAVE_ORDER for n in group)
+    every = {n: MB for n in names}
+    assert choose_saved(every, None) == names
+    assert choose_saved(every, 2 * MB) == ("attn_lse", "attn_o")
+    assert choose_saved({}, None) == () == choose_saved({}, 0)
+    # the projections in front of the kernel go first when room runs out
+    assert SAVE_ORDER[-1] == ("q_proj", "k_proj", "v_proj")
+    assert len(set(names)) == len(names)
+
+
+@pytest.mark.parametrize("room,want", [
+    (None, None),                                        # no reading: no budget
+    (0, 0),
+    ((36 + WORKING_CARRIES) * 10 * MB, 0),               # the step's own needs
+    ((36 + WORKING_CARRIES) * 10 * MB + 18 * MB, int(18 * MB / STACK_COST)),
+    (1 << 40, int(((1 << 40) - (36 + WORKING_CARRIES) * 10 * MB) / STACK_COST)),
+])
+def test_saved_budget_table(room, want):
+    """Room less every layer's input and the working set, over what a
+    saved byte costs the step's peak."""
+    assert saved_budget(room, layers=36, carry_bytes=10 * MB) == want
+
+
+@pytest.mark.parametrize("family", ["dense", "rope_gated", "moe_qk_norm"])
+def test_scores_are_never_a_candidate(monkeypatch, family):
+    """The XLA routes' [B, H, S, S] tensors stay recomputed: with no budget
+    at all the saved names are the projections' and the experts' alone."""
+    monkeypatch.setenv("DSTPU_ATTN", "xla")
+    _, kept = _grads(_model(family, KEEP_PRODUCTS))
+    assert "attn_big" not in kept["saved"]
+    assert not {"attn_o", "attn_lse"} & set(kept["saved"])   # the kernel's
+    rows, heads = 2, _model(family, KEEP_PRODUCTS).config.num_heads
+    assert kept["candidate_bytes"] < rows * heads * SEQ * SEQ * 4 * 2 * 8
+
+
+def test_every_name_a_producer_gives_is_in_the_order(monkeypatch):
+    """A value named in the block and missing from SAVE_ORDER would never
+    be kept, silently: the three families' names are all listed (the
+    scores apart), the kernel's two among them."""
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    for family in ("dense", "rope_gated", "moe_qk_norm"):
+        _, kept = _grads(_model(family, KEEP_PRODUCTS))
+        assert {"attn_o", "attn_lse", "q_proj", "o_proj"} <= set(kept["saved"])
+        assert set(kept["saved"]) <= {n for group in SAVE_ORDER for n in group}
+    assert {"wi_gate", "wi_up", "wo", "moe_logits"} <= set(kept["saved"])
+
+
+# -- (c) what the program is: the backward's recomputation -------------------
+
+def _recomputed(model, budget=None):
+    """Counts of the primitives (and Pallas kernel names) inside the
+    differentiated ``remat2`` equations of the loss's gradient: what the
+    backward of a block runs, recomputation and gradient products."""
+    params = model.init(jax.random.PRNGKey(0), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: model.loss(p, _batch(), remat_budget=budget)))(params)
+    counts: dict = {}
+
+    def walk(jx, inside):
+        for eqn in jx.eqns:
+            here = inside or (eqn.primitive.name == "remat2"
+                              and eqn.params.get("differentiated"))
+            if inside:
+                key = eqn.primitive.name
+                if key == "pallas_call":
+                    key = eqn.params["name"]
+                counts[key] = counts.get(key, 0) + 1
+            if eqn.primitive.name == "pallas_call":
+                continue    # a kernel's own body is not the block's program
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, here)
+    walk(jaxpr.jaxpr, False)
+    return counts
+
+
+@pytest.mark.parametrize("family,matmuls,named", [
+    ("dense", 6, 5),         # q k v o fc_in fc_out; fc_out's result feeds an add
+    ("rope_gated", 7, 6),    # q k v o gate up down
+])
+def test_backward_recomputes_no_matmul_and_no_kernel(monkeypatch, family,
+                                                     matmuls, named):
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    kept = _recomputed(_model(family, KEEP_PRODUCTS))
+    full = _recomputed(_model(family, "nothing_saveable"))
+    # two gradient products a matmul, and with everything saved no more
+    assert kept["dot_general"] == 2 * matmuls
+    assert full["dot_general"] == 2 * matmuls + named
+    assert kept.get("flash_fwd", 0) == 0 and full["flash_fwd"] == 1
+    assert kept["flash_bwd"] == full["flash_bwd"] == 1
+    # what stays recomputed is elementwise or a reduction over a row
+    assert kept["rsqrt"] == full["rsqrt"] > 0
+
+
+def test_backward_recomputes_no_grouped_matmul(monkeypatch):
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    kept = _recomputed(_model("moe_qk_norm", KEEP_PRODUCTS))
+    full = _recomputed(_model("moe_qk_norm", "nothing_saveable"))
+    grouped = lambda c: sum(v for k, v in c.items() if k.startswith("ragged_dot"))
+    assert grouped(kept) == 6          # two gradient products for each of three
+    assert grouped(full) == 9          # and the three forward products again
+    assert kept.get("flash_fwd", 0) == 0 and full["flash_fwd"] == 1
+    # q k v o and the router: their products and nothing recomputed
+    assert kept["dot_general"] == 2 * 5
+    assert full["dot_general"] == 2 * 5 + 5
+    # the router's top-k and the sort are recomputed under both
+    assert kept["top_k"] == full["top_k"] == 1
+    assert kept["sort"] == full["sort"] >= 1
+
+
+@pytest.mark.parametrize("policy", ["nothing_saveable", "full", "attention_only",
+                                    "alternating", "dots_saveable"])
+def test_explicit_policies_record_nothing(policy):
+    """The knob keeps its meanings: under an explicit policy no value is
+    chosen by budget, whatever the tags."""
+    _, kept = _grads(_model("dense", policy), 0)
+    assert kept == {}
+
+
+@pytest.mark.parametrize("policy", [KEEP_PRODUCTS, "nothing_saveable",
+                                    "attention_only", "alternating"])
+def test_pipeline_stage_builds_the_same_policy(policy):
+    """One builder: a pipeline stage's layer slice under each policy gives
+    the gradients of the slice without remat, and under the default one the
+    budget's report names the stage's own layer count."""
+    from deepspeed_tpu.models import gpt2_config
+    from deepspeed_tpu.runtime.pipe.module import PipelineModule
+
+    def stage_grads(remat):
+        cfg = gpt2_config("gpt2-tiny", num_layers=4, max_seq_len=32,
+                          vocab_size=256, remat=remat, remat_policy=policy)
+        pipe = PipelineModule(cfg, num_stages=2)
+        blocks = jax.tree.map(lambda a: a[0], pipe.init(jax.random.PRNGKey(0))["blocks"])
+        x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 128))
+        budget = Budget(None)
+        loss = lambda b, v: jnp.sum(pipe._stage_fn(
+            b, v, jnp.arange(32)[None], passes=3, remat_budget=budget)[0] ** 2)
+        return jax.jit(jax.grad(loss, (0, 1)))(blocks, x), budget.totals
+
+    (want, _), (got, kept) = stage_grads(False), stage_grads(True)
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+    if policy == KEEP_PRODUCTS:
+        # 2 of the 4 layers, kept once for each of the schedule's 3 passes
+        # through the stage, x [2, 32] x 1024 wide x float32
+        assert kept["saved_bytes"] == 2 * 3 * 2 * 32 * 1024 * 4
+    else:
+        assert kept == {}
+
+
+# -- (d) the engine's counter -------------------------------------------------
+
+def test_engine_remat_totals(eight_devices):
+    model = gpt2_model("gpt2-tiny", max_seq_len=32, vocab_size=256)
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, config={
+        "train_micro_batch_size_per_gpu": 1,
+        "zero_optimization": {"stage": 1},
+        "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
+    assert engine.remat_totals["policy"] is None      # nothing traced yet
+    batch = {"input_ids": np.random.default_rng(0).integers(0, 256, size=(8, 32))}
+    engine.train_batch(batch)
+    totals = engine.remat_totals
+    assert totals["policy"] == KEEP_PRODUCTS
+    assert totals["saved"] == ("fc_in", "o_proj", "q_proj", "k_proj", "v_proj")
+    assert totals["budget_bytes"] is None             # the CPU reports no memory
+    # [8, 32] tokens x (4 x 128 + 512) wide x float32 x 2 layers
+    assert totals["saved_bytes"] == totals["candidate_bytes"] == 8 * 32 * 1024 * 4 * 2
+    engine.train_batch(batch)
+    assert engine.remat_totals is totals              # set once
+
+
+def test_engine_remat_totals_explicit_policy(eight_devices):
+    model = gpt2_model("gpt2-tiny", max_seq_len=32, vocab_size=256,
+                       remat_policy="nothing_saveable")
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, config={
+        "train_micro_batch_size_per_gpu": 1,
+        "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
+    engine.train_batch({"input_ids": np.zeros((8, 32), np.int32)})
+    assert engine.remat_totals["policy"] is None and engine.remat_totals["saved"] == ()
+
+
+def test_engine_reads_its_room_from_the_device(eight_devices):
+    """limit less in use less gradients, times the ways the mesh splits the
+    batch; read once, and the step is then traced under the budget that
+    ``saved_budget`` makes of it."""
+    import types
+    model = gpt2_model("gpt2-tiny", max_seq_len=32, vocab_size=256)
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, config={
+        "train_micro_batch_size_per_gpu": 1,
+        "zero_optimization": {"stage": 1},
+        "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
+    grads = sum(p.size * 4 for p in jax.tree.leaves(engine.state["params"]))
+    ids = np.zeros((8, 32), np.int32)
+    carry = checkpointing._bytes(jax.eval_shape(
+        lambda p: model.embed(p, ids, None) + (model._aux_zero(),),
+        engine.state["params"]))
+    wide = lambda width: 2 * 8 * 32 * width * 4      # 2 layers x [8, 32] x float32
+    # room for fc_in and o_proj, and not for the three projections
+    room = (2 + WORKING_CARRIES) * carry \
+        + int(np.ceil((wide(512 + 128) + 8) * STACK_COST))
+    room += -room % 8
+    free = grads + room // 8
+
+    class Device:
+        process_index = jax.process_index()
+
+        def __init__(self, in_use):
+            self.in_use = in_use
+
+        def memory_stats(self):
+            return {"bytes_limit": 10 * free, "bytes_in_use": self.in_use}
+
+    real = engine.mesh
+    # the fullest device decides
+    engine.mesh = types.SimpleNamespace(
+        devices=np.array([Device(9 * free), Device(8 * free)], dtype=object),
+        shape=real.shape)
+    assert engine._remat_room_bytes == room // 8
+    engine.mesh = real
+    engine.train_batch({"input_ids": ids})
+    totals = engine.remat_totals
+    assert totals["budget_bytes"] == saved_budget(room, 2, carry)
+    assert totals["saved"] == ("fc_in", "o_proj")
+    assert totals["saved_bytes"] == wide(640) <= totals["budget_bytes"] < wide(1024)
+    assert totals["candidate_bytes"] == wide(1024)

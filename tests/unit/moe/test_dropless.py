@@ -101,8 +101,9 @@ def route_of(moe, params, x):
 
 @pytest.mark.parametrize("num_experts,top_k", CASES)
 def test_gradients_under_checkpoint(num_experts, top_k):
-    """Rematerialised (the benchmark's cell trains with full remat): the
-    custom backward of the two row movements replays the same numbers."""
+    """Rematerialised whole (an explicit ``"nothing_saveable"``, or a
+    budget with no room, recomputes the layer): the custom backward of the
+    two row movements replays the same numbers."""
     moe = layer(num_experts, top_k, normalize_weights=False)
     params, x = skewed(moe, seed=1)
     plain = jax.grad(lambda p, v: probe(run_layer, moe, p, v), (0, 1))(params, x)

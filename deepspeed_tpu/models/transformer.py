@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -97,11 +98,79 @@ class MoEConfig:
     normalize_weights: bool = True       # HF norm_topk_prob (OLMoE: False)
     balance_loss: str = "gshard_top1"    # | 'topk_share' (OLMoE, Switch)
     z_loss_coef: float = 0.0             # router z-loss (no-drop path only)
+    # the no-drop path's DeepSeek-V3 form (``moe/layer.py::MoE`` has each
+    # field's meaning): sigmoid affinities chosen under a correction bias
+    # that ``bias_update`` moves by load after each step (``noaux_tc``; no
+    # gradient, no Adam, no decay), the chosen affinities times
+    # ``routed_scale``, a shared expert of ``shared_width`` beside the
+    # routed ones, the sequence-wise balance loss under
+    # ``seq_balance_coef`` (summed over the layers; ``aux_loss_coef`` and
+    # ``z_loss_coef`` are the softmax router's), and the range of experts
+    # this chip holds of a layer that several chips share (None: all)
+    router: str = "softmax"              # | 'sigmoid_bias'
+    routed_scale: float = 1.0
+    shared_width: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    seq_balance_coef: float = 0.0
+    bias_update: float = 0.0
 
     def __post_init__(self):
         if self.capacity_factor is not None and self.z_loss_coef:
             raise ValueError("the router z-loss is the no-drop path's "
                              "(capacity_factor=None)")
+        if self.router != "sigmoid_bias" and (self.seq_balance_coef
+                                              or self.bias_update):
+            raise ValueError("the sequence-wise balance loss and the bias "
+                             "update are the 'sigmoid_bias' router's")
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN's rotary frequencies (Peng et al. 2023, as DeepSeek-V3 and HF
+    ``rope_scaling: {"type": "yarn"}`` use them): frequency ``i`` of a
+    rotary width ``d`` is divided by ``factor`` where its wavelength is
+    long (``i >= high``), left alone where it is short (``i <= low``) and
+    blended linearly between, ``low`` / ``high`` the indices whose
+    wavelengths fit ``beta_fast`` / ``beta_slow`` turns into
+    ``original_max_position`` positions. Attention scores are scaled by
+    ``softmax_scale ** 2`` more (``0.1 mscale_all_dim ln(factor) + 1``),
+    and cos and sin by the ratio of the two mscales."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def band(self, dim: int, theta: float) -> Tuple[int, int]:
+        """(low, high): the frequency indices between which the blend runs."""
+        at = lambda turns: (dim * math.log(self.original_max_position
+                                           / (turns * 2 * math.pi))
+                            / (2 * math.log(theta)))
+        return (max(math.floor(at(self.beta_fast)), 0),
+                min(math.ceil(at(self.beta_slow)), dim - 1))
+
+    def frequencies(self, dim: int, theta: float) -> jax.Array:
+        """The ``dim // 2`` scaled inverse frequencies, float32."""
+        i = jnp.arange(dim // 2, dtype=jnp.float32)
+        freqs = theta ** (-2.0 * i / dim)
+        low, high = self.band(dim, theta)
+        ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return freqs * (1.0 - ramp) + freqs / self.factor * ramp
+
+    @staticmethod
+    def _mscale(factor: float, m: float) -> float:
+        return 1.0 if factor <= 1.0 or not m else 0.1 * m * math.log(factor) + 1.0
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the attention scores' scale is multiplied by, squared."""
+        return self._mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,8 +225,41 @@ class TransformerConfig:
     # [heads*head_dim] and k vector [kv_heads*head_dim], before the head
     # split and rope (HF OlmoeAttention q_norm / k_norm)
     qk_norm: bool = False
+    # with qk_norm: RMSNorm over each HEAD's vector of q and of k instead,
+    # one gain of head_dim shared by the heads, before rope
+    qk_norm_per_head: bool = False
     moe: Optional[MoEConfig] = None
     moe_layer_freq: int = 1          # every k-th layer is MoE when moe is set
+    # 'latent' (DeepSeek-V2/V3 multi-head latent attention, no query
+    # compression): keys and values come from ONE compressed vector a token
+    # (``kv_latent_rank`` wide, normed) through an up-projection, a head's
+    # query and key are ``qk_nope_dim`` such values and ``qk_rope_dim``
+    # rotated ones (the key's rotated part is one vector shared by the
+    # heads), its value ``v_head_dim`` wide. 'mha': the projections above.
+    attention: str = "mha"
+    kv_latent_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[YarnScaling] = None
+    # a sigmoid gate on the attention output, element-wise, from the
+    # sub-block's input (Qiu et al. 2025): o_proj(attn * sigmoid(x W_g))
+    attn_gate: bool = False
+    # FarSkip-Collective's residual (Dukler et al. 2025): sub-block i reads
+    # the stream as it stood BEFORE sub-block i-1 added to it,
+    # r_i = r_(i-1) + f_i(norm(r_(i-2))), r_(-1) = r_0
+    farskip: bool = False
+    # the first layers' MLP is dense, ``dense_intermediate_size`` wide, where
+    # the others have experts (DeepSeek's first_k_dense_replace); they run
+    # before the scan with parameters of their own (``dense_blocks``)
+    first_dense_layers: int = 0
+    dense_intermediate_size: Optional[int] = None
+    # multi-token prediction (DeepSeek-V3 report, section 2.2): one module
+    # after the last block predicts the token after next from the final
+    # stream and the next token's embedding, through a block of its own and
+    # the shared embedding and head; its loss counts ``mtp_loss_coef`` times
+    mtp_layers: int = 0
+    mtp_loss_coef: float = 0.3
 
     @property
     def kv_heads(self) -> int:
@@ -165,7 +267,14 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.attention == "latent":
+            return self.qk_nope_dim + self.qk_rope_dim
         return self.hidden_size // self.num_heads
+
+    @property
+    def scan_layers(self) -> int:
+        """The layers the scan runs: all but the leading dense ones."""
+        return self.num_layers - self.first_dense_layers
 
     @property
     def ffn_size(self) -> int:
@@ -276,22 +385,50 @@ class TransformerLM:
         attn_bias = c.attn_bias if c.attn_bias is not None else use_bias
         attn_out_bias = (c.attn_out_bias if c.attn_out_bias is not None
                          else attn_bias)
-        kv_out = c.kv_heads * c.head_dim
-        self._block_layers = {
-            "ln_1": norm_cls(c.hidden_size),
-            "q_proj": nn.Linear(c.hidden_size, c.hidden_size, use_bias=attn_bias, shard="column"),
-            "k_proj": nn.Linear(c.hidden_size, kv_out, use_bias=attn_bias, shard="column"),
-            "v_proj": nn.Linear(c.hidden_size, kv_out, use_bias=attn_bias, shard="column"),
-            "o_proj": nn.Linear(c.hidden_size, c.hidden_size, use_bias=attn_out_bias, shard="row"),
-        }
-        if c.qk_norm:
-            self._block_layers["q_norm"] = nn.RMSNorm(c.hidden_size, eps=c.norm_eps)
+        lin = lambda i, o, bias, shard: nn.Linear(i, o, use_bias=bias, shard=shard)
+        if c.attention == "latent":
+            self._check_latent()
+            q_out = c.num_heads * c.head_dim
+            attn_out = c.num_heads * c.v_head_dim
+            attn_layers = {
+                "q_proj": lin(c.hidden_size, q_out, False, "column"),
+                # the compressed keys and values with the shared rotary key
+                "kv_a_proj": lin(c.hidden_size, c.kv_latent_rank + c.qk_rope_dim, False, None),
+                "kv_a_norm": nn.RMSNorm(c.kv_latent_rank, eps=c.norm_eps),
+                "kv_b_proj": lin(c.kv_latent_rank,
+                                 c.num_heads * (c.qk_nope_dim + c.v_head_dim), False, "column"),
+                "o_proj": lin(attn_out, c.hidden_size, False, "row"),
+            }
+        else:
+            kv_out = c.kv_heads * c.head_dim
+            q_out = attn_out = c.hidden_size
+            attn_layers = {
+                "q_proj": lin(c.hidden_size, c.hidden_size, attn_bias, "column"),
+                "k_proj": lin(c.hidden_size, kv_out, attn_bias, "column"),
+                "v_proj": lin(c.hidden_size, kv_out, attn_bias, "column"),
+                "o_proj": lin(c.hidden_size, c.hidden_size, attn_out_bias, "row"),
+            }
+        self._block_layers = {"ln_1": norm_cls(c.hidden_size), **attn_layers}
+        if c.qk_norm and c.qk_norm_per_head:
+            self._block_layers["q_norm"] = nn.RMSNorm(c.head_dim, eps=c.norm_eps)
+            self._block_layers["k_norm"] = nn.RMSNorm(c.head_dim, eps=c.norm_eps)
+        elif c.qk_norm:
+            self._block_layers["q_norm"] = nn.RMSNorm(q_out, eps=c.norm_eps)
             self._block_layers["k_norm"] = nn.RMSNorm(kv_out, eps=c.norm_eps)
+        if c.attn_gate:
+            self._block_layers["attn_gate"] = lin(c.hidden_size, attn_out, False, "column")
         if not c.parallel_block or c.parallel_norms:
             # parallel blocks (falcon-7b/phi) feed attention and MLP from the
             # SAME normed input — no second norm exists in the checkpoint;
             # falcon-40b's "new decoder" norms each parallel branch separately
             self._block_layers["ln_2"] = norm_cls(c.hidden_size)
+        gated = lambda width: {
+            "gate_proj": lin(c.hidden_size, width, False, "column"),
+            "up_proj": lin(c.hidden_size, width, False, "column"),
+            "down_proj": lin(width, c.hidden_size, False, "row"),
+        }
+        # what a leading dense layer has where the others have experts
+        self._dense_mlp_layers = {}
         if c.moe is not None:
             from ..moe.layer import MoE
             self._moe = MoE(
@@ -304,18 +441,74 @@ class TransformerLM:
                 activation=c.activation,
                 normalize_weights=c.moe.normalize_weights,
                 balance_loss=c.moe.balance_loss,
+                router=c.moe.router, routed_scale=c.moe.routed_scale,
+                shared_width=c.moe.shared_width,
+                experts_held=c.moe.experts_held,
             )
+            if c.first_dense_layers:
+                self._dense_mlp_layers = gated(c.dense_intermediate_size)
         elif c.activation == "silu_gated":
-            self._block_layers.update({
-                "gate_proj": nn.Linear(c.hidden_size, c.ffn_size, use_bias=False, shard="column"),
-                "up_proj": nn.Linear(c.hidden_size, c.ffn_size, use_bias=False, shard="column"),
-                "down_proj": nn.Linear(c.ffn_size, c.hidden_size, use_bias=False, shard="row"),
-            })
+            self._block_layers.update(gated(c.ffn_size))
         else:
             self._block_layers.update({
                 "fc_in": nn.Linear(c.hidden_size, c.ffn_size, use_bias=use_bias, shard="column"),
                 "fc_out": nn.Linear(c.ffn_size, c.hidden_size, use_bias=use_bias, shard="row"),
             })
+        if c.mtp_layers:
+            self._mtp_layers = {
+                "norm_h": norm_cls(c.hidden_size), "norm_e": norm_cls(c.hidden_size),
+                "merge": lin(2 * c.hidden_size, c.hidden_size, False, None),
+                "ln_f": norm_cls(c.hidden_size),
+            }
+        self._check_kinds()
+
+    def _check_latent(self) -> None:
+        c = self.config
+        if min(c.kv_latent_rank, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim) <= 0:
+            raise ValueError("latent attention needs kv_latent_rank, "
+                             "qk_nope_dim, qk_rope_dim and v_head_dim")
+        if (c.position != "rope" or c.num_kv_heads not in (None, c.num_heads)
+                or c.attn_windows is not None or c.norm_style != "pre"
+                or c.parallel_block or not c.causal
+                or (c.qk_norm and not c.qk_norm_per_head)):
+            raise ValueError(
+                "latent attention is written for a causal pre-norm rotary "
+                "decoder with as many key heads as query heads, no windows, "
+                "and QK-norm (if any) per head")
+        if c.v_head_dim != c.head_dim:
+            raise NotImplementedError(
+                f"value heads of {c.v_head_dim} beside query heads of "
+                f"{c.head_dim}: the attention routes take one head size")
+
+    def _check_kinds(self) -> None:
+        """What the second kind of layer, the two streams and the
+        prediction module are written for, and nothing wider."""
+        c = self.config
+        if c.attention not in ("mha", "latent"):
+            raise ValueError(f"attention {c.attention!r} is not 'mha' or 'latent'")
+        if c.rope_scaling is not None and c.position != "rope":
+            raise ValueError("rope_scaling needs position='rope'")
+        if c.qk_norm_per_head and not c.qk_norm:
+            raise ValueError("qk_norm_per_head says how qk_norm is taken")
+        if c.first_dense_layers and (
+                c.moe is None or c.activation != "silu_gated"
+                or not c.dense_intermediate_size
+                or not 0 < c.first_dense_layers < c.num_layers):
+            raise ValueError(
+                "first_dense_layers: leading gated-SiLU layers of "
+                "dense_intermediate_size in a model whose other layers "
+                "have experts")
+        if c.mtp_layers not in (0, 1):
+            raise NotImplementedError("one multi-token-prediction module")
+        if c.mtp_layers and (not c.causal or c.norm_style != "pre"):
+            raise ValueError("multi-token prediction is a causal pre-norm decoder's")
+        if (c.farskip or c.first_dense_layers or c.mtp_layers) and (
+                c.norm_style != "pre" or c.parallel_block
+                or c.remat_policy == "alternating" or self._windows is not None):
+            raise ValueError(
+                "farskip, first_dense_layers and mtp_layers are written for "
+                "sequential pre-norm blocks without windows, under any "
+                "remat_policy but 'alternating'")
 
     # -- init / specs --------------------------------------------------------
     def init(self, rng: jax.Array, dtype=jnp.float32) -> Params:
@@ -340,13 +533,24 @@ class TransformerLM:
                 "bias": jnp.zeros((c.vocab_size,), dtype),
             }
 
-        def init_block(r):
-            block, _ = nn.init_tree(self._block_layers, r, dtype)
-            if c.moe is not None:
+        def init_block(r, dense=False):
+            layers = self._block_layers
+            if dense:
+                layers = {**layers, **self._dense_mlp_layers}
+            block, _ = nn.init_tree(layers, r, dtype)
+            if c.moe is not None and not dense:
                 block["moe"] = self._moe.init(jax.random.fold_in(r, 7), dtype)
             return block
 
-        params["blocks"] = jax.vmap(init_block)(jax.random.split(rng_blocks, c.num_layers))
+        params["blocks"] = jax.vmap(init_block)(jax.random.split(rng_blocks, c.scan_layers))
+        if c.first_dense_layers:
+            params["dense_blocks"] = jax.vmap(functools.partial(init_block, dense=True))(
+                jax.random.split(jax.random.fold_in(rng_blocks, 1), c.first_dense_layers))
+        if c.mtp_layers:
+            r = jax.random.fold_in(rng_head, 5)
+            params["mtp"], _ = nn.init_tree(self._mtp_layers, r, dtype)
+            params["mtp"]["blocks"] = jax.vmap(init_block)(
+                jax.random.split(jax.random.fold_in(r, 1), c.mtp_layers))
         return params
 
     def specs(self) -> Params:
@@ -367,13 +571,19 @@ class TransformerLM:
                             "ln": self._mlm_ln.specs(),
                             "bias": P(None)}
         block_specs = {name: layer.specs() for name, layer in self._block_layers.items()}
+        dense_specs = {**block_specs, **{name: layer.specs() for name, layer
+                                         in self._dense_mlp_layers.items()}}
         if c.moe is not None:
             block_specs["moe"] = self._moe.specs()
         # stacked over layers: prepend None for the layer dim
-        block_specs = jax.tree.map(
-            lambda s: P(None, *s), block_specs,
-            is_leaf=lambda s: isinstance(s, P))
-        specs["blocks"] = block_specs
+        stacked = lambda tree: jax.tree.map(
+            lambda s: P(None, *s), tree, is_leaf=lambda s: isinstance(s, P))
+        specs["blocks"] = stacked(block_specs)
+        if c.first_dense_layers:
+            specs["dense_blocks"] = stacked(dense_specs)
+        if c.mtp_layers:
+            specs["mtp"] = {name: layer.specs() for name, layer in self._mtp_layers.items()}
+            specs["mtp"]["blocks"] = stacked(block_specs)
         return specs
 
     # -- forward -------------------------------------------------------------
@@ -387,10 +597,27 @@ class TransformerLM:
         rot = nn.rotary_embedding(x[..., :rd], positions, c.rope_theta, c.rope_style)
         return jnp.concatenate([rot, x[..., rd:]], axis=-1)
 
+    def _rotate_tail(self, x: jax.Array, positions: jax.Array) -> jax.Array:
+        """Latent attention's rotary embedding: the LAST ``qk_rope_dim`` of
+        each head turned (``rope_style`` pairs, ``rope_scaling``'s
+        frequencies), the first ``qk_nope_dim`` passed through."""
+        c = self.config
+        scaling = c.rope_scaling
+        freqs = (None if scaling is None
+                 else scaling.frequencies(c.qk_rope_dim, c.rope_theta))
+        rot = nn.rotary_embedding(
+            x[..., c.qk_nope_dim:], positions, c.rope_theta, c.rope_style,
+            freqs=freqs, scale=1.0 if scaling is None else scaling.cos_sin_scale)
+        return jnp.concatenate([x[..., :c.qk_nope_dim], rot], axis=-1)
+
     def _project(self, block: Params, name: str, h: jax.Array) -> jax.Array:
         """The block's linear layer ``name`` over ``h``, its result named
         as one the backward may keep (``remat_policy``'s default)."""
-        return checkpoint_name(self._block_layers[name](block[name], h), name)
+        return checkpoint_name(self._layer(name)(block[name], h), name)
+
+    def _layer(self, name: str):
+        """A block's layer by name, of either kind of block."""
+        return self._block_layers.get(name) or self._dense_mlp_layers[name]
 
     def _attn(self, block: Params, h: jax.Array, positions: jax.Array,
               attn_mask: Optional[jax.Array] = None,
@@ -402,6 +629,8 @@ class TransformerLM:
         (mistral sliding window / gpt-neo local layers)."""
         c = self.config
         B, S, _ = h.shape
+        if c.attention == "latent":
+            return self._latent_attn(block, h, positions)
         with jax.named_scope("attn"):
             with jax.named_scope("qkv"):
                 # saved as projected: QK-norm's backward needs its input
@@ -422,13 +651,58 @@ class TransformerLM:
                 out = out.reshape(B, S, c.num_heads * c.head_dim)
                 return self._project(block, "o_proj", out)
 
-    def _attn_core(self, q, k, v, attn_mask, window) -> jax.Array:
+    def _latent_attn(self, block: Params, h: jax.Array,
+                     positions: jax.Array) -> jax.Array:
+        """Multi-head latent attention over the pre-normed ``h`` (config's
+        ``attention='latent'`` has the sizes): training form, the keys and
+        values decompressed for every token, so the attention core sees
+        plain heads and takes the route any model's takes."""
+        c = self.config
+        B, S, _ = h.shape
+        nh, nope, vd = c.num_heads, c.qk_nope_dim, c.v_head_dim
+        with jax.named_scope("attn"):
+            with jax.named_scope("qkv"):
+                q = self._project(block, "q_proj", h).reshape(B, S, nh, c.head_dim)
+            with jax.named_scope("latent"):
+                kv_a = checkpoint_name(
+                    self._block_layers["kv_a_proj"](block["kv_a_proj"], h), "kv_latent")
+                latent = self._block_layers["kv_a_norm"](
+                    block["kv_a_norm"], kv_a[..., :c.kv_latent_rank])
+                kv = checkpoint_name(
+                    self._block_layers["kv_b_proj"](block["kv_b_proj"], latent),
+                    "kv_up").reshape(B, S, nh, nope + vd)
+            with jax.named_scope("qkv"):
+                k_rope = jnp.broadcast_to(kv_a[:, :, None, c.kv_latent_rank:],
+                                          (B, S, nh, c.qk_rope_dim))
+                k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
+                v = kv[..., nope:]
+                if c.qk_norm:
+                    q = self._block_layers["q_norm"](block["q_norm"], q)
+                    k = self._block_layers["k_norm"](block["k_norm"], k)
+                q = self._rotate_tail(q, positions)
+                k = self._rotate_tail(k, positions)
+            with jax.named_scope("core"):
+                scale = c.attn_scale or c.head_dim ** -0.5
+                if c.rope_scaling is not None:
+                    scale *= c.rope_scaling.softmax_scale ** 2
+                out = self._attn_core(q, k, v, None, None, scale=scale)
+            out = out.reshape(B, S, nh * vd)
+            if c.attn_gate:
+                with jax.named_scope("gate"):
+                    gate = checkpoint_name(self._block_layers["attn_gate"](
+                        block["attn_gate"], h), "attn_gate")
+                    out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+            with jax.named_scope("out"):
+                return self._project(block, "o_proj", out)
+
+    def _attn_core(self, q, k, v, attn_mask, window, scale=None) -> jax.Array:
         """Scores, softmax and values (XLA, flash, ring or Ulysses)."""
         c = self.config
         seg = attn_mask.astype(jnp.int32) if attn_mask is not None else None
         kw = {}
-        if c.attn_scale is not None:
-            kw["scale"] = c.attn_scale
+        scale = c.attn_scale if scale is None else scale
+        if scale is not None:
+            kw["scale"] = scale
         if window is not None:
             kw["window"] = window
         if c.seq_parallel == "ring":
@@ -436,7 +710,7 @@ class TransformerLM:
                 raise ValueError("ring attention does not support padding "
                                  "masks (attention_mask)")
             from ..sequence.ring_attention import ring_attention
-            return ring_attention(q, k, v, causal=True, scale=c.attn_scale)
+            return ring_attention(q, k, v, causal=True, scale=scale)
         if self._alibi_slopes is not None:
             kw["alibi_slopes"] = jnp.asarray(self._alibi_slopes)
         return ulysses_attention(flash_attention, q, k, v, causal=c.causal,
@@ -463,14 +737,14 @@ class TransformerLM:
         path's rows per expert [experts] or None)."""
         c = self.config
         aux, rows = self._aux_zero(), None
-        if self.moe_path == "dropless":
+        if "moe" in block and self.moe_path == "dropless":
             out, aux, rows = self._moe.dropless_forward(block["moe"], h)
-        elif c.moe is not None:
+        elif "moe" in block:
             out, aux = self._moe(block["moe"], h)
         elif c.activation == "silu_gated":
             gate = nn.silu(self._project(block, "gate_proj", h))
             up = self._project(block, "up_proj", h)
-            out = self._block_layers["down_proj"](block["down_proj"], gate * up)
+            out = self._layer("down_proj")(block["down_proj"], gate * up)
         else:
             h2 = ACTIVATIONS[c.activation](self._project(block, "fc_in", h))
             out = self._block_layers["fc_out"](block["fc_out"], h2)
@@ -497,6 +771,16 @@ class TransformerLM:
             mlp_out, aux, rows = self._mlp(block, h)
             y = self._block_layers["ln_2"](block["ln_2"], h + mlp_out)
             x = _c(keep * y + (1 - keep) * x, ACT_SPEC)
+            return (x, positions, aux_acc + keep * aux), rows
+        if c.farskip:
+            # x = (r_(i-1), r_(i-2)): attention reads the stream as it stood
+            # before the sub-block in front of it, and so does the MLP
+            near, far = x
+            after_attn = near + keep * self._attn(
+                block, self._block_layers["ln_1"](block["ln_1"], far), positions)
+            mlp_out, aux, rows = self._mlp(
+                block, self._block_layers["ln_2"](block["ln_2"], near))
+            x = (_c(after_attn + keep * mlp_out, ACT_SPEC), after_attn)
             return (x, positions, aux_acc + keep * aux), rows
         h1 = self._block_layers["ln_1"](block["ln_1"], x)
         if c.parallel_block:
@@ -546,14 +830,17 @@ class TransformerLM:
         return _c(x.astype(c.dtype), ACT_SPEC), positions
 
     @scoped("head")
-    def head(self, params: Params, x: jax.Array) -> jax.Array:
+    def head(self, params: Params, x: jax.Array,
+             ln_f: Optional[Params] = None) -> jax.Array:
         """Back of the network: final norm (pre-LN), MLM transform, LM/MLM
         head. Input is the last block's output; returns fp32 logits. The
         tied-embedding head reads ``params['wte']`` — the param-streaming
-        trainer keeps the embedding leaves resident for this reason."""
+        trainer keeps the embedding leaves resident for this reason.
+        ``ln_f``: another final norm's parameters (the prediction module
+        has its own in front of the shared head)."""
         c = self.config
         if self._ln_f is not None:
-            x = self._ln_f(params["ln_f"], x)
+            x = self._ln_f(params["ln_f"] if ln_f is None else ln_f, x)
         if c.mlm_head:
             # bert cls.predictions: dense → act → LN → tied decoder + bias
             x = ACTIVATIONS[c.activation](
@@ -575,6 +862,13 @@ class TransformerLM:
         param-streaming trainer's unit of compute (reference fetches one
         module's partitions at a time, partitioned_param_coordinator.py:280).
         Returns (x', moe_aux)."""
+        c = self.config
+        if c.farskip or c.first_dense_layers or c.mtp_layers:
+            raise NotImplementedError(
+                "one block at a time (parameter streaming, the ZeRO-3 "
+                "pipelined scan) is written for one stream through blocks "
+                "of one kind: farskip, first_dense_layers and mtp_layers "
+                "take the whole-model scan of TransformerLM.apply")
         carry = (x, positions, self._aux_zero())
         keep = jnp.asarray(keep, self.config.dtype)
         packed = (block, keep) if window is None else (block, keep, window)
@@ -854,6 +1148,22 @@ class TransformerLM:
         under the default ``remat_policy`` (an engine's reading; ``None``
         saves everything named) and where the decision is written.
         """
+        x, aux, stats, _ = self._trunk(
+            params, input_ids, layer_mask, token_type_ids, attention_mask,
+            remat_budget, with_mtp=False)
+        stats = (stats,) if return_stats else ()
+        if return_hidden:
+            if self._ln_f is not None:
+                x = self._ln_f(params["ln_f"], x)
+            return (x, aux) + stats
+        return (self.head(params, x), aux) + stats
+
+    def _trunk(self, params, input_ids, layer_mask, token_type_ids,
+               attention_mask, remat_budget, with_mtp: bool):
+        """Embedding and every block: (the last block's output stream,
+        the accumulated MoE aux, the step's statistics, the prediction
+        module's output stream or None). ``with_mtp``: run the module (a
+        training loss asks for it; logits alone do not need it)."""
         c = self.config
         x, positions = self.embed(params, input_ids, token_type_ids)
 
@@ -862,10 +1172,26 @@ class TransformerLM:
             keep = jnp.ones((c.num_layers,), c.dtype)
         else:
             keep = layer_mask.astype(c.dtype)
-        xs = (params["blocks"], keep)
+        dense = c.first_dense_layers
+        xs = (params["blocks"], keep[dense:])
         if self._windows is not None:
             xs = xs + (jnp.asarray(self._windows, jnp.int32),)
-        init = (x, positions, self._aux_zero())
+        # FarSkip carries two streams: (r_(i-1), r_(i-2)), r_(-1) = r_0
+        init = ((x, x) if c.farskip else x, positions, self._aux_zero())
+        blocks_in_all = c.num_layers + (c.mtp_layers if with_mtp else 0)
+
+        def one_block():
+            """``block_fn`` for a block outside the scan, under the scan's
+            remat policy: its bytes reckoned as one of all the blocks, in
+            the same room, its decision written nowhere (the scan's is)."""
+            if not c.remat:
+                return block_fn
+            room = None if remat_budget is None else Budget(remat_budget.room_bytes)
+            return checkpointed(block_fn, c.remat_policy, blocks_in_all, room)
+
+        for i in range(dense):       # the leading dense layers, one by one
+            layer = jax.tree.map(lambda a: a[i], params["dense_blocks"])
+            init, _ = one_block()(init, (layer, keep[i]))
         rows = None
         if c.remat and c.remat_policy == "alternating":
             # HALF-remat: scan over layer pairs, checkpointing only the
@@ -891,18 +1217,51 @@ class TransformerLM:
                     (x, positions, aux),
                     jax.tree.map(lambda a: a[-1], xs))
         elif c.remat:
-            ck_fn = checkpointed(block_fn, c.remat_policy, c.num_layers,
+            ck_fn = checkpointed(block_fn, c.remat_policy, blocks_in_all,
                                  remat_budget)
             (x, _, aux), rows = jax.lax.scan(ck_fn, init, xs)
         else:
             (x, _, aux), rows = jax.lax.scan(block_fn, init, xs)
-        stats = ({} if rows is None else {"moe_expert_rows": rows},) \
-            if return_stats else ()
-        if return_hidden:
-            if self._ln_f is not None:
-                x = self._ln_f(params["ln_f"], x)
-            return (x, aux) + stats
-        return (self.head(params, x), aux) + stats
+        if c.farskip:
+            x = x[0]
+        mtp_x = None
+        if with_mtp and c.mtp_layers:
+            with jax.named_scope("mtp"):
+                # position i: the final stream and the NEXT token's
+                # embedding. The row's last position has no next token and
+                # is given the row's first (its loss is masked and the
+                # causal mask keeps it from every other position)
+                nxt = self._wte(params["wte"], jnp.roll(input_ids, -1, axis=1))
+                mp = params["mtp"]
+                with jax.named_scope("merge"):
+                    merged = self._mtp_layers["merge"](mp["merge"], jnp.concatenate(
+                        [self._mtp_layers["norm_h"](mp["norm_h"], x),
+                         self._mtp_layers["norm_e"](mp["norm_e"], nxt.astype(c.dtype))],
+                        axis=-1))
+                merged = _c(merged, ACT_SPEC)
+                start = ((merged, merged) if c.farskip else merged, positions, aux)
+                (mtp_x, _, aux), mtp_rows = one_block()(
+                    start, (jax.tree.map(lambda a: a[0], mp["blocks"]),
+                            jnp.ones((), c.dtype)))
+                if c.farskip:
+                    mtp_x = mtp_x[0]
+            if rows is not None:
+                rows = jnp.concatenate([rows, mtp_rows[None]], axis=0)
+        return x, aux, self._stats_of(rows), mtp_x
+
+    def _stats_of(self, rows: Optional[jax.Array]) -> Dict[str, jax.Array]:
+        """The step's device-side statistics from the no-drop path's
+        assignments per expert ``[expert layers, experts]`` (the prediction
+        module's layer last): ``moe_expert_rows``, the columns of the
+        experts held here, and with a bias that load moves
+        ``moe_router_load``, every column."""
+        if rows is None:
+            return {}
+        lo, hi = self._moe.held
+        stats = {"moe_expert_rows": rows[:, lo:hi]}
+        if self.config.moe.bias_update:
+            stats["moe_router_load"] = rows
+        return stats
 
     # The three loss ingredients are separate methods because the ZeRO
     # overlap schedule (engine._build_zeropp_micro_overlap) composes the
@@ -930,11 +1289,15 @@ class TransformerLM:
 
     def combine_aux(self, loss: jax.Array, aux: jax.Array) -> jax.Array:
         """Fold the accumulated MoE aux loss into the objective: each
-        router loss under its own coefficient, averaged over the layers.
-        Reads ``self.config`` alone (``PipelineModule`` borrows it)."""
+        router loss under its own coefficient, averaged over the layers
+        (the sigmoid router's sequence-wise balance loss is summed over
+        them, as its report has it). Reads ``self.config`` alone
+        (``PipelineModule`` borrows it)."""
         moe = self.config.moe
         if moe is None:
             return loss
+        if moe.router == "sigmoid_bias":
+            return loss + moe.seq_balance_coef * aux[0]
         if moe.capacity_factor is None:   # the no-drop path's two losses
             aux = moe.aux_loss_coef * aux[0] + moe.z_loss_coef * aux[1]
         else:
@@ -946,7 +1309,12 @@ class TransformerLM:
         """Cross-entropy: next-token for causal LMs (labels derived by shift
         when absent), masked-LM for encoders (labels required, -100 = ignore).
         batch: input_ids [B,S], optional labels/loss_mask/token_type_ids/
-        attention_mask. ``remat_budget`` as in :meth:`apply`."""
+        attention_mask. ``remat_budget`` as in :meth:`apply`. Calls
+        ``self.apply``, ``self.derive_labels`` and ``self.combine_aux``
+        alone where there is no prediction module (``PipelineModule``
+        borrows it)."""
+        if self.config.mtp_layers:
+            return self.loss_and_stats(params, batch, remat_budget)[0]
         labels = self.derive_labels(batch)
         logits, aux = self.apply(params, batch["input_ids"],
                                  layer_mask=batch.get("layer_mask"),
@@ -960,14 +1328,64 @@ class TransformerLM:
     def loss_and_stats(self, params: Params, batch: Dict[str, jax.Array],
                        remat_budget: Optional[Budget] = None
                        ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        """``loss`` with ``apply``'s device-side statistics of the step
-        (the engine keeps them on the device beside the loss)."""
+        """``loss`` with the device-side statistics of the step (the engine
+        keeps them on the device beside the loss). With a prediction module
+        the objective is the next-token loss plus ``mtp_loss_coef`` times
+        the module's loss on the token after next."""
+        c = self.config
         labels = self.derive_labels(batch)
-        logits, aux, stats = self.apply(
-            params, batch["input_ids"], layer_mask=batch.get("layer_mask"),
-            token_type_ids=batch.get("token_type_ids"),
-            attention_mask=batch.get("attention_mask"), return_stats=True,
-            remat_budget=remat_budget)
-        loss = masked_cross_entropy(logits, labels,
-                                    extra_mask=batch.get("loss_mask"))
+        mask = batch.get("loss_mask")
+        x, aux, stats, mtp_x = self._trunk(
+            params, batch["input_ids"], batch.get("layer_mask"),
+            batch.get("token_type_ids"), batch.get("attention_mask"),
+            remat_budget, with_mtp=True)
+        if mtp_x is None:
+            return self.combine_aux(self.head_loss(
+                params, x, labels, extra_mask=mask), aux), stats
+        later = lambda a, fill: jnp.pad(a[:, 1:], ((0, 0), (0, 1)),
+                                        constant_values=fill)
+
+        def head_loss(x, labels, mask, ln_f=None):
+            return masked_cross_entropy(self.head(params, x, ln_f=ln_f),
+                                        labels, extra_mask=mask)
+        if c.remat:
+            # two float32 logit tables: neither is kept for the backward,
+            # which runs each head's matmul again instead
+            head_loss = jax.checkpoint(head_loss)
+        loss = head_loss(x, labels, mask)
+        with jax.named_scope("mtp"):
+            loss = loss + c.mtp_loss_coef * head_loss(
+                mtp_x, later(labels, -100),
+                None if mask is None else later(mask, 0), params["mtp"]["ln_f"])
         return self.combine_aux(loss, aux), stats
+
+    def hold_router_bias(self, old: Params, new: Params,
+                         load: Optional[jax.Array]) -> Params:
+        """``new`` (a tree shaped as the parameters, after an optimizer's
+        step from ``old``) with every router's correction bias taken from
+        ``old`` instead and moved by ``load`` alone (``sharded_moe.
+        bias_step`` under ``bias_update``; ``load`` None: left where it
+        was): the leaf no gradient, moment or decay touches. ``load``:
+        ``moe_router_load`` of the step's statistics."""
+        from ..moe.sharded_moe import bias_step
+        rate, n = self.config.moe.bias_update, self.config.scan_layers
+        moved = lambda bias, counts: (bias if load is None else bias_step(
+            bias.astype(jnp.float32), counts, rate))
+
+        def held(new_tree, old_tree, counts):
+            bias = moved(old_tree["moe"]["bias"], counts)
+            return {**new_tree, "moe": {**new_tree["moe"], "bias": bias.astype(
+                new_tree["moe"]["bias"].dtype)}}
+
+        out = {**new, "blocks": held(new["blocks"], old["blocks"],
+                                     None if load is None else load[:n])}
+        if "mtp" in new:
+            out["mtp"] = {**new["mtp"], "blocks": held(
+                new["mtp"]["blocks"], old["mtp"]["blocks"],
+                None if load is None else load[n:])}
+        return out
+
+    @property
+    def has_router_bias(self) -> bool:
+        moe = self.config.moe
+        return moe is not None and moe.router == "sigmoid_bias"

@@ -29,8 +29,8 @@ from jax.sharding import PartitionSpec as P
 from ..runtime import topology as topo_mod
 from ..runtime.topology import BATCH_AXES, DATA_AXIS, EXPERT_AXIS
 from ..utils.jax_compat import with_sharding_constraint
-from .sharded_moe import (capacity as _capacity, softmax_topk_router,
-                          top_k_gating_indices)
+from .sharded_moe import (capacity as _capacity, sigmoid_bias_router,
+                          softmax_topk_router, top_k_gating_indices)
 
 Params = Dict[str, Any]
 
@@ -128,6 +128,117 @@ def _unsort_rows_bwd(order, g):
 _unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
 
 
+# A chip that holds some of the experts sorts the assignments of ITS experts
+# first (``order``: held assignments by expert, then the absent ones) and
+# moves the first ``cap`` sorted rows alone; ``held`` counts the real ones.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch_held_rows(tokens, order, inv, held, top_k):
+    """[T, h] -> [cap, h] (``order`` has ``cap`` entries): row ``i`` is the
+    token of sorted assignment ``i``. Backward: each token's held rows,
+    found by ``inv`` (< ``held``), summed."""
+    return tokens.at[order // top_k].get(mode="promise_in_bounds")
+
+
+def _dispatch_held_rows_fwd(tokens, order, inv, held, top_k):
+    return _dispatch_held_rows(tokens, order, inv, held, top_k), (inv, held)
+
+
+def _dispatch_held_rows_bwd(top_k, res, g):
+    inv, held = res
+    picked = g.at[jnp.minimum(inv, g.shape[0] - 1)].get(mode="promise_in_bounds")
+    picked = jnp.where((inv < held)[:, None], picked.astype(jnp.float32), 0.0)
+    d = jnp.sum(picked.reshape(-1, top_k, g.shape[-1]), axis=1)
+    return d.astype(g.dtype), None, None, None
+
+
+_dispatch_held_rows.defvjp(_dispatch_held_rows_fwd, _dispatch_held_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_held_rows(rows, weight, order, inv, held):
+    """[cap, h] rows in expert order, [T, k] routing weights -> [T, h]
+    float32: each token's held rows weighted and summed, one slot of the k
+    at a time (no ``[T, k, h]`` tensor: most slots are some other chip's).
+    Backward, in the ``cap`` rows' own order: the rows' gradient is the
+    token's times the weight, the weight's the row's product with it."""
+    return _combine_held_rows_fwd(rows, weight, order, inv, held)[0]
+
+
+def _combine_held_rows_fwd(rows, weight, order, inv, held):
+    cap, k = rows.shape[0], weight.shape[1]
+    real = (inv < held).reshape(weight.shape)
+    at = jnp.minimum(inv, cap - 1).reshape(weight.shape)
+    # selected, not multiplied by 0: the buffer's rows past ``held`` are
+    # other tokens' (and a grouped matmul leaves rows past its last group
+    # as it found them, on the TPU possibly no number)
+    out = sum(jnp.where(real[:, j, None],
+                        rows.at[at[:, j]].get(mode="promise_in_bounds")
+                        .astype(jnp.float32) * weight[:, j, None], 0.0)
+              for j in range(k))
+    return out, (rows, weight, order, inv, held)
+
+
+def _combine_held_rows_bwd(res, g):
+    rows, weight, order, inv, held = res
+    k = weight.shape[1]
+    real = (jnp.arange(rows.shape[0]) < held)[:, None]
+    g_rows = g.at[order // k].get(mode="promise_in_bounds")          # [cap, h]
+    w_rows = weight.reshape(-1).at[order].get(mode="promise_in_bounds")
+    d_rows = jnp.where(real, g_rows * w_rows[:, None], 0.0).astype(rows.dtype)
+    dot = jnp.sum(jnp.where(real, g_rows * rows.astype(jnp.float32), 0.0), axis=-1)
+    d_w = jnp.where(inv < held,
+                    dot.at[jnp.minimum(inv, rows.shape[0] - 1)]
+                    .get(mode="promise_in_bounds"), 0.0).reshape(weight.shape)
+    return d_rows, d_w, None, None, None
+
+
+_combine_held_rows.defvjp(_combine_held_rows_fwd, _combine_held_rows_bwd)
+
+
+def _once(run, fn, like):
+    """``fn()`` if ``run`` else zeros shaped ``like``, as a loop of at most
+    one trip (no branch instruction: a profile counts a loop's body once,
+    where it counts a conditional and its branch both)."""
+    zeros = jax.tree.map(jnp.zeros_like, like)
+    return jax.lax.while_loop(lambda c: c[0], lambda c: (jnp.zeros((), bool), fn()),
+                              (run, zeros))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _overflow_rows(layer, run, tokens, by_expert, stacks):
+    """What the held experts add for the assignments beyond the sorted
+    buffer, ``layer._every_token`` under ``by_expert`` (0 but for those
+    assignments), run only in a step that has any (``run``): [T, h]
+    float32. Both directions are loops of at most one trip, so a step
+    without overflow pays nothing for the guarantee that no row is dropped."""
+    return _once(run, lambda: layer._every_token(tokens, by_expert, stacks),
+                 jax.ShapeDtypeStruct(tokens.shape, jnp.float32))
+
+
+def _overflow_rows_fwd(layer, run, tokens, by_expert, stacks):
+    return (_overflow_rows(layer, run, tokens, by_expert, stacks),
+            (run, tokens, by_expert, stacks))
+
+
+def _overflow_rows_bwd(layer, res, g):
+    run, *args = res
+    grads = _once(run, lambda: jax.vjp(layer._every_token, *args)[1](g), tuple(args))
+    return (None,) + tuple(grads)
+
+
+_overflow_rows.defvjp(_overflow_rows_fwd, _overflow_rows_bwd)
+
+
+def held_capacity(assignments: int, held: int, experts: int) -> int:
+    """Rows of the buffer a chip that holds ``held`` of ``experts`` experts
+    sorts its rows into, of the ``assignments`` = tokens x top_k a step
+    routes: three times the even share, in whole 512s, at most all of them
+    (then the buffer can never be short). Pure."""
+    even = -(-assignments * held // experts)
+    return min(assignments, -(-3 * even // 512) * 512)
+
+
 @dataclasses.dataclass(frozen=True)
 class MoE:
     hidden_size: int
@@ -144,6 +255,19 @@ class MoE:
     #: over the kept choices and balances over the first choice, always)
     normalize_weights: bool = True
     balance_loss: str = "gshard_top1"  # | 'topk_share' (sharded_moe.BALANCE_LOSSES)
+    #: the no-drop path's router: 'softmax' (``softmax_topk_router``) |
+    #: 'sigmoid_bias' (``sigmoid_bias_router``: a ``bias`` leaf that load
+    #: moves and no gradient, weights times ``routed_scale``)
+    router: str = "softmax"
+    routed_scale: float = 1.0
+    #: width of the shared expert every token passes, unweighted (0: none)
+    shared_width: int = 0
+    #: the experts THIS chip holds, ``(first, past the last)`` of
+    #: ``num_experts``: the router stays whole, the weight stacks hold these
+    #: alone and the layer returns their part of the result (what the other
+    #: chips' experts would add is left out, and nothing stands in for them
+    #: or their exchange). None: all of them.
+    experts_held: Optional[Tuple[int, int]] = None
     #: fused Pallas kernel dispatch (ISSUE 11): None = the
     #: ``DSTPU_MOE_KERNEL`` env gate (auto: Pallas on single-chip TPU,
     #: XLA elsewhere); 'xla'/'pallas' pin per-layer (lint entries,
@@ -159,13 +283,31 @@ class MoE:
                 "its balance loss from the first choice; normalize_weights="
                 "False and other balance losses need capacity_factor=None "
                 "(the no-drop path)")
+        if not self.dropless and (self.router != "softmax" or self.shared_width
+                                  or self.experts_held is not None):
+            raise ValueError(
+                "the sigmoid router, a shared expert and a share of the "
+                "experts are the no-drop path's (capacity_factor=None)")
+        if self.router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(f"router {self.router!r} is not 'softmax' or "
+                             "'sigmoid_bias'")
+        lo, hi = self.held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is no range "
+                             f"of {self.num_experts} experts")
 
     @property
     def dropless(self) -> bool:
         return self.capacity_factor is None
 
+    @property
+    def held(self) -> Tuple[int, int]:
+        """The range of experts whose weights this layer holds."""
+        return self.experts_held or (0, self.num_experts)
+
     def init(self, rng, dtype=jnp.float32) -> Params:
-        e, h, f = self.num_experts, self.hidden_size, self.intermediate_size
+        h, f = self.hidden_size, self.intermediate_size
+        e = self.held[1] - self.held[0]
         ks = jax.random.split(rng, 4)
         scale = self.init_scale
 
@@ -179,6 +321,13 @@ class MoE:
         else:
             params["wi"] = w(ks[1], (e, h, f))
         params["wo"] = w(ks[3], (e, f, h))
+        if self.router == "sigmoid_bias":
+            params["bias"] = jnp.zeros((self.num_experts,), dtype)
+        if self.shared_width:
+            sk = jax.random.split(jax.random.fold_in(rng, 1), 3)
+            params["shared"] = {"gate_proj": w(sk[0], (h, self.shared_width)),
+                                "up_proj": w(sk[1], (h, self.shared_width)),
+                                "down_proj": w(sk[2], (self.shared_width, h))}
         return params
 
     def specs(self) -> Params:
@@ -189,6 +338,11 @@ class MoE:
             out["wi_up"] = expert_w
         else:
             out["wi"] = expert_w
+        if self.router == "sigmoid_bias":
+            out["bias"] = P(None)
+        if self.shared_width:
+            out["shared"] = {k: P(None, None)
+                             for k in ("gate_proj", "up_proj", "down_proj")}
         return out
 
     def _expert_ffn(self, params: Params, expert_in: jax.Array,
@@ -242,33 +396,123 @@ class MoE:
                              params["gate"].astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
             logits = checkpoint_name(logits, "moe_logits")
-            eidx, weight, losses, rows = softmax_topk_router(
-                logits, k, normalize=self.normalize_weights,
-                balance_loss=self.balance_loss)
+            if self.router == "sigmoid_bias":
+                eidx, weight, losses, rows = sigmoid_bias_router(
+                    logits, params["bias"], k, normalize=self.normalize_weights,
+                    routed_scale=self.routed_scale, rows_per_seq=s)
+            else:
+                eidx, weight, losses, rows = softmax_topk_router(
+                    logits, k, normalize=self.normalize_weights,
+                    balance_loss=self.balance_loss)
+        if self.held == (0, self.num_experts):
+            out = self._all_rows(params, tokens, eidx, weight, rows)
+        else:
+            out = self._held_rows(params, tokens, eidx, weight, rows)
+        if self.shared_width:
+            with jax.named_scope("moe/shared"):
+                sp = params["shared"]
+                first = lambda name: checkpoint_name(
+                    tokens @ sp[name].astype(dt), name)
+                mid = jax.nn.silu(first("gate_proj")) * first("up_proj")
+                out = out + (mid @ sp["down_proj"].astype(dt)).astype(jnp.float32)
+        return out.astype(dt).reshape(b, s, h), losses, rows
+
+    def _ffn(self, params: Params, rows_in: jax.Array, product) -> jax.Array:
+        """The expert MLP over rows, ``product(a, name)`` its matmuls; the
+        first products are named for the block's remat policy (what the
+        backward may keep: the activation's gradient needs them)."""
+        first = lambda name: checkpoint_name(product(rows_in, name), name)
+        if self.activation == "silu_gated":
+            mid = jax.nn.silu(first("wi_gate")) * first("wi_up")
+        else:
+            mid = jax.nn.gelu(first("wi"))
+        return product(mid, "wo")
+
+    def _all_rows(self, params, tokens, eidx, weight, rows) -> jax.Array:
+        """Every expert is here: all ``tokens x top_k`` assignments sorted
+        by expert, gathered, multiplied and combined. -> [T, h] float32."""
+        n_tok, h = tokens.shape
+        k, dt = self.top_k, tokens.dtype
+        with jax.named_scope("moe/route"):
             order = jnp.argsort(eidx.reshape(-1), stable=True).astype(jnp.int32)
             inv = jnp.zeros_like(order).at[order].set(
                 jnp.arange(n_tok * k, dtype=jnp.int32), unique_indices=True)
         with jax.named_scope("moe/dispatch"):
             expert_in = _dispatch_rows(tokens, order, inv, k)
         with jax.named_scope("moe/experts"):
-            grouped = lambda a, name: jax.lax.ragged_dot(
-                a, params[name].astype(dt), rows)
-            # named for the block's remat policy (what the backward may
-            # keep): the activation's gradient needs the first products
-            first = lambda name: checkpoint_name(grouped(expert_in, name), name)
-            if self.activation == "silu_gated":
-                mid = jax.nn.silu(first("wi_gate")) * first("wi_up")
-            else:
-                mid = jax.nn.gelu(first("wi"))
-            expert_out = grouped(mid, "wo")
+            expert_out = self._ffn(params, expert_in, lambda a, name: jax.lax.ragged_dot(
+                a, params[name].astype(dt), rows))
         with jax.named_scope("moe/combine"):
             # the combine's gradient by the routing weights needs the
             # experts' rows as it reads them, in assignment order: named
             # after the gather, so a backward that keeps them gathers once
             picked = checkpoint_name(_unsort_rows(expert_out, order, inv),
                                      "wo").reshape(n_tok, k, h)
-            out = jnp.sum(picked.astype(jnp.float32) * weight[:, :, None], axis=1)
-        return out.astype(dt).reshape(b, s, h), losses, rows
+            return jnp.sum(picked.astype(jnp.float32) * weight[:, :, None], axis=1)
+
+    def _held_rows(self, params, tokens, eidx, weight, rows) -> jax.Array:
+        """This chip's experts' part of the result, [T, h] float32: the
+        assignments of the held experts sorted first, THOSE rows gathered
+        into a buffer of ``held_capacity`` rows (a static shape: three
+        times the even share, multiplied whole), grouped matmuls over the held weight
+        stacks, combined under the full-width routing weights. Nothing is
+        dropped: the assignments a step has beyond the buffer (the held
+        experts can draw up to all ``tokens x top_k``) go through
+        ``_overflow_rows``, which costs nothing in a step that has none."""
+        lo, hi = self.held
+        n_tok, h = tokens.shape
+        k, dt, nh = self.top_k, tokens.dtype, hi - lo
+        cap = held_capacity(n_tok * k, nh, self.num_experts)
+        with jax.named_scope("moe/route"):
+            local = eidx.reshape(-1) - lo
+            key = jnp.where((local >= 0) & (local < nh), local, nh)
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            inv = jnp.zeros_like(order).at[order].set(
+                jnp.arange(n_tok * k, dtype=jnp.int32), unique_indices=True)
+            order = order[:cap]
+            ends = jnp.cumsum(rows[lo:hi])
+            held = ends[-1]
+            # the groups as far as the buffer reaches; the last one takes the
+            # buffer's unfilled rows with it, so that the grouped matmuls
+            # multiply ``cap`` rows whatever the router did: a step's time
+            # does not follow the held experts' luck (those rows are some
+            # token's, their results are selected away and their gradients 0)
+            ends = jnp.minimum(ends, cap)
+            filled = ends[-1]
+            in_buffer = jnp.diff(ends.at[-1].set(cap), prepend=0)
+        with jax.named_scope("moe/dispatch"):
+            expert_in = _dispatch_held_rows(tokens, order, inv, filled, k)
+        with jax.named_scope("moe/experts"):
+            expert_out = checkpoint_name(
+                self._ffn(params, expert_in, lambda a, name: jax.lax.ragged_dot(
+                    a, params[name].astype(dt), in_buffer)), "wo")
+        with jax.named_scope("moe/combine"):
+            out = _combine_held_rows(expert_out, weight, order, inv, filled)
+        if cap == n_tok * k:
+            return out
+        with jax.named_scope("moe/experts"):
+            # [held, T]: each token's weight for each held expert, over the
+            # assignments the buffer had no room for
+            beyond = (inv >= cap).reshape(eidx.shape) & (eidx >= lo) & (eidx < hi)
+            onehot = (eidx[:, :, None] - lo == jnp.arange(nh)).astype(jnp.float32)
+            by_expert = jnp.einsum("tk,tke->et", jnp.where(beyond, weight, 0.0), onehot)
+            stacks = {n: params[n] for n in ("wi_gate", "wi_up", "wi", "wo")
+                      if n in params}
+            return out + _overflow_rows(self, held > cap, tokens, by_expert, stacks)
+
+    def _every_token(self, tokens, by_expert, stacks) -> jax.Array:
+        """sum_e by_expert[e] x E_e(tokens), every held expert over every
+        token, one expert at a time. -> [T, h] float32."""
+        dt = tokens.dtype
+
+        @jax.checkpoint
+        def one(acc, xs):
+            w, weights = xs
+            y = self._ffn(weights, tokens, lambda a, name: a @ weights[name].astype(dt))
+            return acc + y.astype(jnp.float32) * w[:, None], None
+
+        return jax.lax.scan(one, jnp.zeros(tokens.shape, jnp.float32),
+                            (by_expert, stacks))[0]
 
     def __call__(self, params: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """x: [batch, seq, hidden] → (out, aux_loss); the no-drop path's

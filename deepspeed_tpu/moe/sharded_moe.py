@@ -17,7 +17,9 @@ Beside the GShard gate stands the router of the dropless sparse models
 (``softmax_topk_router``: OLMoE, arXiv:2409.02060): no capacity, no drop,
 the top-k probabilities used as they are or renormalised, and the paper's
 two router losses. ``moe/layer.py`` takes it when a layer's
-``capacity_factor`` is None.
+``capacity_factor`` is None. ``sigmoid_bias_router`` stands beside it: the
+DeepSeek-V3 report's router (sigmoid affinities, a correction bias that load
+and not gradient moves, the sequence-wise balance loss).
 """
 
 from __future__ import annotations
@@ -145,6 +147,55 @@ def softmax_topk_router(logits: jax.Array, top_k: int, *, normalize: bool,
     balance = num_experts * jnp.sum(share * jnp.mean(gates, axis=0))
     z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
     return (expert_idx.astype(jnp.int32), weight, jnp.stack([balance, z]), rows)
+
+
+def sigmoid_bias_router(logits: jax.Array, bias: jax.Array, top_k: int, *,
+                        normalize: bool, routed_scale: float, rows_per_seq: int):
+    """The router of DeepSeek-V3's report (section 2.1.2, ``noaux_tc``):
+    ``s = sigmoid(logits)`` in float32 over ALL experts; the ``top_k``
+    largest of ``s + bias`` are chosen (one group; the lowest index wins a
+    tie); the routing weights are the chosen ``s`` WITHOUT the bias,
+    divided by their sum (+1e-20) when ``normalize``, times
+    ``routed_scale``. ``bias`` enters under ``stop_gradient``: load moves
+    it (:func:`bias_step`), no gradient does. No capacity, no drop.
+
+    logits: [tokens, experts], ``tokens`` = sequences x ``rows_per_seq``,
+    sequence-major. Returns ``(expert_idx [tokens, k] int32, weight
+    [tokens, k] f32, losses [2] f32, rows [experts] int32)``: ``losses[0]``
+    is the sequence-wise balance loss ``sum_e f_e P_e`` averaged over the
+    sequences, ``f_e = E / (k T) x #{t of the sequence that chose e}`` and
+    ``P_e`` the sequence's mean of ``s_e / sum_j s_j`` (the report's
+    formula 17-20, without its coefficient); ``losses[1]`` is 0: this
+    router has no z-loss."""
+    tokens, num_experts = logits.shape
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    biased = s + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    _, expert_idx = jax.lax.top_k(biased, top_k)
+    weight = jnp.take_along_axis(s, expert_idx, axis=-1)
+    if normalize:
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    weight = weight * routed_scale
+    seqs = tokens // rows_per_seq
+    seq_of = jnp.repeat(jnp.arange(seqs, dtype=jnp.int32), rows_per_seq * top_k)
+    chose = jnp.zeros((seqs, num_experts), jnp.int32).at[
+        seq_of, expert_idx.reshape(-1)].add(1)
+    f = chose.astype(jnp.float32) * (num_experts / (top_k * rows_per_seq))
+    p = jnp.mean((s / jnp.sum(s, axis=-1, keepdims=True))
+                 .reshape(seqs, rows_per_seq, num_experts), axis=1)
+    balance = jnp.mean(jnp.sum(f * p, axis=-1))
+    return (expert_idx.astype(jnp.int32), weight,
+            jnp.stack([balance, jnp.zeros((), jnp.float32)]),
+            jnp.sum(chose, axis=0))
+
+
+def bias_step(bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:
+    """``noaux_tc``'s update of the correction bias after a step: up by
+    ``rate`` for an expert that drew fewer assignments than the mean, down
+    for one that drew more. ``load``: the assignments each expert drew in
+    the step, the last axis over the experts."""
+    load = load.astype(jnp.float32)
+    mean = jnp.mean(load, axis=-1, keepdims=True)
+    return bias + rate * jnp.sign(mean - load).astype(bias.dtype)
 
 
 def top_k_gating(logits: jax.Array, top_k: int, capacity_: int

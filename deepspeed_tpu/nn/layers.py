@@ -159,7 +159,8 @@ def silu(x: jax.Array) -> jax.Array:
 
 
 def rotary_embedding(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
-                     style: str = "half") -> jax.Array:
+                     style: str = "half", freqs: Optional[jax.Array] = None,
+                     scale: float = 1.0) -> jax.Array:
     """Apply rotary position embeddings.
 
     x: [..., seq, heads, head_dim]; positions: [..., seq].
@@ -167,14 +168,19 @@ def rotary_embedding(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
     half"); ``style='interleaved'`` pairs adjacent dims (2i, 2i+1) — gpt-j's
     "rotate every two". TPU-native equivalent of the reference's
     ``apply_rotary_pos_emb.cu``; left to XLA fusion (elementwise, fuses into
-    the surrounding matmuls).
+    the surrounding matmuls). ``freqs`` [head_dim / 2] replaces the plain
+    ``theta ** (-2i / head_dim)`` (a scaled rope's), ``scale`` multiplies
+    cos and sin.
     """
     head_dim = x.shape[-1]
     half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., seq, half]
     cos = jnp.cos(angles)[..., :, None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     if style == "interleaved":
         x1, x2 = x[..., 0::2], x[..., 1::2]
         y1 = x1 * cos - x2 * sin

@@ -140,9 +140,17 @@ def resolve_policy(name: Optional[str]):
 #: layout copies in front of the kernel are recomputed either way). A value
 #: under any other name is never kept: an XLA attention route's ``[B,H,S,S]``
 #: scores (``attn_big``) are no candidate.
+#: Latent attention's and the gate's products (PR 32) stand where what a
+#: kept byte spares puts them, reckoned and not yet measured: ``attn_gate``
+#: and ``kv_latent`` are products over the hidden size like ``o_proj`` and
+#: ``q_proj`` (2 x hidden FLOPs recomputed for each 2 bytes kept) and join
+#: their groups' neighbourhood; ``kv_up`` contracts over the latent rank, a
+#: quarter of that a byte, and comes last. A shared expert's first products
+#: are a dense MLP's and take its names (``gate_proj``, ``up_proj``).
 SAVE_ORDER = (("attn_lse", "attn_o"), ("moe_logits",), ("wi_gate", "wi_up"),
               ("wi",), ("wo",), ("fc_in",), ("gate_proj", "up_proj"),
-              ("o_proj",), ("q_proj", "k_proj", "v_proj"))
+              ("o_proj",), ("attn_gate",),
+              ("q_proj", "k_proj", "v_proj", "kv_latent"), ("kv_up",))
 
 #: What a saved byte costs the step's peak, measured on the chip by filling
 #: the device until a step fails (PERF.md, PR 30): 1.0 to 1.2 once something

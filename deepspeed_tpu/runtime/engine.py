@@ -529,13 +529,14 @@ class DeepSpeedEngine:
         self._jit_train_step = None
         # MoE counters, plain values kept with telemetry off: the path the
         # expert layers take ("dropless" / "capacity" / None, which follows
-        # from the model's configuration), the assignments (tokens x top_k x
-        # MoE layers) dispatched by the fused steps so far, and those steps.
+        # from the model's configuration), the experts a layer has
+        # (``experts_published``) and those this chip holds of them
+        # (``experts_held``), and the fused steps so far.
         # On the no-drop path the fused step also returns the per-expert row
         # counts [layers, experts]; they stay on the device until
         # ``moe_expert_rows()`` asks for them.
         self.moe_totals = {"path": getattr(self.model, "moe_path", None),
-                           "rows_dispatched": 0, "steps": 0}
+                           "steps": 0, **self._experts_of_model()}
         self._step_stats = None
         # Optimizer-kernel counters, kept with telemetry off and filled from
         # the static bucket plan when a step that updates is traced: the
@@ -1197,7 +1198,7 @@ class DeepSpeedEngine:
 
     @scoped("optimizer")
     def _apply_from_grads(self, state, grads, lr, spike_thresh=None,
-                          loss=None):
+                          loss=None, stats=()):
         """The apply boundary with the gradient source explicit: the split
         path passes the persistent ``grad_acc`` buffer; the fused gas==1
         path passes the backward's output directly — those gradients are
@@ -1210,7 +1211,14 @@ class DeepSpeedEngine:
         in-graph skip generalizes from the fp16 overflow to any anomaly
         bit (``skip_on_anomaly``). ``spike_thresh=None`` (guardian off)
         traces the exact pre-guardian program — the
-        ``guardian-step-parity`` lint entry machine-checks that."""
+        ``guardian-step-parity`` lint entry machine-checks that.
+
+        ``stats``: the step's statistics as ``_loss_and_stats`` returns
+        them (the fused step has them, the split path's apply does not). A
+        model with a router bias (``has_router_bias``) has that one leaf
+        taken out of the optimizer's hands here: its master and its weight
+        come from the old master moved by the step's load alone, or left
+        where they were without ``stats``."""
         scale = state["loss_scale"]["cur_scale"]
         overflow = has_overflow(grads) if self.config.fp16.enabled else jnp.asarray(False)
 
@@ -1232,7 +1240,13 @@ class DeepSpeedEngine:
             factor = inv * clip
 
         def do_update(_):
-            return self._optimizer_update(grads, state["opt"], lr, factor)
+            params, opt = self._optimizer_update(grads, state["opt"], lr, factor)
+            if getattr(self.model, "has_router_bias", False):
+                load = stats[0].get("moe_router_load") if stats else None
+                old = state["opt"]["master"]
+                hold = lambda new: self.model.hold_router_bias(old, new, load)
+                params, opt = hold(params), {**opt, "master": hold(opt["master"])}
+            return params, opt
 
         def skip_update(_):
             return state["params"], state["opt"]
@@ -1303,7 +1317,7 @@ class DeepSpeedEngine:
                                                       with_stats=True)
             res = self._apply_from_grads(
                 state, state["grad_acc"], lr, spike_thresh=spike_thresh,
-                loss=loss if guardian else None)
+                loss=loss if guardian else None, stats=tuple(stats))
             return (res[0], loss) + res[1:] + tuple(stats)
         scale = state["loss_scale"]["cur_scale"]
 
@@ -1316,7 +1330,8 @@ class DeepSpeedEngine:
         grads = jax.tree.map(lambda g: g.astype(self.grad_dtype), grads)
         res = self._apply_from_grads(state, grads, lr,
                                      spike_thresh=spike_thresh,
-                                     loss=loss if guardian else None)
+                                     loss=loss if guardian else None,
+                                     stats=stats)
         return (res[0], loss) + res[1:] + stats
 
     def _train_step_fn_guardian(self, state, batch, lr, spike_thresh):
@@ -2199,7 +2214,7 @@ class DeepSpeedEngine:
                 else:
                     self.state, loss, overflow, gnorm, *stats = \
                         self._jit_train_step(self.state, batch, lr)
-        self._count_moe(batch, stats)
+        self._count_moe(stats)
         self._cached_loss = loss
         self.micro_steps += 1
         with self.telemetry.phase("post_step", phase="step",
@@ -2207,14 +2222,20 @@ class DeepSpeedEngine:
             self._post_step(overflow, gnorm, anomaly=anomaly, loss=loss)
         return loss
 
-    def _count_moe(self, batch, stats) -> None:
-        """The fused step's MoE counters: host arithmetic on shapes, and
-        the step's statistics kept as the device arrays they are."""
+    def _experts_of_model(self) -> Dict[str, int]:
+        """``experts_published`` and ``experts_held`` of the model's expert
+        layers (nothing for a model without)."""
+        moe = getattr(getattr(self.model, "config", None), "moe", None)
+        if moe is None:
+            return {}
+        lo, hi = moe.experts_held or (0, moe.num_experts)
+        return {"experts_published": moe.num_experts, "experts_held": hi - lo}
+
+    def _count_moe(self, stats) -> None:
+        """The fused step's MoE counters; the step's statistics are kept
+        as the device arrays they are."""
         if self.moe_totals["path"] is None:
             return
-        c = self.model.config
-        self.moe_totals["rows_dispatched"] += (
-            int(np.prod(batch["input_ids"].shape)) * c.moe.top_k * c.num_layers)
         self.moe_totals["steps"] += 1
         self._step_stats = stats[0] if stats else None
 

@@ -1099,6 +1099,9 @@ def _register_builtins() -> None:
                           _bert_params_for("roberta.", "lm_head"))
     register_architecture("distilbert", _distilbert_config, _distilbert_params)
     register_architecture("gpt_neo", _gpt_neo_config, _gpt_neo_params)
+    from ..models import instella_moe
+    register_architecture("deepseek_v3", instella_moe.config_kwargs,
+                          instella_moe.checkpoint_params)
 
 
 _register_builtins()

@@ -1,0 +1,322 @@
+"""Instella-MoE's block on the CPU at the ``instella-tiny`` preset (the
+tests' benchmark data): the program against the plain reference
+(benchmark/reference/instella_moe.py) in float32 on seeded random weights,
+for the loss, the gradient and the first step through ``initialize``; the
+shares of a layer that several chips divide add up to the whole layer;
+FarSkip off is the standard block; YaRN's band at the published keys; the
+correction bias moves by load alone; a held range of everything is the
+program it was."""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import harness
+from deepspeed_tpu.models import instella_moe_model, olmoe_model
+from deepspeed_tpu.models.transformer import (MoEConfig, TransformerConfig,
+                                              TransformerLM, YarnScaling)
+from deepspeed_tpu.moe.layer import MoE, held_capacity
+from deepspeed_tpu.moe.sharded_moe import bias_step, sigmoid_bias_router
+from tests.benchmark.helpers import DATA
+
+MANIFEST = os.path.join(DATA, "BENCHMARK.instella-tiny.json")
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def cell():
+    return harness.Cell(MANIFEST, "instella-tiny.train")
+
+
+@pytest.fixture(scope="module")
+def parts(cell):
+    """(reference module, adapter module, configuration, weights, ids)."""
+    ref = cell.load_module("reference", cell.config["reference"])
+    adapter = cell.load_module("adapters", cell.config["adapter"])
+    w = ref.make_weights(ref.key_of(7), cell.config, F32)
+    ids = jnp.asarray(np.random.default_rng(0).integers(
+        0, cell.config["vocab_size"], (8, 48)), jnp.int32)
+    return ref, adapter, cell.config, w, ids
+
+
+def close(a, b, rel=2e-4):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.abs(a - b).max() <= rel * max(np.abs(b).max(), 1e-12)
+
+
+def test_loss_and_gradient_match_the_reference(parts):
+    ref, adapter, cfg, w, ids = parts
+    model = adapter.model(cfg, remat=True, dtype="float32")
+    want, want_g = jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg))(w)
+    got, got_g = jax.value_and_grad(
+        lambda p: model.loss(p, {"input_ids": ids}))(adapter.to_program(w))
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    flat = adapter.from_program(got_g)
+    assert set(flat) == set(w)
+    for name, g in want_g.items():
+        assert close(flat[name], g), name
+    assert not np.asarray(flat["router_bias"]).any()     # stop_gradient: exactly 0
+    # logits alone (no prediction module is run) agree too
+    logits, _ = model.apply(adapter.to_program(w), ids)
+    assert close(logits, ref.forward(w, ids, cfg), rel=1e-4)
+
+
+def test_first_step_through_initialize(parts):
+    """``initialize`` -> ``train_batch`` in float32: the step's loss and
+    gradient norm are the reference's, every weight moves against the
+    reference's gradient, and the correction bias moves by gamma against
+    the load the reference counts, with no decay and no moment."""
+    import deepspeed_tpu
+    ref, adapter, cfg, w, ids = parts
+    model = adapter.model(cfg, remat=True, dtype="float32")
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=adapter.to_program(w), config={
+            "train_micro_batch_size_per_gpu": 1,
+            "zero_optimization": {"stage": 1},
+            "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}}})
+    loss = float(engine.train_batch({"input_ids": np.asarray(ids)}))
+    want, gnorm, signs = ref.loss_and_gradient(w, ids, cfg)
+    assert loss == pytest.approx(float(want), rel=1e-5)
+    assert float(engine.get_global_grad_norm()) == pytest.approx(float(gnorm), rel=1e-4)
+    new = adapter.from_program(engine.state["opt"]["master"])
+    wrong = total = 0
+    for name, s in signs.items():
+        s = np.asarray(s)
+        moved = np.sign(np.asarray(new[name], np.float64) - np.asarray(w[name], np.float64))
+        wrong += np.sum((moved + s != 0) & (s != 0))
+        total += np.sum(s != 0)
+    assert wrong / total < 2e-3
+    # the bias: old + gamma x sign(mean load - load), exactly, on every layer
+    load = np.asarray(ref.router_load(w, ids, cfg))
+    gamma = cfg["assumed"]["bias_update_gamma"]
+    want_bias = np.concatenate([np.asarray(w["router_bias"]), np.asarray(w["m_router_bias"])])
+    want_bias = want_bias + gamma * np.sign(load.mean(-1, keepdims=True) - load)
+    got_bias = np.concatenate([np.asarray(new["router_bias"]), np.asarray(new["m_router_bias"])])
+    np.testing.assert_allclose(got_bias, want_bias, rtol=0, atol=1e-7)
+    assert np.abs(got_bias - np.concatenate(
+        [np.asarray(w["router_bias"]), np.asarray(w["m_router_bias"])])).max() == pytest.approx(
+            gamma, rel=1e-3)
+    for slot in ("exp_avg", "exp_avg_sq"):
+        moments = adapter.from_program(engine.state["opt"][slot])
+        assert not np.asarray(moments["router_bias"]).any()
+        assert np.asarray(moments["router"]).any()
+    # the weight the model reads is the master's
+    np.testing.assert_array_equal(
+        np.asarray(adapter.from_program(engine.state["params"])["router_bias"]),
+        np.asarray(new["router_bias"]))
+    # counters: [expert layers + the module's, experts held], a share of the rows
+    rows = engine.moe_expert_rows()
+    assert rows.shape == (cfg["num_hidden_layers"] - 1 + 1, 8)
+    np.testing.assert_array_equal(rows, load[:, :8].astype(np.int32))
+    assert engine.moe_totals == {"path": "dropless", "steps": 1,
+                                 "experts_published": 16, "experts_held": 8}
+    assert (rows.sum(1) < 8 * 48 * cfg["num_experts_per_tok"]).all()
+
+
+def test_the_shares_add_up_to_the_whole_layer(parts):
+    """``chips_sharing_a_layer`` = 2: the two held ranges' routed parts
+    (program, each on its own weight stacks) plus the shared expert counted
+    once are the uncut reference's whole expert layer."""
+    ref, _, cfg, _, _ = parts
+    chips = cfg["share"]["chips_sharing_a_layer"]
+    whole = {k: v for k, v in cfg.items() if k != "share"}
+    whole["n_routed_experts"] = cfg["share"]["published"]["n_routed_experts"]
+    s = ref.sizes(whole)
+    w = ref.make_weights(ref.key_of(3), whole, F32)
+    lw = {k: w[k][1] for k in ("router", "router_bias", "w_gate", "w_up", "w_down",
+                               "s_gate", "s_up", "s_down")}
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, s["H"]), F32)
+    h = x.reshape(-1, s["H"])
+    with jax.default_matmul_precision("highest"):
+        weight, _, load = ref.route(h, lw["router"], lw["router_bias"], s, 24)
+        want = (ref.held_experts(h, weight, lw, s)
+                + ref.gated_mlp(h, lw["s_gate"], lw["s_up"], lw["s_down"]))
+    assert s["Eh"] == s["E"] == 16 and int(load.sum()) == 48 * s["k"]
+    held = s["E"] // chips
+    total = ref.gated_mlp(h, lw["s_gate"], lw["s_up"], lw["s_down"])   # once
+    for rank in range(chips):
+        lo, hi = rank * held, (rank + 1) * held
+        layer = MoE(s["H"], s["I"], num_experts=s["E"], top_k=s["k"], capacity_factor=None,
+                    balance_loss="topk_share", router="sigmoid_bias",
+                    routed_scale=s["scale"], experts_held=(lo, hi))
+        params = {"gate": lw["router"], "bias": lw["router_bias"],
+                  "wi_gate": lw["w_gate"][lo:hi], "wi_up": lw["w_up"][lo:hi],
+                  "wo": lw["w_down"][lo:hi]}
+        out, _, rows = layer.dropless_forward(params, x)
+        np.testing.assert_array_equal(np.asarray(rows), np.asarray(load, np.int32))
+        total = total + out.reshape(-1, s["H"])
+    assert close(total, want, rel=1e-5)
+
+
+def test_the_reference_given_a_share_leaves_the_absent_experts_out(parts):
+    """The same weights under rank 0's and rank 1's share: the two partial
+    expert sums add up to the uncut one."""
+    ref, _, cfg, _, _ = parts
+    whole = {k: v for k, v in cfg.items() if k != "share"}
+    whole["n_routed_experts"] = 16
+    w = ref.make_weights(ref.key_of(3), whole, F32)
+    s = ref.sizes(whole)
+    lw = {k: w[k][0] for k in ("router", "router_bias", "w_gate", "w_up", "w_down")}
+    h = jax.random.normal(jax.random.PRNGKey(2), (32, s["H"]), F32)
+    weight, _, _ = ref.route(h, lw["router"], lw["router_bias"], s, 32)
+    parts_sum = 0
+    for rank in (0, 1):
+        shared = dict(cfg, assumed=dict(cfg["assumed"], share_rank=rank))
+        sr = ref.sizes(shared)
+        assert (sr["lo"], sr["Eh"], sr["E"]) == (8 * rank, 8, 16)
+        mine = {k: (v[8 * rank:8 * rank + 8] if k.startswith("w_") else v)
+                for k, v in lw.items()}
+        parts_sum = parts_sum + ref.held_experts(h, weight, mine, sr)
+    assert close(parts_sum, ref.held_experts(h, weight, lw, s), rel=1e-5)
+
+
+def test_farskip_off_is_the_standard_block(parts):
+    ref, adapter, cfg, w, ids = parts
+    plain = dict(cfg, farskip=False)
+    model = adapter.model(plain, remat=False, dtype="float32")
+    assert not model.config.farskip
+    got = model.loss(adapter.to_program(w), {"input_ids": ids})
+    assert float(got) == pytest.approx(float(ref.next_token_loss(w, ids, plain)), rel=1e-5)
+    with_flag = adapter.model(cfg, remat=False, dtype="float32").loss(
+        adapter.to_program(w), {"input_ids": ids})
+    assert abs(float(with_flag) - float(got)) > 1e-6
+    # and by hand on one block: x + attn(norm(x)), then + mlp(norm(that))
+    block = jax.tree.map(lambda a: a[0], adapter.to_program(w)["dense_blocks"])
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, 16, cfg["hidden_size"]), F32)
+    positions = jnp.arange(16)[None]
+    carry = (x, positions, model._aux_zero())
+    (y, _, _), _ = model._block_fn(None, carry, (block, jnp.ones((), F32)))
+    ln = lambda name, t: model._block_layers[name](block[name], t)
+    mid = x + model._attn(block, ln("ln_1", x), positions)
+    want = mid + model._mlp(block, ln("ln_2", mid))[0]
+    assert close(y, want, rel=1e-6)
+
+
+def test_yarn_band_at_the_published_keys(parts):
+    ref = parts[0]
+    yarn = YarnScaling(factor=40.0, original_max_position=4096, beta_fast=32.0,
+                       beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0)
+    assert yarn.band(32, 8e6) == (3, 7)
+    assert ref.yarn_band(32, 8e6, {"original_max_position_embeddings": 4096,
+                                   "beta_fast": 32, "beta_slow": 1}) == (3, 7)
+    assert yarn.softmax_scale == pytest.approx(0.1 * np.log(40) + 1) == pytest.approx(1.3689, abs=1e-4)
+    assert yarn.cos_sin_scale == 1.0
+    f = np.asarray(yarn.frequencies(32, 8e6))
+    plain = 8e6 ** (-2 * np.arange(16) / 32)
+    np.testing.assert_allclose(f[:4], plain[:4], rtol=1e-6)          # short waves: as they are
+    np.testing.assert_allclose(f[7:], plain[7:] / 40, rtol=1e-6)     # long ones: over the factor
+    assert (f[4:7] < plain[4:7]).all() and (f[4:7] > plain[4:7] / 40).all()
+    model = instella_moe_model("instella-moe-16b-a3b", num_layers=2, vocab_size=64)
+    assert model.config.rope_scaling == yarn and model.config.head_dim == 128
+
+
+def test_bias_step_and_router():
+    bias = jnp.zeros((2, 4))
+    load = jnp.asarray([[4, 0, 2, 2], [1, 1, 1, 1]])
+    np.testing.assert_allclose(np.asarray(bias_step(bias, load, 0.5)),
+                               [[-0.5, 0.5, 0, 0], [0, 0, 0, 0]])
+    # the bias decides the choice and stays out of the weight
+    logits = jnp.asarray([[0.0, 0.1, 0.2, 0.3]] * 6)
+    idx, weight, losses, rows = sigmoid_bias_router(
+        logits, jnp.asarray([1.0, 0, 0, 0]), 2, normalize=True, routed_scale=2.5,
+        rows_per_seq=3)
+    assert sorted(np.asarray(idx[0]).tolist()) == [0, 3]
+    s = jax.nn.sigmoid(jnp.asarray([0.0, 0.3]))
+    np.testing.assert_allclose(sorted(np.asarray(weight[0])), np.asarray(s / s.sum() * 2.5),
+                               rtol=1e-6)
+    assert np.asarray(rows).tolist() == [6, 0, 0, 6] and float(losses[1]) == 0.0
+    # f_e = 4 / (2 x 3) x 3 = 2 for the two chosen, P their normalised mean score
+    p = np.asarray(jax.nn.sigmoid(logits[0]) / jnp.sum(jax.nn.sigmoid(logits[0])))
+    assert float(losses[0]) == pytest.approx(2 * (p[0] + p[3]), rel=1e-6)
+
+
+def test_held_range_of_everything_is_the_program_it_was():
+    """``experts_held`` = all of them: OLMoE's tiny preset to the digit,
+    loss and every gradient."""
+    base = olmoe_model("olmoe-tiny", dtype=F32)
+    moe = dataclasses.replace(base.config.moe, experts_held=(0, 8))
+    told = TransformerLM(dataclasses.replace(base.config, moe=moe))
+    params = base.init(jax.random.PRNGKey(0))
+    batch = {"input_ids": jnp.asarray(
+        np.random.default_rng(0).integers(0, 512, (2, 32)), jnp.int32)}
+    a, ga = jax.jit(jax.value_and_grad(base.loss))(params, batch)
+    b, gb = jax.jit(jax.value_and_grad(told.loss))(params, batch)
+    assert float(a) == float(b)
+    assert all(np.array_equal(x, y) for x, y in
+               zip(jax.tree.leaves(ga), jax.tree.leaves(gb)))
+    import re
+    text = lambda m: re.sub(r"0x[0-9a-f]+", "", str(jax.make_jaxpr(m.loss)(params, batch)))
+    assert text(base) == text(told)
+
+
+@pytest.mark.parametrize("bias,short", [(0.0, False), (6.0, True)])
+def test_a_share_never_drops_a_row(bias, short):
+    """2 of 16 experts held, 2048 tokens: the buffer is four times the even
+    share, multiplied whole; with the router pushed onto the held experts they draw more rows
+    than it has, and the layer computes the same sum, the rest without it."""
+    E, k, h, f, lo, hi = 16, 3, 32, 16, 0, 2
+    layer = MoE(h, f, num_experts=E, top_k=k, capacity_factor=None,
+                balance_loss="topk_share", router="sigmoid_bias", routed_scale=2.5,
+                experts_held=(lo, hi))
+    params = jax.tree.map(lambda a: a * 20, layer.init(jax.random.PRNGKey(0)))
+    params["bias"] = jnp.zeros((E,)).at[lo:hi].set(bias)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 1024, h))
+    cap = held_capacity(2048 * k, hi - lo, E)
+    assert cap == 2560 < 2048 * k
+
+    def dense(p, x):
+        t = x.reshape(-1, h)
+        idx, w, _, _ = sigmoid_bias_router(t @ p["gate"], p["bias"], k, normalize=True,
+                                           routed_scale=2.5, rows_per_seq=1024)
+        out = 0
+        for e in range(lo, hi):
+            we = jnp.sum(jnp.where(idx == e, w, 0), axis=1)
+            y = (jax.nn.silu(t @ p["wi_gate"][e - lo]) * (t @ p["wi_up"][e - lo])) @ p["wo"][e - lo]
+            out = out + y * we[:, None]
+        return jnp.sum(jnp.sin(out))
+
+    loss = lambda p, x: jnp.sum(jnp.sin(layer.dropless_forward(p, x)[0]))
+    rows = jax.jit(layer.dropless_forward)(params, x)[2]
+    assert (int(rows[lo:hi].sum()) > cap) is short
+    got, g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+    want, gd = jax.value_and_grad(dense, argnums=(0, 1))(params, x)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    assert close(g[1], gd[1], rel=1e-4)
+    assert all(close(g[0][n], gd[0][n], rel=1e-4) for n in ("gate", "wi_gate", "wi_up", "wo"))
+    # and under a block's rematerialisation, the loop's backward as well
+    again = jax.jit(jax.grad(jax.checkpoint(loss), argnums=1))(params, x)
+    assert close(again, g[1], rel=1e-6)
+    # held_capacity: a quarter or more of the experts held needs no second path
+    assert held_capacity(6144, 6, 16) == 6144 and held_capacity(98304, 8, 64) == 36864
+
+
+def test_what_the_new_fields_refuse():
+    moe = MoEConfig(num_experts=8, top_k=2, capacity_factor=None, router="sigmoid_bias")
+    with pytest.raises(ValueError, match="sigmoid_bias"):
+        MoEConfig(seq_balance_coef=1e-4)
+    with pytest.raises(ValueError, match="no-drop"):
+        MoE(8, 8, router="sigmoid_bias")
+    with pytest.raises(ValueError, match="no range"):
+        MoE(8, 8, capacity_factor=None, experts_held=(4, 12))
+    with pytest.raises(ValueError, match="latent attention needs"):
+        TransformerLM(TransformerConfig(attention="latent", position="rope", norm="rmsnorm"))
+    with pytest.raises(ValueError, match="first_dense_layers"):
+        TransformerLM(TransformerConfig(first_dense_layers=1))
+    with pytest.raises(NotImplementedError, match="one multi-token"):
+        TransformerLM(TransformerConfig(mtp_layers=2))
+    model = instella_moe_model("instella-tiny", dtype=F32)
+    assert model.config.moe.router == moe.router and model.has_router_bias
+    x = jnp.zeros((1, 8, 64))
+    block = jax.tree.map(lambda a: a[0], model.init(jax.random.PRNGKey(0))["blocks"])
+    with pytest.raises(NotImplementedError, match="one block at a time"):
+        model.block_apply(block, x, jnp.arange(8)[None])
+    from deepspeed_tpu.models.registry import get_architecture
+    spec = get_architecture("deepseek_v3")
+    with pytest.raises(NotImplementedError, match="q_lora_rank"):
+        spec.config_fn({"q_lora_rank": 1536, "num_attention_heads": 4})
+    with pytest.raises(NotImplementedError, match="checkpoint"):
+        spec.params_fn(None, {})

@@ -156,7 +156,7 @@ def test_trains_through_initialize_and_counts_every_assignment(tiny):
     assert losses[-1] < losses[0]
     k, layers = model.config.moe.top_k, model.config.num_layers
     assert engine.moe_totals == {"path": "dropless", "steps": 3,
-                                 "rows_dispatched": 3 * 8 * 32 * k * layers}
+                                 "experts_published": 8, "experts_held": 8}
     rows = engine.moe_expert_rows()
     assert rows.shape == (layers, 8) and (rows.sum(1) == 8 * 32 * k).all()
 
@@ -173,7 +173,7 @@ def test_capacity_models_count_too_and_keep_their_program():
         "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
     engine.train_batch({"input_ids": np.random.default_rng(0).integers(0, 256, (8, 16))})
     assert engine.moe_totals == {"path": "capacity", "steps": 1,
-                                 "rows_dispatched": 8 * 16 * 2 * 2}
+                                 "experts_published": 4, "experts_held": 4}
     assert engine.moe_expert_rows() is None
 
 

@@ -166,7 +166,9 @@ def test_choose_saved_takes_the_measured_order():
     assert choose_saved(every, 2 * MB) == ("attn_lse", "attn_o")
     assert choose_saved({}, None) == () == choose_saved({}, 0)
     # the projections in front of the kernel go first when room runs out
-    assert SAVE_ORDER[-1] == ("q_proj", "k_proj", "v_proj")
+    # (latent attention's compressed vector with them, its up-projection,
+    # which contracts over the rank alone, after them)
+    assert SAVE_ORDER[-2:] == (("q_proj", "k_proj", "v_proj", "kv_latent"), ("kv_up",))
     assert len(set(names)) == len(names)
 
 

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ..ops.transformer import pallas_gmm
 from ..runtime import topology as topo_mod
 from ..runtime.topology import BATCH_AXES, DATA_AXIS, EXPERT_AXIS
 from ..utils.jax_compat import with_sharding_constraint
@@ -230,6 +231,13 @@ def _overflow_rows_bwd(layer, res, g):
 _overflow_rows.defvjp(_overflow_rows_fwd, _overflow_rows_bwd)
 
 
+def _mesh_devices() -> int:
+    """The devices of the live mesh (1 where no engine has set one up): what
+    ``pallas_gmm.choose_route`` keeps its kernel out of a partitioned
+    program by."""
+    return topo_mod.get_topology().world_size if topo_mod.is_initialized() else 1
+
+
 def held_capacity(assignments: int, held: int, experts: int) -> int:
     """Rows of the buffer a chip that holds ``held`` of ``experts`` experts
     sorts its rows into, of the ``assignments`` = tokens x top_k a step
@@ -371,8 +379,10 @@ class MoE:
 
         The ``tokens x top_k`` assignments are sorted by expert, their rows
         gathered once, the expert FFN run as grouped matmuls over
-        ``rows[experts]`` (``jax.lax.ragged_dot``: its backward products
-        are a grouped matmul and one that contracts over the ragged rows),
+        ``rows[experts]`` (``pallas_gmm.grouped_matmul``: the in-repo kernel
+        or ``jax.lax.ragged_dot`` by its ``choose_route``; the backward
+        products are a grouped matmul and one that contracts over the
+        ragged rows),
         and each token's k rows weighted and summed in float32. No
         ``[experts, tokens, ..]`` tensor exists forward or backward, an
         expert with no rows costs nothing and one with most of them is
@@ -428,6 +438,19 @@ class MoE:
             mid = jax.nn.gelu(first("wi"))
         return product(mid, "wo")
 
+    def grouped_products(self, n_tok: int) -> Tuple[Tuple[str, int, int, int, int], ...]:
+        """``(name, m, k, n, g)`` of each grouped matmul one forward of the
+        no-drop path launches over ``n_tok`` tokens, ``rows [m, k] x stack
+        [g, k, n]``, in the order ``_ffn`` calls them: static shapes, for the
+        engine's counters."""
+        g = self.held[1] - self.held[0]
+        m = n_tok * self.top_k
+        if g != self.num_experts:
+            m = held_capacity(m, g, self.num_experts)
+        h, f = self.hidden_size, self.intermediate_size
+        first = ("wi_gate", "wi_up") if self.activation == "silu_gated" else ("wi",)
+        return tuple((name, m, h, f, g) for name in first) + (("wo", m, f, h, g),)
+
     def _all_rows(self, params, tokens, eidx, weight, rows) -> jax.Array:
         """Every expert is here: all ``tokens x top_k`` assignments sorted
         by expert, gathered, multiplied and combined. -> [T, h] float32."""
@@ -440,8 +463,8 @@ class MoE:
         with jax.named_scope("moe/dispatch"):
             expert_in = _dispatch_rows(tokens, order, inv, k)
         with jax.named_scope("moe/experts"):
-            expert_out = self._ffn(params, expert_in, lambda a, name: jax.lax.ragged_dot(
-                a, params[name].astype(dt), rows))
+            expert_out = self._ffn(params, expert_in, lambda a, name: pallas_gmm.grouped_matmul(
+                a, params[name].astype(dt), rows, _mesh_devices()))
         with jax.named_scope("moe/combine"):
             # the combine's gradient by the routing weights needs the
             # experts' rows as it reads them, in assignment order: named
@@ -484,8 +507,8 @@ class MoE:
             expert_in = _dispatch_held_rows(tokens, order, inv, filled, k)
         with jax.named_scope("moe/experts"):
             expert_out = checkpoint_name(
-                self._ffn(params, expert_in, lambda a, name: jax.lax.ragged_dot(
-                    a, params[name].astype(dt), in_buffer)), "wo")
+                self._ffn(params, expert_in, lambda a, name: pallas_gmm.grouped_matmul(
+                    a, params[name].astype(dt), in_buffer, _mesh_devices())), "wo")
         with jax.named_scope("moe/combine"):
             out = _combine_held_rows(expert_out, weight, order, inv, filled)
         if cap == n_tok * k:

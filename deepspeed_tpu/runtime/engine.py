@@ -535,8 +535,14 @@ class DeepSpeedEngine:
         # On the no-drop path the fused step also returns the per-expert row
         # counts [layers, experts]; they stay on the device until
         # ``moe_expert_rows()`` asks for them.
+        # ``grouped_matmul_route`` and the two ``products_*`` (no-drop path;
+        # None until a step is traced): which of ``pallas_gmm.choose_route``'s
+        # routes the expert layers' grouped matmuls take ("kernel" / "xla" /
+        # "mixed"), and the products one step launches by route and kind.
         self.moe_totals = {"path": getattr(self.model, "moe_path", None),
-                           "steps": 0, **self._experts_of_model()}
+                           "steps": 0, **self._experts_of_model(),
+                           "grouped_matmul_route": None,
+                           "products_kernel": None, "products_xla": None}
         self._step_stats = None
         # Optimizer-kernel counters, kept with telemetry off and filled from
         # the static bucket plan when a step that updates is traced: the
@@ -1116,6 +1122,7 @@ class DeepSpeedEngine:
             loss, stats = self.model.loss_and_stats(params, batch,
                                                     **self._remat_kw())
             out = loss, (stats,)
+            self._count_grouped_products(batch, stats["moe_expert_rows"].shape[0])
         else:
             out = self.model.loss(params, batch, **self._remat_kw()), ()
         kept = self.remat_totals
@@ -2230,6 +2237,32 @@ class DeepSpeedEngine:
             return {}
         lo, hi = moe.experts_held or (0, moe.num_experts)
         return {"experts_published": moe.num_experts, "experts_held": hi - lo}
+
+    def _count_grouped_products(self, batch, expert_layers: int) -> None:
+        """``moe_totals``' route and products a step, by kind, of the no-drop
+        path's grouped matmuls: host arithmetic from static shapes while the
+        step is traced (as ``opt_kernel_totals`` is). One differentiated
+        layer launches each product forward, as a row gradient and as a
+        weight gradient; the backward runs a forward product again where the
+        block is rematerialised and the policy did not keep its name
+        (``remat_totals``; a policy that is not ``KEEP_PRODUCTS`` is counted
+        as keeping none)."""
+        from ..ops.transformer import pallas_gmm
+        moe, cfg = self.model._moe, self.model.config
+        b, s = batch["input_ids"].shape[:2]
+        kept = self.remat_totals["saved"] if cfg.remat else None
+        counts = {route: dict.fromkeys(pallas_gmm.KINDS, 0) for route in ("kernel", "xla")}
+        for name, m, k, n, g in moe.grouped_products(b * s):
+            route = counts[pallas_gmm.choose_route(
+                m, k, n, g, self.param_dtype, jax.default_backend(), self.mesh.size)]
+            again = kept is not None and name not in kept
+            route["forward"] += expert_layers * (1 + again)
+            route["row_gradient"] += expert_layers
+            route["weight_gradient"] += expert_layers
+        used = [r for r in counts if any(counts[r].values())]
+        self.moe_totals.update(
+            grouped_matmul_route=used[0] if len(used) == 1 else "mixed",
+            products_kernel=counts["kernel"], products_xla=counts["xla"])
 
     def _count_moe(self, stats) -> None:
         """The fused step's MoE counters; the step's statistics are kept

@@ -112,8 +112,18 @@ def test_first_step_through_initialize(parts):
     rows = engine.moe_expert_rows()
     assert rows.shape == (cfg["num_hidden_layers"] - 1 + 1, 8)
     np.testing.assert_array_equal(rows, load[:, :8].astype(np.int32))
-    assert engine.moe_totals == {"path": "dropless", "steps": 1,
-                                 "experts_published": 16, "experts_held": 8}
+    totals = dict(engine.moe_totals)
+    products = totals.pop("products_xla")
+    assert totals == {"path": "dropless", "steps": 1,
+                      "experts_published": 16, "experts_held": 8,
+                      "grouped_matmul_route": "xla",
+                      "products_kernel": dict.fromkeys(products, 0)}
+    # three products a layer by kind, the forward's once more where the
+    # backward reruns the block and kept none of its names
+    again = 0 if engine.remat_totals["saved"] or not engine.model.config.remat else 1
+    assert products == {"forward": 3 * rows.shape[0] * (1 + again),
+                        "row_gradient": 3 * rows.shape[0],
+                        "weight_gradient": 3 * rows.shape[0]}
     assert (rows.sum(1) < 8 * 48 * cfg["num_experts_per_tok"]).all()
 
 

@@ -155,8 +155,16 @@ def test_trains_through_initialize_and_counts_every_assignment(tiny):
     losses = [float(engine.train_batch(batch)) for _ in range(3)]
     assert losses[-1] < losses[0]
     k, layers = model.config.moe.top_k, model.config.num_layers
+    # three grouped matmuls a layer, each forward, as a row gradient and as
+    # a weight gradient; remat keeps every name here, so none runs again; on
+    # the CPU the route is ``ragged_dot``
+    each = dict.fromkeys(("forward", "row_gradient", "weight_gradient"), 3 * layers)
+    assert engine.remat_totals["saved"]
     assert engine.moe_totals == {"path": "dropless", "steps": 3,
-                                 "experts_published": 8, "experts_held": 8}
+                                 "experts_published": 8, "experts_held": 8,
+                                 "grouped_matmul_route": "xla",
+                                 "products_kernel": dict.fromkeys(each, 0),
+                                 "products_xla": each}
     rows = engine.moe_expert_rows()
     assert rows.shape == (layers, 8) and (rows.sum(1) == 8 * 32 * k).all()
 
@@ -173,7 +181,9 @@ def test_capacity_models_count_too_and_keep_their_program():
         "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
     engine.train_batch({"input_ids": np.random.default_rng(0).integers(0, 256, (8, 16))})
     assert engine.moe_totals == {"path": "capacity", "steps": 1,
-                                 "experts_published": 4, "experts_held": 4}
+                                 "experts_published": 4, "experts_held": 4,
+                                 "grouped_matmul_route": None,
+                                 "products_kernel": None, "products_xla": None}
     assert engine.moe_expert_rows() is None
 
 

@@ -190,3 +190,94 @@ def test_bf16_fails_the_float32_tolerance():
     low = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
     got = moe(low, x.astype(jnp.bfloat16))[0].astype(jnp.float32)
     assert float(jnp.abs(got - dense(moe, params, x)).max()) > 10 * TOL
+
+
+def both_routes(monkeypatch, make, *args):
+    """``make()(*args)`` traced with ``grouped_matmul`` on its ``ragged_dot``
+    route (what the CPU takes) and again with the kernel forced through the
+    route function itself (interpret mode here). ``make`` builds the function
+    anew: a scan keeps the jaxpr of a body it has traced."""
+    from deepspeed_tpu.ops.transformer import pallas_gmm
+    want = make()(*args)
+    assert "pallas_call" not in str(jax.make_jaxpr(make())(*args))
+    monkeypatch.setattr(pallas_gmm, "choose_route", lambda *a: "kernel")
+    text = str(jax.make_jaxpr(make())(*args))
+    assert "pallas_call" in text and "ragged_dot" not in text
+    return make()(*args), want
+
+
+def under(remat, moe_loss):
+    """``moe_loss(params, x)`` as one block of a scan, plain or rematerialised
+    under the models' default policy (``KEEP_PRODUCTS``: the products' names
+    are what the backward keeps)."""
+    from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
+        KEEP_PRODUCTS, checkpointed)
+
+    def block(carry, layer):
+        acc, x = carry
+        return (acc + moe_loss(layer, x), x), None
+    if remat:
+        block = checkpointed(block, KEEP_PRODUCTS, 1)
+
+    def loss(params, x):
+        stacked = jax.tree.map(lambda a: a[None], params)
+        return jax.lax.scan(block, (jnp.zeros(()), x), stacked)[0][0]
+    return jax.value_and_grad(loss, (0, 1))
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "keep-products"])
+def test_all_rows_through_the_kernel_is_the_ragged_dot_program(remat, monkeypatch):
+    """``_all_rows`` with its three products through the Pallas grouped
+    matmul against the same layer through ``ragged_dot``: loss and every
+    gradient, at the file's float32 tolerance."""
+    moe = layer(8, 3, normalize_weights=False)
+    params, x = skewed(moe, seed=2)
+    make = lambda: under(remat, lambda p, v: probe(run_layer, moe, p, v))
+    (got, g), (want, w) = both_routes(monkeypatch, make, params, x)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+        close(a, b)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "keep-products"])
+def test_held_rows_through_the_kernel_is_the_ragged_dot_program(remat, monkeypatch):
+    """``_held_rows`` (4 of 16 experts held, the buffer's unfilled rows in
+    the last group) the same way."""
+    moe = MoE(H, F, num_experts=16, top_k=3, capacity_factor=None,
+              balance_loss="topk_share", router="sigmoid_bias", routed_scale=2.5,
+              experts_held=(4, 8))
+    params = jax.tree.map(lambda a: a * 10.0, moe.init(jax.random.PRNGKey(3)))
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 512, H))
+    ct = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def moe_loss(p, v):
+        with jax.default_matmul_precision("highest"):
+            return jnp.sum(moe.dropless_forward(p, v)[0] * ct)
+    make = lambda: jax.jit(under(remat, lambda p, v: moe_loss(p, v)))
+    (got, g), (want, w) = both_routes(monkeypatch, make, params, x)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+        close(a, b)
+
+
+def test_grouped_products_are_the_shapes_the_layer_multiplies(monkeypatch):
+    """What the engine counts (``MoE.grouped_products``) is what
+    ``dropless_forward`` hands ``grouped_matmul``, with all experts held and
+    with a share."""
+    from deepspeed_tpu.ops.transformer import pallas_gmm
+    seen = []
+
+    def spy(rows, stack, sizes, devices=1):
+        seen.append((rows.shape[0], rows.shape[1], stack.shape[2], stack.shape[0]))
+        assert devices == 1
+        return jax.lax.ragged_dot(rows, stack, sizes)
+    monkeypatch.setattr(pallas_gmm, "grouped_matmul", spy)
+    for held in (None, (4, 8)):
+        moe = MoE(H, F, num_experts=16, top_k=3, capacity_factor=None,
+                  balance_loss="topk_share", experts_held=held)
+        x = jnp.ones((2, 512, H))
+        del seen[:]
+        jax.eval_shape(moe.dropless_forward, moe.init(jax.random.PRNGKey(0)), x)
+        names, shapes = zip(*((p[0], p[1:]) for p in moe.grouped_products(2 * 512)))
+        assert names == ("wi_gate", "wi_up", "wo") and list(shapes) == seen
+    assert seen[0][0] < 2 * 512 * 3 and seen[0][3] == 4       # a share's buffer

@@ -79,6 +79,29 @@ class TestCompilesForV5e:
                          chip((B, S, H, D), BF16), chip((B, S, kvH, D), BF16),
                          chip((B, S, kvH, D), BF16))
 
+    @pytest.mark.parametrize("m,k,n,g", [
+        (32768, 2048, 1024, 64),    # olmoe-1b-7b.train.seq4k, wi_gate / wi_up
+        (32768, 1024, 2048, 64),    # its wo
+        (36864, 2048, 1408, 8),     # instella-moe-16b-a3b.train.seq8k: 11 x 128
+        (36864, 1408, 2048, 8),
+    ])
+    def test_grouped_matmul_fwd_bwd(self, chip, m, k, n, g):
+        """The three grouped-matmul kernels at the MoE cells' shapes, with
+        the tiles and the VMEM limit ``choose_tiles`` gives them, and under
+        the names the trace's reader finds a step's products by."""
+        from deepspeed_tpu.ops.transformer import pallas_gmm
+        assert pallas_gmm.choose_route(m, k, n, g, BF16, "tpu", 1) == "kernel"
+
+        def loss(rows, stack, sizes, ct):
+            return jnp.sum(pallas_gmm.kernel_grouped_matmul(
+                rows, stack, sizes, interpret=False).astype(F32) * ct)
+
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            chip((m, k), BF16), chip((g, k, n), BF16), chip((g,), I32),
+            chip((m, n), F32)).compile().as_text()
+        for name in ("ragged-dot-gmm-fwd", "ragged-dot-gmm-dlhs", "ragged-dot-gmm-dw"):
+            assert name in text
+
     @pytest.mark.parametrize("mode,moments,n", [
         ("adamw", "fp32", REAL.bucket_elems),
         ("adamw", "bf16-sr", REAL.bucket_elems),

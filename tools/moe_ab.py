@@ -1,21 +1,37 @@
 #!/usr/bin/env python
-"""A/B: capacity-dense batched einsum vs jax.lax.ragged_dot for the MoE
-expert FFN, at the smoke's MoE dims, on the attached chip (VERDICT r2 next
-#5 — record the grouped-matmul decision with numbers).
+"""A/B microbenchmarks of the expert FFN's matmuls on the attached chip.
 
-Interleaved timed windows per the repo's noise protocol (A and B
-alternate within one process and the BEST window of each is compared).
-Sync is by scalar fetch.
+``python tools/moe_ab.py gmm`` (PR 33): the in-repo Pallas grouped matmul
+(``ops/transformer/pallas_gmm.py``) against ``jax.lax.ragged_dot`` and its
+transposes, the three product kinds, at the two MoE cells' shapes (32,768
+rows x 2048 <-> 1024 over 64 groups; 36,864 x 2048 <-> 1408 over 8) and at
+the loads the cells draw (``moe_expert_rows`` as a run printed them) beside
+even, Dirichlet(0.3) and one-group-takes-most loads. ``--sweep`` times every
+row tile of ``ROW_TILES`` instead of ``choose_tiles``' own (a shared tile in
+parts of 128 rows, the kernel's way), ``--parts`` row tiles 256 and 512 with a
+shared tile multiplied whole against in parts. One JSON line a
+reading on stdout and in ``chiprun_out/gmm_ab.jsonl``: ms (best of the
+windows, host clock around ``block_until_ready``) and the share of the
+chip's 197 TFLOP/s.
 
-Run:  python tools/moe_ab.py        (writes one JSON line per variant)
+``python tools/moe_ab.py`` (round 2): the capacity-dense batched einsum
+against ``ragged_dot`` at the smoke's MoE dims.
+
+Interleaved timed windows (A and B alternate within one process and the
+BEST window of each is compared). No cell runs this file.
 """
 
+import argparse
 import json
+import os
+import sys
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # the smoke's MoE dims (chip_smoke.moe_train_model): h=1024, f=3584, 8 experts
 # top-2, tokens = micro(8) x seq(1024), capacity_factor 1.25
@@ -24,6 +40,34 @@ TOKENS = 8 * 1024
 TOPK = 2
 CAP = int(1.25 * TOKENS * TOPK / E)
 STEPS = 30
+
+PEAK_FLOPS = 197e12      # one v5e chip, bf16 (benchmark/peaks.py)
+ROW_TILES = (128, 256, 512, 1024, 2048)
+# the rows each expert drew in one step of a cell's run (my chip runs, PR 32:
+# `moe_expert_rows` of olmoe-1b-7b.train.seq4k seed 3200000113, both layers,
+# and of instella-moe-16b-a3b.train.seq8k seed 3200000081, layers 0, 3 and 5;
+# `_held_rows` gives the last group the buffer's unfilled rows as well)
+OLMOE_ROWS = {
+    "cell_layer0": [493, 851, 20, 222, 742, 902, 834, 2, 211, 328, 541, 256, 299, 1090,
+                    505, 136, 623, 63, 109, 666, 363, 351, 83, 465, 145, 654, 14, 448,
+                    293, 963, 1625, 853, 630, 1013, 1152, 459, 765, 382, 778, 993, 527,
+                    513, 330, 223, 162, 288, 512, 192, 230, 388, 939, 1357, 622, 291,
+                    936, 442, 128, 615, 145, 1074, 458, 111, 128, 835],
+    "cell_layer1": [491, 120, 0, 473, 527, 1852, 662, 189, 76, 185, 779, 1579, 240, 488,
+                    1253, 1, 125, 628, 387, 853, 1759, 55, 0, 1338, 85, 123, 0, 1066,
+                    106, 1127, 732, 112, 411, 869, 74, 153, 488, 254, 128, 275, 199,
+                    192, 417, 779, 2288, 81, 102, 19, 46, 716, 3, 157, 588, 17, 23, 113,
+                    762, 25, 774, 488, 667, 1279, 179, 2791],
+}
+INSTELLA_ROWS = {
+    "cell_layer0": [1249, 1494, 1503, 1771, 2250, 1117, 2537, 2056],
+    "cell_layer3": [534, 2351, 1093, 4555, 1647, 2360, 1892, 5419],
+    "cell_layer5": [1621, 8334, 5760, 1781, 373, 1909, 1574, 947],
+}
+SHAPES = {  # name -> (rows, hidden, expert width, groups, the cell's loads, padded)
+    "olmoe": (32768, 2048, 1024, 64, OLMOE_ROWS, False),
+    "instella": (36864, 2048, 1408, 8, INSTELLA_ROWS, True),
+}
 
 
 def capacity_dense(expert_in, wi, wo):
@@ -42,7 +86,7 @@ def sync(x):
     return float(jax.device_get(jnp.ravel(x)[0]))
 
 
-def main():
+def dense_against_ragged():
     rng = np.random.default_rng(0)
     dt = jnp.bfloat16
     expert_in = jnp.asarray(rng.normal(size=(E, CAP, H)), dt)
@@ -95,6 +139,154 @@ def main():
             "padding_flops_frac": round(1 - n_real / (E * CAP), 3)
                 if name == "dense" else 0.0,
         }), flush=True)
+
+
+# ---------------------------------------------------------------------------
+# the grouped matmul kernel against ragged_dot (PR 33)
+# ---------------------------------------------------------------------------
+
+
+def loads(m, g, cell_rows, padded, seed=0):
+    """name -> group sizes [g] summing to m."""
+    rng = np.random.default_rng(seed)
+
+    def scaled(p):
+        sizes = np.floor(np.asarray(p, np.float64) / np.sum(p) * m).astype(np.int64)
+        sizes[np.argmax(sizes)] += m - sizes.sum()
+        return sizes
+
+    hot = np.zeros(g)
+    hot[0] = 9 / 16
+    rest = np.arange(1, g)[: g - 1 - (g * 23) // 64]     # 23 of 64 groups empty
+    hot[rest] = (7 / 16) / len(rest)
+    out = {"even": scaled(np.ones(g)),
+           "dirichlet0.3": scaled(rng.dirichlet(np.full(g, 0.3))),
+           "one_hot_9_16": scaled(hot)}
+    for name, rows in cell_rows.items():
+        rows = np.asarray(rows, np.int64)
+        if padded:
+            rows[-1] += m - rows.sum()
+        if rows.sum() != m:
+            raise ValueError(f"{name}: {rows.sum()} rows for a buffer of {m}")
+        out[name] = rows
+    return out
+
+
+def products(kind, tiles, sub_rows=None):
+    """(kernel, xla) jitted functions of one product kind: arguments
+    (rows [m, k], stack [g, k, n], d_out [m, n], sizes [g]). ``sub_rows``:
+    the rows a shared tile is multiplied in parts of (None: the kernel's own)."""
+    from deepspeed_tpu.ops.transformer import pallas_gmm as G
+    limit = dict(vmem_limit_bytes=tiles.vmem_limit_bytes, interpret=False,
+                 sub_rows=sub_rows)
+    rdot = jax.lax.ragged_dot
+    if kind == "forward":
+        kernel = lambda a, w, d, s: G._rows_call(a, w, s, tiles.fwd, transposed=False, **limit)
+        xla = lambda a, w, d, s: rdot(a, w, s)
+    elif kind == "row_gradient":
+        kernel = lambda a, w, d, s: G._rows_call(d, w, s, tiles.dlhs, transposed=True, **limit)
+        xla = lambda a, w, d, s: jax.vjp(lambda x: rdot(x, w, s), a)[1](d)[0]
+    else:
+        kernel = lambda a, w, d, s: G._weights_call(a, d, s, tiles.dw, out_dtype=w.dtype, **limit)
+        xla = lambda a, w, d, s: jax.vjp(lambda x: rdot(a, x, s), w)[1](d)[0]
+    return jax.jit(kernel), jax.jit(xla)
+
+
+def best_ms(fn, args, iters=10, windows=3):
+    best = float("inf")
+    for _ in range(windows):
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / iters)
+    return 1e3 * best
+
+
+def gmm_against_ragged(sweep: bool, parts: bool, only):
+    from deepspeed_tpu.ops.transformer import pallas_gmm as G
+    if jax.default_backend() != "tpu":
+        raise SystemExit("tools/moe_ab.py gmm measures a TPU; none is attached")
+    os.makedirs("chiprun_out", exist_ok=True)
+    log = open("chiprun_out/gmm_ab.jsonl", "a")
+
+    def emit(rec):
+        line = json.dumps(rec)
+        print(line, flush=True)
+        log.write(line + "\n")
+        log.flush()
+
+    dt = jnp.bfloat16
+    for shape, (m, h, f, g, cell_rows, padded) in SHAPES.items():
+        if only and shape not in only:
+            continue
+        sizes = {name: jnp.asarray(s, jnp.int32)
+                 for name, s in loads(m, g, cell_rows, padded).items()}
+        for way, (k, n) in (("up", (h, f)), ("down", (f, h))):
+            key = jax.random.split(jax.random.PRNGKey(0), 3)
+            a = jax.random.normal(key[0], (m, k), dt)
+            w = jax.random.normal(key[1], (g, k, n), dt) * 0.02
+            d = jax.random.normal(key[2], (m, n), dt)
+            flops = 2 * m * k * n
+            chosen = G.choose_tiles(m, k, n, g, 2)
+            for kind in G.KINDS:
+                variants = {}
+                if sweep:
+                    for tm in ROW_TILES:
+                        if m % tm:
+                            continue
+                        variants[f"kernel_tm{tm}"] = (G.GmmTiles(
+                            (tm, n), (tm, k), (tm, k, n), G.VMEM_CAP), None)
+                elif parts:
+                    for tm in (256, 512):
+                        tiles = G.GmmTiles((tm, n), (tm, k), (tm, k, n), G.VMEM_CAP)
+                        variants[f"kernel_tm{tm}_whole"] = (tiles, tm)
+                        variants[f"kernel_tm{tm}_parts128"] = (tiles, 128)
+                else:
+                    variants["kernel"] = (chosen, None)
+                xla = None
+                for label, (tiles, sub_rows) in variants.items():
+                    kernel, xla_fn = products(kind, tiles, sub_rows)
+                    xla = xla or xla_fn
+                    try:
+                        got = kernel(a, w, d, sizes["dirichlet0.3"])
+                        want = xla(a, w, d, sizes["dirichlet0.3"])
+                        err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                                    - want.astype(jnp.float32))))
+                        scale = float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+                    except Exception as e:  # noqa: BLE001  a tile Mosaic refuses
+                        emit({"shape": shape, "way": way, "kind": kind, "variant": label,
+                              "error": f"{type(e).__name__}: {str(e)[:300]}"})
+                        continue
+                    for load, s in sizes.items():
+                        ms = best_ms(kernel, (a, w, d, s))
+                        emit({"shape": shape, "way": way, "kind": kind, "variant": label,
+                              "tiles": [list(tiles.fwd), list(tiles.dlhs), list(tiles.dw)],
+                              "load": load, "ms": round(ms, 4),
+                              "peak_share": round(flops / (ms / 1e3) / PEAK_FLOPS, 4),
+                              "max_abs_err": err, "max_abs": scale})
+                for load, s in sizes.items():
+                    ms = best_ms(xla, (a, w, d, s))
+                    emit({"shape": shape, "way": way, "kind": kind, "variant": "ragged_dot",
+                          "load": load, "ms": round(ms, 4),
+                          "peak_share": round(flops / (ms / 1e3) / PEAK_FLOPS, 4)})
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("mode", nargs="?", default="dense", choices=("dense", "gmm"))
+    ap.add_argument("--sweep", action="store_true",
+                    help="gmm: every row tile of ROW_TILES, not choose_tiles' own")
+    ap.add_argument("--parts", action="store_true",
+                    help="gmm: row tiles 256 and 512 with a shared tile multiplied "
+                         "whole against in parts of 128 rows")
+    ap.add_argument("--shape", action="append", choices=sorted(SHAPES),
+                    help="gmm: only this shape (may repeat)")
+    args = ap.parse_args()
+    if args.mode == "gmm":
+        gmm_against_ragged(args.sweep, args.parts, args.shape)
+    else:
+        dense_against_ragged()
 
 
 if __name__ == "__main__":

@@ -4,6 +4,7 @@ from .llama import llama_config, llama_model  # noqa: F401
 from .mixtral import mixtral_config, mixtral_model  # noqa: F401
 from .olmoe import olmoe_config, olmoe_model  # noqa: F401
 from .instella_moe import instella_moe_config, instella_moe_model  # noqa: F401
+from .afmoe import afmoe_config, afmoe_model  # noqa: F401
 from .opt_phi_falcon import (falcon_config, falcon_model, opt_config,  # noqa: F401
                              opt_model, phi_config, phi_model)
 from .bloom_neox_gptj import (bloom_config, bloom_model, gpt_neo_config,  # noqa: F401

@@ -181,6 +181,9 @@ class TransformerConfig:
     num_heads: int = 12
     num_kv_heads: Optional[int] = None  # None => MHA
     hidden_size: int = 768
+    # the width of one head where heads x head is not the hidden size
+    # (afmoe: 32 heads of 128 on a stream of 2048); None => hidden / heads
+    head_size: Optional[int] = None
     intermediate_size: Optional[int] = None  # None => 4*hidden
     activation: str = "gelu"        # 'gelu' | 'gelu_exact' | 'relu' | 'silu_gated'
     norm: str = "layernorm"          # 'layernorm' | 'rmsnorm'
@@ -191,9 +194,18 @@ class TransformerConfig:
     rope_dim: Optional[int] = None   # partial rotary (phi/neox/gpt-j); None => head_dim
     rope_style: str = "half"         # 'half' (llama/neox) | 'interleaved' (gpt-j)
     # per-layer causal attention windows (mistral sliding_window; gpt-neo
-    # alternating global/local): 0 = global, w > 0 = attend the last w keys.
-    # A single int applies to every layer.
+    # alternating global/local; afmoe's layer_types): 0 = global, w > 0 =
+    # attend the last w keys. A single int applies to every layer. The
+    # windows are STATIC in ``TransformerLM.apply``: the layer scan's unit
+    # is one period of the layers' kinds (``TransformerLM.scan_plan``), so a
+    # windowed layer's attention is built knowing its window (the flash
+    # kernel's grids are cut to it) and runs under the scope
+    # ``attn/core_window``. Only the ZeRO-3 pipelined scan and
+    # ``remat_policy='alternating'`` still hand the window over traced.
     attn_windows: Any = None         # Optional[int | Tuple[int, ...]]
+    # which layers a rotary ``position`` turns: 'all', or 'windowed' (afmoe:
+    # the layers with a window alone; a global layer has no positional term)
+    rope_layers: str = "all"
     attn_scale: Optional[float] = None  # gpt-neo: 1.0 (unscaled); None => 1/sqrt(hd)
     embedding_norm: bool = False     # bloom: LayerNorm right after wte
     parallel_block: bool = False     # falcon/phi: x + attn(ln(x)) + mlp(ln(x))
@@ -204,7 +216,16 @@ class TransformerConfig:
     lm_head_bias: bool = False       # phi/gpt-j lm_head carries a bias
     tie_embeddings: bool = True
     causal: bool = True              # False: bidirectional encoder (bert)
-    norm_style: str = "pre"          # 'pre' | 'post' (bert-era encoders)
+    # 'pre' | 'post' (bert-era encoders) | 'sandwich' (afmoe: a sub-block's
+    # input is normed as in 'pre' AND its output before it is added,
+    # x + post_ln(f(ln(x))), under the scope ``norm_post``)
+    norm_style: str = "pre"
+    # the embedding's output times this (afmoe's mup_enabled: hidden ** 0.5)
+    embedding_scale: Optional[float] = None
+    # packed documents: a token id that ENDS a document. Attention then stays
+    # inside a document (segment ids = the separators before a position);
+    # None: a row is one document
+    document_separator: Optional[int] = None
     type_vocab_size: int = 0         # bert segment (token-type) embeddings
     mlm_head: bool = False           # bert cls.predictions transform + bias
     # roberta: position ids are a cumsum over non-pad tokens offset by
@@ -226,7 +247,8 @@ class TransformerConfig:
     # split and rope (HF OlmoeAttention q_norm / k_norm)
     qk_norm: bool = False
     # with qk_norm: RMSNorm over each HEAD's vector of q and of k instead,
-    # one gain of head_dim shared by the heads, before rope
+    # one gain of head_dim shared by the heads (query heads and the fewer
+    # key heads alike), before rope
     qk_norm_per_head: bool = False
     moe: Optional[MoEConfig] = None
     moe_layer_freq: int = 1          # every k-th layer is MoE when moe is set
@@ -243,7 +265,8 @@ class TransformerConfig:
     v_head_dim: int = 0
     rope_scaling: Optional[YarnScaling] = None
     # a sigmoid gate on the attention output, element-wise, from the
-    # sub-block's input (Qiu et al. 2025): o_proj(attn * sigmoid(x W_g))
+    # sub-block's input (Qiu et al. 2025): o_proj(attn * sigmoid(x W_g));
+    # either kind of ``attention``
     attn_gate: bool = False
     # FarSkip-Collective's residual (Dukler et al. 2025): sub-block i reads
     # the stream as it stood BEFORE sub-block i-1 added to it,
@@ -269,7 +292,7 @@ class TransformerConfig:
     def head_dim(self) -> int:
         if self.attention == "latent":
             return self.qk_nope_dim + self.qk_rope_dim
-        return self.hidden_size // self.num_heads
+        return self.head_size or self.hidden_size // self.num_heads
 
     @property
     def scan_layers(self) -> int:
@@ -284,7 +307,7 @@ class TransformerConfig:
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
         ffn = self.ffn_size
         kv = self.kv_heads * self.head_dim
-        attn = h * (h + 2 * kv) + h * h
+        attn = 2 * h * self.num_heads * self.head_dim + 2 * h * kv
         if self.qk_norm:
             attn += h + kv
         if self.activation == "silu_gated":
@@ -324,7 +347,7 @@ class TransformerLM:
         self._norm = norm_cls
         # post-LN (bert): the last block's output LN already normalizes the
         # final hidden states — there is no separate final norm
-        self._ln_f = norm_cls(c.hidden_size) if c.norm_style == "pre" else None
+        self._ln_f = norm_cls(c.hidden_size) if c.norm_style != "post" else None
         # bloom normalizes embeddings before the first block; bert-era
         # encoders do the same (embeddings.LayerNorm)
         self._ln_emb = norm_cls(c.hidden_size) if c.embedding_norm else None
@@ -363,6 +386,13 @@ class TransformerLM:
                 self._windows = None
         else:
             self._windows = None
+        if c.rope_layers not in ("all", "windowed"):
+            raise ValueError(f"rope_layers {c.rope_layers!r} is not 'all' or 'windowed'")
+        # each layer's KIND, static: (window, 0 = global; whether a rotary
+        # position turns its queries and keys)
+        self._kinds = tuple(
+            (w, c.position == "rope" and (c.rope_layers == "all" or w > 0))
+            for w in (self._windows or (0,) * c.num_layers))
         if c.position == "alibi":
             if c.seq_parallel == "ring":
                 raise ValueError("alibi positions are not supported with "
@@ -401,12 +431,12 @@ class TransformerLM:
             }
         else:
             kv_out = c.kv_heads * c.head_dim
-            q_out = attn_out = c.hidden_size
+            q_out = attn_out = c.num_heads * c.head_dim
             attn_layers = {
-                "q_proj": lin(c.hidden_size, c.hidden_size, attn_bias, "column"),
+                "q_proj": lin(c.hidden_size, q_out, attn_bias, "column"),
                 "k_proj": lin(c.hidden_size, kv_out, attn_bias, "column"),
                 "v_proj": lin(c.hidden_size, kv_out, attn_bias, "column"),
-                "o_proj": lin(c.hidden_size, c.hidden_size, attn_out_bias, "row"),
+                "o_proj": lin(attn_out, c.hidden_size, attn_out_bias, "row"),
             }
         self._block_layers = {"ln_1": norm_cls(c.hidden_size), **attn_layers}
         if c.qk_norm and c.qk_norm_per_head:
@@ -422,6 +452,10 @@ class TransformerLM:
             # SAME normed input — no second norm exists in the checkpoint;
             # falcon-40b's "new decoder" norms each parallel branch separately
             self._block_layers["ln_2"] = norm_cls(c.hidden_size)
+        if c.norm_style == "sandwich":
+            # the norms of the two branches' OUTPUTS
+            self._block_layers["post_ln_1"] = norm_cls(c.hidden_size)
+            self._block_layers["post_ln_2"] = norm_cls(c.hidden_size)
         gated = lambda width: {
             "gate_proj": lin(c.hidden_size, width, False, "column"),
             "up_proj": lin(c.hidden_size, width, False, "column"),
@@ -480,6 +514,16 @@ class TransformerLM:
                 f"value heads of {c.v_head_dim} beside query heads of "
                 f"{c.head_dim}: the attention routes take one head size")
 
+    @property
+    def layer_kinds(self) -> Tuple[Tuple[int, bool], ...]:
+        """Each layer's static (window, 0 = global; whether rope turns it)."""
+        return self._kinds
+
+    @property
+    def _mixed_rope(self) -> bool:
+        """Whether some layers turn their queries and keys and others do not."""
+        return len({rope for _, rope in self._kinds}) > 1
+
     def _check_kinds(self) -> None:
         """What the second kind of layer, the two streams and the
         prediction module are written for, and nothing wider."""
@@ -503,12 +547,29 @@ class TransformerLM:
         if c.mtp_layers and (not c.causal or c.norm_style != "pre"):
             raise ValueError("multi-token prediction is a causal pre-norm decoder's")
         if (c.farskip or c.first_dense_layers or c.mtp_layers) and (
-                c.norm_style != "pre" or c.parallel_block
-                or c.remat_policy == "alternating" or self._windows is not None):
+                c.norm_style == "post" or c.parallel_block
+                or c.remat_policy == "alternating"):
             raise ValueError(
                 "farskip, first_dense_layers and mtp_layers are written for "
-                "sequential pre-norm blocks without windows, under any "
-                "remat_policy but 'alternating'")
+                "sequential pre-norm blocks, under any remat_policy but "
+                "'alternating'")
+        if (c.farskip or c.mtp_layers) and (
+                c.norm_style != "pre" or self._windows is not None):
+            raise ValueError("farskip and mtp_layers are written for pre-norm "
+                             "blocks without windows")
+        if c.norm_style not in ("pre", "post", "sandwich"):
+            raise ValueError(f"norm_style {c.norm_style!r}")
+        if c.norm_style == "sandwich" and c.parallel_block:
+            raise ValueError("sandwich norms are a sequential block's")
+        if self._mixed_rope and c.remat and c.remat_policy == "alternating":
+            raise NotImplementedError(
+                "remat_policy='alternating' scans layer pairs of one kind: "
+                "layers with and without a rotary position "
+                "(rope_layers='windowed') take any other policy")
+        if c.document_separator is not None and (
+                not c.causal or c.seq_parallel == "ring"):
+            raise ValueError("document_separator: packed documents are a causal "
+                             "decoder's, and not ring attention's")
 
     # -- init / specs --------------------------------------------------------
     def init(self, rng: jax.Array, dtype=jnp.float32) -> Params:
@@ -621,35 +682,58 @@ class TransformerLM:
 
     def _attn(self, block: Params, h: jax.Array, positions: jax.Array,
               attn_mask: Optional[jax.Array] = None,
-              window: Optional[jax.Array] = None) -> jax.Array:
+              window=None, rope: Optional[bool] = None) -> jax.Array:
         """Attention over the (pre-normed, or raw for post-LN) input h.
         ``attn_mask`` [B, S] (1 = real token) masks padding bidirectionally
-        via the segment-ids mechanism (encoders). ``window`` (traced scalar,
-        0 = global) restricts each query to the last ``window`` keys
-        (mistral sliding window / gpt-neo local layers)."""
+        via the segment-ids mechanism (encoders); with packed documents it
+        holds each position's document. ``window`` restricts each query to
+        the last ``window`` keys (mistral sliding window / gpt-neo local
+        layers / afmoe's sliding layers): a Python int where the layer's
+        kind is static (0 or None = global; the core then runs under the
+        scope ``core_window``), or a traced scalar (0 = global). ``rope``:
+        whether a rotary position turns this layer's queries and keys
+        (None: ``position`` says)."""
         c = self.config
         B, S, _ = h.shape
         if c.attention == "latent":
             return self._latent_attn(block, h, positions)
+        if rope is None:
+            rope = c.position == "rope"
+        if isinstance(window, int) and window <= 0:
+            window = None
         with jax.named_scope("attn"):
             with jax.named_scope("qkv"):
                 # saved as projected: QK-norm's backward needs its input
                 q = self._project(block, "q_proj", h)
                 k = self._project(block, "k_proj", h)
-                if c.qk_norm:
+                per_head = c.qk_norm and c.qk_norm_per_head
+                if c.qk_norm and not per_head:
                     q = self._block_layers["q_norm"](block["q_norm"], q)
                     k = self._block_layers["k_norm"](block["k_norm"], k)
                 q = q.reshape(B, S, c.num_heads, c.head_dim)
                 k = k.reshape(B, S, c.kv_heads, c.head_dim)
+                if per_head:
+                    q = self._block_layers["q_norm"](block["q_norm"], q)
+                    k = self._block_layers["k_norm"](block["k_norm"], k)
                 v = self._project(block, "v_proj", h).reshape(B, S, c.kv_heads, c.head_dim)
-                if c.position == "rope":
+                if rope:
                     q = self._rotate(q, positions)
                     k = self._rotate(k, positions)
-            with jax.named_scope("core"):
+            with jax.named_scope("core_window" if isinstance(window, int) else "core"):
                 out = self._attn_core(q, k, v, attn_mask, window)
+            out = out.reshape(B, S, c.num_heads * c.head_dim)
+            if c.attn_gate:
+                out = self._gated(block, h, out)
             with jax.named_scope("out"):
-                out = out.reshape(B, S, c.num_heads * c.head_dim)
                 return self._project(block, "o_proj", out)
+
+    def _gated(self, block: Params, h: jax.Array, out: jax.Array) -> jax.Array:
+        """``out`` under the attention gate: times the sigmoid (float32) of
+        the sub-block's input through ``attn_gate``, element-wise."""
+        with jax.named_scope("gate"):
+            gate = checkpoint_name(self._block_layers["attn_gate"](
+                block["attn_gate"], h), "attn_gate")
+            return out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
 
     def _latent_attn(self, block: Params, h: jax.Array,
                      positions: jax.Array) -> jax.Array:
@@ -688,10 +772,7 @@ class TransformerLM:
                 out = self._attn_core(q, k, v, None, None, scale=scale)
             out = out.reshape(B, S, nh * vd)
             if c.attn_gate:
-                with jax.named_scope("gate"):
-                    gate = checkpoint_name(self._block_layers["attn_gate"](
-                        block["attn_gate"], h), "attn_gate")
-                    out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+                out = self._gated(block, h, out)
             with jax.named_scope("out"):
                 return self._project(block, "o_proj", out)
 
@@ -751,12 +832,16 @@ class TransformerLM:
         return out, aux, rows
 
     @scoped("block")   # norms and residual adds are "block" and nothing finer
-    def _block_fn(self, attn_mask, carry, block_and_keep):
+    def _block_fn(self, attn_mask, carry, block_and_keep, kind=None):
+        """One block. ``kind``: the layer's static (window, rope) of
+        ``_kinds`` (None: global attention, ``position`` says whether rope).
+        A third element of ``block_and_keep`` is a TRACED window instead
+        (the ZeRO-3 pipelined scan and ``remat_policy='alternating'``)."""
+        window, rope = kind or (None, None)
         if len(block_and_keep) == 3:
             block, keep, window = block_and_keep
-        else:  # pipeline stage path: global attention only
+        else:
             block, keep = block_and_keep
-            window = None
         x, positions, aux_acc = carry
         c = self.config
         # keep: per-layer stochastic-depth gate (progressive layer drop,
@@ -787,19 +872,27 @@ class TransformerLM:
             # falcon/phi residual form: both branches read the block INPUT —
             # through one shared norm (phi/falcon-7b) or per-branch norms
             # (falcon-40b new decoder)
-            attn_out = self._attn(block, h1, positions, attn_mask, window)
+            attn_out = self._attn(block, h1, positions, attn_mask, window, rope)
             hm = (self._block_layers["ln_2"](block["ln_2"], x)
                   if c.parallel_norms else h1)
             mlp_out, aux, rows = self._mlp(block, hm)
             x = _c(x + keep * (attn_out + mlp_out), ACT_SPEC)
         else:
-            x = x + keep * self._attn(block, h1, positions, attn_mask, window)
+            # 'sandwich': a branch's output is normed before it is added
+            post = ((lambda name, y: y) if c.norm_style != "sandwich"
+                    else functools.partial(self._norm_post, block))
+            x = x + keep * post("post_ln_1", self._attn(
+                block, h1, positions, attn_mask, window, rope))
             h2 = self._block_layers["ln_2"](block["ln_2"], x)
             mlp_out, aux, rows = self._mlp(block, h2)
-            x = _c(x + keep * mlp_out, ACT_SPEC)
+            x = _c(x + keep * post("post_ln_2", mlp_out), ACT_SPEC)
         # the scan stacks the no-drop path's rows per expert over the
         # layers ([layers, experts]); every other model's ys stay None
         return (x, positions, aux_acc + keep * aux), rows
+
+    @scoped("norm_post")
+    def _norm_post(self, block: Params, name: str, y: jax.Array) -> jax.Array:
+        return self._block_layers[name](block[name], y)
 
     @scoped("embed")
     def embed(self, params: Params, input_ids: jax.Array,
@@ -813,6 +906,8 @@ class TransformerLM:
         c = self.config
         positions = jnp.arange(input_ids.shape[1])[None, :]
         x = self._wte(params["wte"], input_ids)
+        if c.embedding_scale is not None:
+            x = x * jnp.asarray(c.embedding_scale, x.dtype)
         if self._wpe is not None:
             if c.pad_based_positions:
                 pad = c.pad_token_id  # __init__ rejects None
@@ -863,12 +958,15 @@ class TransformerLM:
         module's partitions at a time, partitioned_param_coordinator.py:280).
         Returns (x', moe_aux)."""
         c = self.config
-        if c.farskip or c.first_dense_layers or c.mtp_layers:
+        if (c.farskip or c.first_dense_layers or c.mtp_layers or self._mixed_rope
+                or c.document_separator is not None):
             raise NotImplementedError(
                 "one block at a time (parameter streaming, the ZeRO-3 "
                 "pipelined scan) is written for one stream through blocks "
-                "of one kind: farskip, first_dense_layers and mtp_layers "
-                "take the whole-model scan of TransformerLM.apply")
+                "of one kind: farskip, first_dense_layers, mtp_layers, layers "
+                "with and without a rotary position (rope_layers='windowed') "
+                "and packed documents (document_separator) take the "
+                "whole-model scan of TransformerLM.apply")
         carry = (x, positions, self._aux_zero())
         keep = jnp.asarray(keep, self.config.dtype)
         packed = (block, keep) if window is None else (block, keep, window)
@@ -1167,6 +1265,11 @@ class TransformerLM:
         c = self.config
         x, positions = self.embed(params, input_ids, token_type_ids)
 
+        if c.document_separator is not None:
+            if attention_mask is not None:
+                raise ValueError("packed documents (document_separator) take "
+                                 "no attention_mask: a row is full")
+            attention_mask = self._documents(input_ids)
         block_fn = functools.partial(self._block_fn, attention_mask)
         if layer_mask is None:
             keep = jnp.ones((c.num_layers,), c.dtype)
@@ -1174,24 +1277,23 @@ class TransformerLM:
             keep = layer_mask.astype(c.dtype)
         dense = c.first_dense_layers
         xs = (params["blocks"], keep[dense:])
-        if self._windows is not None:
-            xs = xs + (jnp.asarray(self._windows, jnp.int32),)
         # FarSkip carries two streams: (r_(i-1), r_(i-2)), r_(-1) = r_0
         init = ((x, x) if c.farskip else x, positions, self._aux_zero())
         blocks_in_all = c.num_layers + (c.mtp_layers if with_mtp else 0)
 
-        def one_block():
+        def one_block(kind=None):
             """``block_fn`` for a block outside the scan, under the scan's
             remat policy: its bytes reckoned as one of all the blocks, in
             the same room, its decision written nowhere (the scan's is)."""
+            fn = functools.partial(block_fn, kind=kind)
             if not c.remat:
-                return block_fn
+                return fn
             room = None if remat_budget is None else Budget(remat_budget.room_bytes)
-            return checkpointed(block_fn, c.remat_policy, blocks_in_all, room)
+            return checkpointed(fn, c.remat_policy, blocks_in_all, room)
 
         for i in range(dense):       # the leading dense layers, one by one
             layer = jax.tree.map(lambda a: a[i], params["dense_blocks"])
-            init, _ = one_block()(init, (layer, keep[i]))
+            init, _ = one_block(self._kinds[i])(init, (layer, keep[i]))
         rows = None
         if c.remat and c.remat_policy == "alternating":
             # HALF-remat: scan over layer pairs, checkpointing only the
@@ -1199,7 +1301,10 @@ class TransformerLM:
             # layer (half the recompute FLOPs of full remat) while the
             # scan stores residuals for only half the layers (half the
             # activation memory of no remat). The sweet spot when full
-            # activations don't fit but full recompute over-pays.
+            # activations don't fit but full recompute over-pays. The pairs
+            # are one program, so a layer's window rides the scan TRACED.
+            if self._windows is not None:
+                xs = xs + (jnp.asarray(self._windows, jnp.int32),)
             ck_fn = jax.checkpoint(block_fn)
 
             def pair_fn(carry, xs_pair):
@@ -1216,12 +1321,9 @@ class TransformerLM:
                 (x, _, aux), _ = ck_fn(
                     (x, positions, aux),
                     jax.tree.map(lambda a: a[-1], xs))
-        elif c.remat:
-            ck_fn = checkpointed(block_fn, c.remat_policy, blocks_in_all,
-                                 remat_budget)
-            (x, _, aux), rows = jax.lax.scan(ck_fn, init, xs)
         else:
-            (x, _, aux), rows = jax.lax.scan(block_fn, init, xs)
+            (x, _, aux), rows = self._scan_by_kind(
+                block_fn, init, xs, blocks_in_all, remat_budget, one_block)
         if c.farskip:
             x = x[0]
         mtp_x = None
@@ -1248,6 +1350,64 @@ class TransformerLM:
             if rows is not None:
                 rows = jnp.concatenate([rows, mtp_rows[None]], axis=0)
         return x, aux, self._stats_of(rows), mtp_x
+
+    def _documents(self, input_ids: jax.Array) -> jax.Array:
+        """Each position's document in a packed row, [B, S] int32: the
+        separators BEFORE it (a separator ends its own document)."""
+        ends = (input_ids == self.config.document_separator).astype(jnp.int32)
+        return jnp.cumsum(ends, axis=1) - ends
+
+    @functools.cached_property
+    def scan_plan(self) -> Tuple[Tuple[Any, ...], int, Tuple[Any, ...]]:
+        """How the layers after the leading dense ones run, from their static
+        kinds ``(window, rope)``: ``(unit, repeats, tail)``. ``unit`` is the
+        kinds' shortest period, which ``lax.scan`` runs ``repeats`` times
+        (each layer of the unit traced once, as its own kind); ``tail`` is
+        what is left of a last, partial period, run one block at a time
+        after the scan. One kind throughout: a unit of one. afmoe's 30
+        expert layers (s F s s s F ...): the unit (s, F, s, s) seven times
+        and a tail of (s, F)."""
+        kinds = self._kinds[self.config.first_dense_layers:]
+        n = len(kinds)
+        period = next(p for p in range(1, n + 1)
+                      if all(kinds[i] == kinds[i - p] for i in range(p, n)))
+        return kinds[:period], n // period, kinds[n - n % period:]
+
+    def _scan_by_kind(self, block_fn, init, xs, blocks_in_all, remat_budget,
+                      one_block):
+        """The layer scan over ``xs`` (stacked blocks and their keep gates)
+        as ``scan_plan`` lays it out -> (carry, the no-drop path's rows
+        per expert ``[layers, experts]`` or None)."""
+        c = self.config
+        unit, repeats, tail = self.scan_plan
+
+        def of_kind(kind):
+            fn = functools.partial(block_fn, kind=kind)
+            if not c.remat:
+                return fn
+            return checkpointed(fn, c.remat_policy, blocks_in_all, remat_budget)
+
+        fns = [of_kind(kind) for kind in unit]
+        p, n = len(unit), len(unit) * repeats
+        if p == 1:
+            carry, rows = jax.lax.scan(fns[0], init, xs)
+        else:
+            def unit_fn(carry, xs_unit):
+                out = []
+                for i, fn in enumerate(fns):
+                    carry, r = fn(carry, jax.tree.map(lambda a: a[i], xs_unit))
+                    out.append(r)
+                return carry, None if out[0] is None else jnp.stack(out)
+
+            carry, rows = jax.lax.scan(unit_fn, init, jax.tree.map(
+                lambda a: a[:n].reshape((repeats, p) + a.shape[1:]), xs))
+            if rows is not None:
+                rows = rows.reshape((n,) + rows.shape[2:])
+        for i, kind in enumerate(tail):
+            carry, r = one_block(kind)(carry, jax.tree.map(lambda a: a[n + i], xs))
+            if rows is not None:
+                rows = jnp.concatenate([rows, r[None]], axis=0)
+        return carry, rows
 
     def _stats_of(self, rows: Optional[jax.Array]) -> Dict[str, jax.Array]:
         """The step's device-side statistics from the no-drop path's

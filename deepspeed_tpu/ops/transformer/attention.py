@@ -256,19 +256,24 @@ def flash_attention(q: jax.Array,
     every shape off the TPU, keep the one-shot XLA path. `choose_route`
     is the decision table (docs/LONG_CONTEXT.md).
     ``alibi_slopes`` [num_heads] adds the ALiBi positional bias (bloom);
-    ``window`` (0 = global) is the causal sliding window.
+    ``window`` (0 = global) is the causal sliding window: a Python int is
+    static, and the kernel's grids are then cut to it (a sliding layer
+    fetches and multiplies a window's worth of keys); a traced scalar masks
+    and skips inside whole-sequence grids.
     """
     mode = attn_mode()
     backend = jax.default_backend()
     route = choose_route(q.shape, k.shape, backend, mode)
     if route == "kernel":
         from . import pallas_flash as _pf
+        static = _pf.static_window(window, q.shape[1], k.shape[1])
         tiles = _pf.choose_tiles(q.shape[1], k.shape[1], q.shape[-1],
                                  q.dtype.itemsize, causal=causal,
-                                 compiled=backend != "cpu")
+                                 compiled=backend != "cpu", window=static)
         _log_path_once(
             "pallas_flash_inrepo, tiles (block_q x block_k) forward "
-            "%dx%d backward %dx%d" % (tiles.fwd + tiles.bwd))
+            "%dx%d backward %dx%d%s" % (tiles.fwd + tiles.bwd + (
+                "" if static is None else f", grids cut to a window of {static}",)))
         return _pf.flash_attention_kernel(
             q, k, v, causal=causal, scale=scale,
             segment_ids=segment_ids, alibi_slopes=alibi_slopes,

@@ -26,6 +26,17 @@ in the future). The with-LSE entry point returns the per-row logsumexp so
 ring attention can accumulate partial softmax state across ppermute hops
 exactly (see ``sequence/ring_attention.py``).
 
+A window that is a Python int when the launch is built, on the training
+call (no ``q_offset``, as many keys as queries), is STATIC
+(``FlashConfig.window``): both grids then hold only the blocks a window
+can reach (a q-block's k-steps run from the block of its first row's
+oldest visible key to the block of its diagonal, a k-block's q-steps the
+other way round), so a sliding layer fetches and multiplies a window's
+worth of keys however long the sequence is; the tiles are chosen knowing
+the window and the launches are named ``flash_fwd_window`` /
+``flash_bwd_window``. A traced window keeps the whole-sequence grids and
+skips a tile's work alone.
+
 Runs in interpret mode off-TPU (``pl.pallas_call(interpret=True)``) so the
 CPU tier-1 tests validate numerics of the same program the chip runs.
 
@@ -47,6 +58,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.ad_checkpoint import checkpoint_name
@@ -88,6 +100,9 @@ class FlashConfig:
     kv_heads: int
     tiles: FlashTiles
     interpret: bool
+    # the window where it is known when the launch is built (None: the
+    # window, if any, is the traced scalar in SMEM): the grids are cut to it
+    window: Optional[int] = None
 
 
 def _lanes(x: jax.Array, n: int) -> jax.Array:
@@ -99,6 +114,51 @@ def _lanes(x: jax.Array, n: int) -> jax.Array:
     if n % NUM_LANES:
         raise NotImplementedError(f"width {n} not a multiple of {NUM_LANES}")
     return jnp.concatenate([x] * (n // NUM_LANES), axis=1)
+
+
+# The blocks a STATIC window reaches (offset 0, as many keys as queries).
+# Each takes Python ints (grid sizes) or traced ints (index maps, kernels).
+
+def _bound(pick, a, b):
+    """``max`` / ``min`` of Python ints as a Python int, else traced."""
+    if isinstance(a, int) and isinstance(b, int):
+        return pick(a, b)
+    return (jnp.maximum if pick is max else jnp.minimum)(a, b)
+
+
+def _first_k_block(tile: Tile, i, window: int):
+    """The lowest k-block that holds a key q-block ``i`` sees."""
+    bq, bk = tile
+    return _bound(max, i * bq - (window - 1), 0) // bk
+
+
+def _last_k_block(tile: Tile, i):
+    """The k-block of q-block ``i``'s last row's own key (the diagonal)."""
+    bq, bk = tile
+    return ((i + 1) * bq - 1) // bk
+
+
+def _first_q_block(tile: Tile, j):
+    """The lowest q-block that holds a row which sees k-block ``j``."""
+    bq, bk = tile
+    return (j * bk) // bq
+
+
+def _last_q_block(tile: Tile, j, window: int, nq: int):
+    """The highest q-block with a row inside the window of k-block ``j``'s
+    last key."""
+    bq, bk = tile
+    return _bound(min, ((j + 1) * bk + window - 2) // bq, nq - 1)
+
+
+def window_steps(tile: Tile, window: int, nq: int, nk: int) -> Tuple[int, int]:
+    """(k-steps a q-block, q-steps a k-block) of the grids under a static
+    ``window``: the most blocks any one block reaches."""
+    k_steps = max(_last_k_block(tile, i) - _first_k_block(tile, i, window)
+                  for i in range(nq)) + 1
+    q_steps = max(_last_q_block(tile, j, window, nq) - _first_q_block(tile, j)
+                  for j in range(nk)) + 1
+    return k_steps, q_steps
 
 
 def _should_run(cfg: FlashConfig, tile: Tile, i, j, info_ref):
@@ -132,13 +192,18 @@ def _fully_visible(cfg: FlashConfig, tile: Tile, i, j, info_ref):
     return full
 
 
-def _for_visible_tile(cfg: FlashConfig, tile: Tile, i, j, info_ref, body):
+def _for_visible_tile(cfg: FlashConfig, tile: Tile, i, j, info_ref, body,
+                      also=None):
     """Run ``body(positional)`` for a tile with any unmasked key; tiles
-    wholly below the diagonal run it without the positional mask."""
+    wholly below the diagonal run it without the positional mask.
+    ``also``: a further condition of the step (a static window's grid: the
+    block the step names exists)."""
     if not cfg.causal:
         body(False)
         return
     run = _should_run(cfg, tile, i, j, info_ref)
+    if also is not None:
+        run = run & also
     full = _fully_visible(cfg, tile, i, j, info_ref)
     pl.when(run & full)(lambda: body(False))
     pl.when(run & jnp.logical_not(full))(lambda: body(True))
@@ -208,10 +273,14 @@ def _fwd_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
                 o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                 cfg: FlashConfig, G: int, nk: int, head_dim: int):
     b, g = pl.program_id(0), pl.program_id(1)
-    i, j = pl.program_id(2), pl.program_id(3)
+    i, step = pl.program_id(2), pl.program_id(3)
     tile = cfg.tiles.fwd
+    # the k-block of this step: under a static window the steps start at
+    # the first block the q-block reaches (a step past its diagonal names a
+    # block the causal mask hides whole, and is skipped as one)
+    j = step if cfg.window is None else _first_k_block(tile, i, cfg.window) + step
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, MASK_VALUE, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
@@ -240,7 +309,7 @@ def _fwd_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
 
     _for_visible_tile(cfg, tile, i, j, info, _compute)
 
-    @pl.when(j == nk - 1)
+    @pl.when(step == nk - 1)
     def _store():
         l = l_scr[...]
         m_safe = jnp.maximum(m_scr[...], HALF_MASK)
@@ -260,13 +329,23 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
     Sk = k.shape[1]
     tile = bq, bk = cfg.tiles.fwd
     nq, nk = Sq // bq, Sk // bk
+    if cfg.window is not None:
+        nk = window_steps(tile, cfg.window, nq, nk)[0]
     grid = (BK, G, nq, nk)
     kvH = cfg.kv_heads
 
-    def kv_idx(b, g, i, j, info, slopes):
+    def k_blk(i, j, info):
+        """The k-block step ``j`` of q-block ``i`` fetches; a step that
+        computes nothing re-names a block already resident: no DMA."""
+        if cfg.window is not None:
+            return jnp.minimum(_first_k_block(tile, i, cfg.window) + j,
+                               _last_k_block(tile, i))
         if cfg.causal:
             j = lax.select(_should_run(cfg, tile, i, j, info), j, 0)
-        return (b, j, 0)
+        return j
+
+    def kv_idx(b, g, i, j, info, slopes):
+        return (b, k_blk(i, j, info), 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, g, i, j, *_: (b, g, i, 0)),
@@ -278,9 +357,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
             (1, bq, NUM_LANES), lambda b, g, i, j, *_: (b // kvH, i, 0)))
 
         def kseg_idx(b, g, i, j, info, slopes):
-            if cfg.causal:
-                j = lax.select(_should_run(cfg, tile, i, j, info), j, 0)
-            return (b // kvH, 0, j)
+            return (b // kvH, 0, k_blk(i, j, info))
         in_specs.append(pl.BlockSpec((1, NUM_SUBLANES, bk), kseg_idx))
     else:
         in_specs += [None, None]
@@ -310,7 +387,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
         compiler_params=_compiler_params(
             cfg, ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
-        name="flash_fwd",
+        name="flash_fwd" if cfg.window is None else "flash_fwd_window",
     )(info, slopes, q, k, v, qseg_c, kseg_r)
 
 
@@ -321,22 +398,36 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
 
 def _bwd_kernel(info, slopes, q_ref, k_ref, v_ref, kseg_ref, qseg_ref,
                 do_ref, lse_ref, di_ref, dq_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, cfg: FlashConfig, G: int, nq: int):
+                dk_scr, dv_scr, *, cfg: FlashConfig, G: int, nq: int,
+                steps: int):
     """dq, dk and dv of one (k-block, q-block) tile from ONE recomputed
     P^T = exp(K Q^T - lse): five matmuls, none with a transposed left
     operand except dq's (one XLU transpose of dS^T). dk/dv accumulate in
     scratch over the groups and q-blocks of their k-block; dq leaves per
-    k-block and the caller sums the k-blocks."""
+    k-block and the caller sums the k-blocks. ``steps``: the q-steps of
+    the grid (``nq``, or under a static window the q-blocks one k-block
+    reaches)."""
     b = pl.program_id(0)
-    j, g, i = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    j, g, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     tile = cfg.tiles.bwd
+    # the q-block of this step, and whether there is one: a static
+    # window's steps start at the k-block's own diagonal
+    i, exists = step, None
+    if cfg.window is not None:
+        i = _first_q_block(tile, j) + step
+        exists = i <= _last_q_block(tile, j, cfg.window, nq)
 
-    @pl.when((g == 0) & (i == 0))
+    @pl.when((g == 0) & (step == 0))
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    if cfg.causal:
+    if cfg.causal and cfg.window is None:
+        # every (k-block, q-block) pair has a dq slot of its own, which the
+        # caller sums: a skipped pair's is zero. (Under a static window a
+        # step without a q-block writes nothing: the block it names holds
+        # the step before's result, and the caller masks the slots no pair
+        # wrote.)
         @pl.when(jnp.logical_not(_should_run(cfg, tile, i, j, info)))
         def _skipped():
             dq_ref[0, 0, 0] = jnp.zeros(dq_ref.shape[3:], dq_ref.dtype)
@@ -368,12 +459,33 @@ def _bwd_kernel(info, slopes, q_ref, k_ref, v_ref, kseg_ref, qseg_ref,
                                   preferred_element_type=jnp.float32
                                   ).astype(dq_ref.dtype)
 
-    _for_visible_tile(cfg, tile, i, j, info, _compute)
+    _for_visible_tile(cfg, tile, i, j, info, _compute, also=exists)
 
-    @pl.when((g == G - 1) & (i == nq - 1))
+    @pl.when((g == G - 1) & (step == steps - 1))
     def _store():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+#: The most bytes the backward's per-k-block dq partials may take in one
+#: launch; a call that would write more runs a few of its folded batch rows
+#: at a time. 32 heads at 16,384 under 1024-wide k-blocks are 4.3 GB whole.
+DQ_PARTIAL_BYTES = 2 ** 30
+
+
+def _rows_a_launch(cfg: FlashConfig, rows: int, bytes_a_row: int) -> int:
+    """How many of the ``rows`` folded (batch x key head) rows one backward
+    launch takes: all of them where their dq partials fit
+    ``DQ_PARTIAL_BYTES``, else the most that do and that either divide the
+    key heads or hold whole batch rows (a launch finds its segment ids by
+    ``row // kv_heads``). ALiBi's slopes go by the global row: never split."""
+    if cfg.use_alibi or rows * bytes_a_row <= DQ_PARTIAL_BYTES:
+        return rows
+    kvH = cfg.kv_heads
+    fits = [r for r in range(1, rows) if rows % r == 0
+            and (r % kvH == 0 or kvH % r == 0)
+            and r * bytes_a_row <= DQ_PARTIAL_BYTES]
+    return max(fits, default=1)
 
 
 def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
@@ -383,6 +495,12 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
     tile = bq, bk = cfg.tiles.bwd
     nq, nk = Sq // bq, Sk // bk
     kvH = cfg.kv_heads
+    W = cfg.window
+    # the grid's q-steps a k-block, and the dq partials a q-block gets: one
+    # from every k-block, or under a static window one from each it reaches
+    steps, slots = nq, nk
+    if W is not None:
+        slots, steps = window_steps(tile, W, nq, nk)
 
     # di = rowsum(dO * O) (the softmax-jacobian diagonal term); a cotangent
     # on the LSE output folds in here: dL/ds = P*(dP - di) + dlse*P
@@ -392,7 +510,11 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
         di = di - dlse.astype(jnp.float32)
 
     def q_blk(i, j, info):
-        # a skipped tile re-names the block already resident: no DMA
+        # a step that computes nothing re-names a block already resident:
+        # no DMA
+        if W is not None:
+            return jnp.minimum(_first_q_block(tile, j) + i,
+                               _last_q_block(tile, j, W, nq))
         if cfg.causal:
             i = lax.select(_should_run(cfg, tile, i, j, info), i, nq - 1)
         return i
@@ -406,6 +528,12 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
     def kv_idx(b, j, g, i, *_):
         return (b, j, 0)
 
+    def dq_idx(b, j, g, i, info, slopes):
+        if W is None:
+            return (j, b, g, i, 0)
+        i = q_blk(i, j, info)
+        return (j - _first_k_block(tile, i, W), b, g, i, 0)
+
     seg_specs = [None, None]
     if cfg.use_seg:
         seg_specs = [
@@ -416,40 +544,70 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
                 lambda b, j, g, i, info, slopes: (
                     b // kvH, 0, q_blk(i, j, info))),
         ]
-    # one k-block holds every key: its dq IS the answer, in q's dtype
-    dq_dtype = q.dtype if nk == 1 else jnp.float32
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, cfg=cfg, G=G, nq=nq),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(BK, nk, G, nq),
-            in_specs=[
-                pl.BlockSpec((1, 1, bq, D), q_idx),
-                pl.BlockSpec((1, bk, D), kv_idx),
-                pl.BlockSpec((1, bk, D), kv_idx),
-                *seg_specs,
-                pl.BlockSpec((1, 1, bq, D), q_idx),
-                pl.BlockSpec((1, 1, 1, bq), q_row_idx),
-                pl.BlockSpec((1, 1, 1, bq), q_row_idx),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, 1, bq, D),
-                             lambda b, j, g, i, *_: (j, b, g, i, 0)),
-                pl.BlockSpec((1, bk, D), kv_idx),
-                pl.BlockSpec((1, bk, D), kv_idx),
-            ],
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((nk, BK, G, Sq, D), dq_dtype),
-                   jax.ShapeDtypeStruct((BK, Sk, D), k.dtype),
-                   jax.ShapeDtypeStruct((BK, Sk, D), v.dtype)],
-        compiler_params=_compiler_params(
-            cfg, ("parallel", "parallel", "arbitrary", "arbitrary")),
-        interpret=cfg.interpret,
-        name="flash_bwd",
-    )(info, slopes, q, k, v, kseg_c, qseg_r, do, lse, di)
-    dq = dq[0] if nk == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
-    return dq, dk, dv
+    # one partial a q-block: its dq IS the answer, in q's dtype
+    dq_dtype = q.dtype if slots == 1 else jnp.float32
+    written = None
+    if W is not None and slots > 1:
+        # slot s of q-block i holds k-block first(i) + s, where there is one
+        reach = np.repeat([_last_k_block(tile, i) - _first_k_block(tile, i, W)
+                           for i in range(nq)], bq)
+        written = jnp.asarray(np.arange(slots)[:, None] <= reach[None, :]
+                              )[:, None, None, :, None]
+
+    def launch(q, k, v, kseg_c, qseg_r, do, lse, di):
+        rows = q.shape[0]
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_kernel, cfg=cfg, G=G, nq=nq, steps=steps),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(rows, nk, G, steps),
+                in_specs=[
+                    pl.BlockSpec((1, 1, bq, D), q_idx),
+                    pl.BlockSpec((1, bk, D), kv_idx),
+                    pl.BlockSpec((1, bk, D), kv_idx),
+                    *seg_specs,
+                    pl.BlockSpec((1, 1, bq, D), q_idx),
+                    pl.BlockSpec((1, 1, 1, bq), q_row_idx),
+                    pl.BlockSpec((1, 1, 1, bq), q_row_idx),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, 1, 1, bq, D), dq_idx),
+                    pl.BlockSpec((1, bk, D), kv_idx),
+                    pl.BlockSpec((1, bk, D), kv_idx),
+                ],
+                scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                                pltpu.VMEM((bk, D), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct((slots, rows, G, Sq, D), dq_dtype),
+                       jax.ShapeDtypeStruct((rows, Sk, D), k.dtype),
+                       jax.ShapeDtypeStruct((rows, Sk, D), v.dtype)],
+            compiler_params=_compiler_params(
+                cfg, ("parallel", "parallel", "arbitrary", "arbitrary")),
+            interpret=cfg.interpret,
+            name="flash_bwd" if W is None else "flash_bwd_window",
+        )(info, slopes, q, k, v, kseg_c, qseg_r, do, lse, di)
+        if slots == 1:
+            return dq[0], dk, dv
+        if written is not None:
+            # a slot no (k-block, q-block) pair wrote holds whatever was there
+            dq = jnp.where(written, dq, 0.0)
+        return jnp.sum(dq, axis=0).astype(q.dtype), dk, dv
+
+    each = _rows_a_launch(cfg, BK, slots * G * Sq * D * jnp.dtype(dq_dtype).itemsize)
+    if each == BK:
+        return launch(q, k, v, kseg_c, qseg_r, do, lse, di)
+    n = BK // each
+    split = lambda a: a.reshape((n, each) + a.shape[1:])
+    if not cfg.use_seg:
+        seg = lambda ids: None
+    elif each % kvH == 0:        # whole batch rows a launch
+        seg = lambda ids: ids.reshape((n, each // kvH) + ids.shape[1:])
+    else:                        # every launch inside one batch row
+        seg = lambda ids: jnp.repeat(ids, kvH // each, axis=0)[:, None]
+    dq, dk, dv = lax.map(lambda xs: launch(*xs), (
+        split(q), split(k), split(v), seg(kseg_c), seg(qseg_r), split(do),
+        split(lse), split(di)))
+    join = lambda a: a.reshape((BK,) + a.shape[2:])
+    return join(dq), join(dk), join(dv)
 
 
 # ---------------------------------------------------------------------------
@@ -507,6 +665,13 @@ def _auto_interpret() -> bool:
 # largest 128-multiple under it that does.
 FWD_CAUSAL_TILE_TARGET: Tile = (512, 512)
 TILE_TARGET: Tile = (1024, 1024)
+# Under a static window the same targets won (v5e, PR 34; docs/KERNELS.md has
+# the sweep at 16,384 x 32q/4kv x 128 under a window of 2048: forward 512 x
+# 512 6.89 ms against 7.6-9.6, backward 1024 x 1024 20.93 against 21.07 at
+# 512 x 512 and 23-28 at wider or narrower ones). A window NARROWER than a
+# target caps it, at 512 or more (reckoned, not measured: a tile wider than
+# the window's band is mostly masked work, and under 512 the steps bound).
+WINDOW_TILE_FLOOR = 512
 # Scoped VMEM. The budget is the compiler's own default limit, so the
 # chosen tiles need no ``vmem_limit_bytes``; explicit larger tiles get the
 # limit their estimate asks for, up to the cap (v5e/v6e hold 128 MiB).
@@ -553,13 +718,15 @@ def _fit(length: int, target: int, compiled: bool) -> Optional[int]:
 
 def choose_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
                  causal: bool = True, block_q: Optional[int] = None,
-                 block_k: Optional[int] = None,
-                 compiled: bool = True) -> Optional[FlashTiles]:
+                 block_k: Optional[int] = None, compiled: bool = True,
+                 window: Optional[int] = None) -> Optional[FlashTiles]:
     """Tiles of the forward and backward kernels for one call, from what
-    the call can observe: the two lengths, whether it is causal, and (for
-    the VMEM budget) the head dim and the operand size. Head dim 64 or
-    128, MHA or grouped heads and segment ids did not move the winner in
-    the sweep; a sliding window is a traced value the choice cannot see.
+    the call can observe: the two lengths, whether it is causal, a window
+    that is static (``FlashConfig.window``; a traced one the choice cannot
+    see), and (for the VMEM budget) the head dim and the operand size. Head
+    dim 64 or 128, MHA or grouped heads and segment ids did not move the
+    winner in the sweep, and neither did a static window of twice the widest
+    tile; one narrower than a target caps it (``WINDOW_TILE_FLOOR``).
     Explicit ``block_q``/``block_k`` win and apply to both kernels,
     clamped to the lengths as the old 128-default was. None
     where no legal tile exists: a length that no 128-multiple divides, or
@@ -584,8 +751,14 @@ def choose_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
                 break
         return bq, bk
 
-    fwd = pick(FWD_CAUSAL_TILE_TARGET if causal else TILE_TARGET, False)
-    bwd = pick(TILE_TARGET, True)
+    def under_window(target: Tile) -> Tile:
+        if window is None:
+            return target
+        cap = max(WINDOW_TILE_FLOOR, -(-window // NUM_LANES) * NUM_LANES)
+        return min(target[0], cap), min(target[1], cap)
+
+    fwd = pick(under_window(FWD_CAUSAL_TILE_TARGET if causal else TILE_TARGET), False)
+    bwd = pick(under_window(TILE_TARGET), True)
     if fwd is None or bwd is None:
         return None
     need = max(tile_vmem_bytes(fwd, head_dim, itemsize, backward=False),
@@ -609,6 +782,15 @@ def supports(q_shape, k_shape, block_q: Optional[int] = None,
                         compiled=compiled) is not None
 
 
+def static_window(window, sq: int, sk: int, q_offset=None) -> Optional[int]:
+    """The window the grids are cut to: a Python int that can bind, on the
+    training call (no ``q_offset``, as many keys as queries); else None."""
+    if (isinstance(window, (int, np.integer)) and q_offset is None and sq == sk
+            and 0 < window < sk):
+        return int(window)
+    return None
+
+
 def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
              alibi_slopes, window, q_offset, block_q, block_k, interpret):
     B, Sq, H, D = q.shape
@@ -618,10 +800,14 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
     G = H // kvH
     if window is not None and not causal:
         raise ValueError("sliding window is causal-only")
+    if isinstance(window, (int, np.integer)) and (
+            window <= 0 or (q_offset is None and window >= Sk)):
+        window = None                # global, or a window that never binds
+    cut = static_window(window, Sq, Sk, q_offset)
     interp = _auto_interpret() if interpret is None else interpret
     tiles = choose_tiles(Sq, Sk, D, q.dtype.itemsize, causal=bool(causal),
                          block_q=block_q, block_k=block_k,
-                         compiled=not interp)
+                         compiled=not interp, window=cut)
     if tiles is None:
         raise ValueError(f"seq lengths ({Sq}, {Sk}) have no legal tiles "
                          f"(block_q={block_q}, block_k={block_k})")
@@ -641,7 +827,7 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         use_seg=segment_ids is not None,
         use_alibi=alibi_slopes is not None,
         use_window=window is not None,
-        kv_heads=kvH, tiles=tiles, interpret=bool(interp))
+        kv_heads=kvH, tiles=tiles, interpret=bool(interp), window=cut)
 
     segs = (None, None, None, None)
     if segment_ids is not None:

@@ -544,6 +544,14 @@ class DeepSpeedEngine:
                            "grouped_matmul_route": None,
                            "products_kernel": None, "products_xla": None}
         self._step_stats = None
+        # Attention counters, plain values kept with telemetry off: how many
+        # of the model's layers attend under a window (static kinds, scope
+        # ``attn/core_window``) and how many over the whole row
+        # (``attn/core``), the windows, the key heads, and, once a step is
+        # traced, the route ``attention.choose_route`` gives each kind's
+        # call ("kernel" / "xla" / "xla_chunked"; None until then, and for
+        # a kind the model has no layer of).
+        self.attn_totals = self._attention_of_model()
         # Optimizer-kernel counters, kept with telemetry off and filled from
         # the static bucket plan when a step that updates is traced: the
         # path ("pallas" / "xla"; None until then), and on the kernel path
@@ -1118,6 +1126,7 @@ class DeepSpeedEngine:
         device-side step statistics where the fused step returns them
         (``_step_has_stats``); else the empty tuple."""
         traced = self.remat_totals["policy"] is not None
+        self._count_attention(batch)
         if self._step_has_stats:
             loss, stats = self.model.loss_and_stats(params, batch,
                                                     **self._remat_kw())
@@ -2237,6 +2246,32 @@ class DeepSpeedEngine:
             return {}
         lo, hi = moe.experts_held or (0, moe.num_experts)
         return {"experts_published": moe.num_experts, "experts_held": hi - lo}
+
+    def _attention_of_model(self) -> Dict[str, Any]:
+        kinds = getattr(self.model, "layer_kinds", None)
+        if kinds is None:
+            return {}
+        windows = sorted({w for w, _ in kinds if w})
+        return {"layers_window": sum(1 for w, _ in kinds if w),
+                "layers_full": sum(1 for w, _ in kinds if not w),
+                "window": windows[0] if len(windows) == 1 else (windows or None),
+                "kv_heads": self.model.config.kv_heads,
+                "route": {"window": None, "full": None}}
+
+    def _count_attention(self, batch) -> None:
+        """``attn_totals['route']``: host arithmetic from static shapes while
+        the step is traced."""
+        if not self.attn_totals or "input_ids" not in batch:
+            return
+        from ..ops.transformer import attention
+        cfg = self.model.config
+        b, s = batch["input_ids"].shape[:2]
+        route = attention.choose_route(
+            (b, s, cfg.num_heads, cfg.head_dim), (b, s, cfg.kv_heads, cfg.head_dim),
+            jax.default_backend(), attention.attn_mode())
+        self.attn_totals["route"] = {
+            kind: route if self.attn_totals[f"layers_{kind}"] else None
+            for kind in ("window", "full")}
 
     def _count_grouped_products(self, batch, expert_layers: int) -> None:
         """``moe_totals``' route and products a step, by kind, of the no-drop

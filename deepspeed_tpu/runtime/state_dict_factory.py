@@ -1102,6 +1102,8 @@ def _register_builtins() -> None:
     from ..models import instella_moe
     register_architecture("deepseek_v3", instella_moe.config_kwargs,
                           instella_moe.checkpoint_params)
+    from ..models import afmoe
+    register_architecture("afmoe", afmoe.config_kwargs, afmoe.checkpoint_params)
 
 
 _register_builtins()

@@ -60,7 +60,7 @@ def test_custom_registration():
 
 def test_architecture_registry_builtin():
     assert supported_architectures() == \
-        ["bert", "bloom", "deepseek_v3", "distilbert", "falcon", "gpt2", "gpt_neo",
+        ["afmoe", "bert", "bloom", "deepseek_v3", "distilbert", "falcon", "gpt2", "gpt_neo",
          "gpt_neox", "gptj", "internlm", "llama", "mistral", "mixtral",
          "opt", "phi", "qwen2", "roberta"]
     spec = get_architecture("falcon")

@@ -9,7 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models import (bert_model, bloom_model, falcon_model,
+from deepspeed_tpu.models import (afmoe_model, bert_model, bloom_model, falcon_model,
                                   gpt2_model, gpt_neo_model, gpt_neox_model,
                                   gptj_model, llama_model, mixtral_model,
                                   opt_model, phi_model, roberta_model)
@@ -37,6 +37,11 @@ FAMILIES = {
     "roberta": lambda: roberta_model("bert-tiny", **TINY),
     # alternating global/local windowed attention, unscaled logits
     "gpt-neo": lambda: gpt_neo_model("gpt-neo-tiny", **TINY),
+    # mistral-shaped: ONE sliding window for every layer
+    "mistral-window": lambda: llama_model("llama2-tiny", attn_windows=8, **TINY),
+    # sliding and full layers mixed, rope on the sliding ones only, sandwich
+    # norms, gated attention over grouped heads, dense then expert layers
+    "afmoe": lambda: afmoe_model("afmoe-tiny", **TINY),
 }
 
 
@@ -58,6 +63,30 @@ def test_family_forward_and_grad(eight_devices, family):
     gnorm = jax.tree.reduce(
         lambda a, g: a + jnp.sum(jnp.square(g)), grads, jnp.zeros(()))
     assert float(gnorm) > 0.0
+
+
+def test_one_window_for_every_layer_is_one_static_kind(eight_devices):
+    """A Mistral-shaped preset passes one int: every layer is the same static
+    kind (a scan unit of one), the core runs under ``core_window``, the
+    per-layer tuple of the same window is the same program, and the window
+    binds (the loss differs from the global model's on the same weights)."""
+    import re
+    model = FAMILIES["mistral-window"]()
+    L = model.config.num_layers
+    assert model.scan_plan == (((8, True),), L, ())
+    params = model.init(jax.random.PRNGKey(0))
+    batch = {"input_ids": jnp.asarray(
+        np.random.default_rng(0).integers(0, 128, size=(2, 16)))}
+    loss = model.loss(params, batch)
+    text = jax.jit(model.loss).lower(params, batch).as_text(debug_info=True)
+    assert set(re.findall(r"attn/(core[a-z_]*)/", text)) == {"core_window"}
+    listed = llama_model("llama2-tiny", attn_windows=(8,) * L, **TINY)
+    assert float(listed.loss(params, batch)) == float(loss)
+    unwindowed = llama_model("llama2-tiny", **TINY)
+    assert unwindowed.scan_plan == (((0, True),), L, ())
+    assert abs(float(unwindowed.loss(params, batch)) - float(loss)) > 1e-6
+    # a window the context never reaches is no window
+    assert llama_model("llama2-tiny", attn_windows=32, **TINY)._windows is None
 
 
 def test_post_ln_layer_drop_is_identity(eight_devices):

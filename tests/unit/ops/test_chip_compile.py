@@ -79,6 +79,25 @@ class TestCompilesForV5e:
                          chip((B, S, H, D), BF16), chip((B, S, kvH, D), BF16),
                          chip((B, S, kvH, D), BF16))
 
+    @pytest.mark.parametrize("window", [2048, None])
+    def test_flash_fwd_bwd_packed_documents_at_16k(self, chip, window):
+        """The trinity-mini cell's two kinds of layer: 32 query heads over 4
+        key heads of 128 at 16,384 with segment ids, under a static window
+        (grids cut to it) and without one (dq's partials a key head at a
+        time)."""
+        from deepspeed_tpu.ops.transformer.pallas_flash import \
+            flash_attention_kernel
+        B, S, H, kvH, D = 1, 16384, 32, 4, 128
+
+        def loss(q, k, v, seg):
+            return jnp.sum(flash_attention_kernel(
+                q, k, v, causal=True, segment_ids=seg, window=window,
+                interpret=False).astype(F32))
+
+        compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                         chip((B, S, H, D), BF16), chip((B, S, kvH, D), BF16),
+                         chip((B, S, kvH, D), BF16), chip((B, S), I32))
+
     @pytest.mark.parametrize("m,k,n,g", [
         (32768, 2048, 1024, 64),    # olmoe-1b-7b.train.seq4k, wi_gate / wi_up
         (32768, 1024, 2048, 64),    # its wo

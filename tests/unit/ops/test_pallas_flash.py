@@ -13,6 +13,7 @@ Documented tolerances:
   keep the error at input-quantization scale rather than sqrt(S) growth.
 """
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -74,6 +75,19 @@ CASES = {
                          tiles=(256, 512)),
     "tiles_window_segids_alibi": dict(causal=True, window=300, segids=True,
                                       alibi=True, S=1024, tiles=(256, 512)),
+    # a window that is a Python int on the training call is STATIC: the
+    # grids hold the blocks it reaches alone (one tile; q-blocks narrower
+    # and wider than k-blocks; square tiles narrower than the window and a
+    # window that ends on a block's edge; with documents and ALiBi)
+    "static_window": dict(causal=True, window=64, static=True),
+    "static_window_256x512": dict(causal=True, window=300, static=True,
+                                  S=1024, tiles=(256, 512)),
+    "static_window_512x256": dict(causal=True, window=300, static=True,
+                                  segids=True, S=1024, tiles=(512, 256)),
+    "static_window_128x128": dict(causal=True, window=256, static=True,
+                                  segids=True, S=1024, tiles=(128, 128)),
+    "static_window_auto_2048": dict(causal=True, window=700, static=True,
+                                    segids=True, alibi=True, S=2048, B=1),
 }
 
 
@@ -94,6 +108,8 @@ def _run_pair(case, kvH=2, dtype=jnp.float32, seed=0):
           else None)
     w = (jnp.asarray(case["window"], jnp.int32) if case.get("window")
          else None)
+    if case.get("static"):
+        w = case["window"]
     bq, bk = case.get("tiles", (None, None))
 
     def reference(q, k, v):
@@ -364,6 +380,95 @@ def test_tile_rule_is_the_measured_one(sq, causal, fwd, bwd):
     assert (t.fwd, t.bwd) == (fwd, bwd)
 
 
+@pytest.mark.parametrize("tile,window,nq,nk,steps", [
+    # (k-steps a q-block, q-steps a k-block) of the cut grids
+    ((512, 512), 2048, 32, 32, (5, 5)),      # the cell's forward: 5 of 32 k-blocks
+    ((1024, 1024), 2048, 16, 16, (3, 3)),    # its backward: 3 of 16
+    ((512, 512), 2049, 32, 32, (5, 5)),
+    ((512, 512), 2050, 32, 32, (6, 6)),      # one key past a block's edge
+    ((256, 512), 300, 4, 2, None),
+    ((512, 256), 300, 2, 4, None),
+    ((128, 128), 1, 8, 8, (1, 1)),           # a window of the token itself
+    ((128, 128), 10 ** 6, 8, 8, (8, 8)),     # one that never binds: all of them
+])
+def test_a_static_window_cuts_the_grids_to_its_reach(tile, window, nq, nk, steps):
+    """The blocks the cut grids visit are the blocks with a visible pair,
+    found here from the mask itself, position by position."""
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    bq, bk = tile
+    i, j = np.indices((nq * bq, nk * bk))
+    seen = ((j <= i) & (i - j < window)).reshape(nq, bq, nk, bk).any(axis=(1, 3))
+    for qb in range(nq):
+        reached = np.flatnonzero(seen[qb])
+        assert (reached[0], reached[-1]) == (pf._first_k_block(tile, qb, window),
+                                             pf._last_k_block(tile, qb))
+        assert len(reached) == reached[-1] - reached[0] + 1
+    for kb in range(nk):
+        reached = np.flatnonzero(seen[:, kb])
+        assert (reached[0], reached[-1]) == (pf._first_q_block(tile, kb),
+                                             pf._last_q_block(tile, kb, window, nq))
+    got = pf.window_steps(tile, window, nq, nk)
+    assert got == (seen.sum(1).max(), seen.sum(0).max())
+    assert steps is None or got == steps
+
+
+def test_a_static_window_is_in_the_config_the_name_and_the_tiles(eight_devices):
+    """A Python int on the training call is static (cut grids, kernels named
+    ``*_window``); a traced one, one with a ``q_offset``, or one that cannot
+    bind is not."""
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    q, k, v = _qkv(S=1024)
+    prep = lambda w, **kw: pf._prepare(q, k, v, True, None, None, None, None, w,
+                                       kw.get("q_offset"), None, None, True)[0]
+    assert prep(300).window == 300 and prep(300).use_window
+    assert prep(jnp.asarray(300)).window is None and prep(jnp.asarray(300)).use_window
+    assert prep(300, q_offset=0).window is None and prep(300, q_offset=0).use_window
+    for never in (0, -1, 1024, 5000, None):
+        assert prep(never).window is None and not prep(never).use_window
+    text = str(jax.make_jaxpr(lambda q, k, v: jax.grad(
+        lambda q: jnp.sum(flash_attention_kernel(q, k, v, window=300, interpret=True)))(q))(
+            q, k, v))
+    assert "flash_fwd_window" in text and "flash_bwd_window" in text
+    # the measured window (2048) keeps the measured tiles; a narrower one caps them
+    at = lambda w: pf.choose_tiles(16384, 16384, 128, window=w)
+    assert (at(2048).fwd, at(2048).bwd) == (at(None).fwd, at(None).bwd) == ((512, 512), (1024, 1024))
+    assert (at(700).fwd, at(700).bwd) == ((512, 512), (512, 512))
+    assert (at(64).fwd, at(64).bwd) == ((512, 512), (512, 512))
+
+
+@pytest.mark.parametrize("rows,kvh,bytes_a_row,want", [
+    (32, 16, 2 ** 25, 32),       # instella's 2 x 16 heads at 8192: exactly the limit, whole
+    (4, 4, 2 ** 30, 1),          # trinity's full layer at 16,384: a key head a launch
+    (8, 4, 2 ** 28, 4),          # whole batch rows
+    (8, 4, 2 ** 29, 2),          # half a batch row's key heads
+    (6, 3, 2 ** 29, 1),          # 2 fits the bytes but neither divides 3 nor holds it
+    (4, 4, 2 ** 40, 1),          # nothing fits: one row at a time
+])
+def test_the_backward_splits_its_rows_where_dq_partials_outgrow_the_limit(
+        rows, kvh, bytes_a_row, want):
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    cfg = pf.FlashConfig(True, 1.0, True, False, False, kvh, None, True)
+    assert pf._rows_a_launch(cfg, rows, bytes_a_row) == want
+    alibi = dataclasses.replace(cfg, use_alibi=True)
+    assert pf._rows_a_launch(alibi, rows, bytes_a_row) == rows
+
+
+@pytest.mark.parametrize("name,kvH", [("tiles_segids", 2), ("tiles_256x512", 8),
+                                      ("static_window_512x256", 2)])
+def test_a_split_backward_is_the_whole_one(eight_devices, monkeypatch, name, kvH):
+    """With the limit at one row's worth, the backward runs a key head (or a
+    batch row) at a time and gives the same gradients."""
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    q, k, v, _, kernel = _run_pair(CASES[name], kvH=kvH)
+    grad = lambda: jax.grad(lambda *a: jnp.sum(jnp.square(kernel(*a))),
+                            argnums=(0, 1, 2))(q, k, v)
+    whole = grad()
+    monkeypatch.setattr(pf, "DQ_PARTIAL_BYTES", 1)
+    split = grad()
+    for a, b in zip(split, whole):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6)
+
+
 @pytest.mark.parametrize("sq,sk,d,itemsize", TILE_SHAPES)
 def test_chosen_tiles_are_legal(sq, sk, d, itemsize):
     from deepspeed_tpu.ops.transformer import pallas_flash as pf
@@ -455,6 +560,12 @@ def test_route_rule_is_shape_and_platform_only(q_shape, k_shape, monkeypatch):
     ((1, 4000, 16, 64), 16, "tpu", "", "xla"),        # no 128-multiple tile
     ((1, 4104, 16, 64), 16, "tpu", "", "xla_chunked"),
     ((1, 8192, 16, 128), 16, "tpu", "", "kernel"),
+    # the trinity-mini cell: 32q/4kv x 128 at 16,384 with segment ids, under
+    # a window of 2048 and under none (neither is an argument of the route)
+    ((1, 16384, 32, 128), 4, "tpu", "", "kernel"),
+    ((1, 16384, 32, 128), 4, "tpu", "xla", "xla_chunked"),
+    ((1, 16384, 32, 128), 4, "cpu", "", "xla"),
+    ((1, 16384, 32, 128), 4, "cpu", "pallas", "kernel"),
     ((2, 128, 8, 64), 2, "tpu", "", "xla"),           # under the crossover
     ((1, 8192, 16, 128), 16, "tpu", "xla", "xla_chunked"),
     ((4, 1024, 20, 64), 20, "tpu", "xla", "xla"),

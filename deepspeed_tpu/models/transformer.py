@@ -27,7 +27,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1246,6 +1246,8 @@ class TransformerLM:
         under the default ``remat_policy`` (an engine's reading; ``None``
         saves everything named) and where the decision is written.
         """
+        if not return_hidden:
+            self._charge_head(remat_budget, input_ids)
         x, aux, stats, _ = self._trunk(
             params, input_ids, layer_mask, token_type_ids, attention_mask,
             remat_budget, with_mtp=False)
@@ -1255,6 +1257,13 @@ class TransformerLM:
                 x = self._ln_f(params["ln_f"], x)
             return (x, aux) + stats
         return (self.head(params, x), aux) + stats
+
+    def _charge_head(self, remat_budget: Optional[Budget], input_ids) -> None:
+        """Tell the budget what a differentiated step holds outside its
+        blocks: a head's float32 logits and their gradient (two heads are
+        each run again in the backward, so one counts)."""
+        if remat_budget is not None:
+            remat_budget.outside_bytes = 2 * 4 * input_ids.size * self.config.vocab_size
 
     def _trunk(self, params, input_ids, layer_mask, token_type_ids,
                attention_mask, remat_budget, with_mtp: bool):
@@ -1280,20 +1289,35 @@ class TransformerLM:
         # FarSkip carries two streams: (r_(i-1), r_(i-2)), r_(-1) = r_0
         init = ((x, x) if c.farskip else x, positions, self._aux_zero())
         blocks_in_all = c.num_layers + (c.mtp_layers if with_mtp else 0)
+        blocks: Dict[Any, Callable] = {}
 
-        def one_block(kind=None):
-            """``block_fn`` for a block outside the scan, under the scan's
-            remat policy: its bytes reckoned as one of all the blocks, in
-            the same room, its decision written nowhere (the scan's is)."""
-            fn = functools.partial(block_fn, kind=kind)
-            if not c.remat:
-                return fn
-            room = None if remat_budget is None else Budget(remat_budget.room_bytes)
-            return checkpointed(fn, c.remat_policy, blocks_in_all, room)
+        def block_of(kind=None):
+            """``block_fn`` for a layer of ``kind`` under the remat policy:
+            one of all the step's blocks, in the same room. A kind is built
+            once (and under the default policy traced once a shape)."""
+            if kind not in blocks:
+                fn = functools.partial(block_fn, kind=kind)
+                blocks[kind] = checkpointed(
+                    fn, c.remat_policy, blocks_in_all, remat_budget) if c.remat else fn
+            return blocks[kind]
+
+        unit, _, tail = self.scan_plan
+        if c.remat and c.remat_policy == KEEP_PRODUCTS:
+            # every kind of block is reckoned before the first is decided:
+            # they run one after another, so each is held to the largest
+            one = lambda tree: jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), tree)
+            for i in range(dense):
+                block_of(self._kinds[i]).reckon(
+                    init, one((params["dense_blocks"], keep[:1])))
+            for kind in unit + tail:
+                block_of(kind).reckon(init, one(xs))
+            if with_mtp and c.mtp_layers:
+                block_of().reckon(init, one((params["mtp"]["blocks"], keep[:1])))
 
         for i in range(dense):       # the leading dense layers, one by one
             layer = jax.tree.map(lambda a: a[i], params["dense_blocks"])
-            init, _ = one_block(self._kinds[i])(init, (layer, keep[i]))
+            init, _ = block_of(self._kinds[i])(init, (layer, keep[i]))
         rows = None
         if c.remat and c.remat_policy == "alternating":
             # HALF-remat: scan over layer pairs, checkpointing only the
@@ -1322,8 +1346,7 @@ class TransformerLM:
                     (x, positions, aux),
                     jax.tree.map(lambda a: a[-1], xs))
         else:
-            (x, _, aux), rows = self._scan_by_kind(
-                block_fn, init, xs, blocks_in_all, remat_budget, one_block)
+            (x, _, aux), rows = self._scan_by_kind(init, xs, block_of)
         if c.farskip:
             x = x[0]
         mtp_x = None
@@ -1342,7 +1365,7 @@ class TransformerLM:
                         axis=-1))
                 merged = _c(merged, ACT_SPEC)
                 start = ((merged, merged) if c.farskip else merged, positions, aux)
-                (mtp_x, _, aux), mtp_rows = one_block()(
+                (mtp_x, _, aux), mtp_rows = block_of()(
                     start, (jax.tree.map(lambda a: a[0], mp["blocks"]),
                             jnp.ones((), c.dtype)))
                 if c.farskip:
@@ -1373,21 +1396,13 @@ class TransformerLM:
                       if all(kinds[i] == kinds[i - p] for i in range(p, n)))
         return kinds[:period], n // period, kinds[n - n % period:]
 
-    def _scan_by_kind(self, block_fn, init, xs, blocks_in_all, remat_budget,
-                      one_block):
+    def _scan_by_kind(self, init, xs, block_of):
         """The layer scan over ``xs`` (stacked blocks and their keep gates)
-        as ``scan_plan`` lays it out -> (carry, the no-drop path's rows
-        per expert ``[layers, experts]`` or None)."""
-        c = self.config
+        as ``scan_plan`` lays it out, ``block_of(kind)`` a layer's function
+        -> (carry, the no-drop path's rows per expert ``[layers, experts]``
+        or None)."""
         unit, repeats, tail = self.scan_plan
-
-        def of_kind(kind):
-            fn = functools.partial(block_fn, kind=kind)
-            if not c.remat:
-                return fn
-            return checkpointed(fn, c.remat_policy, blocks_in_all, remat_budget)
-
-        fns = [of_kind(kind) for kind in unit]
+        fns = [block_of(kind) for kind in unit]
         p, n = len(unit), len(unit) * repeats
         if p == 1:
             carry, rows = jax.lax.scan(fns[0], init, xs)
@@ -1404,7 +1419,7 @@ class TransformerLM:
             if rows is not None:
                 rows = rows.reshape((n,) + rows.shape[2:])
         for i, kind in enumerate(tail):
-            carry, r = one_block(kind)(carry, jax.tree.map(lambda a: a[n + i], xs))
+            carry, r = block_of(kind)(carry, jax.tree.map(lambda a: a[n + i], xs))
             if rows is not None:
                 rows = jnp.concatenate([rows, r[None]], axis=0)
         return carry, rows
@@ -1495,6 +1510,7 @@ class TransformerLM:
         c = self.config
         labels = self.derive_labels(batch)
         mask = batch.get("loss_mask")
+        self._charge_head(remat_budget, batch["input_ids"])
         x, aux, stats, mtp_x = self._trunk(
             params, batch["input_ids"], batch.get("layer_mask"),
             batch.get("token_type_ids"), batch.get("attention_mask"),

@@ -31,6 +31,15 @@ the block's own differentiated jaxpr, :func:`choose_saved` takes the prefix
 of :data:`SAVE_ORDER` that fits a byte budget, and :func:`saved_budget` makes
 that budget of the room the engine reads from the device when the step is
 first traced (:class:`Budget`). Without a reading everything named is saved.
+
+**What the budget is charged first** (PR 35): every layer's input, and the
+step's own working set in bytes, reckoned from the step's shapes while it is
+traced: the largest block's forward and backward as :func:`live_bytes` walks
+their jaxpr (every kind of block a step runs is reckoned before the first is
+decided: they run one after another, so the largest counts), and what lives
+outside the blocks, which the model fills in (a head's float32 logits and
+their gradient). The sum is charged at :data:`WORKING_SHARE`, one number
+fitted on the chip; what is left, over :data:`STACK_COST`, is the budget.
 """
 
 from __future__ import annotations
@@ -39,7 +48,9 @@ import dataclasses
 from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
 import jax
-from jax.extend.core import jaxpr_as_fun
+import jax.numpy as jnp
+import numpy as np
+from jax.extend.core import Literal, jaxpr_as_fun
 
 from ..topology import MODEL_AXIS
 
@@ -140,8 +151,16 @@ def resolve_policy(name: Optional[str]):
 #: layout copies in front of the kernel are recomputed either way). A value
 #: under any other name is never kept: an XLA attention route's ``[B,H,S,S]``
 #: scores (``attn_big``) are no candidate.
+#: At 16,384 tokens a step (PERF.md, PR 35, the two long-sequence cells, the
+#: fill probe's synced steps): the kernel's pair with the router's logits
+#: spared 45 and 68 ms a GB (0.87 and 0.51 GB kept: a second ``flash_fwd`` a
+#: layer), the experts' first two products 15-24 and 12 (their launches of
+#: 1.25 ms), the experts' rows after the combine's gather 0 (1.06 GB in the
+#: latent-attention cell, 715.3 against 714.9 ms): the order holds, and its
+#: third group is worth its bytes only where they are few.
 #: Latent attention's and the gate's products (PR 32) stand where what a
-#: kept byte spares puts them, reckoned and not yet measured: ``attn_gate``
+#: kept byte spares puts them, reckoned and still not measured (no cell's
+#: budget reaches them): ``attn_gate``
 #: and ``kv_latent`` are products over the hidden size like ``o_proj`` and
 #: ``q_proj`` (2 x hidden FLOPs recomputed for each 2 bytes kept) and join
 #: their groups' neighbourhood; ``kv_up`` contracts over the latent rank, a
@@ -158,12 +177,11 @@ SAVE_ORDER = (("attn_lse", "attn_o"), ("moe_logits",), ("wi_gate", "wi_up"),
 #: layer's slice out of it before the backward reads it).
 STACK_COST = 1.2
 
-#: The step's working set beside the gradients and the layers' inputs (the
-#: head's logits, one block's backward, and the jump the first kept value
-#: brings), in units of one layer's input: all scale with a step's tokens.
-#: The largest measured was 90 (gpt2-large at 8 x 1024 keeping the kernel's
-#: output), the others 44 to 60.
-WORKING_CARRIES = 128
+#: What the step's reckoned working set (:attr:`Budget.working_bytes`) is
+#: charged at: the walk counts every link of an elementwise chain that XLA
+#: fuses, and the head and a block are not live together. Fitted on the chip
+#: to the fill probe's peaks of four cells (PERF.md, PR 35).
+WORKING_SHARE = 0.4
 
 
 def choose_saved(candidates: Mapping[str, int],
@@ -184,17 +202,18 @@ def choose_saved(candidates: Mapping[str, int],
     return tuple(saved)
 
 
-def saved_budget(room_bytes: Optional[int], layers: int,
-                 carry_bytes: int) -> Optional[int]:
+def saved_budget(room_bytes: Optional[int], layers: int, carry_bytes: int,
+                 working_bytes: int) -> Optional[int]:
     """The bytes the saved values may take, of ``room_bytes``: what the
     device can give the step's activations (:class:`Budget`). First comes
     what the step needs besides: every layer's input (``carry_bytes``, the
-    scan's own residual) and a working set of :data:`WORKING_CARRIES` more;
+    scan's own residual) and its working set (``working_bytes`` as
+    :attr:`Budget.working_bytes` reckons it, at :data:`WORKING_SHARE`);
     what is left is divided by :data:`STACK_COST`. ``None`` (no reading)
     stays ``None``: no budget. Pure."""
     if room_bytes is None:
         return None
-    left = room_bytes - (layers + WORKING_CARRIES) * carry_bytes
+    left = room_bytes - layers * carry_bytes - WORKING_SHARE * working_bytes
     return max(0, int(left / STACK_COST))
 
 
@@ -203,11 +222,32 @@ class Budget:
     """What an engine hands a model's differentiated call (``remat_budget=``):
     ``room_bytes``, what the device can give the step's activations, counted
     over the whole batch (free memory less the gradients, times the ways the
-    mesh splits an activation), or ``None`` where it cannot be read. Each
-    :func:`checkpointed` block under ``KEEP_PRODUCTS`` writes what it decided
-    into ``totals`` while it is traced."""
+    mesh splits an activation), or ``None`` where it cannot be read. The
+    step's working set is reckoned while it is traced, in two parts:
+    ``outside_bytes``, what lives outside the blocks, which the model knows
+    and fills in (a head's float32 logits and their gradient), and
+    ``block_bytes``, the largest block's forward and backward as
+    :func:`live_bytes` walks them, which every :func:`checkpointed` block
+    raises when it is reckoned (blocks run one after another, so the largest
+    counts). Each block under ``KEEP_PRODUCTS`` writes what it decided into
+    ``totals`` while it is traced."""
     room_bytes: Optional[int]
     totals: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    outside_bytes: int = 0
+    block_bytes: int = 0
+
+    @property
+    def working_bytes(self) -> int:
+        return self.outside_bytes + self.block_bytes
+
+
+def _aval_bytes(var) -> int:
+    aval = var.aval
+    return aval.size * aval.dtype.itemsize if hasattr(aval, "dtype") else 0
+
+
+def _bytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
 def named_bytes(jaxpr) -> Dict[str, int]:
@@ -216,16 +256,119 @@ def named_bytes(jaxpr) -> Dict[str, int]:
     found: Dict[str, int] = {}
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "name":
-            aval = eqn.outvars[0].aval
-            found[eqn.params["name"]] = aval.size * aval.dtype.itemsize
+            found[eqn.params["name"]] = _aval_bytes(eqn.outvars[0])
         elif eqn.primitive.name != "pallas_call":
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 found.update(named_bytes(sub))
     return found
 
 
-def _bytes(tree) -> int:
-    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+#: results XLA never writes out by themselves (the consumer reads the
+#: smaller operand)
+_NEVER_WRITTEN = frozenset({"broadcast_in_dim", "iota"})
+
+
+def live_bytes(jaxpr) -> int:
+    """The most bytes live at once while ``jaxpr`` runs in program order,
+    beside its inputs: an equation's results from where they are made to
+    their last use, the jaxpr's own results to its end; inside an equation
+    that holds jaxprs (a ``pjit``, a loop's body, a branch) what the worst of
+    them holds; a ``pallas_call``'s results as declared, its body not looked
+    into. A ``checkpoint_name`` is its operand under another name. An upper
+    bound of what the compiled program holds, since XLA fuses elementwise
+    chains of which every link counts here. Pure: a jaxpr in, bytes out."""
+    is_var = lambda v: not isinstance(v, Literal)
+    alias: Dict[Any, Any] = {}
+    last: Dict[Any, int] = {}
+    for i, eqn in enumerate(jaxpr.eqns):
+        if eqn.primitive.name == "name" and is_var(eqn.invars[0]):
+            alias[eqn.outvars[0]] = alias.get(eqn.invars[0], eqn.invars[0])
+        for v in filter(is_var, eqn.invars):
+            last[alias.get(v, v)] = i
+    for v in filter(is_var, jaxpr.outvars):
+        last[alias.get(v, v)] = len(jaxpr.eqns)
+    held: Dict[Any, int] = {}
+    live = peak = 0
+    for i, eqn in enumerate(jaxpr.eqns):
+        prim = eqn.primitive.name
+        if prim == "name":
+            continue
+        made = 0 if prim in _NEVER_WRITTEN else sum(map(_aval_bytes, eqn.outvars))
+        inside = 0 if prim == "pallas_call" else max(
+            (live_bytes(getattr(sub, "jaxpr", sub))
+             for sub in jax.core.jaxprs_in_params(eqn.params)), default=0)
+        peak = max(peak, live + max(made, inside))
+        if prim not in _NEVER_WRITTEN:
+            for v in eqn.outvars:
+                if v in last:            # a result nothing reads is gone at once
+                    held[v] = _aval_bytes(v)
+                    live += held[v]
+        for v in {alias.get(v, v) for v in filter(is_var, eqn.invars)}:
+            if last[v] == i:
+                live -= held.pop(v, 0)   # an input of the jaxpr was never held
+    return peak
+
+
+def _backward_of(block: Callable) -> Callable:
+    """``block``'s forward and backward as one function of its arguments
+    (the cotangents are ones: a traced shape, no value)."""
+    def both(*args):
+        out, pull = jax.vjp(block, *args)
+        return pull(jax.tree.map(
+            lambda o: jnp.ones(o.shape, o.dtype)
+            if jnp.issubdtype(o.dtype, jnp.inexact)
+            else np.zeros(o.shape, jax.dtypes.float0), out))
+    return both
+
+
+class _KeptBlock:
+    """``block_fn(carry, layer)`` under ``KEEP_PRODUCTS``, one of ``layers``
+    blocks held to ``budget``. The block is traced once for each shape it is
+    given: to its own jaxpr, and that jaxpr's forward and backward to one
+    more, off which the names, their bytes and the block's live bytes are
+    read (a custom VJP's residuals are named in its forward rule, which only
+    differentiation traces). :meth:`reckon` does that much and may be called
+    ahead for every kind of block of a step, so that each is held to the
+    largest; calling the block decides and applies the policy."""
+
+    def __init__(self, block_fn: Callable, layers: int, budget: Optional[Budget]):
+        self.block_fn, self.layers, self.budget = block_fn, layers, budget
+        self._traced: Dict[Any, Any] = {}
+
+    def reckon(self, carry, layer):
+        args = jax.tree.leaves((carry, layer))
+        key = (jax.tree.structure((carry, layer)),
+               tuple((x.shape, str(x.dtype)) for x in args))
+        if key not in self._traced:
+            closed, out = jax.make_jaxpr(self.block_fn, return_shape=True)(carry, layer)
+            both = jax.make_jaxpr(_backward_of(jaxpr_as_fun(closed)))(*args).jaxpr
+            self._traced[key] = closed, out, named_bytes(both)
+            if self.budget is not None:
+                self.budget.block_bytes = max(
+                    self.budget.block_bytes,
+                    _bytes((args, out)) + live_bytes(both))
+        return self._traced[key]
+
+    def __call__(self, carry, layer):
+        closed, out, named = self.reckon(carry, layer)
+        budget = self.budget
+        candidates = {n: self.layers * named[n] for group in SAVE_ORDER
+                      for n in group if n in named}
+        budget_bytes = None if budget is None else saved_budget(
+            budget.room_bytes, self.layers, _bytes(carry), budget.working_bytes)
+        saved = choose_saved(candidates, budget_bytes)
+        if budget is not None:
+            budget.totals.update(
+                policy=KEEP_PRODUCTS, saved=saved,
+                saved_bytes=sum(candidates[n] for n in saved),
+                candidate_bytes=sum(candidates.values()),
+                room_bytes=budget.room_bytes, budget_bytes=budget_bytes,
+                working_bytes=budget.working_bytes,
+                block_bytes=budget.block_bytes, outside_bytes=budget.outside_bytes)
+        block = jax.checkpoint(jaxpr_as_fun(closed), policy=jax.checkpoint_policies
+                               .save_only_these_names(*saved))
+        return jax.tree.unflatten(jax.tree.structure(out),
+                                  block(*jax.tree.leaves((carry, layer))))
 
 
 def checkpointed(block_fn: Callable, policy: Optional[str], layers: int,
@@ -235,33 +378,11 @@ def checkpointed(block_fn: Callable, policy: Optional[str], layers: int,
     one builder of the models' block policy (``TransformerLM.apply`` and
     the pipeline stage both call it). Every explicit name is
     :func:`resolve_policy`'s. ``KEEP_PRODUCTS`` saves the named values that
-    :func:`choose_saved` admits into :func:`saved_budget`'s bytes; the names
-    and their bytes are read off the block's differentiated jaxpr (a custom
-    VJP's residuals are named in its forward rule, which only
-    differentiation traces), so ``block_fn`` itself is traced once."""
+    :func:`choose_saved` admits into :func:`saved_budget`'s bytes
+    (:class:`_KeptBlock`)."""
     if policy != KEEP_PRODUCTS:
         return jax.checkpoint(block_fn, policy=resolve_policy(policy or "full"))
-
-    def kept(carry, layer):
-        closed, out = jax.make_jaxpr(block_fn, return_shape=True)(carry, layer)
-        block, args = jaxpr_as_fun(closed), jax.tree.leaves((carry, layer))
-        named = named_bytes(jax.make_jaxpr(
-            lambda *a: jax.vjp(block, *a)[0])(*args).jaxpr)
-        candidates = {n: layers * named[n] for group in SAVE_ORDER
-                      for n in group if n in named}
-        budget_bytes = saved_budget(
-            None if budget is None else budget.room_bytes, layers, _bytes(carry))
-        saved = choose_saved(candidates, budget_bytes)
-        if budget is not None:
-            budget.totals.update(
-                policy=KEEP_PRODUCTS, saved=saved,
-                saved_bytes=sum(candidates[n] for n in saved),
-                candidate_bytes=sum(candidates.values()),
-                budget_bytes=budget_bytes)
-        flat = jax.checkpoint(block, policy=jax.checkpoint_policies
-                              .save_only_these_names(*saved))(*args)
-        return jax.tree.unflatten(jax.tree.structure(out), flat)
-    return kept
+    return _KeptBlock(block_fn, layers, budget)
 
 
 def checkpoint(function: Callable, *args, policy: Optional[str] = None, **kwargs) -> Any:

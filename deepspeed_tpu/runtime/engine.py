@@ -563,12 +563,17 @@ class DeepSpeedEngine:
         # What ``remat=True`` kept, kept with telemetry off and written
         # while a step is traced (checkpointing.KEEP_PRODUCTS): the
         # policy, the names saved, their bytes over all layers, the bytes
-        # of every candidate, and the budget they were held to (None where
-        # the backend reports no memory). ``policy`` None: no block was
+        # of every candidate, the room the device gave and the budget they
+        # were held to (None where the backend reports no memory), and the
+        # working set the budget was charged first: the largest block's
+        # forward and backward (``block_bytes``) and what lives outside the
+        # blocks (``outside_bytes``). ``policy`` None: no block was
         # differentiated under that policy (remat off, an explicit policy,
         # the ZeRO-3 overlap schedule).
         self.remat_totals = {"policy": None, "saved": (), "saved_bytes": 0,
-                             "candidate_bytes": 0, "budget_bytes": None}
+                             "candidate_bytes": 0, "room_bytes": None,
+                             "budget_bytes": None, "working_bytes": 0,
+                             "block_bytes": 0, "outside_bytes": 0}
         # overlap-planner state (set for real when the pipelined micro
         # builds; defaults keep non-overlap engines on the plain carry)
         self._ef_carry_active = False
@@ -1140,7 +1145,11 @@ class DeepSpeedEngine:
                      f"{kept['saved_bytes'] / 1e6:.1f} of "
                      f"{kept['candidate_bytes'] / 1e6:.1f} MB"
                      + ("" if kept["budget_bytes"] is None else
-                        f" (budget {kept['budget_bytes'] / 1e6:.1f} MB)"),
+                        f" (budget {kept['budget_bytes'] / 1e6:.1f} MB"
+                        f" of a room of {kept['room_bytes'] / 1e6:.1f})")
+                     + f"; working set {kept['working_bytes'] / 1e6:.1f} MB"
+                       f" = a block's {kept['block_bytes'] / 1e6:.1f}"
+                       f" + outside them {kept['outside_bytes'] / 1e6:.1f}",
                      ranks=[0])
         return out
 

@@ -116,6 +116,7 @@ class PipelineModule:
         assert B % M == 0, f"batch {B} not divisible by num_microbatches {M}"
         mb = B // M
         positions = jnp.arange(S)[None, :]
+        self._lm._charge_head(remat_budget, input_ids)
 
         x = self._lm._wte(params["wte"], input_ids)
         if self._lm._wpe is not None:

@@ -16,8 +16,8 @@ import deepspeed_tpu
 from deepspeed_tpu.models import gpt2_model, llama_model, olmoe_model
 from deepspeed_tpu.runtime.activation_checkpointing import checkpointing
 from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
-    KEEP_PRODUCTS, SAVE_ORDER, STACK_COST, WORKING_CARRIES, Budget,
-    choose_saved, saved_budget)
+    KEEP_PRODUCTS, SAVE_ORDER, STACK_COST, WORKING_SHARE, Budget,
+    choose_saved, live_bytes, saved_budget)
 
 SEQ = 128   # the interpreted flash kernel's smallest tile
 
@@ -48,14 +48,16 @@ def _grads(model, room=None, dtype=jnp.float32):
     return fn(params), budget.totals
 
 
-def _room_for(model, saved_bytes: int) -> int:
+def _room_for(model, saved_bytes: int, working_bytes: int) -> int:
     """The engine's reading under which ``saved_budget`` comes to
-    ``saved_bytes``: the inverse of that function at the model's shapes."""
+    ``saved_bytes``: the inverse of that function at the model's shapes,
+    ``working_bytes`` as a trace of the model reckoned it."""
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.float32))
     carry = jax.eval_shape(lambda p: model.embed(p, _batch()["input_ids"], None)
                            + (model._aux_zero(),), params)
     layers = model.config.num_layers
-    return ((layers + WORKING_CARRIES) * checkpointing._bytes(carry)
+    return (layers * checkpointing._bytes(carry)
+            + int(np.ceil(WORKING_SHARE * working_bytes))
             + int(np.ceil(saved_bytes * STACK_COST)))
 
 
@@ -88,7 +90,7 @@ def test_gradients_equal_under_a_partial_budget(monkeypatch, family):
     model = _model(family, KEEP_PRODUCTS)
     _, everything = _grads(model)
     half = everything["candidate_bytes"] // 2
-    got, kept = _grads(model, _room_for(model, half))
+    got, kept = _grads(model, _room_for(model, half, everything["working_bytes"]))
     assert 0 < len(kept["saved"]) < len(everything["saved"])
     assert kept["saved"] == everything["saved"][:len(kept["saved"])]
     assert 0 < kept["saved_bytes"] <= half == kept["budget_bytes"]
@@ -172,17 +174,221 @@ def test_choose_saved_takes_the_measured_order():
     assert len(set(names)) == len(names)
 
 
+#: the step's own needs at 36 layers of 10 MB and a working set of 900 MB
+NEEDS = 36 * 10 * MB + int(WORKING_SHARE * 900 * MB)
+
+
 @pytest.mark.parametrize("room,want", [
     (None, None),                                        # no reading: no budget
     (0, 0),
-    ((36 + WORKING_CARRIES) * 10 * MB, 0),               # the step's own needs
-    ((36 + WORKING_CARRIES) * 10 * MB + 18 * MB, int(18 * MB / STACK_COST)),
-    (1 << 40, int(((1 << 40) - (36 + WORKING_CARRIES) * 10 * MB) / STACK_COST)),
+    (NEEDS, 0),                                          # the step's own needs
+    (NEEDS + 18 * MB, int(18 * MB / STACK_COST)),
+    (1 << 40, int(((1 << 40) - NEEDS) / STACK_COST)),
 ])
 def test_saved_budget_table(room, want):
     """Room less every layer's input and the working set, over what a
     saved byte costs the step's peak."""
-    assert saved_budget(room, layers=36, carry_bytes=10 * MB) == want
+    assert saved_budget(room, layers=36, carry_bytes=10 * MB,
+                        working_bytes=900 * MB) == want
+
+
+# -- (b') the working set the budget is charged: a walk over a jaxpr ----------
+
+KB = 1 << 10
+X = jax.ShapeDtypeStruct((256, 256), jnp.float32)      # 256 KB
+
+
+def _live(fn, *args) -> int:
+    return live_bytes(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def test_live_bytes_counts_what_is_held_together():
+    def chain(x):                  # each link dies as the next is made
+        return jnp.tanh(jnp.cos(jnp.sin(x)))
+    assert _live(chain, X) == 2 * 256 * KB
+
+    def held(x):                   # a stays for the last line: three at once
+        a = jnp.sin(x)
+        b = jnp.cos(a)
+        return jnp.tanh(b) + a
+    assert _live(held, X) == 3 * 256 * KB
+
+
+def test_live_bytes_leaves_out_what_died_before_the_peak():
+    def early_and_late(x):
+        a = jnp.sin(x) @ jnp.cos(x)              # three values, then one scalar
+        early = jnp.sum(a)
+        b = jnp.tanh(x)
+        return early + jnp.sum(b * jnp.exp(b))   # b, exp(b), their product
+    # the first half's three values are gone when the second half's three live
+    assert _live(early_and_late, X) == 3 * 256 * KB + 4
+
+
+def test_live_bytes_reads_through_a_name_and_skips_broadcasts():
+    from jax.ad_checkpoint import checkpoint_name
+
+    def named(x, scale):
+        a = checkpoint_name(jnp.sin(x), "a")          # a name is no copy
+        return a * scale[None, :]                     # nor is a broadcast written
+    assert _live(named, X, jax.ShapeDtypeStruct((256,), jnp.float32)) == 2 * 256 * KB
+
+
+def test_live_bytes_counts_a_kernels_declared_results():
+    """A ``pallas_call``'s results count as declared, whatever its body
+    holds: the float32 dq partials of the flash backward are results."""
+    from jax.experimental import pallas as pl
+
+    def body(x_ref, o_ref, partial_ref):
+        wide = jnp.concatenate([x_ref[...]] * 8, axis=1)    # 8x inside the body
+        o_ref[...] = wide[:, :256]
+        partial_ref[...] = jnp.stack([x_ref[...]] * 4)
+
+    def launch(x):
+        o, partials = pl.pallas_call(
+            body, out_shape=[jax.ShapeDtypeStruct((256, 256), jnp.float32),
+                             jax.ShapeDtypeStruct((4, 256, 256), jnp.float32)],
+            interpret=True, name="toy")(x)
+        return o + jnp.sum(partials, axis=0)
+    # the two results (1 + 4) and the sum; nothing of the body's 8x value
+    assert _live(launch, X) == (1 + 4 + 1) * 256 * KB
+
+
+def test_live_bytes_looks_inside_a_jit_and_a_loop():
+    inner = jax.jit(lambda x: jnp.sum(jnp.sin(x) * jnp.cos(x)))
+    assert _live(lambda x: inner(x) + 1.0, X) == 3 * 256 * KB
+
+    def loop(x):
+        step = lambda c, _: (c + jnp.sum(jnp.tanh(x) * jnp.exp(x)), None)
+        return jax.lax.scan(step, 0.0, None, length=3)[0]
+    assert _live(loop, X) == 3 * 256 * KB
+
+
+def _toy_block(width: int):
+    from jax.ad_checkpoint import checkpoint_name
+
+    def block(carry, layer):
+        h = checkpoint_name(carry @ layer, "fc_in")              # [64, width]
+        return jnp.tanh(h) @ layer.T, None
+    return block, jax.ShapeDtypeStruct((64, 64), jnp.float32), \
+        jax.ShapeDtypeStruct((64, width), jnp.float32)
+
+
+def test_the_largest_kind_of_block_counts():
+    """Two kinds of block of one step: each is reckoned before either
+    decides, and both are held to the larger one's bytes."""
+    budget = Budget(room_bytes=1 << 40)
+    kinds = [(checkpointing.checkpointed(fn, KEEP_PRODUCTS, 2, budget), carry, layer)
+             for fn, carry, layer in (_toy_block(128), _toy_block(1024))]
+    seen = []
+    for block, carry, layer in kinds:
+        block.reckon(carry, layer)
+        seen.append(budget.block_bytes)
+    assert 0 < seen[0] < seen[1]                 # the wider block raised it
+    kinds[0][0].reckon(*kinds[0][1:])            # and a smaller one does not lower it
+    assert budget.block_bytes == seen[1]
+    x, w = jnp.ones((64, 64)), jnp.ones((64, 128))
+    jax.make_jaxpr(jax.grad(lambda x: jnp.sum(kinds[0][0](x, w)[0])))(x)
+    assert budget.totals["block_bytes"] == seen[1] == budget.totals["working_bytes"]
+    # a room that would hold the narrow block's product beside its own bytes
+    # holds nothing beside the wide block's
+    narrow = Budget(room_bytes=None)
+    checkpointing.checkpointed(_toy_block(128)[0], KEEP_PRODUCTS, 2, narrow).reckon(
+        *kinds[0][1:])
+    product = 2 * 64 * 128 * 4
+    room = 2 * 64 * 64 * 4 + int(WORKING_SHARE * narrow.block_bytes) \
+        + int(np.ceil(product * STACK_COST)) + 8
+    for budget, saved in ((Budget(room), ("fc_in",)), (Budget(room, block_bytes=seen[1]), ())):
+        block = checkpointing.checkpointed(_toy_block(128)[0], KEEP_PRODUCTS, 2, budget)
+        jax.make_jaxpr(jax.grad(lambda x: jnp.sum(block(x, w)[0])))(x)
+        assert budget.totals["saved"] == saved
+
+
+def test_a_block_is_traced_once_a_shape():
+    calls = []
+    fn, carry, layer = _toy_block(128)
+
+    def counted(c, l):
+        calls.append(1)
+        return fn(c, l)
+    block = checkpointing.checkpointed(counted, KEEP_PRODUCTS, 2, Budget(None))
+    block.reckon(carry, layer)
+    jax.make_jaxpr(jax.grad(lambda x: jnp.sum(
+        block(block(x, jnp.ones((64, 128)))[0], jnp.ones((64, 128)))[0])))(jnp.ones((64, 64)))
+    assert len(calls) == 1
+
+
+@pytest.mark.parametrize("family,heads", [("dense", 1), ("moe_qk_norm", 1),
+                                          ("two_heads", 2)])
+def test_the_heads_bytes_are_added_once(family, heads):
+    """Outside the blocks a step holds one head's float32 logits and their
+    gradient; a prediction module's second head is run again in the
+    backward like the first, so one counts."""
+    if family == "two_heads":
+        from deepspeed_tpu.models import instella_moe_model
+        model = instella_moe_model("instella-tiny", dtype=jnp.float32)
+        assert model.config.mtp_layers == heads - 1
+    else:
+        model = _model(family, KEEP_PRODUCTS)
+    ids = jnp.zeros((2, SEQ), jnp.int32) % model.config.vocab_size
+    budget = Budget(None)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.float32))
+    jax.make_jaxpr(jax.grad(lambda p: model.loss(
+        p, {"input_ids": ids}, remat_budget=budget)))(params)
+    assert budget.outside_bytes == 2 * (2 * SEQ * model.config.vocab_size * 4)
+    assert budget.totals["outside_bytes"] == budget.outside_bytes
+    assert budget.totals["working_bytes"] == budget.outside_bytes + budget.block_bytes
+    assert budget.block_bytes > 0
+
+
+#: The four benchmark cells as the chip traced them (PERF.md, PR 35: the
+#: engine's log lines and ``remat_totals``): layers, one layer's input, the
+#: room the engine read (free less gradients, with the reference's signs
+#: resident), the working set as reckoned (a block's + outside the blocks),
+#: name -> bytes over all layers; and the group the decision must reach.
+CELLS = {
+    "gpt2-large.train.seq1k": (36, 10_489_860, 6_850_000_000, 587_672_606 + 1_646_821_376, {
+        "attn_lse": 11_796_480, "attn_o": 377_487_360, "fc_in": 1_509_949_440,
+        "o_proj": 377_487_360, "q_proj": 377_487_360, "k_proj": 377_487_360,
+        "v_proj": 377_487_360}, "v_proj"),
+    "olmoe-1b-7b.train.seq4k": (2, 16_793_608, 3_320_000_000, 2_587_466_536 + 1_648_361_472, {
+        "attn_lse": 524_288, "attn_o": 33_554_432, "moe_logits": 2_097_152,
+        "wi_gate": 134_217_728, "wi_up": 134_217_728, "wo": 268_435_456,
+        "o_proj": 33_554_432, "q_proj": 33_554_432, "k_proj": 33_554_432,
+        "v_proj": 33_554_432}, "v_proj"),
+    "instella-moe-16b-a3b.train.seq8k": (7, 134_250_504, 6_900_000_000, 5_241_973_156 + 2_111_832_064, {
+        "attn_lse": 7_340_032, "attn_o": 469_762_048, "moe_logits": 29_360_128,
+        "wi_gate": 322_961_408, "wi_up": 322_961_408, "wo": 1_056_964_608,
+        "gate_proj": 645_922_816, "up_proj": 645_922_816, "o_proj": 469_762_048,
+        "attn_gate": 469_762_048, "q_proj": 469_762_048, "kv_latent": 124_780_544,
+        "kv_up": 822_083_584}, "wo"),
+    "trinity-mini.train.seq16k": (6, 67_174_408, 6_890_000_000, 6_650_287_908 + 3_279_945_728, {
+        "attn_lse": 12_582_912, "attn_o": 805_306_368, "moe_logits": 50_331_648,
+        "wi_gate": 201_326_592, "wi_up": 201_326_592, "wo": 1_207_959_552,
+        "gate_proj": 201_326_592, "up_proj": 201_326_592, "o_proj": 402_653_184,
+        "attn_gate": 805_306_368, "q_proj": 805_306_368, "k_proj": 100_663_296,
+        "v_proj": 100_663_296}, "wi_up"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_four_cells_keep_what_the_chip_has_room_for(cell):
+    """The two short cells keep every name, as before PR 35; the two
+    long-sequence cells, which 128 layer inputs left with nothing, keep the
+    kernel's pair and the experts' first products (the Instella cell their
+    rows after the combine as well). No decision sits within
+    a twentieth of the room of a group's edge: the same names at 95 % and at
+    105 % of the reading, so what a run keeps does not hang on a few MB."""
+    layers, carry, room, working, candidates, reaches = CELLS[cell]
+    saved = choose_saved(candidates, saved_budget(room, layers, carry, working))
+    assert {"attn_lse", "attn_o"} <= set(saved) and saved[-1] == reaches
+    if reaches == "v_proj":
+        assert set(saved) == set(candidates)
+    for share in (0.95, 1.05):
+        assert choose_saved(candidates, saved_budget(
+            int(share * room), layers, carry, working)) == saved
+    # the rule PR 35 replaced: 128 more layer inputs before anything is kept
+    old = max(0, int((room - (layers + 128) * carry) / STACK_COST))
+    assert (choose_saved(candidates, old) == ()) == ("seq8k" in cell or "seq16k" in cell)
 
 
 @pytest.mark.parametrize("family", ["dense", "rope_gated", "moe_qk_norm"])
@@ -358,8 +564,14 @@ def test_engine_reads_its_room_from_the_device(eight_devices):
         lambda p: model.embed(p, ids, None) + (model._aux_zero(),),
         engine.state["params"]))
     wide = lambda width: 2 * 8 * 32 * width * 4      # 2 layers x [8, 32] x float32
+    # the working set, as a trace without an engine reckons it: the two
+    # float32 tables of [8, 32] x 256 logits outside the blocks, and a block
+    working = Budget(None)
+    jax.make_jaxpr(jax.grad(lambda p: model.loss(
+        p, {"input_ids": ids}, remat_budget=working)))(engine.state["params"])
+    assert working.outside_bytes == 2 * 8 * 32 * 256 * 4 < working.working_bytes
     # room for fc_in and o_proj, and not for the three projections
-    room = (2 + WORKING_CARRIES) * carry \
+    room = 2 * carry + int(np.ceil(WORKING_SHARE * working.working_bytes)) \
         + int(np.ceil((wide(512 + 128) + 8) * STACK_COST))
     room += -room % 8
     free = grads + room // 8
@@ -382,7 +594,9 @@ def test_engine_reads_its_room_from_the_device(eight_devices):
     engine.mesh = real
     engine.train_batch({"input_ids": ids})
     totals = engine.remat_totals
-    assert totals["budget_bytes"] == saved_budget(room, 2, carry)
+    assert totals["working_bytes"] == working.working_bytes \
+        == totals["block_bytes"] + totals["outside_bytes"]
+    assert totals["budget_bytes"] == saved_budget(room, 2, carry, totals["working_bytes"])
     assert totals["saved"] == ("fc_in", "o_proj")
     assert totals["saved_bytes"] == wide(640) <= totals["budget_bytes"] < wide(1024)
     assert totals["candidate_bytes"] == wide(1024)

@@ -1,0 +1,108 @@
+#!/usr/bin/env python3
+"""A training step's TRUE peak on the chip, which no allocator counter
+shows (PERF.md section 7, row 14): build a benchmark cell's engine as
+``benchmark/jobs/train.py`` does, run two steps, then fill the device with
+128 MiB arrays, one more before each further step, until a step fails.
+
+    chiprun -- python tools/remat_fill_probe.py --workload <cell> [--seed n]
+        [--room BYTES] [--rows r] [--seq s]
+
+``--room`` puts a number in the place of the engine's reading of the device
+(0: the blocks recomputed whole), ``--rows`` / ``--seq`` another batch than
+the cell's. One JSON line: what ``engine.remat_totals`` decided, the bytes
+in use at rest, and ``true_peak_bytes`` = ``bytes_limit`` less the largest
+filling under which a step still ran. This is how ``checkpointing.
+WORKING_SHARE`` and ``STACK_COST`` were fitted (PERF.md, PR 35).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+CHUNK = 128 << 20
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--room", type=int, default=None)
+    ap.add_argument("--rows", type=int, default=None)
+    ap.add_argument("--seq", type=int, default=None)
+    ap.add_argument("--manifest", default="BENCHMARK.json")
+    args = ap.parse_args()
+
+    from benchmark import harness, program, traffic
+    from benchmark.jobs import train
+    cell = harness.Cell(args.manifest, args.workload)
+    harness.place_compile_cache()
+    device = harness.require_device(cell)["devices"][0]
+    import jax
+    import jax.numpy as jnp
+
+    cfg, mix = cell.config, dict(cell.traffic)
+    settings = cfg["engine"]["train"]
+    if args.seq is not None:
+        mix["seq_len"] = args.seq
+    rows = args.rows or int(settings["ds_config"]["train_micro_batch_size_per_gpu"])
+    ds_config = dict(settings["ds_config"], train_micro_batch_size_per_gpu=rows)
+    ref = cell.load_module("reference", cfg["reference"])
+    adapter = cell.load_module("adapters", cfg["adapter"])
+    model = adapter.model(cfg, remat=settings["remat"], dtype=settings["param_dtype"])
+    weights = train.make_weights(ref, cfg, args.seed, settings["param_dtype"])
+    # what the job keeps on the device while the first step is traced: the
+    # reference's sign of every gradient element, one byte each
+    signs = jax.jit(lambda w: {k: jnp.zeros(v.shape, jnp.int8)
+                               for k, v in w.items()})(weights)
+    engine = program.train_engine(model, ds_config, adapter.to_program(weights),
+                                  args.seed)
+    del weights
+    if args.room is not None:
+        engine.__dict__["_remat_room_bytes"] = args.room
+    stream = traffic.train_batches(mix, args.seed, cfg["vocab_size"], rows)
+    in_use = lambda: int(device.memory_stats()["bytes_in_use"])
+    at_trace = in_use()
+    float(engine.train_batch(next(stream)))
+    del signs
+    float(engine.train_batch(next(stream)))
+    jax.block_until_ready(engine.state)
+    limit, at_rest = int(device.memory_stats()["bytes_limit"]), in_use()
+    filling, held, laps, failure = [], 0, [], None
+    while failure is None:
+        try:
+            filling.append(jax.block_until_ready(
+                jax.device_put(jnp.zeros((CHUNK,), jnp.uint8), device)))
+            t0 = time.perf_counter()
+            float(engine.train_batch(next(stream)))
+            laps.append(1e3 * (time.perf_counter() - t0))
+            held = CHUNK * len(filling)
+        except Exception as e:   # RESOURCE_EXHAUSTED, from the chunk or the step
+            failure = str(e).splitlines()[0][:200]
+    line = {
+        "workload": cell.name, "rows": rows, "seq": int(mix["seq_len"]),
+        "room_given": args.room,
+        "remat_totals": {k: list(v) if isinstance(v, tuple) else v
+                         for k, v in engine.remat_totals.items()},
+        "bytes_limit": limit, "in_use_at_trace": at_trace, "in_use_at_rest": at_rest,
+        "filled_bytes": held, "true_peak_bytes": limit - held,
+        "step_temporaries_bytes": limit - held - at_rest, "failure": failure,
+        "synced_step_ms": statistics.median(laps) if laps else None,
+    }
+    print(json.dumps(line), flush=True)
+    out = os.path.join(harness.CHECKOUT, "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "remat_fill_probe.jsonl"), "a") as f:
+        f.write(json.dumps(line) + "\n")
+    # the failed step may have taken the donated state with it: leave now
+    os._exit(0)
+
+
+if __name__ == "__main__":
+    main()

@@ -103,33 +103,15 @@ def emit(obj: Dict[str, Any]) -> None:
 # helpers
 # ---------------------------------------------------------------------------
 
-class CompileStats:
-    """Counts JAX's own compile events: seconds in the backend compiler and
-    persistent-cache hits/misses, read per phase."""
-
-    def __init__(self):
-        import jax.monitoring as mon
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        mon.register_event_duration_secs_listener(self._duration)
-        mon.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def take(self) -> Dict[str, Any]:
-        out = {"backend_compile_s": round(self.compile_s, 2),
-               "cache_hits": self.hits, "cache_misses": self.misses}
-        self.compile_s, self.hits, self.misses = 0.0, 0, 0
-        return out
+def rounded(value: Any) -> Any:
+    """``engine.setup_totals`` (or any nest of it) at a millisecond."""
+    if isinstance(value, float):
+        return round(value, 3)
+    if isinstance(value, dict):
+        return {k: rounded(v) for k, v in value.items()}
+    if isinstance(value, (list, tuple)):
+        return [rounded(v) for v in value]
+    return value
 
 
 def memory() -> Dict[str, Any]:
@@ -265,7 +247,7 @@ def phase_device(chips: int) -> Dict[str, Any]:
     }
 
 
-def phase_train(sz: Sizes, seed: int, stats: CompileStats) -> Dict[str, Any]:
+def phase_train(sz: Sizes, seed: int) -> Dict[str, Any]:
     import numpy as np
 
     import deepspeed_tpu
@@ -303,7 +285,10 @@ def phase_train(sz: Sizes, seed: int, stats: CompileStats) -> Dict[str, Any]:
         "steady_step_s": round(float(np.median(times[2:] or times)), 4),
         "fetch_after_block_until_ready_s": round(fetch, 5),
         "opt_kernel": kernel, "interpret": interpret,
-        **stats.take(), **memory(),
+        # where set-up went, by the engine's own account (import,
+        # initialize, every program's first call: trace, lower, compile or
+        # cache load, run; docs/OBSERVABILITY.md)
+        "setup_totals": rounded(engine.setup_totals), **memory(),
     }
     # a later run in the same checkout reports what the cache saved
     marker = os.path.join(enable_compile_cache(), "chip_smoke_train.json")
@@ -313,7 +298,8 @@ def phase_train(sz: Sizes, seed: int, stats: CompileStats) -> Dict[str, Any]:
     if os.path.isdir(os.path.dirname(marker)):
         with open(marker, "w") as f:
             json.dump({"first_step_s": line["first_step_s"],
-                       "backend_compile_s": line["backend_compile_s"]}, f)
+                       "compile_s": line["setup_totals"]["compile_s"],
+                       "cache_hits": line["setup_totals"]["cache_hits"]}, f)
     del engine
     return line
 
@@ -571,22 +557,28 @@ def _check_wave(sz: Sizes, rng) -> Dict[str, Any]:
             "interpret": not on_tpu(), "max_abs_err": {"out": err}}
 
 
-def phase_kernels(sz: Sizes, seed: int, stats: CompileStats) -> Dict[str, Any]:
+def phase_kernels(sz: Sizes, seed: int) -> Dict[str, Any]:
     import numpy as np
+
+    from deepspeed_tpu.telemetry import NULL_TELEMETRY, setup_spans
     rng = np.random.default_rng(seed + 1)
-    checks = [_check_flash(sz, rng, sz.flash),
-              # a stream of its own: the checks after it keep the draws
-              # (and the tight Adam tolerances) they were written against
-              _check_flash(sz, np.random.default_rng(seed + 2),
-                           sz.flash_train),
-              _check_opt_buckets(sz, rng),
-              _check_quant(sz, rng),
-              # `moe_train_model`'s dims (split FFN + token-major combine),
-              # then a small wave (the fused combine-scatter epilogue)
-              _check_moe(sz, rng, sz.moe[0]),
-              _check_moe(sz, rng, sz.moe_small_tokens),
-              _check_wave(sz, rng)]
-    return {"phase": "kernels", "checks": checks, **stats.take(), **memory()}
+    # no engine here: the phase whole is one first call of the program's
+    # one first-call mechanism, which says what JAX traced and compiled
+    with setup_spans.FirstCall("kernels", NULL_TELEMETRY) as compiled:
+        checks = [_check_flash(sz, rng, sz.flash),
+                  # a stream of its own: the checks after it keep the draws
+                  # (and the tight Adam tolerances) they were written against
+                  _check_flash(sz, np.random.default_rng(seed + 2),
+                               sz.flash_train),
+                  _check_opt_buckets(sz, rng),
+                  _check_quant(sz, rng),
+                  # `moe_train_model`'s dims (split FFN + token-major combine),
+                  # then a small wave (the fused combine-scatter epilogue)
+                  _check_moe(sz, rng, sz.moe[0]),
+                  _check_moe(sz, rng, sz.moe_small_tokens),
+                  _check_wave(sz, rng)]
+    return {"phase": "kernels", "checks": checks,
+            "compiled": rounded(compiled.numbers), **memory()}
 
 
 def moe_train_model():
@@ -614,8 +606,7 @@ def moe_train_config() -> Dict[str, Any]:
     }
 
 
-def phase_train_moe(sz: Sizes, seed: int, stats: CompileStats,
-                    model=None, micro: int = 8, seq: int = 1024
+def phase_train_moe(sz: Sizes, seed: int, model=None, micro: int = 8, seq: int = 1024
                     ) -> Dict[str, Any]:
     import jax.numpy as jnp
     import numpy as np
@@ -641,6 +632,7 @@ def phase_train_moe(sz: Sizes, seed: int, stats: CompileStats,
                for _ in range(sz.moe_steps)]
     losses, times, _ = run_steps(engine, batches)
     assert_finite(losses, "train-moe losses")
+    setup_totals = rounded(engine.setup_totals)
     del engine
     return {"phase": "train-moe", "layers": c.num_layers,
             "hidden": c.hidden_size, "experts": c.moe.num_experts,
@@ -649,10 +641,10 @@ def phase_train_moe(sz: Sizes, seed: int, stats: CompileStats,
             "losses": [round(v, 4) for v in losses],
             "first_step_s": round(times[0], 2),
             "steady_step_s": round(float(np.median(times[1:])), 4),
-            **stats.take(), **memory()}
+            "setup_totals": setup_totals, **memory()}
 
 
-def phase_serve(sz: Sizes, seed: int, stats: CompileStats) -> Dict[str, Any]:
+def phase_serve(sz: Sizes, seed: int) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -730,7 +722,10 @@ def phase_serve(sz: Sizes, seed: int, stats: CompileStats) -> Dict[str, Any]:
                           f"{jnp.dtype(cfg.kv_cache_dtype).name} "
                           f"{'==' if kv_same else '!='} compute {dt.name}; "
                           f"learned positions (no ALiBi, no window)",
-        **stats.take(), **memory(),
+        # which bucket compiled, and what each first call's seconds went on
+        "first_calls": {f"{program}{list(key)}": rounded(numbers) for
+                        (program, key), numbers in engine.seen_buckets().items()},
+        **memory(),
     }
     del engine, sched
     return line
@@ -751,8 +746,7 @@ def capture_fd2():
         os.close(old)
 
 
-def phase_multichip(sz: Sizes, seed: int, stats: CompileStats
-                    ) -> Dict[str, Any]:
+def phase_multichip(sz: Sizes, seed: int) -> Dict[str, Any]:
     import jax
     import numpy as np
     from jax.experimental import mesh_utils
@@ -778,9 +772,9 @@ def phase_multichip(sz: Sizes, seed: int, stats: CompileStats
         model=model, config=train_config(sz, micro * n, {"stage": 1}),
         seed=seed, topology=MeshTopology(TopologyConfig(), devices=devs[:1]))
     ref_losses, _, _ = run_steps(ref_engine, batches)
+    ref_setup = rounded(ref_engine.setup_totals)
     del ref_engine
     release()
-    ref_compile = stats.take()
 
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=model, config=train_config(sz, micro, zero3), seed=seed)
@@ -845,8 +839,8 @@ def phase_multichip(sz: Sizes, seed: int, stats: CompileStats
                                    for d, s in shares.items()},
         "first_step_s": round(times[0], 2),
         "steady_step_s": round(float(np.median(times[1:])), 4),
-        "one_device_compile": ref_compile,
-        **stats.take(), **memory(),
+        "one_device_setup_totals": ref_setup,
+        "setup_totals": rounded(engine.setup_totals), **memory(),
     }
     del engine
     return line
@@ -869,15 +863,13 @@ def main() -> int:
         return 1
 
     sz = Sizes()
-    stats = CompileStats()
     emit(phase_device(args.chips))
     if args.chips == 4:
         phases = (phase_multichip,)
     else:
         phases = (phase_train, phase_kernels, phase_train_moe, phase_serve)
     for phase in phases:
-        stats.take()
-        emit(phase(sz, args.seed, stats))
+        emit(phase(sz, args.seed))
         release()
     emit({"ok": True, "device": {"platform": d0.platform,
                                  "kind": d0.device_kind,

@@ -15,9 +15,13 @@ Front door (reference ``deepspeed/__init__.py:64``):
 
 from __future__ import annotations
 
-import json
-import os
-from typing import Any, Dict, Optional, Tuple
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()   # set-up's first phase: this import
+
+import json  # noqa: E402
+import os  # noqa: E402
+from typing import Any, Dict, Optional, Tuple  # noqa: E402
 
 __version__ = "0.1.0"
 
@@ -28,6 +32,11 @@ from .runtime.engine import DeepSpeedEngine  # noqa: F401
 from .runtime.topology import MeshTopology, TopologyConfig  # noqa: F401
 from .comm.comm import init_distributed  # noqa: F401
 from .utils.compile_cache import enable_compile_cache  # noqa: F401
+from .telemetry import setup_spans as _setup_spans
+
+# ``engine.setup_totals["import_s"]``: this package's import less whatever
+# the process had imported before it (docs/OBSERVABILITY.md)
+_setup_spans.import_s = _time.perf_counter() - _IMPORT_T0
 
 
 def maybe_apply_tuned_config(config: Optional[Any]) -> Optional[Any]:
@@ -65,36 +74,9 @@ def maybe_apply_tuned_config(config: Optional[Any]) -> Optional[Any]:
     return merged
 
 
-def initialize(args=None,
-               model=None,
-               optimizer=None,
-               model_parameters=None,
-               training_data=None,
-               lr_scheduler=None,
-               distributed_port: int = 29500,
-               topology: Optional[MeshTopology] = None,
-               dist_init_required: Optional[bool] = None,
-               collate_fn=None,
-               config: Optional[Any] = None,
-               config_params: Optional[Dict[str, Any]] = None,
-               seed: int = 42):
-    """Build a ready-to-train engine (reference ``deepspeed.initialize``,
-    ``deepspeed/__init__.py:64``).
-
-    ``model`` is a module object exposing ``init(rng, dtype) -> params``,
-    ``specs() -> PartitionSpec tree``, ``loss(params, batch) -> scalar``
-    (e.g. ``deepspeed_tpu.models.TransformerLM``). Returns the same 4-tuple
-    as the reference: (engine, optimizer_descriptor, dataloader, lr_scheduler).
-    """
-    assert model is not None, "deepspeed_tpu.initialize: model is required"
-    config = config if config is not None else config_params
-    if isinstance(config, str):  # JSON path (reference-supported form)
-        with open(config) as f:
-            config = json.load(f)
-    # DSTPU_TUNE overlay: off (unset/"0") this returns `config` itself —
-    # engine construction stays byte-identical to an autotuner-free build
-    config = maybe_apply_tuned_config(config)
-
+def _initialize_engine(config, model, topology, seed, model_parameters):
+    """``initialize``'s engine: the compile cache, the distributed start,
+    the engine class the configuration asks for and an elastic resume."""
     enable_compile_cache()
     init_distributed()
 
@@ -131,6 +113,44 @@ def initialize(args=None,
             + (f"resumed tag {tag} at step {engine.global_steps}" if tag
                else "no committed checkpoint yet — fresh start")
             + f" (dir {_ckpt_dir})", ranks=[0])
+    return engine
+
+
+def initialize(args=None,
+               model=None,
+               optimizer=None,
+               model_parameters=None,
+               training_data=None,
+               lr_scheduler=None,
+               distributed_port: int = 29500,
+               topology: Optional[MeshTopology] = None,
+               dist_init_required: Optional[bool] = None,
+               collate_fn=None,
+               config: Optional[Any] = None,
+               config_params: Optional[Dict[str, Any]] = None,
+               seed: int = 42):
+    """Build a ready-to-train engine (reference ``deepspeed.initialize``,
+    ``deepspeed/__init__.py:64``).
+
+    ``model`` is a module object exposing ``init(rng, dtype) -> params``,
+    ``specs() -> PartitionSpec tree``, ``loss(params, batch) -> scalar``
+    (e.g. ``deepspeed_tpu.models.TransformerLM``). Returns the same 4-tuple
+    as the reference: (engine, optimizer_descriptor, dataloader, lr_scheduler).
+    """
+    assert model is not None, "deepspeed_tpu.initialize: model is required"
+    config = config if config is not None else config_params
+    if isinstance(config, str):  # JSON path (reference-supported form)
+        with open(config) as f:
+            config = json.load(f)
+    # DSTPU_TUNE overlay: off (unset/"0") this returns `config` itself —
+    # engine construction stays byte-identical to an autotuner-free build
+    config = maybe_apply_tuned_config(config)
+
+    # the whole call as one span; the engine built inside takes the span's
+    # record as its ``setup_totals`` (telemetry/setup_spans.py)
+    with _setup_spans.initializing():
+        engine = _initialize_engine(config, model, topology, seed,
+                                    model_parameters)
 
     dataloader = None
     if training_data is not None:

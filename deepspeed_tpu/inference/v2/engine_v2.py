@@ -32,8 +32,8 @@ from ... import comm as dist
 from ...models.transformer import TransformerLM
 from ...runtime.topology import (DATA_AXIS, MODEL_AXIS, MeshTopology,
                                  TopologyConfig)
-from ...telemetry import clock, get_telemetry
-from ...telemetry.trace import PHASE_SERVING
+from ...telemetry import get_telemetry, setup_spans
+from ...telemetry.trace import NULL_SPAN, PHASE_SERVING
 from ...utils.compile_cache import enable_compile_cache
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
@@ -256,7 +256,7 @@ class InferenceEngineV2:
         # Plain integer adds, kept with telemetry on or off.
         self.wave_totals: Dict[str, int] = dict.fromkeys(COUNTER_KEYS, 0)
         self.last_counters: Dict[str, Any] = {}
-        self._seen_buckets: Dict[Tuple[str, Tuple[int, ...]], float] = {}
+        self._seen_buckets: Dict[Tuple[str, Tuple[int, ...]], Dict[str, Any]] = {}
         log_dist(
             f"InferenceEngineV2: {num_blocks} KV blocks × {block_size} tokens "
             f"({self.kv_cache.mem_bytes() / 2**20:.0f} MiB"
@@ -726,26 +726,28 @@ class InferenceEngineV2:
                   uids: Sequence[int], call, **args):
         """Descriptor upload and the call, as one ``wave.dispatch`` span.
         The first call of a bucket key traces and compiles (or loads the
-        compile cache): an instant ``compile:<program>`` says which key and
-        how long that call took."""
+        compile cache), so it goes through the repository's one first-call
+        door (``telemetry/setup_spans.py``): a ``first_call`` span around
+        it and an instant ``compile:<program>`` that says which key and
+        what the call's seconds went on."""
         tele = get_telemetry()
-        first = (program, key) not in self._seen_buckets
-        t0 = clock.now()
+        seen = self._seen_buckets
+        first = (setup_spans.FirstCall(program, tele, PHASE_SERVING, key=list(key))
+                 if (program, key) not in seen else NULL_SPAN)
         with tele.phase("wave.dispatch", phase=PHASE_SERVING, req=uids,
-                        program=program, **args):
+                        program=program, **args), first:
             with self.mesh:
                 out = call()
-        if first:
-            secs = self._seen_buckets[(program, key)] = clock.now() - t0
-            tele.instant(f"compile:{program}", phase=PHASE_SERVING,
-                         key=list(key), seconds=round(secs, 4))
+        if first is not NULL_SPAN:
+            seen[(program, key)] = first.numbers
         return out
 
-    def seen_buckets(self) -> Dict[Tuple[str, Tuple[int, ...]], float]:
+    def seen_buckets(self) -> Dict[Tuple[str, Tuple[int, ...]], Dict[str, Any]]:
         """Every ``(program, bucket key)`` dispatched so far (wave ``(N, A,
         MP, R)``, burst ``(B, mp, k)``, legacy ragged ``(Bd, mpd, Sp, T,
-        mpp)``) with the seconds its first call took: which step
-        recompiled, answered by the program."""
+        mpp)``) with what its first call took (``wall_s`` and, of it,
+        ``trace_s``, ``lower_s``, ``compile_s``, ``run_s``; ``cache``):
+        which step recompiled, answered by the program."""
         return dict(self._seen_buckets)
 
     def can_burst(self, batch_uids: Sequence[int], num_steps: int) -> bool:

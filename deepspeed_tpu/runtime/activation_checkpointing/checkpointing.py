@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.extend.core import Literal, jaxpr_as_fun
 
+from ...telemetry import setup_spans
 from ..topology import MODEL_AXIS
 
 _CONFIG = {
@@ -341,12 +342,16 @@ class _KeptBlock:
                tuple((x.shape, str(x.dtype)) for x in args))
         if key not in self._traced:
             closed, out = jax.make_jaxpr(self.block_fn, return_shape=True)(carry, layer)
-            both = jax.make_jaxpr(_backward_of(jaxpr_as_fun(closed)))(*args).jaxpr
-            self._traced[key] = closed, out, named_bytes(both)
-            if self.budget is not None:
-                self.budget.block_bytes = max(
-                    self.budget.block_bytes,
-                    _bytes((args, out)) + live_bytes(both))
+            # the block's own trace, above, the step needs anyway; what the
+            # plan adds to it is a span (``setup_totals["remat_plan_s"]``;
+            # the choice itself, in __call__, is a few dict operations)
+            with setup_spans.remat_plan():
+                both = jax.make_jaxpr(_backward_of(jaxpr_as_fun(closed)))(*args).jaxpr
+                self._traced[key] = closed, out, named_bytes(both)
+                if self.budget is not None:
+                    self.budget.block_bytes = max(
+                        self.budget.block_bytes,
+                        _bytes((args, out)) + live_bytes(both))
         return self._traced[key]
 
     def __call__(self, carry, layer):

@@ -33,6 +33,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import inspect
+import json
 import os
 import tempfile
 from typing import Any, Dict, Iterable, Optional, Tuple
@@ -46,6 +47,7 @@ from ..resilience.fault_plan import (GUARDIAN_EXIT_CODE, STALL_EXIT_CODE,
                                      fault_point, maybe_install_from_env,
                                      parse_elastic_env)
 from ..resilience.guardian import build_guardian, pack_anomaly_word
+from ..telemetry import NULL_TELEMETRY, setup_spans
 from ..utils.logging import log_dist, logger
 from ..utils.scope import scoped
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER,
@@ -62,6 +64,15 @@ from .topology import (BATCH_AXES, DATA_AXIS, SEQ_AXIS, MeshTopology,
 from .zero.partition import ZeroPartitionPlan
 
 DATA_SPEC = P(BATCH_AXES)  # batches shard their leading dim over both dp axes
+
+# Is a profiler session running? One flag test (jaxlib's TraceMe); where a
+# jaxlib lacks it, the step writes its ``engine_totals`` every time.
+try:
+    from jax._src.lib import _profiler
+except ImportError:  # pragma: no cover - another jaxlib layout
+    _profiler = None
+_profiler_on = getattr(getattr(_profiler, "TraceMe", None), "is_enabled",
+                       lambda: True)
 
 
 def _norm_dt(value) -> str:
@@ -83,6 +94,24 @@ class DeepSpeedEngine:
                  topology: Optional[MeshTopology] = None,
                  seed: int = 42,
                  init_params: Optional[Any] = None):
+        # Set-up's own account (telemetry/setup_spans.py), plain values kept
+        # with telemetry off: the seconds of the import, of ``initialize``
+        # and its parts, of every program's first call by what it was spent
+        # on (trace, lower, compile or cache load, run), and what compiled
+        # after set-up. docs/OBSERVABILITY.md has it key by key.
+        self._setup = setup_spans.take()
+        self.setup_totals = self._setup.totals
+        self._totals_flat = (-1, {})    # (record version, ``engine_totals``)
+        self.telemetry = NULL_TELEMETRY     # until _build_telemetry
+        with self._setup.parts() as part:
+            self._construct(part, model, config, config_dict, topology, seed,
+                            init_params)
+
+    def _construct(self, part, model, config, config_dict, topology, seed,
+                   init_params):
+        """The constructor's body; ``part(name)`` opens the next of
+        set-up's spans (``setup_spans.INITIALIZE_PARTS``)."""
+        part("config_topology")
         if config is None:
             # topology must exist before batch resolution
             topo_cfg = (config_dict or {}).get("topology", {})
@@ -298,6 +327,7 @@ class DeepSpeedEngine:
         self._overlap_fallback = ""       # reason the overlap path was skipped
 
         # -- ZeRO plan -------------------------------------------------------
+        part("zero_plan")
         param_specs = model.specs()
         shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), self.param_dtype))
         self._param_struct = shapes  # abstract param tree, reused throughout
@@ -344,6 +374,7 @@ class DeepSpeedEngine:
         # the empty tree with the fresh gradients instead of accumulating —
         # params + grad buffer + fresh grads would be 3x model bytes, the
         # difference between a 3B step compiling on one chip and OOM)
+        part("init_state")
         self._gradacc_lazy = (
             config.gradient_accumulation_steps == 1
             and not self._explicit_micro
@@ -377,6 +408,7 @@ class DeepSpeedEngine:
         else:
             self.state = self._init_state(seed, init_params)
 
+        part(None)
         # -- bookkeeping -----------------------------------------------------
         self.global_steps = 0
         self.skipped_steps = 0
@@ -590,6 +622,8 @@ class DeepSpeedEngine:
         tele = build_telemetry(cfg, sinks=sinks)
         if not tele.enabled:
             return tele
+        # set-up's spans that closed before this recorder existed
+        self._setup.replay(tele)
         if tele.flush_every <= 1 and (cfg is None or not cfg.flush_interval):
             tele.flush_every = max(1, self.config.steps_per_print)
         if jax.process_index() == 0:
@@ -629,13 +663,7 @@ class DeepSpeedEngine:
                 # the guardian-armed fused jit takes the spike threshold
                 # as a 4th (host-scalar) argument
                 args = args + (jax.ShapeDtypeStruct((), jnp.float32),)
-            abstract = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
-            cost = self._jit_train_step.lower(
-                *abstract).compile().cost_analysis()
-            if isinstance(cost, list):
-                cost = cost[0] if cost else {}
-            flops = float(cost.get("flops", 0.0))
+            flops = self._program_flops(self._jit_train_step, args)
         else:
             self._build_jits()
             flops = self._micro_step_flops(self._last_prepared_batch) \
@@ -643,6 +671,24 @@ class DeepSpeedEngine:
         if flops <= 0:
             raise RuntimeError("cost analysis returned no flops")
         return flops
+
+    def _program_flops(self, jitted, args) -> float:
+        """XLA's count of the FLOPs of ``jitted`` at ``args``' shapes, over
+        the whole mesh: the cost analysis of the LOWERED module, which
+        compiles nothing. A backend that cannot analyse a module it has not
+        compiled (the TPU's client answers None) gets the compile, as a
+        ``first_call`` of the program ``flops_probe`` so that it shows; the
+        compiled module counts one device's share."""
+        lowered = jitted.lower(*jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+        cost = lowered.cost_analysis()
+        if cost and cost.get("flops", 0.0) > 0:
+            return float(cost["flops"])
+        with self._first_call("flops_probe"):
+            cost = lowered.compile().cost_analysis()
+        if isinstance(cost, list):
+            cost = cost[0] if cost else {}
+        return float(cost.get("flops", 0.0)) * self.mesh.size
 
     # ------------------------------------------------------------------
     # 1-bit optimizer construction
@@ -794,10 +840,12 @@ class DeepSpeedEngine:
                     "opt": make_opt(p),
                     "loss_scale": self._loss_scale_state(),
                 }
-                state = jax.jit(make, out_shardings=shardings)(params)
+                with self._first_call("init_state"):
+                    state = jax.jit(make, out_shardings=shardings)(params)
             else:
                 rng = jax.random.PRNGKey(seed)
-                state = jax.jit(make_state, out_shardings=shardings)(rng)
+                with self._first_call("init_state"):
+                    state = jax.jit(make_state, out_shardings=shardings)(rng)
         if offload:
             log_dist("state initialized; building offload runner", ranks=[0])
             self._init_offload_runner(state)
@@ -2225,7 +2273,8 @@ class DeepSpeedEngine:
         lr = jnp.asarray(self.lr_scheduler.get_lr(), jnp.float32)
         anomaly = None
         with self.telemetry.phase("fused_dispatch", phase="step",
-                                  step=self.global_steps):
+                                  step=self.global_steps), \
+                self._first_call("train_step", batch):
             with self.mesh:
                 if self._guardian is not None:
                     thresh = jnp.asarray(self._guardian.spike_threshold(),
@@ -2245,7 +2294,45 @@ class DeepSpeedEngine:
         with self.telemetry.phase("post_step", phase="step",
                                   step=self.global_steps):
             self._post_step(overflow, gnorm, anomaly=anomaly, loss=loss)
+        self._after_step()
         return loss
+
+    def _first_call(self, program: str, batch=None):
+        """The first-call door (``setup_spans.SetupTotals.first_call``): a
+        ``first_call`` span around the call that compiles ``program`` for
+        shapes not met before, a constant no-op on every later call. A
+        program compiled after set-up comes through here too."""
+        key = () if batch is None else tuple(v.shape for v in batch.values())
+        return self._setup.first_call(program, key, self.telemetry)
+
+    def _drop_jits(self, *names: str) -> None:
+        """Forget jitted programs whose captured shardings or donated
+        buffers are stale; each one's next call is a first call again."""
+        for name in names:
+            setattr(self, f"_jit_{name}", None)
+        self._setup.seen = {k: v for k, v in self._setup.seen.items()
+                            if k[0] not in names}
+
+    def _after_step(self) -> None:
+        """The end of an optimizer step: set-up ends with the first one, and
+        while a profiler session is running the engine's counters go into
+        its trace as one zero-length ``engine_totals`` annotation, a stat a
+        scalar (docs/OBSERVABILITY.md)."""
+        if self._setup.open:
+            self._setup.finish()
+            log_dist("set-up: " + json.dumps(self.setup_totals), ranks=[0])
+        if _profiler_on():
+            version, flat = self._totals_flat
+            if version != self._setup.version:
+                flat = setup_spans.flat_totals(
+                    setup=self.setup_totals, moe=self.moe_totals,
+                    attn=self.attn_totals, opt_kernel=self.opt_kernel_totals,
+                    remat=self.remat_totals)
+                self._totals_flat = (self._setup.version, flat)
+            if "moe.steps" in flat:     # the one counter a step writes
+                flat["moe.steps"] = self.moe_totals["steps"]
+            with jax.profiler.TraceAnnotation("engine_totals", **flat):
+                pass
 
     def _experts_of_model(self) -> Dict[str, int]:
         """``experts_published`` and ``experts_held`` of the model's expert
@@ -2393,9 +2480,7 @@ class DeepSpeedEngine:
                     lambda x: jnp.zeros(x.shape, self.grad_dtype), p),
                 out_shardings=self._grad_shardings)(self.state["params"])
         self._cached_shardings = None
-        self._jit_train_step = None
-        self._jit_micro_step = None
-        self._jit_apply_step = None
+        self._drop_jits("train_step", "micro_step", "apply_step")
 
     def _reject_paged(self, op: str) -> None:
         if self._param_stream is not None:
@@ -2424,7 +2509,8 @@ class DeepSpeedEngine:
                     payload=self._inject_numerics_fault)
         self.timers(FORWARD_GLOBAL_TIMER).start()
         with self.telemetry.phase("micro_dispatch", phase="fwd",
-                                  step=self.global_steps):
+                                  step=self.global_steps), \
+                self._first_call("micro_step", batch):
             with self.mesh:
                 if self._explicit_micro:
                     if getattr(self, "_ef_carry_active", False):
@@ -2472,7 +2558,8 @@ class DeepSpeedEngine:
         lr = jnp.asarray(self.lr_scheduler.get_lr(), jnp.float32)
         anomaly = None
         with self.telemetry.phase("apply_step", phase="optimizer",
-                                  step=self.global_steps):
+                                  step=self.global_steps), \
+                self._first_call("apply_step"):
             if self._offload is not None:
                 overflow, gnorm = self._apply_step_offload(float(lr))
                 if self._guardian is not None:
@@ -2493,6 +2580,7 @@ class DeepSpeedEngine:
         with self.telemetry.phase("post_step", phase="step",
                                   step=self.global_steps):
             self._post_step(overflow, gnorm, anomaly=anomaly)
+        self._after_step()
 
     def _post_step(self, overflow, gnorm, anomaly=None, loss=None) -> None:
         """Host-side bookkeeping after the optimizer update (shared by the
@@ -3121,7 +3209,7 @@ class DeepSpeedEngine:
         return jnp.mean(jnp.stack(losses))
 
     def _micro_step_flops(self, batch) -> float:
-        """XLA's exact cost analysis of the compiled micro-step (the
+        """XLA's cost analysis of the micro-step (``_program_flops``; the
         hook-based estimate of the reference's profiler.py:228). ``batch``
         leaves may be arrays or ``ShapeDtypeStruct``s (the telemetry MFU
         path keeps only the abstract batch)."""
@@ -3135,12 +3223,7 @@ class DeepSpeedEngine:
                         self._secondary, dev_batch)
             else:
                 args = (self.state, dev_batch)
-            abstract = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
-            cost = self._jit_micro_step.lower(*abstract).compile().cost_analysis()
-            if isinstance(cost, list):
-                cost = cost[0] if cost else {}
-            return float(cost.get("flops", 0.0))
+            return self._program_flops(self._jit_micro_step, args)
         except Exception:
             return 0.0
 
@@ -3154,7 +3237,7 @@ class DeepSpeedEngine:
             self._jit_eval = jax.jit(self.model.loss)
         self._validate_batch(batch)
         batch = self._device_batch(batch)
-        with self.mesh:
+        with self._first_call("eval", batch), self.mesh:
             return self._jit_eval(self.state["params"], batch)
 
     # ------------------------------------------------------------------
@@ -3263,8 +3346,7 @@ class DeepSpeedEngine:
         self._pcache = {"treedef": treedef, "meta": meta}
         self.state["params"] = None
         # old programs captured donated buffers — both step entry points
-        self._jit_micro_step = None
-        self._jit_train_step = None
+        self._drop_jits("micro_step", "train_step")
 
     def reload_param_cache(self) -> None:
         """Rebuild the device-sharded param tree from the paged shards."""

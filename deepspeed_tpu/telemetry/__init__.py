@@ -21,4 +21,6 @@ from .telemetry import (NULL_TELEMETRY, JsonlMetricsSink, NullTelemetry,  # noqa
                         maybe_enable_from_env, reset_telemetry, set_telemetry)
 from .trace import (PHASE_BWD, PHASE_CHECKPOINT, PHASE_DATA,  # noqa: F401
                     PHASE_FWD, PHASE_GATHER, PHASE_OPTIMIZER, PHASE_OTHER,
-                    PHASE_SCATTER, PHASE_SERVING, PHASE_STEP, TraceRecorder)
+                    PHASE_SCATTER, PHASE_SERVING, PHASE_SETUP, PHASE_STEP,
+                    TraceRecorder)
+from . import setup_spans  # noqa: F401,E402  (registers JAX's listeners, once)

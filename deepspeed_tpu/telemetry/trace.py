@@ -54,6 +54,7 @@ PHASE_OPTIMIZER = "optimizer"    # apply/optimizer dispatch
 PHASE_CHECKPOINT = "checkpoint"  # save/load, incl. async write-behind
 PHASE_SERVING = "serving"        # inference wave/dispatch
 PHASE_OFFLOAD = "offload"        # out-of-core optimizer step pipeline
+PHASE_SETUP = "setup"            # initialize and every program's first call
 PHASE_OTHER = "other"
 
 # collective op -> phase attribution for comm records
@@ -164,13 +165,24 @@ class TraceRecorder:
                 **({"args": span.args} if span.args else {}),
             })
 
+    def start_no_later_than(self, t: float) -> None:
+        """Move the epoch back to ``t`` (a ``clock.now()`` reading) while
+        nothing is recorded yet: set-up's first phases close before the
+        engine has built its recorder, and are written into it afterwards
+        (``setup_spans.SetupTotals.replay``)."""
+        with self._lock:
+            if not self._events:
+                self._epoch = min(self._epoch, t)
+
     def complete_span(self, name: str, phase: str, dur: float,
-                      step: Optional[int] = None, **args) -> None:
+                      step: Optional[int] = None, end: Optional[float] = None,
+                      **args) -> None:
         """Record an already-measured interval as a span (duration events
         accumulated across a step — the offload pipeline's per-phase
         seconds land here post-hoc rather than as hundreds of per-bucket
-        live spans). ``ts`` is backdated so the span ends 'now'."""
-        t = clock.now()
+        live spans). ``ts`` is backdated so the span ends at ``end`` (a
+        ``clock.now()`` reading; 'now' when None)."""
+        t = clock.now() if end is None else end
         with self._lock:
             self._push({
                 "kind": "span", "name": name, "phase": phase,

@@ -271,11 +271,6 @@ TINY = chip_smoke.Sizes(
     token_budget=64, stagger_s=0.001, multichip_steps=2)
 
 
-@pytest.fixture
-def stats():
-    return chip_smoke.CompileStats()
-
-
 class TestSmokePhasesOnCpu:
 
     def test_device(self, eight_devices):
@@ -285,34 +280,45 @@ class TestSmokePhasesOnCpu:
         with pytest.raises(AssertionError, match="asked for 3"):
             chip_smoke.phase_device(3)
 
-    def test_train(self, stats):
-        line = chip_smoke.phase_train(TINY, 0, stats)
+    def test_train(self):
+        line = chip_smoke.phase_train(TINY, 0)
         assert line["losses"][-1] < line["losses"][0]
         assert len(line["losses"]) == TINY.train_steps
+        # the engine's own account of set-up stands where the smoke's copy
+        # of the compile counters stood
+        setup = line["setup_totals"]
+        assert {"init_state", "train_step"} <= set(setup["programs"])
+        assert setup["programs_compiled"] == 2 and setup["compile_s"] > 0
+        assert setup["compiled_after_setup"] == 0
 
-    def test_kernels(self, stats):
-        line = chip_smoke.phase_kernels(TINY, 0, stats)
+    def test_kernels(self):
+        line = chip_smoke.phase_kernels(TINY, 0)
+        assert line["compiled"]["compile_s"] > 0 and line["compiled"]["trace_s"] > 0
         assert [c["kernel"].split()[0] for c in line["checks"]] == [
             "flash", "flash", "adam/lion", "quantize_rows_int8", "moe", "moe",
             "ragged"]
         assert all(c["interpret"] for c in line["checks"])  # CPU backend
 
-    def test_train_moe(self, stats):
+    def test_train_moe(self):
         from deepspeed_tpu.models import mixtral_model
         model = mixtral_model("mixtral-tiny", dtype=BF16, remat=False,
                               max_seq_len=64, vocab_size=512)
-        line = chip_smoke.phase_train_moe(TINY, 0, stats, model=model,
+        line = chip_smoke.phase_train_moe(TINY, 0, model=model,
                                           micro=1, seq=32)
+        assert "train_step" in line["setup_totals"]["programs"]
         assert line["moe_kernel_resolution"].startswith("xla")  # CPU mesh
         assert len(line["losses"]) == TINY.moe_steps
 
-    def test_serve(self, stats):
-        line = chip_smoke.phase_serve(TINY, 0, stats)
+    def test_serve(self):
+        line = chip_smoke.phase_serve(TINY, 0)
+        assert line["first_calls"] and all(
+            c["wall_s"] > 0 for c in line["first_calls"].values())
         assert line["new_tokens"] == [TINY.max_new] * TINY.n_requests
         assert line["prefill_logits_rel_err"] <= line["tolerance"]
 
-    def test_multichip(self, eight_devices, stats):
-        line = chip_smoke.phase_multichip(TINY, 0, stats)
+    def test_multichip(self, eight_devices):
+        line = chip_smoke.phase_multichip(TINY, 0)
+        assert line["setup_totals"]["programs_compiled"] >= 2
         assert line["dp"] == len(jax.devices()) and line["overlap_active"]
         assert line["losses"] == pytest.approx(line["one_device_losses"],
                                                rel=0.05, abs=0.05)

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ..ops.transformer import pallas_gmm
+from ..ops.transformer import pallas_gmm, pallas_segment_sum
 from ..runtime import topology as topo_mod
 from ..runtime.topology import BATCH_AXES, DATA_AXIS, EXPERT_AXIS
 from ..utils.jax_compat import with_sharding_constraint
@@ -132,51 +132,75 @@ _unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
 # A chip that holds some of the experts sorts the assignments of ITS experts
 # first (``order``: held assignments by expert, then the absent ones) and
 # moves the first ``cap`` sorted rows alone; ``held`` counts the real ones.
+# Back to the tokens, both movements go over the buffer's ``cap`` rows and
+# never over ``tokens x top_k``: ``by_token`` lists the buffer's assignments in
+# token order and ``perm`` the buffer row of each (``_token_order``), so ONE
+# gather brings the rows into token order and a sorted segment sum
+# (``pallas_segment_sum``) adds each token's.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch_held_rows(tokens, order, inv, held, top_k):
-    """[T, h] -> [cap, h] (``order`` has ``cap`` entries): row ``i`` is the
-    token of sorted assignment ``i``. Backward: each token's held rows,
-    found by ``inv`` (< ``held``), summed."""
-    return tokens.at[order // top_k].get(mode="promise_in_bounds")
+def _token_order(order, held):
+    """-> (perm, by_token), ``cap`` int32 each: the buffer's first ``held``
+    rows in token order. ``order`` is by expert and inside an expert by
+    token, so its first ``held`` assignments SORTED are the held assignments
+    token-major: one sort of ``cap`` keys with the row as its payload (a
+    sort is the cheapest index operation the TPU has: a scatter or a gather
+    of as many scalars costs ten times as much). ``by_token[i]`` is the
+    assignment and ``perm[i]`` its row of the buffer; from ``held`` on
+    ``perm`` lists the unfilled rows and ``by_token`` reads 0."""
+    at = jnp.arange(order.shape[0], dtype=jnp.int32)
+    real = at < held
+    by_token, perm = jax.lax.sort(
+        (jnp.where(real, order, jnp.iinfo(jnp.int32).max), at), num_keys=1)
+    return perm, jnp.where(real, by_token, 0)
 
 
-def _dispatch_held_rows_fwd(tokens, order, inv, held, top_k):
-    return _dispatch_held_rows(tokens, order, inv, held, top_k), (inv, held)
+def _sum_held_rows(rows, scale, perm, by_token, held, n_tok, top_k):
+    """[cap, h] rows of the buffer -> [T, h] float32: each token's rows
+    among the first ``held``, times ``scale`` ([cap] float32 in token order;
+    None: as they are), summed."""
+    in_token_order = rows.at[perm].get(mode="promise_in_bounds", unique_indices=True)
+    return pallas_segment_sum.segment_sum(in_token_order, by_token // top_k, scale,
+                                          held, n_tok, _mesh_devices())
 
 
-def _dispatch_held_rows_bwd(top_k, res, g):
-    inv, held = res
-    picked = g.at[jnp.minimum(inv, g.shape[0] - 1)].get(mode="promise_in_bounds")
-    picked = jnp.where((inv < held)[:, None], picked.astype(jnp.float32), 0.0)
-    d = jnp.sum(picked.reshape(-1, top_k, g.shape[-1]), axis=1)
-    return d.astype(g.dtype), None, None, None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _dispatch_held_rows(tokens, order, perm, by_token, held, shape):
+    """[T, h] -> [cap, h] (``order`` has ``cap`` entries; ``shape`` = (T,
+    top_k)): row ``i`` is the token of sorted assignment ``i``. Backward:
+    each token's held rows summed (``_sum_held_rows``)."""
+    return tokens.at[order // shape[1]].get(mode="promise_in_bounds")
+
+
+def _dispatch_held_rows_fwd(tokens, order, perm, by_token, held, shape):
+    return (_dispatch_held_rows(tokens, order, perm, by_token, held, shape),
+            (perm, by_token, held))
+
+
+def _dispatch_held_rows_bwd(shape, res, g):
+    d = _sum_held_rows(g, None, *res, *shape)
+    return d.astype(g.dtype), None, None, None, None
 
 
 _dispatch_held_rows.defvjp(_dispatch_held_rows_fwd, _dispatch_held_rows_bwd)
 
 
 @jax.custom_vjp
-def _combine_held_rows(rows, weight, order, inv, held):
+def _combine_held_rows(rows, weight, order, inv, perm, by_token, held):
     """[cap, h] rows in expert order, [T, k] routing weights -> [T, h]
-    float32: each token's held rows weighted and summed, one slot of the k
-    at a time (no ``[T, k, h]`` tensor: most slots are some other chip's).
+    float32: each token's held rows weighted (in float32) and summed
+    (``_sum_held_rows``; no ``[T, k, h]`` tensor: most slots are some other
+    chip's). The buffer's rows past ``held`` are other tokens' (and a
+    grouped matmul leaves rows past its last group as it found them, on the
+    TPU possibly no number): selected away, not multiplied by 0.
     Backward, in the ``cap`` rows' own order: the rows' gradient is the
     token's times the weight, the weight's the row's product with it."""
-    return _combine_held_rows_fwd(rows, weight, order, inv, held)[0]
+    return _combine_held_rows_fwd(rows, weight, order, inv, perm, by_token, held)[0]
 
 
-def _combine_held_rows_fwd(rows, weight, order, inv, held):
-    cap, k = rows.shape[0], weight.shape[1]
-    real = (inv < held).reshape(weight.shape)
-    at = jnp.minimum(inv, cap - 1).reshape(weight.shape)
-    # selected, not multiplied by 0: the buffer's rows past ``held`` are
-    # other tokens' (and a grouped matmul leaves rows past its last group
-    # as it found them, on the TPU possibly no number)
-    out = sum(jnp.where(real[:, j, None],
-                        rows.at[at[:, j]].get(mode="promise_in_bounds")
-                        .astype(jnp.float32) * weight[:, j, None], 0.0)
-              for j in range(k))
+def _combine_held_rows_fwd(rows, weight, order, inv, perm, by_token, held):
+    n_tok, k = weight.shape
+    scale = weight.reshape(-1).at[by_token].get(mode="promise_in_bounds")
+    out = _sum_held_rows(rows, scale, perm, by_token, held, n_tok, k)
     return out, (rows, weight, order, inv, held)
 
 
@@ -191,7 +215,7 @@ def _combine_held_rows_bwd(res, g):
     d_w = jnp.where(inv < held,
                     dot.at[jnp.minimum(inv, rows.shape[0] - 1)]
                     .get(mode="promise_in_bounds"), 0.0).reshape(weight.shape)
-    return d_rows, d_w, None, None, None
+    return d_rows, d_w, None, None, None, None, None
 
 
 _combine_held_rows.defvjp(_combine_held_rows_fwd, _combine_held_rows_bwd)
@@ -451,6 +475,18 @@ class MoE:
         first = ("wi_gate", "wi_up") if self.activation == "silu_gated" else ("wi",)
         return tuple((name, m, h, f, g) for name in first) + (("wo", m, f, h, g),)
 
+    def rows_back(self, n_tok: int) -> Optional[Tuple[int, int, int]]:
+        """``(rows, tokens, h)`` of the sum that brings a share's buffer back
+        to the tokens (``_sum_held_rows``: the combine forward, and again
+        where the backward reruns it, and the dispatch's backward, ``rows``
+        gathered a pass), None with every expert held (``_all_rows`` moves
+        all ``tokens x top_k`` rows, each one real): static shapes, for the
+        engine's counters."""
+        g = self.held[1] - self.held[0]
+        if g == self.num_experts:
+            return None
+        return held_capacity(n_tok * self.top_k, g, self.num_experts), n_tok, self.hidden_size
+
     def _all_rows(self, params, tokens, eidx, weight, rows) -> jax.Array:
         """Every expert is here: all ``tokens x top_k`` assignments sorted
         by expert, gathered, multiplied and combined. -> [T, h] float32."""
@@ -503,14 +539,15 @@ class MoE:
             ends = jnp.minimum(ends, cap)
             filled = ends[-1]
             in_buffer = jnp.diff(ends.at[-1].set(cap), prepend=0)
+            perm, by_token = _token_order(order, filled)
         with jax.named_scope("moe/dispatch"):
-            expert_in = _dispatch_held_rows(tokens, order, inv, filled, k)
+            expert_in = _dispatch_held_rows(tokens, order, perm, by_token, filled, (n_tok, k))
         with jax.named_scope("moe/experts"):
             expert_out = checkpoint_name(
                 self._ffn(params, expert_in, lambda a, name: pallas_gmm.grouped_matmul(
                     a, params[name].astype(dt), in_buffer, _mesh_devices())), "wo")
         with jax.named_scope("moe/combine"):
-            out = _combine_held_rows(expert_out, weight, order, inv, filled)
+            out = _combine_held_rows(expert_out, weight, order, inv, perm, by_token, filled)
         if cap == n_tok * k:
             return out
         with jax.named_scope("moe/experts"):

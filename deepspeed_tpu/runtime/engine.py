@@ -571,10 +571,15 @@ class DeepSpeedEngine:
         # None until a step is traced): which of ``pallas_gmm.choose_route``'s
         # routes the expert layers' grouped matmuls take ("kernel" / "xla" /
         # "mixed"), and the products one step launches by route and kind.
+        # ``combine_route`` and ``combine_rows_moved`` (a share of the
+        # experts alone; None / 0 elsewhere): which of
+        # ``pallas_segment_sum.choose_route``'s routes brings the buffer's
+        # rows back to the tokens, and the rows one step gathers for it.
         self.moe_totals = {"path": getattr(self.model, "moe_path", None),
                            "steps": 0, **self._experts_of_model(),
                            "grouped_matmul_route": None,
-                           "products_kernel": None, "products_xla": None}
+                           "products_kernel": None, "products_xla": None,
+                           "combine_route": None, "combine_rows_moved": 0}
         self._step_stats = None
         # Attention counters, plain values kept with telemetry off: how many
         # of the model's layers attend under a window (static kinds, scope
@@ -2378,7 +2383,7 @@ class DeepSpeedEngine:
         block is rematerialised and the policy did not keep its name
         (``remat_totals``; a policy that is not ``KEEP_PRODUCTS`` is counted
         as keeping none)."""
-        from ..ops.transformer import pallas_gmm
+        from ..ops.transformer import pallas_gmm, pallas_segment_sum
         moe, cfg = self.model._moe, self.model.config
         b, s = batch["input_ids"].shape[:2]
         kept = self.remat_totals["saved"] if cfg.remat else None
@@ -2394,6 +2399,14 @@ class DeepSpeedEngine:
         self.moe_totals.update(
             grouped_matmul_route=used[0] if len(used) == 1 else "mixed",
             products_kernel=counts["kernel"], products_xla=counts["xla"])
+        back = moe.rows_back(b * s)
+        if back is not None:
+            # the combine forward (again where the backward reruns the block)
+            # and the dispatch's backward gather the buffer's rows once each
+            self.moe_totals.update(
+                combine_route=pallas_segment_sum.choose_route(
+                    *back, self.param_dtype, jax.default_backend(), self.mesh.size),
+                combine_rows_moved=expert_layers * (2 + bool(cfg.remat)) * back[0])
 
     def _count_moe(self, stats) -> None:
         """The fused step's MoE counters; the step's statistics are kept

@@ -114,10 +114,15 @@ def test_first_step_through_initialize(parts):
     np.testing.assert_array_equal(rows, load[:, :8].astype(np.int32))
     totals = dict(engine.moe_totals)
     products = totals.pop("products_xla")
+    # the rows back to the tokens: the buffer's, gathered once by the combine
+    # forward, once where the backward reruns it, once by the dispatch's backward
+    cap = held_capacity(8 * 48 * cfg["num_experts_per_tok"], 8, 16)
     assert totals == {"path": "dropless", "steps": 1,
                       "experts_published": 16, "experts_held": 8,
                       "grouped_matmul_route": "xla",
-                      "products_kernel": dict.fromkeys(products, 0)}
+                      "products_kernel": dict.fromkeys(products, 0),
+                      "combine_route": "xla",
+                      "combine_rows_moved": rows.shape[0] * 3 * cap}
     # three products a layer by kind, the forward's once more where the
     # backward reruns the block and kept none of its names
     again = 0 if engine.remat_totals["saved"] or not engine.model.config.remat else 1
@@ -330,3 +335,38 @@ def test_what_the_new_fields_refuse():
         spec.config_fn({"q_lora_rank": 1536, "num_attention_heads": 4})
     with pytest.raises(NotImplementedError, match="checkpoint"):
         spec.params_fn(None, {})
+
+
+@pytest.mark.parametrize("preset", ["instella-tiny", "afmoe-tiny", "olmoe-tiny"])
+def test_the_rows_back_to_the_tokens_are_counted_from_static_shapes(preset):
+    """``moe_totals["combine_route"]`` / ``["combine_rows_moved"]`` of a traced
+    step: for a share (the Instella and Trinity presets hold 8 of 16
+    experts) the route ``pallas_segment_sum.choose_route`` gives the sum's
+    static shape and the buffer's rows a pass of it, with remat three passes
+    a layer; with every expert held (OLMoE) None and no rows."""
+    import deepspeed_tpu
+    from deepspeed_tpu.ops.transformer import pallas_segment_sum
+    cell = harness.Cell(os.path.join(DATA, f"BENCHMARK.{preset}.json"), f"{preset}.train")
+    adapter = cell.load_module("adapters", cell.config["adapter"])
+    model = adapter.model(cell.config, remat=True, dtype="float32")
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, config={
+        "train_micro_batch_size_per_gpu": 1,
+        "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
+    assert engine.moe_totals["combine_route"] is None
+    assert engine.moe_totals["combine_rows_moved"] == 0
+    ids = np.random.default_rng(0).integers(0, cell.config["vocab_size"] - 1, (8, 32))
+    engine.train_batch({"input_ids": ids})
+    moe, layers = model._moe, engine.moe_expert_rows().shape[0]
+    back = moe.rows_back(8 * 32)
+    if preset == "olmoe-tiny":
+        assert back is None and moe.experts_held is None
+        assert engine.moe_totals["combine_route"] is None
+        assert engine.moe_totals["combine_rows_moved"] == 0
+        return
+    rows, tokens, h = back
+    assert (rows, tokens, h) == (held_capacity(8 * 32 * moe.top_k, 8, 16), 8 * 32,
+                                 cell.config["hidden_size"])
+    assert rows <= 8 * 32 * moe.top_k         # at most the slabs' tokens x k (512s: all, here)
+    assert engine.moe_totals["combine_route"] == pallas_segment_sum.choose_route(
+        rows, tokens, h, jnp.float32, "cpu", engine.mesh.size) == "xla"
+    assert engine.moe_totals["combine_rows_moved"] == layers * 3 * rows

@@ -164,7 +164,8 @@ def test_trains_through_initialize_and_counts_every_assignment(tiny):
                                  "experts_published": 8, "experts_held": 8,
                                  "grouped_matmul_route": "xla",
                                  "products_kernel": dict.fromkeys(each, 0),
-                                 "products_xla": each}
+                                 "products_xla": each,
+                                 "combine_route": None, "combine_rows_moved": 0}
     rows = engine.moe_expert_rows()
     assert rows.shape == (layers, 8) and (rows.sum(1) == 8 * 32 * k).all()
 
@@ -183,7 +184,8 @@ def test_capacity_models_count_too_and_keep_their_program():
     assert engine.moe_totals == {"path": "capacity", "steps": 1,
                                  "experts_published": 4, "experts_held": 4,
                                  "grouped_matmul_route": None,
-                                 "products_kernel": None, "products_xla": None}
+                                 "products_kernel": None, "products_xla": None,
+                                 "combine_route": None, "combine_rows_moved": 0}
     assert engine.moe_expert_rows() is None
 
 

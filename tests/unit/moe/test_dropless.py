@@ -281,3 +281,203 @@ def test_grouped_products_are_the_shapes_the_layer_multiplies(monkeypatch):
         names, shapes = zip(*((p[0], p[1:]) for p in moe.grouped_products(2 * 512)))
         assert names == ("wi_gate", "wi_up", "wo") and list(shapes) == seen
     assert seen[0][0] < 2 * 512 * 3 and seen[0][3] == 4       # a share's buffer
+
+
+# -- a share's rows back to the tokens (PR 38) --------------------------------
+# ``_held_rows`` sums each token's rows of the held experts' buffer over the
+# buffer's rows alone (``_token_order``, one gather, ``pallas_segment_sum``).
+# The reference here does it the plain way: every held expert on every token,
+# then one ``[tokens, h]`` slab a slot of the k.
+
+SHARE_T, SHARE_E = 1024, 32
+
+
+def share_layer(top_k, held):
+    return MoE(H, F, num_experts=SHARE_E, top_k=top_k, capacity_factor=None,
+               balance_loss="topk_share", normalize_weights=False, experts_held=held)
+
+
+def steered(moe, fill, seed=0):
+    """Parameters and tokens whose routing is set by hand: the gate copies a
+    token's first E features, which hold its logits. ``fill``: how many
+    assignments the held experts draw against the buffer's rows: ``below``
+    it, exactly ``at`` it, ``beyond`` it (so that ``_overflow_rows`` runs)."""
+    from deepspeed_tpu.moe.layer import held_capacity
+    lo, hi = moe.held
+    k, nh = moe.top_k, hi - lo
+    cap = held_capacity(SHARE_T * k, nh, SHARE_E)
+    want = {"below": cap // 2, "at": cap, "beyond": cap + cap // 4}[fill]
+    rng = np.random.default_rng(seed)
+    logits = rng.normal(0.0, 0.3, (SHARE_T, SHARE_E))
+    logits[:, lo:hi] -= 8.0                       # nobody comes by chance
+    each = min(k, nh)                             # held rows a steered token gives
+    for t in rng.permutation(SHARE_T)[:-(-want // each)]:
+        n = min(each, want)
+        logits[t, lo + rng.permutation(nh)[:n]] += 16.0
+        want -= n
+    assert want == 0
+    x = rng.normal(0.0, 1.0, (1, SHARE_T, H))
+    x[0, :, :SHARE_E] = logits
+    params = jax.tree.map(lambda a: a * 10.0, moe.init(jax.random.PRNGKey(seed)))
+    gate = jnp.zeros((H, SHARE_E)).at[:SHARE_E].set(jnp.eye(SHARE_E))
+    return dict(params, gate=gate), jnp.asarray(x, jnp.float32), cap
+
+
+def slab_by_slab(moe, params, x):
+    """The held experts' part of the layer: float32, a slab a slot."""
+    lo, hi = moe.held
+    t = x.reshape(-1, H).astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        top, idx = jax.lax.top_k(jax.nn.softmax(t @ params["gate"], axis=-1), moe.top_k)
+        mid = (jax.nn.silu(jnp.einsum("th,ehf->etf", t, params["wi_gate"]))
+               * jnp.einsum("th,ehf->etf", t, params["wi_up"]))
+        y = jnp.einsum("etf,efh->eth", mid, params["wo"])           # [held, T, h]
+    out = jnp.zeros_like(t)
+    for j in range(moe.top_k):
+        e = idx[:, j] - lo
+        slab = y[jnp.clip(e, 0, hi - lo - 1), jnp.arange(t.shape[0])]
+        out = out + jnp.where(((e >= 0) & (e < hi - lo))[:, None],
+                              top[:, j, None] * slab, 0.0)
+    return out.reshape(x.shape)
+
+
+@pytest.mark.parametrize("fill", ["below", "at", "beyond"])
+@pytest.mark.parametrize("held", [(8, 16), (4, 8)], ids=["a-quarter", "an-eighth"])
+@pytest.mark.parametrize("top_k", [2, 6, 8])
+def test_a_shares_rows_come_back_to_their_tokens(top_k, held, fill):
+    """Value and every gradient (through the rows: the experts' stacks and
+    the tokens; through the routing weights: the gate) of a share against
+    the slab-by-slab reference, with the buffer half full, exactly full and
+    over full."""
+    moe = share_layer(top_k, held)
+    params, x, cap = steered(moe, fill)
+    rows = np.asarray(route_of(moe, params, x)[3])[held[0]:held[1]].sum()
+    assert rows == {"below": cap // 2, "at": cap, "beyond": cap + cap // 4}[fill]
+    got, g = jax.jit(jax.value_and_grad(
+        lambda p, v: probe(run_layer, moe, p, v), (0, 1)))(params, x)
+    want, w = jax.jit(jax.value_and_grad(
+        lambda p, v: probe(slab_by_slab, moe, p, v), (0, 1)))(params, x)
+    assert float(got) == pytest.approx(float(want), rel=1e-4, abs=1e-3)   # a sum of 32 k terms
+    close(run_layer(moe, params, x), slab_by_slab(moe, params, x))
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+        close(a, b)
+
+
+def buffer_of(top_k, held, fill, dtype, seed=0):
+    """A step's sorted buffer as ``_held_rows`` hands it to the two
+    movements: (rows [cap, h], weight [T, k], order, inv, perm, by_token,
+    filled), the rows past ``filled`` no number."""
+    from deepspeed_tpu.moe.layer import _token_order
+    moe = share_layer(top_k, held)
+    params, x, cap = steered(moe, fill, seed)
+    eidx, weight, _, _ = route_of(moe, params, x)
+    local = eidx.reshape(-1) - held[0]
+    nh = held[1] - held[0]
+    order = jnp.argsort(jnp.where((local >= 0) & (local < nh), local, nh),
+                        stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.size, dtype=jnp.int32))
+    filled = jnp.minimum(jnp.sum((local >= 0) & (local < nh)), cap).astype(jnp.int32)
+    order = order[:cap]
+    rows = jax.random.normal(jax.random.PRNGKey(seed), (cap, 128), jnp.float32)
+    rows = jnp.where((jnp.arange(cap) < filled)[:, None], rows, jnp.nan).astype(dtype)
+    return (rows, weight, order, inv, *_token_order(order, filled), filled)
+
+
+def combine_slabs(rows, weight, inv, filled):
+    """The combine one slab a slot, in float64 on the host."""
+    rows, weight = np.asarray(rows, np.float64), np.asarray(weight, np.float64)
+    inv = np.asarray(inv).reshape(weight.shape)
+    out = np.zeros((weight.shape[0], rows.shape[1]))
+    for j in range(weight.shape[1]):
+        mine = inv[:, j] < int(filled)
+        out[mine] += weight[mine, j, None] * rows[inv[mine, j]]
+    return out
+
+
+def forced_route(monkeypatch, route):
+    """``segment_sum`` on ``route`` whatever the backend (the kernel in
+    interpret mode here); -> the list its kernel calls are noted in."""
+    from deepspeed_tpu.ops.transformer import pallas_segment_sum as S
+    calls, kernel = [], S.kernel_segment_sum
+    monkeypatch.setattr(S, "choose_route", lambda *a: route)
+    monkeypatch.setattr(S, "kernel_segment_sum",
+                        lambda *a, **kw: calls.append(a[0].shape) or kernel(*a, **kw))
+    return calls
+
+
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+@pytest.mark.parametrize("fill", ["below", "at"])
+def test_the_buffers_tail_is_selected_away(fill, route, monkeypatch):
+    """The rows past ``filled`` hold NaN (a grouped matmul leaves them as it
+    found them): both movements give finite results equal to the slabs', on
+    either route (the kernel in interpret mode here)."""
+    from deepspeed_tpu.moe.layer import _combine_held_rows, _dispatch_held_rows
+    calls = forced_route(monkeypatch, route)
+    rows, weight, order, inv, perm, by_token, filled = buffer_of(6, (4, 8), fill, jnp.bfloat16)
+    assert (fill == "at") == (int(filled) == rows.shape[0])
+    out = _combine_held_rows(rows, weight, order, inv, perm, by_token, filled)
+    assert calls == [rows.shape] * (route == "kernel")
+    assert np.isfinite(np.asarray(out)).all()
+    close(out, combine_slabs(rows, weight, inv, filled))
+    # the dispatch's backward is the same sum without the weight
+    tokens = jnp.zeros((weight.shape[0], rows.shape[1]), jnp.bfloat16)
+    d = jax.vjp(lambda t: _dispatch_held_rows(t, order, perm, by_token, filled,
+                                              weight.shape), tokens)[1](rows)[0]
+    assert d.dtype == jnp.bfloat16 and np.isfinite(np.asarray(d, np.float32)).all()
+    want = combine_slabs(rows, np.ones(weight.shape), inv, filled)
+    np.testing.assert_allclose(np.asarray(d, np.float32), want, rtol=2 ** -8,
+                               atol=2 ** -8 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_a_routing_weight_keeps_its_float32(route, monkeypatch):
+    """Weights bfloat16 cannot hold (1/3, 0.1234567, ...) over bfloat16 rows
+    of ones: each token's sum is its weights' to 1e-6, so no operand of the
+    sum was rounded to bfloat16 on the way."""
+    from deepspeed_tpu.moe.layer import _combine_held_rows
+    forced_route(monkeypatch, route)
+    rows, weight, order, inv, perm, by_token, filled = buffer_of(8, (8, 16), "below", jnp.bfloat16)
+    rows = jnp.where(jnp.isnan(rows), rows, jnp.ones_like(rows))
+    odd = jnp.asarray([1 / 3, 0.1234567, 0.7071068, 1e-3 / 7], jnp.float32)
+    weight = odd[jnp.arange(weight.size) % 4].reshape(weight.shape)
+    out = np.asarray(_combine_held_rows(rows, weight, order, inv, perm, by_token, filled))
+    want = combine_slabs(rows, weight, inv, filled)
+    assert want.max() > 1.0
+    np.testing.assert_allclose(out, want, rtol=1e-6, atol=0)
+    rounded = combine_slabs(rows, weight.astype(jnp.bfloat16), inv, filled)
+    assert np.abs(rounded - want).max() > 1e-4 * want.max()       # bf16 would show
+
+
+@pytest.mark.parametrize("m,segments,h,dtype,backend,devices,route", [
+    (36864, 16384, 2048, jnp.bfloat16, "tpu", 1, "kernel"),    # the Instella cell
+    (49152, 16384, 2048, jnp.bfloat16, "tpu", 1, "kernel"),    # the Trinity cell
+    (36864, 16384, 2048, jnp.float16, "tpu", 1, "kernel"),
+    (36864, 16384, 2048, jnp.bfloat16, "cpu", 1, "xla"),       # the tests' program is XLA's
+    (36864, 16384, 2048, jnp.bfloat16, "gpu", 1, "xla"),
+    (36864, 16384, 2048, jnp.bfloat16, "tpu", 4, "xla"),       # GSPMD does not partition it
+    (36864, 16384, 2048, jnp.float32, "tpu", 1, "xla"),        # the MXU would round a row
+    (36864, 16384, 2048, jnp.int8, "tpu", 1, "xla"),
+    (36864, 16384, 2000, jnp.bfloat16, "tpu", 1, "xla"),       # a width off the lanes
+    (36864 + 8, 16384, 2048, jnp.bfloat16, "tpu", 1, "xla"),   # no 128-multiple row tile
+    (36864, 16384 + 8, 2048, jnp.bfloat16, "tpu", 1, "xla"),
+    (36864, 16384, 1 << 16, jnp.bfloat16, "tpu", 1, "xla"),    # blocks over the budget
+    (512, 128, 128, jnp.bfloat16, "tpu", 1, "kernel"),
+])
+def test_segment_sum_route_table(m, segments, h, dtype, backend, devices, route):
+    from deepspeed_tpu.ops.transformer import pallas_segment_sum as S
+    assert S.choose_route(m, segments, h, dtype, backend, devices) == route
+    if route == "kernel":
+        ts, tr, limit = S.choose_tiles(m, segments, h, jnp.dtype(dtype).itemsize)
+        assert segments % ts == 0 and m % tr == 0 and ts % 128 == 0 and tr % 128 == 0
+        assert S.vmem_bytes(ts, tr, h, 2) <= min(S.VMEM_BUDGET, limit) and limit <= S.VMEM_CAP
+
+
+def test_a_steps_work_does_not_follow_the_rows():
+    """The kernel's grid, blocks and loop bounds come from ``(rows, tokens,
+    h)`` alone: the traced program is the same text whatever ``filled``."""
+    from deepspeed_tpu.ops.transformer import pallas_segment_sum as S
+    rows = jnp.ones((512, 128), jnp.bfloat16)
+    seg = jnp.sort(jnp.arange(512) % 256).astype(jnp.int32)
+    texts = {str(jax.make_jaxpr(lambda f: S.kernel_segment_sum(
+        rows, seg, jnp.ones((512,)), f, 256))(jnp.int32(filled))) for filled in (0, 100, 512)}
+    assert len(texts) == 1 and "grid=(" + str(512 // 256 + 256 // 128) in texts.pop().replace(" ", "")

@@ -121,6 +121,26 @@ class TestCompilesForV5e:
         for name in ("ragged-dot-gmm-fwd", "ragged-dot-gmm-dlhs", "ragged-dot-gmm-dw"):
             assert name in text
 
+    @pytest.mark.parametrize("scaled", [True, False], ids=["combine", "dispatch-bwd"])
+    @pytest.mark.parametrize("rows,tokens,h", [
+        (36864, 16384, 2048),       # instella-moe-16b-a3b.train.seq8k, 6 a token
+        (49152, 16384, 2048),       # trinity-mini.train.seq16k, 8 a token
+    ])
+    def test_segment_sum(self, chip, rows, tokens, h, scaled):
+        """A share's rows back to the tokens at the two share cells' shapes,
+        with the tiles ``choose_tiles`` gives, weighted (the combine) and not
+        (the dispatch's backward)."""
+        from deepspeed_tpu.ops.transformer import pallas_segment_sum as S
+        assert S.choose_route(rows, tokens, h, BF16, "tpu", 1) == "kernel"
+
+        def back(x, segment, scale, filled):
+            return S.kernel_segment_sum(x, segment, scale if scaled else None, filled,
+                                        tokens, interpret=False)
+
+        text = jax.jit(back).lower(chip((rows, h), BF16), chip((rows,), I32),
+                                   chip((rows,), F32), chip((), I32)).compile().as_text()
+        assert "segment-sum" in text and "tpu_custom_call" in text
+
     @pytest.mark.parametrize("mode,moments,n", [
         ("adamw", "fp32", REAL.bucket_elems),
         ("adamw", "bf16-sr", REAL.bucket_elems),

@@ -137,7 +137,10 @@ KERNEL_NAMES = {
     "ragged-dot-gmm-fwd", "ragged-dot-gmm-dlhs", "ragged-dot-gmm-dw",
     # the flash pair with its grids cut to a static window (PR 34): a name of
     # their own, so that a reader tells a sliding layer's launch from a full one's
-    "flash_fwd_window", "flash_bwd_window"}
+    "flash_fwd_window", "flash_bwd_window",
+    # a share's rows back to the tokens (PR 38), under ``mlp/moe/combine`` and
+    # ``mlp/moe/dispatch``: ``train_moe_dispatch_ms`` finds it by its scope
+    "segment-sum"}
 
 
 def _names_of(name):
@@ -160,9 +163,9 @@ def test_every_pallas_call_has_a_name(site):
 
 
 def test_kernel_names_are_distinct_and_complete():
-    assert len(PALLAS_SITES) == 17
+    assert len(PALLAS_SITES) == 18
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 19
+    assert len(set(names)) == len(names) == 20
     assert set(names) == KERNEL_NAMES
 
 

@@ -14,6 +14,16 @@ reading on stdout and in ``chiprun_out/gmm_ab.jsonl``: ms (best of the
 windows, host clock around ``block_until_ready``) and the share of the
 chip's 197 TFLOP/s.
 
+``python tools/moe_ab.py rows`` (PR 38): a chip's share's rows back to the
+tokens at the two share cells' shapes ([36,864, 2048] -> [16,384, 2048] at 6
+a token of 64 experts, 8 held; [49,152, 2048] -> [16,384, 2048] at 8 of 128,
+16 held): the slab-by-slab gathers the layer had (one ``[tokens, h]`` slab a
+slot, kept here as the reference) against ``moe/layer.py``'s
+``_sum_held_rows`` (one gather of the buffer's rows, then
+``ops/transformer/pallas_segment_sum.py``), with the kernel alone at a few
+tiles, the gather alone, the index arithmetic alone and the XLA form. One
+JSON line a reading on stdout and in ``chiprun_out/rows_ab.jsonl``.
+
 ``python tools/moe_ab.py`` (round 2): the capacity-dense batched einsum
 against ``ragged_dot`` at the smoke's MoE dims.
 
@@ -22,6 +32,7 @@ BEST window of each is compared). No cell runs this file.
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -272,18 +283,128 @@ def gmm_against_ragged(sweep: bool, parts: bool, only):
                           "peak_share": round(flops / (ms / 1e3) / PEAK_FLOPS, 4)})
 
 
+# ---------------------------------------------------------------------------
+# a share's rows back to the tokens (PR 38)
+# ---------------------------------------------------------------------------
+
+#: name -> (tokens, hidden, top_k, experts published, experts held)
+ROW_SHAPES = {"instella": (16384, 2048, 6, 64, 8), "trinity": (16384, 2048, 8, 128, 16)}
+SEGMENT_TILES = ((128, 128), (128, 256), (128, 512), (256, 256))
+
+
+def slab_combine(rows, weight, inv, held):
+    """The combine as the layer had it until PR 38: one slab a slot."""
+    cap, k = rows.shape[0], weight.shape[1]
+    real = (inv < held).reshape(weight.shape)
+    at = jnp.minimum(inv, cap - 1).reshape(weight.shape)
+    return sum(jnp.where(real[:, j, None],
+                         rows.at[at[:, j]].get(mode="promise_in_bounds")
+                         .astype(jnp.float32) * weight[:, j, None], 0.0)
+               for j in range(k))
+
+
+def slab_dispatch_bwd(g, inv, held, top_k):
+    """The dispatch's backward as the layer had it until PR 38."""
+    picked = g.at[jnp.minimum(inv, g.shape[0] - 1)].get(mode="promise_in_bounds")
+    picked = jnp.where((inv < held)[:, None], picked.astype(jnp.float32), 0.0)
+    return jnp.sum(picked.reshape(-1, top_k, g.shape[-1]), axis=1).astype(g.dtype)
+
+
+def routing(n_tok, k, experts, nh, seed):
+    """A step's routing as ``MoE._held_rows`` sorts it: Zipf-hot experts."""
+    from deepspeed_tpu.moe import layer as L
+    key = jax.random.split(jax.random.PRNGKey(seed), 3)
+    logits = (jax.random.normal(key[0], (n_tok, experts))
+              + 0.5 * jax.random.normal(key[1], (experts,)))
+    weight, eidx = jax.lax.top_k(jax.nn.softmax(logits), k)
+    cap = L.held_capacity(n_tok * k, nh, experts)
+    local = eidx.reshape(-1)
+    sort_key = jnp.where(local < nh, local, nh)
+    order = jnp.argsort(sort_key, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(n_tok * k, dtype=jnp.int32))
+    held = jnp.minimum(jnp.sum(sort_key < nh), cap).astype(jnp.int32)
+    return weight.astype(jnp.float32), order[:cap], inv, held, cap
+
+
+def rows_to_tokens(only):
+    from deepspeed_tpu.moe import layer as L
+    from deepspeed_tpu.ops.transformer import pallas_segment_sum as S
+    if jax.default_backend() != "tpu":
+        raise SystemExit("tools/moe_ab.py rows measures a TPU; none is attached")
+    os.makedirs("chiprun_out", exist_ok=True)
+    log = open("chiprun_out/rows_ab.jsonl", "a")
+
+    def emit(rec):
+        line = json.dumps(rec)
+        print(line, flush=True)
+        log.write(line + "\n")
+        log.flush()
+
+    for shape, (n_tok, h, k, experts, nh) in ROW_SHAPES.items():
+        if only and shape not in only:
+            continue
+        weight, order, inv, held, cap = routing(n_tok, k, experts, nh, seed=38)
+        rows = jax.random.normal(jax.random.PRNGKey(1), (cap, h), jnp.bfloat16)
+        rows = jnp.where((jnp.arange(cap) < held)[:, None], rows, jnp.nan)
+        perm, by_token = jax.jit(L._token_order)(order, held)
+        scale = weight.reshape(-1)[by_token]
+        in_order = rows[perm]
+        base = {"shape": shape, "rows": cap, "tokens": n_tok, "top_k": k,
+                "held_rows": int(held)}
+        readings = {
+            "slab_combine": (jax.jit(slab_combine), (rows, weight, inv, held)),
+            "slab_dispatch_bwd": (jax.jit(lambda g, i, f: slab_dispatch_bwd(g, i, f, k)),
+                                  (rows, inv, held)),
+            "token_order": (jax.jit(L._token_order), (order, held)),
+            "gather_rows": (jax.jit(lambda r, p: r.at[p].get(mode="promise_in_bounds", unique_indices=True)),
+                            (rows, perm)),
+            "sum_scaled": (jax.jit(lambda r, s, p, b, f: L._sum_held_rows(
+                r, s, p, b, f, n_tok, k)), (rows, scale, perm, by_token, held)),
+            "sum_unscaled": (jax.jit(lambda r, p, b, f: L._sum_held_rows(
+                r, None, p, b, f, n_tok, k)), (rows, perm, by_token, held)),
+            "xla_scaled": (jax.jit(lambda r, b, s, f: S.xla_segment_sum(
+                r, b // k, s, f, n_tok)), (in_order, by_token, scale, held)),
+        }
+        for ts, tr in SEGMENT_TILES:
+            for label, sc in (("scaled", scale), ("unscaled", None)):
+                readings[f"kernel_{label}_ts{ts}_tr{tr}"] = (
+                    jax.jit(functools.partial(
+                        lambda r, b, s, f, tiles: S.kernel_segment_sum(
+                            r, b // k, s, f, n_tok, tiles=tiles, interpret=False),
+                        tiles=(ts, tr, S.VMEM_CAP))),
+                    (in_order, by_token, sc, held))
+        want = jnp.where(jnp.isfinite(rows), rows, 0)
+        want_c = slab_combine(want, weight, inv, held)
+        want_d = slab_dispatch_bwd(want, inv, held, k).astype(jnp.float32)
+        for name, (fn, args) in readings.items():
+            try:
+                got = fn(*args)
+                ms = best_ms(fn, args)
+            except Exception as e:  # noqa: BLE001  tiles Mosaic refuses
+                emit(dict(base, variant=name, error=f"{type(e).__name__}: {str(e)[:300]}"))
+                continue
+            rec = dict(base, variant=name, ms=round(ms, 4))
+            if name not in ("token_order", "gather_rows"):
+                ref = want_d if "unscaled" in name or "dispatch" in name else want_c
+                rec["max_abs_err"] = float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref)))
+                rec["finite"] = bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))
+            emit(rec)
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("mode", nargs="?", default="dense", choices=("dense", "gmm"))
+    ap.add_argument("mode", nargs="?", default="dense", choices=("dense", "gmm", "rows"))
     ap.add_argument("--sweep", action="store_true",
                     help="gmm: every row tile of ROW_TILES, not choose_tiles' own")
     ap.add_argument("--parts", action="store_true",
                     help="gmm: row tiles 256 and 512 with a shared tile multiplied "
                          "whole against in parts of 128 rows")
-    ap.add_argument("--shape", action="append", choices=sorted(SHAPES),
-                    help="gmm: only this shape (may repeat)")
+    ap.add_argument("--shape", action="append", choices=sorted(SHAPES) + sorted(ROW_SHAPES),
+                    help="gmm, rows: only this shape (may repeat)")
     args = ap.parse_args()
-    if args.mode == "gmm":
+    if args.mode == "rows":
+        rows_to_tokens(args.shape)
+    elif args.mode == "gmm":
         gmm_against_ragged(args.sweep, args.parts, args.shape)
     else:
         dense_against_ragged()

@@ -81,6 +81,12 @@ class RaggedInferenceModel:
         # program (forces the XLA path; the stock Pallas kernel has no bias)
         self._alibi = (jnp.asarray(model._alibi_slopes)
                        if model._alibi_slopes is not None else None)
+        if c.diffusion:
+            raise NotImplementedError(
+                "serving objective='block_diffusion' is not supported: a step "
+                "that yields a block of tokens by several denoising passes is "
+                "none of the ragged engine's programs (one token a sequence a "
+                "step); the model trains through deepspeed_tpu.initialize")
         if c.qk_norm or (c.moe is not None and c.moe.capacity_factor is None):
             raise NotImplementedError(
                 "serving OLMoE is not supported yet: the ragged engine's "

@@ -24,6 +24,7 @@ TPU-first structure:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -62,6 +63,14 @@ def _c(x, spec):
     return with_sharding_constraint(x, spec)
 
 
+def _token_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """Each position's negative log-likelihood of its target, by a one-hot
+    contraction (:func:`masked_cross_entropy` says why not a gather)."""
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    onehot = jax.nn.one_hot(targets, logits.shape[-1], dtype=logp.dtype)
+    return -jnp.sum(logp * onehot, axis=-1)
+
+
 @scoped("loss")
 def masked_cross_entropy(logits: jax.Array, labels: jax.Array,
                          extra_mask: Optional[jax.Array] = None) -> jax.Array:
@@ -72,14 +81,29 @@ def masked_cross_entropy(logits: jax.Array, labels: jax.Array,
     logits are vocab-sharded (TP lm_head). XLA fuses the one-hot into the
     reduction, so no [..., V] buffer is materialized."""
     valid = labels >= 0
-    safe = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    onehot = jax.nn.one_hot(safe, logits.shape[-1], dtype=logp.dtype)
-    nll = -jnp.sum(logp * onehot, axis=-1)
+    nll = _token_nll(logits, jnp.where(valid, labels, 0))
     mask = valid.astype(jnp.float32)
     if extra_mask is not None:
         mask = mask * extra_mask.astype(jnp.float32)
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+@contextlib.contextmanager
+def _noise_scope():
+    """The scope ``diffusion/noise``, as two components: a transform wraps a
+    name-stack entry whole (``jvp(diffusion/noise)`` would be one component
+    to whoever splits an ``op_name`` at its slashes)."""
+    with jax.named_scope("diffusion"), jax.named_scope("noise"):
+        yield
+
+
+@scoped("loss")
+def weighted_cross_entropy(logits: jax.Array, targets: jax.Array,
+                           weights: jax.Array) -> jax.Array:
+    """``sum(weights x CE(logits, targets)) / positions``: the
+    block-diffusion objective's sum (a position's weight is 0 or 1 / t),
+    over every position of the batch, weighted or not."""
+    return jnp.sum(_token_nll(logits, targets) * weights) / targets.size
 
 
 @dataclasses.dataclass(frozen=True)
@@ -283,6 +307,35 @@ class TransformerConfig:
     # the shared embedding and head; its loss counts ``mtp_loss_coef`` times
     mtp_layers: int = 0
     mtp_loss_coef: float = 0.3
+    # the training objective: 'next_token' (by shift; an encoder's masked-LM
+    # over given labels) | 'block_diffusion' (BD3-LM, arXiv:2503.09573, the
+    # vectorised form): every block of ``block_length`` positions (a power
+    # of two, counted from the row's start) draws t ~ U[1e-3, 1] and each of
+    # its tokens becomes ``mask_token_id`` with probability t; the network
+    # runs on the clean AND the noised copy of a row (2 L rows of
+    # activations for L tokens, one position for both copies of a token)
+    # under ONE mask (``ops/transformer/attention.py::blockdiff_visible``);
+    # the head reads the noised copy alone, unshifted, and a masked position's
+    # cross-entropy counts 1 / t, over rows x L. The noise is a pure function
+    # of the batch (``TransformerLM.noise_key``: ``batch["noise_key"]`` or
+    # ``noise_seed`` folded with the ids). ``mask_token_id`` may lie one past
+    # the vocabulary: the embedding then has that row more than the head.
+    objective: str = "next_token"
+    block_length: int = 4
+    mask_token_id: Optional[int] = None
+    noise_seed: int = 0
+
+    @property
+    def diffusion(self) -> bool:
+        return self.objective == "block_diffusion"
+
+    @property
+    def embedding_rows(self) -> int:
+        """Rows of the token embedding: the vocabulary, and the mask
+        token's row where it lies past it."""
+        if self.diffusion:
+            return max(self.vocab_size, self.mask_token_id + 1)
+        return self.vocab_size
 
     @property
     def kv_heads(self) -> int:
@@ -316,8 +369,9 @@ class TransformerConfig:
             mlp = 2 * h * ffn
         if self.moe is not None:
             mlp = mlp * self.moe.num_experts + h * self.moe.num_experts
-        embed = v * h + ((self.max_seq_len + self.position_offset) * h
-                         if self.position == "learned" else 0)
+        embed = self.embedding_rows * h + (
+            (self.max_seq_len + self.position_offset) * h
+            if self.position == "learned" else 0)
         embed += self.type_vocab_size * h
         head = 0 if self.tie_embeddings else v * h
         if self.mlm_head:
@@ -339,7 +393,12 @@ class TransformerLM:
     def __init__(self, config: TransformerConfig):
         self.config = config
         c = config
-        self._wte = nn.Embedding(c.vocab_size, c.hidden_size, shard=True)
+        if c.objective not in ("next_token", "block_diffusion"):
+            raise ValueError(f"objective {c.objective!r} is not 'next_token' "
+                             "or 'block_diffusion'")
+        if c.diffusion and c.mask_token_id is None:
+            raise ValueError("objective='block_diffusion' needs mask_token_id")
+        self._wte = nn.Embedding(c.embedding_rows, c.hidden_size, shard=True)
         self._wpe = (nn.Embedding(c.max_seq_len + c.position_offset, c.hidden_size)
                      if c.position == "learned" else None)
         base_cls = nn.LayerNorm if c.norm == "layernorm" else nn.RMSNorm
@@ -566,6 +625,22 @@ class TransformerLM:
                 "remat_policy='alternating' scans layer pairs of one kind: "
                 "layers with and without a rotary position "
                 "(rope_layers='windowed') take any other policy")
+        if c.diffusion:
+            b = c.block_length
+            if b < 1 or b & (b - 1):
+                raise ValueError(f"block_length {b} is no power of two")
+            if (not c.causal or c.attention != "mha" or c.position != "rope"
+                    or self._windows is not None or self._mixed_rope
+                    or c.seq_parallel == "ring" or c.tie_embeddings
+                    or c.norm_style == "post" or c.farskip or c.mtp_layers
+                    or c.mlm_head or c.type_vocab_size
+                    or (c.remat and c.remat_policy == "alternating")):
+                raise ValueError(
+                    "objective='block_diffusion' is written for a rotary "
+                    "decoder with its own head: no latent attention, window, "
+                    "ALiBi or learned position, ring attention, tied "
+                    "embedding, post-norm, FarSkip, prediction module or "
+                    "remat_policy='alternating'")
         if c.document_separator is not None and (
                 not c.causal or c.seq_parallel == "ring"):
             raise ValueError("document_separator: packed documents are a causal "
@@ -719,8 +794,14 @@ class TransformerLM:
                 if rope:
                     q = self._rotate(q, positions)
                     k = self._rotate(k, positions)
-            with jax.named_scope("core_window" if isinstance(window, int) else "core"):
-                out = self._attn_core(q, k, v, attn_mask, window)
+            if c.diffusion:
+                # both copies of the row under one mask; attn_mask holds the
+                # clean copy's documents [B, L]
+                with jax.named_scope("core_blockdiff"):
+                    out = self._blockdiff_core(q, k, v, attn_mask)
+            else:
+                with jax.named_scope("core_window" if isinstance(window, int) else "core"):
+                    out = self._attn_core(q, k, v, attn_mask, window)
             out = out.reshape(B, S, c.num_heads * c.head_dim)
             if c.attn_gate:
                 out = self._gated(block, h, out)
@@ -796,6 +877,20 @@ class TransformerLM:
             kw["alibi_slopes"] = jnp.asarray(self._alibi_slopes)
         return ulysses_attention(flash_attention, q, k, v, causal=c.causal,
                                  segment_ids=seg, **kw)
+
+    def _blockdiff_core(self, q, k, v, documents) -> jax.Array:
+        """Scores, softmax and values of the clean and the noised copy under
+        the block-diffusion mask (``attention.blockdiff_attention``)."""
+        from ..ops.transformer.attention import blockdiff_attention
+        from ..runtime import topology as topo_mod
+        topo = topo_mod.get_topology() if topo_mod.is_initialized() else None
+        if topo is not None and topo.sequence_parallel_size > 1:
+            raise NotImplementedError(
+                "objective='block_diffusion': the mask over a clean and a "
+                "noised copy is not written for sequence parallelism "
+                f"(sequence={topo.sequence_parallel_size})")
+        return blockdiff_attention(q, k, v, self.config.block_length, documents,
+                                   scale=self.config.attn_scale)
 
     @property
     def moe_path(self) -> Optional[str]:
@@ -958,6 +1053,12 @@ class TransformerLM:
         module's partitions at a time, partitioned_param_coordinator.py:280).
         Returns (x', moe_aux)."""
         c = self.config
+        if c.diffusion:
+            raise NotImplementedError(
+                "objective='block_diffusion' runs a clean and a noised copy "
+                "of every row under one mask, which one block at a time "
+                "(parameter streaming, the ZeRO-3 pipelined scan) does not "
+                "carry: it takes the whole-model scan of TransformerLM.loss")
         if (c.farskip or c.first_dense_layers or c.mtp_layers or self._mixed_rope
                 or c.document_separator is not None):
             raise NotImplementedError(
@@ -1248,9 +1349,14 @@ class TransformerLM:
         """
         if not return_hidden:
             self._charge_head(remat_budget, input_ids)
+        # under the block-diffusion objective: the FIRST DENOISING PASS of
+        # every block, the noised copy all mask tokens (position i's logits
+        # read the clean tokens of strictly earlier blocks alone)
+        noised = (jnp.full_like(input_ids, self.config.mask_token_id)
+                  if self.config.diffusion else None)
         x, aux, stats, _ = self._trunk(
             params, input_ids, layer_mask, token_type_ids, attention_mask,
-            remat_budget, with_mtp=False)
+            remat_budget, with_mtp=False, noised_ids=noised)
         stats = (stats,) if return_stats else ()
         if return_hidden:
             if self._ln_f is not None:
@@ -1266,13 +1372,31 @@ class TransformerLM:
             remat_budget.outside_bytes = 2 * 4 * input_ids.size * self.config.vocab_size
 
     def _trunk(self, params, input_ids, layer_mask, token_type_ids,
-               attention_mask, remat_budget, with_mtp: bool):
+               attention_mask, remat_budget, with_mtp: bool,
+               noised_ids: Optional[jax.Array] = None):
         """Embedding and every block: (the last block's output stream,
         the accumulated MoE aux, the step's statistics, the prediction
         module's output stream or None). ``with_mtp``: run the module (a
-        training loss asks for it; logits alone do not need it)."""
+        training loss asks for it; logits alone do not need it).
+        ``noised_ids`` (the block-diffusion objective): the noised copy of
+        ``input_ids``; the blocks then run on ``[B, 2 L]`` rows, the clean
+        copy's and then the noised copy's, and the stream returned is the
+        NOISED copy's ``[B, L, H]`` (the clean copy's rows after the last
+        layer feed nothing; they are computed all the same)."""
         c = self.config
-        x, positions = self.embed(params, input_ids, token_type_ids)
+        if c.diffusion:
+            if attention_mask is not None or token_type_ids is not None:
+                raise ValueError("objective='block_diffusion' takes full rows: "
+                                 "no attention_mask, no token_type_ids")
+            L = input_ids.shape[1]
+            if L % c.block_length:
+                raise ValueError(f"a row of {L} tokens is no multiple of "
+                                 f"block_length {c.block_length}")
+            x, positions = self.embed(
+                params, jnp.concatenate([input_ids, noised_ids], axis=1))
+            positions = positions % L     # one position for both copies
+        else:
+            x, positions = self.embed(params, input_ids, token_type_ids)
 
         if c.document_separator is not None:
             if attention_mask is not None:
@@ -1349,6 +1473,8 @@ class TransformerLM:
             (x, _, aux), rows = self._scan_by_kind(init, xs, block_of)
         if c.farskip:
             x = x[0]
+        if c.diffusion:
+            x = x[:, input_ids.shape[1]:]
         mtp_x = None
         if with_mtp and c.mtp_layers:
             with jax.named_scope("mtp"):
@@ -1481,14 +1607,18 @@ class TransformerLM:
 
     def loss(self, params: Params, batch: Dict[str, jax.Array],
              remat_budget: Optional[Budget] = None) -> jax.Array:
-        """Cross-entropy: next-token for causal LMs (labels derived by shift
-        when absent), masked-LM for encoders (labels required, -100 = ignore).
+        """The training objective, one of three: next-token cross-entropy
+        for causal LMs (labels derived by shift when absent), masked-LM for
+        encoders (labels required, -100 = ignore), and with
+        ``objective='block_diffusion'`` the weighted denoising loss over a
+        clean and a noised copy of every row (:meth:`_diffusion_loss`; the
+        batch's ``input_ids`` and optional ``noise_key`` alone).
         batch: input_ids [B,S], optional labels/loss_mask/token_type_ids/
         attention_mask. ``remat_budget`` as in :meth:`apply`. Calls
         ``self.apply``, ``self.derive_labels`` and ``self.combine_aux``
-        alone where there is no prediction module (``PipelineModule``
-        borrows it)."""
-        if self.config.mtp_layers:
+        alone where there is no prediction module and no noise
+        (``PipelineModule`` borrows it)."""
+        if self.config.mtp_layers or self.config.diffusion:
             return self.loss_and_stats(params, batch, remat_budget)[0]
         labels = self.derive_labels(batch)
         logits, aux = self.apply(params, batch["input_ids"],
@@ -1508,6 +1638,8 @@ class TransformerLM:
         the objective is the next-token loss plus ``mtp_loss_coef`` times
         the module's loss on the token after next."""
         c = self.config
+        if c.diffusion:
+            return self._diffusion_loss(params, batch, remat_budget)
         labels = self.derive_labels(batch)
         mask = batch.get("loss_mask")
         self._charge_head(remat_budget, batch["input_ids"])
@@ -1534,6 +1666,72 @@ class TransformerLM:
                 mtp_x, later(labels, -100),
                 None if mask is None else later(mask, 0), params["mtp"]["ln_f"])
         return self.combine_aux(loss, aux), stats
+
+    # -- the block-diffusion objective ----------------------------------------
+    @property
+    def rows_per_token(self) -> int:
+        """Rows of activations a data token costs every layer: 2 under the
+        block-diffusion objective (a clean and a noised copy), else 1."""
+        return 2 if self.config.diffusion else 1
+
+    def noise_key(self, batch: Dict[str, jax.Array]) -> jax.Array:
+        """The key a step's noise is drawn from, a pure function of the
+        batch: ``batch["noise_key"]`` where the caller gives one, else
+        ``noise_seed`` folded with ``f(input_ids)``, ``f`` the sum over the
+        flattened ids of ``id x (2 x index + 1)`` in uint32 arithmetic,
+        shifted right one bit (a fresh batch gives fresh noise, the same
+        batch the same)."""
+        key = batch.get("noise_key")
+        if key is not None:
+            return key
+        ids = batch["input_ids"].reshape(-1).astype(jnp.uint32)
+        odd = 2 * jnp.arange(ids.size, dtype=jnp.uint32) + 1
+        return jax.random.fold_in(jax.random.PRNGKey(self.config.noise_seed),
+                                  jnp.sum(ids * odd, dtype=jnp.uint32) >> 1)
+
+    def noise(self, batch: Dict[str, jax.Array]
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """(the noised copy of ``input_ids``, each position's loss weight
+        [B, L] float32: 1 / t where it was masked and 0 elsewhere, which
+        positions were masked). A block's t ~ U[1e-3, 1] and each token's
+        Bernoulli(t) are two uniform draws from the two splits of
+        :meth:`noise_key` (linear schedule, alpha_t = 1 - t)."""
+        c = self.config
+        ids = batch["input_ids"]
+        B, L = ids.shape
+        with _noise_scope():
+            key_t, key_mask = jax.random.split(self.noise_key(batch))
+            t = jax.random.uniform(key_t, (B, L // c.block_length), jnp.float32,
+                                   minval=1e-3, maxval=1.0)
+            t = jnp.repeat(t, c.block_length, axis=1)
+            masked = jax.random.uniform(key_mask, (B, L), jnp.float32) < t
+            noised = jnp.where(masked, jnp.asarray(c.mask_token_id, ids.dtype), ids)
+            return noised, jnp.where(masked, 1.0 / t, 0.0), masked
+
+    def _diffusion_loss(self, params: Params, batch: Dict[str, jax.Array],
+                        remat_budget: Optional[Budget] = None
+                        ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """The block-diffusion objective (``TransformerConfig.objective``):
+        ``(1 / (rows x L)) x sum over masked i of (1 / t_blk(i)) x
+        CE(logits_i, x_0[i])``, the logits the head's over the noised copy,
+        unshifted. The statistics gain the step's masked share and the mean
+        weight of a masked position."""
+        if batch.get("labels") is not None or batch.get("loss_mask") is not None:
+            raise ValueError("objective='block_diffusion' draws its own targets: "
+                             "the batch takes no labels and no loss_mask")
+        ids = batch["input_ids"]
+        noised, weights, masked = self.noise(batch)
+        self._charge_head(remat_budget, ids)
+        x, aux, stats, _ = self._trunk(
+            params, ids, batch.get("layer_mask"), batch.get("token_type_ids"),
+            batch.get("attention_mask"), remat_budget, with_mtp=False,
+            noised_ids=noised)
+        logits = self.head(params, x)
+        with _noise_scope():
+            count = jnp.sum(masked, dtype=jnp.float32)
+            stats = {**stats, "diffusion_masked_share": count / ids.size,
+                     "diffusion_mean_weight": jnp.sum(weights) / jnp.maximum(count, 1.0)}
+        return self.combine_aux(weighted_cross_entropy(logits, ids, weights), aux), stats
 
     def hold_router_bias(self, old: Params, new: Params,
                          load: Optional[jax.Array]) -> Params:

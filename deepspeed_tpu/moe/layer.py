@@ -319,7 +319,8 @@ class MoE:
                                   or self.experts_held is not None):
             raise ValueError(
                 "the sigmoid router, a shared expert and a share of the "
-                "experts are the no-drop path's (capacity_factor=None)")
+                "experts (under either router: experts_held is the softmax "
+                "router's too) are the no-drop path's (capacity_factor=None)")
         if self.router not in ("softmax", "sigmoid_bias"):
             raise ValueError(f"router {self.router!r} is not 'softmax' or "
                              "'sigmoid_bias'")

@@ -53,10 +53,12 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
                    alibi: Optional[jax.Array] = None,
                    window: Optional[jax.Array] = None,
                    q_offset: Optional[jax.Array] = None,
-                   q_segment_ids: Optional[jax.Array] = None) -> jax.Array:
+                   q_segment_ids: Optional[jax.Array] = None,
+                   visible: Optional[jax.Array] = None) -> jax.Array:
     """Reference-semantics attention in pure XLA, GQA-NATIVE: K/V keep
     their kv_heads — query heads are grouped for the contractions, so
-    grouped-query models never materialize a repeated KV.
+    grouped-query models never materialize a repeated KV. ``visible``
+    [B, Sq, K] bool: a further mask, built by the caller (True = seen).
 
     Layout: inputs transpose to [B, H, S, D] up front so both einsums are
     plain batch matmuls over contiguous minor dims (XLA schedules the
@@ -99,6 +101,8 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
         q_seg = q_segment_ids if q_segment_ids is not None else segment_ids
         seg_mask = q_seg[:, :, None] == segment_ids[:, None, :]
         logits = jnp.where(seg_mask[:, None, None], logits, -1e30)
+    if visible is not None:
+        logits = jnp.where(visible[:, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     probs = checkpoint_name(probs, "attn_big")
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, vt)
@@ -200,7 +204,8 @@ FLASH_MIN_SEQ = 384
 FLASH_MIN_SEQ_WIDE_HEAD = 256    # head dim >= 128
 
 
-def kernel_is_default(q_shape, k_shape, backend: str) -> bool:
+def kernel_is_default(q_shape, k_shape, backend: str,
+                      blockdiff: Optional[int] = None) -> bool:
     """Whether ``flash_attention`` takes the in-repo blockwise kernel for
     this call when nothing forces a route: a rule of shape and platform
     alone. Off the TPU the XLA path stays (tier-1 dispatch and the
@@ -210,13 +215,17 @@ def kernel_is_default(q_shape, k_shape, backend: str) -> bool:
     from . import pallas_flash as _pf
     min_seq = FLASH_MIN_SEQ_WIDE_HEAD if q_shape[3] >= 128 else FLASH_MIN_SEQ
     return (q_shape[1] >= min_seq
-            and _pf.supports(q_shape, k_shape, compiled=True))
+            and _pf.supports(q_shape, k_shape, compiled=True, blockdiff=blockdiff))
 
 
-def choose_route(q_shape, k_shape, backend: str, mode: str) -> str:
-    """The whole decision of `flash_attention`: ``"kernel"`` (the in-repo
-    blockwise pair), ``"xla"`` (one shot) or ``"xla_chunked"``. A pure
-    function of the two shapes, the platform and `attn_mode`'s value.
+def choose_route(q_shape, k_shape, backend: str, mode: str,
+                 blockdiff: Optional[int] = None) -> str:
+    """The whole decision of `flash_attention` and of `blockdiff_attention`:
+    ``"kernel"`` (the in-repo blockwise pair), ``"xla"`` (one shot) or
+    ``"xla_chunked"``. A pure function of the two shapes, the platform,
+    `attn_mode`'s value and, under the block-diffusion mask, its block
+    length (``q_shape`` then holds both copies' ``2 L`` rows and ``k_shape``
+    the clean copy's ``L``; the crossover is asked of the ``2 L``).
 
     ``mode == "pallas"`` takes the kernel wherever it CAN run (interpret
     mode off the TPU relaxes the 128-lane tile requirement to plain
@@ -226,9 +235,10 @@ def choose_route(q_shape, k_shape, backend: str, mode: str) -> str:
     """
     if mode == "pallas":
         from . import pallas_flash as _pf
-        if _pf.supports(q_shape, k_shape, compiled=backend != "cpu"):
+        if _pf.supports(q_shape, k_shape, compiled=backend != "cpu",
+                        blockdiff=blockdiff):
             return "kernel"
-    elif mode == "" and kernel_is_default(q_shape, k_shape, backend):
+    elif mode == "" and kernel_is_default(q_shape, k_shape, backend, blockdiff):
         return "kernel"
     if q_shape[1] >= XLA_CHUNK_MIN_SEQ and backend != "cpu":
         return "xla_chunked"
@@ -290,6 +300,118 @@ def flash_attention(q: jax.Array,
                                       alibi_slopes, window)
     return _xla_attention(q, k, v, causal, scale, segment_ids, alibi_slopes,
                           window)
+
+
+# ---------------------------------------------------------------------------
+# the block-diffusion mask (BD3-LM's vectorised training form)
+# ---------------------------------------------------------------------------
+# A row holds L data tokens; the network runs on 2 L rows of activations, the
+# clean copy (rows 0..L-1) and then the noised copy (rows L..2L-1), the same
+# position for both copies of a token. With ``blk(i) = i // b``:
+#   a clean query i sees the clean key j   iff blk(j) <= blk(i);
+#   a noised query i sees the clean key j  iff blk(j) <  blk(i),
+#              and the noised key j        iff blk(j) == blk(i);
+#   a clean query sees no noised key;
+# all of it inside a packed document. One softmax a query over what it sees.
+
+def blockdiff_visible(q_noised, q_pos, k_noised, k_pos, block_length: int):
+    """Whether a key is visible to a query under the block-diffusion mask
+    (broadcasting; documents aside): THE definition, which the XLA route
+    computes and the kernel route is tested against."""
+    qb, kb = q_pos // block_length, k_pos // block_length
+    return jnp.where(k_noised, q_noised & (kb == qb),
+                     jnp.where(q_noised, kb < qb, kb <= qb))
+
+
+def _xla_blockdiff_attention(q, k, v, documents, block_length: int,
+                             scale: Optional[float], chunk: Optional[int]):
+    """The whole mask over the ``2 L x 2 L`` concatenation, built densely
+    and handed to `_xla_attention`; ``chunk``: queries at a time (a memory
+    bound)."""
+    S = q.shape[1]
+    at = jnp.arange(S)
+    noised, pos = at >= S // 2, at % (S // 2)
+    doc = jnp.concatenate([documents, documents], axis=1)          # [B, 2L]
+
+    def rows(lo, n):
+        seen = blockdiff_visible(noised[lo:lo + n, None], pos[lo:lo + n, None],
+                                 noised[None, :], pos[None, :], block_length)
+        seen = seen[None] & (doc[:, lo:lo + n, None] == doc[:, None, :])
+        return _xla_attention(q[:, lo:lo + n], k, v, False, scale, None, visible=seen)
+
+    if chunk is None or S <= chunk or S % chunk:
+        return rows(0, S)
+    return jnp.concatenate([rows(lo, chunk) for lo in range(0, S, chunk)], axis=1)
+
+
+def _own_block_attention(q, k, v, documents, block_length: int,
+                         scale: Optional[float]):
+    """The noised copy among itself: a query sees the keys of its own block
+    (and document), ``[B, L / b, heads, b, b]`` logits. -> (out [B, L, H,
+    D] normalised by its own sum, lse [B, H, L] float32), the partial
+    softmax ``merge_partials`` joins with the clean keys'. A query always
+    sees itself, so no row is empty."""
+    B, L, H, D = q.shape
+    kvH, b = k.shape[2], block_length
+    G, n = H // kvH, L // block_length
+    scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    qb = q.reshape(B, n, b, kvH, G, D)
+    kb, vb = k.reshape(B, n, b, kvH, D), v.reshape(B, n, b, kvH, D)
+    logits = jnp.einsum("bnqhgd,bnkhd->bnhgqk", qb, kb,
+                        preferred_element_type=jnp.float32) * scale
+    doc = documents.reshape(B, n, b)
+    same = doc[:, :, :, None] == doc[:, :, None, :]                 # [B,n,b,b]
+    logits = jnp.where(same[:, :, None, None], logits, -1e30)
+    lse = jax.nn.logsumexp(logits, axis=-1)                         # [B,n,h,g,b]
+    probs = jnp.exp(logits - lse[..., None]).astype(q.dtype)
+    out = jnp.einsum("bnhgqk,bnkhd->bnqhgd", probs, vb)
+    return (out.reshape(B, L, H, D),
+            lse.transpose(0, 2, 3, 1, 4).reshape(B, H, L))
+
+
+def blockdiff_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                        block_length: int,
+                        documents: Optional[jax.Array] = None,
+                        scale: Optional[float] = None) -> jax.Array:
+    """Attention under the block-diffusion mask (above). q [B, 2L, H, D],
+    k, v [B, 2L, kvH, D]: the clean copy's rows, then the noised copy's;
+    ``documents`` [B, L] int: each position's packed document (None: a row
+    is one). -> [B, 2L, H, D].
+
+    On the kernel route (`choose_route`) no tile without a visible pair is
+    multiplied: ONE launch of the flash pair over the clean keys for both
+    copies' queries (``pallas_flash``'s ``blockdiff``: the limit of a row in
+    ``q_pos``'s place, block skipping as under the causal mask; launches
+    ``flash_fwd_blockdiff`` / ``flash_bwd_blockdiff``), the noised copy's
+    own blocks as an einsum, and `merge_partials` for the two partial
+    softmaxes of a noised query (exact, differentiable through the LSE, safe
+    for a row with no clean key: a document's first block). Elsewhere the
+    whole mask in XLA, its queries in chunks from `XLA_CHUNK_MIN_SEQ` up on
+    a device."""
+    B, S, H, D = q.shape
+    L = S // 2
+    if S % 2 or L % block_length:
+        raise ValueError(f"the block-diffusion mask needs 2 x L rows, L a "
+                         f"multiple of the block length {block_length}; got {S}")
+    if documents is None:
+        documents = jnp.zeros((B, L), jnp.int32)
+    documents = documents.astype(jnp.int32)
+    mode, backend = attn_mode(), jax.default_backend()
+    route = choose_route(q.shape, (B, L) + k.shape[2:], backend, mode, block_length)
+    _log_path_once(f"blockdiff {route}")
+    if route != "kernel":
+        return _xla_blockdiff_attention(
+            q, k, v, documents, block_length, scale,
+            1024 if route == "xla_chunked" else None)
+    from . import pallas_flash as _pf
+    o, lse = _pf.flash_attention_with_lse(
+        q, k[:, :L], v[:, :L], causal=True, scale=scale, segment_ids=documents,
+        q_segment_ids=jnp.concatenate([documents, documents], axis=1),
+        blockdiff=block_length)
+    own, own_lse = _own_block_attention(q[:, L:], k[:, L:], v[:, L:], documents,
+                                        block_length, scale)
+    noised, _ = _pf.merge_partials(o[:, L:], lse[:, :, L:], own, own_lse)
+    return jnp.concatenate([o[:, :L], noised], axis=1)
 
 
 @functools.lru_cache(None)

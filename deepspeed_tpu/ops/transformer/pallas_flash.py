@@ -4,7 +4,8 @@ The training-attention slot's fast path on a TPU from sequence 384 up. The
 pair supports the full feature matrix the XLA reference path
 (`attention._xla_attention`) already has — causal (bottom-right aligned via
 ``q_offset``), GQA-NATIVE (K/V stay at kv_heads), sliding window (shared
-``sliding_window_allowed`` semantics), segment ids, ALiBi — with fp32
+``sliding_window_allowed`` semantics), segment ids, ALiBi — and one mask the
+XLA path builds densely, block diffusion's (below), with fp32
 accumulation and a saved row LSE residual, bound with ``jax.custom_vjp`` so
 the backward is blockwise too (no O(S^2) score re-materialization: backward
 FLOPs are recomputed per tile, memory stays O(S) + the LSE).
@@ -36,6 +37,23 @@ worth of keys however long the sequence is; the tiles are chosen knowing
 the window and the launches are named ``flash_fwd_window`` /
 ``flash_bwd_window``. A traced window keeps the whole-sequence grids and
 skips a tile's work alone.
+
+The block-diffusion mask (``blockdiff=b``; BD3-LM's vectorised training
+form, ``attention.blockdiff_attention``): the ``2 L`` query rows are a clean
+and a noised copy of ``L`` positions, the keys the clean copy's. With ``b`` a
+power of two a row's LAST visible key is ``pos | (b - 1)`` on the clean half
+(its own block whole) and ``(pos | (b - 1)) - b`` on the noised half
+(strictly earlier blocks), a limit that rises with the row inside each half:
+block skipping, the wholly-visible test and the tile's compare keep the
+causal mask's form with that limit in ``q_pos``'s place (``_block_limits``,
+``_tile_logits``: ONE definition for the forward and the backward), a q-block
+lies in one half, and the launches are named ``flash_fwd_blockdiff`` /
+``flash_bwd_blockdiff``. It composes with segment ids (packed documents) and
+grouped heads, and with nothing else: a window, ALiBi or ``q_offset`` beside
+it is refused. The noised copy's own blocks (``b x b`` squares on a diagonal,
+far narrower than any tile) are an einsum outside this module, joined by
+``merge_partials``; a noised row with no clean key (a document's first block)
+leaves here 0 with the sentinel LSE.
 
 Runs in interpret mode off-TPU (``pl.pallas_call(interpret=True)``) so the
 CPU tier-1 tests validate numerics of the same program the chip runs.
@@ -103,6 +121,10 @@ class FlashConfig:
     # the window where it is known when the launch is built (None: the
     # window, if any, is the traced scalar in SMEM): the grids are cut to it
     window: Optional[int] = None
+    # the block-diffusion mask, ``(block length b, data tokens L)``: the 2L
+    # query rows are a clean and a noised copy of L positions, the L keys
+    # the clean copy's (module docstring); None: causal / window
+    blockdiff: Optional[Tuple[int, int]] = None
 
 
 def _lanes(x: jax.Array, n: int) -> jax.Array:
@@ -161,13 +183,42 @@ def window_steps(tile: Tile, window: int, nq: int, nk: int) -> Tuple[int, int]:
     return k_steps, q_steps
 
 
+# The block-diffusion mask (``FlashConfig.blockdiff = (b, L)``). Query row r
+# of the 2L is position ``r`` of the clean copy (r < L) or ``r - L`` of the
+# noised one; with b a power of two its LAST visible clean key is
+# ``pos | (b - 1)`` for a clean row (its own block whole) and
+# ``(pos | (b - 1)) - b`` for a noised one (strictly earlier blocks; below 0:
+# none). A q-block lies in one half (its rows divide L), so the half is a
+# scalar a tile, and the causal compare ``q_pos >= k_pos`` keeps its form
+# with the limit in ``q_pos``'s place.
+
+def _half_of(cfg: FlashConfig, bq: int, i):
+    """(position of q-block ``i``'s first row in its half, what a row's
+    limit loses there: 0 on the clean half, b on the noised)."""
+    b, L = cfg.blockdiff
+    noised = i * bq >= L
+    return i * bq - jnp.where(noised, L, 0), jnp.where(noised, b, 0)
+
+
+def _block_limits(cfg: FlashConfig, bq: int, i):
+    """The last visible key of q-block ``i``'s first row and of its last
+    (``bq`` is a multiple of b: a block of positions is never cut)."""
+    b = cfg.blockdiff[0]
+    first, lost = _half_of(cfg, bq, i)
+    return first + b - 1 - lost, first + bq - 1 - lost
+
+
 def _should_run(cfg: FlashConfig, tile: Tile, i, j, info_ref):
     """Whether q-block i has ANY unmasked key in k-block j (block-level
     flop skip). info = [q_offset, window] (traced scalars in SMEM)."""
     if not cfg.causal:
         return True
-    q_off = info_ref[0]
     bq, bk = tile
+    if cfg.blockdiff is not None:
+        # the limit rises with the row inside each half: the block's last
+        # row has its highest
+        return _block_limits(cfg, bq, i)[1] >= j * bk
+    q_off = info_ref[0]
     # last q row of the block sits at or after the block's first key
     run = (q_off + (i + 1) * bq - 1) >= (j * bk)
     if cfg.use_window:
@@ -181,8 +232,10 @@ def _fully_visible(cfg: FlashConfig, tile: Tile, i, j, info_ref):
     """Whether EVERY key of k-block j is visible to every row of q-block i
     under the causal (and window) mask: such a tile needs no positions,
     compare or select. Only asked of causal configurations."""
-    q_off = info_ref[0]
     bq, bk = tile
+    if cfg.blockdiff is not None:
+        return _block_limits(cfg, bq, i)[0] >= j * bk + bk - 1
+    q_off = info_ref[0]
     # first q row sits at or after the block's last key
     full = (q_off + i * bq) >= (j * bk + bk - 1)
     if cfg.use_window:
@@ -241,7 +294,12 @@ def _tile_logits(cfg: FlashConfig, tile: Tile, q, k, i, j, info_ref,
             # per row)
             slope = slopes_ref[head_idx]
             s = s + slope * (k_pos - q_pos).astype(jnp.float32)
-        if cfg.causal and positional:
+        if cfg.causal and positional and cfg.blockdiff is not None:
+            first, lost = _half_of(cfg, bq, i)
+            at = lax.broadcasted_iota(jnp.int32, s.shape, q_axis) + first
+            cm = ((at | (cfg.blockdiff[0] - 1)) - lost) >= k_pos
+            mask = cm if mask is None else mask & cm
+        elif cfg.causal and positional:
             cm = q_pos >= k_pos
             if cfg.use_window:
                 w = info_ref[1]
@@ -387,7 +445,10 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
         compiler_params=_compiler_params(
             cfg, ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
-        name="flash_fwd" if cfg.window is None else "flash_fwd_window",
+        # a name of its own for each mask whose grids or compare are its own
+        # (the benchmark's readers find the launches by it)
+        name=("flash_fwd_blockdiff" if cfg.blockdiff is not None
+              else "flash_fwd" if cfg.window is None else "flash_fwd_window"),
     )(info, slopes, q, k, v, qseg_c, kseg_r)
 
 
@@ -583,7 +644,8 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
             compiler_params=_compiler_params(
                 cfg, ("parallel", "parallel", "arbitrary", "arbitrary")),
             interpret=cfg.interpret,
-            name="flash_bwd" if W is None else "flash_bwd_window",
+            name=("flash_bwd_blockdiff" if cfg.blockdiff is not None
+                  else "flash_bwd" if W is None else "flash_bwd_window"),
         )(info, slopes, q, k, v, kseg_c, qseg_r, do, lse, di)
         if slots == 1:
             return dq[0], dk, dv
@@ -768,18 +830,43 @@ def choose_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
 
 
 def supports(q_shape, k_shape, block_q: Optional[int] = None,
-             block_k: Optional[int] = None, compiled: bool = True) -> bool:
+             block_k: Optional[int] = None, compiled: bool = True,
+             blockdiff: Optional[int] = None) -> bool:
     """Shape gate. ``compiled=True`` (the TPU path) additionally requires
     tiles on the 128-lane layout; ``compiled=False`` (the interpret path
-    driven on CPU test meshes) accepts anything the tiles divide evenly."""
+    driven on CPU test meshes) accepts anything the tiles divide evenly.
+    ``blockdiff``: the block length of the block-diffusion mask, whose
+    queries are two copies of the keys' positions (``blockdiff_tiles``)."""
     B, Sq, H, D = q_shape
     Sk, kvH = k_shape[1], k_shape[2]
     if H % kvH:
         return False
     if D > NUM_LANES and D % NUM_LANES:
         return False
+    if blockdiff is not None:
+        return Sq == 2 * Sk and blockdiff_tiles(
+            Sk, D, blockdiff, block_q=block_q, block_k=block_k,
+            compiled=compiled) is not None
     return choose_tiles(Sq, Sk, D, block_q=block_q, block_k=block_k,
                         compiled=compiled) is not None
+
+
+def blockdiff_tiles(keys: int, head_dim: int, block_length: int,
+                    itemsize: int = 2, *, block_q: Optional[int] = None,
+                    block_k: Optional[int] = None, compiled: bool = True
+                    ) -> Optional[FlashTiles]:
+    """The tiles of a launch under the block-diffusion mask: the causal
+    choice for ``keys`` queries (a q-block then lies in one half of the
+    ``2 x keys`` rows), where the block length is a power of two that
+    divides both q tiles (a block of positions is never cut); else None."""
+    b = int(block_length)
+    if b < 1 or b & (b - 1) or keys % b:
+        return None
+    tiles = choose_tiles(keys, keys, head_dim, itemsize, causal=True,
+                         block_q=block_q, block_k=block_k, compiled=compiled)
+    if tiles is None or tiles.fwd[0] % b or tiles.bwd[0] % b:
+        return None
+    return tiles
 
 
 def static_window(window, sq: int, sk: int, q_offset=None) -> Optional[int]:
@@ -792,7 +879,8 @@ def static_window(window, sq: int, sk: int, q_offset=None) -> Optional[int]:
 
 
 def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
-             alibi_slopes, window, q_offset, block_q, block_k, interpret):
+             alibi_slopes, window, q_offset, block_q, block_k, interpret,
+             blockdiff=None):
     B, Sq, H, D = q.shape
     Sk, kvH = k.shape[1], k.shape[2]
     if H % kvH:
@@ -805,9 +893,20 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         window = None                # global, or a window that never binds
     cut = static_window(window, Sq, Sk, q_offset)
     interp = _auto_interpret() if interpret is None else interpret
-    tiles = choose_tiles(Sq, Sk, D, q.dtype.itemsize, causal=bool(causal),
-                         block_q=block_q, block_k=block_k,
-                         compiled=not interp, window=cut)
+    if blockdiff is not None:
+        if (not causal or window is not None or alibi_slopes is not None
+                or q_offset is not None or Sq != 2 * Sk):
+            raise ValueError(
+                "the block-diffusion mask takes 2 x keys query rows (a clean "
+                "and a noised copy) and no window, ALiBi or q_offset")
+        tiles = blockdiff_tiles(Sk, D, blockdiff, q.dtype.itemsize,
+                                block_q=block_q, block_k=block_k,
+                                compiled=not interp)
+        q_offset = 0
+    else:
+        tiles = choose_tiles(Sq, Sk, D, q.dtype.itemsize, causal=bool(causal),
+                             block_q=block_q, block_k=block_k,
+                             compiled=not interp, window=cut)
     if tiles is None:
         raise ValueError(f"seq lengths ({Sq}, {Sk}) have no legal tiles "
                          f"(block_q={block_q}, block_k={block_k})")
@@ -827,7 +926,8 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         use_seg=segment_ids is not None,
         use_alibi=alibi_slopes is not None,
         use_window=window is not None,
-        kv_heads=kvH, tiles=tiles, interpret=bool(interp), window=cut)
+        kv_heads=kvH, tiles=tiles, interpret=bool(interp), window=cut,
+        blockdiff=None if blockdiff is None else (int(blockdiff), Sk))
 
     segs = (None, None, None, None)
     if segment_ids is not None:
@@ -871,7 +971,8 @@ def flash_attention_with_lse(
         alibi_slopes: Optional[jax.Array] = None,
         window: Optional[jax.Array] = None,
         q_offset=None, block_q: Optional[int] = None,
-        block_k: Optional[int] = None, interpret: Optional[bool] = None
+        block_k: Optional[int] = None, interpret: Optional[bool] = None,
+        blockdiff: Optional[int] = None
 ) -> Tuple[jax.Array, jax.Array]:
     """Flash attention returning ``(out [B, Sq, H, D], lse [B, H, Sq])``.
 
@@ -880,11 +981,19 @@ def flash_attention_with_lse(
     the partial-softmax state ring attention accumulates across hops.
     Differentiable in q/k/v including through ``lse``. Tiles come from
     :func:`choose_tiles` unless ``block_q``/``block_k`` name them.
+
+    ``blockdiff``: a block length b (a power of two) puts the launch under
+    the block-diffusion mask's clean-key part: ``q`` holds ``2 x Sk`` rows,
+    the clean copy of the ``Sk`` positions and then the noised copy, ``k``
+    and ``v`` the clean copy's; a clean row sees the keys of its own and of
+    earlier blocks, a noised row those of strictly earlier blocks (a row
+    with none comes back 0 with the sentinel LSE, for ``merge_partials``);
+    ``segment_ids`` / ``q_segment_ids`` keep both inside a document.
     """
     B, Sq, H, D = q.shape
     cfg, q4, k3, v3, segs, slopes, info, dims = _prepare(
         q, k, v, causal, scale, segment_ids, q_segment_ids, alibi_slopes,
-        window, q_offset, block_q, block_k, interpret)
+        window, q_offset, block_q, block_k, interpret, blockdiff)
     _, _, kvH, G = dims
     o, lse = _flash(cfg, q4, k3, v3, segs, slopes, info)
     out = o.reshape(B, kvH, G, Sq, D).reshape(B, H, Sq, D)

@@ -589,6 +589,15 @@ class DeepSpeedEngine:
         # call ("kernel" / "xla" / "xla_chunked"; None until then, and for
         # a kind the model has no layer of).
         self.attn_totals = self._attention_of_model()
+        # The block-diffusion objective's record (None for every other
+        # model), plain values kept with telemetry off: the block length,
+        # the rows of activations a data token costs every layer (2: a clean
+        # and a noised copy), the fused steps so far and, once a step is
+        # traced, the route ``attention.choose_route`` gives the mask's call.
+        # The LAST step's masked share of the positions and mean weight
+        # (1 / t) of a masked one stay on the device beside the loss:
+        # ``diffusion_last_step()`` fetches them.
+        self.diffusion_totals = self._diffusion_of_model()
         # Optimizer-kernel counters, kept with telemetry off and filled from
         # the static bucket plan when a step that updates is traced: the
         # path ("pallas" / "xla"; None until then), and on the kernel path
@@ -1128,15 +1137,25 @@ class DeepSpeedEngine:
         """Whether the fused step returns the model's device-side
         statistics as a last output (the no-drop MoE path's rows per
         expert); every other model's program is as it was."""
-        return self.moe_totals["path"] == "dropless"
+        return (self.moe_totals["path"] == "dropless"
+                or self.diffusion_totals is not None)
 
     def moe_expert_rows(self):
         """The last fused step's assignments per expert, ``[layers,
         experts]`` int32, fetched now (the step itself never syncs on
         them); None off the no-drop MoE path or before a step."""
-        if not self._step_stats:
+        if not self._step_stats or "moe_expert_rows" not in self._step_stats:
             return None
         return np.asarray(self._step_stats["moe_expert_rows"])
+
+    def diffusion_last_step(self) -> Optional[Dict[str, float]]:
+        """The last fused step's ``masked_share`` (masked positions over all)
+        and ``mean_weight`` (the mean 1 / t of a masked position), fetched
+        now; None for another objective or before a step."""
+        if not self._step_stats or "diffusion_masked_share" not in self._step_stats:
+            return None
+        return {"masked_share": float(self._step_stats["diffusion_masked_share"]),
+                "mean_weight": float(self._step_stats["diffusion_mean_weight"])}
 
     @functools.cached_property
     def _remat_room_bytes(self) -> Optional[int]:
@@ -1189,7 +1208,8 @@ class DeepSpeedEngine:
             loss, stats = self.model.loss_and_stats(params, batch,
                                                     **self._remat_kw())
             out = loss, (stats,)
-            self._count_grouped_products(batch, stats["moe_expert_rows"].shape[0])
+            if "moe_expert_rows" in stats:
+                self._count_grouped_products(batch, stats["moe_expert_rows"].shape[0])
         else:
             out = self.model.loss(params, batch, **self._remat_kw()), ()
         kept = self.remat_totals
@@ -2332,10 +2352,12 @@ class DeepSpeedEngine:
                 flat = setup_spans.flat_totals(
                     setup=self.setup_totals, moe=self.moe_totals,
                     attn=self.attn_totals, opt_kernel=self.opt_kernel_totals,
-                    remat=self.remat_totals)
+                    remat=self.remat_totals, diffusion=self.diffusion_totals or {})
                 self._totals_flat = (self._setup.version, flat)
-            if "moe.steps" in flat:     # the one counter a step writes
+            if "moe.steps" in flat:     # the counters a step writes
                 flat["moe.steps"] = self.moe_totals["steps"]
+            if "diffusion.steps" in flat:
+                flat["diffusion.steps"] = self.diffusion_totals["steps"]
             with jax.profiler.TraceAnnotation("engine_totals", **flat):
                 pass
 
@@ -2359,14 +2381,29 @@ class DeepSpeedEngine:
                 "kv_heads": self.model.config.kv_heads,
                 "route": {"window": None, "full": None}}
 
+    def _diffusion_of_model(self) -> Optional[Dict[str, Any]]:
+        cfg = getattr(self.model, "config", None)
+        if not getattr(cfg, "diffusion", False):
+            return None
+        return {"block_length": cfg.block_length,
+                "rows_per_token": self.model.rows_per_token,
+                "steps": 0, "route": None}
+
     def _count_attention(self, batch) -> None:
-        """``attn_totals['route']``: host arithmetic from static shapes while
-        the step is traced."""
+        """``attn_totals['route']`` (``diffusion_totals['route']`` under the
+        block-diffusion mask): host arithmetic from static shapes while the
+        step is traced."""
         if not self.attn_totals or "input_ids" not in batch:
             return
         from ..ops.transformer import attention
         cfg = self.model.config
         b, s = batch["input_ids"].shape[:2]
+        if self.diffusion_totals is not None:
+            self.diffusion_totals["route"] = attention.choose_route(
+                (b, 2 * s, cfg.num_heads, cfg.head_dim),
+                (b, s, cfg.kv_heads, cfg.head_dim), jax.default_backend(),
+                attention.attn_mode(), cfg.block_length)
+            return
         route = attention.choose_route(
             (b, s, cfg.num_heads, cfg.head_dim), (b, s, cfg.kv_heads, cfg.head_dim),
             jax.default_backend(), attention.attn_mode())
@@ -2386,6 +2423,7 @@ class DeepSpeedEngine:
         from ..ops.transformer import pallas_gmm, pallas_segment_sum
         moe, cfg = self.model._moe, self.model.config
         b, s = batch["input_ids"].shape[:2]
+        s *= getattr(self.model, "rows_per_token", 1)   # a noised copy's rows too
         kept = self.remat_totals["saved"] if cfg.remat else None
         counts = {route: dict.fromkeys(pallas_gmm.KINDS, 0) for route in ("kernel", "xla")}
         for name, m, k, n, g in moe.grouped_products(b * s):
@@ -2411,9 +2449,10 @@ class DeepSpeedEngine:
     def _count_moe(self, stats) -> None:
         """The fused step's MoE counters; the step's statistics are kept
         as the device arrays they are."""
-        if self.moe_totals["path"] is None:
-            return
-        self.moe_totals["steps"] += 1
+        if self.diffusion_totals is not None:
+            self.diffusion_totals["steps"] += 1
+        if self.moe_totals["path"] is not None:
+            self.moe_totals["steps"] += 1
         self._step_stats = stats[0] if stats else None
 
     # ------------------------------------------------------------------

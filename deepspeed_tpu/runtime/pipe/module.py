@@ -55,6 +55,11 @@ class PipelineModule:
             raise ValueError(
                 "PipelineModule supports causal pre-LN decoders; encoder "
                 "configs (bidirectional/post-LN/MLM head) are not pipelined")
+        if config.diffusion:
+            raise NotImplementedError(
+                "PipelineModule trains the next-token objective: "
+                "objective='block_diffusion' (a clean and a noised copy of "
+                "every row, its own mask and loss weights) is not pipelined")
         self.config = config
         self.num_stages = num_stages
         self.layers_per_stage = config.num_layers // num_stages

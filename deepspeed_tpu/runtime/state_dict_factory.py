@@ -1104,6 +1104,9 @@ def _register_builtins() -> None:
                           instella_moe.checkpoint_params)
     from ..models import afmoe
     register_architecture("afmoe", afmoe.config_kwargs, afmoe.checkpoint_params)
+    from ..models import sdar_moe
+    register_architecture("sdar_moe", sdar_moe.config_kwargs,
+                          sdar_moe.checkpoint_params)
 
 
 _register_builtins()

@@ -62,7 +62,7 @@ def test_architecture_registry_builtin():
     assert supported_architectures() == \
         ["afmoe", "bert", "bloom", "deepseek_v3", "distilbert", "falcon", "gpt2", "gpt_neo",
          "gpt_neox", "gptj", "internlm", "llama", "mistral", "mixtral",
-         "opt", "phi", "qwen2", "roberta"]
+         "opt", "phi", "qwen2", "roberta", "sdar_moe"]
     spec = get_architecture("falcon")
     cfg = spec.config_fn({"model_type": "falcon", "vocab_size": 128,
                           "hidden_size": 64, "num_hidden_layers": 2,

@@ -337,11 +337,12 @@ def test_what_the_new_fields_refuse():
         spec.params_fn(None, {})
 
 
-@pytest.mark.parametrize("preset", ["instella-tiny", "afmoe-tiny", "olmoe-tiny"])
+@pytest.mark.parametrize("preset", ["instella-tiny", "afmoe-tiny", "olmoe-tiny", "sdar-tiny"])
 def test_the_rows_back_to_the_tokens_are_counted_from_static_shapes(preset):
     """``moe_totals["combine_route"]`` / ``["combine_rows_moved"]`` of a traced
-    step: for a share (the Instella and Trinity presets hold 8 of 16
-    experts) the route ``pallas_segment_sum.choose_route`` gives the sum's
+    step: for a share (the Instella, Trinity and SDAR presets hold 8 of 16
+    experts; under SDAR's block-diffusion objective a token is two rows) the
+    route ``pallas_segment_sum.choose_route`` gives the sum's
     static shape and the buffer's rows a pass of it, with remat three passes
     a layer; with every expert held (OLMoE) None and no rows."""
     import deepspeed_tpu
@@ -357,16 +358,17 @@ def test_the_rows_back_to_the_tokens_are_counted_from_static_shapes(preset):
     ids = np.random.default_rng(0).integers(0, cell.config["vocab_size"] - 1, (8, 32))
     engine.train_batch({"input_ids": ids})
     moe, layers = model._moe, engine.moe_expert_rows().shape[0]
-    back = moe.rows_back(8 * 32)
+    n = 8 * 32 * model.rows_per_token
+    back = moe.rows_back(n)
     if preset == "olmoe-tiny":
         assert back is None and moe.experts_held is None
         assert engine.moe_totals["combine_route"] is None
         assert engine.moe_totals["combine_rows_moved"] == 0
         return
     rows, tokens, h = back
-    assert (rows, tokens, h) == (held_capacity(8 * 32 * moe.top_k, 8, 16), 8 * 32,
+    assert (rows, tokens, h) == (held_capacity(n * moe.top_k, 8, 16), n,
                                  cell.config["hidden_size"])
-    assert rows <= 8 * 32 * moe.top_k         # at most the slabs' tokens x k (512s: all, here)
+    assert rows <= n * moe.top_k         # at most the slabs' tokens x k (512s: all, here)
     assert engine.moe_totals["combine_route"] == pallas_segment_sum.choose_route(
         rows, tokens, h, jnp.float32, "cpu", engine.mesh.size) == "xla"
     assert engine.moe_totals["combine_rows_moved"] == layers * 3 * rows

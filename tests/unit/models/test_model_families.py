@@ -12,7 +12,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.models import (afmoe_model, bert_model, bloom_model, falcon_model,
                                   gpt2_model, gpt_neo_model, gpt_neox_model,
                                   gptj_model, llama_model, mixtral_model,
-                                  opt_model, phi_model, roberta_model)
+                                  opt_model, phi_model, roberta_model,
+                                  sdar_moe_model)
 
 TINY = dict(max_seq_len=32, vocab_size=128, remat=False, dtype=jnp.float32)
 
@@ -42,6 +43,9 @@ FAMILIES = {
     # sliding and full layers mixed, rope on the sliding ones only, sandwich
     # norms, gated attention over grouped heads, dense then expert layers
     "afmoe": lambda: afmoe_model("afmoe-tiny", **TINY),
+    # the block-diffusion objective: a clean and a noised copy of every row
+    # under one mask; ``apply`` is the first denoising pass of every block
+    "sdar_moe": lambda: sdar_moe_model("sdar-tiny", **TINY),
 }
 
 

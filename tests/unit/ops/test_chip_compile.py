@@ -98,11 +98,34 @@ class TestCompilesForV5e:
                          chip((B, S, H, D), BF16), chip((B, S, kvH, D), BF16),
                          chip((B, S, kvH, D), BF16), chip((B, S), I32))
 
+    def test_blockdiff_attention_at_8k(self, chip, monkeypatch):
+        """The sdar-30b-a3b cell's attention core: 32 query heads over 4 key
+        heads of 128, 16,384 rows (a clean and a noised copy of 8,192
+        positions) under the block-diffusion mask with b 4 and documents:
+        the flash pair over the clean keys, the own-block einsum and the
+        merge, under the launches' own names."""
+        from deepspeed_tpu.ops.transformer import attention
+        B, L, H, kvH, D = 1, 8192, 32, 4, 128
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv("DSTPU_ATTN", raising=False)
+
+        def loss(q, k, v, doc):
+            return jnp.sum(attention.blockdiff_attention(q, k, v, 4, doc).astype(F32))
+
+        args = (chip((B, 2 * L, H, D), BF16), chip((B, 2 * L, kvH, D), BF16),
+                chip((B, 2 * L, kvH, D), BF16), chip((B, L), I32))
+        fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
+        compile_for_chip(fn, *args)
+        text = jax.jit(fn).lower(*args).as_text()
+        assert "flash_fwd_blockdiff" in text and "flash_bwd_blockdiff" in text
+
     @pytest.mark.parametrize("m,k,n,g", [
         (32768, 2048, 1024, 64),    # olmoe-1b-7b.train.seq4k, wi_gate / wi_up
         (32768, 1024, 2048, 64),    # its wo
         (36864, 2048, 1408, 8),     # instella-moe-16b-a3b.train.seq8k: 11 x 128
         (36864, 1408, 2048, 8),
+        (49152, 2048, 768, 16),     # sdar-30b-a3b.train.bd8k: the narrowest experts
+        (49152, 768, 2048, 16),
     ])
     def test_grouped_matmul_fwd_bwd(self, chip, m, k, n, g):
         """The three grouped-matmul kernels at the MoE cells' shapes, with

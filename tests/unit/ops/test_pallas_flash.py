@@ -579,15 +579,33 @@ def test_route_rule_is_shape_and_platform_only(q_shape, k_shape, monkeypatch):
     ((2, 128, 8, 64), 2, "tpu", "pallas", "kernel"),  # forced under the crossover
     ((2, 100, 8, 64), 2, "tpu", "pallas", "xla"),     # no compiled tile
     ((1, 4096, 8, 192), 8, "tpu", "pallas", "xla_chunked"),
+    # the sdar-30b-a3b cell: the block-diffusion mask (a block length in the
+    # key heads' place: (kv heads, b)), 32q/4kv x 128, 2 x 8192 query rows (a
+    # clean and a noised copy) over the clean copy's 8192 keys, segment ids
+    ((1, 16384, 32, 128), (4, 4), "tpu", "", "kernel"),
+    ((1, 16384, 32, 128), (4, 4), "tpu", "xla", "xla_chunked"),
+    ((1, 16384, 32, 128), (4, 4), "cpu", "", "xla"),
+    ((1, 16384, 32, 128), (4, 4), "cpu", "pallas", "kernel"),
+    ((8, 128, 8, 16), (2, 4), "cpu", "pallas", "kernel"),   # the tiny preset, interpret
+    ((8, 128, 8, 16), (2, 4), "tpu", "pallas", "xla"),      # head dim and tiles off the lanes
+    ((1, 16384, 32, 128), (4, 6), "tpu", "", "xla_chunked"),  # b no power of two
+    ((1, 16384, 32, 128), (4, 256), "tpu", "", "kernel"),   # a block of 256 divides the tiles
+    ((1, 16384, 32, 128), (4, 2048), "tpu", "", "xla_chunked"),  # wider than a tile: never cut
+    ((1, 256, 32, 128), (4, 4), "tpu", "", "kernel"),       # 2 x 128 rows: at the crossover
+    ((1, 128, 32, 128), (4, 4), "tpu", "", "xla"),          # 2 x 64: under it, and no tile
 ])
 def test_route_table(q_shape, kv_heads, backend, mode, route, monkeypatch):
-    """`choose_route` is the whole decision of `flash_attention`, a pure
-    function: the TPU's rows are checked here on the CPU, and an
-    environment that asks for another route moves none of them."""
+    """`choose_route` is the whole decision of `flash_attention` and of
+    `blockdiff_attention`, a pure function: the TPU's rows are checked here on
+    the CPU, and an environment that asks for another route moves none of
+    them."""
     from deepspeed_tpu.ops.transformer import attention as attn_mod
-    k_shape = q_shape[:2] + (kv_heads,) + q_shape[3:]
+    blockdiff = None
+    if isinstance(kv_heads, tuple):       # the mask's rows: keys are half the queries
+        kv_heads, blockdiff = kv_heads
+    k_shape = (q_shape[0], q_shape[1] // (2 if blockdiff else 1), kv_heads, q_shape[3])
     monkeypatch.setenv("DSTPU_ATTN", "xla" if route == "kernel" else "pallas")
-    assert attn_mod.choose_route(q_shape, k_shape, backend, mode) == route
+    assert attn_mod.choose_route(q_shape, k_shape, backend, mode, blockdiff) == route
 
 
 def test_attention_reads_one_environment_variable():
@@ -626,3 +644,86 @@ def test_log_names_the_path_and_the_tiles(eight_devices, monkeypatch):
     attn_mod.flash_attention(q, k, v, causal=True)       # CPU: XLA
     assert said == ["pallas_flash_inrepo, tiles (block_q x block_k) "
                     "forward 512x512 backward 1024x1024", "xla"]
+
+
+# ---------------------------------------------------------------------------
+# the block-diffusion mask (a clean and a noised copy of every row)
+# ---------------------------------------------------------------------------
+
+def _blockdiff_case(L=128, b=4, H=4, kvH=2, D=16, seed=0):
+    """2 L query rows (clean, then noised), their keys and values, and
+    documents whose ends cut blocks: one ends ON a block's last position (the
+    next document's first block has no clean key behind it), one inside a
+    block, one of a single token."""
+    rng = np.random.default_rng(seed)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 2 * L, h, D)), jnp.float32) * 0.5
+               for h in (H, kvH, kvH))
+    ends = np.zeros((2, L), np.int32)
+    ends[0, [7, 21, 22, 70]] = 1
+    ends[1, [0, L - 2]] = 1
+    return q, k, v, jnp.asarray(np.cumsum(ends, 1) - ends, jnp.int32)
+
+
+def _dense_blockdiff(q, k, v, doc, b):
+    from deepspeed_tpu.ops.transformer.attention import _xla_blockdiff_attention
+    return _xla_blockdiff_attention(q, k, v, doc, b, None, None)
+
+
+@pytest.mark.parametrize("b,tiles", [(4, None), (4, (32, 32)), (4, (16, 64)),
+                                     (4, (64, 16)), (16, (32, 32)), (32, (32, 64))])
+def test_blockdiff_kernel_route_matches_the_dense_mask(b, tiles, monkeypatch):
+    """`blockdiff_attention` on the kernel route (one flash launch over the
+    clean keys for both copies' queries, the own-block einsum, the merge)
+    against the whole mask built densely, forward and backward, over tiles
+    that make skipped, wholly visible and edge blocks in both halves; a
+    document's first block (no clean key: the kernel's row is empty and the
+    merge takes the own block alone) and a block cut by a document's end."""
+    from deepspeed_tpu.ops.transformer import attention as attn_mod
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    q, k, v, doc = _blockdiff_case()
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    if tiles is not None:
+        real = pf.flash_attention_with_lse
+        monkeypatch.setattr(pf, "flash_attention_with_lse", functools.partial(
+            real, block_q=tiles[0], block_k=tiles[1]))
+    w = jnp.asarray(np.random.default_rng(1).normal(size=q.shape), jnp.float32)
+    run = lambda fn: jax.value_and_grad(
+        lambda q, k, v: jnp.sum(fn(q, k, v) * w), argnums=(0, 1, 2))(q, k, v)
+    kernel = lambda q, k, v: attn_mod.blockdiff_attention(q, k, v, b, doc)
+    np.testing.assert_allclose(kernel(q, k, v), _dense_blockdiff(q, k, v, doc, b), **FP32_TOL)
+    (got, got_g), (want, want_g) = run(kernel), run(
+        lambda q, k, v: _dense_blockdiff(q, k, v, doc, b))
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for a, c in zip(got_g, want_g):
+        np.testing.assert_allclose(a, c, **GRAD_TOL)
+
+
+def test_blockdiff_launch_multiplies_no_hidden_quadrant():
+    """The launch under the mask is ONE flash pair over the clean keys: its
+    LSE says which rows saw a key (a document's first block's noised rows:
+    none), its launches carry the mask's name, and its grids are the causal
+    ones over the keys for twice the q-blocks."""
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    L, b = 128, 4
+    q, k, v, doc = _blockdiff_case(L, b)
+    out, lse = flash_attention_with_lse(
+        q, k[:, :L], v[:, :L], causal=True, segment_ids=doc,
+        q_segment_ids=jnp.concatenate([doc, doc], 1), blockdiff=b, block_q=32, block_k=32)
+    lse = np.asarray(lse)
+    # row 0's documents start at 0, 8, 22, 23, 71: the noised rows of their
+    # first blocks have no clean key, every clean row has itself
+    assert (lse[0, :, :L] > MASK_VALUE / 2).all()
+    empty = {L + p for start in (0, 8, 22, 23, 71) for p in range(start, (start | 3) + 1)}
+    assert {int(r) for r in np.flatnonzero(lse[0, 0] < MASK_VALUE / 2)} == empty
+    assert not np.asarray(out)[0, sorted(empty)].any()
+    text = str(jax.make_jaxpr(lambda q, k, v: jax.grad(lambda q: jnp.sum(
+        flash_attention_with_lse(q, k, v, causal=True, blockdiff=b)[0]))(q))(
+            q, k[:, :L], v[:, :L]))
+    assert "flash_fwd_blockdiff" in text and "flash_bwd_blockdiff" in text
+    tiles = pf.blockdiff_tiles(8192, 128, 4)
+    assert tiles == pf.choose_tiles(8192, 8192, 128, causal=True)
+    assert pf.blockdiff_tiles(8192, 128, 6) is None and pf.blockdiff_tiles(8190, 128, 4) is None
+    with pytest.raises(ValueError, match="block-diffusion"):
+        flash_attention_with_lse(q, k[:, :L], v[:, :L], blockdiff=b, window=16)
+    with pytest.raises(ValueError, match="block-diffusion"):
+        flash_attention_with_lse(q, k, v, blockdiff=b)

@@ -138,6 +138,9 @@ KERNEL_NAMES = {
     # the flash pair with its grids cut to a static window (PR 34): a name of
     # their own, so that a reader tells a sliding layer's launch from a full one's
     "flash_fwd_window", "flash_bwd_window",
+    # the flash pair under the block-diffusion mask (PR 39): one launch over
+    # the clean keys for a clean and a noised copy's queries
+    "flash_fwd_blockdiff", "flash_bwd_blockdiff",
     # a share's rows back to the tokens (PR 38), under ``mlp/moe/combine`` and
     # ``mlp/moe/dispatch``: ``train_moe_dispatch_ms`` finds it by its scope
     "segment-sum"}
@@ -165,7 +168,7 @@ def test_every_pallas_call_has_a_name(site):
 def test_kernel_names_are_distinct_and_complete():
     assert len(PALLAS_SITES) == 18
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 20
+    assert len(set(names)) == len(names) == 22
     assert set(names) == KERNEL_NAMES
 
 

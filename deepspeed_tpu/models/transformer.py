@@ -1402,7 +1402,7 @@ class TransformerLM:
             if attention_mask is not None:
                 raise ValueError("packed documents (document_separator) take "
                                  "no attention_mask: a row is full")
-            attention_mask = self._documents(input_ids)
+            attention_mask = documents = self._documents(input_ids)
         block_fn = functools.partial(self._block_fn, attention_mask)
         if layer_mask is None:
             keep = jnp.ones((c.num_layers,), c.dtype)
@@ -1498,13 +1498,62 @@ class TransformerLM:
                     mtp_x = mtp_x[0]
             if rows is not None:
                 rows = jnp.concatenate([rows, mtp_rows[None]], axis=0)
-        return x, aux, self._stats_of(rows), mtp_x
+        stats = self._stats_of(rows)
+        if c.document_separator is not None:
+            stats = {**stats, **self._attn_tile_stats(documents)}
+        return x, aux, stats, mtp_x
 
     def _documents(self, input_ids: jax.Array) -> jax.Array:
         """Each position's document in a packed row, [B, S] int32: the
         separators BEFORE it (a separator ends its own document)."""
         ends = (input_ids == self.config.document_separator).astype(jnp.int32)
         return jnp.cumsum(ends, axis=1) - ends
+
+    @functools.cached_property
+    def attn_tile_kinds(self) -> Tuple[Tuple[str, int], ...]:
+        """The kinds of attention launch that a step's tile count
+        (``attn_tiles`` of its statistics) has a line for, ``(name, window,
+        0 = none)``: none without packed documents; ``blockdiff`` under the
+        block-diffusion objective; else ``window`` for the layers' static
+        window (``window_<w>`` where they have several) and ``full``."""
+        c = self.config
+        if c.document_separator is None:
+            return ()
+        if c.diffusion:
+            return (("blockdiff", 0),)
+        windows = sorted({w for w, _ in self._kinds}, reverse=True)
+        several = sum(map(bool, windows)) > 1
+        return tuple(("full" if not w else f"window_{w}" if several else "window", w)
+                     for w in windows)
+
+    def _attn_tile_stats(self, documents: jax.Array) -> Dict[str, jax.Array]:
+        """``attn_tiles``, int32 ``[kinds, 2 (forward, backward), 2 (the
+        tiles the position test alone runs, the tiles run)]``: how far the
+        row's documents cut one flash launch's tiles a head, for each of
+        `attn_tile_kinds`, by the kernels' own table and predicate
+        (``pallas_flash.tiles_run``) at the tiles the kernel route takes for
+        the shape. Nothing where the shape has no legal tile."""
+        from ..ops.transformer import pallas_flash as pf
+        c = self.config
+        L = documents.shape[1]
+        shape = dict(head_dim=c.head_dim, itemsize=jnp.dtype(c.dtype).itemsize,
+                     compiled=jax.default_backend() != "cpu")
+        lines = []
+        for _, window in self.attn_tile_kinds:
+            if c.diffusion:
+                tiles = pf.blockdiff_tiles(L, block_length=c.block_length, **shape)
+                q_ids = jnp.concatenate([documents, documents], axis=1)
+                mask = dict(blockdiff=c.block_length)
+            else:
+                window = pf.static_window(window, L, L)
+                tiles = pf.choose_tiles(L, L, causal=c.causal, window=window, **shape)
+                q_ids, mask = documents, dict(causal=c.causal, window=window)
+            if tiles is None:
+                return {}
+            lines.append(jnp.stack([
+                jnp.stack(pf.tiles_run(q_ids, documents, tile, **mask))
+                for tile in (tiles.fwd, tiles.bwd)]))
+        return {"attn_tiles": jnp.stack(lines)}
 
     @functools.cached_property
     def scan_plan(self) -> Tuple[Tuple[Any, ...], int, Tuple[Any, ...]]:

@@ -55,6 +55,25 @@ far narrower than any tile) are an einsum outside this module, joined by
 ``merge_partials``; a noised row with no clean key (a document's first block)
 leaves here 0 with the sentinel LSE.
 
+The table of documents (a launch with segment ids; none is built, and no
+operand added, without): ``_prepare`` reduces the ids once to each block's
+LOWEST and HIGHEST id (``block_ranges``: for the forward's tiles and for the
+backward's, the q side from ``q_segment_ids`` where given, under
+``blockdiff`` the ``2 L`` rows'), a few hundred int32 a kernel that ride
+scalar prefetch (SMEM) beside ``info`` and ``slopes``, so kernel bodies and
+index maps both read them, and are residuals of the ``custom_vjp`` like the
+ids. "Does q-block i have ANY visible key in k-block j" (``_should_run``, the
+one definition behind ``_for_visible_tile``, the forward's ``k_blk``, the
+backward's ``q_blk`` and its zero-write of a skipped pair's dq slot) then also
+asks ``k_hi[j] >= q_lo[i] and k_lo[j] <= q_hi[i]``. Two ranges that do not
+meet hold no equal pair, whatever the order of the ids, so no pair of such a
+tile would pass ``_tile_logits``' compare and skipping it changes no bit
+(a masked tile added exact zeros): the test is SOUND for any ids (ring hops,
+random ids) and TIGHT for packed documents, whose ids rise along the row: a
+k-block wholly in an earlier document is neither fetched nor multiplied. A
+tile the test lets through keeps its segment compare. ``tiles_run`` counts
+what the table spares, by the same predicate over every (i, j) at once.
+
 Runs in interpret mode off-TPU (``pl.pallas_call(interpret=True)``) so the
 CPU tier-1 tests validate numerics of the same program the chip runs.
 
@@ -72,7 +91,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -208,30 +227,79 @@ def _block_limits(cfg: FlashConfig, bq: int, i):
     return first + b - 1 - lost, first + bq - 1 - lost
 
 
-def _should_run(cfg: FlashConfig, tile: Tile, i, j, info_ref):
+class _BlockDocs(NamedTuple):
+    """One batch row's line of a launch's table of documents (module
+    docstring; :func:`block_ranges` makes it): ``ref`` is the table flat,
+    int32 ``[batch rows x 2 (nq + nk)]``, in SMEM for a kernel or an index
+    map (a plain array for :func:`tiles_run`), ``row`` the batch row
+    (folded row // kv_heads), ``nq`` / ``nk`` the launch's blocks."""
+    ref: Any
+    row: Any
+    nq: int
+    nk: int
+
+    def meet(self, i, j):
+        """Whether q-block ``i``'s range of segment ids and k-block ``j``'s
+        meet. Where they do not, no id of the one equals an id of the other,
+        whatever the order of the ids: the tile holds no pair that passes
+        ``_tile_logits``' compare. (A step of a static window's grid may name
+        a block past the last: it reads the last's line and is hidden by
+        position.)"""
+        nq, nk = self.nq, self.nk
+        # (``lax`` and not ``jnp``: the test is traced in every index map and
+        # kernel body of every launch, a dozen times a flash pair)
+        q = lax.add(lax.mul(self.row, 2 * (nq + nk)), lax.min(i, nq - 1))
+        k = lax.add(lax.mul(self.row, 2 * (nq + nk)), lax.min(j, nk - 1) + 2 * nq)
+        q_lo, q_hi = self.ref[q], self.ref[lax.add(q, nq)]
+        k_lo, k_hi = self.ref[k], self.ref[lax.add(k, nk)]
+        return lax.bitwise_and(lax.ge(k_hi, q_lo), lax.le(k_lo, q_hi))
+
+
+def _split_prefetch(cfg: FlashConfig, refs):
+    """A kernel's arguments as (the scalar-prefetch operands ``(info,
+    slopes[, table])``, the rest): the table is there where the launch has
+    segment ids."""
+    n = 3 if cfg.use_seg else 2
+    return refs[:n], refs[n:]
+
+
+def _docs_of(cfg: FlashConfig, prefetch, b, blocks) -> Optional[_BlockDocs]:
+    """Folded row ``b``'s documents among a launch's scalar-prefetch
+    operands ``(info, slopes[, table])``; None for a launch without ids."""
+    if not cfg.use_seg:
+        return None
+    return _BlockDocs(prefetch[2], lax.div(b, cfg.kv_heads), *blocks)
+
+
+def _should_run(cfg: FlashConfig, tile: Tile, i, j, info_ref,
+                docs: Optional[_BlockDocs] = None):
     """Whether q-block i has ANY unmasked key in k-block j (block-level
-    flop skip). info = [q_offset, window] (traced scalars in SMEM)."""
+    flop skip): by position, info = [q_offset, window] (traced scalars in
+    SMEM), and, where the launch has segment ids, by the two blocks' ranges
+    of documents."""
     if not cfg.causal:
-        return True
+        return True if docs is None else docs.meet(i, j)
     bq, bk = tile
     if cfg.blockdiff is not None:
         # the limit rises with the row inside each half: the block's last
         # row has its highest
-        return _block_limits(cfg, bq, i)[1] >= j * bk
-    q_off = info_ref[0]
-    # last q row of the block sits at or after the block's first key
-    run = (q_off + (i + 1) * bq - 1) >= (j * bk)
-    if cfg.use_window:
-        w = info_ref[1]
-        # first q row within window of the block's last key
-        run = run & ((w <= 0) | ((q_off + i * bq) - (j * bk + bk - 1) < w))
-    return run
+        run = _block_limits(cfg, bq, i)[1] >= j * bk
+    else:
+        q_off = info_ref[0]
+        # last q row of the block sits at or after the block's first key
+        run = (q_off + (i + 1) * bq - 1) >= (j * bk)
+        if cfg.use_window:
+            w = info_ref[1]
+            # first q row within window of the block's last key
+            run = run & ((w <= 0) | ((q_off + i * bq) - (j * bk + bk - 1) < w))
+    return run if docs is None else run & docs.meet(i, j)
 
 
 def _fully_visible(cfg: FlashConfig, tile: Tile, i, j, info_ref):
     """Whether EVERY key of k-block j is visible to every row of q-block i
     under the causal (and window) mask: such a tile needs no positions,
-    compare or select. Only asked of causal configurations."""
+    compare or select (its segment compare, where it has ids, stays). Only
+    asked of causal configurations."""
     bq, bk = tile
     if cfg.blockdiff is not None:
         return _block_limits(cfg, bq, i)[0] >= j * bk + bk - 1
@@ -245,18 +313,21 @@ def _fully_visible(cfg: FlashConfig, tile: Tile, i, j, info_ref):
     return full
 
 
-def _for_visible_tile(cfg: FlashConfig, tile: Tile, i, j, info_ref, body,
-                      also=None):
-    """Run ``body(positional)`` for a tile with any unmasked key; tiles
-    wholly below the diagonal run it without the positional mask.
-    ``also``: a further condition of the step (a static window's grid: the
-    block the step names exists)."""
-    if not cfg.causal:
+def _for_visible_tile(cfg: FlashConfig, tile: Tile, i, j, info_ref, docs,
+                      body, also=None):
+    """Run ``body(positional)`` for a tile with any unmasked key
+    (``_should_run``); tiles wholly below the diagonal run it without the
+    positional mask. ``also``: a further condition of the step (a static
+    window's grid: the block the step names exists)."""
+    if not cfg.causal and docs is None:
         body(False)
         return
-    run = _should_run(cfg, tile, i, j, info_ref)
+    run = _should_run(cfg, tile, i, j, info_ref, docs)
     if also is not None:
         run = run & also
+    if not cfg.causal:
+        pl.when(run)(lambda: body(False))
+        return
     full = _fully_visible(cfg, tile, i, j, info_ref)
     pl.when(run & full)(lambda: body(False))
     pl.when(run & jnp.logical_not(full))(lambda: body(True))
@@ -327,12 +398,19 @@ def _compiler_params(cfg: FlashConfig, semantics):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
-                o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                cfg: FlashConfig, G: int, nk: int, head_dim: int):
+def _fwd_kernel(*refs, cfg: FlashConfig, G: int, nk: int, head_dim: int,
+                blocks: Tuple[int, int]):
+    """``refs``: the scalar-prefetch operands ``(info, slopes[, table])``,
+    then q, k, v, the q and k segment ids, the two outputs and the scratch.
+    ``nk``: the k-steps of the grid; ``blocks``: the launch's (q-blocks,
+    k-blocks)."""
+    prefetch, (q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref,
+               m_scr, l_scr, acc_scr) = _split_prefetch(cfg, refs)
+    info, slopes = prefetch[:2]
     b, g = pl.program_id(0), pl.program_id(1)
     i, step = pl.program_id(2), pl.program_id(3)
     tile = cfg.tiles.fwd
+    docs = _docs_of(cfg, prefetch, b, blocks)
     # the k-block of this step: under a static window the steps start at
     # the first block the q-block reaches (a step past its diagonal names a
     # block the causal mask hides whole, and is skipped as one)
@@ -365,7 +443,7 @@ def _fwd_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
                         + lax.dot(p.astype(v.dtype), v,
                                   preferred_element_type=jnp.float32))
 
-    _for_visible_tile(cfg, tile, i, j, info, _compute)
+    _for_visible_tile(cfg, tile, i, j, info, docs, _compute)
 
     @pl.when(step == nk - 1)
     def _store():
@@ -381,29 +459,36 @@ def _fwd_kernel(info, slopes, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
         lse_ref[0, 0] = lse.T[:1]
 
 
-def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
-    """-> o [BK, G, Sq, D], lse [BK, G, 1, Sq] (fp32 rows)."""
+def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info):
+    """-> o [BK, G, Sq, D], lse [BK, G, 1, Sq] (fp32 rows). ``table``: the
+    forward tiles' :func:`block_ranges` (None: a launch without ids)."""
     BK, G, Sq, D = q.shape
     Sk = k.shape[1]
     tile = bq, bk = cfg.tiles.fwd
-    nq, nk = Sq // bq, Sk // bk
+    blocks = nq, nk = Sq // bq, Sk // bk
     if cfg.window is not None:
         nk = window_steps(tile, cfg.window, nq, nk)[0]
     grid = (BK, G, nq, nk)
     kvH = cfg.kv_heads
+    prefetch = (info, slopes) + (() if table is None else (table.reshape(-1),))
 
-    def k_blk(i, j, info):
+    def k_blk(b, i, j, prefetch):
         """The k-block step ``j`` of q-block ``i`` fetches; a step that
-        computes nothing re-names a block already resident: no DMA."""
+        computes nothing re-names a block already resident, or one that a
+        whole run of such steps shares: no DMA, or one a run. (A row's
+        skipped steps lie before its first visible document and past its
+        diagonal; block 0 is what the row before it ended on.)"""
+        docs = _docs_of(cfg, prefetch, b, blocks)
         if cfg.window is not None:
-            return jnp.minimum(_first_k_block(tile, i, cfg.window) + j,
-                               _last_k_block(tile, i))
-        if cfg.causal:
-            j = lax.select(_should_run(cfg, tile, i, j, info), j, 0)
+            last = _last_k_block(tile, i)
+            blk = jnp.minimum(_first_k_block(tile, i, cfg.window) + j, last)
+            return blk if docs is None else lax.select(docs.meet(i, blk), blk, last)
+        if cfg.causal or docs is not None:
+            j = lax.select(_should_run(cfg, tile, i, j, prefetch[0], docs), j, 0)
         return j
 
-    def kv_idx(b, g, i, j, info, slopes):
-        return (b, k_blk(i, j, info), 0)
+    def kv_idx(b, g, i, j, *prefetch):
+        return (b, k_blk(b, i, j, prefetch), 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, g, i, j, *_: (b, g, i, 0)),
@@ -414,8 +499,8 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
         in_specs.append(pl.BlockSpec(
             (1, bq, NUM_LANES), lambda b, g, i, j, *_: (b // kvH, i, 0)))
 
-        def kseg_idx(b, g, i, j, info, slopes):
-            return (b // kvH, 0, k_blk(i, j, info))
+        def kseg_idx(b, g, i, j, *prefetch):
+            return (b // kvH, 0, k_blk(b, i, j, prefetch))
         in_specs.append(pl.BlockSpec((1, NUM_SUBLANES, bk), kseg_idx))
     else:
         in_specs += [None, None]
@@ -428,11 +513,12 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
         jax.ShapeDtypeStruct((BK, G, Sq, D), q.dtype),
         jax.ShapeDtypeStruct((BK, G, 1, Sq), jnp.float32),
     ]
-    kernel = functools.partial(_fwd_kernel, cfg=cfg, G=G, nk=nk, head_dim=D)
+    kernel = functools.partial(_fwd_kernel, cfg=cfg, G=G, nk=nk, head_dim=D,
+                               blocks=blocks)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
@@ -449,7 +535,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
         # (the benchmark's readers find the launches by it)
         name=("flash_fwd_blockdiff" if cfg.blockdiff is not None
               else "flash_fwd" if cfg.window is None else "flash_fwd_window"),
-    )(info, slopes, q, k, v, qseg_c, kseg_r)
+    )(*prefetch, q, k, v, qseg_c, kseg_r)
 
 
 # ---------------------------------------------------------------------------
@@ -457,39 +543,49 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, slopes, info):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_kernel(info, slopes, q_ref, k_ref, v_ref, kseg_ref, qseg_ref,
-                do_ref, lse_ref, di_ref, dq_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, cfg: FlashConfig, G: int, nq: int,
-                steps: int):
+def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
+                blocks: Tuple[int, int]):
     """dq, dk and dv of one (k-block, q-block) tile from ONE recomputed
     P^T = exp(K Q^T - lse): five matmuls, none with a transposed left
     operand except dq's (one XLU transpose of dS^T). dk/dv accumulate in
     scratch over the groups and q-blocks of their k-block; dq leaves per
-    k-block and the caller sums the k-blocks. ``steps``: the q-steps of
-    the grid (``nq``, or under a static window the q-blocks one k-block
-    reaches)."""
+    k-block and the caller sums the k-blocks. ``refs``: the scalar-prefetch
+    operands ``(info, slopes[, table])``, then q, k, v, the k and q segment
+    ids, do, lse, di, the three outputs and the scratch. ``steps``: the
+    q-steps of the grid (every q-block, or under a static window the
+    q-blocks one k-block reaches); ``blocks``: the launch's (q-blocks,
+    k-blocks)."""
+    prefetch, (q_ref, k_ref, v_ref, kseg_ref, qseg_ref, do_ref, lse_ref,
+               di_ref, dq_ref, dk_ref, dv_ref, dk_scr, dv_scr
+               ) = _split_prefetch(cfg, refs)
+    info, slopes = prefetch[:2]
     b = pl.program_id(0)
     j, g, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     tile = cfg.tiles.bwd
+    docs = _docs_of(cfg, prefetch, b, blocks)
     # the q-block of this step, and whether there is one: a static
     # window's steps start at the k-block's own diagonal
     i, exists = step, None
     if cfg.window is not None:
         i = _first_q_block(tile, j) + step
-        exists = i <= _last_q_block(tile, j, cfg.window, nq)
+        exists = i <= _last_q_block(tile, j, cfg.window, blocks[0])
 
     @pl.when((g == 0) & (step == 0))
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    if cfg.causal and cfg.window is None:
+    if (cfg.causal and cfg.window is None) or docs is not None:
         # every (k-block, q-block) pair has a dq slot of its own, which the
         # caller sums: a skipped pair's is zero. (Under a static window a
         # step without a q-block writes nothing: the block it names holds
         # the step before's result, and the caller masks the slots no pair
-        # wrote.)
-        @pl.when(jnp.logical_not(_should_run(cfg, tile, i, j, info)))
+        # wrote; a pair that exists there is skipped by its documents alone.)
+        skipped = jnp.logical_not(_should_run(cfg, tile, i, j, info, docs))
+        if exists is not None:
+            skipped = skipped & exists
+
+        @pl.when(skipped)
         def _skipped():
             dq_ref[0, 0, 0] = jnp.zeros(dq_ref.shape[3:], dq_ref.dtype)
 
@@ -520,7 +616,7 @@ def _bwd_kernel(info, slopes, q_ref, k_ref, v_ref, kseg_ref, qseg_ref,
                                   preferred_element_type=jnp.float32
                                   ).astype(dq_ref.dtype)
 
-    _for_visible_tile(cfg, tile, i, j, info, _compute, also=exists)
+    _for_visible_tile(cfg, tile, i, j, info, docs, _compute, also=exists)
 
     @pl.when((g == G - 1) & (step == steps - 1))
     def _store():
@@ -549,12 +645,14 @@ def _rows_a_launch(cfg: FlashConfig, rows: int, bytes_a_row: int) -> int:
     return max(fits, default=1)
 
 
-def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
+def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
               o, lse, do, dlse):
+    """``table``: the backward tiles' :func:`block_ranges` (None: a launch
+    without ids)."""
     BK, G, Sq, D = q.shape
     Sk = k.shape[1]
     tile = bq, bk = cfg.tiles.bwd
-    nq, nk = Sq // bq, Sk // bk
+    blocks = nq, nk = Sq // bq, Sk // bk
     kvH = cfg.kv_heads
     W = cfg.window
     # the grid's q-steps a k-block, and the dq partials a q-block gets: one
@@ -570,29 +668,42 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
     if dlse is not None:
         di = di - dlse.astype(jnp.float32)
 
-    def q_blk(i, j, info):
-        # a step that computes nothing re-names a block already resident:
-        # no DMA
+    def q_at(i, j):
+        """The q-block of step ``i`` of k-block ``j`` by position: under a
+        static window a step past the last block reached names that one."""
+        if W is None:
+            return i
+        return jnp.minimum(_first_q_block(tile, j) + i,
+                           _last_q_block(tile, j, W, nq))
+
+    def q_blk(b, i, j, prefetch):
+        """The q-block the step fetches: one that computes nothing re-names
+        a block already resident, or one that a whole run of such steps
+        shares (no DMA, or one a run: the last block of all, or of the
+        window's reach)."""
+        docs = _docs_of(cfg, prefetch, b, blocks)
         if W is not None:
-            return jnp.minimum(_first_q_block(tile, j) + i,
-                               _last_q_block(tile, j, W, nq))
-        if cfg.causal:
-            i = lax.select(_should_run(cfg, tile, i, j, info), i, nq - 1)
+            blk = q_at(i, j)
+            return blk if docs is None else lax.select(
+                docs.meet(blk, j), blk, _last_q_block(tile, j, W, nq))
+        if cfg.causal or docs is not None:
+            i = lax.select(_should_run(cfg, tile, i, j, prefetch[0], docs), i, nq - 1)
         return i
 
-    def q_idx(b, j, g, i, info, slopes):
-        return (b, g, q_blk(i, j, info), 0)
+    def q_idx(b, j, g, i, *prefetch):
+        return (b, g, q_blk(b, i, j, prefetch), 0)
 
-    def q_row_idx(b, j, g, i, info, slopes):
-        return (b, g, 0, q_blk(i, j, info))
+    def q_row_idx(b, j, g, i, *prefetch):
+        return (b, g, 0, q_blk(b, i, j, prefetch))
 
     def kv_idx(b, j, g, i, *_):
         return (b, j, 0)
 
-    def dq_idx(b, j, g, i, info, slopes):
+    def dq_idx(b, j, g, i, *_):
+        # a pair's own slot, whatever its step fetched
         if W is None:
             return (j, b, g, i, 0)
-        i = q_blk(i, j, info)
+        i = q_at(i, j)
         return (j - _first_k_block(tile, i, W), b, g, i, 0)
 
     seg_specs = [None, None]
@@ -602,8 +713,8 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
                          lambda b, j, g, i, *_: (b // kvH, j, 0)),
             pl.BlockSpec(
                 (1, NUM_SUBLANES, bq),
-                lambda b, j, g, i, info, slopes: (
-                    b // kvH, 0, q_blk(i, j, info))),
+                lambda b, j, g, i, *prefetch: (
+                    b // kvH, 0, q_blk(b, i, j, prefetch))),
         ]
     # one partial a q-block: its dq IS the answer, in q's dtype
     dq_dtype = q.dtype if slots == 1 else jnp.float32
@@ -615,12 +726,14 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
         written = jnp.asarray(np.arange(slots)[:, None] <= reach[None, :]
                               )[:, None, None, :, None]
 
-    def launch(q, k, v, kseg_c, qseg_r, do, lse, di):
+    def launch(q, k, v, kseg_c, qseg_r, table, do, lse, di):
         rows = q.shape[0]
+        prefetch = (info, slopes) + (() if table is None else (table.reshape(-1),))
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_kernel, cfg=cfg, G=G, nq=nq, steps=steps),
+            functools.partial(_bwd_kernel, cfg=cfg, G=G, steps=steps,
+                              blocks=blocks),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
+                num_scalar_prefetch=len(prefetch),
                 grid=(rows, nk, G, steps),
                 in_specs=[
                     pl.BlockSpec((1, 1, bq, D), q_idx),
@@ -646,7 +759,7 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
             interpret=cfg.interpret,
             name=("flash_bwd_blockdiff" if cfg.blockdiff is not None
                   else "flash_bwd" if W is None else "flash_bwd_window"),
-        )(info, slopes, q, k, v, kseg_c, qseg_r, do, lse, di)
+        )(*prefetch, q, k, v, kseg_c, qseg_r, do, lse, di)
         if slots == 1:
             return dq[0], dk, dv
         if written is not None:
@@ -656,9 +769,11 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
 
     each = _rows_a_launch(cfg, BK, slots * G * Sq * D * jnp.dtype(dq_dtype).itemsize)
     if each == BK:
-        return launch(q, k, v, kseg_c, qseg_r, do, lse, di)
+        return launch(q, k, v, kseg_c, qseg_r, table, do, lse, di)
     n = BK // each
     split = lambda a: a.reshape((n, each) + a.shape[1:])
+    # the ids and their table go by batch row (a launch finds its own by
+    # ``row // kv_heads``)
     if not cfg.use_seg:
         seg = lambda ids: None
     elif each % kvH == 0:        # whole batch rows a launch
@@ -666,8 +781,8 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
     else:                        # every launch inside one batch row
         seg = lambda ids: jnp.repeat(ids, kvH // each, axis=0)[:, None]
     dq, dk, dv = lax.map(lambda xs: launch(*xs), (
-        split(q), split(k), split(v), seg(kseg_c), seg(qseg_r), split(do),
-        split(lse), split(di)))
+        split(q), split(k), split(v), seg(kseg_c), seg(qseg_r), seg(table),
+        split(do), split(lse), split(di)))
     join = lambda a: a.reshape((BK,) + a.shape[2:])
     return join(dq), join(dk), join(dv)
 
@@ -679,14 +794,15 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, slopes, info,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _flash(cfg: FlashConfig, q, k, v, segs, slopes, info):
-    """``segs`` = (q ids as columns, k ids as rows, k ids as columns, q ids
-    as rows) or four Nones: the forward reads the first pair, the backward
-    (transposed tiles) the second."""
-    return _fwd_call(cfg, q, k, v, segs[0], segs[1], slopes, info)
+    """``segs`` = (q ids as columns, k ids as rows, the forward tiles' table
+    of documents, k ids as columns, q ids as rows, the backward tiles'
+    table) or six Nones: the forward reads the first three, the backward
+    (transposed tiles) the others."""
+    return _fwd_call(cfg, q, k, v, *segs[:3], slopes, info)
 
 
 def _flash_fwd(cfg, q, k, v, segs, slopes, info):
-    o, lse = _fwd_call(cfg, q, k, v, segs[0], segs[1], slopes, info)
+    o, lse = _fwd_call(cfg, q, k, v, *segs[:3], slopes, info)
     # named HERE so that the residuals are the values a checkpoint policy
     # saves: with both kept, a rematerialised block's backward launches no
     # ``flash_fwd`` (models/transformer.py ``remat_policy``); either costs
@@ -698,7 +814,7 @@ def _flash_fwd(cfg, q, k, v, segs, slopes, info):
 def _flash_bwd(cfg, res, cts):
     q, k, v, segs, slopes, info, o, lse = res
     do, dlse = cts  # a discarded LSE output arrives as a zero array
-    dq, dk, dv = _bwd_call(cfg, q, k, v, segs[2], segs[3], slopes, info,
+    dq, dk, dv = _bwd_call(cfg, q, k, v, *segs[3:], slopes, info,
                            o, lse, do, dlse)
     return dq, dk, dv, None, None, None
 
@@ -878,6 +994,45 @@ def static_window(window, sq: int, sk: int, q_offset=None) -> Optional[int]:
     return None
 
 
+def block_ranges(q_ids: jax.Array, k_ids: jax.Array, tile: Tile) -> jax.Array:
+    """A launch's table of documents (module docstring): each block's lowest
+    and highest segment id, int32 ``[B, 2 (nq + nk)]``, a batch row's line
+    ``[q_lo (nq), q_hi (nq), k_lo (nk), k_hi (nk)]`` (``_BlockDocs`` reads
+    it). ``q_ids`` [B, Sq], ``k_ids`` [B, Sk]; ``tile``: the kernel's."""
+    def lo_hi(ids, block):
+        blocks = ids.astype(jnp.int32).reshape(ids.shape[0], -1, block)
+        return [jnp.min(blocks, axis=2), jnp.max(blocks, axis=2)]
+    return jnp.concatenate(lo_hi(q_ids, tile[0]) + lo_hi(k_ids, tile[1]), axis=1)
+
+
+def tiles_run(q_ids: jax.Array, k_ids: jax.Array, tile: Tile, *,
+              causal: bool = True, window=None,
+              blockdiff: Optional[int] = None, q_offset=None
+              ) -> Tuple[jax.Array, jax.Array]:
+    """How far the documents cut one kernel's work: (the tiles the position
+    test alone runs, the tiles run), each summed over the batch rows, for a
+    launch over ``q_ids`` [B, Sq] and ``k_ids`` [B, Sk] with the kernel's
+    ``tile`` (one head's: every head of a row runs the same tiles). The
+    kernels' own table and predicate (``block_ranges``, ``_should_run``)
+    over every (q-block, k-block) at once; under a static window the blocks
+    the position test passes are the ones the cut grids hold."""
+    (B, Sq), Sk = q_ids.shape, k_ids.shape[1]
+    nq, nk = Sq // tile[0], Sk // tile[1]
+    cfg = FlashConfig(
+        causal=causal, scale=1.0, use_seg=True, use_alibi=False,
+        use_window=window is not None, kv_heads=1, tiles=None, interpret=False,
+        blockdiff=None if blockdiff is None else (int(blockdiff), Sk))
+    if q_offset is None:     # bottom-right alignment, as ``_prepare``
+        q_offset = 0 if blockdiff is not None else Sk - Sq
+    info = jnp.stack([jnp.asarray(q_offset, jnp.int32),
+                      jnp.asarray(0 if window is None else window, jnp.int32)])
+    row, i, j = (lax.broadcasted_iota(jnp.int32, (B, nq, nk), axis) for axis in range(3))
+    docs = _BlockDocs(block_ranges(q_ids, k_ids, tile).reshape(-1), row, nq, nk)
+    count = lambda run: jnp.sum(jnp.broadcast_to(run, (B, nq, nk)), dtype=jnp.int32)
+    return (count(_should_run(cfg, tile, i, j, info)),
+            count(_should_run(cfg, tile, i, j, info, docs)))
+
+
 def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
              alibi_slopes, window, q_offset, block_q, block_k, interpret,
              blockdiff=None):
@@ -929,7 +1084,7 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         kv_heads=kvH, tiles=tiles, interpret=bool(interp), window=cut,
         blockdiff=None if blockdiff is None else (int(blockdiff), Sk))
 
-    segs = (None, None, None, None)
+    segs = (None,) * 6
     if segment_ids is not None:
         qseg = (q_segment_ids if q_segment_ids is not None
                 else segment_ids).astype(jnp.int32)
@@ -940,8 +1095,8 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
 
         def rows(ids, n):   # sublane-replicated: the ids of its column axis
             return lax.broadcast_in_dim(ids, (B, NUM_SUBLANES, n), (0, 2))
-        segs = (cols(qseg, Sq), rows(kseg, Sk), cols(kseg, Sk),
-                rows(qseg, Sq))
+        segs = (cols(qseg, Sq), rows(kseg, Sk), block_ranges(qseg, kseg, tiles.fwd),
+                cols(kseg, Sk), rows(qseg, Sq), block_ranges(qseg, kseg, tiles.bwd))
     if alibi_slopes is not None:
         # ALiBi slopes are a positional SCHEDULE (the fixed geometric
         # sequence of Press et al. — explicitly not learned), so the

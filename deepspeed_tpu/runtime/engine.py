@@ -587,7 +587,11 @@ class DeepSpeedEngine:
         # (``attn/core``), the windows, the key heads, and, once a step is
         # traced, the route ``attention.choose_route`` gives each kind's
         # call ("kernel" / "xla" / "xla_chunked"; None until then, and for
-        # a kind the model has no layer of).
+        # a kind the model has no layer of); ``documents``: whether the rows
+        # are packed documents (``document_separator``), so that a flash
+        # launch carries its table of each block's documents and skips a
+        # tile whose keys all lie in other ones. The LAST step's count of
+        # tiles stays on the device: ``attn_last_step()`` fetches it.
         self.attn_totals = self._attention_of_model()
         # The block-diffusion objective's record (None for every other
         # model), plain values kept with telemetry off: the block length,
@@ -1136,9 +1140,12 @@ class DeepSpeedEngine:
     def _step_has_stats(self) -> bool:
         """Whether the fused step returns the model's device-side
         statistics as a last output (the no-drop MoE path's rows per
-        expert); every other model's program is as it was."""
+        expert, the block-diffusion objective's masked share, packed
+        documents' count of tiles); every other model's program is as it
+        was."""
         return (self.moe_totals["path"] == "dropless"
-                or self.diffusion_totals is not None)
+                or self.diffusion_totals is not None
+                or bool(self.attn_totals.get("documents")))
 
     def moe_expert_rows(self):
         """The last fused step's assignments per expert, ``[layers,
@@ -1156,6 +1163,21 @@ class DeepSpeedEngine:
             return None
         return {"masked_share": float(self._step_stats["diffusion_masked_share"]),
                 "mean_weight": float(self._step_stats["diffusion_mean_weight"])}
+
+    def attn_last_step(self) -> Optional[Dict[str, Dict[str, Dict[str, int]]]]:
+        """The last fused step's count of flash tiles under its rows' packed
+        documents, fetched now: ``{kind: {"forward" | "backward":
+        {"position": the tiles the position test alone runs, "run": the
+        tiles run}}}`` for one launch, a head (``pallas_flash.tiles_run`` at
+        the tiles the kernel route takes; kinds ``window``, ``full``,
+        ``blockdiff``: ``TransformerLM.attn_tile_kinds``). None for a model
+        without ``document_separator`` or before a step."""
+        if not self._step_stats or "attn_tiles" not in self._step_stats:
+            return None
+        tiles = np.asarray(self._step_stats["attn_tiles"])
+        return {kind: {kernel: {"position": int(by_position), "run": int(run)}
+                       for kernel, (by_position, run) in zip(("forward", "backward"), line)}
+                for (kind, _), line in zip(self.model.attn_tile_kinds, tiles)}
 
     @functools.cached_property
     def _remat_room_bytes(self) -> Optional[int]:
@@ -2379,6 +2401,7 @@ class DeepSpeedEngine:
                 "layers_full": sum(1 for w, _ in kinds if not w),
                 "window": windows[0] if len(windows) == 1 else (windows or None),
                 "kv_heads": self.model.config.kv_heads,
+                "documents": self.model.config.document_separator is not None,
                 "route": {"window": None, "full": None}}
 
     def _diffusion_of_model(self) -> Optional[Dict[str, Any]]:

@@ -92,7 +92,9 @@ def test_first_step_through_initialize(parts):
             "zero_optimization": {"stage": 1},
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}}})
     assert engine.attn_totals == {"layers_window": 5, "layers_full": 1, "window": 16,
-                                  "kv_heads": 2, "route": {"window": None, "full": None}}
+                                  "kv_heads": 2, "documents": True,
+                                  "route": {"window": None, "full": None}}
+    assert engine.attn_last_step() is None
     loss = float(engine.train_batch({"input_ids": np.asarray(ids)}))
     want, gnorm, signs = ref.loss_and_gradient(w, ids, cfg)
     assert loss == pytest.approx(float(want), rel=1e-5)
@@ -275,3 +277,38 @@ def test_what_the_configuration_maps_to_and_refuses():
             attn_windows=(8, 0, 8, 0), max_seq_len=64, remat_policy="alternating"))
     with pytest.raises(ValueError, match="document_separator"):
         TransformerLM(TransformerConfig(causal=False, document_separator=3))
+
+
+@pytest.mark.parametrize("separator", [63, None])
+def test_attn_last_step_counts_the_tiles_packed_documents_leave(separator):
+    """A dense two-layer model (a window of 256, then the whole row) over one
+    row of 1,024 whose second half is another document: ``attn_last_step()``
+    gives, for each kind of launch and kernel, the tiles the position test
+    alone runs and the tiles the documents leave (the forward's 512 x 512:
+    the tile under the diagonal goes; the full layer's backward is one
+    tile), ``attn_totals`` says the launches carry the table; without a
+    separator there is no count and the step returns no statistics."""
+    import deepspeed_tpu
+    model = TransformerLM(TransformerConfig(
+        vocab_size=64, max_seq_len=1024, num_layers=2, num_heads=2, hidden_size=32,
+        position="rope", norm="rmsnorm", attn_windows=(256, 0),
+        document_separator=separator, dtype=F32, remat=False))
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, config={
+        "train_micro_batch_size_per_gpu": 1,
+        "zero_optimization": {"stage": 1},
+        "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
+    assert engine.attn_totals["documents"] == (separator is not None)
+    assert engine.attn_last_step() is None
+    ids = np.random.default_rng(0).integers(0, 63, (8, 1024))
+    ids[:, 511] = 63
+    assert np.isfinite(float(engine.train_batch({"input_ids": ids})))
+    if separator is None:
+        assert engine.attn_last_step() is None and not engine._step_has_stats
+        return
+    assert model.attn_tile_kinds == (("window", 256), ("full", 0))
+    rows = 8    # the count is a launch's, over its batch rows
+    assert engine.attn_last_step() == {
+        "window": {"forward": {"position": 3 * rows, "run": 2 * rows},
+                   "backward": {"position": 3 * rows, "run": 2 * rows}},
+        "full": {"forward": {"position": 3 * rows, "run": 2 * rows},
+                 "backward": {"position": rows, "run": rows}}}

@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
-"""The block-diffusion attention core on the chip, beside causal launches.
+"""The block-diffusion attention core on the chip, beside causal launches,
+over the cells' packed documents and over one document a row.
 
-    chiprun -- python tools/attn_blockdiff_ab.py [--seq 8192] [--block 4]
+    chiprun -- python tools/attn_blockdiff_ab.py [--seq 8192] [--block 4] [--rows 8]
 
 At the ``sdar-30b-a3b.train.bd8k`` cell's shape (32 query heads over 4 key
 heads of 128, bf16, one row of documents as the cell's traffic packs them):
@@ -9,8 +10,16 @@ heads of 128, bf16, one row of documents as the cell's traffic packs them):
 one causal flash call over the 2 L concatenation with the same documents
 repeated (what running the pair causally would multiply), one causal call
 over L (a next-token layer of the same stack), and the own-block einsum
-alone; forward, and forward + backward, median of ``--iters`` timed calls
-each. One JSON line a row, also in ``chiprun_out/attn_blockdiff_ab.jsonl``.
+alone; then the ``trinity-mini.train.seq16k`` cell's two kinds of layer at
+2 L = 16,384 over ITS documents (the whole row, and a static window of
+2048). Every call with documents has a twin ``.. / one document`` (the same
+launch, ids all 0: the table rides along and skips nothing), so the pair
+shows what the table of documents (``pallas_flash.block_ranges``) spares.
+Forward, and forward + backward, median of ``--iters`` timed calls on each
+of ``--rows`` successive rows of the traffic, averaged over the rows; beside
+them the tiles the position test alone runs and the tiles run, a head
+(``pallas_flash.tiles_run``; left out where the package has no such
+function). One JSON line a row, also in ``chiprun_out/attn_blockdiff_ab.jsonl``.
 """
 
 import argparse
@@ -28,6 +37,7 @@ def main() -> int:
     ap.add_argument("--seq", type=int, default=8192)
     ap.add_argument("--block", type=int, default=4)
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--rows", type=int, default=8)
     ap.add_argument("--seed", type=int, default=1)
     args = ap.parse_args()
     import jax
@@ -35,46 +45,90 @@ def main() -> int:
     import numpy as np
 
     from benchmark import traffic
-    from deepspeed_tpu.ops.transformer import attention
+    from deepspeed_tpu.ops.transformer import attention, pallas_flash
     from deepspeed_tpu.ops.transformer.pallas_flash import flash_attention_kernel
 
     L, b, H, kvH, D = args.seq, args.block, 32, 4, 128
-    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "traffic",
-                           "train.bd8k.json")) as f:
-        mix = dict(json.load(f), seq_len=L)
-    ids = next(traffic.train_batches(mix, args.seed, 18992, 1))["input_ids"]
-    ends = (ids == 18991).astype(np.int32)
-    doc = jnp.asarray(np.cumsum(ends, axis=1) - ends, jnp.int32)
+
+    def documents(mix_name, seq, vocab):
+        """``--rows`` successive rows of a cell's traffic -> their documents."""
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "traffic",
+                               mix_name + ".json")) as f:
+            mix = dict(json.load(f), seq_len=seq)
+        stream = traffic.train_batches(mix, args.seed, vocab, 1)
+        out = []
+        for _ in range(args.rows):
+            ends = (next(stream)["input_ids"] == mix["separator"] % vocab).astype(np.int32)
+            out.append(jnp.asarray(np.cumsum(ends, axis=1) - ends, jnp.int32))
+        return out
+
+    docs = documents("train.bd8k", L, 18992)
+    docs_2l = documents("train.seq16k", 2 * L, 25024)
+    one = lambda rows: [jnp.zeros_like(d) for d in rows[:1]]
     key = jax.random.PRNGKey(args.seed)
     draw = lambda i, rows, heads: jax.random.normal(
         jax.random.fold_in(key, i), (1, rows, heads, D), jnp.bfloat16)
     q2, k2, v2 = draw(0, 2 * L, H), draw(1, 2 * L, kvH), draw(2, 2 * L, kvH)
-    doc2 = jnp.concatenate([doc, doc + doc.max() + 1], axis=1)
+    twice = lambda doc: jnp.concatenate([doc, doc + doc.max() + 1], axis=1)
+    first = lambda a: a[:, :L]
+    tiles_run = getattr(pallas_flash, "tiles_run", None)
 
+    def blockdiff_tiles(doc):
+        return pallas_flash.blockdiff_tiles(L, D, b), dict(
+            q_ids=jnp.concatenate([doc, doc], axis=1), k_ids=doc, blockdiff=b)
+
+    def causal_tiles(window=None):
+        return lambda doc: (pallas_flash.choose_tiles(
+            doc.shape[1], doc.shape[1], D, causal=True, window=window),
+            dict(q_ids=doc, k_ids=doc, window=window))
+
+    blockdiff = lambda q, k, v, doc: attention.blockdiff_attention(q, k, v, b, doc)
+    causal = lambda q, k, v, doc: flash_attention_kernel(
+        q, k, v, causal=True, segment_ids=doc)
+    window = lambda q, k, v, doc: flash_attention_kernel(
+        q, k, v, causal=True, segment_ids=doc, window=2048)
+    # name: (the call, its operands, the rows' documents, the tiles' arguments)
     cases = {
-        "blockdiff_2L": (lambda q, k, v: attention.blockdiff_attention(q, k, v, b, doc),
-                         (q2, k2, v2)),
-        "causal_2L": (lambda q, k, v: flash_attention_kernel(
-            q, k, v, causal=True, segment_ids=doc2), (q2, k2, v2)),
-        "causal_L": (lambda q, k, v: flash_attention_kernel(
-            q, k, v, causal=True, segment_ids=doc), (q2[:, :L], k2[:, :L], v2[:, :L])),
-        "own_block_einsum": (lambda q, k, v: attention._own_block_attention(
-            q, k, v, doc, b, None)[0], (q2[:, L:], k2[:, L:], v2[:, L:])),
+        "blockdiff_2L": (blockdiff, (q2, k2, v2), docs, blockdiff_tiles),
+        "blockdiff_2L / one document": (blockdiff, (q2, k2, v2), one(docs), blockdiff_tiles),
+        "causal_2L": (causal, (q2, k2, v2), [twice(d) for d in docs], causal_tiles()),
+        "causal_L": (causal, (first(q2), first(k2), first(v2)), docs, causal_tiles()),
+        "causal_L / one document": (causal, (first(q2), first(k2), first(v2)), one(docs),
+                                    causal_tiles()),
+        "own_block_einsum": (lambda q, k, v, doc: attention._own_block_attention(
+            q, k, v, doc, b, None)[0], (q2[:, L:], k2[:, L:], v2[:, L:]), docs[:1], None),
+        "causal_16k": (causal, (q2, k2, v2), docs_2l, causal_tiles()),
+        "causal_16k / one document": (causal, (q2, k2, v2), one(docs_2l), causal_tiles()),
+        "window_16k": (window, (q2, k2, v2), docs_2l, causal_tiles(2048)),
+        "window_16k / one document": (window, (q2, k2, v2), one(docs_2l), causal_tiles(2048)),
     }
     out = []
-    for name, (fn, operands) in cases.items():
+    for name, (fn, operands, rows, tiles_of) in cases.items():
         fwd = jax.jit(fn)
-        both = jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
-                                argnums=(0, 1, 2)))
-        row = {"case": name, "seq": L, "block": b, "device": jax.devices()[0].device_kind}
+        both = jax.jit(jax.grad(lambda q, k, v, doc: jnp.sum(fn(q, k, v, doc).astype(
+            jnp.float32)), argnums=(0, 1, 2)))
+        row = {"case": name, "seq": L, "block": b, "rows": len(rows),
+               "device": jax.devices()[0].device_kind}
         for kind, f in (("forward_ms", fwd), ("forward_backward_ms", both)):
-            jax.block_until_ready(f(*operands))
-            times = []
-            for _ in range(args.iters):
-                t0 = time.perf_counter()
-                jax.block_until_ready(f(*operands))
-                times.append(1e3 * (time.perf_counter() - t0))
-            row[kind] = statistics.median(times)
+            medians = []
+            for doc in rows:
+                jax.block_until_ready(f(*operands, doc))
+                times = []
+                for _ in range(args.iters):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(f(*operands, doc))
+                    times.append(1e3 * (time.perf_counter() - t0))
+                medians.append(statistics.median(times))
+            row[kind] = statistics.fmean(medians)
+        if tiles_run is not None and tiles_of is not None:
+            counts = np.zeros((2, 2))
+            for doc in rows:
+                tiles, mask = tiles_of(doc)
+                counts += [[int(n) for n in tiles_run(tile=tile, **mask)]
+                           for tile in (tiles.fwd, tiles.bwd)]
+            counts /= len(rows)
+            row.update(forward_tiles_by_position=counts[0, 0], forward_tiles_run=counts[0, 1],
+                       backward_tiles_by_position=counts[1, 0], backward_tiles_run=counts[1, 1])
         print(json.dumps(row), flush=True)
         out.append(row)
     os.makedirs("chiprun_out", exist_ok=True)

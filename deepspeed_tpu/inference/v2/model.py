@@ -87,6 +87,14 @@ class RaggedInferenceModel:
                 "that yields a block of tokens by several denoising passes is "
                 "none of the ragged engine's programs (one token a sequence a "
                 "step); the model trains through deepspeed_tpu.initialize")
+        if c.attention == "eva" or c.pred_heads > 1:
+            raise NotImplementedError(
+                "serving attention='eva' is not supported: its cache is exact "
+                "pages for the open window plus one summary a closed chunk, "
+                "which the ragged engine's key-value pages do not hold, and "
+                f"drafting with pred_heads ({c.pred_heads}) needs a step of "
+                "more than one token; the model trains through "
+                "deepspeed_tpu.initialize")
         if c.qk_norm or (c.moe is not None and c.moe.capacity_factor is None):
             raise NotImplementedError(
                 "serving OLMoE is not supported yet: the ragged engine's "

@@ -11,6 +11,7 @@ with _setup_spans.importing():
     from .instella_moe import instella_moe_config, instella_moe_model  # noqa: F401
     from .afmoe import afmoe_config, afmoe_model  # noqa: F401
     from .sdar_moe import sdar_moe_config, sdar_moe_model  # noqa: F401
+    from .evabyte import evabyte_config, evabyte_model  # noqa: F401
     from .opt_phi_falcon import (falcon_config, falcon_model, opt_config,  # noqa: F401
                                  opt_model, phi_config, phi_model)
     from .bloom_neox_gptj import (bloom_config, bloom_model, gpt_neo_config,  # noqa: F401

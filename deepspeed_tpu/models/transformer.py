@@ -88,6 +88,28 @@ def masked_cross_entropy(logits: jax.Array, labels: jax.Array,
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
+@scoped("loss")
+def pred_heads_cross_entropy(logits: jax.Array, labels: jax.Array,
+                             extra_mask: Optional[jax.Array] = None) -> jax.Array:
+    """Several next-token heads on one trunk: ``logits`` [B, S, heads, V],
+    ``labels`` [B, S] the FIRST head's (position i's next token, -100 =
+    none). Head m's target at position i is ``labels[i + m]`` (token ``i +
+    1 + m``); the loss is the mean over the heads of each head's mean
+    cross-entropy over the positions that have a target."""
+    heads = logits.shape[2]
+    later = lambda a, m, fill: jnp.pad(a[:, m:], ((0, 0), (0, m)),
+                                       constant_values=fill)
+    targets = jnp.stack([later(labels, m, -100) for m in range(heads)], axis=2)
+    valid = targets >= 0
+    nll = _token_nll(logits, jnp.where(valid, targets, 0))          # [B, S, heads]
+    mask = valid.astype(jnp.float32)
+    if extra_mask is not None:
+        mask = mask * jnp.stack([later(extra_mask, m, 0) for m in range(heads)],
+                                axis=2).astype(jnp.float32)
+    each = jnp.sum(nll * mask, axis=(0, 1)) / jnp.maximum(jnp.sum(mask, axis=(0, 1)), 1.0)
+    return jnp.mean(each)
+
+
 @contextlib.contextmanager
 def _noise_scope():
     """The scope ``diffusion/noise``, as two components: a transform wraps a
@@ -104,6 +126,51 @@ def weighted_cross_entropy(logits: jax.Array, targets: jax.Array,
     block-diffusion objective's sum (a position's weight is 0 or 1 / t),
     over every position of the batch, weighted or not."""
     return jnp.sum(_token_nll(logits, targets) * weights) / targets.size
+
+
+def next_token_cross_entropy(config, logits, labels, extra_mask=None) -> jax.Array:
+    """The next-token objective over a head's logits: one head's masked
+    cross-entropy, or with ``config.pred_heads`` the mean over the heads. (A
+    function of the configuration: ``PipelineModule`` borrows
+    ``TransformerLM.loss``.)"""
+    if config.pred_heads > 1:
+        return pred_heads_cross_entropy(logits, labels, extra_mask)
+    return masked_cross_entropy(logits, labels, extra_mask=extra_mask)
+
+
+#: The most elements a dense MLP's ``[rows, intermediate]`` product may have
+#: before the rows are taken in slices, and the most one EVA head group's
+#: ``[rows, heads x head]`` projection may have before the heads are taken in
+#: groups. At 32,768 rows x 11008 a product is 0.72 GB in bfloat16 and a
+#: block's backward holds six of them beside a dozen ``[rows, hidden]`` copies
+#: of the attention's: 9.2 GB by the chip's compiler, beside 9.9 GB of
+#: training state on a 16 GB chip; in slices of 4,096 rows and groups of 4
+#: heads it holds 6.1 (PERF.md, PR 42). Memory only: the same arithmetic, a
+#: slice's intermediates at a time.
+MLP_WHOLE_ELEMENTS = 2 ** 28
+MLP_SLICE_ELEMENTS = 2 ** 26
+EVA_GROUP_ELEMENTS = 2 ** 24
+
+
+def mlp_row_slices(rows: int, width: int) -> int:
+    """How many slices of its rows a dense gated MLP is computed in: 1 up to
+    ``MLP_WHOLE_ELEMENTS`` elements in a ``[rows, width]`` product (every
+    shape the benchmark had before a 32,768-row dense stack: the widest was
+    16,384 x 10944), else the least power of two that brings a slice's
+    product under ``MLP_SLICE_ELEMENTS`` and divides the rows."""
+    n = 1
+    if rows * width > MLP_WHOLE_ELEMENTS:
+        while rows % (2 * n) == 0 and rows // n * width > MLP_SLICE_ELEMENTS:
+            n *= 2
+    return n
+
+
+def eva_head_groups(rows: int, heads: int, head_dim: int) -> int:
+    """How many groups of its heads an EVA layer's attention is computed in:
+    the least divisor of ``heads`` that brings a group's ``[rows, heads x
+    head_dim]`` under ``EVA_GROUP_ELEMENTS``."""
+    return next(g for g in range(1, heads + 1) if heads % g == 0
+                and (g == heads or rows * (heads // g) * head_dim <= EVA_GROUP_ELEMENTS))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -282,7 +349,15 @@ class TransformerConfig:
     # query and key are ``qk_nope_dim`` such values and ``qk_rope_dim``
     # rotated ones (the key's rotated part is one vector shared by the
     # heads), its value ``v_head_dim`` wide. 'mha': the projections above.
+    # 'eva' (EVA, arXiv:2302.04542, as EvaByte runs it): a query sees the
+    # exact keys of its own window of ``eva_window`` positions and ONE learned
+    # summary a chunk of ``eva_chunk`` for every window before it, under one
+    # softmax (``ops/transformer/attention.py::eva_visible``); a layer holds
+    # two vectors a head for the summaries (``eva_phi``, ``eva_mu``). Windows
+    # and chunks are counted from the row's start; no document ids enter.
     attention: str = "mha"
+    eva_window: int = 0
+    eva_chunk: int = 0
     kv_latent_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
@@ -320,6 +395,17 @@ class TransformerConfig:
     # of the batch (``TransformerLM.noise_key``: ``batch["noise_key"]`` or
     # ``noise_seed`` folded with the ids). ``mask_token_id`` may lie one past
     # the vocabulary: the embedding then has that row more than the head.
+    # next-token heads on the one trunk: the head's ``pred_heads x vocab``
+    # outputs at position i are head m's logits for token i + 1 + m, and the
+    # loss is the mean over the heads of each head's mean cross-entropy over
+    # the positions that have such a token (EvaByte's ``num_pred_heads``; no
+    # module of its own, unlike ``mtp_layers``)
+    pred_heads: int = 1
+    # RMSNorm's gain is 1 + scale (EvaByte's ``norm_add_unit_offset``)
+    norm_unit_offset: bool = False
+    # a branch is added to the stream in float32 and the sum rounded to the
+    # compute dtype (EvaByte's ``fp32_skip_add``)
+    residual_fp32: bool = False
     objective: str = "next_token"
     block_length: int = 4
     mask_token_id: Optional[int] = None
@@ -373,7 +459,9 @@ class TransformerConfig:
             (self.max_seq_len + self.position_offset) * h
             if self.position == "learned" else 0)
         embed += self.type_vocab_size * h
-        head = 0 if self.tie_embeddings else v * h
+        head = 0 if self.tie_embeddings else self.pred_heads * v * h
+        if self.attention == "eva":
+            attn += 2 * self.num_heads * self.head_dim
         if self.mlm_head:
             head += h * h + v  # prediction transform + decoder bias
         return embed + head + L * (attn + mlp)
@@ -401,7 +489,10 @@ class TransformerLM:
         self._wte = nn.Embedding(c.embedding_rows, c.hidden_size, shard=True)
         self._wpe = (nn.Embedding(c.max_seq_len + c.position_offset, c.hidden_size)
                      if c.position == "learned" else None)
-        base_cls = nn.LayerNorm if c.norm == "layernorm" else nn.RMSNorm
+        if c.norm_unit_offset and c.norm != "rmsnorm":
+            raise ValueError("norm_unit_offset is RMSNorm's")
+        base_cls = (nn.LayerNorm if c.norm == "layernorm" else functools.partial(
+            nn.RMSNorm, unit_offset=True) if c.norm_unit_offset else nn.RMSNorm)
         norm_cls = lambda features: base_cls(features, eps=c.norm_eps)
         self._norm = norm_cls
         # post-LN (bert): the last block's output LN already normalizes the
@@ -462,7 +553,7 @@ class TransformerLM:
         else:
             self._alibi_slopes = None
         if not c.tie_embeddings:
-            self._lm_head = nn.Linear(c.hidden_size, c.vocab_size,
+            self._lm_head = nn.Linear(c.hidden_size, c.pred_heads * c.vocab_size,
                                       use_bias=c.lm_head_bias, shard="column")
 
         # gpt2-style models use biases; falcon keeps layernorm but bias-free
@@ -498,6 +589,9 @@ class TransformerLM:
                 "o_proj": lin(attn_out, c.hidden_size, attn_out_bias, "row"),
             }
         self._block_layers = {"ln_1": norm_cls(c.hidden_size), **attn_layers}
+        if c.attention == "eva":
+            self._block_layers["eva_phi"] = nn.HeadVectors(c.num_heads, c.head_dim)
+            self._block_layers["eva_mu"] = nn.HeadVectors(c.num_heads, c.head_dim)
         if c.qk_norm and c.qk_norm_per_head:
             self._block_layers["q_norm"] = nn.RMSNorm(c.head_dim, eps=c.norm_eps)
             self._block_layers["k_norm"] = nn.RMSNorm(c.head_dim, eps=c.norm_eps)
@@ -587,8 +681,32 @@ class TransformerLM:
         """What the second kind of layer, the two streams and the
         prediction module are written for, and nothing wider."""
         c = self.config
-        if c.attention not in ("mha", "latent"):
-            raise ValueError(f"attention {c.attention!r} is not 'mha' or 'latent'")
+        if c.attention not in ("mha", "latent", "eva"):
+            raise ValueError(f"attention {c.attention!r} is not 'mha', 'latent' or 'eva'")
+        if c.attention == "eva":
+            if (c.eva_chunk < 1 or c.eva_window < c.eva_chunk
+                    or c.eva_window % c.eva_chunk):
+                raise ValueError(f"EVA attention needs a window ({c.eva_window}) "
+                                 f"of whole chunks ({c.eva_chunk})")
+            if (not c.causal or c.num_kv_heads not in (None, c.num_heads)
+                    or self._windows is not None or c.position == "alibi"
+                    or c.seq_parallel == "ring" or c.diffusion
+                    or c.document_separator is not None or c.attn_gate
+                    or c.qk_norm or c.norm_style == "post"
+                    or (c.linear_bias if c.attn_bias is None else c.attn_bias)
+                    is not False):
+                raise ValueError(
+                    "EVA attention is written for a causal decoder with as "
+                    "many key heads as query heads: no sliding window, ALiBi, "
+                    "ring attention, block diffusion, packed documents (its "
+                    "windows are the row's), attention gate, QK-norm, post-norm "
+                    "or bias on its projections (linear_bias=False)")
+        if c.pred_heads < 1 or (c.pred_heads > 1 and (
+                c.tie_embeddings or not c.causal or c.diffusion or c.mtp_layers
+                or c.mlm_head)):
+            raise ValueError(
+                "pred_heads: next-token heads of a causal decoder with its own "
+                "head, no prediction module, no block diffusion")
         if c.rope_scaling is not None and c.position != "rope":
             raise ValueError("rope_scaling needs position='rope'")
         if c.qk_norm_per_head and not c.qk_norm:
@@ -616,6 +734,10 @@ class TransformerLM:
                 c.norm_style != "pre" or self._windows is not None):
             raise ValueError("farskip and mtp_layers are written for pre-norm "
                              "blocks without windows")
+        if c.residual_fp32 and (c.norm_style == "post" or c.farskip
+                                or c.parallel_block):
+            raise ValueError("residual_fp32 is written for sequential pre-norm "
+                             "or sandwich blocks")
         if c.norm_style not in ("pre", "post", "sandwich"):
             raise ValueError(f"norm_style {c.norm_style!r}")
         if c.norm_style == "sandwich" and c.parallel_block:
@@ -772,6 +894,8 @@ class TransformerLM:
         B, S, _ = h.shape
         if c.attention == "latent":
             return self._latent_attn(block, h, positions)
+        if c.attention == "eva":
+            return self._eva_attn(block, h, positions)
         if rope is None:
             rope = c.position == "rope"
         if isinstance(window, int) and window <= 0:
@@ -857,6 +981,85 @@ class TransformerLM:
             with jax.named_scope("out"):
                 return self._project(block, "o_proj", out)
 
+    def _eva_attn(self, block: Params, h: jax.Array,
+                  positions: jax.Array) -> jax.Array:
+        """EVA attention over the pre-normed ``h`` (config's
+        ``attention='eva'``): the projections and rope as any layer's, one
+        summary key and value a chunk under ``attn/eva_summaries`` (kept for
+        the backward under the names ``eva_kbar`` / ``eva_vbar``), the core
+        under ``attn/core_eva`` (``attention.eva_attention``). A row too long
+        for its heads at once (``eva_head_groups``) takes them a group at a
+        time: a head's attention reads no other head, and the output
+        projection is the float32 sum of the groups' parts."""
+        from ..runtime import topology as topo_mod
+        c = self.config
+        B, S, H = h.shape
+        topo = topo_mod.get_topology() if topo_mod.is_initialized() else None
+        if topo is not None and topo.sequence_parallel_size > 1:
+            raise NotImplementedError(
+                "attention='eva': windows and chunks are counted over the whole "
+                "row, which sequence parallelism "
+                f"(sequence={topo.sequence_parallel_size}) divides")
+        groups = eva_head_groups(B * S, c.num_heads, c.head_dim)
+        phi, mu = block["eva_phi"]["value"], block["eva_mu"]["value"]
+        with jax.named_scope("attn"):
+            if groups == 1:
+                with jax.named_scope("qkv"):
+                    q, k, v = (self._project(block, name, h)
+                               for name in ("q_proj", "k_proj", "v_proj"))
+                out = self._eva_heads(q, k, v, phi, mu, positions, named=True)
+                with jax.named_scope("out"):
+                    return self._project(block, "o_proj", out)
+            each = c.num_heads // groups
+            kernel = lambda name: block[name]["kernel"].astype(h.dtype)
+            columns = lambda name: kernel(name).reshape(
+                H, groups, each * c.head_dim).transpose(1, 0, 2)
+            by_group = lambda a: a.reshape(groups, each, c.head_dim)
+
+            def one_group(acc, weights):
+                wq, wk, wv, wo, phi, mu = weights
+                with jax.named_scope("qkv"):
+                    q, k, v = h @ wq, h @ wk, h @ wv
+                out = self._eva_heads(q, k, v, phi, mu, positions)
+                with jax.named_scope("out"):
+                    return acc + jnp.matmul(
+                        out, wo, preferred_element_type=jnp.float32), None
+
+            # a group's values are made again in its own backward: only the
+            # groups' shared input and the running sum outlive a group (and
+            # nothing in a group is named: the block's policy would keep a
+            # named value of every group, stacked)
+            acc, _ = jax.lax.scan(
+                jax.checkpoint(one_group), jnp.zeros(h.shape, jnp.float32),
+                (columns("q_proj"), columns("k_proj"), columns("v_proj"),
+                 kernel("o_proj").reshape(groups, each * c.head_dim, H),
+                 by_group(phi), by_group(mu)))
+            return acc.astype(h.dtype)
+
+    def _eva_heads(self, q, k, v, phi, mu, positions,
+                   named: bool = False) -> jax.Array:
+        """Rope, summaries and the core over projected q, k, v ``[B, S,
+        heads x head]`` of some of the layer's heads (``phi``, ``mu`` theirs)
+        -> ``[B, S, heads x head]``. ``named``: the summaries are values the
+        backward may keep (``eva_kbar`` / ``eva_vbar``)."""
+        from ..ops.transformer.attention import eva_attention, eva_summaries
+        c = self.config
+        B, S, wide = q.shape
+        heads = lambda a: a.reshape(B, S, wide // c.head_dim, c.head_dim)
+        q, k, v = heads(q), heads(k), heads(v)
+        if c.position == "rope":
+            with jax.named_scope("qkv"):
+                q, k = self._rotate(q, positions), self._rotate(k, positions)
+        with jax.named_scope("eva_summaries"):
+            kbar, vbar = eva_summaries(k, v, phi, mu, c.eva_chunk)
+            if named:
+                kbar = checkpoint_name(kbar, "eva_kbar")
+                vbar = checkpoint_name(vbar, "eva_vbar")
+        with jax.named_scope("core_eva"):
+            out = eva_attention(q, k, v, kbar, vbar, c.eva_window, c.eva_chunk,
+                                scale=c.attn_scale)
+        return out.reshape(B, S, wide)
+
     def _attn_core(self, q, k, v, attn_mask, window, scale=None) -> jax.Array:
         """Scores, softmax and values (XLA, flash, ring or Ulysses)."""
         c = self.config
@@ -918,13 +1121,32 @@ class TransformerLM:
         elif "moe" in block:
             out, aux = self._moe(block["moe"], h)
         elif c.activation == "silu_gated":
-            gate = nn.silu(self._project(block, "gate_proj", h))
-            up = self._project(block, "up_proj", h)
-            out = self._layer("down_proj")(block["down_proj"], gate * up)
+            out = self._gated_mlp(block, h)
         else:
             h2 = ACTIVATIONS[c.activation](self._project(block, "fc_in", h))
             out = self._block_layers["fc_out"](block["fc_out"], h2)
         return out, aux, rows
+
+    def _gated_mlp(self, block: Params, h: jax.Array) -> jax.Array:
+        """The dense gated-SiLU MLP; over slices of its rows where one
+        ``[rows, width]`` product is too large (``mlp_row_slices``: the same
+        arithmetic a slice at a time, a slice's products made again in its
+        own backward, so that they are never all held)."""
+        def rows(h, project):
+            gate = nn.silu(project("gate_proj", h))
+            up = project("up_proj", h)
+            return self._layer("down_proj")(block["down_proj"], gate * up)
+
+        B, S, H = h.shape
+        slices = mlp_row_slices(B * S, self._layer("gate_proj").out_features)
+        if slices == 1:
+            return rows(h, functools.partial(self._project, block))
+        # (nothing in a slice is named: the block's policy would keep a named
+        # value of every slice, stacked)
+        unnamed = lambda name, h: self._layer(name)(block[name], h)
+        out = jax.lax.map(jax.checkpoint(functools.partial(rows, project=unnamed)),
+                          h.reshape(slices, B * S // slices, H))
+        return out.reshape(B, S, H)
 
     @scoped("block")   # norms and residual adds are "block" and nothing finer
     def _block_fn(self, attn_mask, carry, block_and_keep, kind=None):
@@ -976,14 +1198,21 @@ class TransformerLM:
             # 'sandwich': a branch's output is normed before it is added
             post = ((lambda name, y: y) if c.norm_style != "sandwich"
                     else functools.partial(self._norm_post, block))
-            x = x + keep * post("post_ln_1", self._attn(
-                block, h1, positions, attn_mask, window, rope))
+            add = self._add_fp32 if c.residual_fp32 else (lambda x, y: x + y)
+            x = add(x, keep * post("post_ln_1", self._attn(
+                block, h1, positions, attn_mask, window, rope)))
             h2 = self._block_layers["ln_2"](block["ln_2"], x)
             mlp_out, aux, rows = self._mlp(block, h2)
-            x = _c(x + keep * post("post_ln_2", mlp_out), ACT_SPEC)
+            x = _c(add(x, keep * post("post_ln_2", mlp_out)), ACT_SPEC)
         # the scan stacks the no-drop path's rows per expert over the
         # layers ([layers, experts]); every other model's ys stay None
         return (x, positions, aux_acc + keep * aux), rows
+
+    @staticmethod
+    def _add_fp32(x: jax.Array, y: jax.Array) -> jax.Array:
+        """``x + y`` summed in float32, the sum in x's dtype
+        (``residual_fp32``)."""
+        return (x.astype(jnp.float32) + y.astype(jnp.float32)).astype(x.dtype)
 
     @scoped("norm_post")
     def _norm_post(self, block: Params, name: str, y: jax.Array) -> jax.Array:
@@ -1042,6 +1271,8 @@ class TransformerLM:
             logits = self._lm_head(params["lm_head"], x)
         if c.mlm_head:
             logits = logits + params["mlm"]["bias"].astype(logits.dtype)
+        if c.pred_heads > 1:     # [B, S, heads, V]: head m reads token i + 1 + m
+            logits = logits.reshape(logits.shape[:-1] + (c.pred_heads, c.vocab_size))
         return logits.astype(jnp.float32)
 
     def block_apply(self, block: Params, x: jax.Array, positions: jax.Array,
@@ -1060,14 +1291,14 @@ class TransformerLM:
                 "(parameter streaming, the ZeRO-3 pipelined scan) does not "
                 "carry: it takes the whole-model scan of TransformerLM.loss")
         if (c.farskip or c.first_dense_layers or c.mtp_layers or self._mixed_rope
-                or c.document_separator is not None):
+                or c.document_separator is not None or c.attention == "eva"):
             raise NotImplementedError(
                 "one block at a time (parameter streaming, the ZeRO-3 "
                 "pipelined scan) is written for one stream through blocks "
                 "of one kind: farskip, first_dense_layers, mtp_layers, layers "
-                "with and without a rotary position (rope_layers='windowed') "
-                "and packed documents (document_separator) take the "
-                "whole-model scan of TransformerLM.apply")
+                "with and without a rotary position (rope_layers='windowed'), "
+                "packed documents (document_separator) and attention='eva' "
+                "take the whole-model scan of TransformerLM.apply")
         carry = (x, positions, self._aux_zero())
         keep = jnp.asarray(keep, self.config.dtype)
         packed = (block, keep) if window is None else (block, keep, window)
@@ -1331,7 +1562,8 @@ class TransformerLM:
               return_hidden: bool = False,
               return_stats: bool = False,
               remat_budget: Optional[Budget] = None) -> Tuple[jax.Array, ...]:
-        """Return (logits [B,S,V] in fp32, moe_aux_loss scalar).
+        """Return (logits [B,S,V] in fp32, ``[B,S,heads,V]`` with
+        ``pred_heads``; moe_aux_loss scalar).
 
         ``return_stats`` appends the step's device-side statistics, a dict:
         ``moe_expert_rows`` [layers, experts] int32 on the no-drop MoE path
@@ -1369,7 +1601,9 @@ class TransformerLM:
         blocks: a head's float32 logits and their gradient (two heads are
         each run again in the backward, so one counts)."""
         if remat_budget is not None:
-            remat_budget.outside_bytes = 2 * 4 * input_ids.size * self.config.vocab_size
+            c = self.config
+            remat_budget.outside_bytes = (2 * 4 * input_ids.size * c.vocab_size
+                                          * c.pred_heads)
 
     def _trunk(self, params, input_ids, layer_mask, token_type_ids,
                attention_mask, remat_budget, with_mtp: bool,
@@ -1634,8 +1868,8 @@ class TransformerLM:
                   extra_mask: Optional[jax.Array] = None) -> jax.Array:
         """Final norm + LM/MLM head + masked cross-entropy over the last
         block's output (the differentiated tail of the overlap schedule)."""
-        return masked_cross_entropy(self.head(params, x), labels,
-                                    extra_mask=extra_mask)
+        return next_token_cross_entropy(self.config, self.head(params, x), labels,
+                                        extra_mask)
 
     def combine_aux(self, loss: jax.Array, aux: jax.Array) -> jax.Array:
         """Fold the accumulated MoE aux loss into the objective: each
@@ -1675,8 +1909,8 @@ class TransformerLM:
                                  token_type_ids=batch.get("token_type_ids"),
                                  attention_mask=batch.get("attention_mask"),
                                  remat_budget=remat_budget)
-        loss = masked_cross_entropy(logits, labels,
-                                    extra_mask=batch.get("loss_mask"))
+        loss = next_token_cross_entropy(self.config, logits, labels,
+                                        batch.get("loss_mask"))
         return self.combine_aux(loss, aux)
 
     def loss_and_stats(self, params: Params, batch: Dict[str, jax.Array],

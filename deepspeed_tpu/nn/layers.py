@@ -134,12 +134,16 @@ class LayerNorm:
 
 @dataclasses.dataclass(frozen=True)
 class RMSNorm:
-    """Pre-norm used by Llama-family models (reference rms_norm.cu)."""
+    """Pre-norm used by Llama-family models (reference rms_norm.cu).
+    ``unit_offset``: the gain is ``1 + scale`` (EvaByte's
+    ``norm_add_unit_offset``), so the stored scale starts at 0."""
     features: int
     eps: float = 1e-6
+    unit_offset: bool = False
 
     def init(self, rng, dtype=jnp.float32) -> Params:
-        return {"scale": jnp.ones((self.features,), dtype=dtype)}
+        fill = jnp.zeros if self.unit_offset else jnp.ones
+        return {"scale": fill((self.features,), dtype=dtype)}
 
     def specs(self) -> Params:
         return {"scale": P()}
@@ -147,7 +151,27 @@ class RMSNorm:
     def __call__(self, params: Params, x: jax.Array) -> jax.Array:
         xf = x.astype(jnp.float32)
         y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps)
-        return (y * params["scale"].astype(jnp.float32)).astype(x.dtype)
+        gain = params["scale"].astype(jnp.float32)
+        if self.unit_offset:
+            gain = 1.0 + gain
+        return (y * gain).astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class HeadVectors:
+    """One learned vector a head, ``[heads, features]``, drawn normal(0,
+    ``init_scale``): EVA attention's ``phi`` and ``mu`` of a layer. The
+    caller reads the array itself (``params["value"]``)."""
+    heads: int
+    features: int
+    init_scale: float = 0.02
+
+    def init(self, rng, dtype=jnp.float32) -> Params:
+        return {"value": _init_dense(rng, (self.heads, self.features),
+                                     self.init_scale, dtype)}
+
+    def specs(self) -> Params:
+        return {"value": P(MODEL_AXIS, None)}
 
 
 def gelu(x: jax.Array) -> jax.Array:

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -205,7 +205,8 @@ FLASH_MIN_SEQ_WIDE_HEAD = 256    # head dim >= 128
 
 
 def kernel_is_default(q_shape, k_shape, backend: str,
-                      blockdiff: Optional[int] = None) -> bool:
+                      blockdiff: Optional[int] = None,
+                      eva: Optional[Tuple[int, int]] = None) -> bool:
     """Whether ``flash_attention`` takes the in-repo blockwise kernel for
     this call when nothing forces a route: a rule of shape and platform
     alone. Off the TPU the XLA path stays (tier-1 dispatch and the
@@ -214,18 +215,25 @@ def kernel_is_default(q_shape, k_shape, backend: str,
         return False
     from . import pallas_flash as _pf
     min_seq = FLASH_MIN_SEQ_WIDE_HEAD if q_shape[3] >= 128 else FLASH_MIN_SEQ
-    return (q_shape[1] >= min_seq
-            and _pf.supports(q_shape, k_shape, compiled=True, blockdiff=blockdiff))
+    # an EVA row's exact keys are launched a window at a time
+    seq = q_shape[1] if eva is None else min(q_shape[1], eva[0])
+    return (seq >= min_seq
+            and _pf.supports(q_shape, k_shape, compiled=True, blockdiff=blockdiff,
+                             eva=eva))
 
 
 def choose_route(q_shape, k_shape, backend: str, mode: str,
-                 blockdiff: Optional[int] = None) -> str:
-    """The whole decision of `flash_attention` and of `blockdiff_attention`:
+                 blockdiff: Optional[int] = None,
+                 eva: Optional[Tuple[int, int]] = None) -> str:
+    """The whole decision of `flash_attention`, of `blockdiff_attention` and
+    of `eva_attention`:
     ``"kernel"`` (the in-repo blockwise pair), ``"xla"`` (one shot) or
     ``"xla_chunked"``. A pure function of the two shapes, the platform,
     `attn_mode`'s value and, under the block-diffusion mask, its block
     length (``q_shape`` then holds both copies' ``2 L`` rows and ``k_shape``
-    the clean copy's ``L``; the crossover is asked of the ``2 L``).
+    the clean copy's ``L``; the crossover is asked of the ``2 L``); under
+    EVA's, ``eva = (window, chunk)`` (both shapes the row's ``L``; the
+    crossover is asked of one window, which is what a launch sees).
 
     ``mode == "pallas"`` takes the kernel wherever it CAN run (interpret
     mode off the TPU relaxes the 128-lane tile requirement to plain
@@ -236,9 +244,9 @@ def choose_route(q_shape, k_shape, backend: str, mode: str,
     if mode == "pallas":
         from . import pallas_flash as _pf
         if _pf.supports(q_shape, k_shape, compiled=backend != "cpu",
-                        blockdiff=blockdiff):
+                        blockdiff=blockdiff, eva=eva):
             return "kernel"
-    elif mode == "" and kernel_is_default(q_shape, k_shape, backend, blockdiff):
+    elif mode == "" and kernel_is_default(q_shape, k_shape, backend, blockdiff, eva):
         return "kernel"
     if q_shape[1] >= XLA_CHUNK_MIN_SEQ and backend != "cpu":
         return "xla_chunked"
@@ -412,6 +420,113 @@ def blockdiff_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                         block_length, scale)
     noised, _ = _pf.merge_partials(o[:, L:], lse[:, :, L:], own, own_lse)
     return jnp.concatenate([o[:, :L], noised], axis=1)
+
+
+# ---------------------------------------------------------------------------
+# EVA attention (Zheng et al., arXiv:2302.04542, as EvaByte runs it)
+# ---------------------------------------------------------------------------
+# A row of L positions is cut, from its start, into windows of W positions
+# and chunks of c (c divides W). Every chunk g has ONE learned summary key and
+# value (`eva_summaries`). With ``win(i) = i // W``:
+#   query i sees the exact key j    iff win(j) == win(i) and j <= i;
+#   query i sees the summary of g   iff win(g c) < win(i)
+#              (every chunk of every window that is complete before i's own);
+# one softmax a query over both. No document ids enter.
+
+def eva_visible(q_pos, k_at, k_summary, window: int, chunk: int):
+    """Whether a key is visible to a query under EVA's mask (broadcasting):
+    THE definition, which the XLA route computes and the kernel route is
+    tested against. ``k_at``: an exact key's position, a summary's chunk
+    index; ``k_summary``: which of the two a key is."""
+    qw = q_pos // window
+    exact = (k_at // window == qw) & (k_at <= q_pos)
+    return jnp.where(k_summary, (k_at * chunk) // window < qw, exact)
+
+
+def eva_summaries(k: jax.Array, v: jax.Array, phi: jax.Array, mu: jax.Array,
+                  chunk: int):
+    """One summary key and value a chunk and head: ``w_j = softmax over the
+    chunk's j of (k_j . phi_h)`` (float32), ``kbar = sum_j w_j k_j + mu_h``,
+    ``vbar = sum_j w_j v_j``. k, v [B, L, H, D]; phi, mu [H, D] ->
+    kbar, vbar [B, L // chunk, H, D] in k's dtype (a trailing part of a
+    chunk has no summary: no query could see it)."""
+    B, L, H, D = k.shape
+    n = L // chunk
+    f32 = jnp.float32
+    kc = k[:, :n * chunk].reshape(B, n, chunk, H, D).astype(f32)
+    vc = v[:, :n * chunk].reshape(B, n, chunk, H, D).astype(f32)
+    w = jax.nn.softmax(jnp.sum(kc * phi.astype(f32), axis=-1), axis=2)[..., None]
+    kbar = jnp.sum(w * kc, axis=2) + mu.astype(f32)
+    return kbar.astype(k.dtype), jnp.sum(w * vc, axis=2).astype(v.dtype)
+
+
+def _xla_eva_attention(q, k, v, kbar, vbar, window: int, chunk: int,
+                       scale: Optional[float], rows: Optional[int]):
+    """`eva_visible` built densely over the exact keys and the summaries
+    side by side and handed to `_xla_attention`; ``rows``: queries at a time
+    (a memory bound; they divide a window, so a part's keys are its own
+    window's up to its last row and the summaries before that window)."""
+    L = q.shape[1]
+
+    def part(lo, n):
+        first = lo // window * window                   # its window's first key
+        summaries = (lo + n - 1) // window * window // chunk
+        k_at = jnp.concatenate([jnp.arange(first, lo + n), jnp.arange(summaries)])
+        seen = eva_visible(jnp.arange(lo, lo + n)[:, None], k_at[None, :],
+                           (jnp.arange(k_at.size) >= lo + n - first)[None, :],
+                           window, chunk)
+        cat = lambda exact, far: jnp.concatenate(
+            [exact[:, first:lo + n], far[:, :summaries]], axis=1)
+        return _xla_attention(q[:, lo:lo + n], cat(k, kbar), cat(v, vbar), False,
+                              scale, None, visible=seen[None])
+
+    if rows is None or L <= rows or window % rows or L % rows:
+        return part(0, L)
+    return jnp.concatenate([part(lo, rows) for lo in range(0, L, rows)], axis=1)
+
+
+def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                  kbar: jax.Array, vbar: jax.Array, window: int, chunk: int,
+                  scale: Optional[float] = None) -> jax.Array:
+    """Attention under EVA's mask (above). q, k, v [B, L, H, D] (as many key
+    heads as query heads); kbar, vbar [B, L // chunk, H, D] from
+    `eva_summaries`. -> [B, L, H, D].
+
+    On the kernel route (`choose_route`) two launches of the flash pair and
+    `merge_partials`: the exact keys as a plain causal launch over the row's
+    windows, one window a batch row (``flash_*_eva_local``: no tile off a
+    window's own diagonal exists, so none is stepped over), and the
+    summaries as a launch of L queries over L / chunk keys under a q-block's
+    limit (``pallas_flash``'s ``summaries``; ``flash_*_eva_far``); a query of
+    the row's first window sees no summary and leaves the second launch 0
+    with the sentinel LSE. A row no longer than a window is the first launch
+    alone. Elsewhere the whole mask in XLA, its queries in parts from
+    `XLA_CHUNK_MIN_SEQ` up on a device."""
+    B, L, H, D = q.shape
+    if k.shape != q.shape or window % chunk:
+        raise ValueError(f"EVA attention takes as many key heads as query heads "
+                         f"and a window ({window}) of whole chunks ({chunk}); "
+                         f"got q {q.shape}, k {k.shape}")
+    mode, backend = attn_mode(), jax.default_backend()
+    route = choose_route(q.shape, k.shape, backend, mode, eva=(window, chunk))
+    _log_path_once(f"eva {route}")
+    if route != "kernel":
+        return _xla_eva_attention(q, k, v, kbar, vbar, window, chunk, scale,
+                                  1024 if route == "xla_chunked" else None)
+    from . import pallas_flash as _pf
+    windows = -(-L // window)
+    each = L // windows
+    fold = lambda a: a.reshape((B * windows, each) + a.shape[2:])
+    o, lse = _pf.flash_attention_with_lse(fold(q), fold(k), fold(v), causal=True,
+                                          scale=scale, tag="eva_local")
+    o = o.reshape(B, L, H, D)
+    if windows == 1:
+        return o
+    lse = lse.reshape(B, windows, H, each).transpose(0, 2, 1, 3).reshape(B, H, L)
+    far, far_lse = _pf.flash_attention_with_lse(
+        q, kbar, vbar, causal=True, scale=scale,
+        summaries=(window, window // chunk), tag="eva_far")
+    return _pf.merge_partials(o, lse, far, far_lse)[0]
 
 
 @functools.lru_cache(None)

@@ -55,6 +55,19 @@ far narrower than any tile) are an einsum outside this module, joined by
 ``merge_partials``; a noised row with no clean key (a document's first block)
 leaves here 0 with the sentinel LSE.
 
+EVA's summaries (``summaries=(W, per)``; ``attention.eva_attention``): the
+keys are one learned summary a chunk of the queries' row, ``per`` a window of
+``W`` positions, and a row of window ``w`` sees the summaries of the windows
+before it, keys ``0 .. w x per - 1``. ``W`` is a multiple of both q tiles
+(``summary_tiles``), so a q-block has ONE limit, a scalar, in the place of the
+block-diffusion mask's two (``_block_limits``; ``_limited`` says a launch has
+such a limit): skipping, the wholly-visible test and the edge tile's compare
+are the same code. A row of the first window leaves 0 with the sentinel LSE.
+It composes with nothing else (no ids, window, ALiBi or ``q_offset``). EVA's
+exact keys are a plain causal launch over the row folded to one batch row a
+window. ``tag`` names a launch ``flash_fwd_<tag>`` / ``flash_bwd_<tag>`` and
+its two residuals ``attn_o_<tag>`` / ``attn_lse_<tag>``.
+
 The table of documents (a launch with segment ids; none is built, and no
 operand added, without): ``_prepare`` reduces the ids once to each block's
 LOWEST and HIGHEST id (``block_ranges``: for the forward's tiles and for the
@@ -144,6 +157,15 @@ class FlashConfig:
     # query rows are a clean and a noised copy of L positions, the L keys
     # the clean copy's (module docstring); None: causal / window
     blockdiff: Optional[Tuple[int, int]] = None
+    # EVA's far keys, ``(window W, summaries a window)``: the keys are one
+    # summary a chunk of the queries' row, and a q-block (its rows lie in one
+    # window: W is a multiple of both q tiles) sees the summaries of the
+    # windows before its own, keys ``0 .. (first row // W) x per - 1``
+    summaries: Optional[Tuple[int, int]] = None
+    # a name of its own for the launches (``flash_fwd_<tag>``) and their two
+    # residuals, for whoever reads a trace or lists kept names; one of
+    # ``TAGS``, or None: by the mask
+    tag: Optional[str] = None
 
 
 def _lanes(x: jax.Array, n: int) -> jax.Array:
@@ -221,10 +243,22 @@ def _half_of(cfg: FlashConfig, bq: int, i):
 
 def _block_limits(cfg: FlashConfig, bq: int, i):
     """The last visible key of q-block ``i``'s first row and of its last
-    (``bq`` is a multiple of b: a block of positions is never cut)."""
+    (``bq`` is a multiple of b: a block of positions is never cut). Over
+    EVA's summaries both are the q-block's one limit."""
+    if cfg.summaries is not None:
+        window, per = cfg.summaries
+        limit = lax.div(i, window // bq) * per - 1
+        return limit, limit
     b = cfg.blockdiff[0]
     first, lost = _half_of(cfg, bq, i)
     return first + b - 1 - lost, first + bq - 1 - lost
+
+
+def _limited(cfg: FlashConfig) -> bool:
+    """Whether a row's last visible key is a limit of its own, in
+    ``q_pos``'s place (`_block_limits`): the block-diffusion mask, EVA's
+    summaries."""
+    return cfg.blockdiff is not None or cfg.summaries is not None
 
 
 class _BlockDocs(NamedTuple):
@@ -280,7 +314,7 @@ def _should_run(cfg: FlashConfig, tile: Tile, i, j, info_ref,
     if not cfg.causal:
         return True if docs is None else docs.meet(i, j)
     bq, bk = tile
-    if cfg.blockdiff is not None:
+    if _limited(cfg):
         # the limit rises with the row inside each half: the block's last
         # row has its highest
         run = _block_limits(cfg, bq, i)[1] >= j * bk
@@ -301,7 +335,7 @@ def _fully_visible(cfg: FlashConfig, tile: Tile, i, j, info_ref):
     compare or select (its segment compare, where it has ids, stays). Only
     asked of causal configurations."""
     bq, bk = tile
-    if cfg.blockdiff is not None:
+    if _limited(cfg):
         return _block_limits(cfg, bq, i)[0] >= j * bk + bk - 1
     q_off = info_ref[0]
     # first q row sits at or after the block's last key
@@ -365,7 +399,10 @@ def _tile_logits(cfg: FlashConfig, tile: Tile, q, k, i, j, info_ref,
             # per row)
             slope = slopes_ref[head_idx]
             s = s + slope * (k_pos - q_pos).astype(jnp.float32)
-        if cfg.causal and positional and cfg.blockdiff is not None:
+        if cfg.causal and positional and cfg.summaries is not None:
+            cm = _block_limits(cfg, bq, i)[1] >= k_pos
+            mask = cm if mask is None else mask & cm
+        elif cfg.causal and positional and cfg.blockdiff is not None:
             first, lost = _half_of(cfg, bq, i)
             at = lax.broadcasted_iota(jnp.int32, s.shape, q_axis) + first
             cm = ((at | (cfg.blockdiff[0] - 1)) - lost) >= k_pos
@@ -385,6 +422,10 @@ def _head_index(cfg: FlashConfig, b, g, G):
     """Global query-head index for (folded batch*kv_head, group) — the
     ALiBi slope lookup."""
     return (b % cfg.kv_heads) * G + g
+
+
+#: the tags a caller may give a launch (``FlashConfig.tag``): EVA's two
+TAGS = ("eva_local", "eva_far")
 
 
 def _compiler_params(cfg: FlashConfig, semantics):
@@ -531,9 +572,12 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info):
         compiler_params=_compiler_params(
             cfg, ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
-        # a name of its own for each mask whose grids or compare are its own
-        # (the benchmark's readers find the launches by it)
-        name=("flash_fwd_blockdiff" if cfg.blockdiff is not None
+        # a name of its own for each mask whose grids or compare are its own,
+        # and for a caller's tag (the benchmark's readers find the launches
+        # by it; literal, so that a test can list every kernel's names)
+        name=("flash_fwd_eva_local" if cfg.tag == "eva_local"
+              else "flash_fwd_eva_far" if cfg.tag == "eva_far"
+              else "flash_fwd_blockdiff" if cfg.blockdiff is not None
               else "flash_fwd" if cfg.window is None else "flash_fwd_window"),
     )(*prefetch, q, k, v, qseg_c, kseg_r)
 
@@ -757,7 +801,9 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
             compiler_params=_compiler_params(
                 cfg, ("parallel", "parallel", "arbitrary", "arbitrary")),
             interpret=cfg.interpret,
-            name=("flash_bwd_blockdiff" if cfg.blockdiff is not None
+            name=("flash_bwd_eva_local" if cfg.tag == "eva_local"
+                  else "flash_bwd_eva_far" if cfg.tag == "eva_far"
+                  else "flash_bwd_blockdiff" if cfg.blockdiff is not None
                   else "flash_bwd" if W is None else "flash_bwd_window"),
         )(*prefetch, q, k, v, kseg_c, qseg_r, do, lse, di)
         if slots == 1:
@@ -807,7 +853,10 @@ def _flash_fwd(cfg, q, k, v, segs, slopes, info):
     # saves: with both kept, a rematerialised block's backward launches no
     # ``flash_fwd`` (models/transformer.py ``remat_policy``); either costs
     # the whole kernel to make again
-    o, lse = checkpoint_name(o, "attn_o"), checkpoint_name(lse, "attn_lse")
+    # (a tagged launch's carry the tag: its caller decides whether the order
+    # of kept names lists them)
+    tag = "" if cfg.tag is None else "_" + cfg.tag
+    o, lse = checkpoint_name(o, "attn_o" + tag), checkpoint_name(lse, "attn_lse" + tag)
     return (o, lse), (q, k, v, segs, slopes, info, o, lse)
 
 
@@ -947,18 +996,30 @@ def choose_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
 
 def supports(q_shape, k_shape, block_q: Optional[int] = None,
              block_k: Optional[int] = None, compiled: bool = True,
-             blockdiff: Optional[int] = None) -> bool:
+             blockdiff: Optional[int] = None,
+             eva: Optional[Tuple[int, int]] = None) -> bool:
     """Shape gate. ``compiled=True`` (the TPU path) additionally requires
     tiles on the 128-lane layout; ``compiled=False`` (the interpret path
     driven on CPU test meshes) accepts anything the tiles divide evenly.
     ``blockdiff``: the block length of the block-diffusion mask, whose
-    queries are two copies of the keys' positions (``blockdiff_tiles``)."""
+    queries are two copies of the keys' positions (``blockdiff_tiles``).
+    ``eva``: EVA's ``(window, chunk)``, whose row is launched a window at a
+    time over its exact keys and once over its summaries
+    (``summary_tiles``); a row no longer than a window is one causal launch."""
     B, Sq, H, D = q_shape
     Sk, kvH = k_shape[1], k_shape[2]
     if H % kvH:
         return False
     if D > NUM_LANES and D % NUM_LANES:
         return False
+    if eva is not None:
+        window, chunk = eva
+        if Sq != Sk or H != kvH or blockdiff is not None:
+            return False
+        if Sq > window and (Sq % window or window % chunk or summary_tiles(
+                Sq, window, window // chunk, D, compiled=compiled) is None):
+            return False
+        Sq = Sk = min(Sq, window)
     if blockdiff is not None:
         return Sq == 2 * Sk and blockdiff_tiles(
             Sk, D, blockdiff, block_q=block_q, block_k=block_k,
@@ -983,6 +1044,23 @@ def blockdiff_tiles(keys: int, head_dim: int, block_length: int,
     if tiles is None or tiles.fwd[0] % b or tiles.bwd[0] % b:
         return None
     return tiles
+
+
+def summary_tiles(queries: int, window: int, per: int, head_dim: int,
+                  itemsize: int = 2, *, block_q: Optional[int] = None,
+                  block_k: Optional[int] = None, compiled: bool = True
+                  ) -> Optional[FlashTiles]:
+    """The tiles of a launch over EVA's summaries (``FlashConfig.
+    summaries``): ``queries`` rows over ``queries // window x per`` keys, the
+    causal choice where both q tiles divide the window (a q-block then has
+    ONE limit), else that choice under a q tile of one window; else None."""
+    keys = queries // window * per
+    for bq in dict.fromkeys((block_q, block_q or window)):
+        tiles = choose_tiles(queries, keys, head_dim, itemsize, causal=True,
+                             block_q=bq, block_k=block_k, compiled=compiled)
+        if tiles is not None and not (window % tiles.fwd[0] or window % tiles.bwd[0]):
+            return tiles
+    return None
 
 
 def static_window(window, sq: int, sk: int, q_offset=None) -> Optional[int]:
@@ -1035,7 +1113,7 @@ def tiles_run(q_ids: jax.Array, k_ids: jax.Array, tile: Tile, *,
 
 def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
              alibi_slopes, window, q_offset, block_q, block_k, interpret,
-             blockdiff=None):
+             blockdiff=None, summaries=None, tag=None):
     B, Sq, H, D = q.shape
     Sk, kvH = k.shape[1], k.shape[2]
     if H % kvH:
@@ -1048,6 +1126,8 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         window = None                # global, or a window that never binds
     cut = static_window(window, Sq, Sk, q_offset)
     interp = _auto_interpret() if interpret is None else interpret
+    if tag is not None and tag not in TAGS:
+        raise ValueError(f"tag {tag!r} is none of {TAGS}")
     if blockdiff is not None:
         if (not causal or window is not None or alibi_slopes is not None
                 or q_offset is not None or Sq != 2 * Sk):
@@ -1057,6 +1137,17 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         tiles = blockdiff_tiles(Sk, D, blockdiff, q.dtype.itemsize,
                                 block_q=block_q, block_k=block_k,
                                 compiled=not interp)
+        q_offset = 0
+    elif summaries is not None:
+        if (not causal or window is not None or alibi_slopes is not None
+                or q_offset is not None or segment_ids is not None
+                or Sq // summaries[0] * summaries[1] != Sk):
+            raise ValueError(
+                "EVA's summaries take one key a chunk of the queries' row and "
+                "no window, ALiBi, q_offset or segment ids")
+        tiles = summary_tiles(Sq, *summaries, D, q.dtype.itemsize,
+                              block_q=block_q, block_k=block_k,
+                              compiled=not interp)
         q_offset = 0
     else:
         tiles = choose_tiles(Sq, Sk, D, q.dtype.itemsize, causal=bool(causal),
@@ -1082,7 +1173,9 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         use_alibi=alibi_slopes is not None,
         use_window=window is not None,
         kv_heads=kvH, tiles=tiles, interpret=bool(interp), window=cut,
-        blockdiff=None if blockdiff is None else (int(blockdiff), Sk))
+        blockdiff=None if blockdiff is None else (int(blockdiff), Sk),
+        summaries=None if summaries is None else tuple(map(int, summaries)),
+        tag=tag)
 
     segs = (None,) * 6
     if segment_ids is not None:
@@ -1127,7 +1220,8 @@ def flash_attention_with_lse(
         window: Optional[jax.Array] = None,
         q_offset=None, block_q: Optional[int] = None,
         block_k: Optional[int] = None, interpret: Optional[bool] = None,
-        blockdiff: Optional[int] = None
+        blockdiff: Optional[int] = None,
+        summaries: Optional[Tuple[int, int]] = None, tag: Optional[str] = None
 ) -> Tuple[jax.Array, jax.Array]:
     """Flash attention returning ``(out [B, Sq, H, D], lse [B, H, Sq])``.
 
@@ -1144,11 +1238,18 @@ def flash_attention_with_lse(
     earlier blocks, a noised row those of strictly earlier blocks (a row
     with none comes back 0 with the sentinel LSE, for ``merge_partials``);
     ``segment_ids`` / ``q_segment_ids`` keep both inside a document.
+
+    ``summaries``: ``(window W, summaries a window)`` puts the launch over
+    EVA's far keys (``attention.eva_attention``): ``k`` and ``v`` hold one
+    summary a chunk of the ``Sq`` positions, and a row of window ``w`` sees
+    the summaries of the windows before it, keys ``0 .. w x per - 1`` (a row
+    of the first window none: 0 with the sentinel LSE). ``tag`` names the
+    launches ``flash_fwd_<tag>`` / ``flash_bwd_<tag>``.
     """
     B, Sq, H, D = q.shape
     cfg, q4, k3, v3, segs, slopes, info, dims = _prepare(
         q, k, v, causal, scale, segment_ids, q_segment_ids, alibi_slopes,
-        window, q_offset, block_q, block_k, interpret, blockdiff)
+        window, q_offset, block_q, block_k, interpret, blockdiff, summaries, tag)
     _, _, kvH, G = dims
     o, lse = _flash(cfg, q4, k3, v3, segs, slopes, info)
     out = o.reshape(B, kvH, G, Sq, D).reshape(B, H, Sq, D)

@@ -167,7 +167,17 @@ def resolve_policy(name: Optional[str]):
 #: their groups' neighbourhood; ``kv_up`` contracts over the latent rank, a
 #: quarter of that a byte, and comes last. A shared expert's first products
 #: are a dense MLP's and take its names (``gate_proj``, ``up_proj``).
-SAVE_ORDER = (("attn_lse", "attn_o"), ("moe_logits",), ("wi_gate", "wi_up"),
+#: EVA's summaries (``eva_kbar``, ``eva_vbar``; one a chunk of 16: a
+#: sixteenth of k and v's bytes) come second, reckoned and not measured: kept,
+#: they spare the backward the in-chunk softmax and two weighted sums over
+#: the whole of k and v, a pass bound by memory. They are named where a
+#: layer's heads run at once; where they run in groups (a 32,768-row step)
+#: nothing inside a group is named, since a value kept from inside a group's
+#: own ``jax.checkpoint`` would be stacked over the groups, whole. EVA's two
+#: launches name their residuals after their tags (``attn_o_eva_local``, ...),
+#: which this order does not list: they are made again.
+SAVE_ORDER = (("attn_lse", "attn_o"), ("eva_kbar", "eva_vbar"),
+              ("moe_logits",), ("wi_gate", "wi_up"),
               ("wi",), ("wo",), ("fc_in",), ("gate_proj", "up_proj"),
               ("o_proj",), ("attn_gate",),
               ("q_proj", "k_proj", "v_proj", "kv_latent"), ("kv_up",))

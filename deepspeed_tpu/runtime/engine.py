@@ -2397,12 +2397,20 @@ class DeepSpeedEngine:
         if kinds is None:
             return {}
         windows = sorted({w for w, _ in kinds if w})
-        return {"layers_window": sum(1 for w, _ in kinds if w),
-                "layers_full": sum(1 for w, _ in kinds if not w),
-                "window": windows[0] if len(windows) == 1 else (windows or None),
-                "kv_heads": self.model.config.kv_heads,
-                "documents": self.model.config.document_separator is not None,
-                "route": {"window": None, "full": None}}
+        cfg = self.model.config
+        totals = {"layers_window": sum(1 for w, _ in kinds if w),
+                  "layers_full": sum(1 for w, _ in kinds if not w),
+                  "window": windows[0] if len(windows) == 1 else (windows or None),
+                  "kv_heads": cfg.kv_heads,
+                  "documents": cfg.document_separator is not None,
+                  "route": {"window": None, "full": None}}
+        if getattr(cfg, "attention", None) == "eva":
+            # EVA's counts (docs/OBSERVABILITY.md): the summaries of a row
+            # and the route are a traced step's (`_count_attention`)
+            totals["eva"] = {"window": cfg.eva_window, "chunk": cfg.eva_chunk,
+                             "summaries_a_row": None,
+                             "pred_heads": cfg.pred_heads, "route": None}
+        return totals
 
     def _diffusion_of_model(self) -> Optional[Dict[str, Any]]:
         cfg = getattr(self.model, "config", None)
@@ -2426,6 +2434,14 @@ class DeepSpeedEngine:
                 (b, 2 * s, cfg.num_heads, cfg.head_dim),
                 (b, s, cfg.kv_heads, cfg.head_dim), jax.default_backend(),
                 attention.attn_mode(), cfg.block_length)
+            return
+        if "eva" in self.attn_totals:
+            shape = (b, s, cfg.num_heads, cfg.head_dim)
+            self.attn_totals["eva"].update(
+                summaries_a_row=s // cfg.eva_chunk,
+                route=attention.choose_route(
+                    shape, shape, jax.default_backend(), attention.attn_mode(),
+                    eva=(cfg.eva_window, cfg.eva_chunk)))
             return
         route = attention.choose_route(
             (b, s, cfg.num_heads, cfg.head_dim), (b, s, cfg.kv_heads, cfg.head_dim),

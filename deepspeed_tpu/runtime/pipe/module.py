@@ -60,6 +60,12 @@ class PipelineModule:
                 "PipelineModule trains the next-token objective: "
                 "objective='block_diffusion' (a clean and a noised copy of "
                 "every row, its own mask and loss weights) is not pipelined")
+        if config.attention == "eva" or config.pred_heads > 1:
+            raise NotImplementedError(
+                "PipelineModule's stage scan runs mha or latent attention under "
+                "one next-token head: attention='eva' (its per-layer summary "
+                "vectors, windows counted over the whole row) and pred_heads "
+                f"({config.pred_heads}) are not pipelined")
         self.config = config
         self.num_stages = num_stages
         self.layers_per_stage = config.num_layers // num_stages

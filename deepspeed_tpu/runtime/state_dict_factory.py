@@ -1107,6 +1107,9 @@ def _register_builtins() -> None:
     from ..models import sdar_moe
     register_architecture("sdar_moe", sdar_moe.config_kwargs,
                           sdar_moe.checkpoint_params)
+    from ..models import evabyte
+    register_architecture("evabyte", evabyte.config_kwargs,
+                          evabyte.checkpoint_params)
 
 
 _register_builtins()

@@ -60,8 +60,8 @@ def test_custom_registration():
 
 def test_architecture_registry_builtin():
     assert supported_architectures() == \
-        ["afmoe", "bert", "bloom", "deepseek_v3", "distilbert", "falcon", "gpt2", "gpt_neo",
-         "gpt_neox", "gptj", "internlm", "llama", "mistral", "mixtral",
+        ["afmoe", "bert", "bloom", "deepseek_v3", "distilbert", "evabyte", "falcon", "gpt2",
+         "gpt_neo", "gpt_neox", "gptj", "internlm", "llama", "mistral", "mixtral",
          "opt", "phi", "qwen2", "roberta", "sdar_moe"]
     spec = get_architecture("falcon")
     cfg = spec.config_fn({"model_type": "falcon", "vocab_size": 128,

@@ -119,6 +119,31 @@ class TestCompilesForV5e:
         text = jax.jit(fn).lower(*args).as_text()
         assert "flash_fwd_blockdiff" in text and "flash_bwd_blockdiff" in text
 
+    def test_eva_attention_at_32k(self, chip, monkeypatch):
+        """The evabyte-6.5b cell's attention: 32 heads of 128 over a row of
+        32,768 under EVA's mask with a window of 2048 and chunks of 16: the
+        summaries, the exact keys a window at a time, the 2,048 summaries
+        under a q-block's limit and the merge, under the launches' own
+        names."""
+        from deepspeed_tpu.ops.transformer import attention
+        B, L, H, D, W, c = 1, 32768, 32, 128, 2048, 16
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv("DSTPU_ATTN", raising=False)
+        assert attention.choose_route((B, L, H, D), (B, L, H, D), "tpu", "",
+                                      eva=(W, c)) == "kernel"
+
+        def loss(q, k, v, phi, mu):
+            kbar, vbar = attention.eva_summaries(k, v, phi, mu, c)
+            return jnp.sum(attention.eva_attention(q, k, v, kbar, vbar, W, c).astype(F32))
+
+        args = (chip((B, L, H, D), BF16),) * 3 + (chip((H, D), BF16),) * 2
+        fn = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+        compile_for_chip(fn, *args)
+        text = jax.jit(fn).lower(*args).as_text()
+        for name in ("flash_fwd_eva_local", "flash_bwd_eva_local",
+                     "flash_fwd_eva_far", "flash_bwd_eva_far"):
+            assert name in text
+
     @pytest.mark.parametrize("m,k,n,g", [
         (32768, 2048, 1024, 64),    # olmoe-1b-7b.train.seq4k, wi_gate / wi_up
         (32768, 1024, 2048, 64),    # its wo

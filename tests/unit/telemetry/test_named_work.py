@@ -141,6 +141,10 @@ KERNEL_NAMES = {
     # the flash pair under the block-diffusion mask (PR 39): one launch over
     # the clean keys for a clean and a noised copy's queries
     "flash_fwd_blockdiff", "flash_bwd_blockdiff",
+    # EVA's two launches (PR 42): the exact keys a window at a time, the
+    # summaries under a q-block's limit (``FlashConfig.tag``)
+    "flash_fwd_eva_local", "flash_bwd_eva_local",
+    "flash_fwd_eva_far", "flash_bwd_eva_far",
     # a share's rows back to the tokens (PR 38), under ``mlp/moe/combine`` and
     # ``mlp/moe/dispatch``: ``train_moe_dispatch_ms`` finds it by its scope
     "segment-sum"}
@@ -168,7 +172,7 @@ def test_every_pallas_call_has_a_name(site):
 def test_kernel_names_are_distinct_and_complete():
     assert len(PALLAS_SITES) == 18
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 22
+    assert len(set(names)) == len(names) == 26
     assert set(names) == KERNEL_NAMES
 
 

@@ -1034,7 +1034,11 @@ class TransformerLM:
                 (columns("q_proj"), columns("k_proj"), columns("v_proj"),
                  kernel("o_proj").reshape(groups, each * c.head_dim, H),
                  by_group(phi), by_group(mu)))
-            return acc.astype(h.dtype)
+            # the branch's output outlives every group: named as the ungrouped
+            # path names it, once a layer. Kept, it is the MLP's input in the
+            # block's recompute, which then drops the group scan whole (a
+            # group's forward runs twice a step, not three times)
+            return checkpoint_name(acc.astype(h.dtype), "o_proj")
 
     def _eva_heads(self, q, k, v, phi, mu, positions,
                    named: bool = False) -> jax.Array:

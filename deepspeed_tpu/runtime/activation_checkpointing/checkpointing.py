@@ -173,9 +173,20 @@ def resolve_policy(name: Optional[str]):
 #: the whole of k and v, a pass bound by memory. They are named where a
 #: layer's heads run at once; where they run in groups (a 32,768-row step)
 #: nothing inside a group is named, since a value kept from inside a group's
-#: own ``jax.checkpoint`` would be stacked over the groups, whole. EVA's two
-#: launches name their residuals after their tags (``attn_o_eva_local``, ...),
-#: which this order does not list: they are made again.
+#: own ``jax.checkpoint`` would be stacked over the groups, whole. What
+#: OUTLIVES the groups is named: the branch's output, the float32 sum of the
+#: groups' output projections cast to the stream's dtype, is the value the
+#: ungrouped path calls ``o_proj`` and takes that name, once a layer (PR 43).
+#: It is such a row's only candidate. Kept (1.07 GB over four layers of
+#: ``bf16[1, 32768, 4096]``), it is the MLP's input in the block's recompute,
+#: which then drops the group scan whole: a group's forward runs twice a
+#: step, not three times. On the chip (PERF.md, PR 43) the kept gigabyte
+#: raised the step's true peak by 1.07 GB (14.09 -> 15.16 of 16.91: a kept
+#: byte at 1.0) and spared 216 ms of a 1,975 ms step, 201 ms a GB: the
+#: dearest reading of this order, since one name stands for a whole branch.
+#: EVA's two launches name their residuals after their tags
+#: (``attn_o_eva_local``, ...), which this order does not list: they are
+#: made again.
 SAVE_ORDER = (("attn_lse", "attn_o"), ("eva_kbar", "eva_vbar"),
               ("moe_logits",), ("wi_gate", "wi_up"),
               ("wi",), ("wo",), ("fc_in",), ("gate_proj", "up_proj"),

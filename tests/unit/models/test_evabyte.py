@@ -20,6 +20,8 @@ from deepspeed_tpu.models import evabyte_model, transformer
 from deepspeed_tpu.models.registry import get_architecture
 from deepspeed_tpu.models.transformer import TransformerConfig, TransformerLM
 from deepspeed_tpu.ops.transformer import attention, pallas_flash
+from deepspeed_tpu.runtime.activation_checkpointing import checkpointing
+from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import Budget
 from tests.benchmark.helpers import DATA
 
 MANIFEST = os.path.join(DATA, "BENCHMARK.evabyte-tiny.json")
@@ -56,12 +58,14 @@ def worst(a, b):
     return np.abs(a - b).max() / max(np.abs(b).max(), 1e-12)
 
 
-def program_loss_and_grad(parts, **model_kw):
+def program_loss_and_grad(parts, budget=None, **model_kw):
+    """``budget``: the engine's reading of the device (``checkpointing.
+    Budget``); None keeps every named value, as a model without an engine."""
     _, adapter, cfg, w, ids = parts
     model = adapter.model(cfg, dtype="float32", **{"remat": True, **model_kw})
     with jax.default_matmul_precision("highest"):
-        loss, g = jax.value_and_grad(
-            lambda p: model.loss(p, {"input_ids": ids}))(adapter.to_program(w))
+        loss, g = jax.value_and_grad(lambda p: model.loss(
+            p, {"input_ids": ids}, remat_budget=budget))(adapter.to_program(w))
     return float(loss), adapter.from_program(g)
 
 
@@ -201,17 +205,28 @@ def test_eight_shifts_against_eight_loops():
     assert abs(np.mean(heads[:-1]) - np.mean(heads)) > 1e-3
 
 
-@pytest.mark.parametrize("what", ["head_groups", "mlp_slices"])
+def two_head_groups(monkeypatch):
+    """The tiny preset's four heads in two groups of two, as a 32,768-row
+    step takes its 32 in eight groups of four."""
+    monkeypatch.setattr(transformer, "EVA_GROUP_ELEMENTS", 2 * 128 * 2 * 16)
+    assert transformer.eva_head_groups(256, 4, 16) == 2
+
+
+@pytest.mark.parametrize("what", ["head_groups", "head_groups_no_room", "mlp_slices"])
 def test_slices_are_the_whole(parts, want, what, monkeypatch):
     """A long row's heads in groups and its MLP over slices of the rows (what
     a 32,768-row step takes so that it fits) are the same arithmetic: the MLP
     slices' forward bit for bit (a row's MLP reads no other row), loss and
     gradients to the tolerance of the whole's (a weight's gradient is summed
-    over the slices in another order)."""
+    over the slices in another order). The grouped branch's output kept for
+    the block's backward (``head_groups``: no reading of the device keeps
+    every name) or made again (``head_groups_no_room``) is one value: both
+    equal the whole's gradients, and so each other's."""
     _, adapter, cfg, w, ids = parts
-    if what == "head_groups":
-        monkeypatch.setattr(transformer, "EVA_GROUP_ELEMENTS", 2 * 128 * 2 * 16)
-        assert transformer.eva_head_groups(256, 4, 16) == 2
+    budget = None
+    if what.startswith("head_groups"):
+        two_head_groups(monkeypatch)
+        budget = Budget(0 if what == "head_groups_no_room" else None)
     else:
         monkeypatch.setattr(transformer, "MLP_WHOLE_ELEMENTS", 64 * 96)
         monkeypatch.setattr(transformer, "MLP_SLICE_ELEMENTS", 64 * 96)
@@ -225,10 +240,74 @@ def test_slices_are_the_whole(parts, want, what, monkeypatch):
         np.testing.assert_array_equal(np.asarray(sliced),
                                       np.asarray(model._gated_mlp(block, h)))
         monkeypatch.setattr(transformer, "MLP_WHOLE_ELEMENTS", 64 * 96)
-    got, flat = program_loss_and_grad(parts)
+    got, flat = program_loss_and_grad(parts, budget)
     assert got == pytest.approx(float(want[0]), rel=1e-6)
     for name, g in want[1].items():
         assert worst(flat[name], g) < 2e-5, name
+    if budget is not None:
+        assert ("o_proj" in budget.totals["saved"]) == (budget.room_bytes is None)
+
+
+def test_a_grouped_branch_names_its_output_once(parts, monkeypatch):
+    """What outlives a long row's inner checkpoints is named for the block's
+    policy, and nothing inside one is: of the names ``SAVE_ORDER`` lists the
+    grouped branch's jaxpr holds ONE, ``o_proj`` (the name the ungrouped path
+    gives the same value), at rows x hidden x itemsize, made outside the
+    group scan. A listed name inside a group would be kept for every group,
+    stacked (the scores' ``attn_big`` is no candidate on either path)."""
+    _, adapter, cfg, w, ids = parts
+    model = adapter.model(cfg, remat=True, dtype="float32")
+    block = jax.tree.map(lambda a: a[0], adapter.to_program(w)["blocks"])
+    h = jax.ShapeDtypeStruct((2, 128, 64), F32)
+    positions = jnp.broadcast_to(jnp.arange(128), (2, 128))
+    branch = lambda: jax.make_jaxpr(
+        lambda b, x: model._eva_attn(b, x, positions))(block, h).jaxpr
+    listed = {n for group in checkpointing.SAVE_ORDER for n in group}
+    candidates = lambda jaxpr: {n: b for n, b in checkpointing.named_bytes(
+        jaxpr).items() if n in listed}
+    whole = candidates(branch())
+    assert whole["o_proj"] == 2 * 128 * 64 * 4
+    assert set(whole) == {"q_proj", "k_proj", "v_proj", "eva_kbar", "eva_vbar", "o_proj"}
+    two_head_groups(monkeypatch)
+    grouped = branch()
+    assert candidates(grouped) == {"o_proj": 2 * 128 * 64 * 4}
+    named = [e for e in grouped.eqns if e.primitive.name == "name"]
+    assert [e.params["name"] for e in named] == ["o_proj"]       # not in the scan
+    assert named[0].outvars[0].aval.shape == (2, 128, 64)
+
+
+def launches(jaxpr, counts=None):
+    """Pallas kernel name -> launches in ``jaxpr`` and every jaxpr inside it
+    (a scan's body once: launches a layer, or a group)."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] = counts.get(eqn.params["name"], 0) + 1
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            launches(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize("room,forwards", [(None, 2), (0, 3)])
+def test_a_kept_branch_output_drops_the_groups_from_the_blocks_recompute(
+        parts, room, forwards, monkeypatch):
+    """A group's attention forward in the differentiated step: once forward,
+    once in the group's own recompute for its backward, and once more in the
+    BLOCK's recompute only where the branch's output (the MLP's input) is not
+    kept. With it kept the block's recompute has no use for the group scan,
+    and JAX's dead-code pass drops it: the chip's 96 launches a step of each
+    forward kind become 64 (4 layers x 8 groups x 2)."""
+    _, adapter, cfg, w, ids = parts
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    two_head_groups(monkeypatch)
+    model = adapter.model(cfg, remat=True, dtype="float32")
+    budget = Budget(room)
+    found = launches(jax.make_jaxpr(jax.grad(lambda p: model.loss(
+        p, {"input_ids": ids}, remat_budget=budget)))(adapter.to_program(w)).jaxpr)
+    assert found["flash_fwd_eva_local"] == found["flash_fwd_eva_far"] == forwards
+    assert found["flash_bwd_eva_local"] == found["flash_bwd_eva_far"] == 1
+    assert ("o_proj" in budget.totals["saved"]) == (room is None)
 
 
 def test_the_slicing_rules():

@@ -142,23 +142,33 @@ RANKED = ("attn_lse", "attn_o", "wo", "fc_in", "q_proj", "k_proj", "v_proj")
 KERNEL = 10 * MB + MB // 4
 
 
-@pytest.mark.parametrize("budget,saved", [
-    (None, RANKED),                            # no budget: every name listed
-    (0, ()),                                   # no room: the parent's program
-    (10 * MB, ()),                             # the kernel's two or neither
-    (KERNEL, RANKED[:2]),
+#: a 32,768-row EVA step's one candidate (PR 43): nothing inside a head group
+#: or an MLP slice is named, the grouped branch's output is, once a layer:
+#: four ``bf16[1, 32768, 4096]``
+LONG_EVA_ROW = {"o_proj": 4 * 32768 * 4096 * 2}
+
+
+@pytest.mark.parametrize("candidates,budget,saved", [
+    (CANDIDATES, None, RANKED),                # no budget: every name listed
+    (CANDIDATES, 0, ()),                       # no room: the parent's program
+    (CANDIDATES, 10 * MB, ()),                 # the kernel's two or neither
+    (CANDIDATES, KERNEL, RANKED[:2]),
     # wo does not fit and fc_in, cheaper per byte, may not jump the queue
-    (KERNEL + 50 * MB, RANKED[:2]),
-    (KERNEL + 80 * MB, RANKED[:3]),
-    (KERNEL + 120 * MB - 1, RANKED[:3]),
-    (KERNEL + 120 * MB, RANKED[:4]),
+    (CANDIDATES, KERNEL + 50 * MB, RANKED[:2]),
+    (CANDIDATES, KERNEL + 80 * MB, RANKED[:3]),
+    (CANDIDATES, KERNEL + 120 * MB - 1, RANKED[:3]),
+    (CANDIDATES, KERNEL + 120 * MB, RANKED[:4]),
     # q and k without v spare nothing (one merged matmul): all three or none
-    (KERNEL + 140 * MB, RANKED[:4]),
-    (KERNEL + 150 * MB, RANKED),
-    (1 << 40, RANKED),                         # and never the scores
+    (CANDIDATES, KERNEL + 140 * MB, RANKED[:4]),
+    (CANDIDATES, KERNEL + 150 * MB, RANKED),
+    (CANDIDATES, 1 << 40, RANKED),             # and never the scores
+    # the EvaByte cell's budget as traced at its shape with 5.9 GB of room
+    # admits the four outputs with 37 % to spare; a byte short keeps none
+    (LONG_EVA_ROW, 1_693_311_258, ("o_proj",)),
+    (LONG_EVA_ROW, 1_073_741_823, ()),
 ])
-def test_choose_saved_table(budget, saved):
-    assert choose_saved(CANDIDATES, budget) == saved
+def test_choose_saved_table(candidates, budget, saved):
+    assert choose_saved(candidates, budget) == saved
 
 
 def test_choose_saved_takes_the_measured_order():
@@ -340,8 +350,8 @@ def test_the_heads_bytes_are_added_once(family, heads):
     assert budget.block_bytes > 0
 
 
-#: The four benchmark cells as the chip traced them (PERF.md, PR 35: the
-#: engine's log lines and ``remat_totals``): layers, one layer's input, the
+#: Five benchmark cells as the chip traced them (PERF.md, PR 35, and PR 43 for
+#: the last: the engine's log lines and ``remat_totals``): layers, one layer's input, the
 #: room the engine read (free less gradients, with the reference's signs
 #: resident), the working set as reckoned (a block's + outside the blocks),
 #: name -> bytes over all layers; and the group the decision must reach.
@@ -367,28 +377,34 @@ CELLS = {
         "gate_proj": 201_326_592, "up_proj": 201_326_592, "o_proj": 402_653_184,
         "attn_gate": 805_306_368, "q_proj": 805_306_368, "k_proj": 100_663_296,
         "v_proj": 100_663_296}, "wi_up"),
+    # PR 43: a 32,768-row EVA step's one candidate, the grouped branch's output
+    # (its flash launches' residuals are inside the groups and unlisted)
+    "evabyte-6.5b.train.seq32k": (4, 268_566_532, 6_231_251_968, 5_908_512_778 + 671_088_640, {
+        "o_proj": 1_073_741_824}, "o_proj"),
 }
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
-def test_the_four_cells_keep_what_the_chip_has_room_for(cell):
+def test_the_cells_keep_what_the_chip_has_room_for(cell):
     """The two short cells keep every name, as before PR 35; the two
     long-sequence cells, which 128 layer inputs left with nothing, keep the
     kernel's pair and the experts' first products (the Instella cell their
-    rows after the combine as well). No decision sits within
+    rows after the combine as well); the 32,768-row EVA cell keeps its one
+    candidate, the grouped branches' outputs (PR 43). No decision sits within
     a twentieth of the room of a group's edge: the same names at 95 % and at
     105 % of the reading, so what a run keeps does not hang on a few MB."""
     layers, carry, room, working, candidates, reaches = CELLS[cell]
     saved = choose_saved(candidates, saved_budget(room, layers, carry, working))
-    assert {"attn_lse", "attn_o"} <= set(saved) and saved[-1] == reaches
-    if reaches == "v_proj":
+    assert {"attn_lse", "attn_o"} & set(candidates) <= set(saved) and saved[-1] == reaches
+    if reaches in ("v_proj", "o_proj"):
         assert set(saved) == set(candidates)
     for share in (0.95, 1.05):
         assert choose_saved(candidates, saved_budget(
             int(share * room), layers, carry, working)) == saved
     # the rule PR 35 replaced: 128 more layer inputs before anything is kept
     old = max(0, int((room - (layers + 128) * carry) / STACK_COST))
-    assert (choose_saved(candidates, old) == ()) == ("seq8k" in cell or "seq16k" in cell)
+    assert (choose_saved(candidates, old) == ()) == any(
+        long in cell for long in ("seq8k", "seq16k", "seq32k"))
 
 
 @pytest.mark.parametrize("family", ["dense", "rope_gated", "moe_qk_norm"])

@@ -17,7 +17,16 @@ backward works on TRANSPOSED tiles S^T = K Q^T: per-query statistics (LSE,
 di) are then rows that broadcast over sublanes, so they travel as
 ``[.., 1, Sq]`` rows and no ``[.., Sq, 128]`` lane-replicated copy of them
 is ever written to HBM, and dk/dv need no transposed-left matmul. Tiles
-come from the shape (:func:`choose_tiles`).
+come from the shape (:func:`choose_tiles`). dk and dv accumulate in VMEM over
+a k-block's q-steps; dq belongs to the q-block, and how it leaves follows the
+k-blocks a q-block meets (:func:`dq_mode`): one, and dq is the kernel's own
+output in q's dtype; a few (``DQ_SUMMED_PARTIALS``: a static window's reach,
+a row of two to four k-blocks), and every pair writes a float32 partial that
+the caller sums; more, and dq is ONE float32 array in HBM that the pairs
+which run read, add to and write back with the kernel's own copies (k-blocks
+in ascending order, one rounding to q's dtype at the end): a skipped pair
+touches nothing, nothing is summed afterwards, and a launch is one launch
+however long its rows.
 
 ``q_offset`` and ``window`` ride scalar prefetch (SMEM), so they may be
 TRACED values — the same compiled kernel serves the main training call
@@ -76,8 +85,8 @@ backward's, the q side from ``q_segment_ids`` where given, under
 scalar prefetch (SMEM) beside ``info`` and ``slopes``, so kernel bodies and
 index maps both read them, and are residuals of the ``custom_vjp`` like the
 ids. "Does q-block i have ANY visible key in k-block j" (``_should_run``, the
-one definition behind ``_for_visible_tile``, the forward's ``k_blk``, the
-backward's ``q_blk`` and its zero-write of a skipped pair's dq slot) then also
+one definition behind ``_for_visible_tile``, the forward's ``k_blk`` and the
+backward's ``q_blk``) then also
 asks ``k_hi[j] >= q_lo[i] and k_lo[j] <= q_hi[i]``. Two ranges that do not
 meet hold no equal pair, whatever the order of the ids, so no pair of such a
 tile would pass ``_tile_logits``' compare and skipping it changes no bit
@@ -587,25 +596,68 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info):
 # ---------------------------------------------------------------------------
 
 
+#: The most k-blocks a q-block may meet for dq to leave as one float32
+#: partial a k-block that the caller sums (v5e, PR 44; docs/KERNELS.md): a
+#: pair that adds in place pays 1.2 us for its two copies of the tile, a
+#: summed partial 0.08 us a pair, and what in-place adding wins is the skipped
+#: pairs (0.2 us for 0.85) and the memory. At 3 (a window of two tiles) the sum
+#: read 1.15 ms a layer less, at 8 and 16 adding in place 0.8 to 5.2 ms less.
+DQ_SUMMED_PARTIALS = 4
+
+
+def dq_partials(sq: int, sk: int, tile: Tile, window: Optional[int] = None) -> int:
+    """The k-blocks a q-block of the backward's ``tile`` meets: every one, or
+    under a static ``window`` the most its reach holds."""
+    nq, nk = sq // tile[0], sk // tile[1]
+    return nk if window is None else window_steps(tile, window, nq, nk)[0]
+
+
+def dq_mode(sq: int, sk: int, tiles: FlashTiles,
+            window: Optional[int] = None) -> str:
+    """How the backward of a launch over ``sq`` queries and ``sk`` keys makes
+    dq, from the k-blocks a q-block of ``tiles.bwd`` meets (`dq_partials`):
+    ``"one_block"`` where that is one (dq is the kernel's own output, in q's
+    dtype), ``"summed"`` up to ``DQ_SUMMED_PARTIALS`` (a float32 partial a
+    k-block met, which the caller sums), ``"in_place"`` past it (ONE float32
+    array a launch, which the pairs that run read, add to and write back).
+    ``_bwd_call`` goes by it."""
+    met = dq_partials(sq, sk, tiles.bwd, window)
+    if met == 1:
+        return "one_block"
+    return "summed" if met <= DQ_SUMMED_PARTIALS else "in_place"
+
+
 def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
-                blocks: Tuple[int, int]):
+                blocks: Tuple[int, int], in_place: bool):
     """dq, dk and dv of one (k-block, q-block) tile from ONE recomputed
     P^T = exp(K Q^T - lse): five matmuls, none with a transposed left
     operand except dq's (one XLU transpose of dS^T). dk/dv accumulate in
-    scratch over the groups and q-blocks of their k-block; dq leaves per
-    k-block and the caller sums the k-blocks. ``refs``: the scalar-prefetch
-    operands ``(info, slopes[, table])``, then q, k, v, the k and q segment
-    ids, do, lse, di, the three outputs and the scratch. ``steps``: the
-    q-steps of the grid (every q-block, or under a static window the
-    q-blocks one k-block reaches); ``blocks``: the launch's (q-blocks,
-    k-blocks)."""
-    prefetch, (q_ref, k_ref, v_ref, kseg_ref, qseg_ref, do_ref, lse_ref,
-               di_ref, dq_ref, dk_ref, dv_ref, dk_scr, dv_scr
-               ) = _split_prefetch(cfg, refs)
+    scratch over the groups and q-blocks of their k-block. dq belongs to the
+    q-block (`dq_mode`): every (k-block, q-block) pair has an output block of
+    its own, which where a q-block meets several k-blocks the caller sums;
+    or, ``in_place``, dq is ONE float32 array in HBM that starts at zero,
+    and a pair that runs fetches its q-block's tile into ``dq_scr`` (the
+    read runs under the tile's first four matmuls), adds its product and
+    sends the tile back (the write runs under the next pair's logits: the
+    next read of ANY tile waits for it, so a tile is never read before an
+    earlier pair's sum has landed); a pair that is skipped touches nothing.
+    ``refs``: the scalar-prefetch operands ``(info, slopes[, table])``, then
+    q, k, v, the k and q segment ids, do, lse, di, ``in_place`` the zeros dq
+    starts from (aliased to it), the three outputs and the scratch.
+    ``steps``: the q-steps of the grid (every q-block, or under a static
+    window the q-blocks one k-block reaches); ``blocks``: the launch's
+    (q-blocks, k-blocks)."""
+    prefetch, refs = _split_prefetch(cfg, refs)
+    (q_ref, k_ref, v_ref, kseg_ref, qseg_ref, do_ref, lse_ref, di_ref
+     ), refs = refs[:8], refs[8:]
+    if in_place:
+        _, dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, dq_scr, dq_sem, dq_sent = refs
+    else:
+        dq_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
     info, slopes = prefetch[:2]
     b = pl.program_id(0)
     j, g, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    tile = cfg.tiles.bwd
+    tile = bq, _ = cfg.tiles.bwd
     docs = _docs_of(cfg, prefetch, b, blocks)
     # the q-block of this step, and whether there is one: a static
     # window's steps start at the k-block's own diagonal
@@ -619,11 +671,29 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    if (cfg.causal and cfg.window is None) or docs is not None:
-        # every (k-block, q-block) pair has a dq slot of its own, which the
+    if in_place:
+        # a folded row's pairs are a chain of their own (the row axis may be
+        # split between cores): no write in flight when it starts or ends
+        @pl.when((j == 0) & (g == 0) & (step == 0))
+        def _none_sent():
+            dq_sent[0] = 0
+
+        # (a static window's step without a q-block names a tile past the
+        # last, and starts no copy of it)
+        dq_tile = dq_ref.at[b, g, pl.ds(pl.multiple_of(i * bq, bq), bq)]
+        dq_read = pltpu.make_async_copy(dq_tile, dq_scr, dq_sem.at[0])
+        dq_write = pltpu.make_async_copy(dq_scr, dq_tile, dq_sem.at[1])
+
+        def _landed():
+            @pl.when(dq_sent[0] == 1)
+            def _wait():
+                dq_write.wait()
+                dq_sent[0] = 0
+    elif (cfg.causal and cfg.window is None) or docs is not None:
+        # every (k-block, q-block) pair has a dq block of its own, which the
         # caller sums: a skipped pair's is zero. (Under a static window a
         # step without a q-block writes nothing: the block it names holds
-        # the step before's result, and the caller masks the slots no pair
+        # the step before's result, and the caller masks the blocks no pair
         # wrote; a pair that exists there is skipped by its documents alone.)
         skipped = jnp.logical_not(_should_run(cfg, tile, i, j, info, docs))
         if exists is not None:
@@ -647,6 +717,9 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
         # rows whose LSE is the MASK_VALUE sentinel (no unmasked key
         # anywhere) contribute exactly 0
         pt = jnp.where(lse > HALF_MASK, jnp.exp(st - lse), 0.0)
+        if in_place:
+            _landed()
+            dq_read.start()
         dv_scr[...] += lax.dot(pt.astype(do.dtype), do,
                                preferred_element_type=jnp.float32)
         dpt = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
@@ -656,9 +729,14 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
             dst = dst * cfg.scale
         dk_scr[...] += lax.dot(dst.astype(q.dtype), q,
                                preferred_element_type=jnp.float32)
-        dq_ref[0, 0, 0] = lax.dot(dst.T.astype(k.dtype), k,
-                                  preferred_element_type=jnp.float32
-                                  ).astype(dq_ref.dtype)
+        dq = lax.dot(dst.T.astype(k.dtype), k, preferred_element_type=jnp.float32)
+        if in_place:
+            dq_read.wait()
+            dq_scr[:, :dq.shape[1]] += dq
+            dq_write.start()
+            dq_sent[0] = 1
+        else:
+            dq_ref[0, 0, 0] = dq.astype(dq_ref.dtype)
 
     _for_visible_tile(cfg, tile, i, j, info, docs, _compute, also=exists)
 
@@ -667,43 +745,29 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
-
-#: The most bytes the backward's per-k-block dq partials may take in one
-#: launch; a call that would write more runs a few of its folded batch rows
-#: at a time. 32 heads at 16,384 under 1024-wide k-blocks are 4.3 GB whole.
-DQ_PARTIAL_BYTES = 2 ** 30
-
-
-def _rows_a_launch(cfg: FlashConfig, rows: int, bytes_a_row: int) -> int:
-    """How many of the ``rows`` folded (batch x key head) rows one backward
-    launch takes: all of them where their dq partials fit
-    ``DQ_PARTIAL_BYTES``, else the most that do and that either divide the
-    key heads or hold whole batch rows (a launch finds its segment ids by
-    ``row // kv_heads``). ALiBi's slopes go by the global row: never split."""
-    if cfg.use_alibi or rows * bytes_a_row <= DQ_PARTIAL_BYTES:
-        return rows
-    kvH = cfg.kv_heads
-    fits = [r for r in range(1, rows) if rows % r == 0
-            and (r % kvH == 0 or kvH % r == 0)
-            and r * bytes_a_row <= DQ_PARTIAL_BYTES]
-    return max(fits, default=1)
+    if in_place:
+        pl.when((j == blocks[1] - 1) & (g == G - 1) & (step == steps - 1))(_landed)
 
 
 def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
               o, lse, do, dlse):
     """``table``: the backward tiles' :func:`block_ranges` (None: a launch
-    without ids)."""
+    without ids). One launch whatever the shape. Where dq is added to in
+    place (:func:`dq_mode`) it accumulates across the k-block axis, which is
+    then ``"arbitrary"``; the folded-row axis stays ``"parallel"`` (a chip
+    with two cores may split it: a row's dq tiles and its writes in flight
+    are its own)."""
     BK, G, Sq, D = q.shape
     Sk = k.shape[1]
     tile = bq, bk = cfg.tiles.bwd
     blocks = nq, nk = Sq // bq, Sk // bk
     kvH = cfg.kv_heads
     W = cfg.window
-    # the grid's q-steps a k-block, and the dq partials a q-block gets: one
-    # from every k-block, or under a static window one from each it reaches
-    steps, slots = nq, nk
-    if W is not None:
-        slots, steps = window_steps(tile, W, nq, nk)
+    # the grid's q-steps a k-block: every q-block, or under a static window
+    # the most one k-block reaches; and the k-blocks a q-block meets
+    steps = nq if W is None else window_steps(tile, W, nq, nk)[1]
+    met = dq_partials(Sq, Sk, tile, W)
+    in_place = dq_mode(Sq, Sk, cfg.tiles, W) == "in_place"
 
     # di = rowsum(dO * O) (the softmax-jacobian diagonal term); a cotangent
     # on the LSE output folds in here: dL/ds = P*(dP - di) + dlse*P
@@ -743,13 +807,6 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
     def kv_idx(b, j, g, i, *_):
         return (b, j, 0)
 
-    def dq_idx(b, j, g, i, *_):
-        # a pair's own slot, whatever its step fetched
-        if W is None:
-            return (j, b, g, i, 0)
-        i = q_at(i, j)
-        return (j - _first_k_block(tile, i, W), b, g, i, 0)
-
     seg_specs = [None, None]
     if cfg.use_seg:
         seg_specs = [
@@ -760,77 +817,77 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
                 lambda b, j, g, i, *prefetch: (
                     b // kvH, 0, q_blk(b, i, j, prefetch))),
         ]
-    # one partial a q-block: its dq IS the answer, in q's dtype
-    dq_dtype = q.dtype if slots == 1 else jnp.float32
-    written = None
-    if W is not None and slots > 1:
-        # slot s of q-block i holds k-block first(i) + s, where there is one
+    prefetch = (info, slopes) + (() if table is None else (table.reshape(-1),))
+    if in_place:
+        # dq starts at zero and is the launch's own to add to, wherever
+        # its tiles lie (a tile is copied whole lanes at a time: a narrower
+        # head's is padded to them)
+        dq_shape = (BK, G, Sq, -(-D // NUM_LANES) * NUM_LANES)
+        zeros = [jnp.zeros(dq_shape, jnp.float32)]
+        dq_spec = pl.BlockSpec(memory_space=pl.ANY)
+        dq_scratch = [pltpu.VMEM((bq, dq_shape[3]), jnp.float32),
+                      pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]
+        # (the zeros follow the operands a launch has: no ids, no id operands)
+        aliases = {len(prefetch) + (8 if cfg.use_seg else 6): 0}
+    else:
+        # a pair's own block, whatever its step fetched: partial ``j`` of its
+        # q-block, or under a static window ``j`` less the first k-block the
+        # q-block reaches; one partial IS the answer, in q's dtype
+        dq_shape, zeros, dq_scratch, aliases = (met,) + q.shape, [], [], {}
+
+        def dq_idx(b, j, g, i, *_):
+            if W is None:
+                return (j, b, g, i, 0)
+            i = q_at(i, j)
+            return (j - _first_k_block(tile, i, W), b, g, i, 0)
+        dq_spec = pl.BlockSpec((1, 1, 1, bq, D), dq_idx)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, cfg=cfg, G=G, steps=steps,
+                          blocks=blocks, in_place=in_place),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(BK, nk, G, steps),
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, D), q_idx),
+                pl.BlockSpec((1, bk, D), kv_idx),
+                pl.BlockSpec((1, bk, D), kv_idx),
+                *seg_specs,
+                pl.BlockSpec((1, 1, bq, D), q_idx),
+                pl.BlockSpec((1, 1, 1, bq), q_row_idx),
+                pl.BlockSpec((1, 1, 1, bq), q_row_idx),
+                *[dq_spec] * len(zeros),
+            ],
+            out_specs=[
+                dq_spec,
+                pl.BlockSpec((1, bk, D), kv_idx),
+                pl.BlockSpec((1, bk, D), kv_idx),
+            ],
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32), *dq_scratch]),
+        out_shape=[jax.ShapeDtypeStruct(
+                       dq_shape, q.dtype if met == 1 else jnp.float32),
+                   jax.ShapeDtypeStruct((BK, Sk, D), k.dtype),
+                   jax.ShapeDtypeStruct((BK, Sk, D), v.dtype)],
+        input_output_aliases=aliases,
+        compiler_params=_compiler_params(
+            cfg, ("parallel", "arbitrary" if in_place else "parallel",
+                  "arbitrary", "arbitrary")),
+        interpret=cfg.interpret,
+        name=("flash_bwd_eva_local" if cfg.tag == "eva_local"
+              else "flash_bwd_eva_far" if cfg.tag == "eva_far"
+              else "flash_bwd_blockdiff" if cfg.blockdiff is not None
+              else "flash_bwd" if W is None else "flash_bwd_window"),
+    )(*prefetch, q, k, v, kseg_c, qseg_r, do, lse, di, *zeros)
+    if in_place:
+        return dq[..., :D].astype(q.dtype), dk, dv
+    if W is not None and met > 1:
+        # partial s of q-block i holds k-block first(i) + s, where there is
+        # one: a block no pair wrote holds whatever was there
         reach = np.repeat([_last_k_block(tile, i) - _first_k_block(tile, i, W)
                            for i in range(nq)], bq)
-        written = jnp.asarray(np.arange(slots)[:, None] <= reach[None, :]
-                              )[:, None, None, :, None]
-
-    def launch(q, k, v, kseg_c, qseg_r, table, do, lse, di):
-        rows = q.shape[0]
-        prefetch = (info, slopes) + (() if table is None else (table.reshape(-1),))
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_kernel, cfg=cfg, G=G, steps=steps,
-                              blocks=blocks),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=len(prefetch),
-                grid=(rows, nk, G, steps),
-                in_specs=[
-                    pl.BlockSpec((1, 1, bq, D), q_idx),
-                    pl.BlockSpec((1, bk, D), kv_idx),
-                    pl.BlockSpec((1, bk, D), kv_idx),
-                    *seg_specs,
-                    pl.BlockSpec((1, 1, bq, D), q_idx),
-                    pl.BlockSpec((1, 1, 1, bq), q_row_idx),
-                    pl.BlockSpec((1, 1, 1, bq), q_row_idx),
-                ],
-                out_specs=[
-                    pl.BlockSpec((1, 1, 1, bq, D), dq_idx),
-                    pl.BlockSpec((1, bk, D), kv_idx),
-                    pl.BlockSpec((1, bk, D), kv_idx),
-                ],
-                scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                                pltpu.VMEM((bk, D), jnp.float32)]),
-            out_shape=[jax.ShapeDtypeStruct((slots, rows, G, Sq, D), dq_dtype),
-                       jax.ShapeDtypeStruct((rows, Sk, D), k.dtype),
-                       jax.ShapeDtypeStruct((rows, Sk, D), v.dtype)],
-            compiler_params=_compiler_params(
-                cfg, ("parallel", "parallel", "arbitrary", "arbitrary")),
-            interpret=cfg.interpret,
-            name=("flash_bwd_eva_local" if cfg.tag == "eva_local"
-                  else "flash_bwd_eva_far" if cfg.tag == "eva_far"
-                  else "flash_bwd_blockdiff" if cfg.blockdiff is not None
-                  else "flash_bwd" if W is None else "flash_bwd_window"),
-        )(*prefetch, q, k, v, kseg_c, qseg_r, do, lse, di)
-        if slots == 1:
-            return dq[0], dk, dv
-        if written is not None:
-            # a slot no (k-block, q-block) pair wrote holds whatever was there
-            dq = jnp.where(written, dq, 0.0)
-        return jnp.sum(dq, axis=0).astype(q.dtype), dk, dv
-
-    each = _rows_a_launch(cfg, BK, slots * G * Sq * D * jnp.dtype(dq_dtype).itemsize)
-    if each == BK:
-        return launch(q, k, v, kseg_c, qseg_r, table, do, lse, di)
-    n = BK // each
-    split = lambda a: a.reshape((n, each) + a.shape[1:])
-    # the ids and their table go by batch row (a launch finds its own by
-    # ``row // kv_heads``)
-    if not cfg.use_seg:
-        seg = lambda ids: None
-    elif each % kvH == 0:        # whole batch rows a launch
-        seg = lambda ids: ids.reshape((n, each // kvH) + ids.shape[1:])
-    else:                        # every launch inside one batch row
-        seg = lambda ids: jnp.repeat(ids, kvH // each, axis=0)[:, None]
-    dq, dk, dv = lax.map(lambda xs: launch(*xs), (
-        split(q), split(k), split(v), seg(kseg_c), seg(qseg_r), seg(table),
-        split(do), split(lse), split(di)))
-    join = lambda a: a.reshape((BK,) + a.shape[2:])
-    return join(dq), join(dk), join(dv)
+        dq = jnp.where(jnp.asarray(np.arange(met)[:, None] <= reach[None, :]
+                                   )[:, None, None, :, None], dq, 0.0)
+    return (dq[0] if met == 1 else jnp.sum(dq, axis=0).astype(q.dtype)), dk, dv
 
 
 # ---------------------------------------------------------------------------

@@ -2403,13 +2403,15 @@ class DeepSpeedEngine:
                   "window": windows[0] if len(windows) == 1 else (windows or None),
                   "kv_heads": cfg.kv_heads,
                   "documents": cfg.document_separator is not None,
-                  "route": {"window": None, "full": None}}
+                  "route": {"window": None, "full": None},
+                  "dq": {"window": None, "full": None}}
         if getattr(cfg, "attention", None) == "eva":
             # EVA's counts (docs/OBSERVABILITY.md): the summaries of a row
             # and the route are a traced step's (`_count_attention`)
             totals["eva"] = {"window": cfg.eva_window, "chunk": cfg.eva_chunk,
                              "summaries_a_row": None,
-                             "pred_heads": cfg.pred_heads, "route": None}
+                             "pred_heads": cfg.pred_heads, "route": None,
+                             "dq_local": None, "dq_far": None}
         return totals
 
     def _diffusion_of_model(self) -> Optional[Dict[str, Any]]:
@@ -2418,37 +2420,71 @@ class DeepSpeedEngine:
             return None
         return {"block_length": cfg.block_length,
                 "rows_per_token": self.model.rows_per_token,
-                "steps": 0, "route": None}
+                "steps": 0, "route": None, "dq": None}
 
     def _count_attention(self, batch) -> None:
         """``attn_totals['route']`` (``diffusion_totals['route']`` under the
-        block-diffusion mask): host arithmetic from static shapes while the
-        step is traced."""
+        block-diffusion mask) and, beside each, how the kernel's backward
+        makes dq (``pallas_flash.dq_mode`` over the tiles the launch will
+        choose: ``one_block`` / ``summed`` / ``in_place``; None off the
+        kernel): host arithmetic from static shapes while the step is
+        traced."""
         if not self.attn_totals or "input_ids" not in batch:
             return
-        from ..ops.transformer import attention
+        from ..ops.transformer import attention, pallas_flash
         cfg = self.model.config
         b, s = batch["input_ids"].shape[:2]
+        backend = jax.default_backend()
+        choose = dict(head_dim=cfg.head_dim, compiled=backend != "cpu",
+                      itemsize=jnp.dtype(self.param_dtype).itemsize)
+
+        def dq_of(route, sq, sk, tiles, window=None):
+            if route != "kernel" or tiles is None:
+                return None
+            return pallas_flash.dq_mode(sq, sk, tiles, window)
+
         if self.diffusion_totals is not None:
-            self.diffusion_totals["route"] = attention.choose_route(
+            route = attention.choose_route(
                 (b, 2 * s, cfg.num_heads, cfg.head_dim),
-                (b, s, cfg.kv_heads, cfg.head_dim), jax.default_backend(),
+                (b, s, cfg.kv_heads, cfg.head_dim), backend,
                 attention.attn_mode(), cfg.block_length)
+            self.diffusion_totals.update(route=route, dq=dq_of(
+                route, 2 * s, s, pallas_flash.blockdiff_tiles(
+                    s, block_length=cfg.block_length, **choose)))
             return
         if "eva" in self.attn_totals:
             shape = (b, s, cfg.num_heads, cfg.head_dim)
+            route = attention.choose_route(
+                shape, shape, backend, attention.attn_mode(),
+                eva=(cfg.eva_window, cfg.eva_chunk))
+            # the launches `attention.eva_attention` makes: the row's windows
+            # folded to batch rows, and (a row of several) its summaries
+            windows = -(-s // cfg.eva_window)
+            each, per = s // windows, cfg.eva_window // cfg.eva_chunk
             self.attn_totals["eva"].update(
-                summaries_a_row=s // cfg.eva_chunk,
-                route=attention.choose_route(
-                    shape, shape, jax.default_backend(), attention.attn_mode(),
-                    eva=(cfg.eva_window, cfg.eva_chunk)))
+                summaries_a_row=s // cfg.eva_chunk, route=route,
+                dq_local=dq_of(route, each, each, pallas_flash.choose_tiles(
+                    each, each, **choose)),
+                dq_far=None if windows == 1 else dq_of(
+                    route, s, s // cfg.eva_chunk, pallas_flash.summary_tiles(
+                        s, cfg.eva_window, per, **choose)))
             return
         route = attention.choose_route(
             (b, s, cfg.num_heads, cfg.head_dim), (b, s, cfg.kv_heads, cfg.head_dim),
-            jax.default_backend(), attention.attn_mode())
+            backend, attention.attn_mode())
+        layers = {kind: self.attn_totals[f"layers_{kind}"] for kind in ("window", "full")}
         self.attn_totals["route"] = {
-            kind: route if self.attn_totals[f"layers_{kind}"] else None
-            for kind in ("window", "full")}
+            kind: route if layers[kind] else None for kind in layers}
+
+        def dq_under(window):
+            cut = pallas_flash.static_window(window or None, s, s)
+            return dq_of(route, s, s, pallas_flash.choose_tiles(
+                s, s, window=cut, **choose), cut)
+        # (sliding layers of several widths: the mode they share, else both)
+        under = sorted({dq_under(w) or "" for w, _ in self.model.layer_kinds if w})
+        self.attn_totals["dq"] = {
+            "window": ("+".join(under) or None) if layers["window"] else None,
+            "full": dq_under(None) if layers["full"] else None}
 
     def _count_grouped_products(self, batch, expert_layers: int) -> None:
         """``moe_totals``' route and products a step, by kind, of the no-drop

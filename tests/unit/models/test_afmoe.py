@@ -93,7 +93,8 @@ def test_first_step_through_initialize(parts):
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}}})
     assert engine.attn_totals == {"layers_window": 5, "layers_full": 1, "window": 16,
                                   "kv_heads": 2, "documents": True,
-                                  "route": {"window": None, "full": None}}
+                                  "route": {"window": None, "full": None},
+                                  "dq": {"window": None, "full": None}}
     assert engine.attn_last_step() is None
     loss = float(engine.train_batch({"input_ids": np.asarray(ids)}))
     want, gnorm, signs = ref.loss_and_gradient(w, ids, cfg)
@@ -117,6 +118,7 @@ def test_first_step_through_initialize(parts):
     assert rows.shape == (4, 8)
     np.testing.assert_array_equal(rows, load[:, :8].astype(np.int32))
     assert engine.attn_totals["route"] == {"window": "xla", "full": "xla"}
+    assert engine.attn_totals["dq"] == {"window": None, "full": None}   # no kernel, no mode
     assert {k: engine.moe_totals[k] for k in ("path", "experts_published", "experts_held")} \
         == {"path": "dropless", "experts_published": 16, "experts_held": 8}
 

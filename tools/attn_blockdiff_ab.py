@@ -19,12 +19,19 @@ Forward, and forward + backward, median of ``--iters`` timed calls on each
 of ``--rows`` successive rows of the traffic, averaged over the rows; beside
 them the tiles the position test alone runs and the tiles run, a head
 (``pallas_flash.tiles_run``; left out where the package has no such
-function). One JSON line a row, also in ``chiprun_out/attn_blockdiff_ab.jsonl``.
+function). Before the timings, the smallest grids on which a dq that is added
+to where it lies could race (``pallas_flash.dq_mode`` ``in_place``, five
+k-blocks or more: a q-block's tile is read again one grid step after it was
+written where there is ONE q-block and one head a key head) are compared with
+``_xla_attention`` in float32, gradient by gradient: interpret mode copies in
+order and cannot show a race, the chip can. One JSON line a row, also in
+``chiprun_out/attn_blockdiff_ab.jsonl``.
 """
 
 import argparse
 import json
 import os
+import re
 import statistics
 import sys
 import time
@@ -39,6 +46,7 @@ def main() -> int:
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--rows", type=int, default=8)
     ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--cases", default="", help="a regular expression: the timed calls to run")
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
@@ -103,7 +111,38 @@ def main() -> int:
         "window_16k / one document": (window, (q2, k2, v2), one(docs_2l), causal_tiles(2048)),
     }
     out = []
+    # (q rows, keys, query heads, key heads) -> (q-blocks, k-blocks, G) of
+    # the backward's 1024 x 1024 tiles
+    for sq, sk, heads, kv_heads in ((1024, 5120, 2, 2), (2048, 6144, 2, 2),
+                                    (1024, 5120, 8, 2)):
+        shape = lambda rows, h: (2, rows, h, D)
+        q, k, v = (jax.random.normal(jax.random.fold_in(key, 10 + n), shape(rows, h),
+                                     jnp.bfloat16)
+                   for n, (rows, h) in enumerate(((sq, heads), (sk, kv_heads), (sk, kv_heads))))
+        ids = jnp.asarray(np.sort(np.random.default_rng(args.seed).integers(
+            0, 3, (2, sk)), axis=1), jnp.int32)
+        q_ids = ids[:, sk - sq:]
+        grads = lambda attend, *x: jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32) ** 2), argnums=(0, 1, 2)))(*x)
+        got = grads(lambda q, k, v: flash_attention_kernel(
+            q, k, v, causal=True, segment_ids=ids, q_segment_ids=q_ids), q, k, v)
+        want = grads(lambda q, k, v: attention._xla_attention(
+            q, k, v, True, None, ids, q_segment_ids=q_ids),
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+        tiles = pallas_flash.choose_tiles(sq, sk, D, causal=True)
+        row = {"case": f"grid {sq // tiles.bwd[0]} x {sk // tiles.bwd[1]} x {heads // kv_heads}",
+               "dq_mode": getattr(pallas_flash, "dq_mode", lambda *a: None)(sq, sk, tiles),
+               "device": jax.devices()[0].device_kind}
+        for name, ours, theirs in zip(("dq", "dk", "dv"), got, want):
+            # the kernel's operands are bfloat16: its gradient is rounded once
+            row[name + "_error"] = float(jnp.max(jnp.abs(ours.astype(jnp.float32) - theirs))
+                                         / jnp.max(jnp.abs(theirs)))
+        row["matches"] = all(row[n + "_error"] < 2e-2 for n in ("dq", "dk", "dv"))
+        print(json.dumps(row), flush=True)
+        out.append(row)
     for name, (fn, operands, rows, tiles_of) in cases.items():
+        if not re.search(args.cases, name):
+            continue
         fwd = jax.jit(fn)
         both = jax.jit(jax.grad(lambda q, k, v, doc: jnp.sum(fn(q, k, v, doc).astype(
             jnp.float32)), argnums=(0, 1, 2)))
@@ -134,7 +173,7 @@ def main() -> int:
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/attn_blockdiff_ab.jsonl", "w") as f:
         f.writelines(json.dumps(r) + "\n" for r in out)
-    return 0
+    return 0 if all(r.get("matches", True) for r in out) else 1
 
 
 if __name__ == "__main__":

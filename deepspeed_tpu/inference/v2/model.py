@@ -41,6 +41,11 @@ Params = Dict[str, Any]
 
 class RaggedInferenceModel:
 
+    #: what `_layer_loop` (a plain pre-norm block of its own over paged keys
+    #: and values, one token a sequence a step) and `_embed` compute before
+    #: ``TransformerLM.head``; the experts run without drops (``_moe_serve``)
+    RUNS = frozenset({"attn_windows", "moe"})
+
     def __init__(self, model: TransformerLM, block_size: int, max_blocks_per_seq: int,
                  use_pallas: bool = None, ragged_block_q: int = 8,
                  replicate_kv_writes: bool = False):
@@ -61,11 +66,7 @@ class RaggedInferenceModel:
         # atom tile of the unified wave program (wave_forward)
         self.ragged_block_q = ragged_block_q
         c = self.config
-        if not c.causal:
-            raise ValueError(
-                "the ragged serving engine generates autoregressively; "
-                "bidirectional encoders (bert/roberta) have no decode "
-                "semantics — use the model's apply() for MLM scoring")
+        model.require("the ragged serving engine (RaggedInferenceModel)", self.RUNS)
         # per-layer sliding windows (mistral / gpt-neo): a [L] vector read
         # inside the layer loop; forces the XLA paged path (the stock Pallas
         # kernel takes no window mask)
@@ -81,28 +82,6 @@ class RaggedInferenceModel:
         # program (forces the XLA path; the stock Pallas kernel has no bias)
         self._alibi = (jnp.asarray(model._alibi_slopes)
                        if model._alibi_slopes is not None else None)
-        if c.diffusion:
-            raise NotImplementedError(
-                "serving objective='block_diffusion' is not supported: a step "
-                "that yields a block of tokens by several denoising passes is "
-                "none of the ragged engine's programs (one token a sequence a "
-                "step); the model trains through deepspeed_tpu.initialize")
-        if c.attention == "eva" or c.pred_heads > 1:
-            raise NotImplementedError(
-                "serving attention='eva' is not supported: its cache is exact "
-                "pages for the open window plus one summary a closed chunk, "
-                "which the ragged engine's key-value pages do not hold, and "
-                f"drafting with pred_heads ({c.pred_heads}) needs a step of "
-                "more than one token; the model trains through "
-                "deepspeed_tpu.initialize")
-        if c.qk_norm or (c.moe is not None and c.moe.capacity_factor is None):
-            raise NotImplementedError(
-                "serving OLMoE is not supported yet: the ragged engine's "
-                "programs apply no QK-norm and route through the capacity "
-                "path (renormalised weights), so they would compute another "
-                f"model (qk_norm={c.qk_norm}, moe="
-                f"{None if c.moe is None else c.moe}); it trains through "
-                "deepspeed_tpu.initialize")
         # MoE serving routes DROPLESS: capacity_factor = num_experts makes
         # capacity == token count, so no token is ever dropped — the
         # training path's capacity cropping is a throughput/regularization
@@ -130,18 +109,6 @@ class RaggedInferenceModel:
         if m._ln_emb is not None:
             x = m._ln_emb(params["ln_emb"], x)
         return x.astype(self.config.dtype)
-
-    @scoped("head")
-    def _unembed(self, params: Params, x: jax.Array) -> jax.Array:
-        """x [N, hidden] -> logits [N, vocab] fp32 (reference
-        ``_forward_unembed``, gather_for_logits)."""
-        m = self.model
-        x = m._ln_f(params["ln_f"], x)
-        if self.config.tie_embeddings:
-            logits = m._wte.attend(params["wte"], x)
-        else:
-            logits = m._lm_head(params["lm_head"], x)
-        return logits.astype(jnp.float32)
 
     def _qkv(self, block: Params, h: jax.Array, positions: jax.Array):
         """PRE-NORMED h [N, hidden] -> q [N, H, D], k/v [N, kvH, D] with rope
@@ -266,7 +233,7 @@ class RaggedInferenceModel:
         x, k_pages, v_pages = self._layer_loop(
             params, k_pages, v_pages, x, attn, write_idx, positions)
         sel = x[jnp.clip(last_rows, 0, x.shape[0] - 1)]
-        logits = self._unembed(params, sel)
+        logits = self.model.head(params, sel)
         return logits, k_pages, v_pages
 
     def ragged_forward(self, params: Params, k_pages, v_pages,
@@ -334,7 +301,7 @@ class RaggedInferenceModel:
         if Sp:
             last = jnp.clip(p_valid - 1, 0, T - 1)
             rows.append(x[Bd:].reshape(Sp, T, -1)[jnp.arange(Sp), last])
-        logits = self._unembed(params, jnp.concatenate(rows) if Sp else rows[0])
+        logits = self.model.head(params, jnp.concatenate(rows) if Sp else rows[0])
         return logits, k_pages, v_pages
 
     def prefill_chunk(self, params: Params, k_pages, v_pages, tokens, positions,
@@ -368,7 +335,7 @@ class RaggedInferenceModel:
         x, k_pages, v_pages = self._layer_loop(
             params, k_pages, v_pages, x, attn, write_idx, positions)
         last = jnp.clip(n_valid - 1, 0, T - 1)
-        logits = self._unembed(params, x[last][None, :])[0]
+        logits = self.model.head(params, x[last][None, :])[0]
         return logits, k_pages, v_pages
 
     def decode_burst(self, params: Params, k_pages, v_pages, tokens, positions,
@@ -408,7 +375,7 @@ class RaggedInferenceModel:
 
             x, k_pages, v_pages = self._layer_loop(
                 params, k_pages, v_pages, x, attn, write_idx, positions)
-            logits = self._unembed(params, x)              # [B, V]
+            logits = self.model.head(params, x)              # [B, V]
             with jax.named_scope("sample"):
                 rng, sub = jax.random.split(rng)
                 greedy = jnp.argmax(logits, axis=-1)
@@ -445,5 +412,5 @@ class RaggedInferenceModel:
 
         x, k_pages, v_pages = self._layer_loop(
             params, k_pages, v_pages, x, attn, write_idx, positions)
-        logits = self._unembed(params, x)
+        logits = self.model.head(params, x)
         return logits, k_pages, v_pages

@@ -33,6 +33,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
+from .registry import register_architecture
 from .transformer import MoEConfig, TransformerConfig, TransformerLM
 
 KINDS = ("sliding_attention", "full_attention")
@@ -118,6 +119,9 @@ def checkpoint_params(cfg, state_dict):
         "loading an afmoe / Trinity checkpoint is not written; build the "
         "model from its configuration (afmoe_model) and hand initialize() "
         "its parameters")
+
+
+register_architecture("afmoe", config_kwargs, checkpoint_params)
 
 
 def afmoe_config(preset: str = "trinity-mini", dtype=jnp.bfloat16,

@@ -29,6 +29,7 @@ from typing import Any, Dict
 
 import jax.numpy as jnp
 
+from .registry import register_architecture
 from .transformer import TransformerConfig, TransformerLM
 
 #: EvaByte/EvaByte config.json, and a toy of the same block
@@ -88,6 +89,9 @@ def checkpoint_params(cfg, state_dict):
         "loading an evabyte checkpoint is not written; build the model from "
         "its configuration (evabyte_model) and hand initialize() its "
         "parameters")
+
+
+register_architecture("evabyte", config_kwargs, checkpoint_params)
 
 
 def evabyte_config(preset: str = "evabyte-6.5b", dtype=jnp.bfloat16,

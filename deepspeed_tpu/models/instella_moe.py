@@ -31,6 +31,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
+from .registry import register_architecture
 from .transformer import MoEConfig, TransformerConfig, TransformerLM, YarnScaling
 
 #: amd/Instella-MoE-16B-A3B-Base config.json, and a toy of the same block
@@ -131,6 +132,9 @@ def checkpoint_params(cfg, state_dict):
         "loading a deepseek_v3 / Instella-MoE checkpoint is not written; "
         "build the model from its configuration (instella_moe_model) and "
         "hand initialize() its parameters")
+
+
+register_architecture("deepseek_v3", config_kwargs, checkpoint_params)
 
 
 def instella_moe_config(preset: str = "instella-moe-16b-a3b", dtype=jnp.bfloat16,

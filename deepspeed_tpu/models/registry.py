@@ -10,10 +10,12 @@ their registration (``inference/v2/model_implementations/*`` registered via
 - ``config_fn(hf_config_dict) -> kwargs for TransformerConfig``
 - ``params_fn(cfg, state_dict) -> TransformerLM param pytree``
 
-``runtime/state_dict_factory.py`` registers the built-in sixteen
-(gpt2/llama/mistral/mixtral/internlm/qwen2/opt/phi/falcon/bloom/gpt_neo/
-gpt_neox/gptj and the bert/roberta/distilbert encoders) at import; user code can register
-additional families without touching the loader.
+An architecture registers where it is defined: a ``models/<arch>.py`` with
+``config_kwargs`` and ``checkpoint_params`` calls `register_architecture`
+itself (the package imports it), ``runtime/state_dict_factory.py`` registers
+the families whose adapters it holds (gpt2, the llama family, opt, phi, falcon,
+bloom, gpt_neo, gpt_neox, gptj, the bert encoders) at import, and user code
+can register more without touching the loader.
 """
 
 from __future__ import annotations

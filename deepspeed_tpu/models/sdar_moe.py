@@ -31,6 +31,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
+from .registry import register_architecture
 from .transformer import MoEConfig, TransformerConfig, TransformerLM
 
 #: JetLM/SDAR-30B-A3B-Chat config.json, and a toy of the same block
@@ -93,6 +94,9 @@ def checkpoint_params(cfg, state_dict):
         "loading an sdar_moe checkpoint is not written; build the model from "
         "its configuration (sdar_moe_model) and hand initialize() its "
         "parameters")
+
+
+register_architecture("sdar_moe", config_kwargs, checkpoint_params)
 
 
 def sdar_moe_config(preset: str = "sdar-30b-a3b", dtype=jnp.bfloat16,

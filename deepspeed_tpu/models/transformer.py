@@ -467,6 +467,63 @@ class TransformerConfig:
         return embed + head + L * (attn + mlp)
 
 
+#: What a configuration can ask beyond the plain causal pre-norm decoder (one
+#: stream through blocks of one kind, ``mha`` over whole rows, dense MLPs, one
+#: next-token head), under its own spelling: whether a model uses it, and
+#: what running it takes. A consumer that computes less than
+#: ``TransformerLM.loss`` (`block_apply`, ``PipelineModule``,
+#: ``RaggedInferenceModel``) lists what it runs and `TransformerLM.require`
+#: refuses the rest: what is added here is refused until a consumer lists it.
+MECHANISMS: Dict[str, Tuple[Callable[["TransformerLM"], bool], str]] = {
+    "causal=False": (lambda m: not m.config.causal,
+                     "a bidirectional encoder, not a decoder: it attends both ways under a mask"),
+    "norm_style='post'": (lambda m: m.config.norm_style == "post",
+                          "a block norms after each residual add, and there is no final norm"),
+    "norm_style='sandwich'": (lambda m: m.config.norm_style == "sandwich",
+                              "a branch's output has a norm of its own before it is added"),
+    "mlm_head": (lambda m: m.config.mlm_head,
+                 "the masked-LM head transforms and norms the stream before the tied decoder"),
+    "type_vocab_size": (lambda m: bool(m.config.type_vocab_size),
+                        "segment embeddings are added from the batch's token_type_ids"),
+    "pad_based_positions": (lambda m: m.config.pad_based_positions,
+                            "positions count the tokens that are not padding"),
+    "attn_windows": (lambda m: m._windows is not None,
+                     "each layer attends under its own window"),
+    "rope_layers='windowed'": (
+        lambda m: m.config.position == "rope" and not all(rope for _, rope in m._kinds),
+        "some layers turn queries and keys, others do not: a block must know its layer's kind"),
+    "document_separator": (lambda m: m.config.document_separator is not None,
+                           "attention stays inside a packed document, found from the whole row"),
+    "embedding_scale": (lambda m: m.config.embedding_scale is not None,
+                        "the embedding's output is multiplied before the first block"),
+    "residual_fp32": (lambda m: m.config.residual_fp32,
+                      "a branch is added to the stream in float32"),
+    "qk_norm": (lambda m: m.config.qk_norm, "queries and keys are normed before rope"),
+    "attn_gate": (lambda m: m.config.attn_gate,
+                  "the attention output is gated from the sub-block's input"),
+    "attention='latent'": (lambda m: m.config.attention == "latent",
+                           "keys and values come from one compressed vector a token"),
+    "attention='eva'": (lambda m: m.config.attention == "eva",
+                        "a query sees its window's keys and a learned summary a chunk before it"),
+    "pred_heads": (lambda m: m.config.pred_heads > 1,
+                   "the head's outputs are several next-token heads under a loss of their own"),
+    "farskip": (lambda m: m.config.farskip,
+                "a block carries two streams: the residual now and a sub-block earlier"),
+    "first_dense_layers": (lambda m: bool(m.config.first_dense_layers),
+                           "the leading layers are dense blocks of their own, run before the scan"),
+    "mtp_layers": (lambda m: bool(m.config.mtp_layers),
+                   "a prediction module after the last block has a block and a loss of its own"),
+    "objective='block_diffusion'": (lambda m: m.config.diffusion,
+                                    "a clean and a noised copy of every row run under one mask"),
+    "moe": (lambda m: m.config.moe is not None,
+            "the MLP is a layer of experts with an auxiliary loss"),
+    "moe.capacity_factor=None": (lambda m: m.moe_path == "dropless",
+                                 "the no-drop path carries two router losses and rows per expert"),
+    "moe.bias_update": (lambda m: m.config.moe is not None and bool(m.config.moe.bias_update),
+                        "the router's bias moves by the step's load, which loss_and_stats returns"),
+}
+
+
 class TransformerLM:
 
     #: top-level param keys :meth:`embed` reads — the overlap planner's
@@ -666,11 +723,6 @@ class TransformerLM:
             raise NotImplementedError(
                 f"value heads of {c.v_head_dim} beside query heads of "
                 f"{c.head_dim}: the attention routes take one head size")
-
-    @property
-    def layer_kinds(self) -> Tuple[Tuple[int, bool], ...]:
-        """Each layer's static (window, 0 = global; whether rope turns it)."""
-        return self._kinds
 
     @property
     def _mixed_rope(self) -> bool:
@@ -1279,6 +1331,32 @@ class TransformerLM:
             logits = logits.reshape(logits.shape[:-1] + (c.pred_heads, c.vocab_size))
         return logits.astype(jnp.float32)
 
+    @functools.cached_property
+    def mechanisms(self) -> Tuple[str, ...]:
+        """The names of `MECHANISMS` this model's configuration uses."""
+        return tuple(name for name, (uses, _) in MECHANISMS.items() if uses(self))
+
+    def require(self, consumer: str, runs) -> None:
+        """Refuse, by name and reason, every mechanism this model uses that
+        ``consumer`` (its name, for the message) does not list in ``runs``."""
+        unknown = set(runs) - set(MECHANISMS)
+        if unknown:
+            raise KeyError(f"{consumer} lists {sorted(unknown)}, which are no MECHANISMS")
+        unmet = [name for name in self.mechanisms if name not in runs]
+        if unmet:
+            raise NotImplementedError(
+                f"{consumer} does not run " + "; ".join(
+                    f"{name} ({MECHANISMS[name][1]})" for name in unmet)
+                + ": TransformerLM.loss (initialize -> train_batch) runs the whole model")
+
+    #: what one block of `_block_fn` without its layer's kind carries, and
+    #: what `embed` and `head` (which its callers run around it) do
+    _BLOCK_APPLY_RUNS = frozenset({
+        "causal=False", "norm_style='post'", "norm_style='sandwich'", "mlm_head",
+        "type_vocab_size", "pad_based_positions", "attn_windows",
+        "embedding_scale", "residual_fp32", "qk_norm", "attn_gate",
+        "attention='latent'", "moe", "moe.capacity_factor=None"})
+
     def block_apply(self, block: Params, x: jax.Array, positions: jax.Array,
                     keep=1.0, attn_mask: Optional[jax.Array] = None,
                     window: Optional[jax.Array] = None
@@ -1287,22 +1365,8 @@ class TransformerLM:
         param-streaming trainer's unit of compute (reference fetches one
         module's partitions at a time, partitioned_param_coordinator.py:280).
         Returns (x', moe_aux)."""
-        c = self.config
-        if c.diffusion:
-            raise NotImplementedError(
-                "objective='block_diffusion' runs a clean and a noised copy "
-                "of every row under one mask, which one block at a time "
-                "(parameter streaming, the ZeRO-3 pipelined scan) does not "
-                "carry: it takes the whole-model scan of TransformerLM.loss")
-        if (c.farskip or c.first_dense_layers or c.mtp_layers or self._mixed_rope
-                or c.document_separator is not None or c.attention == "eva"):
-            raise NotImplementedError(
-                "one block at a time (parameter streaming, the ZeRO-3 "
-                "pipelined scan) is written for one stream through blocks "
-                "of one kind: farskip, first_dense_layers, mtp_layers, layers "
-                "with and without a rotary position (rope_layers='windowed'), "
-                "packed documents (document_separator) and attention='eva' "
-                "take the whole-model scan of TransformerLM.apply")
+        self.require("one block at a time (parameter streaming, the ZeRO-3 "
+                     "pipelined scan)", self._BLOCK_APPLY_RUNS)
         carry = (x, positions, self._aux_zero())
         keep = jnp.asarray(keep, self.config.dtype)
         packed = (block, keep) if window is None else (block, keep, window)
@@ -1769,29 +1833,137 @@ class TransformerLM:
         tiles the position test alone runs, the tiles run)]``: how far the
         row's documents cut one flash launch's tiles a head, for each of
         `attn_tile_kinds`, by the kernels' own table and predicate
-        (``pallas_flash.tiles_run``) at the tiles the kernel route takes for
-        the shape. Nothing where the shape has no legal tile."""
+        (``pallas_flash.tiles_run``) at the launch the kernel route's plan
+        lists for the shape. Nothing where the kernel cannot run it."""
         from ..ops.transformer import pallas_flash as pf
         c = self.config
-        L = documents.shape[1]
-        shape = dict(head_dim=c.head_dim, itemsize=jnp.dtype(c.dtype).itemsize,
-                     compiled=jax.default_backend() != "cpu")
         lines = []
         for _, window in self.attn_tile_kinds:
+            # (``"pallas"``: the plan wherever the kernel CAN run)
+            plan = self._attention_plan(*documents.shape, window, mode="pallas")
+            if plan.route != "kernel":
+                return {}
+            (at,) = plan.launches
             if c.diffusion:
-                tiles = pf.blockdiff_tiles(L, block_length=c.block_length, **shape)
                 q_ids = jnp.concatenate([documents, documents], axis=1)
                 mask = dict(blockdiff=c.block_length)
             else:
-                window = pf.static_window(window, L, L)
-                tiles = pf.choose_tiles(L, L, causal=c.causal, window=window, **shape)
-                q_ids, mask = documents, dict(causal=c.causal, window=window)
-            if tiles is None:
-                return {}
+                q_ids, mask = documents, dict(causal=c.causal, window=at.window)
             lines.append(jnp.stack([
                 jnp.stack(pf.tiles_run(q_ids, documents, tile, **mask))
-                for tile in (tiles.fwd, tiles.bwd)]))
+                for tile in (at.tiles.fwd, at.tiles.bwd)]))
         return {"attn_tiles": jnp.stack(lines)}
+
+    def _attention_plan(self, batch: int, seq: int, window: int = 0,
+                        mode: Optional[str] = None):
+        """The ``attention.Plan`` of one layer's call over ``batch`` whole rows
+        of ``seq`` tokens here, by the entry point the configuration takes
+        (``window``: the layer's static one; ``mode``: `attn_mode`'s, or given)."""
+        from ..ops.transformer import attention
+        c = self.config
+        mask = dict(causal=c.causal, window=window or None)
+        if c.diffusion:
+            mask = dict(blockdiff=c.block_length)
+        elif c.attention == "eva":
+            mask = dict(eva=(c.eva_window, c.eva_chunk))
+        return attention.plan(
+            (batch, seq * self.rows_per_token, c.num_heads, c.head_dim),
+            (batch, seq, c.kv_heads, c.head_dim), jax.default_backend(),
+            attention.attn_mode() if mode is None else mode,
+            jnp.dtype(c.dtype).itemsize, **mask)
+
+    def attention_records(self, batch: Optional[int] = None, seq: Optional[int] = None
+                          ) -> Tuple[Dict[str, Any], Optional[Dict[str, Any]]]:
+        """``(attn, diffusion)``: what an engine keeps as ``attn_totals`` and
+        ``diffusion_totals`` (docs/OBSERVABILITY.md has every key; None for
+        another objective). Without a shape what the configuration says; with
+        the rows and tokens a step is traced for, also each kind's route and
+        how its backward makes dq, off the launches' own plans."""
+        c = self.config
+        windows = sorted({w for w, _ in self._kinds if w})
+        layers = {"window": sum(1 for w, _ in self._kinds if w),
+                  "full": sum(1 for w, _ in self._kinds if not w)}
+        attn = {"layers_window": layers["window"], "layers_full": layers["full"],
+                "window": windows[0] if len(windows) == 1 else (windows or None),
+                "kv_heads": c.kv_heads,
+                "documents": c.document_separator is not None,
+                "route": {"window": None, "full": None},
+                "dq": {"window": None, "full": None}}
+        if c.attention == "eva":
+            attn["eva"] = {"window": c.eva_window, "chunk": c.eva_chunk,
+                           "summaries_a_row": None, "pred_heads": c.pred_heads,
+                           "route": None, "dq_local": None, "dq_far": None}
+        diffusion = {"block_length": c.block_length, "rows_per_token": self.rows_per_token,
+                     "route": None, "dq": None} if c.diffusion else None
+        if seq is None:
+            return attn, diffusion
+        plans = {w: self._attention_plan(batch, seq, w) for w in [0] + windows}
+        if c.diffusion:
+            diffusion.update(route=plans[0].route, dq=plans[0].dq("blockdiff"))
+        elif c.attention == "eva":
+            attn["eva"].update(
+                summaries_a_row=seq // c.eva_chunk, route=plans[0].route,
+                dq_local=plans[0].dq("eva_local"), dq_far=plans[0].dq("eva_far"))
+        else:
+            # (sliding layers of several widths: the mode they share, else both)
+            under = sorted({plans[w].dq("flash") or "" for w in windows})
+            attn["route"] = {kind: plans[0].route if n else None
+                             for kind, n in layers.items()}
+            attn["dq"] = {
+                "window": ("+".join(under) or None) if layers["window"] else None,
+                "full": plans[0].dq("flash") if layers["full"] else None}
+        return attn, diffusion
+
+    def expert_records(self, batch: Optional[int] = None, seq: Optional[int] = None,
+                       *, expert_layers: int = 0, dtype=None, devices: int = 1,
+                       kept: Tuple[str, ...] = ()) -> Dict[str, Any]:
+        """What an engine's ``moe_totals`` says of the expert layers (nothing
+        without): ``experts_published`` and ``experts_held`` and, with the
+        rows and tokens a step is traced for (the no-drop path), the route
+        and the products a step launches by kind over ``expert_layers``
+        layers, from static shapes. A differentiated layer launches each
+        product forward, as a row and as a weight gradient, and forward again
+        where the block is rematerialised and the policy kept no such name
+        (``kept``: the names saved; another policy than ``KEEP_PRODUCTS``
+        keeps none). ``dtype``: the operands'; ``devices``: the mesh's."""
+        c = self.config
+        if c.moe is None:
+            return {}
+        moe = self._moe
+        record = {"experts_published": moe.num_experts,
+                  "experts_held": moe.held[1] - moe.held[0]}
+        if seq is None:
+            return record
+        from ..ops.transformer import pallas_gmm, pallas_segment_sum
+        backend = jax.default_backend()
+        tokens = batch * seq * self.rows_per_token     # a noised copy's rows too
+        counts = {route: dict.fromkeys(pallas_gmm.KINDS, 0) for route in ("kernel", "xla")}
+        for name, m, k, n, g in moe.grouped_products(tokens):
+            route = counts[pallas_gmm.choose_route(m, k, n, g, dtype, backend, devices)]
+            route["forward"] += expert_layers * (1 + (c.remat and name not in kept))
+            route["row_gradient"] += expert_layers
+            route["weight_gradient"] += expert_layers
+        used = [r for r in counts if any(counts[r].values())]
+        record.update(
+            grouped_matmul_route=used[0] if len(used) == 1 else "mixed",
+            products_kernel=counts["kernel"], products_xla=counts["xla"])
+        back = moe.rows_back(tokens)
+        if back is not None:
+            # the combine forward (again where the backward reruns the block)
+            # and the dispatch's backward gather the buffer's rows once each
+            record.update(
+                combine_route=pallas_segment_sum.choose_route(
+                    *back, dtype, backend, devices),
+                combine_rows_moved=expert_layers * (2 + bool(c.remat)) * back[0])
+        return record
+
+    @property
+    def returns_step_stats(self) -> bool:
+        """Whether ``loss_and_stats`` returns statistics a fused step carries
+        out beside the loss: the no-drop path's rows per expert, the masked
+        share of block diffusion, packed documents' count of tiles."""
+        return (self.moe_path == "dropless" or self.config.diffusion
+                or self.config.document_separator is not None)
 
     @functools.cached_property
     def scan_plan(self) -> Tuple[Tuple[Any, ...], int, Tuple[Any, ...]]:

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -204,53 +204,100 @@ FLASH_MIN_SEQ = 384
 FLASH_MIN_SEQ_WIDE_HEAD = 256    # head dim >= 128
 
 
-def kernel_is_default(q_shape, k_shape, backend: str,
-                      blockdiff: Optional[int] = None,
-                      eva: Optional[Tuple[int, int]] = None) -> bool:
+def kernel_is_default(q_shape, k_shape, backend: str) -> bool:
     """Whether ``flash_attention`` takes the in-repo blockwise kernel for
     this call when nothing forces a route: a rule of shape and platform
     alone. Off the TPU the XLA path stays (tier-1 dispatch and the
     ``analysis/`` HLO artifacts are the CPU's)."""
-    if backend != "tpu":
-        return False
-    from . import pallas_flash as _pf
-    min_seq = FLASH_MIN_SEQ_WIDE_HEAD if q_shape[3] >= 128 else FLASH_MIN_SEQ
-    # an EVA row's exact keys are launched a window at a time
-    seq = q_shape[1] if eva is None else min(q_shape[1], eva[0])
-    return (seq >= min_seq
-            and _pf.supports(q_shape, k_shape, compiled=True, blockdiff=blockdiff,
-                             eva=eva))
+    return choose_route(q_shape, k_shape, backend, "") == "kernel"
 
 
 def choose_route(q_shape, k_shape, backend: str, mode: str,
                  blockdiff: Optional[int] = None,
                  eva: Optional[Tuple[int, int]] = None) -> str:
-    """The whole decision of `flash_attention`, of `blockdiff_attention` and
-    of `eva_attention`:
-    ``"kernel"`` (the in-repo blockwise pair), ``"xla"`` (one shot) or
-    ``"xla_chunked"``. A pure function of the two shapes, the platform,
-    `attn_mode`'s value and, under the block-diffusion mask, its block
-    length (``q_shape`` then holds both copies' ``2 L`` rows and ``k_shape``
-    the clean copy's ``L``; the crossover is asked of the ``2 L``); under
-    EVA's, ``eva = (window, chunk)`` (both shapes the row's ``L``; the
-    crossover is asked of one window, which is what a launch sees).
+    """The route of `flash_attention`, of `blockdiff_attention` and of
+    `eva_attention`: ``"kernel"`` (the in-repo blockwise pair), ``"xla"`` (one
+    shot) or ``"xla_chunked"``: that of the call's `plan`."""
+    return plan(q_shape, k_shape, backend, mode, blockdiff=blockdiff, eva=eva).route
 
-    ``mode == "pallas"`` takes the kernel wherever it CAN run (interpret
-    mode off the TPU relaxes the 128-lane tile requirement to plain
-    divisibility); ``""`` takes it where the chip showed it faster
-    (`kernel_is_default`); every other call, a refused ``"pallas"`` among
-    them, is XLA's, chunked from `XLA_CHUNK_MIN_SEQ` up on a device.
-    """
-    if mode == "pallas":
+
+class Launch(NamedTuple):
+    """One launch of the flash pair: its ``tag`` (``"flash"``, ``"blockdiff"``,
+    ``"eva_local"``, ``"eva_far"``), a batch row's queries and keys, the tiles
+    (``pallas_flash.launch_tiles``), the static window the grids are cut to."""
+    tag: str
+    sq: int
+    sk: int
+    tiles: Any
+    window: Optional[int] = None
+
+
+class Plan(NamedTuple):
+    """The whole decision of one attention call: the route and, on the kernel
+    route, the launches in order. The entry point launches FROM this value and
+    the counters read it (``TransformerLM.attention_records``): a record
+    cannot say what was not launched."""
+    route: str
+    launches: Tuple[Launch, ...] = ()
+
+    def dq(self, tag: str) -> Optional[str]:
+        """How the backward of the launch tagged ``tag`` makes dq
+        (``pallas_flash.dq_mode``); None without such a launch."""
         from . import pallas_flash as _pf
-        if _pf.supports(q_shape, k_shape, compiled=backend != "cpu",
-                        blockdiff=blockdiff, eva=eva):
-            return "kernel"
-    elif mode == "" and kernel_is_default(q_shape, k_shape, backend, blockdiff, eva):
-        return "kernel"
-    if q_shape[1] >= XLA_CHUNK_MIN_SEQ and backend != "cpu":
-        return "xla_chunked"
-    return "xla"
+        return next((_pf.dq_mode(at.sq, at.sk, at.tiles, at.window)
+                     for at in self.launches if at.tag == tag), None)
+
+
+def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
+         causal: bool = True, window=None, blockdiff: Optional[int] = None,
+         eva: Optional[Tuple[int, int]] = None) -> Plan:
+    """THE decision of the three entry points, a pure function of the two
+    shapes, the platform, `attn_mode`'s value, the operands' size and the
+    mask, which says what the kernel route would launch:
+
+    - `flash_attention` (``causal``, ``window``): one launch, its grids cut to
+      a window that is static;
+    - `blockdiff_attention` (``blockdiff``: the block length; ``q_shape`` holds
+      both copies' ``2 L`` rows, ``k_shape`` the clean copy's ``L``): one
+      launch of both copies' queries over the clean keys;
+    - `eva_attention` (``eva = (window, chunk)``; as many key heads as query
+      heads, a row of whole windows of whole chunks, else no launch): the
+      row's windows folded to batch rows as one causal launch and, for a row
+      of several, one launch of the row's queries over its summaries.
+
+    ``mode == "pallas"`` takes the kernel wherever it CAN run every launch
+    (heads it folds, a legal tile each; interpret mode off the TPU relaxes the
+    128-lane tiles to plain divisibility); ``""`` where the chip showed it
+    faster: on a TPU, from the crossover up in the rows ONE launch sees (the
+    2 L; one window of EVA's); every other call, a refused ``"pallas"`` among
+    them, is XLA's, chunked from `XLA_CHUNK_MIN_SEQ` up on a device."""
+    from . import pallas_flash as _pf
+    sq, sk = q_shape[1], k_shape[1]
+    rows, launches = sq, ()
+    if blockdiff is not None:
+        launches = [("blockdiff", sq, sk, dict(blockdiff=blockdiff))]
+    elif eva is not None:
+        span, chunk = eva
+        windows, rows = -(-sq // span), min(sq, span)
+        if tuple(k_shape) == tuple(q_shape) and not (sq % windows or span % chunk):
+            launches = [("eva_local", sq // windows, sq // windows, {})]
+            if windows > 1:
+                launches.append(("eva_far", sq, sq // chunk,
+                                 dict(summaries=(span, span // chunk))))
+    else:
+        launches = [("flash", sq, sk, dict(
+            causal=causal, window=_pf.static_window(window, sq, sk)))]
+    compiled = backend != "cpu"
+    min_rows = FLASH_MIN_SEQ_WIDE_HEAD if q_shape[3] >= 128 else FLASH_MIN_SEQ
+    if mode == "pallas" or (mode == "" and backend == "tpu" and rows >= min_rows):
+        made = tuple(
+            Launch(tag, sq, sk, _pf.launch_tiles(
+                sq, sk, q_shape[3], itemsize, compiled=compiled, **kind),
+                kind.get("window"))
+            for tag, sq, sk, kind in launches)
+        if made and _pf.folds(q_shape, k_shape) and all(at.tiles for at in made):
+            return Plan("kernel", made)
+    return Plan("xla_chunked" if q_shape[1] >= XLA_CHUNK_MIN_SEQ and compiled else "xla")
 
 
 def flash_attention(q: jax.Array,
@@ -280,18 +327,16 @@ def flash_attention(q: jax.Array,
     and skips inside whole-sequence grids.
     """
     mode = attn_mode()
-    backend = jax.default_backend()
-    route = choose_route(q.shape, k.shape, backend, mode)
+    made = plan(q.shape, k.shape, jax.default_backend(), mode, q.dtype.itemsize,
+                causal=causal, window=window)
+    route = made.route
     if route == "kernel":
         from . import pallas_flash as _pf
-        static = _pf.static_window(window, q.shape[1], k.shape[1])
-        tiles = _pf.choose_tiles(q.shape[1], k.shape[1], q.shape[-1],
-                                 q.dtype.itemsize, causal=causal,
-                                 compiled=backend != "cpu", window=static)
+        (at,) = made.launches
         _log_path_once(
             "pallas_flash_inrepo, tiles (block_q x block_k) forward "
-            "%dx%d backward %dx%d%s" % (tiles.fwd + tiles.bwd + (
-                "" if static is None else f", grids cut to a window of {static}",)))
+            "%dx%d backward %dx%d%s" % (at.tiles.fwd + at.tiles.bwd + (
+                "" if at.window is None else f", grids cut to a window of {at.window}",)))
         return _pf.flash_attention_kernel(
             q, k, v, causal=causal, scale=scale,
             segment_ids=segment_ids, alibi_slopes=alibi_slopes,
@@ -404,16 +449,17 @@ def blockdiff_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if documents is None:
         documents = jnp.zeros((B, L), jnp.int32)
     documents = documents.astype(jnp.int32)
-    mode, backend = attn_mode(), jax.default_backend()
-    route = choose_route(q.shape, (B, L) + k.shape[2:], backend, mode, block_length)
-    _log_path_once(f"blockdiff {route}")
-    if route != "kernel":
+    made = plan(q.shape, (B, L) + k.shape[2:], jax.default_backend(), attn_mode(),
+                q.dtype.itemsize, blockdiff=block_length)
+    _log_path_once(f"blockdiff {made.route}")
+    if made.route != "kernel":
         return _xla_blockdiff_attention(
             q, k, v, documents, block_length, scale,
-            1024 if route == "xla_chunked" else None)
+            1024 if made.route == "xla_chunked" else None)
     from . import pallas_flash as _pf
+    (at,) = made.launches
     o, lse = _pf.flash_attention_with_lse(
-        q, k[:, :L], v[:, :L], causal=True, scale=scale, segment_ids=documents,
+        q, k[:, :at.sk], v[:, :at.sk], causal=True, scale=scale, segment_ids=documents,
         q_segment_ids=jnp.concatenate([documents, documents], axis=1),
         blockdiff=block_length)
     own, own_lse = _own_block_attention(q[:, L:], k[:, L:], v[:, L:], documents,
@@ -507,26 +553,26 @@ def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError(f"EVA attention takes as many key heads as query heads "
                          f"and a window ({window}) of whole chunks ({chunk}); "
                          f"got q {q.shape}, k {k.shape}")
-    mode, backend = attn_mode(), jax.default_backend()
-    route = choose_route(q.shape, k.shape, backend, mode, eva=(window, chunk))
-    _log_path_once(f"eva {route}")
-    if route != "kernel":
+    made = plan(q.shape, k.shape, jax.default_backend(), attn_mode(),
+                q.dtype.itemsize, eva=(window, chunk))
+    _log_path_once(f"eva {made.route}")
+    if made.route != "kernel":
         return _xla_eva_attention(q, k, v, kbar, vbar, window, chunk, scale,
-                                  1024 if route == "xla_chunked" else None)
+                                  1024 if made.route == "xla_chunked" else None)
     from . import pallas_flash as _pf
-    windows = -(-L // window)
-    each = L // windows
-    fold = lambda a: a.reshape((B * windows, each) + a.shape[2:])
+    local, *far = made.launches
+    windows = L // local.sq
+    fold = lambda a: a.reshape((B * windows, local.sq) + a.shape[2:])
     o, lse = _pf.flash_attention_with_lse(fold(q), fold(k), fold(v), causal=True,
-                                          scale=scale, tag="eva_local")
+                                          scale=scale, tag=local.tag)
     o = o.reshape(B, L, H, D)
-    if windows == 1:
+    if not far:
         return o
-    lse = lse.reshape(B, windows, H, each).transpose(0, 2, 1, 3).reshape(B, H, L)
-    far, far_lse = _pf.flash_attention_with_lse(
+    lse = lse.reshape(B, windows, H, local.sq).transpose(0, 2, 1, 3).reshape(B, H, L)
+    far_o, far_lse = _pf.flash_attention_with_lse(
         q, kbar, vbar, causal=True, scale=scale,
-        summaries=(window, window // chunk), tag="eva_far")
-    return _pf.merge_partials(o, lse, far, far_lse)[0]
+        summaries=(window, window // chunk), tag=far[0].tag)
+    return _pf.merge_partials(o, lse, far_o, far_lse)[0]
 
 
 @functools.lru_cache(None)

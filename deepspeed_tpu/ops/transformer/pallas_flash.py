@@ -68,7 +68,7 @@ EVA's summaries (``summaries=(W, per)``; ``attention.eva_attention``): the
 keys are one learned summary a chunk of the queries' row, ``per`` a window of
 ``W`` positions, and a row of window ``w`` sees the summaries of the windows
 before it, keys ``0 .. w x per - 1``. ``W`` is a multiple of both q tiles
-(``summary_tiles``), so a q-block has ONE limit, a scalar, in the place of the
+(``launch_tiles``), so a q-block has ONE limit, a scalar, in the place of the
 block-diffusion mask's two (``_block_limits``; ``_limited`` says a launch has
 such a limit): skipping, the wholly-visible test and the edge tile's compare
 are the same code. A row of the first window leaves 0 with the sentinel LSE.
@@ -1052,72 +1052,61 @@ def choose_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
 
 
 def supports(q_shape, k_shape, block_q: Optional[int] = None,
-             block_k: Optional[int] = None, compiled: bool = True,
-             blockdiff: Optional[int] = None,
-             eva: Optional[Tuple[int, int]] = None) -> bool:
-    """Shape gate. ``compiled=True`` (the TPU path) additionally requires
-    tiles on the 128-lane layout; ``compiled=False`` (the interpret path
-    driven on CPU test meshes) accepts anything the tiles divide evenly.
-    ``blockdiff``: the block length of the block-diffusion mask, whose
-    queries are two copies of the keys' positions (``blockdiff_tiles``).
-    ``eva``: EVA's ``(window, chunk)``, whose row is launched a window at a
-    time over its exact keys and once over its summaries
-    (``summary_tiles``); a row no longer than a window is one causal launch."""
-    B, Sq, H, D = q_shape
-    Sk, kvH = k_shape[1], k_shape[2]
-    if H % kvH:
-        return False
-    if D > NUM_LANES and D % NUM_LANES:
-        return False
-    if eva is not None:
-        window, chunk = eva
-        if Sq != Sk or H != kvH or blockdiff is not None:
-            return False
-        if Sq > window and (Sq % window or window % chunk or summary_tiles(
-                Sq, window, window // chunk, D, compiled=compiled) is None):
-            return False
-        Sq = Sk = min(Sq, window)
+             block_k: Optional[int] = None, compiled: bool = True, **kind) -> bool:
+    """Shape gate of ONE launch: heads the kernel `folds` and a legal tile
+    (`launch_tiles` under the launch's ``kind``). ``compiled=True`` (the TPU
+    path) requires tiles on the 128-lane layout; ``compiled=False`` (interpret
+    mode on CPU test meshes) accepts anything the tiles divide evenly."""
+    return folds(q_shape, k_shape) and launch_tiles(
+        q_shape[1], k_shape[1], q_shape[3], block_q=block_q, block_k=block_k,
+        compiled=compiled, **kind) is not None
+
+
+def folds(q_shape, k_shape) -> bool:
+    """Heads the kernel folds: query heads a multiple of the key heads, a
+    head dim under the lanes' 128 or a multiple of it."""
+    H, D, kvH = q_shape[2], q_shape[3], k_shape[2]
+    return not (H % kvH or (D > NUM_LANES and D % NUM_LANES))
+
+
+def launch_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
+                 causal: bool = True, window: Optional[int] = None,
+                 blockdiff: Optional[int] = None,
+                 summaries: Optional[Tuple[int, int]] = None,
+                 block_q: Optional[int] = None, block_k: Optional[int] = None,
+                 compiled: bool = True) -> Optional[FlashTiles]:
+    """The tiles of ONE launch of ``sq`` queries over ``sk`` keys, by its
+    kind; None: no legal tile. Which rule a kind takes is written HERE alone:
+    the kernel (`_prepare`), the gate (`supports`) and ``attention.plan``,
+    which routes and counters are read from, all ask this.
+
+    - causal or not, under a static ``window`` or none: `choose_tiles`;
+    - ``blockdiff`` (the block-diffusion mask's block length; ``sq`` is
+      ``2 sk``): the causal choice for ``sk`` queries (a q-block then lies in
+      one half of the rows), where the block length is a power of two that
+      divides both q tiles (a block of positions is never cut);
+    - ``summaries = (window, summaries a window)`` (EVA's far keys,
+      ``FlashConfig.summaries``; ``sk`` is ``sq // window x`` that): the
+      causal choice where both q tiles divide the window (a q-block then has
+      ONE limit), else that choice under a q tile of one window."""
+    choose = functools.partial(choose_tiles, head_dim=head_dim, itemsize=itemsize,
+                               block_k=block_k, compiled=compiled)
     if blockdiff is not None:
-        return Sq == 2 * Sk and blockdiff_tiles(
-            Sk, D, blockdiff, block_q=block_q, block_k=block_k,
-            compiled=compiled) is not None
-    return choose_tiles(Sq, Sk, D, block_q=block_q, block_k=block_k,
-                        compiled=compiled) is not None
-
-
-def blockdiff_tiles(keys: int, head_dim: int, block_length: int,
-                    itemsize: int = 2, *, block_q: Optional[int] = None,
-                    block_k: Optional[int] = None, compiled: bool = True
-                    ) -> Optional[FlashTiles]:
-    """The tiles of a launch under the block-diffusion mask: the causal
-    choice for ``keys`` queries (a q-block then lies in one half of the
-    ``2 x keys`` rows), where the block length is a power of two that
-    divides both q tiles (a block of positions is never cut); else None."""
-    b = int(block_length)
-    if b < 1 or b & (b - 1) or keys % b:
+        b = int(blockdiff)
+        if sq != 2 * sk or b < 1 or b & (b - 1) or sk % b:
+            return None
+        tiles = choose(sk, sk, block_q=block_q)
+        if tiles is None or tiles.fwd[0] % b or tiles.bwd[0] % b:
+            return None
+        return tiles
+    if summaries is not None:
+        span, per = summaries
+        for bq in dict.fromkeys((block_q, block_q or span)):
+            tiles = choose(sq, sk, block_q=bq)
+            if tiles is not None and not (span % tiles.fwd[0] or span % tiles.bwd[0]):
+                return tiles if sq // span * per == sk else None
         return None
-    tiles = choose_tiles(keys, keys, head_dim, itemsize, causal=True,
-                         block_q=block_q, block_k=block_k, compiled=compiled)
-    if tiles is None or tiles.fwd[0] % b or tiles.bwd[0] % b:
-        return None
-    return tiles
-
-
-def summary_tiles(queries: int, window: int, per: int, head_dim: int,
-                  itemsize: int = 2, *, block_q: Optional[int] = None,
-                  block_k: Optional[int] = None, compiled: bool = True
-                  ) -> Optional[FlashTiles]:
-    """The tiles of a launch over EVA's summaries (``FlashConfig.
-    summaries``): ``queries`` rows over ``queries // window x per`` keys, the
-    causal choice where both q tiles divide the window (a q-block then has
-    ONE limit), else that choice under a q tile of one window; else None."""
-    keys = queries // window * per
-    for bq in dict.fromkeys((block_q, block_q or window)):
-        tiles = choose_tiles(queries, keys, head_dim, itemsize, causal=True,
-                             block_q=bq, block_k=block_k, compiled=compiled)
-        if tiles is not None and not (window % tiles.fwd[0] or window % tiles.bwd[0]):
-            return tiles
-    return None
+    return choose(sq, sk, causal=causal, window=window, block_q=block_q)
 
 
 def static_window(window, sq: int, sk: int, q_offset=None) -> Optional[int]:
@@ -1191,9 +1180,6 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
             raise ValueError(
                 "the block-diffusion mask takes 2 x keys query rows (a clean "
                 "and a noised copy) and no window, ALiBi or q_offset")
-        tiles = blockdiff_tiles(Sk, D, blockdiff, q.dtype.itemsize,
-                                block_q=block_q, block_k=block_k,
-                                compiled=not interp)
         q_offset = 0
     elif summaries is not None:
         if (not causal or window is not None or alibi_slopes is not None
@@ -1202,14 +1188,10 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
             raise ValueError(
                 "EVA's summaries take one key a chunk of the queries' row and "
                 "no window, ALiBi, q_offset or segment ids")
-        tiles = summary_tiles(Sq, *summaries, D, q.dtype.itemsize,
-                              block_q=block_q, block_k=block_k,
-                              compiled=not interp)
         q_offset = 0
-    else:
-        tiles = choose_tiles(Sq, Sk, D, q.dtype.itemsize, causal=bool(causal),
-                             block_q=block_q, block_k=block_k,
-                             compiled=not interp, window=cut)
+    tiles = launch_tiles(Sq, Sk, D, q.dtype.itemsize, causal=bool(causal),
+                         window=cut, blockdiff=blockdiff, summaries=summaries,
+                         block_q=block_q, block_k=block_k, compiled=not interp)
     if tiles is None:
         raise ValueError(f"seq lengths ({Sq}, {Sk}) have no legal tiles "
                          f"(block_q={block_q}, block_k={block_k})")

@@ -559,49 +559,27 @@ class DeepSpeedEngine:
         self._jit_micro_step = None
         self._jit_apply_step = None
         self._jit_train_step = None
-        # MoE counters, plain values kept with telemetry off: the path the
-        # expert layers take ("dropless" / "capacity" / None, which follows
-        # from the model's configuration), the experts a layer has
-        # (``experts_published``) and those this chip holds of them
-        # (``experts_held``), and the fused steps so far.
-        # On the no-drop path the fused step also returns the per-expert row
-        # counts [layers, experts]; they stay on the device until
-        # ``moe_expert_rows()`` asks for them.
-        # ``grouped_matmul_route`` and the two ``products_*`` (no-drop path;
-        # None until a step is traced): which of ``pallas_gmm.choose_route``'s
-        # routes the expert layers' grouped matmuls take ("kernel" / "xla" /
-        # "mixed"), and the products one step launches by route and kind.
-        # ``combine_route`` and ``combine_rows_moved`` (a share of the
-        # experts alone; None / 0 elsewhere): which of
-        # ``pallas_segment_sum.choose_route``'s routes brings the buffer's
-        # rows back to the tokens, and the rows one step gathers for it.
+        # What the model launches, plain values kept with telemetry off
+        # (docs/OBSERVABILITY.md has every key): the model's own records
+        # (``expert_records`` / ``attention_records``), from its configuration
+        # now and from the launches' plans once a step is traced (None until
+        # then); the engine adds the experts' path and the fused steps so far.
+        # What a step leaves on the device beside the loss,
+        # ``moe_expert_rows()``, ``diffusion_last_step()`` and
+        # ``attn_last_step()`` fetch.
         self.moe_totals = {"path": getattr(self.model, "moe_path", None),
-                           "steps": 0, **self._experts_of_model(),
+                           "steps": 0, **(self._model_records("expert_records") or {}),
                            "grouped_matmul_route": None,
                            "products_kernel": None, "products_xla": None,
                            "combine_route": None, "combine_rows_moved": 0}
+        # whether the fused step returns the model's device-side statistics as
+        # a last output (the model says); every other program is as it was
+        self._step_has_stats = bool(getattr(self.model, "returns_step_stats", False))
         self._step_stats = None
-        # Attention counters, plain values kept with telemetry off: how many
-        # of the model's layers attend under a window (static kinds, scope
-        # ``attn/core_window``) and how many over the whole row
-        # (``attn/core``), the windows, the key heads, and, once a step is
-        # traced, the route ``attention.choose_route`` gives each kind's
-        # call ("kernel" / "xla" / "xla_chunked"; None until then, and for
-        # a kind the model has no layer of); ``documents``: whether the rows
-        # are packed documents (``document_separator``), so that a flash
-        # launch carries its table of each block's documents and skips a
-        # tile whose keys all lie in other ones. The LAST step's count of
-        # tiles stays on the device: ``attn_last_step()`` fetches it.
-        self.attn_totals = self._attention_of_model()
-        # The block-diffusion objective's record (None for every other
-        # model), plain values kept with telemetry off: the block length,
-        # the rows of activations a data token costs every layer (2: a clean
-        # and a noised copy), the fused steps so far and, once a step is
-        # traced, the route ``attention.choose_route`` gives the mask's call.
-        # The LAST step's masked share of the positions and mean weight
-        # (1 / t) of a masked one stay on the device beside the loss:
-        # ``diffusion_last_step()`` fetches them.
-        self.diffusion_totals = self._diffusion_of_model()
+        self.attn_totals, self.diffusion_totals = (
+            self._model_records("attention_records") or ({}, None))
+        if self.diffusion_totals is not None:
+            self.diffusion_totals["steps"] = 0
         # Optimizer-kernel counters, kept with telemetry off and filled from
         # the static bucket plan when a step that updates is traced: the
         # path ("pallas" / "xla"; None until then), and on the kernel path
@@ -1136,17 +1114,6 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------
     # jitted step functions
     # ------------------------------------------------------------------
-    @property
-    def _step_has_stats(self) -> bool:
-        """Whether the fused step returns the model's device-side
-        statistics as a last output (the no-drop MoE path's rows per
-        expert, the block-diffusion objective's masked share, packed
-        documents' count of tiles); every other model's program is as it
-        was."""
-        return (self.moe_totals["path"] == "dropless"
-                or self.diffusion_totals is not None
-                or bool(self.attn_totals.get("documents")))
-
     def moe_expert_rows(self):
         """The last fused step's assignments per expert, ``[layers,
         experts]`` int32, fetched now (the step itself never syncs on
@@ -1225,13 +1192,17 @@ class DeepSpeedEngine:
         device-side step statistics where the fused step returns them
         (``_step_has_stats``); else the empty tuple."""
         traced = self.remat_totals["policy"] is not None
-        self._count_attention(batch)
+        self._count_launches(batch)
         if self._step_has_stats:
             loss, stats = self.model.loss_and_stats(params, batch,
                                                     **self._remat_kw())
             out = loss, (stats,)
-            if "moe_expert_rows" in stats:
-                self._count_grouped_products(batch, stats["moe_expert_rows"].shape[0])
+            if "moe_expert_rows" in stats:   # the no-drop path's layers, now known
+                self.moe_totals.update(self._model_records(
+                    "expert_records", batch,
+                    expert_layers=stats["moe_expert_rows"].shape[0],
+                    dtype=self.param_dtype, devices=self.mesh.size,
+                    kept=self.remat_totals["saved"]))
         else:
             out = self.model.loss(params, batch, **self._remat_kw()), ()
         kept = self.remat_totals
@@ -2383,143 +2354,22 @@ class DeepSpeedEngine:
             with jax.profiler.TraceAnnotation("engine_totals", **flat):
                 pass
 
-    def _experts_of_model(self) -> Dict[str, int]:
-        """``experts_published`` and ``experts_held`` of the model's expert
-        layers (nothing for a model without)."""
-        moe = getattr(getattr(self.model, "config", None), "moe", None)
-        if moe is None:
-            return {}
-        lo, hi = moe.experts_held or (0, moe.num_experts)
-        return {"experts_published": moe.num_experts, "experts_held": hi - lo}
-
-    def _attention_of_model(self) -> Dict[str, Any]:
-        kinds = getattr(self.model, "layer_kinds", None)
-        if kinds is None:
-            return {}
-        windows = sorted({w for w, _ in kinds if w})
-        cfg = self.model.config
-        totals = {"layers_window": sum(1 for w, _ in kinds if w),
-                  "layers_full": sum(1 for w, _ in kinds if not w),
-                  "window": windows[0] if len(windows) == 1 else (windows or None),
-                  "kv_heads": cfg.kv_heads,
-                  "documents": cfg.document_separator is not None,
-                  "route": {"window": None, "full": None},
-                  "dq": {"window": None, "full": None}}
-        if getattr(cfg, "attention", None) == "eva":
-            # EVA's counts (docs/OBSERVABILITY.md): the summaries of a row
-            # and the route are a traced step's (`_count_attention`)
-            totals["eva"] = {"window": cfg.eva_window, "chunk": cfg.eva_chunk,
-                             "summaries_a_row": None,
-                             "pred_heads": cfg.pred_heads, "route": None,
-                             "dq_local": None, "dq_far": None}
-        return totals
-
-    def _diffusion_of_model(self) -> Optional[Dict[str, Any]]:
-        cfg = getattr(self.model, "config", None)
-        if not getattr(cfg, "diffusion", False):
+    def _model_records(self, name: str, batch=None, **how):
+        """The model's record ``name`` (``TransformerLM.attention_records`` /
+        ``expert_records``): of its configuration, or of the ``batch`` a step
+        is being traced for (host arithmetic from static shapes). None for a
+        model without, or a batch without ids."""
+        records = getattr(self.model, name, None)
+        if records is None or (batch is not None and "input_ids" not in batch):
             return None
-        return {"block_length": cfg.block_length,
-                "rows_per_token": self.model.rows_per_token,
-                "steps": 0, "route": None, "dq": None}
+        return records(*(() if batch is None else batch["input_ids"].shape[:2]), **how)
 
-    def _count_attention(self, batch) -> None:
-        """``attn_totals['route']`` (``diffusion_totals['route']`` under the
-        block-diffusion mask) and, beside each, how the kernel's backward
-        makes dq (``pallas_flash.dq_mode`` over the tiles the launch will
-        choose: ``one_block`` / ``summed`` / ``in_place``; None off the
-        kernel): host arithmetic from static shapes while the step is
-        traced."""
-        if not self.attn_totals or "input_ids" not in batch:
-            return
-        from ..ops.transformer import attention, pallas_flash
-        cfg = self.model.config
-        b, s = batch["input_ids"].shape[:2]
-        backend = jax.default_backend()
-        choose = dict(head_dim=cfg.head_dim, compiled=backend != "cpu",
-                      itemsize=jnp.dtype(self.param_dtype).itemsize)
-
-        def dq_of(route, sq, sk, tiles, window=None):
-            if route != "kernel" or tiles is None:
-                return None
-            return pallas_flash.dq_mode(sq, sk, tiles, window)
-
-        if self.diffusion_totals is not None:
-            route = attention.choose_route(
-                (b, 2 * s, cfg.num_heads, cfg.head_dim),
-                (b, s, cfg.kv_heads, cfg.head_dim), backend,
-                attention.attn_mode(), cfg.block_length)
-            self.diffusion_totals.update(route=route, dq=dq_of(
-                route, 2 * s, s, pallas_flash.blockdiff_tiles(
-                    s, block_length=cfg.block_length, **choose)))
-            return
-        if "eva" in self.attn_totals:
-            shape = (b, s, cfg.num_heads, cfg.head_dim)
-            route = attention.choose_route(
-                shape, shape, backend, attention.attn_mode(),
-                eva=(cfg.eva_window, cfg.eva_chunk))
-            # the launches `attention.eva_attention` makes: the row's windows
-            # folded to batch rows, and (a row of several) its summaries
-            windows = -(-s // cfg.eva_window)
-            each, per = s // windows, cfg.eva_window // cfg.eva_chunk
-            self.attn_totals["eva"].update(
-                summaries_a_row=s // cfg.eva_chunk, route=route,
-                dq_local=dq_of(route, each, each, pallas_flash.choose_tiles(
-                    each, each, **choose)),
-                dq_far=None if windows == 1 else dq_of(
-                    route, s, s // cfg.eva_chunk, pallas_flash.summary_tiles(
-                        s, cfg.eva_window, per, **choose)))
-            return
-        route = attention.choose_route(
-            (b, s, cfg.num_heads, cfg.head_dim), (b, s, cfg.kv_heads, cfg.head_dim),
-            backend, attention.attn_mode())
-        layers = {kind: self.attn_totals[f"layers_{kind}"] for kind in ("window", "full")}
-        self.attn_totals["route"] = {
-            kind: route if layers[kind] else None for kind in layers}
-
-        def dq_under(window):
-            cut = pallas_flash.static_window(window or None, s, s)
-            return dq_of(route, s, s, pallas_flash.choose_tiles(
-                s, s, window=cut, **choose), cut)
-        # (sliding layers of several widths: the mode they share, else both)
-        under = sorted({dq_under(w) or "" for w, _ in self.model.layer_kinds if w})
-        self.attn_totals["dq"] = {
-            "window": ("+".join(under) or None) if layers["window"] else None,
-            "full": dq_under(None) if layers["full"] else None}
-
-    def _count_grouped_products(self, batch, expert_layers: int) -> None:
-        """``moe_totals``' route and products a step, by kind, of the no-drop
-        path's grouped matmuls: host arithmetic from static shapes while the
-        step is traced (as ``opt_kernel_totals`` is). One differentiated
-        layer launches each product forward, as a row gradient and as a
-        weight gradient; the backward runs a forward product again where the
-        block is rematerialised and the policy did not keep its name
-        (``remat_totals``; a policy that is not ``KEEP_PRODUCTS`` is counted
-        as keeping none)."""
-        from ..ops.transformer import pallas_gmm, pallas_segment_sum
-        moe, cfg = self.model._moe, self.model.config
-        b, s = batch["input_ids"].shape[:2]
-        s *= getattr(self.model, "rows_per_token", 1)   # a noised copy's rows too
-        kept = self.remat_totals["saved"] if cfg.remat else None
-        counts = {route: dict.fromkeys(pallas_gmm.KINDS, 0) for route in ("kernel", "xla")}
-        for name, m, k, n, g in moe.grouped_products(b * s):
-            route = counts[pallas_gmm.choose_route(
-                m, k, n, g, self.param_dtype, jax.default_backend(), self.mesh.size)]
-            again = kept is not None and name not in kept
-            route["forward"] += expert_layers * (1 + again)
-            route["row_gradient"] += expert_layers
-            route["weight_gradient"] += expert_layers
-        used = [r for r in counts if any(counts[r].values())]
-        self.moe_totals.update(
-            grouped_matmul_route=used[0] if len(used) == 1 else "mixed",
-            products_kernel=counts["kernel"], products_xla=counts["xla"])
-        back = moe.rows_back(b * s)
-        if back is not None:
-            # the combine forward (again where the backward reruns the block)
-            # and the dispatch's backward gather the buffer's rows once each
-            self.moe_totals.update(
-                combine_route=pallas_segment_sum.choose_route(
-                    *back, self.param_dtype, jax.default_backend(), self.mesh.size),
-                combine_rows_moved=expert_layers * (2 + bool(cfg.remat)) * back[0])
+    def _count_launches(self, batch) -> None:
+        """``attn_totals`` and ``diffusion_totals`` while a step is traced."""
+        attn, diffusion = self._model_records("attention_records", batch) or ({}, None)
+        self.attn_totals.update(attn)
+        if diffusion is not None:
+            self.diffusion_totals.update(diffusion)
 
     def _count_moe(self, stats) -> None:
         """The fused step's MoE counters; the step's statistics are kept

@@ -47,33 +47,23 @@ class PipelineModule:
     mode is meaningless here because every stage holds the same block shapes).
     """
 
+    #: what the stage scan (``TransformerLM._block_fn`` without a mask or a
+    #: layer's kind, one stream, a scalar auxiliary loss) carries between
+    #: ``TransformerLM.embed`` and ``.head``, under ``.loss``
+    RUNS = frozenset({
+        "norm_style='sandwich'", "embedding_scale", "residual_fp32", "qk_norm",
+        "attn_gate", "attention='latent'", "pred_heads", "moe"})
+
     def __init__(self, config: TransformerConfig, num_stages: int,
                  num_microbatches: int = None):
         assert config.num_layers % num_stages == 0, (
             f"num_layers {config.num_layers} not divisible by num_stages {num_stages}")
-        if not config.causal or config.norm_style != "pre" or config.mlm_head:
-            raise ValueError(
-                "PipelineModule supports causal pre-LN decoders; encoder "
-                "configs (bidirectional/post-LN/MLM head) are not pipelined")
-        if config.diffusion:
-            raise NotImplementedError(
-                "PipelineModule trains the next-token objective: "
-                "objective='block_diffusion' (a clean and a noised copy of "
-                "every row, its own mask and loss weights) is not pipelined")
-        if config.attention == "eva" or config.pred_heads > 1:
-            raise NotImplementedError(
-                "PipelineModule's stage scan runs mha or latent attention under "
-                "one next-token head: attention='eva' (its per-layer summary "
-                "vectors, windows counted over the whole row) and pred_heads "
-                f"({config.pred_heads}) are not pipelined")
         self.config = config
         self.num_stages = num_stages
         self.layers_per_stage = config.num_layers // num_stages
         self.num_microbatches = num_microbatches or num_stages
         self._lm = TransformerLM(config)
-        if self._lm._windows is not None:  # all-zero windows normalize away
-            raise ValueError("per-layer attention windows are not threaded "
-                             "through the pipeline stage scan yet")
+        self._lm.require("PipelineModule", self.RUNS)
 
     # -- params: reshape blocks [L, ...] -> [P, L/P, ...] --------------------
     def init(self, rng: jax.Array, dtype=jnp.float32) -> Dict[str, Any]:
@@ -126,17 +116,8 @@ class PipelineModule:
         B = input_ids.shape[0]
         assert B % M == 0, f"batch {B} not divisible by num_microbatches {M}"
         mb = B // M
-        positions = jnp.arange(S)[None, :]
         self._lm._charge_head(remat_budget, input_ids)
-
-        x = self._lm._wte(params["wte"], input_ids)
-        if self._lm._wpe is not None:
-            # same offset as TransformerLM.apply — OPT's learned table is
-            # padded by 2
-            x = x + self._lm._wpe(params["wpe"], positions + c.position_offset)
-        if self._lm._ln_emb is not None:  # bloom's embedding LayerNorm
-            x = self._lm._ln_emb(params["ln_emb"], x)
-        x = x.astype(c.dtype)
+        x, positions = self._lm.embed(params, input_ids)
 
         # microbatch major: [M, mb, S, D]
         x_mb = x.reshape(M, mb, S, c.hidden_size)
@@ -186,14 +167,8 @@ class PipelineModule:
         (buf, out_mb, aux_total), _ = jax.lax.scan(
             tick, (buf, out_mb, aux_total), jnp.arange(ticks))
 
-        x = out_mb.reshape(B, S, c.hidden_size)
-        x = _c(x, ACT_SPEC)
-        x = self._lm._ln_f(params["ln_f"], x)
-        if c.tie_embeddings:
-            logits = self._lm._wte.attend(params["wte"], x)
-        else:
-            logits = self._lm._lm_head(params["lm_head"], x)
-        return logits.astype(jnp.float32), aux_total
+        x = _c(out_mb.reshape(B, S, c.hidden_size), ACT_SPEC)
+        return self._lm.head(params, x), aux_total
 
     # The shared loss ingredients (transformer.py): ``TransformerLM.loss``
     # calls ``self.derive_labels``/``self.combine_aux``, and both read only

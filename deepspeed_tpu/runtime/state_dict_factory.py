@@ -1082,7 +1082,8 @@ def load_megatron_model(ckpt, config: TransformerConfig,
     return TransformerLM(cfg), params
 
 
-# built-in architecture registrations (models/registry.py dispatches here)
+# the families whose adapters are this file's register here; an architecture
+# with a module of its own (models/<arch>.py) registers itself there
 def _register_builtins() -> None:
     from ..models.registry import register_architecture
     register_architecture("gpt2", _gpt2_config, _gpt2_params)
@@ -1099,17 +1100,6 @@ def _register_builtins() -> None:
                           _bert_params_for("roberta.", "lm_head"))
     register_architecture("distilbert", _distilbert_config, _distilbert_params)
     register_architecture("gpt_neo", _gpt_neo_config, _gpt_neo_params)
-    from ..models import instella_moe
-    register_architecture("deepseek_v3", instella_moe.config_kwargs,
-                          instella_moe.checkpoint_params)
-    from ..models import afmoe
-    register_architecture("afmoe", afmoe.config_kwargs, afmoe.checkpoint_params)
-    from ..models import sdar_moe
-    register_architecture("sdar_moe", sdar_moe.config_kwargs,
-                          sdar_moe.checkpoint_params)
-    from ..models import evabyte
-    register_architecture("evabyte", evabyte.config_kwargs,
-                          evabyte.checkpoint_params)
 
 
 _register_builtins()

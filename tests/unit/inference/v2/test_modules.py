@@ -59,10 +59,25 @@ def test_custom_registration():
 
 
 def test_architecture_registry_builtin():
-    assert supported_architectures() == \
-        ["afmoe", "bert", "bloom", "deepseek_v3", "distilbert", "evabyte", "falcon", "gpt2",
-         "gpt_neo", "gpt_neox", "gptj", "internlm", "llama", "mistral", "mixtral",
-         "opt", "phi", "qwen2", "roberta", "sdar_moe"]
+    """Every ``models/<arch>.py`` that adapts a configuration and a checkpoint
+    (``config_kwargs`` / ``checkpoint_params``) is in the registry under its
+    own pair, whatever registered it, and beside them the families whose
+    adapters ``state_dict_factory`` holds."""
+    import importlib
+    import pkgutil
+
+    from deepspeed_tpu import models
+    from deepspeed_tpu.runtime import state_dict_factory
+    specs = [get_architecture(name) for name in supported_architectures()]
+    pairs = {(spec.config_fn, spec.params_fn) for spec in specs}
+    found = [importlib.import_module(f"{models.__name__}.{info.name}")
+             for info in pkgutil.iter_modules(models.__path__)]
+    adapters = [m for m in found if hasattr(m, "config_kwargs")]
+    assert len(adapters) >= 4
+    assert all((m.config_kwargs, m.checkpoint_params) in pairs for m in adapters)
+    homes = {m.__name__ for m in adapters} | {state_dict_factory.__name__}
+    assert {spec.config_fn.__module__ for spec in specs} == homes
+    assert {"gpt2", "llama", "bert", "deepseek_v3", "evabyte"} <= set(supported_architectures())
     spec = get_architecture("falcon")
     cfg = spec.config_fn({"model_type": "falcon", "vocab_size": 128,
                           "hidden_size": 64, "num_hidden_layers": 2,

@@ -192,6 +192,6 @@ def test_capacity_models_count_too_and_keep_their_program():
 def test_serving_refuses_the_configuration():
     from deepspeed_tpu.inference.v2.model import RaggedInferenceModel
     from deepspeed_tpu.models import olmoe_model
-    with pytest.raises(NotImplementedError, match="serving OLMoE is not supported"):
+    with pytest.raises(NotImplementedError, match="serving engine.*qk_norm.*capacity_factor=None"):
         RaggedInferenceModel(olmoe_model("olmoe-tiny"), block_size=16,
                              max_blocks_per_seq=8)

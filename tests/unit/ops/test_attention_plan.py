@@ -1,0 +1,90 @@
+"""An attention entry point launches exactly what its plan lists: the
+``attention.Plan`` the counters read (``TransformerLM.attention_records``)
+against the ``FlashConfig`` the kernel is built with, under
+``DSTPU_ATTN=pallas`` in interpret mode."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.ops.transformer import attention, pallas_flash
+
+B, H, D = 1, 2, 16
+
+
+def _qkv(rows, keys=None, kv_heads=H):
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (B, rows, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, keys or rows, kv_heads, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, keys or rows, kv_heads, D), jnp.float32)
+    return q, k, v
+
+
+def _flash(**mask):
+    q, k, v = _qkv(64, kv_heads=1)
+    plan = attention.plan(q.shape, k.shape, "cpu", "pallas", 4, **mask)
+    return plan, lambda: attention.flash_attention(q, k, v, **mask)
+
+
+def _blockdiff():
+    q, k, v = _qkv(128)
+    plan = attention.plan(q.shape, (B, 64, H, D), "cpu", "pallas", 4, blockdiff=4)
+    return plan, lambda: attention.blockdiff_attention(q, k, v, 4)
+
+
+def _eva(rows):
+    q, k, v = _qkv(rows)
+    kbar, vbar = attention.eva_summaries(k, v, jnp.ones((H, D)), jnp.zeros((H, D)), 4)
+    plan = attention.plan(q.shape, k.shape, "cpu", "pallas", 4, eva=(32, 4))
+    return plan, lambda: attention.eva_attention(q, k, v, kbar, vbar, 32, 4)
+
+
+# the call -> (its plan and the call itself, the launches' tags)
+CALLS = {
+    "flash": (lambda: _flash(), ["flash"]),
+    "flash_static_window": (lambda: _flash(window=16), ["flash"]),
+    "flash_bidirectional": (lambda: _flash(causal=False), ["flash"]),
+    "blockdiff": (_blockdiff, ["blockdiff"]),
+    "eva_one_window": (lambda: _eva(32), ["eva_local"]),
+    "eva_four_windows": (lambda: _eva(128), ["eva_local", "eva_far"]),
+}
+
+
+@pytest.mark.parametrize("call", sorted(CALLS))
+def test_an_entry_point_launches_what_its_plan_lists(monkeypatch, call):
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    build, tags = CALLS[call]
+    plan, run = build()
+    assert plan.route == "kernel" and [at.tag for at in plan.launches] == tags
+    launched = []
+    kernel = pallas_flash._flash
+
+    def recording(cfg, q4, k3, *rest):
+        launched.append((cfg, q4.shape[2], k3.shape[1]))
+        return kernel(cfg, q4, k3, *rest)
+
+    monkeypatch.setattr(pallas_flash, "_flash", recording)
+    run()
+    assert len(launched) == len(plan.launches)
+    for at, (cfg, sq, sk) in zip(plan.launches, launched):
+        assert (at.sq, at.sk, at.tiles, at.window) == (sq, sk, cfg.tiles, cfg.window)
+        assert cfg.tag == (at.tag if at.tag in pallas_flash.TAGS else None)
+        assert (cfg.blockdiff is not None) == (at.tag == "blockdiff")
+        assert (cfg.summaries is not None) == (at.tag == "eva_far")
+        assert plan.dq(at.tag) == pallas_flash.dq_mode(sq, sk, cfg.tiles, cfg.window)
+    if call == "flash_static_window":
+        assert plan.launches[0].window == 16
+
+
+@pytest.mark.parametrize("call", sorted(CALLS))
+def test_off_the_kernel_route_a_plan_lists_no_launch(monkeypatch, call):
+    """The CPU's own route is XLA's: no launch, no dq mode, and no kernel is
+    built."""
+    monkeypatch.setenv("DSTPU_ATTN", "")
+    monkeypatch.setattr(pallas_flash, "_flash", lambda *a: pytest.fail("a kernel launch"))
+    _, run = CALLS[call][0]()
+    run()
+    for plan in (attention.plan((B, 64, H, D), (B, 64, 1, D), "cpu", ""),
+                 attention.plan((B, 128, H, D), (B, 64, H, D), "cpu", "", blockdiff=4),
+                 attention.plan((B, 128, H, D), (B, 128, H, D), "cpu", "", eva=(32, 4))):
+        assert plan.route == "xla" and plan.launches == () and plan.dq("flash") is None

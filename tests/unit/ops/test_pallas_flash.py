@@ -704,9 +704,10 @@ def test_blockdiff_launch_multiplies_no_hidden_quadrant():
         flash_attention_with_lse(q, k, v, causal=True, blockdiff=b)[0]))(q))(
             q, k[:, :L], v[:, :L]))
     assert "flash_fwd_blockdiff" in text and "flash_bwd_blockdiff" in text
-    tiles = pf.blockdiff_tiles(8192, 128, 4)
+    tiles = pf.launch_tiles(16384, 8192, 128, blockdiff=4)
     assert tiles == pf.choose_tiles(8192, 8192, 128, causal=True)
-    assert pf.blockdiff_tiles(8192, 128, 6) is None and pf.blockdiff_tiles(8190, 128, 4) is None
+    assert pf.launch_tiles(16384, 8192, 128, blockdiff=6) is None
+    assert pf.launch_tiles(16380, 8190, 128, blockdiff=4) is None
     with pytest.raises(ValueError, match="block-diffusion"):
         flash_attention_with_lse(q, k[:, :L], v[:, :L], blockdiff=b, window=16)
     with pytest.raises(ValueError, match="block-diffusion"):
@@ -1006,11 +1007,11 @@ def test_summary_tiles_keep_a_q_block_inside_a_window():
     2048); a q tile wider than the window is replaced by one window; keys no
     compiled tile divides have none."""
     from deepspeed_tpu.ops.transformer import pallas_flash as pf
-    cell = pf.summary_tiles(32768, 2048, 128, 128)
+    cell = pf.launch_tiles(32768, 2048, 128, summaries=(2048, 128))
     assert cell.fwd == (512, 512) and cell.bwd == (1024, 1024)
-    tiny = pf.summary_tiles(128, 32, 8, 16, compiled=False)
+    tiny = pf.launch_tiles(128, 32, 16, summaries=(32, 8), compiled=False)
     assert tiny.fwd[0] == 32 and tiny.bwd[0] == 32
-    assert pf.summary_tiles(4096, 2048, 100, 128) is None    # 200 keys: off the lanes
+    assert pf.launch_tiles(4096, 200, 128, summaries=(2048, 100)) is None    # off the lanes
     with pytest.raises(ValueError, match="summaries"):
         flash_attention_with_lse(*_eva_case(128), causal=True, summaries=(32, 8))
 
@@ -1072,9 +1073,9 @@ def test_dq_mode_is_the_k_blocks_a_q_block_meets(sq, sk, window, cell, want):
     from deepspeed_tpu.ops.transformer import pallas_flash as pf
     tiles = pf.choose_tiles(sq, sk, 128, causal=True, window=window)
     if sk == sq // 2:
-        tiles = pf.blockdiff_tiles(sk, 128, 4)
+        tiles = pf.launch_tiles(sq, sk, 128, blockdiff=4)
     elif sk == sq // 16:
-        tiles = pf.summary_tiles(sq, 2048, 128, 128)
+        tiles = pf.launch_tiles(sq, sk, 128, summaries=(2048, 128))
     assert pf.dq_mode(sq, sk, tiles, window) == want, cell
 
 

@@ -1,7 +1,7 @@
 """How the flash backward makes dq, as the engine's counters say it
 (``attn_totals["dq"]``, ``attn_totals["eva"]["dq_local"]`` / ``["dq_far"]``,
 ``diffusion_totals["dq"]``): host arithmetic from static shapes
-(``engine._count_attention`` over ``pallas_flash.dq_mode``), kept with
+(the model's ``attention_records`` over the launches' own plans, ``attention.Plan.dq``), kept with
 telemetry off, and in the ``engine_totals`` annotation's dotted keys."""
 
 import jax.numpy as jnp
@@ -56,8 +56,8 @@ def test_the_counters_carry_the_dq_mode_of_each_kind(eight_devices, monkeypatch,
         if ".dq" in k}
     assert flat() == {}                 # before a step is traced: None, left out
     batch = {"input_ids": np.zeros((8, row), np.int32)}
-    engine._count_attention(batch)      # the CPU's route is XLA's: no kernel, no mode
+    engine._count_launches(batch)      # the CPU's route is XLA's: no kernel, no mode
     assert flat() == {}
     monkeypatch.setenv("DSTPU_ATTN", "pallas")
-    engine._count_attention(batch)
+    engine._count_launches(batch)
     assert flat() == want

@@ -1,0 +1,62 @@
+"""What a traced step writes into ``attn_totals``, ``moe_totals`` and
+``diffusion_totals`` for the six benchmark architectures' tiny presets, under
+``DSTPU_ATTN=''`` and ``'pallas'``, as ``setup_spans.flat_totals`` carries it
+into the ``engine_totals`` annotation: equal to ``launch_records.json``, which
+was taken from the parent of PR 45 (the engine then reckoned these itself)
+before the model and the launches' own plans took the reckoning over.
+"""
+
+import json
+import pathlib
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu import models
+from deepspeed_tpu.telemetry import setup_spans
+
+FIXTURE = pathlib.Path(__file__).with_name("launch_records.json")
+# preset -> (its factory, the cell's own keys beside the preset's, a row's tokens)
+PRESETS = {
+    "gpt2-tiny": (models.gpt2_model, {}, 128),
+    "olmoe-tiny": (models.olmoe_model, {}, 128),
+    "instella-tiny": (models.instella_moe_model, {}, 128),
+    "afmoe-tiny": (models.afmoe_model, dict(document_separator=1), 256),
+    "sdar-tiny": (models.sdar_moe_model, dict(document_separator=1, experts_held=(0, 8)), 128),
+    "evabyte-tiny": (models.evabyte_model, {}, 128),
+}
+MODES = {"default": "", "pallas": "pallas"}
+
+
+def trace_step(engine, batch):
+    """Trace (lower, never compile or run) the fused train step for
+    ``batch``: what fills the counters. -> the lowered step."""
+    batch = engine._prepare_batch(batch)
+    engine._build_fused_jit()
+    with engine.mesh:
+        return engine._jit_train_step.lower(
+            engine.state, batch, jnp.asarray(1e-3, jnp.float32))
+
+
+def records_of(engine):
+    return setup_spans.flat_totals(
+        attn=engine.attn_totals, moe=engine.moe_totals,
+        diffusion=engine.diffusion_totals or {})
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+@pytest.mark.parametrize("preset", sorted(PRESETS))
+def test_a_traced_step_writes_the_parents_records(eight_devices, monkeypatch, preset, mode):
+    import deepspeed_tpu
+    factory, keys, row = PRESETS[preset]
+    monkeypatch.setenv("DSTPU_ATTN", MODES[mode])
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=factory(preset, dtype=jnp.float32, remat=True, **keys), config={
+            "train_micro_batch_size_per_gpu": 1,
+            "zero_optimization": {"stage": 1},
+            "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
+    before = records_of(engine)
+    trace_step(engine, {"input_ids": np.ones((8, row), np.int32)})
+    got = {"before": before, "traced": records_of(engine)}
+    assert got == json.loads(FIXTURE.read_text())[f"{preset}/{mode}"]

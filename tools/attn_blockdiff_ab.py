@@ -82,7 +82,7 @@ def main() -> int:
     tiles_run = getattr(pallas_flash, "tiles_run", None)
 
     def blockdiff_tiles(doc):
-        return pallas_flash.blockdiff_tiles(L, D, b), dict(
+        return pallas_flash.launch_tiles(2 * L, L, D, blockdiff=b), dict(
             q_ids=jnp.concatenate([doc, doc], axis=1), k_ids=doc, blockdiff=b)
 
     def causal_tiles(window=None):

@@ -25,7 +25,7 @@ eight head groups, which is what one launch of the cell holds):
   would cost the same heads).
 
 Forward, and forward + backward, median of ``--iters`` timed calls; for the
-launches the tiles ``choose_tiles`` / ``summary_tiles`` give and, for
+launches the tiles ``launch_tiles`` gives and, for
 ``local_by_ids``, the tiles run of the tiles the position test alone runs
 (``pallas_flash.tiles_run``). One JSON line a case, also in
 ``chiprun_out/attn_eva_ab.jsonl``.
@@ -78,7 +78,7 @@ def main() -> int:
             pallas_flash.choose_tiles(L, L, D, causal=True)),
         "far": (lambda q, k, v: out_of(flash_attention_with_lse(
             q, kbar, vbar, causal=True, summaries=(W, W // c), tag="eva_far")),
-            pallas_flash.summary_tiles(L, W, W // c, D)),
+            pallas_flash.launch_tiles(L, L // c, D, summaries=(W, W // c))),
         "summaries": (lambda q, k, v: attention.eva_summaries(k, v, phi, mu, c)[0], None),
         "xla": (lambda q, k, v: attention._xla_eva_attention(
             q, k, v, kbar, vbar, W, c, None, 1024), None),

@@ -5,7 +5,9 @@ inputs. The program under test never sees the seed, only the inputs.
 Sizes (document lengths) come from the mix alone: the quantiles of its
 stated distribution, the same multiset for every seed. ``--seed`` draws
 their order and the token ids (and the weights), so a run's work does not
-depend on the seed it was given.
+depend on the seed it was given; where it still would (a window shorter than
+a cycle, under attention that stops at a document's end), the mix draws the
+order itself: ``"order_seed": n``.
 
 Length distribution: ``{"dist": "lognormal", "median": m, "sigma": s,
 "min": a, "max": b}`` or ``{"dist": "fixed", "value": v}``.
@@ -53,21 +55,33 @@ def train_batches(mix: dict, seed: int, vocab: int, rows: int) -> Iterator[Dict[
     """Endless stream of ``{"input_ids": [rows, seq_len]}``: documents of
     heavy-tailed length, each ended by the separator token, packed back to
     back into rows of ``seq_len`` (a document may straddle two rows, as in
-    GPT-2's own training data)."""
+    GPT-2's own training data).
+
+    A batch is a VIEW of the cycle's buffer: between two refills the
+    generator allocates nothing, so what a batch costs does not hang on the
+    shape of the host's heap (PERF.md, PR 47: a copy of the 35 MiB that was
+    left, every batch, gave one cell two speeds). A refill makes a new
+    array, the remainder and one more cycle of documents, and never writes
+    to the old one: whoever still holds a batch of it keeps its bytes."""
     seq = int(mix["seq_len"])
     sep = int(mix["separator"]) % vocab
     doc_lens = lengths(mix["doc_len"], int(mix["docs_per_cycle"]))
     rng = rng_of(seed, 1)
+    # Where a step's cost follows the documents' places in the rows (attention
+    # cut at a separator), a mix fixes their order with "order_seed": --seed
+    # then draws the ids alone, and every seed's window does the same work.
+    order = rng_of(mix["order_seed"], 2) if "order_seed" in mix else rng
     need = rows * seq
-    buf = np.empty(0, np.int32)
+    buf, at = np.empty(0, np.int32), 0
     while True:
-        parts = [buf]
-        have = len(buf)
-        while have < need:
-            for n in rng.permutation(doc_lens):
-                doc = tokens(rng, mix["token_dist"], vocab - 1, int(n))
-                parts += [doc, np.asarray([sep], np.int32)]
-                have += int(n) + 1
-        flat = np.concatenate(parts)
-        yield {"input_ids": flat[:need].reshape(rows, seq)}
-        buf = flat[need:]
+        have = len(buf) - at
+        if have < need:
+            parts = [buf[at:]]
+            while have < need:
+                for n in order.permutation(doc_lens):
+                    doc = tokens(rng, mix["token_dist"], vocab - 1, int(n))
+                    parts += [doc, np.asarray([sep], np.int32)]
+                    have += int(n) + 1
+            buf, at = np.concatenate(parts), 0
+        yield {"input_ids": buf[at:at + need].reshape(rows, seq)}
+        at += need

@@ -129,12 +129,14 @@ def test_the_manifest_lists_the_three_for_the_new_cell_alone():
     assert {m["name"] for m in cell.end_to_end} == {"train_tokens_per_s", "setup_s"}
     with open(manifest) as f:
         m = json.load(f)
-    assert m["workloads"][-1]["name"] == CELL and m["configs"][-1]["name"] == "sdar-30b-a3b"
-    assert [p["name"] for p in m["per_layer"][-3:]] == [
+    # by name, wherever a later PR's entries have pushed them
+    assert cell.entry["config"] == "sdar-30b-a3b" and cell.entry["chips"] == 1
+    assert [p["name"] for p in m["per_layer"] if p["name"] in THREE] == [
         "train_attn_blockdiff_ms", "attn_blockdiff_roofline", "train_diffusion_noise_ms"]
-    for w in m["workloads"][:-1]:
-        theirs = harness.Cell(manifest, w["name"]).per_layer
-        assert not THREE & {p["name"] for p in theirs}
+    for w in m["workloads"]:
+        if w["name"] != CELL:
+            theirs = harness.Cell(manifest, w["name"]).per_layer
+            assert not THREE & {p["name"] for p in theirs}
     c = cell.config
     assert (c["num_experts"], c["vocab_size"], c["num_hidden_layers"]) == (16, 18992, 8)
     assert (c["hidden_size"], c["moe_intermediate_size"], c["num_attention_heads"],

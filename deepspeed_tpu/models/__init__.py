@@ -12,6 +12,7 @@ with _setup_spans.importing():
     from .afmoe import afmoe_config, afmoe_model  # noqa: F401
     from .sdar_moe import sdar_moe_config, sdar_moe_model  # noqa: F401
     from .evabyte import evabyte_config, evabyte_model  # noqa: F401
+    from .keye_vl2 import keye_vl2_config, keye_vl2_model  # noqa: F401
     from .opt_phi_falcon import (falcon_config, falcon_model, opt_config,  # noqa: F401
                                  opt_model, phi_config, phi_model)
     from .bloom_neox_gptj import (bloom_config, bloom_model, gpt_neo_config,  # noqa: F401

@@ -32,6 +32,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
@@ -265,6 +266,19 @@ class YarnScaling:
 
 
 @dataclasses.dataclass(frozen=True)
+class IndexerConfig:
+    """A learned selection of keys inside ``mha`` attention (DeepSeek Sparse
+    Attention's lightning indexer; ``ops/transformer/attention.py`` has the
+    equations): ``heads`` small query heads and ONE key head of ``head_dim``
+    score every visible key of a query, the ``topk`` largest are the keys its
+    main heads attend, and the indexer learns from their own distribution
+    (a KL term beside the language-model loss, which passes no gradient to it)."""
+    heads: int
+    head_dim: int
+    topk: int
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 50257
     max_seq_len: int = 1024
@@ -284,6 +298,11 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     rope_dim: Optional[int] = None   # partial rotary (phi/neox/gpt-j); None => head_dim
     rope_style: str = "half"         # 'half' (llama/neox) | 'interleaved' (gpt-j)
+    # rope by sections (Qwen2-VL's ``mrope_section``): how many of a head's
+    # frequency pairs each of THREE position streams (temporal, height, width)
+    # turns, in that order; a batch's ``position_ids`` [3, B, L] gives the
+    # streams (without them all three are a token's index: plain rope)
+    rope_sections: Optional[Tuple[int, ...]] = None
     # per-layer causal attention windows (mistral sliding_window; gpt-neo
     # alternating global/local; afmoe's layer_types): 0 = global, w > 0 =
     # attend the last w keys. A single int applies to every layer. The
@@ -356,6 +375,8 @@ class TransformerConfig:
     # two vectors a head for the summaries (``eva_phi``, ``eva_mu``). Windows
     # and chunks are counted from the row's start; no document ids enter.
     attention: str = "mha"
+    # ``mha`` over a learned selection of each query's keys (`IndexerConfig`)
+    indexer: Optional[IndexerConfig] = None
     eva_window: int = 0
     eva_chunk: int = 0
     kv_latent_rank: int = 0
@@ -462,6 +483,9 @@ class TransformerConfig:
         head = 0 if self.tie_embeddings else self.pred_heads * v * h
         if self.attention == "eva":
             attn += 2 * self.num_heads * self.head_dim
+        if self.indexer is not None:
+            ix = self.indexer
+            attn += h * (ix.heads * ix.head_dim + ix.head_dim + ix.heads) + 2 * ix.head_dim
         if self.mlm_head:
             head += h * h + v  # prediction transform + decoder bias
         return embed + head + L * (attn + mlp)
@@ -505,6 +529,10 @@ MECHANISMS: Dict[str, Tuple[Callable[["TransformerLM"], bool], str]] = {
                            "keys and values come from one compressed vector a token"),
     "attention='eva'": (lambda m: m.config.attention == "eva",
                         "a query sees its window's keys and a learned summary a chunk before it"),
+    "indexer": (lambda m: m.config.indexer is not None,
+                "a learned indexer picks each query's keys and adds a loss of its own"),
+    "rope_sections": (lambda m: m.config.rope_sections is not None,
+                      "a head's frequency pairs are turned by three position streams"),
     "pred_heads": (lambda m: m.config.pred_heads > 1,
                    "the head's outputs are several next-token heads under a loss of their own"),
     "farskip": (lambda m: m.config.farskip,
@@ -646,6 +674,13 @@ class TransformerLM:
                 "o_proj": lin(attn_out, c.hidden_size, attn_out_bias, "row"),
             }
         self._block_layers = {"ln_1": norm_cls(c.hidden_size), **attn_layers}
+        if c.indexer is not None:
+            ix = c.indexer
+            self._block_layers.update({
+                "indexer_q": lin(c.hidden_size, ix.heads * ix.head_dim, False, None),
+                "indexer_k": lin(c.hidden_size, ix.head_dim, False, None),
+                "indexer_k_norm": nn.LayerNorm(ix.head_dim, eps=1e-6),
+                "indexer_w": lin(c.hidden_size, ix.heads, False, None)})
         if c.attention == "eva":
             self._block_layers["eva_phi"] = nn.HeadVectors(c.num_heads, c.head_dim)
             self._block_layers["eva_mu"] = nn.HeadVectors(c.num_heads, c.head_dim)
@@ -753,6 +788,26 @@ class TransformerLM:
                     "ring attention, block diffusion, packed documents (its "
                     "windows are the row's), attention gate, QK-norm, post-norm "
                     "or bias on its projections (linear_bias=False)")
+        if c.indexer is not None and (
+                c.attention != "mha" or not c.causal or c.position != "rope"
+                or self._windows is not None or self._mixed_rope or c.diffusion
+                or c.seq_parallel == "ring" or c.norm_style == "post"
+                or c.parallel_block or c.farskip or c.attn_gate
+                or min(c.indexer.heads, c.indexer.head_dim, c.indexer.topk) < 1
+                or c.indexer.head_dim % 2
+                or (c.remat and c.remat_policy == "alternating")):
+            raise ValueError(
+                "indexer: a selection inside 'mha' attention of a causal rotary "
+                "pre-norm or sandwich decoder: no window, block diffusion, ring "
+                "attention, parallel block, FarSkip, attention gate or "
+                "remat_policy='alternating'")
+        if c.rope_sections is not None and (
+                c.position != "rope" or c.rope_style != "half" or c.rope_dim
+                or c.attention != "mha" or len(c.rope_sections) != 3
+                or sum(c.rope_sections) != c.head_dim // 2):
+            raise ValueError(
+                f"rope_sections {c.rope_sections}: three sections of a plain "
+                f"'half' rope that add up to head_dim / 2 ({c.head_dim // 2})")
         if c.pred_heads < 1 or (c.pred_heads > 1 and (
                 c.tie_embeddings or not c.causal or c.diffusion or c.mtp_layers
                 or c.mlm_head)):
@@ -901,11 +956,28 @@ class TransformerLM:
         """Rotary embedding, possibly PARTIAL (phi applies rope to only the
         first rope_dim of each head, passing the rest through)."""
         c = self.config
+        if positions.ndim == 3:
+            return self._rotate_sections(x, positions)
         rd = c.rope_dim or c.head_dim
         if rd >= c.head_dim:
             return nn.rotary_embedding(x, positions, c.rope_theta, c.rope_style)
         rot = nn.rotary_embedding(x[..., :rd], positions, c.rope_theta, c.rope_style)
         return jnp.concatenate([rot, x[..., rd:]], axis=-1)
+
+    def _rotate_sections(self, x: jax.Array, positions: jax.Array) -> jax.Array:
+        """Rope by sections (``rope_sections``): frequency pair i, ``theta **
+        (-i / half)``, is turned by the position stream of its section.
+        positions [3, B, S]; x [B, S, heads, head]; the pair (i, i + half)."""
+        c = self.config
+        half = c.head_dim // 2
+        stream = np.repeat(np.arange(3), c.rope_sections)           # [half]
+        freqs = c.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        at = jnp.moveaxis(positions.astype(jnp.float32)[stream], 0, -1)  # [B, S, half]
+        angles = (at * freqs)[:, :, None, :]
+        cos, sin = jnp.cos(angles), jnp.sin(angles)
+        x1, x2 = x[..., :half], x[..., half:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
 
     def _rotate_tail(self, x: jax.Array, positions: jax.Array) -> jax.Array:
         """Latent attention's rotary embedding: the LAST ``qk_rope_dim`` of
@@ -953,23 +1025,7 @@ class TransformerLM:
         if isinstance(window, int) and window <= 0:
             window = None
         with jax.named_scope("attn"):
-            with jax.named_scope("qkv"):
-                # saved as projected: QK-norm's backward needs its input
-                q = self._project(block, "q_proj", h)
-                k = self._project(block, "k_proj", h)
-                per_head = c.qk_norm and c.qk_norm_per_head
-                if c.qk_norm and not per_head:
-                    q = self._block_layers["q_norm"](block["q_norm"], q)
-                    k = self._block_layers["k_norm"](block["k_norm"], k)
-                q = q.reshape(B, S, c.num_heads, c.head_dim)
-                k = k.reshape(B, S, c.kv_heads, c.head_dim)
-                if per_head:
-                    q = self._block_layers["q_norm"](block["q_norm"], q)
-                    k = self._block_layers["k_norm"](block["k_norm"], k)
-                v = self._project(block, "v_proj", h).reshape(B, S, c.kv_heads, c.head_dim)
-                if rope:
-                    q = self._rotate(q, positions)
-                    k = self._rotate(k, positions)
+            q, k, v = self._qkv(block, h, positions, rope)
             if c.diffusion:
                 # both copies of the row under one mask; attn_mask holds the
                 # clean copy's documents [B, L]
@@ -983,6 +1039,91 @@ class TransformerLM:
                 out = self._gated(block, h, out)
             with jax.named_scope("out"):
                 return self._project(block, "o_proj", out)
+
+    @scoped("qkv")
+    def _qkv(self, block: Params, h: jax.Array, positions: jax.Array, rope: bool):
+        """``mha``'s projected, normed and turned q [B, S, heads, head] and k,
+        v [B, S, kv heads, head]."""
+        c = self.config
+        B, S, _ = h.shape
+        # saved as projected: QK-norm's backward needs its input
+        q = self._project(block, "q_proj", h)
+        k = self._project(block, "k_proj", h)
+        per_head = c.qk_norm and c.qk_norm_per_head
+        if c.qk_norm and not per_head:
+            q = self._block_layers["q_norm"](block["q_norm"], q)
+            k = self._block_layers["k_norm"](block["k_norm"], k)
+        q = q.reshape(B, S, c.num_heads, c.head_dim)
+        k = k.reshape(B, S, c.kv_heads, c.head_dim)
+        if per_head:
+            q = self._block_layers["q_norm"](block["q_norm"], q)
+            k = self._block_layers["k_norm"](block["k_norm"], k)
+        v = self._project(block, "v_proj", h).reshape(B, S, c.kv_heads, c.head_dim)
+        if rope:
+            q = self._rotate(q, positions)
+            k = self._rotate(k, positions)
+        return q, k, v
+
+    def _attn_selected(self, block: Params, h: jax.Array, positions: jax.Array,
+                       documents: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
+        """``mha`` over a learned selection (``config.indexer``;
+        ``ops/transformer/attention.py`` has the equations) -> (the branch's
+        output, this layer's share of L_I: the rows' KL summed, over rows x
+        L). The indexer reads ``stop_gradient(h)`` and the selection has no
+        gradient, so the language-model loss reaches no indexer leaf; the KL's
+        target is the main attention's own distribution under stop_gradient,
+        so L_I reaches nothing else. Scopes: ``attn/indexer`` (projections and
+        scores), ``attn/select``, ``attn/core_dsa``, ``attn/indexer_kl``."""
+        from ..ops.transformer import attention
+        from ..runtime import topology as topo_mod
+        c, topk = self.config, self.config.indexer.topk
+        B, S, _ = h.shape
+        topo = topo_mod.get_topology() if topo_mod.is_initialized() else None
+        if topo is not None and topo.sequence_parallel_size > 1:
+            raise NotImplementedError(
+                "indexer: a query's selection is over the whole row, which "
+                f"sequence parallelism (sequence={topo.sequence_parallel_size}) divides")
+        if documents is None:
+            documents = jnp.zeros((B, S), jnp.int32)
+        scale = c.attn_scale or c.head_dim ** -0.5
+        with jax.named_scope("attn"):
+            q, k, v = self._qkv(block, h, positions, True)
+            q_idx, k_idx, w, picked = self.selection(block, h, positions, documents)
+            with jax.named_scope("core_dsa"):
+                out, lse = attention.selected_attention(
+                    q, k, v, picked, documents, topk, scale)
+            with jax.named_scope("indexer_kl"):
+                kl = attention.indexer_kl(
+                    q_idx, k_idx, w, *jax.lax.stop_gradient((q, k, lse)), picked,
+                    float(scale)) / (B * S)
+            with jax.named_scope("out"):
+                return self._project(block, "o_proj", out.reshape(
+                    B, S, c.num_heads * c.head_dim)), kl
+
+    def selection(self, block: Params, h: jax.Array, positions: jax.Array,
+                  documents: jax.Array):
+        """A layer's indexer over its normed input ``h`` -> (q_idx [B, S, J,
+        d], k_idx [B, S, d], w [B, S, J] float32 and scaled, the selection as
+        the int8 [B, S, S] operand, kept under the name ``dsa_mask``). No
+        gradient reaches ``h``."""
+        from ..ops.transformer import attention
+        c, ix = self.config, self.config.indexer
+        B, S, _ = h.shape
+        x = jax.lax.stop_gradient(h)
+        with jax.named_scope("indexer"):
+            at = positions[0] if positions.ndim == 3 else positions
+            turned = lambda a: nn.rotary_embedding(a, at, c.rope_theta, "half")
+            q_idx = turned(self._project(block, "indexer_q", x).reshape(
+                B, S, ix.heads, ix.head_dim))
+            k_idx = self._block_layers["indexer_k_norm"](
+                block["indexer_k_norm"], self._project(block, "indexer_k", x))
+            k_idx = turned(k_idx[:, :, None, :])[:, :, 0, :]
+            w = self._block_layers["indexer_w"](block["indexer_w"], x).astype(
+                jnp.float32) * (ix.heads ** -0.5 * ix.head_dim ** -0.5)
+        # (its two scopes are opened a block of queries at a time, inside)
+        picked = checkpoint_name(attention.dsa_select(
+            q_idx, k_idx, w, documents, ix.topk), "dsa_mask")
+        return q_idx, k_idx, w, picked
 
     def _gated(self, block: Params, h: jax.Array, out: jax.Array) -> jax.Array:
         """``out`` under the attention gate: times the sigmoid (float32) of
@@ -1159,11 +1300,17 @@ class TransformerLM:
             return None
         return "dropless" if self._moe.dropless else "capacity"
 
-    def _aux_zero(self) -> jax.Array:
+    def _moe_aux_zero(self) -> jax.Array:
         """The MoE auxiliary-loss accumulator's zero: a scalar (the GShard
         balance loss), or the no-drop path's two router losses."""
         return jnp.zeros((2,) if self.moe_path == "dropless" else (),
                          dtype=jnp.float32)
+
+    def _aux_zero(self):
+        """What the layers' carry starts from beside the stream: the MoE
+        accumulator, and with an indexer the pair (that, L_I so far)."""
+        aux = self._moe_aux_zero()
+        return aux if self.config.indexer is None else (aux, jnp.zeros((), jnp.float32))
 
     @scoped("mlp")
     def _mlp(self, block: Params, h: jax.Array
@@ -1171,7 +1318,7 @@ class TransformerLM:
         """MLP over the PRE-NORMED input h -> (out, aux, the no-drop
         path's rows per expert [experts] or None)."""
         c = self.config
-        aux, rows = self._aux_zero(), None
+        aux, rows = self._moe_aux_zero(), None
         if "moe" in block and self.moe_path == "dropless":
             out, aux, rows = self._moe.dropless_forward(block["moe"], h)
         elif "moe" in block:
@@ -1255,11 +1402,17 @@ class TransformerLM:
             post = ((lambda name, y: y) if c.norm_style != "sandwich"
                     else functools.partial(self._norm_post, block))
             add = self._add_fp32 if c.residual_fp32 else (lambda x, y: x + y)
-            x = add(x, keep * post("post_ln_1", self._attn(
-                block, h1, positions, attn_mask, window, rope)))
+            if c.indexer is not None:
+                attn_out, kl = self._attn_selected(block, h1, positions, attn_mask)
+            else:
+                attn_out = self._attn(block, h1, positions, attn_mask, window, rope)
+            x = add(x, keep * post("post_ln_1", attn_out))
             h2 = self._block_layers["ln_2"](block["ln_2"], x)
             mlp_out, aux, rows = self._mlp(block, h2)
             x = _c(add(x, keep * post("post_ln_2", mlp_out)), ACT_SPEC)
+            if c.indexer is not None:
+                moe_acc, kl_acc = aux_acc
+                return (x, positions, (moe_acc + keep * aux, kl_acc + keep * kl)), rows
         # the scan stacks the no-drop path's rows per expert over the
         # layers ([layers, experts]); every other model's ys stay None
         return (x, positions, aux_acc + keep * aux), rows
@@ -1629,7 +1782,8 @@ class TransformerLM:
               attention_mask: Optional[jax.Array] = None,
               return_hidden: bool = False,
               return_stats: bool = False,
-              remat_budget: Optional[Budget] = None) -> Tuple[jax.Array, ...]:
+              remat_budget: Optional[Budget] = None,
+              position_ids: Optional[jax.Array] = None) -> Tuple[jax.Array, ...]:
         """Return (logits [B,S,V] in fp32, ``[B,S,heads,V]`` with
         ``pred_heads``; moe_aux_loss scalar).
 
@@ -1646,6 +1800,9 @@ class TransformerLM:
         ``remat_budget``: what the device can give the blocks' saved values
         under the default ``remat_policy`` (an engine's reading; ``None``
         saves everything named) and where the decision is written.
+        ``position_ids`` [3, B, S]: the three position streams of
+        ``rope_sections`` (None: a token's index for all three). With an
+        indexer ``moe_aux_loss`` is the pair (that, L_I): `combine_aux` adds both.
         """
         if not return_hidden:
             self._charge_head(remat_budget, input_ids)
@@ -1656,7 +1813,8 @@ class TransformerLM:
                   if self.config.diffusion else None)
         x, aux, stats, _ = self._trunk(
             params, input_ids, layer_mask, token_type_ids, attention_mask,
-            remat_budget, with_mtp=False, noised_ids=noised)
+            remat_budget, with_mtp=False, noised_ids=noised,
+            position_ids=position_ids)
         stats = (stats,) if return_stats else ()
         if return_hidden:
             if self._ln_f is not None:
@@ -1675,7 +1833,8 @@ class TransformerLM:
 
     def _trunk(self, params, input_ids, layer_mask, token_type_ids,
                attention_mask, remat_budget, with_mtp: bool,
-               noised_ids: Optional[jax.Array] = None):
+               noised_ids: Optional[jax.Array] = None,
+               position_ids: Optional[jax.Array] = None):
         """Embedding and every block: (the last block's output stream,
         the accumulated MoE aux, the step's statistics, the prediction
         module's output stream or None). ``with_mtp``: run the module (a
@@ -1699,6 +1858,11 @@ class TransformerLM:
             positions = positions % L     # one position for both copies
         else:
             x, positions = self.embed(params, input_ids, token_type_ids)
+        if position_ids is not None:
+            if c.rope_sections is None or position_ids.shape != (3,) + input_ids.shape:
+                raise ValueError("position_ids [3, B, S] are rope_sections' three "
+                                 f"streams; got {position_ids.shape}")
+            positions = position_ids
 
         if c.document_separator is not None:
             if attention_mask is not None:
@@ -1803,6 +1967,18 @@ class TransformerLM:
         stats = self._stats_of(rows)
         if c.document_separator is not None:
             stats = {**stats, **self._attn_tile_stats(documents)}
+        if c.indexer is not None:
+            # selected over visible pairs, from the documents alone: a query
+            # with v visible keys picks min(v, topk) of them in every layer
+            docs = (documents if c.document_separator is not None
+                    else jnp.zeros(input_ids.shape, jnp.int32))
+            at = jnp.arange(docs.shape[1], dtype=jnp.int32)[None]
+            starts = jnp.pad(docs[:, 1:] != docs[:, :-1], ((0, 0), (1, 0)),
+                             constant_values=True)
+            first = jax.lax.cummax(jnp.where(starts, at, 0), axis=1)
+            visible = (at - first + 1).astype(jnp.float32)
+            stats = {**stats, "attn_selected_share": jnp.sum(
+                jnp.minimum(visible, c.indexer.topk)) / jnp.sum(visible)}
         return x, aux, stats, mtp_x
 
     def _documents(self, input_ids: jax.Array) -> jax.Array:
@@ -1823,6 +1999,8 @@ class TransformerLM:
             return ()
         if c.diffusion:
             return (("blockdiff", 0),)
+        if c.indexer is not None:
+            return (("dsa", 0),)
         windows = sorted({w for w, _ in self._kinds}, reverse=True)
         several = sum(map(bool, windows)) > 1
         return tuple(("full" if not w else f"window_{w}" if several else "window", w)
@@ -1866,6 +2044,8 @@ class TransformerLM:
             mask = dict(blockdiff=c.block_length)
         elif c.attention == "eva":
             mask = dict(eva=(c.eva_window, c.eva_chunk))
+        elif c.indexer is not None:
+            mask = dict(selected=c.indexer.topk)
         return attention.plan(
             (batch, seq * self.rows_per_token, c.num_heads, c.head_dim),
             (batch, seq, c.kv_heads, c.head_dim), jax.default_backend(),
@@ -1893,6 +2073,11 @@ class TransformerLM:
             attn["eva"] = {"window": c.eva_window, "chunk": c.eva_chunk,
                            "summaries_a_row": None, "pred_heads": c.pred_heads,
                            "route": None, "dq_local": None, "dq_far": None}
+        if c.indexer is not None:
+            from ..ops.transformer.attention import SELECT_THRESHOLD
+            attn["dsa"] = {"topk": c.indexer.topk, "indexer_heads": c.indexer.heads,
+                           "indexer_head_dim": c.indexer.head_dim, "route": None,
+                           "select": SELECT_THRESHOLD, "dq": None}
         diffusion = {"block_length": c.block_length, "rows_per_token": self.rows_per_token,
                      "route": None, "dq": None} if c.diffusion else None
         if seq is None:
@@ -1904,6 +2089,8 @@ class TransformerLM:
             attn["eva"].update(
                 summaries_a_row=seq // c.eva_chunk, route=plans[0].route,
                 dq_local=plans[0].dq("eva_local"), dq_far=plans[0].dq("eva_far"))
+        elif c.indexer is not None:
+            attn["dsa"].update(route=plans[0].route, dq=plans[0].dq("dsa"))
         else:
             # (sliding layers of several widths: the mode they share, else both)
             under = sorted({plans[w].dq("flash") or "" for w in windows})
@@ -1963,7 +2150,8 @@ class TransformerLM:
         out beside the loss: the no-drop path's rows per expert, the masked
         share of block diffusion, packed documents' count of tiles."""
         return (self.moe_path == "dropless" or self.config.diffusion
-                or self.config.document_separator is not None)
+                or self.config.document_separator is not None
+                or self.config.indexer is not None)
 
     @functools.cached_property
     def scan_plan(self) -> Tuple[Tuple[Any, ...], int, Tuple[Any, ...]]:
@@ -2054,6 +2242,10 @@ class TransformerLM:
         them, as its report has it). Reads ``self.config`` alone
         (``PipelineModule`` borrows it)."""
         moe = self.config.moe
+        if self.config.indexer is not None:
+            # the pair (the MoE accumulator, L_I): L_I counts once (coefficient 1)
+            aux, kl = aux
+            loss = loss + kl
         if moe is None:
             return loss
         if moe.router == "sigmoid_bias":
@@ -2075,9 +2267,12 @@ class TransformerLM:
         batch: input_ids [B,S], optional labels/loss_mask/token_type_ids/
         attention_mask. ``remat_budget`` as in :meth:`apply`. Calls
         ``self.apply``, ``self.derive_labels`` and ``self.combine_aux``
-        alone where there is no prediction module and no noise
-        (``PipelineModule`` borrows it)."""
-        if self.config.mtp_layers or self.config.diffusion:
+        alone where there is no prediction module, no noise and no rope by
+        sections (``PipelineModule`` borrows it)."""
+        c = self.config
+        if c.mtp_layers or c.diffusion or c.rope_sections is not None:
+            # (three position streams are a batch's, which `apply` as
+            # ``PipelineModule`` has it does not take)
             return self.loss_and_stats(params, batch, remat_budget)[0]
         labels = self.derive_labels(batch)
         logits, aux = self.apply(params, batch["input_ids"],
@@ -2105,10 +2300,12 @@ class TransformerLM:
         x, aux, stats, mtp_x = self._trunk(
             params, batch["input_ids"], batch.get("layer_mask"),
             batch.get("token_type_ids"), batch.get("attention_mask"),
-            remat_budget, with_mtp=True)
+            remat_budget, with_mtp=True, position_ids=batch.get("position_ids"))
         if mtp_x is None:
-            return self.combine_aux(self.head_loss(
-                params, x, labels, extra_mask=mask), aux), stats
+            loss = self.head_loss(params, x, labels, extra_mask=mask)
+            if c.indexer is not None:       # the two terms of L_LM + L_I, reported
+                stats = {**stats, "attn_lm_loss": loss, "attn_indexer_kl": aux[1]}
+            return self.combine_aux(loss, aux), stats
         later = lambda a, fill: jnp.pad(a[:, 1:], ((0, 0), (0, 1)),
                                         constant_values=fill)
 

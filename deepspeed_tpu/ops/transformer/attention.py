@@ -214,16 +214,19 @@ def kernel_is_default(q_shape, k_shape, backend: str) -> bool:
 
 def choose_route(q_shape, k_shape, backend: str, mode: str,
                  blockdiff: Optional[int] = None,
-                 eva: Optional[Tuple[int, int]] = None) -> str:
-    """The route of `flash_attention`, of `blockdiff_attention` and of
-    `eva_attention`: ``"kernel"`` (the in-repo blockwise pair), ``"xla"`` (one
-    shot) or ``"xla_chunked"``: that of the call's `plan`."""
-    return plan(q_shape, k_shape, backend, mode, blockdiff=blockdiff, eva=eva).route
+                 eva: Optional[Tuple[int, int]] = None,
+                 selected: Optional[int] = None) -> str:
+    """The route of `flash_attention`, of `blockdiff_attention`, of
+    `eva_attention` and of `selected_attention`: ``"kernel"`` (the in-repo
+    blockwise pair), ``"xla"`` (one shot) or ``"xla_chunked"``: that of the
+    call's `plan`."""
+    return plan(q_shape, k_shape, backend, mode, blockdiff=blockdiff, eva=eva,
+                selected=selected).route
 
 
 class Launch(NamedTuple):
     """One launch of the flash pair: its ``tag`` (``"flash"``, ``"blockdiff"``,
-    ``"eva_local"``, ``"eva_far"``), a batch row's queries and keys, the tiles
+    ``"eva_local"``, ``"eva_far"``, ``"dsa"``), a batch row's queries and keys, the tiles
     (``pallas_flash.launch_tiles``), the static window the grids are cut to."""
     tag: str
     sq: int
@@ -250,8 +253,9 @@ class Plan(NamedTuple):
 
 def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
          causal: bool = True, window=None, blockdiff: Optional[int] = None,
-         eva: Optional[Tuple[int, int]] = None) -> Plan:
-    """THE decision of the three entry points, a pure function of the two
+         eva: Optional[Tuple[int, int]] = None,
+         selected: Optional[int] = None) -> Plan:
+    """THE decision of the four entry points, a pure function of the two
     shapes, the platform, `attn_mode`'s value, the operands' size and the
     mask, which says what the kernel route would launch:
 
@@ -276,6 +280,8 @@ def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
     rows, launches = sq, ()
     if blockdiff is not None:
         launches = [("blockdiff", sq, sk, dict(blockdiff=blockdiff))]
+    elif selected is not None:
+        launches = [("dsa", sq, sk, dict(selected=True))] if sq == sk else []
     elif eva is not None:
         span, chunk = eva
         windows, rows = -(-sq // span), min(sq, span)
@@ -573,6 +579,252 @@ def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         q, kbar, vbar, causal=True, scale=scale,
         summaries=(window, window // chunk), tag=far[0].tag)
     return _pf.merge_partials(o, lse, far_o, far_lse)[0]
+
+
+# ---------------------------------------------------------------------------
+# a learned selection of keys (DeepSeek Sparse Attention's lightning indexer)
+# ---------------------------------------------------------------------------
+# Beside the main projections a layer holds an INDEXER: J small query heads
+# and ONE key head of d dims, and a weight a query head. With ``visible(t, s)``
+# = ``s <= t`` inside t's packed document:
+#   I[t, s] = sum_j w[t, j] x ReLU(qI[t, j] . kI[s]), float32, for visible s;
+#   S_t     = the k visible s of largest I[t, s], ties to the lower s (every
+#             visible s where there are k or fewer): `select_topk`, EXACT;
+#   o[t, h] = sum over s in S_t of softmax over S_t of (q[t, h] . k[s, g(h)]
+#             x scale) v[s, g(h)]: one selection for every head;
+#   L_I     = sum_t KL(p_t || softmax over S_t of I[t, .]), p_t the mean over
+#             the heads of the main attention's own probabilities: `indexer_kl`.
+# The selection is data: it has no gradient, and is handed on as an int8
+# ``[B, L, L]`` array (1 = picked), which already holds the causal and
+# same-document rule.
+
+#: queries whose scores ``[rows, J, L]`` float32 are held at once while the
+#: selection is made (at 16,384 keys x 16 heads: 0.5 GB), and while the KL's
+#: target holds every main head's probabilities beside them (32 heads: 0.27 GB)
+SELECT_QUERY_BLOCK = 512
+KL_QUERY_BLOCK = 128
+
+
+def _query_blocks(a: jax.Array, n: int) -> jax.Array:
+    """[B, L, ...] -> [L / n, B, n, ...]: blocks of ``n`` queries, leading."""
+    return jnp.moveaxis(a.reshape((a.shape[0], a.shape[1] // n, n) + a.shape[2:]), 1, 0)
+
+
+def index_scores(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array) -> jax.Array:
+    """``I[b, t, s] = sum_j w[b, t, j] x ReLU(q_idx[b, t, j] . k_idx[b, s])``,
+    float32. q_idx [B, T, J, d], k_idx [B, S, d], w [B, T, J] (already scaled).
+    The products of the operands' dtype are summed in float32; the sum over
+    the heads is a float32 multiply-add (no matmul rounds ``w``)."""
+    dots = jnp.einsum("btjd,bsd->btjs", q_idx, k_idx,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots) * w.astype(jnp.float32)[..., None], axis=2)
+
+
+def causal_in_document(q_pos, documents_q, documents_k):
+    """``visible(t, s)`` [B, T, S]: key s at or before query t (``q_pos`` [T],
+    the keys from the row's start) in t's packed document (ids [B, T], [B, S])."""
+    k_pos = jnp.arange(documents_k.shape[1])
+    return ((k_pos[None, None, :] <= q_pos[None, :, None])
+            & (documents_q[:, :, None] == documents_k[:, None, :]))
+
+
+def _sortable(x: jax.Array) -> jax.Array:
+    """float32 -> uint32 in the floats' own order (-0.0 as 0.0)."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x).astype(jnp.float32),
+                                        jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000))
+
+
+def _kth_by_bisection(keys: jax.Array, k: int) -> jax.Array:
+    """The k-th largest of each row of uint32 ``keys`` (0 where all are
+    wanted): the largest T with ``count(keys >= T) >= k``, found a bit at a
+    time from the top, 32 counting passes over the row, no sort."""
+    def bit(i, t):
+        trial = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(keys >= trial[..., None], axis=-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, trial, t)
+    return jax.lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
+def _kth_by_top_k(keys: jax.Array, k: int) -> jax.Array:
+    """The same by ``lax.top_k`` over the keys as ordered int32."""
+    ordered = jax.lax.bitcast_convert_type(keys ^ jnp.uint32(0x80000000), jnp.int32)
+    kth = jax.lax.top_k(ordered, k)[0][..., -1]
+    return jax.lax.bitcast_convert_type(kth, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+#: how `select_topk` finds a row's threshold, each exact; the chip chose
+#: (docs/KERNELS.md, PR 48, has the readings)
+THRESHOLDS = {"bisection": _kth_by_bisection, "top_k": _kth_by_top_k}
+SELECT_THRESHOLD = "bisection"
+
+
+def select_topk(scores: jax.Array, visible: jax.Array, k: int,
+                how: Optional[str] = None) -> jax.Array:
+    """THE definition of a query's selection: bool ``[..., T, S]``, True for the
+    ``k`` visible keys of largest ``scores`` (float32 ``[..., T, S]``), a tie
+    at the threshold to the lower s; every visible key where there are ``k`` or
+    fewer. Exact in every form of ``how`` (`THRESHOLDS`; None: the default):
+    the k-th largest score is found, what lies above it is taken, and of what
+    equals it the first ``k - above`` (a prefix count, run only where some row
+    has more equals than places)."""
+    S = scores.shape[-1]
+    if k >= S:
+        return visible
+    # an invisible key is 0, under every float's key
+    keys = jnp.where(visible, _sortable(scores), jnp.uint32(0))
+    kth = THRESHOLDS[how or SELECT_THRESHOLD](keys, k)[..., None]
+    above = keys > kth
+    equal = (keys == kth) & visible
+    places = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    crowded = jnp.any(jnp.sum(equal, axis=-1, keepdims=True, dtype=jnp.int32) > places)
+    first = lambda: equal & (jnp.cumsum(equal, axis=-1, dtype=jnp.int32) <= places)
+    return above | jax.lax.cond(crowded, first, lambda: equal)
+
+
+def dsa_select(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array,
+               documents: jax.Array, k: int) -> jax.Array:
+    """Every query's selection as the operand the attention reads: int8 ``[B,
+    L, L]``, 1 where ``s`` is in ``S_t``. The scores (scope ``indexer``) and
+    the selection (scope ``select``) a block of `SELECT_QUERY_BLOCK` queries at
+    a time: ``[L, J, L]`` is never held. No gradient passes."""
+    B, L = documents.shape
+    q_idx, k_idx, w = jax.lax.stop_gradient((q_idx, k_idx, w))
+    n = SELECT_QUERY_BLOCK if L % SELECT_QUERY_BLOCK == 0 else L
+
+    def block(xs):
+        qb, wb, docs_q, first = xs
+        # (``attn`` again: a loop's body stands between the caller's scope and
+        # these, and a reader of ``attn/<name>`` wants the two side by side)
+        with jax.named_scope("attn"), jax.named_scope("indexer"):
+            scores = index_scores(qb, k_idx, wb)
+        with jax.named_scope("attn"), jax.named_scope("select"):
+            seen = causal_in_document(first + jnp.arange(n), docs_q, documents)
+            return select_topk(scores, seen, k).astype(jnp.int8)
+
+    picked = jax.lax.map(block, (_query_blocks(q_idx, n), _query_blocks(w, n),
+                                 _query_blocks(documents, n), jnp.arange(0, L, n)))
+    return jnp.moveaxis(picked, 0, 1).reshape(B, L, L)
+
+
+def _xla_selected_attention(q, k, v, selected, scale):
+    """-> (o [B, L, H, D], lse [B, H, L] float32): the softmax over the picked
+    keys, densely."""
+    B, L, H, D = q.shape
+    kvH = k.shape[2]
+    qt = q.transpose(0, 2, 1, 3).reshape(B, kvH, H // kvH, L, D)
+    logits = jnp.einsum("bhgqd,bkhd->bhgqk", qt, k,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where((selected != 0)[:, None, None], logits, -1e30)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    probs = jnp.exp(logits - lse[..., None]).astype(q.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bhgqd", probs, v)
+    return (out.reshape(B, H, L, D).transpose(0, 2, 1, 3), lse.reshape(B, H, L))
+
+
+def selected_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                       selected: jax.Array, documents: jax.Array, topk: int,
+                       scale: Optional[float] = None
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """Attention over each query's selection (above). q [B, L, H, D], k, v
+    [B, L, kvH, D]; ``selected`` int8 [B, L, L] from `dsa_select`;
+    ``documents`` [B, L] int. -> (o [B, L, H, D], lse [B, H, L] float32: the
+    log-sum-exp over the picked keys, which `indexer_kl` reads).
+
+    On the kernel route ONE launch of the flash pair whose tiles read their
+    block of ``selected`` (``flash_fwd_dsa`` / ``flash_bwd_dsa``): dense tiles
+    under the mask, skipped by position and documents as a full layer's; no
+    key is gathered. Elsewhere the masked softmax in XLA, its queries in chunks
+    from `XLA_CHUNK_MIN_SEQ` up on a device."""
+    B, L, H, D = q.shape
+    scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    made = plan(q.shape, k.shape, jax.default_backend(), attn_mode(),
+                q.dtype.itemsize, selected=topk)
+    _log_path_once(f"dsa {made.route}")
+    if made.route == "kernel":
+        from . import pallas_flash as _pf
+        return _pf.flash_attention_with_lse(
+            q, k, v, causal=True, scale=scale, segment_ids=documents.astype(jnp.int32),
+            selected=selected)
+    chunk = 1024 if made.route == "xla_chunked" and L % 1024 == 0 else L
+    parts = [_xla_selected_attention(q[:, lo:lo + chunk], k, v,
+                                     selected[:, lo:lo + chunk], scale)
+             for lo in range(0, L, chunk)]
+    return (jnp.concatenate([o for o, _ in parts], axis=1),
+            jnp.concatenate([lse for _, lse in parts], axis=2))
+
+
+def _kl_rows(q_idx, w, q, lse, selected, k_idx, k, scale):
+    """``sum_t KL(p_t || softmax over S_t of I[t, .])`` over a block of queries
+    (q_idx [B, n, J, d], w [B, n, J], q [B, n, H, D], lse [B, H, n], selected
+    [B, n, L]) against all keys: p_t the mean over the heads of ``exp(q . k x
+    scale - lse)`` on the picked keys. float32."""
+    B, n, H, D = q.shape
+    kvH = k.shape[2]
+    picked = selected != 0
+    scores = jnp.where(picked, index_scores(q_idx, k_idx, w), -1e30)
+    log_r = scores - jax.nn.logsumexp(scores, axis=-1, keepdims=True)
+    qt = q.transpose(0, 2, 1, 3).reshape(B, kvH, H // kvH, n, D)
+    logits = jnp.einsum("bhgqd,bkhd->bhgqk", qt, k,
+                        preferred_element_type=jnp.float32) * scale
+    probs = jnp.exp(logits - lse.reshape(B, kvH, H // kvH, n)[..., None])
+    p = jnp.where(picked, jnp.mean(probs, axis=(1, 2)), 0.0)
+    return jnp.sum(jnp.where(p > 0, p * (jnp.log(jnp.where(p > 0, p, 1.0)) - log_r), 0.0))
+
+
+def _kl_blocks(L: int) -> int:
+    return KL_QUERY_BLOCK if L % KL_QUERY_BLOCK == 0 else L
+
+
+def _kl_operands(q_idx, w, q, lse, selected, n):
+    return (_query_blocks(q_idx, n), _query_blocks(w, n), _query_blocks(q, n),
+            jnp.moveaxis(lse.reshape(lse.shape[:2] + (-1, n)), 2, 0),
+            _query_blocks(selected, n))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def indexer_kl(q_idx, k_idx, w, q, k, lse, selected, scale: float):
+    """The indexer's objective for one layer, ``sum over b, t of KL(p_t ||
+    softmax over S_t of I[t, .])`` (above), float32, a block of
+    `KL_QUERY_BLOCK` queries at a time. Differentiable in ``q_idx``, ``k_idx``
+    and ``w`` alone: the target (``q``, ``k``, ``lse`` of the main attention)
+    is data. The forward of a differentiated call takes the three gradients in
+    the same pass over the blocks (every head's scores are made once a step,
+    not once more in the backward) and keeps them under the names
+    ``indexer_kl_dq`` / ``_dk`` / ``_dw``; the backward scales them."""
+    n = _kl_blocks(selected.shape[1])
+    each = lambda xs: _kl_rows(*xs, k_idx, k, scale)
+    return jnp.sum(jax.lax.map(each, _kl_operands(q_idx, w, q, lse, selected, n)))
+
+
+def _indexer_kl_fwd(q_idx, k_idx, w, q, k, lse, selected, scale):
+    B, L = selected.shape[:2]
+    n = _kl_blocks(L)
+
+    def each(carry, xs):
+        qb, wb, *target = xs
+        value, (dq, dw, dk) = jax.value_and_grad(
+            lambda qb, wb, kb: _kl_rows(qb, wb, *target, kb, k, scale),
+            argnums=(0, 1, 2))(qb, wb, k_idx)
+        total, dk_sum = carry
+        return (total + value, dk_sum + dk.astype(jnp.float32)), (dq, dw)
+
+    (total, dk), (dq, dw) = jax.lax.scan(
+        each, (jnp.zeros((), jnp.float32), jnp.zeros(k_idx.shape, jnp.float32)),
+        _kl_operands(q_idx, w, q, lse, selected, n))
+    whole = lambda a: jnp.moveaxis(a, 0, 1).reshape((B, L) + a.shape[3:])
+    # (a name each: a policy reckons a name's bytes from ONE value)
+    grads = tuple(checkpoint_name(g, "indexer_kl_" + name) for name, g in (
+        ("dq", whole(dq)), ("dk", dk.astype(k_idx.dtype)), ("dw", whole(dw))))
+    return total, grads
+
+
+def _indexer_kl_bwd(scale, grads, ct):
+    dq, dk, dw = (g * ct.astype(g.dtype) for g in grads)
+    return dq, dk, dw, None, None, None, None
+
+
+indexer_kl.defvjp(_indexer_kl_fwd, _indexer_kl_bwd)
 
 
 @functools.lru_cache(None)

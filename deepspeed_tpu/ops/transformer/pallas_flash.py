@@ -77,6 +77,18 @@ exact keys are a plain causal launch over the row folded to one batch row a
 window. ``tag`` names a launch ``flash_fwd_<tag>`` / ``flash_bwd_<tag>`` and
 its two residuals ``attn_o_<tag>`` / ``attn_lse_<tag>``.
 
+A learned selection (``selected=``; ``attention.selected_attention``): a
+causal launch over as many keys as queries with one operand more, int8
+``[batch, Sq, Sk]``, 1 where the query's selection holds the key (one selection
+for all heads): the first mask here that is data and not a rule of positions
+and ids. A tile reads its block of it (the backward the transposed operand's,
+which ``_flash_bwd`` makes) and ANDs it with the causal and same-document
+compare in ``_tile_logits``; tiles are skipped by position and documents as
+without it, and a tile that runs is a full tile. Tiles and ``dq_mode`` are a
+full causal layer's; the launches are named ``flash_fwd_dsa`` /
+``flash_bwd_dsa``, their residuals ``attn_o_dsa`` / ``attn_lse_dsa``. It
+composes with segment ids and grouped heads, and with nothing else.
+
 The table of documents (a launch with segment ids; none is built, and no
 operand added, without): ``_prepare`` reduces the ids once to each block's
 LOWEST and HIGHEST id (``block_ranges``: for the forward's tiles and for the
@@ -175,6 +187,11 @@ class FlashConfig:
     # residuals, for whoever reads a trace or lists kept names; one of
     # ``TAGS``, or None: by the mask
     tag: Optional[str] = None
+    # a learned selection of keys (``attention.selected_attention``): the
+    # launch has one operand more, int8 ``[batch, Sq, Sk]`` (the backward's
+    # transposed), 1 where the query's selection holds the key; a tile reads
+    # its block of it beside the causal and same-document rule
+    selected: bool = False
 
 
 def _lanes(x: jax.Array, n: int) -> jax.Array:
@@ -378,7 +395,7 @@ def _for_visible_tile(cfg: FlashConfig, tile: Tile, i, j, info_ref, docs,
 
 def _tile_logits(cfg: FlashConfig, tile: Tile, q, k, i, j, info_ref,
                  slopes_ref, head_idx, seg_col, seg_row, *,
-                 positional: bool, transposed: bool = False):
+                 positional: bool, transposed: bool = False, sel=None):
     """Masked, scaled fp32 logits for one tile — ONE definition shared by
     the forward and the backward kernel so the recomputed tiles cannot
     diverge from the forward's. ``transposed`` gives S^T = K Q^T
@@ -387,7 +404,9 @@ def _tile_logits(cfg: FlashConfig, tile: Tile, q, k, i, j, info_ref,
     ``seg_col`` is the lane-replicated ``[rows, 128]`` segment ids of the
     tile's row axis, ``seg_row`` the ``[8, cols]`` ids of its column axis.
     ``positional`` False leaves out the causal/window mask (the caller has
-    shown the tile to be wholly visible)."""
+    shown the tile to be wholly visible). ``sel``: the tile's block of a
+    selection's operand (``FlashConfig.selected``), in the tile's own
+    orientation: data, so it is compared in every tile that runs."""
     lhs, rhs = (k, q) if transposed else (q, k)
     s = lax.dot_general(lhs, rhs, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
@@ -398,6 +417,9 @@ def _tile_logits(cfg: FlashConfig, tile: Tile, q, k, i, j, info_ref,
     mask = None
     if cfg.use_seg:
         mask = _lanes(seg_col, s.shape[1]) == seg_row[:1, :]
+    if sel is not None:
+        picked = sel.astype(jnp.int32) != 0
+        mask = picked if mask is None else mask & picked
     if cfg.use_alibi or (cfg.causal and positional):
         q_pos = (lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
                  + (i * bq + info_ref[0]))
@@ -454,7 +476,7 @@ def _fwd_kernel(*refs, cfg: FlashConfig, G: int, nk: int, head_dim: int,
     then q, k, v, the q and k segment ids, the two outputs and the scratch.
     ``nk``: the k-steps of the grid; ``blocks``: the launch's (q-blocks,
     k-blocks)."""
-    prefetch, (q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref,
+    prefetch, (q_ref, k_ref, v_ref, qseg_ref, kseg_ref, sel_ref, o_ref, lse_ref,
                m_scr, l_scr, acc_scr) = _split_prefetch(cfg, refs)
     info, slopes = prefetch[:2]
     b, g = pl.program_id(0), pl.program_id(1)
@@ -480,7 +502,8 @@ def _fwd_kernel(*refs, cfg: FlashConfig, G: int, nk: int, head_dim: int,
         kseg = kseg_ref[0] if cfg.use_seg else None
         s = _tile_logits(cfg, tile, q, k, i, j, info, slopes,
                          _head_index(cfg, b, g, G), qseg, kseg,
-                         positional=positional)
+                         positional=positional,
+                         sel=sel_ref[0] if cfg.selected else None)
         m_prev = m_scr[...]
         l_prev = l_scr[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -509,9 +532,11 @@ def _fwd_kernel(*refs, cfg: FlashConfig, G: int, nk: int, head_dim: int,
         lse_ref[0, 0] = lse.T[:1]
 
 
-def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info):
+def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
+              sel=None):
     """-> o [BK, G, Sq, D], lse [BK, G, 1, Sq] (fp32 rows). ``table``: the
-    forward tiles' :func:`block_ranges` (None: a launch without ids)."""
+    forward tiles' :func:`block_ranges` (None: a launch without ids); ``sel``:
+    a selection's operand ``[B, Sq, Sk]`` (``FlashConfig.selected``)."""
     BK, G, Sq, D = q.shape
     Sk = k.shape[1]
     tile = bq, bk = cfg.tiles.fwd
@@ -554,6 +579,9 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info):
         in_specs.append(pl.BlockSpec((1, NUM_SUBLANES, bk), kseg_idx))
     else:
         in_specs += [None, None]
+    in_specs.append(None if sel is None else pl.BlockSpec(
+        (1, bq, bk), lambda b, g, i, j, *prefetch: (
+            b // kvH, i, k_blk(b, i, j, prefetch))))
 
     out_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, g, i, j, *_: (b, g, i, 0)),
@@ -586,9 +614,10 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info):
         # by it; literal, so that a test can list every kernel's names)
         name=("flash_fwd_eva_local" if cfg.tag == "eva_local"
               else "flash_fwd_eva_far" if cfg.tag == "eva_far"
+              else "flash_fwd_dsa" if cfg.selected
               else "flash_fwd_blockdiff" if cfg.blockdiff is not None
               else "flash_fwd" if cfg.window is None else "flash_fwd_window"),
-    )(*prefetch, q, k, v, qseg_c, kseg_r)
+    )(*prefetch, q, k, v, qseg_c, kseg_r, sel)
 
 
 # ---------------------------------------------------------------------------
@@ -642,14 +671,15 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
     next read of ANY tile waits for it, so a tile is never read before an
     earlier pair's sum has landed); a pair that is skipped touches nothing.
     ``refs``: the scalar-prefetch operands ``(info, slopes[, table])``, then
-    q, k, v, the k and q segment ids, do, lse, di, ``in_place`` the zeros dq
-    starts from (aliased to it), the three outputs and the scratch.
+    q, k, v, the k and q segment ids, a selection's operand (transposed: ``[B,
+    Sk, Sq]``), do, lse, di, ``in_place`` the zeros dq starts from (aliased
+    to it), the three outputs and the scratch.
     ``steps``: the q-steps of the grid (every q-block, or under a static
     window the q-blocks one k-block reaches); ``blocks``: the launch's
     (q-blocks, k-blocks)."""
     prefetch, refs = _split_prefetch(cfg, refs)
-    (q_ref, k_ref, v_ref, kseg_ref, qseg_ref, do_ref, lse_ref, di_ref
-     ), refs = refs[:8], refs[8:]
+    (q_ref, k_ref, v_ref, kseg_ref, qseg_ref, sel_ref, do_ref, lse_ref, di_ref
+     ), refs = refs[:9], refs[9:]
     if in_place:
         _, dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, dq_scr, dq_sem, dq_sent = refs
     else:
@@ -712,7 +742,8 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
         qseg = qseg_ref[0] if cfg.use_seg else None
         st = _tile_logits(cfg, tile, q, k, i, j, info, slopes,
                           _head_index(cfg, b, g, G), kseg, qseg,
-                          positional=positional, transposed=True)
+                          positional=positional, transposed=True,
+                          sel=sel_ref[0] if cfg.selected else None)
         lse = lse_ref[0, 0]      # [1, bq]
         # rows whose LSE is the MASK_VALUE sentinel (no unmasked key
         # anywhere) contribute exactly 0
@@ -750,9 +781,10 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
 
 
 def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
-              o, lse, do, dlse):
+              o, lse, do, dlse, sel_t=None):
     """``table``: the backward tiles' :func:`block_ranges` (None: a launch
-    without ids). One launch whatever the shape. Where dq is added to in
+    without ids); ``sel_t``: a selection's operand transposed, ``[B, Sk, Sq]``.
+    One launch whatever the shape. Where dq is added to in
     place (:func:`dq_mode`) it accumulates across the k-block axis, which is
     then ``"arbitrary"``; the folded-row axis stays ``"parallel"`` (a chip
     with two cores may split it: a row's dq tiles and its writes in flight
@@ -817,6 +849,9 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
                 lambda b, j, g, i, *prefetch: (
                     b // kvH, 0, q_blk(b, i, j, prefetch))),
         ]
+    sel_spec = None if sel_t is None else pl.BlockSpec(
+        (1, bk, bq), lambda b, j, g, i, *prefetch: (
+            b // kvH, j, q_blk(b, i, j, prefetch)))
     prefetch = (info, slopes) + (() if table is None else (table.reshape(-1),))
     if in_place:
         # dq starts at zero and is the launch's own to add to, wherever
@@ -828,7 +863,8 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
         dq_scratch = [pltpu.VMEM((bq, dq_shape[3]), jnp.float32),
                       pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]
         # (the zeros follow the operands a launch has: no ids, no id operands)
-        aliases = {len(prefetch) + (8 if cfg.use_seg else 6): 0}
+        aliases = {len(prefetch) + (8 if cfg.use_seg else 6)
+                   + (sel_t is not None): 0}
     else:
         # a pair's own block, whatever its step fetched: partial ``j`` of its
         # q-block, or under a static window ``j`` less the first k-block the
@@ -852,6 +888,7 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
                 pl.BlockSpec((1, bk, D), kv_idx),
                 pl.BlockSpec((1, bk, D), kv_idx),
                 *seg_specs,
+                sel_spec,
                 pl.BlockSpec((1, 1, bq, D), q_idx),
                 pl.BlockSpec((1, 1, 1, bq), q_row_idx),
                 pl.BlockSpec((1, 1, 1, bq), q_row_idx),
@@ -875,9 +912,10 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
         interpret=cfg.interpret,
         name=("flash_bwd_eva_local" if cfg.tag == "eva_local"
               else "flash_bwd_eva_far" if cfg.tag == "eva_far"
+              else "flash_bwd_dsa" if cfg.selected
               else "flash_bwd_blockdiff" if cfg.blockdiff is not None
               else "flash_bwd" if W is None else "flash_bwd_window"),
-    )(*prefetch, q, k, v, kseg_c, qseg_r, do, lse, di, *zeros)
+    )(*prefetch, q, k, v, kseg_c, qseg_r, sel_t, do, lse, di, *zeros)
     if in_place:
         return dq[..., :D].astype(q.dtype), dk, dv
     if W is not None and met > 1:
@@ -896,33 +934,35 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _flash(cfg: FlashConfig, q, k, v, segs, slopes, info):
+def _flash(cfg: FlashConfig, q, k, v, segs, slopes, info, sel=None):
     """``segs`` = (q ids as columns, k ids as rows, the forward tiles' table
     of documents, k ids as columns, q ids as rows, the backward tiles'
     table) or six Nones: the forward reads the first three, the backward
-    (transposed tiles) the others."""
-    return _fwd_call(cfg, q, k, v, *segs[:3], slopes, info)
+    (transposed tiles) the others. ``sel``: a selection's operand ``[B, Sq,
+    Sk]`` int8 (``FlashConfig.selected``; the backward transposes it)."""
+    return _fwd_call(cfg, q, k, v, *segs[:3], slopes, info, sel)
 
 
-def _flash_fwd(cfg, q, k, v, segs, slopes, info):
-    o, lse = _fwd_call(cfg, q, k, v, *segs[:3], slopes, info)
+def _flash_fwd(cfg, q, k, v, segs, slopes, info, sel=None):
+    o, lse = _fwd_call(cfg, q, k, v, *segs[:3], slopes, info, sel)
     # named HERE so that the residuals are the values a checkpoint policy
     # saves: with both kept, a rematerialised block's backward launches no
     # ``flash_fwd`` (models/transformer.py ``remat_policy``); either costs
     # the whole kernel to make again
     # (a tagged launch's carry the tag: its caller decides whether the order
     # of kept names lists them)
-    tag = "" if cfg.tag is None else "_" + cfg.tag
+    tag = "_dsa" if cfg.selected else "" if cfg.tag is None else "_" + cfg.tag
     o, lse = checkpoint_name(o, "attn_o" + tag), checkpoint_name(lse, "attn_lse" + tag)
-    return (o, lse), (q, k, v, segs, slopes, info, o, lse)
+    return (o, lse), (q, k, v, segs, slopes, info, sel, o, lse)
 
 
 def _flash_bwd(cfg, res, cts):
-    q, k, v, segs, slopes, info, o, lse = res
+    q, k, v, segs, slopes, info, sel, o, lse = res
     do, dlse = cts  # a discarded LSE output arrives as a zero array
     dq, dk, dv = _bwd_call(cfg, q, k, v, *segs[3:], slopes, info,
-                           o, lse, do, dlse)
-    return dq, dk, dv, None, None, None
+                           o, lse, do, dlse,
+                           None if sel is None else jnp.swapaxes(sel, 1, 2))
+    return dq, dk, dv, None, None, None, None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1073,6 +1113,7 @@ def launch_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
                  causal: bool = True, window: Optional[int] = None,
                  blockdiff: Optional[int] = None,
                  summaries: Optional[Tuple[int, int]] = None,
+                 selected: bool = False,
                  block_q: Optional[int] = None, block_k: Optional[int] = None,
                  compiled: bool = True) -> Optional[FlashTiles]:
     """The tiles of ONE launch of ``sq`` queries over ``sk`` keys, by its
@@ -1088,7 +1129,11 @@ def launch_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
     - ``summaries = (window, summaries a window)`` (EVA's far keys,
       ``FlashConfig.summaries``; ``sk`` is ``sq // window x`` that): the
       causal choice where both q tiles divide the window (a q-block then has
-      ONE limit), else that choice under a q tile of one window."""
+      ONE limit), else that choice under a q tile of one window;
+    - ``selected`` (a learned selection's operand, ``FlashConfig.selected``;
+      causal, as many keys as queries): the causal choice, its tiles as a
+      full layer's, with the scoped VMEM a tile of the operand adds (the int8
+      block twice, its int32 copy once)."""
     choose = functools.partial(choose_tiles, head_dim=head_dim, itemsize=itemsize,
                                block_k=block_k, compiled=compiled)
     if blockdiff is not None:
@@ -1106,7 +1151,16 @@ def launch_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
             if tiles is not None and not (span % tiles.fwd[0] or span % tiles.bwd[0]):
                 return tiles if sq // span * per == sk else None
         return None
-    return choose(sq, sk, causal=causal, window=window, block_q=block_q)
+    tiles = choose(sq, sk, causal=causal, window=window, block_q=block_q)
+    if selected and tiles is not None:
+        if not causal or window is not None or sq != sk:
+            return None
+        need = max(
+            tile_vmem_bytes(t, head_dim, itemsize, backward=back) + 6 * t[0] * t[1]
+            for t, back in ((tiles.fwd, False), (tiles.bwd, True)))
+        tiles = dataclasses.replace(
+            tiles, vmem_limit_bytes=None if need <= VMEM_BUDGET else min(need, VMEM_CAP))
+    return tiles
 
 
 def static_window(window, sq: int, sk: int, q_offset=None) -> Optional[int]:
@@ -1159,7 +1213,7 @@ def tiles_run(q_ids: jax.Array, k_ids: jax.Array, tile: Tile, *,
 
 def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
              alibi_slopes, window, q_offset, block_q, block_k, interpret,
-             blockdiff=None, summaries=None, tag=None):
+             blockdiff=None, summaries=None, tag=None, selected=False):
     B, Sq, H, D = q.shape
     Sk, kvH = k.shape[1], k.shape[2]
     if H % kvH:
@@ -1189,8 +1243,14 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
                 "EVA's summaries take one key a chunk of the queries' row and "
                 "no window, ALiBi, q_offset or segment ids")
         q_offset = 0
+    if selected and (not causal or window is not None or alibi_slopes is not None
+                     or q_offset is not None or blockdiff is not None
+                     or summaries is not None or Sq != Sk):
+        raise ValueError("a selection's operand takes a causal launch of as many "
+                         "keys as queries and no window, ALiBi or q_offset")
     tiles = launch_tiles(Sq, Sk, D, q.dtype.itemsize, causal=bool(causal),
                          window=cut, blockdiff=blockdiff, summaries=summaries,
+                         selected=selected,
                          block_q=block_q, block_k=block_k, compiled=not interp)
     if tiles is None:
         raise ValueError(f"seq lengths ({Sq}, {Sk}) have no legal tiles "
@@ -1214,7 +1274,7 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         kv_heads=kvH, tiles=tiles, interpret=bool(interp), window=cut,
         blockdiff=None if blockdiff is None else (int(blockdiff), Sk),
         summaries=None if summaries is None else tuple(map(int, summaries)),
-        tag=tag)
+        tag=tag, selected=bool(selected))
 
     segs = (None,) * 6
     if segment_ids is not None:
@@ -1260,7 +1320,8 @@ def flash_attention_with_lse(
         q_offset=None, block_q: Optional[int] = None,
         block_k: Optional[int] = None, interpret: Optional[bool] = None,
         blockdiff: Optional[int] = None,
-        summaries: Optional[Tuple[int, int]] = None, tag: Optional[str] = None
+        summaries: Optional[Tuple[int, int]] = None, tag: Optional[str] = None,
+        selected: Optional[jax.Array] = None
 ) -> Tuple[jax.Array, jax.Array]:
     """Flash attention returning ``(out [B, Sq, H, D], lse [B, H, Sq])``.
 
@@ -1284,13 +1345,20 @@ def flash_attention_with_lse(
     the summaries of the windows before it, keys ``0 .. w x per - 1`` (a row
     of the first window none: 0 with the sentinel LSE). ``tag`` names the
     launches ``flash_fwd_<tag>`` / ``flash_bwd_<tag>``.
+
+    ``selected``: int8 ``[B, Sq, Sk]``, 1 where the query's learned selection
+    holds the key (``attention.select_topk``), one selection for all heads: a
+    tile reads its block of it beside the causal and same-document rule
+    (launches ``flash_fwd_dsa`` / ``flash_bwd_dsa``); tiles are skipped by
+    position and documents as without it.
     """
     B, Sq, H, D = q.shape
     cfg, q4, k3, v3, segs, slopes, info, dims = _prepare(
         q, k, v, causal, scale, segment_ids, q_segment_ids, alibi_slopes,
-        window, q_offset, block_q, block_k, interpret, blockdiff, summaries, tag)
+        window, q_offset, block_q, block_k, interpret, blockdiff, summaries, tag,
+        selected is not None)
     _, _, kvH, G = dims
-    o, lse = _flash(cfg, q4, k3, v3, segs, slopes, info)
+    o, lse = _flash(cfg, q4, k3, v3, segs, slopes, info, selected)
     out = o.reshape(B, kvH, G, Sq, D).reshape(B, H, Sq, D)
     out = out.transpose(0, 2, 1, 3)
     return out, lse.reshape(B, H, Sq)

@@ -187,11 +187,23 @@ def resolve_policy(name: Optional[str]):
 #: EVA's two launches name their residuals after their tags
 #: (``attn_o_eva_local``, ...), which this order does not list: they are
 #: made again.
-SAVE_ORDER = (("attn_lse", "attn_o"), ("eva_kbar", "eva_vbar"),
+#: A learned selection's values (PR 48) lead the order, reckoned from what a
+#: kept byte spares and not measured one by one (the cell's budget keeps all
+#: of them): ``indexer_kl_*``, the three gradients the KL's forward takes in its
+#: one pass over every head's scores (45 MB a layer at 16,384 rows; made again
+#: they cost that whole pass, 2.8 TFLOP a layer in XLA); ``dsa_mask``, the
+#: selection as the kernel reads it (int8 ``[rows, L, L]``, 268 MB a layer;
+#: made again it costs the indexer's scores over every visible pair and the
+#: threshold); the selected launch's pair ``attn_lse_dsa`` / ``attn_o_dsa`` as
+#: the plain launch's pair. The indexer's two projections (``indexer_q``,
+#: ``indexer_k``) are products over the hidden size and stand with ``q_proj``.
+SAVE_ORDER = (("indexer_kl_dq", "indexer_kl_dk", "indexer_kl_dw"), ("dsa_mask",), ("attn_lse_dsa", "attn_o_dsa"),
+              ("attn_lse", "attn_o"), ("eva_kbar", "eva_vbar"),
               ("moe_logits",), ("wi_gate", "wi_up"),
               ("wi",), ("wo",), ("fc_in",), ("gate_proj", "up_proj"),
               ("o_proj",), ("attn_gate",),
-              ("q_proj", "k_proj", "v_proj", "kv_latent"), ("kv_up",))
+              ("q_proj", "k_proj", "v_proj", "kv_latent", "indexer_q", "indexer_k"),
+              ("kv_up",))
 
 #: What a saved byte costs the step's peak, measured on the chip by filling
 #: the device until a step fails (PERF.md, PR 30): 1.0 to 1.2 once something

@@ -1131,20 +1131,27 @@ class DeepSpeedEngine:
         return {"masked_share": float(self._step_stats["diffusion_masked_share"]),
                 "mean_weight": float(self._step_stats["diffusion_mean_weight"])}
 
-    def attn_last_step(self) -> Optional[Dict[str, Dict[str, Dict[str, int]]]]:
+    def attn_last_step(self) -> Optional[Dict[str, Any]]:
         """The last fused step's count of flash tiles under its rows' packed
         documents, fetched now: ``{kind: {"forward" | "backward":
         {"position": the tiles the position test alone runs, "run": the
         tiles run}}}`` for one launch, a head (``pallas_flash.tiles_run`` at
         the tiles the kernel route takes; kinds ``window``, ``full``,
-        ``blockdiff``: ``TransformerLM.attn_tile_kinds``). None for a model
-        without ``document_separator`` or before a step."""
-        if not self._step_stats or "attn_tiles" not in self._step_stats:
-            return None
-        tiles = np.asarray(self._step_stats["attn_tiles"])
-        return {kind: {kernel: {"position": int(by_position), "run": int(run)}
+        ``blockdiff``, ``dsa``: ``TransformerLM.attn_tile_kinds``), and beside
+        them every scalar the model left under ``attn_<name>``, as ``name``
+        (a learned selection's ``selected_share``, selected over visible
+        pairs, ``indexer_kl`` and ``lm_loss``, the two terms of its loss).
+        None for a model that leaves neither, or before a step."""
+        stats = self._step_stats or {}
+        out = {name[len("attn_"):]: float(value) for name, value in stats.items()
+               if name.startswith("attn_") and name != "attn_tiles"}
+        if "attn_tiles" in stats:
+            tiles = np.asarray(stats["attn_tiles"])
+            out.update({
+                kind: {kernel: {"position": int(by_position), "run": int(run)}
                        for kernel, (by_position, run) in zip(("forward", "backward"), line)}
-                for (kind, _), line in zip(self.model.attn_tile_kinds, tiles)}
+                for (kind, _), line in zip(self.model.attn_tile_kinds, tiles)})
+        return out or None
 
     @functools.cached_property
     def _remat_room_bytes(self) -> Optional[int]:

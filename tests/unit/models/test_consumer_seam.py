@@ -14,8 +14,8 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference.v2.model import RaggedInferenceModel
-from deepspeed_tpu.models.transformer import (MECHANISMS, MoEConfig, TransformerConfig,
-                                              TransformerLM)
+from deepspeed_tpu.models.transformer import (MECHANISMS, IndexerConfig, MoEConfig,
+                                              TransformerConfig, TransformerLM)
 from deepspeed_tpu.runtime.pipe.module import PipelineModule
 
 BASE = dict(vocab_size=64, max_seq_len=32, num_layers=2, num_heads=2, hidden_size=16,
@@ -45,6 +45,8 @@ USES = {
     "attention='latent'": dict(attention="latent", kv_latent_rank=8, qk_nope_dim=6,
                                qk_rope_dim=2, v_head_dim=8),
     "attention='eva'": dict(attention="eva", eva_window=4, eva_chunk=2, attn_bias=False),
+    "indexer": dict(indexer=IndexerConfig(heads=2, head_dim=4, topk=3)),
+    "rope_sections": dict(rope_sections=(1, 2, 1)),
     "pred_heads": dict(pred_heads=2),
     "farskip": dict(farskip=True),
     "first_dense_layers": dict(first_dense_layers=1, dense_intermediate_size=32, moe=EXPERTS),

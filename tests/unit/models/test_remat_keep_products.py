@@ -175,12 +175,15 @@ def test_choose_saved_takes_the_measured_order():
     names = tuple(n for group in SAVE_ORDER for n in group)
     every = {n: MB for n in names}
     assert choose_saved(every, None) == names
-    assert choose_saved(every, 2 * MB) == ("attn_lse", "attn_o")
+    # (a learned selection's values lead: the KL's gradients, the operand)
+    assert choose_saved(every, 3 * MB) == ("indexer_kl_dq", "indexer_kl_dk", "indexer_kl_dw")
+    assert choose_saved(every, 8 * MB)[-2:] == ("attn_lse", "attn_o")
     assert choose_saved({}, None) == () == choose_saved({}, 0)
     # the projections in front of the kernel go first when room runs out
     # (latent attention's compressed vector with them, its up-projection,
     # which contracts over the rank alone, after them)
-    assert SAVE_ORDER[-2:] == (("q_proj", "k_proj", "v_proj", "kv_latent"), ("kv_up",))
+    assert SAVE_ORDER[-2:] == (("q_proj", "k_proj", "v_proj", "kv_latent",
+                                "indexer_q", "indexer_k"), ("kv_up",))
     assert len(set(names)) == len(names)
 
 

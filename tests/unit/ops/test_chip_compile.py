@@ -119,6 +119,26 @@ class TestCompilesForV5e:
         text = jax.jit(fn).lower(*args).as_text()
         assert "flash_fwd_blockdiff" in text and "flash_bwd_blockdiff" in text
 
+    def test_selected_attention_at_16k(self, chip, monkeypatch):
+        """The keye-vl2-30b-a3b cell's attention core: 32 query heads over 4
+        key heads of 128, one row of 16,384 under documents, the flash pair
+        reading the selection's int8 operand, under the launches' own names."""
+        from deepspeed_tpu.ops.transformer import attention
+        B, L, H, kvH, D = 1, 16384, 32, 4, 128
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv("DSTPU_ATTN", raising=False)
+
+        def loss(q, k, v, sel, doc):
+            o, lse = attention.selected_attention(q, k, v, sel, doc, 2048)
+            return jnp.sum(o.astype(F32)) + jnp.sum(lse)
+
+        args = (chip((B, L, H, D), BF16), chip((B, L, kvH, D), BF16),
+                chip((B, L, kvH, D), BF16), chip((B, L, L), jnp.int8), chip((B, L), I32))
+        fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
+        compile_for_chip(fn, *args)
+        text = jax.jit(fn).lower(*args).as_text()
+        assert "flash_fwd_dsa" in text and "flash_bwd_dsa" in text
+
     def test_eva_attention_at_32k(self, chip, monkeypatch):
         """The evabyte-6.5b cell's attention: 32 heads of 128 over a row of
         32,768 under EVA's mask with a window of 2048 and chunks of 16: the

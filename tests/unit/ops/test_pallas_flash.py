@@ -559,6 +559,17 @@ def test_route_rule_is_shape_and_platform_only(q_shape, k_shape, monkeypatch):
     ((1, 16384, 32, 128), (4, 2048), "tpu", "", "xla_chunked"),  # wider than a tile: never cut
     ((1, 256, 32, 128), (4, 4), "tpu", "", "kernel"),       # 2 x 128 rows: at the crossover
     ((1, 128, 32, 128), (4, 4), "tpu", "", "xla"),          # 2 x 64: under it, and no tile
+    # the keye-vl2-30b-a3b cell: a learned selection's operand (("dsa", kv
+    # heads, topk) in the key heads' place), 32q/4kv x 128 over 16,384 rows: a
+    # full layer's tiles and route, whatever topk is
+    ((1, 16384, 32, 128), ("dsa", 4, 2048), "tpu", "", "kernel"),
+    ((1, 16384, 32, 128), ("dsa", 4, 2048), "tpu", "xla", "xla_chunked"),
+    ((1, 16384, 32, 128), ("dsa", 4, 2048), "cpu", "", "xla"),
+    ((1, 16384, 32, 128), ("dsa", 4, 2048), "cpu", "pallas", "kernel"),
+    ((1, 16384, 32, 128), ("dsa", 4, 64), "tpu", "", "kernel"),
+    ((4, 64, 4, 16), ("dsa", 2, 8), "cpu", "pallas", "kernel"),       # the tiny preset, interpret
+    ((4, 64, 4, 16), ("dsa", 2, 8), "tpu", "pallas", "xla"),          # tiles off the lanes
+    ((1, 128, 32, 128), ("dsa", 4, 2048), "tpu", "", "xla"),          # under the crossover
     # the evabyte-6.5b cell: EVA's mask (("eva", window, chunk) in the key
     # heads' place: as many key heads as query heads), 32 x 128 at 32,768, and
     # a group of 4 of its heads, which is what one launch of the cell holds
@@ -582,14 +593,17 @@ def test_route_table(q_shape, kv_heads, backend, mode, route, monkeypatch):
     rows are checked here on the CPU, and an environment that asks for another
     route moves none of them."""
     from deepspeed_tpu.ops.transformer import attention as attn_mod
-    blockdiff = eva = None
+    blockdiff = eva = selected = None
     if isinstance(kv_heads, tuple) and kv_heads[0] == "eva":
         kv_heads, eva = q_shape[2], kv_heads[1:]
+    elif isinstance(kv_heads, tuple) and kv_heads[0] == "dsa":
+        _, kv_heads, selected = kv_heads
     elif isinstance(kv_heads, tuple):     # the mask's rows: keys are half the queries
         kv_heads, blockdiff = kv_heads
     k_shape = (q_shape[0], q_shape[1] // (2 if blockdiff else 1), kv_heads, q_shape[3])
     monkeypatch.setenv("DSTPU_ATTN", "xla" if route == "kernel" else "pallas")
-    assert attn_mod.choose_route(q_shape, k_shape, backend, mode, blockdiff, eva) == route
+    assert attn_mod.choose_route(q_shape, k_shape, backend, mode, blockdiff, eva,
+                                 selected) == route
 
 
 def test_attention_reads_one_environment_variable():
@@ -1291,3 +1305,70 @@ def test_a_long_rows_backward_is_one_launch_and_one_float32_dq(cell, rows, G, sq
     assert len(backward) == 1 and backward[0][1] == (), cell
     assert max(len(a.shape) for a in floats) <= 4
     assert max(a.size * 4 for a in floats) == rows * G * sq * D * 4
+
+
+# ---------------------------------------------------------------------------
+# a learned selection's operand (PR 48)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("tiles,documents,dq", [
+    ((64, 64), True, "summed"),       # four k-blocks a q-block: partials summed
+    ((32, 32), True, "in_place"),     # eight: dq added to where it lies
+    ((128, 128), False, "summed"),    # two, no ids: the operand alone beside the causal rule
+    ((256, 256), True, "one_block"),  # one tile a row: dq the kernel's own output
+])
+def test_selected_launch_matches_the_masked_softmax(tiles, documents, dq):
+    """The flash pair reading a selection's operand (``flash_*_dsa``) against
+    the masked softmax in XLA: output, LSE and the three gradients (through
+    both outputs), grouped heads, with and without packed documents, in every
+    way the backward makes dq. The operand is `dsa_select`'s: a subset of the
+    causal, same-document pairs, a row of the document's first positions
+    picking fewer than k."""
+    from deepspeed_tpu.ops.transformer import attention as attn_mod
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    rng = np.random.default_rng(0)
+    B, L, H, kvH, D, J, d, K = 2, 256, 4, 2, 32, 2, 8, 24
+    f = lambda *s: jnp.asarray(rng.normal(size=s).astype(np.float32))
+    q, k, v = f(B, L, H, D), f(B, L, kvH, D), f(B, L, kvH, D)
+    docs = jnp.asarray(np.stack([np.arange(L) >= 100, np.arange(L) >= 37]).astype(np.int32))
+    docs = docs if documents else jnp.zeros_like(docs)
+    sel = attn_mod.dsa_select(f(B, L, J, d), f(B, L, d), f(B, L, J), docs, K)
+    assert sel.dtype == jnp.int8 and int(sel[0, 5].sum()) == 6 and int(sel[1, 200].sum()) == K
+    made = pf.launch_tiles(L, L, D, 4, selected=True, block_q=tiles[0], block_k=tiles[1],
+                           compiled=False)
+    assert pf.dq_mode(L, L, made) == dq
+
+    def both(fn):
+        def loss(q, k, v):
+            o, lse = fn(q, k, v)
+            return jnp.sum(o * jnp.cos(o)) + jnp.sum(jnp.sin(lse))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))
+    want = both(lambda q, k, v: attn_mod._xla_selected_attention(q, k, v, sel, D ** -0.5))(q, k, v)
+    got = both(lambda q, k, v: pf.flash_attention_with_lse(
+        q, k, v, causal=True, segment_ids=docs if documents else None, selected=sel,
+        block_q=tiles[0], block_k=tiles[1], interpret=True))(q, k, v)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=1e-4)
+
+
+def test_selected_launch_names_its_kernels_and_residuals_and_refuses_the_rest():
+    from deepspeed_tpu.ops.transformer import pallas_flash as pf
+    q = jnp.zeros((1, 128, 4, 16)); k = v = jnp.zeros((1, 128, 2, 16))
+    sel = jnp.ones((1, 128, 128), jnp.int8)
+    fn = lambda q, k, v: jnp.sum(pf.flash_attention_with_lse(
+        q, k, v, causal=True, selected=sel, interpret=True)[0])
+    text = str(jax.make_jaxpr(jax.grad(fn, argnums=(0, 1, 2)))(q, k, v))
+    assert "flash_fwd_dsa" in text and "flash_bwd_dsa" in text
+    assert "attn_o_dsa" in text and "attn_lse_dsa" in text
+    for bad in (dict(causal=False), dict(window=16), dict(q_offset=0), dict(blockdiff=4)):
+        with pytest.raises(ValueError):
+            pf.flash_attention_with_lse(q, k, v, **{"causal": True, **bad},
+                                        selected=sel, interpret=True)
+    # the tiles are a full causal layer's at the cell's shape, with the scoped
+    # VMEM the operand's tile adds; no other launch kind's tiles move
+    full = pf.launch_tiles(16384, 16384, 128)
+    mine = pf.launch_tiles(16384, 16384, 128, selected=True)
+    assert (mine.fwd, mine.bwd) == (full.fwd, full.bwd) == ((512, 512), (1024, 1024))
+    assert full.vmem_limit_bytes is None and mine.vmem_limit_bytes > pf.VMEM_BUDGET
+    assert pf.dq_mode(16384, 16384, mine) == "in_place"

@@ -145,6 +145,8 @@ KERNEL_NAMES = {
     # summaries under a q-block's limit (``FlashConfig.tag``)
     "flash_fwd_eva_local", "flash_bwd_eva_local",
     "flash_fwd_eva_far", "flash_bwd_eva_far",
+    # the flash pair whose tiles read a learned selection's operand (PR 48)
+    "flash_fwd_dsa", "flash_bwd_dsa",
     # a share's rows back to the tokens (PR 38), under ``mlp/moe/combine`` and
     # ``mlp/moe/dispatch``: ``train_moe_dispatch_ms`` finds it by its scope
     "segment-sum"}
@@ -172,7 +174,7 @@ def test_every_pallas_call_has_a_name(site):
 def test_kernel_names_are_distinct_and_complete():
     assert len(PALLAS_SITES) == 18
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 26
+    assert len(set(names)) == len(names) == 28
     assert set(names) == KERNEL_NAMES
 
 

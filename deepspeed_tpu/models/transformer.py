@@ -1095,7 +1095,7 @@ class TransformerLM:
             with jax.named_scope("indexer_kl"):
                 kl = attention.indexer_kl(
                     q_idx, k_idx, w, *jax.lax.stop_gradient((q, k, lse)), picked,
-                    float(scale)) / (B * S)
+                    documents, float(scale)) / (B * S)
             with jax.named_scope("out"):
                 return self._project(block, "o_proj", out.reshape(
                     B, S, c.num_heads * c.head_dim)), kl
@@ -1979,6 +1979,13 @@ class TransformerLM:
             visible = (at - first + 1).astype(jnp.float32)
             stats = {**stats, "attn_selected_share": jnp.sum(
                 jnp.minimum(visible, c.indexer.topk)) / jnp.sum(visible)}
+            # the tiles the KL's kernel runs a layer, of its grid's
+            from ..ops.transformer import attention, pallas_flash, pallas_indexer_kl
+            tile = attention.kl_launch(self._attention_plan(*docs.shape), docs.shape[1])[1]
+            if tile is not None:
+                stats["dsa_kl_tiles"] = jnp.stack([
+                    pallas_flash.tiles_run(docs, docs, tile)[1],
+                    jnp.int32(pallas_indexer_kl.tiles_of(*docs.shape, tile))])
         return x, aux, stats, mtp_x
 
     def _documents(self, input_ids: jax.Array) -> jax.Array:
@@ -2074,10 +2081,11 @@ class TransformerLM:
                            "summaries_a_row": None, "pred_heads": c.pred_heads,
                            "route": None, "dq_local": None, "dq_far": None}
         if c.indexer is not None:
-            from ..ops.transformer.attention import SELECT_THRESHOLD
+            from ..ops.transformer.attention import SELECT_THRESHOLD, kl_launch
             attn["dsa"] = {"topk": c.indexer.topk, "indexer_heads": c.indexer.heads,
                            "indexer_head_dim": c.indexer.head_dim, "route": None,
-                           "select": SELECT_THRESHOLD, "dq": None}
+                           "select": SELECT_THRESHOLD, "dq": None,
+                           "kl": None, "kl_tiles": None}
         diffusion = {"block_length": c.block_length, "rows_per_token": self.rows_per_token,
                      "route": None, "dq": None} if c.diffusion else None
         if seq is None:
@@ -2090,7 +2098,8 @@ class TransformerLM:
                 summaries_a_row=seq // c.eva_chunk, route=plans[0].route,
                 dq_local=plans[0].dq("eva_local"), dq_far=plans[0].dq("eva_far"))
         elif c.indexer is not None:
-            attn["dsa"].update(route=plans[0].route, dq=plans[0].dq("dsa"))
+            attn["dsa"].update(route=plans[0].route, dq=plans[0].dq("dsa"),
+                               kl=kl_launch(plans[0], seq)[0])
         else:
             # (sliding layers of several widths: the mode they share, else both)
             under = sorted({plans[w].dq("flash") or "" for w in windows})
@@ -2100,6 +2109,16 @@ class TransformerLM:
                 "window": ("+".join(under) or None) if layers["window"] else None,
                 "full": plans[0].dq("flash") if layers["full"] else None}
         return attn, diffusion
+
+    def traced_rows_records(self, stats: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
+        """What a step's statistics add to ``attn_totals``, by its key there
+        (an engine asks once, of the first step's: the rows the step was traced
+        for): a learned selection's ``kl_tiles``, ``[run, of]`` tiles of one
+        layer's ``indexer_kl_fwd`` launch (``pallas_flash.tiles_run`` at the
+        pair's tile), where the KL takes the kernel."""
+        if "dsa_kl_tiles" not in stats:
+            return {}
+        return {"dsa": {"kl_tiles": [int(n) for n in np.asarray(stats["dsa_kl_tiles"])]}}
 
     def expert_records(self, batch: Optional[int] = None, seq: Optional[int] = None,
                        *, expert_layers: int = 0, dtype=None, devices: int = 1,

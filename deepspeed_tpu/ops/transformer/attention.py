@@ -303,7 +303,13 @@ def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
             for tag, sq, sk, kind in launches)
         if made and _pf.folds(q_shape, k_shape) and all(at.tiles for at in made):
             return Plan("kernel", made)
-    return Plan("xla_chunked" if q_shape[1] >= XLA_CHUNK_MIN_SEQ and compiled else "xla")
+    return Plan(_xla_route(q_shape[1], compiled))
+
+
+def _xla_route(rows: int, compiled: bool) -> str:
+    """The XLA side's name for a call over ``rows`` queries: chunked from
+    `XLA_CHUNK_MIN_SEQ` up on a device."""
+    return "xla_chunked" if rows >= XLA_CHUNK_MIN_SEQ and compiled else "xla"
 
 
 def flash_attention(q: jax.Array,
@@ -782,46 +788,86 @@ def _kl_operands(q_idx, w, q, lse, selected, n):
             _query_blocks(selected, n))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def indexer_kl(q_idx, k_idx, w, q, k, lse, selected, scale: float):
+def kl_launch(made: Plan, length: int) -> Tuple[str, Any]:
+    """Where `indexer_kl` runs beside a selected call whose `plan` is ``made``,
+    over rows of ``length``: ``("kernel", the pair's tile)`` where the flash
+    pair is a kernel and ``pallas_indexer_kl`` has a legal tile for the length
+    (interpret mode off the TPU included), else the plan's own XLA route and
+    None: the scan of `KL_QUERY_BLOCK` queries at a time."""
+    if made.route != "kernel":
+        return made.route, None
+    from . import pallas_indexer_kl as _kl
+    compiled = jax.default_backend() != "cpu"
+    tile = _kl.choose_tile(length, compiled=compiled)
+    return ("kernel", tile) if tile is not None else (_xla_route(length, compiled), None)
+
+
+def _kl_launch(q, k, selected):
+    """`kl_launch` of a call's operands, said once a path."""
+    route, tile = kl_launch(plan(q.shape, k.shape, jax.default_backend(), attn_mode(),
+                                 q.dtype.itemsize, selected=True), selected.shape[1])
+    _log_path_once(f"indexer_kl {route}")
+    return route, tile
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def indexer_kl(q_idx, k_idx, w, q, k, lse, selected, documents, scale: float):
     """The indexer's objective for one layer, ``sum over b, t of KL(p_t ||
-    softmax over S_t of I[t, .])`` (above), float32, a block of
-    `KL_QUERY_BLOCK` queries at a time. Differentiable in ``q_idx``, ``k_idx``
-    and ``w`` alone: the target (``q``, ``k``, ``lse`` of the main attention)
-    is data. The forward of a differentiated call takes the three gradients in
-    the same pass over the blocks (every head's scores are made once a step,
-    not once more in the backward) and keeps them under the names
-    ``indexer_kl_dq`` / ``_dk`` / ``_dw``; the backward scales them."""
+    softmax over S_t of I[t, .])`` (above), float32. Differentiable in
+    ``q_idx``, ``k_idx`` and ``w`` alone: the target (``q``, ``k``, ``lse`` of
+    the main attention) is data. ``documents`` [B, L] int or None: each
+    position's packed document, by which the kernel route skips tiles.
+
+    On the kernel route (`kl_launch`: beside a selected call that is a kernel)
+    the Pallas pair of ``pallas_indexer_kl``: every head's probabilities, the
+    scores and the KL a tile at a time in VMEM, a tile no query of which sees a
+    key skipped (``indexer_kl_fwd``; the differentiated forward also
+    ``indexer_kl_bwd``). Elsewhere `_kl_rows` over a block of `KL_QUERY_BLOCK`
+    queries at a time. Either way the forward of a differentiated call takes
+    the three gradients as well (every head's scores are made once a step, not
+    once more in the backward) and keeps them under the names ``indexer_kl_dq``
+    / ``_dk`` / ``_dw``; the backward scales them."""
+    route, tile = _kl_launch(q, k, selected)
+    if route == "kernel":
+        from . import pallas_indexer_kl as _kl
+        return _kl.value(q_idx, k_idx, w, q, k, lse, selected, documents, scale, tile)
     n = _kl_blocks(selected.shape[1])
     each = lambda xs: _kl_rows(*xs, k_idx, k, scale)
     return jnp.sum(jax.lax.map(each, _kl_operands(q_idx, w, q, lse, selected, n)))
 
 
-def _indexer_kl_fwd(q_idx, k_idx, w, q, k, lse, selected, scale):
+def _indexer_kl_fwd(q_idx, k_idx, w, q, k, lse, selected, documents, scale):
     B, L = selected.shape[:2]
-    n = _kl_blocks(L)
+    route, tile = _kl_launch(q, k, selected)
+    if route == "kernel":
+        from . import pallas_indexer_kl as _kl
+        total, (dq, dk, dw) = _kl.value_and_gradients(
+            q_idx, k_idx, w, q, k, lse, selected, documents, scale, tile)
+    else:
+        n = _kl_blocks(L)
 
-    def each(carry, xs):
-        qb, wb, *target = xs
-        value, (dq, dw, dk) = jax.value_and_grad(
-            lambda qb, wb, kb: _kl_rows(qb, wb, *target, kb, k, scale),
-            argnums=(0, 1, 2))(qb, wb, k_idx)
-        total, dk_sum = carry
-        return (total + value, dk_sum + dk.astype(jnp.float32)), (dq, dw)
+        def each(carry, xs):
+            qb, wb, *target = xs
+            value, (dq, dw, dk) = jax.value_and_grad(
+                lambda qb, wb, kb: _kl_rows(qb, wb, *target, kb, k, scale),
+                argnums=(0, 1, 2))(qb, wb, k_idx)
+            total, dk_sum = carry
+            return (total + value, dk_sum + dk.astype(jnp.float32)), (dq, dw)
 
-    (total, dk), (dq, dw) = jax.lax.scan(
-        each, (jnp.zeros((), jnp.float32), jnp.zeros(k_idx.shape, jnp.float32)),
-        _kl_operands(q_idx, w, q, lse, selected, n))
-    whole = lambda a: jnp.moveaxis(a, 0, 1).reshape((B, L) + a.shape[3:])
+        (total, dk), (dq, dw) = jax.lax.scan(
+            each, (jnp.zeros((), jnp.float32), jnp.zeros(k_idx.shape, jnp.float32)),
+            _kl_operands(q_idx, w, q, lse, selected, n))
+        whole = lambda a: jnp.moveaxis(a, 0, 1).reshape((B, L) + a.shape[3:])
+        dq, dk, dw = whole(dq), dk.astype(k_idx.dtype), whole(dw)
     # (a name each: a policy reckons a name's bytes from ONE value)
     grads = tuple(checkpoint_name(g, "indexer_kl_" + name) for name, g in (
-        ("dq", whole(dq)), ("dk", dk.astype(k_idx.dtype)), ("dw", whole(dw))))
+        ("dq", dq), ("dk", dk), ("dw", dw)))
     return total, grads
 
 
 def _indexer_kl_bwd(scale, grads, ct):
     dq, dk, dw = (g * ct.astype(g.dtype) for g in grads)
-    return dq, dk, dw, None, None, None, None
+    return dq, dk, dw, None, None, None, None, None
 
 
 indexer_kl.defvjp(_indexer_kl_fwd, _indexer_kl_bwd)

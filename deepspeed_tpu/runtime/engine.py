@@ -2345,6 +2345,7 @@ class DeepSpeedEngine:
         scalar (docs/OBSERVABILITY.md)."""
         if self._setup.open:
             self._setup.finish()
+            self._count_traced_rows()
             log_dist("set-up: " + json.dumps(self.setup_totals), ranks=[0])
         if _profiler_on():
             version, flat = self._totals_flat
@@ -2377,6 +2378,15 @@ class DeepSpeedEngine:
         self.attn_totals.update(attn)
         if diffusion is not None:
             self.diffusion_totals.update(diffusion)
+
+    def _count_traced_rows(self) -> None:
+        """What the model's records say of the rows the first step ran
+        (``TransformerLM.traced_rows_records`` over that step's statistics,
+        fetched this once, as set-up ends), into ``attn_totals``."""
+        records = getattr(self.model, "traced_rows_records", None)
+        if records is not None and self._step_stats:
+            for kind, found in records(self._step_stats).items():
+                self.attn_totals[kind].update(found)
 
     def _count_moe(self, stats) -> None:
         """The fused step's MoE counters; the step's statistics are kept

@@ -70,6 +70,8 @@ def test_loss_and_gradient_match_the_reference(parts, route, monkeypatch):
     assert model.config.rope_sections == (2, 3, 3) and model.scan_plan == (((0, True),), 2, ())
     plan = model._attention_plan(*ids.shape)
     assert plan.route == ("kernel" if route == "pallas" else "xla")
+    # the KL beside it: through the Pallas pair where the flash pair is a kernel
+    assert model.attention_records(*ids.shape)[0]["dsa"]["kl"] == plan.route
     want, want_g = jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg))(w)
     lm, kl = ref.loss_terms(w, ids, cfg)
     with jax.default_matmul_precision("highest"):
@@ -251,7 +253,7 @@ def test_first_step_through_initialize(parts):
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}}})
     assert engine.attn_totals["dsa"] == {
         "topk": 8, "indexer_heads": 2, "indexer_head_dim": 8, "route": None,
-        "select": attention.SELECT_THRESHOLD, "dq": None}
+        "select": attention.SELECT_THRESHOLD, "dq": None, "kl": None, "kl_tiles": None}
     assert engine.attn_last_step() is None
     # (a row a device of the test mesh)
     batch = {"input_ids": np.concatenate([np.asarray(ids), np.asarray(ids)[:, ::-1]]
@@ -271,6 +273,7 @@ def test_first_step_through_initialize(parts):
         total += int(np.sum(s != 0))
     assert wrong / total < 0.01
     assert engine.attn_totals["dsa"]["route"] == "xla" and engine.attn_totals["dsa"]["dq"] is None
+    assert engine.attn_totals["dsa"]["kl"] == "xla" and engine.attn_totals["dsa"]["kl_tiles"] is None
     last = engine.attn_last_step()
     assert last["indexer_kl"] == pytest.approx(float(kl), rel=1e-4)
     assert last["lm_loss"] == pytest.approx(float(lm), rel=1e-4)
@@ -279,6 +282,35 @@ def test_first_step_through_initialize(parts):
     from deepspeed_tpu.telemetry import setup_spans
     flat = setup_spans.flat_totals(attn=engine.attn_totals)
     assert flat["attn.dsa.topk"] == 8 and flat["attn.dsa.select"] == attention.SELECT_THRESHOLD
+
+
+def test_the_kl_counters_on_the_kernel_route(parts, monkeypatch):
+    """``DSTPU_ATTN=pallas`` through ``initialize`` -> ``train_batch``: the KL
+    takes the Pallas pair, and the first step leaves the tiles one layer's
+    forward launch ran of its grid (``[run, of]``; a row of 64 is one tile
+    here), which ride in ``engine_totals`` as ``attn.dsa.kl_tiles``."""
+    import deepspeed_tpu
+    from deepspeed_tpu.telemetry import setup_spans
+    ref, adapter, cfg, w, ids = parts
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=adapter.model(cfg, remat=True, dtype="float32"),
+        model_parameters=adapter.to_program(w), config={
+            "train_micro_batch_size_per_gpu": 1, "zero_optimization": {"stage": 1},
+            "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
+    batch = {"input_ids": np.concatenate([np.asarray(ids), np.asarray(ids)[:, ::-1]]
+                                         )[:jax.device_count()]}
+    rows = jnp.asarray(batch["input_ids"])
+    loss = engine.train_batch(batch)
+    assert float(loss) == pytest.approx(float(ref.loss_and_gradient(w, rows, cfg)[0]), rel=1e-4)
+    dsa = engine.attn_totals["dsa"]
+    assert dsa["route"] == dsa["kl"] == "kernel"
+    assert dsa["kl_tiles"] == [len(rows), len(rows)]
+    flat = setup_spans.flat_totals(attn=engine.attn_totals)
+    assert flat["attn.dsa.kl"] == "kernel"
+    assert flat["attn.dsa.kl_tiles"] == f"{len(rows)}+{len(rows)}"
+    engine.train_batch(batch)       # asked once: a later step moves nothing
+    assert engine.attn_totals["dsa"]["kl_tiles"] == [len(rows), len(rows)]
 
 
 def test_the_shares_add_up_to_the_whole_layer(parts):

@@ -139,6 +139,29 @@ class TestCompilesForV5e:
         text = jax.jit(fn).lower(*args).as_text()
         assert "flash_fwd_dsa" in text and "flash_bwd_dsa" in text
 
+    def test_indexer_kl_at_16k(self, chip, monkeypatch):
+        """The keye-vl2-30b-a3b cell's indexer objective: one row of 16,384, 32
+        query heads over 4 key heads of 128, an indexer of 16 heads of 64, the
+        selection's int8 operand: the differentiated forward launches the
+        Pallas pair, value and three gradients, under the launches' own names."""
+        from deepspeed_tpu.ops.transformer import attention
+        B, L, H, kvH, D, J, d = 1, 16384, 32, 4, 128, 16, 64
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv("DSTPU_ATTN", raising=False)
+        made = attention.plan((B, L, H, D), (B, L, kvH, D), "tpu", "", selected=2048)
+        assert attention.kl_launch(made, L)[0] == "kernel"
+
+        def loss(q_idx, k_idx, w, q, k, lse, sel, doc):
+            return attention.indexer_kl(q_idx, k_idx, w, q, k, lse, sel, doc, D ** -0.5)
+
+        args = (chip((B, L, J, d), BF16), chip((B, L, d), BF16), chip((B, L, J), F32),
+                chip((B, L, H, D), BF16), chip((B, L, kvH, D), BF16),
+                chip((B, H, L), F32), chip((B, L, L), jnp.int8), chip((B, L), I32))
+        fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
+        compile_for_chip(fn, *args)
+        text = jax.jit(fn).lower(*args).as_text()
+        assert "indexer_kl_fwd" in text and "indexer_kl_bwd" in text
+
     def test_eva_attention_at_32k(self, chip, monkeypatch):
         """The evabyte-6.5b cell's attention: 32 heads of 128 over a row of
         32,768 under EVA's mask with a window of 2048 and chunks of 16: the

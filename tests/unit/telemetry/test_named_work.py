@@ -147,6 +147,9 @@ KERNEL_NAMES = {
     "flash_fwd_eva_far", "flash_bwd_eva_far",
     # the flash pair whose tiles read a learned selection's operand (PR 48)
     "flash_fwd_dsa", "flash_bwd_dsa",
+    # the indexer's KL a tile at a time (PR 49; ``pallas_indexer_kl``): NOT
+    # ``flash_*_dsa``, whose reader sums the selected flash pair alone
+    "indexer_kl_fwd", "indexer_kl_bwd",
     # a share's rows back to the tokens (PR 38), under ``mlp/moe/combine`` and
     # ``mlp/moe/dispatch``: ``train_moe_dispatch_ms`` finds it by its scope
     "segment-sum"}
@@ -172,9 +175,9 @@ def test_every_pallas_call_has_a_name(site):
 
 
 def test_kernel_names_are_distinct_and_complete():
-    assert len(PALLAS_SITES) == 18
+    assert len(PALLAS_SITES) == 20
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 28
+    assert len(set(names)) == len(names) == 30
     assert set(names) == KERNEL_NAMES
 
 

@@ -21,7 +21,10 @@ bf16 operands), one layer:
   selection's operand against the plain causal pair over the same heads and
   documents (forward, and forward + backward);
 - ``kl``: ``attention.indexer_kl``'s differentiated forward (value and the
-  three gradients in one pass) and its primal alone.
+  three gradients in one pass) and its primal alone, as the Pallas pair
+  (``pallas_indexer_kl``; ``kl_kernel_*``, with the tiles it ran of its grid)
+  beside the XLA form (``kl_xla_*``: a scan of 128 queries at a time), and the
+  pair under each of ``--kl-tiles`` (``kl_kernel_<bq>x<bk>``).
 
 Median of ``--iters`` timed calls. One JSON line a case, also in
 ``chiprun_out/attn_dsa_ab.jsonl``.
@@ -44,12 +47,15 @@ def main() -> int:
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--skip", default="", help="cases to leave out, comma-separated")
+    ap.add_argument("--only", default="", help="run the cases that start with this alone")
+    ap.add_argument("--kl-tiles", default="", help="the KL pair's tiles to try beside its "
+                    "own choice, comma-separated <block_q>x<block_k>")
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from deepspeed_tpu.ops.transformer import attention
+    from deepspeed_tpu.ops.transformer import attention, pallas_flash
     from deepspeed_tpu.ops.transformer.pallas_flash import flash_attention_with_lse
 
     L, K, H, kvH, D, J, d = args.seq, args.topk, 32, 4, 128, 16, 64
@@ -68,12 +74,12 @@ def main() -> int:
     seen = attention.causal_in_document(jnp.arange(L - n, L), docs[:, block], docs)
     picked = jax.jit(lambda: attention.dsa_select(q_idx, k_idx, w, docs, K))()
     scale = D ** -0.5
-    lse = jax.jit(lambda: flash_attention_with_lse(
-        q, k, v, causal=True, segment_ids=docs, selected=picked)[1])()
+    lse = jax.jit(lambda q, k, v, picked: flash_attention_with_lse(
+        q, k, v, causal=True, segment_ids=docs, selected=picked)[1])(q, k, v, picked)
     timed = {}
 
     def timing(name, fn, *xs, times=1):
-        if name in args.skip.split(","):
+        if name in args.skip.split(",") or not name.startswith(args.only):
             return None
         f = jax.jit(fn)
         out = jax.block_until_ready(f(*xs))
@@ -86,6 +92,7 @@ def main() -> int:
                        "times_a_layer": times,
                        "ms_a_layer": times * statistics.median(laps),
                        "device": jax.devices()[0].device_kind}
+        print("timed", name, "%.3f ms" % timed[name]["ms"], file=sys.stderr, flush=True)
         return out
 
     blocks = L // n
@@ -108,9 +115,42 @@ def main() -> int:
     for name, fn in (("core_dsa", dsa), ("core_causal", causal)):
         timing(name + "_forward", fn, q, k, v)
         timing(name + "_forward_backward", jax.grad(fn, argnums=(0, 1, 2)), q, k, v)
-    kl = lambda a, b, c: attention.indexer_kl(a, b, c, q, k, lse, picked, scale)
-    timing("kl_primal", kl, q_idx, k_idx, w)
-    timing("kl_value_and_gradients", jax.value_and_grad(kl, argnums=(0, 1, 2)), q_idx, k_idx, w)
+    from deepspeed_tpu.ops.transformer import pallas_indexer_kl
+    # (the target and the operand as arguments: a closed-over 268 MB constant is
+    # compiled into every case's program, a minute each)
+    target = (q, k, lse, picked, docs)
+    kl = lambda a, b, c, *target: attention.indexer_kl(a, b, c, *target, scale)
+    launch, outs = attention.kl_launch, {}
+    for form in ("kernel", "xla"):
+        if form == "xla":       # the route a shape without a tile takes
+            attention.kl_launch = lambda made, length: ("xla_chunked", None)
+        timing(f"kl_{form}_primal", kl, q_idx, k_idx, w, *target)
+        outs[form] = timing(f"kl_{form}_value_and_gradients",
+                            jax.value_and_grad(kl, argnums=(0, 1, 2)), q_idx, k_idx, w, *target)
+    attention.kl_launch = launch
+    if outs.get("kernel") is not None and outs.get("xla") is not None:
+        # the pair beside the XLA form: each against the largest element
+        f32 = lambda a: jnp.asarray(a, jnp.float32)
+        flat = lambda out: [out[0], *out[1]]
+        timed["kl_kernel_value_and_gradients"]["against_xla"] = {
+            name: float(jnp.max(jnp.abs(f32(a) - f32(b))) / jnp.max(jnp.abs(f32(b))))
+            for name, a, b in zip(("value", "dq_idx", "dk_idx", "dw"),
+                                  flat(outs["kernel"]), flat(outs["xla"]))}
+    tiles = [pallas_indexer_kl.choose_tile(L, compiled=jax.default_backend() != "cpu")]
+    tiles += [tuple(map(int, t.split("x"))) for t in args.kl_tiles.split(",") if t]
+    for at, tile in enumerate(tiles):
+        name = "kl_kernel_value_and_gradients" if at == 0 else "kl_kernel_%dx%d" % tile
+        if at:
+            pair = lambda a, b, c, *target, tile=tile: pallas_indexer_kl.value_and_gradients(
+                a, b, c, *target, scale, tile)
+            fwd = lambda a, b, c, *target, tile=tile: pallas_indexer_kl.value(
+                a, b, c, *target, scale, tile)
+            timing(name, pair, q_idx, k_idx, w, *target)
+            timing(name + "_primal", fwd, q_idx, k_idx, w, *target)
+        if name in timed:
+            timed[name].update(
+                tile=list(tile), tiles_run=int(pallas_flash.tiles_run(docs, docs, tile)[1]),
+                tiles_of=pallas_indexer_kl.tiles_of(1, L, tile))
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/attn_dsa_ab.jsonl", "w") as f:
         for row in timed.values():

@@ -1104,7 +1104,8 @@ class TransformerLM:
                   documents: jax.Array):
         """A layer's indexer over its normed input ``h`` -> (q_idx [B, S, J,
         d], k_idx [B, S, d], w [B, S, J] float32 and scaled, the selection as
-        the int8 [B, S, S] operand, kept under the name ``dsa_mask``). No
+        the operand its readers unpack: bits, int8 [B, S / 8, S]
+        (``attention.pack_selection``), kept under the name ``dsa_mask``). No
         gradient reaches ``h``."""
         from ..ops.transformer import attention
         c, ix = self.config, self.config.indexer
@@ -2081,11 +2082,12 @@ class TransformerLM:
                            "summaries_a_row": None, "pred_heads": c.pred_heads,
                            "route": None, "dq_local": None, "dq_far": None}
         if c.indexer is not None:
-            from ..ops.transformer.attention import SELECT_THRESHOLD, kl_launch
+            from ..ops.transformer.attention import SELECT_THRESHOLD, kl_launch, packed_rows
             attn["dsa"] = {"topk": c.indexer.topk, "indexer_heads": c.indexer.heads,
                            "indexer_head_dim": c.indexer.head_dim, "route": None,
                            "select": SELECT_THRESHOLD, "dq": None,
-                           "kl": None, "kl_tiles": None}
+                           "kl": None, "kl_tiles": None,
+                           "operand": "bits", "operand_bytes": None}
         diffusion = {"block_length": c.block_length, "rows_per_token": self.rows_per_token,
                      "route": None, "dq": None} if c.diffusion else None
         if seq is None:
@@ -2099,7 +2101,8 @@ class TransformerLM:
                 dq_local=plans[0].dq("eva_local"), dq_far=plans[0].dq("eva_far"))
         elif c.indexer is not None:
             attn["dsa"].update(route=plans[0].route, dq=plans[0].dq("dsa"),
-                               kl=kl_launch(plans[0], seq)[0])
+                               kl=kl_launch(plans[0], seq)[0],
+                               operand_bytes=batch * packed_rows(seq) * seq)
         else:
             # (sliding layers of several widths: the mode they share, else both)
             under = sorted({plans[w].dq("flash") or "" for w in windows})

@@ -600,9 +600,9 @@ def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 #             x scale) v[s, g(h)]: one selection for every head;
 #   L_I     = sum_t KL(p_t || softmax over S_t of I[t, .]), p_t the mean over
 #             the heads of the main attention's own probabilities: `indexer_kl`.
-# The selection is data: it has no gradient, and is handed on as an int8
-# ``[B, L, L]`` array (1 = picked), which already holds the causal and
-# same-document rule.
+# The selection is data: it has no gradient, and is handed on as BITS, one a
+# (query, key) pair (`pack_selection`: int8 ``[B, L / 8, L]``), which already
+# hold the causal and same-document rule.
 
 #: queries whose scores ``[rows, J, L]`` float32 are held at once while the
 #: selection is made (at 16,384 keys x 16 heads: 0.5 GB), and while the KL's
@@ -688,15 +688,92 @@ def select_topk(scores: jax.Array, visible: jax.Array, k: int,
     return above | jax.lax.cond(crowded, first, lambda: equal)
 
 
+# A selection's operand, the ONE layout every reader shares. Bits along the
+# QUERY axis: a row of ``L`` queries is cut into groups of ``8 p`` (``p`` =
+# `selection_plane`: 128, a short row's ``ceil(L / 8)``; the last group padded
+# with zeros), a group is ``p`` packed rows, and bit ``j`` of packed row ``r``
+# of group ``g`` holds query ``g x 8 p + j x p + r``. A tile of ``n`` queries is
+# then whole bit PLANES of ``p`` queries (`selection_tile`): ``n / p`` bits of
+# its group's packed rows, or every bit of ``n / 8 p`` groups', and its unpack
+# is an AND a plane and the planes side by side, ``p`` sublanes each in a
+# ``[q, k]`` tile and ``p`` lanes each in a ``[k, q]`` one: no shuffle either
+# way, and the transposed readers' operand is the packed array's transpose.
+
+SELECT_PLANE = 128
+
+
+def selection_plane(length: int) -> int:
+    """The queries a bit plane holds in a row of ``length``."""
+    return min(SELECT_PLANE, -(-length // 8))
+
+
+def packed_rows(length: int) -> int:
+    """The packed rows of ``length`` queries: ``length / 8`` for whole groups."""
+    p = selection_plane(length)
+    return -(-length // (8 * p)) * p
+
+
+def selection_tile(length: int, n: int, compiled: bool = False
+                   ) -> Optional[Tuple[int, int]]:
+    """How a tile of ``n`` queries (from a multiple of ``n`` on) of a row of
+    ``length`` lies in the operand: (the packed rows of the block that holds
+    it, the tiles that share such a block), the tile's block being ``tile //
+    shared``. None where it is not whole planes of one group or whole groups;
+    ``compiled``: nor where a plane is not the chip's 128 lanes."""
+    p = selection_plane(length)
+    if n % p or (compiled and p != SELECT_PLANE):
+        return None
+    if (8 * p) % n == 0:
+        return p, 8 * p // n
+    return (n // 8, 1) if n % (8 * p) == 0 else None
+
+
+def pack_selection(picked: jax.Array) -> jax.Array:
+    """bool ``[..., T, S]`` (T queries, a whole row's) -> the operand, int8
+    ``[..., packed_rows(T), S]`` (layout above)."""
+    *lead, T, S = picked.shape
+    p = selection_plane(T)
+    groups = packed_rows(T) // p
+    padded = jnp.pad(picked, [(0, 0)] * len(lead) + [(0, groups * 8 * p - T), (0, 0)])
+    bit = (jnp.uint8(1) << jnp.arange(8, dtype=jnp.uint8))[:, None, None]
+    planes = padded.reshape(*lead, groups, 8, p, S)
+    packed = jnp.sum(jnp.where(planes, bit, jnp.uint8(0)), axis=-3, dtype=jnp.uint8)
+    return jax.lax.bitcast_convert_type(packed.reshape(*lead, groups * p, S), jnp.int8)
+
+
+def unpack_selection(packed: jax.Array, length: int,
+                     tile: Optional[Tuple[int, Any]] = None, axis: int = -2
+                     ) -> jax.Array:
+    """`pack_selection`'s inverse, bool: the whole operand of a row of
+    ``length`` queries, or ``tile = (n, i)``: queries ``i n .. (i + 1) n - 1``
+    (``i`` may be traced) from the block `selection_tile` names for them.
+    ``axis``: the packed one (the last in a transposed reader's block)."""
+    axis %= packed.ndim
+    p = selection_plane(length)
+    n, i = tile if tile is not None else (8 * packed.shape[axis], 0)
+    rows, shared = selection_tile(length, n)
+    first = (i % shared) * (n // p) if shared > 1 else 0
+    bits = packed.astype(jnp.int32)
+    planes = [jax.lax.slice_in_dim(bits, g * p, (g + 1) * p, axis=axis) & (1 << (first + j))
+              for g in range(rows // p) for j in range(min(8, n // p))]
+    picked = jnp.concatenate(planes, axis=axis) != 0
+    return picked if tile is not None else jax.lax.slice_in_dim(picked, 0, length, axis=axis)
+
+
 def dsa_select(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array,
                documents: jax.Array, k: int) -> jax.Array:
-    """Every query's selection as the operand the attention reads: int8 ``[B,
-    L, L]``, 1 where ``s`` is in ``S_t``. The scores (scope ``indexer``) and
-    the selection (scope ``select``) a block of `SELECT_QUERY_BLOCK` queries at
-    a time: ``[L, J, L]`` is never held. No gradient passes."""
+    """Every query's selection as the operand the attention reads: the bits of
+    ``s in S_t`` (`pack_selection`: int8 ``[B, packed_rows(L), L]``). The
+    scores (scope ``indexer``) and the selection (scope ``select``) a block of
+    `SELECT_QUERY_BLOCK` queries at a time, packed a group of the layout at a
+    time: neither ``[L, J, L]`` nor a byte a pair is ever held whole. No
+    gradient passes."""
     B, L = documents.shape
     q_idx, k_idx, w = jax.lax.stop_gradient((q_idx, k_idx, w))
     n = SELECT_QUERY_BLOCK if L % SELECT_QUERY_BLOCK == 0 else L
+    # the queries packed at once: whole groups (of whole blocks), else the row
+    m = max(n, 8 * selection_plane(L))
+    m = m if L % m == 0 else L
 
     def block(xs):
         qb, wb, docs_q, first = xs
@@ -706,22 +783,29 @@ def dsa_select(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array,
             scores = index_scores(qb, k_idx, wb)
         with jax.named_scope("attn"), jax.named_scope("select"):
             seen = causal_in_document(first + jnp.arange(n), docs_q, documents)
-            return select_topk(scores, seen, k).astype(jnp.int8)
+            return select_topk(scores, seen, k)
 
-    picked = jax.lax.map(block, (_query_blocks(q_idx, n), _query_blocks(w, n),
-                                 _query_blocks(documents, n), jnp.arange(0, L, n)))
-    return jnp.moveaxis(picked, 0, 1).reshape(B, L, L)
+    def groups(xs):
+        picked = jnp.moveaxis(jax.lax.map(block, xs), 0, 1).reshape(B, m, L)
+        with jax.named_scope("attn"), jax.named_scope("select"):
+            return pack_selection(picked)
+
+    blocks = lambda a: a.reshape((L // m, m // n) + a.shape[1:])
+    packed = jax.lax.map(groups, jax.tree.map(blocks, (
+        _query_blocks(q_idx, n), _query_blocks(w, n), _query_blocks(documents, n),
+        jnp.arange(0, L, n))))
+    return jnp.moveaxis(packed, 0, 1).reshape(B, packed_rows(L), L)
 
 
-def _xla_selected_attention(q, k, v, selected, scale):
+def _xla_selected_attention(q, k, v, picked, scale):
     """-> (o [B, L, H, D], lse [B, H, L] float32): the softmax over the picked
-    keys, densely."""
+    keys (``picked`` bool [B, L, S]: `unpack_selection`'s), densely."""
     B, L, H, D = q.shape
     kvH = k.shape[2]
     qt = q.transpose(0, 2, 1, 3).reshape(B, kvH, H // kvH, L, D)
     logits = jnp.einsum("bhgqd,bkhd->bhgqk", qt, k,
                         preferred_element_type=jnp.float32) * scale
-    logits = jnp.where((selected != 0)[:, None, None], logits, -1e30)
+    logits = jnp.where(picked[:, None, None], logits, -1e30)
     lse = jax.nn.logsumexp(logits, axis=-1)
     probs = jnp.exp(logits - lse[..., None]).astype(q.dtype)
     out = jnp.einsum("bhgqk,bkhd->bhgqd", probs, v)
@@ -733,15 +817,17 @@ def selected_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                        scale: Optional[float] = None
                        ) -> Tuple[jax.Array, jax.Array]:
     """Attention over each query's selection (above). q [B, L, H, D], k, v
-    [B, L, kvH, D]; ``selected`` int8 [B, L, L] from `dsa_select`;
-    ``documents`` [B, L] int. -> (o [B, L, H, D], lse [B, H, L] float32: the
-    log-sum-exp over the picked keys, which `indexer_kl` reads).
+    [B, L, kvH, D]; ``selected`` the operand of `dsa_select` (bits, int8 [B,
+    packed_rows(L), L]); ``documents`` [B, L] int. -> (o [B, L, H, D], lse [B,
+    H, L] float32: the log-sum-exp over the picked keys, which `indexer_kl`
+    reads).
 
-    On the kernel route ONE launch of the flash pair whose tiles read their
+    On the kernel route ONE launch of the flash pair whose tiles unpack their
     block of ``selected`` (``flash_fwd_dsa`` / ``flash_bwd_dsa``): dense tiles
     under the mask, skipped by position and documents as a full layer's; no
-    key is gathered. Elsewhere the masked softmax in XLA, its queries in chunks
-    from `XLA_CHUNK_MIN_SEQ` up on a device."""
+    key is gathered. Elsewhere the masked softmax in XLA over the operand
+    unpacked (`unpack_selection`), its queries in chunks from
+    `XLA_CHUNK_MIN_SEQ` up on a device."""
     B, L, H, D = q.shape
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     made = plan(q.shape, k.shape, jax.default_backend(), attn_mode(),
@@ -753,21 +839,21 @@ def selected_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             q, k, v, causal=True, scale=scale, segment_ids=documents.astype(jnp.int32),
             selected=selected)
     chunk = 1024 if made.route == "xla_chunked" and L % 1024 == 0 else L
+    picked = unpack_selection(selected, L)
     parts = [_xla_selected_attention(q[:, lo:lo + chunk], k, v,
-                                     selected[:, lo:lo + chunk], scale)
+                                     picked[:, lo:lo + chunk], scale)
              for lo in range(0, L, chunk)]
     return (jnp.concatenate([o for o, _ in parts], axis=1),
             jnp.concatenate([lse for _, lse in parts], axis=2))
 
 
-def _kl_rows(q_idx, w, q, lse, selected, k_idx, k, scale):
+def _kl_rows(q_idx, w, q, lse, picked, k_idx, k, scale):
     """``sum_t KL(p_t || softmax over S_t of I[t, .])`` over a block of queries
-    (q_idx [B, n, J, d], w [B, n, J], q [B, n, H, D], lse [B, H, n], selected
-    [B, n, L]) against all keys: p_t the mean over the heads of ``exp(q . k x
-    scale - lse)`` on the picked keys. float32."""
+    (q_idx [B, n, J, d], w [B, n, J], q [B, n, H, D], lse [B, H, n], picked
+    bool [B, n, L]: `unpack_selection`'s) against all keys: p_t the mean over
+    the heads of ``exp(q . k x scale - lse)`` on the picked keys. float32."""
     B, n, H, D = q.shape
     kvH = k.shape[2]
-    picked = selected != 0
     scores = jnp.where(picked, index_scores(q_idx, k_idx, w), -1e30)
     log_r = scores - jax.nn.logsumexp(scores, axis=-1, keepdims=True)
     qt = q.transpose(0, 2, 1, 3).reshape(B, kvH, H // kvH, n, D)
@@ -783,9 +869,10 @@ def _kl_blocks(L: int) -> int:
 
 
 def _kl_operands(q_idx, w, q, lse, selected, n):
+    """The XLA form's operands a block of ``n`` queries, the operand unpacked."""
     return (_query_blocks(q_idx, n), _query_blocks(w, n), _query_blocks(q, n),
             jnp.moveaxis(lse.reshape(lse.shape[:2] + (-1, n)), 2, 0),
-            _query_blocks(selected, n))
+            _query_blocks(unpack_selection(selected, q.shape[1]), n))
 
 
 def kl_launch(made: Plan, length: int) -> Tuple[str, Any]:
@@ -802,10 +889,10 @@ def kl_launch(made: Plan, length: int) -> Tuple[str, Any]:
     return ("kernel", tile) if tile is not None else (_xla_route(length, compiled), None)
 
 
-def _kl_launch(q, k, selected):
+def _kl_launch(q, k):
     """`kl_launch` of a call's operands, said once a path."""
     route, tile = kl_launch(plan(q.shape, k.shape, jax.default_backend(), attn_mode(),
-                                 q.dtype.itemsize, selected=True), selected.shape[1])
+                                 q.dtype.itemsize, selected=True), q.shape[1])
     _log_path_once(f"indexer_kl {route}")
     return route, tile
 
@@ -827,18 +914,18 @@ def indexer_kl(q_idx, k_idx, w, q, k, lse, selected, documents, scale: float):
     the three gradients as well (every head's scores are made once a step, not
     once more in the backward) and keeps them under the names ``indexer_kl_dq``
     / ``_dk`` / ``_dw``; the backward scales them."""
-    route, tile = _kl_launch(q, k, selected)
+    route, tile = _kl_launch(q, k)
     if route == "kernel":
         from . import pallas_indexer_kl as _kl
         return _kl.value(q_idx, k_idx, w, q, k, lse, selected, documents, scale, tile)
-    n = _kl_blocks(selected.shape[1])
+    n = _kl_blocks(q.shape[1])
     each = lambda xs: _kl_rows(*xs, k_idx, k, scale)
     return jnp.sum(jax.lax.map(each, _kl_operands(q_idx, w, q, lse, selected, n)))
 
 
 def _indexer_kl_fwd(q_idx, k_idx, w, q, k, lse, selected, documents, scale):
-    B, L = selected.shape[:2]
-    route, tile = _kl_launch(q, k, selected)
+    B, L = q.shape[:2]
+    route, tile = _kl_launch(q, k)
     if route == "kernel":
         from . import pallas_indexer_kl as _kl
         total, (dq, dk, dw) = _kl.value_and_gradients(

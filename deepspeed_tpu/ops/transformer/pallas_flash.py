@@ -78,13 +78,16 @@ window. ``tag`` names a launch ``flash_fwd_<tag>`` / ``flash_bwd_<tag>`` and
 its two residuals ``attn_o_<tag>`` / ``attn_lse_<tag>``.
 
 A learned selection (``selected=``; ``attention.selected_attention``): a
-causal launch over as many keys as queries with one operand more, int8
-``[batch, Sq, Sk]``, 1 where the query's selection holds the key (one selection
-for all heads): the first mask here that is data and not a rule of positions
-and ids. A tile reads its block of it (the backward the transposed operand's,
-which ``_flash_bwd`` makes) and ANDs it with the causal and same-document
-compare in ``_tile_logits``; tiles are skipped by position and documents as
-without it, and a tile that runs is a full tile. Tiles and ``dq_mode`` are a
+causal launch over as many keys as queries with one operand more, the BITS of
+"the query's selection holds the key" (one selection for all heads; int8
+``[batch, Sq / 8, Sk]`` in ``attention.pack_selection``'s layout: bit planes of
+128 queries): the first mask here that is data and not a rule of positions
+and ids. A tile reads the packed block that holds its queries' planes (the
+backward the transposed operand's, which ``_flash_bwd`` makes: a byte
+transpose of the packed array), unpacks it (``attention.unpack_selection``: an
+AND a plane, the planes side by side) and ANDs it with the causal and
+same-document compare in ``_tile_logits``; tiles are skipped by position and
+documents as without it, and a tile that runs is a full tile. Tiles and ``dq_mode`` are a
 full causal layer's; the launches are named ``flash_fwd_dsa`` /
 ``flash_bwd_dsa``, their residuals ``attn_o_dsa`` / ``attn_lse_dsa``. It
 composes with segment ids and grouped heads, and with nothing else.
@@ -134,6 +137,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.pallas import tpu as pltpu
+
+from . import attention as _attention
 
 NUM_LANES = 128
 NUM_SUBLANES = 8
@@ -188,9 +193,9 @@ class FlashConfig:
     # ``TAGS``, or None: by the mask
     tag: Optional[str] = None
     # a learned selection of keys (``attention.selected_attention``): the
-    # launch has one operand more, int8 ``[batch, Sq, Sk]`` (the backward's
-    # transposed), 1 where the query's selection holds the key; a tile reads
-    # its block of it beside the causal and same-document rule
+    # launch has one operand more, the selection's bits (int8 ``[batch, Sq / 8,
+    # Sk]``, ``attention.pack_selection``; the backward's transposed); a tile
+    # unpacks its block of it beside the causal and same-document rule
     selected: bool = False
 
 
@@ -395,7 +400,8 @@ def _for_visible_tile(cfg: FlashConfig, tile: Tile, i, j, info_ref, docs,
 
 def _tile_logits(cfg: FlashConfig, tile: Tile, q, k, i, j, info_ref,
                  slopes_ref, head_idx, seg_col, seg_row, *,
-                 positional: bool, transposed: bool = False, sel=None):
+                 positional: bool, transposed: bool = False, sel=None,
+                 rows: Optional[int] = None):
     """Masked, scaled fp32 logits for one tile — ONE definition shared by
     the forward and the backward kernel so the recomputed tiles cannot
     diverge from the forward's. ``transposed`` gives S^T = K Q^T
@@ -404,9 +410,10 @@ def _tile_logits(cfg: FlashConfig, tile: Tile, q, k, i, j, info_ref,
     ``seg_col`` is the lane-replicated ``[rows, 128]`` segment ids of the
     tile's row axis, ``seg_row`` the ``[8, cols]`` ids of its column axis.
     ``positional`` False leaves out the causal/window mask (the caller has
-    shown the tile to be wholly visible). ``sel``: the tile's block of a
-    selection's operand (``FlashConfig.selected``), in the tile's own
-    orientation: data, so it is compared in every tile that runs."""
+    shown the tile to be wholly visible). ``sel``: the packed block of a
+    selection's operand that holds the tile's queries (``FlashConfig.selected``;
+    of a launch over ``rows`` queries), in the tile's own orientation: data, so
+    it is unpacked and compared in every tile that runs."""
     lhs, rhs = (k, q) if transposed else (q, k)
     s = lax.dot_general(lhs, rhs, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
@@ -418,7 +425,7 @@ def _tile_logits(cfg: FlashConfig, tile: Tile, q, k, i, j, info_ref,
     if cfg.use_seg:
         mask = _lanes(seg_col, s.shape[1]) == seg_row[:1, :]
     if sel is not None:
-        picked = sel.astype(jnp.int32) != 0
+        picked = _attention.unpack_selection(sel, rows, (bq, i), axis=q_axis)
         mask = picked if mask is None else mask & picked
     if cfg.use_alibi or (cfg.causal and positional):
         q_pos = (lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
@@ -503,7 +510,8 @@ def _fwd_kernel(*refs, cfg: FlashConfig, G: int, nk: int, head_dim: int,
         s = _tile_logits(cfg, tile, q, k, i, j, info, slopes,
                          _head_index(cfg, b, g, G), qseg, kseg,
                          positional=positional,
-                         sel=sel_ref[0] if cfg.selected else None)
+                         sel=sel_ref[0] if cfg.selected else None,
+                         rows=blocks[0] * tile[0])
         m_prev = m_scr[...]
         l_prev = l_scr[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -536,7 +544,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
               sel=None):
     """-> o [BK, G, Sq, D], lse [BK, G, 1, Sq] (fp32 rows). ``table``: the
     forward tiles' :func:`block_ranges` (None: a launch without ids); ``sel``:
-    a selection's operand ``[B, Sq, Sk]`` (``FlashConfig.selected``)."""
+    a selection's operand ``[B, Sq / 8, Sk]`` (``FlashConfig.selected``)."""
     BK, G, Sq, D = q.shape
     Sk = k.shape[1]
     tile = bq, bk = cfg.tiles.fwd
@@ -579,9 +587,14 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
         in_specs.append(pl.BlockSpec((1, NUM_SUBLANES, bk), kseg_idx))
     else:
         in_specs += [None, None]
-    in_specs.append(None if sel is None else pl.BlockSpec(
-        (1, bq, bk), lambda b, g, i, j, *prefetch: (
-            b // kvH, i, k_blk(b, i, j, prefetch))))
+    if sel is None:
+        in_specs.append(None)
+    else:
+        # the packed rows that hold the q-block's bit planes
+        packed, shared = _attention.selection_tile(Sq, bq)
+        in_specs.append(pl.BlockSpec(
+            (1, packed, bk), lambda b, g, i, j, *prefetch: (
+                b // kvH, i // shared, k_blk(b, i, j, prefetch))))
 
     out_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, g, i, j, *_: (b, g, i, 0)),
@@ -672,7 +685,7 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
     earlier pair's sum has landed); a pair that is skipped touches nothing.
     ``refs``: the scalar-prefetch operands ``(info, slopes[, table])``, then
     q, k, v, the k and q segment ids, a selection's operand (transposed: ``[B,
-    Sk, Sq]``), do, lse, di, ``in_place`` the zeros dq starts from (aliased
+    Sk, Sq / 8]``), do, lse, di, ``in_place`` the zeros dq starts from (aliased
     to it), the three outputs and the scratch.
     ``steps``: the q-steps of the grid (every q-block, or under a static
     window the q-blocks one k-block reaches); ``blocks``: the launch's
@@ -743,7 +756,8 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
         st = _tile_logits(cfg, tile, q, k, i, j, info, slopes,
                           _head_index(cfg, b, g, G), kseg, qseg,
                           positional=positional, transposed=True,
-                          sel=sel_ref[0] if cfg.selected else None)
+                          sel=sel_ref[0] if cfg.selected else None,
+                          rows=blocks[0] * bq)
         lse = lse_ref[0, 0]      # [1, bq]
         # rows whose LSE is the MASK_VALUE sentinel (no unmasked key
         # anywhere) contribute exactly 0
@@ -783,7 +797,8 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
 def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
               o, lse, do, dlse, sel_t=None):
     """``table``: the backward tiles' :func:`block_ranges` (None: a launch
-    without ids); ``sel_t``: a selection's operand transposed, ``[B, Sk, Sq]``.
+    without ids); ``sel_t``: a selection's operand transposed, ``[B, Sk, Sq /
+    8]``.
     One launch whatever the shape. Where dq is added to in
     place (:func:`dq_mode`) it accumulates across the k-block axis, which is
     then ``"arbitrary"``; the folded-row axis stays ``"parallel"`` (a chip
@@ -849,9 +864,12 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
                 lambda b, j, g, i, *prefetch: (
                     b // kvH, 0, q_blk(b, i, j, prefetch))),
         ]
-    sel_spec = None if sel_t is None else pl.BlockSpec(
-        (1, bk, bq), lambda b, j, g, i, *prefetch: (
-            b // kvH, j, q_blk(b, i, j, prefetch)))
+    sel_spec = None
+    if sel_t is not None:
+        packed, shared = _attention.selection_tile(Sq, bq)
+        sel_spec = pl.BlockSpec(
+            (1, bk, packed), lambda b, j, g, i, *prefetch: (
+                b // kvH, j, q_blk(b, i, j, prefetch) // shared))
     prefetch = (info, slopes) + (() if table is None else (table.reshape(-1),))
     if in_place:
         # dq starts at zero and is the launch's own to add to, wherever
@@ -938,8 +956,8 @@ def _flash(cfg: FlashConfig, q, k, v, segs, slopes, info, sel=None):
     """``segs`` = (q ids as columns, k ids as rows, the forward tiles' table
     of documents, k ids as columns, q ids as rows, the backward tiles'
     table) or six Nones: the forward reads the first three, the backward
-    (transposed tiles) the others. ``sel``: a selection's operand ``[B, Sq,
-    Sk]`` int8 (``FlashConfig.selected``; the backward transposes it)."""
+    (transposed tiles) the others. ``sel``: a selection's operand, int8 ``[B,
+    Sq / 8, Sk]`` (``FlashConfig.selected``; the backward transposes it)."""
     return _fwd_call(cfg, q, k, v, *segs[:3], slopes, info, sel)
 
 
@@ -1132,8 +1150,10 @@ def launch_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
       ONE limit), else that choice under a q tile of one window;
     - ``selected`` (a learned selection's operand, ``FlashConfig.selected``;
       causal, as many keys as queries): the causal choice, its tiles as a
-      full layer's, with the scoped VMEM a tile of the operand adds (the int8
-      block twice, its int32 copy once)."""
+      full layer's, where both q tiles are whole bit planes of the operand
+      (``attention.selection_tile``), with the scoped VMEM a tile of it adds
+      (the packed block twice, its int32 copy and the unpacked planes: under 6
+      bytes a pair)."""
     choose = functools.partial(choose_tiles, head_dim=head_dim, itemsize=itemsize,
                                block_k=block_k, compiled=compiled)
     if blockdiff is not None:
@@ -1153,7 +1173,8 @@ def launch_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
         return None
     tiles = choose(sq, sk, causal=causal, window=window, block_q=block_q)
     if selected and tiles is not None:
-        if not causal or window is not None or sq != sk:
+        if not causal or window is not None or sq != sk or not all(
+                _attention.selection_tile(sq, t[0], compiled) for t in (tiles.fwd, tiles.bwd)):
             return None
         need = max(
             tile_vmem_bytes(t, head_dim, itemsize, backward=back) + 6 * t[0] * t[1]
@@ -1346,13 +1367,18 @@ def flash_attention_with_lse(
     of the first window none: 0 with the sentinel LSE). ``tag`` names the
     launches ``flash_fwd_<tag>`` / ``flash_bwd_<tag>``.
 
-    ``selected``: int8 ``[B, Sq, Sk]``, 1 where the query's learned selection
-    holds the key (``attention.select_topk``), one selection for all heads: a
-    tile reads its block of it beside the causal and same-document rule
+    ``selected``: the bits of "the query's learned selection holds the key"
+    (``attention.select_topk``; int8 ``[B, Sq / 8, Sk]`` as
+    ``attention.pack_selection`` lays them out), one selection for all heads: a
+    tile unpacks its block of it beside the causal and same-document rule
     (launches ``flash_fwd_dsa`` / ``flash_bwd_dsa``); tiles are skipped by
     position and documents as without it.
     """
     B, Sq, H, D = q.shape
+    packed = (B, _attention.packed_rows(Sq), k.shape[1])
+    if selected is not None and selected.shape != packed:
+        raise ValueError(f"selected {selected.shape}: the operand of {Sq} queries over "
+                         f"{k.shape[1]} keys is bits, {packed} (attention.pack_selection)")
     cfg, q4, k3, v3, segs, slopes, info, dims = _prepare(
         q, k, v, causal, scale, segment_ids, q_segment_ids, alibi_slopes,
         window, q_offset, block_q, block_k, interpret, blockdiff, summaries, tag,

@@ -14,8 +14,9 @@ Two launches over the same (q-block, k-block) tiles, the q-block the outer axis:
 
 ``indexer_kl_fwd``: per tile, each main head's ``exp(k q^T x scale - lse)``
 summed and divided by the heads -> ``p``; each indexer head's ``ReLU(k_idx
-q_idx^T) x w`` summed -> ``I``; both under the tile's block of the int8
-selection. Across a q-block's k-blocks, online: the log-sum-exp of ``I`` over
+q_idx^T) x w`` summed -> ``I``; both under the tile's bit planes of the
+selection (``attention.unpack_selection`` of the packed block that holds
+them). Across a q-block's k-blocks, online: the log-sum-exp of ``I`` over
 the picked keys (``lse_I``), ``P = sum p``, ``A = sum p log p`` (0 where ``p ==
 0``) and ``C = sum p I``; a row's KL is ``A - C + P x lse_I``. Out: one float32
 ``[B, 8, L]`` array of per-query ROWS (`ROWS`: the running statistics where
@@ -37,8 +38,8 @@ per-query value (a head's ``lse``, ``w_j``, ``P``, ``lse_I``, the running
 statistics) is then a row that broadcasts over sublanes, a sum over keys runs
 down the sublanes, and no matmul has a transposed left operand (dq is made
 transposed, ``k_idx^T g_j``, ``[d, block_q]`` a head). The callers' arrays are
-laid out once a launch in XLA (heads leading; the selection, ``k_idx`` and ``w``
-transposed).
+laid out once a launch in XLA (heads leading; the selection's packed bytes,
+``k_idx`` and ``w`` transposed).
 
 The arithmetic is ``_kl_rows``': matmul operands in the operands' dtype with
 float32 accumulation, every ``exp``, ``log``, ReLU x w sum and reduction in
@@ -57,6 +58,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import attention as _attention
 from . import pallas_flash as _pf
 from .pallas_flash import HALF_MASK, MASK_VALUE, NUM_LANES, Tile, block_ranges
 
@@ -101,7 +103,8 @@ def choose_tile(length: int, compiled: bool = True, block_q: Optional[int] = Non
         return None
     if compiled and (bq % NUM_LANES or bk % NUM_LANES):
         return None
-    return bq, bk
+    # a q-block is whole bit planes of the selection's operand
+    return (bq, bk) if _attention.selection_tile(length, bq, compiled) else None
 
 
 def vmem_bytes(tile: Tile, length: int, heads: int, kv_heads: int, head_dim: int,
@@ -137,13 +140,14 @@ def _visible(cfg: KLConfig, blocks, b, i, j, prefetch):
     return _pf._should_run(flash, cfg.tile, i, j, prefetch[0], docs)
 
 
-def _tile(cfg: KLConfig, q_ref, k_ref, lse_ref, sel_ref, qi_ref, ki_ref, w_ref,
-          relu_scr=None):
-    """One tile, transposed: (picked ``[bk, bq]`` bool, ``p``, ``I``), float32.
-    ``relu_scr``: where each indexer head's ``ReLU(dots)`` is kept."""
+def _tile(cfg: KLConfig, i, rows: int, q_ref, k_ref, lse_ref, sel_ref, qi_ref, ki_ref,
+          w_ref, relu_scr=None):
+    """One tile of q-block ``i`` of ``rows`` queries, transposed: (picked ``[bk,
+    bq]`` bool, ``p``, ``I``), float32. ``relu_scr``: where each indexer head's
+    ``ReLU(dots)`` is kept."""
     f32 = jnp.float32
     group = cfg.heads // cfg.kv_heads
-    picked = sel_ref[0].astype(jnp.int32) != 0
+    picked = _attention.unpack_selection(sel_ref[0], rows, (cfg.tile[0], i), axis=1)
     lse = lse_ref[0]                                        # [H, bq]
     total = None
     for kh in range(cfg.kv_heads):
@@ -192,8 +196,8 @@ def _fwd_kernel(*refs, cfg: KLConfig, blocks: Tuple[int, int]):
 
     @pl.when(_visible(cfg, blocks, b, i, j, prefetch))
     def _compute():
-        picked, p, scores = _tile(cfg, q_ref, k_ref, lse_ref, sel_ref, qi_ref,
-                                  ki_ref, w_ref)
+        picked, p, scores = _tile(cfg, i, blocks[0] * cfg.tile[0], q_ref, k_ref, lse_ref,
+                                  sel_ref, qi_ref, ki_ref, w_ref)
         over = lambda x: jnp.sum(x, axis=0, keepdims=True)
         masked = jnp.where(picked, scores, MASK_VALUE)
         m_prev = _row(out_ref, "max")
@@ -241,8 +245,8 @@ def _bwd_kernel(*refs, cfg: KLConfig, blocks: Tuple[int, int]):
 
     @pl.when(_visible(cfg, blocks, b, i, j, prefetch))
     def _compute():
-        picked, p, scores = _tile(cfg, q_ref, k_ref, lse_ref, sel_ref, qi_ref,
-                                  ki_ref, w_ref, relu_scr)
+        picked, p, scores = _tile(cfg, i, blocks[0] * cfg.tile[0], q_ref, k_ref, lse_ref,
+                                  sel_ref, qi_ref, ki_ref, w_ref, relu_scr)
         r = jnp.exp(jnp.where(picked, scores, MASK_VALUE) - _row(rows_ref, "lse_I"))
         di = jnp.where(picked, _row(rows_ref, "P") * r - p, 0.0)     # [bk, bq]
         w = w_ref[0].astype(f32)
@@ -267,12 +271,13 @@ def _bwd_kernel(*refs, cfg: KLConfig, blocks: Tuple[int, int]):
 def _operands(cfg: KLConfig, q_idx, k_idx, w, q, k, lse, selected, documents):
     """The callers' arrays as the tiles read them -> (prefetch, operands,
     specs, the launch's (q-blocks, k-blocks), the index map's k-block): heads
-    leading, the selection and ``w`` transposed."""
-    L = selected.shape[1]
+    leading, the selection's packed bytes and ``w`` transposed."""
+    L = q.shape[1]
     bq, bk = cfg.tile
     blocks = L // bq, L // bk
     H, D = q.shape[2:]
     J, d = q_idx.shape[2:]
+    packed, shared = _attention.selection_tile(L, bq)
     prefetch = (jnp.zeros((2,), jnp.int32),)
     if cfg.documents:
         ids = documents.astype(jnp.int32)
@@ -290,7 +295,8 @@ def _operands(cfg: KLConfig, q_idx, k_idx, w, q, k, lse, selected, documents):
         pl.BlockSpec((1, k.shape[2], bk, D),
                      lambda b, i, j, *pre: (b, 0, k_blk(b, i, j, pre), 0)),
         pl.BlockSpec((1, H, bq), lambda b, i, j, *_: (b, 0, i)),
-        pl.BlockSpec((1, bk, bq), lambda b, i, j, *pre: (b, k_blk(b, i, j, pre), i)),
+        pl.BlockSpec((1, bk, packed),
+                     lambda b, i, j, *pre: (b, k_blk(b, i, j, pre), i // shared)),
         pl.BlockSpec((1, J, bq, d), lambda b, i, j, *_: (b, 0, i, 0)),
         pl.BlockSpec((1, bk, d), lambda b, i, j, *pre: (b, k_blk(b, i, j, pre), 0)),
         pl.BlockSpec((1, J, bq), lambda b, i, j, *_: (b, 0, i)),
@@ -305,7 +311,7 @@ def _params(cfg: KLConfig, semantics):
 
 def _fwd_call(cfg: KLConfig, q_idx, k_idx, w, q, k, lse, selected, documents):
     """-> the forward's `ROWS`, float32 ``[B, 8, L]``."""
-    B, L = selected.shape[:2]
+    B, L = q.shape[:2]
     prefetch, operands, specs, blocks, _ = _operands(
         cfg, q_idx, k_idx, w, q, k, lse, selected, documents)
     rows = pl.BlockSpec((1, N_ROWS, cfg.tile[0]), lambda b, i, j, *_: (b, 0, i))
@@ -323,7 +329,7 @@ def _fwd_call(cfg: KLConfig, q_idx, k_idx, w, q, k, lse, selected, documents):
 
 def _bwd_call(cfg: KLConfig, q_idx, k_idx, w, q, k, lse, selected, documents, rows):
     """-> (dq ``[B, L, J, d]``, dk ``[B, L, d]``, dw ``[B, L, J]``), float32."""
-    B, L = selected.shape[:2]
+    B, L = q.shape[:2]
     bq, bk = cfg.tile
     J, d = q_idx.shape[2:]
     prefetch, operands, specs, blocks, k_blk = _operands(
@@ -367,7 +373,8 @@ def value(q_idx, k_idx, w, q, k, lse, selected, documents, scale: float, tile: T
           interpret: Optional[bool] = None):
     """``attention.indexer_kl``'s value by the forward launch alone. q_idx [B,
     L, J, d], k_idx [B, L, d], w [B, L, J], q [B, L, H, D], k [B, L, kvH, D],
-    lse [B, H, L] float32, selected int8 [B, L, L], documents [B, L] int or
+    lse [B, H, L] float32, selected the operand of ``attention.dsa_select``
+    (bits, int8 [B, L / 8, L]), documents [B, L] int or
     None (tiles are then skipped by position alone)."""
     cfg = _config(q_idx, q, k, scale, documents, tile, interpret)
     rows = _fwd_call(cfg, q_idx, k_idx, w, q, k, lse, selected, documents)

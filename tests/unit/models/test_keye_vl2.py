@@ -129,8 +129,9 @@ def test_the_selection_is_the_references(parts):
         block = jax.tree.map(lambda a: a[0], params["blocks"])
         x, _ = model.embed(params, ids)
         h = model._layer("ln_1")(block["ln_1"], x)
-        got = np.asarray(model.selection(block, h, jnp.arange(64)[None],
-                                         model._documents(ids))[3]) != 0
+        packed = model.selection(block, h, jnp.arange(64)[None], model._documents(ids))[3]
+        got = np.asarray(attention.unpack_selection(packed, 64))
+    assert packed.dtype == jnp.int8 and packed.shape == (ids.shape[0], 8, 64)
     np.testing.assert_array_equal(got, want)
     doc = np.asarray(model._documents(ids))
     seen = (np.arange(64)[None, :] <= np.arange(64)[:, None])[None] & (
@@ -253,7 +254,8 @@ def test_first_step_through_initialize(parts):
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}}})
     assert engine.attn_totals["dsa"] == {
         "topk": 8, "indexer_heads": 2, "indexer_head_dim": 8, "route": None,
-        "select": attention.SELECT_THRESHOLD, "dq": None, "kl": None, "kl_tiles": None}
+        "select": attention.SELECT_THRESHOLD, "dq": None, "kl": None, "kl_tiles": None,
+        "operand": "bits", "operand_bytes": None}
     assert engine.attn_last_step() is None
     # (a row a device of the test mesh)
     batch = {"input_ids": np.concatenate([np.asarray(ids), np.asarray(ids)[:, ::-1]]
@@ -274,6 +276,8 @@ def test_first_step_through_initialize(parts):
     assert wrong / total < 0.01
     assert engine.attn_totals["dsa"]["route"] == "xla" and engine.attn_totals["dsa"]["dq"] is None
     assert engine.attn_totals["dsa"]["kl"] == "xla" and engine.attn_totals["dsa"]["kl_tiles"] is None
+    # a layer's operand over the step's rows: a bit a (query, key) pair
+    assert engine.attn_totals["dsa"]["operand_bytes"] == len(rows) * 64 * 64 // 8
     last = engine.attn_last_step()
     assert last["indexer_kl"] == pytest.approx(float(kl), rel=1e-4)
     assert last["lm_loss"] == pytest.approx(float(lm), rel=1e-4)
@@ -282,6 +286,8 @@ def test_first_step_through_initialize(parts):
     from deepspeed_tpu.telemetry import setup_spans
     flat = setup_spans.flat_totals(attn=engine.attn_totals)
     assert flat["attn.dsa.topk"] == 8 and flat["attn.dsa.select"] == attention.SELECT_THRESHOLD
+    assert flat["attn.dsa.operand"] == "bits"
+    assert flat["attn.dsa.operand_bytes"] == len(rows) * 64 * 64 // 8
 
 
 def test_the_kl_counters_on_the_kernel_route(parts, monkeypatch):

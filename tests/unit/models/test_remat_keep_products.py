@@ -410,6 +410,67 @@ def test_the_cells_keep_what_the_chip_has_room_for(cell):
         long in cell for long in ("seq8k", "seq16k", "seq32k"))
 
 
+@pytest.fixture(scope="module")
+def keye_cell_candidates():
+    """name -> bytes over the eight layers of what the
+    ``keye-vl2-30b-a3b.train.dsa16k`` cell's block names, read by
+    ``named_bytes`` off the block's own jaxpr at the cell's shapes (one row of
+    16,384 at the published widths, bf16; shapes alone, no chip): what
+    ``choose_saved`` is handed on the kernel route."""
+    import json
+    import os
+    from benchmark.adapters import keye_vl2 as adapter
+    from tests.benchmark.helpers import REPO
+    with open(os.path.join(REPO, "benchmark", "configs", "keye-vl2-30b-a3b.json")) as f:
+        model = adapter.model(json.load(f), remat=True, dtype="bfloat16")
+    seen = []
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        patch.delenv("DSTPU_ATTN", raising=False)
+        patch.setattr(checkpointing, "choose_saved", lambda candidates, budget: (
+            seen.append(dict(candidates)), choose_saved(candidates, budget))[1])
+        params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.bfloat16))
+        jax.make_jaxpr(jax.grad(lambda p, ids: model.loss(
+            p, {"input_ids": ids}, remat_budget=Budget(None))))(
+                params, jax.ShapeDtypeStruct((1, 16384), jnp.int32))
+    (candidates,) = seen
+    return candidates
+
+
+def test_the_selections_operand_is_a_bit_a_pair_at_the_keye_cell(keye_cell_candidates):
+    """``dsa_mask`` is ``L x L / 8`` bytes a row and layer (PR 50; a byte a
+    pair it was 2,147.5 MB over the eight layers, more than the whole budget),
+    beside the selected launch's pair and the KL's three gradients."""
+    L, layers = 16384, 8
+    named = keye_cell_candidates
+    assert named["dsa_mask"] == layers * L * L // 8 == 268_435_456
+    assert named["attn_o_dsa"] == layers * L * 32 * 128 * 2
+    assert named["attn_lse_dsa"] == layers * 32 * L * 4
+    assert sum(named[n] for n in SAVE_ORDER[0]) == 293_601_280
+
+
+@pytest.mark.parametrize("budget_mb,groups,last", [
+    (1927.5, 3, "moe_logits"),      # the parent's budget on the chip (PERF.md, PR 49)
+    (1652.6, 3, "attn_o_dsa"),      # the three leading groups alone: 293.6 + 268.4 + 1,090.5
+    (1600.0, 2, "dsa_mask"),        # the operand fits, the selected pair's results do not
+    (500.0, 1, "indexer_kl_dw"),
+    (2122.4, 3, "wi_up"),           # the experts' first products: 116 MB past the chip's ~2,006
+])
+def test_the_keye_cell_keeps_the_three_leading_groups(keye_cell_candidates, budget_mb,
+                                                      groups, last):
+    """Under the cell's budget `choose_saved` takes the KL's gradients, the
+    operand and the selected launch's pair (1,652.5 MB) by the rule it has,
+    `SAVE_ORDER`, `STACK_COST` and `WORKING_SHARE` as they were; the router's
+    float32 logits (67.1 MB, the next group the cell's names hold) ride along
+    where they fit, and the experts' first products (402.6 MB) do not."""
+    saved = choose_saved(keye_cell_candidates, int(budget_mb * 1e6))
+    leading = [n for group in SAVE_ORDER[:groups] for n in group]
+    assert list(saved[:len(leading)]) == leading and saved[-1] == last
+    assert set(saved) - set(leading) <= {"moe_logits", "wi_gate", "wi_up"}
+    if groups < 3:
+        assert len(saved) == len(leading)
+
+
 @pytest.mark.parametrize("family", ["dense", "rope_gated", "moe_qk_norm"])
 def test_scores_are_never_a_candidate(monkeypatch, family):
     """The XLA routes' [B, H, S, S] tensors stay recomputed: with no budget

@@ -122,7 +122,8 @@ class TestCompilesForV5e:
     def test_selected_attention_at_16k(self, chip, monkeypatch):
         """The keye-vl2-30b-a3b cell's attention core: 32 query heads over 4
         key heads of 128, one row of 16,384 under documents, the flash pair
-        reading the selection's int8 operand, under the launches' own names."""
+        unpacking the selection's operand (bits: int8 ``[1, 2048, 16384]``), under
+        the launches' own names."""
         from deepspeed_tpu.ops.transformer import attention
         B, L, H, kvH, D = 1, 16384, 32, 4, 128
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -133,7 +134,7 @@ class TestCompilesForV5e:
             return jnp.sum(o.astype(F32)) + jnp.sum(lse)
 
         args = (chip((B, L, H, D), BF16), chip((B, L, kvH, D), BF16),
-                chip((B, L, kvH, D), BF16), chip((B, L, L), jnp.int8), chip((B, L), I32))
+                chip((B, L, kvH, D), BF16), chip((B, L // 8, L), jnp.int8), chip((B, L), I32))
         fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
         compile_for_chip(fn, *args)
         text = jax.jit(fn).lower(*args).as_text()
@@ -142,7 +143,7 @@ class TestCompilesForV5e:
     def test_indexer_kl_at_16k(self, chip, monkeypatch):
         """The keye-vl2-30b-a3b cell's indexer objective: one row of 16,384, 32
         query heads over 4 key heads of 128, an indexer of 16 heads of 64, the
-        selection's int8 operand: the differentiated forward launches the
+        selection's operand of bits: the differentiated forward launches the
         Pallas pair, value and three gradients, under the launches' own names."""
         from deepspeed_tpu.ops.transformer import attention
         B, L, H, kvH, D, J, d = 1, 16384, 32, 4, 128, 16, 64
@@ -156,7 +157,7 @@ class TestCompilesForV5e:
 
         args = (chip((B, L, J, d), BF16), chip((B, L, d), BF16), chip((B, L, J), F32),
                 chip((B, L, H, D), BF16), chip((B, L, kvH, D), BF16),
-                chip((B, H, L), F32), chip((B, L, L), jnp.int8), chip((B, L), I32))
+                chip((B, H, L), F32), chip((B, L // 8, L), jnp.int8), chip((B, L), I32))
         fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
         compile_for_chip(fn, *args)
         text = jax.jit(fn).lower(*args).as_text()

@@ -570,6 +570,9 @@ def test_route_rule_is_shape_and_platform_only(q_shape, k_shape, monkeypatch):
     ((4, 64, 4, 16), ("dsa", 2, 8), "cpu", "pallas", "kernel"),       # the tiny preset, interpret
     ((4, 64, 4, 16), ("dsa", 2, 8), "tpu", "pallas", "xla"),          # tiles off the lanes
     ((1, 128, 32, 128), ("dsa", 4, 2048), "tpu", "", "xla"),          # under the crossover
+    ((1, 1024, 32, 128), ("dsa", 4, 2048), "tpu", "", "kernel"),      # one group of the operand's bits
+    ((1, 512, 32, 128), ("dsa", 4, 2048), "tpu", "", "xla"),          # a plane under the chip's 128 lanes
+    ((1, 512, 32, 128), ("dsa", 4, 2048), "cpu", "pallas", "kernel"),  # interpret mode takes it
     # the evabyte-6.5b cell: EVA's mask (("eva", window, chunk) in the key
     # heads' place: as many key heads as query heads), 32 x 128 at 32,768, and
     # a group of 4 of its heads, which is what one launch of the cell holds
@@ -1333,7 +1336,9 @@ def test_selected_launch_matches_the_masked_softmax(tiles, documents, dq):
     docs = jnp.asarray(np.stack([np.arange(L) >= 100, np.arange(L) >= 37]).astype(np.int32))
     docs = docs if documents else jnp.zeros_like(docs)
     sel = attn_mod.dsa_select(f(B, L, J, d), f(B, L, d), f(B, L, J), docs, K)
-    assert sel.dtype == jnp.int8 and int(sel[0, 5].sum()) == 6 and int(sel[1, 200].sum()) == K
+    picked = attn_mod.unpack_selection(sel, L)
+    assert sel.dtype == jnp.int8 and sel.shape == (B, L // 8, L)
+    assert int(picked[0, 5].sum()) == 6 and int(picked[1, 200].sum()) == K
     made = pf.launch_tiles(L, L, D, 4, selected=True, block_q=tiles[0], block_k=tiles[1],
                            compiled=False)
     assert pf.dq_mode(L, L, made) == dq
@@ -1343,7 +1348,8 @@ def test_selected_launch_matches_the_masked_softmax(tiles, documents, dq):
             o, lse = fn(q, k, v)
             return jnp.sum(o * jnp.cos(o)) + jnp.sum(jnp.sin(lse))
         return jax.value_and_grad(loss, argnums=(0, 1, 2))
-    want = both(lambda q, k, v: attn_mod._xla_selected_attention(q, k, v, sel, D ** -0.5))(q, k, v)
+    want = both(lambda q, k, v: attn_mod._xla_selected_attention(
+        q, k, v, picked, D ** -0.5))(q, k, v)
     got = both(lambda q, k, v: pf.flash_attention_with_lse(
         q, k, v, causal=True, segment_ids=docs if documents else None, selected=sel,
         block_q=tiles[0], block_k=tiles[1], interpret=True))(q, k, v)
@@ -1355,7 +1361,7 @@ def test_selected_launch_matches_the_masked_softmax(tiles, documents, dq):
 def test_selected_launch_names_its_kernels_and_residuals_and_refuses_the_rest():
     from deepspeed_tpu.ops.transformer import pallas_flash as pf
     q = jnp.zeros((1, 128, 4, 16)); k = v = jnp.zeros((1, 128, 2, 16))
-    sel = jnp.ones((1, 128, 128), jnp.int8)
+    sel = jnp.full((1, 16, 128), -1, jnp.int8)     # every pair's bit
     fn = lambda q, k, v: jnp.sum(pf.flash_attention_with_lse(
         q, k, v, causal=True, selected=sel, interpret=True)[0])
     text = str(jax.make_jaxpr(jax.grad(fn, argnums=(0, 1, 2)))(q, k, v))
@@ -1365,6 +1371,10 @@ def test_selected_launch_names_its_kernels_and_residuals_and_refuses_the_rest():
         with pytest.raises(ValueError):
             pf.flash_attention_with_lse(q, k, v, **{"causal": True, **bad},
                                         selected=sel, interpret=True)
+    # a byte a pair (the operand before PR 50) is refused by name, not misread
+    with pytest.raises(ValueError, match="pack_selection"):
+        pf.flash_attention_with_lse(q, k, v, causal=True, interpret=True,
+                                    selected=jnp.ones((1, 128, 128), jnp.int8))
     # the tiles are a full causal layer's at the cell's shape, with the scoped
     # VMEM the operand's tile adds; no other launch kind's tiles move
     full = pf.launch_tiles(16384, 16384, 128)
@@ -1372,3 +1382,7 @@ def test_selected_launch_names_its_kernels_and_residuals_and_refuses_the_rest():
     assert (mine.fwd, mine.bwd) == (full.fwd, full.bwd) == ((512, 512), (1024, 1024))
     assert full.vmem_limit_bytes is None and mine.vmem_limit_bytes > pf.VMEM_BUDGET
     assert pf.dq_mode(16384, 16384, mine) == "in_place"
+    # a q tile that is not whole bit planes of the operand has no launch
+    assert pf.launch_tiles(256, 256, 32, 4, selected=True, block_q=16, block_k=64,
+                           compiled=False) is None
+    assert pf.launch_tiles(256, 256, 32, 4, block_q=16, block_k=64, compiled=False) is not None

@@ -47,13 +47,18 @@ def operands(L=64, H=4, kvH=2, D=16, J=2, d=8, topk=16, cuts=(0,), dtype=F32,
     documents = jnp.repeat(documents, rows, axis=0)
     picked = attention.dsa_select(q_idx, k_idx, w, documents, topk)
     scale = D ** -0.5
-    _, lse = attention._xla_selected_attention(q, k, v, picked, scale)
+    _, lse = attention._xla_selected_attention(q, k, v, unpacked(picked), scale)
     return q_idx, k_idx, w, q, k, lse + lse_shift, picked, documents, scale
+
+
+def unpacked(picked):
+    """The packed operand of whole rows as bool ``[rows, L, L]``."""
+    return attention.unpack_selection(picked, picked.shape[-1])
 
 
 def reference(q_idx, k_idx, w, q, k, lse, picked, documents, scale):
     up = lambda a: a.astype(F32)
-    f = lambda a, b, c: attention._kl_rows(a, c, up(q), lse, picked, b, up(k), scale)
+    f = lambda a, b, c: attention._kl_rows(a, c, up(q), lse, unpacked(picked), b, up(k), scale)
     return jax.value_and_grad(f, argnums=(0, 1, 2))(up(q_idx), up(k_idx), up(w))
 
 
@@ -113,8 +118,8 @@ def test_the_underflow_case_holds_a_picked_pair_with_p_zero():
     q_idx, k_idx, w, q, k, lse, picked, _, scale = operands(**CASES["a_picked_p_underflows"])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, axis=2)) * scale
     p = jnp.mean(jnp.exp(logits - lse[..., None]), axis=1)
-    assert bool(jnp.any((picked != 0) & (p == 0)))
-    assert bool(jnp.all(jnp.sum(jnp.where(picked != 0, p, 0.0), -1) > 0.5))
+    assert bool(jnp.any(unpacked(picked) & (p == 0)))
+    assert bool(jnp.all(jnp.sum(jnp.where(unpacked(picked), p, 0.0), -1) > 0.5))
 
 
 def test_a_shifted_lse_leaves_the_rows_sum_off_one():
@@ -133,7 +138,7 @@ def test_bf16_operands(dtype, cuts):
     one rounding of its dtype."""
     args = operands(dtype=dtype, cuts=cuts)
     q_idx, k_idx, w, q, k, lse, picked, documents, scale = args
-    f = lambda a, b, c: attention._kl_rows(a, c, q, lse, picked, b, k, scale)
+    f = lambda a, b, c: attention._kl_rows(a, c, q, lse, unpacked(picked), b, k, scale)
     want, grads = jax.value_and_grad(f, argnums=(0, 1, 2))(q_idx, k_idx, w)
     got, (dq, dk, dw) = kl.value_and_gradients(*args, (16, 16))
     assert float(got) == pytest.approx(float(want), rel=1e-5)
@@ -153,7 +158,7 @@ def test_a_tile_in_another_document_is_skipped(documents):
     args = list(operands(cuts=(0, 32), topk=64, rows=1))
     q_idx, k_idx, w, q, k, lse, picked, docs, scale = args
     want, grads = reference(*args)
-    unsound = picked.at[:, 32:, :32].set(1)
+    unsound = attention.pack_selection(unpacked(picked).at[:, 32:, :32].set(True))
     got, (dq, dk, dw) = kl.value_and_gradients(
         q_idx, k_idx, w, q, k, lse, unsound, docs if documents else None, scale, (16, 16))
     if not documents:
@@ -178,7 +183,7 @@ def test_tiles_run_is_the_kernels_own_count(cuts, tile, run):
     q_idx, k_idx, w, q, k, lse, picked, docs, scale = operands(cuts=cuts, rows=1)
     counted = pallas_flash.tiles_run(docs, docs, tile)
     assert int(counted[1]) == run and kl.tiles_of(1, 64, tile) == (64 // tile[0]) * (64 // tile[1])
-    everything = jnp.ones_like(picked)
+    everything = jnp.full_like(picked, -1)      # every bit set
     cfg = kl._config(q_idx, q, k, scale, docs, tile, None)
     rows = kl._fwd_call(cfg, q_idx, k_idx, w, q, k, jnp.zeros_like(lse), everything, docs)
     # with lse 0 and every pair "picked" a tile that runs adds its p > 0 to P
@@ -202,8 +207,9 @@ def _runs(cuts, tile, i, j):
 
 
 @pytest.mark.parametrize("length,compiled,want", [
-    (16384, True, (256, 256)), (8192, True, (256, 256)), (512, True, (256, 256)),
-    (384, True, (384, 384)), (128, True, (128, 128)), (1280, True, (256, 256)),
+    (16384, True, (256, 256)), (8192, True, (256, 256)), (1280, True, (256, 256)),
+    # on the chip a bit plane of the operand is 128 lanes: rows over 1,016
+    (512, True, None), (384, True, None), (128, True, None), (512, False, (256, 256)),
     (640, True, None),          # only 128 and 640 divide it: a side over the cap
     (200, True, None), (64, False, (64, 64)), (64, True, None), (96, False, (96, 96)),
 ])
@@ -212,7 +218,8 @@ def test_choose_tile(length, compiled, want):
 
 
 @pytest.mark.parametrize("backend,mode,length,want", [
-    ("tpu", "", 16384, "kernel"), ("tpu", "", 512, "kernel"),
+    ("tpu", "", 16384, "kernel"), ("tpu", "", 1024, "kernel"),
+    ("tpu", "", 512, "xla"),            # the operand's planes are 128 lanes on the chip
     ("tpu", "xla", 16384, "xla_chunked"), ("tpu", "xla", 512, "xla"),
     ("tpu", "", 128, "xla"),            # under the flash pair's crossover
     ("cpu", "", 16384, "xla"), ("cpu", "pallas", 64, "kernel"),

@@ -16,8 +16,10 @@ bf16 operands), one layer:
   rows for scale; x 32 a layer. Every form's picks are compared with the
   first's: ``same_as_first``;
 - ``select``: ``attention.dsa_select``, the layer's whole pass (scores and
-  selection over the 32 blocks, the int8 operand out);
-- ``core_dsa`` against ``core_causal``: the flash pair whose tiles read the
+  selection over the 32 blocks, the operand out: bits since PR 50, with its
+  ``operand_bytes``), and ``operand_transpose``, the copy the transposed
+  readers (``flash_bwd_dsa``, the KL pair) are handed;
+- ``core_dsa`` against ``core_causal``: the flash pair whose tiles unpack the
   selection's operand against the plain causal pair over the same heads and
   documents (forward, and forward + backward);
 - ``kl``: ``attention.indexer_kl``'s differentiated forward (value and the
@@ -107,6 +109,9 @@ def main() -> int:
             timed[f"threshold_{how}"]["picked_a_row"] = float(jnp.mean(jnp.sum(got, -1)))
     timing("threshold_sort", lambda s: jnp.sort(s, axis=-1)[..., -K], scores, times=blocks)
     timing("select", lambda: attention.dsa_select(q_idx, k_idx, w, docs, K))
+    timing("operand_transpose", lambda p: jnp.swapaxes(p, 1, 2), picked)
+    for name in {"select", "operand_transpose"} & set(timed):
+        timed[name]["operand_bytes"] = picked.size * picked.dtype.itemsize
     total = lambda pair: jnp.sum(pair[0].astype(jnp.float32))
     dsa = lambda q, k, v: total(flash_attention_with_lse(
         q, k, v, causal=True, segment_ids=docs, selected=picked))
@@ -116,8 +121,8 @@ def main() -> int:
         timing(name + "_forward", fn, q, k, v)
         timing(name + "_forward_backward", jax.grad(fn, argnums=(0, 1, 2)), q, k, v)
     from deepspeed_tpu.ops.transformer import pallas_indexer_kl
-    # (the target and the operand as arguments: a closed-over 268 MB constant is
-    # compiled into every case's program, a minute each)
+    # (the target and the operand as arguments: a closed-over constant of tens
+    # of MB is compiled into every case's program)
     target = (q, k, lse, picked, docs)
     kl = lambda a, b, c, *target: attention.indexer_kl(a, b, c, *target, scale)
     launch, outs = attention.kl_launch, {}

@@ -49,7 +49,9 @@ def main() -> int:
         block = jax.tree.map(lambda a: a[0], params["blocks"])
         x, positions = model.embed(params, ids)
         h = model._layer("ln_1")(block["ln_1"], x)
-        return model.selection(block, h, positions, model._documents(ids))[3] != 0
+        from deepspeed_tpu.ops.transformer import attention
+        return attention.unpack_selection(
+            model.selection(block, h, positions, model._documents(ids))[3], ids.shape[1])
     got = jax.jit(mine)(adapter.to_program(weights), ids)
     count = lambda a: int(jnp.sum(a, dtype=jnp.int32))
     out = {"workload": args.workload, "seed": args.seed, "layer": 0,

@@ -2065,8 +2065,9 @@ class TransformerLM:
         """``(attn, diffusion)``: what an engine keeps as ``attn_totals`` and
         ``diffusion_totals`` (docs/OBSERVABILITY.md has every key; None for
         another objective). Without a shape what the configuration says; with
-        the rows and tokens a step is traced for, also each kind's route and
-        how its backward makes dq, off the launches' own plans."""
+        the rows and tokens a step is traced for, also each kind's route, how
+        its backward makes dq and where its launches take the operands' heads
+        (``layout``), off the launches' own plans."""
         c = self.config
         windows = sorted({w for w, _ in self._kinds if w})
         layers = {"window": sum(1 for w, _ in self._kinds if w),
@@ -2076,31 +2077,36 @@ class TransformerLM:
                 "kv_heads": c.kv_heads,
                 "documents": c.document_separator is not None,
                 "route": {"window": None, "full": None},
-                "dq": {"window": None, "full": None}}
+                "dq": {"window": None, "full": None},
+                "layout": {"window": None, "full": None}}
         if c.attention == "eva":
             attn["eva"] = {"window": c.eva_window, "chunk": c.eva_chunk,
                            "summaries_a_row": None, "pred_heads": c.pred_heads,
-                           "route": None, "dq_local": None, "dq_far": None}
+                           "route": None, "dq_local": None, "dq_far": None,
+                           "layout": None}
         if c.indexer is not None:
             from ..ops.transformer.attention import SELECT_THRESHOLD, kl_launch, packed_rows
             attn["dsa"] = {"topk": c.indexer.topk, "indexer_heads": c.indexer.heads,
                            "indexer_head_dim": c.indexer.head_dim, "route": None,
-                           "select": SELECT_THRESHOLD, "dq": None,
+                           "select": SELECT_THRESHOLD, "dq": None, "layout": None,
                            "kl": None, "kl_tiles": None,
                            "operand": "bits", "operand_bytes": None}
         diffusion = {"block_length": c.block_length, "rows_per_token": self.rows_per_token,
-                     "route": None, "dq": None} if c.diffusion else None
+                     "route": None, "dq": None, "layout": None} if c.diffusion else None
         if seq is None:
             return attn, diffusion
         plans = {w: self._attention_plan(batch, seq, w) for w in [0] + windows}
         if c.diffusion:
-            diffusion.update(route=plans[0].route, dq=plans[0].dq("blockdiff"))
+            diffusion.update(route=plans[0].route, dq=plans[0].dq("blockdiff"),
+                             layout=plans[0].layout("blockdiff"))
         elif c.attention == "eva":
             attn["eva"].update(
                 summaries_a_row=seq // c.eva_chunk, route=plans[0].route,
-                dq_local=plans[0].dq("eva_local"), dq_far=plans[0].dq("eva_far"))
+                dq_local=plans[0].dq("eva_local"), dq_far=plans[0].dq("eva_far"),
+                layout=plans[0].layout("eva_local"))
         elif c.indexer is not None:
             attn["dsa"].update(route=plans[0].route, dq=plans[0].dq("dsa"),
+                               layout=plans[0].layout("dsa"),
                                kl=kl_launch(plans[0], seq)[0],
                                operand_bytes=batch * packed_rows(seq) * seq)
         else:
@@ -2111,6 +2117,9 @@ class TransformerLM:
             attn["dq"] = {
                 "window": ("+".join(under) or None) if layers["window"] else None,
                 "full": plans[0].dq("flash") if layers["full"] else None}
+            attn["layout"] = {
+                "window": plans[windows[0]].layout("flash") if layers["window"] else None,
+                "full": plans[0].layout("flash") if layers["full"] else None}
         return attn, diffusion
 
     def traced_rows_records(self, stats: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:

@@ -227,12 +227,17 @@ def choose_route(q_shape, k_shape, backend: str, mode: str,
 class Launch(NamedTuple):
     """One launch of the flash pair: its ``tag`` (``"flash"``, ``"blockdiff"``,
     ``"eva_local"``, ``"eva_far"``, ``"dsa"``), a batch row's queries and keys, the tiles
-    (``pallas_flash.launch_tiles``), the static window the grids are cut to."""
+    (``pallas_flash.launch_tiles``), the static window the grids are cut to, and
+    where the launch takes its query side's heads (q, ``o`` and their gradients;
+    ``pallas_flash.launch_layout``: ``"rows"``, as the projections leave them,
+    ``[B, S, heads x D]`` and no transpose around the launch; ``"heads"``,
+    transposed to lead, as the keys and values are in both)."""
     tag: str
     sq: int
     sk: int
     tiles: Any
     window: Optional[int] = None
+    layout: str = "heads"
 
 
 class Plan(NamedTuple):
@@ -243,12 +248,22 @@ class Plan(NamedTuple):
     route: str
     launches: Tuple[Launch, ...] = ()
 
+    def launch(self, tag: str) -> Optional[Launch]:
+        """The launch tagged ``tag``; None without one."""
+        return next((at for at in self.launches if at.tag == tag), None)
+
     def dq(self, tag: str) -> Optional[str]:
         """How the backward of the launch tagged ``tag`` makes dq
         (``pallas_flash.dq_mode``); None without such a launch."""
         from . import pallas_flash as _pf
-        return next((_pf.dq_mode(at.sq, at.sk, at.tiles, at.window)
-                     for at in self.launches if at.tag == tag), None)
+        at = self.launch(tag)
+        return None if at is None else _pf.dq_mode(at.sq, at.sk, at.tiles, at.window)
+
+    def layout(self, tag: str) -> Optional[str]:
+        """Where the launch tagged ``tag`` takes its query side's heads
+        (``Launch.layout``); None without such a launch."""
+        at = self.launch(tag)
+        return None if at is None else at.layout
 
 
 def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
@@ -257,7 +272,8 @@ def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
          selected: Optional[int] = None) -> Plan:
     """THE decision of the four entry points, a pure function of the two
     shapes, the platform, `attn_mode`'s value, the operands' size and the
-    mask, which says what the kernel route would launch:
+    mask, which says what the kernel route would launch, each launch with its
+    tiles and its layout (the entry point hands the kernel THIS layout):
 
     - `flash_attention` (``causal``, ``window``): one launch, its grids cut to
       a window that is static;
@@ -299,7 +315,7 @@ def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
         made = tuple(
             Launch(tag, sq, sk, _pf.launch_tiles(
                 sq, sk, q_shape[3], itemsize, compiled=compiled, **kind),
-                kind.get("window"))
+                kind.get("window"), _pf.launch_layout(q_shape, k_shape))
             for tag, sq, sk, kind in launches)
         if made and _pf.folds(q_shape, k_shape) and all(at.tiles for at in made):
             return Plan("kernel", made)
@@ -347,12 +363,14 @@ def flash_attention(q: jax.Array,
         (at,) = made.launches
         _log_path_once(
             "pallas_flash_inrepo, tiles (block_q x block_k) forward "
-            "%dx%d backward %dx%d%s" % (at.tiles.fwd + at.tiles.bwd + (
-                "" if at.window is None else f", grids cut to a window of {at.window}",)))
+            "%dx%d backward %dx%d, operands by %s%s" % (
+                at.tiles.fwd + at.tiles.bwd + (at.layout,) + (
+                    "" if at.window is None
+                    else f", grids cut to a window of {at.window}",)))
         return _pf.flash_attention_kernel(
             q, k, v, causal=causal, scale=scale,
             segment_ids=segment_ids, alibi_slopes=alibi_slopes,
-            window=window)
+            window=window, layout=at.layout)
     if mode == "pallas":
         # an explicit DSTPU_ATTN=pallas that cannot be honored must
         # not pass silently (round-1 review: perf regressions hide in
@@ -473,7 +491,7 @@ def blockdiff_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     o, lse = _pf.flash_attention_with_lse(
         q, k[:, :at.sk], v[:, :at.sk], causal=True, scale=scale, segment_ids=documents,
         q_segment_ids=jnp.concatenate([documents, documents], axis=1),
-        blockdiff=block_length)
+        blockdiff=block_length, layout=at.layout)
     own, own_lse = _own_block_attention(q[:, L:], k[:, L:], v[:, L:], documents,
                                         block_length, scale)
     noised, _ = _pf.merge_partials(o[:, L:], lse[:, :, L:], own, own_lse)
@@ -576,14 +594,15 @@ def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     windows = L // local.sq
     fold = lambda a: a.reshape((B * windows, local.sq) + a.shape[2:])
     o, lse = _pf.flash_attention_with_lse(fold(q), fold(k), fold(v), causal=True,
-                                          scale=scale, tag=local.tag)
+                                          scale=scale, tag=local.tag,
+                                          layout=local.layout)
     o = o.reshape(B, L, H, D)
     if not far:
         return o
     lse = lse.reshape(B, windows, H, local.sq).transpose(0, 2, 1, 3).reshape(B, H, L)
     far_o, far_lse = _pf.flash_attention_with_lse(
         q, kbar, vbar, causal=True, scale=scale,
-        summaries=(window, window // chunk), tag=far[0].tag)
+        summaries=(window, window // chunk), tag=far[0].tag, layout=far[0].layout)
     return _pf.merge_partials(o, lse, far_o, far_lse)[0]
 
 
@@ -835,9 +854,10 @@ def selected_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     _log_path_once(f"dsa {made.route}")
     if made.route == "kernel":
         from . import pallas_flash as _pf
+        (at,) = made.launches
         return _pf.flash_attention_with_lse(
             q, k, v, causal=True, scale=scale, segment_ids=documents.astype(jnp.int32),
-            selected=selected)
+            selected=selected, layout=at.layout)
     chunk = 1024 if made.route == "xla_chunked" and L % 1024 == 0 else L
     picked = unpack_selection(selected, L)
     parts = [_xla_selected_attention(q[:, lo:lo + chunk], k, v,

@@ -114,10 +114,34 @@ what the table spares, by the same predicate over every (i, j) at once.
 Runs in interpret mode off-TPU (``pl.pallas_call(interpret=True)``) so the
 CPU tier-1 tests validate numerics of the same program the chip runs.
 
-Layout conventions (GQA-folded, MXU-aligned tiles):
-  q  [B, Sq, H, D]   -> [B*kvH, G, Sq, D]
-  k,v[B, Sk, kvH, D] -> [B*kvH, Sk, D]
-  lse, di            -> [B*kvH, G, 1, Sq]  (fp32 rows)
+Layout conventions (`launch_layout`; MXU-aligned tiles; ``b`` the batch row,
+``kvh`` the key head, ``g`` the query head's place in its group of G):
+  the key side leads with its heads in EITHER layout (GQA-folded)
+    k, v, dk, dv  [B, Sk, kvH, D] -> [B*kvH, Sk, D]      (a transpose each)
+  the query side by ``FlashConfig.layout``:
+  ``"rows"`` (a head dim that is a multiple of the 128 lanes, query heads
+  grouped over fewer key heads): where the
+  projections leave it, heads side by side in a row; the index maps pick a
+  head's 128-aligned columns, and no transpose stands before a launch or
+  after it
+    q, o, do, dq  [B, Sq, H, D]   -> [B, 1, Sq, H*D]     (a reshape), a block
+                                     ``(1, 1, bq, D)`` at column block kvh*G + g
+  ``"heads"`` (a narrower head: ``(bq, 64)`` is half a lane tile; or as many
+  key heads as query heads): heads leading, a transpose before each launch and
+  after it
+    q, o, do, dq  [B, Sq, H, D]   -> [B*kvH, G, Sq, D]
+  either way a q-side operand has rank 4 and a k-side one rank 3, the grids
+  run over the folded rows ``b*kvH + kvh`` (one axis in ``"heads"``, the two
+  axes ``b`` and ``kvh`` in ``"rows"``: `_Row`; the same tiles, skipped steps
+  and order of accumulation) and
+    lse, di                       -> [B*kvH, G, 1, Sq]  (fp32 rows)
+  A dq that leaves a launch in float32 (`dq_mode` ``summed`` / ``in_place``)
+  leads with its heads in either layout, and by rows ``di`` is a launch of its
+  own (``flash_delta``). Why the keys are NOT read by rows, on the v5e (PR 51;
+  docs/KERNELS.md): a k-block is fetched every grid step, and as 4 KB pieces
+  of ``[B, Sk, kvH*D]`` the forward ran 15 % (a window's) to 27 % (a full
+  layer's) slower than over one run of HBM; a q-block is fetched once a row of
+  steps, and costs nothing that shows.
 Inside the forward the running max and sum are lane-replicated
 ``[block_q, 128]`` scratch (lane replication is free; the one sublane->lane
 transpose happens once a q block, when the LSE row is stored).
@@ -197,6 +221,10 @@ class FlashConfig:
     # Sk]``, ``attention.pack_selection``; the backward's transposed); a tile
     # unpacks its block of it beside the causal and same-document rule
     selected: bool = False
+    # where the query side's heads lie (q, o, do, dq; module docstring,
+    # `launch_layout`): ``"rows"``: ``[B, 1, S, heads * D]``, a head picked by
+    # the index maps; ``"heads"``: heads leading, ``[B * kv_heads, G, S, D]``
+    layout: str = "heads"
 
 
 def _lanes(x: jax.Array, n: int) -> jax.Array:
@@ -328,12 +356,13 @@ def _split_prefetch(cfg: FlashConfig, refs):
     return refs[:n], refs[n:]
 
 
-def _docs_of(cfg: FlashConfig, prefetch, b, blocks) -> Optional[_BlockDocs]:
-    """Folded row ``b``'s documents among a launch's scalar-prefetch
-    operands ``(info, slopes[, table])``; None for a launch without ids."""
+def _docs_of(cfg: FlashConfig, prefetch, row: "_Row", blocks) -> Optional[_BlockDocs]:
+    """The documents of a grid step's ``row`` among a launch's
+    scalar-prefetch operands ``(info, slopes[, table])``; None for a launch
+    without ids."""
     if not cfg.use_seg:
         return None
-    return _BlockDocs(prefetch[2], lax.div(b, cfg.kv_heads), *blocks)
+    return _BlockDocs(prefetch[2], row.batch, *blocks)
 
 
 def _should_run(cfg: FlashConfig, tile: Tile, i, j, info_ref,
@@ -456,10 +485,10 @@ def _tile_logits(cfg: FlashConfig, tile: Tile, q, k, i, j, info_ref,
     return s
 
 
-def _head_index(cfg: FlashConfig, b, g, G):
-    """Global query-head index for (folded batch*kv_head, group) — the
-    ALiBi slope lookup."""
-    return (b % cfg.kv_heads) * G + g
+def _head_index(row: "_Row", g, G):
+    """Global query-head index of group ``g`` of a grid step's ``row``: the
+    ALiBi slope lookup, a head's columns."""
+    return row.head * G + g
 
 
 #: the tags a caller may give a launch (``FlashConfig.tag``): EVA's two
@@ -470,6 +499,112 @@ def _compiler_params(cfg: FlashConfig, semantics):
     return pltpu.CompilerParams(
         dimension_semantics=semantics,
         vmem_limit_bytes=cfg.tiles.vmem_limit_bytes)
+
+
+# Where a launch's query side lies (``FlashConfig.layout``; module docstring).
+# A kernel body takes a ``(rows, D)`` tile either way, ``ref[0, 0]``; these say
+# which block of which array that is, so the grids, the tiles and the order of
+# every sum are one layout's as the other's.
+
+LAYOUTS = ("rows", "heads")
+
+
+def launch_layout(q_shape, k_shape) -> str:
+    """The layout of ONE launch over ``[B, S, heads, D]`` operands, written
+    HERE alone (the kernel, `_prepare`, and ``attention.plan``, which the
+    counters read, both ask this): ``"rows"`` where a head's columns are whole
+    lane tiles (``D % 128 == 0``: a block ``(1, bq, D)`` of ``[B, S, H*D]`` is
+    made of whole 4 KB tiles of the array's own HBM layout) AND the query heads
+    are grouped over fewer key heads; else ``"heads"``. At head dim 64 a ``(bq,
+    64)`` block is half a lane tile. With as many key heads as query heads
+    (EVA's launches among them) the key side, which leads with its heads either
+    way, is half the bytes, so half the transposes stay, and on the v5e (PR 51;
+    docs/KERNELS.md, PERF.md section 6) the 16 x 16-head cells read 0.16 % and
+    0.37 % SLOWER by rows where the 32-over-4 cells read 0.06 to 1.3 % faster."""
+    return "rows" if q_shape[3] % NUM_LANES == 0 and q_shape[2] > k_shape[2] else "heads"
+
+
+def _dims(cfg: FlashConfig, q, k) -> Tuple[int, int, int, int, int]:
+    """(folded rows ``B * kv_heads``, G, Sq, Sk, D) of a launch's operands."""
+    BK, Sk, D = k.shape
+    if cfg.layout == "rows":
+        return BK, q.shape[3] // D // cfg.kv_heads, q.shape[2], Sk, D
+    return BK, q.shape[1], q.shape[2], Sk, D
+
+
+class _Row:
+    """The folded row ``batch row x kv_heads + key head`` of one grid step,
+    from the step's leading ids: ONE id in ``"heads"`` (the folded row itself,
+    the axis the operands lead with), TWO in ``"rows"`` (batch row and key
+    head, the operands' own axes: no index map or kernel body divides).
+    Each property is a scalar expression made where it is asked for (never
+    kept: a kernel body asks inside ``pl.when``)."""
+
+    def __init__(self, cfg: FlashConfig, ids):
+        self._kv_heads, self._ids = cfg.kv_heads, ids
+        self._split = cfg.layout == "rows"
+
+    @property
+    def batch(self):
+        return self._ids[0] if self._split else lax.div(self._ids[0], self._kv_heads)
+
+    @property
+    def head(self):
+        return self._ids[1] if self._split else lax.rem(self._ids[0], self._kv_heads)
+
+    @property
+    def folded(self):
+        return self._ids[0] * self._kv_heads + self._ids[1] if self._split else self._ids[0]
+
+
+def _row_axes(cfg: FlashConfig, BK: int) -> Tuple[int, ...]:
+    """The grid's leading axes, over the folded rows (`_Row`): in the same
+    order and as many steps in either layout."""
+    return (BK // cfg.kv_heads, cfg.kv_heads) if cfg.layout == "rows" else (BK,)
+
+
+def _row_rank(cfg: FlashConfig) -> int:
+    """How many of a grid step's ids are its row's (`_row_axes`)."""
+    return 2 if cfg.layout == "rows" else 1
+
+
+def _row(cfg: FlashConfig, ids):
+    """(a grid step's `_Row`, its other ids) of an index map's or a kernel's
+    ``ids``."""
+    n = _row_rank(cfg)
+    return _Row(cfg, ids[:n]), ids[n:]
+
+
+def _by_row(cfg: FlashConfig, index):
+    """The index map ``index(row, *the step's other ids, *prefetch)``."""
+    def index_map(*ids):
+        row, rest = _row(cfg, ids)
+        return index(row, *rest)
+    return index_map
+
+
+def _step_ids(cfg: FlashConfig, rest: int):
+    """A kernel body's `_row` of the grid's ids (``rest``: after the rows')."""
+    return _row(cfg, [pl.program_id(a) for a in range(_row_rank(cfg) + rest)])
+
+
+def _q_block(cfg: FlashConfig, G: int, rows: int, D: int, heads: bool = False):
+    """(block shape, ``index(row, g, i)``) of q-block ``i`` of query head ``g``
+    of a grid step's ``row``: a ``(rows, D)`` tile of q, o, do or dq, at
+    ``[0, 0]`` of its block. ``heads``: of an array that leads with its heads
+    whatever the layout (dq in float32, `_bwd_call`)."""
+    if cfg.layout == "rows" and not heads:
+        index = lambda row, g, i: (row.batch, 0, i, _head_index(row, g, G))
+    else:
+        index = lambda row, g, i: (row.folded, g, i, 0)
+    return (1, 1, rows, D), index
+
+
+def _kv_block(rows: int, D: int):
+    """(block shape, ``index(row, j)``) of k-block ``j`` of a grid step's
+    ``row``: a ``(rows, D)`` tile of k, v, dk or dv, which lead with their
+    heads in either layout."""
+    return (1, rows, D), lambda row, j: (row.folded, j, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -486,10 +621,9 @@ def _fwd_kernel(*refs, cfg: FlashConfig, G: int, nk: int, head_dim: int,
     prefetch, (q_ref, k_ref, v_ref, qseg_ref, kseg_ref, sel_ref, o_ref, lse_ref,
                m_scr, l_scr, acc_scr) = _split_prefetch(cfg, refs)
     info, slopes = prefetch[:2]
-    b, g = pl.program_id(0), pl.program_id(1)
-    i, step = pl.program_id(2), pl.program_id(3)
+    row, (g, i, step) = _step_ids(cfg, 3)
     tile = cfg.tiles.fwd
-    docs = _docs_of(cfg, prefetch, b, blocks)
+    docs = _docs_of(cfg, prefetch, row, blocks)
     # the k-block of this step: under a static window the steps start at
     # the first block the q-block reaches (a step past its diagonal names a
     # block the causal mask hides whole, and is skipped as one)
@@ -508,7 +642,7 @@ def _fwd_kernel(*refs, cfg: FlashConfig, G: int, nk: int, head_dim: int,
         qseg = qseg_ref[0] if cfg.use_seg else None
         kseg = kseg_ref[0] if cfg.use_seg else None
         s = _tile_logits(cfg, tile, q, k, i, j, info, slopes,
-                         _head_index(cfg, b, g, G), qseg, kseg,
+                         _head_index(row, g, G), qseg, kseg,
                          positional=positional,
                          sel=sel_ref[0] if cfg.selected else None,
                          rows=blocks[0] * tile[0])
@@ -542,26 +676,27 @@ def _fwd_kernel(*refs, cfg: FlashConfig, G: int, nk: int, head_dim: int,
 
 def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
               sel=None):
-    """-> o [BK, G, Sq, D], lse [BK, G, 1, Sq] (fp32 rows). ``table``: the
-    forward tiles' :func:`block_ranges` (None: a launch without ids); ``sel``:
-    a selection's operand ``[B, Sq / 8, Sk]`` (``FlashConfig.selected``)."""
-    BK, G, Sq, D = q.shape
-    Sk = k.shape[1]
+    """-> o laid out as q is (``cfg.layout``), lse [BK, G, 1, Sq] (fp32 rows).
+    ``table``: the forward tiles' :func:`block_ranges` (None: a launch without
+    ids); ``sel``: a selection's operand ``[B, Sq / 8, Sk]``
+    (``FlashConfig.selected``)."""
+    BK, G, Sq, Sk, D = _dims(cfg, q, k)
     tile = bq, bk = cfg.tiles.fwd
     blocks = nq, nk = Sq // bq, Sk // bk
     if cfg.window is not None:
         nk = window_steps(tile, cfg.window, nq, nk)[0]
-    grid = (BK, G, nq, nk)
-    kvH = cfg.kv_heads
+    rows = _row_axes(cfg, BK)
+    grid = rows + (G, nq, nk)
+    by_row = functools.partial(_by_row, cfg)
     prefetch = (info, slopes) + (() if table is None else (table.reshape(-1),))
 
-    def k_blk(b, i, j, prefetch):
+    def k_blk(row, i, j, prefetch):
         """The k-block step ``j`` of q-block ``i`` fetches; a step that
         computes nothing re-names a block already resident, or one that a
         whole run of such steps shares: no DMA, or one a run. (A row's
         skipped steps lie before its first visible document and past its
         diagonal; block 0 is what the row before it ended on.)"""
-        docs = _docs_of(cfg, prefetch, b, blocks)
+        docs = _docs_of(cfg, prefetch, row, blocks)
         if cfg.window is not None:
             last = _last_k_block(tile, i)
             blk = jnp.minimum(_first_k_block(tile, i, cfg.window) + j, last)
@@ -570,21 +705,23 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
             j = lax.select(_should_run(cfg, tile, i, j, prefetch[0], docs), j, 0)
         return j
 
-    def kv_idx(b, g, i, j, *prefetch):
-        return (b, k_blk(b, i, j, prefetch), 0)
+    q_block, q_at = _q_block(cfg, G, bq, D)
+    kv_block, kv_at = _kv_block(bk, D)
 
+    q_idx = by_row(lambda row, g, i, j, *_: q_at(row, g, i))
+    kv_idx = by_row(lambda row, g, i, j, *prefetch: kv_at(row, k_blk(row, i, j, prefetch)))
     in_specs = [
-        pl.BlockSpec((1, 1, bq, D), lambda b, g, i, j, *_: (b, g, i, 0)),
-        pl.BlockSpec((1, bk, D), kv_idx),
-        pl.BlockSpec((1, bk, D), kv_idx),
+        pl.BlockSpec(q_block, q_idx),
+        pl.BlockSpec(kv_block, kv_idx),
+        pl.BlockSpec(kv_block, kv_idx),
     ]
     if cfg.use_seg:
-        in_specs.append(pl.BlockSpec(
-            (1, bq, NUM_LANES), lambda b, g, i, j, *_: (b // kvH, i, 0)))
-
-        def kseg_idx(b, g, i, j, *prefetch):
-            return (b // kvH, 0, k_blk(b, i, j, prefetch))
-        in_specs.append(pl.BlockSpec((1, NUM_SUBLANES, bk), kseg_idx))
+        in_specs += [
+            pl.BlockSpec((1, bq, NUM_LANES),
+                         by_row(lambda row, g, i, j, *_: (row.batch, i, 0))),
+            pl.BlockSpec((1, NUM_SUBLANES, bk), by_row(
+                lambda row, g, i, j, *prefetch: (row.batch, 0, k_blk(row, i, j, prefetch)))),
+        ]
     else:
         in_specs += [None, None]
     if sel is None:
@@ -593,15 +730,15 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
         # the packed rows that hold the q-block's bit planes
         packed, shared = _attention.selection_tile(Sq, bq)
         in_specs.append(pl.BlockSpec(
-            (1, packed, bk), lambda b, g, i, j, *prefetch: (
-                b // kvH, i // shared, k_blk(b, i, j, prefetch))))
+            (1, packed, bk), by_row(lambda row, g, i, j, *prefetch: (
+                row.batch, i // shared, k_blk(row, i, j, prefetch)))))
 
     out_specs = [
-        pl.BlockSpec((1, 1, bq, D), lambda b, g, i, j, *_: (b, g, i, 0)),
-        pl.BlockSpec((1, 1, 1, bq), lambda b, g, i, j, *_: (b, g, 0, i)),
+        pl.BlockSpec(q_block, q_idx),
+        pl.BlockSpec((1, 1, 1, bq), by_row(lambda row, g, i, j, *_: (row.folded, g, 0, i))),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((BK, G, Sq, D), q.dtype),
+        jax.ShapeDtypeStruct(q.shape, q.dtype),
         jax.ShapeDtypeStruct((BK, G, 1, Sq), jnp.float32),
     ]
     kernel = functools.partial(_fwd_kernel, cfg=cfg, G=G, nk=nk, head_dim=D,
@@ -620,7 +757,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
             ]),
         out_shape=out_shape,
         compiler_params=_compiler_params(
-            cfg, ("parallel", "parallel", "parallel", "arbitrary")),
+            cfg, ("parallel",) * (len(rows) + 2) + ("arbitrary",)),
         interpret=cfg.interpret,
         # a name of its own for each mask whose grids or compare are its own,
         # and for a caller's tag (the benchmark's readers find the launches
@@ -698,10 +835,9 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
     else:
         dq_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
     info, slopes = prefetch[:2]
-    b = pl.program_id(0)
-    j, g, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    row, (j, g, step) = _step_ids(cfg, 3)
     tile = bq, _ = cfg.tiles.bwd
-    docs = _docs_of(cfg, prefetch, b, blocks)
+    docs = _docs_of(cfg, prefetch, row, blocks)
     # the q-block of this step, and whether there is one: a static
     # window's steps start at the k-block's own diagonal
     i, exists = step, None
@@ -723,7 +859,7 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
 
         # (a static window's step without a q-block names a tile past the
         # last, and starts no copy of it)
-        dq_tile = dq_ref.at[b, g, pl.ds(pl.multiple_of(i * bq, bq), bq)]
+        dq_tile = dq_ref.at[row.folded, g, pl.ds(pl.multiple_of(i * bq, bq), bq)]
         dq_read = pltpu.make_async_copy(dq_tile, dq_scr, dq_sem.at[0])
         dq_write = pltpu.make_async_copy(dq_scr, dq_tile, dq_sem.at[1])
 
@@ -754,7 +890,7 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
         kseg = kseg_ref[0] if cfg.use_seg else None
         qseg = qseg_ref[0] if cfg.use_seg else None
         st = _tile_logits(cfg, tile, q, k, i, j, info, slopes,
-                          _head_index(cfg, b, g, G), kseg, qseg,
+                          _head_index(row, g, G), kseg, qseg,
                           positional=positional, transposed=True,
                           sel=sel_ref[0] if cfg.selected else None,
                           rows=blocks[0] * bq)
@@ -794,6 +930,38 @@ def _bwd_kernel(*refs, cfg: FlashConfig, G: int, steps: int,
         pl.when((j == blocks[1] - 1) & (g == G - 1) & (step == steps - 1))(_landed)
 
 
+def _delta_kernel(o_ref, do_ref, di_ref):
+    """A q-block's ``rowsum(dO * O)`` as a ROW ``[1, bq]`` (as the forward
+    leaves the LSE: lane-replicated, one XLU transpose)."""
+    rows = jnp.sum(do_ref[0, 0].astype(jnp.float32) * o_ref[0, 0].astype(jnp.float32),
+                   axis=1, keepdims=True)
+    di_ref[0, 0] = jnp.broadcast_to(rows, (rows.shape[0], NUM_LANES)).T[:1]
+
+
+def _delta_call(cfg: FlashConfig, o, do, BK: int, G: int, Sq: int, D: int):
+    """-> di ``[BK, G, 1, Sq]`` (fp32 rows) from ``o`` and ``do`` BY ROWS
+    (``[B, Sq, H x D]``), a launch of its own, ``flash_delta``: XLA would sum a
+    head's columns only after a relayout of the whole float32 product (268 MB
+    a layer at 16,384 x 32 x 128: found in the compiled step, PR 51), where
+    with the heads leading it fuses product and sum into one pass. The same
+    128 products summed in float32 either way."""
+    bq = cfg.tiles.bwd[0]
+    block, at = _q_block(cfg, G, bq, D)
+    grid = _row_axes(cfg, BK) + (G, Sq // bq)
+    return pl.pallas_call(
+        _delta_kernel,
+        grid=grid,
+        in_specs=[pl.BlockSpec(block, _by_row(cfg, at))] * 2,
+        out_specs=pl.BlockSpec((1, 1, 1, bq), _by_row(
+            cfg, lambda row, g, i: (row.folded, g, 0, i))),
+        out_shape=jax.ShapeDtypeStruct((BK, G, 1, Sq), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * len(grid)),
+        interpret=cfg.interpret,
+        name="flash_delta",
+    )(o, do)
+
+
 def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
               o, lse, do, dlse, sel_t=None):
     """``table``: the backward tiles' :func:`block_ranges` (None: a launch
@@ -803,9 +971,13 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
     place (:func:`dq_mode`) it accumulates across the k-block axis, which is
     then ``"arbitrary"``; the folded-row axis stays ``"parallel"`` (a chip
     with two cores may split it: a row's dq tiles and its writes in flight
-    are its own)."""
-    BK, G, Sq, D = q.shape
-    Sk = k.shape[1]
+    are its own). q, ``o`` and ``do`` as ``cfg.layout`` has them, and dq comes
+    back so. A dq that leaves the launch in float32
+    (partials, or the one array added to in place) leads with its heads in
+    EITHER layout: the sum or the cast that reads it anyway writes it where q
+    lies, and a pair's tile of it is one run of HBM and not 4 KB pieces."""
+    BK, G, Sq, Sk, D = _dims(cfg, q, k)
+    by_rows = cfg.layout == "rows"
     tile = bq, bk = cfg.tiles.bwd
     blocks = nq, nk = Sq // bq, Sk // bk
     kvH = cfg.kv_heads
@@ -818,12 +990,15 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
 
     # di = rowsum(dO * O) (the softmax-jacobian diagonal term); a cotangent
     # on the LSE output folds in here: dL/ds = P*(dP - di) + dlse*P
-    di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    di = di.reshape(BK, G, 1, Sq)
+    if by_rows:
+        di = _delta_call(cfg, o, do, BK, G, Sq, D)
+    else:
+        di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+        di = di.reshape(BK, G, 1, Sq)
     if dlse is not None:
         di = di - dlse.astype(jnp.float32)
 
-    def q_at(i, j):
+    def q_step(i, j):
         """The q-block of step ``i`` of k-block ``j`` by position: under a
         static window a step past the last block reached names that one."""
         if W is None:
@@ -831,45 +1006,43 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
         return jnp.minimum(_first_q_block(tile, j) + i,
                            _last_q_block(tile, j, W, nq))
 
-    def q_blk(b, i, j, prefetch):
+    def q_blk(row, i, j, prefetch):
         """The q-block the step fetches: one that computes nothing re-names
         a block already resident, or one that a whole run of such steps
         shares (no DMA, or one a run: the last block of all, or of the
         window's reach)."""
-        docs = _docs_of(cfg, prefetch, b, blocks)
+        docs = _docs_of(cfg, prefetch, row, blocks)
         if W is not None:
-            blk = q_at(i, j)
+            blk = q_step(i, j)
             return blk if docs is None else lax.select(
                 docs.meet(blk, j), blk, _last_q_block(tile, j, W, nq))
         if cfg.causal or docs is not None:
             i = lax.select(_should_run(cfg, tile, i, j, prefetch[0], docs), i, nq - 1)
         return i
 
-    def q_idx(b, j, g, i, *prefetch):
-        return (b, g, q_blk(b, i, j, prefetch), 0)
-
-    def q_row_idx(b, j, g, i, *prefetch):
-        return (b, g, 0, q_blk(b, i, j, prefetch))
-
-    def kv_idx(b, j, g, i, *_):
-        return (b, j, 0)
+    rows = _row_axes(cfg, BK)
+    by_row = functools.partial(_by_row, cfg)
+    q_block, q_at = _q_block(cfg, G, bq, D)
+    kv_block, kv_at = _kv_block(bk, D)
+    q_idx = by_row(lambda row, j, g, i, *prefetch: q_at(row, g, q_blk(row, i, j, prefetch)))
+    q_row_idx = by_row(lambda row, j, g, i, *prefetch: (
+        row.folded, g, 0, q_blk(row, i, j, prefetch)))
+    kv_idx = by_row(lambda row, j, g, i, *_: kv_at(row, j))
 
     seg_specs = [None, None]
     if cfg.use_seg:
         seg_specs = [
             pl.BlockSpec((1, bk, NUM_LANES),
-                         lambda b, j, g, i, *_: (b // kvH, j, 0)),
-            pl.BlockSpec(
-                (1, NUM_SUBLANES, bq),
-                lambda b, j, g, i, *prefetch: (
-                    b // kvH, 0, q_blk(b, i, j, prefetch))),
+                         by_row(lambda row, j, g, i, *_: (row.batch, j, 0))),
+            pl.BlockSpec((1, NUM_SUBLANES, bq), by_row(
+                lambda row, j, g, i, *prefetch: (row.batch, 0, q_blk(row, i, j, prefetch)))),
         ]
     sel_spec = None
     if sel_t is not None:
         packed, shared = _attention.selection_tile(Sq, bq)
         sel_spec = pl.BlockSpec(
-            (1, bk, packed), lambda b, j, g, i, *prefetch: (
-                b // kvH, j, q_blk(b, i, j, prefetch) // shared))
+            (1, bk, packed), by_row(lambda row, j, g, i, *prefetch: (
+                row.batch, j, q_blk(row, i, j, prefetch) // shared)))
     prefetch = (info, slopes) + (() if table is None else (table.reshape(-1),))
     if in_place:
         # dq starts at zero and is the launch's own to add to, wherever
@@ -886,47 +1059,50 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
     else:
         # a pair's own block, whatever its step fetched: partial ``j`` of its
         # q-block, or under a static window ``j`` less the first k-block the
-        # q-block reaches; one partial IS the answer, in q's dtype
-        dq_shape, zeros, dq_scratch, aliases = (met,) + q.shape, [], [], {}
+        # q-block reaches; one partial IS the answer, in q's dtype and where
+        # q lies, several are float32 and lead with their heads
+        dq_block, dq_at = _q_block(cfg, G, bq, D, heads=met > 1)
+        dq_shape = (met,) + (q.shape if met == 1 else (BK, G, Sq, D))
+        zeros, dq_scratch, aliases = [], [], {}
 
-        def dq_idx(b, j, g, i, *_):
+        def dq_idx(row, j, g, i, *_):
             if W is None:
-                return (j, b, g, i, 0)
-            i = q_at(i, j)
-            return (j - _first_k_block(tile, i, W), b, g, i, 0)
-        dq_spec = pl.BlockSpec((1, 1, 1, bq, D), dq_idx)
+                return (j,) + dq_at(row, g, i)
+            i = q_step(i, j)
+            return (j - _first_k_block(tile, i, W),) + dq_at(row, g, i)
+        dq_spec = pl.BlockSpec((1,) + dq_block, by_row(dq_idx))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, cfg=cfg, G=G, steps=steps,
                           blocks=blocks, in_place=in_place),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(BK, nk, G, steps),
+            grid=rows + (nk, G, steps),
             in_specs=[
-                pl.BlockSpec((1, 1, bq, D), q_idx),
-                pl.BlockSpec((1, bk, D), kv_idx),
-                pl.BlockSpec((1, bk, D), kv_idx),
+                pl.BlockSpec(q_block, q_idx),
+                pl.BlockSpec(kv_block, kv_idx),
+                pl.BlockSpec(kv_block, kv_idx),
                 *seg_specs,
                 sel_spec,
-                pl.BlockSpec((1, 1, bq, D), q_idx),
+                pl.BlockSpec(q_block, q_idx),
                 pl.BlockSpec((1, 1, 1, bq), q_row_idx),
                 pl.BlockSpec((1, 1, 1, bq), q_row_idx),
                 *[dq_spec] * len(zeros),
             ],
             out_specs=[
                 dq_spec,
-                pl.BlockSpec((1, bk, D), kv_idx),
-                pl.BlockSpec((1, bk, D), kv_idx),
+                pl.BlockSpec(kv_block, kv_idx),
+                pl.BlockSpec(kv_block, kv_idx),
             ],
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                             pltpu.VMEM((bk, D), jnp.float32), *dq_scratch]),
         out_shape=[jax.ShapeDtypeStruct(
                        dq_shape, q.dtype if met == 1 else jnp.float32),
-                   jax.ShapeDtypeStruct((BK, Sk, D), k.dtype),
-                   jax.ShapeDtypeStruct((BK, Sk, D), v.dtype)],
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
         input_output_aliases=aliases,
         compiler_params=_compiler_params(
-            cfg, ("parallel", "arbitrary" if in_place else "parallel",
-                  "arbitrary", "arbitrary")),
+            cfg, ("parallel",) * len(rows) + (
+                "arbitrary" if in_place else "parallel", "arbitrary", "arbitrary")),
         interpret=cfg.interpret,
         name=("flash_bwd_eva_local" if cfg.tag == "eva_local"
               else "flash_bwd_eva_far" if cfg.tag == "eva_far"
@@ -934,16 +1110,23 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
               else "flash_bwd_blockdiff" if cfg.blockdiff is not None
               else "flash_bwd" if W is None else "flash_bwd_window"),
     )(*prefetch, q, k, v, kseg_c, qseg_r, sel_t, do, lse, di, *zeros)
+    if met == 1:
+        return dq[0], dk, dv
     if in_place:
-        return dq[..., :D].astype(q.dtype), dk, dv
-    if W is not None and met > 1:
-        # partial s of q-block i holds k-block first(i) + s, where there is
-        # one: a block no pair wrote holds whatever was there
-        reach = np.repeat([_last_k_block(tile, i) - _first_k_block(tile, i, W)
-                           for i in range(nq)], bq)
-        dq = jnp.where(jnp.asarray(np.arange(met)[:, None] <= reach[None, :]
-                                   )[:, None, None, :, None], dq, 0.0)
-    return (dq[0] if met == 1 else jnp.sum(dq, axis=0).astype(q.dtype)), dk, dv
+        dq = dq[..., :D].astype(q.dtype)
+    else:
+        if W is not None:
+            # partial s of q-block i holds k-block first(i) + s, where there
+            # is one: a block no pair wrote holds whatever was there
+            reach = np.repeat([_last_k_block(tile, i) - _first_k_block(tile, i, W)
+                               for i in range(nq)], bq)
+            dq = jnp.where(jnp.asarray(np.arange(met)[:, None] <= reach[None, :]
+                                       )[:, None, None, :, None], dq, 0.0)
+        dq = jnp.sum(dq, axis=0).astype(q.dtype)
+    if by_rows:
+        # (the pass that sums or casts writes a head's rows to its columns)
+        dq = dq.reshape(BK // kvH, kvH * G, Sq, D).transpose(0, 2, 1, 3).reshape(q.shape)
+    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -953,7 +1136,8 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _flash(cfg: FlashConfig, q, k, v, segs, slopes, info, sel=None):
-    """``segs`` = (q ids as columns, k ids as rows, the forward tiles' table
+    """q as ``cfg.layout`` has it, k and v leading with their heads. ``segs``
+    = (q ids as columns, k ids as rows, the forward tiles' table
     of documents, k ids as columns, q ids as rows, the backward tiles'
     table) or six Nones: the forward reads the first three, the backward
     (transposed tiles) the others. ``sel``: a selection's operand, int8 ``[B,
@@ -1234,7 +1418,11 @@ def tiles_run(q_ids: jax.Array, k_ids: jax.Array, tile: Tile, *,
 
 def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
              alibi_slopes, window, q_offset, block_q, block_k, interpret,
-             blockdiff=None, summaries=None, tag=None, selected=False):
+             blockdiff=None, summaries=None, tag=None, selected=False,
+             layout=None):
+    """-> (the launch's ``FlashConfig``, q as the launch's layout has it and k
+    and v leading with their heads (module docstring), the six id operands, the
+    slopes, ``info``). ``layout``: None, `launch_layout`'s."""
     B, Sq, H, D = q.shape
     Sk, kvH = k.shape[1], k.shape[2]
     if H % kvH:
@@ -1277,16 +1465,29 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         raise ValueError(f"seq lengths ({Sq}, {Sk}) have no legal tiles "
                          f"(block_q={block_q}, block_k={block_k})")
     scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
+    if layout is None:
+        layout = launch_layout(q.shape, k.shape)
+    if layout not in LAYOUTS or (layout == "rows" and D % NUM_LANES):
+        raise ValueError(f"layout {layout!r}: one of {LAYOUTS}, 'rows' for a head "
+                         f"dim of whole {NUM_LANES}-lane tiles (got {D})")
 
-    # GQA-folded layout
-    q4 = q.transpose(0, 2, 1, 3).reshape(B * kvH, G, Sq, D)
-    k3 = k.transpose(0, 2, 1, 3).reshape(B * kvH, Sk, D)
-    v3 = v.transpose(0, 2, 1, 3).reshape(B * kvH, Sk, D)
+    if layout == "rows":
+        # the query heads stay side by side where the projection left them:
+        # the index maps pick a head's columns
+        # (a unit axis in the heads' place: every q-side operand has rank 4
+        # in either layout, a k-side one rank 3)
+        q = q.reshape(B, 1, Sq, H * D)
+    else:
+        q = q.transpose(0, 2, 1, 3).reshape(B * kvH, G, Sq, D)
+    # the keys and values lead with their heads either way (GQA-folded)
+    k = k.transpose(0, 2, 1, 3).reshape(B * kvH, Sk, D)
+    v = v.transpose(0, 2, 1, 3).reshape(B * kvH, Sk, D)
     if math.frexp(scale)[0] == 0.5:
         # a power of two scales q EXACTLY in any float type, so the same
         # logits come out of the matmul already scaled and the kernels skip
-        # one multiply per logit (XLA fuses this one into the transpose)
-        q4, scale = q4 * jnp.asarray(scale, q4.dtype), 1.0
+        # one multiply per logit (XLA fuses this one into the transpose, or
+        # with no transpose into what made q: the rotary embedding, the norm)
+        q, scale = q * jnp.asarray(scale, q.dtype), 1.0
     cfg = FlashConfig(
         causal=bool(causal), scale=scale,
         use_seg=segment_ids is not None,
@@ -1295,7 +1496,7 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         kv_heads=kvH, tiles=tiles, interpret=bool(interp), window=cut,
         blockdiff=None if blockdiff is None else (int(blockdiff), Sk),
         summaries=None if summaries is None else tuple(map(int, summaries)),
-        tag=tag, selected=bool(selected))
+        tag=tag, selected=bool(selected), layout=layout)
 
     segs = (None,) * 6
     if segment_ids is not None:
@@ -1328,7 +1529,7 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         jnp.asarray(window if window is not None else 0,
                     jnp.int32).reshape(()),
     ])
-    return cfg, q4, k3, v3, segs, slopes, info, (B, H, kvH, G)
+    return cfg, q, k, v, segs, slopes, info
 
 
 def flash_attention_with_lse(
@@ -1342,9 +1543,19 @@ def flash_attention_with_lse(
         block_k: Optional[int] = None, interpret: Optional[bool] = None,
         blockdiff: Optional[int] = None,
         summaries: Optional[Tuple[int, int]] = None, tag: Optional[str] = None,
-        selected: Optional[jax.Array] = None
+        selected: Optional[jax.Array] = None, layout: Optional[str] = None
 ) -> Tuple[jax.Array, jax.Array]:
     """Flash attention returning ``(out [B, Sq, H, D], lse [B, H, Sq])``.
+
+    q ``[B, Sq, H, D]``, k and v ``[B, Sk, kvH, D]``, as the projections leave
+    them. ``layout`` says how the launches take the QUERY side (module
+    docstring): in ``"rows"`` (a head dim of whole 128-lane tiles) as it is,
+    ``[B, 1, Sq, H * D]`` by a reshape, and ``out`` and dq come back the same
+    way: no transpose of q, ``out`` or their gradients before a launch or after
+    it; in ``"heads"`` with the heads leading, a transpose each way. k and v
+    (and dk, dv) lead with their heads in both. None: `launch_layout`'s, which
+    is what ``attention.plan`` hands its entry points; both layouts give the
+    same bits.
 
     ``lse`` is the per-row logsumexp of the masked scaled logits (fp32;
     rows with no unmasked key hold the finite ``MASK_VALUE`` sentinel) —
@@ -1379,14 +1590,15 @@ def flash_attention_with_lse(
     if selected is not None and selected.shape != packed:
         raise ValueError(f"selected {selected.shape}: the operand of {Sq} queries over "
                          f"{k.shape[1]} keys is bits, {packed} (attention.pack_selection)")
-    cfg, q4, k3, v3, segs, slopes, info, dims = _prepare(
+    cfg, q, k, v, segs, slopes, info = _prepare(
         q, k, v, causal, scale, segment_ids, q_segment_ids, alibi_slopes,
         window, q_offset, block_q, block_k, interpret, blockdiff, summaries, tag,
-        selected is not None)
-    _, _, kvH, G = dims
-    o, lse = _flash(cfg, q4, k3, v3, segs, slopes, info, selected)
-    out = o.reshape(B, kvH, G, Sq, D).reshape(B, H, Sq, D)
-    out = out.transpose(0, 2, 1, 3)
+        selected is not None, layout)
+    o, lse = _flash(cfg, q, k, v, segs, slopes, info, selected)
+    if cfg.layout == "rows":
+        out = o.reshape(B, Sq, H, D)
+    else:
+        out = o.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     return out, lse.reshape(B, H, Sq)
 
 
@@ -1399,14 +1611,18 @@ def flash_attention_kernel(
         window: Optional[jax.Array] = None,
         q_offset=None, block_q: Optional[int] = None,
         block_k: Optional[int] = None,
-        interpret: Optional[bool] = None) -> jax.Array:
+        interpret: Optional[bool] = None,
+        layout: Optional[str] = None) -> jax.Array:
     """Flash attention, ``[B, S, H, D]`` in and out — the drop-in training
-    kernel `attention.flash_attention` dispatches to at long sequence."""
+    kernel `attention.flash_attention` dispatches to at long sequence. q and
+    the result go to and from the launches in ``layout``
+    (`flash_attention_with_lse`: at a head dim of whole lane tiles as they are,
+    no transpose either way)."""
     out, _ = flash_attention_with_lse(
         q, k, v, causal=causal, scale=scale, segment_ids=segment_ids,
         q_segment_ids=q_segment_ids, alibi_slopes=alibi_slopes,
         window=window, q_offset=q_offset, block_q=block_q, block_k=block_k,
-        interpret=interpret)
+        interpret=interpret, layout=layout)
     return out
 
 

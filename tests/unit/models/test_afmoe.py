@@ -94,7 +94,8 @@ def test_first_step_through_initialize(parts):
     assert engine.attn_totals == {"layers_window": 5, "layers_full": 1, "window": 16,
                                   "kv_heads": 2, "documents": True,
                                   "route": {"window": None, "full": None},
-                                  "dq": {"window": None, "full": None}}
+                                  "dq": {"window": None, "full": None},
+                                  "layout": {"window": None, "full": None}}
     assert engine.attn_last_step() is None
     loss = float(engine.train_batch({"input_ids": np.asarray(ids)}))
     want, gnorm, signs = ref.loss_and_gradient(w, ids, cfg)

@@ -409,7 +409,7 @@ def test_first_step_through_initialize(parts, want):
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.0}}})
     assert engine.attn_totals["eva"] == {"window": 32, "chunk": 4, "summaries_a_row": None,
                                          "pred_heads": 8, "route": None,
-                                         "dq_local": None, "dq_far": None}
+                                         "dq_local": None, "dq_far": None, "layout": None}
     # the CPU mesh's eight devices take a row each: the two rows four times
     # over have the two rows' loss and gradient
     loss = float(engine.train_batch({"input_ids": np.tile(np.asarray(ids), (4, 1))}))
@@ -418,7 +418,7 @@ def test_first_step_through_initialize(parts, want):
     assert float(engine.get_global_grad_norm()) == pytest.approx(gnorm, rel=1e-4)
     assert engine.attn_totals["eva"] == {"window": 32, "chunk": 4, "summaries_a_row": 32,
                                          "pred_heads": 8, "route": "xla",
-                                         "dq_local": None, "dq_far": None}
+                                         "dq_local": None, "dq_far": None, "layout": None}
     # the summaries are values the backward may keep, and on the CPU it does
     assert {"eva_kbar", "eva_vbar"} <= set(engine.remat_totals["saved"])
     after = adapter.from_program(engine.state["opt"]["master"])

@@ -254,8 +254,8 @@ def test_first_step_through_initialize(parts):
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}}})
     assert engine.attn_totals["dsa"] == {
         "topk": 8, "indexer_heads": 2, "indexer_head_dim": 8, "route": None,
-        "select": attention.SELECT_THRESHOLD, "dq": None, "kl": None, "kl_tiles": None,
-        "operand": "bits", "operand_bytes": None}
+        "select": attention.SELECT_THRESHOLD, "dq": None, "layout": None, "kl": None,
+        "kl_tiles": None, "operand": "bits", "operand_bytes": None}
     assert engine.attn_last_step() is None
     # (a row a device of the test mesh)
     batch = {"input_ids": np.concatenate([np.asarray(ids), np.asarray(ids)[:, ::-1]]

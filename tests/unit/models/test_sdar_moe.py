@@ -116,7 +116,8 @@ def test_first_step_through_initialize(parts):
             "zero_optimization": {"stage": 1},
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}}})
     assert engine.diffusion_totals == {"block_length": 4, "rows_per_token": 2,
-                                       "steps": 0, "route": None, "dq": None}
+                                       "steps": 0, "route": None, "dq": None,
+                                       "layout": None}
     assert engine.diffusion_last_step() is None
     loss = float(engine.train_batch({"input_ids": np.asarray(ids)}))
     want, gnorm, signs = ref.loss_and_gradient(w, ids, cfg)
@@ -141,7 +142,8 @@ def test_first_step_through_initialize(parts):
     assert rows.shape == (2, 8) and int(load.sum()) == 2 * 2 * ids.size * 3
     np.testing.assert_array_equal(rows, load[:, :8].astype(np.int32))
     assert engine.diffusion_totals == {"block_length": 4, "rows_per_token": 2,
-                                       "steps": 1, "route": "xla", "dq": None}
+                                       "steps": 1, "route": "xla", "dq": None,
+                                       "layout": None}
     _, weights, masked = model.noise({"input_ids": ids})
     last = engine.diffusion_last_step()
     assert last["masked_share"] == pytest.approx(float(jnp.mean(masked)), rel=1e-6)

@@ -61,3 +61,28 @@ def test_the_counters_carry_the_dq_mode_of_each_kind(eight_devices, monkeypatch,
     monkeypatch.setenv("DSTPU_ATTN", "pallas")
     engine._count_launches(batch)
     assert flat() == want
+
+
+@pytest.mark.parametrize("hidden,kv_heads,layout", [
+    (32, 1, "heads"), (256, 2, "heads"), (256, 1, "rows")])
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_the_records_carry_the_layout_of_each_kind(monkeypatch, kind, hidden, kv_heads, layout):
+    """Where a kind's launches take the query side's heads, beside its dq mode
+    and off the same plan (``attention.Plan.layout``): two heads of 16 are
+    transposed to lead, and two of 128 over two key heads; two of 128 over ONE
+    key head stay where the projections leave them (EVA has as many key heads
+    as query heads)."""
+    keys, row, want = KINDS[kind]
+    if "eva" in kind:
+        kv_heads, layout = 2, "heads"
+    model = TransformerLM(TransformerConfig(**{
+        **BASE, **keys, "hidden_size": hidden, "num_kv_heads": kv_heads}))
+
+    def carried():
+        attn, diffusion = model.attention_records(1, row)
+        return {k: v for k, v in setup_spans.flat_totals(
+            attn=attn, diffusion=diffusion or {}).items() if "layout" in k}
+    assert carried() == {}              # the CPU's route is XLA's: no launch, no layout
+    monkeypatch.setenv("DSTPU_ATTN", "pallas")
+    at = lambda key: key.replace(".dq_local", ".layout").replace(".dq", ".layout")
+    assert carried() == {at(key): layout for key in want if not key.endswith("dq_far")}

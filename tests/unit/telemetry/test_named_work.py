@@ -147,6 +147,9 @@ KERNEL_NAMES = {
     "flash_fwd_eva_far", "flash_bwd_eva_far",
     # the flash pair whose tiles read a learned selection's operand (PR 48)
     "flash_fwd_dsa", "flash_bwd_dsa",
+    # ``dO x O``'s row sum for a launch whose operands lie by rows (PR 51): NOT
+    # ``flash_bwd*``, whose readers sum the backward launches alone
+    "flash_delta",
     # the indexer's KL a tile at a time (PR 49; ``pallas_indexer_kl``): NOT
     # ``flash_*_dsa``, whose reader sums the selected flash pair alone
     "indexer_kl_fwd", "indexer_kl_bwd",
@@ -175,9 +178,9 @@ def test_every_pallas_call_has_a_name(site):
 
 
 def test_kernel_names_are_distinct_and_complete():
-    assert len(PALLAS_SITES) == 20
+    assert len(PALLAS_SITES) == 21
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 30
+    assert len(set(names)) == len(names) == 31
     assert set(names) == KERNEL_NAMES
 
 

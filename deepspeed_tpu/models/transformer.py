@@ -1973,20 +1973,25 @@ class TransformerLM:
             # with v visible keys picks min(v, topk) of them in every layer
             docs = (documents if c.document_separator is not None
                     else jnp.zeros(input_ids.shape, jnp.int32))
-            at = jnp.arange(docs.shape[1], dtype=jnp.int32)[None]
-            starts = jnp.pad(docs[:, 1:] != docs[:, :-1], ((0, 0), (1, 0)),
-                             constant_values=True)
-            first = jax.lax.cummax(jnp.where(starts, at, 0), axis=1)
-            visible = (at - first + 1).astype(jnp.float32)
+            from ..ops.transformer import attention, pallas_flash, pallas_indexer_kl
+            visible = attention.visible_counts(docs).astype(jnp.float32)
             stats = {**stats, "attn_selected_share": jnp.sum(
                 jnp.minimum(visible, c.indexer.topk)) / jnp.sum(visible)}
-            # the tiles the KL's kernel runs a layer, of its grid's
-            from ..ops.transformer import attention, pallas_flash, pallas_indexer_kl
-            tile = attention.kl_launch(self._attention_plan(*docs.shape), docs.shape[1])[1]
-            if tile is not None:
-                stats["dsa_kl_tiles"] = jnp.stack([
-                    pallas_flash.tiles_run(docs, docs, tile)[1],
-                    jnp.int32(pallas_indexer_kl.tiles_of(*docs.shape, tile))])
+            # the tiles the KL's kernel and the selection's run a layer, of
+            # their grids', and the queries the selection finds a threshold for
+            tiles = {"kl": attention.kl_launch(self._attention_plan(*docs.shape),
+                                               docs.shape[1])[1],
+                     "select": attention.select_launch(
+                         docs.shape[1], jax.default_backend(), attention.attn_mode())[1]}
+            for name, tile in tiles.items():
+                if tile is not None:
+                    stats[f"dsa_{name}_tiles"] = jnp.stack([
+                        pallas_flash.tiles_run(docs, docs, tile)[1],
+                        jnp.int32(pallas_indexer_kl.tiles_of(*docs.shape, tile))])
+            if tiles["select"] is not None:
+                stats["dsa_select_rows"] = jnp.stack([
+                    jnp.sum(visible > c.indexer.topk, dtype=jnp.int32),
+                    jnp.int32(visible.size)])
         return x, aux, stats, mtp_x
 
     def _documents(self, input_ids: jax.Array) -> jax.Array:
@@ -2085,11 +2090,12 @@ class TransformerLM:
                            "route": None, "dq_local": None, "dq_far": None,
                            "layout": None}
         if c.indexer is not None:
-            from ..ops.transformer.attention import SELECT_THRESHOLD, kl_launch, packed_rows
+            from ..ops.transformer.attention import (attn_mode, kl_launch, packed_rows,
+                                                     select_launch)
             attn["dsa"] = {"topk": c.indexer.topk, "indexer_heads": c.indexer.heads,
                            "indexer_head_dim": c.indexer.head_dim, "route": None,
-                           "select": SELECT_THRESHOLD, "dq": None, "layout": None,
-                           "kl": None, "kl_tiles": None,
+                           "select": None, "select_tiles": None, "select_rows": None,
+                           "dq": None, "layout": None, "kl": None, "kl_tiles": None,
                            "operand": "bits", "operand_bytes": None}
         diffusion = {"block_length": c.block_length, "rows_per_token": self.rows_per_token,
                      "route": None, "dq": None, "layout": None} if c.diffusion else None
@@ -2108,6 +2114,8 @@ class TransformerLM:
             attn["dsa"].update(route=plans[0].route, dq=plans[0].dq("dsa"),
                                layout=plans[0].layout("dsa"),
                                kl=kl_launch(plans[0], seq)[0],
+                               select=select_launch(
+                                   seq, jax.default_backend(), attn_mode())[0],
                                operand_bytes=batch * packed_rows(seq) * seq)
         else:
             # (sliding layers of several widths: the mode they share, else both)
@@ -2125,12 +2133,15 @@ class TransformerLM:
     def traced_rows_records(self, stats: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
         """What a step's statistics add to ``attn_totals``, by its key there
         (an engine asks once, of the first step's: the rows the step was traced
-        for): a learned selection's ``kl_tiles``, ``[run, of]`` tiles of one
-        layer's ``indexer_kl_fwd`` launch (``pallas_flash.tiles_run`` at the
-        pair's tile), where the KL takes the kernel."""
-        if "dsa_kl_tiles" not in stats:
-            return {}
-        return {"dsa": {"kl_tiles": [int(n) for n in np.asarray(stats["dsa_kl_tiles"])]}}
+        for): a learned selection's ``kl_tiles`` and ``select_tiles``, ``[run,
+        of]`` tiles of one layer's ``indexer_kl_fwd`` and ``dsa_select`` launch
+        (``pallas_flash.tiles_run`` at each launch's tile), and
+        ``select_rows``, ``[thresholded, of]``: the queries with more than
+        ``topk`` visible keys; each where its launch is the kernel."""
+        found = {name: [int(n) for n in np.asarray(stats["dsa_" + name])]
+                 for name in ("kl_tiles", "select_tiles", "select_rows")
+                 if "dsa_" + name in stats}
+        return {"dsa": found} if found else {}
 
     def expert_records(self, batch: Optional[int] = None, seq: Optional[int] = None,
                        *, expert_layers: int = 0, dtype=None, devices: int = 1,

@@ -653,6 +653,17 @@ def causal_in_document(q_pos, documents_q, documents_k):
             & (documents_q[:, :, None] == documents_k[:, None, :]))
 
 
+def visible_counts(documents: jax.Array) -> jax.Array:
+    """Each query's count of visible keys (`causal_in_document`), int32 [B, L],
+    from the documents alone, for ids that change only where a document ends
+    (a packed row's: ``TransformerLM._documents``): the query's place in its
+    document, from 1."""
+    at = jnp.arange(documents.shape[1], dtype=jnp.int32)[None]
+    starts = jnp.pad(documents[:, 1:] != documents[:, :-1], ((0, 0), (1, 0)),
+                     constant_values=True)
+    return at - jax.lax.cummax(jnp.where(starts, at, 0), axis=1) + 1
+
+
 def _sortable(x: jax.Array) -> jax.Array:
     """float32 -> uint32 in the floats' own order (-0.0 as 0.0)."""
     bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x).astype(jnp.float32),
@@ -779,16 +790,46 @@ def unpack_selection(packed: jax.Array, length: int,
     return picked if tile is not None else jax.lax.slice_in_dim(picked, 0, length, axis=axis)
 
 
+def select_launch(length: int, backend: str, mode: str) -> Tuple[str, Any]:
+    """How `dsa_select` makes a row of ``length`` queries' operand:
+    ``("kernel", the launch's tile)`` on a TPU where the row is whole groups of
+    8 planes of the chip's 128 lanes (``pallas_select.choose_tile``) and
+    `attn_mode` does not ask for XLA; else ``("bisection", None)``: the XLA
+    loop of `SELECT_QUERY_BLOCK` queries at a time (`SELECT_THRESHOLD`), on
+    the CPU always (a model's interpreted runs too)."""
+    if backend == "tpu" and mode != "xla":
+        from . import pallas_select as _ps
+        tile = _ps.choose_tile(length, compiled=True)
+        if tile is not None:
+            return "kernel", tile
+    return SELECT_THRESHOLD, None
+
+
 def dsa_select(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array,
                documents: jax.Array, k: int) -> jax.Array:
     """Every query's selection as the operand the attention reads: the bits of
-    ``s in S_t`` (`pack_selection`: int8 ``[B, packed_rows(L), L]``). The
-    scores (scope ``indexer``) and the selection (scope ``select``) a block of
-    `SELECT_QUERY_BLOCK` queries at a time, packed a group of the layout at a
-    time: neither ``[L, J, L]`` nor a byte a pair is ever held whole. No
-    gradient passes."""
-    B, L = documents.shape
+    ``s in S_t`` (`pack_selection`: int8 ``[B, packed_rows(L), L]``). No
+    gradient passes. By `select_launch`: ONE launch a layer under scope
+    ``select`` (``pallas_select``: the scores, the threshold and the bits a
+    plane of 128 queries at a time in VMEM, over the key blocks the plane can
+    see), or `dsa_select_xla`."""
     q_idx, k_idx, w = jax.lax.stop_gradient((q_idx, k_idx, w))
+    form, tile = select_launch(documents.shape[1], jax.default_backend(), attn_mode())
+    _log_path_once(f"dsa_select {form}")
+    if form != "kernel":
+        return dsa_select_xla(q_idx, k_idx, w, documents, k)
+    from . import pallas_select as _ps
+    with jax.named_scope("select"):
+        return _ps.select(q_idx, k_idx, w, documents, k, tile)
+
+
+def dsa_select_xla(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array,
+                   documents: jax.Array, k: int) -> jax.Array:
+    """`dsa_select`'s operand in XLA: the scores (scope ``indexer``) and the
+    selection (scope ``select``) a block of `SELECT_QUERY_BLOCK` queries at a
+    time against every key, packed a group of the layout at a time: neither
+    ``[L, J, L]`` nor a byte a pair is ever held whole."""
+    B, L = documents.shape
     n = SELECT_QUERY_BLOCK if L % SELECT_QUERY_BLOCK == 0 else L
     # the queries packed at once: whole groups (of whole blocks), else the row
     m = max(n, 8 * selection_plane(L))

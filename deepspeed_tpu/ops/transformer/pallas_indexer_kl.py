@@ -140,6 +140,22 @@ def _visible(cfg: KLConfig, blocks, b, i, j, prefetch):
     return _pf._should_run(flash, cfg.tile, i, j, prefetch[0], docs)
 
 
+def index_tile(dots_of, w_of, heads: int, relu_scr=None):
+    """A tile of the indexer's scores, ``sum_j ReLU(dots_of(j)) x w_of(j)``,
+    float32, the heads in order: ``dots_of(j)`` head j's products of the tile
+    (float32), ``w_of(j)`` its weight a query, shaped to broadcast over the
+    tile's keys. ``relu_scr``: where each head's ``ReLU(dots)`` is kept. The
+    selection's launch (``pallas_select``) makes its scores with this too."""
+    scores = None
+    for j in range(heads):
+        relu = jnp.maximum(dots_of(j), 0.0)
+        if relu_scr is not None:
+            relu_scr[j] = relu
+        term = relu * w_of(j)
+        scores = term if scores is None else scores + term
+    return scores
+
+
 def _tile(cfg: KLConfig, i, rows: int, q_ref, k_ref, lse_ref, sel_ref, qi_ref, ki_ref,
           w_ref, relu_scr=None):
     """One tile of q-block ``i`` of ``rows`` queries, transposed: (picked ``[bk,
@@ -160,14 +176,9 @@ def _tile(cfg: KLConfig, i, rows: int, q_ref, k_ref, lse_ref, sel_ref, qi_ref, k
     p = jnp.where(picked, total / cfg.heads, 0.0)
     w = w_ref[0].astype(f32)                                # [J, bq]
     keys = ki_ref[0]                                        # [bk, d]
-    scores = None
-    for j in range(cfg.index_heads):
-        dots = lax.dot_general(keys, qi_ref[0, j], _NT, preferred_element_type=f32)
-        relu = jnp.maximum(dots, 0.0)
-        if relu_scr is not None:
-            relu_scr[j] = relu
-        term = relu * w[j:j + 1, :]
-        scores = term if scores is None else scores + term
+    scores = index_tile(
+        lambda j: lax.dot_general(keys, qi_ref[0, j], _NT, preferred_element_type=f32),
+        lambda j: w[j:j + 1, :], cfg.index_heads, relu_scr)
     return picked, p, scores
 
 

@@ -254,8 +254,9 @@ def test_first_step_through_initialize(parts):
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}}})
     assert engine.attn_totals["dsa"] == {
         "topk": 8, "indexer_heads": 2, "indexer_head_dim": 8, "route": None,
-        "select": attention.SELECT_THRESHOLD, "dq": None, "layout": None, "kl": None,
-        "kl_tiles": None, "operand": "bits", "operand_bytes": None}
+        "select": None, "select_tiles": None, "select_rows": None, "dq": None,
+        "layout": None, "kl": None, "kl_tiles": None, "operand": "bits",
+        "operand_bytes": None}
     assert engine.attn_last_step() is None
     # (a row a device of the test mesh)
     batch = {"input_ids": np.concatenate([np.asarray(ids), np.asarray(ids)[:, ::-1]]
@@ -276,6 +277,10 @@ def test_first_step_through_initialize(parts):
     assert wrong / total < 0.01
     assert engine.attn_totals["dsa"]["route"] == "xla" and engine.attn_totals["dsa"]["dq"] is None
     assert engine.attn_totals["dsa"]["kl"] == "xla" and engine.attn_totals["dsa"]["kl_tiles"] is None
+    # off the chip the selection is the XLA loop's: no launch, no counts of one
+    assert engine.attn_totals["dsa"]["select"] == attention.SELECT_THRESHOLD
+    assert engine.attn_totals["dsa"]["select_tiles"] is None
+    assert engine.attn_totals["dsa"]["select_rows"] is None
     # a layer's operand over the step's rows: a bit a (query, key) pair
     assert engine.attn_totals["dsa"]["operand_bytes"] == len(rows) * 64 * 64 // 8
     last = engine.attn_last_step()

@@ -109,3 +109,46 @@ def test_off_the_kernel_route_a_plan_lists_no_launch(monkeypatch, call):
                  attention.plan((B, 128, H, D), (B, 128, H, D), "cpu", "", eva=(32, 4))):
         assert plan.route == "xla" and plan.launches == () and plan.dq("flash") is None
         assert plan.layout("flash") is None
+
+
+@pytest.mark.parametrize("backend,mode,length,want", [
+    ("tpu", "", 16384, ("kernel", (128, 512))), ("tpu", "", 1024, ("kernel", (128, 512))),
+    ("tpu", "pallas", 2048, ("kernel", (128, 512))),
+    ("tpu", "", 3072, ("kernel", (128, 512))),
+    ("tpu", "xla", 16384, ("bisection", None)),     # `attn_mode` asks for XLA
+    ("tpu", "", 512, ("bisection", None)),          # a short row's plane is 64 queries
+    ("tpu", "", 1536, ("bisection", None)),         # not whole groups of 1,024
+    ("tpu", "", 1000, ("bisection", None)),
+    ("cpu", "", 16384, ("bisection", None)),        # off the chip the XLA loop, always:
+    ("cpu", "pallas", 16384, ("bisection", None)),  # a model's interpreted runs too
+    ("cpu", "pallas", 64, ("bisection", None)),
+])
+def test_the_selections_form_follows_the_back_end_and_the_row(backend, mode, length, want):
+    """`attention.select_launch`: the one launch of ``pallas_select`` only on a
+    TPU, at whole groups of 1,024 queries in planes of the chip's 128 lanes."""
+    assert attention.select_launch(length, backend, mode) == want
+    assert attention.SELECT_THRESHOLD == "bisection"
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_dsa_select_takes_the_form_its_rule_names(monkeypatch, backend):
+    """`dsa_select` on a row of 1,024: the rule's launch, under scope
+    ``select`` and by the launch's name, where the back end is the chip; the
+    XLA loop's two scopes elsewhere."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setenv("DSTPU_ATTN", "")
+    monkeypatch.setattr(pallas_flash, "_auto_interpret", lambda: True)
+    L, J, d = 1024, 2, 8
+    draw = lambda i, shape: jax.random.normal(jax.random.PRNGKey(i), shape, jnp.float32)
+    args = draw(0, (B, L, J, d)), draw(1, (B, L, d)), draw(2, (B, L, J))
+    docs = (jnp.arange(L) >= 300).astype(jnp.int32)[None]
+    fn = jax.jit(lambda *a: attention.dsa_select(*a, docs, 64))
+    text, jaxpr = fn.lower(*args).as_text(debug_info=True), str(jax.make_jaxpr(fn)(*args))
+    assert ("pallas_call" in jaxpr and "name=dsa_select" in jaxpr) == (backend == "tpu")
+    assert "select/" in text and ("attn/indexer" in text) == (backend == "cpu")
+    want = attention.dsa_select_xla(*args, docs, 64)
+    got = fn(*args)
+    assert got.shape == want.shape and got.dtype == jnp.int8
+    # (the heads' sum in another order: a pair at a row's threshold may differ)
+    differ = attention.unpack_selection(got, L) != attention.unpack_selection(want, L)
+    assert int(jnp.sum(differ)) <= 2 * 4

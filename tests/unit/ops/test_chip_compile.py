@@ -163,6 +163,23 @@ class TestCompilesForV5e:
         text = jax.jit(fn).lower(*args).as_text()
         assert "indexer_kl_fwd" in text and "indexer_kl_bwd" in text
 
+    def test_dsa_select_at_16k(self, chip, monkeypatch):
+        """The keye-vl2-30b-a3b cell's selection: one row of 16,384 under
+        documents, an indexer of 16 heads of 64, topk 2048: `dsa_select` takes
+        the one launch (the scores' slots 8 MB of VMEM, the counting passes,
+        the triangular product of the tie rule, the int8 block ORed a plane at
+        a time), under the launch's own name."""
+        from deepspeed_tpu.ops.transformer import attention
+        B, L, J, d = 1, 16384, 16, 64
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv("DSTPU_ATTN", raising=False)
+        assert attention.select_launch(L, "tpu", "") == ("kernel", (128, 512))
+        fn = lambda q_idx, k_idx, w, doc: attention.dsa_select(q_idx, k_idx, w, doc, 2048)
+        args = (chip((B, L, J, d), BF16), chip((B, L, d), BF16), chip((B, L, J), F32),
+                chip((B, L), I32))
+        compile_for_chip(fn, *args)
+        assert "dsa_select" in jax.jit(fn).lower(*args).as_text()
+
     def test_eva_attention_at_32k(self, chip, monkeypatch):
         """The evabyte-6.5b cell's attention: 32 heads of 128 over a row of
         32,768 under EVA's mask with a window of 2048 and chunks of 16: the

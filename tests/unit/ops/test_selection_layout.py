@@ -129,3 +129,26 @@ def test_dsa_select_is_pack_of_select_topk_under_documents(length, cuts, topk):
     picked = np.asarray(attention.unpack_selection(got, length))
     np.testing.assert_array_equal(picked.sum(-1), np.minimum(np.asarray(seen).sum(-1), topk))
     assert not (picked & ~np.asarray(seen)).any()
+
+
+@pytest.mark.parametrize("backend,form", [("tpu", "kernel"), ("cpu", "bisection")])
+def test_the_records_carry_the_selections_form_and_counts(monkeypatch, backend, form):
+    """``attention_records`` says which form a step of 2,048-token rows traces
+    (`attention.select_launch`), and ``traced_rows_records`` hands on, beside
+    ``kl_tiles``, the launch's ``select_tiles`` ``[run, of]`` and
+    ``select_rows`` ``[thresholded, of]`` from a step's statistics."""
+    from deepspeed_tpu.models import keye_vl2_model
+    model = keye_vl2_model("keye-vl2-tiny", dtype=jnp.float32, max_seq_len=2048)
+    dsa = model.attention_records()[0]["dsa"]
+    assert dsa["select"] is None and dsa["select_tiles"] is None and dsa["select_rows"] is None
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setenv("DSTPU_ATTN", "")
+    dsa = model.attention_records(1, 2048)[0]["dsa"]
+    assert dsa["select"] == form and dsa["select_tiles"] is None
+    stats = {"dsa_kl_tiles": np.asarray([5, 64]), "dsa_select_tiles": np.asarray([30, 64]),
+             "dsa_select_rows": np.asarray([1200, 2048]), "attn_selected_share": 0.5}
+    assert model.traced_rows_records(stats) == {"dsa": {
+        "kl_tiles": [5, 64], "select_tiles": [30, 64], "select_rows": [1200, 2048]}}
+    assert model.traced_rows_records({"dsa_kl_tiles": np.asarray([5, 64])}) == {
+        "dsa": {"kl_tiles": [5, 64]}}
+    assert model.traced_rows_records({"attn_selected_share": 0.5}) == {}

@@ -153,6 +153,9 @@ KERNEL_NAMES = {
     # the indexer's KL a tile at a time (PR 49; ``pallas_indexer_kl``): NOT
     # ``flash_*_dsa``, whose reader sums the selected flash pair alone
     "indexer_kl_fwd", "indexer_kl_bwd",
+    # the selection a plane at a time (PR 52; ``pallas_select``): the launch
+    # the cell's ``breakdown`` shows in the XLA loop's two fusions' place
+    "dsa_select",
     # a share's rows back to the tokens (PR 38), under ``mlp/moe/combine`` and
     # ``mlp/moe/dispatch``: ``train_moe_dispatch_ms`` finds it by its scope
     "segment-sum"}
@@ -178,9 +181,9 @@ def test_every_pallas_call_has_a_name(site):
 
 
 def test_kernel_names_are_distinct_and_complete():
-    assert len(PALLAS_SITES) == 21
+    assert len(PALLAS_SITES) == 22
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 31
+    assert len(set(names)) == len(names) == 32
     assert set(names) == KERNEL_NAMES
 
 

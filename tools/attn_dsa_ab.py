@@ -15,10 +15,20 @@ bf16 operands), one layer:
   ``lax.top_k``), and ``threshold_sort``, a whole ``jnp.sort`` of the block's
   rows for scale; x 32 a layer. Every form's picks are compared with the
   first's: ``same_as_first``;
-- ``select``: ``attention.dsa_select``, the layer's whole pass (scores and
-  selection over the 32 blocks, the operand out: bits since PR 50, with its
-  ``operand_bytes``), and ``operand_transpose``, the copy the transposed
-  readers (``flash_bwd_dsa``, the KL pair) are handed;
+- ``select``: ``attention.dsa_select_xla``, the layer's whole pass in XLA
+  (scores and selection over the 32 blocks, the operand out: bits since PR 50,
+  with its ``operand_bytes``), and ``operand_transpose``, the copy the
+  transposed readers (``flash_bwd_dsa``, the KL pair) are handed;
+- ``select_kernel``: the same operand by the one launch of ``pallas_select``
+  (since PR 52; ``dsa_select`` on the chip), with the tiles it ran of its grid,
+  the queries it found a threshold for and the pairs whose bit differs from
+  the XLA form's (a score's last bit: the sixteen terms' order), the launch
+  with ``topk`` the row's length (``select_kernel_no_threshold``: no query has
+  a threshold, so what is left is the scores, one counting pass and the bits)
+  and under each of ``--select-blocks`` (``select_kernel_<block_k>``); then both
+  forms on each of the cell's own first ``--cell-rows`` rows' documents
+  (``benchmark/traffic/train.dsa16k.json``: ``select_row<i>``,
+  ``select_kernel_row<i>``);
 - ``core_dsa`` against ``core_causal``: the flash pair whose tiles unpack the
   selection's operand against the plain causal pair over the same heads and
   documents (forward, and forward + backward);
@@ -52,6 +62,10 @@ def main() -> int:
     ap.add_argument("--only", default="", help="run the cases that start with this alone")
     ap.add_argument("--kl-tiles", default="", help="the KL pair's tiles to try beside its "
                     "own choice, comma-separated <block_q>x<block_k>")
+    ap.add_argument("--select-blocks", default="", help="the selection launch's key "
+                    "blocks to try beside its own choice, comma-separated")
+    ap.add_argument("--cell-rows", type=int, default=3, help="rows of the Keye cell's "
+                    "own documents to time both forms of the selection on")
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
@@ -74,7 +88,7 @@ def main() -> int:
     block = slice(L - n, L)      # the row's last block: the most visible keys
     scores = jax.jit(attention.index_scores)(q_idx[:, block], k_idx, w[:, block])
     seen = attention.causal_in_document(jnp.arange(L - n, L), docs[:, block], docs)
-    picked = jax.jit(lambda: attention.dsa_select(q_idx, k_idx, w, docs, K))()
+    picked = jax.jit(lambda: attention.dsa_select_xla(q_idx, k_idx, w, docs, K))()
     scale = D ** -0.5
     lse = jax.jit(lambda q, k, v, picked: flash_attention_with_lse(
         q, k, v, causal=True, segment_ids=docs, selected=picked)[1])(q, k, v, picked)
@@ -108,10 +122,55 @@ def main() -> int:
             timed[f"threshold_{how}"]["same_as_first"] = bool(jnp.all(got == first))
             timed[f"threshold_{how}"]["picked_a_row"] = float(jnp.mean(jnp.sum(got, -1)))
     timing("threshold_sort", lambda s: jnp.sort(s, axis=-1)[..., -K], scores, times=blocks)
-    timing("select", lambda: attention.dsa_select(q_idx, k_idx, w, docs, K))
+    timing("select", lambda: attention.dsa_select_xla(q_idx, k_idx, w, docs, K))
     timing("operand_transpose", lambda p: jnp.swapaxes(p, 1, 2), picked)
     for name in {"select", "operand_transpose"} & set(timed):
         timed[name]["operand_bytes"] = picked.size * picked.dtype.itemsize
+    from deepspeed_tpu.ops.transformer import pallas_indexer_kl, pallas_select
+    compiled = jax.default_backend() != "cpu"
+
+    def select_pair(suffix, docs, want, blocks=()):
+        """Both forms of the selection under ``docs``: the XLA loop (unless
+        ``want``, its operand, is at hand) and the launch at its own tile and
+        at each of ``blocks``."""
+        if want is None:
+            want = timing("select" + suffix, lambda docs: attention.dsa_select_xla(
+                q_idx, k_idx, w, docs, K), docs)
+        visible = attention.visible_counts(docs)
+        for bk in (None,) + tuple(blocks):
+            tile = pallas_select.choose_tile(L, compiled, bk)
+            name = "select_kernel" + suffix + ("_%d" % bk if bk else "")
+            got = timing(name, lambda docs, tile=tile: pallas_select.select(
+                q_idx, k_idx, w, docs, K, tile), docs)
+            if got is None:
+                continue
+            timed[name].update(
+                tile=list(tile), tiles_run=int(pallas_flash.tiles_run(docs, docs, tile)[1]),
+                tiles_of=pallas_indexer_kl.tiles_of(1, L, tile),
+                rows_thresholded=int(jnp.sum(visible > K)),
+                visible_pairs=int(jnp.sum(visible)))
+            if bk is None:
+                # the same launch where no query has a threshold (topk the row's
+                # length): the scores, the visible count and the bits alone
+                timing(name + "_no_threshold", lambda docs, tile=tile: pallas_select.select(
+                    q_idx, k_idx, w, docs, L, tile), docs)
+            if want is not None:
+                differ = attention.unpack_selection(got, L) != attention.unpack_selection(want, L)
+                timed[name].update(
+                    pairs_differ_from_xla=int(jnp.sum(differ)),
+                    rows_differ_from_xla=int(jnp.sum(jnp.any(differ, axis=-1))))
+
+    select_pair("", docs, picked, [int(b) for b in args.select_blocks.split(",") if b])
+    if args.cell_rows:
+        from benchmark import traffic
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "traffic",
+                               "train.dsa16k.json")) as f:
+            mix = json.load(f)
+        vocab = mix["separator"] + 1         # the cell's slice: the separator its last id
+        batches = traffic.train_batches(dict(mix, seq_len=L), 0, vocab, 1)
+        for i in range(args.cell_rows):
+            ends = (next(batches)["input_ids"] == mix["separator"]).astype(np.int32)
+            select_pair("_row%d" % i, jnp.asarray(np.cumsum(ends, axis=1) - ends), None)
     total = lambda pair: jnp.sum(pair[0].astype(jnp.float32))
     dsa = lambda q, k, v: total(flash_attention_with_lse(
         q, k, v, causal=True, segment_ids=docs, selected=picked))
@@ -120,7 +179,6 @@ def main() -> int:
     for name, fn in (("core_dsa", dsa), ("core_causal", causal)):
         timing(name + "_forward", fn, q, k, v)
         timing(name + "_forward_backward", jax.grad(fn, argnums=(0, 1, 2)), q, k, v)
-    from deepspeed_tpu.ops.transformer import pallas_indexer_kl
     # (the target and the operand as arguments: a closed-over constant of tens
     # of MB is compiled into every case's program)
     target = (q, k, lse, picked, docs)

@@ -7,11 +7,16 @@ float32 reference's agree at a benchmark cell's own size, on the chip.
 The cell's weights and its first batch from ``--seed`` as ``jobs/train.py``
 makes them; layer 0's selection by the reference (``reference.selection``:
 float32 at ``highest``, ``lax.top_k`` a query) and by the program
-(``TransformerLM.selection`` in the cell's dtype, the threshold by
-``attention.SELECT_THRESHOLD``). One JSON line: the picked pairs of each, the
-pairs in one set and not the other as a share of the reference's
-(``differ_share``), and the rows whose two sets are equal; also in
-``chiprun_out/dsa_selection_agreement.json``.
+(``TransformerLM.selection`` in the cell's dtype, in the form
+``attention.select_launch`` names for the row and the back end: on the chip the
+one launch of ``pallas_select``, since PR 52; ``select`` says which) and, where
+that is the launch, by the XLA loop in its place as well
+(``attention.dsa_select_xla``, the threshold by ``attention.SELECT_THRESHOLD``).
+One JSON line: the picked pairs of each, the pairs in one set and not the other
+as a share of the reference's (``differ_share``; ``xla_differ_share`` the XLA
+loop's), the rows whose two sets are equal, and the pairs the program's two
+forms pick apart (``forms_differ_pairs``: a score's last bit, the sixteen
+terms' order); also in ``chiprun_out/dsa_selection_agreement.json``.
 """
 
 import argparse
@@ -49,18 +54,27 @@ def main() -> int:
         block = jax.tree.map(lambda a: a[0], params["blocks"])
         x, positions = model.embed(params, ids)
         h = model._layer("ln_1")(block["ln_1"], x)
-        from deepspeed_tpu.ops.transformer import attention
         return attention.unpack_selection(
             model.selection(block, h, positions, model._documents(ids))[3], ids.shape[1])
-    got = jax.jit(mine)(adapter.to_program(weights), ids)
+    from deepspeed_tpu.ops.transformer import attention
+    params = adapter.to_program(weights)
+    form = attention.select_launch(ids.shape[1], jax.default_backend(), attention.attn_mode())[0]
+    got = jax.jit(mine)(params, ids)
     count = lambda a: int(jnp.sum(a, dtype=jnp.int32))
-    out = {"workload": args.workload, "seed": args.seed, "layer": 0,
+    out = {"workload": args.workload, "seed": args.seed, "layer": 0, "select": form,
            "picked_reference": count(want), "picked_program": count(got),
            "differ_pairs": count(want != got),
            "rows": int(want.shape[0] * want.shape[1]),
            "rows_equal": count(jnp.all(want == got, axis=-1)),
            "device": device["kind"]}
     out["differ_share"] = out["differ_pairs"] / out["picked_reference"]
+    if form == "kernel":
+        os.environ["DSTPU_ATTN"] = "xla"        # `select_launch` then names the loop
+        loop = jax.jit(mine)(params, ids)
+        del os.environ["DSTPU_ATTN"]
+        out.update(xla_differ_share=count(want != loop) / out["picked_reference"],
+                   xla_rows_equal=count(jnp.all(want == loop, axis=-1)),
+                   forms_differ_pairs=count(got != loop))
     print(json.dumps(out), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/dsa_selection_agreement.json", "w") as f:

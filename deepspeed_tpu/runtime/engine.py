@@ -47,7 +47,8 @@ from ..resilience.fault_plan import (GUARDIAN_EXIT_CODE, STALL_EXIT_CODE,
                                      fault_point, maybe_install_from_env,
                                      parse_elastic_env)
 from ..resilience.guardian import build_guardian, pack_anomaly_word
-from ..telemetry import NULL_TELEMETRY, setup_spans
+from ..telemetry import NULL_TELEMETRY, clock, setup_spans
+from ..telemetry import memory as step_memory
 from ..utils.logging import log_dist, logger
 from ..utils.scope import scoped
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER,
@@ -602,6 +603,18 @@ class DeepSpeedEngine:
                              "candidate_bytes": 0, "room_bytes": None,
                              "budget_bytes": None, "working_bytes": 0,
                              "block_bytes": 0, "outside_bytes": 0}
+        # A step's memory, kept with telemetry off and written ONCE, when the
+        # first optimizer step has returned (``_account_memory``), from two
+        # readings of the allocator: ``limit_bytes``, ``resident_bytes``,
+        # ``step_extra_bytes`` (what the running step needs beyond what is
+        # resident before it: the runtime's reservation, where the step's own
+        # programs raised it), ``step_peak_bytes`` = residents + extra,
+        # ``headroom_bytes``. Every key is there from construction, None.
+        # Under a profiler session the residents are renewed once, so a trace
+        # holds the traced steps'.
+        self.memory_totals = step_memory.empty_totals()
+        self._memory_before = None      # the allocator before the step's first call
+        self._profiler_seen = False     # was the last step under a session?
         # overlap-planner state (set for real when the pipelined micro
         # builds; defaults keep non-overlap engines on the plain carry)
         self._ef_carry_active = False
@@ -1163,13 +1176,12 @@ class DeepSpeedEngine:
         CPU): everything named is saved. Across processes every host must
         decide alike, so until the reading is exchanged a multi-process run
         gets 0: the block recomputed whole."""
-        stats = [d.memory_stats() for d in self.mesh.devices.flat
-                 if d.process_index == jax.process_index()]
-        if not stats or not all(s and "bytes_limit" in s for s in stats):
+        fullest = step_memory.device_memory(self.mesh.devices.flat)
+        if fullest is None:
             return None
         if jax.process_count() > 1:
             return 0
-        free = min(s["bytes_limit"] - s["bytes_in_use"] for s in stats)
+        free = fullest["bytes_limit"] - fullest["bytes_in_use"]
         width = jnp.dtype(self.grad_dtype).itemsize
         grads = sum(p.size * max(p.dtype.itemsize, width)
                     for p in jax.tree.leaves(self.state["params"]))
@@ -2328,6 +2340,10 @@ class DeepSpeedEngine:
         shapes not met before, a constant no-op on every later call. A
         program compiled after set-up comes through here too."""
         key = () if batch is None else tuple(v.shape for v in batch.values())
+        if self._setup.open and program in ("train_step", "micro_step") \
+                and self._memory_before is None:
+            # the step's first program is about to be loaded (``memory_totals``)
+            self._memory_before = step_memory.device_memory(self.mesh.devices.flat)
         return self._setup.first_call(program, key, self.telemetry)
 
     def _drop_jits(self, *names: str) -> None:
@@ -2338,6 +2354,19 @@ class DeepSpeedEngine:
         self._setup.seen = {k: v for k, v in self._setup.seen.items()
                             if k[0] not in names}
 
+    def _account_memory(self) -> None:
+        """``memory_totals``, once, when the first optimizer step has
+        returned: the allocator as it reads now against how it read before
+        the step's first program was called (``_first_call``). No sync: the
+        allocator's bytes in use are the live arrays, which a step that is
+        still running has already written over its donated state, and the
+        reservation is made when a program is loaded."""
+        t0 = clock.now()
+        self.memory_totals.update(step_memory.step_totals(
+            self._memory_before, step_memory.device_memory(self.mesh.devices.flat)))
+        self.memory_totals["account_s"] = clock.now() - t0
+        log_dist(step_memory.describe(self.memory_totals), ranks=[0])
+
     def _after_step(self) -> None:
         """The end of an optimizer step: set-up ends with the first one, and
         while a profiler session is running the engine's counters go into
@@ -2347,13 +2376,24 @@ class DeepSpeedEngine:
             self._setup.finish()
             self._count_traced_rows()
             log_dist("set-up: " + json.dumps(self.setup_totals), ranks=[0])
-        if _profiler_on():
+            self._account_memory()
+        traced, seen = _profiler_on(), self._profiler_seen
+        self._profiler_seen = traced
+        if traced:
+            if not seen:
+                # a session's first step: the residents are the TRACED
+                # steps' (a harness may have freed buffers since the first)
+                step_memory.with_residents(
+                    self.memory_totals,
+                    step_memory.device_memory(self.mesh.devices.flat))
+                self._totals_flat = (-1, {})
             version, flat = self._totals_flat
             if version != self._setup.version:
                 flat = setup_spans.flat_totals(
                     setup=self.setup_totals, moe=self.moe_totals,
                     attn=self.attn_totals, opt_kernel=self.opt_kernel_totals,
-                    remat=self.remat_totals, diffusion=self.diffusion_totals or {})
+                    remat=self.remat_totals, diffusion=self.diffusion_totals or {},
+                    memory=self.memory_totals)
                 self._totals_flat = (self._setup.version, flat)
             if "moe.steps" in flat:     # the counters a step writes
                 flat["moe.steps"] = self.moe_totals["steps"]

@@ -7,6 +7,7 @@ trace."""
 
 import glob
 import json
+import sys
 import re
 import threading
 
@@ -17,7 +18,7 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.models import gpt2_model
-from deepspeed_tpu.telemetry import (NULL_TELEMETRY, get_telemetry,
+from deepspeed_tpu.telemetry import (NULL_TELEMETRY, get_telemetry, memory,
                                      reset_telemetry, setup_spans)
 
 #: every key of ``setup_totals`` (ISSUE 36, C; docs/OBSERVABILITY.md)
@@ -50,6 +51,7 @@ def stepped():
     """One engine through ``initialize`` and two steps, telemetry off."""
     reset_telemetry()
     engine = _engine()
+    engine.memory_at_construction = dict(engine.memory_totals)
     for _ in range(2):
         engine.train_batch(_batch())
     return engine
@@ -277,12 +279,26 @@ def test_flat_totals_encoding():
     assert all(not set("#,=") & set(f"{k}{v}") for k, v in flat.items())
 
 
-def test_engine_totals_rides_in_the_profiler_trace(stepped, tmp_path):
+def test_engine_totals_rides_in_the_profiler_trace(stepped, tmp_path, monkeypatch):
     """With a profiler session running a step writes ONE ``engine_totals``
     annotation whose stats are the flat counters; with none it writes
-    nothing (one flag test)."""
+    nothing (one flag test). The allocator is read again ONCE a session, at
+    its first step, and never on an untraced step: a trace's
+    ``memory.resident_bytes`` and what is made of it are of the steps it
+    holds."""
     from jax.profiler import ProfileData
+    readings = []
+
+    def allocator(devices):     # what a device with an allocator would report
+        readings.append(len(list(devices)))
+        return {"bytes_limit": 16_000, "bytes_in_use": 1_000 * len(readings),
+                "bytes_reserved": 500}
+
+    monkeypatch.setattr(memory, "device_memory", allocator)
+    # as if the first step had found its programs' reservation (the CPU has none)
+    monkeypatch.setitem(stepped.memory_totals, "step_extra_bytes", 500)
     stepped.train_batch(_batch())        # no session: nothing to find later
+    assert readings == []
     jax.profiler.start_trace(str(tmp_path))
     try:
         for _ in range(2):
@@ -290,6 +306,8 @@ def test_engine_totals_rides_in_the_profiler_trace(stepped, tmp_path):
         jax.block_until_ready(stepped.state)
     finally:
         jax.profiler.stop_trace()
+    stepped.train_batch(_batch())        # untraced again: no reading
+    assert len(readings) == 1
     [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
     found = [dict(e.stats) for plane in ProfileData.from_file(path).planes
              for line in plane.lines for e in line.events
@@ -306,6 +324,46 @@ def test_engine_totals_rides_in_the_profiler_trace(stepped, tmp_path):
         assert stats["remat.saved"] == "+".join(stepped.remat_totals["saved"])
         assert stats["opt_kernel.path"] == stepped.opt_kernel_totals["path"]
         assert stats["attn.layers_full"] == 2
+        assert {k: v for k, v in stats.items() if k.startswith("memory.")} == {
+            "memory.limit_bytes": 16_000, "memory.resident_bytes": 1_000,
+            "memory.step_extra_bytes": 500, "memory.step_peak_bytes": 1_500,
+            "memory.headroom_bytes": 14_500,
+            "memory.account_s": pytest.approx(stepped.memory_totals["account_s"])}
+    # as the CPU leaves it, for the tests that follow
+    memory.with_residents(stepped.memory_totals, None)
+
+
+# ---------------------------------------------------------------------------
+# a step's memory (ISSUE 53): ``engine.memory_totals``, written once when the
+# first optimizer step has returned, from the allocator's own reading
+# ---------------------------------------------------------------------------
+
+def test_memory_totals_from_construction_and_after_two_steps(stepped):
+    assert stepped.memory_at_construction == memory.empty_totals()
+    m = stepped.memory_totals
+    assert set(m) == set(memory.empty_totals())
+    assert 0 < m["account_s"] < 1.0
+    # the CPU's allocator reports nothing: every byte stays None
+    assert {v for k, v in m.items() if k != "account_s"} == {None}
+    # it cost set-up no program
+    t = stepped.setup_totals
+    assert set(t["programs"]) == {"init_state", "train_step", setup_spans.EAGER}
+    assert t["programs_compiled"] == 2 and t["compiled_after_setup"] == 0
+    # written once: a later step leaves the very dict as it was
+    before = json.dumps(m)
+    stepped.train_batch(_batch())
+    assert json.dumps(stepped.memory_totals) == before
+
+
+def test_flat_totals_carries_the_memory_counter(stepped):
+    flat = setup_spans.flat_totals(memory=stepped.memory_totals)
+    assert flat == {"memory.account_s": stepped.memory_totals["account_s"]}   # None is left out
+    full = memory.step_totals({"bytes_limit": 16, "bytes_in_use": 5, "bytes_reserved": 0},
+                              {"bytes_limit": 16, "bytes_in_use": 4, "bytes_reserved": 1})
+    assert setup_spans.flat_totals(memory=full) == {
+        "memory.limit_bytes": 16, "memory.resident_bytes": 4,
+        "memory.reserved_before_bytes": 0, "memory.step_extra_bytes": 1,
+        "memory.step_peak_bytes": 5, "memory.headroom_bytes": 11}
 
 
 # ---------------------------------------------------------------------------
@@ -317,6 +375,14 @@ def test_the_split_path_and_the_flops_probe(monkeypatch, tmp_path):
     ``apply_step``; the MFU's FLOPs come from the lowered module, so the
     first flush compiles nothing (no ``flops_probe`` entry)."""
     monkeypatch.setenv("DSTPU_FUSED_STEP", "0")
+    readings = []
+
+    def allocator(devices):     # a device whose step programs reserve 500 bytes
+        readings.append(sys._getframe(1).f_code.co_name)
+        return {"bytes_limit": 16 * 10 ** 9, "bytes_in_use": 1_000,
+                "bytes_reserved": 500 if len(readings) > 1 else 0}
+
+    monkeypatch.setattr(memory, "device_memory", allocator)
     reset_telemetry()
     engine = _engine(tmp_path)
     for _ in range(2):
@@ -328,6 +394,14 @@ def test_the_split_path_and_the_flops_probe(monkeypatch, tmp_path):
     assert engine._telemetry_flops() > 0
     assert "flops_probe" not in t["programs"]
     assert t["programs_compiled"] == compiled
+    # the allocator was read before the step's FIRST program (not again
+    # before its second) and when the first optimizer step had returned
+    m = engine.memory_totals
+    assert readings[0] == "_first_call" and readings.count("_first_call") == 1
+    assert readings[-1] == "_account_memory" and readings.count("_account_memory") == 1
+    assert m["reserved_before_bytes"] == 0 and m["step_extra_bytes"] == 500
+    assert m["resident_bytes"] == 1_000 and m["step_peak_bytes"] == 1_500
+    assert m["headroom_bytes"] == 16 * 10 ** 9 - 1_500 and 0 < m["account_s"] < 1.0
     reset_telemetry()
 
 

@@ -4,15 +4,25 @@ shows (PERF.md section 7, row 14): build a benchmark cell's engine as
 ``benchmark/jobs/train.py`` does, run two steps, then fill the device with
 128 MiB arrays, one more before each further step, until a step fails.
 
+Since PR 53 the program keeps an account of its own, ``engine.memory_totals``
+(two readings of the allocator, on every traced run's line as
+``train_step_peak_gb`` / ``train_step_temp_gb``): this probe is that
+account's CHECK, at its grain of one filling, and no longer the only source.
+It prints the account beside its own truth, and every candidate there is for
+``step_extra_bytes`` from inside the program less the truth
+(``account_minus_probe_bytes``): the compiled step's fields, which the tool
+reads itself and the program does not keep, and the allocator's counters.
+
     chiprun -- python tools/remat_fill_probe.py --workload <cell> [--seed n]
         [--room BYTES] [--rows r] [--seq s]
 
 ``--room`` puts a number in the place of the engine's reading of the device
 (0: the blocks recomputed whole), ``--rows`` / ``--seq`` another batch than
 the cell's. One JSON line: what ``engine.remat_totals`` decided, the bytes
-in use at rest, and ``true_peak_bytes`` = ``bytes_limit`` less the largest
-filling under which a step still ran. This is how ``checkpointing.
-WORKING_SHARE`` and ``STACK_COST`` were fitted (PERF.md, PR 35).
+in use at rest, ``true_peak_bytes`` = ``bytes_limit`` less the largest
+filling under which a step still ran, ``memory_totals`` and the candidates'
+differences. This is how ``checkpointing.WORKING_SHARE`` and ``STACK_COST``
+were fitted (PERF.md, PR 35). A fresh process a cell and seed (row 22(h)).
 """
 
 from __future__ import annotations
@@ -69,11 +79,13 @@ def main() -> None:
     stream = traffic.train_batches(mix, args.seed, cfg["vocab_size"], rows)
     in_use = lambda: int(device.memory_stats()["bytes_in_use"])
     at_trace = in_use()
+    step_avals = spy_on_the_step(engine)
     float(engine.train_batch(next(stream)))
     del signs
     float(engine.train_batch(next(stream)))
     jax.block_until_ready(engine.state)
     limit, at_rest = int(device.memory_stats()["bytes_limit"]), in_use()
+    account = account_of(engine, at_rest, step_avals)
     filling, held, laps, failure = [], 0, [], None
     while failure is None:
         try:
@@ -94,7 +106,12 @@ def main() -> None:
         "filled_bytes": held, "true_peak_bytes": limit - held,
         "step_temporaries_bytes": limit - held - at_rest, "failure": failure,
         "synced_step_ms": statistics.median(laps) if laps else None,
+        **account,
     }
+    truth = line["step_temporaries_bytes"]
+    line["account_minus_probe_bytes"] = {
+        name: None if got is None else got - truth
+        for name, got in line.pop("candidates").items()}
     print(json.dumps(line), flush=True)
     out = os.path.join(harness.CHECKOUT, "chiprun_out")
     os.makedirs(out, exist_ok=True)
@@ -102,6 +119,65 @@ def main() -> None:
         f.write(json.dumps(line) + "\n")
     # the failed step may have taken the donated state with it: leave now
     os._exit(0)
+
+
+def spy_on_the_step(engine) -> list:
+    """The fused step's arguments at its next call, as ``ShapeDtypeStruct``s
+    that find the call's lowering again (a committed array keeps its
+    sharding, an uncommitted scalar has none, as the call saw them): taken
+    before the call donates its state. The list is filled by that call."""
+    import jax
+    engine._build_fused_jit()
+    jitted, seen = engine._jit_train_step, []
+
+    def aval(x):
+        if not isinstance(x, jax.Array):
+            return x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+
+    def step(*args):
+        seen.extend(jax.tree.map(aval, args))
+        engine._jit_train_step = jitted
+        return jitted(*args)
+
+    engine._jit_train_step = step
+    return seen
+
+
+def account_of(engine, at_rest: int, step_avals: list) -> dict:
+    """The program's account beside the probe's truth: ``engine.memory_totals``
+    with its residents renewed to this moment's (the signs are gone, as in a
+    traced window), and every candidate for ``step_extra_bytes`` there is
+    from inside the program: the compiled step's own fields (the tool asks
+    the executable again: ``lower(...).compile()`` on the call's own avals
+    finds it, and where it does not the tool pays a compile), and the
+    allocator's counters. PR 53 found the allocator's reservation the true
+    one in seven cells of seven (PERF.md section 7, row 14)."""
+    from deepspeed_tpu.telemetry import memory
+    device = memory.device_memory(engine.mesh.devices.flat)
+    out = {"memory_totals": memory.with_residents(dict(engine.memory_totals), device),
+           "device": device}
+    with engine.mesh:
+        mem = engine._jit_train_step.lower(*step_avals).compile().memory_analysis()
+    temp, peak = mem.temp_size_in_bytes, getattr(mem, "peak_memory_in_bytes", 0)
+    # the donated state is resident before the step and its new value is
+    # written over it: what an output adds is what is NOT aliased
+    fresh = mem.output_size_in_bytes - mem.alias_size_in_bytes
+    out["compiled"] = {
+        "argument_bytes": mem.argument_size_in_bytes, "output_bytes": mem.output_size_in_bytes,
+        "alias_bytes": mem.alias_size_in_bytes, "temp_bytes": temp,
+        "code_bytes": mem.generated_code_size_in_bytes, "compiler_peak_bytes": peak or None,
+        "buffer_assignment_bytes": len(getattr(
+            mem, "serialized_buffer_assignment_proto", b"") or b"")}
+    out["candidates"] = {
+        "temp_output_less_alias": temp + fresh,
+        "temp_output_code_less_alias": temp + fresh + mem.generated_code_size_in_bytes,
+        "peak_less_argument": peak - mem.argument_size_in_bytes if peak else None,
+        "peak_less_alias": peak - mem.alias_size_in_bytes if peak else None,
+        "allocator_peak_less_rest": device["peak_bytes_in_use"] - at_rest,
+        "reserved": memory.reserved_bytes(device)}
+    return out
 
 
 if __name__ == "__main__":

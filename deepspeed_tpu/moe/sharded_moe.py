@@ -24,6 +24,7 @@ and not gradient moves, the sequence-wise balance loss).
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -107,6 +108,40 @@ def top_k_gating_indices(logits: jax.Array, top_k: int, capacity_: int):
 BALANCE_LOSSES = ("gshard_top1", "topk_share")
 
 
+# The no-drop routers' bookkeeping is written over the one-hot of the picks,
+# ``[tokens, k, experts]`` bool, which is never stored: each reader is one
+# fused compare-select-reduce. Nothing here is a scatter, a scatter-add or a
+# gather of scalars, which the TPU runs one element at a time (8.7, 4.6 and
+# 7.9 ns each on the v5e: docs/KERNELS.md, "The no-drop expert path").
+
+def _picks(expert_idx: jax.Array, num_experts: int) -> jax.Array:
+    """[tokens, k] int -> [tokens, k, experts] bool: the one-hot of each pick."""
+    return expert_idx[:, :, None] == jnp.arange(num_experts, dtype=expert_idx.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _picked(scores: jax.Array, expert_idx: jax.Array, num_experts: int) -> jax.Array:
+    """``scores[t, expert_idx[t, j]]``, [tokens, k] float32: the scores
+    selected under the picks' one-hot, and the largest over the experts (one
+    entry is selected, so it is that entry to the bit; and no sum, which the
+    compiler would merge with a sum over the k that follows and add the k
+    in another order). Backward: a pick's cotangent lands on one expert."""
+    return jnp.max(jnp.where(_picks(expert_idx, num_experts), scores[:, None, :],
+                             -jnp.inf), axis=-1)
+
+
+def _picked_fwd(scores, expert_idx, num_experts):
+    return _picked(scores, expert_idx, num_experts), expert_idx
+
+
+def _picked_bwd(num_experts, expert_idx, g):
+    return jnp.sum(jnp.where(_picks(expert_idx, num_experts), g[:, :, None], 0.0),
+                   axis=1), None
+
+
+_picked.defvjp(_picked_fwd, _picked_bwd)
+
+
 def softmax_topk_router(logits: jax.Array, top_k: int, *, normalize: bool,
                         balance_loss: str = "topk_share"):
     """The dropless router: a float32 softmax over ALL experts' logits,
@@ -135,10 +170,12 @@ def softmax_topk_router(logits: jax.Array, top_k: int, *, normalize: bool,
     tokens, num_experts = logits.shape
     logits = logits.astype(jnp.float32)
     gates = jax.nn.softmax(logits, axis=-1)
-    weight, expert_idx = jax.lax.top_k(gates, top_k)
+    # ``top_k`` for the picks alone: its values' gradient is a scatter-add
+    _, expert_idx = jax.lax.top_k(jax.lax.stop_gradient(gates), top_k)
+    weight = _picked(gates, expert_idx, num_experts)
     if normalize:
         weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
-    rows = jnp.zeros((num_experts,), jnp.int32).at[expert_idx.reshape(-1)].add(1)
+    rows = jnp.sum(_picks(expert_idx, num_experts), axis=(0, 1), dtype=jnp.int32)
     if balance_loss == "topk_share":
         share = rows.astype(jnp.float32) / (tokens * top_k)
     else:
@@ -171,14 +208,14 @@ def sigmoid_bias_router(logits: jax.Array, bias: jax.Array, top_k: int, *,
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     biased = s + jax.lax.stop_gradient(bias.astype(jnp.float32))
     _, expert_idx = jax.lax.top_k(biased, top_k)
-    weight = jnp.take_along_axis(s, expert_idx, axis=-1)
+    weight = _picked(s, expert_idx, num_experts)
     if normalize:
         weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
     weight = weight * routed_scale
     seqs = tokens // rows_per_seq
-    seq_of = jnp.repeat(jnp.arange(seqs, dtype=jnp.int32), rows_per_seq * top_k)
-    chose = jnp.zeros((seqs, num_experts), jnp.int32).at[
-        seq_of, expert_idx.reshape(-1)].add(1)
+    chose = jnp.sum(_picks(expert_idx, num_experts)
+                    .reshape(seqs, rows_per_seq * top_k, num_experts),
+                    axis=1, dtype=jnp.int32)
     f = chose.astype(jnp.float32) * (num_experts / (top_k * rows_per_seq))
     p = jnp.mean((s / jnp.sum(s, axis=-1, keepdims=True))
                  .reshape(seqs, rows_per_seq, num_experts), axis=1)

@@ -309,6 +309,81 @@ def test_a_share_never_drops_a_row(bias, short):
     assert held_capacity(6144, 6, 16) == 6144 and held_capacity(98304, 8, 64) == 36864
 
 
+# The sigmoid router's formulas until PR 53, kept as the plain reference of
+# what replaced them in PR 54 (tests/unit/moe/test_dropless.py has the
+# softmax router's and the sort's): the chosen scores by a gather of scalars,
+# a sequence's counts by a scatter-add.
+
+def sigmoid_router_as_it_was(logits, bias, top_k, *, normalize, routed_scale, rows_per_seq):
+    tokens, num_experts = logits.shape
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    biased = s + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    _, expert_idx = jax.lax.top_k(biased, top_k)
+    weight = jnp.take_along_axis(s, expert_idx, axis=-1)
+    if normalize:
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    weight = weight * routed_scale
+    seqs = tokens // rows_per_seq
+    seq_of = jnp.repeat(jnp.arange(seqs, dtype=jnp.int32), rows_per_seq * top_k)
+    chose = jnp.zeros((seqs, num_experts), jnp.int32).at[
+        seq_of, expert_idx.reshape(-1)].add(1)
+    f = chose.astype(jnp.float32) * (num_experts / (top_k * rows_per_seq))
+    p = jnp.mean((s / jnp.sum(s, axis=-1, keepdims=True))
+                 .reshape(seqs, rows_per_seq, num_experts), axis=1)
+    balance = jnp.mean(jnp.sum(f * p, axis=-1))
+    return (expert_idx.astype(jnp.int32), weight,
+            jnp.stack([balance, jnp.zeros((), jnp.float32)]),
+            jnp.sum(chose, axis=0))
+
+
+@pytest.mark.parametrize("rows_per_seq", [96, 48], ids=["one-sequence", "two-sequences"])
+@pytest.mark.parametrize("normalize", [False, True], ids=["as-is", "renormalised"])
+@pytest.mark.parametrize("top_k", [1, 6, 8])
+def test_the_sigmoid_router_is_the_integers_and_the_bits_it_was(top_k, normalize, rows_per_seq):
+    """Tied logits, a bias that breaks some of the ties, an expert that
+    draws nothing: picks and counts as integers; weights, losses and both
+    gradients by the logits to the bit."""
+    from tests.unit.moe.test_dropless import routed, same_bits, tied_logits
+    logits = tied_logits(96, 16, seed=top_k)
+    bias = jnp.asarray(np.random.default_rng(1).choice([0.0, 0.05], 16), jnp.float32)
+    ct = jax.random.normal(jax.random.PRNGKey(3), (96, top_k))
+    kw = dict(top_k=top_k, normalize=normalize, routed_scale=2.5, rows_per_seq=rows_per_seq)
+    got = jax.jit(lambda l, c: routed(
+        lambda x, **k: sigmoid_bias_router(x, bias, **k), l, c, **kw))(logits, ct)
+    want = jax.jit(lambda l, c: routed(
+        lambda x, **k: sigmoid_router_as_it_was(x, bias, **k), l, c, **kw))(logits, ct)
+    same_bits(got, want)
+    rows = np.asarray(got[0][3])
+    assert rows[-1] == 0 and rows.sum() == 96 * top_k
+    assert float(jnp.abs(got[1]).max()) > 0 and float(jnp.abs(got[2]).max()) > 0
+
+
+@pytest.mark.parametrize("held,bias", [(None, 0.0), ((0, 2), 0.0), ((0, 2), 6.0)],
+                         ids=["every-expert", "a-share", "a-share-over-its-buffer"])
+def test_the_sigmoid_layer_is_the_program_it_was(held, bias, monkeypatch):
+    """Both paths under the sigmoid router, two sequences a batch, the last
+    case with more rows for the held experts than the buffer has: output,
+    losses and rows to the bit, the gradients to float32's rounding (as
+    ``test_dropless.py::test_the_layer_is_the_program_it_was`` says why)."""
+    from deepspeed_tpu.moe import layer as L
+    from tests.unit.moe.test_dropless import (close as near, layer_and_gradients, same_bits,
+                                              sorted_as_it_was)
+    E, k, h, f = 16, 3, 32, 16
+    moe = MoE(h, f, num_experts=E, top_k=k, capacity_factor=None, balance_loss="topk_share",
+              router="sigmoid_bias", routed_scale=2.5, experts_held=held)
+    params = jax.tree.map(lambda a: a * 20, moe.init(jax.random.PRNGKey(0)))
+    params["bias"] = jnp.zeros((E,)).at[0:2].set(bias)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 1024, h))
+    (got, got_aux), got_g = layer_and_gradients(moe, params, x)
+    monkeypatch.setattr(L, "sigmoid_bias_router", sigmoid_router_as_it_was)
+    monkeypatch.setattr(L, "_sorted_by", sorted_as_it_was)
+    (want, want_aux), want_g = layer_and_gradients(moe, params, x)
+    same_bits((got, got_aux), (want, want_aux))
+    for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        near(a, b)
+    assert (int(got_aux[2][0:2].sum()) > held_capacity(2048 * k, 2, E)) is (bias > 0)
+
+
 def test_what_the_new_fields_refuse():
     moe = MoEConfig(num_experts=8, top_k=2, capacity_factor=None, router="sigmoid_bias")
     with pytest.raises(ValueError, match="sigmoid_bias"):

@@ -481,3 +481,169 @@ def test_a_steps_work_does_not_follow_the_rows():
     texts = {str(jax.make_jaxpr(lambda f: S.kernel_segment_sum(
         rows, seg, jnp.ones((512,)), f, 256))(jnp.int32(filled))) for filled in (0, 100, 512)}
     assert len(texts) == 1 and "grid=(" + str(512 // 256 + 256 // 128) in texts.pop().replace(" ", "")
+
+
+# -- the router's bookkeeping without a scatter or a gather of scalars (PR 54) --
+# The formulas the routers and the two paths had until PR 53, kept here as the
+# plain reference: the experts' counts by a scatter-add, the inverse
+# permutation by a scatter, the chosen scores as ``top_k``'s values (whose
+# gradient is a scatter-add). What replaced them gives the same integers and
+# the same bits, forward and in the gradient.
+
+def softmax_router_as_it_was(logits, top_k, *, normalize, balance_loss="topk_share"):
+    tokens, num_experts = logits.shape
+    logits = logits.astype(jnp.float32)
+    gates = jax.nn.softmax(logits, axis=-1)
+    weight, expert_idx = jax.lax.top_k(gates, top_k)
+    if normalize:
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    rows = jnp.zeros((num_experts,), jnp.int32).at[expert_idx.reshape(-1)].add(1)
+    if balance_loss == "topk_share":
+        share = rows.astype(jnp.float32) / (tokens * top_k)
+    else:
+        share = jnp.mean(jax.nn.one_hot(expert_idx[:, 0], num_experts,
+                                        dtype=jnp.float32), axis=0)
+    balance = num_experts * jnp.sum(share * jnp.mean(gates, axis=0))
+    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    return (expert_idx.astype(jnp.int32), weight, jnp.stack([balance, z]), rows)
+
+
+def sorted_as_it_was(key):
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.size, dtype=jnp.int32), unique_indices=True)
+    return order, inv
+
+
+def tied_logits(tokens, experts, seed):
+    """Logits in steps of a half, so that most rows hold ties among their
+    largest; the last expert far below (it draws nothing)."""
+    rng = np.random.default_rng(seed)
+    logits = np.round(rng.normal(0.0, 1.0, (tokens, experts)) * 2) / 2
+    logits[:, -1] = -30.0
+    return jnp.asarray(logits, jnp.float32)
+
+
+def same_bits(got, want):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def routed(router, logits, ct, **kw):
+    """What a router returns, and the gradients of its weights (under the
+    cotangent ``ct``) and of its losses by the logits."""
+    out = router(logits, **kw)
+    d_weight = jax.grad(lambda l: jnp.sum(router(l, **kw)[1] * ct))(logits)
+    d_losses = jax.grad(lambda l: jnp.sum(router(l, **kw)[2] * jnp.asarray([1.0, 0.37])))(logits)
+    return out, d_weight, d_losses
+
+
+@pytest.mark.parametrize("balance_loss", ["topk_share", "gshard_top1"])
+@pytest.mark.parametrize("normalize", [False, True], ids=["as-is", "renormalised"])
+@pytest.mark.parametrize("top_k", [1, 6, 8])
+def test_the_softmax_router_is_the_integers_and_the_bits_it_was(top_k, normalize, balance_loss):
+    logits = tied_logits(96, 16, seed=top_k)
+    ct = jax.random.normal(jax.random.PRNGKey(3), (96, top_k))
+    kw = dict(top_k=top_k, normalize=normalize, balance_loss=balance_loss)
+    got = jax.jit(lambda l, c: routed(softmax_topk_router, l, c, **kw))(logits, ct)
+    want = jax.jit(lambda l, c: routed(softmax_router_as_it_was, l, c, **kw))(logits, ct)
+    same_bits(got, want)
+    rows = np.asarray(got[0][3])
+    assert rows[-1] == 0 and rows.sum() == 96 * top_k
+    assert float(jnp.abs(got[1]).max()) > 0 and float(jnp.abs(got[2]).max()) > 0
+
+
+@pytest.mark.parametrize("held", [(0, 16), (4, 8)], ids=["every-expert", "a-quarter"])
+@pytest.mark.parametrize("top_k", [1, 6, 8])
+def test_the_sort_and_its_inverse_are_the_permutations_they_were(top_k, held):
+    from deepspeed_tpu.moe.layer import _sorted_by
+    eidx = jax.lax.top_k(tied_logits(96, 16, seed=top_k), top_k)[1].reshape(-1)
+    local, nh = eidx - held[0], held[1] - held[0]
+    key = jnp.where((local >= 0) & (local < nh), local, nh)
+    order, inv = jax.jit(_sorted_by)(key)
+    same_bits((order, inv), sorted_as_it_was(key))
+    np.testing.assert_array_equal(np.asarray(order)[np.asarray(inv)], np.arange(96 * top_k))
+
+
+def as_it_was(monkeypatch):
+    """The layer on PR 53's formulas."""
+    from deepspeed_tpu.moe import layer as L
+    monkeypatch.setattr(L, "softmax_topk_router", softmax_router_as_it_was)
+    monkeypatch.setattr(L, "_sorted_by", sorted_as_it_was)
+
+
+def layer_and_gradients(moe, params, x):
+    def loss(p, v):
+        out, losses, rows = moe.dropless_forward(p, v)
+        return jnp.sum(jnp.sin(out)) + jnp.sum(losses), (out, losses, rows)
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(params, x)
+
+
+@pytest.mark.parametrize("top_k,held,fill", [
+    (1, (8, 16), "below"), (6, None, "below"), (8, (8, 16), "beyond")])
+def test_the_layer_is_the_program_it_was(top_k, held, fill, monkeypatch):
+    """Both paths over two sequences a batch, with the held experts drawing
+    fewer rows than the buffer has and more (``_overflow_rows`` runs):
+    output, losses and rows to the bit. The gradients to float32's rounding:
+    op for op they are the same bits too (run eagerly they are), but the CPU
+    compiler contracts multiply-adds as its fusions fall, and those fall
+    differently around a scatter-add and a select."""
+    moe = share_layer(top_k, held)
+    params, x, cap = steered(share_layer(top_k, (8, 16)), fill)
+    if held is None:
+        whole = jax.tree.map(lambda a: a * 10.0, moe.init(jax.random.PRNGKey(0)))
+        params = dict(whole, gate=params["gate"])
+    x = x.reshape(2, SHARE_T // 2, H)
+    (got, got_aux), got_g = layer_and_gradients(moe, params, x)
+    as_it_was(monkeypatch)
+    (want, want_aux), want_g = layer_and_gradients(moe, params, x)
+    same_bits((got, got_aux), (want, want_aux))
+    for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        close(a, b)
+    drawn = int(np.asarray(got_aux[2])[8:16].sum())
+    assert (drawn > cap) is (fill == "beyond")
+
+
+def under_scope(jaxpr, scope, outer=""):
+    """(name stack, primitive) of every equation under the name scope,
+    through every jaxpr an equation carries (``jit``, a custom gradient, a
+    loop, a rematerialised block)."""
+    for eqn in jaxpr.eqns:
+        stack = f"{outer}/{eqn.source_info.name_stack}"
+        if scope in stack:
+            yield stack, eqn.primitive.name
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from under_scope(sub, scope, stack)
+
+
+@pytest.mark.parametrize("held", [None, (4, 8)], ids=["every-expert", "a-quarter"])
+@pytest.mark.parametrize("router", ["softmax", "sigmoid_bias"])
+def test_the_route_lowers_to_no_scatter_and_no_gather(router, held):
+    """No chip needed: the forward, and the gradient of a block made again
+    (forward, made again and backward), as jaxprs. The TPU runs a scatter,
+    a scatter-add and a gather of scalars one element at a time; a sort, a
+    compare and a select it does not."""
+    moe = MoE(H, F, num_experts=16, top_k=3, capacity_factor=None, router=router,
+              balance_loss="topk_share", experts_held=held)
+    params, x = moe.init(jax.random.PRNGKey(0)), jnp.zeros((2, 64, H))
+
+    def loss(p, v):
+        out, losses, _ = moe.dropless_forward(p, v)
+        return jnp.sum(out) + jnp.sum(losses)
+
+    made_again = ("/jvp(moe/route", "/transpose(jvp(jvp()))/rematted_computation/moe/route",
+                  "/transpose(jvp(jvp()))/moe/route")
+    for fn, passes in ((moe.dropless_forward, ("/moe/route",)),
+                       (jax.grad(jax.checkpoint(loss), (0, 1)), made_again)):
+        jaxpr = jax.make_jaxpr(fn)(params, x).jaxpr
+        found = list(under_scope(jaxpr, "moe/route"))
+        assert all(any(s.startswith(p) for s, _ in found) for p in passes)
+        assert {"top_k", "sort", "dot_general"} <= {name for _, name in found}
+        assert not [f for f in found if "scatter" in f[1] or "gather" in f[1]]
+        # and the walk does see one where there is one
+        assert any("gather" in name for _, name in under_scope(jaxpr, "moe/dispatch"))

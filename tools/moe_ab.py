@@ -24,6 +24,21 @@ slot, kept here as the reference) against ``moe/layer.py``'s
 tiles, the gather alone, the index arithmetic alone and the XLA form. One
 JSON line a reading on stdout and in ``chiprun_out/rows_ab.jsonl``.
 
+``python tools/moe_ab.py route`` (PR 54): the no-drop routers' bookkeeping at
+the expert cells' three shapes (16,384 tokens x 8 of 128 experts; 16,384 x 6
+of 64; 4,096 x 8 of 64): the forms the routers and the two paths had until
+PR 53 (the experts' counts by a scatter-add, the inverse permutation by a
+scatter, the chosen scores by ``take_along_axis`` or as ``top_k``'s values,
+whose gradients are scatter-adds; the whole routers as the tests keep them,
+``tests/unit/moe/test_dropless.py`` and ``tests/unit/models/
+test_instella_moe.py``) against what ``moe/sharded_moe.py`` and
+``moe/layer.py`` have now, forward and under ``jax.grad``, with a few forms
+that were not taken. One JSON line a reading on stdout and in
+``chiprun_out/route_ab.jsonl``: ms a trip of a loop on the device (64 trips a
+dispatch: a call from the host costs 0.2 ms, more than most of these forms),
+``floor_ms`` what the loop itself costs a trip, and ``same``: whether the new
+form's result is the old one's to the bit ON THE CHIP.
+
 ``python tools/moe_ab.py`` (round 2): the capacity-dense batched einsum
 against ``ragged_dot`` at the smoke's MoE dims.
 
@@ -214,18 +229,24 @@ def best_ms(fn, args, iters=10, windows=3):
     return 1e3 * best
 
 
-def gmm_against_ragged(sweep: bool, parts: bool, only):
-    from deepspeed_tpu.ops.transformer import pallas_gmm as G
-    if jax.default_backend() != "tpu":
-        raise SystemExit("tools/moe_ab.py gmm measures a TPU; none is attached")
+def emitter(name):
+    """-> emit(record): one JSON line on stdout and in ``chiprun_out/<name>.jsonl``."""
     os.makedirs("chiprun_out", exist_ok=True)
-    log = open("chiprun_out/gmm_ab.jsonl", "a")
+    log = open(f"chiprun_out/{name}.jsonl", "a")
 
     def emit(rec):
         line = json.dumps(rec)
         print(line, flush=True)
         log.write(line + "\n")
         log.flush()
+    return emit
+
+
+def gmm_against_ragged(sweep: bool, parts: bool, only):
+    from deepspeed_tpu.ops.transformer import pallas_gmm as G
+    if jax.default_backend() != "tpu":
+        raise SystemExit("tools/moe_ab.py gmm measures a TPU; none is attached")
+    emit = emitter("gmm_ab")
 
     dt = jnp.bfloat16
     for shape, (m, h, f, g, cell_rows, padded) in SHAPES.items():
@@ -331,14 +352,7 @@ def rows_to_tokens(only):
     from deepspeed_tpu.ops.transformer import pallas_segment_sum as S
     if jax.default_backend() != "tpu":
         raise SystemExit("tools/moe_ab.py rows measures a TPU; none is attached")
-    os.makedirs("chiprun_out", exist_ok=True)
-    log = open("chiprun_out/rows_ab.jsonl", "a")
-
-    def emit(rec):
-        line = json.dumps(rec)
-        print(line, flush=True)
-        log.write(line + "\n")
-        log.flush()
+    emit = emitter("rows_ab")
 
     for shape, (n_tok, h, k, experts, nh) in ROW_SHAPES.items():
         if only and shape not in only:
@@ -391,19 +405,158 @@ def rows_to_tokens(only):
             emit(rec)
 
 
+# ---------------------------------------------------------------------------
+# the routers' bookkeeping without a scatter or a gather of scalars (PR 54)
+# ---------------------------------------------------------------------------
+
+#: name -> (tokens, top_k, experts, sequences a batch): the SDAR, Keye and
+#: Trinity cells' layer, the Instella cell's, the OLMoE cell's
+ROUTE_SHAPES = {"sdar": (16384, 8, 128, 1), "instella": (16384, 6, 64, 2),
+                "olmoe": (4096, 8, 64, 1)}
+
+
+def route_forms(n_tok, k, experts, seqs):
+    """name -> (old, new or {label: new}, arguments): each function of the
+    arguments, jitted by the caller. A name ending in ``_grad`` is a gradient
+    by its first argument."""
+    from deepspeed_tpu.moe import layer as L
+    from deepspeed_tpu.moe import sharded_moe as M
+    from tests.unit.models.test_instella_moe import sigmoid_router_as_it_was
+    from tests.unit.moe.test_dropless import softmax_router_as_it_was, sorted_as_it_was
+    key = jax.random.split(jax.random.PRNGKey(54), 4)
+    logits = (jax.random.normal(key[0], (n_tok, experts))
+              + 0.5 * jax.random.normal(key[1], (experts,)))
+    scores = jax.nn.sigmoid(logits)
+    eidx = jax.lax.top_k(scores, k)[1].astype(jnp.int32)
+    ct = jax.random.normal(key[2], (n_tok, k))
+    bias = jnp.zeros((experts,), jnp.float32)
+    flat = eidx.reshape(-1)
+    per_seq = n_tok // seqs
+    weighed = lambda pick: lambda s, i, c: jnp.sum(pick(s, i) * c)
+    soft = dict(top_k=k, normalize=True, balance_loss="topk_share")
+    sig = dict(top_k=k, normalize=True, routed_scale=2.5, rows_per_seq=per_seq)
+
+    def router_loss(router, **kw):
+        def loss(x, c):
+            _, weight, losses, _ = router(x, **kw)
+            return jnp.sum(weight * c) + jnp.sum(losses)
+        return loss
+
+    pick_old = lambda s, i: jnp.take_along_axis(s, i, axis=-1)
+    pick_new = lambda s, i: M._picked(s, i, experts)
+    pick_sum = lambda s, i: jnp.sum(jnp.where(M._picks(i, experts), s[:, None, :], 0.0), axis=-1)
+    values_old = lambda s, i: jax.lax.top_k(s, k)[0]
+    values_new = lambda s, i: M._picked(s, jax.lax.top_k(jax.lax.stop_gradient(s), k)[1], experts)
+    sigmoid_old = lambda x, **kw: sigmoid_router_as_it_was(x, bias, **kw)
+    sigmoid_new = lambda x, **kw: M.sigmoid_bias_router(x, bias, **kw)
+    return {
+        "rows": (lambda i: jnp.zeros((experts,), jnp.int32).at[i.reshape(-1)].add(1),
+                 {"new": lambda i: jnp.sum(M._picks(i, experts), axis=(0, 1), dtype=jnp.int32),
+                  "flat_compare": lambda i: jnp.sum(
+                      i.reshape(-1)[:, None] == jnp.arange(experts), axis=0, dtype=jnp.int32),
+                  "mxu_one_hot": lambda i: jnp.sum(jnp.einsum(
+                      "t,tke->ke", jnp.ones((n_tok,), jnp.bfloat16),
+                      M._picks(i, experts).astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32), axis=0).astype(jnp.int32)},
+                 (eidx,)),
+        "seq_rows": (lambda i: jnp.zeros((seqs, experts), jnp.int32).at[
+                         jnp.repeat(jnp.arange(seqs, dtype=jnp.int32), per_seq * k),
+                         i.reshape(-1)].add(1),
+                     lambda i: jnp.sum(M._picks(i, experts).reshape(seqs, per_seq * k, experts),
+                                       axis=1, dtype=jnp.int32),
+                     (eidx,)),
+        "sort_and_inverse": (sorted_as_it_was,
+                             {"new": L._sorted_by,
+                              "stable_argsorts": lambda key: (
+                                  jnp.argsort(key, stable=True).astype(jnp.int32),
+                                  jnp.argsort(jnp.argsort(key, stable=True)).astype(jnp.int32))},
+                             (flat,)),
+        "inverse_alone": (lambda o: jnp.zeros_like(o).at[o].set(
+                              jnp.arange(o.size, dtype=jnp.int32), unique_indices=True),
+                          {"argsort": lambda o: jnp.argsort(o).astype(jnp.int32)},
+                          (jnp.argsort(flat, stable=True).astype(jnp.int32),)),
+        "pick": (pick_old, {"new": pick_new, "select_sum": pick_sum}, (scores, eidx)),
+        "pick_grad": (jax.grad(weighed(pick_old)),
+                      {"new": jax.grad(weighed(pick_new)),
+                       "select_sum": jax.grad(weighed(pick_sum))}, (scores, eidx, ct)),
+        "top_k_values": (values_old, values_new, (scores, eidx)),
+        "top_k_values_grad": (jax.grad(weighed(values_old)), jax.grad(weighed(values_new)),
+                              (scores, eidx, ct)),
+        "softmax_router": (lambda x: softmax_router_as_it_was(x, **soft),
+                           lambda x: M.softmax_topk_router(x, **soft), (logits,)),
+        "softmax_router_grad": (jax.grad(router_loss(softmax_router_as_it_was, **soft)),
+                                jax.grad(router_loss(M.softmax_topk_router, **soft)),
+                                (logits, ct)),
+        "sigmoid_router": (lambda x: sigmoid_old(x, **sig), lambda x: sigmoid_new(x, **sig),
+                           (logits,)),
+        "sigmoid_router_grad": (jax.grad(router_loss(sigmoid_old, **sig)),
+                                jax.grad(router_loss(sigmoid_new, **sig)), (logits, ct)),
+    }
+
+
+ROUTE_TRIPS = 64
+
+
+def in_one_dispatch(fn):
+    """``fn`` run ``ROUTE_TRIPS`` times in ONE dispatch (a call from the host
+    costs 0.2 ms, ten times what some of these forms take): each trip on
+    arguments moved by the trip's number (an index stays an index of its
+    range, a permutation a permutation), each result summed into the carry so
+    that no trip is dead or another's. What the moving and the summing cost
+    is the ``floor_ms`` beside every reading: the same loop around a function
+    that hands its first argument back."""
+    def run(*args):
+        spans = [jnp.max(a) + 1 if jnp.issubdtype(a.dtype, jnp.integer) else None for a in args]
+
+        def trip(i, acc):
+            moved = jax.lax.optimization_barrier(
+                [a + i.astype(a.dtype) * 1e-6 if m is None else (a + i.astype(a.dtype)) % m
+                 for a, m in zip(args, spans)])       # or the moving fuses into what is timed
+            return acc + sum(jnp.sum(leaf.astype(jnp.float32))
+                             for leaf in jax.tree.leaves(fn(*moved)))
+        return jax.lax.fori_loop(0, ROUTE_TRIPS, trip, jnp.zeros((), jnp.float32))
+    return jax.jit(run)
+
+
+def route_bookkeeping(only):
+    if jax.default_backend() != "tpu":
+        raise SystemExit("tools/moe_ab.py route measures a TPU; none is attached")
+    emit = emitter("route_ab")
+
+    def trip_ms(fn, args):
+        return round(best_ms(in_one_dispatch(fn), args, iters=3) / ROUTE_TRIPS, 4)
+
+    for shape, (n_tok, k, experts, seqs) in ROUTE_SHAPES.items():
+        if only and shape not in only:
+            continue
+        base = {"shape": shape, "tokens": n_tok, "top_k": k, "experts": experts}
+        for name, (old, new, args) in route_forms(n_tok, k, experts, seqs).items():
+            floor = trip_ms(lambda *a: a[0], args)
+            want = jax.jit(old)(*args)
+            emit(dict(base, reading=name, variant="old", ms=trip_ms(old, args), floor_ms=floor))
+            for label, fn in (new if isinstance(new, dict) else {"new": new}).items():
+                got = jax.jit(fn)(*args)
+                same = all(bool(jnp.array_equal(a, b)) for a, b in
+                           zip(jax.tree.leaves(got), jax.tree.leaves(want)))
+                emit(dict(base, reading=name, variant=label, same=same,
+                          ms=trip_ms(fn, args), floor_ms=floor))
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("mode", nargs="?", default="dense", choices=("dense", "gmm", "rows"))
+    ap.add_argument("mode", nargs="?", default="dense", choices=("dense", "gmm", "rows", "route"))
     ap.add_argument("--sweep", action="store_true",
                     help="gmm: every row tile of ROW_TILES, not choose_tiles' own")
     ap.add_argument("--parts", action="store_true",
                     help="gmm: row tiles 256 and 512 with a shared tile multiplied "
                          "whole against in parts of 128 rows")
-    ap.add_argument("--shape", action="append", choices=sorted(SHAPES) + sorted(ROW_SHAPES),
-                    help="gmm, rows: only this shape (may repeat)")
+    ap.add_argument("--shape", action="append", choices=sorted({*SHAPES, *ROW_SHAPES, *ROUTE_SHAPES}),
+                    help="gmm, rows, route: only this shape (may repeat)")
     args = ap.parse_args()
     if args.mode == "rows":
         rows_to_tokens(args.shape)
+    elif args.mode == "route":
+        route_bookkeeping(args.shape)
     elif args.mode == "gmm":
         gmm_against_ragged(args.sweep, args.parts, args.shape)
     else:

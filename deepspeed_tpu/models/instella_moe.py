@@ -2,8 +2,9 @@
 from Hugging Face's configuration keys onto ``TransformerLM``: DeepSeek-V3's
 block with three flags of AMD's own.
 
-- multi-head latent attention without query compression (``kv_lora_rank``,
-  ``qk_nope_head_dim`` + ``qk_rope_head_dim``, ``v_head_dim``), interleaved
+- multi-head latent attention (``kv_lora_rank``, ``qk_nope_head_dim`` +
+  ``qk_rope_head_dim``, ``v_head_dim``; ``q_lora_rank``: the compressed
+  query, null in Instella's own file), interleaved
   rotary pairs on the rope part, YaRN frequencies (``rope_scaling``);
 - ``qk_layernorm``: RMSNorm over each head's query and key vector before
   rope; ``gated_attention``: a sigmoid gate on the attention output from the
@@ -71,7 +72,6 @@ def config_kwargs(hf: Dict[str, Any]) -> Dict[str, Any]:
     """``TransformerConfig`` arguments from a ``deepseek_v3`` configuration
     dict; what this program does not compute is refused by name."""
     refused = {
-        "q_lora_rank": hf.get("q_lora_rank") is not None,
         "hidden_act": hf.get("hidden_act", "silu") != "silu",
         "attention_bias": bool(hf.get("attention_bias")),
         "n_group / topk_group": (hf.get("n_group", 1), hf.get("topk_group", 1)) != (1, 1),
@@ -115,6 +115,7 @@ def config_kwargs(hf: Dict[str, Any]) -> Dict[str, Any]:
         position="rope", rope_theta=float(hf["rope_theta"]),
         rope_style="interleaved" if hf.get("rope_interleave", True) else "half",
         rope_scaling=scaling, attention="latent", kv_latent_rank=hf["kv_lora_rank"],
+        q_latent_rank=hf.get("q_lora_rank") or 0,
         qk_nope_dim=hf["qk_nope_head_dim"], qk_rope_dim=hf["qk_rope_head_dim"],
         v_head_dim=hf["v_head_dim"], qk_norm=bool(hf.get("qk_layernorm")),
         qk_norm_per_head=bool(hf.get("qk_layernorm")),

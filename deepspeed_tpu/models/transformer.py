@@ -380,6 +380,9 @@ class TransformerConfig:
     eva_window: int = 0
     eva_chunk: int = 0
     kv_latent_rank: int = 0
+    # latent attention's compressed QUERY (DeepSeek-V2's q_lora_rank): q =
+    # q_b_proj(RMSNorm(q_a_proj(h))), the rank in between; 0: ``q_proj`` alone
+    q_latent_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
@@ -431,6 +434,21 @@ class TransformerConfig:
     block_length: int = 4
     mask_token_id: Optional[int] = None
     noise_seed: int = 0
+    # manifold-constrained hyper-connections (mHC, arXiv:2512.24880, on
+    # Hyper-Connections, arXiv:2409.19606): the residual is n =
+    # ``residual_streams`` streams of the hidden size, carried side by side as
+    # vec(X) ``[B, S, n x H]``. Every sub-layer (attention, MLP) has
+    # coefficients of its own, made a position at a time from the RMS-normed
+    # vec(X) (`TransformerLM._hc_coefficients`): it reads ``sum_i H_pre[i]
+    # X[i]``, and writes ``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y``,
+    # ``H_res`` made doubly stochastic by ``hc_sinkhorn_iters`` rounds of
+    # Sinkhorn from ``exp(clamp(., hc_res_clamp))``, every sum guarded by
+    # ``hc_eps``. The stack starts from n copies of the embedding and ends in
+    # the streams' sum. 1: the one stream of every other model.
+    residual_streams: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     @property
     def diffusion(self) -> bool:
@@ -535,6 +553,11 @@ MECHANISMS: Dict[str, Tuple[Callable[["TransformerLM"], bool], str]] = {
                       "a head's frequency pairs are turned by three position streams"),
     "pred_heads": (lambda m: m.config.pred_heads > 1,
                    "the head's outputs are several next-token heads under a loss of their own"),
+    "q_latent_rank": (lambda m: bool(m.config.q_latent_rank),
+                      "latent attention's queries come through a compressed, normed vector"),
+    "residual_streams": (lambda m: m.config.residual_streams > 1,
+                         "a block carries n residual streams mixed by a doubly-stochastic "
+                         "matrix a sub-layer (hyper-connections)"),
     "farskip": (lambda m: m.config.farskip,
                 "a block carries two streams: the residual now and a sub-block earlier"),
     "first_dense_layers": (lambda m: bool(m.config.first_dense_layers),
@@ -655,8 +678,14 @@ class TransformerLM:
             self._check_latent()
             q_out = c.num_heads * c.head_dim
             attn_out = c.num_heads * c.v_head_dim
+            q_layers = {"q_proj": lin(c.hidden_size, q_out, False, "column")}
+            if c.q_latent_rank:
+                q_layers = {
+                    "q_a_proj": lin(c.hidden_size, c.q_latent_rank, False, None),
+                    "q_a_norm": nn.RMSNorm(c.q_latent_rank, eps=c.norm_eps),
+                    "q_b_proj": lin(c.q_latent_rank, q_out, False, "column")}
             attn_layers = {
-                "q_proj": lin(c.hidden_size, q_out, False, "column"),
+                **q_layers,
                 # the compressed keys and values with the shared rotary key
                 "kv_a_proj": lin(c.hidden_size, c.kv_latent_rank + c.qk_rope_dim, False, None),
                 "kv_a_norm": nn.RMSNorm(c.kv_latent_rank, eps=c.norm_eps),
@@ -674,6 +703,11 @@ class TransformerLM:
                 "o_proj": lin(attn_out, c.hidden_size, attn_out_bias, "row"),
             }
         self._block_layers = {"ln_1": norm_cls(c.hidden_size), **attn_layers}
+        if c.residual_streams > 1:
+            # a sub-layer's coefficients (`_hc_coefficients`)
+            for name in ("hc_attn", "hc_mlp"):
+                self._block_layers[name] = nn.HyperConnection(
+                    c.residual_streams, c.hidden_size)
         if c.indexer is not None:
             ix = c.indexer
             self._block_layers.update({
@@ -754,10 +788,10 @@ class TransformerLM:
                 "latent attention is written for a causal pre-norm rotary "
                 "decoder with as many key heads as query heads, no windows, "
                 "and QK-norm (if any) per head")
-        if c.v_head_dim != c.head_dim:
+        if c.v_head_dim != c.head_dim and c.seq_parallel == "ring":
             raise NotImplementedError(
                 f"value heads of {c.v_head_dim} beside query heads of "
-                f"{c.head_dim}: the attention routes take one head size")
+                f"{c.head_dim}: ring attention takes one head size")
 
     @property
     def _mixed_rope(self) -> bool:
@@ -841,6 +875,18 @@ class TransformerLM:
                 c.norm_style != "pre" or self._windows is not None):
             raise ValueError("farskip and mtp_layers are written for pre-norm "
                              "blocks without windows")
+        if c.q_latent_rank and c.attention != "latent":
+            raise ValueError("q_latent_rank is latent attention's compressed query")
+        if c.residual_streams < 1 or (c.residual_streams > 1 and (
+                c.norm_style != "pre" or c.parallel_block or c.farskip
+                or c.residual_fp32 or c.indexer is not None or c.diffusion
+                or not c.causal or c.hc_sinkhorn_iters < 1
+                or (c.remat and c.remat_policy == "alternating"))):
+            raise ValueError(
+                "residual_streams: hyper-connected streams are written for a causal "
+                "decoder's sequential pre-norm blocks: no FarSkip, parallel block, "
+                "float32 residual, indexer, block diffusion or "
+                "remat_policy='alternating'")
         if c.residual_fp32 and (c.norm_style == "post" or c.farskip
                                 or c.parallel_block):
             raise ValueError("residual_fp32 is written for sequential pre-norm "
@@ -1017,7 +1063,7 @@ class TransformerLM:
         c = self.config
         B, S, _ = h.shape
         if c.attention == "latent":
-            return self._latent_attn(block, h, positions)
+            return self._latent_attn(block, h, positions, attn_mask)
         if c.attention == "eva":
             return self._eva_attn(block, h, positions)
         if rope is None:
@@ -1134,18 +1180,29 @@ class TransformerLM:
                 block["attn_gate"], h), "attn_gate")
             return out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
 
-    def _latent_attn(self, block: Params, h: jax.Array,
-                     positions: jax.Array) -> jax.Array:
+    def _latent_attn(self, block: Params, h: jax.Array, positions: jax.Array,
+                     documents: Optional[jax.Array] = None) -> jax.Array:
         """Multi-head latent attention over the pre-normed ``h`` (config's
         ``attention='latent'`` has the sizes): training form, the keys and
         values decompressed for every token, so the attention core sees
-        plain heads and takes the route any model's takes."""
+        plain heads and takes the route any model's takes (value heads
+        narrower than the key heads: ``flash_attention``'s two-width launch,
+        under the scope ``attn/core_mla``). ``documents``: each position's
+        packed document (`_attn`'s ``attn_mask``)."""
         c = self.config
         B, S, _ = h.shape
         nh, nope, vd = c.num_heads, c.qk_nope_dim, c.v_head_dim
         with jax.named_scope("attn"):
-            with jax.named_scope("qkv"):
-                q = self._project(block, "q_proj", h).reshape(B, S, nh, c.head_dim)
+            if c.q_latent_rank:
+                with jax.named_scope("latent"):
+                    # the compressed query: down, a norm, up to every head
+                    q_a = checkpoint_name(self._block_layers["q_a_proj"](
+                        block["q_a_proj"], h), "q_latent")
+                    q = self._project(block, "q_b_proj", self._block_layers["q_a_norm"](
+                        block["q_a_norm"], q_a)).reshape(B, S, nh, c.head_dim)
+            else:
+                with jax.named_scope("qkv"):
+                    q = self._project(block, "q_proj", h).reshape(B, S, nh, c.head_dim)
             with jax.named_scope("latent"):
                 kv_a = checkpoint_name(
                     self._block_layers["kv_a_proj"](block["kv_a_proj"], h), "kv_latent")
@@ -1164,11 +1221,12 @@ class TransformerLM:
                     k = self._block_layers["k_norm"](block["k_norm"], k)
                 q = self._rotate_tail(q, positions)
                 k = self._rotate_tail(k, positions)
-            with jax.named_scope("core"):
+            # (values narrower than the keys: the two-width launches, their own scope)
+            with jax.named_scope("core" if vd == c.head_dim else "core_mla"):
                 scale = c.attn_scale or c.head_dim ** -0.5
                 if c.rope_scaling is not None:
                     scale *= c.rope_scaling.softmax_scale ** 2
-                out = self._attn_core(q, k, v, None, None, scale=scale)
+                out = self._attn_core(q, k, v, documents, None, scale=scale)
             out = out.reshape(B, S, nh * vd)
             if c.attn_gate:
                 out = self._gated(block, h, out)
@@ -1309,8 +1367,13 @@ class TransformerLM:
 
     def _aux_zero(self):
         """What the layers' carry starts from beside the stream: the MoE
-        accumulator, and with an indexer the pair (that, L_I so far)."""
+        accumulator, with an indexer the pair (that, L_I so far), with
+        hyper-connected streams the pair (that, H_res's error so far)."""
         aux = self._moe_aux_zero()
+        if self.config.residual_streams > 1:
+            # (that, the largest distance so far of a row or column sum of a
+            # sub-layer's H_res from 1: `_hc_sublayer`)
+            return aux, jnp.zeros((), jnp.float32)
         return aux if self.config.indexer is None else (aux, jnp.zeros((), jnp.float32))
 
     @scoped("mlp")
@@ -1352,6 +1415,71 @@ class TransformerLM:
                           h.reshape(slices, B * S // slices, H))
         return out.reshape(B, S, H)
 
+    # -- hyper-connected residual streams (``residual_streams`` > 1) ----------
+    def _hc_coefficients(self, hc: Params, X: jax.Array):
+        """One sub-layer's mixing coefficients from the streams ``X`` ``[B, S,
+        n x H]`` (vec(X): the n streams side by side), a position at a time, in
+        float32 -> ``(H_pre [n, B, S], H_post [n, B, S], H_res [n, n, B, S])``
+        (the positions LAST: they fill the lanes, n and n x n lead):
+        ``m = (vec(X) rsqrt(mean(vec(X)^2) + eps)) Phi``; ``H_pre =
+        sigmoid(a_pre m_pre + b_pre)``; ``H_post = 2 sigmoid(a_post m_post +
+        b_post)``; ``H_res`` = ``hc_sinkhorn_iters`` rounds (every row over its
+        sum + eps, then every column) from ``exp(clamp(a_res m_res + b_res))``.
+        Scope ``hc/coeff``."""
+        c, n = self.config, self.config.residual_streams
+        f32 = jnp.float32
+        with jax.named_scope("hc"), jax.named_scope("coeff"):
+            x = X.astype(f32)
+            x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + c.hc_eps)
+            m = jnp.einsum("bsk,kc->cbs", x, hc["phi"].astype(f32),
+                           precision=jax.lax.Precision.HIGHEST)
+            alpha, bias = hc["alpha"].astype(f32), hc["bias"].astype(f32)
+            at = lambda lo, hi, a: a * m[lo:hi] + bias[lo:hi, None, None]
+            pre = jax.nn.sigmoid(at(0, n, alpha[0]))
+            post = 2.0 * jax.nn.sigmoid(at(n, 2 * n, alpha[1]))
+            res = jnp.exp(jnp.clip(at(2 * n, 2 * n + n * n, alpha[2]), *c.hc_res_clamp))
+            res = res.reshape((n, n) + res.shape[1:])
+            # (unrolled, not a ``lax.scan``: a loop keeps every round's matrix
+            # for its backward as one stacked buffer, 0.19 GB more of the step's
+            # temporaries at 8,192 rows, which the new cell did not have: PERF.md, PR 55)
+            for _ in range(c.hc_sinkhorn_iters):
+                res = res / (jnp.sum(res, axis=1, keepdims=True) + c.hc_eps)
+                res = res / (jnp.sum(res, axis=0, keepdims=True) + c.hc_eps)
+            return pre, post, res
+
+    def _hc_streams(self, X: jax.Array):
+        """The n streams of vec(X) ``[B, S, n x H]``, each ``[B, S, H]`` float32."""
+        H = self.config.hidden_size
+        return [X[..., i * H:(i + 1) * H].astype(jnp.float32)
+                for i in range(self.config.residual_streams)]
+
+    def _hc_sublayer(self, hc: Params, X: jax.Array, branch) -> Tuple[jax.Array, Any, jax.Array]:
+        """ONE sub-layer under hyper-connections, the helper every sub-layer
+        goes through: the coefficients (`_hc_coefficients`), the weighted read
+        ``u = sum_i H_pre[i] X[i]`` (scope ``hc/pre``), ``y, *rest =
+        branch(u)`` (the norm and the attention or the MLP), the mix and the
+        write-back ``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y`` (scope
+        ``hc/post``), all mixing in float32 from and to the streams' dtype.
+        -> ``(X', rest, err)``, ``err`` the largest distance of a row or a column
+        sum of ``H_res`` from 1 (no gradient). Nothing here is named: a
+        block's backward makes the coefficients and both mixings again."""
+        n = self.config.residual_streams
+        pre, post, res = self._hc_coefficients(hc, X)
+        err = jax.lax.stop_gradient(jnp.maximum(
+            jnp.max(jnp.abs(jnp.sum(res, axis=1) - 1.0)),
+            jnp.max(jnp.abs(jnp.sum(res, axis=0) - 1.0))))
+        with jax.named_scope("hc"), jax.named_scope("pre"):
+            u = sum(pre[i][..., None] * x for i, x in enumerate(self._hc_streams(X)))
+            u = u.astype(X.dtype)
+        y, *rest = branch(u)
+        with jax.named_scope("hc"), jax.named_scope("post"):
+            streams, y32 = self._hc_streams(X), y.astype(jnp.float32)
+            X = jnp.concatenate([
+                (sum(res[i, j][..., None] * streams[j] for j in range(n))
+                 + post[i][..., None] * y32).astype(X.dtype)
+                for i in range(n)], axis=-1)
+        return X, rest, err
+
     @scoped("block")   # norms and residual adds are "block" and nothing finer
     def _block_fn(self, attn_mask, carry, block_and_keep, kind=None):
         """One block. ``kind``: the layer's static (window, rope) of
@@ -1378,6 +1506,21 @@ class TransformerLM:
             y = self._block_layers["ln_2"](block["ln_2"], h + mlp_out)
             x = _c(keep * y + (1 - keep) * x, ACT_SPEC)
             return (x, positions, aux_acc + keep * aux), rows
+        if c.residual_streams > 1:
+            # x = vec(X), the n streams: each sub-layer reads a mix of them
+            # and writes to all of them (`_hc_sublayer`)
+            x, _, err_attn = self._hc_sublayer(block["hc_attn"], x, lambda u: (
+                keep * self._attn(block, self._block_layers["ln_1"](block["ln_1"], u),
+                                  positions, attn_mask, window, rope),))
+            def mlp(u):
+                out, aux, rows = self._mlp(
+                    block, self._block_layers["ln_2"](block["ln_2"], u))
+                return keep * out, aux, rows
+
+            x, (aux, rows), err_mlp = self._hc_sublayer(block["hc_mlp"], x, mlp)
+            moe_acc, err_acc = aux_acc
+            return (_c(x, ACT_SPEC), positions, (
+                moe_acc + keep * aux, jnp.maximum(err_acc, jnp.maximum(err_attn, err_mlp)))), rows
         if c.farskip:
             # x = (r_(i-1), r_(i-2)): attention reads the stream as it stood
             # before the sub-block in front of it, and so does the MLP
@@ -1877,8 +2020,9 @@ class TransformerLM:
             keep = layer_mask.astype(c.dtype)
         dense = c.first_dense_layers
         xs = (params["blocks"], keep[dense:])
-        # FarSkip carries two streams: (r_(i-1), r_(i-2)), r_(-1) = r_0
-        init = ((x, x) if c.farskip else x, positions, self._aux_zero())
+        # FarSkip carries two streams: (r_(i-1), r_(i-2)), r_(-1) = r_0;
+        # hyper-connections n, which start as n copies of the embedding
+        init = ((x, x) if c.farskip else self._hc_start(x), positions, self._aux_zero())
         blocks_in_all = c.num_layers + (c.mtp_layers if with_mtp else 0)
         blocks: Dict[Any, Callable] = {}
 
@@ -1940,6 +2084,7 @@ class TransformerLM:
             (x, _, aux), rows = self._scan_by_kind(init, xs, block_of)
         if c.farskip:
             x = x[0]
+        x = self._hc_collapse(x)
         if c.diffusion:
             x = x[:, input_ids.shape[1]:]
         mtp_x = None
@@ -1957,15 +2102,21 @@ class TransformerLM:
                          self._mtp_layers["norm_e"](mp["norm_e"], nxt.astype(c.dtype))],
                         axis=-1))
                 merged = _c(merged, ACT_SPEC)
-                start = ((merged, merged) if c.farskip else merged, positions, aux)
+                start = ((merged, merged) if c.farskip else self._hc_start(merged),
+                         positions, aux)
                 (mtp_x, _, aux), mtp_rows = block_of()(
                     start, (jax.tree.map(lambda a: a[0], mp["blocks"]),
                             jnp.ones((), c.dtype)))
                 if c.farskip:
                     mtp_x = mtp_x[0]
+                mtp_x = self._hc_collapse(mtp_x)
             if rows is not None:
                 rows = jnp.concatenate([rows, mtp_rows[None]], axis=0)
         stats = self._stats_of(rows)
+        if c.residual_streams > 1:
+            # (``engine.attn_last_step()["hc_res_row_err"]``: a projection that
+            # stopped projecting shows here)
+            stats = {**stats, "attn_hc_res_row_err": aux[1]}
         if c.document_separator is not None:
             stats = {**stats, **self._attn_tile_stats(documents)}
         if c.indexer is not None:
@@ -1993,6 +2144,20 @@ class TransformerLM:
                     jnp.sum(visible > c.indexer.topk, dtype=jnp.int32),
                     jnp.int32(visible.size)])
         return x, aux, stats, mtp_x
+
+    def _hc_start(self, x: jax.Array) -> jax.Array:
+        """The stack's first carry from one stream ``[B, S, H]``: with
+        hyper-connections n copies side by side, vec(X) ``[B, S, n x H]``."""
+        n = self.config.residual_streams
+        return x if n == 1 else _c(jnp.tile(x, (1, 1, n)), ACT_SPEC)
+
+    def _hc_collapse(self, x: jax.Array) -> jax.Array:
+        """The stack's last carry as one stream: the n streams' sum (float32,
+        rounded once)."""
+        if self.config.residual_streams == 1:
+            return x
+        with jax.named_scope("hc"), jax.named_scope("post"):
+            return _c(sum(self._hc_streams(x)).astype(x.dtype), ACT_SPEC)
 
     def _documents(self, input_ids: jax.Array) -> jax.Array:
         """Each position's document in a packed row, [B, S] int32: the
@@ -2045,6 +2210,13 @@ class TransformerLM:
                 for tile in (at.tiles.fwd, at.tiles.bwd)]))
         return {"attn_tiles": jnp.stack(lines)}
 
+    @property
+    def _mla_widths(self) -> bool:
+        """Whether the value heads are narrower (or wider) than the key heads:
+        latent attention's two-width launch (``flash_*_mla``)."""
+        c = self.config
+        return c.attention == "latent" and c.v_head_dim != c.head_dim
+
     def _attention_plan(self, batch: int, seq: int, window: int = 0,
                         mode: Optional[str] = None):
         """The ``attention.Plan`` of one layer's call over ``batch`` whole rows
@@ -2059,6 +2231,8 @@ class TransformerLM:
             mask = dict(eva=(c.eva_window, c.eva_chunk))
         elif c.indexer is not None:
             mask = dict(selected=c.indexer.topk)
+        elif self._mla_widths:
+            mask["v_dim"] = c.v_head_dim
         return attention.plan(
             (batch, seq * self.rows_per_token, c.num_heads, c.head_dim),
             (batch, seq, c.kv_heads, c.head_dim), jax.default_backend(),
@@ -2097,11 +2271,24 @@ class TransformerLM:
                            "select": None, "select_tiles": None, "select_rows": None,
                            "dq": None, "layout": None, "kl": None, "kl_tiles": None,
                            "operand": "bits", "operand_bytes": None}
+        if self._mla_widths:
+            attn["mla"] = {"qk_dim": c.head_dim, "v_dim": c.v_head_dim,
+                           "q_rank": c.q_latent_rank, "kv_rank": c.kv_latent_rank,
+                           "route": None, "dq": None, "layout": None}
+        if c.residual_streams > 1:
+            # (the streams' record rides here: an engine copies this dict whole)
+            attn["hc"] = {"streams": c.residual_streams,
+                          "sinkhorn_iters": c.hc_sinkhorn_iters,
+                          "sublayers": 2 * (c.num_layers + c.mtp_layers)}
         diffusion = {"block_length": c.block_length, "rows_per_token": self.rows_per_token,
                      "route": None, "dq": None, "layout": None} if c.diffusion else None
         if seq is None:
             return attn, diffusion
         plans = {w: self._attention_plan(batch, seq, w) for w in [0] + windows}
+        tag = "mla" if self._mla_widths else "flash"
+        if self._mla_widths:
+            attn["mla"].update(route=plans[0].route, dq=plans[0].dq("mla"),
+                               layout=plans[0].layout("mla"))
         if c.diffusion:
             diffusion.update(route=plans[0].route, dq=plans[0].dq("blockdiff"),
                              layout=plans[0].layout("blockdiff"))
@@ -2119,15 +2306,15 @@ class TransformerLM:
                                operand_bytes=batch * packed_rows(seq) * seq)
         else:
             # (sliding layers of several widths: the mode they share, else both)
-            under = sorted({plans[w].dq("flash") or "" for w in windows})
+            under = sorted({plans[w].dq(tag) or "" for w in windows})
             attn["route"] = {kind: plans[0].route if n else None
                              for kind, n in layers.items()}
             attn["dq"] = {
                 "window": ("+".join(under) or None) if layers["window"] else None,
-                "full": plans[0].dq("flash") if layers["full"] else None}
+                "full": plans[0].dq(tag) if layers["full"] else None}
             attn["layout"] = {
-                "window": plans[windows[0]].layout("flash") if layers["window"] else None,
-                "full": plans[0].layout("flash") if layers["full"] else None}
+                "window": plans[windows[0]].layout(tag) if layers["window"] else None,
+                "full": plans[0].layout(tag) if layers["full"] else None}
         return attn, diffusion
 
     def traced_rows_records(self, stats: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
@@ -2193,7 +2380,8 @@ class TransformerLM:
         share of block diffusion, packed documents' count of tiles."""
         return (self.moe_path == "dropless" or self.config.diffusion
                 or self.config.document_separator is not None
-                or self.config.indexer is not None)
+                or self.config.indexer is not None
+                or self.config.residual_streams > 1)
 
     @functools.cached_property
     def scan_plan(self) -> Tuple[Tuple[Any, ...], int, Tuple[Any, ...]]:
@@ -2284,6 +2472,8 @@ class TransformerLM:
         them, as its report has it). Reads ``self.config`` alone
         (``PipelineModule`` borrows it)."""
         moe = self.config.moe
+        if self.config.residual_streams > 1:
+            aux = aux[0]       # (the pair's other half is a statistic, no loss)
         if self.config.indexer is not None:
             # the pair (the MoE accumulator, L_I): L_I counts once (coefficient 1)
             aux, kl = aux

@@ -174,6 +174,35 @@ class HeadVectors:
         return {"value": P(MODEL_AXIS, None)}
 
 
+@dataclasses.dataclass(frozen=True)
+class HyperConnection:
+    """One sub-layer's hyper-connection coefficients over ``streams`` residual
+    streams of ``features`` (mHC, arXiv:2512.24880; ``TransformerLM.
+    _hc_coefficients`` reads the arrays itself): ``phi`` ``[streams x features,
+    streams^2 + 2 streams]`` (the columns: ``pre`` [n], ``post`` [n], ``res`` [n
+    x n] row-major), ``bias`` of as many, ``alpha`` the three scalars (pre,
+    post, res). A fresh layer mixes nothing: ``res`` starts at the identity
+    (its off-diagonal logits at -8), ``pre`` at a half a stream, ``post`` at 1."""
+    streams: int
+    features: int
+    init_scale: float = 0.02
+
+    @property
+    def outputs(self) -> int:
+        return self.streams * (self.streams + 2)
+
+    def init(self, rng, dtype=jnp.float32) -> Params:
+        n = self.streams
+        res = jnp.where(jnp.eye(n, dtype=bool), 0.0, -8.0).reshape(-1)
+        return {"phi": _init_dense(rng, (n * self.features, self.outputs),
+                                   self.init_scale, dtype),
+                "bias": jnp.concatenate([jnp.zeros((2 * n,)), res]).astype(dtype),
+                "alpha": jnp.full((3,), 0.01, dtype)}
+
+    def specs(self) -> Params:
+        return {"phi": P(None, None), "bias": P(None), "alpha": P(None)}
+
+
 def gelu(x: jax.Array) -> jax.Array:
     return jax.nn.gelu(x, approximate=True)
 

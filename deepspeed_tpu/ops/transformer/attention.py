@@ -106,7 +106,8 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     probs = checkpoint_name(probs, "attn_big")
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, vt)
-    return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    # (the values' own width: latent attention's are narrower than its keys)
+    return out.reshape(B, H, Sq, v.shape[3]).transpose(0, 2, 1, 3)
 
 
 def _xla_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -167,8 +168,8 @@ def _xla_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
             segment_ids[:, :end] if segment_ids is not None else None,
             alibi, window, q_offset=off,
             q_segment_ids=(sq_c[i] if sq_c is not None else None)))
-    # [nc, B, chunk, H, D] -> [B, Sq, H, D]
-    return jnp.stack(outs).transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, D)
+    # [nc, B, chunk, H, Dv] -> [B, Sq, H, Dv]
+    return jnp.stack(outs).transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, v.shape[3])
 
 
 def attn_mode() -> str:
@@ -226,7 +227,8 @@ def choose_route(q_shape, k_shape, backend: str, mode: str,
 
 class Launch(NamedTuple):
     """One launch of the flash pair: its ``tag`` (``"flash"``, ``"blockdiff"``,
-    ``"eva_local"``, ``"eva_far"``, ``"dsa"``), a batch row's queries and keys, the tiles
+    ``"eva_local"``, ``"eva_far"``, ``"dsa"``, ``"mla"``: the two-width launch of
+    latent attention), a batch row's queries and keys, the tiles
     (``pallas_flash.launch_tiles``), the static window the grids are cut to, and
     where the launch takes its query side's heads (q, ``o`` and their gradients;
     ``pallas_flash.launch_layout``: ``"rows"``, as the projections leave them,
@@ -269,14 +271,16 @@ class Plan(NamedTuple):
 def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
          causal: bool = True, window=None, blockdiff: Optional[int] = None,
          eva: Optional[Tuple[int, int]] = None,
-         selected: Optional[int] = None) -> Plan:
+         selected: Optional[int] = None, v_dim: Optional[int] = None) -> Plan:
     """THE decision of the four entry points, a pure function of the two
     shapes, the platform, `attn_mode`'s value, the operands' size and the
     mask, which says what the kernel route would launch, each launch with its
     tiles and its layout (the entry point hands the kernel THIS layout):
 
     - `flash_attention` (``causal``, ``window``): one launch, its grids cut to
-      a window that is static;
+      a window that is static; with ``v_dim`` (the values' width where it is
+      not the keys': ``q_shape`` and ``k_shape`` hold the keys') the two-width
+      launch, tagged ``"mla"``;
     - `blockdiff_attention` (``blockdiff``: the block length; ``q_shape`` holds
       both copies' ``2 L`` rows, ``k_shape`` the clean copy's ``L``): one
       launch of both copies' queries over the clean keys;
@@ -307,8 +311,9 @@ def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
                 launches.append(("eva_far", sq, sq // chunk,
                                  dict(summaries=(span, span // chunk))))
     else:
-        launches = [("flash", sq, sk, dict(
-            causal=causal, window=_pf.static_window(window, sq, sk)))]
+        kind = dict(causal=causal, window=_pf.static_window(window, sq, sk))
+        launches = [("flash", sq, sk, kind) if v_dim is None
+                    else ("mla", sq, sk, dict(kind, v_dim=v_dim))]
     compiled = backend != "cpu"
     min_rows = FLASH_MIN_SEQ_WIDE_HEAD if q_shape[3] >= 128 else FLASH_MIN_SEQ
     if mode == "pallas" or (mode == "" and backend == "tpu" and rows >= min_rows):
@@ -317,7 +322,7 @@ def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
                 sq, sk, q_shape[3], itemsize, compiled=compiled, **kind),
                 kind.get("window"), _pf.launch_layout(q_shape, k_shape))
             for tag, sq, sk, kind in launches)
-        if made and _pf.folds(q_shape, k_shape) and all(at.tiles for at in made):
+        if made and _pf.folds(q_shape, k_shape, v_dim) and all(at.tiles for at in made):
             return Plan("kernel", made)
     return Plan(_xla_route(q_shape[1], compiled))
 
@@ -356,7 +361,8 @@ def flash_attention(q: jax.Array,
     """
     mode = attn_mode()
     made = plan(q.shape, k.shape, jax.default_backend(), mode, q.dtype.itemsize,
-                causal=causal, window=window)
+                causal=causal, window=window,
+                v_dim=None if v.shape[3] == q.shape[3] else v.shape[3])
     route = made.route
     if route == "kernel":
         from . import pallas_flash as _pf

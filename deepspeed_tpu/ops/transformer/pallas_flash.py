@@ -225,6 +225,10 @@ class FlashConfig:
     # `launch_layout`): ``"rows"``: ``[B, 1, S, heads * D]``, a head picked by
     # the index maps; ``"heads"``: heads leading, ``[B * kv_heads, G, S, D]``
     layout: str = "heads"
+    # the VALUE heads' width where it is not the keys' (latent attention: keys
+    # and queries of nope + rope = 192, values of 128): v, ``o`` and their
+    # gradients are this wide, q, k and theirs the keys' width; None: one width
+    v_dim: Optional[int] = None
 
 
 def _lanes(x: jax.Array, n: int) -> jax.Array:
@@ -491,8 +495,9 @@ def _head_index(row: "_Row", g, G):
     return row.head * G + g
 
 
-#: the tags a caller may give a launch (``FlashConfig.tag``): EVA's two
-TAGS = ("eva_local", "eva_far")
+#: the tags a caller may give a launch (``FlashConfig.tag``): EVA's two, and
+#: the two-width launch of latent attention (``FlashConfig.v_dim``)
+TAGS = ("eva_local", "eva_far", "mla")
 
 
 def _compiler_params(cfg: FlashConfig, semantics):
@@ -530,6 +535,12 @@ def _dims(cfg: FlashConfig, q, k) -> Tuple[int, int, int, int, int]:
     if cfg.layout == "rows":
         return BK, q.shape[3] // D // cfg.kv_heads, q.shape[2], Sk, D
     return BK, q.shape[1], q.shape[2], Sk, D
+
+
+def _value_dim(cfg: FlashConfig, D: int) -> int:
+    """The width of v, ``o`` and their gradients in a launch whose keys are
+    ``D`` wide (``FlashConfig.v_dim``)."""
+    return D if cfg.v_dim is None else cfg.v_dim
 
 
 class _Row:
@@ -681,6 +692,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
     ids); ``sel``: a selection's operand ``[B, Sq / 8, Sk]``
     (``FlashConfig.selected``)."""
     BK, G, Sq, Sk, D = _dims(cfg, q, k)
+    Dv = _value_dim(cfg, D)
     tile = bq, bk = cfg.tiles.fwd
     blocks = nq, nk = Sq // bq, Sk // bk
     if cfg.window is not None:
@@ -707,13 +719,15 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
 
     q_block, q_at = _q_block(cfg, G, bq, D)
     kv_block, kv_at = _kv_block(bk, D)
+    # (the value side's blocks: the same index maps over a width of its own)
+    o_block, v_block = _q_block(cfg, G, bq, Dv)[0], _kv_block(bk, Dv)[0]
 
     q_idx = by_row(lambda row, g, i, j, *_: q_at(row, g, i))
     kv_idx = by_row(lambda row, g, i, j, *prefetch: kv_at(row, k_blk(row, i, j, prefetch)))
     in_specs = [
         pl.BlockSpec(q_block, q_idx),
         pl.BlockSpec(kv_block, kv_idx),
-        pl.BlockSpec(kv_block, kv_idx),
+        pl.BlockSpec(v_block, kv_idx),
     ]
     if cfg.use_seg:
         in_specs += [
@@ -734,14 +748,14 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
                 row.batch, i // shared, k_blk(row, i, j, prefetch)))))
 
     out_specs = [
-        pl.BlockSpec(q_block, q_idx),
+        pl.BlockSpec(o_block, q_idx),
         pl.BlockSpec((1, 1, 1, bq), by_row(lambda row, g, i, j, *_: (row.folded, g, 0, i))),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        jax.ShapeDtypeStruct(q.shape[:3] + (q.shape[3] // D * Dv,), q.dtype),
         jax.ShapeDtypeStruct((BK, G, 1, Sq), jnp.float32),
     ]
-    kernel = functools.partial(_fwd_kernel, cfg=cfg, G=G, nk=nk, head_dim=D,
+    kernel = functools.partial(_fwd_kernel, cfg=cfg, G=G, nk=nk, head_dim=Dv,
                                blocks=blocks)
     return pl.pallas_call(
         kernel,
@@ -753,7 +767,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
             scratch_shapes=[
                 pltpu.VMEM((bq, NUM_LANES), jnp.float32),
                 pltpu.VMEM((bq, NUM_LANES), jnp.float32),
-                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, Dv), jnp.float32),
             ]),
         out_shape=out_shape,
         compiler_params=_compiler_params(
@@ -764,6 +778,7 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
         # by it; literal, so that a test can list every kernel's names)
         name=("flash_fwd_eva_local" if cfg.tag == "eva_local"
               else "flash_fwd_eva_far" if cfg.tag == "eva_far"
+              else "flash_fwd_mla" if cfg.tag == "mla"
               else "flash_fwd_dsa" if cfg.selected
               else "flash_fwd_blockdiff" if cfg.blockdiff is not None
               else "flash_fwd" if cfg.window is None else "flash_fwd_window"),
@@ -946,7 +961,7 @@ def _delta_call(cfg: FlashConfig, o, do, BK: int, G: int, Sq: int, D: int):
     with the heads leading it fuses product and sum into one pass. The same
     128 products summed in float32 either way."""
     bq = cfg.tiles.bwd[0]
-    block, at = _q_block(cfg, G, bq, D)
+    block, at = _q_block(cfg, G, bq, _value_dim(cfg, D))
     grid = _row_axes(cfg, BK) + (G, Sq // bq)
     return pl.pallas_call(
         _delta_kernel,
@@ -977,6 +992,7 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
     EITHER layout: the sum or the cast that reads it anyway writes it where q
     lies, and a pair's tile of it is one run of HBM and not 4 KB pieces."""
     BK, G, Sq, Sk, D = _dims(cfg, q, k)
+    Dv = _value_dim(cfg, D)
     by_rows = cfg.layout == "rows"
     tile = bq, bk = cfg.tiles.bwd
     blocks = nq, nk = Sq // bq, Sk // bk
@@ -1024,6 +1040,7 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
     by_row = functools.partial(_by_row, cfg)
     q_block, q_at = _q_block(cfg, G, bq, D)
     kv_block, kv_at = _kv_block(bk, D)
+    do_block, v_block = _q_block(cfg, G, bq, Dv)[0], _kv_block(bk, Dv)[0]
     q_idx = by_row(lambda row, j, g, i, *prefetch: q_at(row, g, q_blk(row, i, j, prefetch)))
     q_row_idx = by_row(lambda row, j, g, i, *prefetch: (
         row.folded, g, 0, q_blk(row, i, j, prefetch)))
@@ -1080,10 +1097,10 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
             in_specs=[
                 pl.BlockSpec(q_block, q_idx),
                 pl.BlockSpec(kv_block, kv_idx),
-                pl.BlockSpec(kv_block, kv_idx),
+                pl.BlockSpec(v_block, kv_idx),
                 *seg_specs,
                 sel_spec,
-                pl.BlockSpec(q_block, q_idx),
+                pl.BlockSpec(do_block, q_idx),
                 pl.BlockSpec((1, 1, 1, bq), q_row_idx),
                 pl.BlockSpec((1, 1, 1, bq), q_row_idx),
                 *[dq_spec] * len(zeros),
@@ -1091,10 +1108,10 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
             out_specs=[
                 dq_spec,
                 pl.BlockSpec(kv_block, kv_idx),
-                pl.BlockSpec(kv_block, kv_idx),
+                pl.BlockSpec(v_block, kv_idx),
             ],
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32), *dq_scratch]),
+                            pltpu.VMEM((bk, Dv), jnp.float32), *dq_scratch]),
         out_shape=[jax.ShapeDtypeStruct(
                        dq_shape, q.dtype if met == 1 else jnp.float32),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -1106,6 +1123,7 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
         interpret=cfg.interpret,
         name=("flash_bwd_eva_local" if cfg.tag == "eva_local"
               else "flash_bwd_eva_far" if cfg.tag == "eva_far"
+              else "flash_bwd_mla" if cfg.tag == "mla"
               else "flash_bwd_dsa" if cfg.selected
               else "flash_bwd_blockdiff" if cfg.blockdiff is not None
               else "flash_bwd" if W is None else "flash_bwd_window"),
@@ -1206,19 +1224,29 @@ VMEM_CAP = 96 * 1024 * 1024
 
 
 def tile_vmem_bytes(tile: Tile, head_dim: int, itemsize: int, *,
-                    backward: bool) -> int:
+                    backward: bool, v_dim: Optional[int] = None) -> int:
     """Upper estimate of the scoped VMEM one kernel needs: two fp32
     ``[bq, bk]`` temporaries (Mosaic streams the softmax chain through
     vregs and keeps about that much: 6.0-8.5 B per tile element with the
     blocks, by bisecting ``vmem_limit_bytes`` on the v5e compiler at
     512 x 512 to 1024 x 1024, head dim 64 and 128, masks and segment ids
     in or out), the double-buffered operand blocks, and the fp32
-    accumulators."""
+    accumulators. ``v_dim``: the value side's width where it is its own
+    (``FlashConfig.v_dim``): q, k, dq, dk are ``head_dim`` wide, v, o, do, dv
+    ``v_dim``, each padded to whole lane tiles (192 takes 256 lanes in VMEM),
+    and the float32 tile dq is added to in place is counted (a launch of one
+    width never counted it, and its tiles stay what they were)."""
     bq, bk = tile
-    d = max(head_dim, NUM_LANES)           # blocks pad to the lane width
-    rows = (3 * bq + 4 * bk) if backward else (2 * bq + 2 * bk)
-    acc = (2 * bk * d if backward else bq * (d + 2 * NUM_LANES)) * 4
-    return 2 * 4 * bq * bk + 2 * itemsize * d * rows + acc
+    pad = lambda n: -(-n // NUM_LANES) * NUM_LANES   # blocks pad to whole lane tiles
+    d = max(head_dim, NUM_LANES) if v_dim is None else pad(head_dim)
+    dv = d if v_dim is None else pad(v_dim)
+    if backward:      # q, dq and do; k, dk and v, dv; the two accumulators
+        blocks = bq * (2 * d + dv) + bk * (2 * d + 2 * dv)
+        acc = bk * (d + dv) + (0 if v_dim is None else bq * d)
+    else:             # q and o; k and v; o's accumulator, the running max and sum
+        blocks = (bq + bk) * (d + dv)
+        acc = bq * (dv + 2 * NUM_LANES)
+    return 2 * 4 * bq * bk + 2 * itemsize * blocks + 4 * acc
 
 
 def _largest_tile(length: int, cap: int) -> Optional[int]:
@@ -1245,7 +1273,8 @@ def _fit(length: int, target: int, compiled: bool) -> Optional[int]:
 def choose_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
                  causal: bool = True, block_q: Optional[int] = None,
                  block_k: Optional[int] = None, compiled: bool = True,
-                 window: Optional[int] = None) -> Optional[FlashTiles]:
+                 window: Optional[int] = None,
+                 v_dim: Optional[int] = None) -> Optional[FlashTiles]:
     """Tiles of the forward and backward kernels for one call, from what
     the call can observe: the two lengths, whether it is causal, a window
     that is static (``FlashConfig.window``; a traced one the choice cannot
@@ -1257,7 +1286,11 @@ def choose_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
     clamped to the lengths as the old 128-default was. None
     where no legal tile exists: a length that no 128-multiple divides, or
     (``compiled``, the TPU path) a tile off the 128-lane layout; interpret
-    mode accepts any length under 128 whole."""
+    mode accepts any length under 128 whole. ``v_dim``: the value side's
+    width where it is its own (`tile_vmem_bytes` reckons both)."""
+    vmem = functools.partial(tile_vmem_bytes, head_dim=head_dim, itemsize=itemsize,
+                             v_dim=v_dim)
+
     def pick(target: Tile, backward: bool) -> Optional[Tile]:
         bq = min(block_q, sq) if block_q else _fit(sq, target[0], compiled)
         bk = min(block_k, sk) if block_k else _fit(sk, target[1], compiled)
@@ -1267,8 +1300,7 @@ def choose_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
             return None
         # step what was not asked for down to the next legal tile until
         # the kernel fits the budget
-        while tile_vmem_bytes((bq, bk), head_dim, itemsize,
-                              backward=backward) > VMEM_BUDGET:
+        while vmem((bq, bk), backward=backward) > VMEM_BUDGET:
             if not block_q and bq > NUM_LANES and (bq >= bk or block_k):
                 bq = _largest_tile(sq, bq - NUM_LANES)
             elif not block_k and bk > NUM_LANES:
@@ -1287,8 +1319,7 @@ def choose_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
     bwd = pick(under_window(TILE_TARGET), True)
     if fwd is None or bwd is None:
         return None
-    need = max(tile_vmem_bytes(fwd, head_dim, itemsize, backward=False),
-               tile_vmem_bytes(bwd, head_dim, itemsize, backward=True))
+    need = max(vmem(fwd, backward=False), vmem(bwd, backward=True))
     return FlashTiles(fwd, bwd,
                       None if need <= VMEM_BUDGET else min(need, VMEM_CAP))
 
@@ -1299,23 +1330,29 @@ def supports(q_shape, k_shape, block_q: Optional[int] = None,
     (`launch_tiles` under the launch's ``kind``). ``compiled=True`` (the TPU
     path) requires tiles on the 128-lane layout; ``compiled=False`` (interpret
     mode on CPU test meshes) accepts anything the tiles divide evenly."""
-    return folds(q_shape, k_shape) and launch_tiles(
+    return folds(q_shape, k_shape, kind.get("v_dim")) and launch_tiles(
         q_shape[1], k_shape[1], q_shape[3], block_q=block_q, block_k=block_k,
         compiled=compiled, **kind) is not None
 
 
-def folds(q_shape, k_shape) -> bool:
+def folds(q_shape, k_shape, v_dim: Optional[int] = None) -> bool:
     """Heads the kernel folds: query heads a multiple of the key heads, a
-    head dim under the lanes' 128 or a multiple of it."""
+    head dim under the lanes' 128 or a multiple of it. With a value width of
+    its own (``v_dim``) that holds for the values, and the keys may also be a
+    multiple of half a lane tile (192: Mosaic takes the block whole and pads
+    it to 256 lanes in VMEM; by heads alone, `launch_layout`)."""
     H, D, kvH = q_shape[2], q_shape[3], k_shape[2]
-    return not (H % kvH or (D > NUM_LANES and D % NUM_LANES))
+    wide = lambda d, step: d > NUM_LANES and d % step
+    if v_dim is not None:
+        return not (H % kvH or wide(D, NUM_LANES // 2) or wide(v_dim, NUM_LANES))
+    return not (H % kvH or wide(D, NUM_LANES))
 
 
 def launch_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
                  causal: bool = True, window: Optional[int] = None,
                  blockdiff: Optional[int] = None,
                  summaries: Optional[Tuple[int, int]] = None,
-                 selected: bool = False,
+                 selected: bool = False, v_dim: Optional[int] = None,
                  block_q: Optional[int] = None, block_k: Optional[int] = None,
                  compiled: bool = True) -> Optional[FlashTiles]:
     """The tiles of ONE launch of ``sq`` queries over ``sk`` keys, by its
@@ -1337,9 +1374,13 @@ def launch_tiles(sq: int, sk: int, head_dim: int, itemsize: int = 2, *,
       full layer's, where both q tiles are whole bit planes of the operand
       (``attention.selection_tile``), with the scoped VMEM a tile of it adds
       (the packed block twice, its int32 copy and the unpacked planes: under 6
-      bytes a pair)."""
+      bytes a pair);
+    - ``v_dim`` (a value width of its own, ``FlashConfig.v_dim``; a causal or
+      windowed launch alone): the same choice, the VMEM reckoned at both widths."""
+    if v_dim is not None and (blockdiff is not None or summaries is not None or selected):
+        return None
     choose = functools.partial(choose_tiles, head_dim=head_dim, itemsize=itemsize,
-                               block_k=block_k, compiled=compiled)
+                               block_k=block_k, compiled=compiled, v_dim=v_dim)
     if blockdiff is not None:
         b = int(blockdiff)
         if sq != 2 * sk or b < 1 or b & (b - 1) or sk % b:
@@ -1425,6 +1466,13 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
     slopes, ``info``). ``layout``: None, `launch_layout`'s."""
     B, Sq, H, D = q.shape
     Sk, kvH = k.shape[1], k.shape[2]
+    # a value width of its own makes the launch the two-width one
+    v_dim = None if v.shape[3] == D else v.shape[3]
+    if v_dim is not None:
+        if tag not in (None, "mla") or k.shape[3] != D:
+            raise ValueError(f"values of {v.shape[3]} beside keys of {k.shape[3]} and "
+                             f"queries of {D}: the two-width launch is tagged 'mla'")
+        tag = "mla"
     if H % kvH:
         raise ValueError(f"query heads {H} not a multiple of kv heads {kvH}")
     G = H // kvH
@@ -1459,7 +1507,7 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
                          "keys as queries and no window, ALiBi or q_offset")
     tiles = launch_tiles(Sq, Sk, D, q.dtype.itemsize, causal=bool(causal),
                          window=cut, blockdiff=blockdiff, summaries=summaries,
-                         selected=selected,
+                         selected=selected, v_dim=v_dim,
                          block_q=block_q, block_k=block_k, compiled=not interp)
     if tiles is None:
         raise ValueError(f"seq lengths ({Sq}, {Sk}) have no legal tiles "
@@ -1481,7 +1529,7 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         q = q.transpose(0, 2, 1, 3).reshape(B * kvH, G, Sq, D)
     # the keys and values lead with their heads either way (GQA-folded)
     k = k.transpose(0, 2, 1, 3).reshape(B * kvH, Sk, D)
-    v = v.transpose(0, 2, 1, 3).reshape(B * kvH, Sk, D)
+    v = v.transpose(0, 2, 1, 3).reshape(B * kvH, Sk, v.shape[3])
     if math.frexp(scale)[0] == 0.5:
         # a power of two scales q EXACTLY in any float type, so the same
         # logits come out of the matmul already scaled and the kernels skip
@@ -1496,7 +1544,7 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
         kv_heads=kvH, tiles=tiles, interpret=bool(interp), window=cut,
         blockdiff=None if blockdiff is None else (int(blockdiff), Sk),
         summaries=None if summaries is None else tuple(map(int, summaries)),
-        tag=tag, selected=bool(selected), layout=layout)
+        tag=tag, selected=bool(selected), layout=layout, v_dim=v_dim)
 
     segs = (None,) * 6
     if segment_ids is not None:
@@ -1595,10 +1643,11 @@ def flash_attention_with_lse(
         window, q_offset, block_q, block_k, interpret, blockdiff, summaries, tag,
         selected is not None, layout)
     o, lse = _flash(cfg, q, k, v, segs, slopes, info, selected)
+    Dv = _value_dim(cfg, D)
     if cfg.layout == "rows":
-        out = o.reshape(B, Sq, H, D)
+        out = o.reshape(B, Sq, H, Dv)
     else:
-        out = o.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+        out = o.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3)
     return out, lse.reshape(B, H, Sq)
 
 

@@ -197,12 +197,23 @@ def resolve_policy(name: Optional[str]):
 #: threshold); the selected launch's pair ``attn_lse_dsa`` / ``attn_o_dsa`` as
 #: the plain launch's pair. The indexer's two projections (``indexer_q``,
 #: ``indexer_k``) are products over the hidden size and stand with ``q_proj``.
+#: Latent attention's two-width launch (PR 55) names its pair after its tag
+#: (``attn_lse_mla`` / ``attn_o_mla``) and stands right after the plain pair,
+#: for the plain pair's reason; the compressed query's two products
+#: (``q_latent`` over the hidden size, ``q_b_proj`` over the rank, which stands in
+#: ``q_proj``'s place) join the projections' group. Hyper-connections name
+#: nothing: the coefficients, the Sinkhorn rounds and both mixings are made
+#: again in the backward (arXiv:2512.24880's own choice), and the carry the
+#: budget is charged for every layer is the block's real one, the n streams
+#: (``_bytes(carry)``: 28,672 B a token at n = 4 x 3584 in bfloat16).
 SAVE_ORDER = (("indexer_kl_dq", "indexer_kl_dk", "indexer_kl_dw"), ("dsa_mask",), ("attn_lse_dsa", "attn_o_dsa"),
-              ("attn_lse", "attn_o"), ("eva_kbar", "eva_vbar"),
+              ("attn_lse", "attn_o"), ("attn_lse_mla", "attn_o_mla"),
+              ("eva_kbar", "eva_vbar"),
               ("moe_logits",), ("wi_gate", "wi_up"),
               ("wi",), ("wo",), ("fc_in",), ("gate_proj", "up_proj"),
               ("o_proj",), ("attn_gate",),
-              ("q_proj", "k_proj", "v_proj", "kv_latent", "indexer_q", "indexer_k"),
+              ("q_proj", "k_proj", "v_proj", "kv_latent", "q_latent", "q_b_proj",
+               "indexer_q", "indexer_k"),
               ("kv_up",))
 
 #: What a saved byte costs the step's peak, measured on the chip by filling

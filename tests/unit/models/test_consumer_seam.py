@@ -49,6 +49,9 @@ USES = {
     "rope_sections": dict(rope_sections=(1, 2, 1)),
     "pred_heads": dict(pred_heads=2),
     "farskip": dict(farskip=True),
+    "q_latent_rank": dict(attention="latent", kv_latent_rank=8, q_latent_rank=4, qk_nope_dim=6,
+                          qk_rope_dim=2, v_head_dim=8),
+    "residual_streams": dict(residual_streams=2, hc_sinkhorn_iters=3),
     "first_dense_layers": dict(first_dense_layers=1, dense_intermediate_size=32, moe=EXPERTS),
     "mtp_layers": dict(mtp_layers=1),
     "objective='block_diffusion'": dict(objective="block_diffusion", block_length=4,
@@ -141,6 +144,7 @@ PLAIN = {
     "dtype", "remat", "remat_policy", "pad_token_id", "qk_norm_per_head", "moe_layer_freq",
     "eva_window", "eva_chunk", "kv_latent_rank", "qk_nope_dim", "qk_rope_dim", "v_head_dim",
     "dense_intermediate_size", "mtp_loss_coef", "block_length", "mask_token_id", "noise_seed",
+    "hc_sinkhorn_iters", "hc_eps", "hc_res_clamp",
 }
 
 

@@ -1,0 +1,182 @@
+"""Hyper-connected residual streams (PR 55; ``TransformerConfig.residual_streams``)
+on the CPU: ``H_res`` is doubly stochastic after the configured rounds and the
+clamp binds at its two ends; a fresh layer mixes nothing; the carry is vec(X);
+the consumers that compute less than ``TransformerLM.loss`` refuse the streams
+and the compressed query by name; and a configuration WITHOUT streams is the
+program it was: the standing tiny presets' loss and gradient norm, to the bit
+(read on the parent commit of PR 55 with this file's own arithmetic)."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu import models
+from deepspeed_tpu.models.transformer import MECHANISMS, TransformerConfig, TransformerLM
+from deepspeed_tpu.nn import layers as nn
+
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def model():
+    """The tiny preset (which runs 6 rounds, for the CPU's compile time) at
+    the published 20 rounds."""
+    return models.xing4_model("xing4-tiny", dtype=F32, remat=False, hc_sinkhorn_iters=20)
+
+
+def coefficients(model, alpha_res=0.5, bias_res=None, seed=0, tokens=(2, 16)):
+    n, H = model.config.residual_streams, model.config.hidden_size
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    hc = {"phi": jax.random.normal(ks[0], (n * H, n * (n + 2)), F32) * 0.05,
+          "bias": jax.random.normal(ks[1], (n * (n + 2),), F32) if bias_res is None
+          else jnp.concatenate([jnp.zeros((2 * n,)), jnp.asarray(bias_res, F32).reshape(-1)]),
+          "alpha": jnp.asarray([0.5, 0.5, alpha_res], F32)}
+    X = jax.random.normal(ks[2], tokens + (n * H,), F32)
+    return model._hc_coefficients(hc, X), hc, X
+
+
+def test_h_res_is_doubly_stochastic_after_twenty_rounds(model):
+    (pre, post, res), _, _ = coefficients(model)
+    assert pre.shape == (4, 2, 16) and post.shape == (4, 2, 16) and res.shape == (4, 4, 2, 16)
+    np.testing.assert_allclose(jnp.sum(res, axis=0), 1.0, atol=1e-5)    # columns: exact
+    np.testing.assert_allclose(jnp.sum(res, axis=1), 1.0, atol=1e-5)    # rows: converged
+    assert float(res.min()) > 0 and 0 < float(pre.min()) and float(pre.max()) < 1
+    assert 0 < float(post.min()) and float(post.max()) < 2
+    # visibly not the identity, and not the same for every position
+    assert float(jnp.max(res[0, 1])) > 0.02 and float(jnp.std(res[0, 0])) > 1e-3
+    # one round is not enough: the rounds are run
+    few = TransformerLM(dataclasses.replace(model.config, hc_sinkhorn_iters=1))
+    (_, _, once), _, _ = coefficients(few)
+    assert float(jnp.max(jnp.abs(jnp.sum(once, axis=1) - 1.0))) > 1e-3
+
+
+def test_the_clamp_binds_at_both_ends(model):
+    """Logits of +-1000 on H_res's first row: unclamped, exp overflows (inf /
+    inf) or underflows a whole row to 0 / eps; clamped at +-30 the matrix stays
+    finite, positive and doubly stochastic, and a wider logit changes nothing."""
+    def bias(big):
+        b = np.zeros((4, 4), np.float32)
+        b[0, 0], b[0, 1], b[1, :] = big, -big, -big
+        return b
+    (_, _, at_1000), _, _ = coefficients(model, alpha_res=0.0, bias_res=bias(1000.0))
+    (_, _, at_30), _, _ = coefficients(model, alpha_res=0.0, bias_res=bias(30.0))
+    (_, _, at_29), _, _ = coefficients(model, alpha_res=0.0, bias_res=bias(29.0))
+    assert np.isfinite(np.asarray(at_1000)).all()
+    np.testing.assert_array_equal(np.asarray(at_1000), np.asarray(at_30))
+    assert np.abs(np.asarray(at_29) - np.asarray(at_30)).max() > 0
+    np.testing.assert_allclose(jnp.sum(at_30, axis=0), 1.0, atol=1e-5)
+    loose = TransformerLM(dataclasses.replace(model.config, hc_res_clamp=(-2000.0, 2000.0)))
+    (_, _, unclamped), _, _ = coefficients(loose, alpha_res=0.0, bias_res=bias(1000.0))
+    assert not np.isfinite(np.asarray(unclamped)).all()
+
+
+def test_a_fresh_layer_mixes_nothing_and_the_carry_is_vec_x(model):
+    c = model.config
+    fresh = nn.HyperConnection(c.residual_streams, c.hidden_size).init(jax.random.PRNGKey(1))
+    assert {k: v.shape for k, v in fresh.items()} == {
+        "phi": (4 * 64, 24), "bias": (24,), "alpha": (3,)}
+    X = jax.random.normal(jax.random.PRNGKey(2), (1, 8, 4 * 64), F32)
+    y = jax.random.normal(jax.random.PRNGKey(3), (1, 8, 64), F32)
+    out, rest, err = model._hc_sublayer(fresh, X, lambda u: (y, "more"))
+    assert out.shape == X.shape and rest == ["more"] and float(err) < 1e-3
+    # H_res near the identity, H_post near 1: every stream gains y
+    np.testing.assert_allclose(out, X + jnp.tile(y, (1, 1, 4)), atol=5e-3 * float(jnp.abs(X).max()))
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, 8, 64), F32)
+    start = model._hc_start(x)
+    assert start.shape == (1, 8, 256)
+    np.testing.assert_array_equal(np.asarray(start).reshape(1, 8, 4, 64)[:, :, 3], np.asarray(x))
+    np.testing.assert_allclose(model._hc_collapse(start), 4 * x, rtol=1e-6)
+    # one stream: both ends are the identity
+    plain = models.instella_moe_model("instella-tiny", dtype=F32)
+    assert plain._hc_start(x) is x and plain._hc_collapse(x) is x
+
+
+def test_the_records_of_the_streams_and_the_two_widths(model):
+    attn, _ = model.attention_records(2, 32)
+    assert attn["hc"] == {"streams": 4, "sinkhorn_iters": 20, "sublayers": 2 * (6 + 1)}
+    assert attn["mla"] == {"qk_dim": 32, "v_dim": 16, "q_rank": 16, "kv_rank": 24,
+                           "route": "xla", "dq": None, "layout": None}
+    assert model.returns_step_stats
+    # one stream and one width: neither record
+    plain, _ = models.instella_moe_model("instella-tiny", dtype=F32).attention_records(2, 32)
+    assert "hc" not in plain and "mla" not in plain
+
+
+def test_consumers_refuse_the_streams_and_the_compressed_query_by_name(model):
+    assert {"residual_streams", "q_latent_rank"} <= set(MECHANISMS)
+    assert {"residual_streams", "q_latent_rank"} <= set(model.mechanisms)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    block = jax.tree.map(lambda a: jnp.zeros(a.shape[1:], a.dtype), shapes["blocks"])
+    with pytest.raises(NotImplementedError, match="q_latent_rank.*residual_streams"):
+        model.block_apply(block, jnp.zeros((1, 8, 256)), jnp.arange(8)[None])
+    from deepspeed_tpu.runtime.pipe.module import PipelineModule
+    with pytest.raises(NotImplementedError, match="residual_streams"):
+        PipelineModule(model.config, num_stages=2)
+    from deepspeed_tpu.inference.v2.model import RaggedInferenceModel
+    with pytest.raises(NotImplementedError, match="residual_streams"):
+        RaggedInferenceModel(model, block_size=8, max_blocks_per_seq=1)
+
+
+def test_what_the_new_fields_refuse():
+    base = dict(norm="rmsnorm", position="rope", activation="silu_gated", linear_bias=False)
+    with pytest.raises(ValueError, match="residual_streams"):
+        TransformerLM(TransformerConfig(residual_streams=4, farskip=True, **base))
+    with pytest.raises(ValueError, match="residual_streams"):
+        TransformerLM(TransformerConfig(residual_streams=4, parallel_block=True, **base))
+    with pytest.raises(ValueError, match="residual_streams"):
+        TransformerLM(TransformerConfig(residual_streams=0, **base))
+    with pytest.raises(ValueError, match="q_latent_rank"):
+        TransformerLM(TransformerConfig(q_latent_rank=8, **base))
+    from deepspeed_tpu.models.registry import get_architecture
+    spec = get_architecture("xing4_0")
+    with pytest.raises(NotImplementedError, match="made_up_key"):
+        spec.config_fn({"made_up_key": 1})
+    with pytest.raises(NotImplementedError, match="ep_size"):
+        spec.config_fn({"ep_size": 8})
+    with pytest.raises(NotImplementedError, match="checkpoint"):
+        spec.params_fn(None, {})
+    # a dense stack takes the streams too (the mechanism is the block's, not the experts')
+    dense = TransformerLM(TransformerConfig(
+        vocab_size=64, max_seq_len=16, num_layers=2, num_heads=2, hidden_size=16,
+        residual_streams=2, hc_sinkhorn_iters=4, remat=False, **base))
+    params = dense.init(jax.random.PRNGKey(0))
+    loss = dense.loss(params, {"input_ids": np.arange(16).reshape(1, 16) % 64})
+    assert np.isfinite(float(loss))
+
+
+#: (builder, preset) -> what the loss TRACES to (`fingerprint`), as the PARENT
+#: of PR 55 traces it. (The losses and gradient norms of the seven read equal
+#: to the bit on both sides too, float32 on the CPU: PERF.md, PR 55.)
+AS_IT_WAS = {
+    ("gpt2_model", "gpt2-tiny"): "975cb11b83d0d964",
+    ("olmoe_model", "olmoe-tiny"): "ef43a54abcad9a7f",
+    ("instella_moe_model", "instella-tiny"): "b2d2ea7c69805d89",
+    ("afmoe_model", "afmoe-tiny"): "d3676aa8920b7aa8",
+    ("sdar_moe_model", "sdar-tiny"): "193b51969fc03af5",
+    ("evabyte_model", "evabyte-tiny"): "19bbdc60ddbeb808",
+    ("keye_vl2_model", "keye-vl2-tiny"): "1de5b9a03f491d4c",
+}
+
+
+def fingerprint(m) -> str:
+    """sha256 of the jaxpr of the loss and its gradient over ``[2, 32]`` ids,
+    addresses taken out."""
+    import hashlib
+    import re
+    params = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    seq = min(32, m.config.max_seq_len)
+    ids = jax.ShapeDtypeStruct((2, seq), jnp.int32)
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        lambda p, ids: m.loss(p, {"input_ids": ids})))(params, ids))
+    return hashlib.sha256(re.sub(r"0x[0-9a-f]+", "", text).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("builder,preset", sorted(AS_IT_WAS))
+def test_a_configuration_without_streams_traces_what_it_traced(builder, preset):
+    m = getattr(models, builder)(preset, dtype=F32)
+    assert m.config.residual_streams == 1 and not m.config.q_latent_rank
+    assert not {"residual_streams", "q_latent_rank"} & set(m.mechanisms)
+    assert fingerprint(m) == AS_IT_WAS[(builder, preset)]

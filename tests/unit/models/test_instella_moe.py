@@ -406,8 +406,11 @@ def test_what_the_new_fields_refuse():
         model.block_apply(block, x, jnp.arange(8)[None])
     from deepspeed_tpu.models.registry import get_architecture
     spec = get_architecture("deepseek_v3")
-    with pytest.raises(NotImplementedError, match="q_lora_rank"):
-        spec.config_fn({"q_lora_rank": 1536, "num_attention_heads": 4})
+    # (a compressed query is read since PR 55: ``q_latent_rank``)
+    with pytest.raises(NotImplementedError, match="hidden_act"):
+        spec.config_fn({"hidden_act": "gelu", "num_attention_heads": 4})
+    with pytest.raises(ValueError, match="q_latent_rank"):
+        TransformerLM(TransformerConfig(q_latent_rank=16))
     with pytest.raises(NotImplementedError, match="checkpoint"):
         spec.params_fn(None, {})
 

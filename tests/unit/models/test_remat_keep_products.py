@@ -182,8 +182,10 @@ def test_choose_saved_takes_the_measured_order():
     # the projections in front of the kernel go first when room runs out
     # (latent attention's compressed vector with them, its up-projection,
     # which contracts over the rank alone, after them)
-    assert SAVE_ORDER[-2:] == (("q_proj", "k_proj", "v_proj", "kv_latent",
-                                "indexer_q", "indexer_k"), ("kv_up",))
+    assert SAVE_ORDER[-2:] == (("q_proj", "k_proj", "v_proj", "kv_latent", "q_latent",
+                                "q_b_proj", "indexer_q", "indexer_k"), ("kv_up",))
+    # (the two-width launch's pair stands right after the plain pair: PR 55)
+    assert SAVE_ORDER[3:5] == (("attn_lse", "attn_o"), ("attn_lse_mla", "attn_o_mla"))
     assert len(set(names)) == len(names)
 
 

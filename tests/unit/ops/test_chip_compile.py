@@ -98,6 +98,26 @@ class TestCompilesForV5e:
                          chip((B, S, H, D), BF16), chip((B, S, kvH, D), BF16),
                          chip((B, S, kvH, D), BF16), chip((B, S), I32))
 
+    def test_flash_fwd_bwd_two_widths_at_8k(self, chip):
+        """The xing4-29b-a4b cell's attention core (PR 55): 32 heads whose
+        queries and keys are 192 wide (one and a half lane tiles) and whose
+        values are 128, at 8,192 with segment ids: the two-width launches, by
+        heads, the backward's dq added to in place in a 256-lane tile."""
+        from deepspeed_tpu.ops.transformer.pallas_flash import \
+            flash_attention_kernel
+        B, S, H, D, Dv = 1, 8192, 32, 192, 128
+
+        def loss(q, k, v, seg):
+            return jnp.sum(flash_attention_kernel(
+                q, k, v, causal=True, segment_ids=seg, interpret=False).astype(F32))
+
+        fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
+        args = (chip((B, S, H, D), BF16), chip((B, S, H, D), BF16),
+                chip((B, S, H, Dv), BF16), chip((B, S), I32))
+        compile_for_chip(fn, *args)
+        text = jax.jit(fn).lower(*args).as_text()
+        assert "flash_fwd_mla" in text and "flash_bwd_mla" in text
+
     def test_blockdiff_attention_at_8k(self, chip, monkeypatch):
         """The sdar-30b-a3b cell's attention core: 32 query heads over 4 key
         heads of 128, 16,384 rows (a clean and a noised copy of 8,192

@@ -147,6 +147,9 @@ KERNEL_NAMES = {
     "flash_fwd_eva_far", "flash_bwd_eva_far",
     # the flash pair whose tiles read a learned selection's operand (PR 48)
     "flash_fwd_dsa", "flash_bwd_dsa",
+    # the flash pair whose values have a width of their own (PR 55: latent
+    # attention's 192 / 128; ``FlashConfig.v_dim``, tag ``mla``)
+    "flash_fwd_mla", "flash_bwd_mla",
     # ``dO x O``'s row sum for a launch whose operands lie by rows (PR 51): NOT
     # ``flash_bwd*``, whose readers sum the backward launches alone
     "flash_delta",
@@ -183,7 +186,7 @@ def test_every_pallas_call_has_a_name(site):
 def test_kernel_names_are_distinct_and_complete():
     assert len(PALLAS_SITES) == 22
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 32
+    assert len(set(names)) == len(names) == 34
     assert set(names) == KERNEL_NAMES
 
 

@@ -1421,18 +1421,25 @@ class TransformerLM:
         n x H]`` (vec(X): the n streams side by side), a position at a time, in
         float32 -> ``(H_pre [n, B, S], H_post [n, B, S], H_res [n, n, B, S])``
         (the positions LAST: they fill the lanes, n and n x n lead):
-        ``m = (vec(X) rsqrt(mean(vec(X)^2) + eps)) Phi``; ``H_pre =
-        sigmoid(a_pre m_pre + b_pre)``; ``H_post = 2 sigmoid(a_post m_post +
-        b_post)``; ``H_res`` = ``hc_sinkhorn_iters`` rounds (every row over its
-        sum + eps, then every column) from ``exp(clamp(a_res m_res + b_res))``.
-        Scope ``hc/coeff``."""
+        ``m = (vec(X) rsqrt(mean(vec(X)^2) + eps)) Phi``, as ``rsqrt(..) x
+        (vec(X) Phi)`` in one pass over the streams as they lie
+        (``ops/transformer/pallas_hc.py``: the kernel or its XLA form by
+        `_hc_route`); ``H_pre = sigmoid(a_pre m_pre + b_pre)``; ``H_post = 2
+        sigmoid(a_post m_post + b_post)``; ``H_res`` = ``hc_sinkhorn_iters``
+        rounds (every row over its sum + eps, then every column) from
+        ``exp(clamp(a_res m_res + b_res))``. Scope ``hc/coeff``."""
+        from ..ops.transformer import pallas_hc
+        with jax.named_scope("hc"), jax.named_scope("coeff"):
+            m = pallas_hc.coeff_product(X, hc["phi"], self.config.hc_eps,
+                                        self._hc_route(X.shape[1], X.dtype)[0])
+        return self._hc_mixes(hc, m)
+
+    def _hc_mixes(self, hc: Params, m: jax.Array):
+        """`_hc_coefficients`' second half: ``m [n (n + 2), B, S]`` float32 ->
+        ``(H_pre, H_post, H_res)``, the sigmoids and the Sinkhorn rounds."""
         c, n = self.config, self.config.residual_streams
         f32 = jnp.float32
         with jax.named_scope("hc"), jax.named_scope("coeff"):
-            x = X.astype(f32)
-            x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + c.hc_eps)
-            m = jnp.einsum("bsk,kc->cbs", x, hc["phi"].astype(f32),
-                           precision=jax.lax.Precision.HIGHEST)
             alpha, bias = hc["alpha"].astype(f32), hc["bias"].astype(f32)
             at = lambda lo, hi, a: a * m[lo:hi] + bias[lo:hi, None, None]
             pre = jax.nn.sigmoid(at(0, n, alpha[0]))
@@ -1446,6 +1453,19 @@ class TransformerLM:
                 res = res / (jnp.sum(res, axis=1, keepdims=True) + c.hc_eps)
                 res = res / (jnp.sum(res, axis=0, keepdims=True) + c.hc_eps)
             return pre, post, res
+
+    def _hc_route(self, seq: int, dtype) -> Tuple[str, Optional[int]]:
+        """``(route, tile_rows)`` of a sub-layer's coefficients over rows of
+        ``seq`` positions of streams of ``dtype`` here:
+        ``pallas_hc.choose_route`` by the backend, the type, the shape and the
+        live mesh's devices, and the kernel's row tile (None on the XLA route)."""
+        from ..ops.transformer import pallas_hc
+        from ..runtime import topology as topo_mod
+        c = self.config
+        K = c.residual_streams * c.hidden_size
+        devices = topo_mod.get_topology().world_size if topo_mod.is_initialized() else 1
+        route = pallas_hc.choose_route(seq, K, dtype, jax.default_backend(), devices)
+        return route, pallas_hc.choose_tiles(seq, K).tm if route == "kernel" else None
 
     def _hc_streams(self, X: jax.Array):
         """The n streams of vec(X) ``[B, S, n x H]``, each ``[B, S, H]`` float32."""
@@ -2279,13 +2299,17 @@ class TransformerLM:
             # (the streams' record rides here: an engine copies this dict whole)
             attn["hc"] = {"streams": c.residual_streams,
                           "sinkhorn_iters": c.hc_sinkhorn_iters,
-                          "sublayers": 2 * (c.num_layers + c.mtp_layers)}
+                          "sublayers": 2 * (c.num_layers + c.mtp_layers),
+                          "route": None, "tile_rows": None}
         diffusion = {"block_length": c.block_length, "rows_per_token": self.rows_per_token,
                      "route": None, "dq": None, "layout": None} if c.diffusion else None
         if seq is None:
             return attn, diffusion
         plans = {w: self._attention_plan(batch, seq, w) for w in [0] + windows}
         tag = "mla" if self._mla_widths else "flash"
+        if c.residual_streams > 1:
+            route, tile_rows = self._hc_route(seq * self.rows_per_token, c.dtype)
+            attn["hc"].update(route=route, tile_rows=tile_rows)
         if self._mla_widths:
             attn["mla"].update(route=plans[0].route, dq=plans[0].dq("mla"),
                                layout=plans[0].layout("mla"))

@@ -96,7 +96,8 @@ def test_a_fresh_layer_mixes_nothing_and_the_carry_is_vec_x(model):
 
 def test_the_records_of_the_streams_and_the_two_widths(model):
     attn, _ = model.attention_records(2, 32)
-    assert attn["hc"] == {"streams": 4, "sinkhorn_iters": 20, "sublayers": 2 * (6 + 1)}
+    assert attn["hc"] == {"streams": 4, "sinkhorn_iters": 20, "sublayers": 2 * (6 + 1),
+                          "route": "xla", "tile_rows": None}
     assert attn["mla"] == {"qk_dim": 32, "v_dim": 16, "q_rank": 16, "kv_rank": 24,
                            "route": "xla", "dq": None, "layout": None}
     assert model.returns_step_stats

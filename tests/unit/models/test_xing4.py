@@ -90,7 +90,8 @@ def test_first_step_through_initialize_leaf_by_leaf(parts, wanted):
         total += np.sum(s != 0)
     assert wrong / total < 2e-3
     assert engine.attn_totals["hc"] == {"streams": 4, "sublayers": 14,
-                                        "sinkhorn_iters": cfg["hc_sinkhorn_iters"]}
+                                        "sinkhorn_iters": cfg["hc_sinkhorn_iters"],
+                                        "route": "xla", "tile_rows": None}
     assert engine.attn_totals["mla"] == {"qk_dim": 32, "v_dim": 16, "q_rank": 16,
                                          "kv_rank": 24, "route": "xla", "dq": None,
                                          "layout": None}

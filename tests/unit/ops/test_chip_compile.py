@@ -270,6 +270,37 @@ class TestCompilesForV5e:
                                    chip((rows,), F32), chip((), I32)).compile().as_text()
         assert "segment-sum" in text and "tpu_custom_call" in text
 
+    @pytest.mark.parametrize("phi_dtype", [BF16, F32], ids=["phi16", "phi32"])
+    def test_hc_coeff(self, chip, phi_dtype):
+        """The streams' coefficients at the xing4 cell's shape (8,192 rows of
+        4 x 3584) with the tiles ``choose_tiles`` gives, forward and backward:
+        the launch, and ``hc/coeff`` in the ``op_name`` of the launch and of
+        the backward's products when the model's scope is around the call."""
+        from deepspeed_tpu import models
+        from deepspeed_tpu.ops.transformer import pallas_hc
+        rows, K = 8192, 4 * 3584
+        assert pallas_hc.choose_route(rows, K, BF16, "tpu", 1) == "kernel"
+        model = models.xing4_model("xing4-tiny", dtype=BF16, remat=False, hidden_size=3584)
+
+        def loss(X, phi, alpha, bias, ct):
+            # (`_hc_coefficients` with the route the chip takes: the backend
+            # here is the CPU's)
+            with jax.named_scope("hc"), jax.named_scope("coeff"):
+                m = pallas_hc.coeff_product(X, phi, model.config.hc_eps,
+                                            "kernel", interpret=False)
+            pre, post, res = model._hc_mixes({"alpha": alpha, "bias": bias}, m)
+            return jnp.sum(pre) + jnp.sum(post) + jnp.sum(res * ct)
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+            chip((1, rows, K), BF16), chip((K, 24), phi_dtype), chip((3,), F32),
+            chip((24,), F32), chip((4, 4, 1, rows), F32)).compile().as_text()
+        lines = text.splitlines()
+        assert any("tpu_custom_call" in line and "hc_coeff_fwd" in line
+                   and "jvp(hc)/coeff/" in line for line in lines)
+        products = [line for line in lines if "transpose(jvp(hc))/coeff/" in line
+                    and ("convolution" in line or "fusion" in line)]
+        assert products, "the backward's products lost the scope"
+
     @pytest.mark.parametrize("mode,moments,n", [
         ("adamw", "fp32", REAL.bucket_elems),
         ("adamw", "bf16-sr", REAL.bucket_elems),

@@ -161,7 +161,11 @@ KERNEL_NAMES = {
     "dsa_select",
     # a share's rows back to the tokens (PR 38), under ``mlp/moe/combine`` and
     # ``mlp/moe/dispatch``: ``train_moe_dispatch_ms`` finds it by its scope
-    "segment-sum"}
+    "segment-sum",
+    # a hyper-connected sub-layer's coefficients in one pass over vec(X)
+    # (PR 56; ``pallas_hc``), under ``hc/coeff``: both ``hc`` metrics find it
+    # by its scope
+    "hc_coeff_fwd"}
 
 
 def _names_of(name):
@@ -184,9 +188,9 @@ def test_every_pallas_call_has_a_name(site):
 
 
 def test_kernel_names_are_distinct_and_complete():
-    assert len(PALLAS_SITES) == 22
+    assert len(PALLAS_SITES) == 23
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 34
+    assert len(set(names)) == len(names) == 35
     assert set(names) == KERNEL_NAMES
 
 

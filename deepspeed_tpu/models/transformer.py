@@ -166,6 +166,43 @@ def mlp_row_slices(rows: int, width: int) -> int:
     return n
 
 
+#: The share of the room a step's activations have (``Budget.room_bytes``) that
+#: a head's float32 logits and their gradient, ``2 x 4 x rows x vocab`` bytes,
+#: may take whole, and the share one slice of the rows may take where they do
+#: not. On the chip the seven cells that ran a whole head stand at 18 to 43 %
+#: of their room (Trinity's 16,384 x 25,024: 3.28 of 7.65 GB); 16,384 x 25,008
+#: beside 10.98 GB of state is 3.28 GB of a room the chip read as 5.01 (PR 57),
+#: 65 %, and runs in four slices of 0.82.
+HEAD_WHOLE_SHARE = 0.5
+HEAD_SLICE_SHARE = 0.25
+
+
+def head_row_slices(rows: int, vocab: int, room_bytes: Optional[int]) -> int:
+    """How many slices of its rows the final norm, the head and the
+    cross-entropy are computed in: 1 where the room cannot be read (None, or
+    the 0 a run across processes is given until its reading is exchanged) or
+    the float32 logits and their gradient fit `HEAD_WHOLE_SHARE` of it, else
+    the least power of two that divides the rows and brings a slice's under
+    `HEAD_SLICE_SHARE`. Pure: the shapes and the room in, a count out."""
+    n, whole = 1, 2 * 4 * rows * vocab
+    if room_bytes and whole > HEAD_WHOLE_SHARE * room_bytes:
+        while rows % (2 * n) == 0 and whole / n > HEAD_SLICE_SHARE * room_bytes:
+            n *= 2
+    return n
+
+
+def head_slices(config, remat_budget: Optional[Budget], input_ids) -> int:
+    """`head_row_slices` of a step over ``input_ids`` under the budget's room:
+    one next-token head of a causal decoder alone, and 1 without a budget. (A
+    function of the configuration: ``PipelineModule`` borrows
+    ``TransformerLM.loss``.)"""
+    c = config
+    if (remat_budget is None or c.pred_heads > 1 or c.mlm_head or c.diffusion
+            or c.mtp_layers or not c.causal):
+        return 1
+    return head_row_slices(input_ids.size, c.vocab_size, remat_budget.room_bytes)
+
+
 def eva_head_groups(rows: int, heads: int, head_dim: int) -> int:
     """How many groups of its heads an EVA layer's attention is computed in:
     the least divisor of ``heads`` that brings a group's ``[rows, heads x
@@ -449,6 +486,60 @@ class TransformerConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # state-space layers (Mamba-1's selective scan, arXiv:2312.00752): with
+    # ``ssm_state`` (states a channel; 0: none) every layer l with ``l %
+    # ssm_period == 0`` has, in attention's place, ``[a, z] = u W_in`` (``ssm_expand
+    # x hidden`` channels each), a causal depthwise convolution of ``ssm_conv``
+    # taps and a SiLU on ``a``, ``[r, B, C] = a W_x`` (``ssm_dt_rank``, None:
+    # ceil(hidden / 16), and twice the states), ``dt = softplus(r W_dt + b)``,
+    # the scan ``h_t = exp(dt_t A) h_{t-1} + dt_t a_t (x) B_t``, ``m_t = h_t C_t +
+    # D a_t`` (``ops/transformer/pallas_scan.py``) and ``(m * silu(z)) W_out``. In
+    # a packed row (``document_separator``) the state and the taps start anew
+    # at a document's first token.
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: Optional[int] = None
+    ssm_period: int = 2
+    # differential attention (arXiv:2410.05258): the heads are paired by
+    # parity, each half a softmax of its own over the pair's two value heads
+    # side by side, ``o = RMSNorm(o1 - lambda o2) (1 - lambda_init)``, ``lambda =
+    # exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`` from four learned vectors a
+    # layer, ``lambda_init = 0.8 - 0.6 exp(-0.3 l)`` at layer l
+    differential_attention: bool = False
+    # a cross-decoder (YOCO, arXiv:2405.05254; SambaY, arXiv:2507.06607): layer
+    # ``shared_from`` is a scan layer whose scan output m every later scan-slot
+    # layer reads in a scan's place (a gated memory unit, ``(silu(u W_1) * m)
+    # W_2``), and layer ``shared_from + 1`` an attention layer whose keys and
+    # values every later attention layer attends with queries of its own
+    shared_from: Optional[int] = None
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_expand * self.hidden_size
+
+    @property
+    def ssm_rank(self) -> int:
+        return self.ssm_dt_rank or -(-self.hidden_size // 16)
+
+    @property
+    def mixed(self) -> bool:
+        """Whether the layers' mixers are not all plain attention of one
+        parameter layout: the layers then run as `TransformerLM.run_plan` lays
+        them out and their parameters lie under ``params["runs"]``, a stack a
+        run and place in its unit, in ``params["blocks"]``' place."""
+        return bool(self.ssm_state or self.differential_attention
+                    or self.shared_from is not None)
+
+    def mixer_of(self, layer: int) -> Tuple[str, Optional[str]]:
+        """Layer ``layer``'s mixer and what it hands on: ``("ssm" | "attn" |
+        "gmu" | "cross", None | "memory" | "kv")``."""
+        scan = bool(self.ssm_state) and layer % self.ssm_period == 0
+        at = self.shared_from
+        if at is not None and layer >= at + 2:
+            return ("gmu" if scan else "cross"), None
+        hands = None if at is None or layer < at else ("memory" if scan else "kv")
+        return ("ssm" if scan else "attn"), hands
 
     @property
     def diffusion(self) -> bool:
@@ -506,6 +597,22 @@ class TransformerConfig:
             attn += h * (ix.heads * ix.head_dim + ix.head_dim + ix.heads) + 2 * ix.head_dim
         if self.mlm_head:
             head += h * h + v  # prediction transform + decoder bias
+        if self.mixed:
+            # each layer its own mixer; LayerNorm's and the projections' biases
+            # counted (the published 3.8 B of a 32-layer SambaY stack is this sum)
+            norm = (2 if self.norm == "layernorm" else 1) * h
+            bias = self.norm == "layernorm"
+            di, n, r = self.ssm_inner, self.ssm_state, self.ssm_rank
+            q_out, hd = self.num_heads * self.head_dim, self.head_dim
+            diff = 4 * hd + 2 * hd if self.differential_attention else 0
+            each = {
+                "ssm": (h * 2 * di + (self.ssm_conv + 1) * di + di * (r + 2 * n)
+                        + r * di + di + di * n + di + di * h),
+                "attn": h * q_out + 2 * h * kv + q_out * h + bias * (q_out + 2 * kv + h) + diff,
+                "gmu": 2 * h * di,
+                "cross": h * q_out + q_out * h + bias * (q_out + h) + diff}
+            return embed + head + norm + sum(
+                each[self.mixer_of(l)[0]] + mlp + 2 * norm for l in range(L))
         return embed + head + L * (attn + mlp)
 
 
@@ -558,6 +665,13 @@ MECHANISMS: Dict[str, Tuple[Callable[["TransformerLM"], bool], str]] = {
     "residual_streams": (lambda m: m.config.residual_streams > 1,
                          "a block carries n residual streams mixed by a doubly-stochastic "
                          "matrix a sub-layer (hyper-connections)"),
+    "ssm_state": (lambda m: bool(m.config.ssm_state),
+                  "some layers carry a selective scan's state along the row in attention's place"),
+    "differential_attention": (lambda m: m.config.differential_attention,
+                               "paired heads' two softmaxes are subtracted under a learned scalar"),
+    "shared_from": (lambda m: m.config.shared_from is not None,
+                    "later layers read one layer's keys and values and one layer's scan "
+                    "output: a layer cannot be applied alone"),
     "farskip": (lambda m: m.config.farskip,
                 "a block carries two streams: the residual now and a sub-block earlier"),
     "first_dense_layers": (lambda m: bool(m.config.first_dense_layers),
@@ -702,7 +816,9 @@ class TransformerLM:
                 "v_proj": lin(c.hidden_size, kv_out, attn_bias, "column"),
                 "o_proj": lin(attn_out, c.hidden_size, attn_out_bias, "row"),
             }
-        self._block_layers = {"ln_1": norm_cls(c.hidden_size), **attn_layers}
+        # (``mixed``: what every layer has; the mixers' layers a kind, below)
+        self._block_layers = {"ln_1": norm_cls(c.hidden_size),
+                              **({} if c.mixed else attn_layers)}
         if c.residual_streams > 1:
             # a sub-layer's coefficients (`_hc_coefficients`)
             for name in ("hc_attn", "hc_mlp"):
@@ -773,6 +889,34 @@ class TransformerLM:
                 "merge": lin(2 * c.hidden_size, c.hidden_size, False, None),
                 "ln_f": norm_cls(c.hidden_size),
             }
+        # each layer's mixer and what it hands on (``TransformerConfig.mixer_of``),
+        # and the layers of each kind of mixer, a stack a kind
+        self._mixers = (tuple(c.mixer_of(l) for l in range(c.num_layers))
+                        if c.mixed else None)
+        self._mixer_layers: Dict[str, Dict[str, Any]] = {}
+        if c.mixed:
+            if c.attention != "mha":
+                raise ValueError("ssm_state, differential_attention and shared_from "
+                                 "are written for 'mha' heads")
+            di = c.ssm_inner
+            diff = ({"diff_lambda": nn.HeadVectors(4, c.head_dim, init_scale=0.1),
+                     "diff_norm": nn.RMSNorm(2 * c.head_dim, eps=c.norm_eps)}
+                    if c.differential_attention else {})
+            kinds = {
+                "ssm": {"in_proj": lin(c.hidden_size, 2 * di, False, "column"),
+                        "ssm": nn.ScanParams(di, c.ssm_state, c.ssm_conv),
+                        "x_proj": lin(di, c.ssm_rank + 2 * c.ssm_state, False, None),
+                        "dt_proj": lin(c.ssm_rank, di, False, "column"),
+                        "out_proj": lin(di, c.hidden_size, False, "row")},
+                "attn": {"q_proj": attn_layers["q_proj"],
+                         "kv_proj": lin(c.hidden_size, 2 * kv_out, attn_bias, "column"),
+                         "o_proj": attn_layers["o_proj"], **diff},
+                "gmu": {"gmu_in": lin(c.hidden_size, di, False, "column"),
+                        "gmu_out": lin(di, c.hidden_size, False, "row")},
+                "cross": {"q_proj": attn_layers["q_proj"],
+                          "o_proj": attn_layers["o_proj"], **diff}}
+            self._mixer_layers = {name: layers for name, layers in kinds.items()
+                                  if self._mixer_count(name)}
         self._check_kinds()
 
     def _check_latent(self) -> None:
@@ -920,6 +1064,36 @@ class TransformerLM:
                 not c.causal or c.seq_parallel == "ring"):
             raise ValueError("document_separator: packed documents are a causal "
                              "decoder's, and not ring attention's")
+        if c.mixed:
+            # (``attention='mha'``: `__init__` refused another before it built a layer)
+            if (not c.causal or c.norm_style != "pre"
+                    or c.parallel_block or c.farskip or c.moe is not None
+                    or c.indexer is not None or c.diffusion or c.mtp_layers
+                    or c.residual_streams > 1 or c.qk_norm or c.attn_gate
+                    or c.seq_parallel == "ring" or c.position == "alibi"
+                    or c.activation != "silu_gated"
+                    or (c.remat and c.remat_policy == "alternating")):
+                raise ValueError(
+                    "ssm_state, differential_attention and shared_from are written for "
+                    "a causal decoder's sequential pre-norm blocks with 'mha' heads and "
+                    "a dense gated-SiLU MLP: no experts, indexer, block diffusion, "
+                    "prediction module, hyper-connections, FarSkip, QK-norm, attention "
+                    "gate, ALiBi, ring attention or remat_policy='alternating'")
+            if c.ssm_state and min(c.ssm_conv, c.ssm_expand, c.ssm_period) < 1:
+                raise ValueError("ssm_state needs ssm_conv, ssm_expand and ssm_period >= 1")
+            if c.differential_attention and (c.num_heads % 2 or c.kv_heads % 2
+                                             or (c.num_heads // 2) % (c.kv_heads // 2)):
+                raise ValueError("differential_attention pairs the heads by parity: an "
+                                 "even number of query heads over an even number of key heads")
+            at = c.shared_from
+            if at is not None and not (
+                    0 <= at < c.num_layers - 2 and c.ssm_state
+                    and self._mixers[at] == ("ssm", "memory")
+                    and self._mixers[at + 1] == ("attn", "kv")
+                    and not self._kinds[at + 1][0]):
+                raise ValueError(
+                    f"shared_from {at}: a scan layer (l % ssm_period == 0) followed by a "
+                    "full attention layer, with layers after both")
 
     # -- init / specs --------------------------------------------------------
     def init(self, rng: jax.Array, dtype=jnp.float32) -> Params:
@@ -953,7 +1127,18 @@ class TransformerLM:
                 block["moe"] = self._moe.init(jax.random.fold_in(r, 7), dtype)
             return block
 
-        params["blocks"] = jax.vmap(init_block)(jax.random.split(rng_blocks, c.scan_layers))
+        if c.mixed:
+            # a stack a run and place in its unit (`run_plan`): what a layer
+            # scan reads and what its gradient comes back as, no slice between
+            params["runs"] = {
+                str(i): {str(j): jax.vmap(
+                    lambda r, layers=self._layers_of(kind): nn.init_tree(layers, r, dtype)[0])(
+                        jax.random.split(jax.random.fold_in(rng_blocks, 64 * i + j), repeats))
+                    for j, kind in enumerate(unit)}
+                for i, (unit, repeats) in enumerate(self.run_plan)}
+        else:
+            params["blocks"] = jax.vmap(init_block)(
+                jax.random.split(rng_blocks, c.scan_layers))
         if c.first_dense_layers:
             params["dense_blocks"] = jax.vmap(functools.partial(init_block, dense=True))(
                 jax.random.split(jax.random.fold_in(rng_blocks, 1), c.first_dense_layers))
@@ -989,13 +1174,29 @@ class TransformerLM:
         # stacked over layers: prepend None for the layer dim
         stacked = lambda tree: jax.tree.map(
             lambda s: P(None, *s), tree, is_leaf=lambda s: isinstance(s, P))
-        specs["blocks"] = stacked(block_specs)
+        if c.mixed:
+            specs["runs"] = {
+                str(i): {str(j): stacked({name: layer.specs() for name, layer
+                                          in self._layers_of(kind).items()})
+                         for j, kind in enumerate(unit)}
+                for i, (unit, _) in enumerate(self.run_plan)}
+        else:
+            specs["blocks"] = stacked(block_specs)
         if c.first_dense_layers:
             specs["dense_blocks"] = stacked(dense_specs)
         if c.mtp_layers:
             specs["mtp"] = {name: layer.specs() for name, layer in self._mtp_layers.items()}
             specs["mtp"]["blocks"] = stacked(block_specs)
         return specs
+
+    def _mixer_count(self, mixer: str) -> int:
+        """How many layers of a mixed stack have the mixer ``mixer``."""
+        return sum(1 for m, _ in self._mixers if m == mixer)
+
+    def _layers_of(self, kind) -> Dict[str, Any]:
+        """The layers of one block of a mixed stack: what every block has and
+        its mixer's (``kind``: `run_plan`'s, the mixer third)."""
+        return {**self._block_layers, **self._mixer_layers[kind[2]]}
 
     # -- forward -------------------------------------------------------------
     def _rotate(self, x: jax.Array, positions: jax.Array) -> jax.Array:
@@ -1045,7 +1246,11 @@ class TransformerLM:
 
     def _layer(self, name: str):
         """A block's layer by name, of either kind of block."""
-        return self._block_layers.get(name) or self._dense_mlp_layers[name]
+        for layers in (self._block_layers, self._dense_mlp_layers,
+                       *self._mixer_layers.values()):
+            if name in layers:
+                return layers[name]
+        raise KeyError(name)
 
     def _attn(self, block: Params, h: jax.Array, positions: jax.Array,
               attn_mask: Optional[jax.Array] = None,
@@ -1316,11 +1521,12 @@ class TransformerLM:
                                 scale=c.attn_scale)
         return out.reshape(B, S, wide)
 
-    def _attn_core(self, q, k, v, attn_mask, window, scale=None) -> jax.Array:
-        """Scores, softmax and values (XLA, flash, ring or Ulysses)."""
+    def _attn_core(self, q, k, v, attn_mask, window, scale=None, tag=None) -> jax.Array:
+        """Scores, softmax and values (XLA, flash, ring or Ulysses). ``tag``: a
+        two-width launch's name (``pallas_flash.TAGS``)."""
         c = self.config
         seg = attn_mask.astype(jnp.int32) if attn_mask is not None else None
-        kw = {}
+        kw = {} if tag is None else {"tag": tag}
         scale = c.attn_scale if scale is None else scale
         if scale is not None:
             kw["scale"] = scale
@@ -1350,6 +1556,254 @@ class TransformerLM:
                 f"(sequence={topo.sequence_parallel_size})")
         return blockdiff_attention(q, k, v, self.config.block_length, documents,
                                    scale=self.config.attn_scale)
+
+
+    # -- mixed stacks (``TransformerConfig.mixed``): scan layers, memory units,
+    # -- differential attention, a cross-decoder ---------------------------------
+    def _devices(self) -> int:
+        """The live mesh's devices (a Pallas launch is not partitioned)."""
+        from ..runtime import topology as topo_mod
+        return topo_mod.get_topology().world_size if topo_mod.is_initialized() else 1
+
+    @staticmethod
+    def _first_of_document(documents: Optional[jax.Array], shape) -> jax.Array:
+        """``[B, S]`` bool: a row's first position, and a packed document's
+        first token."""
+        B, S = shape
+        start = jnp.broadcast_to(jnp.arange(S)[None, :] == 0, (B, S))
+        if documents is None:
+            return start
+        return start | (documents != jnp.pad(documents[:, :-1], ((0, 0), (1, 0))))
+
+    def _short_conv(self, ssm: Params, a: jax.Array,
+                    documents: Optional[jax.Array]) -> jax.Array:
+        """A scan layer's causal depthwise convolution over ``a`` ``[B, S, Di]``,
+        float32: ``sum_s conv[taps - 1 - s] a[t - s] + conv_bias`` over the taps
+        s whose token ``t - s`` lies in the row and in t's document."""
+        B, S, _ = a.shape
+        taps = self.config.ssm_conv
+        w, a32 = ssm["conv"].astype(jnp.float32), a.astype(jnp.float32)
+        at = jnp.arange(S)[None, :]
+        out = a32 * w[taps - 1] + ssm["conv_bias"].astype(jnp.float32)
+        for s in range(1, min(taps, S)):
+            seen = at >= s
+            if documents is not None:
+                seen = seen & (documents == jnp.pad(documents[:, :S - s], ((0, 0), (s, 0))))
+            back = jnp.pad(a32[:, :S - s], ((0, 0), (s, 0), (0, 0)))
+            out = out + jnp.where(seen[..., None], back, 0.0) * w[taps - 1 - s]
+        return out
+
+    def _scan_mixer(self, block: Params, h: jax.Array,
+                    documents: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
+        """A selective-scan layer's mixer over the pre-normed ``h``
+        (``TransformerConfig.ssm_state`` has the equations) -> (the branch's
+        output, the scan's output m ``[B, S, Di]`` before the gate: the memory a
+        cross-decoder's units read). Scopes ``ssm/in`` (the in projection, the
+        convolution), ``ssm/scan`` (``x_proj``, ``dt_proj``, the scan) and
+        ``ssm/out`` (the gate and the out projection)."""
+        from ..ops.transformer import pallas_scan
+        c = self.config
+        B, S, _ = h.shape
+        Di, N, R = c.ssm_inner, c.ssm_state, c.ssm_rank
+        ssm = block["ssm"]
+        project = lambda name, x, keep: checkpoint_name(
+            self._layer(name)(block[name], x), keep)
+        with jax.named_scope("ssm"):
+            with jax.named_scope("in"):
+                az = project("in_proj", h, "ssm_in")
+                a = nn.silu(self._short_conv(ssm, az[..., :Di], documents)).astype(h.dtype)
+            with jax.named_scope("scan"):
+                rbc = project("x_proj", a, "ssm_x")
+                dt_raw = project("dt_proj", rbc[..., :R], "ssm_dt")
+                flat = lambda t: t.reshape((B * S,) + t.shape[2:])
+                m = pallas_scan.selective_scan(
+                    flat(a), flat(dt_raw), -jnp.exp(ssm["A_log"].astype(jnp.float32)),
+                    flat(rbc[..., R:R + N]), flat(rbc[..., R + N:]), ssm["D"],
+                    ssm["dt_bias"], flat(self._first_of_document(documents, (B, S))),
+                    devices=self._devices()).reshape(B, S, Di)
+            with jax.named_scope("out"):
+                y = self._layer("out_proj")(block["out_proj"], m * nn.silu(az[..., Di:]))
+        return y, m
+
+    def _memory_unit(self, block: Params, h: jax.Array, memory: jax.Array) -> jax.Array:
+        """A gated memory unit over the pre-normed ``h`` (arXiv:2507.06607,
+        section 2): ``(silu(h W_1) * m) W_2``, ``m`` an earlier layer's scan
+        output at the same token. Scope ``gmu``."""
+        with jax.named_scope("gmu"):
+            gate = nn.silu(checkpoint_name(
+                self._layer("gmu_in")(block["gmu_in"], h), "gmu_in"))
+            return self._layer("gmu_out")(block["gmu_out"], gate * memory)
+
+    def _mixer_attn(self, block: Params, h: jax.Array, positions: jax.Array,
+                    documents: Optional[jax.Array], window, rope: bool, lam_init,
+                    kv=None) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+        """A mixed stack's attention over the pre-normed ``h`` -> (the branch's
+        output, (k, v) as attended, ``[B, S, kv heads, head]``). ``kv``: an
+        earlier layer's keys and values (a cross layer: it projects queries
+        alone). ``lam_init``: differential attention's constant at this layer."""
+        c = self.config
+        B, S, _ = h.shape
+        nh, kvh, hd = c.num_heads, c.kv_heads, c.head_dim
+        if isinstance(window, int) and window <= 0:
+            window = None
+        with jax.named_scope("attn"):
+            with jax.named_scope("qkv"):
+                q = self._project(block, "q_proj", h).reshape(B, S, nh, hd)
+                if rope:
+                    q = self._rotate(q, positions)
+                if kv is None:
+                    made = self._project(block, "kv_proj", h)
+                    k = made[..., :kvh * hd].reshape(B, S, kvh, hd)
+                    v = made[..., kvh * hd:].reshape(B, S, kvh, hd)
+                    if rope:
+                        k = self._rotate(k, positions)
+                else:
+                    k, v = kv
+            if c.differential_attention:
+                with jax.named_scope("core_diff"):
+                    out = self._diff_core(block, q, k, v, documents, window, lam_init)
+            else:
+                with jax.named_scope("core_window" if window else "core"):
+                    out = self._attn_core(q, k, v, documents, window)
+            with jax.named_scope("out"):
+                return self._project(block, "o_proj", out.reshape(B, S, nh * hd)), (k, v)
+
+    def _diff_core(self, block: Params, q, k, v, documents, window, lam_init) -> jax.Array:
+        """Differential attention's core (``TransformerConfig.
+        differential_attention``): the heads paired by parity; each half ONE
+        softmax of its queries over its keys, multiplied into the pair's two
+        value heads side by side (twice the keys' width: the two-width launch
+        tagged ``"diff"``, two launches a layer); the halves subtracted under
+        lambda and normed a pair in float32."""
+        f32 = jnp.float32
+        values = jnp.concatenate([v[:, :, 0::2], v[:, :, 1::2]], axis=-1)
+        first, second = (
+            self._attn_core(q[:, :, i::2], k[:, :, i::2], values, documents, window,
+                            tag="diff").astype(f32) for i in (0, 1))
+        lam = block["diff_lambda"]["value"].astype(f32)
+        lam = (jnp.exp(jnp.sum(lam[0] * lam[1])) - jnp.exp(jnp.sum(lam[2] * lam[3]))
+               + lam_init)
+        out = self._layer("diff_norm")(block["diff_norm"], first - lam * second)
+        return (out * (1.0 - lam_init)).astype(q.dtype)
+
+    def _shared_dtype(self, what: str):
+        """The dtype a layer's keys and values (``"kv"``) or scan output
+        (``"memory"``) are handed on in: float32 where several layers read them,
+        so that their cotangents are summed in float32 (a reader casts its copy
+        down); the stream's own with one reader."""
+        readers = self._mixer_count("cross" if what == "kv" else "gmu")
+        return jnp.float32 if readers > 1 else self.config.dtype
+
+    @scoped("block")
+    def _mixed_block_fn(self, documents, carry, layer, kind):
+        """One block of a mixed stack. ``kind``: `run_plan`'s ``(window, rope,
+        mixer, hands)``; ``layer``: ``(block, keep, lambda_init)`` and, for a
+        memory unit or a cross layer, what it reads of an earlier layer. ->
+        (carry, what this layer hands on or None)."""
+        window, rope, mixer, hands = kind
+        block, keep, lam_init, *shared = layer
+        x, positions, aux_acc = carry
+        c = self.config
+        add = self._add_fp32 if c.residual_fp32 else (lambda x, y: x + y)
+        if shared:
+            with jax.named_scope("attn"), jax.named_scope("shared"):
+                shared = jax.tree.map(lambda t: t.astype(c.dtype), shared[0])
+        h1 = self._block_layers["ln_1"](block["ln_1"], x)
+        if mixer == "ssm":
+            out, handed = self._scan_mixer(block, h1, documents)
+        elif mixer == "gmu":
+            out, handed = self._memory_unit(block, h1, shared), None
+        else:
+            out, handed = self._mixer_attn(
+                block, h1, positions, documents, window, rope, lam_init,
+                kv=shared if mixer == "cross" else None)
+        if hands is None:
+            handed = None
+        else:
+            with jax.named_scope("attn"), jax.named_scope("shared"):
+                handed = jax.tree.map(lambda t: t.astype(self._shared_dtype(hands)), handed)
+        x = add(x, keep * out)
+        mlp_out, aux, _ = self._mlp(block, self._block_layers["ln_2"](block["ln_2"], x))
+        x = _c(add(x, keep * mlp_out), ACT_SPEC)
+        return (x, positions, aux_acc + keep * aux), handed
+
+    @functools.cached_property
+    def run_plan(self) -> Tuple[Tuple[Tuple[Any, ...], int], ...]:
+        """How a mixed stack's layers run, from their static kinds ``(window,
+        rope, mixer, hands)``: consecutive RUNS ``(unit, repeats)``, each the
+        longest stretch from where the last ended that is some unit of kinds
+        repeated at least twice (the shortest such unit), which ``lax.scan``
+        runs; layers that repeat nothing join into one run of one repeat, run a
+        block at a time. A decoder-hybrid-decoder stack of 32 layers: ``(scan,
+        window) x 8``, ``(scan*, full) x 1``, ``(memory unit, cross) x 7``."""
+        kinds = tuple(k + m for k, m in zip(self._kinds, self._mixers))
+        runs, i, n = [], 0, len(kinds)
+        while i < n:
+            p, r = 1, 1
+            for unit in range(1, (n - i) // 2 + 1):
+                times = 1
+                while (kinds[i + times * unit:i + (times + 1) * unit]
+                       == kinds[i:i + unit]):
+                    times += 1
+                if times >= 2 and times * unit > p * r:
+                    p, r = unit, times
+            if r == 1 and runs and runs[-1][1] == 1:
+                runs[-1] = (runs[-1][0] + kinds[i:i + 1], 1)
+            else:
+                runs.append((kinds[i:i + p], r))
+            i += p * r
+        return tuple(runs)
+
+    def _scan_runs(self, init, runs: Params, keep: jax.Array, block_of, shapes_only=False):
+        """A mixed stack's layers as `run_plan` lays them out over ``runs``
+        (``params["runs"]``) -> the last carry. What the boundary layers hand on
+        is a value the later blocks take as an argument (a scan's body closes
+        over it: kept once, its cotangent summed over the readers in its own,
+        float32, dtype). ``shapes_only``: reckon every kind of block for the
+        remat budget (`_KeptBlock.reckon`) and run nothing."""
+        c = self.config
+        lam_init = jnp.asarray([0.8 - 0.6 * math.exp(-0.3 * l)
+                                for l in range(c.num_layers)], jnp.float32)
+        one = lambda tree: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), tree)
+        shared: Dict[str, Any] = {}
+        carry, at = init, 0
+        if shapes_only:
+            B, S = init[0].shape[:2]
+            kv = jax.ShapeDtypeStruct((B, S, c.kv_heads, c.head_dim), self._shared_dtype("kv"))
+            shared = {"kv": (kv, kv), "memory": jax.ShapeDtypeStruct(
+                (B, S, c.ssm_inner), self._shared_dtype("memory"))}
+        reads = {"gmu": "memory", "cross": "kv"}
+        for i, (unit, repeats) in enumerate(self.run_plan):
+            p = len(unit)
+            stacks = [runs[str(i)][str(j)] for j in range(p)]
+            gates = [(keep[at + j:at + p * repeats:p], lam_init[at + j:at + p * repeats:p])
+                     for j in range(p)]
+            args = lambda kind: ((shared[reads[kind[2]]],) if kind[2] in reads else ())
+            if shapes_only:
+                for j, kind in enumerate(unit):
+                    block_of(kind).reckon(init, one((stacks[j],) + gates[j]) + args(kind))
+            elif repeats == 1:
+                for j, kind in enumerate(unit):
+                    layer = jax.tree.map(lambda a: a[0], (stacks[j],) + gates[j])
+                    carry, handed = block_of(kind)(carry, layer + args(kind))
+                    if kind[3] is not None:
+                        shared[kind[3]] = handed
+            else:
+                if any(kind[3] is not None for kind in unit):
+                    raise NotImplementedError("a layer that hands its tensors on repeats")
+                fns = [block_of(kind) for kind in unit]
+                extra = [args(kind) for kind in unit]
+
+                def unit_fn(carry, xs_unit):
+                    for fn, layer, more in zip(fns, xs_unit, extra):
+                        carry, _ = fn(carry, layer + more)
+                    return carry, None
+
+                carry, _ = jax.lax.scan(
+                    unit_fn, carry, [(stacks[j],) + gates[j] for j in range(p)])
+            at += p * repeats
+        return carry
 
     @property
     def moe_path(self) -> Optional[str]:
@@ -1992,8 +2446,11 @@ class TransformerLM:
         each run again in the backward, so one counts)."""
         if remat_budget is not None:
             c = self.config
+            slices = head_slices(self.config, remat_budget, input_ids)
             remat_budget.outside_bytes = (2 * 4 * input_ids.size * c.vocab_size
-                                          * c.pred_heads)
+                                          * c.pred_heads) // slices
+            if slices > 1:
+                remat_budget.totals["head_row_slices"] = slices
 
     def _trunk(self, params, input_ids, layer_mask, token_type_ids,
                attention_mask, remat_budget, with_mtp: bool,
@@ -2033,13 +2490,14 @@ class TransformerLM:
                 raise ValueError("packed documents (document_separator) take "
                                  "no attention_mask: a row is full")
             attention_mask = documents = self._documents(input_ids)
-        block_fn = functools.partial(self._block_fn, attention_mask)
+        block_fn = functools.partial(
+            self._mixed_block_fn if c.mixed else self._block_fn, attention_mask)
         if layer_mask is None:
             keep = jnp.ones((c.num_layers,), c.dtype)
         else:
             keep = layer_mask.astype(c.dtype)
         dense = c.first_dense_layers
-        xs = (params["blocks"], keep[dense:])
+        xs = None if c.mixed else (params["blocks"], keep[dense:])
         # FarSkip carries two streams: (r_(i-1), r_(i-2)), r_(-1) = r_0;
         # hyper-connections n, which start as n copies of the embedding
         init = ((x, x) if c.farskip else self._hc_start(x), positions, self._aux_zero())
@@ -2056,12 +2514,23 @@ class TransformerLM:
                     fn, c.remat_policy, blocks_in_all, remat_budget) if c.remat else fn
             return blocks[kind]
 
-        unit, _, tail = self.scan_plan
+        unit, _, tail = ((), 0, ()) if c.mixed else self.scan_plan
+        if c.mixed and remat_budget is not None:
+            # what outlives its layer and is never made again: the keys, the
+            # values and the scan output handed on, and their cotangents
+            B, S = input_ids.shape
+            remat_budget.outside_bytes += B * S * sum(
+                width * (jnp.dtype(self._shared_dtype(what)).itemsize + 4)
+                for what, width in (("kv", 2 * c.kv_heads * c.head_dim),
+                                    ("memory", c.ssm_inner))
+                if any(hands == what for _, hands in self._mixers))
         if c.remat and c.remat_policy == KEEP_PRODUCTS:
             # every kind of block is reckoned before the first is decided:
             # they run one after another, so each is held to the largest
             one = lambda tree: jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), tree)
+            if c.mixed:
+                self._scan_runs(init, params["runs"], keep, block_of, shapes_only=True)
             for i in range(dense):
                 block_of(self._kinds[i]).reckon(
                     init, one((params["dense_blocks"], keep[:1])))
@@ -2100,6 +2569,8 @@ class TransformerLM:
                 (x, _, aux), _ = ck_fn(
                     (x, positions, aux),
                     jax.tree.map(lambda a: a[-1], xs))
+        elif c.mixed:
+            x, _, aux = self._scan_runs(init, params["runs"], keep, block_of)
         else:
             (x, _, aux), rows = self._scan_by_kind(init, xs, block_of)
         if c.farskip:
@@ -2139,6 +2610,12 @@ class TransformerLM:
             stats = {**stats, "attn_hc_res_row_err": aux[1]}
         if c.document_separator is not None:
             stats = {**stats, **self._attn_tile_stats(documents)}
+        if c.ssm_state:
+            # (``engine.attn_last_step()["ssm_resets"]``: the times a scan layer's
+            # state started anew in the step's rows, a row's start or a document's)
+            stats = {**stats, "attn_ssm_resets": jnp.sum(self._first_of_document(
+                attention_mask if c.document_separator is not None else None,
+                input_ids.shape), dtype=jnp.int32)}
         if c.indexer is not None:
             # selected over visible pairs, from the documents alone: a query
             # with v visible keys picks min(v, topk) of them in every layer
@@ -2253,9 +2730,14 @@ class TransformerLM:
             mask = dict(selected=c.indexer.topk)
         elif self._mla_widths:
             mask["v_dim"] = c.v_head_dim
+        heads, kv_heads = c.num_heads, c.kv_heads
+        if c.differential_attention:
+            # ONE half's launch: half the heads, the pair's two value heads wide
+            heads, kv_heads = heads // 2, kv_heads // 2
+            mask.update(v_dim=2 * c.head_dim, tag="diff")
         return attention.plan(
-            (batch, seq * self.rows_per_token, c.num_heads, c.head_dim),
-            (batch, seq, c.kv_heads, c.head_dim), jax.default_backend(),
+            (batch, seq * self.rows_per_token, heads, c.head_dim),
+            (batch, seq, kv_heads, c.head_dim), jax.default_backend(),
             attention.attn_mode() if mode is None else mode,
             jnp.dtype(c.dtype).itemsize, **mask)
 
@@ -2268,9 +2750,13 @@ class TransformerLM:
         its backward makes dq and where its launches take the operands' heads
         (``layout``), off the launches' own plans."""
         c = self.config
-        windows = sorted({w for w, _ in self._kinds if w})
-        layers = {"window": sum(1 for w, _ in self._kinds if w),
-                  "full": sum(1 for w, _ in self._kinds if not w)}
+        # (a mixed stack: its attention layers alone)
+        attending = [w for (w, _), (mixer, _) in zip(
+            self._kinds, self._mixers or [("attn", None)] * c.num_layers)
+            if mixer in ("attn", "cross")]
+        windows = sorted({w for w in attending if w})
+        layers = {"window": sum(1 for w in attending if w),
+                  "full": sum(1 for w in attending if not w)}
         attn = {"layers_window": layers["window"], "layers_full": layers["full"],
                 "window": windows[0] if len(windows) == 1 else (windows or None),
                 "kv_heads": c.kv_heads,
@@ -2301,12 +2787,33 @@ class TransformerLM:
                           "sinkhorn_iters": c.hc_sinkhorn_iters,
                           "sublayers": 2 * (c.num_layers + c.mtp_layers),
                           "route": None, "tile_rows": None}
+        if c.differential_attention:
+            attn["diff"] = {"qk_dim": c.head_dim, "v_dim": 2 * c.head_dim,
+                            "launches_a_layer": 2,
+                            "shared_readers": self._mixer_count("cross")}
+        if c.ssm_state:
+            # (the scan layers' record rides here: an engine copies this dict whole)
+            attn["ssm"] = {"layers": self._mixer_count("ssm"),
+                           "memory_units": self._mixer_count("gmu"),
+                           "d_inner": c.ssm_inner, "d_state": c.ssm_state,
+                           "conv": c.ssm_conv, "dt_rank": c.ssm_rank,
+                           "route": None, "chunk": None, "tile": None}
         diffusion = {"block_length": c.block_length, "rows_per_token": self.rows_per_token,
                      "route": None, "dq": None, "layout": None} if c.diffusion else None
         if seq is None:
             return attn, diffusion
         plans = {w: self._attention_plan(batch, seq, w) for w in [0] + windows}
-        tag = "mla" if self._mla_widths else "flash"
+        tag = ("mla" if self._mla_widths else "diff" if c.differential_attention
+               else "flash")
+        if c.ssm_state:
+            from ..ops.transformer import pallas_scan
+            route = pallas_scan.choose_route(batch * seq, c.ssm_inner, c.ssm_state,
+                                             jax.default_backend(), self._devices())
+            kernel = route == "kernel"
+            attn["ssm"].update(
+                route=route, chunk=pallas_scan.CHUNK if kernel else pallas_scan.XLA_CHUNK,
+                tile=pallas_scan.choose_tile(c.ssm_inner, pallas_scan.CHUNK, c.ssm_state)
+                if kernel else None)
         if c.residual_streams > 1:
             route, tile_rows = self._hc_route(seq * self.rows_per_token, c.dtype)
             attn["hc"].update(route=route, tile_rows=tile_rows)
@@ -2405,7 +2912,7 @@ class TransformerLM:
         return (self.moe_path == "dropless" or self.config.diffusion
                 or self.config.document_separator is not None
                 or self.config.indexer is not None
-                or self.config.residual_streams > 1)
+                or self.config.residual_streams > 1 or bool(self.config.ssm_state))
 
     @functools.cached_property
     def scan_plan(self) -> Tuple[Tuple[Any, ...], int, Tuple[Any, ...]]:
@@ -2483,11 +2990,38 @@ class TransformerLM:
                        constant_values=-100)
 
     def head_loss(self, params: Params, x: jax.Array, labels: jax.Array,
-                  extra_mask: Optional[jax.Array] = None) -> jax.Array:
+                  extra_mask: Optional[jax.Array] = None, slices: int = 1) -> jax.Array:
         """Final norm + LM/MLM head + masked cross-entropy over the last
-        block's output (the differentiated tail of the overlap schedule)."""
-        return next_token_cross_entropy(self.config, self.head(params, x), labels,
-                                        extra_mask)
+        block's output (the differentiated tail of the overlap schedule).
+        ``slices`` > 1 (`head_row_slices`): the same sum over slices of the
+        rows, a slice's logits made again in its backward, so that the float32
+        logits of all the rows are never held; the head's matrix and the final
+        norm enter the slices in float32 (cast down inside a slice), so their
+        gradients are summed over the slices in float32."""
+        if slices == 1:
+            return next_token_cross_entropy(self.config, self.head(params, x), labels,
+                                            extra_mask)
+        B, S, H = x.shape
+        f32 = jnp.float32
+        head_keys = ("ln_f", "wte" if self.config.tie_embeddings else "lm_head")
+        wide = jax.tree.map(lambda a: a.astype(f32), {k: params[k] for k in head_keys})
+        valid = labels >= 0
+        mask = valid.astype(f32) * (1.0 if extra_mask is None else extra_mask.astype(f32))
+        by_slice = lambda a: a.reshape((slices, B * S // slices) + a.shape[2:])
+
+        def one(total, xs):
+            xb, targets, weights = xs
+            narrow = jax.tree.map(lambda a, like: a.astype(like.dtype), wide,
+                                  {k: params[k] for k in head_keys})
+            logits = self.head(narrow, xb[None])[0]
+            with jax.named_scope("loss"):
+                return total + jnp.sum(_token_nll(logits, targets) * weights), None
+
+        with jax.named_scope("head"):
+            total, _ = jax.lax.scan(
+                jax.checkpoint(one), jnp.zeros((), f32),
+                (by_slice(x), by_slice(jnp.where(valid, labels, 0)), by_slice(mask)))
+        return total / jnp.maximum(jnp.sum(mask), 1.0)
 
     def combine_aux(self, loss: jax.Array, aux: jax.Array) -> jax.Array:
         """Fold the accumulated MoE aux loss into the objective: each
@@ -2526,9 +3060,11 @@ class TransformerLM:
         alone where there is no prediction module, no noise and no rope by
         sections (``PipelineModule`` borrows it)."""
         c = self.config
-        if c.mtp_layers or c.diffusion or c.rope_sections is not None:
+        if (c.mtp_layers or c.diffusion or c.rope_sections is not None
+                or head_slices(self.config, remat_budget, batch["input_ids"]) > 1):
             # (three position streams are a batch's, which `apply` as
-            # ``PipelineModule`` has it does not take)
+            # ``PipelineModule`` has it does not take; a head over slices of
+            # the rows is `head_loss`'s)
             return self.loss_and_stats(params, batch, remat_budget)[0]
         labels = self.derive_labels(batch)
         logits, aux = self.apply(params, batch["input_ids"],
@@ -2558,7 +3094,8 @@ class TransformerLM:
             batch.get("token_type_ids"), batch.get("attention_mask"),
             remat_budget, with_mtp=True, position_ids=batch.get("position_ids"))
         if mtp_x is None:
-            loss = self.head_loss(params, x, labels, extra_mask=mask)
+            loss = self.head_loss(params, x, labels, extra_mask=mask,
+                                  slices=head_slices(self.config, remat_budget, batch["input_ids"]))
             if c.indexer is not None:       # the two terms of L_LM + L_I, reported
                 stats = {**stats, "attn_lm_loss": loss, "attn_indexer_kl": aux[1]}
             return self.combine_aux(loss, aux), stats

@@ -203,6 +203,38 @@ class HyperConnection:
         return {"phi": P(None, None), "bias": P(None), "alpha": P(None)}
 
 
+@dataclasses.dataclass(frozen=True)
+class ScanParams:
+    """A selective-scan layer's small arrays (Mamba-1, arXiv:2312.00752; the
+    caller reads them itself): the depthwise convolution's ``conv`` ``[taps,
+    channels]`` (tap k multiplies the input ``taps - 1 - k`` tokens back) and
+    ``conv_bias``, ``A_log`` ``[channels, states]`` (``A = -exp(A_log)``, a
+    channel's states starting at -1 .. -states), ``D`` (ones) and ``dt_bias``
+    (the inverse softplus of a log-uniform draw in [1e-3, 1e-1]): the
+    published initialisers."""
+    channels: int
+    states: int
+    taps: int = 4
+
+    def init(self, rng, dtype=jnp.float32) -> Params:
+        k_conv, k_dt = jax.random.split(rng)
+        bound = self.taps ** -0.5
+        dt = jnp.exp(jax.random.uniform(k_dt, (self.channels,), jnp.float32)
+                     * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+        return {
+            "conv": jax.random.uniform(k_conv, (self.taps, self.channels), jnp.float32,
+                                       -bound, bound).astype(dtype),
+            "conv_bias": jnp.zeros((self.channels,), dtype),
+            "A_log": jnp.broadcast_to(jnp.log(jnp.arange(1, self.states + 1, dtype=jnp.float32)),
+                                      (self.channels, self.states)).astype(dtype),
+            "D": jnp.ones((self.channels,), dtype),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)}
+
+    def specs(self) -> Params:
+        return {"conv": P(None, MODEL_AXIS), "conv_bias": P(MODEL_AXIS),
+                "A_log": P(MODEL_AXIS, None), "D": P(MODEL_AXIS), "dt_bias": P(MODEL_AXIS)}
+
+
 def gelu(x: jax.Array) -> jax.Array:
     return jax.nn.gelu(x, approximate=True)
 

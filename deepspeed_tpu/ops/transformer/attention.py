@@ -271,7 +271,8 @@ class Plan(NamedTuple):
 def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
          causal: bool = True, window=None, blockdiff: Optional[int] = None,
          eva: Optional[Tuple[int, int]] = None,
-         selected: Optional[int] = None, v_dim: Optional[int] = None) -> Plan:
+         selected: Optional[int] = None, v_dim: Optional[int] = None,
+         tag: Optional[str] = None) -> Plan:
     """THE decision of the four entry points, a pure function of the two
     shapes, the platform, `attn_mode`'s value, the operands' size and the
     mask, which says what the kernel route would launch, each launch with its
@@ -280,7 +281,8 @@ def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
     - `flash_attention` (``causal``, ``window``): one launch, its grids cut to
       a window that is static; with ``v_dim`` (the values' width where it is
       not the keys': ``q_shape`` and ``k_shape`` hold the keys') the two-width
-      launch, tagged ``"mla"``;
+      launch, tagged ``"mla"`` (or ``tag``: ``"diff"``, differential attention's
+      pair of value heads side by side);
     - `blockdiff_attention` (``blockdiff``: the block length; ``q_shape`` holds
       both copies' ``2 L`` rows, ``k_shape`` the clean copy's ``L``): one
       launch of both copies' queries over the clean keys;
@@ -313,7 +315,7 @@ def plan(q_shape, k_shape, backend: str, mode: str, itemsize: int = 2, *,
     else:
         kind = dict(causal=causal, window=_pf.static_window(window, sq, sk))
         launches = [("flash", sq, sk, kind) if v_dim is None
-                    else ("mla", sq, sk, dict(kind, v_dim=v_dim))]
+                    else (tag or "mla", sq, sk, dict(kind, v_dim=v_dim))]
     compiled = backend != "cpu"
     min_rows = FLASH_MIN_SEQ_WIDE_HEAD if q_shape[3] >= 128 else FLASH_MIN_SEQ
     if mode == "pallas" or (mode == "" and backend == "tpu" and rows >= min_rows):
@@ -340,7 +342,8 @@ def flash_attention(q: jax.Array,
                     scale: Optional[float] = None,
                     segment_ids: Optional[jax.Array] = None,
                     alibi_slopes: Optional[jax.Array] = None,
-                    window: Optional[jax.Array] = None) -> jax.Array:
+                    window: Optional[jax.Array] = None,
+                    tag: Optional[str] = None) -> jax.Array:
     """Multi-head attention, [B, S, H, D] layout, GQA-aware.
 
     On a TPU, every shape where the chip showed it faster
@@ -357,12 +360,13 @@ def flash_attention(q: jax.Array,
     ``window`` (0 = global) is the causal sliding window: a Python int is
     static, and the kernel's grids are then cut to it (a sliding layer
     fetches and multiplies a window's worth of keys); a traced scalar masks
-    and skips inside whole-sequence grids.
+    and skips inside whole-sequence grids. ``tag``: the name of a two-width
+    launch (values of another width than the keys; ``pallas_flash.TAGS``).
     """
     mode = attn_mode()
     made = plan(q.shape, k.shape, jax.default_backend(), mode, q.dtype.itemsize,
                 causal=causal, window=window,
-                v_dim=None if v.shape[3] == q.shape[3] else v.shape[3])
+                v_dim=None if v.shape[3] == q.shape[3] else v.shape[3], tag=tag)
     route = made.route
     if route == "kernel":
         from . import pallas_flash as _pf
@@ -376,7 +380,7 @@ def flash_attention(q: jax.Array,
         return _pf.flash_attention_kernel(
             q, k, v, causal=causal, scale=scale,
             segment_ids=segment_ids, alibi_slopes=alibi_slopes,
-            window=window, layout=at.layout)
+            window=window, layout=at.layout, tag=tag)
     if mode == "pallas":
         # an explicit DSTPU_ATTN=pallas that cannot be honored must
         # not pass silently (round-1 review: perf regressions hide in

@@ -496,8 +496,10 @@ def _head_index(row: "_Row", g, G):
 
 
 #: the tags a caller may give a launch (``FlashConfig.tag``): EVA's two, and
-#: the two-width launch of latent attention (``FlashConfig.v_dim``)
-TAGS = ("eva_local", "eva_far", "mla")
+#: the two-width launch (``FlashConfig.v_dim``) of latent attention (``"mla"``:
+#: keys wider than the values) and of differential attention (``"diff"``: a
+#: pair's two value heads side by side, twice the keys' width)
+TAGS = ("eva_local", "eva_far", "mla", "diff")
 
 
 def _compiler_params(cfg: FlashConfig, semantics):
@@ -779,6 +781,8 @@ def _fwd_call(cfg: FlashConfig, q, k, v, qseg_c, kseg_r, table, slopes, info,
         name=("flash_fwd_eva_local" if cfg.tag == "eva_local"
               else "flash_fwd_eva_far" if cfg.tag == "eva_far"
               else "flash_fwd_mla" if cfg.tag == "mla"
+              else ("flash_fwd_diff" if cfg.window is None
+                    else "flash_fwd_diff_window") if cfg.tag == "diff"
               else "flash_fwd_dsa" if cfg.selected
               else "flash_fwd_blockdiff" if cfg.blockdiff is not None
               else "flash_fwd" if cfg.window is None else "flash_fwd_window"),
@@ -1124,6 +1128,8 @@ def _bwd_call(cfg: FlashConfig, q, k, v, kseg_c, qseg_r, table, slopes, info,
         name=("flash_bwd_eva_local" if cfg.tag == "eva_local"
               else "flash_bwd_eva_far" if cfg.tag == "eva_far"
               else "flash_bwd_mla" if cfg.tag == "mla"
+              else ("flash_bwd_diff" if W is None
+                    else "flash_bwd_diff_window") if cfg.tag == "diff"
               else "flash_bwd_dsa" if cfg.selected
               else "flash_bwd_blockdiff" if cfg.blockdiff is not None
               else "flash_bwd" if W is None else "flash_bwd_window"),
@@ -1469,10 +1475,10 @@ def _prepare(q, k, v, causal, scale, segment_ids, q_segment_ids,
     # a value width of its own makes the launch the two-width one
     v_dim = None if v.shape[3] == D else v.shape[3]
     if v_dim is not None:
-        if tag not in (None, "mla") or k.shape[3] != D:
+        if tag not in (None, "mla", "diff") or k.shape[3] != D:
             raise ValueError(f"values of {v.shape[3]} beside keys of {k.shape[3]} and "
-                             f"queries of {D}: the two-width launch is tagged 'mla'")
-        tag = "mla"
+                             f"queries of {D}: the two-width launch is tagged 'mla' or 'diff'")
+        tag = tag or "mla"
     if H % kvH:
         raise ValueError(f"query heads {H} not a multiple of kv heads {kvH}")
     G = H // kvH
@@ -1661,17 +1667,17 @@ def flash_attention_kernel(
         q_offset=None, block_q: Optional[int] = None,
         block_k: Optional[int] = None,
         interpret: Optional[bool] = None,
-        layout: Optional[str] = None) -> jax.Array:
+        layout: Optional[str] = None, tag: Optional[str] = None) -> jax.Array:
     """Flash attention, ``[B, S, H, D]`` in and out — the drop-in training
     kernel `attention.flash_attention` dispatches to at long sequence. q and
     the result go to and from the launches in ``layout``
     (`flash_attention_with_lse`: at a head dim of whole lane tiles as they are,
-    no transpose either way)."""
+    no transpose either way). ``tag``: a two-width launch's name (`TAGS`)."""
     out, _ = flash_attention_with_lse(
         q, k, v, causal=causal, scale=scale, segment_ids=segment_ids,
         q_segment_ids=q_segment_ids, alibi_slopes=alibi_slopes,
         window=window, q_offset=q_offset, block_q=block_q, block_k=block_k,
-        interpret=interpret, layout=layout)
+        interpret=interpret, layout=layout, tag=tag)
     return out
 
 

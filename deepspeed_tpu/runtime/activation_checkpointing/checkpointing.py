@@ -206,12 +206,27 @@ def resolve_policy(name: Optional[str]):
 #: again in the backward (arXiv:2512.24880's own choice), and the carry the
 #: budget is charged for every layer is the block's real one, the n streams
 #: (``_bytes(carry)``: 28,672 B a token at n = 4 x 3584 in bfloat16).
+#: A scan layer's values (PR 57) stand where what a kept byte spares puts them,
+#: reckoned and not measured one by one: ``ssm_m`` and ``ssm_state``, the scan
+#: kernel's result and its chunks' entry states (10,240 + 2,560 B a token at 5120
+#: channels of 16 states in chunks of 128; made again they cost the whole
+#: forward scan, vector-unit work the matrix unit cannot hide), lead as the flash
+#: pairs do, and differential attention's launches name their pair after their
+#: tag (``attn_lse_diff`` / ``attn_o_diff``: two launches a layer share the
+#: names, so the budget reckons one launch's bytes for both, PR 48's rule, and
+#: the pair is kept or made again together); ``ssm_in`` and ``gmu_in`` are
+#: products over the hidden size like the MLP's first and join ``o_proj``'s
+#: neighbourhood; ``kv_proj`` (a mixed stack's fused key and value projection)
+#: stands in front of ``k_proj``'s group, and ``ssm_dt`` (contracted over dt's
+#: rank, 160) and ``ssm_x`` (192 wide), cheap to make again, beside it.
 SAVE_ORDER = (("indexer_kl_dq", "indexer_kl_dk", "indexer_kl_dw"), ("dsa_mask",), ("attn_lse_dsa", "attn_o_dsa"),
               ("attn_lse", "attn_o"), ("attn_lse_mla", "attn_o_mla"),
+              ("attn_lse_diff", "attn_o_diff"), ("ssm_m", "ssm_state"),
               ("eva_kbar", "eva_vbar"),
               ("moe_logits",), ("wi_gate", "wi_up"),
               ("wi",), ("wo",), ("fc_in",), ("gate_proj", "up_proj"),
-              ("o_proj",), ("attn_gate",),
+              ("o_proj",), ("attn_gate",), ("ssm_in", "gmu_in"),
+              ("kv_proj",), ("ssm_dt", "ssm_x"),
               ("q_proj", "k_proj", "v_proj", "kv_latent", "q_latent", "q_b_proj",
                "indexer_q", "indexer_k"),
               ("kv_up",))

@@ -52,6 +52,9 @@ USES = {
     "q_latent_rank": dict(attention="latent", kv_latent_rank=8, q_latent_rank=4, qk_nope_dim=6,
                           qk_rope_dim=2, v_head_dim=8),
     "residual_streams": dict(residual_streams=2, hc_sinkhorn_iters=3),
+    "ssm_state": dict(ssm_state=4, ssm_dt_rank=2),
+    "differential_attention": dict(differential_attention=True),
+    "shared_from": dict(num_layers=4, ssm_state=4, ssm_dt_rank=2, shared_from=0),
     "first_dense_layers": dict(first_dense_layers=1, dense_intermediate_size=32, moe=EXPERTS),
     "mtp_layers": dict(mtp_layers=1),
     "objective='block_diffusion'": dict(objective="block_diffusion", block_length=4,
@@ -68,7 +71,8 @@ def _one_block_at_a_time(model, params):
     x, positions = model.embed(params, IDS)
     for i in range(model.config.num_layers):
         window = None if model._windows is None else jnp.asarray(model._windows[i])
-        x, _ = model.block_apply(jax.tree.map(lambda a: a[i], params["blocks"]),
+        # (a mixed stack has no ``blocks``: the consumer refuses before it reads one)
+        x, _ = model.block_apply(jax.tree.map(lambda a: a[i], params.get("blocks")),
                                  x, positions, window=window)
     return model.head(params, x)
 
@@ -145,6 +149,7 @@ PLAIN = {
     "eva_window", "eva_chunk", "kv_latent_rank", "qk_nope_dim", "qk_rope_dim", "v_head_dim",
     "dense_intermediate_size", "mtp_loss_coef", "block_length", "mask_token_id", "noise_seed",
     "hc_sinkhorn_iters", "hc_eps", "hc_res_clamp",
+    "ssm_conv", "ssm_expand", "ssm_dt_rank", "ssm_period",
 }
 
 

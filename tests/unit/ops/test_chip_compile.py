@@ -118,6 +118,50 @@ class TestCompilesForV5e:
         text = jax.jit(fn).lower(*args).as_text()
         assert "flash_fwd_mla" in text and "flash_bwd_mla" in text
 
+    @pytest.mark.parametrize("window", [None, 512], ids=["full", "window"])
+    def test_flash_fwd_bwd_keys_of_64_values_of_128_at_16k(self, chip, window):
+        """The phi4-mini-flash-reasoning cell's attention core (PR 57): ONE half
+        of a layer's pairs, 20 query heads over 10 key heads of 64 (half a lane
+        tile) with the pair's two value heads side by side (128), at 16,384 with
+        segment ids, under the static window and without: the two-width launches
+        under their own tag."""
+        from deepspeed_tpu.ops.transformer.pallas_flash import \
+            flash_attention_kernel
+        B, S, H, kvH, D, Dv = 1, 16384, 20, 10, 64, 128
+
+        def loss(q, k, v, seg):
+            return jnp.sum(flash_attention_kernel(
+                q, k, v, causal=True, segment_ids=seg, window=window, tag="diff",
+                interpret=False).astype(F32))
+
+        fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
+        args = (chip((B, S, H, D), BF16), chip((B, S, kvH, D), BF16),
+                chip((B, S, kvH, Dv), BF16), chip((B, S), I32))
+        compile_for_chip(fn, *args)
+        text = jax.jit(fn).lower(*args).as_text()
+        name = "diff" if window is None else "diff_window"
+        assert f"flash_fwd_{name}" in text and f"flash_bwd_{name}" in text
+
+    def test_selective_scan_fwd_bwd_at_16k(self, chip):
+        """The phi4-mini-flash-reasoning cell's scan (PR 57): 16,384 rows of 5120
+        channels of 16 states in chunks of 128 and tiles of 512, forward and
+        backward, and no value of rows x channels x states in the program."""
+        from deepspeed_tpu.ops.transformer import pallas_scan
+        R, Di, N = 16384, 5120, 16
+
+        def loss(a, dt_raw, A, B, C, D, dt_bias, first):
+            return jnp.sum(pallas_scan.scan_kernel(
+                a, dt_raw, A, B, C, D, dt_bias, first, interpret=False).astype(F32))
+
+        fn = jax.value_and_grad(loss, argnums=tuple(range(7)))
+        args = (chip((R, Di), BF16), chip((R, Di), BF16), chip((Di, N), F32),
+                chip((R, N), BF16), chip((R, N), BF16), chip((Di,), F32),
+                chip((Di,), F32), chip((R,), I32))
+        compile_for_chip(fn, *args)
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+        assert "[16384,5120,16]" not in text and "[16384,16,5120]" not in text
+
     def test_blockdiff_attention_at_8k(self, chip, monkeypatch):
         """The sdar-30b-a3b cell's attention core: 32 query heads over 4 key
         heads of 128, 16,384 rows (a clean and a noised copy of 8,192

@@ -150,6 +150,13 @@ KERNEL_NAMES = {
     # the flash pair whose values have a width of their own (PR 55: latent
     # attention's 192 / 128; ``FlashConfig.v_dim``, tag ``mla``)
     "flash_fwd_mla", "flash_bwd_mla",
+    # the two-width pair of differential attention (PR 57: keys of 64, a pair's
+    # two value heads side by side; tag ``diff``), over whole documents and
+    # with its grids cut to a static window
+    "flash_fwd_diff", "flash_bwd_diff", "flash_fwd_diff_window", "flash_bwd_diff_window",
+    # the selective scan a chunk at a time (PR 57; ``pallas_scan``), under
+    # ``ssm/scan``: ``ssm_scan_roofline`` finds the launches by these names
+    "ssm_scan_fwd", "ssm_scan_bwd",
     # ``dO x O``'s row sum for a launch whose operands lie by rows (PR 51): NOT
     # ``flash_bwd*``, whose readers sum the backward launches alone
     "flash_delta",
@@ -188,9 +195,9 @@ def test_every_pallas_call_has_a_name(site):
 
 
 def test_kernel_names_are_distinct_and_complete():
-    assert len(PALLAS_SITES) == 23
+    assert len(PALLAS_SITES) == 25
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 35
+    assert len(set(names)) == len(names) == 41
     assert set(names) == KERNEL_NAMES
 
 

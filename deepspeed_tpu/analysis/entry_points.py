@@ -354,7 +354,7 @@ def build_flash_kernel() -> EntrySpec:
     here: a regression that bakes either into the kernel's static
     configuration cannot concretize a tracer and surfaces as a hard
     trace-failed finding (and the numerics side is pinned by
-    tests/unit/ops/test_pallas_flash.py::test_traced_q_offset_and_window,
+    tests/unit/ops/test_pallas_flash_parity.py::test_traced_q_offset_and_window,
     which feeds one jitted trace multiple values)."""
     import jax.numpy as jnp
     from deepspeed_tpu.ops.transformer.pallas_flash import \

@@ -48,12 +48,21 @@ def parts(cell):
     return ref, adapter, cell.config, w, jnp.asarray(ids, jnp.int32)
 
 
+@pytest.fixture(scope="module")
+def blocked(parts):
+    """The reference in blocks (its own ``loss_and_gradient``: what runs at
+    16,384) over the module's rows, ONE jitted program for the two tests that
+    read it."""
+    ref, _, cfg, w, ids = parts
+    return jax.jit(lambda p: ref.loss_and_gradient(p, ids, cfg))(w)
+
+
 def close(a, b, rel=2e-4):
     a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
     return np.abs(a - b).max() <= rel * max(np.abs(b).max(), 1e-12)
 
 
-def test_loss_and_gradient_match_the_reference(parts):
+def test_loss_and_gradient_match_the_reference(parts, blocked):
     """float32 against float32 at ``highest``: the loss to 1e-5 (one
     reduction order apart), every gradient to 2e-4 of its largest element
     (the sandwich norms divide by a branch's own RMS, which amplifies a
@@ -61,24 +70,24 @@ def test_loss_and_gradient_match_the_reference(parts):
     ref, adapter, cfg, w, ids = parts
     model = adapter.model(cfg, remat=True, dtype="float32")
     assert model.scan_plan == ((S, F, S), 1, (S,)) and model._kinds[:2] == (S, S)
-    want, want_g = jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg))(w)
+    # (each side ONE jitted program: op by op this test compiled 1,341 of them)
+    want, want_g = jax.jit(jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg)))(w)
     with jax.default_matmul_precision("highest"):
-        got, got_g = jax.value_and_grad(
-            lambda p: model.loss(p, {"input_ids": ids}))(adapter.to_program(w))
-        logits, _ = model.apply(adapter.to_program(w), ids)
+        got, got_g = jax.jit(jax.value_and_grad(
+            lambda p: model.loss(p, {"input_ids": ids})))(adapter.to_program(w))
+        logits, _ = jax.jit(lambda p: model.apply(p, ids))(adapter.to_program(w))
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     flat = adapter.from_program(got_g)
     assert set(flat) == set(w)
     for name, g in want_g.items():
         assert close(flat[name], g), name
     assert not np.asarray(flat["router_bias"]).any()     # stop_gradient: exactly 0
-    assert close(logits, ref.forward(w, ids, cfg), rel=1e-4)
+    assert close(logits, jax.jit(lambda p: ref.forward(p, ids, cfg))(w), rel=1e-4)
     # the reference in blocks (what runs at 16,384) is the reference
-    blocked = ref.loss_and_gradient(w, ids, cfg)[0]
-    assert float(blocked) == pytest.approx(float(want), rel=1e-6)
+    assert float(blocked[0]) == pytest.approx(float(want), rel=1e-6)
 
 
-def test_first_step_through_initialize(parts):
+def test_first_step_through_initialize(parts, blocked):
     """``initialize`` -> ``train_batch`` in float32: the step's loss and
     gradient norm are the reference's, every weight moves against the
     reference's gradient, the router's bias moves by ``load_balance_coeff``
@@ -98,7 +107,7 @@ def test_first_step_through_initialize(parts):
                                   "layout": {"window": None, "full": None}}
     assert engine.attn_last_step() is None
     loss = float(engine.train_batch({"input_ids": np.asarray(ids)}))
-    want, gnorm, signs = ref.loss_and_gradient(w, ids, cfg)
+    want, gnorm, signs = blocked
     assert loss == pytest.approx(float(want), rel=1e-5)
     assert float(engine.get_global_grad_norm()) == pytest.approx(float(gnorm), rel=1e-4)
     new = adapter.from_program(engine.state["opt"]["master"])
@@ -246,9 +255,11 @@ def test_the_published_depth_is_seven_periods_and_a_run_of_two(parts):
     assert set(re.findall(r"attn/(core[a-z_]*)/", text)) == {"core", "core_window"}
     model_s = adapter.model(cfg, remat=False, dtype="float32")
     with jax.default_matmul_precision("highest"):
-        got = model_s.loss(adapter.to_program(w), {"input_ids": ids})
-    assert float(got) == pytest.approx(float(ref.next_token_loss(w, ids, cfg)), rel=2e-5)
-    rows = model_s.loss_and_stats(adapter.to_program(w), {"input_ids": ids})[1]
+        got, rows = jax.jit(lambda p: (model_s.loss(p, {"input_ids": ids}),
+                                       model_s.loss_and_stats(p, {"input_ids": ids})[1]))(
+            adapter.to_program(w))
+    want = jax.jit(lambda p: ref.next_token_loss(p, ids, cfg))(w)
+    assert float(got) == pytest.approx(float(want), rel=2e-5)
     assert rows["moe_expert_rows"].shape == (30, 8)
 
 
@@ -271,9 +282,12 @@ def test_what_the_configuration_maps_to_and_refuses():
     model = afmoe_model("afmoe-tiny", dtype=F32)
     assert model.has_router_bias and model.config.head_dim == 16
     # the paths that take one kind of block say which kinds they refuse
-    block = jax.tree.map(lambda a: a[0], model.init(jax.random.PRNGKey(0))["blocks"])
+    # (shapes alone: the refusal comes before a value is read)
+    block = jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a[0], model.init(jax.random.PRNGKey(0))["blocks"]))
     with pytest.raises(NotImplementedError, match="rope_layers='windowed'"):
-        model.block_apply(block, jnp.zeros((1, 8, 64)), jnp.arange(8)[None])
+        jax.eval_shape(lambda b, x: model.block_apply(b, x, jnp.arange(8)[None]),
+                       block, jnp.zeros((1, 8, 64)))
     with pytest.raises(NotImplementedError, match="alternating"):
         TransformerLM(TransformerConfig(
             num_layers=4, position="rope", norm="rmsnorm", rope_layers="windowed",

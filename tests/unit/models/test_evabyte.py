@@ -47,9 +47,17 @@ def parts(cell):
 
 @pytest.fixture(scope="module")
 def want(parts):
-    """The reference's loss and gradient on ``parts``."""
+    """The reference's loss and gradient on ``parts`` (one jitted program)."""
     ref, _, cfg, w, ids = parts
-    return jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg))(w)
+    return jax.jit(jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg)))(w)
+
+
+@pytest.fixture(scope="module")
+def layer_at_a_time(parts):
+    """The reference a layer at a time (its own ``loss_and_gradient``: what
+    runs at 32,768 rows), one jitted program for both routes."""
+    ref, _, cfg, w, ids = parts
+    return jax.jit(lambda p: ref.loss_and_gradient(p, ids, cfg))(w)
 
 
 def worst(a, b):
@@ -70,7 +78,8 @@ def program_loss_and_grad(parts, budget=None, **model_kw):
 
 
 @pytest.mark.parametrize("route", ["xla", "pallas"])
-def test_loss_and_gradient_match_the_reference(parts, want, route, monkeypatch):
+def test_loss_and_gradient_match_the_reference(parts, want, layer_at_a_time, route,
+                                               monkeypatch):
     """float32 against float32 at ``highest``, the XLA form of
     ``eva_visible`` and the two launches (interpret mode) with the merge: the
     loss to 1e-6 (one reduction order apart), every gradient to 2e-5 of its
@@ -91,7 +100,7 @@ def test_loss_and_gradient_match_the_reference(parts, want, route, monkeypatch):
     np.testing.assert_array_equal(np.asarray(ref.forward(w, ids, cfg)),
                                   np.asarray(ref.forward_heads(w, ids, cfg)[:, :, 0]))
     # the reference a layer at a time (what runs at 32,768 rows) is the reference
-    loss, gnorm, signs = ref.loss_and_gradient(w, ids, cfg)
+    loss, gnorm, signs = layer_at_a_time
     assert float(loss) == pytest.approx(float(want[0]), rel=1e-6)
     assert float(gnorm) == pytest.approx(float(jnp.sqrt(sum(
         jnp.sum(jnp.square(g)) for g in want[1].values()))), rel=1e-5)
@@ -108,8 +117,8 @@ def test_the_reference_scores_queries_in_blocks(parts, want, monkeypatch):
     ref, _, cfg, w, ids = parts
     monkeypatch.setattr(ref, "QUERY_BLOCK", 16)
     monkeypatch.setattr(ref, "TOKEN_BLOCK", 64)
-    blocked, blocked_g = jax.value_and_grad(
-        lambda p: ref.next_token_loss(p, ids, cfg, checkpoint=True))(w)
+    blocked, blocked_g = jax.jit(jax.value_and_grad(
+        lambda p: ref.next_token_loss(p, ids, cfg, checkpoint=True)))(w)
     assert float(blocked) == pytest.approx(float(want[0]), rel=1e-6)
     assert all(worst(blocked_g[k], want[1][k]) < 1e-5 for k in w)
 

@@ -51,9 +51,10 @@ def close(a, b, rel=2e-4):
 def test_loss_and_gradient_match_the_reference(parts):
     ref, adapter, cfg, w, ids = parts
     model = adapter.model(cfg, remat=True, dtype="float32")
-    want, want_g = jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg))(w)
-    got, got_g = jax.value_and_grad(
-        lambda p: model.loss(p, {"input_ids": ids}))(adapter.to_program(w))
+    # (each side ONE jitted program: op by op this is a thousand compiles)
+    want, want_g = jax.jit(jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg)))(w)
+    got, got_g = jax.jit(jax.value_and_grad(
+        lambda p: model.loss(p, {"input_ids": ids})))(adapter.to_program(w))
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     flat = adapter.from_program(got_g)
     assert set(flat) == set(w)
@@ -61,8 +62,8 @@ def test_loss_and_gradient_match_the_reference(parts):
         assert close(flat[name], g), name
     assert not np.asarray(flat["router_bias"]).any()     # stop_gradient: exactly 0
     # logits alone (no prediction module is run) agree too
-    logits, _ = model.apply(adapter.to_program(w), ids)
-    assert close(logits, ref.forward(w, ids, cfg), rel=1e-4)
+    logits, _ = jax.jit(lambda p: model.apply(p, ids))(adapter.to_program(w))
+    assert close(logits, jax.jit(lambda p: ref.forward(p, ids, cfg))(w), rel=1e-4)
 
 
 def test_first_step_through_initialize(parts):
@@ -79,7 +80,7 @@ def test_first_step_through_initialize(parts):
             "zero_optimization": {"stage": 1},
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}}})
     loss = float(engine.train_batch({"input_ids": np.asarray(ids)}))
-    want, gnorm, signs = ref.loss_and_gradient(w, ids, cfg)
+    want, gnorm, signs = jax.jit(lambda p: ref.loss_and_gradient(p, ids, cfg))(w)
     assert loss == pytest.approx(float(want), rel=1e-5)
     assert float(engine.get_global_grad_norm()) == pytest.approx(float(gnorm), rel=1e-4)
     new = adapter.from_program(engine.state["opt"]["master"])
@@ -194,10 +195,11 @@ def test_farskip_off_is_the_standard_block(parts):
     plain = dict(cfg, farskip=False)
     model = adapter.model(plain, remat=False, dtype="float32")
     assert not model.config.farskip
-    got = model.loss(adapter.to_program(w), {"input_ids": ids})
-    assert float(got) == pytest.approx(float(ref.next_token_loss(w, ids, plain)), rel=1e-5)
-    with_flag = adapter.model(cfg, remat=False, dtype="float32").loss(
-        adapter.to_program(w), {"input_ids": ids})
+    loss_of = lambda m: jax.jit(lambda p: m.loss(p, {"input_ids": ids}))(adapter.to_program(w))
+    got = loss_of(model)
+    assert float(got) == pytest.approx(
+        float(jax.jit(lambda p: ref.next_token_loss(p, ids, plain))(w)), rel=1e-5)
+    with_flag = loss_of(adapter.model(cfg, remat=False, dtype="float32"))
     assert abs(float(with_flag) - float(got)) > 1e-6
     # and by hand on one block: x + attn(norm(x)), then + mlp(norm(that))
     block = jax.tree.map(lambda a: a[0], adapter.to_program(w)["dense_blocks"])
@@ -401,9 +403,11 @@ def test_what_the_new_fields_refuse():
     model = instella_moe_model("instella-tiny", dtype=F32)
     assert model.config.moe.router == moe.router and model.has_router_bias
     x = jnp.zeros((1, 8, 64))
-    block = jax.tree.map(lambda a: a[0], model.init(jax.random.PRNGKey(0))["blocks"])
+    # (shapes alone: the refusal comes before a value is read)
+    block = jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a[0], model.init(jax.random.PRNGKey(0))["blocks"]))
     with pytest.raises(NotImplementedError, match="one block at a time"):
-        model.block_apply(block, x, jnp.arange(8)[None])
+        jax.eval_shape(lambda b, x: model.block_apply(b, x, jnp.arange(8)[None]), block, x)
     from deepspeed_tpu.models.registry import get_architecture
     spec = get_architecture("deepseek_v3")
     # (a compressed query is read since PR 55: ``q_latent_rank``)

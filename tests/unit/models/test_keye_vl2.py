@@ -52,13 +52,37 @@ def parts(cell):
     return ref, adapter, cell.config, w, jnp.asarray(ids, jnp.int32)
 
 
+@pytest.fixture(scope="module")
+def wanted(parts):
+    """The reference over the module's rows, each ONE jitted program for the
+    routes and tests that read it: loss and gradient whole, the two terms, the
+    logits, and in blocks a layer at a time (what runs at 16,384 rows)."""
+    ref, _, cfg, w, ids = parts
+    return {"whole": jax.jit(jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg)))(w),
+            "terms": jax.jit(lambda p: ref.loss_terms(p, ids, cfg))(w),
+            "logits": jax.jit(lambda p: ref.forward(p, ids, cfg))(w),
+            "blocked": jax.jit(lambda p: ref.loss_and_gradient(p, ids, cfg))(w)}
+
+
+@pytest.fixture(scope="module")
+def step_wanted(parts):
+    """The batch the two engines step on (a row a device of the test mesh) and
+    the reference over it: (batch, (loss, norm, signs), (L_LM, L_I))."""
+    ref, _, cfg, w, ids = parts
+    batch = {"input_ids": np.concatenate([np.asarray(ids), np.asarray(ids)[:, ::-1]]
+                                         )[:jax.device_count()]}
+    rows = jnp.asarray(batch["input_ids"])
+    return (batch, jax.jit(lambda p: ref.loss_and_gradient(p, rows, cfg))(w),
+            jax.jit(lambda p: ref.loss_terms(p, rows, cfg))(w))
+
+
 def close(a, b, rel=2e-4):
     a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
     return np.abs(a - b).max() <= rel * max(np.abs(b).max(), 1e-12)
 
 
 @pytest.mark.parametrize("route", ["xla", "pallas"])
-def test_loss_and_gradient_match_the_reference(parts, route, monkeypatch):
+def test_loss_and_gradient_match_the_reference(parts, wanted, route, monkeypatch):
     """float32 against float32 at ``highest``: L_LM and L_I each to 1e-5, every
     leaf's gradient (the indexer's five among them) to 2e-4 of its largest
     element, the logits to 1e-4; on the XLA route and with the flash pair
@@ -72,14 +96,13 @@ def test_loss_and_gradient_match_the_reference(parts, route, monkeypatch):
     assert plan.route == ("kernel" if route == "pallas" else "xla")
     # the KL beside it: through the Pallas pair where the flash pair is a kernel
     assert model.attention_records(*ids.shape)[0]["dsa"]["kl"] == plan.route
-    want, want_g = jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg))(w)
-    lm, kl = ref.loss_terms(w, ids, cfg)
+    (want, want_g), (lm, kl) = wanted["whole"], wanted["terms"]
     with jax.default_matmul_precision("highest"):
-        (got, stats), got_g = jax.value_and_grad(
-            lambda p: model.loss_and_stats(p, {"input_ids": ids}), has_aux=True)(
+        (got, stats), got_g = jax.jit(jax.value_and_grad(
+            lambda p: model.loss_and_stats(p, {"input_ids": ids}), has_aux=True))(
                 adapter.to_program(w))
-        logits, aux = model.apply(adapter.to_program(w), ids)
-        plain = model.loss(adapter.to_program(w), {"input_ids": ids})
+        (logits, aux), plain = jax.jit(lambda p: (
+            model.apply(p, ids), model.loss(p, {"input_ids": ids})))(adapter.to_program(w))
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     assert float(plain) == pytest.approx(float(want), rel=1e-5)
     assert float(stats["attn_lm_loss"]) == pytest.approx(float(lm), rel=1e-5)
@@ -90,9 +113,9 @@ def test_loss_and_gradient_match_the_reference(parts, route, monkeypatch):
     for name, g in want_g.items():
         assert close(flat[name], g), name
         assert float(jnp.max(jnp.abs(g))) > 0, name
-    assert close(logits, ref.forward(w, ids, cfg), rel=1e-4)
+    assert close(logits, wanted["logits"], rel=1e-4)
     # the reference in blocks, a layer at a time (what runs at 16,384 rows)
-    blocked = ref.loss_and_gradient(w, ids, cfg)
+    blocked = wanted["blocked"]
     assert float(blocked[0]) == pytest.approx(float(want), rel=1e-6)
     norm = float(jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in want_g.values())))
     assert float(blocked[1]) == pytest.approx(norm, rel=1e-5)
@@ -108,11 +131,11 @@ def test_loss_and_gradient_match_the_reference(parts, route, monkeypatch):
 
 def test_the_reference_scores_queries_in_blocks(parts, monkeypatch):
     ref, _, cfg, w, ids = parts
-    whole, whole_g = jax.value_and_grad(
-        lambda p: ref.next_token_loss(p, ids[:3], cfg, checkpoint=False))(w)
+    whole, whole_g = jax.jit(jax.value_and_grad(
+        lambda p: ref.next_token_loss(p, ids[:3], cfg, checkpoint=False)))(w)
     monkeypatch.setattr(ref, "QUERY_BLOCK", 16)
-    blocked, blocked_g = jax.value_and_grad(
-        lambda p: ref.next_token_loss(p, ids[:3], cfg, checkpoint=True))(w)
+    blocked, blocked_g = jax.jit(jax.value_and_grad(
+        lambda p: ref.next_token_loss(p, ids[:3], cfg, checkpoint=True)))(w)
     assert float(blocked) == pytest.approx(float(whole), rel=1e-6)
     assert all(close(blocked_g[k], whole_g[k], rel=1e-5) for k in w)
 
@@ -124,12 +147,15 @@ def test_the_selection_is_the_references(parts):
     ref, adapter, cfg, w, ids = parts
     model = adapter.model(cfg, remat=False, dtype="float32")
     params = adapter.to_program(w)
-    with jax.default_matmul_precision("highest"):
-        want = np.asarray(ref.selection(w, ids, cfg, 0))
+    def layer_0(params):
         block = jax.tree.map(lambda a: a[0], params["blocks"])
         x, _ = model.embed(params, ids)
         h = model._layer("ln_1")(block["ln_1"], x)
-        packed = model.selection(block, h, jnp.arange(64)[None], model._documents(ids))[3]
+        return model.selection(block, h, jnp.arange(64)[None], model._documents(ids))[3]
+    with jax.default_matmul_precision("highest"):
+        want, want1 = (np.asarray(a) for a in jax.jit(lambda p: (
+            ref.selection(p, ids, cfg, 0), ref.selection(p, ids, cfg, 1)))(w))
+        packed = jax.jit(layer_0)(params)
         got = np.asarray(attention.unpack_selection(packed, 64))
     assert packed.dtype == jnp.int8 and packed.shape == (ids.shape[0], 8, 64)
     np.testing.assert_array_equal(got, want)
@@ -140,7 +166,6 @@ def test_the_selection_is_the_references(parts):
     np.testing.assert_array_equal(got.sum(-1), np.minimum(seen.sum(-1), 8))
     assert (seen.sum(-1) < 8).any() and (seen.sum(-1) > 8).any()
     # layer 1's too, behind a whole layer
-    want1 = np.asarray(ref.selection(w, ids, cfg, 1))
     assert want1.shape == want.shape and (want1 != want).any()
 
 
@@ -181,15 +206,15 @@ def test_the_two_losses_reach_disjoint_leaves_exactly(parts):
     model = adapter.model(cfg, remat=True, dtype="float32")
     term = lambda name: lambda p: model.loss_and_stats(p, {"input_ids": ids})[1][name]
     params = adapter.to_program(w)
-    lm = adapter.from_program(jax.grad(term("attn_lm_loss"))(params))
-    kl = adapter.from_program(jax.grad(term("attn_indexer_kl"))(params))
+    lm, kl = (adapter.from_program(g) for g in jax.jit(lambda p: (
+        jax.grad(term("attn_lm_loss"))(p), jax.grad(term("attn_indexer_kl"))(p)))(params))
     for name in w:
         mine, other = (kl, lm) if name in INDEXER else (lm, kl)
         assert float(jnp.max(jnp.abs(other[name]))) == 0.0, name
         assert float(jnp.max(jnp.abs(mine[name]))) > 0.0, name
     # and the reference says the same of its own two terms
-    ref_lm = jax.grad(lambda p: ref.loss_terms(p, ids, cfg)[0])(w)
-    ref_kl = jax.grad(lambda p: ref.loss_terms(p, ids, cfg)[1])(w)
+    ref_lm, ref_kl = jax.jit(lambda p: tuple(
+        jax.grad(lambda p: ref.loss_terms(p, ids, cfg)[i])(p) for i in (0, 1)))(w)
     for name in w:
         other = ref_lm if name in INDEXER else ref_kl
         assert float(jnp.max(jnp.abs(other[name]))) == 0.0, name
@@ -231,16 +256,17 @@ def test_rope_by_sections(parts):
     assert not close(got, model._rotate(x, equal), rel=1e-3)
     positions = jnp.broadcast_to(streams[:, :1], (3,) + ids.shape)
     with jax.default_matmul_precision("highest"):
-        mine = model.loss(adapter.to_program(w), {"input_ids": ids, "position_ids": positions})
-        text = model.loss(adapter.to_program(w), {"input_ids": ids})
-    theirs = ref.next_token_loss(w, ids, cfg, positions=positions)
+        mine, text = jax.jit(lambda p: (
+            model.loss(p, {"input_ids": ids, "position_ids": positions}),
+            model.loss(p, {"input_ids": ids})))(adapter.to_program(w))
+    theirs = jax.jit(lambda p: ref.next_token_loss(p, ids, cfg, positions=positions))(w)
     assert float(mine) == pytest.approx(float(theirs), rel=1e-5)
     assert abs(float(mine) - float(text)) > 1e-4
     with pytest.raises(ValueError, match="position_ids"):
         model.loss(adapter.to_program(w), {"input_ids": ids, "position_ids": positions[:2]})
 
 
-def test_first_step_through_initialize(parts):
+def test_first_step_through_initialize(parts, step_wanted):
     """``initialize`` -> ``train_batch`` in float32: the step's loss and
     gradient norm are the reference's, every weight moves against the
     reference's gradient, and the counters say what ran."""
@@ -258,12 +284,8 @@ def test_first_step_through_initialize(parts):
         "layout": None, "kl": None, "kl_tiles": None, "operand": "bits",
         "operand_bytes": None}
     assert engine.attn_last_step() is None
-    # (a row a device of the test mesh)
-    batch = {"input_ids": np.concatenate([np.asarray(ids), np.asarray(ids)[:, ::-1]]
-                                         )[:jax.device_count()]}
-    rows = jnp.asarray(batch["input_ids"])
-    want, _, signs = ref.loss_and_gradient(w, rows, cfg)
-    lm, kl = ref.loss_terms(w, rows, cfg)
+    batch, (want, _, signs), (lm, kl) = step_wanted
+    rows = batch["input_ids"]
     before = adapter.from_program(jax.tree.map(np.asarray, engine.state["opt"]["master"]))
     loss = engine.train_batch(batch)
     assert float(loss) == pytest.approx(float(want), rel=1e-4)
@@ -295,7 +317,7 @@ def test_first_step_through_initialize(parts):
     assert flat["attn.dsa.operand_bytes"] == len(rows) * 64 * 64 // 8
 
 
-def test_the_kl_counters_on_the_kernel_route(parts, monkeypatch):
+def test_the_kl_counters_on_the_kernel_route(parts, step_wanted, monkeypatch):
     """``DSTPU_ATTN=pallas`` through ``initialize`` -> ``train_batch``: the KL
     takes the Pallas pair, and the first step leaves the tiles one layer's
     forward launch ran of its grid (``[run, of]``; a row of 64 is one tile
@@ -309,11 +331,10 @@ def test_the_kl_counters_on_the_kernel_route(parts, monkeypatch):
         model_parameters=adapter.to_program(w), config={
             "train_micro_batch_size_per_gpu": 1, "zero_optimization": {"stage": 1},
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
-    batch = {"input_ids": np.concatenate([np.asarray(ids), np.asarray(ids)[:, ::-1]]
-                                         )[:jax.device_count()]}
-    rows = jnp.asarray(batch["input_ids"])
+    batch, (want, _, _), _ = step_wanted
+    rows = batch["input_ids"]
     loss = engine.train_batch(batch)
-    assert float(loss) == pytest.approx(float(ref.loss_and_gradient(w, rows, cfg)[0]), rel=1e-4)
+    assert float(loss) == pytest.approx(float(want), rel=1e-4)
     dsa = engine.attn_totals["dsa"]
     assert dsa["route"] == dsa["kl"] == "kernel"
     assert dsa["kl_tiles"] == [len(rows), len(rows)]

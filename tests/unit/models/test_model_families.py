@@ -3,6 +3,8 @@
 policy matrix in ``module_inject/containers``): every preset family must
 init, forward, and differentiate on the 8-device mesh."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -49,12 +51,22 @@ FAMILIES = {
 }
 
 
+@pytest.fixture(scope="module")
+def built():
+    """A family's model and its parameters (``init`` as one jitted program),
+    built once for the tests that read them."""
+    @functools.lru_cache(None)
+    def build(family):
+        model = FAMILIES[family]()
+        return model, jax.jit(model.init)(jax.random.PRNGKey(0))
+    return build
+
+
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_family_forward_and_grad(eight_devices, family):
-    model = FAMILIES[family]()
-    params = model.init(jax.random.PRNGKey(0))
+def test_family_forward_and_grad(eight_devices, built, family):
+    model, params = built(family)
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 128, size=(2, 16)))
-    logits, _ = model.apply(params, ids)
+    logits, _ = jax.jit(model.apply)(params, ids)
     assert logits.shape == (2, 16, model.config.vocab_size)
     assert bool(jnp.all(jnp.isfinite(logits)))
     batch = {"input_ids": ids}
@@ -62,23 +74,22 @@ def test_family_forward_and_grad(eight_devices, family):
         labels = np.full(ids.shape, -100)
         labels[:, ::4] = np.asarray(ids)[:, ::4]
         batch["labels"] = jnp.asarray(labels)
-    loss, grads = jax.value_and_grad(model.loss)(params, batch)
+    loss, grads = jax.jit(jax.value_and_grad(model.loss))(params, batch)
     assert bool(jnp.isfinite(loss))
     gnorm = jax.tree.reduce(
         lambda a, g: a + jnp.sum(jnp.square(g)), grads, jnp.zeros(()))
     assert float(gnorm) > 0.0
 
 
-def test_one_window_for_every_layer_is_one_static_kind(eight_devices):
+def test_one_window_for_every_layer_is_one_static_kind(eight_devices, built):
     """A Mistral-shaped preset passes one int: every layer is the same static
     kind (a scan unit of one), the core runs under ``core_window``, the
     per-layer tuple of the same window is the same program, and the window
     binds (the loss differs from the global model's on the same weights)."""
     import re
-    model = FAMILIES["mistral-window"]()
+    model, params = built("mistral-window")
     L = model.config.num_layers
     assert model.scan_plan == (((8, True),), L, ())
-    params = model.init(jax.random.PRNGKey(0))
     batch = {"input_ids": jnp.asarray(
         np.random.default_rng(0).integers(0, 128, size=(2, 16)))}
     loss = model.loss(params, batch)
@@ -126,9 +137,9 @@ def test_encoder_configs_rejected_by_pipeline(eight_devices):
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_family_specs_cover_params(eight_devices, family):
+def test_family_specs_cover_params(eight_devices, built, family):
     """Every param leaf must have a matching PartitionSpec leaf (AutoTP and
     ZeRO placement both walk these trees in lockstep)."""
     from tests.unit.models.spec_utils import assert_specs_cover_params
-    model = FAMILIES[family]()
-    assert_specs_cover_params(model.init(jax.random.PRNGKey(0)), model.specs())
+    model, params = built(family)
+    assert_specs_cover_params(params, model.specs())

@@ -57,9 +57,9 @@ def test_loss_and_gradients_match_the_reference(tiny, remat):
     weight, under the reference's names."""
     ref, adapter, cfg, w, ids = tiny
     model = adapter.model(cfg, remat=remat, dtype="float32")
-    loss, grads = jax.value_and_grad(
-        lambda p: program_loss(model, p, ids))(adapter.to_program(w))
-    want, want_g = jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg))(w)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: program_loss(model, p, ids)))(adapter.to_program(w))
+    want, want_g = jax.jit(jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg)))(w)
     # the program shifts the labels and masks the last position; the
     # reference predicts ids[:, 1:]: the same mean
     assert float(loss) == pytest.approx(float(want), abs=1e-5)

@@ -17,8 +17,8 @@ from deepspeed_tpu.models import bert_model, llama_model
 
 
 def _grads(model, batch, seed=0):
-    p = model.init(jax.random.PRNGKey(seed), jnp.float32)
-    loss, g = jax.value_and_grad(lambda pp: model.loss(pp, batch))(p)
+    p = jax.jit(lambda key: model.init(key, jnp.float32))(jax.random.PRNGKey(seed))
+    loss, g = jax.jit(jax.value_and_grad(lambda pp: model.loss(pp, batch)))(p)
     return float(loss), jax.tree.leaves(g)
 
 

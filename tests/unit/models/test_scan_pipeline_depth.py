@@ -53,8 +53,10 @@ class TestPrefetchDepth:
         # 2 steps: every deep slot would just re-gather the final step —
         # the schedule must silently clamp to 1, not duplicate gathers
         model, params, x, positions = _model_and_inputs(num_layers=2)
-        out1, a1, g1, _ = _run(model, params, x, positions, 1)
-        out2, a2, g2, _ = _run(model, params, x, positions, 2)
+        f = jax.jit(lambda p, xx, d: _run(model, p, xx, positions, d),
+                    static_argnums=2)
+        out1, a1, g1, _ = f(params, x, 1)
+        out2, a2, g2, _ = f(params, x, 2)
         np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
         for l1, l2 in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
             np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))

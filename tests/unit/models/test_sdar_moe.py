@@ -68,23 +68,24 @@ def test_loss_and_gradient_match_the_reference(parts, block_length):
     model = adapter.model(cfg, remat=True, dtype="float32")
     assert model.config.block_length == block_length and model.rows_per_token == 2
     assert model.scan_plan == (((0, True),), 2, ())
-    want, want_g = jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg))(w)
+    # (each side ONE jitted program: op by op this is a thousand compiles)
+    want, want_g = jax.jit(jax.value_and_grad(lambda p: ref.next_token_loss(p, ids, cfg)))(w)
     with jax.default_matmul_precision("highest"):
-        got, got_g = jax.value_and_grad(
-            lambda p: model.loss(p, {"input_ids": ids}))(adapter.to_program(w))
-        logits, _ = model.apply(adapter.to_program(w), ids)
+        got, got_g = jax.jit(jax.value_and_grad(
+            lambda p: model.loss(p, {"input_ids": ids})))(adapter.to_program(w))
+        logits, _ = jax.jit(lambda p: model.apply(p, ids))(adapter.to_program(w))
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     flat = adapter.from_program(got_g)
     assert set(flat) == set(w)
     for name, g in want_g.items():
         assert close(flat[name], g), name
-    assert close(logits, ref.forward(w, ids, cfg), rel=1e-4)
+    assert close(logits, jax.jit(lambda p: ref.forward(p, ids, cfg))(w), rel=1e-4)
     noised, weights, masked = model.noise({"input_ids": ids})
     ref_noised, ref_weights = ref.noise(ids, ref.sizes(cfg))
     np.testing.assert_array_equal(np.asarray(noised), np.asarray(ref_noised))
     np.testing.assert_array_equal(np.asarray(weights), np.asarray(ref_weights))
     # the reference in blocks (what runs at 2 x 8,192 rows) is the reference
-    blocked = ref.loss_and_gradient(w, ids, cfg)[0]
+    blocked = jax.jit(lambda p: ref.loss_and_gradient(p, ids, cfg)[0])(w)
     assert float(blocked) == pytest.approx(float(want), rel=1e-6)
 
 
@@ -93,11 +94,11 @@ def test_the_reference_scores_queries_in_blocks(parts, monkeypatch):
     the clean keys and their own positions' noised keys: the same numbers as
     the whole 2 L x 2 L mask at once (here blocks of 16 over rows of 64)."""
     ref, _, cfg, w, ids = parts
-    whole, whole_g = jax.value_and_grad(
-        lambda p: ref.next_token_loss(p, ids[:3], cfg, checkpoint=False))(w)
+    whole, whole_g = jax.jit(jax.value_and_grad(
+        lambda p: ref.next_token_loss(p, ids[:3], cfg, checkpoint=False)))(w)
     monkeypatch.setattr(ref, "QUERY_BLOCK", 16)
-    blocked, blocked_g = jax.value_and_grad(
-        lambda p: ref.next_token_loss(p, ids[:3], cfg, checkpoint=True))(w)
+    blocked, blocked_g = jax.jit(jax.value_and_grad(
+        lambda p: ref.next_token_loss(p, ids[:3], cfg, checkpoint=True)))(w)
     assert float(blocked) == pytest.approx(float(whole), rel=1e-6)
     assert all(close(blocked_g[k], whole_g[k], rel=1e-5) for k in w)
 
@@ -120,7 +121,7 @@ def test_first_step_through_initialize(parts):
                                        "layout": None}
     assert engine.diffusion_last_step() is None
     loss = float(engine.train_batch({"input_ids": np.asarray(ids)}))
-    want, gnorm, signs = ref.loss_and_gradient(w, ids, cfg)
+    want, gnorm, signs = jax.jit(lambda p: ref.loss_and_gradient(p, ids, cfg))(w)
     assert loss == pytest.approx(float(want), rel=1e-5)
     assert float(engine.get_global_grad_norm()) == pytest.approx(float(gnorm), rel=1e-4)
     new = adapter.from_program(engine.state["opt"]["master"])
@@ -128,7 +129,7 @@ def test_first_step_through_initialize(parts):
     # median is 5e-9: the chosen probabilities over their own sum do not move
     # with a logit that was not chosen), with a sign of its own in either
     # program: an element counts from a millionth of its leaf's largest
-    grads = jax.grad(lambda p: ref.next_token_loss(p, ids, cfg))(w)
+    grads = jax.jit(jax.grad(lambda p: ref.next_token_loss(p, ids, cfg)))(w)
     wrong = total = 0
     for name, s in signs.items():
         g = np.abs(np.asarray(grads[name]))

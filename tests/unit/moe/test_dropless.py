@@ -84,9 +84,10 @@ def test_output_and_gradients_match_dense(num_experts, top_k, normalize):
     _, _, _, rows = route_of(moe, params, x)
     assert rows.sum() == B * S * top_k                 # nothing dropped
     assert rows[0] > B * S // 2 and (rows == 0).sum() >= num_experts // 4
-    close(run_layer(moe, params, x), dense(moe, params, x))
-    got = jax.grad(lambda p, v: probe(run_layer, moe, p, v), (0, 1))(params, x)
-    want = jax.grad(lambda p, v: probe(dense, moe, p, v), (0, 1))(params, x)
+    close(jax.jit(lambda p, v: run_layer(moe, p, v))(params, x),
+          jax.jit(lambda p, v: dense(moe, p, v))(params, x))
+    got = jax.jit(jax.grad(lambda p, v: probe(run_layer, moe, p, v), (0, 1)))(params, x)
+    want = jax.jit(jax.grad(lambda p, v: probe(dense, moe, p, v), (0, 1)))(params, x)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         close(g, w)
 
@@ -353,12 +354,17 @@ def test_a_shares_rows_come_back_to_their_tokens(top_k, held, fill):
     params, x, cap = steered(moe, fill)
     rows = np.asarray(route_of(moe, params, x)[3])[held[0]:held[1]].sum()
     assert rows == {"below": cap // 2, "at": cap, "beyond": cap + cap // 4}[fill]
-    got, g = jax.jit(jax.value_and_grad(
-        lambda p, v: probe(run_layer, moe, p, v), (0, 1)))(params, x)
-    want, w = jax.jit(jax.value_and_grad(
-        lambda p, v: probe(slab_by_slab, moe, p, v), (0, 1)))(params, x)
+    ct = jax.random.normal(jax.random.PRNGKey(9), x.shape)      # `probe`'s cotangent
+
+    def both(fn):
+        """`probe`, the layer's output and the gradients: one jitted program."""
+        def scalar(p, v):
+            out = fn(moe, p, v)
+            return jnp.sum(out * ct), out
+        return jax.jit(jax.value_and_grad(scalar, (0, 1), has_aux=True))(params, x)
+    ((got, out), g), ((want, ref_out), w) = both(run_layer), both(slab_by_slab)
     assert float(got) == pytest.approx(float(want), rel=1e-4, abs=1e-3)   # a sum of 32 k terms
-    close(run_layer(moe, params, x), slab_by_slab(moe, params, x))
+    close(out, ref_out)
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
         close(a, b)
 

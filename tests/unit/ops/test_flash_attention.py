@@ -1,8 +1,8 @@
 """The XLA training attention paths (ops/transformer/attention.py): the
 query-chunked route, XLA's memory bound at long sequence, against the
 one-shot one — same math, forward and backward, with and without segment
-ids. The in-repo kernel and the route table are tests/unit/ops/
-test_pallas_flash.py's."""
+ids. The in-repo kernel is tests/unit/ops/test_pallas_flash_*.py's (one file
+a kernel), the route table test_pallas_flash_rules.py's."""
 
 import numpy as np
 import pytest
@@ -37,8 +37,8 @@ def test_chunked_xla_matches_unchunked(eight_devices, causal):
         return jnp.sum(jnp.square(_xla_attention_chunked(
             q, k, v, causal, scale, None, chunk=64)))
 
-    ref, g_ref = jax.value_and_grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-    got, g_chk = jax.value_and_grad(f_chk, argnums=(0, 1, 2))(q, k, v)
+    ref, g_ref = jax.jit(jax.value_and_grad(f_ref, argnums=(0, 1, 2)))(q, k, v)
+    got, g_chk = jax.jit(jax.value_and_grad(f_chk, argnums=(0, 1, 2)))(q, k, v)
     np.testing.assert_allclose(float(got), float(ref), rtol=1e-5)
     for a, b in zip(g_chk, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

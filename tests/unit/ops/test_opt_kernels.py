@@ -176,16 +176,22 @@ class TestStochasticRounding:
     # exists to prevent)
     VAL = 1.0 + 1.0 / 1024
 
-    def _kernel_draw(self, step):
-        st = jnp.asarray(step, jnp.int32)
+    @staticmethod
+    @jax.jit
+    def _draw(st):
+        """(The step is traced, as the engine's is: interpret mode compiles a
+        launch anew at every eager call, and the mean below takes 64.)"""
         g0 = jnp.zeros(4096, jnp.float32)
-        m_in = jnp.full((4096,), self.VAL / 0.9, jnp.float32)  # b1*m = VAL
+        m_in = jnp.full((4096,), TestStochasticRounding.VAL / 0.9, jnp.float32)  # b1*m = VAL
         _, _, m_out, _ = adam_bucket_update(
             g0, g0, m_in, g0, step=st, lr=0.0,
             m_dtype=jnp.bfloat16, v_dtype=jnp.float32,
             seed_m=sr_seed(st, 1, 0), seed_v=sr_seed(st, 2, 0),
             interpret=True)
-        return np.asarray(m_out, np.float32)
+        return m_out
+
+    def _kernel_draw(self, step):
+        return np.asarray(self._draw(jnp.asarray(step, jnp.int32)), np.float32)
 
     def test_in_kernel_sr_mean_preserving(self):
         draws = sum(self._kernel_draw(s) for s in range(64)) / 64

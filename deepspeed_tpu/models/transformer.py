@@ -37,13 +37,12 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..nn import layers as nn
-from ..ops.transformer.attention import flash_attention
 from ..runtime.activation_checkpointing.checkpointing import (
-    KEEP_PRODUCTS, Budget, checkpointed)
+    KEEP_PRODUCTS, Budget, checkpointed, resolve_policy)
 from ..runtime.topology import BATCH_AXES, DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 from ..utils.jax_compat import with_sharding_constraint
 from ..utils.scope import scoped
-from ..sequence.layer import ulysses_attention
+from . import mixers
 
 Params = Dict[str, Any]
 
@@ -62,6 +61,11 @@ ACTIVATIONS = {
 
 def _c(x, spec):
     return with_sharding_constraint(x, spec)
+
+
+def _one_layer(stacked):
+    """One layer of a stacked ``[layers, ...]`` tree, as shapes."""
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), stacked)
 
 
 def _token_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
@@ -140,17 +144,16 @@ def next_token_cross_entropy(config, logits, labels, extra_mask=None) -> jax.Arr
 
 
 #: The most elements a dense MLP's ``[rows, intermediate]`` product may have
-#: before the rows are taken in slices, and the most one EVA head group's
-#: ``[rows, heads x head]`` projection may have before the heads are taken in
-#: groups. At 32,768 rows x 11008 a product is 0.72 GB in bfloat16 and a
-#: block's backward holds six of them beside a dozen ``[rows, hidden]`` copies
-#: of the attention's: 9.2 GB by the chip's compiler, beside 9.9 GB of
-#: training state on a 16 GB chip; in slices of 4,096 rows and groups of 4
-#: heads it holds 6.1 (PERF.md, PR 42). Memory only: the same arithmetic, a
-#: slice's intermediates at a time.
+#: before the rows are taken in slices (and ``mixers.EVA_GROUP_ELEMENTS`` the
+#: most one EVA head group's ``[rows, heads x head]`` projection may have
+#: before the heads are taken in groups). At 32,768 rows x 11008 a product is
+#: 0.72 GB in bfloat16 and a block's backward holds six of them beside a
+#: dozen ``[rows, hidden]`` copies of the attention's: 9.2 GB by the chip's
+#: compiler, beside 9.9 GB of training state on a 16 GB chip; in slices of
+#: 4,096 rows and groups of 4 heads it holds 6.1 (PERF.md, PR 42). Memory
+#: only: the same arithmetic, a slice's intermediates at a time.
 MLP_WHOLE_ELEMENTS = 2 ** 28
 MLP_SLICE_ELEMENTS = 2 ** 26
-EVA_GROUP_ELEMENTS = 2 ** 24
 
 
 def mlp_row_slices(rows: int, width: int) -> int:
@@ -201,14 +204,6 @@ def head_slices(config, remat_budget: Optional[Budget], input_ids) -> int:
             or c.mtp_layers or not c.causal):
         return 1
     return head_row_slices(input_ids.size, c.vocab_size, remat_budget.room_bytes)
-
-
-def eva_head_groups(rows: int, heads: int, head_dim: int) -> int:
-    """How many groups of its heads an EVA layer's attention is computed in:
-    the least divisor of ``heads`` that brings a group's ``[rows, heads x
-    head_dim]`` under ``EVA_GROUP_ELEMENTS``."""
-    return next(g for g in range(1, heads + 1) if heads % g == 0
-                and (g == heads or rows * (heads // g) * head_dim <= EVA_GROUP_ELEMENTS))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -347,8 +342,8 @@ class TransformerConfig:
     # is one period of the layers' kinds (``TransformerLM.scan_plan``), so a
     # windowed layer's attention is built knowing its window (the flash
     # kernel's grids are cut to it) and runs under the scope
-    # ``attn/core_window``. Only the ZeRO-3 pipelined scan and
-    # ``remat_policy='alternating'`` still hand the window over traced.
+    # ``attn/core_window``. Only the ZeRO-3 pipelined scan still hands the
+    # window over traced.
     attn_windows: Any = None         # Optional[int | Tuple[int, ...]]
     # which layers a rotary ``position`` turns: 'all', or 'windowed' (afmoe:
     # the layers with a window alone; a global layer has no positional term)
@@ -386,8 +381,7 @@ class TransformerConfig:
     remat: bool = True
     # what the block's backward keeps: KEEP_PRODUCTS (matmul and kernel
     # outputs inside the device's byte budget) | 'nothing_saveable'/'full'
-    # (recompute the whole block) | 'attention_only' | 'alternating' | any
-    # jax.checkpoint_policies name
+    # (recompute the whole block) | any jax.checkpoint_policies name
     remat_policy: str = KEEP_PRODUCTS
     # olmoe: RMSNorm with its own scale over the WHOLE projected q vector
     # [heads*head_dim] and k vector [kv_heads*head_dim], before the head
@@ -532,8 +526,14 @@ class TransformerConfig:
                     or self.shared_from is not None)
 
     def mixer_of(self, layer: int) -> Tuple[str, Optional[str]]:
-        """Layer ``layer``'s mixer and what it hands on: ``("ssm" | "attn" |
-        "gmu" | "cross", None | "memory" | "kv")``."""
+        """Layer ``layer``'s token mixer (a name of ``mixers.KINDS``) and what
+        it hands on: the ONE place a layer's mixer is picked from the
+        configuration. A plain stack's layers all have ``attention``'s
+        (``"mha"`` under an ``indexer``: ``"selected"``) and hand nothing on; a
+        mixed stack's ``("ssm" | "attn" | "gmu" | "cross", None | "memory" |
+        "kv")``."""
+        if not self.mixed:
+            return ("selected" if self.indexer is not None else self.attention), None
         scan = bool(self.ssm_state) and layer % self.ssm_period == 0
         at = self.shared_from
         if at is not None and layer >= at + 2:
@@ -573,12 +573,10 @@ class TransformerConfig:
         return self.intermediate_size or 4 * self.hidden_size
 
     def num_parameters(self) -> int:
+        """The embeddings, the head, every layer's MLP (in a mixed stack the
+        norms too) and each layer's mixer's own count (``Mixer.parameters``)."""
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
         ffn = self.ffn_size
-        kv = self.kv_heads * self.head_dim
-        attn = 2 * h * self.num_heads * self.head_dim + 2 * h * kv
-        if self.qk_norm:
-            attn += h + kv
         if self.activation == "silu_gated":
             mlp = 3 * h * ffn
         else:
@@ -590,30 +588,16 @@ class TransformerConfig:
             if self.position == "learned" else 0)
         embed += self.type_vocab_size * h
         head = 0 if self.tie_embeddings else self.pred_heads * v * h
-        if self.attention == "eva":
-            attn += 2 * self.num_heads * self.head_dim
-        if self.indexer is not None:
-            ix = self.indexer
-            attn += h * (ix.heads * ix.head_dim + ix.head_dim + ix.heads) + 2 * ix.head_dim
         if self.mlm_head:
             head += h * h + v  # prediction transform + decoder bias
+        of = mixers.build(self)
+        total = embed + head + L * mlp + sum(
+            of[self.mixer_of(l)[0]].parameters() for l in range(L))
         if self.mixed:
-            # each layer its own mixer; LayerNorm's and the projections' biases
-            # counted (the published 3.8 B of a 32-layer SambaY stack is this sum)
-            norm = (2 if self.norm == "layernorm" else 1) * h
-            bias = self.norm == "layernorm"
-            di, n, r = self.ssm_inner, self.ssm_state, self.ssm_rank
-            q_out, hd = self.num_heads * self.head_dim, self.head_dim
-            diff = 4 * hd + 2 * hd if self.differential_attention else 0
-            each = {
-                "ssm": (h * 2 * di + (self.ssm_conv + 1) * di + di * (r + 2 * n)
-                        + r * di + di + di * n + di + di * h),
-                "attn": h * q_out + 2 * h * kv + q_out * h + bias * (q_out + 2 * kv + h) + diff,
-                "gmu": 2 * h * di,
-                "cross": h * q_out + q_out * h + bias * (q_out + h) + diff}
-            return embed + head + norm + sum(
-                each[self.mixer_of(l)[0]] + mlp + 2 * norm for l in range(L))
-        return embed + head + L * (attn + mlp)
+            # LayerNorm's and the projections' biases counted (the published
+            # 3.8 B of a 32-layer SambaY stack is this sum)
+            total += (2 * L + 1) * (2 if self.norm == "layernorm" else 1) * h
+        return total
 
 
 #: What a configuration can ask beyond the plain causal pre-norm decoder (one
@@ -782,66 +766,32 @@ class TransformerLM:
         # linears (linear_bias overrides the norm-derived default)
         use_bias = (c.linear_bias if c.linear_bias is not None
                     else c.norm == "layernorm")
-        # gpt-j: attention projections are bias-free while the MLP keeps
-        # biases — attn_bias overrides the block-wide default for attn only
-        attn_bias = c.attn_bias if c.attn_bias is not None else use_bias
-        attn_out_bias = (c.attn_out_bias if c.attn_out_bias is not None
-                         else attn_bias)
         lin = lambda i, o, bias, shard: nn.Linear(i, o, use_bias=bias, shard=shard)
-        if c.attention == "latent":
-            self._check_latent()
-            q_out = c.num_heads * c.head_dim
-            attn_out = c.num_heads * c.v_head_dim
-            q_layers = {"q_proj": lin(c.hidden_size, q_out, False, "column")}
-            if c.q_latent_rank:
-                q_layers = {
-                    "q_a_proj": lin(c.hidden_size, c.q_latent_rank, False, None),
-                    "q_a_norm": nn.RMSNorm(c.q_latent_rank, eps=c.norm_eps),
-                    "q_b_proj": lin(c.q_latent_rank, q_out, False, "column")}
-            attn_layers = {
-                **q_layers,
-                # the compressed keys and values with the shared rotary key
-                "kv_a_proj": lin(c.hidden_size, c.kv_latent_rank + c.qk_rope_dim, False, None),
-                "kv_a_norm": nn.RMSNorm(c.kv_latent_rank, eps=c.norm_eps),
-                "kv_b_proj": lin(c.kv_latent_rank,
-                                 c.num_heads * (c.qk_nope_dim + c.v_head_dim), False, "column"),
-                "o_proj": lin(attn_out, c.hidden_size, False, "row"),
-            }
-        else:
-            kv_out = c.kv_heads * c.head_dim
-            q_out = attn_out = c.num_heads * c.head_dim
-            attn_layers = {
-                "q_proj": lin(c.hidden_size, q_out, attn_bias, "column"),
-                "k_proj": lin(c.hidden_size, kv_out, attn_bias, "column"),
-                "v_proj": lin(c.hidden_size, kv_out, attn_bias, "column"),
-                "o_proj": lin(attn_out, c.hidden_size, attn_out_bias, "row"),
-            }
-        # (``mixed``: what every layer has; the mixers' layers a kind, below)
+        # each layer's token mixer and what it hands on, picked ONCE
+        # (``TransformerConfig.mixer_of``), and one `mixers.Mixer` a kind by
+        # its name; a plain stack has one kind, `_mixer`
+        self._mixer_kinds = tuple(c.mixer_of(l) for l in range(c.num_layers))
+        self._mixers = mixers.build(c, self)
+        self._mixer = None if c.mixed else next(iter(self._mixers.values()))
+        # whether a mixer returns a loss of its own (the carry then holds the
+        # pair (the MoE accumulator, that loss so far)), and the mixer whose plan
+        # stands for the stack's attention launches: the first that attends
+        self._has_mixer_loss = any(m.has_loss for m in self._mixers.values())
+        self._attending = next((m for m in self._mixers.values() if m.attends), None)
+        for mixer in self._mixers.values():
+            mixer.check()
+        # what every block has, with a plain stack's one mixer's layers; a
+        # mixed stack's mixers' layers, a stack a kind
         self._block_layers = {"ln_1": norm_cls(c.hidden_size),
-                              **({} if c.mixed else attn_layers)}
+                              **({} if c.mixed else self._mixer.layers())}
+        self._mixer_layers: Dict[str, Dict[str, Any]] = (
+            {name: mixer.layers() for name, mixer in self._mixers.items()}
+            if c.mixed else {})
         if c.residual_streams > 1:
             # a sub-layer's coefficients (`_hc_coefficients`)
             for name in ("hc_attn", "hc_mlp"):
                 self._block_layers[name] = nn.HyperConnection(
                     c.residual_streams, c.hidden_size)
-        if c.indexer is not None:
-            ix = c.indexer
-            self._block_layers.update({
-                "indexer_q": lin(c.hidden_size, ix.heads * ix.head_dim, False, None),
-                "indexer_k": lin(c.hidden_size, ix.head_dim, False, None),
-                "indexer_k_norm": nn.LayerNorm(ix.head_dim, eps=1e-6),
-                "indexer_w": lin(c.hidden_size, ix.heads, False, None)})
-        if c.attention == "eva":
-            self._block_layers["eva_phi"] = nn.HeadVectors(c.num_heads, c.head_dim)
-            self._block_layers["eva_mu"] = nn.HeadVectors(c.num_heads, c.head_dim)
-        if c.qk_norm and c.qk_norm_per_head:
-            self._block_layers["q_norm"] = nn.RMSNorm(c.head_dim, eps=c.norm_eps)
-            self._block_layers["k_norm"] = nn.RMSNorm(c.head_dim, eps=c.norm_eps)
-        elif c.qk_norm:
-            self._block_layers["q_norm"] = nn.RMSNorm(q_out, eps=c.norm_eps)
-            self._block_layers["k_norm"] = nn.RMSNorm(kv_out, eps=c.norm_eps)
-        if c.attn_gate:
-            self._block_layers["attn_gate"] = lin(c.hidden_size, attn_out, False, "column")
         if not c.parallel_block or c.parallel_norms:
             # parallel blocks (falcon-7b/phi) feed attention and MLP from the
             # SAME normed input — no second norm exists in the checkpoint;
@@ -889,53 +839,7 @@ class TransformerLM:
                 "merge": lin(2 * c.hidden_size, c.hidden_size, False, None),
                 "ln_f": norm_cls(c.hidden_size),
             }
-        # each layer's mixer and what it hands on (``TransformerConfig.mixer_of``),
-        # and the layers of each kind of mixer, a stack a kind
-        self._mixers = (tuple(c.mixer_of(l) for l in range(c.num_layers))
-                        if c.mixed else None)
-        self._mixer_layers: Dict[str, Dict[str, Any]] = {}
-        if c.mixed:
-            if c.attention != "mha":
-                raise ValueError("ssm_state, differential_attention and shared_from "
-                                 "are written for 'mha' heads")
-            di = c.ssm_inner
-            diff = ({"diff_lambda": nn.HeadVectors(4, c.head_dim, init_scale=0.1),
-                     "diff_norm": nn.RMSNorm(2 * c.head_dim, eps=c.norm_eps)}
-                    if c.differential_attention else {})
-            kinds = {
-                "ssm": {"in_proj": lin(c.hidden_size, 2 * di, False, "column"),
-                        "ssm": nn.ScanParams(di, c.ssm_state, c.ssm_conv),
-                        "x_proj": lin(di, c.ssm_rank + 2 * c.ssm_state, False, None),
-                        "dt_proj": lin(c.ssm_rank, di, False, "column"),
-                        "out_proj": lin(di, c.hidden_size, False, "row")},
-                "attn": {"q_proj": attn_layers["q_proj"],
-                         "kv_proj": lin(c.hidden_size, 2 * kv_out, attn_bias, "column"),
-                         "o_proj": attn_layers["o_proj"], **diff},
-                "gmu": {"gmu_in": lin(c.hidden_size, di, False, "column"),
-                        "gmu_out": lin(di, c.hidden_size, False, "row")},
-                "cross": {"q_proj": attn_layers["q_proj"],
-                          "o_proj": attn_layers["o_proj"], **diff}}
-            self._mixer_layers = {name: layers for name, layers in kinds.items()
-                                  if self._mixer_count(name)}
         self._check_kinds()
-
-    def _check_latent(self) -> None:
-        c = self.config
-        if min(c.kv_latent_rank, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim) <= 0:
-            raise ValueError("latent attention needs kv_latent_rank, "
-                             "qk_nope_dim, qk_rope_dim and v_head_dim")
-        if (c.position != "rope" or c.num_kv_heads not in (None, c.num_heads)
-                or c.attn_windows is not None or c.norm_style != "pre"
-                or c.parallel_block or not c.causal
-                or (c.qk_norm and not c.qk_norm_per_head)):
-            raise ValueError(
-                "latent attention is written for a causal pre-norm rotary "
-                "decoder with as many key heads as query heads, no windows, "
-                "and QK-norm (if any) per head")
-        if c.v_head_dim != c.head_dim and c.seq_parallel == "ring":
-            raise NotImplementedError(
-                f"value heads of {c.v_head_dim} beside query heads of "
-                f"{c.head_dim}: ring attention takes one head size")
 
     @property
     def _mixed_rope(self) -> bool:
@@ -946,42 +850,12 @@ class TransformerLM:
         """What the second kind of layer, the two streams and the
         prediction module are written for, and nothing wider."""
         c = self.config
-        if c.attention not in ("mha", "latent", "eva"):
-            raise ValueError(f"attention {c.attention!r} is not 'mha', 'latent' or 'eva'")
-        if c.attention == "eva":
-            if (c.eva_chunk < 1 or c.eva_window < c.eva_chunk
-                    or c.eva_window % c.eva_chunk):
-                raise ValueError(f"EVA attention needs a window ({c.eva_window}) "
-                                 f"of whole chunks ({c.eva_chunk})")
-            if (not c.causal or c.num_kv_heads not in (None, c.num_heads)
-                    or self._windows is not None or c.position == "alibi"
-                    or c.seq_parallel == "ring" or c.diffusion
-                    or c.document_separator is not None or c.attn_gate
-                    or c.qk_norm or c.norm_style == "post"
-                    or (c.linear_bias if c.attn_bias is None else c.attn_bias)
-                    is not False):
-                raise ValueError(
-                    "EVA attention is written for a causal decoder with as "
-                    "many key heads as query heads: no sliding window, ALiBi, "
-                    "ring attention, block diffusion, packed documents (its "
-                    "windows are the row's), attention gate, QK-norm, post-norm "
-                    "or bias on its projections (linear_bias=False)")
-        if c.indexer is not None and (
-                c.attention != "mha" or not c.causal or c.position != "rope"
-                or self._windows is not None or self._mixed_rope or c.diffusion
-                or c.seq_parallel == "ring" or c.norm_style == "post"
-                or c.parallel_block or c.farskip or c.attn_gate
-                or min(c.indexer.heads, c.indexer.head_dim, c.indexer.topk) < 1
-                or c.indexer.head_dim % 2
-                or (c.remat and c.remat_policy == "alternating")):
-            raise ValueError(
-                "indexer: a selection inside 'mha' attention of a causal rotary "
-                "pre-norm or sandwich decoder: no window, block diffusion, ring "
-                "attention, parallel block, FarSkip, attention gate or "
-                "remat_policy='alternating'")
+        mha_heads = all(mixer.mha_heads for mixer in self._mixers.values())
+        if c.remat and c.remat_policy != KEEP_PRODUCTS:
+            resolve_policy(c.remat_policy)      # (an unknown name is refused here)
         if c.rope_sections is not None and (
                 c.position != "rope" or c.rope_style != "half" or c.rope_dim
-                or c.attention != "mha" or len(c.rope_sections) != 3
+                or not mha_heads or len(c.rope_sections) != 3
                 or sum(c.rope_sections) != c.head_dim // 2):
             raise ValueError(
                 f"rope_sections {c.rope_sections}: three sections of a plain "
@@ -1009,28 +883,22 @@ class TransformerLM:
         if c.mtp_layers and (not c.causal or c.norm_style != "pre"):
             raise ValueError("multi-token prediction is a causal pre-norm decoder's")
         if (c.farskip or c.first_dense_layers or c.mtp_layers) and (
-                c.norm_style == "post" or c.parallel_block
-                or c.remat_policy == "alternating"):
+                c.norm_style == "post" or c.parallel_block):
             raise ValueError(
                 "farskip, first_dense_layers and mtp_layers are written for "
-                "sequential pre-norm blocks, under any remat_policy but "
-                "'alternating'")
+                "sequential pre-norm blocks")
         if (c.farskip or c.mtp_layers) and (
                 c.norm_style != "pre" or self._windows is not None):
             raise ValueError("farskip and mtp_layers are written for pre-norm "
                              "blocks without windows")
-        if c.q_latent_rank and c.attention != "latent":
-            raise ValueError("q_latent_rank is latent attention's compressed query")
         if c.residual_streams < 1 or (c.residual_streams > 1 and (
                 c.norm_style != "pre" or c.parallel_block or c.farskip
-                or c.residual_fp32 or c.indexer is not None or c.diffusion
-                or not c.causal or c.hc_sinkhorn_iters < 1
-                or (c.remat and c.remat_policy == "alternating"))):
+                or c.residual_fp32 or self._has_mixer_loss or c.diffusion
+                or not c.causal or c.hc_sinkhorn_iters < 1)):
             raise ValueError(
                 "residual_streams: hyper-connected streams are written for a causal "
                 "decoder's sequential pre-norm blocks: no FarSkip, parallel block, "
-                "float32 residual, indexer, block diffusion or "
-                "remat_policy='alternating'")
+                "float32 residual, indexer or block diffusion")
         if c.residual_fp32 and (c.norm_style == "post" or c.farskip
                                 or c.parallel_block):
             raise ValueError("residual_fp32 is written for sequential pre-norm "
@@ -1039,61 +907,24 @@ class TransformerLM:
             raise ValueError(f"norm_style {c.norm_style!r}")
         if c.norm_style == "sandwich" and c.parallel_block:
             raise ValueError("sandwich norms are a sequential block's")
-        if self._mixed_rope and c.remat and c.remat_policy == "alternating":
-            raise NotImplementedError(
-                "remat_policy='alternating' scans layer pairs of one kind: "
-                "layers with and without a rotary position "
-                "(rope_layers='windowed') take any other policy")
         if c.diffusion:
             b = c.block_length
             if b < 1 or b & (b - 1):
                 raise ValueError(f"block_length {b} is no power of two")
-            if (not c.causal or c.attention != "mha" or c.position != "rope"
+            if (not c.causal or not mha_heads or c.position != "rope"
                     or self._windows is not None or self._mixed_rope
                     or c.seq_parallel == "ring" or c.tie_embeddings
                     or c.norm_style == "post" or c.farskip or c.mtp_layers
-                    or c.mlm_head or c.type_vocab_size
-                    or (c.remat and c.remat_policy == "alternating")):
+                    or c.mlm_head or c.type_vocab_size):
                 raise ValueError(
                     "objective='block_diffusion' is written for a rotary "
                     "decoder with its own head: no latent attention, window, "
                     "ALiBi or learned position, ring attention, tied "
-                    "embedding, post-norm, FarSkip, prediction module or "
-                    "remat_policy='alternating'")
+                    "embedding, post-norm, FarSkip or prediction module")
         if c.document_separator is not None and (
                 not c.causal or c.seq_parallel == "ring"):
             raise ValueError("document_separator: packed documents are a causal "
                              "decoder's, and not ring attention's")
-        if c.mixed:
-            # (``attention='mha'``: `__init__` refused another before it built a layer)
-            if (not c.causal or c.norm_style != "pre"
-                    or c.parallel_block or c.farskip or c.moe is not None
-                    or c.indexer is not None or c.diffusion or c.mtp_layers
-                    or c.residual_streams > 1 or c.qk_norm or c.attn_gate
-                    or c.seq_parallel == "ring" or c.position == "alibi"
-                    or c.activation != "silu_gated"
-                    or (c.remat and c.remat_policy == "alternating")):
-                raise ValueError(
-                    "ssm_state, differential_attention and shared_from are written for "
-                    "a causal decoder's sequential pre-norm blocks with 'mha' heads and "
-                    "a dense gated-SiLU MLP: no experts, indexer, block diffusion, "
-                    "prediction module, hyper-connections, FarSkip, QK-norm, attention "
-                    "gate, ALiBi, ring attention or remat_policy='alternating'")
-            if c.ssm_state and min(c.ssm_conv, c.ssm_expand, c.ssm_period) < 1:
-                raise ValueError("ssm_state needs ssm_conv, ssm_expand and ssm_period >= 1")
-            if c.differential_attention and (c.num_heads % 2 or c.kv_heads % 2
-                                             or (c.num_heads // 2) % (c.kv_heads // 2)):
-                raise ValueError("differential_attention pairs the heads by parity: an "
-                                 "even number of query heads over an even number of key heads")
-            at = c.shared_from
-            if at is not None and not (
-                    0 <= at < c.num_layers - 2 and c.ssm_state
-                    and self._mixers[at] == ("ssm", "memory")
-                    and self._mixers[at + 1] == ("attn", "kv")
-                    and not self._kinds[at + 1][0]):
-                raise ValueError(
-                    f"shared_from {at}: a scan layer (l % ssm_period == 0) followed by a "
-                    "full attention layer, with layers after both")
 
     # -- init / specs --------------------------------------------------------
     def init(self, rng: jax.Array, dtype=jnp.float32) -> Params:
@@ -1189,10 +1020,6 @@ class TransformerLM:
             specs["mtp"]["blocks"] = stacked(block_specs)
         return specs
 
-    def _mixer_count(self, mixer: str) -> int:
-        """How many layers of a mixed stack have the mixer ``mixer``."""
-        return sum(1 for m, _ in self._mixers if m == mixer)
-
     def _layers_of(self, kind) -> Dict[str, Any]:
         """The layers of one block of a mixed stack: what every block has and
         its mixer's (``kind``: `run_plan`'s, the mixer third)."""
@@ -1245,488 +1072,13 @@ class TransformerLM:
         return checkpoint_name(self._layer(name)(block[name], h), name)
 
     def _layer(self, name: str):
-        """A block's layer by name, of either kind of block."""
-        for layers in (self._block_layers, self._dense_mlp_layers,
-                       *self._mixer_layers.values()):
-            if name in layers:
-                return layers[name]
-        raise KeyError(name)
+        """A layer every block has, or a leading dense block's, by name (a
+        mixer's own: ``Mixer.layers``)."""
+        if name in self._block_layers:
+            return self._block_layers[name]
+        return self._dense_mlp_layers[name]
 
-    def _attn(self, block: Params, h: jax.Array, positions: jax.Array,
-              attn_mask: Optional[jax.Array] = None,
-              window=None, rope: Optional[bool] = None) -> jax.Array:
-        """Attention over the (pre-normed, or raw for post-LN) input h.
-        ``attn_mask`` [B, S] (1 = real token) masks padding bidirectionally
-        via the segment-ids mechanism (encoders); with packed documents it
-        holds each position's document. ``window`` restricts each query to
-        the last ``window`` keys (mistral sliding window / gpt-neo local
-        layers / afmoe's sliding layers): a Python int where the layer's
-        kind is static (0 or None = global; the core then runs under the
-        scope ``core_window``), or a traced scalar (0 = global). ``rope``:
-        whether a rotary position turns this layer's queries and keys
-        (None: ``position`` says)."""
-        c = self.config
-        B, S, _ = h.shape
-        if c.attention == "latent":
-            return self._latent_attn(block, h, positions, attn_mask)
-        if c.attention == "eva":
-            return self._eva_attn(block, h, positions)
-        if rope is None:
-            rope = c.position == "rope"
-        if isinstance(window, int) and window <= 0:
-            window = None
-        with jax.named_scope("attn"):
-            q, k, v = self._qkv(block, h, positions, rope)
-            if c.diffusion:
-                # both copies of the row under one mask; attn_mask holds the
-                # clean copy's documents [B, L]
-                with jax.named_scope("core_blockdiff"):
-                    out = self._blockdiff_core(q, k, v, attn_mask)
-            else:
-                with jax.named_scope("core_window" if isinstance(window, int) else "core"):
-                    out = self._attn_core(q, k, v, attn_mask, window)
-            out = out.reshape(B, S, c.num_heads * c.head_dim)
-            if c.attn_gate:
-                out = self._gated(block, h, out)
-            with jax.named_scope("out"):
-                return self._project(block, "o_proj", out)
-
-    @scoped("qkv")
-    def _qkv(self, block: Params, h: jax.Array, positions: jax.Array, rope: bool):
-        """``mha``'s projected, normed and turned q [B, S, heads, head] and k,
-        v [B, S, kv heads, head]."""
-        c = self.config
-        B, S, _ = h.shape
-        # saved as projected: QK-norm's backward needs its input
-        q = self._project(block, "q_proj", h)
-        k = self._project(block, "k_proj", h)
-        per_head = c.qk_norm and c.qk_norm_per_head
-        if c.qk_norm and not per_head:
-            q = self._block_layers["q_norm"](block["q_norm"], q)
-            k = self._block_layers["k_norm"](block["k_norm"], k)
-        q = q.reshape(B, S, c.num_heads, c.head_dim)
-        k = k.reshape(B, S, c.kv_heads, c.head_dim)
-        if per_head:
-            q = self._block_layers["q_norm"](block["q_norm"], q)
-            k = self._block_layers["k_norm"](block["k_norm"], k)
-        v = self._project(block, "v_proj", h).reshape(B, S, c.kv_heads, c.head_dim)
-        if rope:
-            q = self._rotate(q, positions)
-            k = self._rotate(k, positions)
-        return q, k, v
-
-    def _attn_selected(self, block: Params, h: jax.Array, positions: jax.Array,
-                       documents: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
-        """``mha`` over a learned selection (``config.indexer``;
-        ``ops/transformer/attention.py`` has the equations) -> (the branch's
-        output, this layer's share of L_I: the rows' KL summed, over rows x
-        L). The indexer reads ``stop_gradient(h)`` and the selection has no
-        gradient, so the language-model loss reaches no indexer leaf; the KL's
-        target is the main attention's own distribution under stop_gradient,
-        so L_I reaches nothing else. Scopes: ``attn/indexer`` (projections and
-        scores), ``attn/select``, ``attn/core_dsa``, ``attn/indexer_kl``."""
-        from ..ops.transformer import attention
-        from ..runtime import topology as topo_mod
-        c, topk = self.config, self.config.indexer.topk
-        B, S, _ = h.shape
-        topo = topo_mod.get_topology() if topo_mod.is_initialized() else None
-        if topo is not None and topo.sequence_parallel_size > 1:
-            raise NotImplementedError(
-                "indexer: a query's selection is over the whole row, which "
-                f"sequence parallelism (sequence={topo.sequence_parallel_size}) divides")
-        if documents is None:
-            documents = jnp.zeros((B, S), jnp.int32)
-        scale = c.attn_scale or c.head_dim ** -0.5
-        with jax.named_scope("attn"):
-            q, k, v = self._qkv(block, h, positions, True)
-            q_idx, k_idx, w, picked = self.selection(block, h, positions, documents)
-            with jax.named_scope("core_dsa"):
-                out, lse = attention.selected_attention(
-                    q, k, v, picked, documents, topk, scale)
-            with jax.named_scope("indexer_kl"):
-                kl = attention.indexer_kl(
-                    q_idx, k_idx, w, *jax.lax.stop_gradient((q, k, lse)), picked,
-                    documents, float(scale)) / (B * S)
-            with jax.named_scope("out"):
-                return self._project(block, "o_proj", out.reshape(
-                    B, S, c.num_heads * c.head_dim)), kl
-
-    def selection(self, block: Params, h: jax.Array, positions: jax.Array,
-                  documents: jax.Array):
-        """A layer's indexer over its normed input ``h`` -> (q_idx [B, S, J,
-        d], k_idx [B, S, d], w [B, S, J] float32 and scaled, the selection as
-        the operand its readers unpack: bits, int8 [B, S / 8, S]
-        (``attention.pack_selection``), kept under the name ``dsa_mask``). No
-        gradient reaches ``h``."""
-        from ..ops.transformer import attention
-        c, ix = self.config, self.config.indexer
-        B, S, _ = h.shape
-        x = jax.lax.stop_gradient(h)
-        with jax.named_scope("indexer"):
-            at = positions[0] if positions.ndim == 3 else positions
-            turned = lambda a: nn.rotary_embedding(a, at, c.rope_theta, "half")
-            q_idx = turned(self._project(block, "indexer_q", x).reshape(
-                B, S, ix.heads, ix.head_dim))
-            k_idx = self._block_layers["indexer_k_norm"](
-                block["indexer_k_norm"], self._project(block, "indexer_k", x))
-            k_idx = turned(k_idx[:, :, None, :])[:, :, 0, :]
-            w = self._block_layers["indexer_w"](block["indexer_w"], x).astype(
-                jnp.float32) * (ix.heads ** -0.5 * ix.head_dim ** -0.5)
-        # (its two scopes are opened a block of queries at a time, inside)
-        picked = checkpoint_name(attention.dsa_select(
-            q_idx, k_idx, w, documents, ix.topk), "dsa_mask")
-        return q_idx, k_idx, w, picked
-
-    def _gated(self, block: Params, h: jax.Array, out: jax.Array) -> jax.Array:
-        """``out`` under the attention gate: times the sigmoid (float32) of
-        the sub-block's input through ``attn_gate``, element-wise."""
-        with jax.named_scope("gate"):
-            gate = checkpoint_name(self._block_layers["attn_gate"](
-                block["attn_gate"], h), "attn_gate")
-            return out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
-
-    def _latent_attn(self, block: Params, h: jax.Array, positions: jax.Array,
-                     documents: Optional[jax.Array] = None) -> jax.Array:
-        """Multi-head latent attention over the pre-normed ``h`` (config's
-        ``attention='latent'`` has the sizes): training form, the keys and
-        values decompressed for every token, so the attention core sees
-        plain heads and takes the route any model's takes (value heads
-        narrower than the key heads: ``flash_attention``'s two-width launch,
-        under the scope ``attn/core_mla``). ``documents``: each position's
-        packed document (`_attn`'s ``attn_mask``)."""
-        c = self.config
-        B, S, _ = h.shape
-        nh, nope, vd = c.num_heads, c.qk_nope_dim, c.v_head_dim
-        with jax.named_scope("attn"):
-            if c.q_latent_rank:
-                with jax.named_scope("latent"):
-                    # the compressed query: down, a norm, up to every head
-                    q_a = checkpoint_name(self._block_layers["q_a_proj"](
-                        block["q_a_proj"], h), "q_latent")
-                    q = self._project(block, "q_b_proj", self._block_layers["q_a_norm"](
-                        block["q_a_norm"], q_a)).reshape(B, S, nh, c.head_dim)
-            else:
-                with jax.named_scope("qkv"):
-                    q = self._project(block, "q_proj", h).reshape(B, S, nh, c.head_dim)
-            with jax.named_scope("latent"):
-                kv_a = checkpoint_name(
-                    self._block_layers["kv_a_proj"](block["kv_a_proj"], h), "kv_latent")
-                latent = self._block_layers["kv_a_norm"](
-                    block["kv_a_norm"], kv_a[..., :c.kv_latent_rank])
-                kv = checkpoint_name(
-                    self._block_layers["kv_b_proj"](block["kv_b_proj"], latent),
-                    "kv_up").reshape(B, S, nh, nope + vd)
-            with jax.named_scope("qkv"):
-                k_rope = jnp.broadcast_to(kv_a[:, :, None, c.kv_latent_rank:],
-                                          (B, S, nh, c.qk_rope_dim))
-                k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
-                v = kv[..., nope:]
-                if c.qk_norm:
-                    q = self._block_layers["q_norm"](block["q_norm"], q)
-                    k = self._block_layers["k_norm"](block["k_norm"], k)
-                q = self._rotate_tail(q, positions)
-                k = self._rotate_tail(k, positions)
-            # (values narrower than the keys: the two-width launches, their own scope)
-            with jax.named_scope("core" if vd == c.head_dim else "core_mla"):
-                scale = c.attn_scale or c.head_dim ** -0.5
-                if c.rope_scaling is not None:
-                    scale *= c.rope_scaling.softmax_scale ** 2
-                out = self._attn_core(q, k, v, documents, None, scale=scale)
-            out = out.reshape(B, S, nh * vd)
-            if c.attn_gate:
-                out = self._gated(block, h, out)
-            with jax.named_scope("out"):
-                return self._project(block, "o_proj", out)
-
-    def _eva_attn(self, block: Params, h: jax.Array,
-                  positions: jax.Array) -> jax.Array:
-        """EVA attention over the pre-normed ``h`` (config's
-        ``attention='eva'``): the projections and rope as any layer's, one
-        summary key and value a chunk under ``attn/eva_summaries`` (kept for
-        the backward under the names ``eva_kbar`` / ``eva_vbar``), the core
-        under ``attn/core_eva`` (``attention.eva_attention``). A row too long
-        for its heads at once (``eva_head_groups``) takes them a group at a
-        time: a head's attention reads no other head, and the output
-        projection is the float32 sum of the groups' parts."""
-        from ..runtime import topology as topo_mod
-        c = self.config
-        B, S, H = h.shape
-        topo = topo_mod.get_topology() if topo_mod.is_initialized() else None
-        if topo is not None and topo.sequence_parallel_size > 1:
-            raise NotImplementedError(
-                "attention='eva': windows and chunks are counted over the whole "
-                "row, which sequence parallelism "
-                f"(sequence={topo.sequence_parallel_size}) divides")
-        groups = eva_head_groups(B * S, c.num_heads, c.head_dim)
-        phi, mu = block["eva_phi"]["value"], block["eva_mu"]["value"]
-        with jax.named_scope("attn"):
-            if groups == 1:
-                with jax.named_scope("qkv"):
-                    q, k, v = (self._project(block, name, h)
-                               for name in ("q_proj", "k_proj", "v_proj"))
-                out = self._eva_heads(q, k, v, phi, mu, positions, named=True)
-                with jax.named_scope("out"):
-                    return self._project(block, "o_proj", out)
-            each = c.num_heads // groups
-            kernel = lambda name: block[name]["kernel"].astype(h.dtype)
-            columns = lambda name: kernel(name).reshape(
-                H, groups, each * c.head_dim).transpose(1, 0, 2)
-            by_group = lambda a: a.reshape(groups, each, c.head_dim)
-
-            def one_group(acc, weights):
-                wq, wk, wv, wo, phi, mu = weights
-                with jax.named_scope("qkv"):
-                    q, k, v = h @ wq, h @ wk, h @ wv
-                out = self._eva_heads(q, k, v, phi, mu, positions)
-                with jax.named_scope("out"):
-                    return acc + jnp.matmul(
-                        out, wo, preferred_element_type=jnp.float32), None
-
-            # a group's values are made again in its own backward: only the
-            # groups' shared input and the running sum outlive a group (and
-            # nothing in a group is named: the block's policy would keep a
-            # named value of every group, stacked)
-            acc, _ = jax.lax.scan(
-                jax.checkpoint(one_group), jnp.zeros(h.shape, jnp.float32),
-                (columns("q_proj"), columns("k_proj"), columns("v_proj"),
-                 kernel("o_proj").reshape(groups, each * c.head_dim, H),
-                 by_group(phi), by_group(mu)))
-            # the branch's output outlives every group: named as the ungrouped
-            # path names it, once a layer. Kept, it is the MLP's input in the
-            # block's recompute, which then drops the group scan whole (a
-            # group's forward runs twice a step, not three times)
-            return checkpoint_name(acc.astype(h.dtype), "o_proj")
-
-    def _eva_heads(self, q, k, v, phi, mu, positions,
-                   named: bool = False) -> jax.Array:
-        """Rope, summaries and the core over projected q, k, v ``[B, S,
-        heads x head]`` of some of the layer's heads (``phi``, ``mu`` theirs)
-        -> ``[B, S, heads x head]``. ``named``: the summaries are values the
-        backward may keep (``eva_kbar`` / ``eva_vbar``)."""
-        from ..ops.transformer.attention import eva_attention, eva_summaries
-        c = self.config
-        B, S, wide = q.shape
-        heads = lambda a: a.reshape(B, S, wide // c.head_dim, c.head_dim)
-        q, k, v = heads(q), heads(k), heads(v)
-        if c.position == "rope":
-            with jax.named_scope("qkv"):
-                q, k = self._rotate(q, positions), self._rotate(k, positions)
-        with jax.named_scope("eva_summaries"):
-            kbar, vbar = eva_summaries(k, v, phi, mu, c.eva_chunk)
-            if named:
-                kbar = checkpoint_name(kbar, "eva_kbar")
-                vbar = checkpoint_name(vbar, "eva_vbar")
-        with jax.named_scope("core_eva"):
-            out = eva_attention(q, k, v, kbar, vbar, c.eva_window, c.eva_chunk,
-                                scale=c.attn_scale)
-        return out.reshape(B, S, wide)
-
-    def _attn_core(self, q, k, v, attn_mask, window, scale=None, tag=None) -> jax.Array:
-        """Scores, softmax and values (XLA, flash, ring or Ulysses). ``tag``: a
-        two-width launch's name (``pallas_flash.TAGS``)."""
-        c = self.config
-        seg = attn_mask.astype(jnp.int32) if attn_mask is not None else None
-        kw = {} if tag is None else {"tag": tag}
-        scale = c.attn_scale if scale is None else scale
-        if scale is not None:
-            kw["scale"] = scale
-        if window is not None:
-            kw["window"] = window
-        if c.seq_parallel == "ring":
-            if seg is not None:
-                raise ValueError("ring attention does not support padding "
-                                 "masks (attention_mask)")
-            from ..sequence.ring_attention import ring_attention
-            return ring_attention(q, k, v, causal=True, scale=scale)
-        if self._alibi_slopes is not None:
-            kw["alibi_slopes"] = jnp.asarray(self._alibi_slopes)
-        return ulysses_attention(flash_attention, q, k, v, causal=c.causal,
-                                 segment_ids=seg, **kw)
-
-    def _blockdiff_core(self, q, k, v, documents) -> jax.Array:
-        """Scores, softmax and values of the clean and the noised copy under
-        the block-diffusion mask (``attention.blockdiff_attention``)."""
-        from ..ops.transformer.attention import blockdiff_attention
-        from ..runtime import topology as topo_mod
-        topo = topo_mod.get_topology() if topo_mod.is_initialized() else None
-        if topo is not None and topo.sequence_parallel_size > 1:
-            raise NotImplementedError(
-                "objective='block_diffusion': the mask over a clean and a "
-                "noised copy is not written for sequence parallelism "
-                f"(sequence={topo.sequence_parallel_size})")
-        return blockdiff_attention(q, k, v, self.config.block_length, documents,
-                                   scale=self.config.attn_scale)
-
-
-    # -- mixed stacks (``TransformerConfig.mixed``): scan layers, memory units,
-    # -- differential attention, a cross-decoder ---------------------------------
-    def _devices(self) -> int:
-        """The live mesh's devices (a Pallas launch is not partitioned)."""
-        from ..runtime import topology as topo_mod
-        return topo_mod.get_topology().world_size if topo_mod.is_initialized() else 1
-
-    @staticmethod
-    def _first_of_document(documents: Optional[jax.Array], shape) -> jax.Array:
-        """``[B, S]`` bool: a row's first position, and a packed document's
-        first token."""
-        B, S = shape
-        start = jnp.broadcast_to(jnp.arange(S)[None, :] == 0, (B, S))
-        if documents is None:
-            return start
-        return start | (documents != jnp.pad(documents[:, :-1], ((0, 0), (1, 0))))
-
-    def _short_conv(self, ssm: Params, a: jax.Array,
-                    documents: Optional[jax.Array]) -> jax.Array:
-        """A scan layer's causal depthwise convolution over ``a`` ``[B, S, Di]``,
-        float32: ``sum_s conv[taps - 1 - s] a[t - s] + conv_bias`` over the taps
-        s whose token ``t - s`` lies in the row and in t's document."""
-        B, S, _ = a.shape
-        taps = self.config.ssm_conv
-        w, a32 = ssm["conv"].astype(jnp.float32), a.astype(jnp.float32)
-        at = jnp.arange(S)[None, :]
-        out = a32 * w[taps - 1] + ssm["conv_bias"].astype(jnp.float32)
-        for s in range(1, min(taps, S)):
-            seen = at >= s
-            if documents is not None:
-                seen = seen & (documents == jnp.pad(documents[:, :S - s], ((0, 0), (s, 0))))
-            back = jnp.pad(a32[:, :S - s], ((0, 0), (s, 0), (0, 0)))
-            out = out + jnp.where(seen[..., None], back, 0.0) * w[taps - 1 - s]
-        return out
-
-    def _scan_mixer(self, block: Params, h: jax.Array,
-                    documents: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
-        """A selective-scan layer's mixer over the pre-normed ``h``
-        (``TransformerConfig.ssm_state`` has the equations) -> (the branch's
-        output, the scan's output m ``[B, S, Di]`` before the gate: the memory a
-        cross-decoder's units read). Scopes ``ssm/in`` (the in projection, the
-        convolution), ``ssm/scan`` (``x_proj``, ``dt_proj``, the scan) and
-        ``ssm/out`` (the gate and the out projection)."""
-        from ..ops.transformer import pallas_scan
-        c = self.config
-        B, S, _ = h.shape
-        Di, N, R = c.ssm_inner, c.ssm_state, c.ssm_rank
-        ssm = block["ssm"]
-        project = lambda name, x, keep: checkpoint_name(
-            self._layer(name)(block[name], x), keep)
-        with jax.named_scope("ssm"):
-            with jax.named_scope("in"):
-                az = project("in_proj", h, "ssm_in")
-                a = nn.silu(self._short_conv(ssm, az[..., :Di], documents)).astype(h.dtype)
-            with jax.named_scope("scan"):
-                rbc = project("x_proj", a, "ssm_x")
-                dt_raw = project("dt_proj", rbc[..., :R], "ssm_dt")
-                flat = lambda t: t.reshape((B * S,) + t.shape[2:])
-                m = pallas_scan.selective_scan(
-                    flat(a), flat(dt_raw), -jnp.exp(ssm["A_log"].astype(jnp.float32)),
-                    flat(rbc[..., R:R + N]), flat(rbc[..., R + N:]), ssm["D"],
-                    ssm["dt_bias"], flat(self._first_of_document(documents, (B, S))),
-                    devices=self._devices()).reshape(B, S, Di)
-            with jax.named_scope("out"):
-                y = self._layer("out_proj")(block["out_proj"], m * nn.silu(az[..., Di:]))
-        return y, m
-
-    def _memory_unit(self, block: Params, h: jax.Array, memory: jax.Array) -> jax.Array:
-        """A gated memory unit over the pre-normed ``h`` (arXiv:2507.06607,
-        section 2): ``(silu(h W_1) * m) W_2``, ``m`` an earlier layer's scan
-        output at the same token. Scope ``gmu``."""
-        with jax.named_scope("gmu"):
-            gate = nn.silu(checkpoint_name(
-                self._layer("gmu_in")(block["gmu_in"], h), "gmu_in"))
-            return self._layer("gmu_out")(block["gmu_out"], gate * memory)
-
-    def _mixer_attn(self, block: Params, h: jax.Array, positions: jax.Array,
-                    documents: Optional[jax.Array], window, rope: bool, lam_init,
-                    kv=None) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-        """A mixed stack's attention over the pre-normed ``h`` -> (the branch's
-        output, (k, v) as attended, ``[B, S, kv heads, head]``). ``kv``: an
-        earlier layer's keys and values (a cross layer: it projects queries
-        alone). ``lam_init``: differential attention's constant at this layer."""
-        c = self.config
-        B, S, _ = h.shape
-        nh, kvh, hd = c.num_heads, c.kv_heads, c.head_dim
-        if isinstance(window, int) and window <= 0:
-            window = None
-        with jax.named_scope("attn"):
-            with jax.named_scope("qkv"):
-                q = self._project(block, "q_proj", h).reshape(B, S, nh, hd)
-                if rope:
-                    q = self._rotate(q, positions)
-                if kv is None:
-                    made = self._project(block, "kv_proj", h)
-                    k = made[..., :kvh * hd].reshape(B, S, kvh, hd)
-                    v = made[..., kvh * hd:].reshape(B, S, kvh, hd)
-                    if rope:
-                        k = self._rotate(k, positions)
-                else:
-                    k, v = kv
-            if c.differential_attention:
-                with jax.named_scope("core_diff"):
-                    out = self._diff_core(block, q, k, v, documents, window, lam_init)
-            else:
-                with jax.named_scope("core_window" if window else "core"):
-                    out = self._attn_core(q, k, v, documents, window)
-            with jax.named_scope("out"):
-                return self._project(block, "o_proj", out.reshape(B, S, nh * hd)), (k, v)
-
-    def _diff_core(self, block: Params, q, k, v, documents, window, lam_init) -> jax.Array:
-        """Differential attention's core (``TransformerConfig.
-        differential_attention``): the heads paired by parity; each half ONE
-        softmax of its queries over its keys, multiplied into the pair's two
-        value heads side by side (twice the keys' width: the two-width launch
-        tagged ``"diff"``, two launches a layer); the halves subtracted under
-        lambda and normed a pair in float32."""
-        f32 = jnp.float32
-        values = jnp.concatenate([v[:, :, 0::2], v[:, :, 1::2]], axis=-1)
-        first, second = (
-            self._attn_core(q[:, :, i::2], k[:, :, i::2], values, documents, window,
-                            tag="diff").astype(f32) for i in (0, 1))
-        lam = block["diff_lambda"]["value"].astype(f32)
-        lam = (jnp.exp(jnp.sum(lam[0] * lam[1])) - jnp.exp(jnp.sum(lam[2] * lam[3]))
-               + lam_init)
-        out = self._layer("diff_norm")(block["diff_norm"], first - lam * second)
-        return (out * (1.0 - lam_init)).astype(q.dtype)
-
-    def _shared_dtype(self, what: str):
-        """The dtype a layer's keys and values (``"kv"``) or scan output
-        (``"memory"``) are handed on in: float32 where several layers read them,
-        so that their cotangents are summed in float32 (a reader casts its copy
-        down); the stream's own with one reader."""
-        readers = self._mixer_count("cross" if what == "kv" else "gmu")
-        return jnp.float32 if readers > 1 else self.config.dtype
-
-    @scoped("block")
-    def _mixed_block_fn(self, documents, carry, layer, kind):
-        """One block of a mixed stack. ``kind``: `run_plan`'s ``(window, rope,
-        mixer, hands)``; ``layer``: ``(block, keep, lambda_init)`` and, for a
-        memory unit or a cross layer, what it reads of an earlier layer. ->
-        (carry, what this layer hands on or None)."""
-        window, rope, mixer, hands = kind
-        block, keep, lam_init, *shared = layer
-        x, positions, aux_acc = carry
-        c = self.config
-        add = self._add_fp32 if c.residual_fp32 else (lambda x, y: x + y)
-        if shared:
-            with jax.named_scope("attn"), jax.named_scope("shared"):
-                shared = jax.tree.map(lambda t: t.astype(c.dtype), shared[0])
-        h1 = self._block_layers["ln_1"](block["ln_1"], x)
-        if mixer == "ssm":
-            out, handed = self._scan_mixer(block, h1, documents)
-        elif mixer == "gmu":
-            out, handed = self._memory_unit(block, h1, shared), None
-        else:
-            out, handed = self._mixer_attn(
-                block, h1, positions, documents, window, rope, lam_init,
-                kv=shared if mixer == "cross" else None)
-        if hands is None:
-            handed = None
-        else:
-            with jax.named_scope("attn"), jax.named_scope("shared"):
-                handed = jax.tree.map(lambda t: t.astype(self._shared_dtype(hands)), handed)
-        x = add(x, keep * out)
-        mlp_out, aux, _ = self._mlp(block, self._block_layers["ln_2"](block["ln_2"], x))
-        x = _c(add(x, keep * mlp_out), ACT_SPEC)
-        return (x, positions, aux_acc + keep * aux), handed
-
+    # -- a mixed stack's layout (``TransformerConfig.mixed``) ------------------
     @functools.cached_property
     def run_plan(self) -> Tuple[Tuple[Tuple[Any, ...], int], ...]:
         """How a mixed stack's layers run, from their static kinds ``(window,
@@ -1736,7 +1088,7 @@ class TransformerLM:
         runs; layers that repeat nothing join into one run of one repeat, run a
         block at a time. A decoder-hybrid-decoder stack of 32 layers: ``(scan,
         window) x 8``, ``(scan*, full) x 1``, ``(memory unit, cross) x 7``."""
-        kinds = tuple(k + m for k, m in zip(self._kinds, self._mixers))
+        kinds = tuple(k + m for k, m in zip(self._kinds, self._mixer_kinds))
         runs, i, n = [], 0, len(kinds)
         while i < n:
             p, r = 1, 1
@@ -1762,31 +1114,27 @@ class TransformerLM:
         float32, dtype). ``shapes_only``: reckon every kind of block for the
         remat budget (`_KeptBlock.reckon`) and run nothing."""
         c = self.config
-        lam_init = jnp.asarray([0.8 - 0.6 * math.exp(-0.3 * l)
-                                for l in range(c.num_layers)], jnp.float32)
-        one = lambda tree: jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), tree)
+        lam_init = mixers.lambda_init(c.num_layers)
         shared: Dict[str, Any] = {}
         carry, at = init, 0
         if shapes_only:
-            B, S = init[0].shape[:2]
-            kv = jax.ShapeDtypeStruct((B, S, c.kv_heads, c.head_dim), self._shared_dtype("kv"))
-            shared = {"kv": (kv, kv), "memory": jax.ShapeDtypeStruct(
-                (B, S, c.ssm_inner), self._shared_dtype("memory"))}
-        reads = {"gmu": "memory", "cross": "kv"}
+            shared = self._handed_shapes(*init[0].shape[:2])
+        # what a kind's layer reads of an earlier layer, as one more argument
+        args = lambda kind: tuple(shared[what] for what in (self._mixers[kind[2]].reads,)
+                                  if what is not None)
         for i, (unit, repeats) in enumerate(self.run_plan):
             p = len(unit)
             stacks = [runs[str(i)][str(j)] for j in range(p)]
             gates = [(keep[at + j:at + p * repeats:p], lam_init[at + j:at + p * repeats:p])
                      for j in range(p)]
-            args = lambda kind: ((shared[reads[kind[2]]],) if kind[2] in reads else ())
             if shapes_only:
                 for j, kind in enumerate(unit):
-                    block_of(kind).reckon(init, one((stacks[j],) + gates[j]) + args(kind))
+                    block_of(kind).reckon(
+                        init, _one_layer((stacks[j],) + gates[j]) + args(kind))
             elif repeats == 1:
                 for j, kind in enumerate(unit):
                     layer = jax.tree.map(lambda a: a[0], (stacks[j],) + gates[j])
-                    carry, handed = block_of(kind)(carry, layer + args(kind))
+                    carry, (_, handed) = block_of(kind)(carry, layer + args(kind))
                     if kind[3] is not None:
                         shared[kind[3]] = handed
             else:
@@ -1805,6 +1153,12 @@ class TransformerLM:
             at += p * repeats
         return carry
 
+    def _handed_shapes(self, B: int, S: int) -> Dict[str, Any]:
+        """What the boundary layers hand on over ``B`` rows of ``S`` tokens, by
+        its name, as shapes (``Mixer.handed_shape``): nothing in a plain stack."""
+        return {hands: self._mixers[name].handed_shape(B, S)
+                for name, hands in dict.fromkeys(self._mixer_kinds) if hands is not None}
+
     @property
     def moe_path(self) -> Optional[str]:
         """``"dropless"``, ``"capacity"`` or None (no experts): the path
@@ -1821,14 +1175,15 @@ class TransformerLM:
 
     def _aux_zero(self):
         """What the layers' carry starts from beside the stream: the MoE
-        accumulator, with an indexer the pair (that, L_I so far), with
-        hyper-connected streams the pair (that, H_res's error so far)."""
+        accumulator, with a mixer that has a loss of its own the pair (that,
+        the loss so far: an indexer's L_I), with hyper-connected streams the
+        pair (that, H_res's error so far)."""
         aux = self._moe_aux_zero()
-        if self.config.residual_streams > 1:
-            # (that, the largest distance so far of a row or column sum of a
-            # sub-layer's H_res from 1: `_hc_sublayer`)
+        if self.config.residual_streams > 1 or self._has_mixer_loss:
+            # (with streams: the largest distance so far of a row or column sum
+            # of a sub-layer's H_res from 1: `_hc_sublayer`)
             return aux, jnp.zeros((), jnp.float32)
-        return aux if self.config.indexer is None else (aux, jnp.zeros((), jnp.float32))
+        return aux
 
     @scoped("mlp")
     def _mlp(self, block: Params, h: jax.Array
@@ -1914,11 +1269,10 @@ class TransformerLM:
         ``pallas_hc.choose_route`` by the backend, the type, the shape and the
         live mesh's devices, and the kernel's row tile (None on the XLA route)."""
         from ..ops.transformer import pallas_hc
-        from ..runtime import topology as topo_mod
         c = self.config
         K = c.residual_streams * c.hidden_size
-        devices = topo_mod.get_topology().world_size if topo_mod.is_initialized() else 1
-        route = pallas_hc.choose_route(seq, K, dtype, jax.default_backend(), devices)
+        route = pallas_hc.choose_route(seq, K, dtype, jax.default_backend(),
+                                       mixers.devices())
         return route, pallas_hc.choose_tiles(seq, K).tm if route == "kernel" else None
 
     def _hc_streams(self, X: jax.Array):
@@ -1955,18 +1309,20 @@ class TransformerLM:
         return X, rest, err
 
     @scoped("block")   # norms and residual adds are "block" and nothing finer
-    def _block_fn(self, attn_mask, carry, block_and_keep, kind=None):
-        """One block. ``kind``: the layer's static (window, rope) of
-        ``_kinds`` (None: global attention, ``position`` says whether rope).
-        A third element of ``block_and_keep`` is a TRACED window instead
-        (the ZeRO-3 pipelined scan and ``remat_policy='alternating'``)."""
-        window, rope = kind or (None, None)
-        if len(block_and_keep) == 3:
-            block, keep, window = block_and_keep
-        else:
-            block, keep = block_and_keep
+    def _block_fn(self, attn_mask, carry, layer, kind=None):
+        """One block -> (carry, (the no-drop path's rows per expert or None,
+        what the layer hands on or None)). ``kind``: the layer's static
+        ``(window, rope)`` of ``_kinds`` (None: global attention, ``position``
+        says whether rope), in a mixed stack `run_plan`'s ``(window, rope,
+        mixer, hands)``. ``layer``: ``(block, keep)`` and what else the layer's
+        mixer is given (``Mixer.take``): in a mixed stack ``lambda_init`` and
+        what it reads of an earlier layer; the ZeRO-3 pipelined scan, one
+        program for every layer, still hands a TRACED window over this way."""
+        block, keep, *given = layer
         x, positions, aux_acc = carry
         c = self.config
+        mixer = self._mixer or self._mixers[kind[2]]    # (a mixed stack's kinds name theirs)
+        given = mixer.take(tuple(given))
         # keep: per-layer stochastic-depth gate (progressive layer drop,
         # reference runtime/progressive_layer_drop.py); 1.0 = layer active
         if c.norm_style == "post":
@@ -1974,18 +1330,23 @@ class TransformerLM:
             # gate mixes OUTSIDE the norms (keep*block(x) + (1-keep)*x) so a
             # dropped layer (keep=0, gates are binary draws) is a true
             # identity — gating inside would still double-normalize x.
-            h = self._block_layers["ln_1"](
-                block["ln_1"], x + self._attn(block, x, positions, attn_mask))
+            # (neither the layer's kind nor what it is given enters: as it was)
+            attn_out, handed, _ = mixer(block, x, positions, attn_mask)
+            h = self._block_layers["ln_1"](block["ln_1"], x + attn_out)
             mlp_out, aux, rows = self._mlp(block, h)
             y = self._block_layers["ln_2"](block["ln_2"], h + mlp_out)
             x = _c(keep * y + (1 - keep) * x, ACT_SPEC)
-            return (x, positions, aux_acc + keep * aux), rows
+            return (x, positions, aux_acc + keep * aux), (rows, handed)
         if c.residual_streams > 1:
             # x = vec(X), the n streams: each sub-layer reads a mix of them
             # and writes to all of them (`_hc_sublayer`)
-            x, _, err_attn = self._hc_sublayer(block["hc_attn"], x, lambda u: (
-                keep * self._attn(block, self._block_layers["ln_1"](block["ln_1"], u),
-                                  positions, attn_mask, window, rope),))
+            def attn(u):
+                out, handed, _ = mixer(
+                    block, self._block_layers["ln_1"](block["ln_1"], u),
+                    positions, attn_mask, kind, given)
+                return keep * out, handed
+
+            x, (handed,), err_attn = self._hc_sublayer(block["hc_attn"], x, attn)
             def mlp(u):
                 out, aux, rows = self._mlp(
                     block, self._block_layers["ln_2"](block["ln_2"], u))
@@ -1994,23 +1355,26 @@ class TransformerLM:
             x, (aux, rows), err_mlp = self._hc_sublayer(block["hc_mlp"], x, mlp)
             moe_acc, err_acc = aux_acc
             return (_c(x, ACT_SPEC), positions, (
-                moe_acc + keep * aux, jnp.maximum(err_acc, jnp.maximum(err_attn, err_mlp)))), rows
+                moe_acc + keep * aux, jnp.maximum(err_acc, jnp.maximum(err_attn, err_mlp)))), (
+                    rows, handed)
         if c.farskip:
             # x = (r_(i-1), r_(i-2)): attention reads the stream as it stood
             # before the sub-block in front of it, and so does the MLP
             near, far = x
-            after_attn = near + keep * self._attn(
-                block, self._block_layers["ln_1"](block["ln_1"], far), positions)
+            attn_out, handed, _ = mixer(
+                block, self._block_layers["ln_1"](block["ln_1"], far), positions, None)
+            after_attn = near + keep * attn_out
             mlp_out, aux, rows = self._mlp(
                 block, self._block_layers["ln_2"](block["ln_2"], near))
             x = (_c(after_attn + keep * mlp_out, ACT_SPEC), after_attn)
-            return (x, positions, aux_acc + keep * aux), rows
+            return (x, positions, aux_acc + keep * aux), (rows, handed)
         h1 = self._block_layers["ln_1"](block["ln_1"], x)
+        # (a mixer's own loss, here on: an indexer's KL, None elsewhere)
+        attn_out, handed, own = mixer(block, h1, positions, attn_mask, kind, given)
         if c.parallel_block:
             # falcon/phi residual form: both branches read the block INPUT —
             # through one shared norm (phi/falcon-7b) or per-branch norms
             # (falcon-40b new decoder)
-            attn_out = self._attn(block, h1, positions, attn_mask, window, rope)
             hm = (self._block_layers["ln_2"](block["ln_2"], x)
                   if c.parallel_norms else h1)
             mlp_out, aux, rows = self._mlp(block, hm)
@@ -2020,20 +1384,17 @@ class TransformerLM:
             post = ((lambda name, y: y) if c.norm_style != "sandwich"
                     else functools.partial(self._norm_post, block))
             add = self._add_fp32 if c.residual_fp32 else (lambda x, y: x + y)
-            if c.indexer is not None:
-                attn_out, kl = self._attn_selected(block, h1, positions, attn_mask)
-            else:
-                attn_out = self._attn(block, h1, positions, attn_mask, window, rope)
             x = add(x, keep * post("post_ln_1", attn_out))
             h2 = self._block_layers["ln_2"](block["ln_2"], x)
             mlp_out, aux, rows = self._mlp(block, h2)
             x = _c(add(x, keep * post("post_ln_2", mlp_out)), ACT_SPEC)
-            if c.indexer is not None:
-                moe_acc, kl_acc = aux_acc
-                return (x, positions, (moe_acc + keep * aux, kl_acc + keep * kl)), rows
+            if own is not None:
+                moe_acc, own_acc = aux_acc
+                return (x, positions, (moe_acc + keep * aux, own_acc + keep * own)), (
+                    rows, handed)
         # the scan stacks the no-drop path's rows per expert over the
         # layers ([layers, experts]); every other model's ys stay None
-        return (x, positions, aux_acc + keep * aux), rows
+        return (x, positions, aux_acc + keep * aux), (rows, handed)
 
     @staticmethod
     def _add_fp32(x: jax.Array, y: jax.Array) -> jax.Array:
@@ -2140,15 +1501,14 @@ class TransformerLM:
                      "pipelined scan)", self._BLOCK_APPLY_RUNS)
         carry = (x, positions, self._aux_zero())
         keep = jnp.asarray(keep, self.config.dtype)
-        packed = (block, keep) if window is None else (block, keep, window)
-        (x2, _, aux), _ = self._block_fn(attn_mask, carry, packed)
+        layer = (block, keep) if window is None else (block, keep, window)
+        (x2, _, aux), _ = self._block_fn(attn_mask, carry, layer)
         return x2, aux
 
     def scan_blocks_pipelined(self, blocks: Params, x: jax.Array,
                               positions: jax.Array, *, gather, scatter,
                               keep: Optional[jax.Array] = None,
                               attn_mask: Optional[jax.Array] = None,
-                              layers_per_step: int = 1,
                               prefetch_depth: int = 1,
                               comm_scope=None, comm_edge=None,
                               scatter_err=None):
@@ -2172,10 +1532,9 @@ class TransformerLM:
         of layer *l*'s grads is issued during layer *l−1*'s backward
         compute. Gradients come back dp-sharded, fp32, dp-averaged.
 
-        ``layers_per_step=2`` is the half-remat ('alternating') variant's
-        shape: the schedule pipelines two-layer bundles — half the
-        collective launches (bigger buckets) and half the saved boundary
-        activations, at the same per-layer recompute.
+        One layer a step. A step's leaves keep a leading dimension of one
+        (``[1, ...]``): the comm tree's buckets and error-feedback state are
+        laid out for it (``engine._build_zeropp_micro_overlap``).
 
         ``prefetch_depth=2`` (ISSUE 11; the overlap planner derives it
         when the committed map still shows exposed in-scan bytes at
@@ -2218,31 +1577,22 @@ class TransformerLM:
         scope = comm_scope or (lambda k: contextlib.nullcontext())
         edge = comm_edge or (lambda overlapped: contextlib.nullcontext())
         c = self.config
-        L = c.num_layers
-        lps = int(layers_per_step)
-        if lps < 1 or L % lps:
-            raise ValueError(f"layers_per_step={lps} must divide "
-                             f"num_layers={L}")
-        n_steps = L // lps
+        n_steps = L = c.num_layers
         keep = (jnp.ones((L,), c.dtype) if keep is None
                 else keep.astype(c.dtype))
         windows = (jnp.asarray(self._windows, jnp.int32)
                    if self._windows is not None else None)
-        bundle = lambda a: a.reshape((n_steps, lps) + a.shape[1:])
+        bundle = lambda a: a.reshape((n_steps, 1) + a.shape[1:])
         blocksb = jax.tree.map(bundle, blocks)
         keepb = bundle(keep)
         winb = bundle(windows) if windows is not None else None
         take = lambda t, i: jax.tree.map(lambda a: a[i], t)
 
         def unit_call(bp, xx, kb, wb):
-            aux = self._aux_zero()
-            for j in range(lps):
-                blk = jax.tree.map(lambda a: a[j], bp)
-                w = None if wb is None else wb[j]
-                xx, a = self.block_apply(blk, xx, positions, keep=kb[j],
-                                         attn_mask=attn_mask, window=w)
-                aux = aux + a
-            return xx, aux
+            xx, aux = self.block_apply(
+                jax.tree.map(lambda a: a[0], bp), xx, positions, keep=kb[0],
+                attn_mask=attn_mask, window=None if wb is None else wb[0])
+            return xx, self._aux_zero() + aux
 
         depth = int(prefetch_depth)
         if depth < 1:
@@ -2485,19 +1835,19 @@ class TransformerLM:
                                  f"streams; got {position_ids.shape}")
             positions = position_ids
 
+        documents = None
         if c.document_separator is not None:
             if attention_mask is not None:
                 raise ValueError("packed documents (document_separator) take "
                                  "no attention_mask: a row is full")
             attention_mask = documents = self._documents(input_ids)
-        block_fn = functools.partial(
-            self._mixed_block_fn if c.mixed else self._block_fn, attention_mask)
+        block_fn = functools.partial(self._block_fn, attention_mask)
         if layer_mask is None:
             keep = jnp.ones((c.num_layers,), c.dtype)
         else:
             keep = layer_mask.astype(c.dtype)
         dense = c.first_dense_layers
-        xs = None if c.mixed else (params["blocks"], keep[dense:])
+        after = keep[dense:]         # the gates of the layers `_stack` runs
         # FarSkip carries two streams: (r_(i-1), r_(i-2)), r_(-1) = r_0;
         # hyper-connections n, which start as n copies of the embedding
         init = ((x, x) if c.farskip else self._hc_start(x), positions, self._aux_zero())
@@ -2514,65 +1864,27 @@ class TransformerLM:
                     fn, c.remat_policy, blocks_in_all, remat_budget) if c.remat else fn
             return blocks[kind]
 
-        unit, _, tail = ((), 0, ()) if c.mixed else self.scan_plan
-        if c.mixed and remat_budget is not None:
+        if remat_budget is not None:
             # what outlives its layer and is never made again: the keys, the
-            # values and the scan output handed on, and their cotangents
-            B, S = input_ids.shape
-            remat_budget.outside_bytes += B * S * sum(
-                width * (jnp.dtype(self._shared_dtype(what)).itemsize + 4)
-                for what, width in (("kv", 2 * c.kv_heads * c.head_dim),
-                                    ("memory", c.ssm_inner))
-                if any(hands == what for _, hands in self._mixers))
+            # values and the scan output a boundary layer hands on, and their
+            # cotangents (float32)
+            remat_budget.outside_bytes += sum(
+                a.size * (a.dtype.itemsize + 4) for a in jax.tree.leaves(
+                    self._handed_shapes(*input_ids.shape)))
         if c.remat and c.remat_policy == KEEP_PRODUCTS:
             # every kind of block is reckoned before the first is decided:
             # they run one after another, so each is held to the largest
-            one = lambda tree: jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), tree)
-            if c.mixed:
-                self._scan_runs(init, params["runs"], keep, block_of, shapes_only=True)
             for i in range(dense):
                 block_of(self._kinds[i]).reckon(
-                    init, one((params["dense_blocks"], keep[:1])))
-            for kind in unit + tail:
-                block_of(kind).reckon(init, one(xs))
+                    init, _one_layer((params["dense_blocks"], keep[:1])))
+            self._stack(init, params, after, block_of, shapes_only=True)
             if with_mtp and c.mtp_layers:
-                block_of().reckon(init, one((params["mtp"]["blocks"], keep[:1])))
+                block_of().reckon(init, _one_layer((params["mtp"]["blocks"], keep[:1])))
 
         for i in range(dense):       # the leading dense layers, one by one
             layer = jax.tree.map(lambda a: a[i], params["dense_blocks"])
             init, _ = block_of(self._kinds[i])(init, (layer, keep[i]))
-        rows = None
-        if c.remat and c.remat_policy == "alternating":
-            # HALF-remat: scan over layer pairs, checkpointing only the
-            # first of each pair — the backward recomputes every other
-            # layer (half the recompute FLOPs of full remat) while the
-            # scan stores residuals for only half the layers (half the
-            # activation memory of no remat). The sweet spot when full
-            # activations don't fit but full recompute over-pays. The pairs
-            # are one program, so a layer's window rides the scan TRACED.
-            if self._windows is not None:
-                xs = xs + (jnp.asarray(self._windows, jnp.int32),)
-            ck_fn = jax.checkpoint(block_fn)
-
-            def pair_fn(carry, xs_pair):
-                carry, _ = ck_fn(carry, jax.tree.map(lambda a: a[0], xs_pair))
-                carry, _ = block_fn(carry, jax.tree.map(lambda a: a[1], xs_pair))
-                return carry, None
-
-            n_pairs = c.num_layers // 2
-            xs_even = jax.tree.map(
-                lambda a: a[:n_pairs * 2].reshape((n_pairs, 2) + a.shape[1:]),
-                xs)
-            (x, _, aux), _ = jax.lax.scan(pair_fn, init, xs_even)
-            if c.num_layers % 2:  # odd depth: last layer, checkpointed
-                (x, _, aux), _ = ck_fn(
-                    (x, positions, aux),
-                    jax.tree.map(lambda a: a[-1], xs))
-        elif c.mixed:
-            x, _, aux = self._scan_runs(init, params["runs"], keep, block_of)
-        else:
-            (x, _, aux), rows = self._scan_by_kind(init, xs, block_of)
+        (x, _, aux), rows = self._stack(init, params, after, block_of)
         if c.farskip:
             x = x[0]
         x = self._hc_collapse(x)
@@ -2595,7 +1907,7 @@ class TransformerLM:
                 merged = _c(merged, ACT_SPEC)
                 start = ((merged, merged) if c.farskip else self._hc_start(merged),
                          positions, aux)
-                (mtp_x, _, aux), mtp_rows = block_of()(
+                (mtp_x, _, aux), (mtp_rows, _) = block_of()(
                     start, (jax.tree.map(lambda a: a[0], mp["blocks"]),
                             jnp.ones((), c.dtype)))
                 if c.farskip:
@@ -2610,36 +1922,8 @@ class TransformerLM:
             stats = {**stats, "attn_hc_res_row_err": aux[1]}
         if c.document_separator is not None:
             stats = {**stats, **self._attn_tile_stats(documents)}
-        if c.ssm_state:
-            # (``engine.attn_last_step()["ssm_resets"]``: the times a scan layer's
-            # state started anew in the step's rows, a row's start or a document's)
-            stats = {**stats, "attn_ssm_resets": jnp.sum(self._first_of_document(
-                attention_mask if c.document_separator is not None else None,
-                input_ids.shape), dtype=jnp.int32)}
-        if c.indexer is not None:
-            # selected over visible pairs, from the documents alone: a query
-            # with v visible keys picks min(v, topk) of them in every layer
-            docs = (documents if c.document_separator is not None
-                    else jnp.zeros(input_ids.shape, jnp.int32))
-            from ..ops.transformer import attention, pallas_flash, pallas_indexer_kl
-            visible = attention.visible_counts(docs).astype(jnp.float32)
-            stats = {**stats, "attn_selected_share": jnp.sum(
-                jnp.minimum(visible, c.indexer.topk)) / jnp.sum(visible)}
-            # the tiles the KL's kernel and the selection's run a layer, of
-            # their grids', and the queries the selection finds a threshold for
-            tiles = {"kl": attention.kl_launch(self._attention_plan(*docs.shape),
-                                               docs.shape[1])[1],
-                     "select": attention.select_launch(
-                         docs.shape[1], jax.default_backend(), attention.attn_mode())[1]}
-            for name, tile in tiles.items():
-                if tile is not None:
-                    stats[f"dsa_{name}_tiles"] = jnp.stack([
-                        pallas_flash.tiles_run(docs, docs, tile)[1],
-                        jnp.int32(pallas_indexer_kl.tiles_of(*docs.shape, tile))])
-            if tiles["select"] is not None:
-                stats["dsa_select_rows"] = jnp.stack([
-                    jnp.sum(visible > c.indexer.topk, dtype=jnp.int32),
-                    jnp.int32(visible.size)])
+        for mixer in self._mixers.values():
+            stats = {**stats, **mixer.step_stats(documents, input_ids.shape)}
         return x, aux, stats, mtp_x
 
     def _hc_start(self, x: jax.Array) -> jax.Array:
@@ -2666,16 +1950,18 @@ class TransformerLM:
     def attn_tile_kinds(self) -> Tuple[Tuple[str, int], ...]:
         """The kinds of attention launch that a step's tile count
         (``attn_tiles`` of its statistics) has a line for, ``(name, window,
-        0 = none)``: none without packed documents; ``blockdiff`` under the
-        block-diffusion objective; else ``window`` for the layers' static
-        window (``window_<w>`` where they have several) and ``full``."""
+        0 = none)``: none without packed documents; a mixer's one kind where
+        it has one (``Mixer.tile_kind``: ``blockdiff`` under the
+        block-diffusion objective, ``dsa`` under a learned selection); else
+        ``window`` for the layers' static window (``window_<w>`` where they
+        have several) and ``full``."""
         c = self.config
         if c.document_separator is None:
             return ()
-        if c.diffusion:
-            return (("blockdiff", 0),)
-        if c.indexer is not None:
-            return (("dsa", 0),)
+        fixed = tuple(mixer.tile_kind for mixer in self._mixers.values()
+                      if mixer.tile_kind is not None)
+        if fixed:
+            return fixed
         windows = sorted({w for w, _ in self._kinds}, reverse=True)
         several = sum(map(bool, windows)) > 1
         return tuple(("full" if not w else f"window_{w}" if several else "window", w)
@@ -2693,7 +1979,7 @@ class TransformerLM:
         lines = []
         for _, window in self.attn_tile_kinds:
             # (``"pallas"``: the plan wherever the kernel CAN run)
-            plan = self._attention_plan(*documents.shape, window, mode="pallas")
+            plan = self._attending.plan(*documents.shape, window, mode="pallas")
             if plan.route != "kernel":
                 return {}
             (at,) = plan.launches
@@ -2707,40 +1993,6 @@ class TransformerLM:
                 for tile in (at.tiles.fwd, at.tiles.bwd)]))
         return {"attn_tiles": jnp.stack(lines)}
 
-    @property
-    def _mla_widths(self) -> bool:
-        """Whether the value heads are narrower (or wider) than the key heads:
-        latent attention's two-width launch (``flash_*_mla``)."""
-        c = self.config
-        return c.attention == "latent" and c.v_head_dim != c.head_dim
-
-    def _attention_plan(self, batch: int, seq: int, window: int = 0,
-                        mode: Optional[str] = None):
-        """The ``attention.Plan`` of one layer's call over ``batch`` whole rows
-        of ``seq`` tokens here, by the entry point the configuration takes
-        (``window``: the layer's static one; ``mode``: `attn_mode`'s, or given)."""
-        from ..ops.transformer import attention
-        c = self.config
-        mask = dict(causal=c.causal, window=window or None)
-        if c.diffusion:
-            mask = dict(blockdiff=c.block_length)
-        elif c.attention == "eva":
-            mask = dict(eva=(c.eva_window, c.eva_chunk))
-        elif c.indexer is not None:
-            mask = dict(selected=c.indexer.topk)
-        elif self._mla_widths:
-            mask["v_dim"] = c.v_head_dim
-        heads, kv_heads = c.num_heads, c.kv_heads
-        if c.differential_attention:
-            # ONE half's launch: half the heads, the pair's two value heads wide
-            heads, kv_heads = heads // 2, kv_heads // 2
-            mask.update(v_dim=2 * c.head_dim, tag="diff")
-        return attention.plan(
-            (batch, seq * self.rows_per_token, heads, c.head_dim),
-            (batch, seq, kv_heads, c.head_dim), jax.default_backend(),
-            attention.attn_mode() if mode is None else mode,
-            jnp.dtype(c.dtype).itemsize, **mask)
-
     def attention_records(self, batch: Optional[int] = None, seq: Optional[int] = None
                           ) -> Tuple[Dict[str, Any], Optional[Dict[str, Any]]]:
         """``(attn, diffusion)``: what an engine keeps as ``attn_totals`` and
@@ -2748,12 +2000,13 @@ class TransformerLM:
         another objective). Without a shape what the configuration says; with
         the rows and tokens a step is traced for, also each kind's route, how
         its backward makes dq and where its launches take the operands' heads
-        (``layout``), off the launches' own plans."""
+        (``layout``), off the launches' own plans. Each kind of mixer declares
+        and fills its own entry (``Mixer.record``); the lines by ``window`` /
+        ``full`` are read here, off the attending mixer's plan under its tag."""
         c = self.config
         # (a mixed stack: its attention layers alone)
-        attending = [w for (w, _), (mixer, _) in zip(
-            self._kinds, self._mixers or [("attn", None)] * c.num_layers)
-            if mixer in ("attn", "cross")]
+        attending = [w for (w, _), (name, _) in zip(self._kinds, self._mixer_kinds)
+                     if self._mixers[name].attends]
         windows = sorted({w for w in attending if w})
         layers = {"window": sum(1 for w in attending if w),
                   "full": sum(1 for w in attending if not w)}
@@ -2764,78 +2017,22 @@ class TransformerLM:
                 "route": {"window": None, "full": None},
                 "dq": {"window": None, "full": None},
                 "layout": {"window": None, "full": None}}
-        if c.attention == "eva":
-            attn["eva"] = {"window": c.eva_window, "chunk": c.eva_chunk,
-                           "summaries_a_row": None, "pred_heads": c.pred_heads,
-                           "route": None, "dq_local": None, "dq_far": None,
-                           "layout": None}
-        if c.indexer is not None:
-            from ..ops.transformer.attention import (attn_mode, kl_launch, packed_rows,
-                                                     select_launch)
-            attn["dsa"] = {"topk": c.indexer.topk, "indexer_heads": c.indexer.heads,
-                           "indexer_head_dim": c.indexer.head_dim, "route": None,
-                           "select": None, "select_tiles": None, "select_rows": None,
-                           "dq": None, "layout": None, "kl": None, "kl_tiles": None,
-                           "operand": "bits", "operand_bytes": None}
-        if self._mla_widths:
-            attn["mla"] = {"qk_dim": c.head_dim, "v_dim": c.v_head_dim,
-                           "q_rank": c.q_latent_rank, "kv_rank": c.kv_latent_rank,
-                           "route": None, "dq": None, "layout": None}
+        for mixer in self._mixers.values():
+            attn.update(mixer.record(batch, seq))
+        diffusion = attn.pop("diffusion", None)
         if c.residual_streams > 1:
             # (the streams' record rides here: an engine copies this dict whole)
             attn["hc"] = {"streams": c.residual_streams,
                           "sinkhorn_iters": c.hc_sinkhorn_iters,
                           "sublayers": 2 * (c.num_layers + c.mtp_layers),
                           "route": None, "tile_rows": None}
-        if c.differential_attention:
-            attn["diff"] = {"qk_dim": c.head_dim, "v_dim": 2 * c.head_dim,
-                            "launches_a_layer": 2,
-                            "shared_readers": self._mixer_count("cross")}
-        if c.ssm_state:
-            # (the scan layers' record rides here: an engine copies this dict whole)
-            attn["ssm"] = {"layers": self._mixer_count("ssm"),
-                           "memory_units": self._mixer_count("gmu"),
-                           "d_inner": c.ssm_inner, "d_state": c.ssm_state,
-                           "conv": c.ssm_conv, "dt_rank": c.ssm_rank,
-                           "route": None, "chunk": None, "tile": None}
-        diffusion = {"block_length": c.block_length, "rows_per_token": self.rows_per_token,
-                     "route": None, "dq": None, "layout": None} if c.diffusion else None
-        if seq is None:
-            return attn, diffusion
-        plans = {w: self._attention_plan(batch, seq, w) for w in [0] + windows}
-        tag = ("mla" if self._mla_widths else "diff" if c.differential_attention
-               else "flash")
-        if c.ssm_state:
-            from ..ops.transformer import pallas_scan
-            route = pallas_scan.choose_route(batch * seq, c.ssm_inner, c.ssm_state,
-                                             jax.default_backend(), self._devices())
-            kernel = route == "kernel"
-            attn["ssm"].update(
-                route=route, chunk=pallas_scan.CHUNK if kernel else pallas_scan.XLA_CHUNK,
-                tile=pallas_scan.choose_tile(c.ssm_inner, pallas_scan.CHUNK, c.ssm_state)
-                if kernel else None)
-        if c.residual_streams > 1:
-            route, tile_rows = self._hc_route(seq * self.rows_per_token, c.dtype)
-            attn["hc"].update(route=route, tile_rows=tile_rows)
-        if self._mla_widths:
-            attn["mla"].update(route=plans[0].route, dq=plans[0].dq("mla"),
-                               layout=plans[0].layout("mla"))
-        if c.diffusion:
-            diffusion.update(route=plans[0].route, dq=plans[0].dq("blockdiff"),
-                             layout=plans[0].layout("blockdiff"))
-        elif c.attention == "eva":
-            attn["eva"].update(
-                summaries_a_row=seq // c.eva_chunk, route=plans[0].route,
-                dq_local=plans[0].dq("eva_local"), dq_far=plans[0].dq("eva_far"),
-                layout=plans[0].layout("eva_local"))
-        elif c.indexer is not None:
-            attn["dsa"].update(route=plans[0].route, dq=plans[0].dq("dsa"),
-                               layout=plans[0].layout("dsa"),
-                               kl=kl_launch(plans[0], seq)[0],
-                               select=select_launch(
-                                   seq, jax.default_backend(), attn_mode())[0],
-                               operand_bytes=batch * packed_rows(seq) * seq)
-        else:
+            if seq is not None:
+                route, tile_rows = self._hc_route(seq * self.rows_per_token, c.dtype)
+                attn["hc"].update(route=route, tile_rows=tile_rows)
+        mixer = self._attending
+        if seq is not None and mixer is not None and mixer.tag is not None:
+            tag = mixer.tag
+            plans = {w: mixer.plan(batch, seq, w) for w in [0] + windows}
             # (sliding layers of several widths: the mode they share, else both)
             under = sorted({plans[w].dq(tag) or "" for w in windows})
             attn["route"] = {kind: plans[0].route if n else None
@@ -2851,15 +2048,11 @@ class TransformerLM:
     def traced_rows_records(self, stats: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
         """What a step's statistics add to ``attn_totals``, by its key there
         (an engine asks once, of the first step's: the rows the step was traced
-        for): a learned selection's ``kl_tiles`` and ``select_tiles``, ``[run,
-        of]`` tiles of one layer's ``indexer_kl_fwd`` and ``dsa_select`` launch
-        (``pallas_flash.tiles_run`` at each launch's tile), and
-        ``select_rows``, ``[thresholded, of]``: the queries with more than
-        ``topk`` visible keys; each where its launch is the kernel."""
-        found = {name: [int(n) for n in np.asarray(stats["dsa_" + name])]
-                 for name in ("kl_tiles", "select_tiles", "select_rows")
-                 if "dsa_" + name in stats}
-        return {"dsa": found} if found else {}
+        for): each mixer's own (``Mixer.traced_records``)."""
+        found: Dict[str, Dict[str, Any]] = {}
+        for mixer in self._mixers.values():
+            found.update(mixer.traced_records(stats))
+        return found
 
     def expert_records(self, batch: Optional[int] = None, seq: Optional[int] = None,
                        *, expert_layers: int = 0, dtype=None, devices: int = 1,
@@ -2911,8 +2104,8 @@ class TransformerLM:
         share of block diffusion, packed documents' count of tiles."""
         return (self.moe_path == "dropless" or self.config.diffusion
                 or self.config.document_separator is not None
-                or self.config.indexer is not None
-                or self.config.residual_streams > 1 or bool(self.config.ssm_state))
+                or self.config.residual_streams > 1
+                or any(mixer.has_step_stats for mixer in self._mixers.values()))
 
     @functools.cached_property
     def scan_plan(self) -> Tuple[Tuple[Any, ...], int, Tuple[Any, ...]]:
@@ -2930,6 +2123,23 @@ class TransformerLM:
                       if all(kinds[i] == kinds[i - p] for i in range(p, n)))
         return kinds[:period], n // period, kinds[n - n % period:]
 
+    def _stack(self, init, params: Params, keep: jax.Array, block_of,
+               shapes_only: bool = False):
+        """The layers after the leading dense ones by the stack's layout,
+        the ONE way through them: ``params["runs"]`` as `run_plan` lays a
+        mixed stack out (`_scan_runs`), else ``params["blocks"]`` as
+        `scan_plan` does (`_scan_by_kind`) -> (carry, the no-drop path's rows
+        per expert or None). ``keep``: those layers' gates. ``shapes_only``: reckon every kind of block for
+        the remat budget (`_KeptBlock.reckon`) and run nothing."""
+        if self.config.mixed:
+            return self._scan_runs(init, params["runs"], keep, block_of, shapes_only), None
+        xs = (params["blocks"], keep)
+        if not shapes_only:
+            return self._scan_by_kind(init, xs, block_of)
+        unit, _, tail = self.scan_plan
+        for kind in unit + tail:
+            block_of(kind).reckon(init, _one_layer(xs))
+
     def _scan_by_kind(self, init, xs, block_of):
         """The layer scan over ``xs`` (stacked blocks and their keep gates)
         as ``scan_plan`` lays it out, ``block_of(kind)`` a layer's function
@@ -2939,12 +2149,12 @@ class TransformerLM:
         fns = [block_of(kind) for kind in unit]
         p, n = len(unit), len(unit) * repeats
         if p == 1:
-            carry, rows = jax.lax.scan(fns[0], init, xs)
+            carry, (rows, _) = jax.lax.scan(fns[0], init, xs)
         else:
             def unit_fn(carry, xs_unit):
                 out = []
                 for i, fn in enumerate(fns):
-                    carry, r = fn(carry, jax.tree.map(lambda a: a[i], xs_unit))
+                    carry, (r, _) = fn(carry, jax.tree.map(lambda a: a[i], xs_unit))
                     out.append(r)
                 return carry, None if out[0] is None else jnp.stack(out)
 
@@ -2953,7 +2163,7 @@ class TransformerLM:
             if rows is not None:
                 rows = rows.reshape((n,) + rows.shape[2:])
         for i, kind in enumerate(tail):
-            carry, r = block_of(kind)(carry, jax.tree.map(lambda a: a[n + i], xs))
+            carry, (r, _) = block_of(kind)(carry, jax.tree.map(lambda a: a[n + i], xs))
             if rows is not None:
                 rows = jnp.concatenate([rows, r[None]], axis=0)
         return carry, rows
@@ -3032,10 +2242,11 @@ class TransformerLM:
         moe = self.config.moe
         if self.config.residual_streams > 1:
             aux = aux[0]       # (the pair's other half is a statistic, no loss)
-        if self.config.indexer is not None:
-            # the pair (the MoE accumulator, L_I): L_I counts once (coefficient 1)
-            aux, kl = aux
-            loss = loss + kl
+        if isinstance(aux, tuple):
+            # the pair (the MoE accumulator, a mixer's own loss: an indexer's
+            # L_I), which counts once (coefficient 1)
+            aux, own = aux
+            loss = loss + own
         if moe is None:
             return loss
         if moe.router == "sigmoid_bias":
@@ -3096,8 +2307,8 @@ class TransformerLM:
         if mtp_x is None:
             loss = self.head_loss(params, x, labels, extra_mask=mask,
                                   slices=head_slices(self.config, remat_budget, batch["input_ids"]))
-            if c.indexer is not None:       # the two terms of L_LM + L_I, reported
-                stats = {**stats, "attn_lm_loss": loss, "attn_indexer_kl": aux[1]}
+            for mixer in self._mixers.values():
+                stats = {**stats, **mixer.loss_stats(loss, aux)}
             return self.combine_aux(loss, aux), stats
         later = lambda a, fill: jnp.pad(a[:, 1:], ((0, 0), (0, 1)),
                                         constant_values=fill)

@@ -75,9 +75,9 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
     vt = v.transpose(0, 2, 1, 3)
     logits = jnp.einsum("bhgqd,bhkd->bhgqk", qt, kt,
                         preferred_element_type=jnp.float32) * scale
-    # named for the attention-only remat policy (models/transformer.py
-    # "attention_only"): the [B, H, Sq, Sk] buffers are the ONLY tensors
-    # recomputed in backward — everything else is saved
+    # named so that a block's policy can tell the [B, H, Sq, Sk] buffers from
+    # what it keeps: ``checkpointing.SAVE_ORDER`` does not list the name, so
+    # they are never a candidate and are made again in the backward
     logits = checkpoint_name(logits, "attn_big")
     # q_offset: absolute position of q row 0 (the chunked path passes the
     # chunk's start); default = bottom-right alignment for Sq < k_len

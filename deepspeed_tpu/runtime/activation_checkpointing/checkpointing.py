@@ -73,15 +73,6 @@ POLICIES = {
     # save nothing; recompute everything (classic gradient checkpointing)
     "full": None,
     "nothing_saveable": None,
-    # the pair scan of models/transformer.py checkpoints every other layer
-    # whole; where a block stands alone (a pipeline stage) that is "full"
-    "alternating": None,
-    # recompute ONLY the [B, H, S, S] buffers of the XLA attention routes
-    # (named "attn_big" in ops/transformer/attention.py) and save everything
-    # else. Under the flash kernel no such buffer exists: its custom-VJP
-    # residuals are O(S) (q, k, v, the output and the row LSE)
-    "attention_only": lambda: jax.checkpoint_policies
-    .save_anything_except_these_names("attn_big"),
     # save matmul outputs (skip recomputing the big GEMMs)
     "dots_saveable": "dots_saveable",
     "checkpoint_dots": "dots_saveable",
@@ -125,14 +116,17 @@ def is_configured() -> bool:
 
 def resolve_policy(name: Optional[str]):
     """An explicit policy name -> what ``jax.checkpoint(policy=)`` takes:
-    a row of ``POLICIES`` or any ``jax.checkpoint_policies`` name."""
+    a row of ``POLICIES`` or any ``jax.checkpoint_policies`` name; another
+    name is refused with the names there are."""
     if not name:
         name = _CONFIG["policy"]
     mapped = POLICIES.get(name, name)
     if mapped is None:
         return None
-    if callable(mapped):
-        return mapped()
+    if not hasattr(jax.checkpoint_policies, mapped):
+        raise ValueError(
+            f"remat policy {name!r} is none of {[KEEP_PRODUCTS, *POLICIES]} "
+            "and no name of jax.checkpoint_policies")
     return getattr(jax.checkpoint_policies, mapped)
 
 
@@ -192,8 +186,8 @@ def resolve_policy(name: Optional[str]):
 #: of them): ``indexer_kl_*``, the three gradients the KL's forward takes in its
 #: one pass over every head's scores (45 MB a layer at 16,384 rows; made again
 #: they cost that whole pass, 2.8 TFLOP a layer in XLA); ``dsa_mask``, the
-#: selection as the kernel reads it (int8 ``[rows, L, L]``, 268 MB a layer;
-#: made again it costs the indexer's scores over every visible pair and the
+#: selection as its readers unpack it (bits, int8 ``[B, S / 8, S]``, 33.6 MB a
+#: layer at the Keye cell's row, since PR 50; made again it costs the indexer's scores over every visible pair and the
 #: threshold); the selected launch's pair ``attn_lse_dsa`` / ``attn_o_dsa`` as
 #: the plain launch's pair. The indexer's two projections (``indexer_q``,
 #: ``indexer_k``) are products over the hidden size and stand with ``q_proj``.

@@ -1807,27 +1807,24 @@ class DeepSpeedEngine:
         ag_bucket = plan.allgather_bucket or zc.allgather_bucket_size
         rs_bucket = plan.reduce_bucket or zc.reduce_bucket_size
 
-        c = model.config
-        L = int(c.num_layers)
-        # half-remat variant: the 'alternating' scan pipelines two-layer
-        # bundles (half the launches and boundary activations)
-        lps = 2 if (getattr(c, "remat_policy", None) == "alternating"
-                    and L % 2 == 0 and L >= 2) else 1
-        n_steps = L // lps
+        # one layer a step of the pipelined scan
+        n_steps = L = int(model.config.num_layers)
 
         def split(tree):
             rest = {k: v for k, v in tree.items() if k != "blocks"}
             return rest, tree["blocks"]
 
         def bundle_tree(tree, drop_layer_dim):
-            """Stacked [L, ...] leaves -> per-step bundle view [lps, ...]:
-            specs drop the layer dim and gain a leading None; structs lose
-            the layer dim for the per-layer shape."""
+            """Stacked [L, ...] leaves -> a step's view [1, ...] (the comm
+            tree's buckets and error-feedback state are laid out for a
+            leading dimension of the layers a step): specs drop the layer
+            dim and gain a leading None; structs lose the layer dim for the
+            per-layer shape."""
             if drop_layer_dim == "spec":
                 return jax.tree.map(lambda s: P(*((None,) + tuple(s)[1:])),
                                     tree, is_leaf=is_p)
             return jax.tree.map(
-                lambda l: jax.ShapeDtypeStruct((lps,) + tuple(l.shape)[1:],
+                lambda l: jax.ShapeDtypeStruct((1,) + tuple(l.shape)[1:],
                                                l.dtype), tree)
 
         rest_src_specs, blk_src_specs = split(gather_src_specs)
@@ -1895,7 +1892,7 @@ class DeepSpeedEngine:
                 f"accept single oversized launches")
         log_dist(
             f"zero overlap schedule ({'plan: ' + plan.summary() if planned else 'hand'}): "
-            f"{L} layers x {lps}/step; {blk_comm.plan_summary()}; "
+            f"{L} layers x 1/step; {blk_comm.plan_summary()}; "
             + "; ".join(cm.plan_summary() for cm in rest_comms), ranks=[0])
 
         # --- error-feedback residual carry (the planner owns the scan
@@ -1975,7 +1972,6 @@ class DeepSpeedEngine:
                 blocks, x0, positions,
                 gather=blk_comm.gather, scatter=blk_comm.scatter,
                 keep=layer_mask, attn_mask=batch.get("attention_mask"),
-                layers_per_step=lps,
                 # the plan deepens to 2 when the committed map still
                 # shows exposed in-scan bytes at depth 1 (ISSUE 11);
                 # plan-off keeps the hand schedule's depth 1 bitwise

@@ -93,10 +93,8 @@ class PipelineModule:
         if not self.config.remat:
             (x, _, aux), _ = jax.lax.scan(block_fn, init, stage_blocks)
             return x, aux
-        # the dense model's block policy from the same configuration (its
-        # "alternating" pair scan needs two layers; a stage's slice may be
-        # one, so it is full remat here). What a stage saves it saves once
-        # for every pass of the schedule through it
+        # the dense model's block policy from the same configuration. What
+        # a stage saves it saves once for every pass of the schedule through it
         layers = jax.tree.leaves(stage_blocks)[0].shape[0]
         ck_fn = checkpointed(block_fn, self.config.remat_policy,
                              layers * passes, remat_budget)

@@ -33,6 +33,13 @@ from deepspeed_tpu.runtime import topology as topo_mod  # noqa: E402
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: excluded from the tier-1 gate (-m 'not slow')")
+    if hasattr(config.option, "loadscopereorder"):
+        # pytest-xdist's ``--dist loadfile`` hands the files out by their NUMBER
+        # OF CASES, most first; in path order instead tests/benchmark/
+        # test_reference.py (one worker's 842 s of the driver's 1,365: 19 cases)
+        # starts in the run's first second, so a new reference costs the wall
+        # a sixth of its seconds and not all of them. Without xdist: no option.
+        config.option.loadscopereorder = False
 
 
 # ---------------------------------------------------------------------------

@@ -158,7 +158,7 @@ def test_a_key_outside_the_window_and_a_shift_of_all_positions(kind):
     model, block, h = one_layer(kind)
     window, rope = kind
     positions = jnp.arange(48)[None]
-    out = lambda h, p=positions: model._attn(block, h, p, None, window, rope)[0, -1]
+    out = lambda h, p=positions: model._mixer(block, h, p, None, (window, rope))[0][0, -1]
     base = out(h)
     moved = lambda at: float(jnp.abs(out(h.at[0, at].add(1.0)) - base).max())
     assert moved(47 - 15) > 1e-4                       # inside either kind's reach
@@ -288,7 +288,8 @@ def test_what_the_configuration_maps_to_and_refuses():
     with pytest.raises(NotImplementedError, match="rope_layers='windowed'"):
         jax.eval_shape(lambda b, x: model.block_apply(b, x, jnp.arange(8)[None]),
                        block, jnp.zeros((1, 8, 64)))
-    with pytest.raises(NotImplementedError, match="alternating"):
+    # a policy that is gone is refused by its name, with the names there are
+    with pytest.raises(ValueError, match="'alternating' is none of .*nothing_saveable"):
         TransformerLM(TransformerConfig(
             num_layers=4, position="rope", norm="rmsnorm", rope_layers="windowed",
             attn_windows=(8, 0, 8, 0), max_seq_len=64, remat_policy="alternating"))

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from benchmark import harness
-from deepspeed_tpu.models import evabyte_model, transformer
+from deepspeed_tpu.models import evabyte_model, mixers, transformer
 from deepspeed_tpu.models.registry import get_architecture
 from deepspeed_tpu.models.transformer import TransformerConfig, TransformerLM
 from deepspeed_tpu.ops.transformer import attention, pallas_flash
@@ -217,8 +217,8 @@ def test_eight_shifts_against_eight_loops():
 def two_head_groups(monkeypatch):
     """The tiny preset's four heads in two groups of two, as a 32,768-row
     step takes its 32 in eight groups of four."""
-    monkeypatch.setattr(transformer, "EVA_GROUP_ELEMENTS", 2 * 128 * 2 * 16)
-    assert transformer.eva_head_groups(256, 4, 16) == 2
+    monkeypatch.setattr(mixers, "EVA_GROUP_ELEMENTS", 2 * 128 * 2 * 16)
+    assert mixers.eva_head_groups(256, 4, 16) == 2
 
 
 @pytest.mark.parametrize("what", ["head_groups", "head_groups_no_room", "mlp_slices"])
@@ -270,7 +270,7 @@ def test_a_grouped_branch_names_its_output_once(parts, monkeypatch):
     h = jax.ShapeDtypeStruct((2, 128, 64), F32)
     positions = jnp.broadcast_to(jnp.arange(128), (2, 128))
     branch = lambda: jax.make_jaxpr(
-        lambda b, x: model._eva_attn(b, x, positions))(block, h).jaxpr
+        lambda b, x: model._mixer(b, x, positions)[0])(block, h).jaxpr
     listed = {n for group in checkpointing.SAVE_ORDER for n in group}
     candidates = lambda jaxpr: {n: b for n, b in checkpointing.named_bytes(
         jaxpr).items() if n in listed}
@@ -322,11 +322,11 @@ def test_a_kept_branch_output_drops_the_groups_from_the_blocks_recompute(
 def test_the_slicing_rules():
     """The cell's shape is sliced, no shape the benchmark had before is."""
     assert transformer.mlp_row_slices(32768, 11008) == 8
-    assert transformer.eva_head_groups(32768, 32, 128) == 8
+    assert mixers.eva_head_groups(32768, 32, 128) == 8
     for rows, width in ((16384, 10944), (16384, 6144), (4096, 5120), (128, 96)):
         assert transformer.mlp_row_slices(rows, width) == 1
-    assert transformer.eva_head_groups(128, 4, 16) == 1
-    assert transformer.eva_head_groups(2 ** 30, 3, 128) == 3     # never past one head
+    assert mixers.eva_head_groups(128, 4, 16) == 1
+    assert mixers.eva_head_groups(2 ** 30, 3, 128) == 3     # never past one head
 
 
 def leave_out(name):

@@ -208,7 +208,7 @@ def test_farskip_off_is_the_standard_block(parts):
     carry = (x, positions, model._aux_zero())
     (y, _, _), _ = model._block_fn(None, carry, (block, jnp.ones((), F32)))
     ln = lambda name, t: model._block_layers[name](block[name], t)
-    mid = x + model._attn(block, ln("ln_1", x), positions)
+    mid = x + model._mixer(block, ln("ln_1", x), positions, None)[0]
     want = mid + model._mlp(block, ln("ln_2", mid))[0]
     assert close(y, want, rel=1e-6)
 

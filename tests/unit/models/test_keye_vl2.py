@@ -92,7 +92,7 @@ def test_loss_and_gradient_match_the_reference(parts, wanted, route, monkeypatch
     model = adapter.model(cfg, remat=True, dtype="float32")
     assert model.config.indexer == IndexerConfig(heads=2, head_dim=8, topk=8)
     assert model.config.rope_sections == (2, 3, 3) and model.scan_plan == (((0, True),), 2, ())
-    plan = model._attention_plan(*ids.shape)
+    plan = model._mixer.plan(*ids.shape)
     assert plan.route == ("kernel" if route == "pallas" else "xla")
     # the KL beside it: through the Pallas pair where the flash pair is a kernel
     assert model.attention_records(*ids.shape)[0]["dsa"]["kl"] == plan.route
@@ -151,7 +151,7 @@ def test_the_selection_is_the_references(parts):
         block = jax.tree.map(lambda a: a[0], params["blocks"])
         x, _ = model.embed(params, ids)
         h = model._layer("ln_1")(block["ln_1"], x)
-        return model.selection(block, h, jnp.arange(64)[None], model._documents(ids))[3]
+        return model._mixer.selection(block, h, jnp.arange(64)[None], model._documents(ids))[3]
     with jax.default_matmul_precision("highest"):
         want, want1 = (np.asarray(a) for a in jax.jit(lambda p: (
             ref.selection(p, ids, cfg, 0), ref.selection(p, ids, cfg, 1)))(w))

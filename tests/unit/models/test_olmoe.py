@@ -123,10 +123,10 @@ def test_qk_norm_alone_is_its_equation():
     v = h @ block["v_proj"]["kernel"]
     qh = plain._rotate(q.reshape(2, 12, 4, 8), pos)
     kh = plain._rotate(k.reshape(2, 12, 2, 8), pos)
-    want = plain._attn_core(qh, kh, v.reshape(2, 12, 2, 8), None, None)
+    want = plain._mixer._attn_core(qh, kh, v.reshape(2, 12, 2, 8), None, None)
     want = want.reshape(2, 12, 32) @ block["o_proj"]["kernel"]
-    np.testing.assert_allclose(normed._attn(block, h, pos), want, atol=1e-5)
-    assert float(jnp.abs(plain._attn(block, h, pos) - want).max()) > 1e-2
+    np.testing.assert_allclose(normed._mixer(block, h, pos, None)[0], want, atol=1e-5)
+    assert float(jnp.abs(plain._mixer(block, h, pos, None)[0] - want).max()) > 1e-2
     logits, _ = normed.apply(params, ids)
     assert bool(jnp.isfinite(logits).all())
 

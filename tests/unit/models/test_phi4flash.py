@@ -185,7 +185,7 @@ def test_the_head_over_slices_is_the_head(parts):
 
 def kinds_of(model):
     return [(mixer, hands, bool(window)) for (window, _), (mixer, hands)
-            in zip(model._kinds, model._mixers)]
+            in zip(model._kinds, model._mixer_kinds)]
 
 
 def test_the_released_rule_at_8_and_at_32_layers():
@@ -206,8 +206,9 @@ def test_the_released_rule_at_8_and_at_32_layers():
     assert [(len(unit), n) for unit, n in full.run_plan] == [(2, 8), (2, 1), (2, 7)]
     assert {w for w, _ in full._kinds} == {0, 512}
     # seven readers: their cotangents are summed in float32
-    assert full._shared_dtype("kv") == full._shared_dtype("memory") == F32
-    assert tiny._shared_dtype("kv") == tiny.config.dtype
+    attn = full._mixers["attn"]
+    assert attn.shared_dtype("kv") == attn.shared_dtype("memory") == F32
+    assert tiny._mixers["attn"].shared_dtype("kv") == tiny.config.dtype
     params = jax.eval_shape(lambda: tiny.init(jax.random.PRNGKey(0)))
     assert sum(p.size for p in jax.tree.leaves(params)) == tiny.config.num_parameters()
 

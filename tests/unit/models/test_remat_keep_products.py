@@ -559,8 +559,7 @@ def test_backward_recomputes_no_grouped_matmul(monkeypatch):
     assert kept["sort"] == full["sort"] >= 1
 
 
-@pytest.mark.parametrize("policy", ["nothing_saveable", "full", "attention_only",
-                                    "alternating", "dots_saveable"])
+@pytest.mark.parametrize("policy", ["nothing_saveable", "full", "dots_saveable"])
 def test_explicit_policies_record_nothing(policy):
     """The knob keeps its meanings: under an explicit policy no value is
     chosen by budget, whatever the tags."""
@@ -568,8 +567,7 @@ def test_explicit_policies_record_nothing(policy):
     assert kept == {}
 
 
-@pytest.mark.parametrize("policy", [KEEP_PRODUCTS, "nothing_saveable",
-                                    "attention_only", "alternating"])
+@pytest.mark.parametrize("policy", [KEEP_PRODUCTS, "nothing_saveable"])
 def test_pipeline_stage_builds_the_same_policy(policy):
     """One builder: a pipeline stage's layer slice under each policy gives
     the gradients of the slice without remat, and under the default one the

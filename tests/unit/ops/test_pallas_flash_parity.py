@@ -180,29 +180,6 @@ def test_ring_lse_accumulation_equivalence(eight_devices):
                                    err_msg=nm, **GRAD_TOL)
 
 
-def test_remat_attention_only_policy_composes(eight_devices):
-    """jax.checkpoint with the attention_only policy (which names no
-    tensor inside the kernel) must recompute nothing quadratic and still
-    produce exact grads — the kernel's O(S) LSE residuals replace the
-    attn_big checkpoint."""
-    q, k, v = _qkv(S=128, kvH=2, seed=9)
-    scale = 1.0 / (q.shape[-1] ** 0.5)
-    policy = jax.checkpoint_policies.save_anything_except_these_names(
-        "attn_big")
-
-    @functools.partial(jax.checkpoint, policy=policy)
-    def block(q, k, v):
-        return flash_attention_kernel(q, k, v, causal=True, scale=scale,
-                                      interpret=True)
-
-    g_ck = jax.grad(lambda *a: jnp.sum(jnp.square(block(*a))),
-                    argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(lambda *a: jnp.sum(jnp.square(_xla_attention(
-        a[0], a[1], a[2], True, scale, None))), argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_ck, g_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **GRAD_TOL)
-
-
 def test_alibi_slopes_are_nondifferentiable_by_contract(eight_devices):
     """ALiBi slopes are a fixed positional schedule (Press et al. do not
     learn them); the kernel stop-gradients them EXPLICITLY — this test

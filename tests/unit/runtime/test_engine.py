@@ -108,18 +108,6 @@ def test_fused_step_matches_split(eight_devices, monkeypatch):
     assert e._jit_train_step is None
 
 
-def test_fused_step_alternating_remat(eight_devices):
-    """The 'alternating' half-remat policy trains and learns (odd depth
-    exercises the trailing checkpointed layer)."""
-    m = gpt2_model("gpt2-tiny", max_seq_len=32, vocab_size=256, remat=True,
-                   remat_policy="alternating", num_layers=3)
-    cfg = dict(BASE_CONFIG, zero_optimization={"stage": 1})
-    e, _, _, _ = deepspeed_tpu.initialize(model=m, config=cfg)
-    batch = make_batch()
-    losses = [float(e.train_batch(batch)) for _ in range(4)]
-    assert losses[-1] < losses[0], losses
-
-
 def test_bf16_training(eight_devices):
     config = dict(BASE_CONFIG, bf16={"enabled": True}, zero_optimization={"stage": 2})
     engine, _, _, _ = deepspeed_tpu.initialize(model=tiny_model(dtype=jnp.bfloat16), config=config)

@@ -7,7 +7,7 @@ float32 reference's agree at a benchmark cell's own size, on the chip.
 The cell's weights and its first batch from ``--seed`` as ``jobs/train.py``
 makes them; layer 0's selection by the reference (``reference.selection``:
 float32 at ``highest``, ``lax.top_k`` a query) and by the program
-(``TransformerLM.selection`` in the cell's dtype, in the form
+(``mixers.Selected.selection`` in the cell's dtype, in the form
 ``attention.select_launch`` names for the row and the back end: on the chip the
 one launch of ``pallas_select``, since PR 52; ``select`` says which) and, where
 that is the launch, by the XLA loop in its place as well
@@ -55,7 +55,7 @@ def main() -> int:
         x, positions = model.embed(params, ids)
         h = model._layer("ln_1")(block["ln_1"], x)
         return attention.unpack_selection(
-            model.selection(block, h, positions, model._documents(ids))[3], ids.shape[1])
+            model._mixer.selection(block, h, positions, model._documents(ids))[3], ids.shape[1])
     from deepspeed_tpu.ops.transformer import attention
     params = adapter.to_program(weights)
     form = attention.select_launch(ids.shape[1], jax.default_backend(), attention.attn_mode())[0]

@@ -24,6 +24,7 @@ TPU-first structure:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import functools
@@ -66,6 +67,18 @@ def _c(x, spec):
 def _one_layer(stacked):
     """One layer of a stacked ``[layers, ...]`` tree, as shapes."""
     return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), stacked)
+
+
+def _kind_label(key) -> str:
+    """A kind of block's line in the remat plan's report, from `_trunk`'s
+    key for it: ``mtp``, or ``[dense.][<mixer>.]<full | window<w>>[.hands_<what>]``."""
+    if key is None:
+        return "mtp"
+    if key[0] == "dense":
+        return "dense." + _kind_label(key[1])
+    window, _, *mixer = key
+    return ".".join([*mixer[:1], f"window{window}" if window else "full",
+                     *(f"hands_{what}" for what in mixer[1:] if what is not None)])
 
 
 def _token_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
@@ -1851,31 +1864,35 @@ class TransformerLM:
         # FarSkip carries two streams: (r_(i-1), r_(i-2)), r_(-1) = r_0;
         # hyper-connections n, which start as n copies of the embedding
         init = ((x, x) if c.farskip else self._hc_start(x), positions, self._aux_zero())
-        blocks_in_all = c.num_layers + (c.mtp_layers if with_mtp else 0)
+        layers_of = self._layers_of_kind(with_mtp)
         blocks: Dict[Any, Callable] = {}
 
-        def block_of(kind=None):
-            """``block_fn`` for a layer of ``kind`` under the remat policy:
-            one of all the step's blocks, in the same room. A kind is built
-            once (and under the default policy traced once a shape)."""
-            if kind not in blocks:
+        def block_of(kind=None, leading=False):
+            """``block_fn`` for a layer of ``kind`` under the remat policy
+            (``leading``: one of the dense layers in front, whose
+            parameters are another tree): one kind of the step's blocks, in
+            as many layers as the step runs of it. A kind is built once
+            (and under the default policy traced once a shape)."""
+            key = ("dense", kind) if leading else kind
+            if key not in blocks:
                 fn = functools.partial(block_fn, kind=kind)
-                blocks[kind] = checkpointed(
-                    fn, c.remat_policy, blocks_in_all, remat_budget) if c.remat else fn
-            return blocks[kind]
+                blocks[key] = checkpointed(
+                    fn, c.remat_policy, layers_of[key], remat_budget,
+                    _kind_label(key)) if c.remat else fn
+            return blocks[key]
 
         if remat_budget is not None:
             # what outlives its layer and is never made again: the keys, the
             # values and the scan output a boundary layer hands on, and their
-            # cotangents (float32)
-            remat_budget.outside_bytes += sum(
+            # cotangents (float32), live through every block between
+            remat_budget.handed_bytes = sum(
                 a.size * (a.dtype.itemsize + 4) for a in jax.tree.leaves(
                     self._handed_shapes(*input_ids.shape)))
         if c.remat and c.remat_policy == KEEP_PRODUCTS:
-            # every kind of block is reckoned before the first is decided:
-            # they run one after another, so each is held to the largest
+            # every kind of block is reckoned before the first is applied:
+            # the step has one decision, over all of them
             for i in range(dense):
-                block_of(self._kinds[i]).reckon(
+                block_of(self._kinds[i], leading=True).reckon(
                     init, _one_layer((params["dense_blocks"], keep[:1])))
             self._stack(init, params, after, block_of, shapes_only=True)
             if with_mtp and c.mtp_layers:
@@ -1883,7 +1900,7 @@ class TransformerLM:
 
         for i in range(dense):       # the leading dense layers, one by one
             layer = jax.tree.map(lambda a: a[i], params["dense_blocks"])
-            init, _ = block_of(self._kinds[i])(init, (layer, keep[i]))
+            init, _ = block_of(self._kinds[i], leading=True)(init, (layer, keep[i]))
         (x, _, aux), rows = self._stack(init, params, after, block_of)
         if c.farskip:
             x = x[0]
@@ -1925,6 +1942,25 @@ class TransformerLM:
         for mixer in self._mixers.values():
             stats = {**stats, **mixer.step_stats(documents, input_ids.shape)}
         return x, aux, stats, mtp_x
+
+    def _layers_of_kind(self, with_mtp: bool) -> Dict[Any, int]:
+        """How many of a step's blocks are of each kind, under `_trunk`'s
+        keys: a leading dense layer's ``("dense", kind)``, a stack layer's
+        kind (a mixed stack's as `run_plan` spells it), the prediction
+        module's None."""
+        c = self.config
+        layers = collections.Counter(
+            ("dense", kind) for kind in self._kinds[:c.first_dense_layers])
+        if c.mixed:
+            runs = self.run_plan
+        else:
+            unit, repeats, tail = self.scan_plan
+            runs = ((unit, repeats), (tail, 1))
+        for unit, repeats in runs:
+            layers.update({kind: repeats * unit.count(kind) for kind in unit})
+        if with_mtp and c.mtp_layers:
+            layers[None] = c.mtp_layers
+        return dict(layers)
 
     def _hc_start(self, x: jax.Array) -> jax.Array:
         """The stack's first carry from one stream ``[B, S, H]``: with

@@ -86,6 +86,19 @@ def _norm_dt(value) -> str:
     return str(value)
 
 
+def grad_accum_dtype(knob):
+    """``data_types.grad_accum_dtype`` -> the dtype gradients accumulate in."""
+    return jnp.bfloat16 if knob in ("bf16", "bfloat16") else jnp.float32
+
+
+def gradients_bytes(params, grad_dtype) -> int:
+    """The bytes of ``params``' gradients, each as wide as ``grad_dtype`` or
+    as its parameter, whichever is more (what the remat room sets aside;
+    ``tools/remat_plan.py`` reckons a cell's by the same rule)."""
+    width = jnp.dtype(grad_dtype).itemsize
+    return sum(p.size * max(p.dtype.itemsize, width) for p in jax.tree.leaves(params))
+
+
 class DeepSpeedEngine:
 
     def __init__(self,
@@ -137,9 +150,7 @@ class DeepSpeedEngine:
             self.param_dtype = jnp.bfloat16
         else:
             self.param_dtype = jnp.float32
-        self.grad_dtype = jnp.float32
-        if config.data_types_grad_accum_dtype in ("bf16", "bfloat16"):
-            self.grad_dtype = jnp.bfloat16
+        self.grad_dtype = grad_accum_dtype(config.data_types_grad_accum_dtype)
 
         # -- optimizer + schedule -------------------------------------------
         self.optimizer: Optimizer = build_optimizer(config.optimizer)
@@ -591,18 +602,28 @@ class DeepSpeedEngine:
                                   "elements_flat": 0}
         # What ``remat=True`` kept, kept with telemetry off and written
         # while a step is traced (checkpointing.KEEP_PRODUCTS): the
-        # policy, the names saved, their bytes over all layers, the bytes
-        # of every candidate, the room the device gave and the budget they
-        # were held to (None where the backend reports no memory), and the
-        # working set the budget was charged first: the largest block's
-        # forward and backward (``block_bytes``) and what lives outside the
-        # blocks (``outside_bytes``). ``policy`` None: no block was
-        # differentiated under that policy (remat off, an explicit policy,
-        # the ZeRO-3 overlap schedule).
+        # policy, the names the STEP saves (one choice over every kind of
+        # block), their bytes over the layers that make them, the bytes of
+        # every candidate, the room the device gave, the budget they were
+        # held to (None where the backend reports no memory) and what they
+        # take of it (``saved_cost_bytes``: a byte under the layer scan
+        # costs more than one in a layer that runs by itself), and what the
+        # budget was charged first: every block's input (``carries_bytes``)
+        # and the working set, the larger of the largest block's forward
+        # and backward (``block_bytes``) and what lives outside the blocks
+        # (``outside_bytes``) less the gradients (``grads_bytes``), with
+        # what layers hand on beside either (``handed_bytes``);
+        # ``saved_by_kind``: kind of block -> its layers, its block's bytes,
+        # the names of the choice it has and their bytes. ``policy`` None:
+        # no block was differentiated under that policy (remat off, an
+        # explicit policy, the ZeRO-3 overlap schedule).
         self.remat_totals = {"policy": None, "saved": (), "saved_bytes": 0,
                              "candidate_bytes": 0, "room_bytes": None,
                              "budget_bytes": None, "working_bytes": 0,
-                             "block_bytes": 0, "outside_bytes": 0}
+                             "block_bytes": 0, "outside_bytes": 0,
+                             "grads_bytes": 0, "handed_bytes": 0,
+                             "carries_bytes": 0, "saved_cost_bytes": 0,
+                             "saved_by_kind": {}}
         # A step's memory, kept with telemetry off and written ONCE, when the
         # first optimizer step has returned (``_account_memory``), from two
         # readings of the allocator: ``limit_bytes``, ``resident_bytes``,
@@ -1182,12 +1203,16 @@ class DeepSpeedEngine:
         if jax.process_count() > 1:
             return 0
         free = fullest["bytes_limit"] - fullest["bytes_in_use"]
-        width = jnp.dtype(self.grad_dtype).itemsize
-        grads = sum(p.size * max(p.dtype.itemsize, width)
-                    for p in jax.tree.leaves(self.state["params"]))
+        grads = self._grads_bytes
         log_dist(f"remat room: {free / 1e9:.2f} GB free a device, "
                  f"{grads / 1e9:.2f} GB of gradients", ranks=[0])
         return max(0, free - grads)
+
+    @functools.cached_property
+    def _grads_bytes(self) -> int:
+        """The gradients' bytes on one device, as `_remat_room_bytes` takes
+        them out of what is free."""
+        return gradients_bytes(self.state["params"], self.grad_dtype)
 
     def _remat_kw(self, local: bool = False) -> Dict[str, Any]:
         """The ``remat_budget=`` argument for a differentiated call of the
@@ -1200,11 +1225,13 @@ class DeepSpeedEngine:
         if "remat_budget" not in inspect.signature(self.model.loss).parameters:
             return {}
         room = self._remat_room_bytes
-        if room is not None and not local:
-            room *= int(np.prod([self.mesh.shape[a]
-                                 for a in BATCH_AXES + (SEQ_AXIS,)
-                                 if a in self.mesh.shape]))
-        return {"remat_budget": remat.Budget(room, self.remat_totals)}
+        if room is None:
+            return {"remat_budget": remat.Budget(None, self.remat_totals)}
+        ways = 1 if local else int(np.prod([self.mesh.shape[a]
+                                            for a in BATCH_AXES + (SEQ_AXIS,)
+                                            if a in self.mesh.shape]))
+        return {"remat_budget": remat.Budget(ways * room, self.remat_totals,
+                                             grads_bytes=ways * self._grads_bytes)}
 
     def _loss_and_stats(self, params, batch):
         """(loss, stats): the model's loss and, as a tuple of one, its
@@ -1230,11 +1257,19 @@ class DeepSpeedEngine:
                      f"{kept['saved_bytes'] / 1e6:.1f} of "
                      f"{kept['candidate_bytes'] / 1e6:.1f} MB"
                      + ("" if kept["budget_bytes"] is None else
-                        f" (budget {kept['budget_bytes'] / 1e6:.1f} MB"
+                        f" (costing {kept['saved_cost_bytes'] / 1e6:.1f} of a"
+                        f" budget of {kept['budget_bytes'] / 1e6:.1f} MB"
                         f" of a room of {kept['room_bytes'] / 1e6:.1f})")
                      + f"; working set {kept['working_bytes'] / 1e6:.1f} MB"
-                       f" = a block's {kept['block_bytes'] / 1e6:.1f}"
-                       f" + outside them {kept['outside_bytes'] / 1e6:.1f}",
+                       f" of a block's {kept['block_bytes'] / 1e6:.1f}"
+                       f" and outside them {kept['outside_bytes'] / 1e6:.1f}"
+                       f" less gradients {kept['grads_bytes'] / 1e6:.1f}"
+                       f" with {kept['handed_bytes'] / 1e6:.1f} handed on;"
+                       f" the blocks' inputs {kept['carries_bytes'] / 1e6:.1f}; "
+                     + "; ".join(
+                         f"{kind['layers']} x {label}: {', '.join(kind['saved']) or 'nothing'}"
+                         f" ({kind['saved_bytes'] / 1e6:.1f} MB)"
+                         for label, kind in kept["saved_by_kind"].items()),
                      ranks=[0])
         return out
 

@@ -21,8 +21,10 @@ reads itself and the program does not keep, and the allocator's counters.
 the cell's. One JSON line: what ``engine.remat_totals`` decided, the bytes
 in use at rest, ``true_peak_bytes`` = ``bytes_limit`` less the largest
 filling under which a step still ran, ``memory_totals`` and the candidates'
-differences. This is how ``checkpointing.WORKING_SHARE`` and ``STACK_COST``
-were fitted (PERF.md, PR 35). A fresh process a cell and seed (row 22(h)).
+differences. This is how ``checkpointing.STACK_COST`` was measured (PERF.md,
+PRs 30 and 35); since PR 60 the budget is checked against the account's own
+figure in nine cells and has no other fitted number. A fresh process a cell
+and seed (row 22(h)).
 """
 
 from __future__ import annotations

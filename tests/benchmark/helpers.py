@@ -5,6 +5,7 @@ print."""
 import glob
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -14,9 +15,11 @@ TINY_MANIFEST = os.path.join(DATA, "BENCHMARK.json")
 # a second architecture, as files only: rotary, RMSNorm, gated SiLU, GQA
 ROPE_MANIFEST = os.path.join(DATA, "BENCHMARK.rope-tiny.json")
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
-#: the names a configuration file counts its experts under (a closed set:
-#: test_manifest.py has the rule for cutting one)
-EXPERT_COUNT = {"num_experts", "num_local_experts", "n_routed_experts"}
+#: the names a configuration file counts its ROUTED experts under: a closed
+#: set, the five that the rows of the model-configs guide's catalog use
+#: (test_manifest.py has the rule for cutting one, and where the set is from)
+EXPERT_COUNT = {"num_experts", "num_local_experts", "n_routed_experts",
+                "moe_num_primary_experts", "moe_num_experts"}
 
 
 def data_manifests(data=DATA):
@@ -50,6 +53,25 @@ def reference_cells(data=DATA):
     return cells, found - set(cells)
 
 
+def one_lap_manifest(root, manifest, steps):
+    """A copy of one of the tests' presets under ``root`` whose traffic mixes
+    fetch the losses every ``steps`` steps. A window ends at the first fetch
+    past ``--seconds``, so under a ``--seconds`` no lap can meet it holds
+    exactly ``steps`` steps, however loaded the machine is: a window counted
+    in seconds holds as many as the machine's other work leaves room for,
+    and a toy's loss at its end is then the toss of a coin."""
+    shutil.copytree(os.path.join(DATA, "benchmark"), os.path.join(root, "benchmark"))
+    with open(manifest) as f:
+        m = json.load(f)
+    for w in m["workloads"]:
+        path = os.path.join(root, "benchmark", "traffic", w["traffic"] + ".json")
+        with open(path) as f:
+            mix = json.load(f)
+        with open(path, "w") as f:
+            json.dump(dict(mix, sync_every=steps), f)
+    return shutil.copy(manifest, os.path.join(root, "BENCHMARK.json"))
+
+
 def run_cli(script, *args, devices=1, cwd=REPO, timeout=600):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("BENCH_RUN", None)
@@ -68,3 +90,16 @@ def json_lines(proc):
         if line.startswith("{"):
             out.append(json.loads(line))
     return out
+
+
+def run_one_lap(root, manifest, cell, seed, steps=30):
+    """``run.py`` on one of the tests' presets over a window of exactly
+    ``steps`` steps (``one_lap_manifest``): its result line, and by how much
+    the window's last loss lies under the first step's."""
+    proc = run_cli("run.py", "--manifest", one_lap_manifest(str(root), manifest, steps),
+                   "--workload", cell, "--seed", seed, "--seconds", 0.001, "--trace", 0)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["attempted"] == steps
+    fell, = [c["value"] for c in line["checks"] if c["check"] == "last_loss_minus_first"]
+    return line, -fell

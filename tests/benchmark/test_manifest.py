@@ -28,7 +28,7 @@ MANIFESTS = {"real": os.path.join(REPO, "BENCHMARK.json"), **data_manifests()}
 # of the file.
 #
 # - Depth (``DEPTH``) and the dropouts, in any file.
-# - The number of experts (``EXPERT_COUNT``, a closed set of names) and
+# - The number of routed experts (``EXPERT_COUNT``, a closed set of names) and
 #   ``vocab_size``, ONLY in a file that states the deployment this chip is a
 #   share of, in one block:
 #
@@ -39,24 +39,65 @@ MANIFESTS = {"real": os.path.join(REPO, "BENCHMARK.json"), **data_manifests()}
 #
 #   ``published`` names the key, the file's value is the published one
 #   divided by ``chips_sharing_a_layer`` (rounded up), and the guide's floors
-#   hold: at least 8 experts held, at least an eighth of the vocabulary and,
-#   where the file has ``first_k_dense_replace``, at least four layers after
-#   the leading dense ones (``share_faults`` says which of these a file
-#   breaks). ``published`` may name an expert count, the vocabulary and the
-#   depth and nothing else. An expert count cut with no deployment stated is
-#   what the guide forbids, so without a sound block it is refused.
+#   hold: at least 8 experts held, at least an eighth of the vocabulary and at
+#   least four layers after the leading dense ones (``share_faults`` says
+#   which of these a file breaks). ``published`` may name an expert count,
+#   the vocabulary and the depth and nothing else. An expert count cut with
+#   no deployment stated is what the guide forbids, so without a sound block
+#   it is refused.
+# - The depth floor holds in EVERY file that states a share, whatever its
+#   family calls its leading dense layers: ``first_k_dense_replace`` or
+#   ``num_dense_layers`` (``DENSE_FIRST``) where the file has either, none
+#   where it has neither (every layer is then of the kinds that repeat). A
+#   share file with both of those keys, with more than one depth key or with
+#   none cannot say how many layers follow, and that is a fault. One reading
+#   is left as it was: a ``cpu_test_preset`` that names no leading dense
+#   layers may be shallower. Two toys of 2 layers stand in the tests' data, a
+#   toy has nothing published, and the real manifest may name no preset
+#   (``test_real_manifest_names_are_the_issues``).
 # - Nothing else, with or without a block: a hidden, intermediate, latent,
-#   state or projection size, a head size or count, a window, the positions
-#   or the experts per token is refused without being listed; a cut under
-#   another name has to be argued in a ``benchmark`` PR that adds it here.
+#   state or projection size, a head size or count, a window, the positions,
+#   the experts per token, a shared-expert count, a zero-compute expert count
+#   or a group count is refused without being listed; a cut under another
+#   name has to be argued in a ``benchmark`` PR that adds it here.
+#
+# Where ``EXPERT_COUNT`` is from: the top-level keys of the 88 rows of the
+# guide's catalog (``architectures.jsonl``), scanned for PR 61. The rows count
+# their routed experts under five names: ``n_routed_experts`` (45 rows),
+# ``num_experts`` (23), ``num_local_experts`` (6, one of them beside
+# ``num_experts``), ``moe_num_experts`` (3) and ``moe_num_primary_experts``
+# (1); the last two were missing until PR 61, so four rows could not state
+# their share. One more row counts 4 "dynamic" experts of the dense width
+# under a name of its own and needs none here: fewer than 8 cannot be
+# shared. Every row has one of the three depth names and ``vocab_size``. The
+# set stays closed: a name that merely holds "expert" is a per-token count
+# (``moe_num_active_primary_experts``, ``num_experts_per_token``,
+# ``experts_top_k``), a shared, null or zero-compute count, a group count or
+# a width (``expert_ffn_hidden_size``, ``moe_ffn_hidden_size``) as often as
+# it is a routed count, so no pattern is matched.
 #
 # What the share asks of the PROGRAM (an expert layer told which experts it
 # holds, a sliced embedding and head) and of the traffic (ids drawn from the
 # slice: ``jobs/train.py`` draws them from the file's ``vocab_size``) is the
 # ``model_config`` PR's that adds such a file (PERF.md, section 3).
 DEPTH = {"num_hidden_layers", "n_layer", "num_layers"}
+DENSE_FIRST = {"first_k_dense_replace", "num_dense_layers"}
 VOCABULARY = "vocab_size"
 SHARED = EXPERT_COUNT | {VOCABULARY}
+
+
+def depth_fault(cfg):
+    """Why a share file does not keep four layers after its leading dense
+    ones, or None (the rule is the comment above)."""
+    depth = [cfg[k] for k in DEPTH if k in cfg]
+    dense = [cfg[k] for k in DENSE_FIRST if k in cfg]
+    if len(depth) != 1 or len(dense) > 1:
+        return "one depth key and at most one key for the leading dense layers"
+    if type(depth[0]) is not int or any(type(d) is not int or d < 0 for d in dense):
+        return "the depth and the leading dense layers are whole numbers"
+    if depth[0] - sum(dense) < 4 and (dense or not cfg.get("cpu_test_preset")):
+        return "fewer than four layers after the leading dense ones"
+    return None
 
 
 def share_faults(cfg):
@@ -86,11 +127,8 @@ def share_faults(cfg):
             faults.append(f"{key}: fewer than 8 experts held")
         elif key == VOCABULARY and 8 * here < full:
             faults.append(f"{key}: less than an eighth of the vocabulary")
-    if "first_k_dense_replace" in cfg:
-        depth = [cfg[k] for k in DEPTH if k in cfg]
-        if len(depth) != 1 or depth[0] - cfg["first_k_dense_replace"] < 4:
-            faults.append("fewer than four layers after the leading dense ones")
-    return faults
+    fault = depth_fault(cfg)
+    return faults + ([fault] if fault else [])
 
 
 def may_be_reduced(key, cfg):
@@ -166,17 +204,43 @@ SHARE_CUT = {"n_routed_experts": 8, "vocab_size": 16112, "num_hidden_layers": 6,
                                      "num_hidden_layers": 27},
                        "held": "experts 0-7 of every expert layer, vocabulary rows "
                                "0-16111 (rank 0 of 8)"}}
+#: the cut ISSUE 61 sized, as far as the guard reads the file: 64 routed
+#: experts counted under ``moe_num_primary_experts``, every layer an expert
+#: layer (no key for leading dense ones), a period of 4 layers of 52,
+#: vocabulary 151,936; the four chips of one host share a layer
+PRIMARY_CUT = {"moe_num_primary_experts": 16, "moe_num_active_primary_experts": 6,
+               "vocab_size": 37984, "num_hidden_layers": 4,
+               "reduced": ["moe_num_primary_experts", "num_hidden_layers", "vocab_size"],
+               "share": {"chips_sharing_a_layer": 4,
+                         "published": {"moe_num_primary_experts": 64, "vocab_size": 151936,
+                                       "num_hidden_layers": 52},
+                         "held": "experts 0-15 of every layer, vocabulary rows 0-37983 "
+                                 "(rank 0 of 4)"}}
+#: the sized cuts, by how many chips share a layer
+CUTS = {"eight-share-a-layer": SHARE_CUT, "four-share-a-layer": PRIMARY_CUT}
 
 
 def a_file(key, share):
     """The least file that lists ``key`` in ``reduced``; with ``share``, one
-    that states an eighth of a deployment and names ``key`` as published."""
+    that states an eighth of a deployment and names ``key`` as published
+    (four layers deep, the least a share file may be)."""
     held = 16112 if key == VOCABULARY else 8
     cfg = {key: held, "reduced": [key]}
     if share:
         cfg["share"] = {"chips_sharing_a_layer": 8, "published": {key: 8 * held},
                         "held": "rank 0 of 8"}
+        if key not in DEPTH:
+            cfg["num_hidden_layers"] = 4
     return cfg
+
+
+#: what no block buys (each case runs without a share and with one that names
+#: the key as published): the per-token counts, the shared, zero-compute and
+#: group counts, and the widths and the window under the names of the rows
+#: that PR 61 made room for
+NEVER = ("moe_num_active_primary_experts", "num_experts_per_token", "experts_top_k",
+         "num_shared_experts", "zero_expert_num", "num_expert_groups", "moe_ffn_hidden_size",
+         "sliding_window_size", "expert_ffn_hidden_size")
 
 
 @pytest.mark.parametrize("key,share,allowed", [
@@ -196,6 +260,8 @@ def a_file(key, share):
     ("num_experts", False, False), ("num_experts", True, True),
     ("num_local_experts", False, False), ("num_local_experts", True, True),
     ("n_routed_experts", False, False), ("n_routed_experts", True, True),
+    ("moe_num_primary_experts", False, False), ("moe_num_primary_experts", True, True),
+    ("moe_num_experts", False, False), ("moe_num_experts", True, True),
     ("vocab_size", False, False), ("vocab_size", True, True),
     # no block buys a width: naming one as published spoils the block
     ("hidden_size", True, False), ("moe_intermediate_size", True, False),
@@ -203,17 +269,19 @@ def a_file(key, share):
     ("kv_lora_rank", True, False), ("num_attention_heads", True, False),
     ("sliding_window", True, False), ("max_position_embeddings", True, False),
     ("n_shared_experts", True, False),
+    *[(key, share, False) for key in NEVER for share in (False, True)],
     # depth and dropouts as ever, beside a block too
     ("num_hidden_layers", True, True), ("attn_pdrop", True, True)])
 def test_reduced_may_name_depth_and_never_a_width(key, share, allowed):
     assert may_be_reduced(key, a_file(key, share)) is allowed
 
 
-def spoiled(**changes):
-    """A copy of ``SHARE_CUT`` with top-level keys replaced; ``chips`` and
+def spoiled(cut="eight-share-a-layer", **changes):
+    """A copy of one of ``CUTS`` with top-level keys replaced; ``chips`` and
     ``published`` reach into the block (None takes a key out of it)."""
-    cfg = json.loads(json.dumps(SHARE_CUT))
-    cfg["share"]["chips_sharing_a_layer"] = changes.pop("chips", 8)
+    cfg = json.loads(json.dumps(CUTS[cut]))
+    cfg["share"]["chips_sharing_a_layer"] = changes.pop(
+        "chips", cfg["share"]["chips_sharing_a_layer"])
     cfg["share"]["published"].update(changes.pop("published", {}))
     cfg["share"]["published"] = {k: v for k, v in cfg["share"]["published"].items()
                                  if v is not None}
@@ -221,7 +289,7 @@ def spoiled(**changes):
     return cfg
 
 
-#: the spoiled variants of the cut, each with the fault that refuses it
+#: the spoiled variants of the cuts, each with the fault that refuses it
 SPOILED = {
     "a-sixteenth-of-the-vocabulary": (
         dict(chips=16, vocab_size=8056, published={"n_routed_experts": 128}), "an eighth"),
@@ -235,12 +303,70 @@ SPOILED = {
         dict(moe_intermediate_size=704, published={"moe_intermediate_size": 1408},
              reduced=SHARE_CUT["reduced"] + ["moe_intermediate_size"]),
         "moe_intermediate_size: published may name"),
+    # the second cut's: what eight chips would hold where four are stated,
+    # sixteen chips' four experts, one layer short of the floor where no key
+    # names leading dense layers, the per-token count named as published
+    "an-eighth-held-of-four-chips": (
+        dict(cut="four-share-a-layer", moe_num_primary_experts=8), "8 is not 64 over 4 chips"),
+    "four-primary-experts-held": (
+        dict(cut="four-share-a-layer", chips=16, moe_num_primary_experts=4, vocab_size=151936,
+             published={"vocab_size": None},
+             reduced=["moe_num_primary_experts", "num_hidden_layers"]), "fewer than 8 experts"),
+    "three-layers-and-no-dense-key": (
+        dict(cut="four-share-a-layer", num_hidden_layers=3), "fewer than four layers"),
+    "the-experts-per-token-smuggled-into-published": (
+        dict(cut="four-share-a-layer", moe_num_active_primary_experts=3,
+             published={"moe_num_active_primary_experts": 6},
+             reduced=PRIMARY_CUT["reduced"] + ["moe_num_active_primary_experts"]),
+        "moe_num_active_primary_experts: published may name"),
 }
 
 
-def test_the_sized_cut_is_a_sound_share():
-    assert share_faults(SHARE_CUT) == []
-    assert all(may_be_reduced(k, SHARE_CUT) for k in SHARE_CUT["reduced"])
+@pytest.mark.parametrize("cut", sorted(CUTS))
+def test_the_sized_cut_is_a_sound_share(cut):
+    cfg = CUTS[cut]
+    assert share_faults(cfg) == []
+    assert all(may_be_reduced(k, cfg) for k in cfg["reduced"])
+    # (the second cut's file has the per-token count, at its published 6)
+    assert not [k for k in NEVER if may_be_reduced(k, dict(cfg, reduced=cfg["reduced"] + [k]))]
+
+
+def deep(layers, preset=False, **dense):
+    """``PRIMARY_CUT`` at another depth, with keys for its leading dense
+    layers; ``preset`` makes it a CPU preset."""
+    return spoiled(cut="four-share-a-layer", num_hidden_layers=layers, cpu_test_preset=preset,
+                   **dense)
+
+
+@pytest.mark.parametrize("cfg,fault", [
+    # no key for leading dense layers: four layers, the new reading
+    (deep(4), None), (deep(3), "fewer than four layers"), (deep(1), "fewer than four layers"),
+    # an ``afmoe``-shaped file: two leading dense layers under its own key
+    (deep(6, num_dense_layers=2), None),
+    (deep(5, num_dense_layers=2), "fewer than four layers"),
+    # the reading that was there
+    (deep(5, first_k_dense_replace=1), None),
+    (deep(4, first_k_dense_replace=1), "fewer than four layers"),
+    # a file that cannot say how many layers follow
+    (deep(8, first_k_dense_replace=1, num_dense_layers=1), "at most one key"),
+    (deep(8, n_layer=8), "one depth key"),
+    ({k: v for k, v in deep(8).items() if k != "num_hidden_layers"}, "one depth key"),
+    (deep(8, num_dense_layers="2"), "whole numbers"),
+    (deep(8, first_k_dense_replace=-4), "whole numbers"),
+    # a CPU preset: a toy of two layers stands where it names no leading
+    # dense layers, and is held as any file where it names them
+    (deep(2, preset=True), None),
+    (deep(5, preset=True, num_dense_layers=2), "fewer than four layers"),
+    (deep(4, preset=True, first_k_dense_replace=1), "fewer than four layers"),
+    (deep(2, preset=True, n_layer=2), "one depth key")])
+def test_a_share_file_keeps_four_layers_after_its_leading_dense_ones(cfg, fault):
+    faults = share_faults(cfg)
+    if fault is None:
+        assert faults == [] and all(may_be_reduced(k, cfg) for k in cfg["reduced"])
+    else:
+        assert any(fault in f for f in faults), faults
+        assert not [k for k in SHARED if may_be_reduced(k, cfg)]
+        assert may_be_reduced("num_hidden_layers", cfg)
 
 
 @pytest.mark.parametrize("name", sorted(SPOILED))
@@ -370,12 +496,13 @@ def hold_to_the_contract(path):
 
 def write_share_cell(root, cfg):
     """A manifest with one cell on a configuration file that holds ``cfg``'s
-    keys, as files only: stub reference and adapter of their own, the real
-    benchmark's traffic file, metrics and readers."""
+    keys and no other of a model's, as files only: stub reference and adapter
+    of their own, the real benchmark's traffic file, metrics and readers."""
     source = "https://huggingface.co/some-org/some-moe/blob/main/config.json"
-    cfg = dict(cfg, name="moe-share", source=source, first_k_dense_replace=1,
+    chips = cfg.get("share", {}).get("chips_sharing_a_layer", "some")
+    cfg = dict(cfg, name="moe-share", source=source,
                reference="moe_share_stub", adapter="moe_share_stub", engine={}, limits={},
-               assumed={}, deployment="one of eight chips that share each layer",
+               assumed={}, deployment=f"one of {chips} chips that share each layer",
                stated_precision="bfloat16")
     bench = os.path.join(root, "benchmark")
     for kind, name, text in (
@@ -389,10 +516,10 @@ def write_share_cell(root, cfg):
     m["paths"] = ["benchmark"]
     m["configs"] = [{"name": "moe-share", "source": source, "reduced": cfg["reduced"],
                      "file": "benchmark/configs/moe-share.json",
-                     "why": "latent attention, 64 routed experts of which a chip holds 8"}]
+                     "why": "routed experts of which a chip holds its share"}]
     m["workloads"] = [{"name": "moe-share.train.seq4k", "config": "moe-share",
                        "traffic": "train.seq4k", "chips": 1,
-                       "why": "one chip's share of a layer divided over eight"}]
+                       "why": f"one chip's share of a layer divided over {chips}"}]
     for metric in metrics_of(m):
         if "workloads" in metric:
             metric["workloads"] = ["moe-share.train.seq4k"]
@@ -402,24 +529,34 @@ def write_share_cell(root, cfg):
     return path
 
 
-def test_a_chips_share_is_files_only(tmp_path):
+@pytest.mark.parametrize("cut", sorted(CUTS))
+def test_a_chips_share_is_files_only(tmp_path, cut):
     """What the next ``model_config`` PR brings for a model no chip holds
-    whole: a configuration file with the keys of ISSUE 31's cut (8 of 64
-    routed experts, 16,112 of 128,896 vocabulary rows, 6 of 27 layers, the
-    ``share`` block) and a manifest that lists them in ``reduced``. The
-    contract passes on it unedited."""
-    hold_to_the_contract(write_share_cell(str(tmp_path), SHARE_CUT))
+    whole: a configuration file with the keys of a sized cut and a manifest
+    that lists them in ``reduced``. ISSUE 31's (8 of 64 routed experts,
+    16,112 of 128,896 vocabulary rows, 6 of 27 layers, one of them dense,
+    eight chips) and ISSUE 61's (16 of 64 under ``moe_num_primary_experts``,
+    37,984 of 151,936 rows, 4 of 52 layers and none dense, four chips): the
+    contract passes on each unedited, and the file is written as the cut has
+    it, with no key put in for it."""
+    path = write_share_cell(str(tmp_path), CUTS[cut])
+    hold_to_the_contract(path)
+    written = load(os.path.join(str(tmp_path), "benchmark/configs/moe-share.json"))
+    assert {k: written[k] for k in CUTS[cut]} == CUTS[cut]
+    assert DENSE_FIRST & set(written) == DENSE_FIRST & set(CUTS[cut])
 
 
-@pytest.mark.parametrize("name", sorted(SPOILED) + ["no-share-stated"])
+@pytest.mark.parametrize(
+    "name", sorted(SPOILED) + [f"no-share-stated-{cut}" for cut in sorted(CUTS)])
 def test_a_spoiled_share_fails_the_contract(tmp_path, name):
     """The same files with the share spoiled, or with the experts and the
     vocabulary cut and no deployment stated: the contract refuses them."""
-    if name == "no-share-stated":
-        cfg, fault = {k: v for k, v in SHARE_CUT.items() if k != "share"}, "states no share"
-    else:
+    if name in SPOILED:
         changes, fault = SPOILED[name]
         cfg = spoiled(**changes)
+    else:
+        cut = CUTS[name[len("no-share-stated-"):]]
+        cfg, fault = {k: v for k, v in cut.items() if k != "share"}, "states no share"
     with pytest.raises(AssertionError, match=fault):
         hold_to_the_contract(write_share_cell(str(tmp_path), cfg))
 

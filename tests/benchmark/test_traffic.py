@@ -180,18 +180,25 @@ def test_a_mix_that_fixes_its_order_puts_every_seeds_documents_in_the_same_place
     assert all(np.array_equal(x, y) for x, y in zip(a, forty(fixed, 5)))
 
 
-def test_only_the_block_diffusion_mix_fixes_its_order():
-    """``train.bd8k`` (PR 47: a seed's order moved that cell's rate by up to
-    1.25 %); every other mix's batches stay what ``_plain_batches`` gives,
-    which reads no such key."""
-    found = {}
-    for name in sorted(os.listdir(os.path.join(REPO, "benchmark", "traffic"))):
-        with open(os.path.join(REPO, "benchmark", "traffic", name)) as f:
-            found[name] = json.load(f).get("order_seed")
-    assert {k for k, v in found.items() if v is not None} == {"train.bd8k.json"}
+def test_the_block_diffusion_mix_fixes_its_order_and_a_mix_without_the_key_follows_the_seed():
+    """``train.bd8k`` carries an ``order_seed`` (PR 47: a seed's order moved
+    that cell's rate by up to 1.25 %), so two seeds' windows end their
+    documents at the same places. Which other mixes carry one is theirs to
+    say (a later cell's mix brings its own file); a mix WITHOUT the key, here
+    ``train.seq4k``, still gives what ``_plain_batches`` gives, which reads no
+    such key, and its documents' places follow --seed."""
     mix, vocab = cell_mix("sdar-30b-a3b.train.bd8k")
+    assert "order_seed" in mix
     window = lambda seed: take(traffic.train_batches(mix, seed, vocab, 1), 72)
     assert separators(window(1), 18991) == separators(window(3_000_000_000), 18991)
+    plain, vocab = cell_mix("olmoe-1b-7b.train.seq4k")
+    assert "order_seed" not in plain
+    got = {seed: take(traffic.train_batches(plain, seed, vocab, 1), 12) for seed in SEEDS}
+    for seed in SEEDS:
+        want = take(_plain_batches(plain, seed, vocab, 1), 12)
+        assert all(np.array_equal(a, b) for a, b in zip(got[seed], want))
+    sep = plain["separator"] % vocab
+    assert separators(got[SEEDS[0]], sep) != separators(got[SEEDS[1]], sep)
 
 
 # ---------------------------------------------------------------------------

@@ -250,6 +250,9 @@ class MoEConfig:
     experts_held: Optional[Tuple[int, int]] = None
     seq_balance_coef: float = 0.0
     bias_update: float = 0.0
+    # what the no-drop router reads: the FFN's own pre-normed input, or the
+    # block's un-normed input, routed before the token mixer runs
+    router_input: str = "ffn_input"      # | 'block_input'
 
     def __post_init__(self):
         if self.capacity_factor is not None and self.z_loss_coef:
@@ -335,7 +338,8 @@ class TransformerConfig:
     # (afmoe: 32 heads of 128 on a stream of 2048); None => hidden / heads
     head_size: Optional[int] = None
     intermediate_size: Optional[int] = None  # None => 4*hidden
-    activation: str = "gelu"        # 'gelu' | 'gelu_exact' | 'relu' | 'silu_gated'
+    # 'gelu' | 'gelu_exact' | 'relu' | 'silu_gated' | 'relu_gated' (experts only)
+    activation: str = "gelu"
     norm: str = "layernorm"          # 'layernorm' | 'rmsnorm'
     norm_eps: float = 1e-5           # HF config layer_norm_epsilon / rms_norm_eps
     position: str = "learned"        # 'learned' | 'rope' | 'alibi'
@@ -590,7 +594,7 @@ class TransformerConfig:
         norms too) and each layer's mixer's own count (``Mixer.parameters``)."""
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
         ffn = self.ffn_size
-        if self.activation == "silu_gated":
+        if self.activation in ("silu_gated", "relu_gated"):
             mlp = 3 * h * ffn
         else:
             mlp = 2 * h * ffn
@@ -683,6 +687,10 @@ MECHANISMS: Dict[str, Tuple[Callable[["TransformerLM"], bool], str]] = {
                                  "the no-drop path carries two router losses and rows per expert"),
     "moe.bias_update": (lambda m: m.config.moe is not None and bool(m.config.moe.bias_update),
                         "the router's bias moves by the step's load, which loss_and_stats returns"),
+    "moe.router_input='block_input'": (
+        lambda m: m._routes_ahead,
+        "the router reads the block's un-normed input: the routing is made before the "
+        "token mixer runs and lives across it"),
 }
 
 
@@ -836,6 +844,7 @@ class TransformerLM:
                 router=c.moe.router, routed_scale=c.moe.routed_scale,
                 shared_width=c.moe.shared_width,
                 experts_held=c.moe.experts_held,
+                router_input=c.moe.router_input,
             )
             if c.first_dense_layers:
                 self._dense_mlp_layers = gated(c.dense_intermediate_size)
@@ -916,6 +925,15 @@ class TransformerLM:
                                 or c.parallel_block):
             raise ValueError("residual_fp32 is written for sequential pre-norm "
                              "or sandwich blocks")
+        if c.activation == "relu_gated" and c.moe is None:
+            raise ValueError("activation='relu_gated' is an expert layer's "
+                             "(moe/layer.py); the dense MLPs have 'silu_gated'")
+        if self._routes_ahead and (c.norm_style == "post" or c.parallel_block or c.farskip
+                                   or c.residual_streams > 1):
+            raise NotImplementedError(
+                "moe.router_input='block_input' (the routing made before the token "
+                "mixer) is written for sequential pre-norm or sandwich blocks of one "
+                "stream: no post-norm, parallel block, FarSkip or hyper-connected streams")
         if c.norm_style not in ("pre", "post", "sandwich"):
             raise ValueError(f"norm_style {c.norm_style!r}")
         if c.norm_style == "sandwich" and c.parallel_block:
@@ -1198,15 +1216,34 @@ class TransformerLM:
             return aux, jnp.zeros((), jnp.float32)
         return aux
 
+    @property
+    def _routes_ahead(self) -> bool:
+        """Whether an expert layer's router reads the block's un-normed input,
+        before the token mixer runs (``MoEConfig.router_input``)."""
+        return self.config.moe is not None and self.config.moe.router_input == "block_input"
+
+    def _route_ahead(self, block: Params, x: jax.Array):
+        """An expert block's routing from its own un-normed input ``x``, made
+        before the token mixer is called (`_routes_ahead`; None for every other
+        block), under ``mlp`` where a trace counts the expert layer (the layer
+        names its own ``moe/route/ahead`` inside)."""
+        if not self._routes_ahead or "moe" not in block:
+            return None
+        with jax.named_scope("mlp"):
+            return self._moe.route(block["moe"], x)
+
     @scoped("mlp")
-    def _mlp(self, block: Params, h: jax.Array
+    def _mlp(self, block: Params, h: jax.Array, routing=None
              ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
-        """MLP over the PRE-NORMED input h -> (out, aux, the no-drop
-        path's rows per expert [experts] or None)."""
+        """MLP over ``h``, the sub-block's PRE-NORMED input (what the dense
+        layers and the experts multiply) -> (out, aux, the no-drop path's rows
+        per expert [experts] or None). ``routing``: the expert layer's routing
+        where it was made from ANOTHER tensor, the block's un-normed input
+        before the mixer (`_route_ahead`); None: the router reads ``h``."""
         c = self.config
         aux, rows = self._moe_aux_zero(), None
         if "moe" in block and self.moe_path == "dropless":
-            out, aux, rows = self._moe.dropless_forward(block["moe"], h)
+            out, aux, rows = self._moe.dropless_forward(block["moe"], h, routing)
         elif "moe" in block:
             out, aux = self._moe(block["moe"], h)
         elif c.activation == "silu_gated":
@@ -1381,6 +1418,8 @@ class TransformerLM:
                 block, self._block_layers["ln_2"](block["ln_2"], near))
             x = (_c(after_attn + keep * mlp_out, ACT_SPEC), after_attn)
             return (x, positions, aux_acc + keep * aux), (rows, handed)
+        # (a router that reads the block's input decides before the mixer runs)
+        routing = self._route_ahead(block, x)
         h1 = self._block_layers["ln_1"](block["ln_1"], x)
         # (a mixer's own loss, here on: an indexer's KL, None elsewhere)
         attn_out, handed, own = mixer(block, h1, positions, attn_mask, kind, given)
@@ -1399,7 +1438,7 @@ class TransformerLM:
             add = self._add_fp32 if c.residual_fp32 else (lambda x, y: x + y)
             x = add(x, keep * post("post_ln_1", attn_out))
             h2 = self._block_layers["ln_2"](block["ln_2"], x)
-            mlp_out, aux, rows = self._mlp(block, h2)
+            mlp_out, aux, rows = self._mlp(block, h2, routing)
             x = _c(add(x, keep * post("post_ln_2", mlp_out)), ACT_SPEC)
             if own is not None:
                 moe_acc, own_acc = aux_acc
@@ -2049,6 +2088,8 @@ class TransformerLM:
         attn = {"layers_window": layers["window"], "layers_full": layers["full"],
                 "window": windows[0] if len(windows) == 1 else (windows or None),
                 "kv_heads": c.kv_heads,
+                # the query heads that share a key head
+                "group": c.num_heads // c.kv_heads,
                 "documents": c.document_separator is not None,
                 "route": {"window": None, "full": None},
                 "dq": {"window": None, "full": None},
@@ -2107,7 +2148,8 @@ class TransformerLM:
             return {}
         moe = self._moe
         record = {"experts_published": moe.num_experts,
-                  "experts_held": moe.held[1] - moe.held[0]}
+                  "experts_held": moe.held[1] - moe.held[0],
+                  "router_input": moe.router_input, "activation": moe.activation}
         if seq is None:
             return record
         from ..ops.transformer import pallas_gmm, pallas_segment_sum

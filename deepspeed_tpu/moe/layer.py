@@ -19,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +34,24 @@ from .sharded_moe import (capacity as _capacity, sigmoid_bias_router,
                           softmax_topk_router, top_k_gating_indices)
 
 Params = Dict[str, Any]
+
+#: ``MoE.activation`` -> the function on the gate product of a gated expert
+#: MLP (``act(x W_gate) * (x W_up)``, stacks ``wi_gate`` / ``wi_up`` / ``wo``);
+#: ``'gelu'``, the one form that is not gated, has one ``wi`` stack
+GATE_ACTIVATIONS = {"silu_gated": jax.nn.silu, "relu_gated": jax.nn.relu}
+ACTIVATIONS = tuple(GATE_ACTIVATIONS) + ("gelu",)
+
+
+class Routing(NamedTuple):
+    """What the no-drop router decides of ``T`` tokens (``MoE.route``): the
+    ``k`` experts each chose ``[T, k]`` int32, their float32 weights ``[T,
+    k]``, the two router losses ``[2]`` and the assignments each expert drew
+    ``[experts]`` int32. Made from the tensor the experts multiply or, where
+    the router reads the block's input, before the token mixer runs."""
+    eidx: jax.Array
+    weight: jax.Array
+    losses: jax.Array
+    rows: jax.Array
 
 
 def _c(x, spec):
@@ -66,9 +84,9 @@ def moe_reference_forward(params: Params, tokens: jax.Array, *,
         gathered = jnp.where((src > 0)[:, None], gathered,
                              jnp.zeros((), tokens.dtype))
     expert_in = gathered.reshape(e, cap, h)
-    if activation == "silu_gated":
-        gate = jax.nn.silu(jnp.einsum("ech,ehf->ecf", expert_in,
-                                      params["wi_gate"].astype(tokens.dtype)))
+    if activation in GATE_ACTIVATIONS:
+        gate = GATE_ACTIVATIONS[activation](jnp.einsum(
+            "ech,ehf->ecf", expert_in, params["wi_gate"].astype(tokens.dtype)))
         up = jnp.einsum("ech,ehf->ecf", expert_in,
                         params["wi_up"].astype(tokens.dtype))
         mid = gate * up
@@ -294,7 +312,9 @@ class MoE:
     #: (``dropless_forward``); a number = the capacity-bucketed GShard path
     capacity_factor: Optional[float] = 1.25
     min_capacity: int = 4
-    activation: str = "silu_gated"  # 'silu_gated' | 'gelu'
+    #: the expert MLP's form (``ACTIVATIONS``): 'silu_gated' | 'relu_gated'
+    #: (``act(x W_gate) * (x W_up)``) | 'gelu' (one ``wi`` stack, not gated)
+    activation: str = "silu_gated"
     init_scale: float = 0.02
     #: the no-drop router's description (the capacity gate renormalises
     #: over the kept choices and balances over the first choice, always)
@@ -313,6 +333,11 @@ class MoE:
     #: chips' experts would add is left out, and nothing stands in for them
     #: or their exchange). None: all of them.
     experts_held: Optional[Tuple[int, int]] = None
+    #: what the no-drop router reads: 'ffn_input' (the tensor the experts
+    #: multiply: the block's stream after the mixer, normed) | 'block_input'
+    #: (the block's un-normed input, BEFORE the token mixer runs: the caller
+    #: makes the `Routing` with `route` and hands it to `dropless_forward`)
+    router_input: str = "ffn_input"
     #: fused Pallas kernel dispatch (ISSUE 11): None = the
     #: ``DSTPU_MOE_KERNEL`` env gate (auto: Pallas on single-chip TPU,
     #: XLA elsewhere); 'xla'/'pallas' pin per-layer (lint entries,
@@ -321,6 +346,16 @@ class MoE:
     kernel: Any = None
 
     def __post_init__(self):
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(f"activation {self.activation!r} is none of {ACTIVATIONS}")
+        if self.router_input not in ("ffn_input", "block_input"):
+            raise ValueError(f"router_input {self.router_input!r} is not 'ffn_input' "
+                             "or 'block_input'")
+        if not self.dropless and self.router_input != "ffn_input":
+            raise ValueError(
+                "router_input='block_input' (a router that reads the block's "
+                "input before the token mixer) is the no-drop path's "
+                "(capacity_factor=None): the capacity path routes inside the layer")
         if not self.dropless and not (self.normalize_weights
                                       and self.balance_loss == "gshard_top1"):
             raise ValueError(
@@ -347,6 +382,11 @@ class MoE:
         return self.capacity_factor is None
 
     @property
+    def gated(self) -> bool:
+        """Whether the expert MLP is gated (stacks ``wi_gate`` / ``wi_up``)."""
+        return self.activation in GATE_ACTIVATIONS
+
+    @property
     def held(self) -> Tuple[int, int]:
         """The range of experts whose weights this layer holds."""
         return self.experts_held or (0, self.num_experts)
@@ -361,7 +401,7 @@ class MoE:
             return (jax.random.normal(r, shape, jnp.float32) * scale).astype(dtype)
 
         params = {"gate": w(ks[0], (h, self.num_experts))}
-        if self.activation == "silu_gated":
+        if self.gated:
             params["wi_gate"] = w(ks[1], (e, h, f))
             params["wi_up"] = w(ks[2], (e, h, f))
         else:
@@ -379,7 +419,7 @@ class MoE:
     def specs(self) -> Params:
         expert_w = P(EXPERT_AXIS, None, None)
         out = {"gate": P(None, None), "wo": expert_w}
-        if self.activation == "silu_gated":
+        if self.gated:
             out["wi_gate"] = expert_w
             out["wi_up"] = expert_w
         else:
@@ -398,9 +438,9 @@ class MoE:
         capacity extent, so the overlap planner's chunked dispatch can
         run it per capacity chunk (bitwise: each slot's row contracts
         the same operands either way)."""
-        if self.activation == "silu_gated":
-            gate = jax.nn.silu(jnp.einsum("ech,ehf->ecf", expert_in,
-                                          params["wi_gate"].astype(dtype)))
+        if self.gated:
+            gate = GATE_ACTIVATIONS[self.activation](jnp.einsum(
+                "ech,ehf->ecf", expert_in, params["wi_gate"].astype(dtype)))
             up = jnp.einsum("ech,ehf->ecf", expert_in,
                             params["wi_up"].astype(dtype))
             mid = gate * up
@@ -409,11 +449,42 @@ class MoE:
                                          params["wi"].astype(dtype)))
         return jnp.einsum("ecf,efh->ech", mid, params["wo"].astype(dtype))
 
-    def dropless_forward(self, params: Params, x: jax.Array
+    def route(self, params: Params, route_from: jax.Array) -> Routing:
+        """The no-drop router over ``route_from`` [batch, seq, hidden]: what
+        a block whose router reads its input (``router_input='block_input'``)
+        calls BEFORE the token mixer, under ``moe/route/ahead``
+        (``dropless_forward`` routes the tensor the experts multiply itself,
+        under ``moe/route``). The sort by expert, which needs nothing of the
+        mixer either, stays with the rows it moves."""
+        b, s, h = route_from.shape
+        with jax.named_scope("moe/route"), jax.named_scope("ahead"):
+            return self._router(params, route_from.reshape(b * s, h), s)
+
+    def _router(self, params: Params, tokens: jax.Array, rows_per_seq: int) -> Routing:
+        """``tokens`` [T, hidden], sequence-major -> float32 logits, the
+        ``top_k`` experts a token, their weights, the router losses and the
+        assignments each expert drew."""
+        # float32 logits at full precision: a bf16 logit moves tokens
+        # between experts whose probabilities are close
+        logits = jnp.dot(tokens.astype(jnp.float32),
+                         params["gate"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        logits = checkpoint_name(logits, "moe_logits")
+        if self.router == "sigmoid_bias":
+            return Routing(*sigmoid_bias_router(
+                logits, params["bias"], self.top_k, normalize=self.normalize_weights,
+                routed_scale=self.routed_scale, rows_per_seq=rows_per_seq))
+        return Routing(*softmax_topk_router(
+            logits, self.top_k, normalize=self.normalize_weights,
+            balance_loss=self.balance_loss))
+
+    def dropless_forward(self, params: Params, x: jax.Array,
+                         routing: Optional[Routing] = None
                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """The no-drop path. x: [batch, seq, hidden] -> (out, router losses
         [2] = (load balancing, z-loss), rows [experts] int32: assignments
-        each expert received).
+        each expert received). ``routing``: what `route` made of another
+        tensor than ``x`` (None: routed here, from ``x``).
 
         The ``tokens x top_k`` assignments are sorted by expert, their rows
         gathered once, the expert FFN run as grouped matmuls over
@@ -434,24 +505,18 @@ class MoE:
                 f"are not implemented (expert={topo.expert_parallel_size}, "
                 f"pipe={topo.pipe_parallel_size}). Use a capacity_factor, or "
                 "a mesh without those axes")
+        if (routing is None) != (self.router_input == "ffn_input"):
+            raise ValueError(
+                f"router_input={self.router_input!r}: the routing is made "
+                + ("by the caller, before the token mixer (MoE.route)"
+                   if routing is None else "here, from the experts' own input"))
         b, s, h = x.shape
-        n_tok, k, dt = b * s, self.top_k, x.dtype
+        n_tok, dt = b * s, x.dtype
         tokens = x.reshape(n_tok, h)
-        with jax.named_scope("moe/route"):
-            # float32 logits at full precision: a bf16 logit moves tokens
-            # between experts whose probabilities are close
-            logits = jnp.dot(tokens.astype(jnp.float32),
-                             params["gate"].astype(jnp.float32),
-                             precision=jax.lax.Precision.HIGHEST)
-            logits = checkpoint_name(logits, "moe_logits")
-            if self.router == "sigmoid_bias":
-                eidx, weight, losses, rows = sigmoid_bias_router(
-                    logits, params["bias"], k, normalize=self.normalize_weights,
-                    routed_scale=self.routed_scale, rows_per_seq=s)
-            else:
-                eidx, weight, losses, rows = softmax_topk_router(
-                    logits, k, normalize=self.normalize_weights,
-                    balance_loss=self.balance_loss)
+        if routing is None:
+            with jax.named_scope("moe/route"):
+                routing = self._router(params, tokens, s)
+        eidx, weight, losses, rows = routing
         if self.held == (0, self.num_experts):
             out = self._all_rows(params, tokens, eidx, weight, rows)
         else:
@@ -470,8 +535,8 @@ class MoE:
         first products are named for the block's remat policy (what the
         backward may keep: the activation's gradient needs them)."""
         first = lambda name: checkpoint_name(product(rows_in, name), name)
-        if self.activation == "silu_gated":
-            mid = jax.nn.silu(first("wi_gate")) * first("wi_up")
+        if self.gated:
+            mid = GATE_ACTIVATIONS[self.activation](first("wi_gate")) * first("wi_up")
         else:
             mid = jax.nn.gelu(first("wi"))
         return product(mid, "wo")
@@ -486,7 +551,7 @@ class MoE:
         if g != self.num_experts:
             m = held_capacity(m, g, self.num_experts)
         h, f = self.hidden_size, self.intermediate_size
-        first = ("wi_gate", "wi_up") if self.activation == "silu_gated" else ("wi",)
+        first = ("wi_gate", "wi_up") if self.gated else ("wi",)
         return tuple((name, m, h, f, g) for name in first) + (("wo", m, f, h, g),)
 
     def rows_back(self, n_tok: int) -> Optional[Tuple[int, int, int]]:

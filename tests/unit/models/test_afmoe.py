@@ -101,7 +101,7 @@ def test_first_step_through_initialize(parts, blocked):
             "zero_optimization": {"stage": 1},
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.1}}})
     assert engine.attn_totals == {"layers_window": 5, "layers_full": 1, "window": 16,
-                                  "kv_heads": 2, "documents": True,
+                                  "kv_heads": 2, "group": 4, "documents": True,
                                   "route": {"window": None, "full": None},
                                   "dq": {"window": None, "full": None},
                                   "layout": {"window": None, "full": None}}

@@ -63,6 +63,8 @@ USES = {
     "moe.capacity_factor=None": dict(moe=dataclasses.replace(EXPERTS, capacity_factor=None)),
     "moe.bias_update": dict(moe=MoEConfig(num_experts=4, top_k=2, capacity_factor=None,
                                           router="sigmoid_bias", bias_update=1e-3)),
+    "moe.router_input='block_input'": dict(moe=dataclasses.replace(
+        EXPERTS, capacity_factor=None, router_input="block_input")),
 }
 IDS = np.asarray([[5, 9, 1, 7, 3, 1, 8, 2], [4, 4, 6, 1, 9, 2, 2, 7]], np.int32)
 

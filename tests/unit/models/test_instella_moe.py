@@ -120,6 +120,7 @@ def test_first_step_through_initialize(parts):
     cap = held_capacity(8 * 48 * cfg["num_experts_per_tok"], 8, 16)
     assert totals == {"path": "dropless", "steps": 1,
                       "experts_published": 16, "experts_held": 8,
+                      "router_input": "ffn_input", "activation": "silu_gated",
                       "grouped_matmul_route": "xla",
                       "products_kernel": dict.fromkeys(products, 0),
                       "combine_route": "xla",

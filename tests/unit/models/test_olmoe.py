@@ -162,6 +162,7 @@ def test_trains_through_initialize_and_counts_every_assignment(tiny):
     assert engine.remat_totals["saved"]
     assert engine.moe_totals == {"path": "dropless", "steps": 3,
                                  "experts_published": 8, "experts_held": 8,
+                                 "router_input": "ffn_input", "activation": "silu_gated",
                                  "grouped_matmul_route": "xla",
                                  "products_kernel": dict.fromkeys(each, 0),
                                  "products_xla": each,
@@ -183,6 +184,7 @@ def test_capacity_models_count_too_and_keep_their_program():
     engine.train_batch({"input_ids": np.random.default_rng(0).integers(0, 256, (8, 16))})
     assert engine.moe_totals == {"path": "capacity", "steps": 1,
                                  "experts_published": 4, "experts_held": 4,
+                                 "router_input": "ffn_input", "activation": "silu_gated",
                                  "grouped_matmul_route": None,
                                  "products_kernel": None, "products_xla": None,
                                  "combine_route": None, "combine_rows_moved": 0}

@@ -668,6 +668,14 @@ CELLS = {
             "gmu.full": (1, 2_076_725_266, {"gmu_in": 167_772_160, "gate_proj": 335_544_320, "up_proj": 335_544_320}),
             "cross.full": (1, 2_348_055_848, {"q_proj": 83_886_080, "attn_o_diff": 167_772_160, "attn_lse_diff": 2_621_440, "o_proj": 83_886_080, "gate_proj": 335_544_320, "up_proj": 335_544_320}),
         }),
+    # (PR 62; the room, the blocks' and the step's bytes as the chip's log line and
+    # `memory_totals` print them, in MB to one decimal; the names' bytes exact)
+    "smallthinker-21b-a3b.train.win16k": dict(
+        room=8_374_100_000, grads=1_313_059_840, outside=1_244_659_712, carry=167_837_704,
+        step_bytes=7_066_222_592, reaches='moe_logits', kinds={
+            "full": (1, 5_098_500_000, {"moe_logits": 8_388_608, "q_proj": 234_881_024, "k_proj": 33_554_432, "v_proj": 33_554_432, "attn_o": 234_881_024, "attn_lse": 3_670_016, "o_proj": 167_772_160, "wi_gate": 226_492_416, "wi_up": 226_492_416, "wo": 754_974_720}),
+            "window4096": (3, 5_333_400_000, {"moe_logits": 8_388_608, "q_proj": 234_881_024, "k_proj": 33_554_432, "v_proj": 33_554_432, "attn_o": 234_881_024, "attn_lse": 3_670_016, "o_proj": 167_772_160, "wi_gate": 226_492_416, "wi_up": 226_492_416, "wo": 754_974_720}),
+        }),
 }
 # </CELLS>
 
@@ -698,7 +706,8 @@ def test_the_cells_keep_what_the_chip_has_room_for(cell):
     SDAR, Instella and Keye cells what they kept, and the hyper-connected
     cell nothing: its room ends under its blocks' inputs and one block. No
     decision sits within a twentieth of the room of a group's edge, in any of
-    the nine: the same names at 95 % and at 105 % of the reading, so what a
+    the ten (the SmallThinker cell, PR 62, keeps the flash pair's two and the
+    routing's logits, which its router makes BEFORE the mixer): the same names at 95 % and at 105 % of the reading, so what a
     run keeps does not hang on a few MB; and the step as reckoned (gradients
     + inputs + working set + what the kept values cost, no margin) is never
     under the step the chip measured."""

@@ -24,6 +24,8 @@ from tests.unit.ops.flash_cases import FP32_TOL, GRAD_TOL, _packed_ids, out_and_
     (8192, 8192, None, "instella-moe-16b-a3b.train.seq8k", "in_place"),
     (16384, 16384, None, "trinity-mini.train.seq16k, the full layer", "in_place"),
     (16384, 16384, 2048, "trinity-mini.train.seq16k, a sliding layer", "summed"),
+    # (a q-block of 1024 meets 5 k-blocks under 4096: past DQ_SUMMED_PARTIALS)
+    (16384, 16384, 4096, "smallthinker-21b-a3b.train.win16k, a windowed layer", "in_place"),
     (16384, 8192, None, "sdar-30b-a3b.train.bd8k (both copies' rows over the clean keys)", "in_place"),
     (2048, 2048, None, "evabyte-6.5b.train.seq32k, a window's exact keys", "summed"),
     (32768, 2048, None, "evabyte-6.5b.train.seq32k, the row's summaries", "summed"),

@@ -136,6 +136,8 @@ def test_a_launch_by_rows_has_no_transpose_around_it(eight_devices, name):
     ((4, 1024, 20, 64), 20, "heads"),        # the GPT-2 cell: half a lane tile a head
     ((1, 2048, 32, 64), 4, "heads"),
     ((1, 16384, 32, 128), 4, "rows"),        # the Trinity, SDAR and Keye cells' heads
+    ((2, 16384, 28, 128), 4, "rows"),        # the SmallThinker cell's: a group of 7
+    ((1, 2048, 14, 128), 2, "rows"),         # (its column blocks: 14 over 2 key heads)
     ((1, 2048, 8, 256), 2, "rows"),          # two lane tiles a head
     ((1, 2048, 8, 128), 4, "rows"),
     ((1, 4096, 16, 128), 16, "heads"),       # the OLMoE cell: as many key heads as query heads

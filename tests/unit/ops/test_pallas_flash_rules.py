@@ -138,6 +138,16 @@ def test_route_rule_is_shape_and_platform_only(q_shape, k_shape, monkeypatch):
     ((1, 16384, 32, 128), 4, "tpu", "xla", "xla_chunked"),
     ((1, 16384, 32, 128), 4, "cpu", "", "xla"),
     ((1, 16384, 32, 128), 4, "cpu", "pallas", "kernel"),
+    # the smallthinker-21b-a3b cell: 28q/4kv x 128, a GROUP OF 7 (no power of
+    # two: 28 column blocks of the "rows" layout), two rows of 16,384 with
+    # segment ids, under a window of 4096 (three layers of four) and under
+    # none (neither is an argument of the route); and its tiny preset
+    ((2, 16384, 28, 128), 4, "tpu", "", "kernel"),
+    ((2, 16384, 28, 128), 4, "tpu", "xla", "xla_chunked"),
+    ((2, 16384, 28, 128), 4, "cpu", "", "xla"),
+    ((2, 16384, 28, 128), 4, "cpu", "pallas", "kernel"),
+    ((4, 64, 14, 16), 2, "cpu", "pallas", "kernel"),  # 14q/2kv x 16, interpret
+    ((4, 64, 14, 16), 2, "tpu", "pallas", "xla"),     # tiles off the lanes
     ((2, 128, 8, 64), 2, "tpu", "", "xla"),           # under the crossover
     ((1, 8192, 16, 128), 16, "tpu", "xla", "xla_chunked"),
     ((4, 1024, 20, 64), 20, "tpu", "xla", "xla"),

@@ -22,6 +22,8 @@ from tests.unit.ops.flash_cases import _qkv
     # row's end does, which is all the count is made of
     ((512, 512), 2048, 8, 8, (5, 5)),        # the cell's forward: 5 k-blocks a q-block
     ((1024, 1024), 2048, 6, 6, (3, 3)),      # its backward: 3
+    ((512, 512), 4096, 10, 10, (9, 9)),      # the SmallThinker cell's forward: 9
+    ((1024, 1024), 4096, 6, 6, (5, 5)),      # its backward: 5
     ((512, 512), 2049, 8, 8, (5, 5)),
     ((512, 512), 2050, 8, 8, (6, 6)),        # one key past a block's edge
     ((256, 512), 300, 4, 2, None),

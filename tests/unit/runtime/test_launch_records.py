@@ -66,5 +66,13 @@ def test_a_traced_step_writes_the_parents_records(eight_devices, monkeypatch, pr
     layouts = {k: traced.pop(k) for k in sorted(traced) if k.endswith("layout")
                or ".layout." in k}
     assert set(layouts.values()) <= {"heads"} and bool(layouts) == (mode == "pallas")
+    # since PR 62 the records also say the query heads to a key head, what the
+    # router reads and the experts' activation, from construction on: that
+    # parent had no such keys either, and a traced step does not move them
+    said = {k: (before.pop(k), traced.pop(k))
+            for k in ("attn.group", "moe.router_input", "moe.activation") if k in before}
+    assert all(was == now for was, now in said.values())
+    assert "attn.group" in said and ("moe.activation" in said) == ("moe.path" in before)
+    assert said.get("moe.router_input", ("ffn_input",))[0] == "ffn_input"
     got = {"before": before, "traced": traced}
     assert got == json.loads(FIXTURE.read_text())[f"{preset}/{mode}"]

@@ -62,6 +62,14 @@ CASES = {
     "evabyte local (16 x 2,048 x 4)": (16, 2048, 2048, 4, 4, False, dict(tag="eva_local")),
     "evabyte far (32,768 over 2,048 x 4)": (1, 32768, 2048, 4, 4, False,
                                            dict(summaries=(2048, 128), tag="eva_far")),
+    # (PR 62: a group of 7, 28 column blocks by rows; packed, and each row one
+    # document, where a window of 4,096 binds for three quarters of the positions)
+    "smallthinker window 4096 (2 x 16,384 x 28q/4kv)": (2, 16384, 16384, 28, 4, True,
+                                                        dict(window=4096)),
+    "smallthinker full (2 x 16,384 x 28q/4kv)": (2, 16384, 16384, 28, 4, True, {}),
+    "smallthinker window 4096, whole rows (2 x 16,384 x 28q/4kv)": (
+        2, 16384, 16384, 28, 4, False, dict(window=4096)),
+    "smallthinker full, whole rows (2 x 16,384 x 28q/4kv)": (2, 16384, 16384, 28, 4, False, {}),
 }
 
 

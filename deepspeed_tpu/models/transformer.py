@@ -209,14 +209,23 @@ def head_row_slices(rows: int, vocab: int, room_bytes: Optional[int]) -> int:
 
 def head_slices(config, remat_budget: Optional[Budget], input_ids) -> int:
     """`head_row_slices` of a step over ``input_ids`` under the budget's room:
-    one next-token head of a causal decoder alone, and 1 without a budget. (A
-    function of the configuration: ``PipelineModule`` borrows
-    ``TransformerLM.loss``.)"""
+    a next-token head of a causal decoder alone (a prediction module's two
+    take the same count each; without remat they keep their logits, whole),
+    and 1 without a budget. (A function of the configuration:
+    ``PipelineModule`` borrows ``TransformerLM.loss``.)"""
     c = config
     if (remat_budget is None or c.pred_heads > 1 or c.mlm_head or c.diffusion
-            or c.mtp_layers or not c.causal):
+            or not c.causal or (c.mtp_layers and not c.remat)):
         return 1
     return head_row_slices(input_ids.size, c.vocab_size, remat_budget.room_bytes)
+
+
+#: The products over the vocabulary a differentiated step makes a head, by the
+#: head's form: ``whole`` keeps its float32 logits for the backward (logits,
+#: the rows' gradient, the matrix's), ``fused`` takes a slice's gradient where
+#: its logits are (`TransformerLM.fused_head_loss`: the same three), ``rerun``
+#: makes a slice's logits again in the backward (float16 alone).
+HEAD_PASSES = {"whole": 3, "fused": 3, "rerun": 4}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1842,15 +1851,34 @@ class TransformerLM:
             return (x, aux) + stats
         return (self.head(params, x), aux) + stats
 
+    def head_form(self, slices: int) -> str:
+        """Which of `HEAD_PASSES`' forms a differentiated step's head takes:
+        ``whole`` where its float32 logits are kept for the backward (one
+        slice and no prediction module under remat), else ``fused``, or
+        ``rerun`` under float16 (`fused_head_loss` says why)."""
+        c = self.config
+        if slices == 1 and not (c.mtp_layers and c.remat):
+            return "whole"
+        return "rerun" if jnp.dtype(c.dtype) == jnp.float16 else "fused"
+
     def _charge_head(self, remat_budget: Optional[Budget], input_ids) -> None:
         """Tell the budget what a differentiated step holds outside its
-        blocks: a head's float32 logits and their gradient (two heads are
-        each run again in the backward, so one counts)."""
+        blocks: a head's float32 logits and their gradient, whole or one
+        slice's at a time (two heads run one after the other, so one
+        counts), and under the ``fused`` form what each head's forward hands
+        its backward beside them: the stream's gradient in the stream's
+        dtype and the head's parameters' in float32."""
         if remat_budget is not None:
             c = self.config
             slices = head_slices(self.config, remat_budget, input_ids)
+            form = self.head_form(slices)
             remat_budget.outside_bytes = (2 * 4 * input_ids.size * c.vocab_size
                                           * c.pred_heads) // slices
+            if form == "fused":
+                handed = (input_ids.size * c.hidden_size * jnp.dtype(c.dtype).itemsize
+                          + 4 * c.hidden_size * (c.vocab_size + 1))
+                remat_budget.outside_bytes += handed * (2 if c.mtp_layers else 1)
+            remat_budget.totals.update(head_form=form, head_passes=HEAD_PASSES[form])
             if slices > 1:
                 remat_budget.totals["head_row_slices"] = slices
 
@@ -2281,35 +2309,102 @@ class TransformerLM:
                   extra_mask: Optional[jax.Array] = None, slices: int = 1) -> jax.Array:
         """Final norm + LM/MLM head + masked cross-entropy over the last
         block's output (the differentiated tail of the overlap schedule).
-        ``slices`` > 1 (`head_row_slices`): the same sum over slices of the
-        rows, a slice's logits made again in its backward, so that the float32
-        logits of all the rows are never held; the head's matrix and the final
-        norm enter the slices in float32 (cast down inside a slice), so their
-        gradients are summed over the slices in float32."""
+        ``slices`` > 1 (`head_row_slices`): `fused_head_loss`, the same sum
+        over slices of the rows, so that the float32 logits of all the rows
+        are never held."""
         if slices == 1:
             return next_token_cross_entropy(self.config, self.head(params, x), labels,
                                             extra_mask)
+        return self.fused_head_loss(params, x, labels, extra_mask, slices)
+
+    def fused_head_loss(self, params: Params, x: jax.Array, labels: jax.Array,
+                        extra_mask: Optional[jax.Array], slices: int,
+                        ln_f: Optional[Params] = None, scale: float = 1.0) -> jax.Array:
+        """``scale`` x the masked cross-entropy of `head` over ``x`` [B, S, H]
+        (what `head_loss` returns over whole logits), for a head whose float32
+        logits are not kept for the backward. The rows are walked in
+        ``slices`` slices; the head's matrix and the final norm (``ln_f``:
+        another one's parameters) enter the slices in float32 (cast down
+        inside a slice), so their gradients are summed over the slices in
+        float32.
+
+        Differentiated, a slice's gradient is taken WHERE ITS LOGITS ARE: the
+        head is the last thing a forward computes and the first a backward
+        differentiates, so the forward rule takes `jax.vjp` of each slice's
+        share of the loss at cotangent 1, writes the slice's ``dx`` into its
+        rows and adds the parameters' gradients to the scan's carry. Those
+        are the residuals, and the backward rule multiplies them by the
+        scalar that reaches it (1.0 on a fused step: ``scale`` carries a
+        coefficient the caller knows, so that nothing is rounded twice).
+        Three products over the vocabulary a slice (logits, ``dx``, the
+        matrix's gradient) and one softmax, where a `jax.checkpoint` of the
+        slice makes four and two; one slice's logits are held at a time. Not
+        differentiated, it is the sum over the slices and no gradient.
+
+        float16 keeps autodiff's order (a `jax.checkpoint` of the slice, its
+        logits made again in the backward): a loss scale has to reach the
+        logits' gradient before it is rounded, and a gradient taken at
+        cotangent 1 in float16 would underflow."""
         B, S, H = x.shape
         f32 = jnp.float32
-        head_keys = ("ln_f", "wte" if self.config.tie_embeddings else "lm_head")
-        wide = jax.tree.map(lambda a: a.astype(f32), {k: params[k] for k in head_keys})
+        head = {k: params[k] for k in
+                ("ln_f", "wte" if self.config.tie_embeddings else "lm_head")}
+        if ln_f is not None:
+            head["ln_f"] = ln_f
+        dtypes = jax.tree.map(lambda a: a.dtype, head)
+        wide = jax.tree.map(lambda a: a.astype(f32), head)
         valid = labels >= 0
         mask = valid.astype(f32) * (1.0 if extra_mask is None else extra_mask.astype(f32))
-        by_slice = lambda a: a.reshape((slices, B * S // slices) + a.shape[2:])
+        rows = lambda *operands: tuple(      # the scan's operands: [slices, rows a slice, ...]
+            a.reshape((slices, B * S // slices) + a.shape[2:]) for a in operands)
+        mean = lambda total, weights_sum: scale * (total / jnp.maximum(weights_sum, 1.0))
 
-        def one(total, xs):
-            xb, targets, weights = xs
-            narrow = jax.tree.map(lambda a, like: a.astype(like.dtype), wide,
-                                  {k: params[k] for k in head_keys})
+        def slice_sum(wide, xb, targets, weights):
+            narrow = jax.tree.map(lambda a, dtype: a.astype(dtype), wide, dtypes)
             logits = self.head(narrow, xb[None])[0]
             with jax.named_scope("loss"):
-                return total + jnp.sum(_token_nll(logits, targets) * weights), None
+                return jnp.sum(_token_nll(logits, targets) * weights)
 
-        with jax.named_scope("head"):
-            total, _ = jax.lax.scan(
-                jax.checkpoint(one), jnp.zeros((), f32),
-                (by_slice(x), by_slice(jnp.where(valid, labels, 0)), by_slice(mask)))
-        return total / jnp.maximum(jnp.sum(mask), 1.0)
+        def summed(slice_sum):
+            def value(wide, x, targets, weights):
+                step = lambda total, xs: (total + slice_sum(wide, *xs), None)
+                with jax.named_scope("head"):
+                    total, _ = jax.lax.scan(step, jnp.zeros((), f32),
+                                            rows(x, targets, weights))
+                return mean(total, jnp.sum(weights))
+            return value
+
+        operands = wide, x, jnp.where(valid, labels, 0), mask
+        if x.dtype == jnp.float16:
+            return summed(jax.checkpoint(slice_sum))(*operands)
+
+        def forward(wide, x, targets, weights):
+            weights_sum = jnp.sum(weights)
+
+            def step(carry, xs):
+                xb, *rest = xs
+
+                def share(wide, xb):
+                    total = slice_sum(wide, xb, *rest)
+                    return mean(total, weights_sum), total
+                _, pull, total = jax.vjp(share, wide, xb, has_aux=True)
+                dwide, dxb = pull(jnp.ones((), f32))
+                return (carry[0] + total, jax.tree.map(jnp.add, carry[1], dwide)), dxb
+
+            with jax.named_scope("head"):
+                (total, dwide), dx = jax.lax.scan(
+                    step, (jnp.zeros((), f32), jax.tree.map(jnp.zeros_like, wide)),
+                    rows(x, targets, weights))
+            return mean(total, weights_sum), (dwide, dx.reshape(x.shape))
+
+        def backward(grads, g):
+            with jax.named_scope("head"):
+                dwide, dx = jax.tree.map(lambda a: g.astype(a.dtype) * a, grads)
+            return dwide, dx, None, None
+
+        fused = jax.custom_vjp(summed(slice_sum))
+        fused.defvjp(forward, backward)
+        return fused(*operands)
 
     def combine_aux(self, loss: jax.Array, aux: jax.Array) -> jax.Array:
         """Fold the accumulated MoE aux loss into the objective: each
@@ -2391,18 +2486,23 @@ class TransformerLM:
         later = lambda a, fill: jnp.pad(a[:, 1:], ((0, 0), (0, 1)),
                                         constant_values=fill)
 
+        module = (mtp_x, later(labels, -100), None if mask is None else later(mask, 0))
+        slices = head_slices(self.config, remat_budget, batch["input_ids"])
+        if self.head_form(slices) != "whole":
+            # two float32 logit tables: neither is kept for the backward
+            loss = self.fused_head_loss(params, x, labels, mask, slices)
+            with jax.named_scope("mtp"):
+                loss = loss + self.fused_head_loss(
+                    params, *module, slices, ln_f=params["mtp"]["ln_f"],
+                    scale=c.mtp_loss_coef)
+            return self.combine_aux(loss, aux), stats
+
         def head_loss(x, labels, mask, ln_f=None):
             return masked_cross_entropy(self.head(params, x, ln_f=ln_f),
                                         labels, extra_mask=mask)
-        if c.remat:
-            # two float32 logit tables: neither is kept for the backward,
-            # which runs each head's matmul again instead
-            head_loss = jax.checkpoint(head_loss)
         loss = head_loss(x, labels, mask)
         with jax.named_scope("mtp"):
-            loss = loss + c.mtp_loss_coef * head_loss(
-                mtp_x, later(labels, -100),
-                None if mask is None else later(mask, 0), params["mtp"]["ln_f"])
+            loss = loss + c.mtp_loss_coef * head_loss(*module, params["mtp"]["ln_f"])
         return self.combine_aux(loss, aux), stats
 
     # -- the block-diffusion objective ----------------------------------------

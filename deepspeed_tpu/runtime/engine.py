@@ -1821,6 +1821,7 @@ class DeepSpeedEngine:
         ``DSTPU_OVERLAP_PLAN=0`` / ``overlap_plan: false`` pins the
         identity plan — the hand-written PR 3 schedule, bitwise.
         """
+        from ..models.transformer import head_slices
         from ..utils.jax_compat import shard_map
         from .. import comm as dist
         from . import overlap_planner as op_mod
@@ -1975,6 +1976,10 @@ class DeepSpeedEngine:
             # head_loss / combine_aux) so both schedules train the same
             # objective by construction
             labels = model.derive_labels(batch)
+            # (the head in slices of the rows where one device's room does
+            # not hold its float32 logits, as the fused step has it)
+            head_rows = head_slices(
+                model.config, self._remat_kw(local=True).get("remat_budget"), input_ids)
             ef_local = (jax.tree.map(lambda a: a[0], ef)
                         if ef is not None else None)
             if use_split:
@@ -2026,7 +2031,8 @@ class DeepSpeedEngine:
             if use_split:
                 def head_f(ef_tree, hf, xx):
                     return model.head_loss({**ef_tree, **hf}, xx, labels,
-                                           extra_mask=batch.get("loss_mask"))
+                                           extra_mask=batch.get("loss_mask"),
+                                           slices=head_rows)
                 ce, head_vjp = jax.vjp(head_f, embed_full, head_full,
                                        x_out)
                 loss = model.combine_aux(ce, aux_sum)
@@ -2055,7 +2061,8 @@ class DeepSpeedEngine:
             else:
                 def head_f(rf, xx):
                     return model.head_loss(rf, xx, labels,
-                                           extra_mask=batch.get("loss_mask"))
+                                           extra_mask=batch.get("loss_mask"),
+                                           slices=head_rows)
                 ce, head_vjp = jax.vjp(head_f, rest_full, x_out)
                 loss = model.combine_aux(ce, aux_sum)
                 drf_h, dx_out = head_vjp(s_)

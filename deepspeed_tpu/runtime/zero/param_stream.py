@@ -42,6 +42,7 @@ expert parallelism compose with the resident-param engine paths instead.
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time
@@ -56,7 +57,9 @@ from jax.sharding import PartitionSpec as P
 
 from ...ops.adam.cpu_adam import (DeepSpeedCPUAdagrad, DeepSpeedCPUAdam,
                                   DeepSpeedCPULion)
+from ...telemetry import memory as step_memory
 from ...utils.logging import log_dist
+from ..activation_checkpointing import checkpointing as remat
 
 GLOBALS_UNIT = 0  # unit index of the embedding/head leaves; blocks are 1..L
 
@@ -510,9 +513,19 @@ class ParamStreamRunner:
             return jax.jit(f, out_shardings=shard)
         return self._jit(("bbwd", window), build)
 
+    @functools.cached_property
+    def _head_room_bytes(self) -> Optional[int]:
+        """What the fullest device has free when the head is first traced:
+        the room its float32 logits and their gradient are measured against
+        (``transformer.head_row_slices``). None where the backend reports no
+        memory (the CPU): the head whole."""
+        fullest = step_memory.device_memory(self.mesh.devices.flat)
+        return (None if fullest is None
+                else fullest["bytes_limit"] - fullest["bytes_in_use"])
+
     def _head_fwd_bwd(self, keys):
         def build():
-            from ...models.transformer import masked_cross_entropy
+            from ...models.transformer import head_slices
             wire = self.param_dtype
 
             def f(gleaves, x, batch, inv_gas):
@@ -521,11 +534,13 @@ class ParamStreamRunner:
                 if labels is None:
                     labels = jnp.pad(ids[:, 1:], ((0, 0), (0, 1)),
                                      constant_values=-100)
+                slices = head_slices(self.model.config,
+                                     remat.Budget(self._head_room_bytes), ids)
 
                 def loss_fn(gl, xx):
-                    logits = self.model.head(self._global_tree(gl), xx)
-                    return masked_cross_entropy(
-                        logits, labels, extra_mask=batch.get("loss_mask"))
+                    return self.model.head_loss(
+                        self._global_tree(gl), xx, labels,
+                        extra_mask=batch.get("loss_mask"), slices=slices)
                 loss, vjp = jax.vjp(loss_fn, gleaves, x)
                 # 1/gas cotangent: micro gradients accumulate to the MEAN
                 # over micro-batches, matching the resident engine's

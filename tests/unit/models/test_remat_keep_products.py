@@ -580,8 +580,9 @@ def test_a_block_is_traced_once_a_shape():
                                           ("two_heads", 2)])
 def test_the_heads_bytes_are_added_once(family, heads):
     """Outside the blocks a step holds one head's float32 logits and their
-    gradient; a prediction module's second head is run again in the
-    backward like the first, so one counts."""
+    gradient; a prediction module's two heads run one after the other, so
+    one counts, and each hands its backward the stream's gradient and its
+    parameters' in float32 (`TransformerLM.fused_head_loss`)."""
     if family == "two_heads":
         from deepspeed_tpu.models import instella_moe_model
         model = instella_moe_model("instella-tiny", dtype=jnp.float32)
@@ -593,7 +594,11 @@ def test_the_heads_bytes_are_added_once(family, heads):
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.float32))
     jax.make_jaxpr(jax.grad(lambda p: model.loss(
         p, {"input_ids": ids}, remat_budget=budget)))(params)
-    assert budget.outside_bytes == 2 * (2 * SEQ * model.config.vocab_size * 4)
+    c = model.config
+    handed = 2 * SEQ * c.hidden_size * 4 + 4 * c.hidden_size * (c.vocab_size + 1)
+    assert budget.totals["head_form"] == ("fused" if heads == 2 else "whole")
+    assert budget.outside_bytes == (2 * (2 * SEQ * c.vocab_size * 4)
+                                    + (2 * handed if heads == 2 else 0))
     assert budget.totals["outside_bytes"] == budget.outside_bytes
     assert budget.totals["working_bytes"] == max(budget.outside_bytes, budget.block_bytes)
     assert budget.block_bytes > 0
@@ -609,6 +614,9 @@ def test_the_heads_bytes_are_added_once(family, heads):
 #: and every kind of block: (layers, the block's bytes as the chip's trace
 #: walked them, name -> bytes in ONE layer); ``handed``: what a boundary layer
 #: hands on with its cotangent (the decoder-hybrid-decoder cell alone).
+#: (PR 63: ``outside`` of the four cells whose head takes its gradient in the
+#: forward counts what each head hands its backward too, `_charge_head`'s
+#: arithmetic at the cell's shapes.)
 # <CELLS>
 CELLS = {
     "gpt2-large.train.seq1k": dict(
@@ -622,7 +630,7 @@ CELLS = {
             "full": (2, 1_897_994_314, {"q_proj": 16_777_216, "k_proj": 16_777_216, "v_proj": 16_777_216, "attn_o": 16_777_216, "attn_lse": 262_144, "o_proj": 16_777_216, "moe_logits": 1_048_576, "wi_gate": 67_108_864, "wi_up": 67_108_864, "wo": 134_217_728}),
         }),
     "instella-moe-16b-a3b.train.seq8k": dict(
-        room=6_895_945_984, grads=1_540_443_392, outside=2_111_832_064, carry=134_250_504,
+        room=6_895_945_984, grads=1_540_443_392, outside=2_510_045_184, carry=134_250_504,
         step_bytes=6_734_659_584, reaches='wo', kinds={
             "dense.full": (1, 2_358_912_552, {"q_proj": 67_108_864, "kv_latent": 17_825_792, "kv_up": 117_440_512, "attn_o": 67_108_864, "attn_lse": 1_048_576, "attn_gate": 67_108_864, "o_proj": 67_108_864, "gate_proj": 358_612_992, "up_proj": 358_612_992}),
             "full": (5, 2_201_536_882, {"q_proj": 67_108_864, "kv_latent": 17_825_792, "kv_up": 117_440_512, "attn_o": 67_108_864, "attn_lse": 1_048_576, "attn_gate": 67_108_864, "o_proj": 67_108_864, "moe_logits": 4_194_304, "wi_gate": 103_809_024, "wi_up": 103_809_024, "wo": 150_994_944, "gate_proj": 92_274_688, "up_proj": 92_274_688}),
@@ -651,14 +659,14 @@ CELLS = {
             "full": (8, 2_229_161_998, {"q_proj": 134_217_728, "k_proj": 16_777_216, "v_proj": 16_777_216, "indexer_q": 33_554_432, "indexer_k": 2_097_152, "dsa_mask": 33_554_432, "attn_o_dsa": 134_217_728, "attn_lse_dsa": 2_097_152, "indexer_kl_dq": 33_554_432, "indexer_kl_dk": 2_097_152, "indexer_kl_dw": 1_048_576, "o_proj": 67_108_864, "moe_logits": 8_388_608, "wi_gate": 75_497_472, "wi_up": 75_497_472, "wo": 201_326_592}),
         }),
     "xing4-29b-a4b.train.mhc": dict(
-        room=3_367_239_820, grads=2_083_341_172, outside=1_073_741_824, carry=234_913_804,
+        room=3_367_239_820, grads=2_083_341_172, outside=1_660_973_056, carry=234_913_804,
         step_bytes=5_142_315_008, reaches=None, kinds={
             "dense.full": (2, 2_618_114_866, {"q_latent": 12_582_912, "q_b_proj": 100_663_296, "kv_latent": 9_437_184, "kv_up": 134_217_728, "attn_o_mla": 67_108_864, "attn_lse_mla": 1_048_576, "o_proj": 58_720_256, "gate_proj": 150_994_944, "up_proj": 150_994_944}),
             "full": (4, 2_912_181_306, {"q_latent": 12_582_912, "q_b_proj": 100_663_296, "kv_latent": 9_437_184, "kv_up": 134_217_728, "attn_o_mla": 67_108_864, "attn_lse_mla": 1_048_576, "o_proj": 58_720_256, "moe_logits": 2_097_152, "wi_gate": 25_165_824, "wi_up": 25_165_824, "wo": 88_080_384, "gate_proj": 16_777_216, "up_proj": 16_777_216}),
             "mtp": (1, 2_912_181_306, {"q_latent": 12_582_912, "q_b_proj": 100_663_296, "kv_latent": 9_437_184, "kv_up": 134_217_728, "attn_o_mla": 67_108_864, "attn_lse_mla": 1_048_576, "o_proj": 58_720_256, "moe_logits": 2_097_152, "wi_gate": 25_165_824, "wi_up": 25_165_824, "wo": 88_080_384, "gate_proj": 16_777_216, "up_proj": 16_777_216}),
         }),
     "phi4-mini-flash-reasoning.train.sambay": dict(
-        room=5_009_300_480, grads=1_830_623_232, outside=819_462_144, handed=754_974_720,
+        room=5_009_300_480, grads=1_830_623_232, outside=1_159_440_384, handed=754_974_720,
         carry=83_951_620,
         step_bytes=5_173_051_392, reaches='attn_o_diff', kinds={
             "ssm.full": (2, 2_490_558_486, {"ssm_in": 335_544_320, "ssm_x": 6_291_456, "ssm_dt": 167_772_160, "ssm_m": 167_772_160, "ssm_state": 41_943_040, "gate_proj": 335_544_320, "up_proj": 335_544_320}),
@@ -671,7 +679,7 @@ CELLS = {
     # (PR 62; the room, the blocks' and the step's bytes as the chip's log line and
     # `memory_totals` print them, in MB to one decimal; the names' bytes exact)
     "smallthinker-21b-a3b.train.win16k": dict(
-        room=8_374_100_000, grads=1_313_059_840, outside=1_244_659_712, carry=167_837_704,
+        room=8_374_100_000, grads=1_313_059_840, outside=1_801_398_272, carry=167_837_704,
         step_bytes=7_066_222_592, reaches='moe_logits', kinds={
             "full": (1, 5_098_500_000, {"moe_logits": 8_388_608, "q_proj": 234_881_024, "k_proj": 33_554_432, "v_proj": 33_554_432, "attn_o": 234_881_024, "attn_lse": 3_670_016, "o_proj": 167_772_160, "wi_gate": 226_492_416, "wi_up": 226_492_416, "wo": 754_974_720}),
             "window4096": (3, 5_333_400_000, {"moe_logits": 8_388_608, "q_proj": 234_881_024, "k_proj": 33_554_432, "v_proj": 33_554_432, "attn_o": 234_881_024, "attn_lse": 3_670_016, "o_proj": 167_772_160, "wi_gate": 226_492_416, "wi_up": 226_492_416, "wo": 754_974_720}),
@@ -931,9 +939,10 @@ def test_backward_recomputes_no_grouped_matmul(monkeypatch):
 @pytest.mark.parametrize("policy", ["nothing_saveable", "full", "dots_saveable"])
 def test_explicit_policies_record_nothing(policy):
     """The knob keeps its meanings: under an explicit policy no value is
-    chosen by budget, whatever the tags."""
+    chosen by budget, whatever the tags (the head's form is written
+    whatever the policy: PR 63)."""
     _, kept = _grads(_model("dense", policy), 0)
-    assert kept == {}
+    assert kept == {"head_form": "whole", "head_passes": 3}
 
 
 @pytest.mark.parametrize("policy", [KEEP_PRODUCTS, "nothing_saveable"])

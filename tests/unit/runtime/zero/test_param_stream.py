@@ -76,6 +76,28 @@ class TestParity:
             dl.append(float(dense.train_batch(b)))
         np.testing.assert_allclose(pl, dl, rtol=2e-3, atol=2e-4)
 
+    def test_losses_match_resident_engine_with_a_head_in_slices(self, eight_devices):
+        """A device with less free than the head's float32 logits take:
+        the trainer's ``vjp`` of `head_loss` runs through the head that
+        takes its gradient in the forward (4 slices of the 128 rows) and
+        the trajectory is still the resident engine's."""
+        m = _model()
+        init = _shared_init(m)
+        paged, _, _, _ = deepspeed_tpu.initialize(
+            model=m, config=_cfg(True), model_parameters=init)
+        paged._param_stream.__dict__["_head_room_bytes"] = 2 * (2 * 4 * 128 * 128) - 1
+        seen, head_loss = [], m.head_loss
+        m.head_loss = lambda *a, **kw: (seen.append(kw["slices"]), head_loss(*a, **kw))[1]
+        dense, _, _, _ = deepspeed_tpu.initialize(
+            model=_model(), config=_cfg(False), model_parameters=init)
+        pl, dl = [], []
+        for i in range(4):
+            b = _batch(seed=i)
+            pl.append(float(paged.train_batch(b)))
+            dl.append(float(dense.train_batch(b)))
+        assert seen and set(seen) == {4}
+        np.testing.assert_allclose(pl, dl, rtol=2e-3, atol=2e-4)
+
     def test_gradient_accumulation_parity(self, eight_devices):
         m = _model()
         init = _shared_init(m)

@@ -110,6 +110,26 @@ class TestOverlapNumerics:
         tolerance: the default-off escape is exact."""
         assert_grads_close(dense_grads, overlap_grads, rtol=2e-5)
 
+    def test_overlap_with_a_head_in_slices_matches_dense_micro(
+            self, eight_devices, dense_grads):
+        """A device's room that does not hold the head's float32 logits:
+        the schedule's own ``vjp`` of `head_loss` runs through the head that
+        takes its gradient in the forward (`fused_head_loss`, 4 slices of a
+        device's 16 rows) and still gives the dense step's gradients."""
+        with transport_off():
+            eng = make_engine({"overlap_comm": True})
+            eng.__dict__["_remat_room_bytes"] = 2 * (2 * 4 * 16 * 256) - 1
+            seen, head_loss = [], eng.model.head_loss
+            eng.model.head_loss = lambda *a, **kw: (seen.append(kw["slices"]),
+                                                    head_loss(*a, **kw))[1]
+            try:
+                got = micro_grads(eng)
+            finally:
+                del eng.model.head_loss
+        assert eng._overlap_active, eng._overlap_fallback
+        assert seen and set(seen) == {4}
+        assert_grads_close(dense_grads, got, rtol=2e-5)
+
     def test_overlap_quantized_matches_dense_micro(self, eight_devices,
                                                    dense_grads):
         """Quantized ON: int8 collectives bound the error, but the

@@ -28,15 +28,16 @@ from ..utils.scope import scoped
 
 Params = Dict[str, Any]
 
-#: The most elements one EVA head group's ``[rows, heads x head]`` projection
-#: may have before the heads are taken in groups (``transformer.
+#: The most elements of ``[rows, heads x head]`` an EVA layer's cores (rope,
+#: summaries, the two launches, their float32 partials and the merge) may take
+#: at once before the heads are taken in groups (``transformer.
 #: MLP_WHOLE_ELEMENTS`` has the reckoning). Memory only: the same arithmetic.
 EVA_GROUP_ELEMENTS = 2 ** 24
 
 
 def eva_head_groups(rows: int, heads: int, head_dim: int) -> int:
-    """How many groups of its heads an EVA layer's attention is computed in:
-    the least divisor of ``heads`` that brings a group's ``[rows, heads x
+    """How many groups of its heads an EVA layer's cores are computed in: the
+    least divisor of ``heads`` that brings a group's ``[rows, heads x
     head_dim]`` under ``EVA_GROUP_ELEMENTS``."""
     return next(g for g in range(1, heads + 1) if heads % g == 0
                 and (g == heads or rows * (heads // g) * head_dim <= EVA_GROUP_ELEMENTS))
@@ -634,9 +635,9 @@ class Eva(Attention):
     layer's, one summary key and value a chunk under ``attn/eva_summaries``
     (kept for the backward under the names ``eva_kbar`` / ``eva_vbar``), the
     core under ``attn/core_eva`` (``attention.eva_attention``). A row too long
-    for its heads at once (`eva_head_groups`) takes them a group at a time: a
-    head's attention reads no other head, and the output projection is the
-    float32 sum of the groups' parts."""
+    for its heads' cores at once (`eva_head_groups`) takes rope, summaries and
+    core a group of heads at a time (a head's attention reads no other head);
+    the four projections run once a layer over all heads on either path."""
 
     name, tag = "eva", None
 
@@ -685,53 +686,86 @@ class Eva(Attention):
         return {"eva": {
             "window": c.eva_window, "chunk": c.eva_chunk,
             "summaries_a_row": plan and seq // c.eva_chunk, "pred_heads": c.pred_heads,
+            "head_groups": plan and eva_head_groups(batch * seq, c.num_heads, c.head_dim),
+            "projected": "whole",
             "route": plan and plan.route, "dq_local": plan and plan.dq("eva_local"),
             "dq_far": plan and plan.dq("eva_far"),
             "layout": plan and plan.layout("eva_local")}}
 
     def __call__(self, block, h, positions, documents=None, kind=None, given=()):
         c = self.c
-        B, S, H = h.shape
+        B, S, _ = h.shape
         _whole_rows("attention='eva': windows and chunks are counted over the whole row")
         groups = eva_head_groups(B * S, c.num_heads, c.head_dim)
         phi, mu = block["eva_phi"]["value"], block["eva_mu"]["value"]
         with jax.named_scope("attn"):
-            if groups == 1:
-                with jax.named_scope("qkv"):
-                    q, k, v = (self._project(block, name, h)
-                               for name in ("q_proj", "k_proj", "v_proj"))
-                out = self._eva_heads(q, k, v, phi, mu, positions, named=True)
-                with jax.named_scope("out"):
-                    return self._project(block, "o_proj", out), None, None
-            each = c.num_heads // groups
-            kernel = lambda name: block[name]["kernel"].astype(h.dtype)
-            columns = lambda name: kernel(name).reshape(
-                H, groups, each * c.head_dim).transpose(1, 0, 2)
-            by_group = lambda a: a.reshape(groups, each, c.head_dim)
+            with jax.named_scope("qkv"):
+                q, k, v = (self._project(block, name, h)
+                           for name in ("q_proj", "k_proj", "v_proj"))
+            if groups > 1:
+                # (its result is the branch's output: named once a layer as
+                # below. Kept, it is the MLP's input in the block's recompute,
+                # which then drops the group scan whole: a group's forward
+                # runs twice a step, not three times)
+                return checkpoint_name(self._by_group(groups)(
+                    q, k, v, phi, mu, block["o_proj"]["kernel"].astype(h.dtype),
+                    positions), "o_proj"), None, None
+            out = self._eva_heads(q, k, v, phi, mu, positions, named=True)
+            with jax.named_scope("out"):
+                return self._project(block, "o_proj", out), None, None
 
-            def one_group(acc, weights):
-                wq, wk, wv, wo, phi, mu = weights
-                with jax.named_scope("qkv"):
-                    q, k, v = h @ wq, h @ wk, h @ wv
-                out = self._eva_heads(q, k, v, phi, mu, positions)
-                with jax.named_scope("out"):
-                    return acc + jnp.matmul(
-                        out, wo, preferred_element_type=jnp.float32), None
+    def _by_group(self, groups: int):
+        """``(q, k, v [B, S, heads x head], phi, mu, wo, positions) -> [B, S,
+        hidden]``: rope, summaries and cores a group of heads at a time, the
+        output projection once over all of them. A group is for the cores'
+        memory: what a trip holds is its columns of q, k, v and its ``[B, S,
+        group x head]`` of output, and the scan carries nothing. The backward
+        is written out because ``wo``'s gradient reads the cores' output: left
+        to the product's own rule, the block's recompute would run every
+        group's forward again to have it. Here a trip of the backward makes
+        its group's forward again (as ``jax.checkpoint`` around a group would),
+        takes ``wo``'s rows of the gradient from it, and pulls the output's
+        cotangent back to its columns of q, k, v; nothing in a group is named
+        (the block's policy would keep a named value of every group, stacked)."""
+        c = self.c
 
-            # a group's values are made again in its own backward: only the
-            # groups' shared input and the running sum outlive a group (and
-            # nothing in a group is named: the block's policy would keep a
-            # named value of every group, stacked)
-            acc, _ = jax.lax.scan(
-                jax.checkpoint(one_group), jnp.zeros(h.shape, jnp.float32),
-                (columns("q_proj"), columns("k_proj"), columns("v_proj"),
-                 kernel("o_proj").reshape(groups, each * c.head_dim, H),
-                 by_group(phi), by_group(mu)))
-            # the branch's output outlives every group: named as the ungrouped
-            # path names it, once a layer. Kept, it is the MLP's input in the
-            # block's recompute, which then drops the group scan whole (a
-            # group's forward runs twice a step, not three times)
-            return checkpoint_name(acc.astype(h.dtype), "o_proj"), None, None
+        def columns(a):
+            return jnp.moveaxis(a.reshape(*a.shape[:2], groups, -1), 2, 0)
+
+        def whole(a):
+            return jnp.moveaxis(a, 0, 2).reshape(*a.shape[1:3], -1)
+
+        def operands(q, k, v, phi, mu):
+            return (columns(q), columns(k), columns(v),
+                    *(a.reshape(groups, -1, c.head_dim) for a in (phi, mu)))
+
+        @jax.custom_vjp
+        def branch(q, k, v, phi, mu, wo, positions):
+            _, out = jax.lax.scan(
+                lambda _, of_group: (None, self._eva_heads(*of_group, positions)),
+                None, operands(q, k, v, phi, mu))
+            with jax.named_scope("out"):
+                return whole(out) @ wo
+
+        def backward(given, d):
+            q, k, v, phi, mu, wo, positions = given
+
+            def one_group(_, of_group):
+                *of_heads, wo_g = of_group
+                out, pull = jax.vjp(
+                    lambda *a: self._eva_heads(*a, positions), *of_heads)
+                with jax.named_scope("out"):
+                    d_wo, d_out = jnp.einsum("bsc,bsh->ch", out, d), d @ wo_g.T
+                return None, (*pull(d_out), d_wo)
+
+            _, (dq, dk, dv, dphi, dmu, d_wo) = jax.lax.scan(
+                one_group, None,
+                (*operands(q, k, v, phi, mu), wo.reshape(groups, -1, wo.shape[-1])))
+            return (whole(dq), whole(dk), whole(dv), dphi.reshape(phi.shape),
+                    dmu.reshape(mu.shape), d_wo.reshape(wo.shape), None)
+
+        branch.defvjp(lambda *given: (branch(*given), given), backward)
+        return branch
 
     def _eva_heads(self, q, k, v, phi, mu, positions,
                    named: bool = False) -> jax.Array:

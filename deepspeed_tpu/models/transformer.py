@@ -158,12 +158,13 @@ def next_token_cross_entropy(config, logits, labels, extra_mask=None) -> jax.Arr
 
 #: The most elements a dense MLP's ``[rows, intermediate]`` product may have
 #: before the rows are taken in slices (and ``mixers.EVA_GROUP_ELEMENTS`` the
-#: most one EVA head group's ``[rows, heads x head]`` projection may have
+#: most of ``[rows, heads x head]`` an EVA layer's cores may take at once
 #: before the heads are taken in groups). At 32,768 rows x 11008 a product is
 #: 0.72 GB in bfloat16 and a block's backward holds six of them beside a
 #: dozen ``[rows, hidden]`` copies of the attention's: 9.2 GB by the chip's
 #: compiler, beside 9.9 GB of training state on a 16 GB chip; in slices of
-#: 4,096 rows and groups of 4 heads it holds 6.1 (PERF.md, PR 42). Memory
+#: 4,096 rows and groups of 4 heads it holds 6.1 (PERF.md, PR 42; since PR 64
+#: a group holds the cores alone, the four projections run whole). Memory
 #: only: the same arithmetic, a slice's intermediates at a time.
 MLP_WHOLE_ELEMENTS = 2 ** 28
 MLP_SLICE_ELEMENTS = 2 ** 26

@@ -257,20 +257,61 @@ def test_slices_are_the_whole(parts, want, what, monkeypatch):
         assert ("o_proj" in budget.totals["saved"]) == (budget.room_bytes is None)
 
 
+def eva_branch(parts, dtype="float32", backward=False):
+    """``() -> `` the jaxpr of the tiny preset's EVA branch over ``[2, 128,
+    64]``, or of its gradient (traced anew at every call: `two_head_groups`
+    changes it)."""
+    _, adapter, cfg, w, ids = parts
+    model = adapter.model(cfg, remat=True, dtype=dtype)
+    block = jax.tree.map(lambda a: a[0], adapter.to_program(w)["blocks"])
+    h = jax.ShapeDtypeStruct((2, 128, 64), jnp.dtype(dtype))
+    positions = jnp.broadcast_to(jnp.arange(128), (2, 128))
+
+    def trace():        # (a function of its own a call: ``make_jaxpr`` keeps what it traced)
+        branch = lambda b, x: model._mixer(b, x, positions)[0]
+        if backward:
+            branch = jax.grad(lambda b, x: model._mixer(b, x, positions)[0].astype(F32).sum(),
+                              argnums=(0, 1))
+        return jax.make_jaxpr(branch)(block, h).jaxpr
+    return trace
+
+
+def forward_eqns(jaxpr):
+    """The branch's equations as its forward runs them: the top level's,
+    and in a custom-derivative call's place its forward's own."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "custom_vjp_call":
+            out += forward_eqns(eqn.params["call_jaxpr"].jaxpr)
+        else:
+            out.append(eqn)
+    return out
+
+
+def scans(jaxpr):
+    return [e for e in forward_eqns(jaxpr) if e.primitive.name == "scan"]
+
+
+def inside(jaxpr, found=None):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            inside(sub, found)
+    return found
+
+
 def test_a_grouped_branch_names_its_output_once(parts, monkeypatch):
     """What outlives a long row's inner checkpoints is named for the block's
     policy, and nothing inside one is: of the names ``SAVE_ORDER`` lists the
-    grouped branch's jaxpr holds ONE, ``o_proj`` (the name the ungrouped path
-    gives the same value), at rows x hidden x itemsize, made outside the
-    group scan. A listed name inside a group would be kept for every group,
-    stacked (the scores' ``attn_big`` is no candidate on either path)."""
-    _, adapter, cfg, w, ids = parts
-    model = adapter.model(cfg, remat=True, dtype="float32")
-    block = jax.tree.map(lambda a: a[0], adapter.to_program(w)["blocks"])
-    h = jax.ShapeDtypeStruct((2, 128, 64), F32)
-    positions = jnp.broadcast_to(jnp.arange(128), (2, 128))
-    branch = lambda: jax.make_jaxpr(
-        lambda b, x: model._mixer(b, x, positions)[0])(block, h).jaxpr
+    grouped branch's jaxpr holds FOUR, ``q_proj``, ``k_proj``, ``v_proj`` and
+    ``o_proj`` (the names the ungrouped path gives the same values), each
+    once, each at rows x width x itemsize, each made outside the group scan.
+    A listed name inside a group would be kept for every group, stacked: the
+    summaries' ``eva_kbar`` / ``eva_vbar`` are named on the ungrouped path
+    alone (the scores' ``attn_big`` is no candidate on either path)."""
+    branch = eva_branch(parts)
     listed = {n for group in checkpointing.SAVE_ORDER for n in group}
     candidates = lambda jaxpr: {n: b for n, b in checkpointing.named_bytes(
         jaxpr).items() if n in listed}
@@ -279,10 +320,49 @@ def test_a_grouped_branch_names_its_output_once(parts, monkeypatch):
     assert set(whole) == {"q_proj", "k_proj", "v_proj", "eva_kbar", "eva_vbar", "o_proj"}
     two_head_groups(monkeypatch)
     grouped = branch()
-    assert candidates(grouped) == {"o_proj": 2 * 128 * 64 * 4}
+    projections = ["q_proj", "k_proj", "v_proj", "o_proj"]
+    assert candidates(grouped) == dict.fromkeys(projections, 2 * 128 * 64 * 4)
     named = [e for e in grouped.eqns if e.primitive.name == "name"]
-    assert [e.params["name"] for e in named] == ["o_proj"]       # not in the scan
-    assert named[0].outvars[0].aval.shape == (2, 128, 64)
+    assert [e.params["name"] for e in named] == projections      # not in the scan
+    assert all(e.outvars[0].aval.shape == (2, 128, 64) for e in named)
+    (scan,) = scans(grouped)
+    assert not [e.params["name"] for e in inside(scan.params["jaxpr"].jaxpr)
+                if e.primitive.name == "name" and e.params["name"] in listed]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_grouped_branch_projects_whole_and_carries_nothing(parts, dtype, monkeypatch):
+    """The group scan covers what a group is for: the grouped branch's four
+    products over ``[B, S, hidden]`` run ONCE a layer at the whole width,
+    outside the scan, whose body holds no product of the stream and which
+    carries nothing: it reads a group's columns of q, k, v (and of ``phi``,
+    ``mu``) and yields the group's ``[B, S, heads x head]`` in the stream's
+    dtype. (Until PR 64 the body projected a group's 2 x 16 columns and added
+    ``out_g @ wo_g`` into a float32 ``[B, S, hidden]`` carry.)"""
+    two_head_groups(monkeypatch)
+    jaxpr = eva_branch(parts, dtype)()
+    stream = jnp.dtype(dtype)
+    products = [e for e in forward_eqns(jaxpr) if e.primitive.name == "dot_general"]
+    assert [(e.invars[0].aval.shape, e.invars[1].aval.shape, e.outvars[0].aval.shape)
+            for e in products] == 4 * [((2, 128, 64), (64, 64), (2, 128, 64))]
+    (scan,) = scans(jaxpr)
+    assert scan.params["length"] == 2 and scan.params["num_carry"] == 0
+    # what goes in by group: q, k, v [groups, B, S, 2 x 16], phi and mu
+    # [groups, 2, 16]; no column of a weight, no [B, S, hidden] to add into
+    by_group = [v.aval for v in scan.invars[scan.params["num_consts"]:]]
+    assert sorted((a.shape, a.dtype) for a in by_group) == (
+        2 * [((2, 2, 16), jnp.dtype("float32"))] + 3 * [((2, 2, 128, 32), stream)])
+    assert [(v.aval.shape, v.aval.dtype) for v in scan.outvars] == [((2, 2, 128, 32), stream)]
+    # nothing inside a group is as wide as the stream
+    assert not [v.aval for e in inside(scan.params["jaxpr"].jaxpr) for v in e.outvars
+                if v.aval.shape == (2, 128, 64)]
+    # the backward's scan over the groups carries nothing either: a trip
+    # reads the branch's cotangent and yields its columns' and ``wo``'s rows'
+    trips = [e for e in inside(eva_branch(parts, dtype, backward=True)())
+             if e.primitive.name == "scan" and e.params["length"] == 2]
+    assert len(trips) == 2 and all(e.params["num_carry"] == 0 for e in trips)
+    assert sorted(v.aval.shape for v in trips[1].outvars) == (
+        2 * [(2, 2, 16)] + 3 * [(2, 2, 128, 32)] + [(2, 32, 64)])
 
 
 def launches(jaxpr, counts=None):
@@ -417,7 +497,8 @@ def test_first_step_through_initialize(parts, want):
             "zero_optimization": {"stage": 1},
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3, "weight_decay": 0.0}}})
     assert engine.attn_totals["eva"] == {"window": 32, "chunk": 4, "summaries_a_row": None,
-                                         "pred_heads": 8, "route": None,
+                                         "pred_heads": 8, "head_groups": None,
+                                         "projected": "whole", "route": None,
                                          "dq_local": None, "dq_far": None, "layout": None}
     # the CPU mesh's eight devices take a row each: the two rows four times
     # over have the two rows' loss and gradient
@@ -426,7 +507,8 @@ def test_first_step_through_initialize(parts, want):
     gnorm = float(jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in want[1].values())))
     assert float(engine.get_global_grad_norm()) == pytest.approx(gnorm, rel=1e-4)
     assert engine.attn_totals["eva"] == {"window": 32, "chunk": 4, "summaries_a_row": 32,
-                                         "pred_heads": 8, "route": "xla",
+                                         "pred_heads": 8, "head_groups": 1,
+                                         "projected": "whole", "route": "xla",
                                          "dq_local": None, "dq_far": None, "layout": None}
     # the summaries are values the backward may keep, and on the CPU it does
     assert {"eva_kbar", "eva_vbar"} <= set(engine.remat_totals["saved"])

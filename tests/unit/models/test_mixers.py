@@ -104,8 +104,8 @@ def test_the_counts_add_up(built):
 # the keys docs/OBSERVABILITY.md lists for each kind's entry of ``attn_totals``
 # (``diffusion``: ``diffusion_totals``)
 RECORD_KEYS = {
-    "eva": {"window", "chunk", "summaries_a_row", "pred_heads", "route", "dq_local",
-            "dq_far", "layout"},
+    "eva": {"window", "chunk", "summaries_a_row", "pred_heads", "head_groups",
+            "projected", "route", "dq_local", "dq_far", "layout"},
     "dsa": {"topk", "indexer_heads", "indexer_head_dim", "route", "select",
             "select_tiles", "select_rows", "dq", "layout", "kl", "kl_tiles", "operand",
             "operand_bytes"},

@@ -648,10 +648,12 @@ CELLS = {
         step_bytes=7_337_951_232, reaches='wi_up', kinds={
             "full": (8, 2_328_317_642, {"q_proj": 134_217_728, "k_proj": 16_777_216, "v_proj": 16_777_216, "attn_o": 134_217_728, "attn_lse": 2_097_152, "o_proj": 67_108_864, "moe_logits": 8_388_608, "wi_gate": 75_497_472, "wi_up": 75_497_472, "wo": 201_326_592}),
         }),
+    # (re-read at PR 64: q, k, v outlive the head groups and are named, the
+    # block's walk 2,938.8 -> 3,391.8 MB, the step 6,787.8 -> 7,355.8)
     "evabyte-6.5b.train.seq32k": dict(
         room=6_231_251_968, grads=1_642_733_568, outside=671_088_640, carry=268_566_532,
-        step_bytes=6_787_809_280, reaches='o_proj', kinds={
-            "full": (4, 2_938_800_138, {"attn_o_eva_local": 536_870_912, "attn_lse_eva_local": 8_388_608, "attn_o_eva_far": 536_870_912, "attn_lse_eva_far": 8_388_608, "o_proj": 268_435_456}),
+        step_bytes=7_355_826_176, reaches='o_proj', kinds={
+            "full": (4, 3_391_799_306, {"q_proj": 268_435_456, "k_proj": 268_435_456, "v_proj": 268_435_456, "o_proj": 268_435_456, "attn_o_eva_local": 268_435_456, "attn_lse_eva_local": 4_194_304, "attn_o_eva_far": 268_435_456, "attn_lse_eva_far": 4_194_304}),
         }),
     "keye-vl2-30b-a3b.train.dsa16k": dict(
         room=5_820_218_368, grads=1_705_977_856, outside=2_489_319_424, carry=67_174_412,
@@ -708,8 +710,10 @@ SHORT = ("gpt2-large.train.seq1k", "olmoe-1b-7b.train.seq4k")
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_the_cells_keep_what_the_chip_has_room_for(cell):
     """The decision the chip printed, from the pure functions alone: the two
-    short cells keep every name and the EVA cell its one candidate, as before
-    PR 60; the Trinity cell reaches ``wo`` by its layers' own counts, the
+    short cells keep every name, as before PR 60, and the EVA cell the branch
+    output alone (its one candidate until PR 64; since then q, k, v are named
+    too, 1,288.5 MB each of a budget of 1,765.2 that ``o_proj`` takes 1,288.5
+    of: the last group of ``SAVE_ORDER`` is never reached); the Trinity cell reaches ``wo`` by its layers' own counts, the
     Phi-4 cell the differential launches' pairs where it kept nothing; the
     SDAR, Instella and Keye cells what they kept, and the hyper-connected
     cell nothing: its room ends under its blocks' inputs and one block. No
@@ -726,7 +730,7 @@ def test_the_cells_keep_what_the_chip_has_room_for(cell):
     candidates = step_candidates(budget.kinds)
     listed = [n for group in SAVE_ORDER for n in group if n in candidates]
     assert list(saved) == listed[:len(saved)]
-    if cell in SHORT or cell == "evabyte-6.5b.train.seq32k":
+    if cell in SHORT:
         assert list(saved) == listed
     for share in (0.95, 1.05):
         assert _cell_budget(cell, share).saved() == saved

@@ -74,5 +74,12 @@ def test_a_traced_step_writes_the_parents_records(eight_devices, monkeypatch, pr
     assert all(was == now for was, now in said.values())
     assert "attn.group" in said and ("moe.activation" in said) == ("moe.path" in before)
     assert said.get("moe.router_input", ("ffn_input",))[0] == "ffn_input"
+    # since PR 64 an EVA layer says over what its four projections run (from
+    # construction on) and, once traced, in how many groups its heads' cores
+    # (the tiny preset's row takes them at once)
+    if preset == "evabyte-tiny":
+        assert before.pop("attn.eva.projected") == traced.pop("attn.eva.projected") == "whole"
+        assert "attn.eva.head_groups" not in before
+        assert traced.pop("attn.eva.head_groups") == 1
     got = {"before": before, "traced": traced}
     assert got == json.loads(FIXTURE.read_text())[f"{preset}/{mode}"]

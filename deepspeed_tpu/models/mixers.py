@@ -4,7 +4,8 @@
 ``TransformerConfig.mixer_of(layer)`` picks each layer's kind and
 ``TransformerLM`` holds one `Mixer` a kind (`build`): ``mha``, ``selected``
 (``mha`` under ``config.indexer``), ``latent`` or ``eva`` for a plain stack;
-``ssm``, ``attn``, ``gmu`` and ``cross`` in a mixed one. A block's residual
+``ssm``, ``ssd``, ``attn``, ``gmu`` and ``cross`` (and ``mha`` by a list of layer
+kinds) in a mixed one. A block's residual
 and norm style is NOT a mixer's: ``TransformerLM._block_fn`` has it. A new
 architecture's mixer is a class here, its name in `KINDS`, a branch of
 ``mixer_of``, and a name in ``checkpointing.SAVE_ORDER`` for each value its
@@ -258,6 +259,11 @@ class Mha(Attention):
         super().__init__(config, host)
         if config.diffusion:        # one launch kind, its routes in the record
             self.tile_kind, self.tag = ("blockdiff", 0), None
+
+    def take(self, given: tuple) -> tuple:
+        # (a mixed stack hands every layer differential attention's constant,
+        # which is not this mixer's; a plain stack's traced window passes)
+        return () if self.c.mixed else given
 
     def _layers(self) -> Dict[str, Any]:
         c = self.c
@@ -869,6 +875,7 @@ class Ssm(_Mixed):
             batch * seq, c.ssm_inner, c.ssm_state, jax.default_backend(), devices())
         kernel = route == "kernel"
         return {"ssm": {
+            "kind": "selective", "heads": None, "head_dim": None, "groups": None,
             "layers": self.count("ssm"), "memory_units": self.count("gmu"),
             "d_inner": c.ssm_inner, "d_state": c.ssm_state, "conv": c.ssm_conv,
             "dt_rank": c.ssm_rank, "route": route,
@@ -934,6 +941,94 @@ class Ssm(_Mixed):
             with jax.named_scope("out"):
                 y = layers["out_proj"](block["out_proj"], m * nn.silu(az[..., Di:]))
         return y, self._hand(m, kind[3]), None
+
+
+class Ssd(Ssm):
+    """A Mamba-2 layer (arXiv:2405.21060; ``TransformerConfig.ssm_heads`` has the
+    equations) -> (the branch's output, None, None): heads of ``ssm_head_dim``
+    channels with one scalar decay each, B and C shared by a group's heads, the
+    convolution over a, B and C together, the gate BEFORE a norm over a group's
+    channels. Scopes ``ssm/in`` (the in projection and the convolution),
+    ``ssm/ssd`` (dt, the decays and the chunked core, ``pallas_ssd.ssd``) and
+    ``ssm/out`` (the gated norm and the out projection)."""
+
+    name = "ssd"
+
+    def _layers(self) -> Dict[str, Any]:
+        c, di = self.c, self.c.ssm_inner
+        shared = 2 * c.ssm_groups * c.ssm_state
+        return {"in_proj": _linear(c.hidden_size, 2 * di + shared + c.ssm_heads, False,
+                                   "column"),
+                "ssm": nn.ScanParams(di + shared, c.ssm_state, c.ssm_conv, heads=c.ssm_heads),
+                "ssd_norm": nn.RMSNorm(di, eps=c.norm_eps),
+                "out_proj": _linear(di, c.hidden_size, False, "row")}
+
+    def check(self) -> None:
+        super().check()
+        c = self.c
+        if c.ssm_heads < 1 or c.ssm_groups < 1 or c.ssm_heads % c.ssm_groups or not c.ssm_state:
+            raise ValueError(
+                f"ssm_heads {c.ssm_heads}: Mamba-2's scan layers need ssm_state and "
+                f"ssm_groups ({c.ssm_groups}) that divide the heads")
+        _whole_rows("a state-space layer carries its state along the whole row")
+
+    def parameters(self) -> int:
+        c, h, di = self.c, self.c.hidden_size, self.c.ssm_inner
+        conv = di + 2 * c.ssm_groups * c.ssm_state
+        return (h * (di + conv + c.ssm_heads) + (c.ssm_conv + 1) * conv
+                + 3 * c.ssm_heads + di + di * h)
+
+    def record(self, batch=None, seq=None) -> Dict[str, Any]:
+        from ..ops.transformer import pallas_ssd
+        c = self.c
+        route = seq and pallas_ssd.choose_route(
+            batch * seq, c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_groups,
+            jax.default_backend(), devices())
+        kernel = route == "kernel"
+        return {"ssm": {
+            "kind": "ssd", "heads": c.ssm_heads, "head_dim": c.ssm_head_dim,
+            "groups": c.ssm_groups, "layers": self.count("ssd"), "memory_units": 0,
+            "d_inner": c.ssm_inner, "d_state": c.ssm_state, "conv": c.ssm_conv,
+            "dt_rank": None, "route": route,
+            "chunk": route and (pallas_ssd.CHUNK if kernel
+                                else pallas_ssd.xla_chunk(c.ssm_chunk)),
+            "tile": pallas_ssd.choose_tile(c.ssm_heads, c.ssm_head_dim, c.ssm_groups)
+            if kernel else None}}
+
+    def __call__(self, block, h, positions, documents, kind, given=()):
+        from ..ops.transformer import pallas_ssd
+        c, layers = self.c, self.layers()
+        B, S, _ = h.shape
+        Di, N, G, H = c.ssm_inner, c.ssm_state, c.ssm_groups, c.ssm_heads
+        ssm, f32 = block["ssm"], jnp.float32
+        flat = lambda t: t.reshape((B * S,) + t.shape[2:])
+        with jax.named_scope("ssm"):
+            with jax.named_scope("in"):
+                # ONE matrix, three products over its columns: the gate is read
+                # after the core, and a whole ``[rows, 2 Di + 2 G N + H]`` result
+                # held for it made XLA run the projection three times in a
+                # rematerialised backward (the chip's first trace, PR 65)
+                kernel = block["in_proj"]["kernel"]
+                cut = lambda lo, hi: h @ kernel[:, lo:hi].astype(h.dtype)
+                z = checkpoint_name(cut(0, Di), "ssm_z")
+                xbc = checkpoint_name(cut(Di, 2 * Di + 2 * G * N), "ssm_in")
+                dt_raw = cut(2 * Di + 2 * G * N, None)
+                xbc = nn.silu(self._short_conv(ssm, xbc, documents)).astype(h.dtype)
+            with jax.named_scope("ssd"):
+                dt = jax.nn.softplus(dt_raw.astype(f32) + ssm["dt_bias"].astype(f32))
+                m = pallas_ssd.ssd(
+                    flat(xbc[..., :Di]), flat(dt), -jnp.exp(ssm["A_log"].astype(f32)),
+                    flat(xbc[..., Di:Di + G * N]), flat(xbc[..., Di + G * N:]), ssm["D"],
+                    flat(self._first_of_document(documents, (B, S))), G,
+                    devices=devices(), published_chunk=c.ssm_chunk).reshape(B, S, Di)
+            with jax.named_scope("out"):
+                # the gate goes in before the norm, a group's channels a statistic
+                gated = (m.astype(f32) * nn.silu(z.astype(f32))).reshape(B, S, G, Di // G)
+                normed = gated * jax.lax.rsqrt(
+                    jnp.mean(gated * gated, axis=-1, keepdims=True) + c.norm_eps)
+                normed = normed.reshape(B, S, Di) * block["ssd_norm"]["scale"].astype(f32)
+                y = layers["out_proj"](block["out_proj"], normed.astype(h.dtype))
+        return y, None, None
 
 
 class MemoryUnit(_Mixed):
@@ -1080,7 +1175,7 @@ class Cross(MixedAttention):
 
 
 #: a mixer's class by ``TransformerConfig.mixer_of``'s name for it
-KINDS = {cls.name: cls for cls in (Mha, Selected, Latent, Eva, Ssm, MixedAttention,
+KINDS = {cls.name: cls for cls in (Mha, Selected, Latent, Eva, Ssm, Ssd, MixedAttention,
                                    MemoryUnit, Cross)}
 
 

@@ -522,6 +522,31 @@ class TransformerConfig:
     ssm_expand: int = 2
     ssm_dt_rank: Optional[int] = None
     ssm_period: int = 2
+    # Mamba-2's scan layers (arXiv:2405.21060; state-space duality): with
+    # ``ssm_heads`` (0: Mamba-1's, above) a scan layer has ``ssm_heads`` heads of
+    # ``ssm_head_dim`` channels over ``ssm_state`` states, ONE scalar decay a head
+    # and B and C shared by the heads of each of ``ssm_groups`` groups: ``[z, xBC,
+    # dt_raw] = u W_in`` (``Di + (Di + 2 G N) + heads``), ``xBC = silu(conv(xBC) +
+    # b)`` over all ``Di + 2 G N`` channels, ``[a, B, C] = xBC``, ``dt =
+    # softplus(dt_raw + dt_bias)``, ``A = -exp(A_log)`` a head, the recurrence
+    # above with ``exp(dt_t A)`` a head's scalar (``ops/transformer/pallas_ssd.py``,
+    # chunked), and ``RMSNorm_Di(m * silu(z)) W_out``: the gate goes in BEFORE the
+    # norm, whose statistic is over a group's channels and whose gain is learned.
+    # ``ssm_chunk`` names the published kernels' chunk (a record's line; the
+    # program's kernel picks its own, the mathematics does not depend on it).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
+    # each layer's token mixer by name where no rule gives it (Granite 4.0-H's
+    # ``layer_types``): a tuple of ``num_layers`` names of ``mixers.KINDS``
+    # (``"ssd"``, ``"mha"``); the stack is then a mixed one
+    layer_mixers: Optional[Tuple[str, ...]] = None
+    # a branch's output times this before it is added to the stream, and the
+    # logits divided by this (Granite's ``residual_multiplier``,
+    # ``logits_scaling``); 1.0: absent, nothing traced
+    residual_scale: float = 1.0
+    logits_divisor: float = 1.0
     # differential attention (arXiv:2410.05258): the heads are paired by
     # parity, each half a softmax of its own over the pair's two value heads
     # side by side, ``o = RMSNorm(o1 - lambda o2) (1 - lambda_init)``, ``lambda =
@@ -537,6 +562,8 @@ class TransformerConfig:
 
     @property
     def ssm_inner(self) -> int:
+        if self.ssm_heads:
+            return self.ssm_heads * self.ssm_head_dim
         return self.ssm_expand * self.hidden_size
 
     @property
@@ -550,17 +577,22 @@ class TransformerConfig:
         them out and their parameters lie under ``params["runs"]``, a stack a
         run and place in its unit, in ``params["blocks"]``' place."""
         return bool(self.ssm_state or self.differential_attention
-                    or self.shared_from is not None)
+                    or self.shared_from is not None or self.layer_mixers is not None)
 
     def mixer_of(self, layer: int) -> Tuple[str, Optional[str]]:
         """Layer ``layer``'s token mixer (a name of ``mixers.KINDS``) and what
         it hands on: the ONE place a layer's mixer is picked from the
         configuration. A plain stack's layers all have ``attention``'s
         (``"mha"`` under an ``indexer``: ``"selected"``) and hand nothing on; a
-        mixed stack's ``("ssm" | "attn" | "gmu" | "cross", None | "memory" |
-        "kv")``."""
+        mixed stack's either what ``layer_mixers`` names (``"ssd"`` | ``"mha"``,
+        nothing handed on; `TransformerLM` holds the list to the depth and to those
+        two names) or, without a list, what a decoder-hybrid-decoder stack's ``l %
+        ssm_period`` rule gives: ``("ssm" | "attn" | "gmu" | "cross", None |
+        "memory" | "kv")``."""
         if not self.mixed:
             return ("selected" if self.indexer is not None else self.attention), None
+        if self.layer_mixers is not None:       # a list, where no rule gives the kinds
+            return self.layer_mixers[layer], None
         scan = bool(self.ssm_state) and layer % self.ssm_period == 0
         at = self.shared_from
         if at is not None and layer >= at + 2:
@@ -678,6 +710,17 @@ MECHANISMS: Dict[str, Tuple[Callable[["TransformerLM"], bool], str]] = {
                          "matrix a sub-layer (hyper-connections)"),
     "ssm_state": (lambda m: bool(m.config.ssm_state),
                   "some layers carry a selective scan's state along the row in attention's place"),
+    "ssm_heads": (lambda m: bool(m.config.ssm_heads),
+                  "the scan layers are Mamba-2's: heads with one decay each over shared B and "
+                  "C, a chunked core, a gated norm"),
+    "layer_mixers": (lambda m: m.config.layer_mixers is not None,
+                     "each layer's token mixer is named by a list: the stack runs as runs "
+                     "of kinds, its parameters a stack a run"),
+    "residual_scale": (lambda m: m.config.residual_scale != 1.0,
+                       "a branch's output is multiplied before it is added to the stream"),
+    "logits_divisor": (lambda m: m.config.logits_divisor != 1.0,
+                       "the head's logits are divided by a constant, in the loss and its "
+                       "gradient"),
     "differential_attention": (lambda m: m.config.differential_attention,
                                "paired heads' two softmaxes are subtracted under a learned scalar"),
     "shared_from": (lambda m: m.config.shared_from is not None,
@@ -801,6 +844,7 @@ class TransformerLM:
         # each layer's token mixer and what it hands on, picked ONCE
         # (``TransformerConfig.mixer_of``), and one `mixers.Mixer` a kind by
         # its name; a plain stack has one kind, `_mixer`
+        self._check_layer_mixers()
         self._mixer_kinds = tuple(c.mixer_of(l) for l in range(c.num_layers))
         self._mixers = mixers.build(c, self)
         self._mixer = None if c.mixed else next(iter(self._mixers.values()))
@@ -944,6 +988,12 @@ class TransformerLM:
                 "moe.router_input='block_input' (the routing made before the token "
                 "mixer) is written for sequential pre-norm or sandwich blocks of one "
                 "stream: no post-norm, parallel block, FarSkip or hyper-connected streams")
+        if c.residual_scale != 1.0 and (c.norm_style == "post" or c.parallel_block
+                                        or c.farskip or c.residual_streams > 1):
+            raise NotImplementedError(
+                "residual_scale (a branch's output times a constant before it is added) "
+                "is written for sequential pre-norm or sandwich blocks of one stream: no "
+                "post-norm, parallel block, FarSkip or hyper-connected streams")
         if c.norm_style not in ("pre", "post", "sandwich"):
             raise ValueError(f"norm_style {c.norm_style!r}")
         if c.norm_style == "sandwich" and c.parallel_block:
@@ -1232,6 +1282,27 @@ class TransformerLM:
         before the token mixer runs (``MoEConfig.router_input``)."""
         return self.config.moe is not None and self.config.moe.router_input == "block_input"
 
+    def _check_layer_mixers(self) -> None:
+        """A configuration's list of layer kinds, held where the model is built:
+        a name a layer, of the two kinds a list may carry, a scan layer among them
+        (`mixers._Mixed.check` then holds the stack), and none of what the ``l %
+        ssm_period`` rule's stacks have beside it."""
+        c, kinds = self.config, self.config.layer_mixers
+        if kinds is None:
+            if c.ssm_heads:
+                raise ValueError("ssm_heads: Mamba-2's scan layers are named by "
+                                 "layer_mixers ('ssd'), not by the ssm_period rule")
+            return
+        if (len(kinds) != c.num_layers or set(kinds) - {"ssd", "mha"}
+                or "ssd" not in kinds):
+            raise ValueError(
+                f"layer_mixers {kinds!r}: one name a layer ({c.num_layers}), each 'ssd' "
+                "or 'mha', with a scan layer among them (a stack of 'mha' alone is a "
+                "plain one: leave layer_mixers None)")
+        if c.differential_attention or c.shared_from is not None:
+            raise ValueError("layer_mixers: differential_attention and shared_from are "
+                             "the ssm_period rule's stacks'")
+
     def _route_ahead(self, block: Params, x: jax.Array):
         """An expert block's routing from its own un-normed input ``x``, made
         before the token mixer is called (`_routes_ahead`; None for every other
@@ -1446,6 +1517,12 @@ class TransformerLM:
             post = ((lambda name, y: y) if c.norm_style != "sandwich"
                     else functools.partial(self._norm_post, block))
             add = self._add_fp32 if c.residual_fp32 else (lambda x, y: x + y)
+            if c.residual_scale != 1.0:
+                # a branch's output times a constant: the float32 product rounded,
+                # as the published multiply (0.22 is no bfloat16 number)
+                plain = post
+                post = lambda name, y: (plain(name, y).astype(jnp.float32)
+                                        * c.residual_scale).astype(y.dtype)
             x = add(x, keep * post("post_ln_1", attn_out))
             h2 = self._block_layers["ln_2"](block["ln_2"], x)
             mlp_out, aux, rows = self._mlp(block, h2, routing)
@@ -1523,7 +1600,9 @@ class TransformerLM:
             logits = logits + params["mlm"]["bias"].astype(logits.dtype)
         if c.pred_heads > 1:     # [B, S, heads, V]: head m reads token i + 1 + m
             logits = logits.reshape(logits.shape[:-1] + (c.pred_heads, c.vocab_size))
-        return logits.astype(jnp.float32)
+        logits = logits.astype(jnp.float32)
+        # (every form of the head's loss computes its logits here)
+        return logits if c.logits_divisor == 1.0 else logits / c.logits_divisor
 
     @functools.cached_property
     def mechanisms(self) -> Tuple[str, ...]:

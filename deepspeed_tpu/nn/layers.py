@@ -215,12 +215,26 @@ class ScanParams:
     channels: int
     states: int
     taps: int = 4
+    #: Mamba-2 (arXiv:2405.21060): ``A_log``, ``D`` and ``dt_bias`` are ``[heads]``,
+    #: one scalar a head (``A_log`` the log of a uniform draw in [1, 16]), and
+    #: ``channels`` the convolution's alone; 0: Mamba-1's arrays, above
+    heads: int = 0
 
     def init(self, rng, dtype=jnp.float32) -> Params:
         k_conv, k_dt = jax.random.split(rng)
         bound = self.taps ** -0.5
-        dt = jnp.exp(jax.random.uniform(k_dt, (self.channels,), jnp.float32)
+        dt = jnp.exp(jax.random.uniform(k_dt, (self.heads or self.channels,), jnp.float32)
                      * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+        if self.heads:
+            return {
+                "conv": jax.random.uniform(k_conv, (self.taps, self.channels), jnp.float32,
+                                           -bound, bound).astype(dtype),
+                "conv_bias": jnp.zeros((self.channels,), dtype),
+                "A_log": jnp.log(jax.random.uniform(
+                    jax.random.fold_in(k_dt, 1), (self.heads,), jnp.float32, 1.0, 16.0)
+                ).astype(dtype),
+                "D": jnp.ones((self.heads,), dtype),
+                "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)}
         return {
             "conv": jax.random.uniform(k_conv, (self.taps, self.channels), jnp.float32,
                                        -bound, bound).astype(dtype),
@@ -231,6 +245,8 @@ class ScanParams:
             "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)}
 
     def specs(self) -> Params:
+        if self.heads:      # (B and C's channels are every head's: nothing divided)
+            return {name: P() for name in ("conv", "conv_bias", "A_log", "D", "dt_bias")}
         return {"conv": P(None, MODEL_AXIS), "conv_bias": P(MODEL_AXIS),
                 "A_log": P(MODEL_AXIS, None), "D": P(MODEL_AXIS), "dt_bias": P(MODEL_AXIS)}
 

@@ -224,14 +224,20 @@ def resolve_policy(name: Optional[str]):
 #: products over the hidden size like the MLP's first and join ``o_proj``'s
 #: neighbourhood; ``kv_proj`` (a mixed stack's fused key and value projection)
 #: stands in front of ``k_proj``'s group, and ``ssm_dt`` (contracted over dt's
-#: rank, 160) and ``ssm_x`` (192 wide), cheap to make again, beside it.
+#: rank, 160) and ``ssm_x`` (192 wide), cheap to make again, beside it. A
+#: Mamba-2 layer (PR 65) names its core's result and its chunks' entry states
+#: ``ssd_m`` / ``ssd_state`` (8,192 B a token each at 64 x 64 x 128 in chunks of
+#: 256), right behind the selective scan's pair and for its reason, and the in
+#: projection's two wide products ``ssm_in`` (the convolution's input) and
+#: ``ssm_z`` (the gate), which join ``ssm_in``'s group.
 SAVE_ORDER = (("indexer_kl_dq", "indexer_kl_dk", "indexer_kl_dw"), ("dsa_mask",), ("attn_lse_dsa", "attn_o_dsa"),
               ("attn_lse", "attn_o"), ("attn_lse_mla", "attn_o_mla"),
               ("attn_lse_diff", "attn_o_diff"), ("ssm_m", "ssm_state"),
+              ("ssd_m", "ssd_state"),
               ("eva_kbar", "eva_vbar"),
               ("moe_logits",), ("wi_gate", "wi_up"),
               ("wi",), ("wo",), ("fc_in",), ("gate_proj", "up_proj"),
-              ("o_proj",), ("attn_gate",), ("ssm_in", "gmu_in"),
+              ("o_proj",), ("attn_gate",), ("ssm_in", "ssm_z", "gmu_in"),
               ("kv_proj",), ("ssm_dt", "ssm_x"),
               ("q_proj", "k_proj", "v_proj", "kv_latent", "q_latent", "q_b_proj",
                "indexer_q", "indexer_k"),

@@ -53,6 +53,10 @@ USES = {
                           qk_rope_dim=2, v_head_dim=8),
     "residual_streams": dict(residual_streams=2, hc_sinkhorn_iters=3),
     "ssm_state": dict(ssm_state=4, ssm_dt_rank=2),
+    "ssm_heads": dict(ssm_state=4, ssm_heads=2, ssm_head_dim=8, layer_mixers=("ssd", "ssd")),
+    "layer_mixers": dict(ssm_state=4, ssm_heads=2, ssm_head_dim=8, layer_mixers=("ssd", "mha")),
+    "residual_scale": dict(residual_scale=0.5),
+    "logits_divisor": dict(logits_divisor=2.0),
     "differential_attention": dict(differential_attention=True),
     "shared_from": dict(num_layers=4, ssm_state=4, ssm_dt_rank=2, shared_from=0),
     "first_dense_layers": dict(first_dense_layers=1, dense_intermediate_size=32, moe=EXPERTS),
@@ -152,6 +156,7 @@ PLAIN = {
     "dense_intermediate_size", "mtp_loss_coef", "block_length", "mask_token_id", "noise_seed",
     "hc_sinkhorn_iters", "hc_eps", "hc_res_clamp",
     "ssm_conv", "ssm_expand", "ssm_dt_rank", "ssm_period",
+    "ssm_head_dim", "ssm_groups", "ssm_chunk",
 }
 
 

@@ -26,6 +26,7 @@ PRESETS = {
     "keye-vl2-tiny": (models.keye_vl2_model, ["selected"]),
     "xing4-tiny": (models.xing4_model, ["latent"]),
     "phi4flash-tiny": (models.phi4flash_model, ["ssm", "attn", "gmu", "cross"]),
+    "granite-hybrid-tiny": (models.granite_hybrid_model, ["ssd", "mha"]),
     "llama2-tiny": (models.llama_model, ["mha"]),
     "bert-tiny": (models.bert_model, ["mha"]),
     "falcon-tiny": (models.falcon_model, ["mha"]),
@@ -110,14 +111,14 @@ RECORD_KEYS = {
             "select_tiles", "select_rows", "dq", "layout", "kl", "kl_tiles", "operand",
             "operand_bytes"},
     "mla": {"qk_dim", "v_dim", "q_rank", "kv_rank", "route", "dq", "layout"},
-    "ssm": {"layers", "memory_units", "d_inner", "d_state", "conv", "dt_rank", "route",
-            "chunk", "tile"},
+    "ssm": {"kind", "heads", "head_dim", "groups", "layers", "memory_units", "d_inner",
+            "d_state", "conv", "dt_rank", "route", "chunk", "tile"},
     "diff": {"qk_dim", "v_dim", "launches_a_layer", "shared_readers"},
     "diffusion": {"block_length", "rows_per_token", "route", "dq", "layout"},
 }
 RECORDS = {"gpt2-tiny": [], "instella-tiny": [], "evabyte-tiny": ["eva"],
            "keye-vl2-tiny": ["dsa"], "xing4-tiny": ["mla"], "sdar-tiny": ["diffusion"],
-           "phi4flash-tiny": ["ssm", "diff"]}
+           "phi4flash-tiny": ["ssm", "diff"], "granite-hybrid-tiny": ["ssm"]}
 
 
 @pytest.mark.parametrize("preset", sorted(RECORDS))

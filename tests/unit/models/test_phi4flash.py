@@ -94,6 +94,7 @@ def test_first_step_through_initialize_leaf_by_leaf(parts, wanted):
         total += np.sum(s != 0)
     assert wrong / total < 2e-3
     assert engine.attn_totals["ssm"] == {
+        "kind": "selective", "heads": None, "head_dim": None, "groups": None,
         "layers": 3, "memory_units": 1, "d_inner": 128, "d_state": 4, "conv": 4, "dt_rank": 4,
         "route": "xla", "chunk": 64, "tile": None}
     assert engine.attn_totals["diff"] == {"qk_dim": 16, "v_dim": 32, "launches_a_layer": 2,

@@ -686,6 +686,17 @@ CELLS = {
             "full": (1, 5_098_500_000, {"moe_logits": 8_388_608, "q_proj": 234_881_024, "k_proj": 33_554_432, "v_proj": 33_554_432, "attn_o": 234_881_024, "attn_lse": 3_670_016, "o_proj": 167_772_160, "wi_gate": 226_492_416, "wi_up": 226_492_416, "wo": 754_974_720}),
             "window4096": (3, 5_333_400_000, {"moe_logits": 8_388_608, "q_proj": 234_881_024, "k_proj": 33_554_432, "v_proj": 33_554_432, "attn_o": 234_881_024, "attn_lse": 3_670_016, "o_proj": 167_772_160, "wi_gate": 226_492_416, "wi_up": 226_492_416, "wo": 754_974_720}),
         }),
+    # (PR 65; the room, the dearest block's and the step's bytes as the chip's log
+    # line and `memory_totals` print them, the attention block's as
+    # tools/remat_plan.py walks it; the names' bytes exact. The budget, 1,520.8 MB,
+    # ends between the flash pair's 166.1 and the SSD core's result, 2,899.1 over
+    # nine layers: the backward runs `ssd_fwd` again)
+    "granite-4.0-h-micro.train.ssd32k": dict(
+        room=6_536_500_000, grads=1_595_701_120, outside=1_161_830_400, carry=134_348_804,
+        step_bytes=6_597_361_664, reaches='attn_o', kinds={
+            "ssd.full": (9, 3_672_200_000, {"ssm_in": 285_212_672, "ssm_z": 268_435_456, "ssd_m": 268_435_456, "ssd_state": 268_435_456, "gate_proj": 536_870_912, "up_proj": 536_870_912}),
+            "mha.full": (1, 2_877_825_558, {"q_proj": 134_217_728, "k_proj": 33_554_432, "v_proj": 33_554_432, "attn_o": 134_217_728, "attn_lse": 4_194_304, "o_proj": 134_217_728, "gate_proj": 536_870_912, "up_proj": 536_870_912}),
+        }),
 }
 # </CELLS>
 

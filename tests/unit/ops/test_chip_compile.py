@@ -162,6 +162,24 @@ class TestCompilesForV5e:
         assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
         assert "[16384,5120,16]" not in text and "[16384,16,5120]" not in text
 
+    def test_ssd_fwd_bwd_at_32k(self, chip):
+        """The granite-4.0-h-micro cell's state-space-duality core (PR 65): 32,768
+        rows of 64 heads of 64 over 128 states in one group, forward and backward,
+        and neither the decay mask nor the states a token in the program."""
+        from deepspeed_tpu.ops.transformer import pallas_ssd
+        R, H, P, N = 32768, 64, 64, 128
+
+        def loss(a, dt, A, B, C, D, first):
+            return jnp.sum(pallas_ssd.ssd_kernel(
+                a, dt, A, B, C, D, first, interpret=False).astype(F32))
+
+        fn = jax.value_and_grad(loss, argnums=tuple(range(6)))
+        args = (chip((R, H * P), BF16), chip((R, H), F32), chip((H,), F32),
+                chip((R, N), BF16), chip((R, N), BF16), chip((H,), F32), chip((R,), I32))
+        text = jax.jit(fn).lower(*args).compile().as_text()    # (one compile: 40 s)
+        assert "tpu_custom_call" in text and "ssd_fwd" in text and "ssd_bwd" in text
+        assert "[32768,64,64,128]" not in text and "[128,256,256,64]" not in text
+
     def test_blockdiff_attention_at_8k(self, chip, monkeypatch):
         """The sdar-30b-a3b cell's attention core: 32 query heads over 4 key
         heads of 128, 16,384 rows (a clean and a noised copy of 8,192

@@ -157,6 +157,10 @@ KERNEL_NAMES = {
     # the selective scan a chunk at a time (PR 57; ``pallas_scan``), under
     # ``ssm/scan``: ``ssm_scan_roofline`` finds the launches by these names
     "ssm_scan_fwd", "ssm_scan_bwd",
+    # the state-space-duality core a chunk and a tile of heads at a time (PR 65;
+    # ``pallas_ssd``), under ``ssm/ssd``: ``ssm_ssd_roofline`` finds the launches
+    # by these names
+    "ssd_fwd", "ssd_bwd",
     # ``dO x O``'s row sum for a launch whose operands lie by rows (PR 51): NOT
     # ``flash_bwd*``, whose readers sum the backward launches alone
     "flash_delta",
@@ -195,9 +199,9 @@ def test_every_pallas_call_has_a_name(site):
 
 
 def test_kernel_names_are_distinct_and_complete():
-    assert len(PALLAS_SITES) == 25
+    assert len(PALLAS_SITES) == 27
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 41
+    assert len(set(names)) == len(names) == 43
     assert set(names) == KERNEL_NAMES
 
 

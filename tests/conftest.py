@@ -9,10 +9,27 @@ real shardings. Must run before jax initializes its backends.
 
 import os
 
+#: What the harness asks of XLA, each flag unless the caller's ``XLA_FLAGS``
+#: names it. Level 0: the suite's time is COMPILING (PR 58's profile; six
+#: workers on eight cores), and LLVM's optimiser for the host is no part of what
+#: a test holds: HLO passes, buffers and schedules come before it, the chip's
+#: programs never pass through it. The whole run's seconds: CHANGES.md, PR 66.
+HARNESS_XLA_FLAGS = ("--xla_force_host_platform_device_count=8",
+                     "--xla_backend_optimization_level=0")
+
+
+def harness_xla_flags(incoming):
+    """``XLA_FLAGS`` for the test process, from the caller's: ``XLA_FLAGS=
+    --xla_backend_optimization_level=3 pytest <file>`` keeps the optimiser."""
+    flags = incoming.split()
+    for flag in HARNESS_XLA_FLAGS:
+        if flag.split("=")[0].lstrip("-") not in incoming:
+            flags.append(flag)
+    return " ".join(flags)
+
+
 os.environ["JAX_PLATFORMS"] = "cpu"
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
+os.environ["XLA_FLAGS"] = harness_xla_flags(os.environ.get("XLA_FLAGS", ""))
 os.environ.setdefault("DSTPU_ACCELERATOR", "cpu")
 
 import jax  # noqa: E402

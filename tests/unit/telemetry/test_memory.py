@@ -1,5 +1,7 @@
 """Memory telemetry: compiled-HLO report + live-buffer watermarks."""
 
+import gc
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -33,6 +35,9 @@ def test_lower_and_report_swallow_bad_fn():
 
 def test_live_bytes_watermark_tracks_allocations():
     tracker = MemoryTracker()
+    # the count is of the PROCESS's live arrays: another file's arrays, dead
+    # but in a reference cycle, are collected now and not between two samples
+    gc.collect()
     base = tracker.sample("t0")["live_bytes"]
     big = jnp.zeros((256, 1024), jnp.float32)  # 1 MiB
     s1 = tracker.sample("t1")

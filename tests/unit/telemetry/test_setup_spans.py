@@ -168,7 +168,7 @@ def test_events_outside_any_span_go_nowhere(stepped):
 @pytest.mark.parametrize("telemetry", ["off", "on"])
 def test_another_batch_shape_compiles_through_the_same_door(telemetry, tmp_path):
     reset_telemetry()
-    threads = threading.active_count()
+    threads = set(threading.enumerate())
     engine = _engine(tmp_path if telemetry == "on" else None)
     for _ in range(2):
         engine.train_batch(_batch())
@@ -185,7 +185,8 @@ def test_another_batch_shape_compiles_through_the_same_door(telemetry, tmp_path)
     if telemetry == "off":
         assert engine.telemetry is NULL_TELEMETRY
         assert get_telemetry() is NULL_TELEMETRY
-        assert threading.active_count() == threads       # no thread started
+        # none started; another file's may END meanwhile (3 -> 1 in a whole run)
+        assert set(threading.enumerate()) <= threads
         assert not list(tmp_path.iterdir())              # no file written
         return
     events = engine.telemetry.trace.events()

@@ -45,6 +45,31 @@ MANIFESTS = {"real": os.path.join(REPO, "BENCHMARK.json"), **data_manifests()}
 #   the vocabulary and the depth and nothing else. An expert count cut with
 #   no deployment stated is what the guide forbids, so without a sound block
 #   it is refused.
+# - The two floors are two (the guide, section 4: "at least 8 routed experts
+#   in each layer that has them, and at least an eighth of the vocabulary"),
+#   so the divisions are two (PR 67): the experts are divided over every chip
+#   that shares a layer, the vocabulary's rows over eight of them at the most
+#   (``ROWS_OVER``: over ``min(chips, 8)``). Up to eight chips that is the one
+#   division it was. Beyond eight, as in the guide's own "with 256 experts
+#   over 32 chips, 8 experts live here", the chips come in whole groups of
+#   eight, each slice of the rows lies on ``chips / 8`` of them, a copy each
+#   (vocabulary parallelism inside a subgroup of an expert-parallel group),
+#   and ``published`` names an expert count, so that the number of chips
+#   still reproduces a number of the file. ``held`` says the layout in words:
+#
+#       "share": {"chips_sharing_a_layer": 16,
+#                 "published": {"num_experts": 256, "vocab_size": 163840,
+#                               "num_hidden_layers": 27},
+#                 "held": "experts 0-15 of every expert layer, vocabulary rows 0-20479
+#                          (rank 0 of 16; the rows over 8, two chips a slice)"}
+#
+#   No key says over how many chips the rows lie: no file wants them over
+#   fewer than the floor allows, and the first that does argues the key in a
+#   ``benchmark`` PR. Whom the rule is for: 44 of the catalog's 88 rows count
+#   more than 128 routed experts (scanned for PR 67 under the five names
+#   below: 160 to 896, 23 rows of 256), and under one division each could
+#   slice its vocabulary only while holding an eighth of its experts, 20 to
+#   112 a layer.
 # - The depth floor holds in EVERY file that states a share, whatever its
 #   family calls its leading dense layers: ``first_k_dense_replace`` or
 #   ``num_dense_layers`` (``DENSE_FIRST``) where the file has either, none
@@ -83,6 +108,9 @@ MANIFESTS = {"real": os.path.join(REPO, "BENCHMARK.json"), **data_manifests()}
 DEPTH = {"num_hidden_layers", "n_layer", "num_layers"}
 DENSE_FIRST = {"first_k_dense_replace", "num_dense_layers"}
 VOCABULARY = "vocab_size"
+#: of however many chips share a layer, the vocabulary's rows lie over no more
+#: than this many: its floor, an eighth
+ROWS_OVER = 8
 SHARED = EXPERT_COUNT | {VOCABULARY}
 
 
@@ -112,21 +140,28 @@ def share_faults(cfg):
     chips, published, held = share["chips_sharing_a_layer"], share["published"], share["held"]
     if type(chips) is not int or chips < 2 or not isinstance(published, dict):
         return ["chips_sharing_a_layer is a whole number from 2, published a table"]
+    if chips > ROWS_OVER and chips % ROWS_OVER:
+        return [f"{chips} chips: more than {ROWS_OVER} share a layer in whole groups of "
+                f"{ROWS_OVER}"]
+    if chips > ROWS_OVER and not EXPERT_COUNT & set(published):
+        return [f"{chips} chips: more than {ROWS_OVER} divide experts alone, and published "
+                "names no expert count"]
     faults = [] if isinstance(held, str) and held.strip() else ["held says nothing"]
     for key, full in published.items():
         here = cfg.get(key)
+        over = min(chips, ROWS_OVER) if key == VOCABULARY else chips
         if key not in SHARED | DEPTH:
             faults.append(f"{key}: published may name an expert count, the vocabulary and depth")
         elif type(full) is not int or type(here) is not int or not 0 < here <= full:
             faults.append(f"{key}: the file and published give whole numbers, 0 < file <= published")
         elif here != full and key not in cfg.get("reduced", []):
             faults.append(f"{key}: differs from the published value and is not in reduced")
-        elif key in SHARED and here != -(-full // chips):
-            faults.append(f"{key}: {here} is not {full} over {chips} chips, rounded up")
+        elif key == VOCABULARY and ROWS_OVER * here < full:   # said first: it is the cause
+            faults.append(f"{key}: less than an eighth of the vocabulary")
+        elif key in SHARED and here != -(-full // over):
+            faults.append(f"{key}: {here} is not {full} over {over} chips, rounded up")
         elif key in EXPERT_COUNT and here < 8:
             faults.append(f"{key}: fewer than 8 experts held")
-        elif key == VOCABULARY and 8 * here < full:
-            faults.append(f"{key}: less than an eighth of the vocabulary")
     fault = depth_fault(cfg)
     return faults + ([fault] if fault else [])
 
@@ -216,14 +251,37 @@ PRIMARY_CUT = {"moe_num_primary_experts": 16, "moe_num_active_primary_experts": 
                                        "num_hidden_layers": 52},
                          "held": "experts 0-15 of every layer, vocabulary rows 0-37983 "
                                  "(rank 0 of 4)"}}
+#: the cut ISSUE 67 sized (a file of 256 routed experts under ``num_experts``,
+#: 8 a token, one leading dense layer, a period of 4 layers of 27, vocabulary
+#: 163,840): sixteen chips share each layer's experts and the vocabulary's
+#: rows lie over eight of them, so each floor is met by its own division
+OVER_CUT = {"num_experts": 16, "num_experts_per_token": 8, "first_k_dense_replace": 1,
+            "num_hidden_layers": 5, "vocab_size": 20480,
+            "reduced": ["num_experts", "num_hidden_layers", "vocab_size"],
+            "share": {"chips_sharing_a_layer": 16,
+                      "published": {"num_experts": 256, "vocab_size": 163840,
+                                    "num_hidden_layers": 27},
+                      "held": "experts 0-15 of every expert layer, vocabulary rows 0-20479 "
+                              "(rank 0 of 16; the rows over 8, two chips a slice)"}}
+#: the same deployment as the guide's own example has it, "256 experts over
+#: 32 chips, 8 experts live here": both floors met exactly
+FLOORS_CUT = dict(OVER_CUT, num_experts=8, share=dict(
+    OVER_CUT["share"], chips_sharing_a_layer=32,
+    held="experts 0-7 of every expert layer, vocabulary rows 0-20479 "
+         "(rank 0 of 32; the rows over 8, four chips a slice)"))
 #: the sized cuts, by how many chips share a layer
-CUTS = {"eight-share-a-layer": SHARE_CUT, "four-share-a-layer": PRIMARY_CUT}
+CUTS = {"eight-share-a-layer": SHARE_CUT, "four-share-a-layer": PRIMARY_CUT,
+        "sixteen-share-a-layer": OVER_CUT, "thirty-two-share-a-layer": FLOORS_CUT}
+#: the third cut's name, which most of its spoiled variants start from
+OVER = "sixteen-share-a-layer"
 
 
 def a_file(key, share):
     """The least file that lists ``key`` in ``reduced``; with ``share``, one
     that states an eighth of a deployment and names ``key`` as published
-    (four layers deep, the least a share file may be)."""
+    (four layers deep, the least a share file may be); with ``share`` "over",
+    sixteen chips share a layer's experts (under ``key`` where it counts
+    them) and the vocabulary's rows lie over eight."""
     held = 16112 if key == VOCABULARY else 8
     cfg = {key: held, "reduced": [key]}
     if share:
@@ -231,6 +289,11 @@ def a_file(key, share):
                         "held": "rank 0 of 8"}
         if key not in DEPTH:
             cfg["num_hidden_layers"] = 4
+    if share == "over":
+        count = key if key in EXPERT_COUNT else "n_routed_experts"
+        cfg["share"].update(chips_sharing_a_layer=16, held="rank 0 of 16, the rows over 8")
+        cfg["share"]["published"].update({count: 128, VOCABULARY: 8 * 16112})
+        cfg.update({count: 8, VOCABULARY: 16112, "reduced": sorted({count, VOCABULARY})})
     return cfg
 
 
@@ -271,7 +334,10 @@ NEVER = ("moe_num_active_primary_experts", "num_experts_per_token", "experts_top
     ("n_shared_experts", True, False),
     *[(key, share, False) for key in NEVER for share in (False, True)],
     # depth and dropouts as ever, beside a block too
-    ("num_hidden_layers", True, True), ("attn_pdrop", True, True)])
+    ("num_hidden_layers", True, True), ("attn_pdrop", True, True),
+    # sixteen chips share a layer, the vocabulary's rows over eight of them:
+    # the block buys the expert count, under each of its names, and the slice
+    *[(key, "over", True) for key in sorted(EXPERT_COUNT)], ("vocab_size", "over", True)])
 def test_reduced_may_name_depth_and_never_a_width(key, share, allowed):
     assert may_be_reduced(key, a_file(key, share)) is allowed
 
@@ -319,6 +385,27 @@ SPOILED = {
              published={"moe_num_active_primary_experts": 6},
              reduced=PRIMARY_CUT["reduced"] + ["moe_num_active_primary_experts"]),
         "moe_num_active_primary_experts: published may name"),
+    # the third and fourth cuts' (sixteen and thirty-two chips, the
+    # vocabulary's rows over eight of them): the rows divided by the chips as
+    # the experts are, a slice that is not an eighth, the experts divided by
+    # eight as the rows are, the experts' floor, chips that are no whole
+    # groups of eight, and chips that reproduce no number of the file
+    "a-sixteenth-of-the-rows-over-sixteen": (dict(cut=OVER, vocab_size=10240), "an eighth"),
+    "a-thirty-second-of-the-rows-over-thirty-two": (
+        dict(cut="thirty-two-share-a-layer", vocab_size=5120), "an eighth"),
+    "a-quarter-of-the-rows-over-sixteen": (
+        dict(cut=OVER, vocab_size=40960), "40960 is not 163840 over 8 chips"),
+    "an-eighth-of-the-experts-over-sixteen": (
+        dict(cut=OVER, num_experts=32), "32 is not 256 over 16 chips"),
+    "a-sixteenth-of-the-experts-over-thirty-two": (
+        dict(cut="thirty-two-share-a-layer", num_experts=16), "16 is not 256 over 32 chips"),
+    "four-of-the-experts-over-sixty-four": (
+        dict(cut=OVER, chips=64, num_experts=4), "fewer than 8 experts"),
+    "twelve-share-a-layer": (
+        dict(cut=OVER, chips=12, num_experts=22), "12 chips: more than 8 share a layer in whole"),
+    "sixteen-share-a-layer-and-no-experts-published": (
+        dict(cut=OVER, num_experts=256, published={"num_experts": None},
+             reduced=["num_hidden_layers", "vocab_size"]), "16 chips: more than 8 divide experts"),
 }
 
 
@@ -398,6 +485,16 @@ def test_a_spoiled_share_is_refused_for_its_own_fault(name):
      "whole numbers"),                     # published names a key the file lacks
     ({"chips_sharing_a_layer": 8, "published": {"num_hidden_layers": 5}, "held": "x"},
      "whole numbers"),                     # deeper than published
+    # no key says over how many chips the rows lie: a fourth key is refused
+    ({"chips_sharing_a_layer": 16, "vocabulary_over": 8,
+      "published": {"n_routed_experts": 128, "vocab_size": 128896}, "held": "x"}, "has the keys"),
+    # beyond eight chips: whole groups of eight, and an expert count to divide
+    ({"chips_sharing_a_layer": 20, "published": {"n_routed_experts": 160}, "held": "x"},
+     "20 chips: more than 8 share a layer in whole groups of 8"),
+    ({"chips_sharing_a_layer": 4096, "published": {"vocab_size": 128896}, "held": "x"},
+     "4096 chips: more than 8 divide experts alone"),
+    ({"chips_sharing_a_layer": 16, "published": {"num_hidden_layers": 27}, "held": "x"},
+     "16 chips: more than 8 divide experts alone"),
 ])
 def test_a_share_block_is_held_to_its_form(share, fault):
     cfg = dict(SHARE_CUT, share=share)
@@ -536,9 +633,11 @@ def test_a_chips_share_is_files_only(tmp_path, cut):
     that lists them in ``reduced``. ISSUE 31's (8 of 64 routed experts,
     16,112 of 128,896 vocabulary rows, 6 of 27 layers, one of them dense,
     eight chips) and ISSUE 61's (16 of 64 under ``moe_num_primary_experts``,
-    37,984 of 151,936 rows, 4 of 52 layers and none dense, four chips): the
-    contract passes on each unedited, and the file is written as the cut has
-    it, with no key put in for it."""
+    37,984 of 151,936 rows, 4 of 52 layers and none dense, four chips) and
+    ISSUE 67's (16 of 256 under ``num_experts`` over sixteen chips or 8 over
+    thirty-two, 20,480 of 163,840 rows over eight of either, 5 of 27 layers,
+    one of them dense): the contract passes on each unedited, and the file is
+    written as the cut has it, with no key put in for it."""
     path = write_share_cell(str(tmp_path), CUTS[cut])
     hold_to_the_contract(path)
     written = load(os.path.join(str(tmp_path), "benchmark/configs/moe-share.json"))

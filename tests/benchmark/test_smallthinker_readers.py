@@ -88,7 +88,7 @@ def test_the_manifest_lists_the_new_cell_where_its_readers_find_something():
         manifest = json.load(f)
     new, = [m for m in manifest["per_layer"] if m["name"] == "train_moe_route_ahead_ms"]
     assert new["workloads"] == [CELL] and new["moves"] == "train_tokens_per_s"
-    assert (manifest["workloads"][-1]["name"], manifest["workloads"][-1]["chips"]) == (CELL, 1)
+    assert [w["chips"] for w in manifest["workloads"] if w["name"] == CELL] == [1]
     assert [m["name"] for m in cell.end_to_end] == ["train_tokens_per_s", "setup_s"]
     cfg = cell.config
     assert (cfg["moe_num_primary_experts"], cfg["vocab_size"], cfg["num_hidden_layers"]) \

@@ -201,6 +201,22 @@ def test_the_block_diffusion_mix_fixes_its_order_and_a_mix_without_the_key_follo
     assert separators(got[SEEDS[0]], sep) != separators(got[SEEDS[1]], sep)
 
 
+def test_the_trinity_mix_fixes_its_order_and_a_window_lies_inside_one_cycle():
+    """``train.seq16k`` carries an ``order_seed`` since PR 67 (the check read
+    the cell's rate 0.56 % apart over six seeds' own orders, under a bound of
+    1 %): two seeds' rows end their documents at the same places and hold
+    other ids, and the 90 rows of set-up, trace and a window of 45 s are under
+    a cycle's, so no window sees a second permutation."""
+    mix, vocab = cell_mix("trinity-mini.train.seq16k")
+    assert mix["order_seed"] == 188
+    cycle = int(traffic.lengths(mix["doc_len"], mix["docs_per_cycle"]).sum()) + mix["docs_per_cycle"]
+    assert cycle // mix["seq_len"] > 2 * 90
+    a, b = (take(traffic.train_batches(mix, seed, vocab, 1), 6) for seed in SEEDS)
+    sep = mix["separator"] % vocab
+    assert separators(a, sep) == separators(b, sep)
+    assert np.mean(np.stack(a) != np.stack(b)) > 0.3
+
+
 # ---------------------------------------------------------------------------
 # train_input_ms
 # ---------------------------------------------------------------------------

@@ -17,6 +17,7 @@ with _setup_spans.importing():
     from .phi4flash import phi4flash_config, phi4flash_model  # noqa: F401
     from .smallthinker import smallthinker_config, smallthinker_model  # noqa: F401
     from .granite_hybrid import granite_hybrid_config, granite_hybrid_model  # noqa: F401
+    from .kimi_linear import kimi_linear_config, kimi_linear_model  # noqa: F401
     from .opt_phi_falcon import (falcon_config, falcon_model, opt_config,  # noqa: F401
                                  opt_model, phi_config, phi_model)
     from .bloom_neox_gptj import (bloom_config, bloom_model, gpt_neo_config,  # noqa: F401

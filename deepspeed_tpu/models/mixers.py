@@ -4,8 +4,8 @@
 ``TransformerConfig.mixer_of(layer)`` picks each layer's kind and
 ``TransformerLM`` holds one `Mixer` a kind (`build`): ``mha``, ``selected``
 (``mha`` under ``config.indexer``), ``latent`` or ``eva`` for a plain stack;
-``ssm``, ``ssd``, ``attn``, ``gmu`` and ``cross`` (and ``mha`` by a list of layer
-kinds) in a mixed one. A block's residual
+``ssm``, ``ssd``, ``kda``, ``attn``, ``gmu`` and ``cross`` (and ``mha`` or ``latent``
+by a list of layer kinds) in a mixed one. A block's residual
 and norm style is NOT a mixer's: ``TransformerLM._block_fn`` has it. A new
 architecture's mixer is a class here, its name in `KINDS`, a branch of
 ``mixer_of``, and a name in ``checkpointing.SAVE_ORDER`` for each value its
@@ -34,6 +34,25 @@ Params = Dict[str, Any]
 #: at once before the heads are taken in groups (``transformer.
 #: MLP_WHOLE_ELEMENTS`` has the reckoning). Memory only: the same arithmetic.
 EVA_GROUP_ELEMENTS = 2 ** 24
+
+
+#: The most elements of ``[rows of a row, batch x heads x head]`` a KDA layer's
+#: convolutions, gates and gated norm (float32 passes of that size, a dozen live at
+#: once in a backward) may take whole before they are taken a slice of the row at a
+#: time. Memory only: the same arithmetic.
+KDA_WHOLE_ELEMENTS = 2 ** 25
+KDA_SLICE_ELEMENTS = 2 ** 24
+
+
+def kda_row_slices(rows: int, width: int) -> int:
+    """How many slices of a row a KDA layer's work around its core is computed in:
+    1 up to `KDA_WHOLE_ELEMENTS` elements of ``[rows, width]``, else the least power
+    of two that brings a slice under `KDA_SLICE_ELEMENTS` and divides the rows."""
+    n = 1
+    if rows * width > KDA_WHOLE_ELEMENTS:
+        while rows % (2 * n) == 0 and rows // n * width > KDA_SLICE_ELEMENTS:
+            n *= 2
+    return n
 
 
 def eva_head_groups(rows: int, heads: int, head_dim: int) -> int:
@@ -550,14 +569,15 @@ class Latent(Attention):
         if min(c.kv_latent_rank, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim) <= 0:
             raise ValueError("latent attention needs kv_latent_rank, "
                              "qk_nope_dim, qk_rope_dim and v_head_dim")
-        if (c.position != "rope" or c.num_kv_heads not in (None, c.num_heads)
+        if (c.position not in ("rope", "none") or c.num_kv_heads not in (None, c.num_heads)
                 or c.attn_windows is not None or c.norm_style != "pre"
                 or c.parallel_block or not c.causal
                 or (c.qk_norm and not c.qk_norm_per_head)):
             raise ValueError(
-                "latent attention is written for a causal pre-norm rotary "
-                "decoder with as many key heads as query heads, no windows, "
-                "and QK-norm (if any) per head")
+                "latent attention is written for a causal pre-norm decoder, rotary "
+                "or with no positions at all (position='none': the shared "
+                "qk_rope_dim stay in the products, unturned), with as many key "
+                "heads as query heads, no windows, and QK-norm (if any) per head")
         if self._widths and c.seq_parallel == "ring":
             raise NotImplementedError(
                 f"value heads of {c.v_head_dim} beside query heads of "
@@ -620,8 +640,9 @@ class Latent(Attention):
                 if c.qk_norm:
                     q = layers["q_norm"](block["q_norm"], q)
                     k = layers["k_norm"](block["k_norm"], k)
-                q = self.host._rotate_tail(q, positions)
-                k = self.host._rotate_tail(k, positions)
+                if c.position == "rope":
+                    q = self.host._rotate_tail(q, positions)
+                    k = self.host._rotate_tail(k, positions)
             # (values narrower than the keys: the two-width launches, their own scope)
             with jax.named_scope("core" if vd == c.head_dim else "core_mla"):
                 scale = c.attn_scale or c.head_dim ** -0.5
@@ -801,27 +822,80 @@ class Eva(Attention):
 # -- a mixed stack's kinds (``TransformerConfig.mixed``): scan layers, memory
 # -- units, differential attention, a cross-decoder --------------------------------
 
+def first_of_document(documents: Optional[jax.Array], shape) -> jax.Array:
+    """``[B, S]`` bool: a row's first position, and a packed document's first
+    token (where a scan layer's state and its convolution's taps start anew)."""
+    B, S = shape
+    start = jnp.broadcast_to(jnp.arange(S)[None, :] == 0, (B, S))
+    if documents is None:
+        return start
+    return start | (documents != jnp.pad(documents[:, :-1], ((0, 0), (1, 0))))
+
+
+def short_conv(w: jax.Array, bias: Optional[jax.Array], a: jax.Array,
+               documents: Optional[jax.Array]) -> jax.Array:
+    """A scan layer's causal depthwise convolution over ``a`` ``[B, S, Di]``,
+    float32: ``sum_s w[taps - 1 - s] a[t - s] (+ bias)`` over the taps s whose
+    token ``t - s`` lies in the row and in t's document (``w`` ``[taps, Di]``)."""
+    B, S, _ = a.shape
+    taps = w.shape[0]
+    w, a32 = w.astype(jnp.float32), a.astype(jnp.float32)
+    at = jnp.arange(S)[None, :]
+    out = a32 * w[taps - 1]
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    for s in range(1, min(taps, S)):
+        seen = at >= s
+        if documents is not None:
+            seen = seen & (documents == jnp.pad(documents[:, :S - s], ((0, 0), (s, 0))))
+        back = jnp.pad(a32[:, :S - s], ((0, 0), (s, 0), (0, 0)))
+        out = out + jnp.where(seen[..., None], back, 0.0) * w[taps - 1 - s]
+    return out
+
+
 class _Mixed(Mixer):
     """What a mixed stack's mixers refuse together."""
 
     def check(self) -> None:
         super().check()
         c = self.c
-        if c.attention != "mha":
-            raise ValueError("ssm_state, differential_attention and shared_from "
-                             "are written for 'mha' heads")
-        if (not c.causal or c.norm_style != "pre"
-                or c.parallel_block or c.farskip or c.moe is not None
-                or c.indexer is not None or c.diffusion or c.mtp_layers
-                or c.residual_streams > 1 or c.qk_norm or c.attn_gate
-                or c.seq_parallel == "ring" or c.position == "alibi"
-                or c.activation != "silu_gated"):
-            raise ValueError(
-                "ssm_state, differential_attention and shared_from are written for "
-                "a causal decoder's sequential pre-norm blocks with 'mha' heads and "
-                "a dense gated-SiLU MLP: no experts, indexer, block diffusion, "
-                "prediction module, hyper-connections, FarSkip, QK-norm, attention "
-                "gate, ALiBi or ring attention")
+        listed = c.layer_mixers is not None
+        stack = ("a stack named by layer_mixers" if listed else
+                 "a stack by the ssm_period rule (ssm_state, differential_attention, "
+                 "shared_from)")
+        # what THIS stack cannot have, the first that holds: one cause a message
+        causes = {
+            "causal=False": (not c.causal, "its layers are a causal decoder's"),
+            f"norm_style={c.norm_style!r}": (
+                c.norm_style != "pre", "its blocks are sequential pre-norm blocks"),
+            "parallel_block": (c.parallel_block, "its blocks are sequential pre-norm blocks"),
+            "farskip": (c.farskip, "one residual stream is carried through the runs"),
+            "residual_streams": (c.residual_streams > 1,
+                                 "one residual stream is carried through the runs"),
+            "indexer": (c.indexer is not None, "no layer kind of it selects its keys"),
+            "objective='block_diffusion'": (
+                c.diffusion, "a scan layer's state runs along one copy of the row"),
+            "mtp_layers": (bool(c.mtp_layers), "a prediction module's block is a plain stack's"),
+            "qk_norm": (c.qk_norm, "its attention layers take q and k as projected"),
+            "attn_gate": (c.attn_gate, "its attention layers have no gate"),
+            "seq_parallel='ring'": (c.seq_parallel == "ring",
+                                    "a scan layer reads the whole row"),
+            "position='alibi'": (c.position == "alibi", "its layers take rope or no position"),
+            f"activation={c.activation!r}": (c.activation != "silu_gated",
+                                             "its MLPs and experts are gated SiLU"),
+            # (a list may carry experts behind leading dense layers, and latent heads)
+            "moe": (c.moe is not None and not listed,
+                    "experts run in a stack named by layer_mixers alone"),
+            f"attention={c.attention!r}": (
+                c.attention != "mha" and not (listed and c.attention == "latent"),
+                "its attention layers are 'mha' (a list may name 'latent' ones)"),
+            "moe.router_input='block_input'": (
+                c.moe is not None and c.moe.router_input == "block_input",
+                "a run's experts are routed from the normed stream"),
+        }
+        for name, (held, why) in causes.items():
+            if held:
+                raise ValueError(f"{name} is not computed in {stack}: {why}")
         at = c.shared_from
         if at is not None and not (
                 0 <= at < c.num_layers - 2 and c.ssm_state
@@ -887,35 +961,7 @@ class Ssm(_Mixed):
         # (``engine.attn_last_step()["ssm_resets"]``: the times a scan layer's
         # state started anew in the step's rows, a row's start or a document's)
         return {"attn_ssm_resets": jnp.sum(
-            self._first_of_document(documents, shape), dtype=jnp.int32)}
-
-    @staticmethod
-    def _first_of_document(documents: Optional[jax.Array], shape) -> jax.Array:
-        """``[B, S]`` bool: a row's first position, and a packed document's
-        first token."""
-        B, S = shape
-        start = jnp.broadcast_to(jnp.arange(S)[None, :] == 0, (B, S))
-        if documents is None:
-            return start
-        return start | (documents != jnp.pad(documents[:, :-1], ((0, 0), (1, 0))))
-
-    def _short_conv(self, ssm: Params, a: jax.Array,
-                    documents: Optional[jax.Array]) -> jax.Array:
-        """A scan layer's causal depthwise convolution over ``a`` ``[B, S, Di]``,
-        float32: ``sum_s conv[taps - 1 - s] a[t - s] + conv_bias`` over the taps
-        s whose token ``t - s`` lies in the row and in t's document."""
-        B, S, _ = a.shape
-        taps = self.c.ssm_conv
-        w, a32 = ssm["conv"].astype(jnp.float32), a.astype(jnp.float32)
-        at = jnp.arange(S)[None, :]
-        out = a32 * w[taps - 1] + ssm["conv_bias"].astype(jnp.float32)
-        for s in range(1, min(taps, S)):
-            seen = at >= s
-            if documents is not None:
-                seen = seen & (documents == jnp.pad(documents[:, :S - s], ((0, 0), (s, 0))))
-            back = jnp.pad(a32[:, :S - s], ((0, 0), (s, 0), (0, 0)))
-            out = out + jnp.where(seen[..., None], back, 0.0) * w[taps - 1 - s]
-        return out
+            first_of_document(documents, shape), dtype=jnp.int32)}
 
     def __call__(self, block, h, positions, documents, kind, given=()):
         from ..ops.transformer import pallas_scan
@@ -928,7 +974,7 @@ class Ssm(_Mixed):
         with jax.named_scope("ssm"):
             with jax.named_scope("in"):
                 az = project("in_proj", h, "ssm_in")
-                a = nn.silu(self._short_conv(ssm, az[..., :Di], documents)).astype(h.dtype)
+                a = nn.silu(short_conv(ssm["conv"], ssm["conv_bias"], az[..., :Di], documents)).astype(h.dtype)
             with jax.named_scope("scan"):
                 rbc = project("x_proj", a, "ssm_x")
                 dt_raw = project("dt_proj", rbc[..., :R], "ssm_dt")
@@ -936,7 +982,7 @@ class Ssm(_Mixed):
                 m = pallas_scan.selective_scan(
                     flat(a), flat(dt_raw), -jnp.exp(ssm["A_log"].astype(jnp.float32)),
                     flat(rbc[..., R:R + N]), flat(rbc[..., R + N:]), ssm["D"],
-                    ssm["dt_bias"], flat(self._first_of_document(documents, (B, S))),
+                    ssm["dt_bias"], flat(first_of_document(documents, (B, S))),
                     devices=devices()).reshape(B, S, Di)
             with jax.named_scope("out"):
                 y = layers["out_proj"](block["out_proj"], m * nn.silu(az[..., Di:]))
@@ -1013,13 +1059,13 @@ class Ssd(Ssm):
                 z = checkpoint_name(cut(0, Di), "ssm_z")
                 xbc = checkpoint_name(cut(Di, 2 * Di + 2 * G * N), "ssm_in")
                 dt_raw = cut(2 * Di + 2 * G * N, None)
-                xbc = nn.silu(self._short_conv(ssm, xbc, documents)).astype(h.dtype)
+                xbc = nn.silu(short_conv(ssm["conv"], ssm["conv_bias"], xbc, documents)).astype(h.dtype)
             with jax.named_scope("ssd"):
                 dt = jax.nn.softplus(dt_raw.astype(f32) + ssm["dt_bias"].astype(f32))
                 m = pallas_ssd.ssd(
                     flat(xbc[..., :Di]), flat(dt), -jnp.exp(ssm["A_log"].astype(f32)),
                     flat(xbc[..., Di:Di + G * N]), flat(xbc[..., Di + G * N:]), ssm["D"],
-                    flat(self._first_of_document(documents, (B, S))), G,
+                    flat(first_of_document(documents, (B, S))), G,
                     devices=devices(), published_chunk=c.ssm_chunk).reshape(B, S, Di)
             with jax.named_scope("out"):
                 # the gate goes in before the norm, a group's channels a statistic
@@ -1029,6 +1075,157 @@ class Ssd(Ssm):
                 normed = normed.reshape(B, S, Di) * block["ssd_norm"]["scale"].astype(f32)
                 y = layers["out_proj"](block["out_proj"], normed.astype(h.dtype))
         return y, None, None
+
+
+class Kda(_Mixed):
+    """A Kimi Delta Attention layer (Kimi Linear, arXiv:2510.26692;
+    ``TransformerConfig.kda_heads`` has the equations) -> (the branch's output,
+    None, None): ``kda_heads`` heads whose state is ``[kda_head_dim, kda_head_dim]``
+    under a decay a CHANNEL and a delta-rule write. Scopes, all under ``attn``:
+    ``kda_in`` (the three projections, their convolutions and SiLU; the
+    normalisation of q and k a head is the core's), ``kda_gate`` (both low-rank gates, the softplus,
+    ``beta``), ``core_kda`` (the chunked recurrence, ``pallas_kda.kda``) and
+    ``kda_out`` (the gated norm a head and the out projection)."""
+
+    name = "kda"
+
+    def _layers(self) -> Dict[str, Any]:
+        c, wide, rank = self.c, self.c.kda_inner, self.c.kda_head_dim
+        low = lambda out: _linear(rank, out, False, "column")
+        return {**{name: _linear(c.hidden_size, wide, False, "column")
+                   for name in ("q_proj", "k_proj", "v_proj")},
+                "kda": nn.DeltaParams(c.kda_heads, wide, c.kda_conv),
+                "kda_fa": _linear(c.hidden_size, rank, False, None), "kda_fb": low(wide),
+                "kda_ga": _linear(c.hidden_size, rank, False, None), "kda_gb": low(wide),
+                "kda_beta": _linear(c.hidden_size, c.kda_heads, False, None),
+                "kda_norm": nn.RMSNorm(c.kda_head_dim, eps=c.norm_eps),
+                "o_proj": _linear(wide, c.hidden_size, False, "row")}
+
+    def check(self) -> None:
+        super().check()
+        c = self.c
+        if min(c.kda_heads, c.kda_head_dim, c.kda_conv) < 1:
+            raise ValueError(
+                f"a 'kda' layer needs kda_heads ({c.kda_heads}), kda_head_dim "
+                f"({c.kda_head_dim}) and kda_conv ({c.kda_conv}) >= 1")
+        _whole_rows("a delta-rule layer carries its state along the whole row")
+
+    def parameters(self) -> int:
+        c, h, wide, rank = self.c, self.c.hidden_size, self.c.kda_inner, self.c.kda_head_dim
+        return (4 * h * wide + 2 * (h * rank + rank * wide) + h * c.kda_heads
+                + 3 * c.kda_conv * wide + c.kda_heads + wide + c.kda_head_dim)
+
+    def record(self, batch=None, seq=None) -> Dict[str, Any]:
+        from ..ops.transformer import pallas_kda
+        c = self.c
+        route = seq and pallas_kda.choose_route(
+            batch * seq, c.kda_heads, c.kda_head_dim, c.kda_head_dim,
+            jax.default_backend(), devices())
+        kernel = route == "kernel"
+        return {"kda": {
+            "heads": c.kda_heads, "key_dim": c.kda_head_dim, "value_dim": c.kda_head_dim,
+            "conv": c.kda_conv, "gate_rank": c.kda_head_dim,
+            "layers": [l for l in range(c.num_layers) if c.mixer_of(l)[0] == "kda"],
+            "route": route,
+            "chunk": route and (pallas_kda.CHUNK if kernel else pallas_kda.xla_chunk(seq)[0]),
+            # (a grid step: one head over a span of rows)
+            "tile": [1, pallas_kda.SPAN] if kernel else None}}
+
+    def _front(self, block: Params, h: jax.Array, documents: Optional[jax.Array],
+               named: bool):
+        """What the core reads, from the normed input ``h`` ``[B, S, C]`` -> (q, k, v
+        ``[B, S, H x D]`` in the stream's dtype, g the same float32, beta ``[B, S, H]``
+        float32, the output gate ``[B, S, H x D]``). ``named``: the products are
+        values the backward may keep."""
+        c, layers, small, f32 = self.c, self.layers(), block["kda"], jnp.float32
+        B, S, _ = h.shape
+        keep = checkpoint_name if named else (lambda a, name: a)
+        low = lambda a, b: layers[b](block[b], layers[a](block[a], h))
+        with jax.named_scope("kda_in"):
+            # (q and k are normalised a head, and q scaled, where the core reads them)
+            q, k, v = (nn.silu(short_conv(
+                small["conv_" + name[0]], None,
+                keep(layers[name](block[name], h), name), documents)).astype(h.dtype)
+                for name in ("q_proj", "k_proj", "v_proj"))
+        with jax.named_scope("kda_gate"):
+            # a decay a channel (not positive), a write strength a head, float32
+            decay = keep(low("kda_fa", "kda_fb"), "kda_decay").astype(f32)
+            g = (-jnp.exp(small["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+                decay + small["dt_bias"].astype(f32)).reshape(B, S, c.kda_heads, -1)
+                ).reshape(B, S, -1)
+            beta = jax.nn.sigmoid(layers["kda_beta"](block["kda_beta"], h).astype(f32))
+            gate = keep(low("kda_ga", "kda_gb"), "kda_gate")
+        return q, k, v, g, beta, gate
+
+    def _back(self, block: Params, o: jax.Array, gate: jax.Array, named: bool) -> jax.Array:
+        """RMSNorm over a head's values (one learned gain of a head's width), times
+        the sigmoid of the output gate, through the out projection."""
+        c, f32 = self.c, jnp.float32
+        B, S, _ = o.shape
+        with jax.named_scope("kda_out"):
+            o32 = o.astype(f32).reshape(B, S, c.kda_heads, c.kda_head_dim)
+            normed = o32 * jax.lax.rsqrt(
+                jnp.mean(o32 * o32, axis=-1, keepdims=True) + c.norm_eps)
+            normed = normed * block["kda_norm"]["scale"].astype(f32)
+            gated = (normed * jax.nn.sigmoid(gate.astype(f32).reshape(o32.shape))).reshape(
+                B, S, -1).astype(o.dtype)
+            if named:
+                return self._project(block, "o_proj", gated)
+            return self.layers()["o_proj"](block["o_proj"], gated)
+
+    def _by_slices(self, block: Params, h: jax.Array, documents, slices: int):
+        """`_front` and `_back` for a long row, a slice of the row at a time (memory
+        only; a slice reads the taps' rows before it, inside its document): each slice
+        is made again in its own backward and nothing in it is named (the block's
+        policy would keep a named value of every slice, stacked) -> (`_front`'s six
+        over the whole row, ``(o, gate) -> the branch's output``)."""
+        B, S, _ = h.shape
+        n, halo = S // slices, self.c.kda_conv - 1
+        docs = jnp.zeros((B, S), jnp.int32) if documents is None else documents
+        wide_h = jnp.pad(h, ((0, 0), (halo, 0), (0, 0)))
+        wide_d = jnp.pad(docs, ((0, 0), (halo, 0)), constant_values=-1)
+        window = lambda a, i: jax.lax.dynamic_slice_in_dim(a, i * n, n + halo, axis=1)
+        whole = lambda a: jnp.moveaxis(a, 0, 1).reshape((B, S) + a.shape[3:])
+        by_slice = lambda a: jnp.moveaxis(a.reshape((B, slices, n) + a.shape[2:]), 1, 0)
+
+        # (a scope opened inside a ``lax.map`` body stands behind ``while/body`` in an
+        # operation's name: ``attn`` is opened again inside, so that a reader finds
+        # ``attn/kda_in`` side by side)
+        def front(i):
+            with jax.named_scope("attn"):
+                made = self._front(block, window(wide_h, i), window(wide_d, i), named=False)
+            return tuple(a[:, halo:] for a in made)
+
+        def back(xs):
+            with jax.named_scope("attn"):
+                return self._back(block, *xs, named=False)
+
+        def finish(o, gate):
+            y = jax.lax.map(jax.checkpoint(back), (by_slice(o), by_slice(gate)))
+            # (the branch's output outlives the slices: named once a layer)
+            return checkpoint_name(whole(y), "o_proj")
+        made = jax.lax.map(jax.checkpoint(front), jnp.arange(slices))
+        return tuple(map(whole, made)), finish
+
+    def __call__(self, block, h, positions, documents, kind, given=()):
+        from ..ops.transformer import pallas_kda
+        c = self.c
+        B, S, _ = h.shape
+        flat = lambda t: t.reshape((B * S,) + t.shape[2:])
+        slices = kda_row_slices(S, B * c.kda_inner)
+        with jax.named_scope("attn"):
+            if slices == 1:
+                made = self._front(block, h, documents, named=True)
+                finish = lambda o, gate: self._back(block, o, gate, named=True)
+            else:
+                made, finish = self._by_slices(block, h, documents, slices)
+            q, k, v, g, beta, gate = made
+            with jax.named_scope("core_kda"):
+                o = pallas_kda.kda(
+                    flat(q), flat(k), flat(v), flat(g), flat(beta),
+                    flat(first_of_document(documents, (B, S))), devices=devices(),
+                    row=S).reshape(B, S, -1)
+            return finish(o, gate), None, None
 
 
 class MemoryUnit(_Mixed):
@@ -1175,7 +1372,7 @@ class Cross(MixedAttention):
 
 
 #: a mixer's class by ``TransformerConfig.mixer_of``'s name for it
-KINDS = {cls.name: cls for cls in (Mha, Selected, Latent, Eva, Ssm, Ssd, MixedAttention,
+KINDS = {cls.name: cls for cls in (Mha, Selected, Latent, Eva, Ssm, Ssd, Kda, MixedAttention,
                                    MemoryUnit, Cross)}
 
 

@@ -69,16 +69,42 @@ def _one_layer(stacked):
     return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), stacked)
 
 
+@jax.custom_vjp
+def _taken_together(x, layer):
+    """``(x, layer)`` as given; differentiated, their two cotangents pass ONE
+    ``optimization_barrier``: a block that runs by itself (not in a layer scan) has
+    its parameters' gradients taken before the layer in front of it starts its
+    backward. Left to its scheduler, XLA puts a lone block's weight gradients off
+    (nothing reads them before the optimizer) and holds their operands across the
+    next run's whole backward (the Kimi Linear cell's first compile for the chip:
+    5.7 GB of the layers behind live beside a scan's 5.7). Every lone block of a
+    listed stack takes it, whatever its mixer or MLP: its backward is then one
+    unit, as a scan's trip is."""
+    return x, layer
+
+
+_taken_together.defvjp(lambda x, layer: ((x, layer), None),
+                       lambda _, d: jax.lax.optimization_barrier(d))
+
+
+def _mlp_of(kind) -> Optional[str]:
+    """A run's layer's MLP, the fifth entry of its kind where a mixed stack has
+    experts (``"dense"``: a leading layer; ``"experts"``), else None."""
+    return kind[4] if len(kind) > 4 else None
+
+
 def _kind_label(key) -> str:
     """A kind of block's line in the remat plan's report, from `_trunk`'s
-    key for it: ``mtp``, or ``[dense.][<mixer>.]<full | window<w>>[.hands_<what>]``."""
+    key for it: ``mtp``, or ``[dense.][<mixer>.]<full | window<w>>[.hands_<what>]``
+    and, in a mixed stack with experts, ``.dense`` | ``.experts``."""
     if key is None:
         return "mtp"
     if key[0] == "dense":
         return "dense." + _kind_label(key[1])
     window, _, *mixer = key
     return ".".join([*mixer[:1], f"window{window}" if window else "full",
-                     *(f"hands_{what}" for what in mixer[1:] if what is not None)])
+                     *(f"hands_{what}" for what in mixer[1:2] if what is not None),
+                     *mixer[2:]])
 
 
 def _token_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
@@ -542,6 +568,23 @@ class TransformerConfig:
     # ``layer_types``): a tuple of ``num_layers`` names of ``mixers.KINDS``
     # (``"ssd"``, ``"mha"``); the stack is then a mixed one
     layer_mixers: Optional[Tuple[str, ...]] = None
+    # Kimi Delta Attention layers (KDA; Kimi Linear, arXiv:2510.26692), named
+    # ``"kda"`` by ``layer_mixers``: ``kda_heads`` heads (0: none) with keys and
+    # values of ``kda_head_dim``. ``q, k, v = silu(conv(u W_{q,k,v}))`` (a causal
+    # depthwise convolution of ``kda_conv`` taps, no bias, inside a document), q
+    # and k L2-normalised a head and q times ``kda_head_dim ** -0.5``; a decay a
+    # CHANNEL ``g_t = -exp(A_log) softplus((u W_fa) W_fb + dt_bias)`` (``A_log`` a
+    # head's scalar, ``dt_bias`` a channel's; the low rank a head's width,
+    # ``kda_head_dim``) and a write strength a head ``beta_t = sigmoid(u W_b)``, both
+    # float32; the state a head ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t))
+    # S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t``
+    # (``ops/transformer/pallas_kda.py``, chunked; S is 0 before a document's first
+    # token); and ``(RMSNorm_head(o) * sigmoid((u W_ga) W_gb)) W_o``, the norm over
+    # a head's values with one learned gain of a head's width. The chunk is the
+    # kernels' own choice: the mathematics does not depend on it.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
     # a branch's output times this before it is added to the stream, and the
     # logits divided by this (Granite's ``residual_multiplier``,
     # ``logits_scaling``); 1.0: absent, nothing traced
@@ -567,6 +610,10 @@ class TransformerConfig:
         return self.ssm_expand * self.hidden_size
 
     @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    @property
     def ssm_rank(self) -> int:
         return self.ssm_dt_rank or -(-self.hidden_size // 16)
 
@@ -584,9 +631,9 @@ class TransformerConfig:
         it hands on: the ONE place a layer's mixer is picked from the
         configuration. A plain stack's layers all have ``attention``'s
         (``"mha"`` under an ``indexer``: ``"selected"``) and hand nothing on; a
-        mixed stack's either what ``layer_mixers`` names (``"ssd"`` | ``"mha"``,
-        nothing handed on; `TransformerLM` holds the list to the depth and to those
-        two names) or, without a list, what a decoder-hybrid-decoder stack's ``l %
+        mixed stack's either what ``layer_mixers`` names (``"ssd"`` | ``"kda"`` |
+        ``"mha"`` | ``"latent"``, nothing handed on; `TransformerLM` holds the list to
+        the depth and to those names) or, without a list, what a decoder-hybrid-decoder stack's ``l %
         ssm_period`` rule gives: ``("ssm" | "attn" | "gmu" | "cross", None |
         "memory" | "kv")``."""
         if not self.mixed:
@@ -649,8 +696,19 @@ class TransformerConfig:
         head = 0 if self.tie_embeddings else self.pred_heads * v * h
         if self.mlm_head:
             head += h * h + v  # prediction transform + decoder bias
+        mlps = L * mlp
+        if self.mixed and self.moe is not None:
+            # a listed stack's own: the leading dense MLPs, then a chip's share of
+            # every expert layer (the experts held, the whole router and its bias,
+            # the shared expert), leaf by leaf
+            lo, hi = self.moe.experts_held or (0, self.moe.num_experts)
+            experts = ((hi - lo) * 3 * h * ffn + h * self.moe.num_experts
+                       + self.moe.num_experts * (self.moe.router == "sigmoid_bias")
+                       + 3 * h * self.moe.shared_width)
+            mlps = (self.first_dense_layers * 3 * h * (self.dense_intermediate_size or 0)
+                    + self.scan_layers * experts)
         of = mixers.build(self)
-        total = embed + head + L * mlp + sum(
+        total = embed + head + mlps + sum(
             of[self.mixer_of(l)[0]].parameters() for l in range(L))
         if self.mixed:
             # LayerNorm's and the projections' biases counted (the published
@@ -713,6 +771,9 @@ MECHANISMS: Dict[str, Tuple[Callable[["TransformerLM"], bool], str]] = {
     "ssm_heads": (lambda m: bool(m.config.ssm_heads),
                   "the scan layers are Mamba-2's: heads with one decay each over shared B and "
                   "C, a chunked core, a gated norm"),
+    "kda_heads": (lambda m: bool(m.config.kda_heads),
+                  "some layers are Kimi Delta Attention's: a state a head under a decay a "
+                  "channel and a delta-rule write, carried along the row"),
     "layer_mixers": (lambda m: m.config.layer_mixers is not None,
                      "each layer's token mixer is named by a list: the stack runs as runs "
                      "of kinds, its parameters a stack a run"),
@@ -1054,14 +1115,14 @@ class TransformerLM:
             # scan reads and what its gradient comes back as, no slice between
             params["runs"] = {
                 str(i): {str(j): jax.vmap(
-                    lambda r, layers=self._layers_of(kind): nn.init_tree(layers, r, dtype)[0])(
+                    lambda r, kind=kind: self._init_run_block(kind, r, dtype))(
                         jax.random.split(jax.random.fold_in(rng_blocks, 64 * i + j), repeats))
                     for j, kind in enumerate(unit)}
                 for i, (unit, repeats) in enumerate(self.run_plan)}
         else:
             params["blocks"] = jax.vmap(init_block)(
                 jax.random.split(rng_blocks, c.scan_layers))
-        if c.first_dense_layers:
+        if self._leading_dense:
             params["dense_blocks"] = jax.vmap(functools.partial(init_block, dense=True))(
                 jax.random.split(jax.random.fold_in(rng_blocks, 1), c.first_dense_layers))
         if c.mtp_layers:
@@ -1098,13 +1159,14 @@ class TransformerLM:
             lambda s: P(None, *s), tree, is_leaf=lambda s: isinstance(s, P))
         if c.mixed:
             specs["runs"] = {
-                str(i): {str(j): stacked({name: layer.specs() for name, layer
-                                          in self._layers_of(kind).items()})
+                str(i): {str(j): stacked({
+                    **{name: layer.specs() for name, layer in self._layers_of(kind).items()},
+                    **({"moe": self._moe.specs()} if _mlp_of(kind) == "experts" else {})})
                          for j, kind in enumerate(unit)}
                 for i, (unit, _) in enumerate(self.run_plan)}
         else:
             specs["blocks"] = stacked(block_specs)
-        if c.first_dense_layers:
+        if self._leading_dense:
             specs["dense_blocks"] = stacked(dense_specs)
         if c.mtp_layers:
             specs["mtp"] = {name: layer.specs() for name, layer in self._mtp_layers.items()}
@@ -1112,9 +1174,26 @@ class TransformerLM:
         return specs
 
     def _layers_of(self, kind) -> Dict[str, Any]:
-        """The layers of one block of a mixed stack: what every block has and
-        its mixer's (``kind``: `run_plan`'s, the mixer third)."""
-        return {**self._block_layers, **self._mixer_layers[kind[2]]}
+        """The ``nn`` layers of one block of a mixed stack: what every block has,
+        its mixer's (``kind``: `run_plan`'s, the mixer third) and, for a leading
+        dense layer of a stack with experts, the dense MLP's (an expert layer's
+        ``moe`` is no ``nn`` layer: `_init_run_block` adds it)."""
+        dense = self._dense_mlp_layers if _mlp_of(kind) == "dense" else {}
+        return {**self._block_layers, **self._mixer_layers[kind[2]], **dense}
+
+    def _init_run_block(self, kind, r, dtype) -> Params:
+        """One block of a mixed stack's run, as `init_block` makes a plain one's."""
+        block, _ = nn.init_tree(self._layers_of(kind), r, dtype)
+        if _mlp_of(kind) == "experts":
+            block["moe"] = self._moe.init(jax.random.fold_in(r, 7), dtype)
+        return block
+
+    @property
+    def _leading_dense(self) -> int:
+        """The dense layers that run before the stack from a tree of their own
+        (``params["dense_blocks"]``): a plain stack's ``first_dense_layers``; a
+        mixed stack's lie in its runs."""
+        return 0 if self.config.mixed else self.config.first_dense_layers
 
     # -- forward -------------------------------------------------------------
     def _rotate(self, x: jax.Array, positions: jax.Array) -> jax.Array:
@@ -1180,6 +1259,11 @@ class TransformerLM:
         block at a time. A decoder-hybrid-decoder stack of 32 layers: ``(scan,
         window) x 8``, ``(scan*, full) x 1``, ``(memory unit, cross) x 7``."""
         kinds = tuple(k + m for k, m in zip(self._kinds, self._mixer_kinds))
+        if self.config.moe is not None:
+            # a fifth entry where the stack has experts: the layer's MLP
+            dense = self.config.first_dense_layers
+            kinds = tuple(kind + ("dense" if l < dense else "experts",)
+                          for l, kind in enumerate(kinds))
         runs, i, n = [], 0, len(kinds)
         while i < n:
             p, r = 1, 1
@@ -1199,7 +1283,8 @@ class TransformerLM:
 
     def _scan_runs(self, init, runs: Params, keep: jax.Array, block_of, shapes_only=False):
         """A mixed stack's layers as `run_plan` lays them out over ``runs``
-        (``params["runs"]``) -> the last carry. What the boundary layers hand on
+        (``params["runs"]``) -> (the last carry, the no-drop path's rows per expert
+        ``[expert layers, experts]`` or None). What the boundary layers hand on
         is a value the later blocks take as an argument (a scan's body closes
         over it: kept once, its cotangent summed over the readers in its own,
         float32, dtype). ``shapes_only``: reckon every kind of block for the
@@ -1207,7 +1292,7 @@ class TransformerLM:
         c = self.config
         lam_init = mixers.lambda_init(c.num_layers)
         shared: Dict[str, Any] = {}
-        carry, at = init, 0
+        carry, at, rows = init, 0, []
         if shapes_only:
             shared = self._handed_shapes(*init[0].shape[:2])
         # what a kind's layer reads of an earlier layer, as one more argument
@@ -1225,7 +1310,11 @@ class TransformerLM:
             elif repeats == 1:
                 for j, kind in enumerate(unit):
                     layer = jax.tree.map(lambda a: a[0], (stacks[j],) + gates[j])
-                    carry, (_, handed) = block_of(kind)(carry, layer + args(kind))
+                    x, layer = _taken_together(carry[0], layer)
+                    carry = (x,) + tuple(carry[1:])
+                    carry, (r, handed) = block_of(kind)(carry, layer + args(kind))
+                    if r is not None:
+                        rows.append(r[None])
                     if kind[3] is not None:
                         shared[kind[3]] = handed
             else:
@@ -1235,14 +1324,18 @@ class TransformerLM:
                 extra = [args(kind) for kind in unit]
 
                 def unit_fn(carry, xs_unit):
+                    out = []
                     for fn, layer, more in zip(fns, xs_unit, extra):
-                        carry, _ = fn(carry, layer + more)
-                    return carry, None
+                        carry, (r, _) = fn(carry, layer + more)
+                        out += [] if r is None else [r]
+                    return carry, jnp.stack(out) if out else None
 
-                carry, _ = jax.lax.scan(
+                carry, r = jax.lax.scan(
                     unit_fn, carry, [(stacks[j],) + gates[j] for j in range(p)])
+                if r is not None:       # [repeats, the unit's expert layers, experts]
+                    rows.append(r.reshape((-1,) + r.shape[2:]))
             at += p * repeats
-        return carry
+        return carry, (jnp.concatenate(rows, axis=0) if rows else None)
 
     def _handed_shapes(self, B: int, S: int) -> Dict[str, Any]:
         """What the boundary layers hand on over ``B`` rows of ``S`` tokens, by
@@ -1293,15 +1386,28 @@ class TransformerLM:
                 raise ValueError("ssm_heads: Mamba-2's scan layers are named by "
                                  "layer_mixers ('ssd'), not by the ssm_period rule")
             return
-        if (len(kinds) != c.num_layers or set(kinds) - {"ssd", "mha"}
-                or "ssd" not in kinds):
+        if (len(kinds) != c.num_layers or set(kinds) - {"ssd", "kda", "mha", "latent"}
+                or not {"ssd", "kda"} & set(kinds)):
             raise ValueError(
                 f"layer_mixers {kinds!r}: one name a layer ({c.num_layers}), each 'ssd' "
-                "or 'mha', with a scan layer among them (a stack of 'mha' alone is a "
-                "plain one: leave layer_mixers None)")
+                "or 'mha', 'kda' or 'latent', with a scan layer ('ssd', 'kda') among "
+                "them (a stack of attention alone is a plain one: leave layer_mixers None)")
         if c.differential_attention or c.shared_from is not None:
             raise ValueError("layer_mixers: differential_attention and shared_from are "
                              "the ssm_period rule's stacks'")
+        if ("latent" in kinds) != (c.attention == "latent") or {"mha", "latent"} <= set(kinds):
+            raise ValueError(
+                f"layer_mixers {kinds!r} with attention={c.attention!r}: a list's attention "
+                "layers are all 'mha' or all 'latent' (attention='latent' gives the heads' "
+                "sizes), not both")
+        if ("kda" in kinds) != bool(c.kda_heads) or ("ssd" in kinds) != bool(c.ssm_heads):
+            raise ValueError(
+                f"layer_mixers {kinds!r}: 'kda' layers need kda_heads ({c.kda_heads}) and "
+                f"'ssd' layers ssm_heads ({c.ssm_heads}), and neither is set without its layers")
+        if c.moe is not None and (c.moe_layer_freq != 1 or c.moe.capacity_factor is not None):
+            raise NotImplementedError(
+                "layer_mixers with experts: every layer after the leading dense ones is "
+                "an expert layer of the no-drop path (moe_layer_freq=1, capacity_factor=None)")
 
     def _route_ahead(self, block: Params, x: jax.Array):
         """An expert block's routing from its own un-normed input ``x``, made
@@ -2006,7 +2112,7 @@ class TransformerLM:
             keep = jnp.ones((c.num_layers,), c.dtype)
         else:
             keep = layer_mask.astype(c.dtype)
-        dense = c.first_dense_layers
+        dense = self._leading_dense
         after = keep[dense:]         # the gates of the layers `_stack` runs
         # FarSkip carries two streams: (r_(i-1), r_(i-2)), r_(-1) = r_0;
         # hyper-connections n, which start as n copies of the embedding
@@ -2097,7 +2203,7 @@ class TransformerLM:
         module's None."""
         c = self.config
         layers = collections.Counter(
-            ("dense", kind) for kind in self._kinds[:c.first_dense_layers])
+            ("dense", kind) for kind in self._kinds[:self._leading_dense])
         if c.mixed:
             runs = self.run_plan
         else:
@@ -2318,7 +2424,7 @@ class TransformerLM:
         per expert or None). ``keep``: those layers' gates. ``shapes_only``: reckon every kind of block for
         the remat budget (`_KeptBlock.reckon`) and run nothing."""
         if self.config.mixed:
-            return self._scan_runs(init, params["runs"], keep, block_of, shapes_only), None
+            return self._scan_runs(init, params["runs"], keep, block_of, shapes_only)
         xs = (params["blocks"], keep)
         if not shapes_only:
             return self._scan_by_kind(init, xs, block_of)
@@ -2669,6 +2775,19 @@ class TransformerLM:
             return {**new_tree, "moe": {**new_tree["moe"], "bias": bias.astype(
                 new_tree["moe"]["bias"].dtype)}}
 
+        if self.config.mixed:
+            # a run's stack (run i, place j of a unit of p) holds layers at + j, at +
+            # j + p, ...; every layer after the leading dense ones is an expert layer
+            runs, at, dense = {}, 0, self.config.first_dense_layers
+            for i, (unit, repeats) in enumerate(self.run_plan):
+                p, run = len(unit), dict(new["runs"][str(i)])
+                for j, kind in enumerate(unit):
+                    if "moe" in run[str(j)]:
+                        lo = at + j - dense
+                        run[str(j)] = held(run[str(j)], old["runs"][str(i)][str(j)],
+                                           None if load is None else load[lo:lo + p * repeats:p])
+                runs[str(i)], at = run, at + p * repeats
+            return {**new, "runs": runs}
         out = {**new, "blocks": held(new["blocks"], old["blocks"],
                                      None if load is None else load[:n])}
         if "mtp" in new:

@@ -251,6 +251,38 @@ class ScanParams:
                 "A_log": P(MODEL_AXIS, None), "D": P(MODEL_AXIS), "dt_bias": P(MODEL_AXIS)}
 
 
+@dataclasses.dataclass(frozen=True)
+class DeltaParams:
+    """A Kimi Delta Attention layer's small arrays (arXiv:2510.26692; the caller
+    reads them itself): the three depthwise convolutions ``conv_q`` / ``conv_k`` /
+    ``conv_v`` ``[taps, channels]`` (tap k multiplies the input ``taps - 1 - k``
+    tokens back; no bias), ``A_log`` ``[heads]`` (``A = -exp(A_log)``, the log of a
+    uniform draw in [1, 16]) and ``dt_bias`` ``[channels]`` (the inverse softplus of
+    a log-uniform draw in [1e-3, 1e-1]): Mamba-2's initialisers, a channel's bias
+    where Mamba-2 has a head's."""
+    heads: int
+    channels: int
+    taps: int = 4
+
+    def init(self, rng, dtype=jnp.float32) -> Params:
+        k_conv, k_dt = jax.random.split(rng)
+        bound = self.taps ** -0.5
+        dt = jnp.exp(jax.random.uniform(k_dt, (self.channels,), jnp.float32)
+                     * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+        convs = {name: jax.random.uniform(key, (self.taps, self.channels), jnp.float32,
+                                          -bound, bound).astype(dtype)
+                 for name, key in zip(("conv_q", "conv_k", "conv_v"),
+                                      jax.random.split(k_conv, 3))}
+        return {**convs,
+                "A_log": jnp.log(jax.random.uniform(
+                    jax.random.fold_in(k_dt, 1), (self.heads,), jnp.float32, 1.0, 16.0)
+                ).astype(dtype),
+                "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)}
+
+    def specs(self) -> Params:
+        return {name: P() for name in ("conv_q", "conv_k", "conv_v", "A_log", "dt_bias")}
+
+
 def gelu(x: jax.Array) -> jax.Array:
     return jax.nn.gelu(x, approximate=True)
 

@@ -233,11 +233,12 @@ def resolve_policy(name: Optional[str]):
 SAVE_ORDER = (("indexer_kl_dq", "indexer_kl_dk", "indexer_kl_dw"), ("dsa_mask",), ("attn_lse_dsa", "attn_o_dsa"),
               ("attn_lse", "attn_o"), ("attn_lse_mla", "attn_o_mla"),
               ("attn_lse_diff", "attn_o_diff"), ("ssm_m", "ssm_state"),
-              ("ssd_m", "ssd_state"),
+              ("ssd_m", "ssd_state"), ("kda_o", "kda_state"),
               ("eva_kbar", "eva_vbar"),
               ("moe_logits",), ("wi_gate", "wi_up"),
               ("wi",), ("wo",), ("fc_in",), ("gate_proj", "up_proj"),
               ("o_proj",), ("attn_gate",), ("ssm_in", "ssm_z", "gmu_in"),
+              ("kda_decay", "kda_gate"),
               ("kv_proj",), ("ssm_dt", "ssm_x"),
               ("q_proj", "k_proj", "v_proj", "kv_latent", "q_latent", "q_b_proj",
                "indexer_q", "indexer_k"),

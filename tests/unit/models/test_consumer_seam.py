@@ -55,6 +55,7 @@ USES = {
     "ssm_state": dict(ssm_state=4, ssm_dt_rank=2),
     "ssm_heads": dict(ssm_state=4, ssm_heads=2, ssm_head_dim=8, layer_mixers=("ssd", "ssd")),
     "layer_mixers": dict(ssm_state=4, ssm_heads=2, ssm_head_dim=8, layer_mixers=("ssd", "mha")),
+    "kda_heads": dict(kda_heads=2, kda_head_dim=8, layer_mixers=("kda", "mha")),
     "residual_scale": dict(residual_scale=0.5),
     "logits_divisor": dict(logits_divisor=2.0),
     "differential_attention": dict(differential_attention=True),
@@ -157,6 +158,7 @@ PLAIN = {
     "hc_sinkhorn_iters", "hc_eps", "hc_res_clamp",
     "ssm_conv", "ssm_expand", "ssm_dt_rank", "ssm_period",
     "ssm_head_dim", "ssm_groups", "ssm_chunk",
+    "kda_head_dim", "kda_conv",
 }
 
 

@@ -1,5 +1,5 @@
 """A layer's token mixer as a value (``deepspeed_tpu/models/mixers.py``): over
-the nine benchmark cells' tiny presets and the dense families, a model's
+the benchmark cells' tiny presets and the dense families, a model's
 mixers' layers are exactly a block's leaves beside the norms and the MLP, their
 counts add up to ``num_parameters`` and to what ``init`` makes, each kind's
 record has the keys docs/OBSERVABILITY.md lists for it, and the two remat
@@ -27,6 +27,7 @@ PRESETS = {
     "xing4-tiny": (models.xing4_model, ["latent"]),
     "phi4flash-tiny": (models.phi4flash_model, ["ssm", "attn", "gmu", "cross"]),
     "granite-hybrid-tiny": (models.granite_hybrid_model, ["ssd", "mha"]),
+    "kimi-linear-tiny": (models.kimi_linear_model, ["kda", "latent"]),
     "llama2-tiny": (models.llama_model, ["mha"]),
     "bert-tiny": (models.bert_model, ["mha"]),
     "falcon-tiny": (models.falcon_model, ["mha"]),
@@ -73,7 +74,9 @@ def test_a_models_mixers_layers_are_its_blocks_leaves(built):
         own = set(model._mixers[kind].layers())
         assert own and not own & OTHERS
         assert set(block) - OTHERS == own
-        assert set(block) - own == (set(model._block_layers) | {"moe"} & set(block)) - own
+        # (an expert layer's ``moe``, a leading dense layer's MLP in a listed stack)
+        other = ({"moe"} | set(model._dense_mlp_layers)) & set(block)
+        assert set(block) - own == (set(model._block_layers) | other) - own
 
 
 def test_the_counts_add_up(built):
@@ -114,11 +117,14 @@ RECORD_KEYS = {
     "ssm": {"kind", "heads", "head_dim", "groups", "layers", "memory_units", "d_inner",
             "d_state", "conv", "dt_rank", "route", "chunk", "tile"},
     "diff": {"qk_dim", "v_dim", "launches_a_layer", "shared_readers"},
+    "kda": {"heads", "key_dim", "value_dim", "conv", "gate_rank", "layers", "route", "chunk",
+            "tile"},
     "diffusion": {"block_length", "rows_per_token", "route", "dq", "layout"},
 }
 RECORDS = {"gpt2-tiny": [], "instella-tiny": [], "evabyte-tiny": ["eva"],
            "keye-vl2-tiny": ["dsa"], "xing4-tiny": ["mla"], "sdar-tiny": ["diffusion"],
-           "phi4flash-tiny": ["ssm", "diff"], "granite-hybrid-tiny": ["ssm"]}
+           "phi4flash-tiny": ["ssm", "diff"], "granite-hybrid-tiny": ["ssm"],
+           "kimi-linear-tiny": ["kda", "mla"]}
 
 
 @pytest.mark.parametrize("preset", sorted(RECORDS))

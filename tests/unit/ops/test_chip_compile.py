@@ -180,6 +180,25 @@ class TestCompilesForV5e:
         assert "tpu_custom_call" in text and "ssd_fwd" in text and "ssd_bwd" in text
         assert "[32768,64,64,128]" not in text and "[128,256,256,64]" not in text
 
+    def test_kda_fwd_bwd_at_32k(self, chip):
+        """The kimi-linear-48b-a3b cell's Kimi Delta Attention core (PR 68): 32,768
+        rows of 32 heads with keys and values of 128, forward and backward (the
+        backward is ``jax.vjp`` of the chunk traced into the kernel: every product in
+        it must be one Mosaic takes), and no state a token in the program."""
+        from deepspeed_tpu.ops.transformer import pallas_kda
+        R, H, D = 32768, 32, 128
+
+        def loss(q, k, v, g, beta, first):
+            return jnp.sum(pallas_kda.kda_kernel(q, k, v, g, beta, first,
+                                                 interpret=False).astype(F32))
+
+        fn = jax.value_and_grad(loss, argnums=tuple(range(5)))
+        args = (chip((R, H * D), BF16), chip((R, H * D), BF16), chip((R, H * D), BF16),
+                chip((R, H * D), F32), chip((R, H), F32), chip((R,), I32))
+        text = jax.jit(fn).lower(*args).compile().as_text()    # (one compile: 10 s)
+        assert "tpu_custom_call" in text and "kda_fwd" in text and "kda_bwd" in text
+        assert "[32768,32,128,128]" not in text
+
     def test_blockdiff_attention_at_8k(self, chip, monkeypatch):
         """The sdar-30b-a3b cell's attention core: 32 query heads over 4 key
         heads of 128, 16,384 rows (a clean and a noised copy of 8,192

@@ -161,6 +161,10 @@ KERNEL_NAMES = {
     # ``pallas_ssd``), under ``ssm/ssd``: ``ssm_ssd_roofline`` finds the launches
     # by these names
     "ssd_fwd", "ssd_bwd",
+    # the Kimi Delta Attention core a head and a span of rows at a time (PR 68;
+    # ``pallas_kda``), under ``attn/core_kda``: ``attn_kda_roofline`` finds the
+    # launches by these names
+    "kda_fwd", "kda_bwd",
     # ``dO x O``'s row sum for a launch whose operands lie by rows (PR 51): NOT
     # ``flash_bwd*``, whose readers sum the backward launches alone
     "flash_delta",
@@ -199,9 +203,9 @@ def test_every_pallas_call_has_a_name(site):
 
 
 def test_kernel_names_are_distinct_and_complete():
-    assert len(PALLAS_SITES) == 27
+    assert len(PALLAS_SITES) == 29
     names = [v for _, _, n in PALLAS_SITES for v in _names_of(n)]
-    assert len(set(names)) == len(names) == 43
+    assert len(set(names)) == len(names) == 45
     assert set(names) == KERNEL_NAMES
 
 
